@@ -19,6 +19,5 @@ Layer map (bottom → top), mirroring SURVEY.md §1:
 
 __version__ = "0.1.0"
 
-import cake_tpu.utils.compat  # noqa: F401  (jax API shims, side-effect)
 from cake_tpu.topology import Topology, Node  # noqa: F401
 from cake_tpu.args import Args, SDArgs, ImageGenerationArgs  # noqa: F401

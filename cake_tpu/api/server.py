@@ -570,6 +570,11 @@ class ApiServer:
             # operators can see what the autotuner chose; the epoch
             # pairs with per-request trace attribution
             out["engine_config"] = eng.current_config().to_dict()
+            # what each step kind actually RUNS (the engine resolves
+            # it from the dispatched shapes) beside the paged_attn
+            # name the config asked for
+            out["engine_config"]["attn_impl"] = dict(
+                getattr(eng, "attn_impl", {}))
         return out
 
     def autotune(self) -> dict:

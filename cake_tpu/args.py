@@ -106,8 +106,6 @@ class Args:
     # --draft-model (the spec engine is gated off the paged pool).
     # None = same as dtype.
     kv_dtype: Optional[str] = None      # + f8_e4m3 | f8_e5m2 | int8 | int4
-    cpu: bool = False
-    device_idx: int = 0
     max_seq_len: int = 4096             # reference hard constant (config.rs:6); tunable here
     batch_size: int = 1
     max_slots: int = 8                  # continuous-batching decode slots (API serving)

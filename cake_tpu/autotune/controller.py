@@ -82,8 +82,9 @@ class AutotuneSignals:
     completed_rps: float = 0.0    # retirements per second
     queue_depth: int = 0
     queue_depth_by_class: Dict[str, int] = field(default_factory=dict)
-    mfu: float = 0.0
-    hbm_util: float = 0.0
+    # None = the device kind has no peak in obs/steps.py's table
+    mfu: Optional[float] = None
+    hbm_util: Optional[float] = None
     pages_in_use_frac: float = 0.0
     shed_rps: float = 0.0
     ttft_p99_s: Optional[float] = None
@@ -107,11 +108,13 @@ class AutotuneSignals:
             "service_tps": round(self.service_tps, 3),
             "completed_rps": round(self.completed_rps, 3),
             "queue_depth": self.queue_depth,
-            "mfu": round(self.mfu, 4),
-            "hbm_util": round(self.hbm_util, 4),
             "pages_in_use_frac": round(self.pages_in_use_frac, 4),
             "shed_rps": round(self.shed_rps, 3),
         }
+        if self.mfu is not None:
+            out["mfu"] = round(self.mfu, 4)
+        if self.hbm_util is not None:
+            out["hbm_util"] = round(self.hbm_util, 4)
         if self.queue_depth_by_class:
             out["queue_depth_by_class"] = dict(self.queue_depth_by_class)
         if self.ttft_p99_s is not None:

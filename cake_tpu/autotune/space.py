@@ -88,17 +88,16 @@ class EngineConfig:
 def resolve_paged_attn(paged_attn: Optional[str]) -> str:
     """THE paged_attn auto-resolution rule — pallas on a real TPU,
     fold elsewhere (interpret-mode pallas on CPU is slow) — shared by
-    the engine's dispatch setup (serve/engine._setup_paged_exec) and
+    the engine's dispatch setup (serve/engine._resolve_paged_attn,
+    which then checks the kernels' shape gates per step kind) and
     config_key, so the comparison key can never resolve "auto"
     differently from the engine. Non-auto names pass through
-    unvalidated (the engine validates at dispatch setup)."""
+    unvalidated (the engine validates at dispatch setup). A backend
+    that fails to initialise raises here; it is not read as "no TPU"."""
     impl = paged_attn or "auto"
     if impl == "auto":
-        try:
-            import jax
-            impl = "pallas" if jax.default_backend() == "tpu" else "fold"
-        except Exception:  # noqa: BLE001 — comparison key, not dispatch
-            impl = "fold"
+        import jax
+        impl = "pallas" if jax.default_backend() == "tpu" else "fold"
     return impl
 
 

@@ -489,6 +489,10 @@ def main(argv=None) -> int:
         )
         return 2
 
+    from cake_tpu.utils.compile_cache import enable_compile_cache
+    logging.getLogger(__name__).info(
+        "compile cache: %s", enable_compile_cache())
+
     # multi-host: every host runs this same program (SPMD); coordinates
     # auto-detected on TPU pods or taken from CAKE_* env vars
     from cake_tpu.parallel.distributed import initialize

@@ -7,6 +7,7 @@ On TPU it additionally owns the mesh and sharding plan (parallel/).
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 from dataclasses import dataclass, field
@@ -17,7 +18,7 @@ import jax.numpy as jnp
 
 from cake_tpu.args import Args, SDArgs
 from cake_tpu.topology import Topology
-from cake_tpu.utils.devices import get_inference_device, resolve_dtype
+from cake_tpu.utils.devices import resolve_dtype
 
 log = logging.getLogger(__name__)
 
@@ -26,10 +27,42 @@ def _resolve_flash(args: Args) -> bool:
     """--flash-attention / --no-flash-attention; default on iff real TPU."""
     if args.flash_attention is not None:
         return args.flash_attention
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+@functools.lru_cache(maxsize=8)
+def _sharded_init(cfg, dtype, bits, mesh, tp: bool):
+    """jit(random init, out_shardings=the pipeline plan's specs) for one
+    (config, dtype, quantization, mesh): the same program for every
+    server of a placement, so it is built — and compiles — once per
+    process. bits: init the dense family's int8/int4 leaves directly
+    (init_params_quantized), None = full precision."""
+    from functools import partial
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from cake_tpu.ops.quant import expand_specs_for_quant
+    from cake_tpu.parallel.pipeline import pipeline_param_specs
+
+    if cfg.is_moe:
+        from cake_tpu.models.moe.params import init_params as init
+    elif bits:
+        from cake_tpu.models.llama.params import init_params_quantized
+        init = partial(init_params_quantized, bits=bits)
+    else:
+        from cake_tpu.models.llama.params import init_params as init
+
+    def make():
+        return init(cfg, jax.random.PRNGKey(0), dtype=dtype)
+
+    shapes = jax.eval_shape(make)
+    specs = expand_specs_for_quant(shapes, pipeline_param_specs(
+        shapes["blocks"].keys(), "tp" if tp else None))
+    shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(make, out_shardings=shardings)
 
 
 @dataclass
@@ -37,14 +70,12 @@ class Context:
     args: Args
     sd_args: Optional[SDArgs]
     dtype: object
-    device: object
     topology: Optional[Topology] = None
     llama_config: Optional[object] = None
 
     @classmethod
     def from_args(cls, args: Args, sd_args: Optional[SDArgs] = None) -> "Context":
         dtype = resolve_dtype(args.dtype)
-        device = get_inference_device(cpu=args.cpu, device_idx=args.device_idx)
         topology = Topology.from_path(args.topology) if args.topology else None
 
         llama_config = None
@@ -59,10 +90,10 @@ class Context:
                     use_flash_attention=_resolve_flash(args),
                 )
 
-        log.info("context: device=%s dtype=%s topology=%s",
-                 device, args.dtype,
-                 list(topology.keys()) if topology else None)
-        return cls(args=args, sd_args=sd_args, dtype=dtype, device=device,
+        log.info("context: devices=%s dtype=%s topology=%s",
+                 [f"{d.platform}/{d.device_kind}" for d in jax.devices()],
+                 args.dtype, list(topology.keys()) if topology else None)
+        return cls(args=args, sd_args=sd_args, dtype=dtype,
                    topology=topology, llama_config=llama_config)
 
     # -- model loading -------------------------------------------------------
@@ -92,24 +123,27 @@ class Context:
 
         from cake_tpu.models import load_text_params
         from cake_tpu.parallel.plan import ParallelPlan
-        from cake_tpu.utils.loading import has_weights
         plan = ParallelPlan.from_topology(cfg, self.topology, args=a)
 
-        # stage-local streaming load (reference worker.rs:106-127 parity,
-        # per shard): with a sharded placement and real weights on disk,
-        # every tensor lands directly on its mesh shard — no full-model
+        # stage-local load (reference worker.rs:106-127 parity, per
+        # shard): with a sharded placement the tree is BORN on its mesh
+        # shards (_params_on_mesh: streamed from disk, or random-
+        # initialised under the plan's shardings) — no full-model
         # host/device copy ever exists, which is what lets a 70B (or
         # Mixtral-8x22B) topology actually load instead of dying at the
         # eager full-tree load.
-        stream_sharded = (
+        born_sharded = (
             (plan.stages > 1 or plan.tp > 1 or plan.dp > 1)
-            and (a.sp <= 1 or plan.stages > 1) and has_weights(a.model)
+            and (a.sp <= 1 or plan.stages > 1)
         )
-        if stream_sharded:
-            params = None   # loaded inside the topology branch, post-mesh
+        if born_sharded:
+            params = None   # built inside the topology branch, post-mesh
         else:
-            params = load_text_params(cfg, a.model, self.dtype)
-            params = self._maybe_quantize(params)
+            params = load_text_params(cfg, a.model, self.dtype,
+                                      quant=a.quant)
+            if a.quant in ("int8", "int4"):
+                log.info("weights quantized to %s as they loaded "
+                         "(weight-only)", a.quant)
 
         # --repeat-penalty unset -> reference default 1.1 (llama.rs:311);
         # speculative mode resolves unset to 1.0 instead (parallel verify
@@ -225,9 +259,8 @@ class Context:
                 from cake_tpu.parallel.sp_pipeline import (
                     place_sp_stage_params,
                 )
-                if params is None:   # streaming stage-local load
-                    params = self._load_params_streamed(cfg, mesh, tp > 1)
-                    params = self._maybe_quantize(params)
+                if params is None:
+                    params = self._params_on_mesh(cfg, mesh, tp > 1)
                 params = place_sp_stage_params(mesh, cfg, params,
                                                tp=tp > 1)
             elif dp > 1 or tp > 1:
@@ -285,9 +318,8 @@ class Context:
                 dp_axis="dp" if dp else None,
                 stage_axis="stage", dtype=kv_dtype,
             )
-            if params is None:   # streaming stage-local load (see above)
-                params = self._load_params_streamed(cfg, mesh, tp)
-                params = self._maybe_quantize(params)
+            if params is None:
+                params = self._params_on_mesh(cfg, mesh, tp)
             params, cache = place_for_pipeline(params, cache, mesh,
                                                tp=tp, dp=dp)
             fwd = make_pipeline_forward(
@@ -322,6 +354,25 @@ class Context:
         from cake_tpu.utils.profiling import log_memory
         log_memory("model loaded")  # reference llama.rs:233-236
         return gen
+
+    def _params_on_mesh(self, cfg, mesh, tp: bool):
+        """The (maybe quantized) param tree born on its pipeline shards:
+        streamed from disk when weights exist, else random-initialised
+        under jit with the plan's shardings as out_shardings — each
+        device generates its own shard, so a weightless 8B topology
+        never builds the tree on device 0 first."""
+        from cake_tpu.utils.loading import has_weights
+
+        if has_weights(self.args.model):
+            return self._maybe_quantize(
+                self._load_params_streamed(cfg, mesh, tp))
+        log.warning("no weights at %r; using random init",
+                    self.args.model)
+        bits = {"int8": 8, "int4": 4}.get(self.args.quant)
+        # the MoE family has no direct quantized init: shard-wise after
+        direct_bits = None if cfg.is_moe else bits
+        params = _sharded_init(cfg, self.dtype, direct_bits, mesh, tp)()
+        return self._maybe_quantize(params) if cfg.is_moe else params
 
     def _load_params_streamed(self, cfg, mesh, tp: bool):
         """Stream weights from disk directly onto their pipeline shards
@@ -399,8 +450,8 @@ class Context:
                 f"draft vocab {d_cfg.vocab_size} != target vocab "
                 f"{cfg.vocab_size}: speculation verifies draft token ids "
                 "directly, so the models must share a tokenizer")
-        d_params = self._maybe_quantize(
-            load_text_params(d_cfg, d_dir, self.dtype))
+        d_params = load_text_params(d_cfg, d_dir, self.dtype,
+                                    quant=a.quant)
         log.info("speculative serving: gamma=%d draft=%s", a.spec_gamma,
                  d_dir or "<random tiny>")
         return SpeculativeGenerator(

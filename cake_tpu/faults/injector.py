@@ -154,7 +154,7 @@ class FaultInjector:
         if kind == "oom":
             raise InjectedOOM(site)
         if kind == "wedge":
-            # the compressed form of a hung device/tunnel: hold the
+            # the compressed form of a hung device: hold the
             # calling thread (outside the lock — other sites must keep
             # evaluating), then surface as a failure
             time.sleep(fire.rule.secs)

@@ -20,7 +20,7 @@ Grammar (rules separated by ``;``, fields inside a rule by ``:``)::
     error  := 'transient'    a generic retryable step failure (XLA-ish)
              | 'oom'         a simulated RESOURCE_EXHAUSTED
              | 'wedge'       hold the calling thread for `secs`, then
-                             raise (a hung device/tunnel, compressed)
+                             raise (a hung device, compressed)
              | 'abort'       hard process death via os._exit
                              (ABORT_EXIT_CODE) — a staged kill -9 for
                              restart/journal-replay crash drills
@@ -112,7 +112,7 @@ class InjectedOOM(InjectedFault):
 
 class InjectedWedge(InjectedFault):
     """Raised after a wedge rule's hold expires — the compressed form
-    of a hung accelerator/tunnel (block, then fail)."""
+    of a hung accelerator (block, then fail)."""
 
     kind = "wedge"
 

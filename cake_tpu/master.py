@@ -210,7 +210,7 @@ class Master:
                 g.config, slots,
                 g.config.sliding_window if ring else g.max_seq_len, mesh,
                 tp_axis="tp" if tp else None, dp_axis=None,
-                stage_axis="stage", dtype=g.cache.k.dtype,
+                stage_axis="stage", dtype=g.cache_dtype,
             )
             kwargs = dict(
                 step_fns=make_engine_step_fns(
@@ -226,7 +226,7 @@ class Master:
             sampling=g.sampling,
             seed=self.args.seed,
             decode_scan_steps=self.args.decode_scan,
-            cache_dtype=g.cache.k.dtype,  # follow --kv-dtype
+            cache_dtype=g.cache_dtype,  # follow --kv-dtype
             # honored by the paged (--kv-pages) engine too: prefixes
             # prefill once into pool pages and map shared, and chunked
             # prefill windows scatter into pages at any offset

@@ -42,12 +42,20 @@ class ImageGenerator(Protocol):
 from cake_tpu.models.chat import Message, MessageRole, History  # noqa: E402,F401
 
 
-def load_text_params(config, model_dir: Optional[str], dtype, rng=None):
+def load_text_params(config, model_dir: Optional[str], dtype, rng=None,
+                     quant: Optional[str] = None):
     """Parameter pytree for any text-model family, keyed by the config.
 
     HF safetensors when present under model_dir, else random init (with a
     warning). Family dispatch (dense Llama vs MoE) lives here, next to
     load_config's model_type dispatch, so app layers never branch on it.
+
+    quant ("int8" | "int4", --quant): the tree comes back as
+    ops/quant.quantize_params would leave it, WITHOUT the full-precision
+    tree ever existing on the device — weights on disk quantize leaf by
+    leaf as each tensor lands, and a weightless dense model inits its
+    quantized leaves directly (init_params_quantized). An 8B bf16 tree is
+    ~15 GiB, most of a v5e's HBM; load-then-quantize cannot start there.
     """
     import logging
 
@@ -64,9 +72,25 @@ def load_text_params(config, model_dir: Optional[str], dtype, rng=None):
         from cake_tpu.models.llama.params import (
             init_params, load_params_from_hf,
         )
+    bits = {"int8": 8, "int4": 4}.get(quant)   # None / "none": as is
     if has_weights(model_dir):
-        return load_params_from_hf(model_dir, config, dtype=dtype)
+        finish = None
+        if bits:
+            from cake_tpu.ops.quant import make_leaf_quantizer
+            finish = make_leaf_quantizer(bits)
+        return load_params_from_hf(model_dir, config, dtype=dtype,
+                                   finish=finish)
     logging.getLogger(__name__).warning(
         "no weights at %r; using random init", model_dir)
-    return init_params(config, rng if rng is not None
-                       else jax.random.PRNGKey(0), dtype=dtype)
+    rng = rng if rng is not None else jax.random.PRNGKey(0)
+    if not bits:
+        return init_params(config, rng, dtype=dtype)
+    if not is_moe:
+        from cake_tpu.models.llama.params import init_params_quantized_jit
+        return init_params_quantized_jit(config, rng, dtype=dtype,
+                                         bits=bits)
+    # no direct quantized init for the MoE family yet: the tiny MoE
+    # presets fit either way, a published-width one would not
+    from cake_tpu.ops.quant import quantize_params_leafwise
+    return quantize_params_leafwise(init_params(config, rng, dtype=dtype),
+                                    bits=bits)

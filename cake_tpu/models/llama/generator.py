@@ -162,11 +162,26 @@ class LlamaGenerator:
                 f"prefill_chunk {prefill_chunk} must be >= 1 and divide "
                 f"max_seq_len {max_seq_len}")
         self.prefill_chunk = prefill_chunk
-        self.cache = cache if cache is not None else KVCache.create(
-            config, batch_size, max_seq_len, dtype=cache_dtype)
+        # the dense [L, batch, max_seq, KV, hd] cache is built on first
+        # use: an API server decodes from its engine's own cache and
+        # never touches this one (0.25-0.5 GiB at 8B widths)
+        self.cache_dtype = cache_dtype if cache is None else cache.k.dtype
+        self._cache = cache
         self.history = History(config.chat_template)
         self.rng = jax.random.PRNGKey(seed)
         self._reset_session()
+
+    @property
+    def cache(self) -> KVCache:
+        if self._cache is None:
+            self._cache = KVCache.create(
+                self.config, self.batch_size, self.max_seq_len,
+                dtype=self.cache_dtype)
+        return self._cache
+
+    @cache.setter
+    def cache(self, value) -> None:
+        self._cache = value
 
     # -- TextGenerator protocol ---------------------------------------------
 
@@ -178,7 +193,8 @@ class LlamaGenerator:
         the full KV cache (explicit pipeline-wide reset; see SURVEY.md §3.3
         for the reference wart this avoids)."""
         self.history.clear()
-        self.cache = self.cache.fresh()
+        if self._cache is not None:
+            self._cache = self._cache.fresh()
         self._reset_session()
 
     def _reset_session(self) -> None:

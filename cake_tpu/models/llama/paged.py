@@ -352,8 +352,10 @@ def paged_attention(q, pool_k, pool_v, table, pos, *, impl: str = "fold"):
     copy ever exists. impl="pallas": the TPU-native single kernel
     (ops/ragged_paged_attention.py) — same math, but each row streams
     only its LIVE pages through VMEM and exits at ceil((pos+1)/page)
-    instead of folding the whole pool; falls back to the fold on
-    hardware-untileable shapes (tiny test configs).
+    instead of folding the whole pool. The caller picks the impl the
+    shapes allow (serve/engine._setup_paged_exec resolves it once); on
+    a chip the kernel raises on a shape its gate refuses — nothing here
+    falls back.
 
     q: [B, 1, H, hd] (rope already applied; the current token's KV must
     already be written to its page); pool_k/v: [N_pages, page, KV, hd];
@@ -371,17 +373,15 @@ def paged_attention(q, pool_k, pool_v, table, pos, *, impl: str = "fold"):
 
     if impl == "pallas":
         from cake_tpu.ops.ragged_paged_attention import (
-            ragged_paged_attention, ragged_paged_supported,
+            ragged_paged_attention,
         )
-        if ragged_paged_supported(P, H, KV, hd, quantized=quant,
-                                  n_pages=N, packed4=packed4):
-            if quant:
-                return ragged_paged_attention(
-                    q, pool_k.q, pool_v.q, table, pos,
-                    scale_k=pool_k.scale, scale_v=pool_v.scale,
-                    packed4=packed4)
-            return ragged_paged_attention(q, pool_k, pool_v, table, pos)
-    elif impl != "fold":
+        if quant:
+            return ragged_paged_attention(
+                q, pool_k.q, pool_v.q, table, pos,
+                scale_k=pool_k.scale, scale_v=pool_v.scale,
+                packed4=packed4)
+        return ragged_paged_attention(q, pool_k, pool_v, table, pos)
+    if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
 
     m0 = jnp.full((B, KV, H // KV, 1, 1), -1e30, jnp.float32)
@@ -441,9 +441,9 @@ def paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
     per-slot copy ever exists. impl="pallas": the mixed TPU kernel
     (ops/ragged_paged_attention.ragged_paged_attention_mixed) — same
     math, but each row streams only the pages up to
-    ceil((pos + q_len)/page); falls back to the fold on
-    hardware-untileable shapes (tiny test configs) and on chunk widths
-    whose C-scaled scratch would overflow VMEM (large --prefill-chunk).
+    ceil((pos + q_len)/page). As for decode, the caller resolves the
+    impl from the shapes (chunk widths whose C-scaled scratch overflows
+    VMEM take the fold); the kernel raises rather than fall back.
 
     q: [B, C, H, hd] (rope applied; every real query token's KV already
     written to its page); pos: [B] position of each row's FIRST query;
@@ -461,19 +461,16 @@ def paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
 
     if impl == "pallas":
         from cake_tpu.ops.ragged_paged_attention import (
-            ragged_paged_attention_mixed, ragged_paged_mixed_supported,
+            ragged_paged_attention_mixed,
         )
-        if ragged_paged_mixed_supported(P, H, KV, hd, C,
-                                        quantized=quant, n_pages=N,
-                                        packed4=packed4):
-            if quant:
-                return ragged_paged_attention_mixed(
-                    q, pool_k.q, pool_v.q, table, pos, q_len,
-                    scale_k=pool_k.scale, scale_v=pool_v.scale,
-                    packed4=packed4)
-            return ragged_paged_attention_mixed(q, pool_k, pool_v,
-                                                table, pos, q_len)
-    elif impl != "fold":
+        if quant:
+            return ragged_paged_attention_mixed(
+                q, pool_k.q, pool_v.q, table, pos, q_len,
+                scale_k=pool_k.scale, scale_v=pool_v.scale,
+                packed4=packed4)
+        return ragged_paged_attention_mixed(q, pool_k, pool_v,
+                                            table, pos, q_len)
+    if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
 
     G = H // KV

@@ -74,9 +74,16 @@ MOE_EXPERT_LAYOUT = (("we_gate", "w1"), ("we_up", "w3"),
 
 def load_params_from_hf(model_dir: str, config: MoEConfig,
                         dtype=jnp.bfloat16,
-                        layer_range: Optional[range] = None):
-    """Build the MoE pytree from HF Mixtral safetensors."""
+                        layer_range: Optional[range] = None,
+                        finish=None):
+    """Build the MoE pytree from HF Mixtral safetensors. finish: (leaf
+    name, device array) -> the leaf to keep, applied as each tensor
+    lands (see models/llama/params.load_params_from_hf)."""
     from cake_tpu.utils.loading import load_weights
+
+    if finish is None:
+        def finish(_name, arr):
+            return arr
 
     c = config
     L, E = c.num_hidden_layers, c.num_local_experts
@@ -102,28 +109,29 @@ def load_params_from_hf(model_dir: str, config: MoEConfig,
         return (arr.T if transpose else arr).astype(nd)
 
     blocks = {
-        key: jnp.asarray(np.stack([
+        key: finish(key, jnp.asarray(np.stack([
             t(f"model.layers.{i}.{suffix}", tr) for i in layers
-        ]))
+        ])))
         for key, (suffix, tr) in attn.items()
     }
     # Experts: HF w1 [F, D] = gate, w3 [F, D] = up (both -> [D, F]);
     # w2 [D, F] = down (-> [F, D]).
     for key, wn in MOE_EXPERT_LAYOUT:
-        blocks[key] = jnp.asarray(np.stack([
+        blocks[key] = finish(key, jnp.asarray(np.stack([
             np.stack([
                 t(f"model.layers.{i}.{moe}.experts.{e}.{wn}.weight", True)
                 for e in range(E)
             ]) for i in layers
-        ]))
+        ])))
 
     params = {
         "blocks": blocks,
         "embed": jnp.asarray(t("model.embed_tokens.weight", False)),
         "final_norm": jnp.asarray(t("model.norm.weight", False)),
     }
-    params["lm_head"] = (params["embed"].T if c.tie_word_embeddings
-                         else jnp.asarray(t("lm_head.weight", True)))
+    params["lm_head"] = finish("lm_head", (
+        params["embed"].T if c.tie_word_embeddings
+        else jnp.asarray(t("lm_head.weight", True))))
     return params
 
 

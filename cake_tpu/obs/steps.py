@@ -39,10 +39,9 @@ pieces, all dependency-free:
 
 MFU here is model-FLOPs utilization: (program FLOPs from
 cost_analysis) / (peak chip FLOP/s x measured step seconds), clamped to
-1.0. On backends whose peak is unknown (CPU) a conservative fallback
-peak keeps the number well-defined — treat it as relative, not
-absolute, off-TPU. HBM utilization is bytes-accessed over the chip's
-HBM bandwidth the same way. Both are estimates from *unoptimized* HLO:
+1.0. A device kind that is not in the peak table (a CPU) has no peak,
+so its records carry no mfu/hbm_util at all. HBM utilization is
+bytes-accessed over the chip's HBM bandwidth the same way. Both are estimates from *unoptimized* HLO:
 fusion changes the real byte traffic, but the trend per step and the
 fold-vs-pallas/bucket-vs-bucket comparisons are exactly what they are
 for.
@@ -67,9 +66,9 @@ log = logging.getLogger(__name__)
 # specs), first match wins. THE single table for the whole repo —
 # bench.py delegates here, so the measured (flight recorder) and
 # analytic (roofline) utilization numbers in one BENCH row can never
-# use different hardware constants. Unknown-TPU / CPU fallbacks differ:
-# an unknown accelerator gets a conservative TPU-class figure, a CPU
-# lane a host-class one (the CPU numbers are relative either way).
+# use different hardware constants. A kind that is not in the table has
+# no peak: the lookups return None and no utilization is reported for
+# it (a CPU lane prints no mfu/hbm_util at all).
 PEAK_FLOPS = [
     ("v5 lite", 197e12), ("v5e", 197e12),
     ("v5p", 459e12), ("v5", 459e12),
@@ -77,11 +76,8 @@ PEAK_FLOPS = [
     ("v4", 275e12),
     ("v3", 123e12),
 ]
-DEFAULT_PEAK_FLOPS = 197e12        # unknown accelerator: v5e-class
-CPU_PEAK_FLOPS = 1e12
 
-# HBM bandwidth (bytes/s) by device_kind substring (same entries and
-# defaults bench.py historically used, now sourced from here only).
+# HBM bandwidth (bytes/s) by device_kind substring (same entries).
 HBM_BPS = [
     ("v5 lite", 819e9), ("v5e", 819e9),
     ("v5p", 2765e9), ("v5", 2765e9),
@@ -89,28 +85,19 @@ HBM_BPS = [
     ("v4", 1228e9),
     ("v3", 900e9),
 ]
-DEFAULT_HBM_BPS = 819e9            # unknown accelerator: v5e-class
-CPU_HBM_BPS = 100e9
 
 
-def _is_cpu_kind(k: str) -> bool:
-    return not k or "cpu" in k
-
-
-def peak_flops_for(kind: str) -> float:
+def _lookup(table, kind: str) -> Optional[float]:
     k = (kind or "").lower()
-    for sub, v in PEAK_FLOPS:
-        if sub in k:
-            return v
-    return CPU_PEAK_FLOPS if _is_cpu_kind(k) else DEFAULT_PEAK_FLOPS
+    return next((v for sub, v in table if sub in k), None)
 
 
-def hbm_bps_for(kind: str) -> float:
-    k = (kind or "").lower()
-    for sub, v in HBM_BPS:
-        if sub in k:
-            return v
-    return CPU_HBM_BPS if _is_cpu_kind(k) else DEFAULT_HBM_BPS
+def peak_flops_for(kind: str) -> Optional[float]:
+    return _lookup(PEAK_FLOPS, kind)
+
+
+def hbm_bps_for(kind: str) -> Optional[float]:
+    return _lookup(HBM_BPS, kind)
 
 
 # -- metric families (module-level so the lint/README coverage gate sees
@@ -324,6 +311,10 @@ class JitAccountant:
 ACCOUNTANT = JitAccountant()
 
 
+def _no_cost() -> None:
+    return None
+
+
 class _JitStep:
     """Handle returned by StepTelemetry.jit_step: `.new` says this
     dispatch compiles a fresh signature, `.cost` carries the program's
@@ -402,12 +393,15 @@ class StepRecord:
             "dispatch_s": round(self.dispatch_s, 6),
             "device_s": round(self.device_s, 6),
             "wall_s": round(self.wall_s, 6),
-            # significant digits, not decimal places: a compile-inflated
-            # step's 1e-7 MFU must stay nonzero in the export
-            "mfu": _sig(self.mfu),
-            "hbm_util": _sig(self.hbm_util),
             "compiled": self.compiled,
         }
+        # absent, not null or 0, when the device kind has no peak in
+        # the table. Significant digits, not decimal places: a
+        # compile-inflated step's 1e-7 MFU must stay nonzero
+        if self.mfu is not None:
+            out["mfu"] = _sig(self.mfu)
+        if self.hbm_util is not None:
+            out["hbm_util"] = _sig(self.hbm_util)
         if self.pages_total is not None:
             out["pages_free"] = self.pages_free
             out["pages_total"] = self.pages_total
@@ -449,6 +443,8 @@ class StepTelemetry:
         self._prefix = tuple(key_prefix)
         self._peak = peak_flops
         self._bps = hbm_bps
+        self._peaks_known = (peak_flops is not None
+                             and hbm_bps is not None)
         # obs/events.EventBus (None = disabled plane, one attribute
         # test per publish): new jit signatures publish a "recompile"
         # event, so a shape-leak recompilation storm shows up on the
@@ -473,25 +469,29 @@ class StepTelemetry:
         """Account one dispatch of `fn_name` under signature `key`
         (caller-chosen: the shapes/statics that select the compiled
         program). cost_cb() -> CostInfo|None runs once per new key —
-        typically `lambda: lower_cost(fn, args, kwargs)`."""
+        typically `lambda: lower_cost(fn, args, kwargs)` — and only
+        where a peak exists to divide by: on a device kind with no
+        table entry (the CPU lane) the extra lowering buys nothing."""
+        if not any(self._peaks()):
+            cost_cb = _no_cost
         new, cost = self._acct.begin(
             fn_name, self._prefix + (fn_name,) + tuple(key), cost_cb)
         if new and self._events is not None:
             self._events.publish("recompile", fn=fn_name, impl=self.impl)
         return _JitStep(new, cost, self._acct)
 
-    def _peaks(self) -> Tuple[float, float]:
-        if self._peak is None or self._bps is None:
-            kind = ""
-            try:
-                import jax
-                kind = jax.devices()[0].device_kind
-            except Exception:  # noqa: BLE001
-                pass
+    def _peaks(self) -> Tuple[Optional[float], Optional[float]]:
+        """(peak FLOP/s, HBM bytes/s) — the pinned overrides, else the
+        table entries for this process's device kind (None = not in the
+        table, so no utilization)."""
+        if not self._peaks_known:
+            import jax
+            kind = jax.devices()[0].device_kind
             if self._peak is None:
                 self._peak = peak_flops_for(kind)
             if self._bps is None:
                 self._bps = hbm_bps_for(kind)
+            self._peaks_known = True
         return self._peak, self._bps
 
     # -- recording ----------------------------------------------------------
@@ -507,14 +507,17 @@ class StepTelemetry:
                rows_decode: Optional[int] = None,
                rows_prefill: Optional[int] = None,
                rows_idle: Optional[int] = None,
-               rids: Optional[Sequence[int]] = None) -> StepRecord:
+               rids: Optional[Sequence[int]] = None,
+               impl: Optional[str] = None) -> StepRecord:
         """Append one step record; derives MFU / HBM utilization from
         `cost` and the step's device seconds. Any subset of the three
         timings may be given; missing ones fall back to the others.
         rows_decode/rows_prefill/rows_idle carry a mixed step's
         occupancy split and feed the cake_mixed_step_rows_total
         counters. rids: the requests whose rows rode this dispatch
-        (the per-request explain's step linkage)."""
+        (the per-request explain's step linkage). impl: the attention
+        this step actually ran, where the engine resolved it per step
+        kind (default: the recorder's engine-wide flavor)."""
         wall = wall_s if wall_s is not None else (
             (dispatch_s or 0.0) + (device_s or 0.0))
         disp = dispatch_s if dispatch_s is not None else wall
@@ -522,14 +525,15 @@ class StepTelemetry:
         mfu = hbm = None
         if cost is not None and dev > 0:
             peak, bps = self._peaks()
-            if cost.flops > 0 and peak > 0:
+            if cost.flops > 0 and peak:
                 mfu = min(1.0, cost.flops / (peak * dev))
-            if cost.bytes_accessed > 0 and bps > 0:
+            if cost.bytes_accessed > 0 and bps:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
         with self._lock:
             rec = StepRecord(
                 step=self._next, ts=time.time(), kind=kind,
-                impl=self.impl, rows=int(rows), tokens=int(tokens),
+                impl=impl or self.impl, rows=int(rows),
+                tokens=int(tokens),
                 dispatch_s=float(disp), device_s=float(dev),
                 wall_s=float(wall), mfu=mfu, hbm_util=hbm,
                 pages_free=pages_free, pages_total=pages_total,
@@ -586,15 +590,16 @@ class StepTelemetry:
         compiled a new signature are excluded — their wall is XLA
         compile, not decode — and since_step drops everything up to a
         warmup boundary (pass the post-warmup
-        `summary()["recorded_steps"]`). 0.0 when no remaining record
-        carried cost info — a bench consumer always gets the keys."""
+        `summary()["recorded_steps"]`). A field is ABSENT when no
+        remaining record carried it (no cost info, or a device kind
+        with no peak in the table) — never a 0.0 stand-in."""
         kinds = _DECODE_KINDS + ("prefill",) if include_prefill \
             else _DECODE_KINDS
         with self._lock:
             recs = [r for r in self._ring
                     if r.kind in kinds and not r.compiled
                     and r.step > since_step]
-        out = {"mfu": 0.0, "hbm_util": 0.0}
+        out: Dict[str, float] = {}
         for field in ("mfu", "hbm_util"):
             num = den = 0.0
             for r in recs:

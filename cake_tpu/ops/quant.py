@@ -266,6 +266,37 @@ def quantize_params(params: dict, bits: int = 8, group: int = 128) -> dict:
     return out
 
 
+def make_leaf_quantizer(bits: int = 8, group: int = 128):
+    """(leaf name, full-precision leaf) -> that leaf as
+    quantize_params(bits=bits) would hold it: block matmul weights and
+    the lm_head become QTensors, every other leaf passes through. One
+    jitted call per leaf, so a loader that applies it as each tensor
+    lands (models.load_text_params) never holds more than ONE
+    full-precision weight leaf beside the quantized tree — an 8B bf16
+    tree is ~15 GiB, most of a v5e's HBM, and must never exist whole."""
+    import jax as _jax
+
+    if bits not in (4, 8):
+        raise ValueError(f"bits must be 4 or 8, got {bits}")
+
+    def qz(name: str, w):
+        if name == "lm_head":
+            # per-channel int8 even at bits=4 (see quantize_params)
+            return _jax.jit(lambda v: quantize(v, (0,)))(w)
+        dims = _BLOCK_CONTRACT.get(name)
+        if dims is None:
+            return w
+        if bits == 8:
+            return _jax.jit(lambda v: quantize(v, dims))(w)
+        if name.startswith("we_"):
+            raise NotImplementedError(
+                "int4 is matmul-only; MoE expert weights go through "
+                "qeinsum — use --quant int8 for MoE models")
+        return _jax.jit(lambda v: quantize_group(v, dims[0], group))(w)
+
+    return qz
+
+
 def quantize_params_leafwise(params: dict, bits: int = 4,
                              group: int = 128) -> dict:
     """quantize_params, one jitted call per leaf, dropping each
@@ -283,32 +314,15 @@ def quantize_params_leafwise(params: dict, bits: int = 4,
     through the caller's tree until return, silently losing the bound
     this function exists for.
     """
-    import jax as _jax
-
-    if bits not in (4, 8):
-        raise ValueError(f"bits must be 4 or 8, got {bits}")
+    qz = make_leaf_quantizer(bits, group)
     if bits == 4 and any(k.startswith("we_") for k in params["blocks"]):
         raise NotImplementedError(
             "int4 is matmul-only; MoE expert weights go through "
             "qeinsum — use --quant int8 for MoE models")
     src = params["blocks"]   # shared: pops drop the caller's refs too
-    blocks = {}
-    for k in list(src):
-        if k not in _BLOCK_CONTRACT:
-            blocks[k] = src[k]
-            continue
-        w = src.pop(k)
-        if bits == 4:
-            blocks[k] = _jax.jit(
-                lambda v, d=_BLOCK_CONTRACT[k][0]: quantize_group(
-                    v, d, group))(w)
-        else:
-            blocks[k] = _jax.jit(
-                lambda v, d=_BLOCK_CONTRACT[k]: quantize(v, d))(w)
-        del w
+    blocks = {k: qz(k, src.pop(k) if k in _BLOCK_CONTRACT else src[k])
+              for k in list(src)}
     out = dict(params)
     out["blocks"] = blocks
-    lm = params.pop("lm_head")
-    out["lm_head"] = _jax.jit(lambda v: quantize(v, (0,)))(lm)
-    del lm
+    out["lm_head"] = qz("lm_head", params.pop("lm_head"))
     return out

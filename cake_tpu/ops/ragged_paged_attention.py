@@ -62,6 +62,29 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _flat_scales(scale):
+    """[N_pages, KV] per-page scales -> the 1-D [N_pages*KV] f32 array
+    the quantized kernels prefetch (indexed page*KV + kv). SMEM pads a
+    2-D array's minor dim to 128 words, so the 2-D form cost 16x its
+    bytes at KV=8 (f32[2048, 8] took the whole 1.00M of SMEM under
+    libtpu 0.0.34); 1-D costs what it holds."""
+    return jnp.asarray(scale, jnp.float32).reshape(-1)
+
+
+def _dot(a, b, *, trans_b: bool):
+    """MXU dot with f32 accumulation: a @ b.T (scores) or a @ b (the
+    value fold). bf16 operands pin DEFAULT precision — their products
+    are exact in one pass, and Mosaic refuses a bf16 lhs under an fp32
+    contract precision ("Bad lhs type", v5e, PR 21), which is what a
+    process-wide jax_default_matmul_precision=highest (the test lane's
+    setting) would otherwise hand the kernel."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1 if trans_b else 0,)), ((), ())),
+        precision=(jax.lax.Precision.DEFAULT
+                   if a.dtype == jnp.bfloat16 else None),
+        preferred_element_type=jnp.float32)
+
+
 def _rpa_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                 acc_ref, m_ref, l_ref, *, scale: float, page_size: int,
                 kv_heads: int, group: int, head_dim: int):
@@ -105,9 +128,7 @@ def _rpa_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         for kv in range(kv_heads):
             kh = k_ref[0, :, kv * hd:(kv + 1) * hd]    # [P, hd]
             qh = q[kv * group:(kv + 1) * group]        # [G, hd]
-            parts.append(jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            parts.append(_dot(qh, kh, trans_b=True))
         s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
         s = jnp.where(col_valid, s, NEG_INF)
 
@@ -121,9 +142,7 @@ def _rpa_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         for kv in range(kv_heads):
             vh = v_ref[0, :, kv * hd:(kv + 1) * hd]    # [P, hd]
             ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            outs.append(jax.lax.dot_general(
-                ph.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            outs.append(_dot(ph.astype(vh.dtype), vh, trans_b=False))
         acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -145,10 +164,11 @@ def _rpa_kernel_q8(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
     """int8 variant of _rpa_kernel: the page blocks stream as int8 (a
     quarter of the f32 DMA bytes — the whole point of KV tiering) and
     the per-(page, kv-head) scales ride as scalar-prefetched SMEM
-    operands. Because one scale covers a page's every column for a
-    given kv head, dequantization folds into the dot OUTPUTS: the
-    score block scales by scale_k[page, kv] and the value fold by
-    scale_v[page, kv] — no dequantized page copy ever exists."""
+    operands (1-D, index page*KV + kv — _flat_scales). Because one
+    scale covers a page's every column for a given kv head,
+    dequantization folds into the dot OUTPUTS: the score block scales
+    by scale_k[page, kv] and the value fold by scale_v[page, kv] — no
+    dequantized page copy ever exists."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -176,10 +196,8 @@ def _rpa_kernel_q8(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
             kh = k_ref[0, :, kv * hd:(kv + 1) * hd].astype(
                 jnp.float32)                           # [P, hd]
             qh = q[kv * group:(kv + 1) * group].astype(jnp.float32)
-            s_kv = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            parts.append(s_kv * sk_ref[pid, kv])
+            s_kv = _dot(qh, kh, trans_b=True)
+            parts.append(s_kv * sk_ref[pid * kv_heads + kv])
         s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
         s = jnp.where(col_valid, s, NEG_INF)
 
@@ -193,10 +211,8 @@ def _rpa_kernel_q8(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
         for kv in range(kv_heads):
             vh = v_ref[0, :, kv * hd:(kv + 1) * hd].astype(jnp.float32)
             ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            o_kv = jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            outs.append(o_kv * sv_ref[pid, kv])
+            o_kv = _dot(ph, vh, trans_b=False)
+            outs.append(o_kv * sv_ref[pid * kv_heads + kv])
         acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -257,10 +273,8 @@ def _rpa_kernel_q4(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
             kh = _unpack_nibbles(k_ref[0],
                                  slice(kv * hd, (kv + 1) * hd))  # [P, hd]
             qh = q[kv * group:(kv + 1) * group].astype(jnp.float32)
-            s_kv = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            parts.append(s_kv * sk_ref[pid, kv])
+            s_kv = _dot(qh, kh, trans_b=True)
+            parts.append(s_kv * sk_ref[pid * kv_heads + kv])
         s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
         s = jnp.where(col_valid, s, NEG_INF)
 
@@ -275,10 +289,8 @@ def _rpa_kernel_q4(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
             vh = _unpack_nibbles(v_ref[0],
                                  slice(kv * hd, (kv + 1) * hd))  # [P, hd]
             ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            o_kv = jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            outs.append(o_kv * sv_ref[pid, kv])
+            o_kv = _dot(ph, vh, trans_b=False)
+            outs.append(o_kv * sv_ref[pid * kv_heads + kv])
         acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -327,7 +339,15 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not _on_tpu()
+    if not interpret and not ragged_paged_supported(
+            P, H, KV, hd, quantized=quantized, n_pages=N,
+            packed4=packed4, slots=B, max_pages=max_pages):
+        raise ValueError(
+            f"ragged paged attention cannot run on this chip at page="
+            f"{P} H={H} KV={KV} hd={hd} pool={pool_k.dtype} pages={N} "
+            f"table={B}x{max_pages} (ragged_paged_supported); use the "
+            "fold")
 
     kf = pool_k.reshape(N, Pb, KV * hd)
     vf = pool_v.reshape(N, Pb, KV * hd)
@@ -350,8 +370,8 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
         n_prefetch = 4
         operands = (jnp.asarray(pos, jnp.int32),
                     jnp.asarray(table, jnp.int32),
-                    jnp.asarray(scale_k, jnp.float32),
-                    jnp.asarray(scale_v, jnp.float32), q, kf, vf)
+                    _flat_scales(scale_k), _flat_scales(scale_v),
+                    q, kf, vf)
     else:
         kernel = functools.partial(
             _rpa_kernel, scale=scale, page_size=P, kv_heads=KV, group=G,
@@ -440,9 +460,7 @@ def _rpa_mixed_kernel(pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
             kh = k_ref[0, :, kv * hd:(kv + 1) * hd]          # [P, hd]
             vh = v_ref[0, :, kv * hd:(kv + 1) * hd]          # [P, hd]
             qh = q[:, kv * G:(kv + 1) * G, :].reshape(C * G, hd)
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [C*G, P]
+            s = _dot(qh, kh, trans_b=True) * scale           # [C*G, P]
             s = jnp.where(valid, s, NEG_INF)
             r0 = kv * C * G
             m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
@@ -457,9 +475,8 @@ def _rpa_mixed_kernel(pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
             p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
             l_new = (alpha * l_ref[r0:r0 + C * G, :1]
                      + jnp.sum(p, axis=-1, keepdims=True))
-            out = jax.lax.dot_general(
-                p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [C*G, hd]
+            out = _dot(p.astype(vh.dtype), vh,
+                       trans_b=False)                        # [C*G, hd]
             acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
             m_ref[r0:r0 + C * G] = jnp.broadcast_to(
                 m_new, (C * G, m_ref.shape[1]))
@@ -520,10 +537,8 @@ def _rpa_mixed_kernel_q8(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
                 jnp.float32)                                 # [P, hd]
             qh = q[:, kv * G:(kv + 1) * G, :].reshape(
                 C * G, hd).astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * (
-                    scale * sk_ref[pid, kv])                 # [C*G, P]
+            s = _dot(qh, kh, trans_b=True) * (
+                scale * sk_ref[pid * kv_heads + kv])         # [C*G, P]
             s = jnp.where(valid, s, NEG_INF)
             r0 = kv * C * G
             m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
@@ -535,9 +550,8 @@ def _rpa_mixed_kernel_q8(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
             p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
             l_new = (alpha * l_ref[r0:r0 + C * G, :1]
                      + jnp.sum(p, axis=-1, keepdims=True))
-            out = jax.lax.dot_general(
-                p, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * sv_ref[pid, kv]
+            out = (_dot(p, vh, trans_b=False)
+                   * sv_ref[pid * kv_heads + kv])
             acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
             m_ref[r0:r0 + C * G] = jnp.broadcast_to(
                 m_new, (C * G, m_ref.shape[1]))
@@ -598,10 +612,8 @@ def _rpa_mixed_kernel_q4(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
                                  slice(kv * hd, (kv + 1) * hd))  # [P, hd]
             qh = q[:, kv * G:(kv + 1) * G, :].reshape(
                 C * G, hd).astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * (
-                    scale * sk_ref[pid, kv])                 # [C*G, P]
+            s = _dot(qh, kh, trans_b=True) * (
+                scale * sk_ref[pid * kv_heads + kv])         # [C*G, P]
             s = jnp.where(valid, s, NEG_INF)
             r0 = kv * C * G
             m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
@@ -613,9 +625,8 @@ def _rpa_mixed_kernel_q4(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
             p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
             l_new = (alpha * l_ref[r0:r0 + C * G, :1]
                      + jnp.sum(p, axis=-1, keepdims=True))
-            out = jax.lax.dot_general(
-                p, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * sv_ref[pid, kv]
+            out = (_dot(p, vh, trans_b=False)
+                   * sv_ref[pid * kv_heads + kv])
             acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
             m_ref[r0:r0 + C * G] = jnp.broadcast_to(
                 m_new, (C * G, m_ref.shape[1]))
@@ -672,7 +683,18 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not _on_tpu()
+    if not interpret and not ragged_paged_mixed_supported(
+            P, H, KV, hd, C, quantized=quantized, n_pages=N,
+            packed4=packed4, slots=B, max_pages=max_pages,
+            q_itemsize=q.dtype.itemsize,
+            kv_itemsize=pool_k.dtype.itemsize):
+        raise ValueError(
+            f"mixed ragged paged attention cannot run on this chip at "
+            f"page={P} H={H} KV={KV} hd={hd} C={C} pool={pool_k.dtype} "
+            f"pages={N} table={B}x{max_pages} "
+            "(ragged_paged_mixed_supported); use the fold or a "
+            "narrower window")
 
     kf = pool_k.reshape(N, Pb, KV * hd)
     vf = pool_v.reshape(N, Pb, KV * hd)
@@ -695,8 +717,8 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
         operands = (jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),
                     jnp.asarray(table, jnp.int32),
-                    jnp.asarray(scale_k, jnp.float32),
-                    jnp.asarray(scale_v, jnp.float32), q, kf, vf)
+                    _flat_scales(scale_k), _flat_scales(scale_v),
+                    q, kf, vf)
     else:
         kernel = functools.partial(
             _rpa_mixed_kernel, scale=scale, page_size=P, kv_heads=KV,
@@ -732,37 +754,66 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
     )(*operands)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# What the compiler gives one kernel on a v5e TensorCore, read from its
+# own refusals: AOT compiles against a v5e topology (jax 0.9.0, libtpu
+# 0.0.34, PR 21) said "Scoped allocation with size 16.04M and limit
+# 16.00M exceeded scoped vmem limit" for the mixed kernel at H=32,
+# hd=128, C=256, and "Used 2.01M of 1.00M smem" for two f32[2048, 8]
+# scale operands. The kernels set no vmem_limit_bytes, so the default
+# scoped limit is the ceiling.
+_VMEM_SCOPED_LIMIT = 16 * 2**20
+_SMEM_BYTES = 2**20
+
+
+def _smem_need(slots: int, max_pages: int, scale_words: int) -> int:
+    """Bytes the scalar-prefetch operands take: the [slots, max_pages]
+    int32 table pads its minor dim to 128 words; pos/q_len and the flat
+    scale arrays (2 x scale_words f32) are 1-D."""
+    table = slots * (-(-max_pages // 128) * 128) * 4
+    return table + 2 * slots * 4 + 2 * scale_words * 4
+
+
 def ragged_paged_supported(page_size: int, H: int, KV: int,
                            hd: int, quantized: bool = False,
                            n_pages: Optional[int] = None,
-                           packed4: bool = False) -> bool:
-    """Static shape gate for the hardware path (flash_supported
-    precedent): Mosaic wants the block's minor dim to fill 128-wide
-    lanes and the second-minor (page) dim to tile by 16 — or by 32 for
-    an int8 pool (the int8 sublane tile is twice as deep). A PACKED
-    int4 pool's uint8 block carries page_size//2 sublanes, so the real
-    page size must be a multiple of 64 for the packed axis to tile by
-    32 on silicon. Production configs (hd=128, 128-token pages) pass;
-    tiny test configs fall back to the fold on silicon and keep
-    exercising the kernel in interpret mode on CPU. A quantized pool
-    additionally bounds its whole-pool scale_k/scale_v scalar-prefetch
-    operands against SMEM (pass n_pages to enforce) — an oversized
-    pool must degrade to the fold instead of failing Mosaic allocation
-    at the first dispatch."""
+                           packed4: bool = False,
+                           slots: Optional[int] = None,
+                           max_pages: Optional[int] = None) -> bool:
+    """Static shape gate for the hardware path: the shape classes whose
+    numbers were checked against the fold ON A CHIP (v5e, PR 21), plus
+    the SMEM bound.
+
+    Float pools: hd a multiple of 16 and pages of 8 tokens or more —
+    hd 16/64/128 with 8/16/64/128-token bf16 pages all compiled under
+    Mosaic 0.9.0 and matched to bf16 resolution (max abs error 8e-3),
+    so the old "lane-filling head dim" rule was a guess the compiler
+    does not share. Quantized pools: only the production class was
+    run — hd a multiple of 128 and a page that fills the pool dtype's
+    sublane tile (32 int8 rows; 64 real tokens for a packed int4 block
+    of 32) — so narrower ones stay on the fold there and keep
+    exercising the kernel in interpret mode on the CPU.
+
+    The SMEM rule bounds the scalar-prefetch operands (the page table,
+    and a quantized pool's whole-pool scales) against the measured
+    1 MiB; pass n_pages / slots / max_pages to enforce it."""
     if H % KV != 0:
         return False
     if packed4 and page_size % 2:
         return False
-    if jax.default_backend() != "tpu":
+    if not _on_tpu():
         return True      # interpret mode imposes no tiling constraints
     quantized = quantized or packed4
-    page_tile = 64 if packed4 else (32 if quantized else 16)
-    if not (hd % 128 == 0 and page_size % page_tile == 0):
-        return False
-    if quantized and n_pages is not None:
-        # two [n_pages, KV] f32 arrays ride SMEM alongside pos+table
-        return 2 * 4 * n_pages * KV <= _SCALE_SMEM_BUDGET
-    return True
+    if quantized:
+        tiles = hd % 128 == 0 and page_size % (64 if packed4 else 32) == 0
+    else:
+        tiles = hd % 16 == 0 and page_size % 8 == 0
+    scale_words = n_pages * KV if quantized and n_pages else 0
+    return tiles and _smem_need(slots or 0, max_pages or 0,
+                                scale_words) <= _SMEM_BYTES
 
 
 def mixed_scratch_bytes(H: int, hd: int, q_width: int) -> int:
@@ -772,35 +823,48 @@ def mixed_scratch_bytes(H: int, hd: int, q_width: int) -> int:
     return 4 * q_width * H * (hd + 256)
 
 
-# scratch budget for the mixed kernel on silicon: VMEM is ~16 MB/core
-# on the conservative end of the TPU range; half of that is left for
-# the q/kv/out blocks and Mosaic's own double-buffering.
-_MIXED_VMEM_BUDGET = 8 * 1024 * 1024
-
-# budget for the int8 kernels' whole-pool scale arrays in SMEM: scalar
-# memory is small (order 1 MB/core); a conservative quarter of it is
-# left to the scales so pos + page table always fit beside them.
-# Production-scale pools pass (4096 pages x 8 kv heads = 256 KB for
-# both arrays); a pathologically page-count-heavy config falls back
-# to the fold.
-_SCALE_SMEM_BUDGET = 256 * 1024
+def mixed_vmem_bytes(page_size: int, H: int, KV: int, hd: int,
+                     q_width: int, q_itemsize: int = 2,
+                     kv_itemsize: int = 2) -> int:
+    """Scoped VMEM one mixed grid cell needs: the f32 scratch plus the
+    double-buffered q and out blocks ([C, H, hd]) and k/v page blocks.
+    Checked against the compiler at 20 shapes (H 8-64, KV 1-32, C
+    5-512, pages 16-256): every shape it refused needs more than
+    _VMEM_SCOPED_LIMIT by this count, and none it accepted was more
+    than 0.7 MiB over."""
+    q_block = q_width * H * hd * q_itemsize
+    kv_block = page_size * KV * hd * kv_itemsize
+    return (mixed_scratch_bytes(H, hd, q_width)
+            + 2 * 2 * q_block + 2 * 2 * kv_block)
 
 
 def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
                                  hd: int, q_width: int,
                                  quantized: bool = False,
                                  n_pages: Optional[int] = None,
-                                 packed4: bool = False) -> bool:
-    """Gate for the MIXED hardware kernel: the decode gate's tiling
-    rules PLUS a VMEM bound. Unlike the C=1 decode kernel, the mixed
-    kernel's scratch scales linearly with the query width C
-    (mixed_scratch_bytes) — a large --prefill-chunk must degrade to the
-    fold reference instead of failing Mosaic allocation at the first
-    mixed dispatch."""
+                                 packed4: bool = False,
+                                 slots: Optional[int] = None,
+                                 max_pages: Optional[int] = None,
+                                 q_itemsize: int = 2,
+                                 kv_itemsize: int = 2) -> bool:
+    """Gate for the MIXED hardware kernel: the decode gate's rules PLUS
+    a power-of-two GQA group and the VMEM bound. The kernel folds each
+    kv head's [C, G, hd] queries to [C*G, hd]; Mosaic does that shape
+    cast for G in 1, 2, 4, 8 and refuses it for G=7 ("unsupported shape
+    cast", v5e, PR 21). And unlike the C=1 decode kernel, its scratch
+    and q/out blocks scale with the query width C: the compiler
+    refuses the kernel outright past its scoped limit (at H=32,
+    hd=128: C=128 needs 11 MiB and compiles, C=256 needs 21 MiB and
+    does not)."""
     if not ragged_paged_supported(page_size, H, KV, hd,
                                   quantized=quantized, n_pages=n_pages,
-                                  packed4=packed4):
+                                  packed4=packed4, slots=slots,
+                                  max_pages=max_pages):
         return False
-    if jax.default_backend() != "tpu":
+    if not _on_tpu():
         return True      # interpret mode allocates host memory
-    return mixed_scratch_bytes(H, hd, q_width) <= _MIXED_VMEM_BUDGET
+    G = H // KV
+    if G & (G - 1):
+        return False
+    return mixed_vmem_bytes(page_size, H, KV, hd, q_width, q_itemsize,
+                            kv_itemsize) <= _VMEM_SCOPED_LIMIT

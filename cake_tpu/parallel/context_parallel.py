@@ -355,7 +355,7 @@ def sp_select_last(x, plen, idx, Sl: int, lm_head):
 
 def make_sp_decode_scan(decode_sm, ctx_len: int):
     """K decode+sample steps as ONE compiled program — the long-context
-    analog of the engine's decode scan: host/tunnel dispatch amortizes
+    analog of the engine's decode scan: host dispatch amortizes
     across num_steps tokens instead of paying a round-trip per token
     (the dominant cost of sp serving at small batch). Sampling (incl.
     the repeat-penalty ring) runs inside the scan with the same ops the

@@ -76,7 +76,7 @@ def initialize(coordinator: Optional[str] = None,
                 if h.strip()]) > 1
     )
     if not kwargs and not multi_worker:
-        # Single-worker pod-ish env (e.g. a TPU VM image or tunnel exports
+        # Single-worker pod-ish env (e.g. a TPU VM image exports
         # TPU_WORKER_HOSTNAMES with one entry): there are no peers to
         # coordinate with, and attempting auto-init after the XLA backend
         # is live (library use, REPL, tests) raises RuntimeError.

@@ -6,7 +6,7 @@ detection. This module provides the three detection layers a long-running
 TPU serving deployment needs:
 
   * `probe_devices(timeout_s)` — runs a tiny computation on every local
-    device in a watchdog thread; a hung accelerator/tunnel (which blocks
+    device in a watchdog thread; a hung accelerator (which blocks
     forever rather than raising) is reported as wedged instead of hanging
     the caller.
   * `HeartbeatMonitor` / `HeartbeatSender` — coordinator-side liveness
@@ -79,7 +79,7 @@ def probe_devices(timeout_s: float = 30.0, devices=None) -> List[DeviceProbe]:
     A tiny computation is dispatched from a worker thread; if it neither
     completes nor raises within timeout_s the device is reported wedged
     (ok=False, error='timeout') — unlike a bare jnp op, this never hangs
-    the caller on a dead accelerator or tunnel.
+    the caller on a dead accelerator.
     """
     import jax
     import jax.numpy as jnp
@@ -487,7 +487,7 @@ class Watchdog:
     counter: zero-arg callable (e.g. `lambda: engine.stats.steps`).
     A stall is `active()` holding true for stall_after_s with no counter
     advance — including before the counter's FIRST advance, so a request
-    that hangs before producing any token (wedged compile, dead tunnel)
+    that hangs before producing any token (wedged compile, dead device)
     still fires. While `active()` is false the deadline keeps refreshing:
     an idle engine with an empty queue is never a stall, and a later
     request always gets the full window.

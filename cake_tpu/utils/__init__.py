@@ -3,7 +3,7 @@
 Capability parity with the reference's `cake-core/src/utils/mod.rs`.
 """
 
-from cake_tpu.utils.devices import get_inference_device, resolve_dtype  # noqa: F401
+from cake_tpu.utils.devices import resolve_dtype  # noqa: F401
 from cake_tpu.utils.loading import (  # noqa: F401
     load_safetensors_paths_from_index,
     load_weights,

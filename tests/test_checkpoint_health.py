@@ -211,7 +211,7 @@ def test_watchdog_fires_on_stall_and_rearms():
 
 def test_watchdog_fires_before_first_token():
     """A request that hangs before the counter EVER advances (wedged
-    compile, dead tunnel — the exact failure the watchdog exists for)
+    compile, dead device — the exact failure the watchdog exists for)
     must still fire: the stall clock starts when active() flips on, not
     at the first counter advance (round-4 advisor finding)."""
     from cake_tpu.parallel.health import Watchdog

@@ -96,6 +96,25 @@ def test_mfu_math_against_hand_computed_matmul():
     assert rec2.mfu == 1.0
 
 
+def test_unknown_device_kind_yields_no_utilization():
+    """A kind that is not in the peak table has no peak — no default,
+    no CPU stand-in — so its records and aggregates carry no
+    mfu/hbm_util at all (this lane's device kind is "cpu")."""
+    assert obs_steps.peak_flops_for("cpu") is None
+    assert obs_steps.hbm_bps_for("Some Future Chip") is None
+    assert obs_steps.peak_flops_for("TPU v5 lite") == 197e12
+    assert obs_steps.hbm_bps_for("TPU v5 lite") == 819e9
+    st = obs_steps.StepTelemetry(impl="t")     # peaks from the table
+    rec = st.record("decode", rows=1, tokens=1, wall_s=0.01,
+                    cost=obs_steps.CostInfo(flops=1e6,
+                                            bytes_accessed=1e6))
+    assert rec.mfu is None and rec.hbm_util is None
+    assert "mfu" not in rec.to_dict()
+    assert "hbm_util" not in rec.to_dict()
+    assert st.utilization() == {}
+    assert "mfu" not in st.summary()
+
+
 def test_recompile_counter_increments_on_new_static_shape():
     ctr = m.REGISTRY.get("cake_jit_compiles_total")
     f = jax.jit(lambda x: x * 2)
@@ -138,7 +157,8 @@ def test_lower_cost_unwraps_partials_and_wrappers():
 
 def test_engine_run_yields_flight_records(engine):
     """Acceptance: >= 20 records, monotonic ids, nonzero dispatch
-    walls, decode MFU in (0, 1]."""
+    walls; no utilization on this lane (a CPU has no peak in the
+    table — the MFU math is pinned by the hand-computed test above)."""
     recs = engine.flight.dump()
     assert len(recs) >= 20
     ids = [r["step"] for r in recs]
@@ -146,17 +166,13 @@ def test_engine_run_yields_flight_records(engine):
     assert all(r["dispatch_s"] > 0 for r in recs)
     kinds = {r["kind"] for r in recs}
     assert "prefill" in kinds and "decode" in kinds
-    for r in recs:
-        if r["kind"] == "decode":
-            assert r["mfu"] is not None and 0 < r["mfu"] <= 1.0, r
-            assert r["hbm_util"] is not None and 0 < r["hbm_util"] <= 1.0
+    assert not any("mfu" in r or "hbm_util" in r for r in recs)
     # NOTE: no `any(compiled)` assertion here — the accountant is
     # process-global (it mirrors the process-global jit cache), so when
     # an earlier test module already compiled this config's signatures,
     # this engine's run truthfully reports zero new compiles. The
     # compiled-flag plumbing is unit-tested above instead.
-    util = engine.flight.utilization()
-    assert 0 < util["mfu"] <= 1.0
+    assert engine.flight.utilization() == {}
     summary = engine.flight.summary()
     assert summary["kinds"]["decode"]["count"] >= 19
     assert summary["impl"] == "dense"
@@ -252,10 +268,9 @@ def test_steps_endpoint_contract(server_url):
     assert obj["steps"], obj
     rec = obj["steps"][0]
     for key in ("step", "kind", "impl", "rows", "tokens", "dispatch_s",
-                "wall_s", "mfu", "hbm_util", "compiled"):
+                "wall_s", "compiled"):
         assert key in rec, rec
     assert obj["summary"]["recorded_steps"] >= len(obj["steps"])
-    assert "mfu" in obj["summary"]
     capped = json.loads(urllib.request.urlopen(
         server_url + "/api/v1/steps?limit=1", timeout=10).read())
     assert len(capped["steps"]) == 1

@@ -206,15 +206,121 @@ def test_mixed_fold_decode_row_bitwise_matches_decode_fold():
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
+# -- the shapes the 8B server dispatches, on whatever backend runs them --------
+#
+# H=32, KV=8, hd=128, 128-token pages, bf16 queries: Llama-3-8B's
+# attention exactly, at a few rows and pages so the interpret-mode CPU
+# lane takes seconds. interpret=None, so under CAKE_TESTS_TPU=1 these
+# are the REAL Mosaic kernels on the chip — the only tests that are
+# (every other case pins interpret=True at hd=16).
+#
+# Tolerance 2e-2 (abs and rel), and why: outputs are bf16, whose half
+# ulp is 2**-9 ~ 2e-3 below 1 and 8e-3 below 4, and the two sides round
+# at different points — the kernel casts p to bf16 before the PV dot on
+# a float pool and keeps int pages exact (scale applied in f32 after
+# the dot), the fold rounds dequantized pages to bf16 and merges page
+# stats in another order. Each is a ~2**-9 relative effect; 2e-2 is a
+# few output ulps. A wrong page, a wrong mask edge or a swapped head is
+# an O(1) error on unit-variance values, 50x over the bar.
+
+PROD = dict(H=32, KV=8, hd=128, P=128, n_pages=6)
+PROD_TOL = 2e-2
+
+
+def _prod_pool(rng, kind):
+    """(pool_k, pool_v) of `kind` bf16 | int8 | int4 at PROD shapes, as
+    models/llama/paged.py holds them (QuantPool / Int4Pool halves)."""
+    from cake_tpu.kv.quantized_pool import (
+        Int4Pool, QuantPool, pack_page_nibbles,
+    )
+    shape = (PROD["n_pages"], PROD["P"], PROD["KV"], PROD["hd"])
+
+    def half():
+        x = rng.normal(size=shape).astype(np.float32)
+        if kind == "bf16":
+            return jnp.asarray(x, jnp.bfloat16)
+        qmax = 127.0 if kind == "int8" else 7.0
+        scale = np.abs(x).max(axis=(1, 3)) / qmax          # [N, KV]
+        q = np.clip(np.round(x / scale[:, None, :, None]), -qmax, qmax)
+        if kind == "int8":
+            return QuantPool(q=jnp.asarray(q, jnp.int8),
+                             scale=jnp.asarray(scale, jnp.float32))
+        return Int4Pool(q=pack_page_nibbles(jnp.asarray(q, jnp.int8)),
+                        scale=jnp.asarray(scale, jnp.float32))
+
+    return half(), half()
+
+
+_PROD_TABLE = [[4, 1, 5], [2, 0, -1], [3, -1, -1]]
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "int4"])
+def test_decode_kernel_real_backend_production_shapes(kind):
+    rng = np.random.default_rng(20)
+    pk, pv = _prod_pool(rng, kind)
+    P = PROD["P"]
+    q = jnp.asarray(rng.normal(size=(3, 1, PROD["H"], PROD["hd"])),
+                    jnp.bfloat16)
+    table = jnp.asarray(_PROD_TABLE, jnp.int32)
+    # mid third page, last slot of the first page, first token
+    pos = jnp.asarray([2 * P + 37, P - 1, 0], jnp.int32)
+    want = paged_attention(q, pk, pv, table, pos, impl="fold")
+    got = paged_attention(q, pk, pv, table, pos, impl="pallas")
+    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=PROD_TOL, rtol=PROD_TOL)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "int4"])
+def test_mixed_kernel_real_backend_production_shapes(kind):
+    """C=128 is the width a default 8B server resolves to
+    (engine._resolve_paged_attn): a decode row, a full window that
+    straddles a page edge, and a short window from position 0."""
+    rng = np.random.default_rng(21)
+    pk, pv = _prod_pool(rng, kind)
+    P, C = PROD["P"], 128
+    q = jnp.asarray(rng.normal(size=(3, C, PROD["H"], PROD["hd"])),
+                    jnp.bfloat16)
+    table = jnp.asarray(_PROD_TABLE, jnp.int32)
+    pos = jnp.asarray([2 * P + 37, 100, 0], jnp.int32)
+    qlen = jnp.asarray([1, 128, 77], jnp.int32)
+    want = np.asarray(paged_attention_mixed(
+        q, pk, pv, table, pos, qlen, impl="fold"), np.float32)
+    got = np.asarray(paged_attention_mixed(
+        q, pk, pv, table, pos, qlen, impl="pallas"), np.float32)
+    assert np.isfinite(got).all()
+    for b, n in enumerate(np.asarray(qlen)):
+        np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                   atol=PROD_TOL, rtol=PROD_TOL)
+
+
 def test_supported_gate():
     assert not ragged_paged_supported(P, H=5, KV=2, hd=16)  # H % KV
-    if jax.default_backend() == "tpu":
-        # Mosaic tiling: tiny test shapes fall back to the fold
-        assert not ragged_paged_supported(P, H=4, KV=2, hd=16)
-        assert ragged_paged_supported(128, H=4, KV=2, hd=128)
-    else:
-        # interpret mode takes any shape
-        assert ragged_paged_supported(P, H=4, KV=2, hd=16)
+    # interpret mode takes any shape; so does the chip for a float pool
+    # down to hd=16 and 8-token pages (checked on a v5e, PR 21)
+    assert ragged_paged_supported(P, H=4, KV=2, hd=16)
+    assert ragged_paged_supported(128, H=4, KV=2, hd=128)
+
+
+def test_supported_gate_on_chip_shape_classes(monkeypatch):
+    """On a TPU the gate admits what was verified on silicon: float
+    pools down to hd=16 / 8-token pages, quantized pools at the
+    production class only, and the mixed kernel only for power-of-two
+    GQA groups (Mosaic refuses the [C, 7, hd] -> [7C, hd] cast)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ragged_paged_supported(8, H=4, KV=2, hd=16)
+    assert ragged_paged_supported(128, H=16, KV=4, hd=64)
+    assert not ragged_paged_supported(8, H=4, KV=2, hd=20)
+    assert not ragged_paged_supported(8, H=4, KV=2, hd=16, quantized=True)
+    assert ragged_paged_supported(128, H=32, KV=8, hd=128, quantized=True)
+    assert not ragged_paged_supported(32, H=32, KV=8, hd=128,
+                                      packed4=True)
+    assert ragged_paged_supported(128, H=28, KV=4, hd=128)       # decode
+    assert not ragged_paged_mixed_supported(128, H=28, KV=4, hd=128,
+                                            q_width=16)          # G=7
+    assert ragged_paged_mixed_supported(128, H=32, KV=4, hd=128,
+                                        q_width=16)              # G=8
 
 
 def test_mixed_supported_gate_bounds_scratch_vmem(monkeypatch):
@@ -226,12 +332,16 @@ def test_mixed_supported_gate_bounds_scratch_vmem(monkeypatch):
     # production-tileable shape (hd=128, page%16): decode-width OK ...
     assert ragged_paged_mixed_supported(16, H=32, KV=8, hd=128, q_width=1)
     assert ragged_paged_mixed_supported(16, H=32, KV=8, hd=128, q_width=64)
-    # ... but an 8B-class C=512 chunk allocates ~25 MB of f32 scratch
-    # (4 * C * H * (hd + 256)) — over budget, fold fallback
+    # ... and the 8B line the compiler drew (v5e, PR 21): C=128 needs
+    # 11 MiB of the 16 MiB scoped limit and compiles, C=256 needs 21
+    assert ragged_paged_mixed_supported(128, H=32, KV=8, hd=128,
+                                        q_width=128)
+    assert not ragged_paged_mixed_supported(128, H=32, KV=8, hd=128,
+                                            q_width=256)
     assert not ragged_paged_mixed_supported(16, H=32, KV=8, hd=128,
                                             q_width=512)
-    # tiling rules still apply before the VMEM bound
-    assert not ragged_paged_mixed_supported(P, H=4, KV=2, hd=16, q_width=1)
+    # the decode gate's rules still apply before the VMEM bound
+    assert not ragged_paged_mixed_supported(P, H=4, KV=2, hd=20, q_width=1)
 
 
 def test_supported_gate_bounds_int8_scale_smem(monkeypatch):
@@ -240,11 +350,15 @@ def test_supported_gate_bounds_int8_scale_smem(monkeypatch):
     page-count-heavy pool to the fold instead of letting Mosaic fail
     SMEM allocation at the first dispatch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # production-scale pool fits (4096 pages x 8 kv heads = 256 KB)
+    # production-scale pool fits (4096 pages x 8 kv heads = 256 KB of
+    # the 1 MiB: the scales ride flat, not padded [N, KV] rows)
     assert ragged_paged_supported(128, H=32, KV=8, hd=128,
                                   quantized=True, n_pages=4096)
     assert not ragged_paged_supported(128, H=32, KV=8, hd=128,
                                       quantized=True, n_pages=100_000)
+    # the page table shares that memory, minor dim padded to 128 words
+    assert not ragged_paged_supported(128, H=32, KV=8, hd=128,
+                                      slots=4096, max_pages=64)
     # the bound is int8-only (f32 pools carry no scale operands) and
     # rides through the mixed gate
     assert ragged_paged_supported(128, H=32, KV=8, hd=128,
