@@ -949,8 +949,10 @@ class ApiServer:
 
     def profile(self, body: dict) -> dict:
         """On-demand profiler capture (POST /api/v1/profile
-        {"seconds": N}): grab a jax.profiler Perfetto trace of the next
-        N seconds of live execution and return the artifact paths.
+        {"seconds": N[, "perfetto": true]}): grab a jax.profiler trace
+        of the next N seconds of live execution and return the artifact
+        paths (`xplane`; `perfetto_trace` is null unless asked for — the
+        conversion runs inside this process when the capture stops).
         Single-flight: a concurrent capture raises ProfileBusyError
         (HTTP 409). The capture directory comes from --profile-dir
         (never the request body — clients must not pick server paths)."""
@@ -962,7 +964,11 @@ class ApiServer:
         if not isinstance(seconds, (int, float)) or isinstance(
                 seconds, bool):
             raise ValueError("seconds must be a number")
-        return obs_steps.PROFILER.capture(seconds, self._profile_dir)
+        perfetto = body.get("perfetto", False)
+        if not isinstance(perfetto, bool):
+            raise ValueError("perfetto must be true or false")
+        return obs_steps.PROFILER.capture(seconds, self._profile_dir,
+                                          perfetto=perfetto)
 
     # -- admission -----------------------------------------------------------
 

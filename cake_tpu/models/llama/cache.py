@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -52,6 +53,7 @@ class KVCache(NamedTuple):
         return self.k.shape[1]
 
 
+@jax.named_scope("kv")
 def update_layer_cache_per_row(k_cache, v_cache, new_k, new_v, pos, active):
     """Write one new k/v per row at that row's own position (ragged decode).
 
@@ -73,6 +75,7 @@ def update_layer_cache_per_row(k_cache, v_cache, new_k, new_v, pos, active):
     return k_cache, v_cache
 
 
+@jax.named_scope("kv")
 def update_layer_cache(k_cache, v_cache, new_k, new_v, pos):
     """Write one layer's new k/v at absolute position `pos`.
 
@@ -89,6 +92,7 @@ def update_layer_cache(k_cache, v_cache, new_k, new_v, pos):
 
 # -- ring-buffer (sliding-window) writes --------------------------------------
 
+@jax.named_scope("kv")
 def update_layer_cache_ring(k_cache, v_cache, new_k, new_v, pos, n_real=None):
     """Write S <= W new k/v at ring slots (pos+i) % W.
 
@@ -125,6 +129,7 @@ def update_layer_cache_per_row_ring(k_cache, v_cache, new_k, new_v, pos,
                                       jnp.mod(pos, W), active)
 
 
+@jax.named_scope("kv")
 def update_layer_cache_window_per_row(k_cache, v_cache, new_k, new_v,
                                       pos0, active):
     """Write a W-token window per row at that row's own start position

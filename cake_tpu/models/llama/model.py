@@ -78,36 +78,45 @@ def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
     H = lp["wq"].shape[-1] // hd      # local head count under TP
     KV = lp["wk"].shape[-1] // hd
 
-    h = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
-    q = qmatmul(h, lp["wq"])
-    k = qmatmul(h, lp["wk"])
-    v = qmatmul(h, lp["wv"])
-    if "bq" in lp:  # Qwen2-family QKV bias (config.attention_bias)
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, KV, hd)
-    v = v.reshape(B, S, KV, hd)
-    attn, extras = attn_fn(q, k, v)
-    attn_out = qmatmul(attn.reshape(B, S, H * hd), lp["wo"])
-    if tp_axis is not None:
-        attn_out = lax.psum(attn_out, tp_axis)
-    x = x + attn_out
+    # the named scopes are the device trace's vocabulary (PERF.md §3):
+    # every family and engine inherits them from here, and
+    # benchmarks/harness/trace_spans.py sums device time by them
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
+    with jax.named_scope("qkv"):
+        q = qmatmul(h, lp["wq"])
+        k = qmatmul(h, lp["wk"])
+        v = qmatmul(h, lp["wv"])
+        if "bq" in lp:  # Qwen2-family QKV bias (config.attention_bias)
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(B, S, H, hd)
+        k = k.reshape(B, S, KV, hd)
+        v = v.reshape(B, S, KV, hd)
+    with jax.named_scope("attn"):
+        attn, extras = attn_fn(q, k, v)
+    with jax.named_scope("o_proj"):
+        attn_out = qmatmul(attn.reshape(B, S, H * hd), lp["wo"])
+        if tp_axis is not None:
+            attn_out = lax.psum(attn_out, tp_axis)
+        x = x + attn_out
 
-    h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
-    if "router" in lp:
-        from cake_tpu.ops.moe import moe_mlp
-        # AttributeError here means MoE params were paired with a dense
-        # LlamaConfig — a real mismatch that must not default silently.
-        mlp_out = moe_mlp(h=h, lp=lp, ep_axis=ep_axis,
-                          num_experts_per_tok=config.num_experts_per_tok)
-    else:
-        gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
-        mlp_out = qmatmul(gate * qmatmul(h, lp["w_up"]), lp["w_down"])
-    if tp_axis is not None:
-        mlp_out = lax.psum(mlp_out, tp_axis)
-    x = x + mlp_out
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
+        if "router" in lp:
+            from cake_tpu.ops.moe import moe_mlp
+            # AttributeError here means MoE params were paired with a
+            # dense LlamaConfig — a real mismatch that must not default
+            # silently.
+            mlp_out = moe_mlp(h=h, lp=lp, ep_axis=ep_axis,
+                              num_experts_per_tok=config.num_experts_per_tok)
+        else:
+            gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
+            mlp_out = qmatmul(gate * qmatmul(h, lp["w_up"]), lp["w_down"])
+        if tp_axis is not None:
+            mlp_out = lax.psum(mlp_out, tp_axis)
+        x = x + mlp_out
     return x, extras
 
 
@@ -212,7 +221,8 @@ def run_blocks(blocks, x, cache: KVCache, pos, rope_c, rope_s, mask,
                                   ring=ring, write_len=write_len)
         return h, (kc, vc)
 
-    x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
     return x, KVCache(k=k_new, v=v_new)
 
 
@@ -227,7 +237,8 @@ def forward(params, tokens, cache: KVCache, pos, rope: RopeTables,
     """
     B, S = tokens.shape
     T = cache.max_seq_len
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows(rope.cos, rope.sin, pos, S)
     from cake_tpu.ops.attention import uniform_forward_mask
     mask = uniform_forward_mask(pos, S, T, config.sliding_window, ring,
@@ -235,16 +246,17 @@ def forward(params, tokens, cache: KVCache, pos, rope: RopeTables,
     x, cache = run_blocks(params["blocks"], x, cache, pos, rope_c, rope_s,
                           mask, config, is_prefill=is_prefill,
                           chunked=chunked, ring=ring, write_len=write_len)
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    if return_hidden:
-        return x, cache
-    if last_idx is None:
-        last = x[:, -1]
-    else:
-        last = jnp.take_along_axis(
-            x, last_idx.reshape(B, 1, 1).astype(jnp.int32), axis=1
-        )[:, 0]
-    logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        if return_hidden:
+            return x, cache
+        if last_idx is None:
+            last = x[:, -1]
+        else:
+            last = jnp.take_along_axis(
+                x, last_idx.reshape(B, 1, 1).astype(jnp.int32), axis=1
+            )[:, 0]
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
 
@@ -344,7 +356,8 @@ def run_blocks_ragged(blocks, x, cache: KVCache, pos, active,
                                      tp_axis=tp_axis, ep_axis=ep_axis)
         return h, (kc, vc)
 
-    x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
     return x, KVCache(k=k_new, v=v_new)
 
 
@@ -360,7 +373,8 @@ def ragged_decode(params, tokens, pos, active, cache: KVCache,
     the ragged-decode frame exists exactly once.
     """
     T = cache.max_seq_len
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows_per_row(rope.cos, rope.sin, pos)
     if ring:
         from cake_tpu.ops.attention import ring_decode_mask_per_row
@@ -370,8 +384,9 @@ def ragged_decode(params, tokens, pos, active, cache: KVCache,
                                    window=config.sliding_window)
     x, cache = blocks_runner(params["blocks"], x, cache, pos, active,
                              rope_c, rope_s, mask)
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    logits = qmatmul(x[:, -1], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        logits = qmatmul(x[:, -1], params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
 
@@ -490,6 +505,7 @@ def slot_prefill(params, tokens, prompt_len, slot, cache: KVCache,
     return logits, _slot_writeback(cache, sub, slot)
 
 
+@jax.named_scope("kv")
 def _slot_view(cache: KVCache, slot) -> KVCache:
     """Slice one batch slot's cache lines out ([L, 1, T, KV, hd])."""
     return KVCache(
@@ -498,6 +514,7 @@ def _slot_view(cache: KVCache, slot) -> KVCache:
     )
 
 
+@jax.named_scope("kv")
 def _install_prefix(sub: KVCache, pk, pv) -> KVCache:
     """Write cached-prefix KV [L, 1, P, KV, hd] at positions 0..P-1."""
     return KVCache(
@@ -508,6 +525,7 @@ def _install_prefix(sub: KVCache, pk, pv) -> KVCache:
     )
 
 
+@jax.named_scope("kv")
 def _slot_writeback(cache: KVCache, sub: KVCache, slot) -> KVCache:
     """Splice one slot's updated lines back into the shared cache."""
     return KVCache(

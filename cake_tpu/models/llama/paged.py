@@ -200,6 +200,7 @@ def table_set_slot(table: jnp.ndarray, slot: int,
 # -- device ops ---------------------------------------------------------------
 
 
+@jax.named_scope("kv")
 def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
     """Scatter a prompt window's KV ([1, S, KV, hd]) into the pool pages
     of one slot (per layer — callers run this inside the block scan).
@@ -242,6 +243,7 @@ def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
     return pk, pv
 
 
+@jax.named_scope("kv")
 def write_window_pages(pool_k, pool_v, k, v, table_row, pos0,
                        n_real=None):
     """Scatter one prefill window's KV ([1, C, KV, hd]) at absolute
@@ -277,6 +279,7 @@ def write_window_pages(pool_k, pool_v, k, v, table_row, pos0,
     return pk, pv
 
 
+@jax.named_scope("kv")
 def write_windows_pages(pool_k, pool_v, k, v, pos, q_len, active, table):
     """Batched write_window_pages: every row scatters its q_len-token
     window at absolute position pos[b] into its own pages (per layer).
@@ -313,6 +316,7 @@ def write_windows_pages(pool_k, pool_v, k, v, pos, q_len, active, table):
     return pk, pv
 
 
+@jax.named_scope("kv")
 def update_pool_per_row(pool_k, pool_v, k, v, pos, active, table):
     """Write one decode token per row into its page (per layer).
 
@@ -540,7 +544,8 @@ def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
     return x, cache._replace(k=k_new, v=v_new)
 
 
@@ -555,13 +560,15 @@ def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
     from cake_tpu.ops.norms import rms_norm
     from cake_tpu.ops.quant import qmatmul
 
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows_per_row(rope.cos, rope.sin, pos)
     x, cache = run_blocks_ragged_paged(params["blocks"], x, cache, pos,
                                        active, rope_c, rope_s, config,
                                        attn=attn)
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    logits = qmatmul(x[:, -1], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        logits = qmatmul(x[:, -1], params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
 
@@ -609,7 +616,8 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
     B, S = tokens.shape
     H = config.num_attention_heads
     KV = config.num_key_value_heads
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows(rope.cos, rope.sin, jnp.int32(0), S)
     table_row = jnp.take(cache.table, slot, axis=0)
     use_flash = (attn == "pallas"
@@ -631,13 +639,15 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    x, (k_new, v_new) = lax.scan(body, x,
-                                 (params["blocks"], cache.k, cache.v))
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
-    )[:, 0]
-    logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x,
+                                     (params["blocks"], cache.k, cache.v))
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        last = jnp.take_along_axis(
+            x, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
+        )[:, 0]
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return logits, cache._replace(k=k_new, v=v_new)
 
 
@@ -672,7 +682,8 @@ def prefill_prefix_pages(params, tokens, table_row,
     B, S = tokens.shape
     H = config.num_attention_heads
     KV = config.num_key_value_heads
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows(rope.cos, rope.sin, jnp.int32(0), S)
     use_flash = (attn == "pallas"
                  and flash_supported(S, S, H, KV, hd=config.head_dim))
@@ -692,8 +703,9 @@ def prefill_prefix_pages(params, tokens, table_row,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    _, (k_new, v_new) = lax.scan(body, x,
-                                 (params["blocks"], cache.k, cache.v))
+    with jax.named_scope("layers"):
+        _, (k_new, v_new) = lax.scan(body, x,
+                                     (params["blocks"], cache.k, cache.v))
     # final norm / lm_head skipped on purpose: only the KV matters here
     return cache._replace(k=k_new, v=v_new)
 
@@ -736,7 +748,8 @@ def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
     P = cache.page_size
     n_pp = n_prefix // P          # static: whole pages by contract
     T = n_prefix + S
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows(rope.cos, rope.sin, jnp.int32(n_prefix), S)
     table_row = jnp.take(cache.table, slot, axis=0)
     prefix_pages = jnp.maximum(table_row[:n_pp], 0)
@@ -779,13 +792,15 @@ def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    x, (k_new, v_new) = lax.scan(body, x,
-                                 (params["blocks"], cache.k, cache.v))
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, (suffix_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
-    )[:, 0]
-    logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x,
+                                     (params["blocks"], cache.k, cache.v))
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        last = jnp.take_along_axis(
+            x, (suffix_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
+        )[:, 0]
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return logits, cache._replace(k=k_new, v=v_new)
 
 
@@ -825,7 +840,8 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
     hd = config.head_dim
     N, P = cache.n_pages, cache.page_size
     T = cache.max_seq_len
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows(rope.cos, rope.sin, pos0, C)
     table_row = jnp.take(cache.table, slot, axis=0)
     gather_idx = jnp.where(table_row >= 0, table_row, N)
@@ -867,13 +883,15 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    x, (k_new, v_new) = lax.scan(body, x,
-                                 (params["blocks"], cache.k, cache.v))
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, (n_real - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
-    )[:, 0]
-    logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x,
+                                     (params["blocks"], cache.k, cache.v))
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        last = jnp.take_along_axis(
+            x, (n_real - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
+        )[:, 0]
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return logits, cache._replace(k=k_new, v=v_new)
 
 
@@ -906,7 +924,8 @@ def run_blocks_mixed_paged(blocks, x, cache: PagedKVCache, pos, q_len,
         h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
         return h, (pk2, pv2)
 
-    x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
     return x, cache._replace(k=k_new, v=v_new)
 
 
@@ -921,7 +940,8 @@ def _mixed_windows_trunk(params, tokens, pos, q_len, active,
     from cake_tpu.ops.norms import rms_norm
 
     C = tokens.shape[1]
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     # per-row per-column rope rows: query i of row b sits at absolute
     # position pos[b] + i (clamped into the table for padding columns
     # past the window — their values are garbage nothing reads)
@@ -932,7 +952,8 @@ def _mixed_windows_trunk(params, tokens, pos, q_len, active,
     x, cache = run_blocks_mixed_paged(params["blocks"], x, cache, pos,
                                       q_len, active, rope_c, rope_s,
                                       config, attn=attn)
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
     return x, cache
 
 
@@ -970,10 +991,12 @@ def mixed_step_paged(params, tokens, pos, q_len, active,
     B = tokens.shape[0]
     x, cache = _mixed_windows_trunk(params, tokens, pos, q_len, active,
                                     cache, rope, config, attn)
-    last = jnp.take_along_axis(
-        x, (jnp.maximum(q_len, 1) - 1).reshape(B, 1, 1).astype(jnp.int32),
-        axis=1)[:, 0]
-    logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        last = jnp.take_along_axis(
+            x,
+            (jnp.maximum(q_len, 1) - 1).reshape(B, 1, 1).astype(jnp.int32),
+            axis=1)[:, 0]
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
 
@@ -993,5 +1016,6 @@ def verify_window_paged(params, tokens, pos, q_len, active,
 
     x, cache = _mixed_windows_trunk(params, tokens, pos, q_len, active,
                                     cache, rope, config, attn)
-    logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, cache

@@ -18,7 +18,13 @@ real measurement substrate, dependency-free:
     flight recorder (`GET /api/v1/steps`, `--step-log PATH` JSONL),
     XLA cost-analysis MFU / HBM-utilization accounting, jit-recompile
     counters, per-device HBM gauges, and the single-flight live
-    profiler capture behind `POST /api/v1/profile`.
+    profiler capture behind `POST /api/v1/profile`. Each step record
+    also carries `phases` (host seconds by engine-loop phase —
+    admin / schedule / build / dispatch / sample / fetch / emit —
+    since the previous record) and `gap_s` (previous fetch end to this
+    dispatch start: the device with nothing queued); the same phases
+    are `cake/<phase>` TraceAnnotations with the step number in a
+    capture (`StepTelemetry.span`).
   * `obs.events` — the cross-subsystem event bus: typed,
     request-linked events (preempted, kv_spill/kv_restore, prefix_hit,
     recovered/poisoned, reconfigured, shed, fault_injected, recompile)

@@ -32,10 +32,17 @@ pieces, all dependency-free:
     on the serving heartbeat (parallel/health.py).
 
   * **Live profiler capture** (`ProfileCapture` / module `PROFILER`):
-    `POST /api/v1/profile {"seconds": N}` grabs a jax.profiler
-    Perfetto trace from the *running* serving process
-    (utils/profiling.capture_trace), single-flight-guarded — a second
-    concurrent capture gets `ProfileBusyError` (HTTP 409).
+    `POST /api/v1/profile {"seconds": N}` grabs a jax.profiler trace
+    (`.xplane.pb`; a Perfetto JSON too with `"perfetto": true`) from
+    the *running* serving process (utils/profiling.capture_trace),
+    single-flight-guarded — a second concurrent capture gets
+    `ProfileBusyError` (HTTP 409).
+
+  * **Step phases** (`StepTelemetry.span`): the engine loop times its
+    own phases (PHASES: admin, schedule, build, dispatch, sample,
+    fetch, emit) into each record's `phases` and `gap_s`, and shows
+    them as `cake/<phase>` TraceAnnotations carrying the step number,
+    so a capture's host plane joins `/api/v1/steps` by step.
 
 MFU here is model-FLOPs utilization: (program FLOPs from
 cost_analysis) / (peak chip FLOP/s x measured step seconds), clamped to
@@ -381,6 +388,13 @@ class StepRecord:
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
     rids: Optional[Tuple[int, ...]] = None
+    # host seconds by step phase (StepTelemetry.span) since the previous
+    # record: the emit and admin work that followed the previous step,
+    # then this step's schedule / build / dispatch / sample / fetch
+    phases: Optional[Dict[str, float]] = None
+    # end of the previous step's fetch -> start of this step's first
+    # dispatch: how long the engine left the device with nothing queued
+    gap_s: Optional[float] = None
 
     def to_dict(self) -> Dict:
         out = {
@@ -411,7 +425,49 @@ class StepRecord:
             out["rows_idle"] = self.rows_idle
         if self.rids is not None:
             out["rids"] = list(self.rids)
+        if self.phases:
+            out["phases"] = {k: round(v, 6)
+                             for k, v in self.phases.items()}
+        if self.gap_s is not None:
+            out["gap_s"] = round(self.gap_s, 6)
         return out
+
+
+# The step-phase vocabulary (PERF.md §3 lists what each covers in
+# serve/engine.py). "wait" is the idle engine: a trace annotation only,
+# it belongs to no step and closes the open phase table.
+PHASES = ("admin", "schedule", "build", "dispatch", "sample", "fetch",
+          "emit", "wait")
+
+
+class _Span:
+    """One timed phase of the engine loop (StepTelemetry.span): adds its
+    perf_counter seconds to the open step's phase table and shows as a
+    `cake/<name>` TraceAnnotation carrying the step number, so a
+    profiler capture holds the same span on the host plane, on the
+    clock the device planes use. Engine thread only; spans do not nest
+    (a nested span's seconds would count in both)."""
+
+    __slots__ = ("_tel", "_name", "_ann", "_t0")
+
+    def __init__(self, tel: "StepTelemetry", name: str):
+        self._tel = tel
+        self._name = name
+
+    def __enter__(self):
+        import jax
+        # outside a capture a TraceAnnotation is a flag test
+        self._ann = jax.profiler.TraceAnnotation(
+            "cake/" + self._name, step=self._tel._next)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tel._close_span(self._name, self._t0, t1)
+        return False
 
 
 class StepTelemetry:
@@ -450,6 +506,43 @@ class StepTelemetry:
         # event, so a shape-leak recompilation storm shows up on the
         # event timeline, not only as a rising counter
         self._events = events
+        # the open step's phase table (engine thread only): seconds by
+        # phase since the last record(), its gap_s once it dispatched,
+        # and the end of the newest fetch
+        self._phases: Dict[str, float] = {}
+        self._gap: Optional[float] = None
+        self._fetch_t1: Optional[float] = None
+
+    # -- step phases ----------------------------------------------------------
+
+    def span(self, name: str) -> _Span:
+        """Context manager around one phase of the engine loop (one of
+        PHASES). Its seconds land in the `phases` of the NEXT record —
+        the step being put together — and a capture shows it as
+        `cake/<name>` with that record's step number."""
+        if name not in PHASES:
+            raise ValueError(f"unknown step phase {name!r}: the "
+                             f"vocabulary is {PHASES}")
+        return _Span(self, name)
+
+    def _close_span(self, name: str, t0: float, t1: float) -> None:
+        if name == "wait":
+            # nothing to run: what led up to the wait belongs to no step
+            self._phases = {}
+            self._gap = self._fetch_t1 = None
+            return
+        if name == "fetch":
+            self._fetch_t1 = t1
+        elif (name == "dispatch" and name not in self._phases
+                and self._fetch_t1 is not None):
+            # the open step's first dispatch, after the last step's fetch
+            self._gap = max(0.0, t0 - self._fetch_t1)
+        self._phases[name] = self._phases.get(name, 0.0) + (t1 - t0)
+
+    def open_phase(self, name: str) -> Optional[float]:
+        """Seconds the open step has spent in `name` so far (None if it
+        never entered it)."""
+        return self._phases.get(name)
 
     def rebind(self, *, impl: Optional[str] = None,
                key_prefix: Optional[tuple] = None) -> None:
@@ -529,6 +622,11 @@ class StepTelemetry:
                 mfu = min(1.0, cost.flops / (peak * dev))
             if cost.bytes_accessed > 0 and bps:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
+        phases, self._phases = self._phases, {}
+        gap, self._gap = self._gap, None
+        if "fetch" not in phases:
+            # the device was never drained: the next step has no gap
+            self._fetch_t1 = None
         with self._lock:
             rec = StepRecord(
                 step=self._next, ts=time.time(), kind=kind,
@@ -541,7 +639,8 @@ class StepTelemetry:
                 rows_decode=rows_decode, rows_prefill=rows_prefill,
                 rows_idle=rows_idle,
                 rids=(tuple(int(r) for r in rids)
-                      if rids is not None else None))
+                      if rids is not None else None),
+                phases=phases or None, gap_s=gap)
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
@@ -669,8 +768,8 @@ class ProfileCapture:
         # advisory only (the real gate is the non-blocking acquire)
         return self._lock.locked()
 
-    def capture(self, seconds: float,
-                out_dir: Optional[str] = None) -> Dict:
+    def capture(self, seconds: float, out_dir: Optional[str] = None,
+                perfetto: bool = False) -> Dict:
         try:
             seconds = float(seconds)
         except (TypeError, ValueError):
@@ -683,7 +782,7 @@ class ProfileCapture:
                 "a profiler capture is already in progress")
         try:
             from cake_tpu.utils.profiling import capture_trace
-            return capture_trace(seconds, out_dir)
+            return capture_trace(seconds, out_dir, perfetto=perfetto)
         finally:
             self._lock.release()
 

@@ -190,6 +190,7 @@ def _flash_bhsd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     )
     return pl.pallas_call(
         kernel,
+        name="cake_flash_prefill",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
@@ -299,6 +300,7 @@ def _flash_bhsd_cached(pos, q, k, v, *, scale, block_q, block_k,
     )
     return pl.pallas_call(
         kernel,
+        name="cake_flash_prefill_cached",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

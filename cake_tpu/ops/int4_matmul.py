@@ -155,6 +155,7 @@ def int4_matmul(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
 
     out = pl.pallas_call(
         functools.partial(_int4_kernel, g=g, K=K),
+        name="cake_int4_matmul",
         grid=(Out // block_out, G // K),
         in_specs=[
             pl.BlockSpec((Mp, K * g), lambda io, gi: (0, gi)),

@@ -399,6 +399,7 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
+        name="cake_decode_attn",
         # only the page axis carries scratch state; rows schedule freely
         # across megacore
         compiler_params=pltpu.CompilerParams(
@@ -747,6 +748,7 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
+        name="cake_mixed_attn",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

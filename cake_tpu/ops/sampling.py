@@ -116,6 +116,7 @@ def _apply_repeat_penalty_per_row(logits, recent_tokens, penalty):
 
 
 @partial(jax.jit, static_argnames=("top_k", "n_top"))
+@jax.named_scope("sample")   # the caller's scope stops at a jit's edge
 def sample_tokens_ragged(keys, logits, recent_tokens, temperature, top_p,
                          repeat_penalty, top_k: Optional[int] = None,
                          n_top: int = 0):

@@ -69,14 +69,16 @@ def _gpipe_stage_loop(k, v, x, run_microbatch, *, num_microbatches: int):
         fresh = lax.dynamic_slice_in_dim(x, idx, mb, axis=0)
         inp = jnp.where(sid == 0, fresh, buf)
 
-        k_mb = lax.dynamic_slice_in_dim(k, idx, mb, axis=1)
-        v_mb = lax.dynamic_slice_in_dim(v, idx, mb, axis=1)
+        with jax.named_scope("kv"):
+            k_mb = lax.dynamic_slice_in_dim(k, idx, mb, axis=1)
+            v_mb = lax.dynamic_slice_in_dim(v, idx, mb, axis=1)
         y, k_new, v_new = run_microbatch(inp, k_mb, v_mb, idx, mb)
-        # mask side effects when this stage has no live microbatch
-        k_wr = jnp.where(live, k_new, k_mb)
-        v_wr = jnp.where(live, v_new, v_mb)
-        k = lax.dynamic_update_slice_in_dim(k, k_wr, idx, axis=1)
-        v = lax.dynamic_update_slice_in_dim(v, v_wr, idx, axis=1)
+        with jax.named_scope("kv"):
+            # mask side effects when this stage has no live microbatch
+            k_wr = jnp.where(live, k_new, k_mb)
+            v_wr = jnp.where(live, v_new, v_mb)
+            k = lax.dynamic_update_slice_in_dim(k, k_wr, idx, axis=1)
+            v = lax.dynamic_update_slice_in_dim(v, v_wr, idx, axis=1)
 
         is_last = sid == nstages - 1
         cur = lax.dynamic_slice_in_dim(out, idx, mb, axis=0)
@@ -178,24 +180,27 @@ def make_pipeline_forward(mesh: Mesh, config: LlamaConfig,
                      chunked: bool = False, write_len=None):
         B, S = tokens.shape
         T = cache.max_seq_len
-        x = jnp.take(params["embed"], tokens, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)
         rope_c, rope_s = rope_rows(rope.cos, rope.sin, pos, S)
         from cake_tpu.ops.attention import uniform_forward_mask
         mask = uniform_forward_mask(pos, S, T, config.sliding_window,
                                     ring, n_real=write_len)
         wlen = (jnp.int32(S) if write_len is None
                 else jnp.asarray(write_len, jnp.int32))
-        y, k, v = stage_fns[(is_prefill, chunked)](
-            params["blocks"], cache.k, cache.v,
-            x, pos, wlen, rope_c, rope_s, mask)
-        y = rms_norm(y, params["final_norm"], config.rms_norm_eps)
-        if last_idx is None:
-            last = y[:, -1]
-        else:
-            last = jnp.take_along_axis(
-                y, last_idx.reshape(B, 1, 1).astype(jnp.int32), axis=1
-            )[:, 0]
-        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+        with jax.named_scope("layers"):
+            y, k, v = stage_fns[(is_prefill, chunked)](
+                params["blocks"], cache.k, cache.v,
+                x, pos, wlen, rope_c, rope_s, mask)
+        with jax.named_scope("head"):
+            y = rms_norm(y, params["final_norm"], config.rms_norm_eps)
+            if last_idx is None:
+                last = y[:, -1]
+            else:
+                last = jnp.take_along_axis(
+                    y, last_idx.reshape(B, 1, 1).astype(jnp.int32), axis=1
+                )[:, 0]
+            logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
         return logits, KVCache(k, v)
 
     jitted = jax.jit(forward_body, donate_argnames=("cache",),
@@ -280,8 +285,9 @@ def make_engine_step_fns(mesh: Mesh, config: LlamaConfig,
         """model.forward_ragged-shaped pipelined forward (un-jitted:
         traced inside decode_ragged_fn and the decode scan)."""
         def runner(blocks, x, cache, pos, active, rope_c, rope_s, mask):
-            y, k, v = ragged_stage(blocks, cache.k, cache.v, x,
-                                   pos, active, rope_c, rope_s, mask)
+            with jax.named_scope("layers"):
+                y, k, v = ragged_stage(blocks, cache.k, cache.v, x,
+                                       pos, active, rope_c, rope_s, mask)
             return y, KVCache(k, v)
 
         return ragged_decode(params, tokens, pos, active, cache,
@@ -308,8 +314,11 @@ def make_engine_step_fns(mesh: Mesh, config: LlamaConfig,
 
     @partial(jax.jit, donate_argnames=("cache",),
              static_argnames=("config",))
-    def decode_ragged_fn(params, tokens, pos, active, cache: KVCache,
-                         rope: RopeTables, config=None):
+    def decode_step_ragged_pipelined(params, tokens, pos, active,
+                                     cache: KVCache, rope: RopeTables,
+                                     config=None):
+        # the name is the XLA module's (jit_decode_step_...): the
+        # benchmark finds a decode step's device time by that prefix
         logits, cache = ragged_forward(params, tokens, cache, pos, active,
                                        rope, config)
         return jax.lax.with_sharding_constraint(logits, logits_repl), cache
@@ -334,7 +343,8 @@ def make_engine_step_fns(mesh: Mesh, config: LlamaConfig,
                                      pipelined, pos0=pos0)
         return jax.lax.with_sharding_constraint(logits, logits_repl), cache
 
-    return prefill_slot_fn, decode_ragged_fn, decode_scan_fn, prefill_chunk_fn
+    return (prefill_slot_fn, decode_step_ragged_pipelined, decode_scan_fn,
+            prefill_chunk_fn)
 
 
 def pipeline_param_specs(blocks_keys, tp_axis: Optional[str] = None):

@@ -851,8 +851,6 @@ class InferenceEngine:
         self.tracer = RequestTracer(capacity=trace_ring,
                                     events_path=trace_events,
                                     slo=self.slo)
-        from cake_tpu.utils.profiling import StepStats
-        self._step_stats = StepStats(name="engine", window=100)
         # step-level flight recorder + jit compile/cost accounting
         # (obs/steps.py): one record per engine step at the dispatch
         # seams below, served at GET /api/v1/steps and optionally
@@ -2100,30 +2098,40 @@ class InferenceEngine:
             self._fail_pending_commands()
 
     def _run_loop(self) -> None:
+        # step-phase spans (obs/steps.StepTelemetry.span; vocabulary in
+        # PERF.md §3): host seconds by phase in each step record, and
+        # cake/<phase> annotations in a profiler capture
+        span = self.flight.span
         while not self._stop.is_set():
-            self._drain_cancellations()
-            self._drain_commands()
-            if self._autotuner is not None:
-                # between iterations only — a switch folds every slot,
-                # so it must never land mid-wave (the preemption
-                # invariant); the tick itself is a no-op off-interval
-                self._autotune_tick()
-            if self._slo and self._preemption:
-                # between iterations only: no device work is in flight,
-                # so a reclaimed slot cannot be mid-decode through a
-                # just-released page-table row
-                self._maybe_preempt()
-            prefill_plan, decode_plan = self.scheduler.plan()
-            # decode-resident slots THIS iteration: the candidate set
-            # for _spill_resident_stream — plan()'s decode rows only,
-            # never same-wave admissions (their prefill may be in
-            # flight when an admission later in the wave spills)
-            self._resident_parked = False
-            self._cur_decode = {s: r for r, s in decode_plan}
+            with span("admin"):
+                self._drain_cancellations()
+                self._drain_commands()
+                if self._autotuner is not None:
+                    # between iterations only — a switch folds every
+                    # slot, so it must never land mid-wave (the
+                    # preemption invariant); the tick itself is a no-op
+                    # off-interval
+                    self._autotune_tick()
+                if self._slo and self._preemption:
+                    # between iterations only: no device work is in
+                    # flight, so a reclaimed slot cannot be mid-decode
+                    # through a just-released page-table row
+                    self._maybe_preempt()
+            with span("schedule"):
+                prefill_plan, decode_plan = self.scheduler.plan()
+                # decode-resident slots THIS iteration: the candidate
+                # set for _spill_resident_stream — plan()'s decode rows
+                # only, never same-wave admissions (their prefill may
+                # be in flight when an admission later in the wave
+                # spills)
+                self._resident_parked = False
+                self._cur_decode = {s: r for r, s in decode_plan}
             if self._slo:
-                self._set_queue_gauges()
+                with span("admin"):
+                    self._set_queue_gauges()
             if not prefill_plan and not decode_plan:
-                self._wake.wait(timeout=0.05)
+                with span("wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             if getattr(self, "_page_starved", False):
@@ -2132,7 +2140,8 @@ class InferenceEngine:
                 # back off instead of spin-planning the same admission
                 self._page_starved = False
                 if not decode_plan:
-                    self._wake.wait(timeout=0.02)
+                    with span("wait"):
+                        self._wake.wait(timeout=0.02)
                     self._wake.clear()
             try:
                 if self._faults is not None:
@@ -2186,8 +2195,9 @@ class InferenceEngine:
                     # iteration (+ the batch-mode fsync barrier), then
                     # the size-triggered compaction check — both here,
                     # between iterations, where the registry is stable
-                    self._journal.flush()
-                    self._journal.maybe_compact(self)
+                    with span("admin"):
+                        self._journal.flush()
+                        self._journal.maybe_compact(self)
             except Exception as e:  # noqa: BLE001
                 log.exception("engine iteration failed")
                 # capture the request records FIRST (cheap, pure
@@ -4206,13 +4216,13 @@ class InferenceEngine:
                   else "fold into the prompt")
         return True
 
-    def _do_prefill(self, rid: int, slot: int, defer: bool = False):
-        """Prefill one admission. defer=False: dispatch, fetch, emit —
-        the multi-host lockstep path. defer=True: dispatch only; returns
-        (req, t0, slot, dev) for _do_prefill_batch, which fetches every
-        admission's first token in ONE host round-trip (a per-admission
-        fetch waits for the device once per request — it adds up in
-        TTFT when a wave of requests arrives together)."""
+    def _prefill_admit(self, rid: int, slot: int):
+        """Admission half of _do_prefill (the `schedule` phase): bind
+        the slot, fold a preempted request's tokens into its prompt,
+        match a prefix, allocate pages, adopt a shipped prefill.
+        Returns (req, t0, ids, prime, hit), or None when there is
+        nothing left to dispatch (cancelled, requeued for pages,
+        restored from the host tier, or adopted whole)."""
         req = self._requests.get(rid)
         if req is None:  # cancelled between plan and here
             self.scheduler.cancel(rid)
@@ -4272,6 +4282,20 @@ class InferenceEngine:
             # refused (stale epoch / geometry / injected fault): fall
             # through — whole-prompt prefill rewrites the row's pages
             # and scales, the documented degradation
+        return req, t0, ids, prime, hit
+
+    def _do_prefill(self, rid: int, slot: int, defer: bool = False):
+        """Prefill one admission. defer=False: dispatch, fetch, emit —
+        the multi-host lockstep path. defer=True: dispatch only; returns
+        (req, t0, slot, dev) for _do_prefill_batch, which fetches every
+        admission's first token in ONE host round-trip (a per-admission
+        fetch waits for the device once per request — it adds up in
+        TTFT when a wave of requests arrives together)."""
+        with self.flight.span("schedule"):
+            admitted = self._prefill_admit(rid, slot)
+        if admitted is None:
+            return None
+        req, t0, ids, prime, hit = admitted
         n_top = self._n_top_for([slot])
         if hit is not None:
             hit_pid, entry = hit
@@ -4316,7 +4340,8 @@ class InferenceEngine:
         self._obs_paged_step("prefill", dt)
         self._record_step("prefill", rows=1, tokens=1, wall_s=dt,
                           rids=(rid,))
-        self._emit(req, tok, logprob=lp, top=top)
+        with self.flight.span("emit"):
+            self._emit(req, tok, logprob=lp, top=top)
         return None
 
     # admissions per first-token fetch in _do_prefill_batch: a fetch
@@ -4345,7 +4370,8 @@ class InferenceEngine:
             # admission happened to defer last
             self._implicated = tuple(
                 (req.rid, slot) for (req, _t0, slot, _dev) in pend)
-            hosts = jax.device_get([dev for (_, _, _, dev) in pend])
+            with self.flight.span("fetch"):
+                hosts = jax.device_get([dev for (_, _, _, dev) in pend])
             # one wall-clock interval per GROUP: the admissions overlap
             # (dispatched back to back, fetched together), so summing
             # per-request spans would count the same wall time up to
@@ -4370,9 +4396,11 @@ class InferenceEngine:
                 compiled=any(js is not None and js.new for js in pend_js),
                 rids=[req.rid for (req, _t0, _s, _d) in pend],
                 **self._page_kw())
-            for (req, t0, slot, _), host in zip(pend, hosts):
-                tok, lp, top = self._finish_prefill_complete(slot, host)
-                self._emit(req, tok, logprob=lp, top=top)
+            with self.flight.span("emit"):
+                for (req, t0, slot, _), host in zip(pend, hosts):
+                    tok, lp, top = self._finish_prefill_complete(slot,
+                                                                 host)
+                    self._emit(req, tok, logprob=lp, top=top)
             pend.clear()
             pend_js.clear()
 
@@ -4419,8 +4447,10 @@ class InferenceEngine:
         request is admitted, the loop falls back to single mixed steps
         so its chunks ride every iteration instead of stalling behind a
         K-token scan burst."""
-        for rid, slot in prefill_plan:
-            self._mixed_admit(rid, slot)
+        if prefill_plan:
+            with self.flight.span("schedule"):
+                for rid, slot in prefill_plan:
+                    self._mixed_admit(rid, slot)
         if not self._mixed_pending:
             # pure decode: the phase path's programs are strictly
             # cheaper here (C=1 step, K-step scan bursts) and no
@@ -4530,48 +4560,50 @@ class InferenceEngine:
                for slot, p in self._mixed_pending.items()])
         if self._faults is not None:
             self._faults.check("engine.mixed", step=self.stats.steps)
-        B, C = self.max_slots, self._mixed_chunk
-        tokens = np.zeros((B, C), np.int64)
-        pos = np.zeros(B, np.int64)
-        qlen = np.zeros(B, np.int64)
-        active = np.zeros(B, bool)
-        decode_rows: List[int] = []
-        for rid, slot in decode_plan:
-            if slot in self._mixed_pending:
-                continue    # still prefilling: rides as a chunk row
-            req = self._slot_req[slot]
-            if req is None or req.rid != rid:
-                continue
-            tokens[slot, 0] = self._last_tok[slot]
-            pos[slot] = min(self._pos[slot], self.max_seq_len - 1)
-            qlen[slot] = 1
-            active[slot] = True
-            decode_rows.append(slot)
-        chunk_rows: List[int] = []
-        finished: List[int] = []
-        for slot in sorted(self._mixed_pending):
-            p = self._mixed_pending[slot]
-            ids, off = p["ids"], p["off"]
-            n = min(C, len(ids) - off)
-            tokens[slot, :n] = ids[off:off + n]
-            pos[slot] = off
-            qlen[slot] = n
-            active[slot] = True
-            chunk_rows.append(slot)
-            if off + n >= len(ids):
-                finished.append(slot)
-        if not decode_rows and not chunk_rows:
-            return
-        fargs = (self.params, jnp.asarray(tokens, jnp.int32),
-                 jnp.asarray(pos, jnp.int32),
-                 jnp.asarray(qlen, jnp.int32), jnp.asarray(active),
-                 self.cache, self.rope, self.config)
-        js = self._obs_jit("mixed_step", (C,), self._mixed_step_fn,
-                           fargs)
-        t0d = time.perf_counter()
-        logits, self.cache = self._mixed_step_fn(*fargs)
-        js.finish(time.perf_counter() - t0d)
-        self._last_jit = js
+        with self.flight.span("build"):
+            B, C = self.max_slots, self._mixed_chunk
+            tokens = np.zeros((B, C), np.int64)
+            pos = np.zeros(B, np.int64)
+            qlen = np.zeros(B, np.int64)
+            active = np.zeros(B, bool)
+            decode_rows: List[int] = []
+            for rid, slot in decode_plan:
+                if slot in self._mixed_pending:
+                    continue    # still prefilling: rides as a chunk row
+                req = self._slot_req[slot]
+                if req is None or req.rid != rid:
+                    continue
+                tokens[slot, 0] = self._last_tok[slot]
+                pos[slot] = min(self._pos[slot], self.max_seq_len - 1)
+                qlen[slot] = 1
+                active[slot] = True
+                decode_rows.append(slot)
+            chunk_rows: List[int] = []
+            finished: List[int] = []
+            for slot in sorted(self._mixed_pending):
+                p = self._mixed_pending[slot]
+                ids, off = p["ids"], p["off"]
+                n = min(C, len(ids) - off)
+                tokens[slot, :n] = ids[off:off + n]
+                pos[slot] = off
+                qlen[slot] = n
+                active[slot] = True
+                chunk_rows.append(slot)
+                if off + n >= len(ids):
+                    finished.append(slot)
+            if not decode_rows and not chunk_rows:
+                return
+            fargs = (self.params, jnp.asarray(tokens, jnp.int32),
+                     jnp.asarray(pos, jnp.int32),
+                     jnp.asarray(qlen, jnp.int32), jnp.asarray(active),
+                     self.cache, self.rope, self.config)
+        with self.flight.span("dispatch"):
+            js = self._obs_jit("mixed_step", (C,), self._mixed_step_fn,
+                               fargs)
+            t0d = time.perf_counter()
+            logits, self.cache = self._mixed_step_fn(*fargs)
+            js.finish(time.perf_counter() - t0d)
+            self._last_jit = js
         emit_rows = decode_rows + finished
         # advance the prefill frontiers BEFORE sampling/emit: a
         # finishing row's _pos must read prompt-end when _emit runs
@@ -4601,31 +4633,35 @@ class InferenceEngine:
         self.stats.prefill_time_s += pf
         self.stats.decode_time_s += dt - pf
         self._obs_paged_step("mixed", dt)
+        # dispatch_s / device_s: the step's own spans, as the burst and
+        # speculative paths record them (device_s = the fetch wait)
         self._record_step(
             "mixed", rows=len(decode_rows) + len(chunk_rows),
             tokens=len(emit_rows), wall_s=dt,
+            dispatch_s=self.flight.open_phase("dispatch"),
+            device_s=self.flight.open_phase("fetch"),
             rows_decode=len(decode_rows), rows_prefill=len(chunk_rows),
             rows_idle=B - len(decode_rows) - len(chunk_rows),
             rids=[r for r, _s in self._implicated])
-        self._step_stats.step(bytes_out=len(emit_rows))
 
         def _top(slot):
             return (list(zip(tids[slot].tolist(), tlps[slot].tolist()))
                     if tids.size else [])
 
-        for slot in decode_rows:
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            self._pos[slot] += 1
-            self._emit(req, int(nxt[slot]), logprob=float(lp[slot]),
-                       top=_top(slot))
-        for slot in finished:
-            p = self._mixed_pending.pop(slot, None)
-            if p is None:
-                continue
-            self._emit(p["req"], int(nxt[slot]),
-                       logprob=float(lp[slot]), top=_top(slot))
+        with self.flight.span("emit"):
+            for slot in decode_rows:
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                self._pos[slot] += 1
+                self._emit(req, int(nxt[slot]), logprob=float(lp[slot]),
+                           top=_top(slot))
+            for slot in finished:
+                p = self._mixed_pending.pop(slot, None)
+                if p is None:
+                    continue
+                self._emit(p["req"], int(nxt[slot]),
+                           logprob=float(lp[slot]), top=_top(slot))
 
     def _match_and_validate_prefix(self, ids: List[int]):
         """(pid, (p_ids, k, v)) of the longest matching registered prefix
@@ -4755,26 +4791,29 @@ class InferenceEngine:
 
     def _prefill_raw(self, ids, slot: int):
         """Whole-prompt prefill device call (no sampling-state changes)."""
-        ids = list(ids)
-        bucket = bucket_length(len(ids), self.max_seq_len)
-        padded = ids + [0] * (bucket - len(ids))
-        toks = jnp.asarray([padded], jnp.int32)
-        plen = jnp.asarray([len(ids)], jnp.int32)
-        fargs = (self.params, toks, plen, jnp.int32(slot), self.cache,
-                 self.rope, self.config)
-        js = self._obs_jit("prefill_slot", (bucket,),
-                           self._prefill_slot, fargs)
-        t0 = time.perf_counter()
-        logits, self.cache = self._prefill_slot(*fargs)
-        js.finish(time.perf_counter() - t0)
-        self._last_jit = js
-        if self._spec:
-            # the draft's KV must cover the prompt too (its proposals
-            # attend the same positions the target verifies)
-            _, self.d_cache = self._prefill_slot(
-                self.draft_params, toks, plen, jnp.int32(slot),
-                self.d_cache, self.d_rope, self.draft_config,
-            )
+        with self.flight.span("build"):
+            ids = list(ids)
+            bucket = bucket_length(len(ids), self.max_seq_len)
+            padded = ids + [0] * (bucket - len(ids))
+            toks = jnp.asarray([padded], jnp.int32)
+            plen = jnp.asarray([len(ids)], jnp.int32)
+            fargs = (self.params, toks, plen, jnp.int32(slot), self.cache,
+                     self.rope, self.config)
+        with self.flight.span("dispatch"):
+            js = self._obs_jit("prefill_slot", (bucket,),
+                               self._prefill_slot, fargs)
+            t0 = time.perf_counter()
+            logits, self.cache = self._prefill_slot(*fargs)
+            js.finish(time.perf_counter() - t0)
+            self._last_jit = js
+            if self._spec:
+                # the draft's KV must cover the prompt too (its
+                # proposals attend the same positions the target
+                # verifies)
+                _, self.d_cache = self._prefill_slot(
+                    self.draft_params, toks, plen, jnp.int32(slot),
+                    self.d_cache, self.d_rope, self.draft_config,
+                )
         return logits
 
     def _prefill_device(self, ids, slot: int, temp: float, top_p: float,
@@ -4810,15 +4849,17 @@ class InferenceEngine:
             # process-local computation (identical on every process by
             # determinism) instead of a cross-process collective
             logits = np.asarray(logits)
-        self._pos[slot] = prompt_len
-        self._temp[slot] = temp
-        self._top_p[slot] = top_p
-        self._penalty[slot] = penalty
-        self._prime_ring(slot, prime)
+        with self.flight.span("sample"):
+            self._pos[slot] = prompt_len
+            self._temp[slot] = temp
+            self._top_p[slot] = top_p
+            self._penalty[slot] = penalty
+            self._prime_ring(slot, prime)
+            wide = jnp.broadcast_to(logits,
+                                    (self.max_slots, logits.shape[-1]))
         # sample the first token with the slot's own key/options
-        sampled = self._sample_rows(
-            jnp.broadcast_to(logits, (self.max_slots, logits.shape[-1])),
-            rows=[slot], n_top=n_top, defer=defer)
+        sampled = self._sample_rows(wide, rows=[slot], n_top=n_top,
+                                    defer=defer)
         if defer:
             return sampled          # device tuple for _do_prefill_batch
         return self._finish_prefill_complete(slot, sampled,
@@ -4844,16 +4885,18 @@ class InferenceEngine:
         from cake_tpu.models.llama.generator import chunk_windows
         logits = None
         for window, n_real, start in chunk_windows(ids, C):
-            fargs = (self.params, jnp.asarray([window], jnp.int32),
-                     jnp.asarray([n_real], jnp.int32), jnp.int32(slot),
-                     jnp.int32(pos0 + start), self.cache, self.rope,
-                     self.config)
-            js = self._obs_jit("prefill_chunk", (C,),
-                               self._prefill_chunk_step, fargs)
-            t0 = time.perf_counter()
-            logits, self.cache = self._prefill_chunk_step(*fargs)
-            js.finish(time.perf_counter() - t0)
-            self._last_jit = js
+            with self.flight.span("build"):
+                fargs = (self.params, jnp.asarray([window], jnp.int32),
+                         jnp.asarray([n_real], jnp.int32),
+                         jnp.int32(slot), jnp.int32(pos0 + start),
+                         self.cache, self.rope, self.config)
+            with self.flight.span("dispatch"):
+                js = self._obs_jit("prefill_chunk", (C,),
+                                   self._prefill_chunk_step, fargs)
+                t0 = time.perf_counter()
+                logits, self.cache = self._prefill_chunk_step(*fargs)
+                js.finish(time.perf_counter() - t0)
+                self._last_jit = js
         return logits
 
     @engine_thread_only
@@ -5363,35 +5406,40 @@ class InferenceEngine:
         self._obs_paged_step("decode", dt)
         self._record_step("decode", rows=len(decode_plan),
                           tokens=len(decode_plan), wall_s=dt,
+                          dispatch_s=self.flight.open_phase("dispatch"),
+                          device_s=self.flight.open_phase("fetch"),
                           rids=[r for r, _s in decode_plan])
-        self._step_stats.step(bytes_out=len(decode_plan))
-        for rid, slot in decode_plan:
-            req = self._slot_req[slot]
-            if req is None or req.rid != rid:
-                continue
-            self._emit(req, int(nxt[slot]), logprob=float(lp[slot]),
-                       top=(list(zip(tids[slot].tolist(),
-                                     tlps[slot].tolist()))
-                            if tids.size else []))
+        with self.flight.span("emit"):
+            for rid, slot in decode_plan:
+                req = self._slot_req[slot]
+                if req is None or req.rid != rid:
+                    continue
+                self._emit(req, int(nxt[slot]), logprob=float(lp[slot]),
+                           top=(list(zip(tids[slot].tolist(),
+                                         tlps[slot].tolist()))
+                                if tids.size else []))
 
     def _decode_device(self, rows, n_top: Optional[int] = None) -> tuple:
         """One ragged decode step + sample for the given slot rows: the
         device-and-mirror half of _do_decode, shared verbatim by the
         coordinator and multi-host followers."""
-        B = self.max_slots
-        active = np.zeros(B, bool)
-        for slot in rows:
-            active[slot] = True
-        toks = jnp.asarray(self._last_tok[:, None], jnp.int32)
-        pos = jnp.asarray(np.minimum(self._pos, self.max_seq_len - 1),
-                          jnp.int32)
-        fargs = (self.params, toks, pos, jnp.asarray(active), self.cache,
-                 self.rope, self.config)
-        js = self._obs_jit("decode_step", (), self._decode_step, fargs)
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode_step(*fargs)
-        js.finish(time.perf_counter() - t0)
-        self._last_jit = js
+        with self.flight.span("build"):
+            B = self.max_slots
+            active = np.zeros(B, bool)
+            for slot in rows:
+                active[slot] = True
+            toks = jnp.asarray(self._last_tok[:, None], jnp.int32)
+            pos = jnp.asarray(
+                np.minimum(self._pos, self.max_seq_len - 1), jnp.int32)
+            fargs = (self.params, toks, pos, jnp.asarray(active),
+                     self.cache, self.rope, self.config)
+        with self.flight.span("dispatch"):
+            js = self._obs_jit("decode_step", (), self._decode_step,
+                               fargs)
+            t0 = time.perf_counter()
+            logits, self.cache = self._decode_step(*fargs)
+            js.finish(time.perf_counter() - t0)
+            self._last_jit = js
         if self._multihost:
             logits = np.asarray(logits)  # see _finish_prefill
         nxt, lp, tids, tlps = self._sample_rows(logits, rows=rows,
@@ -5551,7 +5599,6 @@ class InferenceEngine:
         froze it at exactly that point (budget freeze + EOS freeze in
         make_decode_scan), so mirrors advance by the emitted count."""
         toks_host, lps_host, tops_i_host, tops_l_host = fetched
-        self._step_stats.step(bytes_out=int(budget.sum()))
         for rid, slot in decode_plan:
             req = self._slot_req[slot]
             if req is None or req.rid != rid:
@@ -5678,23 +5725,27 @@ class InferenceEngine:
         top-p boundary); None derives it from the rows' requests.
         defer=True returns the device tuple without fetching (the
         caller batches the fetch and runs _sample_complete itself)."""
-        B = self.max_slots
-        row_mask = np.zeros(B, bool)
-        for r in rows:
-            row_mask[r] = True
-        nxt, self._keys, self._ring, lp, top_ids, top_lps = _masked_sample(
-            jnp.asarray(row_mask), self._keys, logits, self._ring,
-            jnp.asarray(self._steps, jnp.int32),
-            jnp.asarray(self._temp), jnp.asarray(self._top_p),
-            jnp.asarray(self._penalty), top_k=self.defaults.top_k,
-            n_top=self._n_top_for(rows) if n_top is None else n_top,
-        )
-        dev = (nxt, lp, top_ids, top_lps)
+        with self.flight.span("sample"):
+            B = self.max_slots
+            row_mask = np.zeros(B, bool)
+            for r in rows:
+                row_mask[r] = True
+            (nxt, self._keys, self._ring, lp, top_ids,
+             top_lps) = _masked_sample(
+                jnp.asarray(row_mask), self._keys, logits, self._ring,
+                jnp.asarray(self._steps, jnp.int32),
+                jnp.asarray(self._temp), jnp.asarray(self._top_p),
+                jnp.asarray(self._penalty), top_k=self.defaults.top_k,
+                n_top=self._n_top_for(rows) if n_top is None else n_top,
+            )
+            dev = (nxt, lp, top_ids, top_lps)
         if defer:
             return dev
         # one batched fetch, not four sequential round-trips (see
-        # _decode_scan_device)
-        return self._sample_complete(rows, jax.device_get(dev))
+        # _decode_scan_device): the host waiting for the device
+        with self.flight.span("fetch"):
+            host = jax.device_get(dev)
+        return self._sample_complete(rows, host)
 
     def _sample_complete(self, rows: List[int], host) -> tuple:
         """Host half of _sample_rows: advance the sampled rows' step and
@@ -5909,12 +5960,14 @@ class QueueFullError(Exception):
 
 
 @jax.jit
+@jax.named_scope("sample")
 def _split_keys(keys):
     """Split a [B]-vector of PRNG keys into (next_keys, subkeys)."""
     split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
     return split[:, 0], split[:, 1]
 
 
+@jax.named_scope("sample")
 def _masked_sample(active_mask, keys, logits, ring, steps, temp, top_p,
                    penalty, *, top_k, n_top=0):
     """ONE per-row sample with masked state advance — the single source of

@@ -11,5 +11,5 @@ from cake_tpu.utils.loading import (  # noqa: F401
 )
 from cake_tpu.utils.debug import panic_on_nan  # noqa: F401
 from cake_tpu.utils.profiling import (  # noqa: F401
-    StepStats, annotate, device_memory_stats, human_bytes, log_memory, trace,
+    device_memory_stats, human_bytes, log_memory, trace,
 )

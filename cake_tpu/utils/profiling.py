@@ -5,11 +5,11 @@ Reference surface (SURVEY.md §5):
     `trace-*.json` (sd/sd.rs:350-356) — here `trace(dir)` wraps
     `jax.profiler.trace`, producing a TensorBoard/Perfetto profile of
     both host Python and on-device XLA execution (strictly more detail
-    than the reference's host-side spans), plus `annotate(name)` for
-    custom spans (`jax.profiler.TraceAnnotation`).
+    than the reference's host-side spans). The engine loop's own spans
+    are `obs/steps.StepTelemetry.span` (`cake/<phase>` annotations).
   * worker ops/s + read/write throughput logged every 5 ops
-    (worker.rs:19, 254-283) — here `StepStats`, a windowed counter the
-    engine/drivers call per step.
+    (worker.rs:19, 254-283) — here the step flight recorder
+    (obs/steps.py, `GET /api/v1/steps`).
   * memory reporting at context creation / model load / inference start
     (cake/mod.rs:65-71, memory-stats + human_bytes) — here
     `log_memory(tag)` over `Device.memory_stats()` (real HBM numbers on
@@ -20,15 +20,14 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import os
+import tempfile
 import time
-from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import jax
 
 log = logging.getLogger(__name__)
-
-NUM_OPS_TO_STATS = 5  # reference worker.rs:19
 
 
 @contextlib.contextmanager
@@ -48,39 +47,50 @@ def trace(log_dir: Optional[str]):
     log.info("profile written to %s", log_dir)
 
 
-def annotate(name: str):
-    """Named span visible in the profile (host + device timeline)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-def capture_trace(seconds: float, out_dir: Optional[str] = None) -> dict:
+def capture_trace(seconds: float, out_dir: Optional[str] = None,
+                  perfetto: bool = False) -> dict:
     """Capture a jax.profiler trace of the NEXT `seconds` of live
     execution (the POST /api/v1/profile backend, obs/steps.py): unlike
     `trace(dir)` — which wraps a code block the caller controls — this
     profiles whatever the process is doing right now (a serving engine
     mid-decode), then returns where the artifacts landed.
 
-    out_dir: capture directory (created if missing); None makes a fresh
-    temp dir per capture. Returns {"dir", "perfetto_trace", "seconds"}
-    where perfetto_trace is the newest ``*.trace.json.gz`` under dir
-    (upload to ui.perfetto.dev), or None if the backend produced only
-    the TensorBoard artifacts."""
-    import os
-    import tempfile
+    The capture is as light as the profiler allows, because it runs
+    inside the serving process: the Python tracer is off (the engine
+    loop's `cake/<phase>` annotations and the device planes do not need
+    it, and it hooks every Python call of every thread), and the
+    Perfetto conversion — which re-reads and re-writes the whole trace
+    as JSON under the GIL at stop — runs only when `perfetto` asks.
 
+    out_dir: capture directory (created if missing); None makes a fresh
+    temp dir per capture. Returns {"dir", "xplane", "perfetto_trace",
+    "seconds"}: xplane is the newest ``*.xplane.pb`` under dir,
+    perfetto_trace the ``perfetto_trace.json.gz`` beside it (upload to
+    ui.perfetto.dev) or None when not asked for."""
     d = out_dir or tempfile.mkdtemp(prefix="cake-profile-")
     os.makedirs(d, exist_ok=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     t0 = time.perf_counter()
-    jax.profiler.start_trace(d, create_perfetto_trace=True)
+    jax.profiler.start_trace(d, create_perfetto_trace=bool(perfetto),
+                             profiler_options=opts)
     try:
         time.sleep(seconds)
     finally:
         jax.profiler.stop_trace()
     captured = time.perf_counter() - t0
+    xplane = _newest(d, ".xplane.pb")
+    trace_json = _newest(d, "perfetto_trace.json.gz") if perfetto else None
+    log.info("profiler capture: %.2fs -> %s", captured, xplane or d)
+    return {"dir": d, "xplane": xplane, "perfetto_trace": trace_json,
+            "seconds": round(captured, 3)}
+
+
+def _newest(directory: str, suffix: str) -> Optional[str]:
     newest, newest_mtime = None, -1.0
-    for root, _dirs, files in os.walk(d):
+    for root, _dirs, files in os.walk(directory):
         for name in files:
-            if name.endswith(".trace.json.gz"):
+            if name.endswith(suffix):
                 p = os.path.join(root, name)
                 try:
                     m = os.path.getmtime(p)
@@ -88,9 +98,7 @@ def capture_trace(seconds: float, out_dir: Optional[str] = None) -> dict:
                     continue
                 if m > newest_mtime:
                     newest, newest_mtime = p, m
-    log.info("profiler capture: %.2fs -> %s", captured, newest or d)
-    return {"dir": d, "perfetto_trace": newest,
-            "seconds": round(captured, 3)}
+    return newest
 
 
 def human_bytes(n: float) -> str:
@@ -133,48 +141,3 @@ def log_memory(tag: str) -> None:
                 human_bytes(used), human_bytes(peak or 0),
                 human_bytes(limit or 0),
             )
-
-
-@dataclass
-class StepStats:
-    """Windowed per-step throughput counters (worker.rs:254-283 analog).
-
-    Call `step(bytes_in, bytes_out)` once per op; every `window` ops the
-    moving-window ops/s + throughput is logged and returned.
-    """
-
-    name: str = "engine"
-    window: int = NUM_OPS_TO_STATS
-    ops: int = 0
-    total_bytes_in: int = 0
-    total_bytes_out: int = 0
-    _win_start: float = field(default_factory=time.perf_counter)
-    _win_bytes_in: int = 0
-    _win_bytes_out: int = 0
-    last_ops_per_s: float = 0.0
-
-    def step(self, bytes_in: int = 0, bytes_out: int = 0) -> Optional[dict]:
-        self.ops += 1
-        self.total_bytes_in += bytes_in
-        self.total_bytes_out += bytes_out
-        self._win_bytes_in += bytes_in
-        self._win_bytes_out += bytes_out
-        if self.ops % self.window:
-            return None
-        now = time.perf_counter()
-        dt = max(now - self._win_start, 1e-9)
-        snap = {
-            "ops_per_s": self.window / dt,
-            "read_bytes_per_s": self._win_bytes_in / dt,
-            "write_bytes_per_s": self._win_bytes_out / dt,
-        }
-        self.last_ops_per_s = snap["ops_per_s"]
-        log.info(
-            "%s: %.1f ops/s | read %s/s | write %s/s", self.name,
-            snap["ops_per_s"], human_bytes(snap["read_bytes_per_s"]),
-            human_bytes(snap["write_bytes_per_s"]),
-        )
-        self._win_start = now
-        self._win_bytes_in = 0
-        self._win_bytes_out = 0
-        return snap

@@ -298,7 +298,7 @@ def test_profile_endpoint_single_flight(server_url, monkeypatch):
     started = threading.Event()
     release = threading.Event()
 
-    def fake_capture(seconds, out_dir=None):
+    def fake_capture(seconds, out_dir=None, perfetto=False):
         started.set()
         release.wait(30)
         return {"dir": "/tmp/fake", "perfetto_trace": None,
@@ -330,14 +330,237 @@ def test_profile_endpoint_single_flight(server_url, monkeypatch):
         assert exc.value.code == 400, bad
 
 
-@pytest.mark.slow  # first jax.profiler capture pays ~10s init
-def test_profile_endpoint_real_capture(server_url):
-    out = _post(server_url, "/api/v1/profile", {"seconds": 0.2},
-                timeout=120)
-    assert out["dir"]
-    assert out["seconds"] >= 0.2
-    import os
-    assert os.path.isdir(out["dir"])
-    # perfetto artifact present (CPU backend produces one too)
-    assert out["perfetto_trace"] and os.path.exists(
-        out["perfetto_trace"])
+@pytest.mark.parametrize("body,want", [
+    ({"seconds": 0.5}, False),                    # the benchmark's request
+    ({"seconds": 0.5, "perfetto": False}, False),
+    ({"seconds": 0.5, "perfetto": True}, True),
+])
+def test_profile_endpoint_perfetto_only_when_asked(server_url, monkeypatch,
+                                                   body, want):
+    """The Perfetto conversion runs inside the serving process at stop,
+    so it is opt-in; the reply keeps its `perfetto_trace` key (null
+    otherwise) and names the `.xplane.pb`."""
+    seen = {}
+
+    def fake_capture(seconds, out_dir=None, perfetto=False):
+        seen["perfetto"] = perfetto
+        return {"dir": "/tmp/fake", "xplane": "/tmp/fake/x.xplane.pb",
+                "perfetto_trace": "/tmp/fake/p.json.gz" if perfetto
+                else None, "seconds": seconds}
+
+    monkeypatch.setattr("cake_tpu.utils.profiling.capture_trace",
+                        fake_capture)
+    out = _post(server_url, "/api/v1/profile", body)
+    assert seen["perfetto"] is want
+    assert out["xplane"].endswith(".xplane.pb")
+    assert (out["perfetto_trace"] is not None) is want
+    with pytest.raises(urllib.error.HTTPError) as exc:
+        _post(server_url, "/api/v1/profile",
+              {"seconds": 0.5, "perfetto": "yes"})
+    assert exc.value.code == 400
+
+
+# -- step phases (StepTelemetry.span) ----------------------------------------
+
+
+class _Clock:
+    """perf_counter under the test's control."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+def _span(st, clock, name, seconds):
+    with st.span(name):
+        clock.t += seconds
+
+
+def test_span_seconds_and_gap_land_in_the_record(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(obs_steps.time, "perf_counter", clock)
+    st = obs_steps.StepTelemetry(impl="t")
+    for name, dt in (("admin", 0.001), ("schedule", 0.002),
+                     ("build", 0.003), ("dispatch", 0.004),
+                     ("sample", 0.005), ("fetch", 0.050)):
+        _span(st, clock, name, dt)
+    first = st.record("decode", rows=1, tokens=1, wall_s=0.062)
+    assert first.phases == pytest.approx(
+        {"admin": 0.001, "schedule": 0.002, "build": 0.003,
+         "dispatch": 0.004, "sample": 0.005, "fetch": 0.050})
+    assert first.gap_s is None            # no fetch before this step
+    assert st.open_phase("dispatch") is None      # record cleared it
+    # what follows a record belongs to the NEXT one: the emit of the
+    # step just recorded, then the next step's own phases; a phase
+    # entered twice adds up
+    _span(st, clock, "emit", 0.006)
+    clock.t += 0.0005                     # uncovered loop time
+    _span(st, clock, "admin", 0.001)
+    _span(st, clock, "build", 0.002)
+    _span(st, clock, "dispatch", 0.004)
+    _span(st, clock, "dispatch", 0.001)
+    assert st.open_phase("dispatch") == pytest.approx(0.005)
+    _span(st, clock, "fetch", 0.040)
+    second = st.record("decode", rows=1, tokens=1, wall_s=0.05)
+    assert second.phases["emit"] == pytest.approx(0.006)
+    assert second.phases["dispatch"] == pytest.approx(0.005)
+    # end of the previous fetch -> start of this step's FIRST dispatch
+    assert second.gap_s == pytest.approx(0.006 + 0.0005 + 0.001 + 0.002)
+    d = second.to_dict()
+    assert d["gap_s"] == pytest.approx(second.gap_s, abs=1e-6)
+    assert set(d["phases"]) == {"emit", "admin", "build", "dispatch",
+                                "fetch"}
+    assert "phases" not in st.record("decode", wall_s=0.01).to_dict()
+
+
+def test_wait_span_belongs_to_no_step(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(obs_steps.time, "perf_counter", clock)
+    st = obs_steps.StepTelemetry(impl="t")
+    _span(st, clock, "dispatch", 0.004)
+    _span(st, clock, "fetch", 0.050)
+    st.record("decode", wall_s=0.054)
+    _span(st, clock, "emit", 0.006)
+    _span(st, clock, "wait", 0.050)       # nothing to run
+    _span(st, clock, "dispatch", 0.004)
+    _span(st, clock, "fetch", 0.050)
+    rec = st.record("decode", wall_s=0.054)
+    assert "emit" not in rec.phases and "wait" not in rec.phases
+    assert rec.gap_s is None              # an idle engine is no gap
+
+
+def test_span_annotation_carries_the_records_step_number(monkeypatch):
+    made = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            made.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    st = obs_steps.StepTelemetry(impl="t")
+    for _ in range(2):
+        with st.span("dispatch"):
+            pass
+        with st.span("fetch"):
+            pass
+        rec = st.record("decode", wall_s=0.01)
+        assert made[-2:] == [("cake/dispatch", {"step": rec.step}),
+                             ("cake/fetch", {"step": rec.step})]
+    assert set(obs_steps.PHASES) >= {"admin", "schedule", "build",
+                                     "dispatch", "sample", "fetch",
+                                     "emit", "wait"}
+
+
+@pytest.fixture(scope="module")
+def paged_engine():
+    eng = _make_engine(kv_pages=16, kv_page_size=16)
+    with eng:
+        # the prompt rides mixed steps as chunk rows, then decode steps
+        h = eng.submit(list(range(3, 3 + 40)), max_new_tokens=10)
+        assert h.wait(180)
+        yield eng
+
+
+@pytest.mark.parametrize("kind", ["mixed", "decode"])
+def test_paged_step_timings_are_the_spans(paged_engine, kind):
+    """dispatch_s is the `dispatch` span and device_s the `fetch` span
+    of a mixed and of a decode step (both used to repeat wall_s);
+    wall_s stays the whole step."""
+    recs = [r for r in paged_engine.flight.dump() if r["kind"] == kind]
+    assert recs, paged_engine.flight.summary()
+    for r in recs:
+        ph = r["phases"]
+        assert {"build", "dispatch", "sample", "fetch"} <= set(ph), r
+        assert r["dispatch_s"] == pytest.approx(ph["dispatch"], abs=2e-6)
+        assert r["device_s"] == pytest.approx(ph["fetch"], abs=2e-6)
+        assert r["dispatch_s"] < r["wall_s"] and r["device_s"] < r["wall_s"]
+        assert r["dispatch_s"] != r["device_s"]
+        covered = sum(ph[k] for k in ("build", "dispatch", "sample",
+                                      "fetch"))
+        assert covered <= r["wall_s"] + 1e-4
+
+
+def test_paged_decode_steps_carry_gap_and_emit(paged_engine):
+    recs = [r for r in reversed(paged_engine.flight.dump())
+            if r["kind"] == "decode"]
+    later = recs[1:]          # the first follows a mixed step's fetch too
+    assert later and all("gap_s" in r for r in later)
+    for r in later:
+        # the gap is host work: the previous step's emit, the loop's
+        # admin and schedule, this step's build
+        ph = r["phases"]
+        host = sum(ph.get(k, 0.0) for k in ("emit", "admin", "schedule",
+                                            "build"))
+        assert 0 < host <= r["gap_s"] + 1e-4, r
+        assert ph["emit"] > 0
+
+
+def test_dense_engine_steps_carry_phases(engine):
+    recs = list(reversed(engine.flight.dump()))
+    prefill = [r for r in recs if r["kind"] == "prefill"]
+    decode = [r for r in recs if r["kind"] == "decode"]
+    assert prefill and decode
+    assert {"schedule", "build", "dispatch", "sample", "fetch"} \
+        <= set(prefill[0]["phases"])
+    assert all({"build", "dispatch", "sample", "fetch", "emit"}
+               <= set(r["phases"]) for r in decode)
+    assert any("gap_s" in r for r in decode)
+
+
+def test_steps_endpoint_carries_phases_and_gap(server_url):
+    _post(server_url, "/api/v1/chat/completions",
+          {"messages": [{"role": "user", "content": "hi"}],
+           "max_tokens": 4}, timeout=120)
+    steps = json.loads(urllib.request.urlopen(
+        server_url + "/api/v1/steps", timeout=10).read())["steps"]
+    assert all("phases" in s for s in steps), steps
+    assert any("gap_s" in s for s in steps)
+    for s in steps:
+        assert set(s["phases"]) <= set(obs_steps.PHASES) - {"wait"}
+
+
+def test_capture_holds_cake_spans_joined_by_step_number(tmp_path):
+    """A jax.profiler capture of a toy paged engine holds the
+    `cake/<phase>` events on the host plane, each carrying the step
+    number of the /api/v1/steps record its seconds went into."""
+    from cake_tpu.utils.profiling import capture_trace
+    eng = _make_engine(kv_pages=16, kv_page_size=16)
+    got = {}
+    with eng:
+        h = eng.submit(list(range(3, 3 + 20)), max_new_tokens=4)
+        assert h.wait(180)                # compile outside the capture
+        t = threading.Thread(target=lambda: got.update(
+            capture_trace(0.8, str(tmp_path))))
+        t.start()
+        import time
+        time.sleep(0.25)
+        h = eng.submit(list(range(3, 3 + 20)), max_new_tokens=6)
+        assert h.wait(180)
+        t.join(120)
+        recs = {r["step"]: r for r in eng.flight.dump()}
+    assert got["xplane"] and got["perfetto_trace"] is None
+    from jax.profiler import ProfileData
+    seen = {}
+    for plane in ProfileData.from_file(got["xplane"]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("cake/"):
+                    step = int(dict(ev.stats)["step"])
+                    seen.setdefault(step, {}).setdefault(
+                        ev.name[5:], 0.0)
+                    seen[step][ev.name[5:]] += ev.duration_ns / 1e9
+    joined = [n for n in seen if n in recs and "dispatch" in seen[n]]
+    assert len(joined) >= 4, (sorted(seen), sorted(recs))
+    for n in joined:
+        for name in ("dispatch", "fetch"):
+            # the same span on two clocks
+            assert seen[n][name] == pytest.approx(
+                recs[n]["phases"][name], abs=2e-3)
