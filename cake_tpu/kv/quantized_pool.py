@@ -10,8 +10,12 @@ in registers exactly like `ops/quant.py` weight-only matmuls.
 
 Layout (the paged pool's, with a scale sidecar):
 
-  pool.q:     [L, N_pages, page, KV, hd] int8
-  pool.scale: [L, N_pages, KV]           f32
+  pool.q:     [L, N_pages, page, KV*hd] int8
+  pool.scale: [L, N_pages, KV]          f32
+
+The two minor axes are stored flattened, the shape the attention kernels
+stream (`ops/ragged_paged_attention.py`); the per-head [.., KV, hd] view
+exists only on the few pages or token rows a writer has gathered.
 
 The scale is PER PAGE, which is what makes spill/restore trivial (a
 page + its scale row is self-contained) but means incremental writes
@@ -32,15 +36,17 @@ The INT4 variant (`Int4Pool`) halves the bytes again: a page stores
 nibble-packed values (the `ops/int4_matmul.pack_int4` group-halves
 layout with one group per page — token t rides the LOW nibble of
 packed row t, token t + page/2 the HIGH nibble, bias +8) in a
-[L, N_pages, page//2, KV, hd] uint8 pool, same f32 scale sidecar, same
+[L, N_pages, page//2, KV*hd] uint8 pool, same f32 scale sidecar, same
 monotone-scale RMW discipline at qmax 7. Every writer here is
 polymorphic over the two pool types: int4 pages unpack on gather and
 repack on scatter, so the quantization math is shared line-for-line.
 
-`QuantPool`/`Int4Pool` are NamedTuples, so a stacked [L, ...] pool
-rides `lax.scan` over the block axis unchanged — each layer's body
-sees a per-layer pool leaf pair, and the writers in
-`models/llama/paged.py` dispatch on the leaf type.
+`QuantPool`/`Int4Pool` are NamedTuples, so the stacked [L, ...] pool
+rides the layer loop's carry like a plain array pool
+(`models/llama/paged.scan_layers_paged`): every writer here takes the
+STACKED pool and a layer index, gathers `[layer, pages]`, and scatters
+the same pages back in place — no per-layer slice of a pool exists.
+The writers in `models/llama/paged.py` dispatch on the leaf type.
 """
 
 from __future__ import annotations
@@ -61,8 +67,8 @@ _EPS = 1e-8
 class QuantPool(NamedTuple):
     """One int8 page pool half (k or v): values + per-page scales.
 
-    q:     int8, [(L,) N_pages, page, KV, hd]
-    scale: f32,  [(L,) N_pages, KV]
+    q:     int8, [L, N_pages, page, KV*hd]
+    scale: f32,  [L, N_pages, KV]
     """
 
     q: jnp.ndarray
@@ -72,11 +78,11 @@ class QuantPool(NamedTuple):
 class Int4Pool(NamedTuple):
     """One int4 page pool half (k or v): nibble-packed values + scales.
 
-    q:     uint8, [(L,) N_pages, page//2, KV, hd] — two tokens per
+    q:     uint8, [L, N_pages, page//2, KV*hd] — two tokens per
            byte: token t in the low nibble of packed row t, token
            t + page//2 in the high nibble, +8 bias (pack_int4 layout
            with one group per page)
-    scale: f32,   [(L,) N_pages, KV]
+    scale: f32,   [L, N_pages, KV]
     """
 
     q: jnp.ndarray
@@ -110,31 +116,40 @@ def _pool_qmax(pool) -> float:
 
 
 def _pool_page(pool) -> int:
-    """Tokens per page for a per-layer pool leaf (the packed int4 axis
-    stores two tokens per row)."""
-    return pool.q.shape[1] * (2 if isinstance(pool, Int4Pool) else 1)
+    """Tokens per page of a stacked pool (the packed int4 axis stores
+    two tokens per row)."""
+    return pool.q.shape[2] * (2 if isinstance(pool, Int4Pool) else 1)
 
 
-def _gather_q(pool, idx) -> jnp.ndarray:
-    """Gather pages `idx` as UNPACKED int values [..., P, KV, hd].
-    Out-of-range ids fill with garbage that every caller either masks
-    (amax) or drops on the scatter-back."""
-    q = jnp.take(pool.q, idx, axis=0, mode="fill", fill_value=0)
+def _gather_q(pool, layer, idx) -> jnp.ndarray:
+    """Gather pages `idx` of `layer` as UNPACKED int values
+    [..., P, KV, hd]. Out-of-range ids fill with garbage that every
+    caller either masks (amax) or drops on the scatter-back."""
+    q = pool.q.at[layer, idx].get(mode="fill", fill_value=0)
+    q = q.reshape(q.shape[:-1] + (pool.scale.shape[-1], -1))
     if isinstance(pool, Int4Pool):
         q = unpack_page_nibbles(q)
     return q
 
 
-def _scatter_q(pool, idx, qw, new_s):
-    """Scatter whole pages back (packing int4 values first); OOB ids
-    drop. qw: [..., P, KV, hd] ints; new_s: [..., KV] f32."""
+def _gather_scale(pool, layer, idx) -> jnp.ndarray:
+    """Scales of pages `idx` of `layer`, [..., KV]; out-of-range ids
+    read 0."""
+    return pool.scale.at[layer, idx].get(mode="fill", fill_value=0.0)
+
+
+def _scatter_q(pool, layer, idx, qw, new_s):
+    """Scatter whole pages of `layer` back in place (packing int4
+    values first); OOB ids drop. qw: [..., P, KV, hd] ints; new_s:
+    [..., KV] f32."""
     if isinstance(pool, Int4Pool):
         qw = pack_page_nibbles(qw)
     else:
         qw = qw.astype(jnp.int8)
+    qw = qw.reshape(qw.shape[:-2] + (-1,))
     return pool._replace(
-        q=pool.q.at[idx].set(qw, mode="drop"),
-        scale=pool.scale.at[idx].set(new_s, mode="drop"),
+        q=pool.q.at[layer, idx].set(qw, mode="drop"),
+        scale=pool.scale.at[layer, idx].set(new_s, mode="drop"),
     )
 
 
@@ -174,7 +189,7 @@ class QuantizedPagedKVCache(NamedTuple):
         L = config.num_hidden_layers
         KV = config.num_key_value_heads
         hd = config.head_dim
-        shape = (L, n_pages, page_size, KV, hd)
+        shape = (L, n_pages, page_size, KV * hd)
         sshape = (L, n_pages, KV)
         return cls(
             k=QuantPool(q=jnp.zeros(shape, jnp.int8),
@@ -233,7 +248,7 @@ class Int4PagedKVCache(NamedTuple):
         L = config.num_hidden_layers
         KV = config.num_key_value_heads
         hd = config.head_dim
-        shape = (L, n_pages, page_size // 2, KV, hd)
+        shape = (L, n_pages, page_size // 2, KV * hd)
         sshape = (L, n_pages, KV)
         return cls(
             k=Int4Pool(q=jnp.zeros(shape, jnp.uint8),
@@ -289,21 +304,13 @@ def _requant(q_old: jnp.ndarray, ratio: jnp.ndarray,
         -qmax, qmax).astype(jnp.int8)
 
 
-def dequantize_pages(pool, idx: jnp.ndarray,
-                     fill_zero: bool = False) -> jnp.ndarray:
-    """Gather pages `idx` and dequantize to f32:
-    [*idx.shape, P, KV, hd]. fill_zero routes out-of-range ids to a
+def dequantize_pages(pool, layer, idx: jnp.ndarray) -> jnp.ndarray:
+    """Gather pages `idx` of `layer` from the stacked pool and
+    dequantize to f32: [*idx.shape, P, KV, hd]. Out-of-range ids read a
     zero page (the fold's unmapped-page semantics; an int4 fill page
     unpacks to -8s but its zero scale zeroes the product)."""
-    if fill_zero:
-        q = jnp.take(pool.q, idx, axis=0, mode="fill", fill_value=0)
-        s = jnp.take(pool.scale, idx, axis=0, mode="fill",
-                     fill_value=0.0)
-    else:
-        q = jnp.take(pool.q, idx, axis=0)
-        s = jnp.take(pool.scale, idx, axis=0)
-    if isinstance(pool, Int4Pool):
-        q = unpack_page_nibbles(q)
+    q = _gather_q(pool, layer, idx)
+    s = _gather_scale(pool, layer, idx)
     return q.astype(jnp.float32) * s[..., None, :, None]
 
 
@@ -323,10 +330,10 @@ def reset_page_scales(cache, pages):
     )
 
 
-# -- writers (per-layer pool leaves, models/llama/paged.py contracts) ---------
+# -- writers (stacked pool + layer index, models/llama/paged.py contracts) ----
 
 
-def qwrite_prompt_pages(pool, vals: jnp.ndarray,
+def qwrite_prompt_pages(pool, layer, vals: jnp.ndarray,
                         table_row: jnp.ndarray, n_real=None):
     """write_prompt_pages over a quantized pool (int8 or int4):
     page-ALIGNED windows
@@ -342,7 +349,7 @@ def qwrite_prompt_pages(pool, vals: jnp.ndarray,
     this write, so a garbage-inflated amax coarsens the page's real
     tokens for the page's whole life. Padding values are zeroed before
     quantization instead."""
-    N, P = pool.q.shape[0], _pool_page(pool)
+    N, P = pool.q.shape[1], _pool_page(pool)
     S = vals.shape[1]
     KV, hd = vals.shape[2], vals.shape[3]
     if n_real is not None:
@@ -356,10 +363,10 @@ def qwrite_prompt_pages(pool, vals: jnp.ndarray,
     idx = jnp.where(pages >= 0, pages, N)
     w = vals[0].reshape(n_win, P, KV, hd)
     q, scale = _quantize_windows(w, _pool_qmax(pool))
-    return _scatter_q(pool, idx, q, scale)
+    return _scatter_q(pool, layer, idx, q, scale)
 
 
-def qupdate_pool_per_row(pool, vals: jnp.ndarray, pos,
+def qupdate_pool_per_row(pool, layer, vals: jnp.ndarray, pos,
                          active, table):
     """update_pool_per_row over a quantized pool: each active row's
     decode token lands in ONE page — gather that page + scale, grow
@@ -368,7 +375,7 @@ def qupdate_pool_per_row(pool, vals: jnp.ndarray, pos,
     the B round-trips are disjoint; inactive/unmapped rows route to
     the out-of-bounds index on both the gather (zero/one fill) and the
     scatter (drop)."""
-    N, P = pool.q.shape[0], _pool_page(pool)
+    N, P = pool.q.shape[1], _pool_page(pool)
     qmax = _pool_qmax(pool)
     B = vals.shape[0]
     rows = jnp.arange(B)
@@ -376,9 +383,8 @@ def qupdate_pool_per_row(pool, vals: jnp.ndarray, pos,
     offs = pos % P
     valid = jnp.logical_and(active, pages >= 0)
     idx = jnp.where(valid, pages, N)
-    qs = _gather_q(pool, idx)                           # [B,P,KV,hd]
-    ss = jnp.take(pool.scale, idx, axis=0, mode="fill",
-                  fill_value=0.0)                       # [B,KV]
+    qs = _gather_q(pool, layer, idx)                    # [B,P,KV,hd]
+    ss = _gather_scale(pool, layer, idx)                # [B,KV]
     tok = vals[:, 0].astype(jnp.float32)                # [B,KV,hd]
     need = jnp.maximum(jnp.max(jnp.abs(tok), axis=-1), _EPS) / qmax
     new_s = jnp.maximum(ss, need)
@@ -387,10 +393,10 @@ def qupdate_pool_per_row(pool, vals: jnp.ndarray, pos,
                   -qmax, qmax).astype(jnp.int8)         # [B,KV,hd]
     mask = (jnp.arange(P)[None, :] == offs[:, None])    # [B,P]
     qw = jnp.where(mask[..., None, None], qt[:, None], qr)
-    return _scatter_q(pool, idx, qw, new_s)
+    return _scatter_q(pool, layer, idx, qw, new_s)
 
 
-def _window_pages_rmw(pool, vals, j_idx, off_idx, wmask_src,
+def _window_pages_rmw(pool, layer, vals, j_idx, off_idx, wmask_src,
                       idx, touched):
     """Shared gather -> rescale -> overwrite -> scatter core for the
     window writers. vals: [..., C, KV, hd] f32; j_idx/off_idx: window
@@ -402,9 +408,8 @@ def _window_pages_rmw(pool, vals, j_idx, off_idx, wmask_src,
     qmax = _pool_qmax(pool)
     KV, hd = vals.shape[-2], vals.shape[-1]
     lead = vals.shape[:-3]
-    qs = _gather_q(pool, idx)                      # [..., W, P, KV, hd]
-    ss = jnp.take(pool.scale, idx, axis=0, mode="fill",
-                  fill_value=0.0)                  # [..., W, KV]
+    qs = _gather_q(pool, layer, idx)               # [..., W, P, KV, hd]
+    ss = _gather_scale(pool, layer, idx)           # [..., W, KV]
     # place the window's values + mask into page coordinates: every
     # (page, offset) target is distinct within a row, so one scatter
     buf = jnp.zeros(lead + (W + 1, P, KV, hd), jnp.float32)
@@ -428,10 +433,10 @@ def _window_pages_rmw(pool, vals, j_idx, off_idx, wmask_src,
                                               None]),
                   -qmax, qmax).astype(jnp.int8)
     qw = jnp.where(msk[..., None, None], qt, qr)
-    return _scatter_q(pool, idx, qw, new_s)
+    return _scatter_q(pool, layer, idx, qw, new_s)
 
 
-def qwrite_window_pages(pool, vals: jnp.ndarray,
+def qwrite_window_pages(pool, layer, vals: jnp.ndarray,
                         table_row, pos0, n_real=None):
     """write_window_pages over a quantized pool: one C-token window at
     absolute position pos0 (any in-page offset). The window touches at
@@ -445,7 +450,7 @@ def qwrite_window_pages(pool, vals: jnp.ndarray,
     writer already masks by q_len). Padding positions neither write
     nor contribute to the amax, and pages touched only by padding are
     left alone entirely."""
-    N, P = pool.q.shape[0], _pool_page(pool)
+    N, P = pool.q.shape[1], _pool_page(pool)
     C = vals.shape[1]
     max_pages = table_row.shape[0]
     if n_real is None:
@@ -465,18 +470,18 @@ def qwrite_window_pages(pool, vals: jnp.ndarray,
     p_pages = table_row[jnp.minimum(pidx, max_pages - 1)]
     wvalid = ((jnp.arange(C) < n_real)
               & (pidx < max_pages) & (p_pages >= 0))
-    return _window_pages_rmw(pool, vals[0], pidx - first, pos % P,
+    return _window_pages_rmw(pool, layer, vals[0], pidx - first, pos % P,
                              wvalid, idx, touched)
 
 
-def qwrite_windows_pages(pool, vals: jnp.ndarray, pos,
+def qwrite_windows_pages(pool, layer, vals: jnp.ndarray, pos,
                          q_len, active, table):
     """write_windows_pages over a quantized pool: the batched mixed
     writer — every row's q_len-token window at its own offset, decode
     rows (q_len=1) included. Per row the window spans at most
     ceil(C/P)+1 consecutive pages; rows own disjoint (non-shared)
     pages, so the batched page round-trips never collide."""
-    N, P = pool.q.shape[0], _pool_page(pool)
+    N, P = pool.q.shape[1], _pool_page(pool)
     B, C = vals.shape[0], vals.shape[1]
     max_pages = table.shape[1]
     W = -(-C // P) + 1
@@ -495,5 +500,5 @@ def qwrite_windows_pages(pool, vals: jnp.ndarray, pos,
         table, jnp.minimum(pidx, max_pages - 1), axis=1)
     wvalid = ((jnp.arange(C)[None, :] < q_len[:, None])
               & active[:, None] & (pidx < max_pages) & (p_pages >= 0))
-    return _window_pages_rmw(pool, vals, pidx - first[:, None],
+    return _window_pages_rmw(pool, layer, vals, pidx - first[:, None],
                              positions % P, wvalid, idx, touched)

@@ -11,7 +11,7 @@ page allocator (host-side free list) gates admission instead of
 over-allocating HBM.
 
 Layout (all static shapes — XLA-friendly):
-  pool_k/pool_v: [L, N_pages, page, KV, hd]  (page = tokens per page)
+  pool_k/pool_v: [L, N_pages, page, KV*hd]   (page = tokens per page)
   table:         [slots, max_pages] int32    (page ids; -1 = unmapped)
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
@@ -50,8 +50,8 @@ class PagedKVCache(NamedTuple):
     """Device state of the paged cache. The page TABLE rides along as a
     device array (updated per admission/retire by the engine); the free
     list stays host-side in the allocator."""
-    k: jnp.ndarray        # [L, N_pages, page, KV, hd]
-    v: jnp.ndarray        # [L, N_pages, page, KV, hd]
+    k: jnp.ndarray        # [L, N_pages, page, KV*hd]
+    v: jnp.ndarray        # [L, N_pages, page, KV*hd]
     table: jnp.ndarray    # [slots, max_pages] int32, -1 = unmapped
 
     @property
@@ -81,7 +81,7 @@ class PagedKVCache(NamedTuple):
         L = config.num_hidden_layers
         KV = config.num_key_value_heads
         hd = config.head_dim
-        shape = (L, n_pages, page_size, KV, hd)
+        shape = (L, n_pages, page_size, KV * hd)
         return cls(
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(shape, dtype),
@@ -198,12 +198,35 @@ def table_set_slot(table: jnp.ndarray, slot: int,
 
 
 # -- device ops ---------------------------------------------------------------
+#
+# Every op below takes the STACKED pool ([L, N_pages, page, KV*hd], or a
+# QuantPool/Int4Pool of that layout) and a traced layer index. A writer
+# scatters its few token rows into `pool[layer]` in place; a reader
+# gathers pages `[layer, ids]`. None slices, reshapes or copies a pool.
+
+
+def _rows(x):
+    """[..., KV, hd] token rows -> the pool's flat [..., KV*hd]."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def gather_layer_pages(pool, layer, idx, kv_heads: int, dtype):
+    """Pages `idx` of `layer` as the per-head view [..., P, KV, hd] in
+    `dtype`; out-of-range ids read zeros. A quantized pool dequantizes
+    page by page on the gather. What the fold reference folds, and what
+    the prefix and chunk prefills attend beside their fresh window."""
+    if isinstance(pool, (QuantPool, Int4Pool)):
+        return dequantize_pages(pool, layer, idx).astype(dtype)
+    pages = pool.at[layer, idx].get(mode="fill", fill_value=0)
+    return pages.reshape(pages.shape[:-1] + (kv_heads, -1)).astype(dtype)
 
 
 @jax.named_scope("kv")
-def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
+def write_prompt_pages(pool_k, pool_v, layer, k, v, table_row,
+                       n_real=None):
     """Scatter a prompt window's KV ([1, S, KV, hd]) into the pool pages
-    of one slot (per layer — callers run this inside the block scan).
+    of one slot, in layer `layer` of the stacked pool (callers run this
+    inside the layer loop).
 
     S need not divide the page size: the final partial window is
     zero-padded to a whole page (a bucket smaller than one page is one
@@ -212,8 +235,8 @@ def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
     edge). Padding positions land in their mapped page as garbage and
     are overwritten by decode before they can be attended, exactly like
     dense padding. UNMAPPED pages (id -1) must not be written — page 0
-    would alias another slot — so those windows write their page's
-    current contents back (masked write).
+    would alias another slot — so those windows route to the
+    out-of-bounds index and drop.
 
     A QuantPool (int8 KV tiering, cake_tpu/kv) quantizes on scatter:
     page-aligned windows fully overwrite their pages, so each window
@@ -222,11 +245,10 @@ def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
     dead data in an f32 pool but would inflate the fresh page scales,
     so the quantized writer zeroes positions >= n_real first."""
     if isinstance(pool_k, (QuantPool, Int4Pool)):
-        return (qwrite_prompt_pages(pool_k, k, table_row, n_real),
-                qwrite_prompt_pages(pool_v, v, table_row, n_real))
-    N, P = pool_k.shape[0], pool_k.shape[1]
+        return (qwrite_prompt_pages(pool_k, layer, k, table_row, n_real),
+                qwrite_prompt_pages(pool_v, layer, v, table_row, n_real))
+    N, P = pool_k.shape[1], pool_k.shape[2]
     S = k.shape[1]
-    KV, hd = k.shape[2], k.shape[3]
     n_win = -(-S // P)
     pad = n_win * P - S
     if pad:
@@ -236,18 +258,18 @@ def write_prompt_pages(pool_k, pool_v, k, v, table_row, n_real=None):
     # index N and mode="drop" skips them (no dummy-page read-back)
     pages = table_row[:n_win]
     idx = jnp.where(pages >= 0, pages, N)
-    kw = k[0].reshape(n_win, P, KV, hd)
-    vw = v[0].reshape(n_win, P, KV, hd)
-    pk = pool_k.at[idx].set(kw.astype(pool_k.dtype), mode="drop")
-    pv = pool_v.at[idx].set(vw.astype(pool_v.dtype), mode="drop")
+    kw = _rows(k[0]).reshape(n_win, P, -1)
+    vw = _rows(v[0]).reshape(n_win, P, -1)
+    pk = pool_k.at[layer, idx].set(kw.astype(pool_k.dtype), mode="drop")
+    pv = pool_v.at[layer, idx].set(vw.astype(pool_v.dtype), mode="drop")
     return pk, pv
 
 
 @jax.named_scope("kv")
-def write_window_pages(pool_k, pool_v, k, v, table_row, pos0,
+def write_window_pages(pool_k, pool_v, layer, k, v, table_row, pos0,
                        n_real=None):
     """Scatter one prefill window's KV ([1, C, KV, hd]) at absolute
-    position `pos0` into one slot's pages (per layer).
+    position `pos0` into one slot's pages of layer `layer`.
 
     Unlike write_prompt_pages, pos0 need NOT be page-aligned: each of
     the C positions resolves its own (page, offset) pair through the
@@ -263,9 +285,11 @@ def write_window_pages(pool_k, pool_v, k, v, table_row, pos0,
     scalar) keeps the window's bucket-padding garbage out of the
     monotone page scales there (dead data for an f32 pool)."""
     if isinstance(pool_k, (QuantPool, Int4Pool)):
-        return (qwrite_window_pages(pool_k, k, table_row, pos0, n_real),
-                qwrite_window_pages(pool_v, v, table_row, pos0, n_real))
-    N, P = pool_k.shape[0], pool_k.shape[1]
+        return (qwrite_window_pages(pool_k, layer, k, table_row, pos0,
+                                    n_real),
+                qwrite_window_pages(pool_v, layer, v, table_row, pos0,
+                                    n_real))
+    N, P = pool_k.shape[1], pool_k.shape[2]
     C = k.shape[1]
     max_pages = table_row.shape[0]
     pos = pos0 + jnp.arange(C)
@@ -274,17 +298,21 @@ def write_window_pages(pool_k, pool_v, k, v, table_row, pos0,
     valid = jnp.logical_and(pidx < max_pages, pages >= 0)
     idx = jnp.where(valid, pages, N)
     offs = pos % P
-    pk = pool_k.at[idx, offs].set(k[0].astype(pool_k.dtype), mode="drop")
-    pv = pool_v.at[idx, offs].set(v[0].astype(pool_v.dtype), mode="drop")
+    pk = pool_k.at[layer, idx, offs].set(
+        _rows(k[0]).astype(pool_k.dtype), mode="drop")
+    pv = pool_v.at[layer, idx, offs].set(
+        _rows(v[0]).astype(pool_v.dtype), mode="drop")
     return pk, pv
 
 
 @jax.named_scope("kv")
-def write_windows_pages(pool_k, pool_v, k, v, pos, q_len, active, table):
+def write_windows_pages(pool_k, pool_v, layer, k, v, pos, q_len, active,
+                        table):
     """Batched write_window_pages: every row scatters its q_len-token
-    window at absolute position pos[b] into its own pages (per layer).
+    window at absolute position pos[b] into its own pages of layer
+    `layer`.
 
-    pool_k/v: [N_pages, page, KV, hd]; k/v: [B, C, KV, hd]; pos/q_len:
+    pool_k/v: [L, N_pages, page, KV*hd]; k/v: [B, C, KV, hd]; pos/q_len:
     [B]; active: [B] bool; table: [slots(=B), max_pages]. One
     vectorized scatter covers the whole mixed batch: decode rows write
     their single token (q_len=1), prefill-chunk rows their window, and
@@ -296,11 +324,11 @@ def write_windows_pages(pool_k, pool_v, k, v, pos, q_len, active, table):
     A QuantPool quantizes on scatter via per-row touched-page
     read-modify-writes (kv/quantized_pool.qwrite_windows_pages)."""
     if isinstance(pool_k, (QuantPool, Int4Pool)):
-        return (qwrite_windows_pages(pool_k, k, pos, q_len, active,
-                                     table),
-                qwrite_windows_pages(pool_v, v, pos, q_len, active,
-                                     table))
-    N, P = pool_k.shape[0], pool_k.shape[1]
+        return (qwrite_windows_pages(pool_k, layer, k, pos, q_len,
+                                     active, table),
+                qwrite_windows_pages(pool_v, layer, v, pos, q_len,
+                                     active, table))
+    N, P = pool_k.shape[1], pool_k.shape[2]
     B, C = k.shape[0], k.shape[1]
     max_pages = table.shape[1]
     positions = pos[:, None] + jnp.arange(C)[None, :]         # [B, C]
@@ -311,44 +339,108 @@ def write_windows_pages(pool_k, pool_v, k, v, pos, q_len, active, table):
              & active[:, None] & (pidx < max_pages) & (pages >= 0))
     idx = jnp.where(valid, pages, N)
     offs = positions % P
-    pk = pool_k.at[idx, offs].set(k.astype(pool_k.dtype), mode="drop")
-    pv = pool_v.at[idx, offs].set(v.astype(pool_v.dtype), mode="drop")
+    pk = pool_k.at[layer, idx, offs].set(
+        _rows(k).astype(pool_k.dtype), mode="drop")
+    pv = pool_v.at[layer, idx, offs].set(
+        _rows(v).astype(pool_v.dtype), mode="drop")
     return pk, pv
 
 
 @jax.named_scope("kv")
-def update_pool_per_row(pool_k, pool_v, k, v, pos, active, table):
-    """Write one decode token per row into its page (per layer).
+def update_pool_per_row(pool_k, pool_v, layer, k, v, pos, active, table):
+    """Write one decode token per row into its page of layer `layer`.
 
-    pool_k/v: [N_pages, page, KV, hd]; k/v: [B, 1, KV, hd]; pos: [B];
+    pool_k/v: [L, N_pages, page, KV*hd]; k/v: [B, 1, KV, hd]; pos: [B];
     active: [B] bool; table: [slots(=B), max_pages]. One vectorized
-    scatter (distinct slots own distinct pages, so the B targets are
-    disjoint); inactive rows — and rows whose position lands on an
-    unmapped page — route to the out-of-bounds index and mode="drop"
-    skips them.
+    scatter of B token rows (distinct slots own distinct pages, so the
+    B targets are disjoint); inactive rows — and rows whose position
+    lands on an unmapped page — route to the out-of-bounds index and
+    mode="drop" skips them.
 
     A QuantPool quantizes on scatter: each row's page is gathered,
     its scale grown to cover the new token, residents re-quantized,
     and the page scattered back (kv/quantized_pool)."""
     if isinstance(pool_k, (QuantPool, Int4Pool)):
-        return (qupdate_pool_per_row(pool_k, k, pos, active, table),
-                qupdate_pool_per_row(pool_v, v, pos, active, table))
-    N, P = pool_k.shape[0], pool_k.shape[1]
+        return (qupdate_pool_per_row(pool_k, layer, k, pos, active, table),
+                qupdate_pool_per_row(pool_v, layer, v, pos, active, table))
+    N, P = pool_k.shape[1], pool_k.shape[2]
     B = k.shape[0]
     rows = jnp.arange(B)
     pages = table[rows, pos // P]
     offs = pos % P
     valid = jnp.logical_and(active, pages >= 0)
     idx = jnp.where(valid, pages, N)
-    pk = pool_k.at[idx, offs].set(k[:, 0].astype(pool_k.dtype),
-                                  mode="drop")
-    pv = pool_v.at[idx, offs].set(v[:, 0].astype(pool_v.dtype),
-                                  mode="drop")
+    pk = pool_k.at[layer, idx, offs].set(
+        _rows(k[:, 0]).astype(pool_k.dtype), mode="drop")
+    pv = pool_v.at[layer, idx, offs].set(
+        _rows(v[:, 0]).astype(pool_v.dtype), mode="drop")
     return pk, pv
 
 
-def paged_attention(q, pool_k, pool_v, table, pos, *, impl: str = "fold"):
-    """Ragged decode attention over paged KV.
+def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
+    """The XLA reference both paged attentions share: a fori_loop over
+    all max_pages, every page gathered once from `pool[layer]` and
+    folded into running (m, l, o) stats. causal_bound: [B, C] — the
+    last absolute slot query (b, i) attends."""
+    B, C, H, hd = q.shape
+    _, N, P, width = getattr(pool_k, "q", pool_k).shape
+    KV = width // hd
+    if isinstance(pool_k, Int4Pool):
+        P *= 2      # the packed axis stores two tokens per byte
+    max_pages = table.shape[1]
+    G = H // KV
+    m0 = jnp.full((B, KV, G, C, 1), -1e30, jnp.float32)
+    l0 = jnp.zeros((B, KV, G, C, 1), jnp.float32)
+    o0 = jnp.zeros((B, KV, G, C, hd), jnp.float32)
+
+    def fold(j, carry):
+        m, l, o = carry
+        pages = table[:, j]                          # [B]
+        # unmapped slots route to the out-of-bounds index N with a zero
+        # fill instead of gathering page 0 (which aliases another
+        # slot's live data into the masked lanes). Whether the OOB row
+        # read is actually elided is up to the XLA gather lowering —
+        # the guarantee that dead pages cost NO bandwidth lives in the
+        # pallas kernel's index-map clamp, not here; the fold's masking
+        # (below) keeps the fill value out of the output either way.
+        idx = jnp.where(pages >= 0, pages, N)
+        # a quantized pool dequantizes in the loop: int page * its
+        # per-head scale, in f32 — the bit-exact reference the int8 and
+        # int4 pallas kernels are pinned against
+        kj = gather_layer_pages(pool_k, layer, idx, KV, q.dtype)
+        vj = gather_layer_pages(pool_v, layer, idx, KV, q.dtype)
+        # validity: absolute slot j*P + t attends for query i iff it is
+        # <= the query's causal bound (current token included) AND the
+        # page is mapped
+        slots_abs = j * P + jnp.arange(P)            # [P]
+        valid = slots_abs[None, None, :] <= causal_bound[:, :, None]
+        valid &= (pages >= 0)[:, None, None]
+        valid = valid[:, None, None, :, :]           # [B,1,1,C,P]
+        mj, lj, oj = partial_attention_stats(q, kj, vj, valid)
+        m_new = jnp.maximum(m, mj)
+        a_old = jnp.exp(m - m_new)
+        a_new = jnp.exp(mj - m_new)
+        return (m_new, a_old * l + a_new * lj,
+                a_old * o + a_new * oj)
+
+    m, l, o = lax.fori_loop(0, max_pages, fold, (m0, l0, o0))
+    out = merge_attention_stats([(m, l, o)])
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(
+        B, C, H, hd).astype(q.dtype)
+
+
+def _kernel_pools(pool_k, pool_v):
+    """The kernels' pool operands: (k, v, keyword arguments)."""
+    if isinstance(pool_k, (QuantPool, Int4Pool)):
+        return pool_k.q, pool_v.q, dict(
+            scale_k=pool_k.scale, scale_v=pool_v.scale,
+            packed4=isinstance(pool_k, Int4Pool))
+    return pool_k, pool_v, {}
+
+
+def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
+                    impl: str = "fold"):
+    """Ragged decode attention over layer `layer` of the paged KV.
 
     impl="fold" (the documented REFERENCE semantics): an XLA fori_loop
     over all max_pages — online-softmax accumulation where every page is
@@ -362,82 +454,27 @@ def paged_attention(q, pool_k, pool_v, table, pos, *, impl: str = "fold"):
     falls back.
 
     q: [B, 1, H, hd] (rope already applied; the current token's KV must
-    already be written to its page); pool_k/v: [N_pages, page, KV, hd];
-    table: [B, max_pages]; pos: [B] (position of the CURRENT token).
+    already be written to its page); pool_k/v: the stacked pool
+    [L, N_pages, page, KV*hd]; layer: traced int32 scalar; table:
+    [B, max_pages]; pos: [B] (position of the CURRENT token).
     Returns [B, 1, H, hd].
     """
-    B, _, H, hd = q.shape
-    quant = isinstance(pool_k, (QuantPool, Int4Pool))
-    packed4 = isinstance(pool_k, Int4Pool)
-    pk_arr = pool_k.q if quant else pool_k
-    N, P, KV = pk_arr.shape[0], pk_arr.shape[1], pk_arr.shape[2]
-    if packed4:
-        P *= 2      # the packed axis stores two tokens per byte
-    max_pages = table.shape[1]
-
     if impl == "pallas":
         from cake_tpu.ops.ragged_paged_attention import (
             ragged_paged_attention,
         )
-        if quant:
-            return ragged_paged_attention(
-                q, pool_k.q, pool_v.q, table, pos,
-                scale_k=pool_k.scale, scale_v=pool_v.scale,
-                packed4=packed4)
-        return ragged_paged_attention(q, pool_k, pool_v, table, pos)
+        kq, vq, kw = _kernel_pools(pool_k, pool_v)
+        return ragged_paged_attention(q, kq, vq, layer, table, pos, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
-
-    m0 = jnp.full((B, KV, H // KV, 1, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, KV, H // KV, 1, 1), jnp.float32)
-    o0 = jnp.zeros((B, KV, H // KV, 1, hd), jnp.float32)
-
-    def fold(j, carry):
-        m, l, o = carry
-        pages = table[:, j]                          # [B]
-        # unmapped slots route to the out-of-bounds index N with a zero
-        # fill instead of gathering page 0 (which aliases another
-        # slot's live data into the masked lanes). Whether the OOB row
-        # read is actually elided is up to the XLA gather lowering —
-        # the guarantee that dead pages cost NO bandwidth lives in the
-        # pallas kernel's index-map clamp, not here; the fold's masking
-        # (below) keeps the fill value out of the output either way.
-        idx = jnp.where(pages >= 0, pages, N)
-        if quant:
-            # dequantize in the loop: int8 page * its per-head scale,
-            # in f32 — the bit-exact reference the int8 pallas kernel
-            # is pinned against
-            kj = dequantize_pages(pool_k, idx,
-                                  fill_zero=True).astype(q.dtype)
-            vj = dequantize_pages(pool_v, idx,
-                                  fill_zero=True).astype(q.dtype)
-        else:
-            kj = jnp.take(pool_k, idx, axis=0, mode="fill",
-                          fill_value=0)              # [B,P,KV,hd]
-            vj = jnp.take(pool_v, idx, axis=0, mode="fill",
-                          fill_value=0)
-        # validity: absolute slots j*P + t attend when <= pos (causal,
-        # current token included) AND the page is mapped
-        slots_abs = j * P + jnp.arange(P)            # [P]
-        valid = (slots_abs[None] <= pos[:, None]) & (pages >= 0)[:, None]
-        valid = valid[:, None, None, None, :]        # [B,1,1,1,P]
-        mj, lj, oj = partial_attention_stats(q, kj, vj, valid)
-        m_new = jnp.maximum(m, mj)
-        a_old = jnp.exp(m - m_new)
-        a_new = jnp.exp(mj - m_new)
-        return (m_new, a_old * l + a_new * lj,
-                a_old * o + a_new * oj)
-
-    m, l, o = lax.fori_loop(0, max_pages, fold, (m0, l0, o0))
-    out = merge_attention_stats([(m, l, o)])
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(
-        B, 1, H, hd).astype(q.dtype)
+    return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None])
 
 
-def paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
+def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
                           impl: str = "fold"):
-    """Mixed ragged attention over paged KV: decode rows (q_len=1) and
-    prefill-chunk rows (q_len=C at arbitrary page offset) in ONE batch.
+    """Mixed ragged attention over layer `layer` of the paged KV: decode
+    rows (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page
+    offset) in ONE batch.
 
     impl="fold" (the bit-exact REFERENCE semantics, exactly as the fold
     is for decode): an XLA fori_loop over all max_pages — per-query
@@ -454,71 +491,54 @@ def paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
     q_len: [B] real query tokens (0 = idle row). Columns past q_len are
     padding whose output the caller never reads. Returns [B, C, H, hd].
     """
-    B, C, H, hd = q.shape
-    quant = isinstance(pool_k, (QuantPool, Int4Pool))
-    packed4 = isinstance(pool_k, Int4Pool)
-    pk_arr = pool_k.q if quant else pool_k
-    N, P, KV = pk_arr.shape[0], pk_arr.shape[1], pk_arr.shape[2]
-    if packed4:
-        P *= 2      # the packed axis stores two tokens per byte
-    max_pages = table.shape[1]
-
     if impl == "pallas":
         from cake_tpu.ops.ragged_paged_attention import (
             ragged_paged_attention_mixed,
         )
-        if quant:
-            return ragged_paged_attention_mixed(
-                q, pool_k.q, pool_v.q, table, pos, q_len,
-                scale_k=pool_k.scale, scale_v=pool_v.scale,
-                packed4=packed4)
-        return ragged_paged_attention_mixed(q, pool_k, pool_v,
-                                            table, pos, q_len)
+        kq, vq, kw = _kernel_pools(pool_k, pool_v)
+        return ragged_paged_attention_mixed(q, kq, vq, layer, table, pos,
+                                            q_len, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
-
-    G = H // KV
-    m0 = jnp.full((B, KV, G, C, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, KV, G, C, 1), jnp.float32)
-    o0 = jnp.zeros((B, KV, G, C, hd), jnp.float32)
-    qi = jnp.arange(C)
-
-    def fold(j, carry):
-        m, l, o = carry
-        pages = table[:, j]                          # [B]
-        idx = jnp.where(pages >= 0, pages, N)
-        if quant:
-            kj = dequantize_pages(pool_k, idx,
-                                  fill_zero=True).astype(q.dtype)
-            vj = dequantize_pages(pool_v, idx,
-                                  fill_zero=True).astype(q.dtype)
-        else:
-            kj = jnp.take(pool_k, idx, axis=0, mode="fill",
-                          fill_value=0)              # [B,P,KV,hd]
-            vj = jnp.take(pool_v, idx, axis=0, mode="fill",
-                          fill_value=0)
-        # per-query causality: absolute slot j*P + t attends for query
-        # i iff <= pos + i (current token included) AND the page is
-        # mapped — the decode fold's mask with a query axis
-        slots_abs = j * P + jnp.arange(P)            # [P]
-        valid = (slots_abs[None, None, :]
-                 <= (pos[:, None] + qi[None, :])[:, :, None])
-        valid &= (pages >= 0)[:, None, None]
-        valid = valid[:, None, None, :, :]           # [B,1,1,C,P]
-        mj, lj, oj = partial_attention_stats(q, kj, vj, valid)
-        m_new = jnp.maximum(m, mj)
-        a_old = jnp.exp(m - m_new)
-        a_new = jnp.exp(mj - m_new)
-        return (m_new, a_old * l + a_new * lj,
-                a_old * o + a_new * oj)
-
-    m, l, o = lax.fori_loop(0, max_pages, fold, (m0, l0, o0))
-    out = merge_attention_stats([(m, l, o)])
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(
-        B, C, H, hd).astype(q.dtype)
+    # per-query causality: query i of row b sits at pos[b] + i
+    C = q.shape[1]
+    return _fold_pages(q, pool_k, pool_v, layer, table,
+                       pos[:, None] + jnp.arange(C)[None, :])
 
 
 # -- model-level steps (engine step-fn signatures) ----------------------------
+
+
+def scan_layers_paged(blocks, x, cache: PagedKVCache,
+                      config: LlamaConfig, layer_attn):
+    """The layer loop of every paged step program.
+
+    The stacked pool travels as loop CARRY beside the hidden state and
+    the layer index; only `blocks` are scanned. A scan's stacked outputs
+    cannot alias its inputs, so a pool passed as xs/ys is sliced per
+    layer, stacked back and kept twice; a carried pool that each layer
+    scatters its token rows into is one buffer from the donated input
+    to the output. Whatever pytree the pool is (plain arrays, QuantPool,
+    Int4Pool) rides along unchanged.
+
+    layer_attn(layer, pool_k, pool_v, q, k, v) -> (attn [B,S,H,hd],
+    pool_k, pool_v): write this layer's new KV, attend."""
+    from cake_tpu.models.llama.model import block_skeleton
+
+    def body(carry, lp):
+        h, layer, pk, pv = carry
+
+        def attn_fn(q, k, v):
+            out, pk2, pv2 = layer_attn(layer, pk, pv, q, k, v)
+            return out, (pk2, pv2)
+
+        h, (pk, pv) = block_skeleton(lp, h, config, attn_fn)
+        return (h, layer + 1, pk, pv), None
+
+    with jax.named_scope("layers"):
+        (x, _, pool_k, pool_v), _ = lax.scan(
+            body, (x, jnp.int32(0), cache.k, cache.v), blocks)
+    return x, cache._replace(k=pool_k, v=pool_v)
 
 
 def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
@@ -527,26 +547,17 @@ def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
     """run_blocks_ragged over the page pool: write the token, attend the
     pages. x: [B, 1, D]; pos/active: [B]; attn: paged_attention impl
     ({fold,pallas} — static under jit)."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.rope import apply_rope
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        pk, pv = update_pool_per_row(pk, pv, layer, k, v, pos, active,
+                                     cache.table)
+        return (paged_attention(q, pk, pv, layer, cache.table, pos,
+                                impl=attn), pk, pv)
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = update_pool_per_row(pk, pv, k, v, pos, active,
-                                           cache.table)
-            return (paged_attention(q, pk2, pv2, cache.table, pos,
-                                    impl=attn), (pk2, pv2))
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
-    return x, cache._replace(k=k_new, v=v_new)
+    return scan_layers_paged(blocks, x, cache, config, layer_attn)
 
 
 def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
@@ -604,7 +615,6 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
     flash over the in-window k/v is exact — no page reads are needed at
     prefill); untileable shapes fall back to the einsum path like the
     dense prefill."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.attention import causal_mask, gqa_attention
     from cake_tpu.ops.flash_attention import (
         flash_attention, flash_supported,
@@ -624,31 +634,24 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
                  and flash_supported(S, S, H, KV, hd=config.head_dim))
     mask = None if use_flash else causal_mask(S)
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        pk, pv = write_prompt_pages(pk, pv, layer, k, v, table_row,
+                                    prompt_len[0])
+        if use_flash:
+            return flash_attention(q, k, v, causal=True), pk, pv
+        return gqa_attention(q, k, v, mask=mask), pk, pv
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = write_prompt_pages(pk, pv, k, v, table_row,
-                                          prompt_len[0])
-            if use_flash:
-                return flash_attention(q, k, v, causal=True), (pk2, pv2)
-            return gqa_attention(q, k, v, mask=mask), (pk2, pv2)
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["blocks"], cache.k, cache.v))
+    x, cache = scan_layers_paged(params["blocks"], x, cache, config,
+                                 layer_attn)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
             x, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
         )[:, 0]
         logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache._replace(k=k_new, v=v_new)
+    return logits, cache
 
 
 # -- prefix sharing + chunked prefill (page-granular) --------------------------
@@ -672,7 +675,6 @@ def prefill_prefix_pages(params, tokens, table_row,
     from the suffix prefill). attn="pallas" routes the fresh-window
     attention through the Pallas flash kernel like prefill_slot_paged.
     Returns the updated cache."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.attention import causal_mask, gqa_attention
     from cake_tpu.ops.flash_attention import (
         flash_attention, flash_supported,
@@ -689,25 +691,18 @@ def prefill_prefix_pages(params, tokens, table_row,
                  and flash_supported(S, S, H, KV, hd=config.head_dim))
     mask = None if use_flash else causal_mask(S)
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        pk, pv = write_prompt_pages(pk, pv, layer, k, v, table_row)
+        if use_flash:
+            return flash_attention(q, k, v, causal=True), pk, pv
+        return gqa_attention(q, k, v, mask=mask), pk, pv
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = write_prompt_pages(pk, pv, k, v, table_row)
-            if use_flash:
-                return flash_attention(q, k, v, causal=True), (pk2, pv2)
-            return gqa_attention(q, k, v, mask=mask), (pk2, pv2)
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        _, (k_new, v_new) = lax.scan(body, x,
-                                     (params["blocks"], cache.k, cache.v))
     # final norm / lm_head skipped on purpose: only the KV matters here
-    return cache._replace(k=k_new, v=v_new)
+    _, cache = scan_layers_paged(params["blocks"], x, cache, config,
+                                 layer_attn)
+    return cache
 
 
 @_partial(jax.jit, static_argnames=("config", "n_prefix", "attn"),
@@ -732,7 +727,6 @@ def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
     small. attn="pallas" routes through the cache-aware flash kernel
     (queries at pos n_prefix+i attend keys <= n_prefix+i); decode needs
     no changes at all — the ragged kernel reads through the table."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.attention import gqa_attention
     from cake_tpu.ops.flash_attention import (
         flash_attention_cached, flash_supported,
@@ -759,49 +753,34 @@ def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
     mask = (None if use_flash else
             (jnp.arange(T)[None, :] <= n_prefix + jnp.arange(S)[:, None]))
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        # gather the shared prefix pages (position-ordered by the row)
+        # into a dense [1, n_prefix, KV, hd] view — read-only (prefix
+        # and suffix pages are disjoint)
+        kp = gather_layer_pages(pk, layer, prefix_pages, KV,
+                                q.dtype).reshape(1, n_prefix, KV, hd)
+        vp = gather_layer_pages(pv, layer, prefix_pages, KV,
+                                q.dtype).reshape(1, n_prefix, KV, hd)
+        pk, pv = write_prompt_pages(pk, pv, layer, k, v, suffix_row,
+                                    suffix_len[0])
+        k_full = jnp.concatenate([kp, k.astype(q.dtype)], axis=1)
+        v_full = jnp.concatenate([vp, v.astype(q.dtype)], axis=1)
+        if use_flash:
+            return (flash_attention_cached(q, k_full, v_full,
+                                           jnp.int32(n_prefix)), pk, pv)
+        return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = write_prompt_pages(pk, pv, k, v, suffix_row,
-                                          suffix_len[0])
-            # gather the shared prefix pages (position-ordered by the
-            # row) into a dense [1, n_prefix, KV, hd] view — read-only,
-            # pre-write pool (prefix and suffix pages are disjoint);
-            # a quantized pool dequantizes page-by-page on the gather
-            if isinstance(pk, (QuantPool, Int4Pool)):
-                kp = dequantize_pages(pk, prefix_pages).reshape(
-                    1, n_prefix, KV, hd).astype(q.dtype)
-                vp = dequantize_pages(pv, prefix_pages).reshape(
-                    1, n_prefix, KV, hd).astype(q.dtype)
-            else:
-                kp = jnp.take(pk, prefix_pages, axis=0).reshape(
-                    1, n_prefix, KV, hd).astype(q.dtype)
-                vp = jnp.take(pv, prefix_pages, axis=0).reshape(
-                    1, n_prefix, KV, hd).astype(q.dtype)
-            k_full = jnp.concatenate([kp, k.astype(q.dtype)], axis=1)
-            v_full = jnp.concatenate([vp, v.astype(q.dtype)], axis=1)
-            if use_flash:
-                return (flash_attention_cached(q, k_full, v_full,
-                                               jnp.int32(n_prefix)),
-                        (pk2, pv2))
-            return gqa_attention(q, k_full, v_full, mask=mask), (pk2, pv2)
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["blocks"], cache.k, cache.v))
+    x, cache = scan_layers_paged(params["blocks"], x, cache, config,
+                                 layer_attn)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
             x, (suffix_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
         )[:, 0]
         logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache._replace(k=k_new, v=v_new)
+    return logits, cache
 
 
 @_partial(jax.jit, static_argnames=("config", "attn"),
@@ -825,7 +804,6 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
     install step. attn="pallas" routes through the cache-aware flash
     kernel; unmapped pages gather as zeros, which only garbage
     (padding) queries can see under the causal bound."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.attention import gqa_attention
     from cake_tpu.ops.flash_attention import (
         flash_attention_cached, flash_supported,
@@ -850,49 +828,30 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
     mask = (None if use_flash else
             (jnp.arange(T)[None, :] <= pos0 + jnp.arange(C)[:, None]))
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        pk, pv = write_window_pages(pk, pv, layer, k, v, table_row, pos0,
+                                    n_real[0])
+        # post-write gather: the dense view holds every written
+        # position (prefix head, earlier windows, this window)
+        k_full = gather_layer_pages(pk, layer, gather_idx, KV,
+                                    q.dtype).reshape(1, T, KV, hd)
+        v_full = gather_layer_pages(pv, layer, gather_idx, KV,
+                                    q.dtype).reshape(1, T, KV, hd)
+        if use_flash:
+            return flash_attention_cached(q, k_full, v_full, pos0), pk, pv
+        return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = write_window_pages(pk, pv, k, v, table_row, pos0,
-                                          n_real[0])
-            # post-write gather: the dense view holds every written
-            # position (prefix head, earlier windows, this window);
-            # a quantized pool dequantizes page-by-page on the gather
-            if isinstance(pk2, (QuantPool, Int4Pool)):
-                k_full = dequantize_pages(
-                    pk2, gather_idx, fill_zero=True).reshape(
-                    1, T, KV, hd).astype(q.dtype)
-                v_full = dequantize_pages(
-                    pv2, gather_idx, fill_zero=True).reshape(
-                    1, T, KV, hd).astype(q.dtype)
-            else:
-                k_full = jnp.take(pk2, gather_idx, axis=0, mode="fill",
-                                  fill_value=0).reshape(
-                    1, T, KV, hd).astype(q.dtype)
-                v_full = jnp.take(pv2, gather_idx, axis=0, mode="fill",
-                                  fill_value=0).reshape(
-                    1, T, KV, hd).astype(q.dtype)
-            if use_flash:
-                return (flash_attention_cached(q, k_full, v_full, pos0),
-                        (pk2, pv2))
-            return gqa_attention(q, k_full, v_full, mask=mask), (pk2, pv2)
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x,
-                                     (params["blocks"], cache.k, cache.v))
+    x, cache = scan_layers_paged(params["blocks"], x, cache, config,
+                                 layer_attn)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
             x, (n_real - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
         )[:, 0]
         logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache._replace(k=k_new, v=v_new)
+    return logits, cache
 
 
 # -- token-level continuous batching: the mixed ragged step -------------------
@@ -906,27 +865,17 @@ def run_blocks_mixed_paged(blocks, x, cache: PagedKVCache, pos, q_len,
     table. x: [B, C, D]; pos/q_len/active: [B]; rope_c/rope_s:
     [B, C, hd//2] per-row per-column tables; attn: paged_attention_mixed
     impl ({fold,pallas} — static under jit)."""
-    from cake_tpu.models.llama.model import block_skeleton
     from cake_tpu.ops.rope import apply_rope
 
-    def body(h, xs):
-        lp, pk, pv = xs
+    def layer_attn(layer, pk, pv, q, k, v):
+        q = apply_rope(q, rope_c, rope_s)
+        k = apply_rope(k, rope_c, rope_s)
+        pk, pv = write_windows_pages(pk, pv, layer, k, v, pos, q_len,
+                                     active, cache.table)
+        return (paged_attention_mixed(q, pk, pv, layer, cache.table, pos,
+                                      q_len, impl=attn), pk, pv)
 
-        def attn_fn(q, k, v):
-            q = apply_rope(q, rope_c, rope_s)
-            k = apply_rope(k, rope_c, rope_s)
-            pk2, pv2 = write_windows_pages(pk, pv, k, v, pos, q_len,
-                                           active, cache.table)
-            return (paged_attention_mixed(q, pk2, pv2, cache.table,
-                                          pos, q_len, impl=attn),
-                    (pk2, pv2))
-
-        h, (pk2, pv2) = block_skeleton(lp, h, config, attn_fn)
-        return h, (pk2, pv2)
-
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
-    return x, cache._replace(k=k_new, v=v_new)
+    return scan_layers_paged(blocks, x, cache, config, layer_attn)
 
 
 def _mixed_windows_trunk(params, tokens, pos, q_len, active,

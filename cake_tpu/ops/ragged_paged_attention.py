@@ -33,10 +33,15 @@ Paged Attention" shape, PAPERS.md arxiv 2604.15464):
     changes — each row streams the shared page like any other, and
     nothing here ever writes the pool.
 
-Layout contract: the pool keeps `models/llama/paged.py`'s
-[N_pages, page, KV, hd] layout; the wrapper flattens the two minor axes
-to [N_pages, page, KV*hd] (free reshape of a contiguous array) so block
-tiles are (page, KV*hd) — lane-aligned when hd is a multiple of 128.
+Layout contract: the kernels take the STACKED pool as it is stored,
+[L, N_pages, page, KV*hd] (`models/llama/paged.py`; a packed int4 pool
+[L, N_pages, page//2, KV*hd]), and the layer as one more scalar-prefetch
+operand. The k/v block is (layer, page) -> one (page, KV*hd) tile,
+lane-aligned when hd is a multiple of 128, DMA'd straight out of the
+pool: the wrappers neither slice a layer out nor reshape anything. (A
+reshape from a per-head [.., KV, hd] pool to this shape is a relayout
+of the whole pool on the chip — four times the kernel's own time,
+PERF.md PR 24 — which is why the pool is STORED this way.)
 
 The MIXED variant (`ragged_paged_attention_mixed`) extends the row
 metadata with a per-row query length: one grid processes decode rows
@@ -62,15 +67,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _flat_scales(scale):
-    """[N_pages, KV] per-page scales -> the 1-D [N_pages*KV] f32 array
-    the quantized kernels prefetch (indexed page*KV + kv). SMEM pads a
-    2-D array's minor dim to 128 words, so the 2-D form cost 16x its
-    bytes at KV=8 (f32[2048, 8] took the whole 1.00M of SMEM under
-    libtpu 0.0.34); 1-D costs what it holds."""
-    return jnp.asarray(scale, jnp.float32).reshape(-1)
-
-
 def _dot(a, b, *, trans_b: bool):
     """MXU dot with f32 accumulation: a @ b.T (scores) or a @ b (the
     value fold). bf16 operands pin DEFAULT precision — their products
@@ -85,13 +81,16 @@ def _dot(a, b, *, trans_b: bool):
         preferred_element_type=jnp.float32)
 
 
-def _rpa_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
+def _rpa_kernel(layer_ref, pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                 acc_ref, m_ref, l_ref, *, scale: float, page_size: int,
                 kv_heads: int, group: int, head_dim: int):
     """One (row, page) grid step of the ragged fold.
 
+    layer_ref: [1] — read by the k/v index maps only (every kernel here
+             takes it first and its body never touches it)
     q_ref:   [1, 1, H, hd] — the row's single decode query, all heads
-    k_ref/v_ref: [1, page, KV*hd] — one physical page (flattened minor)
+    k_ref/v_ref: [1, page, KV*hd] — one physical page of the layer (the
+             block's layer axis is squeezed)
     scratch: acc [H, hd] f32, m/l [H, 128] f32, carried across the page
     axis (innermost, sequential) exactly like flash_attention's k axis.
     """
@@ -157,14 +156,14 @@ def _rpa_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
-def _rpa_kernel_q8(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
+def _rpa_kernel_q8(layer_ref, pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
                    v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
                    page_size: int, kv_heads: int, group: int,
                    head_dim: int):
     """int8 variant of _rpa_kernel: the page blocks stream as int8 (a
     quarter of the f32 DMA bytes — the whole point of KV tiering) and
     the per-(page, kv-head) scales ride as scalar-prefetched SMEM
-    operands (1-D, index page*KV + kv — _flat_scales). Because one
+    operands (1-D, index page*KV + kv — _layer_scales). Because one
     scale covers a page's every column for a given kv head,
     dequantization folds into the dot OUTPUTS: the score block scales
     by scale_k[page, kv] and the value fold by scale_v[page, kv] — no
@@ -236,7 +235,7 @@ def _unpack_nibbles(block, hd_slice):
                            axis=0).astype(jnp.float32)
 
 
-def _rpa_kernel_q4(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
+def _rpa_kernel_q4(layer_ref, pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
                    v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
                    page_size: int, kv_heads: int, group: int,
                    head_dim: int):
@@ -302,7 +301,26 @@ def _rpa_kernel_q4(pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
         o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
-def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
+def _layer_scales(scale, layer):
+    """[L, N_pages, KV] per-page scales -> the layer's, as the 1-D
+    [N_pages*KV] f32 array the quantized kernels prefetch (index
+    page*KV + kv). SMEM pads a 2-D array's minor dim to 128 words, so
+    the 2-D form cost 16x its bytes at KV=8 (f32[2048, 8] took the
+    whole 1.00M of SMEM under libtpu 0.0.34); 1-D costs what it holds.
+    A layer's scales are N_pages*KV words — the slice is not a pool."""
+    scale = jnp.asarray(scale, jnp.float32)
+    return jax.lax.dynamic_index_in_dim(
+        scale, layer, axis=0, keepdims=False).reshape(-1)
+
+
+def _pool_block(Pb: int, width: int, index_map):
+    """One page of one layer: the stacked pool's (layer, page) tile,
+    with the layer axis squeezed so the kernel bodies see
+    [1, page, KV*hd]."""
+    return pl.BlockSpec((None, 1, Pb, width), index_map)
+
+
+def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                            scale: float | None = None,
                            scale_k=None, scale_v=None,
                            packed4: bool = False,
@@ -312,15 +330,17 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
     q:            [B, 1, H, hd] — rope applied; the current token's KV
                   must already be written to its page (the
                   update_pool_per_row contract).
-    pool_k/pool_v:[N_pages, page, KV, hd]
+    pool_k/pool_v:[L, N_pages, page, KV*hd] — the stacked pool, as
+                  stored; only pages of `layer` are read
+    layer:        int32 scalar (traced: the layer loop's counter)
     table:        [B, max_pages] int32 page ids, -1 = unmapped
     pos:          [B] int32 — position of the CURRENT token per row
-    scale_k/scale_v: optional [N_pages, KV] f32 per-page per-kv-head
+    scale_k/scale_v: optional [L, N_pages, KV] f32 per-page per-kv-head
                   dequantization scales — present iff the pool is the
                   int8/int4 KV tier (cake_tpu/kv); pages then stream
-                  quantized and scales prefetch into SMEM.
+                  quantized and the layer's scales prefetch into SMEM.
     packed4:      the pool is nibble-PACKED int4
-                  ([N_pages, page//2, KV, hd] uint8, kv/quantized_pool
+                  ([L, N_pages, page//2, KV*hd] uint8, kv/quantized_pool
                   pack_page_nibbles layout); requires scale_k/scale_v.
     Returns [B, 1, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention` (the fold reference) to f32
@@ -329,7 +349,8 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
     B, S, H, hd = q.shape
     if S != 1:
         raise ValueError(f"decode kernel takes one query per row, got S={S}")
-    N, Pb, KV, _ = pool_k.shape
+    _, N, Pb, width = pool_k.shape
+    KV = width // hd
     P = Pb * 2 if packed4 else Pb       # REAL tokens per page
     G = H // KV
     max_pages = table.shape[1]
@@ -349,10 +370,9 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
             f"table={B}x{max_pages} (ragged_paged_supported); use the "
             "fold")
 
-    kf = pool_k.reshape(N, Pb, KV * hd)
-    vf = pool_v.reshape(N, Pb, KV * hd)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def kv_index(b, j, pos_ref, table_ref, *_scales):
+    def kv_index(b, j, layer_ref, pos_ref, table_ref, *_scales):
         # clamp dead pages (past the row's live count) to the LAST live
         # page: the repeated block index elides the DMA, so a short row
         # streams only its own pages. Unmapped holes inside the live
@@ -360,32 +380,33 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
         # out in compute.
         jj = jnp.minimum(j, pos_ref[b] // P)
         page = table_ref[b, jj]
-        return (jnp.maximum(page, 0), 0, 0)
+        return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
 
     if quantized:
         kern_fn = _rpa_kernel_q4 if packed4 else _rpa_kernel_q8
         kernel = functools.partial(
             kern_fn, scale=scale, page_size=P, kv_heads=KV,
             group=G, head_dim=hd)
-        n_prefetch = 4
-        operands = (jnp.asarray(pos, jnp.int32),
+        n_prefetch = 5
+        operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(table, jnp.int32),
-                    _flat_scales(scale_k), _flat_scales(scale_v),
-                    q, kf, vf)
+                    _layer_scales(scale_k, layer[0]),
+                    _layer_scales(scale_v, layer[0]),
+                    q, pool_k, pool_v)
     else:
         kernel = functools.partial(
             _rpa_kernel, scale=scale, page_size=P, kv_heads=KV, group=G,
             head_dim=hd)
-        n_prefetch = 2
-        operands = (jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(table, jnp.int32), q, kf, vf)
+        n_prefetch = 3
+        operands = (layer, jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(table, jnp.int32), q, pool_k, pool_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Pb, KV * hd), kv_index),
-            pl.BlockSpec((1, Pb, KV * hd), kv_index),
+            _pool_block(Pb, width, kv_index),
+            _pool_block(Pb, width, kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, H, hd),
                                lambda b, j, *_: (b, 0, 0, 0)),
@@ -409,7 +430,7 @@ def ragged_paged_attention(q, pool_k, pool_v, table, pos, *,
     )(*operands)
 
 
-def _rpa_mixed_kernel(pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
+def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
                       o_ref, acc_ref, m_ref, l_ref, *, scale: float,
                       page_size: int, kv_heads: int, group: int,
                       head_dim: int, q_width: int):
@@ -494,7 +515,7 @@ def _rpa_mixed_kernel(pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
             o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
 
 
-def _rpa_mixed_kernel_q8(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
+def _rpa_mixed_kernel_q8(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
                          q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                          l_ref, *, scale: float, page_size: int,
                          kv_heads: int, group: int, head_dim: int,
@@ -569,7 +590,7 @@ def _rpa_mixed_kernel_q8(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
             o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
 
 
-def _rpa_mixed_kernel_q4(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
+def _rpa_mixed_kernel_q4(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
                          q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                          l_ref, *, scale: float, page_size: int,
                          kv_heads: int, group: int, head_dim: int,
@@ -644,7 +665,8 @@ def _rpa_mixed_kernel_q4(pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
             o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
 
 
-def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
+def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
+                                 q_len, *,
                                  scale: float | None = None,
                                  scale_k=None, scale_v=None,
                                  packed4: bool = False,
@@ -662,7 +684,9 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
                   write_windows_pages contract). Columns past q_len are
                   padding: their output is garbage the caller never
                   reads (the step fn samples at column q_len - 1).
-    pool_k/pool_v:[N_pages, page, KV, hd]
+    pool_k/pool_v:[L, N_pages, page, KV*hd] — the stacked pool, as
+                  stored; only pages of `layer` are read
+    layer:        int32 scalar (traced: the layer loop's counter)
     table:        [B, max_pages] int32 page ids, -1 = unmapped
     pos:          [B] int32 — absolute position of each row's FIRST
                   query token (decode rows: the current token's
@@ -674,7 +698,8 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
     to f32 tolerance — tests/test_ragged_paged_attn.py pins the parity.
     """
     B, C, H, hd = q.shape
-    N, Pb, KV, _ = pool_k.shape
+    _, N, Pb, width = pool_k.shape
+    KV = width // hd
     P = Pb * 2 if packed4 else Pb       # REAL tokens per page
     G = H // KV
     max_pages = table.shape[1]
@@ -697,44 +722,44 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, table, pos, q_len, *,
             "(ragged_paged_mixed_supported); use the fold or a "
             "narrower window")
 
-    kf = pool_k.reshape(N, Pb, KV * hd)
-    vf = pool_v.reshape(N, Pb, KV * hd)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def kv_index(b, j, pos_ref, qlen_ref, table_ref, *_scales):
+    def kv_index(b, j, layer_ref, pos_ref, qlen_ref, table_ref, *_scales):
         # clamp dead pages (past the row's live count) to the LAST live
         # page — the repeated block index elides the DMA, so a row
         # streams only the pages its window actually covers
         last = pos_ref[b] + jnp.maximum(qlen_ref[b], 1) - 1
         jj = jnp.minimum(j, last // P)
         page = table_ref[b, jj]
-        return (jnp.maximum(page, 0), 0, 0)
+        return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
 
     if quantized:
         kern_fn = _rpa_mixed_kernel_q4 if packed4 else _rpa_mixed_kernel_q8
         kernel = functools.partial(
             kern_fn, scale=scale, page_size=P, kv_heads=KV,
             group=G, head_dim=hd, q_width=C)
-        n_prefetch = 5
-        operands = (jnp.asarray(pos, jnp.int32),
+        n_prefetch = 6
+        operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),
                     jnp.asarray(table, jnp.int32),
-                    _flat_scales(scale_k), _flat_scales(scale_v),
-                    q, kf, vf)
+                    _layer_scales(scale_k, layer[0]),
+                    _layer_scales(scale_v, layer[0]),
+                    q, pool_k, pool_v)
     else:
         kernel = functools.partial(
             _rpa_mixed_kernel, scale=scale, page_size=P, kv_heads=KV,
             group=G, head_dim=hd, q_width=C)
-        n_prefetch = 3
-        operands = (jnp.asarray(pos, jnp.int32),
+        n_prefetch = 4
+        operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),
-                    jnp.asarray(table, jnp.int32), q, kf, vf)
+                    jnp.asarray(table, jnp.int32), q, pool_k, pool_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, C, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((1, Pb, KV * hd), kv_index),
-            pl.BlockSpec((1, Pb, KV * hd), kv_index),
+            _pool_block(Pb, width, kv_index),
+            _pool_block(Pb, width, kv_index),
         ],
         out_specs=pl.BlockSpec((1, C, H, hd),
                                lambda b, j, *_: (b, 0, 0, 0)),

@@ -152,32 +152,39 @@ def test_quantized_write_error_bound_and_isolation():
     (the RMW writers must not drift neighbors)."""
     rng = np.random.default_rng(5)
     KV, hd = 2, 16
-    pool = QuantPool(q=jnp.zeros((12, PAGE, KV, hd), jnp.int8),
-                     scale=jnp.zeros((12, KV), jnp.float32))
+    # the stacked pool, written at layer 1 of 2: the writers index
+    # [layer, pages] and must leave the other layer alone
+    layer = 1
+    pool = QuantPool(q=jnp.zeros((2, 12, PAGE, KV * hd), jnp.int8),
+                     scale=jnp.zeros((2, 12, KV), jnp.float32))
     vals = jnp.asarray(rng.normal(size=(1, 2 * PAGE + 3, KV, hd)),
                        jnp.float32)
     row = jnp.asarray([7, 2, 9, -1], jnp.int32)
-    pool = qwrite_prompt_pages(pool, vals, row)
-    deq = dequantize_pages(pool, jnp.asarray([7, 2, 9])).reshape(
+    pool = qwrite_prompt_pages(pool, layer, vals, row)
+    deq = dequantize_pages(pool, layer, jnp.asarray([7, 2, 9])).reshape(
         3 * PAGE, KV, hd)[: 2 * PAGE + 3]
     # symmetric int8: error <= scale/2 = amax/254 per (page, head)
     assert float(jnp.max(jnp.abs(deq - vals[0]))) < 0.05
-    before = np.asarray(pool.q[7]), np.asarray(pool.scale[7])
+    before = np.asarray(pool.q[layer, 7]), np.asarray(pool.scale[layer, 7])
     # decode token into page 9 (single-page RMW)
     tok = jnp.asarray(rng.normal(size=(1, 1, KV, hd)), jnp.float32)
     pool2 = qupdate_pool_per_row(
-        pool, tok, jnp.asarray([2 * PAGE + 3], jnp.int32),
+        pool, layer, tok, jnp.asarray([2 * PAGE + 3], jnp.int32),
         jnp.asarray([True]), jnp.asarray([[7, 2, 9, -1]], jnp.int32))
-    np.testing.assert_array_equal(before[0], np.asarray(pool2.q[7]))
-    np.testing.assert_array_equal(before[1], np.asarray(pool2.scale[7]))
-    got = dequantize_pages(pool2, jnp.asarray([9]))[0][3]
+    np.testing.assert_array_equal(before[0], np.asarray(pool2.q[layer, 7]))
+    np.testing.assert_array_equal(before[1],
+                                  np.asarray(pool2.scale[layer, 7]))
+    got = dequantize_pages(pool2, layer, jnp.asarray([9]))[0][3]
     assert float(jnp.max(jnp.abs(got - tok[0, 0]))) < 0.05
     # window write at an arbitrary offset into fresh scale-reset pages
     pool3 = qwrite_window_pages(
-        pool2, tok, jnp.asarray([7, 2, 9, -1], jnp.int32),
+        pool2, layer, tok, jnp.asarray([7, 2, 9, -1], jnp.int32),
         jnp.int32(2 * PAGE + 4))
-    got3 = dequantize_pages(pool3, jnp.asarray([9]))[0][4]
+    got3 = dequantize_pages(pool3, layer, jnp.asarray([9]))[0][4]
     assert float(jnp.max(jnp.abs(got3 - tok[0, 0]))) < 0.05
+    for p in (pool, pool2, pool3):
+        assert not np.asarray(p.q[0]).any()
+        assert not np.asarray(p.scale[0]).any()
 
 
 def test_bucket_padding_cannot_inflate_scales():
@@ -188,8 +195,9 @@ def test_bucket_padding_cannot_inflate_scales():
     writing the real tokens alone."""
     rng = np.random.default_rng(6)
     KV, hd = 2, 16
-    pool0 = QuantPool(q=jnp.zeros((12, PAGE, KV, hd), jnp.int8),
-                      scale=jnp.zeros((12, KV), jnp.float32))
+    layer = 1
+    pool0 = QuantPool(q=jnp.zeros((2, 12, PAGE, KV * hd), jnp.int8),
+                      scale=jnp.zeros((2, 12, KV), jnp.float32))
     row = jnp.asarray([7, 2, 9, -1], jnp.int32)
 
     # prompt writer: bucket 2 pages, real tokens PAGE+3, tail garbage
@@ -199,26 +207,28 @@ def test_bucket_padding_cannot_inflate_scales():
     garbage = vals.at[:, n_real:].mul(100.0)
     live = jnp.arange(2 * PAGE)[None, :, None, None] < n_real
     clean = jnp.where(live, vals, 0.0)
-    got = qwrite_prompt_pages(pool0, garbage, row, jnp.int32(n_real))
-    want = qwrite_prompt_pages(pool0, clean, row)
+    got = qwrite_prompt_pages(pool0, layer, garbage, row,
+                              jnp.int32(n_real))
+    want = qwrite_prompt_pages(pool0, layer, clean, row)
     np.testing.assert_array_equal(np.asarray(got.q), np.asarray(want.q))
     np.testing.assert_array_equal(np.asarray(got.scale),
                                   np.asarray(want.scale))
     # sanity: without n_real the garbage DOES inflate the tail scale
-    bad = qwrite_prompt_pages(pool0, garbage, row)
+    bad = qwrite_prompt_pages(pool0, layer, garbage, row)
     assert float(jnp.max(jnp.abs(bad.scale - want.scale))) > 0
 
     # chunk window writer: C-token window, 4 real, huge padding
     win = jnp.asarray(rng.normal(size=(1, PAGE + 5, KV, hd)),
                       jnp.float32)
     win = win.at[:, 4:].mul(100.0)
-    got = qwrite_window_pages(pool0, win, row, jnp.int32(3),
+    got = qwrite_window_pages(pool0, layer, win, row, jnp.int32(3),
                               jnp.int32(4))
-    want = qwrite_window_pages(pool0, win[:, :4], row, jnp.int32(3))
+    want = qwrite_window_pages(pool0, layer, win[:, :4], row,
+                               jnp.int32(3))
     np.testing.assert_array_equal(np.asarray(got.q), np.asarray(want.q))
     np.testing.assert_array_equal(np.asarray(got.scale),
                                   np.asarray(want.scale))
-    bad = qwrite_window_pages(pool0, win, row, jnp.int32(3))
+    bad = qwrite_window_pages(pool0, layer, win, row, jnp.int32(3))
     assert float(jnp.max(jnp.abs(bad.scale - want.scale))) > 0
 
 
@@ -234,15 +244,14 @@ def test_property_random_int4_pool_interleavings(tiny_config):
     write (the PR 7 bucket-padding regression, int4 edition)."""
     from cake_tpu.models.llama.paged import PageAllocator
 
-    from cake_tpu.kv.quantized_pool import Int4Pool
-
     rng = np.random.default_rng(17)
     N = 8
     cache = Int4PagedKVCache.create(tiny_config, 4, N, PAGE, 4 * PAGE)
     pager = PageAllocator(N, PAGE)
     tier = HostTier(2 * N, page_bytes=page_bytes(tiny_config, PAGE,
                                                  "int4"))
-    L, _, _, KV, hd = cache.k.q.shape
+    L = cache.k.q.shape[0]
+    KV, hd = tiny_config.num_key_value_heads, tiny_config.head_dim
     MAXP = cache.max_pages
     live: dict = {}      # sid -> (pages, n_tokens)
     parked: dict = {}    # sid -> (n_pages, fetched arrays)
@@ -253,10 +262,11 @@ def test_property_random_int4_pool_interleavings(tiny_config):
                            jnp.int32)
 
     def over_layers(pool, fn):
-        """The device writers take per-layer pool leaves (they run
-        inside the block scan); vmap them across the cache's L axis."""
-        return jax.vmap(lambda q, s: fn(Int4Pool(q=q, scale=s)))(
-            pool.q, pool.scale)
+        """The device writers take the stacked pool and a layer index
+        (they run inside the layer loop): apply fn at every layer."""
+        for layer in range(L):
+            pool = fn(pool, layer)
+        return pool
 
     def check_conserved():
         assert pager.free_pages + pager.live_pages == N
@@ -293,11 +303,11 @@ def test_property_random_int4_pool_interleavings(tiny_config):
                 clean = jnp.where(livemask, vals[h], 0.0)
                 got = over_layers(
                     getattr(cache, h),
-                    lambda p: qwrite_prompt_pages(p, garbage, row,
-                                                  jnp.int32(n_tok)))
+                    lambda p, l: qwrite_prompt_pages(
+                        p, l, garbage, row, jnp.int32(n_tok)))
                 want = over_layers(
                     getattr(cache, h),
-                    lambda p: qwrite_prompt_pages(p, clean, row))
+                    lambda p, l: qwrite_prompt_pages(p, l, clean, row))
                 np.testing.assert_array_equal(np.asarray(got.q),
                                               np.asarray(want.q))
                 np.testing.assert_array_equal(np.asarray(got.scale),
@@ -320,8 +330,8 @@ def test_property_random_int4_pool_interleavings(tiny_config):
                                   jnp.float32)
                 new[h] = over_layers(
                     getattr(cache, h),
-                    lambda p: qupdate_pool_per_row(
-                        p, tok, jnp.asarray([n_tok], jnp.int32),
+                    lambda p, l: qupdate_pool_per_row(
+                        p, l, tok, jnp.asarray([n_tok], jnp.int32),
                         jnp.asarray([True]), row[None, :]))
             cache = cache._replace(k=new["k"], v=new["v"])
             live[sid] = (pages, n_tok + 1)

@@ -39,16 +39,21 @@ def test_paged_attention_matches_dense():
     B, H, KV, hd = 2, 4, 2, 16
     n_pages, max_pages = 12, 4
     rng = np.random.default_rng(0)
-    pool_k = jnp.asarray(rng.normal(size=(n_pages, PAGE, KV, hd)),
-                         jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(n_pages, PAGE, KV, hd)),
-                         jnp.float32)
+    # the stacked pool as the engine holds it, [L, N, page, KV*hd];
+    # layer 1 of 2 is attended, layer 0 holds other data
+    layer = 1
+    stack_k = jnp.asarray(rng.normal(size=(2, n_pages, PAGE, KV * hd)),
+                          jnp.float32)
+    stack_v = jnp.asarray(rng.normal(size=(2, n_pages, PAGE, KV * hd)),
+                          jnp.float32)
+    pool_k = stack_k[layer].reshape(n_pages, PAGE, KV, hd)
+    pool_v = stack_v[layer].reshape(n_pages, PAGE, KV, hd)
     # row 0 uses 3 mapped pages (pos mid-page), row 1 uses 2
     table = jnp.asarray([[7, 2, 9, -1], [4, 11, -1, -1]], jnp.int32)
     pos = jnp.asarray([2 * PAGE + 5, PAGE + 3], jnp.int32)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.float32)
 
-    got = paged_attention(q, pool_k, pool_v, table, pos)
+    got = paged_attention(q, stack_k, stack_v, layer, table, pos)
 
     for b in range(B):
         pages = [int(p) for p in table[b] if int(p) >= 0]

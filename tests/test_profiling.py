@@ -169,7 +169,7 @@ def _kernel_calls():
     from cake_tpu.ops import int4_matmul as i4
     from cake_tpu.ops import ragged_paged_attention as rpa
     B, H, KV, hd, P = 2, 4, 2, 16, 8
-    pool = jnp.zeros((4, P, KV, hd), jnp.float32)
+    pool = jnp.zeros((2, 4, P, KV * hd), jnp.float32)
     table = jnp.zeros((B, 2), jnp.int32)
     pos = jnp.zeros(B, jnp.int32)
     q1 = jnp.zeros((B, 1, H, hd), jnp.float32)
@@ -180,9 +180,9 @@ def _kernel_calls():
     packed = i4.pack_int4(jnp.zeros((64, 128), jnp.int8), g)
     return {
         "cake_decode_attn": lambda: rpa.ragged_paged_attention(
-            q1, pool, pool, table, pos, interpret=True),
+            q1, pool, pool, 1, table, pos, interpret=True),
         "cake_mixed_attn": lambda: rpa.ragged_paged_attention_mixed(
-            qc, pool, pool, table, pos, jnp.full(B, 8, jnp.int32),
+            qc, pool, pool, 1, table, pos, jnp.full(B, 8, jnp.int32),
             interpret=True),
         "cake_flash_prefill": lambda: fa.flash_attention(
             qs, ks, ks, interpret=True),

@@ -24,19 +24,28 @@ from cake_tpu.ops.ragged_paged_attention import (
 P = 8           # page size
 N_PAGES = 12
 MAX_PAGES = 5
+# every pool is STACKED ([L, N_pages, page, KV*hd], as the engine holds
+# it) with different data in every layer, and every parity helper
+# compares at the first and the last layer: a kernel (or a fold) that
+# ignored its layer index would read the wrong layer's pages
+LAYERS = 3
+CHECK_LAYERS = (0, LAYERS - 1)
+LAYER = 1       # the layer of the direct kernel calls below
 
 
 def _pool(rng, KV, hd, dtype=jnp.float32):
-    k = jnp.asarray(rng.normal(size=(N_PAGES, P, KV, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(N_PAGES, P, KV, hd)), dtype)
+    k = jnp.asarray(rng.normal(size=(LAYERS, N_PAGES, P, KV * hd)), dtype)
+    v = jnp.asarray(rng.normal(size=(LAYERS, N_PAGES, P, KV * hd)), dtype)
     return k, v
 
 
 def _assert_parity(q, pk, pv, table, pos, atol=1e-5):
-    want = paged_attention(q, pk, pv, table, pos)
-    got = ragged_paged_attention(q, pk, pv, table, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=atol, rtol=atol)
+    for layer in CHECK_LAYERS:
+        want = paged_attention(q, pk, pv, layer, table, pos)
+        got = ragged_paged_attention(q, pk, pv, layer, table, pos,
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=atol, rtol=atol)
 
 
 def test_kernel_parity_ragged_pos():
@@ -76,7 +85,7 @@ def test_kernel_parity_unmapped_holes():
                         jnp.int32)
     pos = jnp.asarray([3 * P + 2, 2 * P + 1, P + 4], jnp.int32)
     _assert_parity(q, pk, pv, table, pos)
-    dead = ragged_paged_attention(q, pk, pv, table, pos,
+    dead = ragged_paged_attention(q, pk, pv, LAYER, table, pos,
                                   interpret=True)[2]
     np.testing.assert_array_equal(np.asarray(dead),
                                   np.zeros_like(np.asarray(dead)))
@@ -103,24 +112,28 @@ def test_kernel_parity_bf16_pool():
     table = jnp.asarray([[7, 2, -1, -1, -1], [4, 11, 3, -1, -1]],
                         jnp.int32)
     pos = jnp.asarray([P + 5, 2 * P + 7], jnp.int32)
-    want = paged_attention(q, pk, pv, table, pos)
-    got = ragged_paged_attention(q, pk, pv, table, pos, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        atol=3e-2, rtol=3e-2)
+    for layer in CHECK_LAYERS:
+        want = paged_attention(q, pk, pv, layer, table, pos)
+        got = ragged_paged_attention(q, pk, pv, layer, table, pos,
+                                     interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=3e-2, rtol=3e-2)
 
 
 def _assert_mixed_parity(q, pk, pv, table, pos, qlen, atol=1e-5):
     """fold reference == interpret-mode mixed kernel, on REAL query
     columns only (padding columns past q_len are garbage by contract —
     the step fn samples at column q_len - 1)."""
-    want = np.asarray(paged_attention_mixed(q, pk, pv, table, pos, qlen))
-    got = np.asarray(ragged_paged_attention_mixed(
-        q, pk, pv, table, pos, qlen, interpret=True))
-    for b in range(q.shape[0]):
-        n = int(qlen[b])
-        np.testing.assert_allclose(got[b, :n], want[b, :n],
-                                   atol=atol, rtol=atol)
+    for layer in CHECK_LAYERS:
+        want = np.asarray(paged_attention_mixed(q, pk, pv, layer, table,
+                                                pos, qlen))
+        got = np.asarray(ragged_paged_attention_mixed(
+            q, pk, pv, layer, table, pos, qlen, interpret=True))
+        for b in range(q.shape[0]):
+            n = int(qlen[b])
+            np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                       atol=atol, rtol=atol)
 
 
 def test_mixed_kernel_parity_decode_and_chunk_rows():
@@ -184,8 +197,8 @@ def test_mixed_kernel_parity_unmapped_holes():
     pos = jnp.asarray([2 * P + 2, P + 1, 0], jnp.int32)
     qlen = jnp.asarray([4, 3, 0], jnp.int32)
     _assert_mixed_parity(q, pk, pv, table, pos, qlen)
-    dead = ragged_paged_attention_mixed(q, pk, pv, table, pos, qlen,
-                                        interpret=True)[2]
+    dead = ragged_paged_attention_mixed(q, pk, pv, LAYER, table, pos,
+                                        qlen, interpret=True)[2]
     np.testing.assert_array_equal(np.asarray(dead),
                                   np.zeros_like(np.asarray(dead)))
 
@@ -200,8 +213,8 @@ def test_mixed_fold_decode_row_bitwise_matches_decode_fold():
     table = jnp.asarray([[7, 2, -1, -1, -1], [4, 11, 3, -1, -1]],
                         jnp.int32)
     pos = jnp.asarray([P + 5, 2 * P + 7], jnp.int32)
-    want = paged_attention(q, pk, pv, table, pos)
-    got = paged_attention_mixed(q, pk, pv, table, pos,
+    want = paged_attention(q, pk, pv, LAYER, table, pos)
+    got = paged_attention_mixed(q, pk, pv, LAYER, table, pos,
                                 jnp.ones(2, jnp.int32))
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
@@ -224,29 +237,37 @@ def test_mixed_fold_decode_row_bitwise_matches_decode_fold():
 # an O(1) error on unit-variance values, 50x over the bar.
 
 PROD = dict(H=32, KV=8, hd=128, P=128, n_pages=6)
+PROD_LAYERS = 2         # the kernels read the LAST layer of the stack
 PROD_TOL = 2e-2
 
 
 def _prod_pool(rng, kind):
     """(pool_k, pool_v) of `kind` bf16 | int8 | int4 at PROD shapes, as
-    models/llama/paged.py holds them (QuantPool / Int4Pool halves)."""
+    models/llama/paged.py holds them: stacked over PROD_LAYERS layers
+    of different data, the two minor axes flattened (QuantPool /
+    Int4Pool halves for the quantized kinds)."""
     from cake_tpu.kv.quantized_pool import (
         Int4Pool, QuantPool, pack_page_nibbles,
     )
-    shape = (PROD["n_pages"], PROD["P"], PROD["KV"], PROD["hd"])
+    shape = (PROD_LAYERS, PROD["n_pages"], PROD["P"], PROD["KV"],
+             PROD["hd"])
+
+    def flat(a):
+        return a.reshape(a.shape[:-2] + (-1,))
 
     def half():
         x = rng.normal(size=shape).astype(np.float32)
         if kind == "bf16":
-            return jnp.asarray(x, jnp.bfloat16)
+            return jnp.asarray(flat(x), jnp.bfloat16)
         qmax = 127.0 if kind == "int8" else 7.0
-        scale = np.abs(x).max(axis=(1, 3)) / qmax          # [N, KV]
-        q = np.clip(np.round(x / scale[:, None, :, None]), -qmax, qmax)
+        scale = np.abs(x).max(axis=(2, 4)) / qmax          # [L, N, KV]
+        q = np.clip(np.round(x / scale[:, :, None, :, None]), -qmax, qmax)
         if kind == "int8":
-            return QuantPool(q=jnp.asarray(q, jnp.int8),
+            return QuantPool(q=jnp.asarray(flat(q), jnp.int8),
                              scale=jnp.asarray(scale, jnp.float32))
-        return Int4Pool(q=pack_page_nibbles(jnp.asarray(q, jnp.int8)),
-                        scale=jnp.asarray(scale, jnp.float32))
+        return Int4Pool(
+            q=flat(pack_page_nibbles(jnp.asarray(q, jnp.int8))),
+            scale=jnp.asarray(scale, jnp.float32))
 
     return half(), half()
 
@@ -264,8 +285,9 @@ def test_decode_kernel_real_backend_production_shapes(kind):
     table = jnp.asarray(_PROD_TABLE, jnp.int32)
     # mid third page, last slot of the first page, first token
     pos = jnp.asarray([2 * P + 37, P - 1, 0], jnp.int32)
-    want = paged_attention(q, pk, pv, table, pos, impl="fold")
-    got = paged_attention(q, pk, pv, table, pos, impl="pallas")
+    layer = jnp.int32(PROD_LAYERS - 1)
+    want = paged_attention(q, pk, pv, layer, table, pos, impl="fold")
+    got = paged_attention(q, pk, pv, layer, table, pos, impl="pallas")
     assert got.dtype == jnp.bfloat16 and got.shape == q.shape
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -285,10 +307,11 @@ def test_mixed_kernel_real_backend_production_shapes(kind):
     table = jnp.asarray(_PROD_TABLE, jnp.int32)
     pos = jnp.asarray([2 * P + 37, 100, 0], jnp.int32)
     qlen = jnp.asarray([1, 128, 77], jnp.int32)
+    layer = jnp.int32(PROD_LAYERS - 1)
     want = np.asarray(paged_attention_mixed(
-        q, pk, pv, table, pos, qlen, impl="fold"), np.float32)
+        q, pk, pv, layer, table, pos, qlen, impl="fold"), np.float32)
     got = np.asarray(paged_attention_mixed(
-        q, pk, pv, table, pos, qlen, impl="pallas"), np.float32)
+        q, pk, pv, layer, table, pos, qlen, impl="pallas"), np.float32)
     assert np.isfinite(got).all()
     for b, n in enumerate(np.asarray(qlen)):
         np.testing.assert_allclose(got[b, :n], want[b, :n],
@@ -457,40 +480,49 @@ def _qpools(rng, KV, hd):
     """Two quantized pools (k, v) built through the production writer
     (qwrite_prompt_pages), so every page carries its own per-head
     scale from its own amax."""
-    from cake_tpu.kv.quantized_pool import QuantPool, qwrite_prompt_pages
+    from cake_tpu.kv.quantized_pool import QuantPool
 
-    def one(seed_vals):
-        pool = QuantPool(q=jnp.zeros((N_PAGES, P, KV, hd), jnp.int8),
-                         scale=jnp.zeros((N_PAGES, KV), jnp.float32))
-        return qwrite_prompt_pages(
-            pool, seed_vals, jnp.arange(N_PAGES, dtype=jnp.int32))
-
-    pk = one(jnp.asarray(rng.normal(size=(1, N_PAGES * P, KV, hd)),
-                         jnp.float32))
-    pv = one(jnp.asarray(rng.normal(size=(1, N_PAGES * P, KV, hd)),
-                         jnp.float32))
-    return pk, pv
+    pool = QuantPool(
+        q=jnp.zeros((LAYERS, N_PAGES, P, KV * hd), jnp.int8),
+        scale=jnp.zeros((LAYERS, N_PAGES, KV), jnp.float32))
+    return _fill_layers(rng, pool, KV, hd), _fill_layers(rng, pool, KV, hd)
 
 
-def _assert_parity_q8(q, pk, pv, table, pos, atol=2e-5):
-    want = paged_attention(q, pk, pv, table, pos)
-    got = ragged_paged_attention(q, pk.q, pv.q, table, pos,
-                                 scale_k=pk.scale, scale_v=pv.scale,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=atol, rtol=atol)
+def _fill_layers(rng, pool, KV, hd):
+    """Every page of every layer of a stacked quantized pool written
+    through the production writer, each layer with its own data."""
+    from cake_tpu.kv.quantized_pool import qwrite_prompt_pages
+
+    for layer in range(LAYERS):
+        vals = jnp.asarray(rng.normal(size=(1, N_PAGES * P, KV, hd)),
+                           jnp.float32)
+        pool = qwrite_prompt_pages(
+            pool, layer, vals, jnp.arange(N_PAGES, dtype=jnp.int32))
+    return pool
 
 
-def _assert_mixed_parity_q8(q, pk, pv, table, pos, qlen, atol=2e-5):
-    want = np.asarray(paged_attention_mixed(q, pk, pv, table, pos,
-                                            qlen))
-    got = np.asarray(ragged_paged_attention_mixed(
-        q, pk.q, pv.q, table, pos, qlen, scale_k=pk.scale,
-        scale_v=pv.scale, interpret=True))
-    for b in range(q.shape[0]):
-        n = int(qlen[b])
-        np.testing.assert_allclose(got[b, :n], want[b, :n],
+def _assert_parity_q8(q, pk, pv, table, pos, atol=2e-5, packed4=False):
+    for layer in CHECK_LAYERS:
+        want = paged_attention(q, pk, pv, layer, table, pos)
+        got = ragged_paged_attention(q, pk.q, pv.q, layer, table, pos,
+                                     scale_k=pk.scale, scale_v=pv.scale,
+                                     packed4=packed4, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=atol, rtol=atol)
+
+
+def _assert_mixed_parity_q8(q, pk, pv, table, pos, qlen, atol=2e-5,
+                            packed4=False):
+    for layer in CHECK_LAYERS:
+        want = np.asarray(paged_attention_mixed(q, pk, pv, layer, table,
+                                                pos, qlen))
+        got = np.asarray(ragged_paged_attention_mixed(
+            q, pk.q, pv.q, layer, table, pos, qlen, scale_k=pk.scale,
+            scale_v=pv.scale, packed4=packed4, interpret=True))
+        for b in range(q.shape[0]):
+            n = int(qlen[b])
+            np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                       atol=atol, rtol=atol)
 
 
 def test_kernel_parity_int8_page_boundaries():
@@ -529,7 +561,7 @@ def test_kernel_parity_int8_unmapped_holes():
                          [-1, -1, -1, -1, -1]], jnp.int32)
     pos = jnp.asarray([3 * P + 2, 2 * P + 1, P + 4], jnp.int32)
     _assert_parity_q8(q, pk, pv, table, pos)
-    dead = ragged_paged_attention(q, pk.q, pv.q, table, pos,
+    dead = ragged_paged_attention(q, pk.q, pv.q, LAYER, table, pos,
                                   scale_k=pk.scale, scale_v=pv.scale,
                                   interpret=True)[2]
     np.testing.assert_array_equal(np.asarray(dead),
@@ -594,41 +626,20 @@ def _q4pools(rng, KV, hd):
     """Two nibble-packed pools (k, v) built through the production
     writer (qwrite_prompt_pages dispatches on the pool type), so every
     page carries its own per-head scale from its own amax."""
-    from cake_tpu.kv.quantized_pool import Int4Pool, qwrite_prompt_pages
+    from cake_tpu.kv.quantized_pool import Int4Pool
 
-    def one(seed_vals):
-        pool = Int4Pool(
-            q=jnp.zeros((N_PAGES, P // 2, KV, hd), jnp.uint8),
-            scale=jnp.zeros((N_PAGES, KV), jnp.float32))
-        return qwrite_prompt_pages(
-            pool, seed_vals, jnp.arange(N_PAGES, dtype=jnp.int32))
-
-    pk = one(jnp.asarray(rng.normal(size=(1, N_PAGES * P, KV, hd)),
-                         jnp.float32))
-    pv = one(jnp.asarray(rng.normal(size=(1, N_PAGES * P, KV, hd)),
-                         jnp.float32))
-    return pk, pv
+    pool = Int4Pool(
+        q=jnp.zeros((LAYERS, N_PAGES, P // 2, KV * hd), jnp.uint8),
+        scale=jnp.zeros((LAYERS, N_PAGES, KV), jnp.float32))
+    return _fill_layers(rng, pool, KV, hd), _fill_layers(rng, pool, KV, hd)
 
 
-def _assert_parity_q4(q, pk, pv, table, pos, atol=2e-5):
-    want = paged_attention(q, pk, pv, table, pos)
-    got = ragged_paged_attention(q, pk.q, pv.q, table, pos,
-                                 scale_k=pk.scale, scale_v=pv.scale,
-                                 packed4=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=atol, rtol=atol)
+def _assert_parity_q4(q, pk, pv, table, pos):
+    _assert_parity_q8(q, pk, pv, table, pos, packed4=True)
 
 
-def _assert_mixed_parity_q4(q, pk, pv, table, pos, qlen, atol=2e-5):
-    want = np.asarray(paged_attention_mixed(q, pk, pv, table, pos,
-                                            qlen))
-    got = np.asarray(ragged_paged_attention_mixed(
-        q, pk.q, pv.q, table, pos, qlen, scale_k=pk.scale,
-        scale_v=pv.scale, packed4=True, interpret=True))
-    for b in range(q.shape[0]):
-        n = int(qlen[b])
-        np.testing.assert_allclose(got[b, :n], want[b, :n],
-                                   atol=atol, rtol=atol)
+def _assert_mixed_parity_q4(q, pk, pv, table, pos, qlen):
+    _assert_mixed_parity_q8(q, pk, pv, table, pos, qlen, packed4=True)
 
 
 def test_kernel_parity_int4_page_boundaries():
@@ -668,7 +679,7 @@ def test_kernel_parity_int4_unmapped_holes():
                          [-1, -1, -1, -1, -1]], jnp.int32)
     pos = jnp.asarray([3 * P + 2, 2 * P + 1, P + 4], jnp.int32)
     _assert_parity_q4(q, pk, pv, table, pos)
-    dead = ragged_paged_attention(q, pk.q, pv.q, table, pos,
+    dead = ragged_paged_attention(q, pk.q, pv.q, LAYER, table, pos,
                                   scale_k=pk.scale, scale_v=pv.scale,
                                   packed4=True, interpret=True)[2]
     np.testing.assert_array_equal(np.asarray(dead),
