@@ -185,6 +185,11 @@ class Args:
     # fold over all pages (the reference semantics; use for debugging
     # or non-TPU backends); "auto" = pallas on TPU, fold elsewhere
     paged_attn: str = "auto"
+    # --require-model-type NAME: refuse to start unless the model
+    # directory's config.json resolves to this family (a config.json
+    # `model_type`: llama, mistral, qwen2, mixtral, olmoe). An assertion
+    # for scripted deployments, not a switch: nothing else reads it
+    require_model_type: Optional[str] = None
     # --mixed-batch: token-level continuous batching for the paged
     # (--kv-pages) engine — ONE jitted mixed step processes decode rows
     # and prefill-chunk rows together (per-row query-length metadata in

@@ -480,6 +480,18 @@ def main(argv=None) -> int:
             "tunes the write-ahead request journal's durability "
             "barrier (serve/journal.py)")
 
+    if getattr(args, "require_model_type", None):
+        # before any device or weight is touched: a directory that
+        # resolves to another family must not be served under this name
+        from cake_tpu.models.llama.config import _read_config
+        found = (_read_config(args.model).get("model_type", "llama")
+                 if args.model else None)
+        if found != args.require_model_type:
+            print(f"--require-model-type {args.require_model_type}: "
+                  f"{args.model!r} resolves to model_type {found!r}",
+                  file=sys.stderr)
+            return 2
+
     if args.mode == "worker":
         print(
             "cake-tpu runs the whole topology as one SPMD program over the "

@@ -35,8 +35,9 @@ def _sharded_init(cfg, dtype, bits, mesh, tp: bool):
     """jit(random init, out_shardings=the pipeline plan's specs) for one
     (config, dtype, quantization, mesh): the same program for every
     server of a placement, so it is built — and compiles — once per
-    process. bits: init the dense family's int8/int4 leaves directly
-    (init_params_quantized), None = full precision."""
+    process. bits: draw the int8/int4 leaves directly (the dense
+    family's init_params_quantized, the MoE family's init_params),
+    None = full precision."""
     from functools import partial
 
     from jax.sharding import NamedSharding
@@ -46,7 +47,8 @@ def _sharded_init(cfg, dtype, bits, mesh, tp: bool):
     from cake_tpu.parallel.pipeline import pipeline_param_specs
 
     if cfg.is_moe:
-        from cake_tpu.models.moe.params import init_params as init
+        from cake_tpu.models.moe.params import init_params
+        init = partial(init_params, bits=bits)
     elif bits:
         from cake_tpu.models.llama.params import init_params_quantized
         init = partial(init_params_quantized, bits=bits)
@@ -369,10 +371,7 @@ class Context:
         log.warning("no weights at %r; using random init",
                     self.args.model)
         bits = {"int8": 8, "int4": 4}.get(self.args.quant)
-        # the MoE family has no direct quantized init: shard-wise after
-        direct_bits = None if cfg.is_moe else bits
-        params = _sharded_init(cfg, self.dtype, direct_bits, mesh, tp)()
-        return self._maybe_quantize(params) if cfg.is_moe else params
+        return _sharded_init(cfg, self.dtype, bits, mesh, tp)()
 
     def _load_params_streamed(self, cfg, mesh, tp: bool):
         """Stream weights from disk directly onto their pipeline shards

@@ -53,9 +53,10 @@ def load_text_params(config, model_dir: Optional[str], dtype, rng=None,
     quant ("int8" | "int4", --quant): the tree comes back as
     ops/quant.quantize_params would leave it, WITHOUT the full-precision
     tree ever existing on the device — weights on disk quantize leaf by
-    leaf as each tensor lands, and a weightless dense model inits its
-    quantized leaves directly (init_params_quantized). An 8B bf16 tree is
-    ~15 GiB, most of a v5e's HBM; load-then-quantize cannot start there.
+    leaf as each tensor lands, and a weightless model draws its
+    quantized leaves directly (the dense family's init_params_quantized,
+    the MoE family's init_params). An 8B bf16 tree is ~15 GiB, most of a
+    v5e's HBM; load-then-quantize cannot start there.
     """
     import logging
 
@@ -85,12 +86,8 @@ def load_text_params(config, model_dir: Optional[str], dtype, rng=None,
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     if not bits:
         return init_params(config, rng, dtype=dtype)
-    if not is_moe:
-        from cake_tpu.models.llama.params import init_params_quantized_jit
-        return init_params_quantized_jit(config, rng, dtype=dtype,
-                                         bits=bits)
-    # no direct quantized init for the MoE family yet: the tiny MoE
-    # presets fit either way, a published-width one would not
-    from cake_tpu.ops.quant import quantize_params_leafwise
-    return quantize_params_leafwise(init_params(config, rng, dtype=dtype),
-                                    bits=bits)
+    if is_moe:
+        from cake_tpu.models.moe.params import init_params_jit
+        return init_params_jit(config, rng, dtype=dtype, bits=bits)
+    from cake_tpu.models.llama.params import init_params_quantized_jit
+    return init_params_quantized_jit(config, rng, dtype=dtype, bits=bits)

@@ -57,7 +57,7 @@ END_HEADER = "<|end_header_id|>"
 EOT = "<|eot_id|>"
 
 
-TEMPLATES = ("llama3", "mistral", "chatml")
+TEMPLATES = ("llama3", "mistral", "chatml", "tulu")
 
 
 class History:
@@ -69,7 +69,10 @@ class History:
     turn (the official template has no system role), ending after the
     last `[/INST]` to cue completion.
     template="chatml": the Qwen2 format — `<|im_start|>{role}\\n{content}
-    <|im_end|>\\n` per message, ending with an open assistant header."""
+    <|im_end|>\\n` per message, ending with an open assistant header.
+    template="tulu": the OLMoE-Instruct (Tülu) format — `<|{role}|>\\n
+    {content}\\n` per message (an assistant turn closes with
+    `<|endoftext|>`), ending with `<|assistant|>\\n`."""
 
     def __init__(self, template: str = "llama3") -> None:
         if template not in TEMPLATES:
@@ -113,6 +116,14 @@ class History:
             out += [f"<|im_start|>{m.role.value}\n{m.content.strip()}"
                     f"<|im_end|>\n" for m in self._messages]
             out.append("<|im_start|>assistant\n")
+            return "".join(out)
+        if self.template == "tulu":
+            out = ["<|endoftext|>"]
+            for m in self._messages:
+                end = ("<|endoftext|>\n"
+                       if m.role == MessageRole.ASSISTANT else "\n")
+                out.append(f"<|{m.role.value}|>\n{m.content.strip()}{end}")
+            out.append("<|assistant|>\n")
             return "".join(out)
         out = [BEGIN_OF_TEXT]
         for m in self._messages:

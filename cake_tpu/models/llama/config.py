@@ -21,10 +21,31 @@ def _read_config(model_dir: str) -> dict:
         return json.load(f)
 
 
+# config.json `model_type` -> what the family adds to the Llama block
+# (README "Models served"). An absent key is read as "llama".
+MODEL_TYPES = {
+    "llama": "the block as it is: GQA, RoPE, RMSNorm, SwiGLU",
+    "mistral": "a sliding window where the config gives one; [INST] chat",
+    "qwen2": "a bias on the q, k and v projections; ChatML",
+    "mixtral": "sparse experts, top-k weights renormalised",
+    "olmoe": "sparse experts with the raw top-k probabilities, and an "
+             "RMSNorm over the query and key projections",
+}
+_MOE_TYPES = ("mixtral", "olmoe")
+
+
 def load_config_dict(raw: dict) -> "LlamaConfig":
-    """Dispatch a parsed config.json on `model_type`: "mixtral" ->
-    MoEConfig (sparse experts), anything else -> LlamaConfig."""
-    if raw.get("model_type") == "mixtral":
+    """Dispatch a parsed config.json on `model_type`: "mixtral" and
+    "olmoe" -> MoEConfig (sparse experts), the dense names and an
+    absent key -> LlamaConfig. A name this program has no block for is
+    refused: served as a Llama it would answer, wrongly, under that
+    model's name."""
+    model_type = raw.get("model_type", "llama")
+    if model_type not in MODEL_TYPES:
+        raise ValueError(
+            f"unknown model_type {model_type!r} in config.json: this "
+            "program serves " + ", ".join(MODEL_TYPES))
+    if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)
     return LlamaConfig.from_hf_dict(raw)
@@ -56,8 +77,8 @@ class LlamaConfig:
     # (correct; a ring buffer is a memory optimization, not semantics).
     sliding_window: Optional[int] = None
     # prompt template for the chat paths (models/chat.TEMPLATES);
-    # from_hf_dict sets "mistral" for model_type mistral/mixtral and
-    # "chatml" for qwen2
+    # from_hf_dict sets "mistral" for model_type mistral/mixtral,
+    # "chatml" for qwen2 and "tulu" for olmoe
     chat_template: str = "llama3"
     # QKV projection bias (Qwen2-family; HF "attention_bias" / implied by
     # model_type qwen2) — adds bq/bk/bv leaves to every block
@@ -119,7 +140,7 @@ class LlamaConfig:
             # SentencePiece vocab — Llama-3 header tokens don't exist
             # there; Qwen2 uses ChatML
             chat_template={"mistral": "mistral", "mixtral": "mistral",
-                           "qwen2": "chatml"}.get(
+                           "qwen2": "chatml", "olmoe": "tulu"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",
                                    raw.get("model_type") == "qwen2"),

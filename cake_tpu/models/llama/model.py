@@ -54,24 +54,46 @@ class RopeTables(NamedTuple):
         return cls(cos, sin)
 
 
-def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
-                   tp_axis: Optional[str] = None,
-                   ep_axis: Optional[str] = None):
+def _qk_norm(x, weight, eps: float, tp_axis: Optional[str]):
+    """RMSNorm over the WHOLE projection [.., heads*hd] (OLMoE: before
+    the split into heads, before RoPE). Under manual tensor parallelism
+    the projection is split by heads over `tp_axis`, so the mean square
+    is summed over the axis."""
+    xf = x.astype(jnp.float32)
+    ss = jnp.sum(jnp.square(xf), axis=-1, keepdims=True)
+    n = x.shape[-1]
+    if tp_axis is not None:
+        ss = lax.psum(ss, tp_axis)
+        n = n * lax.psum(1, tp_axis)
+    y = xf * lax.rsqrt(ss / n + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def block_skeleton_stats(lp, x, config: LlamaConfig, attn_fn,
+                         tp_axis: Optional[str] = None,
+                         ep_axis: Optional[str] = None,
+                         token_mask=None):
     """Decoder-block math with a pluggable attention:
     rms → qkv proj → attn_fn(q, k, v) → o_proj → residual → rms → FFN →
     residual (reference transformer.rs:51-73). attn_fn returns
     (attn [B,S,H,hd], extras) — extras carry e.g. updated caches.
+    Returns (x, extras, the MoE layer's counters or None).
 
-    The FFN is dense SwiGLU (mlp.rs:15-18), or — when the layer params carry
-    a `router` leaf (models/moe) — a sparse mixture-of-experts; every
-    caller (scan, pipeline, ragged decode) works for both since blocks are
-    just pytrees.
+    What a family adds is keyed on the layer's leaves, so every caller
+    (scan, pipeline, ragged decode, the paged steps) works for all of
+    them and a family runs only its own code: `bq` a QKV bias (Qwen2),
+    `q_norm` an RMSNorm over the whole query and key projections
+    (OLMoE), `router` a sparse mixture-of-experts FFN (models/moe) in
+    place of the dense SwiGLU (mlp.rs:15-18).
 
     tp_axis: when running *manually* tensor-parallel under shard_map, the
     mesh axis name to psum partial row-parallel outputs over (Megatron: o_proj
     and down_proj each produce partial sums). Head counts are derived from
     the weight shapes, so the same code runs on full or head-sharded weights.
     ep_axis: shard_map expert-parallel axis for the MoE path (ops/moe.py).
+    token_mask: [B, S] bool, the positions that hold a real token (a
+    mixed step pads every row to its window); the MoE FFN routes no
+    other. The dense FFN ignores it.
     """
     B, S, D = x.shape
     hd = config.head_dim
@@ -91,6 +113,9 @@ def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
+        if "q_norm" in lp:  # OLMoE query/key norm (config.qk_norm)
+            q = _qk_norm(q, lp["q_norm"], config.rms_norm_eps, tp_axis)
+            k = _qk_norm(k, lp["k_norm"], config.rms_norm_eps, tp_axis)
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, KV, hd)
         v = v.reshape(B, S, KV, hd)
@@ -102,6 +127,7 @@ def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
             attn_out = lax.psum(attn_out, tp_axis)
         x = x + attn_out
 
+    stats = None
     with jax.named_scope("ffn"):
         h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
         if "router" in lp:
@@ -109,14 +135,24 @@ def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
             # AttributeError here means MoE params were paired with a
             # dense LlamaConfig — a real mismatch that must not default
             # silently.
-            mlp_out = moe_mlp(h=h, lp=lp, ep_axis=ep_axis,
-                              num_experts_per_tok=config.num_experts_per_tok)
+            mlp_out, stats = moe_mlp(
+                lp, h, config.num_experts_per_tok, config.norm_topk_prob,
+                ep_axis=ep_axis, token_mask=token_mask)
         else:
             gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
             mlp_out = qmatmul(gate * qmatmul(h, lp["w_up"]), lp["w_down"])
         if tp_axis is not None:
             mlp_out = lax.psum(mlp_out, tp_axis)
         x = x + mlp_out
+    return x, extras, stats
+
+
+def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
+                   tp_axis: Optional[str] = None,
+                   ep_axis: Optional[str] = None):
+    """block_skeleton_stats for the callers that keep no counters."""
+    x, extras, _ = block_skeleton_stats(lp, x, config, attn_fn,
+                                        tp_axis=tp_axis, ep_axis=ep_axis)
     return x, extras
 
 

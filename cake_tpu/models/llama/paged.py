@@ -509,8 +509,9 @@ def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
 # -- model-level steps (engine step-fn signatures) ----------------------------
 
 
-def scan_layers_paged(blocks, x, cache: PagedKVCache,
-                      config: LlamaConfig, layer_attn):
+def scan_layers_paged_stats(blocks, x, cache: PagedKVCache,
+                            config: LlamaConfig, layer_attn,
+                            token_mask=None):
     """The layer loop of every paged step program.
 
     The stacked pool travels as loop CARRY beside the hidden state and
@@ -519,11 +520,23 @@ def scan_layers_paged(blocks, x, cache: PagedKVCache,
     layer, stacked back and kept twice; a carried pool that each layer
     scatters its token rows into is one buffer from the donated input
     to the output. Whatever pytree the pool is (plain arrays, QuantPool,
-    Int4Pool) rides along unchanged.
+    Int4Pool) rides along unchanged. A sparse model's expert weights
+    stay out of the scan for the same reason: the grouped matmul is a
+    custom call, a scanned operand of which would be sliced into a copy
+    every layer, so they reach the block as (stack, layer) and the
+    kernel indexes the stack (ops/moe.LayerOf).
 
     layer_attn(layer, pool_k, pool_v, q, k, v) -> (attn [B,S,H,hd],
-    pool_k, pool_v): write this layer's new KV, attend."""
-    from cake_tpu.models.llama.model import block_skeleton
+    pool_k, pool_v): write this layer's new KV, attend.
+    token_mask: [B, S] bool, the positions that hold a real token (the
+    expert FFN routes no other); None = all.
+    Returns (x, cache, the expert layers' ops/moe.MoEStats with a
+    leading layer axis, or None for a dense model)."""
+    from cake_tpu.models.llama.model import block_skeleton_stats
+    from cake_tpu.ops.moe import EXPERT_LEAVES, LayerOf
+
+    stacked = {k: blocks[k] for k in EXPERT_LEAVES if k in blocks}
+    scanned = {k: v for k, v in blocks.items() if k not in stacked}
 
     def body(carry, lp):
         h, layer, pk, pv = carry
@@ -532,13 +545,27 @@ def scan_layers_paged(blocks, x, cache: PagedKVCache,
             out, pk2, pv2 = layer_attn(layer, pk, pv, q, k, v)
             return out, (pk2, pv2)
 
-        h, (pk, pv) = block_skeleton(lp, h, config, attn_fn)
-        return (h, layer + 1, pk, pv), None
+        lp = dict(lp, **{k: LayerOf(v, layer) for k, v in stacked.items()})
+        h, (pk, pv), stats = block_skeleton_stats(
+            lp, h, config, attn_fn, token_mask=token_mask)
+        return (h, layer + 1, pk, pv), stats
 
     with jax.named_scope("layers"):
-        (x, _, pool_k, pool_v), _ = lax.scan(
-            body, (x, jnp.int32(0), cache.k, cache.v), blocks)
-    return x, cache._replace(k=pool_k, v=pool_v)
+        (x, _, pool_k, pool_v), stats = lax.scan(
+            body, (x, jnp.int32(0), cache.k, cache.v), scanned)
+    return x, cache._replace(k=pool_k, v=pool_v), stats
+
+
+def scan_layers_paged(blocks, x, cache: PagedKVCache,
+                      config: LlamaConfig, layer_attn, n_real=None):
+    """scan_layers_paged_stats for the prefill programs, which keep no
+    counters. n_real: [B] real tokens of each right-padded window."""
+    mask = None
+    if n_real is not None:
+        mask = jnp.arange(x.shape[1])[None, :] < n_real[:, None]
+    x, cache, _ = scan_layers_paged_stats(blocks, x, cache, config,
+                                          layer_attn, token_mask=mask)
+    return x, cache
 
 
 def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
@@ -546,7 +573,8 @@ def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
                             attn: str = "fold"):
     """run_blocks_ragged over the page pool: write the token, attend the
     pages. x: [B, 1, D]; pos/active: [B]; attn: paged_attention impl
-    ({fold,pallas} — static under jit)."""
+    ({fold,pallas} — static under jit). Returns (x, cache, expert
+    counters or None); inactive rows are not routed."""
     from cake_tpu.ops.rope import apply_rope
 
     def layer_attn(layer, pk, pv, q, k, v):
@@ -557,7 +585,8 @@ def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
         return (paged_attention(q, pk, pv, layer, cache.table, pos,
                                 impl=attn), pk, pv)
 
-    return scan_layers_paged(blocks, x, cache, config, layer_attn)
+    return scan_layers_paged_stats(blocks, x, cache, config, layer_attn,
+                                   token_mask=active[:, None])
 
 
 def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
@@ -567,6 +596,13 @@ def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
     so serve.engine.make_decode_scan can build the K-step paged decode
     scan from it (dispatch amortization works for paged serving exactly
     like dense)."""
+    return _forward_ragged_paged(params, tokens, cache, pos, active,
+                                 rope, config, attn)[:2]
+
+
+def _forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
+                          active, rope, config: LlamaConfig, attn: str):
+    """(logits, cache, expert counters or None) of one ragged decode."""
     from cake_tpu.models.llama.model import rope_rows_per_row
     from cake_tpu.ops.norms import rms_norm
     from cake_tpu.ops.quant import qmatmul
@@ -574,13 +610,28 @@ def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     rope_c, rope_s = rope_rows_per_row(rope.cos, rope.sin, pos)
-    x, cache = run_blocks_ragged_paged(params["blocks"], x, cache, pos,
-                                       active, rope_c, rope_s, config,
-                                       attn=attn)
+    x, cache, stats = run_blocks_ragged_paged(
+        params["blocks"], x, cache, pos, active, rope_c, rope_s, config,
+        attn=attn)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         logits = qmatmul(x[:, -1], params["lm_head"]).astype(jnp.float32)
-    return logits, cache
+    return logits, cache, stats
+
+
+def _step_result(logits, cache, stats):
+    """What a step program returns: (logits, cache), and for a sparse
+    model a third, its expert counters [5] in the order of
+    obs/steps.MOE_COUNTERS (the engine fetches them with the sampled
+    tokens): rows, padded rows and experts touched summed over the
+    layers, the busiest and the average expert's tokens as the mean
+    over the layers."""
+    if stats is None:
+        return logits, cache
+    return logits, cache, jnp.stack([
+        jnp.sum(stats.rows), jnp.sum(stats.rows_padded),
+        jnp.mean(stats.load_max), jnp.mean(stats.load_mean),
+        jnp.sum(stats.touched)])
 
 
 @_partial(jax.jit, static_argnames=("config", "attn"),
@@ -592,8 +643,8 @@ def decode_step_ragged_paged(params, tokens, pos, active,
     drop-in decode step fn for --kv-pages serving. attn selects the
     paged_attention impl ({fold,pallas}); static, so both variants are
     separately compiled programs with the same traced signature."""
-    return forward_ragged_paged(params, tokens, cache, pos, active,
-                                rope, config, attn=attn)
+    return _step_result(*_forward_ragged_paged(
+        params, tokens, cache, pos, active, rope, config, attn))
 
 
 @_partial(jax.jit, static_argnames=("config", "attn"),
@@ -644,7 +695,7 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
         return gqa_attention(q, k, v, mask=mask), pk, pv
 
     x, cache = scan_layers_paged(params["blocks"], x, cache, config,
-                                 layer_attn)
+                                 layer_attn, n_real=prompt_len)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
@@ -773,7 +824,7 @@ def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
         return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
 
     x, cache = scan_layers_paged(params["blocks"], x, cache, config,
-                                 layer_attn)
+                                 layer_attn, n_real=suffix_len)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
@@ -844,7 +895,7 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
         return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
 
     x, cache = scan_layers_paged(params["blocks"], x, cache, config,
-                                 layer_attn)
+                                 layer_attn, n_real=n_real)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
         last = jnp.take_along_axis(
@@ -875,7 +926,11 @@ def run_blocks_mixed_paged(blocks, x, cache: PagedKVCache, pos, q_len,
         return (paged_attention_mixed(q, pk, pv, layer, cache.table, pos,
                                       q_len, impl=attn), pk, pv)
 
-    return scan_layers_paged(blocks, x, cache, config, layer_attn)
+    # the window's padding and idle rows hold no token: not routed
+    C = x.shape[1]
+    real = (jnp.arange(C)[None, :] < q_len[:, None]) & active[:, None]
+    return scan_layers_paged_stats(blocks, x, cache, config, layer_attn,
+                                   token_mask=real)
 
 
 def _mixed_windows_trunk(params, tokens, pos, q_len, active,
@@ -898,12 +953,12 @@ def _mixed_windows_trunk(params, tokens, pos, q_len, active,
     pos_grid = jnp.minimum(pos[:, None] + jnp.arange(C)[None, :], T - 1)
     rope_c = jnp.take(rope.cos, pos_grid, axis=0)     # [B, C, hd//2]
     rope_s = jnp.take(rope.sin, pos_grid, axis=0)
-    x, cache = run_blocks_mixed_paged(params["blocks"], x, cache, pos,
-                                      q_len, active, rope_c, rope_s,
-                                      config, attn=attn)
+    x, cache, stats = run_blocks_mixed_paged(
+        params["blocks"], x, cache, pos, q_len, active, rope_c, rope_s,
+        config, attn=attn)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    return x, cache
+    return x, cache, stats
 
 
 @_partial(jax.jit, static_argnames=("config", "attn"),
@@ -938,15 +993,16 @@ def mixed_step_paged(params, tokens, pos, q_len, active,
     from cake_tpu.ops.quant import qmatmul
 
     B = tokens.shape[0]
-    x, cache = _mixed_windows_trunk(params, tokens, pos, q_len, active,
-                                    cache, rope, config, attn)
+    x, cache, stats = _mixed_windows_trunk(params, tokens, pos, q_len,
+                                           active, cache, rope, config,
+                                           attn)
     with jax.named_scope("head"):
         last = jnp.take_along_axis(
             x,
             (jnp.maximum(q_len, 1) - 1).reshape(B, 1, 1).astype(jnp.int32),
             axis=1)[:, 0]
         logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache
+    return _step_result(logits, cache, stats)
 
 
 def verify_window_paged(params, tokens, pos, q_len, active,
@@ -963,8 +1019,8 @@ def verify_window_paged(params, tokens, pos, q_len, active,
     own jit."""
     from cake_tpu.ops.quant import qmatmul
 
-    x, cache = _mixed_windows_trunk(params, tokens, pos, q_len, active,
-                                    cache, rope, config, attn)
+    x, cache, _ = _mixed_windows_trunk(params, tokens, pos, q_len, active,
+                                       cache, rope, config, attn)
     with jax.named_scope("head"):
         logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, cache

@@ -351,6 +351,8 @@ def block_param_keys(config=None, *, moe: Optional[bool] = None) -> tuple:
     keys = ["attn_norm", "wq", "wk", "wv", "wo", "mlp_norm"]
     if config is not None and getattr(config, "attention_bias", False):
         keys += ["bq", "bk", "bv"]
+    if config is not None and getattr(config, "qk_norm", False):
+        keys += ["q_norm", "k_norm"]
     keys += (["router", "we_gate", "we_up", "we_down"] if moe
              else ["w_gate", "w_up", "w_down"])
     return tuple(keys)
@@ -376,6 +378,10 @@ def block_specs(keys, stage_axis: Optional[str] = None,
         "bq": P(S, T),
         "bk": P(S, T),
         "bv": P(S, T),
+        # query/key norm (OLMoE): over the whole projection, split by
+        # heads like it
+        "q_norm": P(S, T),
+        "k_norm": P(S, T),
         "wo": P(S, T, None),
         "mlp_norm": P(S, None),
         "w_gate": P(S, None, T),

@@ -1,11 +1,14 @@
-"""MoE parameter pytree: init, HF (Mixtral) safetensors loading, EP specs.
+"""MoE parameter pytree: init, HF safetensors loading, EP specs.
 
 Same stacked-[L, ...] layout as the Llama family (models/llama/params.py)
 so the block walk is one `lax.scan`; expert weights add an E axis:
-router [L, D, E], we_gate/we_up [L, E, D, F], we_down [L, E, F, D].
-On-disk format is HF Mixtral safetensors
-(model.layers.N.block_sparse_moe.gate.weight, .experts.K.{w1,w2,w3}.weight
-— w1=gate, w2=down, w3=up), so public checkpoints load unchanged.
+router [L, D, E], we_gate/we_up [L, E, D, F], we_down [L, E, F, D]; a
+family with query/key norm (OLMoE) adds q_norm [L, H*hd], k_norm
+[L, KV*hd]. On-disk formats are the public ones, so checkpoints load
+unchanged: Mixtral (model.layers.N.block_sparse_moe.gate.weight,
+.experts.K.{w1,w2,w3}.weight — w1=gate, w2=down, w3=up) and OLMoE
+(model.layers.N.mlp.gate.weight, .mlp.experts.K.{gate,up,down}_proj.weight,
+.self_attn.{q,k}_norm.weight).
 """
 
 from __future__ import annotations
@@ -21,62 +24,113 @@ from cake_tpu.models.llama.params import _np_dtype
 from cake_tpu.models.moe.config import MoEConfig
 
 
-def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16):
-    """Random-init MoE parameter pytree (tests/benches)."""
+def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
+                bits: Optional[int] = None):
+    """Random-init MoE parameter pytree (tests, benchmarks, a model
+    directory with no weights).
+
+    bits=8 draws the matmul leaves (attention, experts, head) as int8
+    per-channel QTensors directly, as `quantize_params(bits=8)` would
+    leave them: a full-precision copy of a published-width model never
+    exists (OLMoE-1B-7B is 13.8 GB in bf16, 6.9 GB as drawn here). Run
+    under jit (`init_params_jit`) each leaf's draw-and-cast fuses, so
+    the program holds the tree and nothing beside it. Norm weights are
+    drawn around 1 where the family has query/key norm, so that a test
+    sees them."""
+    from cake_tpu.ops.quant import _BLOCK_CONTRACT, QTensor
+
+    if bits not in (None, 8):
+        raise NotImplementedError(
+            "MoE expert weights quantize per-channel only; use "
+            "--quant int8 for MoE models")
     c = config
     L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
     E = c.num_local_experts
     H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    keys = jax.random.split(rng, 12)
+    keys = iter(jax.random.split(rng, 16))
 
-    def w(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32)
+    def w(shape, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
                 * (1.0 / np.sqrt(fan_in))).astype(dtype)
 
-    params = {
-        "embed": w(keys[0], (c.vocab_size, D), D),
-        "blocks": {
-            "attn_norm": jnp.ones((L, D), dtype),
-            "wq": w(keys[1], (L, D, H * hd), D),
-            "wk": w(keys[2], (L, D, KV * hd), D),
-            "wv": w(keys[3], (L, D, KV * hd), D),
-            "wo": w(keys[4], (L, H * hd, D), H * hd),
-            "mlp_norm": jnp.ones((L, D), dtype),
-            "router": w(keys[5], (L, D, E), D),
-            "we_gate": w(keys[6], (L, E, D, F), D),
-            "we_up": w(keys[7], (L, E, D, F), D),
-            "we_down": w(keys[8], (L, E, F, D), F),
-        },
-        "final_norm": jnp.ones((D,), dtype),
-        "lm_head": w(keys[9], (D, c.vocab_size), D),
+    def mat(name, shape, fan_in):
+        if not bits:
+            return w(shape, fan_in)
+        contract = _BLOCK_CONTRACT.get(name, (0,))
+        q = jax.random.randint(next(keys), shape, -127, 128, dtype=jnp.int8)
+        # uniform int8 has standard deviation 127/sqrt(3): the scale
+        # gives the dequantized weights the float draw's 1/sqrt(fan_in)
+        scale = jnp.full(
+            tuple(n for i, n in enumerate(shape) if i not in contract),
+            np.sqrt(3.0) / (127.0 * np.sqrt(fan_in)), jnp.float32)
+        return QTensor(q=q, scale=scale)
+
+    blocks = {
+        "attn_norm": jnp.ones((L, D), dtype),
+        "wq": mat("wq", (L, D, H * hd), D),
+        "wk": mat("wk", (L, D, KV * hd), D),
+        "wv": mat("wv", (L, D, KV * hd), D),
+        "wo": mat("wo", (L, H * hd, D), H * hd),
+        "mlp_norm": jnp.ones((L, D), dtype),
+        "router": w((L, D, E), D),
+        "we_gate": mat("we_gate", (L, E, D, F), D),
+        "we_up": mat("we_up", (L, E, D, F), D),
+        "we_down": mat("we_down", (L, E, F, D), F),
     }
-    if config.tie_word_embeddings:
-        params["lm_head"] = params["embed"].T
+    if c.qk_norm:
+        blocks["q_norm"] = (1.0 + 0.1 * jax.random.normal(
+            next(keys), (L, H * hd), jnp.float32)).astype(dtype)
+        blocks["k_norm"] = (1.0 + 0.1 * jax.random.normal(
+            next(keys), (L, KV * hd), jnp.float32)).astype(dtype)
+    params = {
+        "embed": w((c.vocab_size, D), D),
+        "blocks": blocks,
+        "final_norm": jnp.ones((D,), dtype),
+    }
+    params["lm_head"] = (params["embed"].T if c.tie_word_embeddings
+                         else mat("lm_head", (D, c.vocab_size), D))
     return params
 
 
-MOE_PREFIX = "block_sparse_moe"
-# our leaf -> (HF per-layer suffix, transpose); shared by the eager and
-# streaming loaders so their trees cannot structurally diverge
-MOE_ATTN_LAYOUT = {
-    "attn_norm": ("input_layernorm.weight", False),
-    "wq": ("self_attn.q_proj.weight", True),
-    "wk": ("self_attn.k_proj.weight", True),
-    "wv": ("self_attn.v_proj.weight", True),
-    "wo": ("self_attn.o_proj.weight", True),
-    "mlp_norm": ("post_attention_layernorm.weight", False),
-    "router": (f"{MOE_PREFIX}.gate.weight", True),
-}
-# our expert leaf -> HF expert weight name (w1=gate, w3=up, w2=down)
-MOE_EXPERT_LAYOUT = (("we_gate", "w1"), ("we_up", "w3"),
-                     ("we_down", "w2"))
+init_params_jit = jax.jit(init_params,
+                          static_argnames=("config", "dtype", "bits"))
+
+
+def hf_layout(config: MoEConfig):
+    """(per-layer {leaf: (HF suffix, transpose)}, expert name
+    ((leaf, HF expert tensor) x 3), HF prefix of the experts) for the
+    config's family; shared by the eager and streaming loaders so their
+    trees cannot structurally diverge."""
+    attn = {
+        "attn_norm": ("input_layernorm.weight", False),
+        "wq": ("self_attn.q_proj.weight", True),
+        "wk": ("self_attn.k_proj.weight", True),
+        "wv": ("self_attn.v_proj.weight", True),
+        "wo": ("self_attn.o_proj.weight", True),
+        "mlp_norm": ("post_attention_layernorm.weight", False),
+    }
+    if config.hf_layout == "olmoe":
+        prefix = "mlp"
+        experts = (("we_gate", "gate_proj"), ("we_up", "up_proj"),
+                   ("we_down", "down_proj"))
+    else:                           # Mixtral: w1=gate, w3=up, w2=down
+        prefix = "block_sparse_moe"
+        experts = (("we_gate", "w1"), ("we_up", "w3"), ("we_down", "w2"))
+    attn["router"] = (f"{prefix}.gate.weight", True)
+    if config.qk_norm:
+        attn["q_norm"] = ("self_attn.q_norm.weight", False)
+        attn["k_norm"] = ("self_attn.k_norm.weight", False)
+    return attn, experts, prefix
+
+
 
 
 def load_params_from_hf(model_dir: str, config: MoEConfig,
                         dtype=jnp.bfloat16,
                         layer_range: Optional[range] = None,
                         finish=None):
-    """Build the MoE pytree from HF Mixtral safetensors. finish: (leaf
+    """Build the MoE pytree from HF Mixtral or OLMoE safetensors (the
+    config says which names). finish: (leaf
     name, device array) -> the leaf to keep, applied as each tensor
     lands (see models/llama/params.load_params_from_hf)."""
     from cake_tpu.utils.loading import load_weights
@@ -90,16 +144,15 @@ def load_params_from_hf(model_dir: str, config: MoEConfig,
     layers = list(layer_range) if layer_range is not None else list(range(L))
     nd = _np_dtype(dtype)
 
-    moe = MOE_PREFIX
+    attn, expert_names, moe = hf_layout(c)
     needed = {"model.embed_tokens.weight", "model.norm.weight"}
     if not c.tie_word_embeddings:
         needed.add("lm_head.weight")
-    attn = MOE_ATTN_LAYOUT
     for i in layers:
         for suffix, _t in attn.values():
             needed.add(f"model.layers.{i}.{suffix}")
         for e in range(E):
-            for wn in ("w1", "w2", "w3"):
+            for _leaf, wn in expert_names:
                 needed.add(f"model.layers.{i}.{moe}.experts.{e}.{wn}.weight")
 
     host = load_weights(model_dir, filter_fn=lambda n: n in needed)
@@ -114,9 +167,8 @@ def load_params_from_hf(model_dir: str, config: MoEConfig,
         ])))
         for key, (suffix, tr) in attn.items()
     }
-    # Experts: HF w1 [F, D] = gate, w3 [F, D] = up (both -> [D, F]);
-    # w2 [D, F] = down (-> [F, D]).
-    for key, wn in MOE_EXPERT_LAYOUT:
+    # Experts: HF gate and up [F, D] (-> [D, F]); down [D, F] (-> [F, D]).
+    for key, wn in expert_names:
         blocks[key] = finish(key, jnp.asarray(np.stack([
             np.stack([
                 t(f"model.layers.{i}.{moe}.experts.{e}.{wn}.weight", True)
@@ -137,7 +189,7 @@ def load_params_from_hf(model_dir: str, config: MoEConfig,
 
 def load_params_sharded(model_dir: str, config: MoEConfig, shardings,
                         dtype=jnp.bfloat16):
-    """Stream HF Mixtral safetensors directly onto mesh shards — the MoE
+    """Stream HF Mixtral or OLMoE safetensors directly onto mesh shards — the MoE
     analog of models/llama/params.load_params_sharded: each leaf is a
     jax.make_array_from_callback over mmap views (prefetch disabled), so
     only locally addressable shard bytes are ever read. At Mixtral-8x22B
@@ -156,7 +208,7 @@ def load_params_sharded(model_dir: str, config: MoEConfig, shardings,
     nd = _np_dtype(dtype)
     simple_leaf, block_leaf = make_stream_leaf_builders(host, nd)
     shard_of = stream_shard_of(shardings)
-    moe = MOE_PREFIX
+    attn, expert_names, moe = hf_layout(c)
 
     def expert_leaf(wn, sharding):
         # [L, E, in, out] stacked from per-expert [out, in] HF tensors
@@ -177,8 +229,8 @@ def load_params_sharded(model_dir: str, config: MoEConfig, shardings,
     blocks = {
         key: block_leaf([f"model.layers.{i}.{suffix}" for i in range(L)],
                         tr, shard_of("blocks", key))
-        for key, (suffix, tr) in MOE_ATTN_LAYOUT.items()}
-    for key, wn in MOE_EXPERT_LAYOUT:
+        for key, (suffix, tr) in attn.items()}
+    for key, wn in expert_names:
         blocks[key] = expert_leaf(wn, shard_of("blocks", key))
 
     params = {
@@ -195,17 +247,19 @@ def load_params_sharded(model_dir: str, config: MoEConfig, shardings,
 
 
 def param_specs(tp_axis: str = "tp", ep_axis: Optional[str] = "ep",
-                stage_axis: Optional[str] = None):
+                stage_axis: Optional[str] = None,
+                config: Optional[MoEConfig] = None):
     """PartitionSpec pytree: experts over ep, Megatron F-dim over tp.
 
-    Under plain jit + NamedSharding, annotating the weights is all EP
-    needs — XLA partitions the expert einsums in ops/moe.py and inserts
-    the reduction. The router stays replicated (it is [D, E]-tiny).
+    Under plain jit + NamedSharding the annotation places the weights;
+    the grouped matmul (ops/moe.py) is one custom call, so XLA gathers
+    its operands — the partitioned form is the shard_map one (`ep_axis`).
+    The router stays replicated (it is [D, E]-tiny).
     """
     from cake_tpu.models.llama.params import block_param_keys, block_specs
     return {
         "embed": P(tp_axis, None),
-        "blocks": block_specs(block_param_keys(moe=True),
+        "blocks": block_specs(block_param_keys(config, moe=True),
                               stage_axis=stage_axis, tp_axis=tp_axis,
                               ep_axis=ep_axis),
         "final_norm": P(None),

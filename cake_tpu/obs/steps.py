@@ -154,6 +154,31 @@ _MIXED_ROWS = _m.counter(
     "kind (decode = one-token decode rows, prefill = prefill-chunk "
     "rows, idle = empty slots in the launch)",
     labelnames=("kind",))
+# sparse-expert counters, computed in the step program from the group
+# sizes its grouped matmuls walk (ops/moe.MoEStats, summed or averaged
+# over the layers by paged.scan_layers_paged_stats) and fetched with the
+# sampled tokens; absent for a dense model. (record key, series), in the
+# order of the step program's vector.
+MOE_COUNTERS = (
+    ("moe_rows", _m.counter(
+        "cake_moe_rows_total",
+        "(token, expert) rows the expert matmuls computed, all layers")),
+    ("moe_rows_padded", _m.counter(
+        "cake_moe_rows_padded_total",
+        "Rows the expert matmuls' tiles covered, tile padding included")),
+    ("moe_load_max", _m.counter(
+        "cake_moe_expert_load_max",
+        "Tokens on a layer's busiest expert, mean over layers, summed "
+        "over steps (over cake_moe_expert_load_mean: the imbalance)")),
+    ("moe_load_mean", _m.counter(
+        "cake_moe_expert_load_mean",
+        "Tokens on a layer's average expert, mean over layers, summed "
+        "over steps")),
+    ("moe_experts_touched", _m.counter(
+        "cake_moe_experts_touched_total",
+        "Experts with at least one token, all layers: the expert "
+        "weights the steps had to read")),
+)
 
 
 def refresh_page_gauges(engine) -> None:
@@ -395,6 +420,9 @@ class StepRecord:
     # end of the previous step's fetch -> start of this step's first
     # dispatch: how long the engine left the device with nothing queued
     gap_s: Optional[float] = None
+    # sparse-expert counters since the previous record that carried
+    # them, in the order of MOE_COUNTERS
+    moe: Optional[Tuple[float, ...]] = None
 
     def to_dict(self) -> Dict:
         out = {
@@ -430,6 +458,9 @@ class StepRecord:
                              for k, v in self.phases.items()}
         if self.gap_s is not None:
             out["gap_s"] = round(self.gap_s, 6)
+        if self.moe is not None:
+            for (key, _series), v in zip(MOE_COUNTERS, self.moe):
+                out[key] = round(v, 3)
         return out
 
 
@@ -601,7 +632,8 @@ class StepTelemetry:
                rows_prefill: Optional[int] = None,
                rows_idle: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
-               impl: Optional[str] = None) -> StepRecord:
+               impl: Optional[str] = None,
+               moe: Optional[Sequence[float]] = None) -> StepRecord:
         """Append one step record; derives MFU / HBM utilization from
         `cost` and the step's device seconds. Any subset of the three
         timings may be given; missing ones fall back to the others.
@@ -610,7 +642,8 @@ class StepTelemetry:
         counters. rids: the requests whose rows rode this dispatch
         (the per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
-        kind (default: the recorder's engine-wide flavor)."""
+        kind (default: the recorder's engine-wide flavor). moe: the
+        step program's sparse-expert counters (StepRecord.moe)."""
         wall = wall_s if wall_s is not None else (
             (dispatch_s or 0.0) + (device_s or 0.0))
         disp = dispatch_s if dispatch_s is not None else wall
@@ -640,7 +673,9 @@ class StepTelemetry:
                 rows_idle=rows_idle,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
-                phases=phases or None, gap_s=gap)
+                phases=phases or None, gap_s=gap,
+                moe=(tuple(float(v) for v in moe)
+                     if moe is not None else None))
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
@@ -649,6 +684,9 @@ class StepTelemetry:
                      ("idle", rows_idle)):
             if v:
                 _MIXED_ROWS.labels(kind=k).inc(v)
+        if moe is not None:
+            for (_key, series), v in zip(MOE_COUNTERS, rec.moe):
+                series.inc(v)
         if mfu is not None:
             _STEP_MFU.labels(kind=kind).set(_sig(mfu))
         if hbm is not None:

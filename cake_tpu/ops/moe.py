@@ -1,81 +1,405 @@
-"""Sparse mixture-of-experts FFN (Mixtral-style) for decoder blocks.
+"""Sparse mixture-of-experts FFN: published routing, experts computed
+only where routed.
 
 The reference is dense-only (`mlp.rs:7-11` — SURVEY.md §2.6 lists expert
-parallelism as absent); this is a capability extension. Design is
-TPU-first:
+parallelism as absent); this is a capability extension, shared by the
+Mixtral and OLMoE families (models/moe). One layer, on N tokens:
 
-  * Routing is `lax.top_k` over router logits with softmax renormalised
-    over the selected experts (Mixtral semantics), producing a dense
-    [tokens, E] combine matrix — static shapes, no sorting/scatter, so the
-    whole thing jits and scans.
-  * Expert computation is batched einsum over the (possibly EP-sharded)
-    expert axis: every expert runs on every token and the combine matrix
-    zeroes the non-selected ones. For inference-sized token counts this
-    keeps the MXU busy with one big contraction instead of ragged gathers;
-    XLA shards the expert axis when the weights carry an `ep`
-    PartitionSpec.
-  * Under `shard_map` (the manual pipeline path), pass `ep_axis`: each
-    shard holds an [E/ep, ...] slice of the expert weights, computes its
-    local experts against its slice of the combine matrix, and `psum`s the
-    partial outputs over the axis — token dispatch rides ICI as a single
-    reduction instead of an all-to-all.
+  * `route`: float32 router logits, softmax over ALL experts, the k
+    largest probabilities and their indices; the weights are divided by
+    their sum only if the family says so (`norm_topk_prob`: Mixtral's
+    softmax over the top-k logits is exactly that; OLMoE keeps the raw
+    probabilities). Returns `(weights [N,k], experts [N,k])`.
+  * `dispatch_plan`: the N*k (token, expert) pairs sorted by expert.
+    Tokens outside `token_mask` (a mixed step's padded positions, idle
+    rows) and, under expert parallelism, pairs whose expert lives on
+    another shard fall into a null group behind the last expert and
+    take no expert's time. Shapes depend on N and k only, so a new
+    routing never compiles anything. A step with a thousand positions
+    or more (a mixed step) packs its real tokens to the front first
+    and, when they fill at most a quarter of the positions, dispatches
+    that quarter alone (`lax.cond`, both sides compiled once): the
+    sorts and row gathers have static shapes, and most of a mixed
+    step's positions are padding.
+  * `grouped_matmul` (`cake_moe_gmm`): one Pallas matmul per projection
+    over the sorted rows. The grid walks (row tile, expert) VISITS: a
+    tile of `tm` sorted rows that straddles two experts is visited once
+    for each and stores only that expert's rows, so work is proportional
+    to N*k plus at most one tile per expert, never to N*E. Weights are
+    read as stored: the kernel takes the layer's STACKED leaf
+    `[L, E, in, out]` and the layer index as a scalar-prefetch operand
+    (a scan-sliced operand of a custom call would be copied every
+    layer), int8 blocks are widened to the activation type in VMEM and
+    the per-channel scale multiplies the f32 accumulator.
+  * the combine gathers each token's k rows back and sums them under
+    the routing weights.
+
+Under `shard_map` pass `ep_axis`: each shard holds an `[E/ep, ...]` slice
+of the expert weights, computes the pairs routed to its experts and
+`psum`s the partial outputs over the axis (a shard_map with
+`check_vma=False`, as parallel/pipeline.py's are: the kernel's result
+carries no varying-axes annotation).
+
+Every call also returns the layer's counters (`MoEStats`), computed on
+the device from the same group sizes the kernel walks.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from cake_tpu.ops.quant import qeinsum
+from cake_tpu.ops import ragged_paged_attention as rpa
+from cake_tpu.ops.quant import QTensor, is_groupwise
+
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
 
-def route_top_k(x, router_w, k: int):
-    """Top-k routing combine matrix.
+class LayerOf(NamedTuple):
+    """A layer's expert weights as (the stacked leaf [L, E, in, out],
+    the layer index): what a layer loop hands `moe_mlp` instead of a
+    slice, so that the kernel indexes the stack where it lies."""
 
-    x:        [N, D] tokens
-    router_w: [D, E] router weights
-    returns   [N, E] float32: softmax weight for each selected expert,
-              zero elsewhere. Softmax is over the top-k logits only
-              (Mixtral renormalisation).
+    stacked: object
+    layer: jnp.ndarray
+
+
+class MoEStats(NamedTuple):
+    """One layer's counters, float32 scalars. rows: (token, expert)
+    pairs computed; rows_padded: rows the kernel's visits cover (tile
+    padding included); load_max / load_mean: tokens on the busiest
+    expert / on the average expert (of those on this shard); touched:
+    experts with at least one token, whose weights the step reads.
+    experts [N, k] int32 is the routing itself, for a tool that
+    compares it with a reference's (chip_compare.py); a step program
+    returns the counters alone and the compiler drops it."""
+
+    rows: jnp.ndarray
+    rows_padded: jnp.ndarray
+    load_max: jnp.ndarray
+    load_mean: jnp.ndarray
+    touched: jnp.ndarray
+    experts: jnp.ndarray
+
+
+def route(x, router_w, k: int, norm_topk_prob: bool):
+    """x [N, D], router_w [D, E] -> (weights [N, k] f32, experts [N, k]
+    int32). float32 logits whatever the activations' type; softmax over
+    all E experts; top-k of the probabilities; renormalised over the k
+    only if `norm_topk_prob`."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+# -- the sorted dispatch -------------------------------------------------------
+
+
+def row_tile(n_rows: int) -> int:
+    """Rows per kernel tile, from the static row count alone: a decode
+    step's few rows take the smallest tile both activation types tile
+    to, a mixed step's thousands fill the MXU's 128."""
+    return 128 if n_rows >= 1024 else 16
+
+
+class DispatchPlan(NamedTuple):
+    """The sorted order and the kernel's walk over it.
+
+    src_token [M]: the token each sorted row reads (M = N*k rounded up
+    to the tile); slot_of [N, k]: the sorted row of each pair (0 for a
+    dropped pair, whose weight is zeroed); valid [N, k]; counts [E];
+    visit_* [V]: the (row tile, expert, first row, one past the last
+    row) of each kernel visit, V = M/tm + E - 1, unused visits last
+    with an empty row range."""
+
+    src_token: jnp.ndarray
+    slot_of: jnp.ndarray
+    valid: jnp.ndarray
+    counts: jnp.ndarray
+    visit_tile: jnp.ndarray
+    visit_expert: jnp.ndarray
+    visit_lo: jnp.ndarray
+    visit_hi: jnp.ndarray
+    tm: int
+
+
+def dispatch_plan(experts, n_experts: int, valid=None,
+                  tm: Optional[int] = None) -> DispatchPlan:
+    """experts [N, k] int32 in [0, n_experts); valid [N, k] bool or
+    None. Pairs that are not valid sort behind every expert and are
+    neither gathered nor computed."""
+    N, k = experts.shape
+    E = n_experts
+    tm = tm or row_tile(N * k)
+    M = -(-(N * k) // tm) * tm
+    flat = experts.reshape(N * k)
+    if valid is not None:
+        flat = jnp.where(valid.reshape(N * k), flat, E)
+    flat = jnp.pad(flat, (0, M - N * k), constant_values=E)
+    # sorted row -> pair, and its inverse (a second sort: a permutation
+    # scatter is serial on the TPU)
+    pair_of = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    slot_of = jnp.argsort(pair_of).astype(jnp.int32)[:N * k]
+    counts = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
+                     dtype=jnp.int32)                        # [E]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    src_token = jnp.minimum(pair_of // k, N - 1)
+    ok = (flat < E)[:N * k].reshape(N, k)
+    slot_of = jnp.where(ok, slot_of.reshape(N, k), 0)
+
+    # the walk: expert e covers tiles starts[e]//tm .. (ends[e]-1)//tm
+    first = starts // tm
+    n_vis = jnp.where(counts > 0, (ends - 1) // tm - first + 1, 0)
+    vis_end = jnp.cumsum(n_vis)
+    vis_start = vis_end - n_vis
+    V = M // tm + E - 1
+    v = jnp.arange(V, dtype=jnp.int32)
+    e_of = jnp.sum(v[:, None] >= vis_end[None, :], axis=1,
+                   dtype=jnp.int32)                          # [V]
+    used = v < vis_end[-1]
+    e_of = jnp.minimum(e_of, E - 1)
+    tile = first[e_of] + (v - vis_start[e_of])
+    lo = jnp.maximum(starts[e_of], tile * tm)
+    hi = jnp.minimum(ends[e_of], (tile + 1) * tm)
+    # unused visits repeat the last used one's blocks (no DMA) with an
+    # empty row range (no compute, no store)
+    last = jnp.maximum(vis_end[-1] - 1, 0)
+    tile = jnp.where(used, tile, tile[last])
+    e_of = jnp.where(used, e_of, e_of[last])
+    lo = jnp.where(used, lo, 0)
+    hi = jnp.where(used, hi, 0)
+    return DispatchPlan(src_token, slot_of, ok, counts,
+                        tile.astype(jnp.int32), e_of.astype(jnp.int32),
+                        lo.astype(jnp.int32), hi.astype(jnp.int32), tm)
+
+
+def plan_stats(plan: DispatchPlan, experts) -> MoEStats:
+    counts = plan.counts.astype(jnp.float32)
+    visits = jnp.sum(plan.visit_hi > plan.visit_lo)
+    return MoEStats(rows=jnp.sum(counts),
+                    rows_padded=(visits * plan.tm).astype(jnp.float32),
+                    load_max=jnp.max(counts), load_mean=jnp.mean(counts),
+                    touched=jnp.sum(counts > 0).astype(jnp.float32),
+                    experts=experts)
+
+
+# -- the grouped matmul --------------------------------------------------------
+
+
+def _out_tile(n_out: int) -> int:
+    for t in (512, 256, 128):
+        if n_out % t == 0:
+            return t
+    return n_out
+
+
+def _gmm_kernel(layer_ref, tile_ref, expert_ref, lo_ref, hi_ref,
+                x_ref, w_ref, *rest, tm: int, scaled: bool):
+    del layer_ref, expert_ref
+    if scaled:
+        s_ref, o_ref = rest
+    else:
+        (o_ref,) = rest
+    v = pl.program_id(1)
+    lo, hi = lo_ref[v], hi_ref[v]
+
+    @pl.when(hi > lo)
+    def _():
+        x = x_ref[...]
+        w = w_ref[...].astype(x.dtype)
+        # bf16 operands pin DEFAULT precision (Mosaic refuses a bf16
+        # lhs under the test lane's process-wide `highest`; see
+        # ragged_paged_attention._dot)
+        acc = lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            precision=(lax.Precision.DEFAULT
+                       if x.dtype == jnp.bfloat16 else None),
+            preferred_element_type=jnp.float32)
+        if scaled:
+            acc = acc * s_ref[...]
+        rows = tile_ref[v] * tm + lax.broadcasted_iota(
+            jnp.int32, acc.shape, 0)
+        mine = (rows >= lo) & (rows < hi)
+        # a tile that straddles experts is visited once for each: keep
+        # what the earlier visits stored
+        o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), o_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def grouped_matmul(x, w, layer, visit_tile, visit_expert, visit_lo,
+                   visit_hi, *, tm: int, interpret: Optional[bool] = None):
+    """Rows [M, K] sorted by expert times the experts' weights.
+
+    w: the STACKED leaf [L, E, K, N] — an array in x's type, or a
+    per-channel QTensor (q int8 [L, E, K, N], scale f32 [L, E, N]);
+    layer: int32 scalar. Row r of the result is x[r] @ w[layer, e(r)]
+    for the rows inside some visit's [lo, hi); the rest are unspecified.
     """
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [N, E]
-    E = logits.shape[-1]
-    top_vals, top_idx = lax.top_k(logits, k)                       # [N, k]
-    weights = jax.nn.softmax(top_vals, axis=-1)                    # [N, k]
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)         # [N, k, E]
-    return jnp.einsum("nk,nke->ne", weights, onehot)
+    if interpret is None:
+        interpret = not rpa._on_tpu()
+    scaled = isinstance(w, QTensor)
+    if scaled and is_groupwise(w):
+        raise NotImplementedError(
+            "group-wise (int4) expert weights are not supported; "
+            "use --quant int8 for MoE models")
+    q = w.q if scaled else w
+    M, K = x.shape
+    _, _, Kw, N = q.shape
+    assert K == Kw and M % tm == 0, (x.shape, q.shape, tm)
+    tn = _out_tile(N)
+    V = visit_tile.shape[0]
+
+    def x_map(j, v, layer, tile, expert, lo, hi):
+        return tile[v], 0
+
+    def w_map(j, v, layer, tile, expert, lo, hi):   # the scales' too
+        return layer[0], expert[v], 0, j
+
+    def o_map(j, v, layer, tile, expert, lo, hi):
+        return tile[v], j
+
+    in_specs = [pl.BlockSpec((tm, K), x_map),
+                pl.BlockSpec((None, None, K, tn), w_map)]
+    operands = [x, q]
+    if scaled:
+        in_specs.append(pl.BlockSpec((None, None, 1, tn), w_map))
+        operands.append(w.scale[:, :, None, :])
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, scaled=scaled),
+        name="cake_moe_gmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(N // tn, V),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tm, tn), o_map)),
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), visit_tile, visit_expert,
+      visit_lo, visit_hi, *operands)
 
 
-def moe_mlp(lp, h, num_experts_per_tok: int,
-            ep_axis: Optional[str] = None):
-    """Sparse SwiGLU FFN over experts.
+# -- the layer -----------------------------------------------------------------
 
-    lp leaves: router [D, E]; we_gate/we_up [E_local, D, F];
-    we_down [E_local, F, D]. E_local == E except under shard_map EP, where
-    each shard holds its contiguous slice and `ep_axis` names the mesh axis.
-    Returns the *unreduced-over-tp* output: when F is additionally
-    Megatron-sharded the caller (block_skeleton) psums over tp, exactly as
-    for the dense path — EP and TP reductions compose.
+
+def _stacked(leaf):
+    """(stacked weights [L, E, in, out], layer) of an expert leaf given
+    as a LayerOf or as one layer's own slice."""
+    if isinstance(leaf, LayerOf):
+        return leaf.stacked, leaf.layer
+    return jax.tree.map(lambda a: a[None], leaf), jnp.int32(0)
+
+
+# A step with this many token positions or more (a mixed step: slots x
+# window) first packs its real tokens to the front and, when they fill
+# no more than a quarter of the positions, dispatches that quarter
+# alone: the sorts and the two row gathers are over static shapes, and
+# most of a mixed step's positions are padding.
+COMPACT_MIN_TOKENS = 1024
+COMPACT_SHARE = 4
+
+
+def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
+    """The routed SwiGLU on tokens x [N, D] -> (out [N, D] f32, plan).
+    weights/experts/valid: [N, k]; stacks: the three stacked leaves."""
+    N, D = x.shape
+    k = experts.shape[1]
+    w_gate, w_up, w_down = stacks
+    with jax.named_scope("moe_dispatch"):
+        plan = dispatch_plan(experts, e_local, valid)
+        xs = jnp.take(x, plan.src_token, axis=0)             # [M, D]
+    with jax.named_scope("experts"):
+        walk = (plan.visit_tile, plan.visit_expert, plan.visit_lo,
+                plan.visit_hi)
+        gate = grouped_matmul(xs, w_gate, layer, *walk, tm=plan.tm)
+        up = grouped_matmul(xs, w_up, layer, *walk, tm=plan.tm)
+        act = jax.nn.silu(gate) * up
+        ys = grouped_matmul(act, w_down, layer, *walk, tm=plan.tm)
+    with jax.named_scope("moe_combine"):
+        picked = jnp.take(ys, plan.slot_of.reshape(N * k), axis=0)
+        wk = jnp.where(plan.valid, weights, 0.0)             # [N, k]
+        # a dropped pair reads row 0, which nothing may have written:
+        # select, never multiply, what is not valid
+        picked = jnp.where(plan.valid.reshape(N * k, 1), picked, 0)
+        out = jnp.einsum("nkd,nk->nd",
+                         picked.reshape(N, k, D).astype(jnp.float32), wk)
+    return out, plan
+
+
+def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
+            ep_axis: Optional[str] = None, token_mask=None):
+    """Sparse SwiGLU FFN over experts -> (out [B, S, D], MoEStats).
+
+    lp leaves: router [D, E]; we_gate/we_up [E_local, D, F]; we_down
+    [E_local, F, D], each an array, a per-channel QTensor, or a LayerOf
+    around the stacked leaf. E_local == E except under shard_map EP,
+    where each shard holds its contiguous slice and `ep_axis` names the
+    mesh axis. token_mask [B, S] bool: positions that are not real
+    (padding of a mixed window, idle rows) are not routed and come back
+    zero. Returns the *unreduced-over-tp* output: when F is additionally
+    Megatron-sharded the caller (block_skeleton) psums over tp, exactly
+    as for the dense path — EP and TP reductions compose.
     """
     B, S, D = h.shape
-    x = h.reshape(B * S, D)
-    combine = route_top_k(x, lp["router"], num_experts_per_tok)    # [N, E]
+    N, k = B * S, num_experts_per_tok
+    x = h.reshape(N, D)
+    with jax.named_scope("router"):
+        weights, experts = route(x, lp["router"], k, norm_topk_prob)
+        routed = experts
 
-    e_local = lp["we_gate"].shape[0]
+    w_gate, layer = _stacked(lp["we_gate"])
+    stacks = (w_gate, _stacked(lp["we_up"])[0], _stacked(lp["we_down"])[0])
+    e_local = w_gate.shape[1]
+    mask = None if token_mask is None else token_mask.reshape(N)
+    valid = None if mask is None else jnp.broadcast_to(mask[:, None], (N, k))
     if ep_axis is not None:
-        offset = lax.axis_index(ep_axis) * e_local
-        combine = lax.dynamic_slice_in_dim(combine, offset, e_local, axis=1)
+        experts = experts - lax.axis_index(ep_axis) * e_local
+        here = (experts >= 0) & (experts < e_local)
+        valid = here if valid is None else valid & here
+        experts = jnp.clip(experts, 0, e_local - 1)
 
-    # [N, E_local, F]: all (local) experts on all tokens; combine masks.
-    gate = qeinsum("nd,edf->nef", x, lp["we_gate"])
-    up = qeinsum("nd,edf->nef", x, lp["we_up"])
-    act = jax.nn.silu(gate) * up
-    per_expert = qeinsum("nef,efd->ned", act, lp["we_down"])       # [N, E, D]
-    out = jnp.einsum("ned,ne->nd", per_expert,
-                     combine.astype(per_expert.dtype))
+    def whole(_):
+        out, plan = _experts_ffn(x, weights, experts, valid, stacks, layer,
+                                 e_local)
+        return out, plan_stats(plan, routed)
+
+    def packed(_):
+        # real tokens first; the first quarter of the positions holds
+        # them all
+        with jax.named_scope("moe_dispatch"):
+            front = jnp.argsort(~mask, stable=True).astype(jnp.int32)
+            place = jnp.argsort(front).astype(jnp.int32)     # token -> rank
+            head = front[:N // COMPACT_SHARE]
+
+        def pick(a):
+            return jnp.take(a, head, axis=0)
+
+        out, plan = _experts_ffn(pick(x), pick(weights), pick(experts),
+                                 pick(valid), stacks, layer, e_local)
+        with jax.named_scope("moe_combine"):
+            out = jnp.take(out, jnp.minimum(place, head.shape[0] - 1),
+                           axis=0)
+        return out, plan_stats(plan, routed)
+
+    if mask is not None and N >= COMPACT_MIN_TOKENS:
+        out, stats = lax.cond(jnp.sum(mask) <= N // COMPACT_SHARE,
+                              packed, whole, None)
+    else:
+        out, stats = whole(None)
+    if mask is not None:
+        out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:
         out = lax.psum(out, ep_axis)
-    return out.reshape(B, S, D).astype(h.dtype)
+    return out.reshape(B, S, D).astype(h.dtype), stats

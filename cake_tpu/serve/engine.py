@@ -870,6 +870,11 @@ class InferenceEngine:
         # latest dispatch's _JitStep (engine-thread-only mailbox between
         # the device-call seam and the step record that follows it)
         self._last_jit = None
+        # a sparse model's step programs return their expert counters;
+        # they wait here, on the device, for the next sampled-token
+        # fetch, and then on the host for the next step record
+        self._moe_pending: list = []
+        self._moe_fetched: list = []
         # distributed-trace annotation: events published with a rid
         # pick up the request's x-cake-trace id from the tracer, so
         # the front-door router's federated timeline can select this
@@ -4601,7 +4606,8 @@ class InferenceEngine:
             js = self._obs_jit("mixed_step", (C,), self._mixed_step_fn,
                                fargs)
             t0d = time.perf_counter()
-            logits, self.cache = self._mixed_step_fn(*fargs)
+            logits, self.cache, *moe = self._mixed_step_fn(*fargs)
+            self._moe_pending += moe
             js.finish(time.perf_counter() - t0d)
             self._last_jit = js
         emit_rows = decode_rows + finished
@@ -4642,7 +4648,8 @@ class InferenceEngine:
             device_s=self.flight.open_phase("fetch"),
             rows_decode=len(decode_rows), rows_prefill=len(chunk_rows),
             rows_idle=B - len(decode_rows) - len(chunk_rows),
-            rids=[r for r, _s in self._implicated])
+            rids=[r for r, _s in self._implicated],
+            moe=self._take_moe())
 
         def _top(slot):
             return (list(zip(tids[slot].tolist(), tlps[slot].tolist()))
@@ -5408,7 +5415,8 @@ class InferenceEngine:
                           tokens=len(decode_plan), wall_s=dt,
                           dispatch_s=self.flight.open_phase("dispatch"),
                           device_s=self.flight.open_phase("fetch"),
-                          rids=[r for r, _s in decode_plan])
+                          rids=[r for r, _s in decode_plan],
+                          moe=self._take_moe())
         with self.flight.span("emit"):
             for rid, slot in decode_plan:
                 req = self._slot_req[slot]
@@ -5437,7 +5445,8 @@ class InferenceEngine:
             js = self._obs_jit("decode_step", (), self._decode_step,
                                fargs)
             t0 = time.perf_counter()
-            logits, self.cache = self._decode_step(*fargs)
+            logits, self.cache, *moe = self._decode_step(*fargs)
+            self._moe_pending += moe
             js.finish(time.perf_counter() - t0)
             self._last_jit = js
         if self._multihost:
@@ -5742,10 +5751,22 @@ class InferenceEngine:
         if defer:
             return dev
         # one batched fetch, not four sequential round-trips (see
-        # _decode_scan_device): the host waiting for the device
+        # _decode_scan_device): the host waiting for the device. A
+        # sparse model's expert counters ride the same fetch.
+        moe, self._moe_pending = self._moe_pending, []
         with self.flight.span("fetch"):
-            host = jax.device_get(dev)
+            *host, moe = jax.device_get(dev + (moe,))
+        self._moe_fetched += moe
         return self._sample_complete(rows, host)
+
+    def _take_moe(self):
+        """The expert counters (in the order of obs/steps.MOE_COUNTERS)
+        of the step programs fetched since the last record, summed;
+        None for a dense model or when nothing was fetched (a mixed
+        step that sampled no row leaves its counters for the next
+        step's fetch)."""
+        got, self._moe_fetched = self._moe_fetched, []
+        return np.sum(got, axis=0) if got else None
 
     def _sample_complete(self, rows: List[int], host) -> tuple:
         """Host half of _sample_rows: advance the sampled rows' step and

@@ -191,12 +191,22 @@ def _kernel_calls():
         "cake_int4_matmul": lambda: i4.int4_matmul(
             jnp.zeros((8, 64), jnp.float32), packed,
             jnp.ones((64 // g, 128), jnp.float32), g=g, interpret=True),
+        "cake_moe_gmm": _moe_gmm_call,
     }
+
+
+def _moe_gmm_call():
+    from cake_tpu.ops import moe
+    plan = moe.dispatch_plan(jnp.zeros((16, 2), jnp.int32), 4, tm=16)
+    return moe.grouped_matmul(
+        jnp.zeros((32, 8), jnp.float32), jnp.zeros((1, 4, 8, 128)),
+        jnp.int32(0), plan.visit_tile, plan.visit_expert, plan.visit_lo,
+        plan.visit_hi, tm=16, interpret=True)
 
 
 @pytest.mark.parametrize("name", [
     "cake_decode_attn", "cake_mixed_attn", "cake_flash_prefill",
-    "cake_flash_prefill_cached", "cake_int4_matmul"])
+    "cake_flash_prefill_cached", "cake_int4_matmul", "cake_moe_gmm"])
 def test_pallas_calls_carry_their_names(name):
     """A kernel event is recognised by name, not by the rank of its
     result: every pl.pallas_call in ops/ passes name=."""
@@ -217,7 +227,7 @@ def test_every_pallas_call_site_is_named():
             # the call's own argument list, up to the operands' call
             named += 'name="cake_' in text[m.end():m.end() + 1200].split(
                 ")(")[0]
-    assert sites == named == 5
+    assert sites == named == 6
 
 
 def test_scopes_leave_the_compiled_program_unchanged(monkeypatch):
