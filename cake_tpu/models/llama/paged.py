@@ -470,6 +470,24 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None])
 
 
+@_partial(jax.jit, static_argnames=("packed4", "interpret"))
+def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
+                  interpret: bool, scale_k=None, scale_v=None,
+                  packed4: bool = False):
+    """The mixed kernel behind a jit of its own. The mixed step's
+    programs of every packed size call it on the same window shapes,
+    and a jitted callee is traced once for all of them: the kernel's
+    body is a third of what a program costs the host to trace
+    (PERF.md §6, PR 27). `interpret` is resolved by the caller, so
+    that it is part of the trace's key."""
+    from cake_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention_mixed,
+    )
+    return ragged_paged_attention_mixed(
+        q, pool_k, pool_v, layer, table, pos, q_len, scale_k=scale_k,
+        scale_v=scale_v, packed4=packed4, interpret=interpret)
+
+
 def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
                           impl: str = "fold"):
     """Mixed ragged attention over layer `layer` of the paged KV: decode
@@ -492,12 +510,10 @@ def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
     padding whose output the caller never reads. Returns [B, C, H, hd].
     """
     if impl == "pallas":
-        from cake_tpu.ops.ragged_paged_attention import (
-            ragged_paged_attention_mixed,
-        )
+        from cake_tpu.ops import ragged_paged_attention as rpa
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
-        return ragged_paged_attention_mixed(q, kq, vq, layer, table, pos,
-                                            q_len, **kw)
+        return _mixed_kernel(q, kq, vq, layer, table, pos, q_len,
+                             interpret=not rpa._on_tpu(), **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
     # per-query causality: query i of row b sits at pos[b] + i
@@ -908,64 +924,189 @@ def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
 # -- token-level continuous batching: the mixed ragged step -------------------
 
 
+class PackPlan(NamedTuple):
+    """Where a mixed step's real tokens sit on the packed token axis
+    [T]: rows in slot order, a row's q_len tokens contiguous from
+    start[b]. Derived inside the program from q_len and active alone.
+
+    start [B]: a row's first packed index (an idle row's is its
+    successor's); row/col [T]: the window cell (b, i) a packed position
+    holds; real [T]: positions below the step's real count (the
+    bucket's padding past it repeats one cell of the last row and is
+    never written, routed or read); width: the windows' static C."""
+
+    start: jnp.ndarray
+    row: jnp.ndarray
+    col: jnp.ndarray
+    real: jnp.ndarray
+    width: int
+
+
+def mixed_token_buckets(slots: int, width: int) -> tuple:
+    """The static sizes T of the packed mixed step for an engine of
+    `slots` rows and `width`-token windows, ascending: what one
+    prefilling row and what two need beside decode rows in every other
+    slot (p*width + slots - p tokens, rounded up to 16 positions). The
+    last is the most one dispatch computes: a step that holds more is
+    run in several (serve/engine._mixed_dispatch), two prefilling rows
+    at a time.
+
+    Why two sizes and not a ladder up to slots*width. Under chat
+    traffic two steps in three have one prefilling row and one in four
+    has two; more come only when every client starts at once. And each
+    further size is a program whose matmuls and reductions XLA:TPU
+    tiles by its shape: a row's logits then depend on the company its
+    step had, by enough to swap a sparse model's experts, while these
+    two sizes gave every row the same bits (PERF.md §6, PR 27). Each
+    size also costs the start-up a trace and a load."""
+    full = slots * width
+    return tuple(sorted({min(full, -(-(p * width + slots - p) // 16) * 16)
+                         for p in (1, 2)}))
+
+
+def mixed_bucket_for(buckets: tuple, n_real: int) -> int:
+    """The `n_tokens` one dispatch of n_real <= buckets[-1] tokens runs
+    at: the smallest size that holds them."""
+    return next(t for t in buckets if n_real <= t)
+
+
+def pack_plan(q_len, active, n_tokens: int, width: int) -> PackPlan:
+    """The PackPlan of a step on an axis of n_tokens positions; q_len,
+    active: [B] (an inactive row holds no token whatever its q_len)."""
+    B = q_len.shape[0]
+    n = jnp.where(active, q_len, 0).astype(jnp.int32)
+    end = jnp.cumsum(n)
+    start = end - n
+    t = jnp.arange(n_tokens, dtype=jnp.int32)
+    row = jnp.minimum(
+        jnp.sum(t[:, None] >= end[None, :], axis=1, dtype=jnp.int32), B - 1)
+    real = t < end[-1]
+    return PackPlan(start, row, jnp.where(real, t - start[row], 0), real,
+                    width)
+
+
+def _unpack_windows(x, plan: PackPlan):
+    """Packed [T, ...] -> windows [B, C, ...]: one contiguous slice a
+    row, x padded by a window so that no slice clamps. The columns past
+    a row's q_len hold its neighbours' tokens, which the consumer masks
+    as it masks a window's padding."""
+    x = jnp.pad(x, ((0, plan.width),) + ((0, 0),) * (x.ndim - 1))
+    return jnp.stack([
+        lax.dynamic_slice_in_dim(x, plan.start[b], plan.width, axis=0)
+        for b in range(plan.start.shape[0])])
+
+
+@jax.named_scope("kv")
+def write_packed_pages(pool_k, pool_v, layer, k, v, plan: PackPlan, pos,
+                       q_len, active, table):
+    """write_windows_pages from the packed axis. k/v: [T, KV, hd]. T
+    row writes a layer: packed position t lands at (table[row, p // P],
+    p % P) with p = pos[row] + col; the bucket's padding and positions
+    on unmapped pages route to the out-of-bounds index and drop.
+
+    A QuantPool's writer read-modify-writes whole pages a row, so it
+    takes the windows: k and v are unpacked as q is."""
+    if isinstance(pool_k, (QuantPool, Int4Pool)):
+        return write_windows_pages(
+            pool_k, pool_v, layer, _unpack_windows(k, plan),
+            _unpack_windows(v, plan), pos, q_len, active, table)
+    N, P = pool_k.shape[1], pool_k.shape[2]
+    max_pages = table.shape[1]
+    positions = pos[plan.row] + plan.col
+    pidx = positions // P
+    pages = table[plan.row, jnp.minimum(pidx, max_pages - 1)]
+    valid = plan.real & (pidx < max_pages) & (pages >= 0)
+    idx = jnp.where(valid, pages, N)
+    offs = positions % P
+    pk = pool_k.at[layer, idx, offs].set(
+        _rows(k).astype(pool_k.dtype), mode="drop")
+    pv = pool_v.at[layer, idx, offs].set(
+        _rows(v).astype(pool_v.dtype), mode="drop")
+    return pk, pv
+
+
 def run_blocks_mixed_paged(blocks, x, cache: PagedKVCache, pos, q_len,
                            active, rope_c, rope_s, config: LlamaConfig,
-                           attn: str = "fold"):
+                           attn: str = "fold",
+                           plan: Optional[PackPlan] = None):
     """run_blocks over a MIXED batch of per-row windows: write each
     row's window into its pages, attend everything written through the
-    table. x: [B, C, D]; pos/q_len/active: [B]; rope_c/rope_s:
-    [B, C, hd//2] per-row per-column tables; attn: paged_attention_mixed
-    impl ({fold,pallas} — static under jit)."""
+    table. pos/q_len/active: [B]; attn: paged_attention_mixed impl
+    ({fold,pallas} — static under jit).
+
+    plan None: x [B, C, D], rope_c/rope_s [B, C, hd//2] per-row
+    per-column tables. With a plan every layer runs over the packed
+    axis, x [1, T, D] and rope rows [1, T, hd//2]: K/V are written from
+    the packed rows, and q alone is unpacked to the windows the kernel
+    takes, [B, C, H, hd], and its result gathered back (T rows)."""
     from cake_tpu.ops.rope import apply_rope
 
     def layer_attn(layer, pk, pv, q, k, v):
         q = apply_rope(q, rope_c, rope_s)
         k = apply_rope(k, rope_c, rope_s)
-        pk, pv = write_windows_pages(pk, pv, layer, k, v, pos, q_len,
-                                     active, cache.table)
-        return (paged_attention_mixed(q, pk, pv, layer, cache.table, pos,
-                                      q_len, impl=attn), pk, pv)
+        if plan is None:
+            pk, pv = write_windows_pages(pk, pv, layer, k, v, pos, q_len,
+                                         active, cache.table)
+            return (paged_attention_mixed(q, pk, pv, layer, cache.table,
+                                          pos, q_len, impl=attn), pk, pv)
+        pk, pv = write_packed_pages(pk, pv, layer, k[0], v[0], plan, pos,
+                                    q_len, active, cache.table)
+        out = paged_attention_mixed(
+            _unpack_windows(q[0], plan), pk, pv, layer, cache.table, pos,
+            q_len, impl=attn)
+        out = jnp.take(out.reshape((-1,) + out.shape[2:]),
+                       plan.row * plan.width + plan.col, axis=0)
+        return out[None], pk, pv
 
-    # the window's padding and idle rows hold no token: not routed
-    C = x.shape[1]
-    real = (jnp.arange(C)[None, :] < q_len[:, None]) & active[:, None]
+    # a window's padding, idle rows and a bucket's padding hold no
+    # token: not routed
+    if plan is None:
+        C = x.shape[1]
+        real = (jnp.arange(C)[None, :] < q_len[:, None]) & active[:, None]
+    else:
+        real = plan.real[None, :]
     return scan_layers_paged_stats(blocks, x, cache, config, layer_attn,
                                    token_mask=real)
 
 
 def _mixed_windows_trunk(params, tokens, pos, q_len, active,
                          cache: PagedKVCache, rope,
-                         config: LlamaConfig, attn: str):
-    """Shared body of the mixed ragged step: embed, per-row per-column
-    rope, run_blocks_mixed_paged, final norm. mixed_step_paged reads
-    one position from the normed hidden states, the speculative verify
-    (verify_window_paged) reads all of them — the window math exists
-    once so the two callers cannot drift."""
+                         config: LlamaConfig, attn: str,
+                         plan: Optional[PackPlan] = None):
+    """Shared body of the mixed ragged step: embed, per-token rope,
+    run_blocks_mixed_paged, final norm. mixed_step_paged reads one
+    position a row from the normed hidden states, the speculative
+    verify (verify_window_paged) reads all of them — the window math
+    exists once so the two callers cannot drift. Returns the hidden
+    states as the layers ran: [B, C, D], or [1, T, D] under a plan."""
     from cake_tpu.ops.norms import rms_norm
 
     C = tokens.shape[1]
+    # query i of row b sits at absolute position pos[b] + i (clamped
+    # into the table for padding — its values are garbage nothing reads)
+    if plan is None:
+        pos_grid = pos[:, None] + jnp.arange(C)[None, :]
+    else:
+        tokens = tokens[plan.row, plan.col][None]
+        pos_grid = (pos[plan.row] + plan.col)[None]
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
-    # per-row per-column rope rows: query i of row b sits at absolute
-    # position pos[b] + i (clamped into the table for padding columns
-    # past the window — their values are garbage nothing reads)
-    T = rope.cos.shape[0]
-    pos_grid = jnp.minimum(pos[:, None] + jnp.arange(C)[None, :], T - 1)
-    rope_c = jnp.take(rope.cos, pos_grid, axis=0)     # [B, C, hd//2]
+    pos_grid = jnp.minimum(pos_grid, rope.cos.shape[0] - 1)
+    rope_c = jnp.take(rope.cos, pos_grid, axis=0)   # [B, C | 1, T, hd//2]
     rope_s = jnp.take(rope.sin, pos_grid, axis=0)
     x, cache, stats = run_blocks_mixed_paged(
         params["blocks"], x, cache, pos, q_len, active, rope_c, rope_s,
-        config, attn=attn)
+        config, attn=attn, plan=plan)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
     return x, cache, stats
 
 
-@_partial(jax.jit, static_argnames=("config", "attn"),
+@_partial(jax.jit, static_argnames=("config", "attn", "n_tokens"),
           donate_argnames=("cache",))
 def mixed_step_paged(params, tokens, pos, q_len, active,
                      cache: PagedKVCache, rope, config: LlamaConfig,
-                     attn: str = "fold"):
+                     attn: str = "fold", n_tokens: Optional[int] = None):
     """ONE jitted step over a mixed batch of row descriptors — the
     token-level continuous-batching step that collapses the
     prefill_slot_paged / prefill_slot_paged_chunk /
@@ -989,18 +1130,32 @@ def mixed_step_paged(params, tokens, pos, q_len, active,
     and mid-prompt rows' logits are simply not consumed. attn selects
     the paged_attention_mixed impl ({fold,pallas}); fold is the
     bit-exact reference for the mixed step exactly as it is for decode.
+
+    n_tokens (static): None runs every layer over all B*C window
+    positions. A size T >= the step's real token count (the caller's
+    to guarantee: sum of q_len over the active rows) packs those tokens
+    out of their windows first and runs embed, norms, projections, FFN
+    and KV writes over [1, T, D]; the attention kernel still takes q as
+    [B, C, H, hd] windows (run_blocks_mixed_paged). Same tokens, same
+    mathematics; how XLA tiles a matmul or a reduction, and so how it
+    rounds, may differ with T (mixed_token_buckets says what followed).
     """
     from cake_tpu.ops.quant import qmatmul
 
-    B = tokens.shape[0]
+    B, C = tokens.shape
+    plan = (None if n_tokens is None
+            else pack_plan(q_len, active, n_tokens, C))
     x, cache, stats = _mixed_windows_trunk(params, tokens, pos, q_len,
                                            active, cache, rope, config,
-                                           attn)
+                                           attn, plan)
     with jax.named_scope("head"):
-        last = jnp.take_along_axis(
-            x,
-            (jnp.maximum(q_len, 1) - 1).reshape(B, 1, 1).astype(jnp.int32),
-            axis=1)[:, 0]
+        last = (jnp.maximum(q_len, 1) - 1).astype(jnp.int32)
+        if plan is None:
+            last = jnp.take_along_axis(x, last.reshape(B, 1, 1),
+                                       axis=1)[:, 0]
+        else:
+            last = jnp.take(
+                x[0], jnp.minimum(plan.start + last, n_tokens - 1), axis=0)
         logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
     return _step_result(logits, cache, stats)
 

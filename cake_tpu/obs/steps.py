@@ -154,6 +154,15 @@ _MIXED_ROWS = _m.counter(
     "kind (decode = one-token decode rows, prefill = prefill-chunk "
     "rows, idle = empty slots in the launch)",
     labelnames=("kind",))
+_MIXED_TOKENS = _m.counter(
+    "cake_mixed_tokens_total",
+    "Tokens the mixed steps held: one a decode row, a window's real "
+    "tokens a prefill-chunk row")
+_MIXED_TOKENS_COMPUTED = _m.counter(
+    "cake_mixed_tokens_computed_total",
+    "Token positions the mixed steps' layers ran over: the packed sizes "
+    "of each step's dispatches (over cake_mixed_tokens_total: how well "
+    "the sizes fit the traffic)")
 # sparse-expert counters, computed in the step program from the group
 # sizes its grouped matmuls walk (ops/moe.MoEStats, summed or averaged
 # over the layers by paged.scan_layers_paged_stats) and fetched with the
@@ -409,6 +418,10 @@ class StepRecord:
     rows_decode: Optional[int] = None
     rows_prefill: Optional[int] = None
     rows_idle: Optional[int] = None
+    # a mixed step's tokens, and the positions its layers ran over (the
+    # packed sizes of its dispatches, summed)
+    tokens_real: Optional[int] = None
+    tokens_computed: Optional[int] = None
     # rids whose rows this step's dispatched batch contained (bounded
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
@@ -451,6 +464,9 @@ class StepRecord:
             out["rows_decode"] = self.rows_decode
             out["rows_prefill"] = self.rows_prefill
             out["rows_idle"] = self.rows_idle
+        if self.tokens_computed is not None:
+            out["tokens_real"] = self.tokens_real
+            out["tokens_computed"] = self.tokens_computed
         if self.rids is not None:
             out["rids"] = list(self.rids)
         if self.phases:
@@ -631,6 +647,8 @@ class StepTelemetry:
                rows_decode: Optional[int] = None,
                rows_prefill: Optional[int] = None,
                rows_idle: Optional[int] = None,
+               tokens_real: Optional[int] = None,
+               tokens_computed: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None) -> StepRecord:
@@ -639,8 +657,10 @@ class StepTelemetry:
         timings may be given; missing ones fall back to the others.
         rows_decode/rows_prefill/rows_idle carry a mixed step's
         occupancy split and feed the cake_mixed_step_rows_total
-        counters. rids: the requests whose rows rode this dispatch
-        (the per-request explain's step linkage). impl: the attention
+        counters; tokens_real/tokens_computed its tokens and the
+        positions its layers ran over (cake_mixed_tokens_total,
+        cake_mixed_tokens_computed_total). rids: the requests whose
+        rows rode this dispatch (the per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
         step program's sparse-expert counters (StepRecord.moe)."""
@@ -671,6 +691,7 @@ class StepTelemetry:
                 compiled=bool(compiled),
                 rows_decode=rows_decode, rows_prefill=rows_prefill,
                 rows_idle=rows_idle,
+                tokens_real=tokens_real, tokens_computed=tokens_computed,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap,
@@ -684,6 +705,9 @@ class StepTelemetry:
                      ("idle", rows_idle)):
             if v:
                 _MIXED_ROWS.labels(kind=k).inc(v)
+        if tokens_computed is not None:
+            _MIXED_TOKENS.inc(tokens_real)
+            _MIXED_TOKENS_COMPUTED.inc(tokens_computed)
         if moe is not None:
             for (_key, series), v in zip(MOE_COUNTERS, rec.moe):
                 series.inc(v)

@@ -15,12 +15,9 @@ Mixtral and OLMoE families (models/moe). One layer, on N tokens:
     rows) and, under expert parallelism, pairs whose expert lives on
     another shard fall into a null group behind the last expert and
     take no expert's time. Shapes depend on N and k only, so a new
-    routing never compiles anything. A step with a thousand positions
-    or more (a mixed step) packs its real tokens to the front first
-    and, when they fill at most a quarter of the positions, dispatches
-    that quarter alone (`lax.cond`, both sides compiled once): the
-    sorts and row gathers have static shapes, and most of a mixed
-    step's positions are padding.
+    routing never compiles anything; the sorts and the row gathers run
+    over all N*k pairs, so a caller with many empty positions packs its
+    tokens first (the mixed step does: paged.pack_plan).
   * `grouped_matmul` (`cake_moe_gmm`): one Pallas matmul per projection
     over the sorted rows. The grid walks (row tile, expert) VISITS: a
     tile of `tm` sorted rows that straddles two experts is visited once
@@ -302,15 +299,6 @@ def _stacked(leaf):
     return jax.tree.map(lambda a: a[None], leaf), jnp.int32(0)
 
 
-# A step with this many token positions or more (a mixed step: slots x
-# window) first packs its real tokens to the front and, when they fill
-# no more than a quarter of the positions, dispatches that quarter
-# alone: the sorts and the two row gathers are over static shapes, and
-# most of a mixed step's positions are padding.
-COMPACT_MIN_TOKENS = 1024
-COMPACT_SHARE = 4
-
-
 def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
     """The routed SwiGLU on tokens x [N, D] -> (out [N, D] f32, plan).
     weights/experts/valid: [N, k]; stacks: the three stacked leaves."""
@@ -370,34 +358,9 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
         valid = here if valid is None else valid & here
         experts = jnp.clip(experts, 0, e_local - 1)
 
-    def whole(_):
-        out, plan = _experts_ffn(x, weights, experts, valid, stacks, layer,
-                                 e_local)
-        return out, plan_stats(plan, routed)
-
-    def packed(_):
-        # real tokens first; the first quarter of the positions holds
-        # them all
-        with jax.named_scope("moe_dispatch"):
-            front = jnp.argsort(~mask, stable=True).astype(jnp.int32)
-            place = jnp.argsort(front).astype(jnp.int32)     # token -> rank
-            head = front[:N // COMPACT_SHARE]
-
-        def pick(a):
-            return jnp.take(a, head, axis=0)
-
-        out, plan = _experts_ffn(pick(x), pick(weights), pick(experts),
-                                 pick(valid), stacks, layer, e_local)
-        with jax.named_scope("moe_combine"):
-            out = jnp.take(out, jnp.minimum(place, head.shape[0] - 1),
-                           axis=0)
-        return out, plan_stats(plan, routed)
-
-    if mask is not None and N >= COMPACT_MIN_TOKENS:
-        out, stats = lax.cond(jnp.sum(mask) <= N // COMPACT_SHARE,
-                              packed, whole, None)
-    else:
-        out, stats = whole(None)
+    out, plan = _experts_ffn(x, weights, experts, valid, stacks, layer,
+                             e_local)
+    stats = plan_stats(plan, routed)
     if mask is not None:
         out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:

@@ -53,6 +53,7 @@ from cake_tpu.models.llama.generator import (
 from cake_tpu.models.llama.model import (
     RopeTables, decode_step_ragged, prefill_slot, prefill_slot_prefixed,
 )
+from cake_tpu.models.llama.paged import mixed_bucket_for
 from cake_tpu.ops.sampling import (
     SamplingConfig, sample_tokens_ragged, update_ring_per_row,
 )
@@ -1067,6 +1068,8 @@ class InferenceEngine:
     def start(self) -> "InferenceEngine":
         if self._thread is None:
             from cake_tpu.utils.profiling import log_memory
+            if self._mixed:
+                self._warm_mixed_buckets()
             log_memory("engine start")
             self._thread = threading.Thread(target=self._run, daemon=True,
                                             name="cake-engine")
@@ -2601,7 +2604,7 @@ class InferenceEngine:
         self.prefill_chunk already set."""
         from cake_tpu.models.llama.paged import (
             PageAllocator, PagedKVCache, decode_step_ragged_paged,
-            mixed_step_paged, prefill_prefix_pages,
+            mixed_step_paged, mixed_token_buckets, prefill_prefix_pages,
             prefill_slot_paged, prefill_slot_paged_chunk,
             prefill_slot_paged_prefixed,
         )
@@ -2644,6 +2647,13 @@ class InferenceEngine:
         # decode rows and prefill-chunk rows in the same launch
         self._mixed_step_fn = partial(mixed_step_paged,
                                       attn=self.attn_impl["mixed"])
+        # the packed sizes a mixed step's dispatches run at (the
+        # program's static n_tokens): _mixed_dispatch takes the smallest
+        # that holds the tokens, start() runs each once so that none
+        # compiles later
+        self._mixed_buckets = (
+            mixed_token_buckets(self.max_slots, self._mixed_chunk)
+            if self._mixed else ())
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -4550,6 +4560,61 @@ class InferenceEngine:
         self._pos[slot] = off
         self._mixed_pending[slot] = {"req": req, "ids": ids, "off": off}
 
+    def _run_mixed_step(self, tokens, pos, qlen, active,
+                        n_tokens: int) -> list:
+        """Dispatch the mixed step program of size n_tokens
+        (paged.mixed_step_paged) on host arrays through the compile
+        accountant; the cache is donated and replaced. Returns [logits,
+        and a sparse model's expert counters]."""
+        fargs = (self.params, jnp.asarray(tokens, jnp.int32),
+                 jnp.asarray(pos, jnp.int32), jnp.asarray(qlen, jnp.int32),
+                 jnp.asarray(active), self.cache, self.rope, self.config)
+        kw = {"n_tokens": n_tokens}
+        js = self._obs_jit("mixed_step", (tokens.shape[1], n_tokens),
+                           self._mixed_step_fn, fargs, kw)
+        t0 = time.perf_counter()
+        logits, self.cache, *moe = self._mixed_step_fn(*fargs, **kw)
+        js.finish(time.perf_counter() - t0)
+        # a step of several dispatches compiled if any of them did
+        js.new |= self._last_jit is not None and self._last_jit.new
+        self._last_jit = js
+        return [logits, *moe]
+
+    def _mixed_groups(self, qlen) -> List[np.ndarray]:
+        """The rows of a mixed step ([B] bool masks) by dispatch: slot
+        order, as many as the largest packed size holds. One group
+        unless three rows or more prefill at once."""
+        budget = self._mixed_buckets[-1]
+        groups, used = [np.zeros(len(qlen), bool)], 0
+        for slot in np.flatnonzero(qlen):
+            if used + qlen[slot] > budget:
+                groups.append(np.zeros(len(qlen), bool))
+                used = 0
+            groups[-1][slot] = True
+            used += int(qlen[slot])
+        return groups
+
+    def _warm_mixed_buckets(self) -> None:
+        """Run the mixed step once at every packed size with all rows
+        idle (an idle row touches neither pages nor output), so that
+        whatever token counts arrive later, no step compiles or loads a
+        program: the traffic's own warm-up cannot be relied on to touch
+        every size."""
+        B, C = self.max_slots, self._mixed_chunk
+        idle = (np.zeros((B, C), np.int64), np.zeros(B, np.int64),
+                np.zeros(B, np.int64), np.zeros(B, bool))
+        marks = [time.perf_counter()]
+        for bucket in self._mixed_buckets:
+            out = self._run_mixed_step(*idle, bucket)
+            marks.append(time.perf_counter())
+        jax.block_until_ready(out)
+        self._last_jit = None
+        log.info("mixed step: sizes %s ready in %.2f s (traced and "
+                 "loaded in %s s, then %.2f s for the device)",
+                 self._mixed_buckets, time.perf_counter() - marks[0],
+                 " + ".join(f"{b - a:.2f}" for a, b in zip(marks, marks[1:])),
+                 time.perf_counter() - marks[-1])
+
     def _mixed_dispatch(self, decode_plan) -> None:
         """Build and run ONE mixed step: every decode row contributes
         its last token (q_len=1), every mid-prefill slot its next
@@ -4598,18 +4663,27 @@ class InferenceEngine:
                     finished.append(slot)
             if not decode_rows and not chunk_rows:
                 return
-            fargs = (self.params, jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(pos, jnp.int32),
-                     jnp.asarray(qlen, jnp.int32), jnp.asarray(active),
-                     self.cache, self.rope, self.config)
+            n_real = int(qlen.sum())
+            groups = self._mixed_groups(qlen)
         with self.flight.span("dispatch"):
-            js = self._obs_jit("mixed_step", (C,), self._mixed_step_fn,
-                               fargs)
-            t0d = time.perf_counter()
-            logits, self.cache, *moe = self._mixed_step_fn(*fargs)
-            self._moe_pending += moe
-            js.finish(time.perf_counter() - t0d)
-            self._last_jit = js
+            # every layer runs over the step's tokens packed out of
+            # their windows (paged.mixed_step_paged), at the smallest
+            # size that holds them. A step over the largest size runs
+            # in several dispatches, each over some of its rows: a row
+            # reads and writes its own pages only, so the rows of one
+            # step do not care which of them share a program.
+            self._last_jit = None
+            logits, computed = None, 0
+            for rows in groups:
+                size = mixed_bucket_for(self._mixed_buckets,
+                                        int(qlen[rows].sum()))
+                out, *moe = self._run_mixed_step(
+                    tokens, pos, np.where(rows, qlen, 0), active & rows,
+                    size)
+                logits = out if logits is None else jnp.where(
+                    jnp.asarray(rows)[:, None], out, logits)
+                self._moe_pending += moe
+                computed += size
         emit_rows = decode_rows + finished
         # advance the prefill frontiers BEFORE sampling/emit: a
         # finishing row's _pos must read prompt-end when _emit runs
@@ -4649,6 +4723,7 @@ class InferenceEngine:
             rows_decode=len(decode_rows), rows_prefill=len(chunk_rows),
             rows_idle=B - len(decode_rows) - len(chunk_rows),
             rids=[r for r, _s in self._implicated],
+            tokens_real=n_real, tokens_computed=computed,
             moe=self._take_moe())
 
         def _top(slot):

@@ -23,7 +23,11 @@ head at every position:
     three between (65 .. 1792 tokens), in 8 of the 16 rows at once;
   * prompts prefilled through 128-wide mixed windows, each row at its
     own pace, rows that have finished decoding (one-token rows) beside
-    rows that still prefill; then decode steps through the decode
+    rows that still prefill, every step at the packed size the engine
+    dispatches (`engine._mixed_groups`, `paged.mixed_bucket_for`: two
+    prefilling rows a dispatch at 272 positions, the last alone at
+    144), each dispatch the engine's own program with the head at
+    every packed position; then decode steps through the decode
     program until every row has 32, teacher-forced;
   * logits at the last 256 prompt positions and at every decode step,
     and each layer's top-k expert sets at those positions, against
@@ -187,10 +191,15 @@ def main() -> int:
         return 1
     attn = "pallas" if impl["mixed"] == "paged-pallas" else "fold"
 
-    @partial(jax.jit, donate_argnames=("cache",))
-    def window_step(params, tokens, pos, q_len, active, cache):
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        # the program mixed_step_paged runs at this size, the head at
+        # every packed position: [1, T, V]
+        plan = paged.pack_plan(q_len, active, n_tokens, tokens.shape[1])
         x, cache, stats = paged._mixed_windows_trunk(
-            params, tokens, pos, q_len, active, cache, rope, cfg, attn)
+            params, tokens, pos, q_len, active, cache, rope, cfg, attn,
+            plan)
         logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
         return logits, cache, stats.experts
 
@@ -223,6 +232,7 @@ def main() -> int:
         return position >= prompts[b] - LAST
 
     steps = {"mixed": 0, "decode": 0}
+    sizes = {}                               # packed size -> mixed steps
     t0 = time.monotonic()
     while any(off[b] < prompts[b] for b in range(len(sequences))):
         toks = np.zeros((B, C), np.int32)
@@ -238,18 +248,32 @@ def main() -> int:
                 continue
             toks[b, :n] = seq[off[b]:off[b] + n]
             pos[b], qlen[b], active[b] = off[b], n, True
-        logits, cache, experts = window_step(
-            params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(qlen),
-            jnp.asarray(active), cache)
-        experts = np.asarray(experts).reshape(-1, B, C, experts.shape[-1])
-        rows = [b for b in range(len(sequences)) if active[b] and any(
-            wanted(b, off[b] + j) for j in range(qlen[b]))]
-        fetched = {b: np.asarray(logits[b, :qlen[b]]) for b in rows}
+        # in the dispatches the engine would run this step in, each at
+        # the packed size the engine would give it
+        for group in engine._mixed_groups(qlen):
+            glen = np.where(group, qlen, 0)
+            n_tokens = paged.mixed_bucket_for(engine._mixed_buckets,
+                                              int(glen.sum()))
+            logits, cache, experts = window_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(glen), jnp.asarray(active & group), cache,
+                n_tokens)
+            sizes[n_tokens] = sizes.get(n_tokens, 0) + 1
+            # a row's first token on the packed axis of the results
+            first = np.cumsum(glen) - glen
+            logits = logits.reshape(-1, logits.shape[-1])
+            experts = np.asarray(experts).reshape(
+                experts.shape[0], -1, experts.shape[-1])
+            rows = [b for b in np.flatnonzero(glen) if any(
+                wanted(b, off[b] + j) for j in range(glen[b]))]
+            fetched = {b: np.asarray(logits[first[b]:first[b] + glen[b]])
+                       for b in rows}
+            for b in np.flatnonzero(glen):
+                for j in range(glen[b]):
+                    routed[b][off[b] + j] = experts[:, first[b] + j]
+                    if b in fetched and wanted(b, off[b] + j):
+                        got[b][off[b] + j] = fetched[b][j]
         for b in range(len(sequences)):
-            for j in range(qlen[b]):
-                routed[b][off[b] + j] = experts[:, b, j]
-                if b in fetched and wanted(b, off[b] + j):
-                    got[b][off[b] + j] = fetched[b][j]
             off[b] += int(qlen[b])
         steps["mixed"] += 1
     while any(off[b] < prompts[b] + n_decode for b in range(len(sequences))):
@@ -269,7 +293,8 @@ def main() -> int:
                 routed[b][off[b]] = experts[:, b]
                 off[b] += 1
         steps["decode"] += 1
-    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+    say(f"served path: {steps['mixed']} mixed (dispatches by packed size: "
+        f"{dict(sorted(sizes.items()))}) and {steps['decode']} decode "
         f"steps in {time.monotonic() - t0:.1f} s")
 
     # -- the reference, on the host ------------------------------------

@@ -118,6 +118,122 @@ def test_mixed_admission_joins_next_step_no_decode_pause(tiny_config,
     assert b._req.first_token_t < a._req.finish_t
 
 
+def _metric(name):
+    """A label-less counter's value off the exposition /metrics serves."""
+    from cake_tpu.obs.metrics import REGISTRY
+    return sum(float(ln.split()[-1]) for ln in REGISTRY.render().splitlines()
+               if ln.startswith(name + " "))
+
+
+TOKEN_SERIES = ("cake_mixed_tokens_total",
+                "cake_mixed_tokens_computed_total")
+
+
+@pytest.fixture(scope="module")
+def sixteen_slots(tiny_config, params):
+    """A started engine of 16 slots x 16-token windows, and what the
+    token counters read before it started."""
+    eng = _engine(tiny_config, params, max_slots=16, kv_pages=96,
+                  kv_page_size=PAGE, prefill_chunk=16)
+    before = {name: _metric(name) for name in TOKEN_SERIES}
+    with eng:
+        yield eng, before
+
+
+def _run_one(eng, prompt, max_new=4):
+    h = eng.submit(prompt, max_new_tokens=max_new, temperature=0.0,
+                   repeat_penalty=1.0)
+    assert h.wait(timeout=300)
+    return list(h._req.out_tokens)
+
+
+def test_start_readies_every_packed_size(sixteen_slots):
+    """start() runs the mixed step once at every packed size with all
+    rows idle: it leaves no step record, counts no token, touches no
+    page, and the compile accountant has seen every size."""
+    from cake_tpu.models.llama.paged import mixed_token_buckets
+
+    eng, before = sixteen_slots
+    assert eng._mixed_buckets == mixed_token_buckets(16, 16) == (32, 48)
+    assert not eng.flight.dump()
+    assert before == {name: _metric(name) for name in TOKEN_SERIES}
+    assert eng._pager.free_pages == 96
+    for n_tokens in (32, 48):
+        js = eng._obs_jit("mixed_step", (16, n_tokens), None, ())
+        assert not js.new, n_tokens
+
+
+def test_a_step_over_the_largest_size_is_split_by_rows(sixteen_slots):
+    """Rows in slot order, as many a dispatch as the largest packed
+    size (48 here) holds: sixteen prefilling rows of 2, 4, .. 16 tokens
+    and eight full windows go in five, decode rows ride along."""
+    import numpy as np
+
+    eng, _ = sixteen_slots
+    qlen = np.asarray([2, 4, 6, 8, 10, 12, 14, 16] + [16] * 8)
+    groups = eng._mixed_groups(qlen)
+    assert [int(qlen[g].sum()) for g in groups] == [42, 46, 48, 48, 16]
+    assert np.sum(groups, axis=0).tolist() == [1] * 16      # each row once
+    assert [np.flatnonzero(g).tolist() for g in groups[:2]] \
+        == [[0, 1, 2, 3, 4, 5], [6, 7, 8]]
+    # one prefilling row, fourteen decode rows, an idle one: one dispatch
+    (only,) = eng._mixed_groups(np.asarray([1] * 7 + [16, 0] + [1] * 7))
+    assert only.tolist() == [True] * 8 + [False] + [True] * 7
+
+
+@pytest.mark.parametrize("arrivals", ["one_at_a_time", "sixteen_at_once"])
+def test_no_mixed_step_compiles_after_start(sixteen_slots, arrivals):
+    """Whatever token counts arrive — a lone short prompt, a lone
+    multi-window prompt, sixteen prompts in one step — no mixed step of
+    the served run is flagged `compiled`; each record's tokens_real fit
+    its tokens_computed, which is the smallest packed size that holds
+    them or, past the largest, the sizes of the step's several
+    dispatches; and the two /metrics counters advance by the records'
+    sums."""
+    eng, _ = sixteen_slots
+    seen = {r["step"] for r in eng.flight.dump()}
+    before = {name: _metric(name) for name in TOKEN_SERIES}
+    if arrivals == "one_at_a_time":
+        for n in (3, 16, 17, 40):
+            _run_one(eng, [5 + n] * n)
+    else:
+        prompts = [[3 + i] * (2 + 2 * i) for i in range(16)]
+        want = [_run_one(eng, p) for p in prompts[::5]]
+        seen = {r["step"] for r in eng.flight.dump()}
+        before = {name: _metric(name) for name in TOKEN_SERIES}
+        hs = [eng.submit(p, max_new_tokens=4, temperature=0.0,
+                         repeat_penalty=1.0) for p in prompts]
+        assert all(h.wait(timeout=300) for h in hs)
+        # a row's tokens do not depend on which rows shared its
+        # dispatch, nor on how many dispatches its step took
+        assert [list(h._req.out_tokens) for h in hs[::5]] == want
+    mixed = [r for r in eng.flight.dump()
+             if r["kind"] == "mixed" and r["step"] not in seen]
+    assert mixed
+    assert not [r for r in mixed if r["compiled"]]
+    sizes = eng._mixed_buckets
+    for r in mixed:
+        assert 0 < r["tokens_real"] <= r["tokens_computed"], r
+        if r["tokens_real"] <= sizes[-1]:
+            assert r["tokens_computed"] == next(
+                t for t in sizes if r["tokens_real"] <= t), r
+        else:
+            # several dispatches, none of them wasted
+            assert r["tokens_computed"] < r["tokens_real"] + 2 * sizes[-1]
+            assert r["tokens_computed"] % 16 == 0
+    if arrivals == "one_at_a_time":
+        assert {r["tokens_computed"] for r in mixed} == {32}
+    else:
+        # the sixteen arrive within a step or two of each other (2, 4,
+        # .. 16 tokens and eight full windows, 200 in all): the
+        # fullest step holds far more than one dispatch computes
+        first = max(mixed, key=lambda r: r["tokens_real"])
+        assert first["rows_prefill"] >= 8, first
+        assert first["tokens_real"] > 2 * sizes[-1]
+    for name, key in zip(TOKEN_SERIES, ("tokens_real", "tokens_computed")):
+        assert _metric(name) - before[name] == sum(r[key] for r in mixed)
+
+
 def test_mixed_off_keeps_phase_split(tiny_config, params):
     eng = _engine(tiny_config, params, kv_pages=24, kv_page_size=PAGE,
                   mixed_batch="off")

@@ -482,10 +482,14 @@ def test_paged_step_timings_are_the_spans(paged_engine, kind):
         assert r["dispatch_s"] == pytest.approx(ph["dispatch"], abs=2e-6)
         assert r["device_s"] == pytest.approx(ph["fetch"], abs=2e-6)
         assert r["dispatch_s"] < r["wall_s"] and r["device_s"] < r["wall_s"]
-        assert r["dispatch_s"] != r["device_s"]
         covered = sum(ph[k] for k in ("build", "dispatch", "sample",
                                       "fetch"))
         assert covered <= r["wall_s"] + 1e-4
+    # two spans of a few hundred microseconds, recorded to the
+    # microsecond, coincide in one record now and then (0.000224 ==
+    # 0.000224 failed the driver's run at PR 26): they are two
+    # measurements if they differ anywhere
+    assert any(r["dispatch_s"] != r["device_s"] for r in recs)
 
 
 def test_paged_decode_steps_carry_gap_and_emit(paged_engine):

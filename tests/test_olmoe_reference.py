@@ -214,9 +214,11 @@ PAGE, WIDTH, SLOTS, T = 8, 16, 3, 96
 N_DECODE = 24
 
 
-def paged_logits(params, sequences, prompt_lens, dtype=jnp.float32):
+def paged_logits(params, sequences, prompt_lens, dtype=jnp.float32,
+                 n_tokens=None):
     """Prefill each row's prompt through mixed steps of WIDTH (the
-    other rows busy in the same launches), then N_DECODE decode steps
+    other rows busy in the same launches; n_tokens: the packed size
+    they run at, None = the windows), then N_DECODE decode steps
     through the page pool, teacher-forced. Returns {row: {position:
     logits}} for every window's last token and every decode step, and
     the counters of the first mixed step."""
@@ -244,7 +246,8 @@ def paged_logits(params, sequences, prompt_lens, dtype=jnp.float32):
             pos[b], qlen[b], active[b] = off[b], n, True
         logits, cache, stats = mixed_step_paged(
             params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(qlen),
-            jnp.asarray(active), cache, rope, config=CFG, attn="fold")
+            jnp.asarray(active), cache, rope, config=CFG, attn="fold",
+            n_tokens=n_tokens)
         if first_stats is None:
             first_stats = (np.asarray(stats), int(qlen.sum()))
         for b in range(len(sequences)):
@@ -266,20 +269,21 @@ def paged_logits(params, sequences, prompt_lens, dtype=jnp.float32):
     return out, first_stats
 
 
-def paged_case(params, dtype=jnp.float32):
+def paged_case(params, dtype=jnp.float32, n_tokens=None):
     rng = np.random.default_rng(6)
     # three windows of 16 for the first row, two and a bit for the other
     prompt_lens = [3 * WIDTH, 2 * WIDTH + 5]
     sequences = [rng.integers(0, CFG.vocab_size, n + N_DECODE)
                  for n in prompt_lens]
-    got, stats = paged_logits(params, sequences, prompt_lens, dtype)
+    got, stats = paged_logits(params, sequences, prompt_lens, dtype,
+                              n_tokens)
     return sequences, prompt_lens, got, stats
 
 
-def paged_errors(params, ref_params, dtype=jnp.float32):
+def paged_errors(params, ref_params, dtype=jnp.float32, n_tokens=None):
     """rel_errs of every compared position: each window's last token
     and every decode step of both rows."""
-    sequences, prompt_lens, got, _ = paged_case(params, dtype)
+    sequences, prompt_lens, got, _ = paged_case(params, dtype, n_tokens)
     errs = []
     for b, seq in enumerate(sequences):
         want = np.asarray(ref.forward(ref_params, seq, REF_CFG))
@@ -290,8 +294,12 @@ def paged_errors(params, ref_params, dtype=jnp.float32):
     return np.asarray(errs)
 
 
-def test_paged_prefill_then_decode_matches_reference(params):
-    assert paged_errors(params, reference_params(params)).max() < F32_TOL
+# the packed sizes the mixed steps run at: the windows (48 positions),
+# and 32, which the first steps' two full windows fill exactly
+@pytest.mark.parametrize("n_tokens", [None, 2 * WIDTH])
+def test_paged_prefill_then_decode_matches_reference(params, n_tokens):
+    assert paged_errors(params, reference_params(params),
+                        n_tokens=n_tokens).max() < F32_TOL
 
 
 def test_paged_int8_weights_match_reference_on_dequantized(params):
@@ -315,8 +323,9 @@ def test_paged_bf16_within_its_tolerance(params):
 # -- (g) padded rows -----------------------------------------------------------
 
 
-def test_padded_mixed_rows_take_no_expert_slot(params):
-    _, _, _, (stats, real_tokens) = paged_case(params)
+@pytest.mark.parametrize("n_tokens", [None, 2 * WIDTH])
+def test_padded_mixed_rows_take_no_expert_slot(params, n_tokens):
+    _, _, _, (stats, real_tokens) = paged_case(params, n_tokens=n_tokens)
     rows, rows_padded, load_max, load_mean, touched = stats
     L, k, E = (CFG.num_hidden_layers, CFG.num_experts_per_tok,
                CFG.num_local_experts)
@@ -342,30 +351,35 @@ def test_masked_tokens_come_back_zero(params):
 
 
 @pytest.mark.parametrize("real", [5, 12, 13, 40])
-def test_packed_and_whole_dispatch_agree(params, monkeypatch, real):
-    """A step with many positions packs its real tokens to the front
-    and dispatches a quarter of the positions when they fit (here 12 of
-    48): the same numbers as the whole dispatch, on either side of the
-    switch."""
-    from cake_tpu.ops import moe
-
+def test_packed_tokens_and_masked_windows_agree(params, real):
+    """What the packed mixed step hands the layer: the real tokens of
+    48 masked window positions, gathered to the front of a shorter
+    axis. Each token's output, the rows computed and the busiest expert
+    are the same; the shorter axis never walks more tiles."""
     lp = layer0(params)
     h = jax.random.normal(jax.random.PRNGKey(9), (3, 16, CFG.hidden_size))
     mask = np.zeros(48, bool)
     mask[np.random.default_rng(real).permutation(48)[:real]] = True
-    mask = jnp.asarray(mask.reshape(3, 16))
-    want, want_stats = moe_mlp(lp, h, 2, False, token_mask=mask)
-    monkeypatch.setattr(moe, "COMPACT_MIN_TOKENS", 16)
+    want, want_stats = moe_mlp(lp, h, 2, False,
+                               token_mask=jnp.asarray(mask.reshape(3, 16)))
+    size = -(-real // 8) * 8
+    where = np.flatnonzero(mask)
+    front = np.zeros(size, np.int64)
+    front[:real] = where
     got, stats = jax.jit(
-        lambda lp, h, m: moe_mlp(lp, h, 2, False, token_mask=m))(lp, h, mask)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+        lambda lp, h, m: moe_mlp(lp, h, 2, False, token_mask=m))(
+            lp, h.reshape(48, -1)[front][None],
+            jnp.arange(size)[None] < real)
+    np.testing.assert_allclose(np.asarray(got)[0, :real],
+                               np.asarray(want).reshape(48, -1)[where],
+                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got)[0, real:], 0.0)
     assert float(stats.rows) == float(want_stats.rows) == real * 2
     assert float(stats.load_max) == float(want_stats.load_max)
-    # the packed dispatch walks fewer tiles
-    if real <= 12:
-        assert float(stats.rows_padded) <= float(want_stats.rows_padded)
-    np.testing.assert_array_equal(np.asarray(stats.experts),
-                                  np.asarray(want_stats.experts))
+    assert float(stats.rows_padded) <= float(want_stats.rows_padded)
+    np.testing.assert_array_equal(
+        np.asarray(stats.experts)[:real],
+        np.asarray(want_stats.experts)[where])
 
 
 def test_step_programs_hold_no_all_experts_intermediate(params):

@@ -15,6 +15,8 @@ kernels the stacked pool plus the layer index. Three things pin that:
       to its outputs and needs no temporary of a pool's size.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -70,7 +72,12 @@ def _step_args(kind_of_step):
             jnp.asarray([1, C], jnp.int32), active)
 
 
-STEPS = {"decode": decode_step_ragged_paged, "mixed": mixed_step_paged}
+# "mixed@T": the packed mixed step at n_tokens = T (PR 27). The step
+# arguments hold 1 + C = 5 tokens: 6 is a packed size just above them,
+# SLOTS * C = 8 the packed program at the windows' own size.
+STEPS = {"decode": decode_step_ragged_paged, "mixed": mixed_step_paged,
+         "mixed@6": partial(mixed_step_paged, n_tokens=6),
+         "mixed@8": partial(mixed_step_paged, n_tokens=SLOTS * C)}
 
 
 # -- (a) structure -------------------------------------------------------------
@@ -97,7 +104,7 @@ def _size(var):
 
 
 @pytest.mark.parametrize("kind", KINDS)
-@pytest.mark.parametrize("step", ["decode", "mixed"])
+@pytest.mark.parametrize("step", ["decode", "mixed", "mixed@6"])
 def test_step_program_never_handles_a_pool(tiny_config, params, step, kind,
                                            monkeypatch):
     import cake_tpu.ops.ragged_paged_attention as rpa
@@ -288,7 +295,7 @@ def test_prefill_window_writers_honour_the_layer(kind):
 # -- (c) donation --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("step", ["decode", "mixed"])
+@pytest.mark.parametrize("step", list(STEPS))
 def test_step_donates_the_pool_and_copies_none(tiny_config, params, step):
     cfg = tiny_config
     cache = PagedKVCache.create(cfg, SLOTS, 600, PAGE, T,
@@ -300,8 +307,10 @@ def test_step_donates_the_pool_and_copies_none(tiny_config, params, step):
     pool_bytes = cache.k.nbytes
     # the fold: the interpreter of a Pallas kernel copies its operands
     # on the CPU, which the chip's kernel does not
-    compiled = STEPS[step].lower(params, *args, cache, rope, config=cfg,
-                                 attn="fold").compile()
+    fn = STEPS[step]
+    lower = getattr(fn, "func", fn).lower
+    compiled = lower(params, *args, cache, rope, config=cfg, attn="fold",
+                     **getattr(fn, "keywords", {})).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // cfg.num_hidden_layers
@@ -311,3 +320,45 @@ def test_step_donates_the_pool_and_copies_none(tiny_config, params, step):
     assert k_in.is_deleted() and v_in.is_deleted()
     assert out.k.shape == k_in.shape and out.k.nbytes == pool_bytes
     assert out.v.shape == v_in.shape and out.v.nbytes == pool_bytes
+
+
+# -- (d) the packed mixed step computes on its tokens --------------------------
+
+
+def test_packed_step_holds_no_window_sized_value(tiny_config, params):
+    """Below slots x window the packed program runs its layers over T
+    positions: no value of the FFN's intermediate width over all B*C
+    window positions, and the pool is written T rows a layer, not B*C.
+    The attention call alone still sees [B, C, H, hd] windows."""
+    cfg = tiny_config
+    B, Cw, n_tokens = 4, 8, 16
+    F = cfg.intermediate_size
+    width = cfg.num_key_value_heads * cfg.head_dim
+    cache = PagedKVCache.create(cfg, B, 40, PAGE, T, dtype=jnp.float32)
+    rope = RopeTables.create(cfg, T)
+    args = (jnp.zeros((B, Cw), jnp.int32), jnp.zeros(B, jnp.int32),
+            jnp.asarray([1, Cw, 0, 3], jnp.int32),
+            jnp.asarray([True, True, False, True]))
+
+    def shapes(n):
+        jaxpr = jax.make_jaxpr(
+            lambda c: mixed_step_paged.__wrapped__(
+                params, *args, c, rope, cfg, attn="fold", n_tokens=n))(
+                    cache).jaxpr
+        values, scatters = set(), []
+        for eqn in _walk(jaxpr):
+            values |= {tuple(v.aval.shape) for v in eqn.outvars}
+            if eqn.primitive.name == "scatter":
+                scatters.append(tuple(eqn.invars[2].aval.shape))
+        return values, scatters
+
+    windows = {(B, Cw, F), (B * Cw, F), (1, B * Cw, F)}
+    values, scatters = shapes(None)
+    assert values & windows                     # the detector detects
+    assert scatters and set(scatters) == {(B, Cw, width)}
+    values, scatters = shapes(n_tokens)
+    assert not values & windows
+    assert (1, n_tokens, F) in values
+    assert scatters and set(scatters) == {(n_tokens, width)}
+    H, hd = cfg.num_attention_heads, cfg.head_dim
+    assert (B, Cw, H, hd) in values             # the kernel's operand
