@@ -566,7 +566,7 @@ class ApiServer:
             out["journal"] = jnl.state()
         if hasattr(eng, "current_config"):
             # the LIVE effective engine config (slots, decode_scan,
-            # kv_pages, kv_dtype, mixed_batch, attn impl) so
+            # kv_pages, kv_dtype, attn impl) so
             # operators can see what the autotuner chose; the epoch
             # pairs with per-request trace attribution
             out["engine_config"] = eng.current_config().to_dict()

@@ -117,9 +117,11 @@ class Args:
                                         # (1 = reference depth-1 behavior)
     # prefill prompts in fixed windows of N tokens (one compiled program
     # for every prompt length; cache-aware flash attention per chunk);
-    # None = whole-prompt prefill with bucketed shapes. Applies to the
-    # paged (--kv-pages) engine too: windows scatter into the slot's
-    # pages at any offset (models/llama/paged.prefill_slot_paged_chunk)
+    # None = whole-prompt prefill with bucketed shapes. On the paged
+    # (--kv-pages) engine it is the mixed step's window width (None =
+    # the widest the mixed kernel admits): a prompt's windows scatter
+    # into the slot's pages at any offset (models/llama/paged
+    # .mixed_step_paged)
     prefill_chunk: Optional[int] = None
     # engine: when no request is queued, decode N tokens per host
     # round-trip as one on-device scan (amortizes dispatch latency);
@@ -174,9 +176,11 @@ class Args:
     # --kv-pages N: paged KV for the serving engine — KV lives in a pool
     # of N pages of --kv-page-size tokens; slot admission is gated by
     # free pages, so resident KV is bounded by the pool instead of
-    # max_slots x max_seq_len (models/llama/paged.py). Composes with
-    # --auto-prefix (shared prefix pages) and --prefill-chunk (windowed
-    # paged prefill)
+    # max_slots x max_seq_len (models/llama/paged.py). Prompts and
+    # decode rows share ONE mixed step (token-level continuous
+    # batching: a new request's windows join the very next step).
+    # Composes with --auto-prefix (shared prefix pages) and
+    # --prefill-chunk (the mixed step's window width)
     kv_pages: Optional[int] = None
     kv_page_size: int = 128
     # --paged-attn: attention impl for the paged (--kv-pages) engine —
@@ -190,14 +194,6 @@ class Args:
     # `model_type`: llama, mistral, qwen2, mixtral, olmoe). An assertion
     # for scripted deployments, not a switch: nothing else reads it
     require_model_type: Optional[str] = None
-    # --mixed-batch: token-level continuous batching for the paged
-    # (--kv-pages) engine — ONE jitted mixed step processes decode rows
-    # and prefill-chunk rows together (per-row query-length metadata in
-    # the ragged paged-attention kernel), so a new request's chunks
-    # join the very next step instead of waiting for a decode pause.
-    # "auto" = on for paged serving, off elsewhere; "on" without
-    # --kv-pages is a config error; "off" keeps the phase-split loop
-    mixed_batch: str = "auto"
     # --kv-host-pages N: host-RAM spill tier for the paged pool
     # (cake_tpu/kv/host_tier.py) — preemption victims' pages and cold
     # shared-prefix pages spill to pinned host memory (LRU, capacity N
@@ -283,7 +279,7 @@ class Args:
     # --autotune {off,manual,auto}: live engine-config hot-switching
     # (cake_tpu/autotune). "manual" arms POST /api/v1/autotune (an
     # operator switches slots/decode-scan/kv-pages/kv-dtype/
-    # mixed-batch/paged-attn under load: in-flight streams fold their
+    # paged-attn under load: in-flight streams fold their
     # generated tokens into their prompts — the checkpoint-resume fold
     # — and requeue with seniority/class preserved, token-identical at
     # f32 KV); "auto" additionally runs the policy controller: an
@@ -426,10 +422,6 @@ class Args:
             raise ValueError(
                 f"unsupported paged_attn '{self.paged_attn}' "
                 "(choose auto, fold or pallas)")
-        if self.mixed_batch not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unsupported mixed_batch '{self.mixed_batch}' "
-                "(choose auto, on or off)")
         if self.kv_dtype in QUANTIZED_KV_DTYPES:
             # quantized KV is page-granular (per-page scales live in
             # the paged pool); without --kv-pages there is nothing to
@@ -478,11 +470,6 @@ class Args:
                     "--spec-draft is not supported with --disagg yet: "
                     "a shipped prefill carries no draft-pool KV (the "
                     "decode host would re-prefill every draft)")
-            if self.mixed_batch == "off":
-                raise ValueError(
-                    "--spec-draft requires the mixed ragged step "
-                    "(--mixed-batch auto/on): spec rows are a row "
-                    "kind of that step")
         if self.kv_host_pages is not None and self.kv_host_pages < 1:
             raise ValueError(
                 f"--kv-host-pages {self.kv_host_pages} must be >= 1")

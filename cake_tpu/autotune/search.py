@@ -12,9 +12,8 @@ Inputs the fitter understands:
   * **observation records** — dicts with a ``config`` (EngineConfig
     JSON) plus measured ``tok_s`` and the ``offered_rps`` the
     measurement was taken under. ``extract_observations`` walks any
-    JSON document (BENCH_*.json round files, ``bench.py --autotune``
-    tier lines, hand-built sweep files) and collects every such record
-    wherever it nests, so bench output is ingestible as-is.
+    JSON document (hand-built sweep files, a harness's result lines)
+    and collects every such record wherever it nests.
   * **step-log JSONL** (the ``--step-log`` flight recorder): has no
     config column — the whole log was captured under ONE config the
     caller names — so ``observations_from_step_log`` slices it into

@@ -11,7 +11,7 @@ config space:
   * ``EngineConfig`` — one point: the engine knobs that can be switched
     LIVE (serve/engine.reconfigure) without reloading weights: decode
     slots, decode-scan burst length, page pool geometry, KV storage
-    dtype, mixed batching, and the paged attention impl. Everything
+    dtype, and the paged attention impl. Everything
     else (model, max_seq_len, sampling defaults, scheduling policy) is
     engine identity and never moves.
   * ``validate_config`` — per-flavor validity rules REUSING args.py
@@ -38,7 +38,7 @@ from typing import Optional, Tuple
 
 # knob names, in the order operators read them (health/autotune JSON)
 CONFIG_KEYS = ("slots", "decode_scan", "kv_pages", "kv_page_size",
-               "kv_dtype", "mixed_batch", "paged_attn")
+               "kv_dtype", "paged_attn")
 
 
 @dataclass(frozen=True)
@@ -55,7 +55,6 @@ class EngineConfig:
     kv_pages: Optional[int] = None
     kv_page_size: int = 128
     kv_dtype: Optional[str] = None
-    mixed_batch: str = "auto"
     paged_attn: str = "auto"
 
     @property
@@ -131,13 +130,12 @@ def config_key(cfg: EngineConfig,
     the default (the controller) leave None distinct."""
     if not cfg.paged:
         return ("dense", cfg.slots, cfg.decode_scan)
-    mixed = (cfg.mixed_batch or "auto") != "off"
     kd = _canon_kv_dtype(cfg.kv_dtype)
     if kd is None and default_kv_dtype is not None:
         kd = _canon_kv_dtype(default_kv_dtype)
     return ("paged", cfg.slots, cfg.decode_scan, cfg.kv_pages,
             cfg.kv_page_size, kd,
-            resolve_paged_attn(cfg.paged_attn), mixed)
+            resolve_paged_attn(cfg.paged_attn))
 
 
 def validate_config(cfg: EngineConfig,
@@ -148,16 +146,11 @@ def validate_config(cfg: EngineConfig,
     args.py leaves to the engine."""
     from cake_tpu.args import Args
 
-    # args.validate covers: paged_attn/mixed_batch enums, kv_dtype name
+    # args.validate covers: the paged_attn enum, kv_dtype name
     # resolution, int8-requires-pages, max_slots/decode_scan >= 1
     Args(model="", max_slots=cfg.slots, decode_scan=cfg.decode_scan,
          kv_pages=cfg.kv_pages, kv_page_size=cfg.kv_page_size,
-         kv_dtype=cfg.kv_dtype, mixed_batch=cfg.mixed_batch,
-         paged_attn=cfg.paged_attn).validate()
-    if cfg.mixed_batch == "on" and not cfg.paged:
-        raise ValueError(
-            "mixed_batch=on requires kv_pages: the mixed ragged step "
-            "dispatches over the paged pool")
+         kv_dtype=cfg.kv_dtype, paged_attn=cfg.paged_attn).validate()
     if cfg.paged and (cfg.kv_pages < 1 or cfg.kv_page_size < 1):
         raise ValueError(
             f"kv_pages {cfg.kv_pages} / kv_page_size "

@@ -560,15 +560,14 @@ def main(argv=None) -> int:
             "single request with nothing to schedule")
     if args.kv_pages or args.auto_prefix \
             or getattr(args, "kv_host_pages", None) \
-            or getattr(args, "kv_dtype", None) in ("int8", "int4") \
-            or getattr(args, "mixed_batch", "auto") == "on":
+            or getattr(args, "kv_dtype", None) in ("int8", "int4"):
         # all live in the serving engine (paged pool / prefix registry
-        # / mixed ragged step / kv tiering); a one-shot generation
+        # / kv tiering); a one-shot generation
         # silently ignoring them would look like the feature "did
         # nothing"
         logging.getLogger(__name__).warning(
-            "--kv-pages / --auto-prefix / --mixed-batch / --kv-dtype "
-            "int8/int4 / --kv-host-pages apply to engine serving "
+            "--kv-pages / --auto-prefix / --kv-dtype int8/int4 / "
+            "--kv-host-pages apply to engine serving "
             "(--api); one-shot generation uses the sequential "
             "generator's dense cache")
     if getattr(args, "autotune", "off") != "off":

@@ -67,8 +67,8 @@ ERRORS = ("transient", "oom", "wedge", "abort")
 
 # `abort` kills the PROCESS (os._exit — no atexit, no flushes beyond
 # what already hit the OS): the in-tree way to stage a kill -9 crash
-# drill. The distinctive exit code lets a drill driver (bench.py
-# --restart, tests) tell a planned abort from an organic death.
+# drill. The distinctive exit code lets a drill driver (the tests)
+# tell a planned abort from an organic death.
 ABORT_EXIT_CODE = 86
 
 # context each call site actually supplies. A rule keyed on context

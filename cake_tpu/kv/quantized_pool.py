@@ -398,33 +398,29 @@ def qupdate_pool_per_row(pool, layer, vals: jnp.ndarray, pos,
 
 def _window_pages_rmw(pool, layer, vals, j_idx, off_idx, wmask_src,
                       idx, touched):
-    """Shared gather -> rescale -> overwrite -> scatter core for the
-    window writers. vals: [..., C, KV, hd] f32; j_idx/off_idx: window
-    page / in-page offset per position; wmask_src: per-position write
-    validity; idx: [..., W] gather/scatter page ids (OOB = dropped);
-    touched: [..., W] pages that receive >= 1 position."""
+    """The gather -> rescale -> overwrite -> scatter core of the
+    window writer. vals: [B, C, KV, hd]; j_idx/off_idx: window page /
+    in-page offset per position; wmask_src: per-position write
+    validity (padding positions neither write nor enter the MONOTONE
+    page scale's amax); idx: [B, W] gather/scatter page ids (OOB =
+    dropped); touched: [B, W] pages that receive >= 1 position."""
     W = idx.shape[-1]
     P = _pool_page(pool)
     qmax = _pool_qmax(pool)
-    KV, hd = vals.shape[-2], vals.shape[-1]
-    lead = vals.shape[:-3]
-    qs = _gather_q(pool, layer, idx)               # [..., W, P, KV, hd]
-    ss = _gather_scale(pool, layer, idx)           # [..., W, KV]
+    B, KV, hd = vals.shape[0], vals.shape[-2], vals.shape[-1]
+    qs = _gather_q(pool, layer, idx)               # [B, W, P, KV, hd]
+    ss = _gather_scale(pool, layer, idx)           # [B, W, KV]
     # place the window's values + mask into page coordinates: every
     # (page, offset) target is distinct within a row, so one scatter
-    buf = jnp.zeros(lead + (W + 1, P, KV, hd), jnp.float32)
-    msk = jnp.zeros(lead + (W + 1, P), bool)
+    buf = jnp.zeros((B, W + 1, P, KV, hd), jnp.float32)
+    msk = jnp.zeros((B, W + 1, P), bool)
     jj = jnp.where(wmask_src, j_idx, W)            # invalid -> dropped row
-    if lead:
-        b = jnp.arange(lead[0])[:, None]
-        buf = buf.at[b, jj, off_idx].set(vals.astype(jnp.float32))
-        msk = msk.at[b, jj, off_idx].set(wmask_src)
-    else:
-        buf = buf.at[jj, off_idx].set(vals.astype(jnp.float32))
-        msk = msk.at[jj, off_idx].set(wmask_src)
-    buf, msk = buf[..., :W, :, :, :], msk[..., :W, :]
+    b = jnp.arange(B)[:, None]
+    buf = buf.at[b, jj, off_idx].set(vals.astype(jnp.float32))
+    msk = msk.at[b, jj, off_idx].set(wmask_src)
+    buf, msk = buf[:, :W], msk[:, :W]
     amax = jnp.max(jnp.where(msk[..., None, None], jnp.abs(buf), 0.0),
-                   axis=(-3, -1))                  # [..., W, KV]
+                   axis=(-3, -1))                  # [B, W, KV]
     need = jnp.maximum(amax, _EPS) / qmax
     new_s = jnp.where(touched[..., None], jnp.maximum(ss, need), ss)
     qr = _requant(qs, jnp.where(new_s > 0, ss / jnp.maximum(new_s, _EPS),
@@ -436,51 +432,15 @@ def _window_pages_rmw(pool, layer, vals, j_idx, off_idx, wmask_src,
     return _scatter_q(pool, layer, idx, qw, new_s)
 
 
-def qwrite_window_pages(pool, layer, vals: jnp.ndarray,
-                        table_row, pos0, n_real=None):
-    """write_window_pages over a quantized pool: one C-token window at
-    absolute position pos0 (any in-page offset). The window touches at
-    most ceil(C/P)+1 consecutive pages — those are gathered, rescaled,
-    overwritten at the window's positions, and scattered back.
-
-    n_real (traced scalar) marks the real tokens in the window: the
-    chunk path pads the last window to bucket width C with token-id-0
-    garbage whose amax would otherwise enter the MONOTONE page scale
-    and permanently coarsen the page's real tokens (the batched mixed
-    writer already masks by q_len). Padding positions neither write
-    nor contribute to the amax, and pages touched only by padding are
-    left alone entirely."""
-    N, P = pool.q.shape[1], _pool_page(pool)
-    C = vals.shape[1]
-    max_pages = table_row.shape[0]
-    if n_real is None:
-        n_real = C
-    n_real = jnp.asarray(n_real, jnp.int32)
-    W = -(-C // P) + 1
-    pos = pos0 + jnp.arange(C)
-    pidx = pos // P
-    first = pos0 // P
-    win_pidx = first + jnp.arange(W)                      # [W]
-    pages = table_row[jnp.minimum(win_pidx, max_pages - 1)]
-    last = pos0 + jnp.maximum(n_real, 1) - 1
-    touched = ((n_real > 0) & (win_pidx <= last // P)
-               & (win_pidx < max_pages) & (pages >= 0))
-    idx = jnp.where(touched, pages, N)
-    # per-position validity mirrors write_window_pages' drop rule
-    p_pages = table_row[jnp.minimum(pidx, max_pages - 1)]
-    wvalid = ((jnp.arange(C) < n_real)
-              & (pidx < max_pages) & (p_pages >= 0))
-    return _window_pages_rmw(pool, layer, vals[0], pidx - first, pos % P,
-                             wvalid, idx, touched)
-
-
 def qwrite_windows_pages(pool, layer, vals: jnp.ndarray, pos,
                          q_len, active, table):
     """write_windows_pages over a quantized pool: the batched mixed
-    writer — every row's q_len-token window at its own offset, decode
-    rows (q_len=1) included. Per row the window spans at most
-    ceil(C/P)+1 consecutive pages; rows own disjoint (non-shared)
-    pages, so the batched page round-trips never collide."""
+    writer — every row's q_len-token window at its own offset (any
+    in-page offset), decode rows (q_len=1) included. Per row the
+    window spans at most ceil(C/P)+1 consecutive pages — those are
+    gathered, rescaled, overwritten at the window's positions, and
+    scattered back; rows own disjoint (non-shared) pages, so the
+    batched page round-trips never collide."""
     N, P = pool.q.shape[1], _pool_page(pool)
     B, C = vals.shape[0], vals.shape[1]
     max_pages = table.shape[1]

@@ -90,10 +90,6 @@ class Master:
                             "prefix caching is not implemented for the "
                             "spec engine (draft cache has no prefix "
                             "install path)")
-            if getattr(self.args, "mixed_batch", "auto") == "on":
-                log.warning("--mixed-batch ignored with --draft-model: "
-                            "the mixed ragged step is a paged-engine "
-                            "path and the spec engine is not paged")
             if getattr(self.args, "autotune", "off") != "off":
                 log.warning("--autotune ignored with --draft-model: "
                             "speculative serving has no hot-switch "
@@ -155,10 +151,6 @@ class Master:
                 log.warning("--auto-prefix ignored: prefix caching is "
                             "not implemented for the sp engine's "
                             "sequence-sharded ctx cache")
-            if getattr(self.args, "mixed_batch", "auto") == "on":
-                log.warning("--mixed-batch ignored: the sp engine's "
-                            "ctx/tail cache is not paged, so there is "
-                            "no mixed ragged step to dispatch")
             if getattr(self.args, "autotune", "off") != "off":
                 log.warning("--autotune ignored: the sp engine's "
                             "custom step fns own their cache contract; "
@@ -243,11 +235,6 @@ class Master:
             # when --kv-pages is absent)
             kv_dtype=getattr(self.args, "kv_dtype", None),
             kv_host_pages=getattr(self.args, "kv_host_pages", None),
-            # token-level continuous batching: the paged engine's mixed
-            # ragged step (auto = on for --kv-pages serving; "on"
-            # without --kv-pages is rejected by the engine with a
-            # named reason instead of silently vanishing)
-            mixed_batch=getattr(self.args, "mixed_batch", "auto"),
             # live config hot-switching (cake_tpu/autotune): the
             # engine itself warns and disables on flavors without the
             # fold (ring/custom step fns)

@@ -38,7 +38,7 @@ from jax import lax
 from cake_tpu.kv.quantized_pool import (
     Int4PagedKVCache, Int4Pool, QuantPool, QuantizedPagedKVCache,
     dequantize_pages, qupdate_pool_per_row, qwrite_prompt_pages,
-    qwrite_window_pages, qwrite_windows_pages,
+    qwrite_windows_pages,
 )
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.parallel.context_parallel import (
@@ -266,51 +266,12 @@ def write_prompt_pages(pool_k, pool_v, layer, k, v, table_row,
 
 
 @jax.named_scope("kv")
-def write_window_pages(pool_k, pool_v, layer, k, v, table_row, pos0,
-                       n_real=None):
-    """Scatter one prefill window's KV ([1, C, KV, hd]) at absolute
-    position `pos0` into one slot's pages of layer `layer`.
-
-    Unlike write_prompt_pages, pos0 need NOT be page-aligned: each of
-    the C positions resolves its own (page, offset) pair through the
-    table row, so chunked prefill windows may straddle page boundaries
-    at any offset. Distinct positions map to distinct targets, so one
-    vectorized scatter covers the window; positions past the slot's
-    mapped pages (bucket padding beyond the allocation, or past the
-    table entirely) route to the out-of-bounds index and mode="drop"
-    skips them — the paged analog of dense padding semantics.
-
-    A QuantPool quantizes on scatter via a touched-page read-modify-
-    write (kv/quantized_pool.qwrite_window_pages); n_real (traced
-    scalar) keeps the window's bucket-padding garbage out of the
-    monotone page scales there (dead data for an f32 pool)."""
-    if isinstance(pool_k, (QuantPool, Int4Pool)):
-        return (qwrite_window_pages(pool_k, layer, k, table_row, pos0,
-                                    n_real),
-                qwrite_window_pages(pool_v, layer, v, table_row, pos0,
-                                    n_real))
-    N, P = pool_k.shape[1], pool_k.shape[2]
-    C = k.shape[1]
-    max_pages = table_row.shape[0]
-    pos = pos0 + jnp.arange(C)
-    pidx = pos // P
-    pages = table_row[jnp.minimum(pidx, max_pages - 1)]
-    valid = jnp.logical_and(pidx < max_pages, pages >= 0)
-    idx = jnp.where(valid, pages, N)
-    offs = pos % P
-    pk = pool_k.at[layer, idx, offs].set(
-        _rows(k[0]).astype(pool_k.dtype), mode="drop")
-    pv = pool_v.at[layer, idx, offs].set(
-        _rows(v[0]).astype(pool_v.dtype), mode="drop")
-    return pk, pv
-
-
-@jax.named_scope("kv")
 def write_windows_pages(pool_k, pool_v, layer, k, v, pos, q_len, active,
                         table):
-    """Batched write_window_pages: every row scatters its q_len-token
-    window at absolute position pos[b] into its own pages of layer
-    `layer`.
+    """Every row scatters its q_len-token window at absolute position
+    pos[b] (NOT necessarily page-aligned: each position resolves its
+    own (page, offset) pair through the row's table) into its own
+    pages of layer `layer`.
 
     pool_k/v: [L, N_pages, page, KV*hd]; k/v: [B, C, KV, hd]; pos/q_len:
     [B]; active: [B] bool; table: [slots(=B), max_pages]. One
@@ -721,7 +682,7 @@ def prefill_slot_paged(params, tokens, prompt_len, slot,
     return logits, cache
 
 
-# -- prefix sharing + chunked prefill (page-granular) --------------------------
+# -- prefix sharing (page-granular) --------------------------------------------
 
 
 @_partial(jax.jit, static_argnames=("config", "attn"),
@@ -770,155 +731,6 @@ def prefill_prefix_pages(params, tokens, table_row,
     _, cache = scan_layers_paged(params["blocks"], x, cache, config,
                                  layer_attn)
     return cache
-
-
-@_partial(jax.jit, static_argnames=("config", "n_prefix", "attn"),
-          donate_argnames=("cache",))
-def prefill_slot_paged_prefixed(params, tokens, suffix_len, slot,
-                                cache: PagedKVCache, rope,
-                                config: LlamaConfig, n_prefix: int,
-                                attn: str = "fold"):
-    """Slot prefill continuing a POOL-RESIDENT shared prefix: prefill
-    only the suffix window, attending the fresh window causally PLUS the
-    prefix pages already mapped into the slot's table row head.
-
-    tokens: [1, S] right-padded suffix; suffix_len: [1] real length;
-    n_prefix: static page-aligned prefix token count — the slot's first
-    n_prefix // page_size table entries are the SHARED prefix pages
-    (read-only here: suffix KV scatters into the row's remaining pages
-    only, so one prefix page can back many slots). The prefix K/V are
-    gathered from their pages once per layer and concatenated with the
-    fresh window, giving dense-prefixed-prefill semantics without any
-    per-slot prefix copy. Compiles once per (suffix bucket, n_prefix)
-    pair — n_prefix is a registered-prefix property, so the set stays
-    small. attn="pallas" routes through the cache-aware flash kernel
-    (queries at pos n_prefix+i attend keys <= n_prefix+i); decode needs
-    no changes at all — the ragged kernel reads through the table."""
-    from cake_tpu.ops.attention import gqa_attention
-    from cake_tpu.ops.flash_attention import (
-        flash_attention_cached, flash_supported,
-    )
-    from cake_tpu.ops.norms import rms_norm
-    from cake_tpu.ops.quant import qmatmul
-    from cake_tpu.ops.rope import apply_rope, rope_rows
-
-    B, S = tokens.shape
-    H = config.num_attention_heads
-    KV = config.num_key_value_heads
-    hd = config.head_dim
-    P = cache.page_size
-    n_pp = n_prefix // P          # static: whole pages by contract
-    T = n_prefix + S
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
-    rope_c, rope_s = rope_rows(rope.cos, rope.sin, jnp.int32(n_prefix), S)
-    table_row = jnp.take(cache.table, slot, axis=0)
-    prefix_pages = jnp.maximum(table_row[:n_pp], 0)
-    suffix_row = table_row[n_pp:]
-    use_flash = (attn == "pallas"
-                 and flash_supported(S, T, H, KV, hd=hd))
-    mask = (None if use_flash else
-            (jnp.arange(T)[None, :] <= n_prefix + jnp.arange(S)[:, None]))
-
-    def layer_attn(layer, pk, pv, q, k, v):
-        q = apply_rope(q, rope_c, rope_s)
-        k = apply_rope(k, rope_c, rope_s)
-        # gather the shared prefix pages (position-ordered by the row)
-        # into a dense [1, n_prefix, KV, hd] view — read-only (prefix
-        # and suffix pages are disjoint)
-        kp = gather_layer_pages(pk, layer, prefix_pages, KV,
-                                q.dtype).reshape(1, n_prefix, KV, hd)
-        vp = gather_layer_pages(pv, layer, prefix_pages, KV,
-                                q.dtype).reshape(1, n_prefix, KV, hd)
-        pk, pv = write_prompt_pages(pk, pv, layer, k, v, suffix_row,
-                                    suffix_len[0])
-        k_full = jnp.concatenate([kp, k.astype(q.dtype)], axis=1)
-        v_full = jnp.concatenate([vp, v.astype(q.dtype)], axis=1)
-        if use_flash:
-            return (flash_attention_cached(q, k_full, v_full,
-                                           jnp.int32(n_prefix)), pk, pv)
-        return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
-
-    x, cache = scan_layers_paged(params["blocks"], x, cache, config,
-                                 layer_attn, n_real=suffix_len)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-        last = jnp.take_along_axis(
-            x, (suffix_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
-        )[:, 0]
-        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache
-
-
-@_partial(jax.jit, static_argnames=("config", "attn"),
-          donate_argnames=("cache",))
-def prefill_slot_paged_chunk(params, tokens, n_real, slot, pos0,
-                             cache: PagedKVCache, rope,
-                             config: LlamaConfig, attn: str = "fold"):
-    """One fixed-size prefill window into a PAGED slot at absolute
-    position `pos0` — the paged analog of model.prefill_slot_chunk,
-    lifting the old "paged prompts prefill whole-window" restriction:
-    long prompts admit in C-token windows with bounded activation
-    memory, one compiled program per window shape (pos0 is traced).
-
-    tokens: [1, C]; n_real: [1] real tokens in the window. The window's
-    KV scatters through write_window_pages (pos0 may sit anywhere
-    inside a page); attention gathers the slot's mapped pages into a
-    position-ordered dense [1, max_seq, KV, hd] view and masks
-    kj <= pos0 + qi — every already-written position (earlier windows
-    AND a shared prefix mapped at the row head) is attended through the
-    same gather, so prefix + chunked-suffix composes with no separate
-    install step. attn="pallas" routes through the cache-aware flash
-    kernel; unmapped pages gather as zeros, which only garbage
-    (padding) queries can see under the causal bound."""
-    from cake_tpu.ops.attention import gqa_attention
-    from cake_tpu.ops.flash_attention import (
-        flash_attention_cached, flash_supported,
-    )
-    from cake_tpu.ops.norms import rms_norm
-    from cake_tpu.ops.quant import qmatmul
-    from cake_tpu.ops.rope import apply_rope, rope_rows
-
-    B, C = tokens.shape
-    H = config.num_attention_heads
-    KV = config.num_key_value_heads
-    hd = config.head_dim
-    N, P = cache.n_pages, cache.page_size
-    T = cache.max_seq_len
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
-    rope_c, rope_s = rope_rows(rope.cos, rope.sin, pos0, C)
-    table_row = jnp.take(cache.table, slot, axis=0)
-    gather_idx = jnp.where(table_row >= 0, table_row, N)
-    use_flash = (attn == "pallas"
-                 and flash_supported(C, T, H, KV, hd=hd))
-    mask = (None if use_flash else
-            (jnp.arange(T)[None, :] <= pos0 + jnp.arange(C)[:, None]))
-
-    def layer_attn(layer, pk, pv, q, k, v):
-        q = apply_rope(q, rope_c, rope_s)
-        k = apply_rope(k, rope_c, rope_s)
-        pk, pv = write_window_pages(pk, pv, layer, k, v, table_row, pos0,
-                                    n_real[0])
-        # post-write gather: the dense view holds every written
-        # position (prefix head, earlier windows, this window)
-        k_full = gather_layer_pages(pk, layer, gather_idx, KV,
-                                    q.dtype).reshape(1, T, KV, hd)
-        v_full = gather_layer_pages(pv, layer, gather_idx, KV,
-                                    q.dtype).reshape(1, T, KV, hd)
-        if use_flash:
-            return flash_attention_cached(q, k_full, v_full, pos0), pk, pv
-        return gqa_attention(q, k_full, v_full, mask=mask), pk, pv
-
-    x, cache = scan_layers_paged(params["blocks"], x, cache, config,
-                                 layer_attn, n_real=n_real)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-        last = jnp.take_along_axis(
-            x, (n_real - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1
-        )[:, 0]
-        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache
 
 
 # -- token-level continuous batching: the mixed ragged step -------------------
@@ -1108,18 +920,17 @@ def mixed_step_paged(params, tokens, pos, q_len, active,
                      cache: PagedKVCache, rope, config: LlamaConfig,
                      attn: str = "fold", n_tokens: Optional[int] = None):
     """ONE jitted step over a mixed batch of row descriptors — the
-    token-level continuous-batching step that collapses the
-    prefill_slot_paged / prefill_slot_paged_chunk /
-    decode_step_ragged_paged zoo behind a single dispatch seam:
+    token-level continuous-batching step, prompts and decode rows
+    behind a single dispatch seam:
 
       * a DECODE row carries (pos = current token position, q_len = 1,
         tokens[:, 0] = last sampled token) — exactly the ragged decode
         semantics (write the token, attend the pages);
       * a PREFILL-CHUNK row carries (pos = window start, q_len = real
-        window tokens, tokens[:, :q_len] = the window) — exactly the
-        prefill_slot_paged_chunk semantics at any page offset, a
-        shared-prefix head included (the window attends every position
-        written through the table);
+        window tokens, tokens[:, :q_len] = the window) at any page
+        offset, a shared-prefix head included: the window's KV is
+        written first, then it attends every position written through
+        the table, causally (kj <= pos + qi);
       * an IDLE row carries (q_len = 0, active = False) and touches
         neither its pages nor the output the caller reads.
 

@@ -70,9 +70,9 @@ def init_params_quantized(config: LlamaConfig, rng: jax.Array,
     Produces the same pytree structure as ``quantize_params(init_params(...))``
     without ever materialising the full-precision tree — a bf16 8B tree is
     ~15 GiB, i.e. most of a v5e's HBM, so the quantize-after-init path is
-    dead on arrival there. Benchmarks are weight-value independent
-    (bench.py), so random weights + constant scales are as good as
-    quantized real weights.
+    dead on arrival there. The benchmark's timings do not depend on
+    the weights' values, so random weights + constant scales serve as
+    well as quantized real weights.
     """
     from cake_tpu.ops.quant import _BLOCK_CONTRACT, QTensor, pick_group
 

@@ -70,12 +70,11 @@ from cake_tpu.obs.jsonl import JsonlAppender
 log = logging.getLogger(__name__)
 
 # Peak dense bf16 matmul FLOP/s by device_kind substring (public TPU
-# specs), first match wins. THE single table for the whole repo —
-# bench.py delegates here, so the measured (flight recorder) and
-# analytic (roofline) utilization numbers in one BENCH row can never
-# use different hardware constants. A kind that is not in the table has
-# no peak: the lookups return None and no utilization is reported for
-# it (a CPU lane prints no mfu/hbm_util at all).
+# specs), first match wins. The program's single table (the
+# benchmark keeps its own, benchmarks/harness/peaks.py, keyed by exact
+# device_kind). A kind that is not in the table has no peak: the
+# lookups return None and no utilization is reported for it (a CPU
+# lane prints no mfu/hbm_util at all).
 PEAK_FLOPS = [
     ("v5 lite", 197e12), ("v5e", 197e12),
     ("v5p", 459e12), ("v5", 459e12),
@@ -738,28 +737,24 @@ class StepTelemetry:
                     if r.rids is not None and rid in r.rids]
         return [r.to_dict() for r in recs]
 
-    def utilization(self, since_step: int = 0, *,
+    def utilization(self, *,
                     include_prefill: bool = False) -> Dict[str, float]:
         """Wall-time-weighted mean MFU / HBM utilization over the
         ring's decode-side records (decode / decode_scan / spec;
         prefill excluded — its utilization profile is a different
         question). include_prefill=True widens the aggregate to
-        prefill records too: an A/B against mixed batching needs it,
-        because a mixed record folds its chunk's prefill FLOPs in and
-        the phase-split side must count the same work to compare
-        occupancy rather than aggregation. Records whose dispatch
+        prefill records too (the autotuner's signal: a mixed record
+        folds its chunk's prefill FLOPs in, so a dense engine's
+        prefill records must count beside it). Records whose dispatch
         compiled a new signature are excluded — their wall is XLA
-        compile, not decode — and since_step drops everything up to a
-        warmup boundary (pass the post-warmup
-        `summary()["recorded_steps"]`). A field is ABSENT when no
-        remaining record carried it (no cost info, or a device kind
-        with no peak in the table) — never a 0.0 stand-in."""
+        compile, not decode. A field is ABSENT when no remaining
+        record carried it (no cost info, or a device kind with no
+        peak in the table) — never a 0.0 stand-in."""
         kinds = _DECODE_KINDS + ("prefill",) if include_prefill \
             else _DECODE_KINDS
         with self._lock:
             recs = [r for r in self._ring
-                    if r.kind in kinds and not r.compiled
-                    and r.step > since_step]
+                    if r.kind in kinds and not r.compiled]
         out: Dict[str, float] = {}
         for field in ("mfu", "hbm_util"):
             num = den = 0.0

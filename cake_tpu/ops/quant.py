@@ -1,7 +1,7 @@
 """Weight-only int8/int4 quantization for decode bandwidth.
 
 Batch-1 decode is HBM-bandwidth-bound: every step streams the full weight
-set once (SURVEY.md §6 / BASELINE.md roofline). Storing linear weights as
+set once (PERF.md §5: the FFN's weight streaming). Storing linear weights as
 int8 with per-output-channel scales halves that traffic — the dequantize
 happens in registers on the way into the bf16 MXU matmul, so throughput
 approaches 2x the bf16 roofline while activations/accumulation stay bf16

@@ -132,7 +132,7 @@ class ReplicaProxy:
                      headers: dict, stream: bool,
                      send_status: Callable[[int, dict, bytes], None],
                      send_line: Callable[[bytes], None],
-                     send_terminal_error: Callable[[str], None],
+                     send_terminal_error: Callable[[str, str], None],
                      on_admitted: Optional[Callable[..., None]] = None,
                      extra_headers: Optional[dict] = None,
                      on_hop: Optional[Callable[..., None]] = None,
@@ -141,9 +141,11 @@ class ReplicaProxy:
 
         send_status(code, relay_headers, body) — relay a complete
         non-stream response. send_line(raw) — relay one SSE line
-        (already includes the newline). send_terminal_error(msg) —
-        write the typed terminal SSE error event (only called after
-        send_line delivered bytes). on_admitted(rid=...) fires as soon
+        (already includes the newline). send_terminal_error(msg, cause)
+        — write the typed terminal SSE error event (only called after
+        send_line delivered bytes; cause is the "midstream" outcome's
+        error, for whoever must record the end BEFORE the client reads
+        it). on_admitted(rid=...) fires as soon
         as the replica answers 200 — i.e. the request holds a slot
         THERE — so idempotency-sticky state exists before the stream
         finishes (a mid-stream reconnect must find its home); rid is
@@ -261,7 +263,8 @@ class ReplicaProxy:
                     send_terminal_error(
                         f"replica {replica} went away mid-stream "
                         f"({type(e).__name__}); reconnect with your "
-                        "idempotency key and Last-Event-ID to resume")
+                        "idempotency key and Last-Event-ID to resume",
+                        str(e))
                     return ProxyOutcome("midstream", error=str(e))
                 if not line:
                     if not sent_any:
@@ -278,7 +281,7 @@ class ReplicaProxy:
                             f"replica {replica} closed the stream "
                             "without finishing; reconnect with your "
                             "idempotency key and Last-Event-ID to "
-                            "resume")
+                            "resume", "eof without terminal")
                         return ProxyOutcome(
                             "midstream", error="eof without terminal")
                     return ProxyOutcome("ok", status=200)

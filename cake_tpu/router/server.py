@@ -649,8 +649,8 @@ def make_router_handler(router: RouterServer):
                     send_status=self._relay_status,
                     send_line=self._relay_line,
                     send_terminal_error=(
-                        lambda msg, name=name:
-                        self._terminal_error(msg, replica=name)),
+                        lambda msg, cause, name=name:
+                        self._terminal_error(msg, cause, replica=name)),
                     on_admitted=admitted,
                     on_hop=hop,
                     extra_headers={"x-cake-trace": tid,
@@ -691,11 +691,7 @@ def make_router_handler(router: RouterServer):
                 if outcome.kind == "midstream":
                     _FAILOVERS.labels(reason="midstream").inc()
                     router.tracker.note_failure(name)
-                    if router.hops is not None:
-                        router.hops.finish(tid, "midstream",
-                                           replica=name,
-                                           error=outcome.error)
-                    return
+                    return   # _terminal_error finished the hop record
                 if outcome.kind == "relayed":
                     _SHEDS.labels(reason="relay").inc()
                     if router.hops is not None:
@@ -760,7 +756,7 @@ def make_router_handler(router: RouterServer):
             self.wfile.write(line + b"\r\n")
             self.wfile.flush()
 
-        def _terminal_error(self, message: str,
+        def _terminal_error(self, message: str, cause: str,
                             replica: Optional[str] = None) -> None:
             # the replica attribution rides the EVENT PAYLOAD, not
             # only a header: a mid-stream death happens long after the
@@ -774,6 +770,12 @@ def make_router_handler(router: RouterServer):
             tid = getattr(self, "_trace_id", None)
             if tid is not None:
                 err["trace"] = tid
+                if router.hops is not None:
+                    # the event hands the client this trace id: the
+                    # record must read "midstream" before the bytes
+                    # can reach anyone who would look it up
+                    router.hops.finish(tid, "midstream", replica=replica,
+                                       error=cause)
             payload = (b"data: " + json.dumps({"error": err}).encode()
                        + b"\n\n")
             try:

@@ -76,7 +76,7 @@ _RESET_FAILURES = obs_metrics.counter(
 # fold and pallas histograms compare like for like at any decode_scan
 _PAGED_ATTN_STEP = obs_metrics.histogram(
     "cake_paged_attn_step_seconds",
-    "Paged-engine step wall latency by path (prefill|decode|mixed)",
+    "Paged-engine step wall latency by path (decode|mixed)",
     labelnames=("path",))
 
 # page-granular prefix sharing (the paged engine's prompt-cache path):
@@ -414,7 +414,6 @@ class InferenceEngine:
         paged_attn: Optional[str] = None,
         kv_dtype: Optional[str] = None,
         kv_host_pages: Optional[int] = None,
-        mixed_batch: Optional[str] = None,
         prompt_limit: Optional[int] = None,
         decode_budget: Optional[int] = None,
         trace_events: Optional[str] = None,
@@ -672,27 +671,10 @@ class InferenceEngine:
         # pid -> monotonic last-hit time (the cold-prefix LRU order)
         self._prefix_last_hit: dict = {}
         self.prefill_chunk = prefill_chunk
-        # --mixed-batch {auto,on,off}: token-level continuous batching
-        # for the paged engine — admissions' prefill chunks join the
-        # very next mixed step alongside decode rows instead of waiting
-        # for a decode pause. auto = on for paged serving, off
-        # elsewhere (the dense/ring/spec engines keep their phase
-        # loops); "on" without --kv-pages is a config error, not a
-        # silent no-op.
-        mb = mixed_batch or "auto"
-        if mb not in ("auto", "on", "off"):
-            raise ValueError(
-                f"--mixed-batch must be auto, on or off, got {mb!r}")
-        if mb == "on" and not self.paged:
-            raise ValueError(
-                "--mixed-batch on requires --kv-pages: the mixed "
-                "ragged step dispatches over the paged pool")
-        self._mixed = self.paged and mb != "off"
-        if self._spec_paged and not self._mixed:
-            raise ValueError(
-                "--spec-draft requires the mixed ragged step "
-                "(--mixed-batch auto/on): spec rows join the one-launch "
-                "mixed iteration, they have no phase-loop flavor")
+        # token-level continuous batching IS the paged engine: an
+        # admission's prefill windows join the very next mixed step
+        # alongside decode rows (_do_mixed). The dense, ring and
+        # pipelined engines keep their prefill/decode phases.
         # slot -> in-flight prefill progress (req, remaining window
         # offsets); teardown paths clear entries via
         # _release_slot_pages so cancel/preempt/error cannot leave a
@@ -1068,7 +1050,7 @@ class InferenceEngine:
     def start(self) -> "InferenceEngine":
         if self._thread is None:
             from cake_tpu.utils.profiling import log_memory
-            if self._mixed:
+            if self.paged:
                 self._warm_mixed_buckets()
             log_memory("engine start")
             self._thread = threading.Thread(target=self._run, daemon=True,
@@ -2157,24 +2139,19 @@ class InferenceEngine:
                     # key off the engine step counter)
                     self._faults.check("engine.step",
                                        step=self.stats.steps)
-                if self._mixed:
+                if self.paged:
+                    # admissions, prefill windows and decode rows all
+                    # go through the one mixed iteration
                     self._do_mixed(prefill_plan, decode_plan)
-                elif prefill_plan and not self._multihost:
-                    self._do_prefill_batch(prefill_plan)
                 else:
-                    for rid, slot in prefill_plan:
-                        self._do_prefill(rid, slot)
-                if decode_plan and not self._mixed:
-                    if self._resident_parked:
-                        # an admission above parked a decode-resident
-                        # slot: the plan predates the park, and the
-                        # device step must not write through a
-                        # released page-table row
-                        decode_plan = self._live_decode_rows(decode_plan)
-                if decode_plan and not self._mixed:
-                    if self._spec:
-                        self._do_decode_spec(decode_plan)
+                    if prefill_plan and not self._multihost:
+                        self._do_prefill_batch(prefill_plan)
                     else:
+                        for rid, slot in prefill_plan:
+                            self._do_prefill(rid, slot)
+                    if decode_plan and self._spec:
+                        self._do_decode_spec(decode_plan)
+                    elif decode_plan:
                         n = self._scan_steps_for(decode_plan)
                         if n > 1 and not self._multihost:
                             self._decode_burst(decode_plan, n)
@@ -2600,13 +2577,12 @@ class InferenceEngine:
         The SINGLE source for __init__ AND the live hot-switch seam
         (_apply_exec_config): a reconfigured pool must resolve exactly
         as a startup one would. Requires self.paged/self.kv_quant/
-        self._kv_dtype_name/self._base_cache_dtype/self._mixed/
+        self._kv_dtype_name/self._base_cache_dtype/
         self.prefill_chunk already set."""
         from cake_tpu.models.llama.paged import (
             PageAllocator, PagedKVCache, decode_step_ragged_paged,
             mixed_step_paged, mixed_token_buckets, prefill_prefix_pages,
-            prefill_slot_paged, prefill_slot_paged_chunk,
-            prefill_slot_paged_prefixed,
+            prefill_slot_paged,
         )
         if kv_pages < 1 or kv_page_size < 1:
             raise ValueError(
@@ -2617,8 +2593,8 @@ class InferenceEngine:
         # really dispatch (_resolve_paged_attn). The choice rides the
         # jitted steps as a STATIC arg, so both variants keep the same
         # traced signature and the engine's dispatch plumbing is
-        # impl-blind. `impl` serves the decode step and the
-        # phase-loop prefill programs (whose "pallas" is the flash
+        # impl-blind. `impl` serves the decode step and the two
+        # whole-window prefill programs (whose "pallas" is the flash
         # kernel over the fresh window, behind its own gate).
         pool_dtype = self._base_cache_dtype
         if self._kv_dtype_name is not None and not self.kv_quant:
@@ -2627,33 +2603,30 @@ class InferenceEngine:
         self._pool_dtype = pool_dtype
         self._resolve_paged_attn(paged_attn, kv_pages, kv_page_size)
         impl = self.attn_impl["decode"]
+        # whole-prompt prefill into one slot's pages: the paged spec's
+        # draft prefill (_spec_activate); requests' prompts ride the
+        # mixed step
         self._prefill_slot = partial(prefill_slot_paged, attn=impl)
         self._decode_step = partial(decode_step_ragged_paged, attn=impl)
         self._decode_scan_impl = (_decode_scan_paged if impl == "fold"
                                   else _decode_scan_paged_pallas)
-        # chunked paged prefill: long prompts admit in C-token windows
-        self._prefill_chunk_step = partial(prefill_slot_paged_chunk,
-                                           attn=impl)
         # page-granular prefix sharing: registered prefixes (and
         # auto_prefix_system heads) prefill ONCE into pool pages and
         # are mapped read-only into every matching slot's table row
         # (_alloc_slot_pages). _prefix_capable stays True.
-        self._paged_prefixed_step = partial(
-            prefill_slot_paged_prefixed, attn=impl)
         self._prefix_pages_step = partial(prefill_prefix_pages,
                                           attn=impl)
-        # token-level continuous batching (--mixed-batch): ONE jitted
-        # step consumes a batch of (row kind, pos, q_len) descriptors —
-        # decode rows and prefill-chunk rows in the same launch
+        # token-level continuous batching: ONE jitted step consumes a
+        # batch of (row kind, pos, q_len) descriptors — decode rows
+        # and prefill-chunk rows in the same launch
         self._mixed_step_fn = partial(mixed_step_paged,
                                       attn=self.attn_impl["mixed"])
         # the packed sizes a mixed step's dispatches run at (the
         # program's static n_tokens): _mixed_dispatch takes the smallest
         # that holds the tokens, start() runs each once so that none
         # compiles later
-        self._mixed_buckets = (
-            mixed_token_buckets(self.max_slots, self._mixed_chunk)
-            if self._mixed else ())
+        self._mixed_buckets = mixed_token_buckets(self.max_slots,
+                                                  self._mixed_chunk)
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -2784,9 +2757,8 @@ class InferenceEngine:
                 width = next((w for w in (256, 128, 64, 32, 16, 8)
                               if w <= width and mixed_ok(w)), width)
         ok = {"decode": rpa.ragged_paged_supported(
-            kv_page_size, *heads(self.config), **kw)}
-        if self._mixed:
-            ok["mixed"] = mixed_ok(width)
+                  kv_page_size, *heads(self.config), **kw),
+              "mixed": mixed_ok(width)}
         if self._specp is not None:
             # one static impl serves the whole round: the draft's
             # decode steps and the target's verify window (gamma+1
@@ -2888,7 +2860,6 @@ class InferenceEngine:
             # cakelint: skip[affinity] taking _switch_lock here would invert the declared order: checkpoint.snapshot calls this under _ckpt_lock (shutdown_save/_snapshot_before_fail); the unlocked read tolerates a torn value mid-switch (informational health/snapshot metadata only)
             kv_page_size=(self._pager.page_size if self.paged else 128),
             kv_dtype=kv_dtype,
-            mixed_batch="on" if self._mixed else "off",
             paged_attn=self.paged_attn or "auto",
         )
 
@@ -3213,7 +3184,6 @@ class InferenceEngine:
         self.paged = new.kv_pages is not None
         self.kv_quant = new.kv_dtype in ("int8", "int4")
         self._kv_dtype_name = new.kv_dtype
-        self._mixed = self.paged and (new.mixed_batch or "auto") != "off"
         # free the OLD cache/pool BEFORE building the new one: unlike
         # _reset_after_error (where donation already consumed the
         # buffers), reconfigure's old pool is fully live — keeping
@@ -4232,12 +4202,13 @@ class InferenceEngine:
         return True
 
     def _prefill_admit(self, rid: int, slot: int):
-        """Admission half of _do_prefill (the `schedule` phase): bind
-        the slot, fold a preempted request's tokens into its prompt,
-        match a prefix, allocate pages, adopt a shipped prefill.
-        Returns (req, t0, ids, prime, hit), or None when there is
-        nothing left to dispatch (cancelled, requeued for pages,
-        restored from the host tier, or adopted whole)."""
+        """The head of every admission (the `schedule` phase), dense
+        (_do_prefill) and paged (_mixed_admit): bind the slot, fold a
+        preempted request's tokens into its prompt, name the failure
+        blast radius. Returns (req, t0, ids, prime), or None when the
+        request was cancelled. Whatever needs pages (allocation, the
+        host tier's restore, a shipped prefill's adoption) lives in
+        _mixed_admit."""
         req = self._requests.get(rid)
         if req is None:  # cancelled between plan and here
             self.scheduler.cancel(rid)
@@ -4265,43 +4236,16 @@ class InferenceEngine:
         if self._faults is not None:
             self._faults.check("engine.prefill", step=self.stats.steps,
                                n_tokens=len(ids))
-        # shipped-prefill adoption (disaggregated decode host): a
-        # staged shipment replaces BOTH the prefix match and the local
-        # compute — the peer's pages hold the whole prompt, so the row
-        # allocates unshared. PEEK only here: the entry must survive a
-        # pool-exhausted requeue; it pops after the row exists.
-        with self._rid_lock:
-            adopt = self._adopt_store.get(rid)
-        # match BEFORE page admission: a paged prefix hit changes the
-        # allocation itself (suffix + budget pages only, prefix pages
-        # mapped shared)
-        hit = (self._match_and_validate_prefix(ids)
-               if self._prefix_capable and adopt is None else None)
-        if self.paged and not self._alloc_slot_pages(req, slot, hit):
-            return None   # pool exhausted: requeued (or failed) inside
-        if self.paged:
-            hit = req._effective_hit   # spilled-prefix restore failure
-        if getattr(req, "_kv_restored", False):
-            # spilled preemption victim restored from the host tier:
-            # KV and sampling state already sit at the preemption
-            # frontier — no prefill dispatch at all (the token that
-            # recompute-resume would re-derive was already emitted)
-            req._kv_restored = False
-            return None
-        if adopt is not None:
-            with self._rid_lock:
-                self._adopt_store.pop(rid, None)
-            if not req.out_tokens \
-                    and self._adopt_install(req, slot, adopt):
-                return None   # pages installed, first token emitted
-            # refused (stale epoch / geometry / injected fault): fall
-            # through — whole-prompt prefill rewrites the row's pages
-            # and scales, the documented degradation
-        return req, t0, ids, prime, hit
+        return req, t0, ids, prime
 
     def _do_prefill(self, rid: int, slot: int, defer: bool = False):
-        """Prefill one admission. defer=False: dispatch, fetch, emit —
-        the multi-host lockstep path. defer=True: dispatch only; returns
+        """Prefill one admission of a DENSE engine (slot, ring or
+        pipelined cache). A paged engine reaches neither this nor any
+        helper below it (_prefill_device, _prefixed_prefill_device,
+        _prefill_chunked, _do_prefill_batch; only the admission head
+        _prefill_admit is shared): its prompts ride _do_mixed.
+        defer=False: dispatch, fetch, emit — the multi-host lockstep
+        path. defer=True: dispatch only; returns
         (req, t0, slot, dev) for _do_prefill_batch, which fetches every
         admission's first token in ONE host round-trip (a per-admission
         fetch waits for the device once per request — it adds up in
@@ -4310,7 +4254,9 @@ class InferenceEngine:
             admitted = self._prefill_admit(rid, slot)
         if admitted is None:
             return None
-        req, t0, ids, prime, hit = admitted
+        req, t0, ids, prime = admitted
+        hit = (self._match_and_validate_prefix(ids)
+               if self._prefix_capable else None)
         n_top = self._n_top_for([slot])
         if hit is not None:
             hit_pid, entry = hit
@@ -4352,7 +4298,6 @@ class InferenceEngine:
         tok, lp, top = out
         dt = time.perf_counter() - t0
         self.stats.prefill_time_s += dt
-        self._obs_paged_step("prefill", dt)
         self._record_step("prefill", rows=1, tokens=1, wall_s=dt,
                           rids=(rid,))
         with self.flight.span("emit"):
@@ -4393,7 +4338,6 @@ class InferenceEngine:
             # PREFILL_FLUSH times
             dt = time.perf_counter() - pend[0][1]
             self.stats.prefill_time_s += dt
-            self._obs_paged_step("prefill", dt / len(pend))
             # one record per admission GROUP (per-admission walls would
             # multi-count the overlap), with the group's SUMMED FLOPs /
             # bytes over the group wall — and a compile anywhere in the
@@ -4409,8 +4353,7 @@ class InferenceEngine:
                 "prefill", rows=len(pend), tokens=len(pend), wall_s=dt,
                 cost=cost,
                 compiled=any(js is not None and js.new for js in pend_js),
-                rids=[req.rid for (req, _t0, _s, _d) in pend],
-                **self._page_kw())
+                rids=[req.rid for (req, _t0, _s, _d) in pend])
             with self.flight.span("emit"):
                 for (req, t0, slot, _), host in zip(pend, hosts):
                     tok, lp, top = self._finish_prefill_complete(slot,
@@ -4430,7 +4373,7 @@ class InferenceEngine:
         if pend:
             flush()
 
-    # -- token-level continuous batching (--mixed-batch) ------------------
+    # -- token-level continuous batching (the paged engine) --------------
 
     def _prime_ring(self, slot: int, prime) -> None:
         """Reset one slot's repeat-penalty ring + step counter, seeding
@@ -4467,14 +4410,14 @@ class InferenceEngine:
                 for rid, slot in prefill_plan:
                     self._mixed_admit(rid, slot)
         if not self._mixed_pending:
-            # pure decode: the phase path's programs are strictly
-            # cheaper here (C=1 step, K-step scan bursts) and no
-            # admission is waiting on a step boundary
+            # pure decode: the decode programs are strictly cheaper
+            # here (C=1 step, K-step scan bursts) and no admission is
+            # waiting on a step boundary
             if decode_plan and self._resident_parked:
                 # an admission above parked a decode-resident slot
                 # (_spill_resident_stream): drop its stale row before
                 # the device step (_mixed_dispatch re-validates per
-                # row; these phase-path programs do not)
+                # row; the decode programs do not)
                 decode_plan = self._live_decode_rows(decode_plan)
             if decode_plan and self._specp is not None:
                 # spec rows ride one batched draft+verify round; rows
@@ -4492,45 +4435,37 @@ class InferenceEngine:
         self._mixed_dispatch(decode_plan)
 
     def _mixed_admit(self, rid: int, slot: int) -> None:
-        """Admission half of _do_prefill for the mixed path: page
-        mapping, prefix matching, and sampling-state setup — but NO
-        device dispatch; the prompt's windows ride the next mixed
-        step(s) as chunk rows."""
-        req = self._requests.get(rid)
-        if req is None:  # cancelled between plan and here
-            self.scheduler.cancel(rid)
+        """The paged engine's one admission: after the shared head
+        (_prefill_admit) match a prefix, allocate pages (or restore
+        them from the host tier, or adopt a shipped prefill), set up
+        the sampling state — and NO device dispatch: the prompt's
+        windows ride the next mixed step(s) as chunk rows."""
+        admitted = self._prefill_admit(rid, slot)
+        if admitted is None:
             return
-        self.tracer.prefill_start(rid)
-        req.slot = slot
-        self._slot_req[slot] = req
-        ids = req.prompt_ids
-        prime = req.prime_tokens
-        if req.out_tokens:
-            # preempted-and-requeued: recompute-style resume — the
-            # generated tokens fold into the prompt and the penalty
-            # ring reconstructs over the whole transcript (_do_prefill
-            # precedent, serve/checkpoint.resume semantics)
-            ids = list(req.prompt_ids) + list(req.out_tokens)
-            prime = list(req.prime_tokens) + list(req.out_tokens)
-        # blast radius + content-keyed fault site (see _do_prefill)
-        self._implicated = ((rid, slot),)
-        if self._faults is not None:
-            self._faults.check("engine.prefill", step=self.stats.steps,
-                               n_tokens=len(ids))
-        # shipped-prefill adoption: PEEK before the prefix match (an
-        # adopted row allocates unshared), pop after the row exists —
-        # see _do_prefill for the full discipline
+        req, _t0, ids, prime = admitted
+        # shipped-prefill adoption (disaggregated decode host): a
+        # staged shipment replaces BOTH the prefix match and the local
+        # compute — the peer's pages hold the whole prompt, so the row
+        # allocates unshared. PEEK only here: the entry must survive a
+        # pool-exhausted requeue; it pops after the row exists.
         with self._rid_lock:
             adopt = self._adopt_store.get(rid)
+        # match BEFORE page admission: a prefix hit changes the
+        # allocation itself (suffix + budget pages only, prefix pages
+        # mapped shared)
         hit = (self._match_and_validate_prefix(ids)
                if self._prefix_capable and adopt is None else None)
-        if self.paged and not self._alloc_slot_pages(req, slot, hit):
+        if not self._alloc_slot_pages(req, slot, hit):
             return   # pool exhausted: requeued (or failed) inside
         hit = req._effective_hit       # spilled-prefix restore failure
         if getattr(req, "_kv_restored", False):
-            # spilled victim restored (see _do_prefill): the slot
-            # resumes mid-decode — it must NOT ride the next mixed
-            # step as a chunk row
+            # spilled preemption victim restored from the host tier:
+            # KV and sampling state already sit at the preemption
+            # frontier (the token that recompute-resume would
+            # re-derive was already emitted) — the slot resumes
+            # mid-decode and must NOT ride the next mixed step as a
+            # chunk row
             req._kv_restored = False
             return
         if adopt is not None:
@@ -4541,7 +4476,9 @@ class InferenceEngine:
                 # the slot resumes as a DECODE row from the shipped
                 # frontier — it must not also ride as a chunk row
                 return
-            # refused: fall through to local chunked prefill
+            # refused (stale epoch / geometry / injected fault): fall
+            # through — local prefill rewrites the row's pages and
+            # scales, the documented degradation
         off = 0
         if hit is not None:
             # shared prefix pages already mapped at the row head
@@ -4772,15 +4709,13 @@ class InferenceEngine:
         One clamp rule for every engine: windows (or the padded
         single-program bucket) must never clamp over the live prefix.
         The pipelined engine ALWAYS windows the suffix at pos0 = P (it
-        has no single-program prefixed-prefill variant); the dense and
-        paged engines window only when --prefill-chunk applies, else
-        take their single program (prefill_slot_prefixed /
-        prefill_slot_paged_prefixed)."""
+        has no single-program prefixed-prefill variant); the dense
+        engine windows only when --prefill-chunk applies, else takes
+        its single program (prefill_slot_prefixed). A paged engine's
+        suffix rides the mixed step's windows from the prefix's end
+        (_mixed_admit) and is held to the same rule."""
         C = self.prefill_chunk
         suffix = ids[len(p_ids):]
-        # the paged engine has its own single-program prefixed prefill
-        # (prefill_slot_paged_prefixed), so only a genuinely pipelined
-        # custom path is forced through suffix windows
         pipelined = (self._prefill_slot is not prefill_slot
                      and not self.paged)
         if pipelined or (C and len(suffix) > C):
@@ -4819,35 +4754,6 @@ class InferenceEngine:
         chunk_suffix, width = plan
         suffix = ids[len(p_ids):]
         _PREFIX_TOKENS_SAVED.inc(len(p_ids))
-        if self.paged:
-            # the shared prefix pages are ALREADY mapped at the head of
-            # this slot's table row (_alloc_slot_pages) — no install
-            # step at all. The suffix prefills through the paged
-            # prefixed program (single window) or the paged chunk fn
-            # (which attends everything written through the table,
-            # prefix head included).
-            _PREFIX_PAGED_HITS.inc()
-            if chunk_suffix:
-                logits = self._prefill_chunked(suffix, slot, width,
-                                               pos0=len(p_ids))
-            else:
-                padded = suffix + [0] * (width - len(suffix))
-                fargs = (self.params, jnp.asarray([padded], jnp.int32),
-                         jnp.asarray([len(suffix)], jnp.int32),
-                         jnp.int32(slot), self.cache, self.rope,
-                         self.config)
-                fkw = dict(n_prefix=len(p_ids))
-                js = self._obs_jit("prefill_paged_prefixed",
-                                   (width, len(p_ids)),
-                                   self._paged_prefixed_step, fargs, fkw)
-                t0 = time.perf_counter()
-                logits, self.cache = self._paged_prefixed_step(*fargs,
-                                                               **fkw)
-                js.finish(time.perf_counter() - t0)
-                self._last_jit = js
-            return self._finish_prefill(logits, slot, len(ids), temp,
-                                        top_p, penalty, prime,
-                                        n_top=n_top, defer=defer)
         if chunk_suffix:
             from cake_tpu.models.llama.model import install_prefix_slot
             self.cache = install_prefix_slot(self.cache, pk, pv,

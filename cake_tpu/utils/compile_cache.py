@@ -1,7 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point that compiles (cli.main, the bench.py
-tier children): where JAX_COMPILATION_CACHE_DIR is set, JAX itself
+One rule for every entry point that compiles (cli.main): where JAX_COMPILATION_CACHE_DIR is set, JAX itself
 keeps the cache there and this module sets no other path; where it is
 not, the cache goes to `<checkout>/.jax_cache` — a fixed path, because
 the path is part of how a later process finds the entries, so a

@@ -26,13 +26,23 @@ TOOLS = pathlib.Path(__file__).resolve().parents[1] / "tools"
 # -- space ------------------------------------------------------------------
 
 
-def test_config_roundtrip_and_unknown_keys():
+# `mixed_batch` was a knob until PR 28 (a paged engine has one prefill
+# path now): a policy file or a POST /api/v1/autotune body that still
+# carries it is refused by name, like any key that never was one
+@pytest.mark.parametrize("key,value", [("max_seq_len", 512),
+                                       ("mixed_batch", "off")])
+def test_config_roundtrip_and_unknown_keys(key, value):
     cfg = EngineConfig(slots=16, decode_scan=4, kv_pages=64,
                        kv_page_size=128, kv_dtype="int8",
-                       mixed_batch="on", paged_attn="fold")
+                       paged_attn="fold")
     assert EngineConfig.from_dict(cfg.to_dict()) == cfg
-    with pytest.raises(ValueError, match="unknown engine config"):
-        EngineConfig.from_dict({"slots": 4, "max_seq_len": 512})
+    with pytest.raises(ValueError,
+                       match=f"unknown engine config keys .'{key}'"):
+        EngineConfig.from_dict({"slots": 4, key: value})
+    with pytest.raises(ValueError, match=key):
+        PolicyTable.from_dict({"version": 1, "regimes": [
+            {"max_offered_rps": None,
+             "config": {"slots": 4, key: value}}]})
 
 
 def test_validate_reuses_args_rules():
@@ -41,8 +51,6 @@ def test_validate_reuses_args_rules():
         validate_config(EngineConfig(kv_dtype="int8"))
     with pytest.raises(ValueError, match="paged_attn"):
         validate_config(EngineConfig(paged_attn="nope"))
-    with pytest.raises(ValueError, match="mixed_batch"):
-        validate_config(EngineConfig(mixed_batch="sometimes"))
     with pytest.raises(ValueError, match="max-slots"):
         validate_config(EngineConfig(slots=0))
     with pytest.raises(ValueError, match=">= 1"):
@@ -52,8 +60,6 @@ def test_validate_reuses_args_rules():
     # additionally refuse pools an in-flight stream does not fit)
     validate_config(EngineConfig(kv_pages=2, kv_page_size=16),
                     max_seq_len=128)
-    with pytest.raises(ValueError, match="mixed_batch=on requires"):
-        validate_config(EngineConfig(mixed_batch="on"))
 
 
 def test_config_key_normalizes_spellings():

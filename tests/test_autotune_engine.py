@@ -396,3 +396,26 @@ def test_api_autotune_contract(tiny_config, params):
         assert api.health()["autotune"] == "off"
         with pytest.raises(ValueError, match="autotune is off"):
             api.autotune_switch({"config": {"slots": 4}})
+
+
+def test_api_autotune_refuses_the_removed_mixed_batch_knob(tiny_config,
+                                                           params):
+    """`mixed_batch` was a switchable knob until PR 28. A paged engine
+    has one prefill path now: a POST body that still carries the key is
+    refused by name and nothing switches, and the reported config has
+    no such key."""
+    from cake_tpu.api.server import ApiServer
+
+    class _M:
+        args = None
+
+    with _engine(tiny_config, params, autotune="manual", kv_pages=24,
+                 kv_page_size=16) as eng:
+        api = ApiServer(_M(), engine=eng)
+        assert "mixed_batch" not in api.health()["engine_config"]
+        with pytest.raises(ValueError,
+                           match="unknown engine config keys .'mixed_batch'"):
+            api.autotune_switch({"config": {"slots": 4,
+                                            "mixed_batch": "off"}})
+        assert api.health()["config_epoch"] == 0
+        assert api.health()["engine_config"]["slots"] == 2

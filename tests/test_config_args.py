@@ -50,6 +50,19 @@ def test_parse_args_roundtrip():
     assert img.sd_n_steps == 20
 
 
+def test_mixed_batch_option_is_gone(capsys):
+    """--mixed-batch chose between the mixed step and the paged phase
+    loop until PR 28; a paged engine has one prefill path now, and a
+    command line that still carries the flag is refused by name."""
+    with pytest.raises(SystemExit) as e:
+        parse_args(["--model", "/tmp/m", "--kv-pages", "16",
+                    "--mixed-batch", "off"])
+    assert e.value.code == 2
+    assert "unrecognized arguments: --mixed-batch off" \
+        in capsys.readouterr().err
+    assert not hasattr(Args(), "mixed_batch")
+
+
 def test_args_validate_dtype():
     with pytest.raises(ValueError):
         Args(dtype="f8").validate()

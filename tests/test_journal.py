@@ -291,8 +291,7 @@ def test_dense_replay_token_identical_after_abandon(
 def test_paged_shared_prefix_replay_identical_and_pool_conserved(
         tiny_config, params, tmp_path):
     prefix = [7] * PAGE
-    kw = dict(kv_pages=16, kv_page_size=PAGE, paged_attn="fold",
-              mixed_batch="off")
+    kw = dict(kv_pages=16, kv_page_size=PAGE, paged_attn="fold")
 
     def submit_wave(eng):
         pid = eng.register_prefix(prefix)

@@ -25,8 +25,17 @@ from cake_tpu.kv.host_tier import HostTier, SpilledPages
 from cake_tpu.kv.quantized_pool import (
     Int4PagedKVCache, QuantPool, QuantizedPagedKVCache,
     dequantize_pages, page_bytes, qupdate_pool_per_row,
-    qwrite_prompt_pages, qwrite_window_pages, reset_page_scales,
+    qwrite_prompt_pages, qwrite_windows_pages, reset_page_scales,
 )
+
+
+def _qwrite_window(pool, layer, vals, row, pos0, n_real=None):
+    """One row's window ([1, C, KV, hd] at absolute position pos0,
+    n_real of its C positions real) through the batched writer."""
+    n = vals.shape[1] if n_real is None else n_real
+    return qwrite_windows_pages(
+        pool, layer, vals, jnp.asarray([pos0], jnp.int32),
+        jnp.asarray([n], jnp.int32), jnp.asarray([True]), row[None])
 
 T = 64
 PAGE = 16
@@ -177,9 +186,9 @@ def test_quantized_write_error_bound_and_isolation():
     got = dequantize_pages(pool2, layer, jnp.asarray([9]))[0][3]
     assert float(jnp.max(jnp.abs(got - tok[0, 0]))) < 0.05
     # window write at an arbitrary offset into fresh scale-reset pages
-    pool3 = qwrite_window_pages(
+    pool3 = _qwrite_window(
         pool2, layer, tok, jnp.asarray([7, 2, 9, -1], jnp.int32),
-        jnp.int32(2 * PAGE + 4))
+        2 * PAGE + 4)
     got3 = dequantize_pages(pool3, layer, jnp.asarray([9]))[0][4]
     assert float(jnp.max(jnp.abs(got3 - tok[0, 0]))) < 0.05
     for p in (pool, pool2, pool3):
@@ -217,18 +226,16 @@ def test_bucket_padding_cannot_inflate_scales():
     bad = qwrite_prompt_pages(pool0, layer, garbage, row)
     assert float(jnp.max(jnp.abs(bad.scale - want.scale))) > 0
 
-    # chunk window writer: C-token window, 4 real, huge padding
+    # window writer: C-token window, 4 real, huge padding
     win = jnp.asarray(rng.normal(size=(1, PAGE + 5, KV, hd)),
                       jnp.float32)
     win = win.at[:, 4:].mul(100.0)
-    got = qwrite_window_pages(pool0, layer, win, row, jnp.int32(3),
-                              jnp.int32(4))
-    want = qwrite_window_pages(pool0, layer, win[:, :4], row,
-                               jnp.int32(3))
+    got = _qwrite_window(pool0, layer, win, row, 3, 4)
+    want = _qwrite_window(pool0, layer, win[:, :4], row, 3)
     np.testing.assert_array_equal(np.asarray(got.q), np.asarray(want.q))
     np.testing.assert_array_equal(np.asarray(got.scale),
                                   np.asarray(want.scale))
-    bad = qwrite_window_pages(pool0, layer, win, row, jnp.int32(3))
+    bad = _qwrite_window(pool0, layer, win, row, 3)
     assert float(jnp.max(jnp.abs(bad.scale - want.scale))) > 0
 
 
@@ -661,10 +668,9 @@ def test_engine_int4_fold_matches_pallas(tiny_config, params):
 
 @pytest.mark.slow  # four engine phases under oversubscription -> slow lane
 @pytest.mark.parametrize("kw", [
-    dict(mixed_batch="off"),
-    dict(mixed_batch="on"),
+    dict(),
     dict(priority_classes=True),
-], ids=["fifo", "mixed", "slo"])
+], ids=["fifo", "slo"])
 def test_resident_spill_restore_token_identity_f32(tiny_config, params,
                                                    kw):
     """THE decode-resident spill acceptance bar: a 2-page pool serving
@@ -673,8 +679,8 @@ def test_resident_spill_restore_token_identity_f32(tiny_config, params,
     admits, the streams time-slice in resident_quantum turns, and both
     emit tokens identical to a non-oversubscribed run (f32 KV). Pool
     conserved and the host tier drained once everyone retired.
-    Parametrized over the FIFO requeue path, the mixed-batch planner,
-    and the SLO scheduler's requeue path."""
+    Parametrized over the FIFO requeue path and the SLO scheduler's
+    requeue path."""
     prompts = [[5] * 9, [3, 7, 9, 11, 2]]
 
     def run(**extra):
@@ -719,24 +725,22 @@ def test_resident_spill_disabled_by_sched_config(tiny_config, params):
 
 
 @pytest.mark.slow  # pool-pressure engine runs -> slow lane
-@pytest.mark.parametrize("mixed", ["off", "on"])
 def test_host_evicted_prefix_degrades_to_full_prefill(
-        tiny_config, params, mixed):
+        tiny_config, params):
     """A spilled prefix whose host entry is gone (LRU-evicted) must
     degrade the admission to a whole-prompt prefill: the stale hit is
-    dropped BEFORE dispatch, so the request never attends the
-    never-written prefix region. Parametrized over both admission
-    paths (_do_prefill and _admit_mixed)."""
+    dropped BEFORE dispatch (_mixed_admit), so the request never
+    attends the never-written prefix region."""
     prompt = list(range(3, 35)) + [7] * 5
     ref = _engine(tiny_config, params, max_seq_len=128, kv_pages=8,
-                  kv_dtype="f32", mixed_batch=mixed)
+                  kv_dtype="f32")
     with ref:
         h = ref.submit(prompt, max_new_tokens=4)
         assert h.wait(timeout=300)
         want = list(h._req.out_tokens)
 
     eng = _engine(tiny_config, params, max_seq_len=128, kv_pages=6,
-                  kv_dtype="f32", kv_host_pages=4, mixed_batch=mixed)
+                  kv_dtype="f32", kv_host_pages=4)
     with eng:
         pid = eng.register_prefix(list(range(3, 35)))     # 2 pages
         # oversubscribe the pool so the cold prefix spills to host

@@ -1,10 +1,10 @@
-"""Token-level continuous batching (--mixed-batch): ONE mixed ragged
-step for prefill chunks and decode rows on the paged engine.
+"""Token-level continuous batching: ONE mixed ragged step for prefill
+chunks and decode rows on the paged engine.
 
 Bars:
   * greedy token equality at f32 KV (the repo convention for
-    token-equality tests): mixed == phase-split == the dense oracle,
-    for both paged-attention impls, multi-window prompts included;
+    token-equality tests): mixed == the dense oracle, for both
+    paged-attention impls, multi-window prompts included;
   * no decode pause: a request admitted mid-decode gets its first
     chunk in the very next step — a `mixed` flight record carrying
     BOTH row kinds — including under preemption;
@@ -74,21 +74,16 @@ def _both_kind_steps(eng):
 PROMPTS = [[5] * 9, [11] * 14, [3, 7, 9]]
 
 
-def test_mixed_token_equality_vs_dense_and_phase_split(tiny_config,
-                                                       params):
-    """Mixed-step serving == phase-split paged == the dense oracle,
-    greedy at f32 KV, for both attention impls — with prefill_chunk=8
-    so the 14-token prompt walks MULTIPLE mixed windows."""
+def test_mixed_token_equality_vs_dense(tiny_config, params):
+    """Mixed-step serving == the dense oracle (the dense engine's
+    prefill/decode phases), greedy at f32 KV, for both attention impls
+    — with prefill_chunk=8 so the 14-token prompt walks MULTIPLE mixed
+    windows."""
     want = _run_tokens(_engine(tiny_config, params), PROMPTS)
-    off = _run_tokens(
-        _engine(tiny_config, params, kv_pages=24, kv_page_size=PAGE,
-                mixed_batch="off"), PROMPTS)
-    assert off == want
     for impl in ("fold", "pallas"):
         eng = _engine(tiny_config, params, kv_pages=24,
                       kv_page_size=PAGE, paged_attn=impl,
-                      prefill_chunk=8, mixed_batch="on")
-        assert eng._mixed
+                      prefill_chunk=8)
         got = _run_tokens(eng, PROMPTS)
         assert got == want, f"paged_attn={impl}"
         assert eng._pager.free_pages == 24
@@ -232,24 +227,6 @@ def test_no_mixed_step_compiles_after_start(sixteen_slots, arrivals):
         assert first["tokens_real"] > 2 * sizes[-1]
     for name, key in zip(TOKEN_SERIES, ("tokens_real", "tokens_computed")):
         assert _metric(name) - before[name] == sum(r[key] for r in mixed)
-
-
-def test_mixed_off_keeps_phase_split(tiny_config, params):
-    eng = _engine(tiny_config, params, kv_pages=24, kv_page_size=PAGE,
-                  mixed_batch="off")
-    assert not eng._mixed
-    _run_tokens(eng, [[5] * 9])
-    assert not [r for r in eng.flight.dump() if r["kind"] == "mixed"]
-    kinds = {r["kind"] for r in eng.flight.dump()}
-    assert "prefill" in kinds and "decode" in kinds
-
-
-def test_mixed_on_requires_paged(tiny_config, params):
-    with pytest.raises(ValueError, match="kv-pages"):
-        _engine(tiny_config, params, mixed_batch="on")
-    with pytest.raises(ValueError, match="mixed-batch"):
-        _engine(tiny_config, params, kv_pages=24, kv_page_size=PAGE,
-                mixed_batch="bogus")
 
 
 @pytest.mark.slow  # two engines under staggered load -> slow lane

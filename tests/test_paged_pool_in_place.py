@@ -31,7 +31,7 @@ from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import (
     PagedKVCache, _kernel_pools, decode_step_ragged_paged, mixed_step_paged,
     paged_attention, paged_attention_mixed, update_pool_per_row,
-    write_prompt_pages, write_window_pages, write_windows_pages,
+    write_prompt_pages, write_windows_pages,
 )
 from cake_tpu.models.llama.params import init_params
 from cake_tpu.ops.ragged_paged_attention import (
@@ -267,8 +267,9 @@ def test_writers_and_kernels_honour_the_layer(step, kind):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_prefill_window_writers_honour_the_layer(kind):
-    """write_prompt_pages and write_window_pages (the four prefill
-    programs' writers) at the last layer of a stacked pool."""
+    """write_prompt_pages (the whole-window prefill programs' writer)
+    and write_windows_pages for one row at an offset inside a page, at
+    the last layer of a stacked pool."""
     rng = np.random.default_rng(8)
     pk0, pv0 = _stacked(rng, kind)
     layer = L - 1
@@ -277,9 +278,10 @@ def test_prefill_window_writers_honour_the_layer(kind):
     for write in (
             lambda a, b, l: write_prompt_pages(a, b, l, k, k, row,
                                                jnp.int32(PAGE + 3)),
-            lambda a, b, l: write_window_pages(a, b, l, k, k, row,
-                                               jnp.int32(2),
-                                               jnp.int32(PAGE + 3))):
+            lambda a, b, l: write_windows_pages(
+                a, b, l, k, k, jnp.asarray([2], jnp.int32),
+                jnp.asarray([PAGE + 3], jnp.int32), jnp.asarray([True]),
+                row[None])):
         pk, pv = write(pk0, pv0, jnp.int32(layer))
         ak, av = write(_one_layer(pk0, layer), _one_layer(pv0, layer), 0)
         assert _leaves_equal(ak, _one_layer(pk, layer))

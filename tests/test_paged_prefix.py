@@ -5,10 +5,10 @@ Three layers of bar:
     double-free / foreign-id rejection, and `free + live == n_pages`
     under random admit/retire/cancel/requeue interleavings (incl.
     shared prefixes);
-  * step-fn parity — prefill_slot_paged_prefixed and
-    prefill_slot_paged_chunk must match the dense/whole-window oracle
-    for BOTH attn impls (fold == pallas, tests/test_ragged_paged_attn.py
-    style);
+  * step-program parity — the mixed step (the one program a paged
+    engine prefills through) must match the dense / whole-window
+    oracle at logit level on a prefix hit and for windows at any page
+    offset, for BOTH attn impls and a float and an int8 pool;
   * engine equivalence — a paged engine serving shared prefixes (and
     chunked prefills) emits token-identical streams to unshared serving
     at f32 cache (bf16 storage flips greedy near-ties — the PR 2
@@ -24,9 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from cake_tpu.models.llama.paged import (
-    PageAllocator, PagedKVCache, prefill_prefix_pages,
-    prefill_slot_paged, prefill_slot_paged_chunk,
-    prefill_slot_paged_prefixed, table_set_slot,
+    PageAllocator, PagedKVCache, mixed_step_paged, mixed_token_buckets,
+    prefill_prefix_pages, prefill_slot_paged, table_set_slot,
 )
 
 PAGE = 16
@@ -131,84 +130,134 @@ def test_allocator_random_interleavings():
     assert alloc.free_pages == 24 and alloc.live_pages == 0
 
 
-# -- step-fn parity (fold == pallas == oracle) --------------------------------
+# -- step-program parity: the mixed step against whole-prompt oracles ----------
+#
+# A paged engine prefills through mixed_step_paged alone (PR 28): a
+# prompt walks it in windows from position 0, or from the end of a
+# shared prefix whose pages sit at the head of its table row. Logits of
+# the prompt's last token, for both attention impls and for a float and
+# a quantized pool, through the window program and a packed size.
 
 
-def _dup(c: PagedKVCache) -> PagedKVCache:
+POOLS = {"float32": 2e-4, "int8": 4e-2}     # logit tolerance by pool
+# a 37-token prompt: a 32-token (2-page) head and a 5-token tail
+IDS = [5] * 20 + [9] * 12 + [3, 7, 9, 11, 2]
+C = 16            # the mixed step's window width
+SLOTS = 2
+
+
+def _pool(cfg, pool: str, n_pages: int = 10):
+    if pool == "int8":
+        from cake_tpu.kv.quantized_pool import QuantizedPagedKVCache
+        return QuantizedPagedKVCache.create(cfg, SLOTS, n_pages, PAGE, T)
+    return PagedKVCache.create(cfg, SLOTS, n_pages, PAGE, T,
+                               dtype=jnp.float32)
+
+
+def _dup(c):
     """Fresh buffers so donating step fns can't consume a fixture."""
-    return PagedKVCache(jnp.array(c.k), jnp.array(c.v),
-                        jnp.array(c.table))
+    return jax.tree_util.tree_map(jnp.array, c)
 
 
-def test_prefixed_step_parity(tiny_config, params):
-    """prefill_slot_paged_prefixed (suffix window + mapped prefix
-    pages) == dense whole-prompt prefill_slot logits, fold and pallas
-    both."""
+def _mixed_window(params, cfg, rope, cache, slot, window, pos, attn,
+                  n_tokens):
+    """One prefill window of row `slot` through the mixed step, every
+    other row idle. Returns (the row's logits, cache)."""
+    tokens = np.zeros((SLOTS, C), np.int32)
+    tokens[slot, :len(window)] = window
+    q_len = np.zeros(SLOTS, np.int32)
+    q_len[slot] = len(window)
+    posv = np.zeros(SLOTS, np.int32)
+    posv[slot] = pos
+    logits, cache = mixed_step_paged(
+        params, jnp.asarray(tokens), jnp.asarray(posv), jnp.asarray(q_len),
+        jnp.asarray(q_len > 0), cache, rope, cfg, attn=attn,
+        n_tokens=n_tokens)
+    return logits[slot], cache
+
+
+@pytest.mark.parametrize("pool", list(POOLS))
+@pytest.mark.parametrize("attn", ["fold", "pallas"])
+def test_mixed_prefix_hit_matches_dense_oracle(tiny_config, params, attn,
+                                               pool):
+    """A prefix hit as the engine serves it: the head prefilled once
+    into pool pages (prefill_prefix_pages), those pages mapped at the
+    head of the slot's row, the suffix one mixed-step window at
+    pos = 32 == the dense engine's whole-prompt prefill_slot logits."""
     from cake_tpu.models.llama.cache import KVCache
     from cake_tpu.models.llama.generator import bucket_length
     from cake_tpu.models.llama.model import RopeTables, prefill_slot
 
     cfg = tiny_config
     rope = RopeTables.create(cfg, T)
-    ids = [5] * 20 + [9] * 12 + [3, 7, 9, 11, 2]   # 32-prefix + 5-suffix
-    prefix, suffix = ids[:32], ids[32:]
-
-    dense = KVCache.create(cfg, 2, T, dtype=jnp.float32)
-    bucket = bucket_length(len(ids), T)
+    prefix, suffix = IDS[:32], IDS[32:]
+    dense = KVCache.create(cfg, SLOTS, T, dtype=jnp.float32)
+    bucket = bucket_length(len(IDS), T)
     want, _ = prefill_slot(
-        params, jnp.asarray([ids + [0] * (bucket - len(ids))], jnp.int32),
-        jnp.asarray([len(ids)], jnp.int32), jnp.int32(0), dense, rope, cfg)
+        params, jnp.asarray([IDS + [0] * (bucket - len(IDS))], jnp.int32),
+        jnp.asarray([len(IDS)], jnp.int32), jnp.int32(0), dense, rope, cfg)
 
     alloc = PageAllocator(n_pages=10, page_size=PAGE)
-    paged = PagedKVCache.create(cfg, 2, 10, PAGE, T, dtype=jnp.float32)
+    paged = _pool(cfg, pool)
     ppages = alloc.alloc(32)
     row = np.full(paged.table.shape[1], -1, np.int64)
     row[:len(ppages)] = ppages
     paged = prefill_prefix_pages(params, jnp.asarray([prefix], jnp.int32),
-                                 jnp.asarray(row, jnp.int32), _dup(paged),
-                                 rope, cfg)
+                                 jnp.asarray(row, jnp.int32), paged,
+                                 rope, cfg, attn=attn)
     spages = alloc.alloc(len(suffix) + 8)
     alloc.retain(ppages)
     paged = paged._replace(
         table=table_set_slot(paged.table, 0, list(ppages) + spages))
-    sb = bucket_length(len(suffix), T)
-    toks = jnp.asarray([suffix + [0] * (sb - len(suffix))], jnp.int32)
-    for attn in ("fold", "pallas"):
-        got, _ = prefill_slot_paged_prefixed(
-            params, toks, jnp.asarray([len(suffix)], jnp.int32),
-            jnp.int32(0), _dup(paged), rope, cfg, n_prefix=32, attn=attn)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-4, rtol=2e-4)
+    head_pages = jax.tree_util.tree_map(
+        lambda x: np.asarray(x[:, np.asarray(ppages)]),
+        (paged.k, paged.v))
+    for n_tokens in (None, mixed_token_buckets(SLOTS, C)[0]):
+        got, after = _mixed_window(params, cfg, rope, _dup(paged), 0,
+                                   suffix, 32, attn, n_tokens)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
+                                   atol=POOLS[pool], rtol=POOLS[pool])
+        # the shared head is read-only: the suffix wrote its own pages
+        jax.tree_util.tree_map(
+            lambda x, h: np.testing.assert_array_equal(
+                np.asarray(x[:, np.asarray(ppages)]), h),
+            (after.k, after.v), head_pages)
 
 
-def test_chunk_step_parity(tiny_config, params):
-    """prefill_slot_paged_chunk windows (16-token C over a 37-token
-    prompt, windows straddling page offsets) == whole-window paged
-    prefill, fold and pallas both."""
-    from cake_tpu.models.llama.generator import bucket_length, chunk_windows
+@pytest.mark.parametrize("pool", list(POOLS))
+@pytest.mark.parametrize("attn", ["fold", "pallas"])
+def test_mixed_windows_at_any_page_offset_match_whole_prompt(
+        tiny_config, params, attn, pool):
+    """The 37-token prompt walked through the mixed step in windows of
+    C = 16 == whole-window paged prefill, for page-aligned windows
+    (16, 16, 5: starts 0, 16, 32) and for windows that straddle page
+    boundaries (5, 16, 16: starts 0, 5, 21 on 16-token pages)."""
+    from cake_tpu.models.llama.generator import bucket_length
     from cake_tpu.models.llama.model import RopeTables
 
     cfg = tiny_config
     rope = RopeTables.create(cfg, T)
-    ids = [5] * 20 + [9] * 12 + [3, 7, 9, 11, 2]
     alloc = PageAllocator(n_pages=10, page_size=PAGE)
-    pg0 = PagedKVCache.create(cfg, 2, 10, PAGE, T, dtype=jnp.float32)
-    pages = alloc.alloc(len(ids) + 8)
+    pg0 = _pool(cfg, pool)
+    pages = alloc.alloc(len(IDS) + 8)
     pg0 = pg0._replace(table=table_set_slot(pg0.table, 1, pages))
-    bucket = bucket_length(len(ids), T)
+    bucket = bucket_length(len(IDS), T)
     want, _ = prefill_slot_paged(
-        params, jnp.asarray([ids + [0] * (bucket - len(ids))], jnp.int32),
-        jnp.asarray([len(ids)], jnp.int32), jnp.int32(1), _dup(pg0),
-        rope, cfg)
-    for attn in ("fold", "pallas"):
-        pg = _dup(pg0)
-        for w, n, start in chunk_windows(ids, 16):
-            got, pg = prefill_slot_paged_chunk(
-                params, jnp.asarray([w], jnp.int32),
-                jnp.asarray([n], jnp.int32), jnp.int32(1),
-                jnp.int32(start), pg, rope, cfg, attn=attn)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-4, rtol=2e-4)
+        params, jnp.asarray([IDS + [0] * (bucket - len(IDS))], jnp.int32),
+        jnp.asarray([len(IDS)], jnp.int32), jnp.int32(1), _dup(pg0),
+        rope, cfg, attn=attn)
+    packed = mixed_token_buckets(SLOTS, C)[0]
+    for lens, n_tokens in (((16, 16, 5), None), ((16, 16, 5), packed),
+                           ((5, 16, 16), None), ((5, 16, 16), packed)):
+        pg, start = _dup(pg0), 0
+        for n in lens:
+            got, pg = _mixed_window(params, cfg, rope, pg, 1,
+                                    IDS[start:start + n], start, attn,
+                                    n_tokens)
+            start += n
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
+                                   atol=POOLS[pool], rtol=POOLS[pool],
+                                   err_msg=f"{lens} n_tokens={n_tokens}")
 
 
 # -- engine equivalence --------------------------------------------------------

@@ -176,9 +176,3 @@ def test_pick_group_shrinks_for_tiny_dims():
     assert pick_group(4096) == 128
     assert pick_group(64) == 64
     assert pick_group(96) == 32
-
-
-def test_bench_smoke_tier_int4(monkeypatch):
-    import bench
-    res = bench.run_tier("tiny_int4", **bench.SMOKE_TIERS["tiny_int4"])
-    assert res["value"] > 0

@@ -205,8 +205,8 @@ def test_mixed_kernel_parity_unmapped_holes():
 
 def test_mixed_fold_decode_row_bitwise_matches_decode_fold():
     """A q_len=1 mixed row through the fold reference is bit-identical
-    to the decode fold — the phase-split token-equality bar rests on
-    this."""
+    to the decode fold — the mixed == dense token-equality bar rests
+    on this."""
     rng = np.random.default_rng(14)
     pk, pv = _pool(rng, KV=2, hd=16)
     q = jnp.asarray(rng.normal(size=(2, 1, 4, 16)), jnp.float32)
@@ -433,9 +433,8 @@ def test_engine_pallas_matches_fold(tiny_config):
 
 
 def test_engine_pallas_records_step_histogram(tiny_config, tiny_params):
-    """The paged engine observes cake_paged_attn_step_seconds on every
-    path: mixed + decode under the default (--mixed-batch auto), and
-    the classic prefill + decode split with the phase loop pinned."""
+    """The paged engine observes cake_paged_attn_step_seconds on both
+    of its paths: the mixed step and the pure-decode step."""
     from cake_tpu.models.llama.generator import ByteTokenizer
     from cake_tpu.obs import metrics as obs_metrics
     from cake_tpu.ops.sampling import SamplingConfig
@@ -443,27 +442,21 @@ def test_engine_pallas_records_step_histogram(tiny_config, tiny_params):
 
     fam = obs_metrics.REGISTRY.get("cake_paged_attn_step_seconds")
     assert fam is not None
-    paths = ("prefill", "decode", "mixed")
+    paths = ("decode", "mixed")
     before = {p: fam.labels(path=p).count for p in paths}
 
-    def run(**kw):
-        eng = InferenceEngine(
-            tiny_config, tiny_params,
-            ByteTokenizer(tiny_config.vocab_size),
-            max_slots=2, max_seq_len=64,
-            sampling=SamplingConfig(temperature=0.0, repeat_penalty=1.0),
-            kv_pages=10, kv_page_size=8, paged_attn="fold", **kw)
-        with eng:
-            h = eng.submit([5] * 9, max_new_tokens=4, temperature=0.0,
-                           repeat_penalty=1.0)
-            assert h.wait(timeout=300)
-
-    run()                            # auto -> mixed step + pure decode
+    eng = InferenceEngine(
+        tiny_config, tiny_params,
+        ByteTokenizer(tiny_config.vocab_size),
+        max_slots=2, max_seq_len=64,
+        sampling=SamplingConfig(temperature=0.0, repeat_penalty=1.0),
+        kv_pages=10, kv_page_size=8, paged_attn="fold")
+    with eng:
+        h = eng.submit([5] * 9, max_new_tokens=4, temperature=0.0,
+                       repeat_penalty=1.0)
+        assert h.wait(timeout=300)
     assert fam.labels(path="mixed").count > before["mixed"]
     assert fam.labels(path="decode").count > before["decode"]
-    assert fam.labels(path="prefill").count == before["prefill"]
-    run(mixed_batch="off")           # phase-split: prefill + decode
-    assert fam.labels(path="prefill").count > before["prefill"]
     rendered = obs_metrics.REGISTRY.render()
     assert 'cake_paged_attn_step_seconds_bucket{path="decode"' in rendered
 

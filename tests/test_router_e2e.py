@@ -219,6 +219,7 @@ def test_drained_replica_gets_zero_new_admissions(tiny_config, params):
     engA, apiA, httpdA, addrA = _replica(tiny_config, params, "A")
     engB, apiB, httpdB, addrB = _replica(tiny_config, params, "B")
     rhttpd, router, raddr = _router_over([addrA, addrB], tiny_config)
+    gate, held = threading.Event(), threading.Event()
     try:
         # place tenant-d's home deterministically by asking the router
         body = {"messages": _messages("tenant-d", "warm"),
@@ -229,14 +230,32 @@ def test_drained_replica_gets_zero_new_admissions(tiny_config, params):
             (engA, apiA, addrA) if homeA else (engB, apiB, addrB)
         cold_eng = engB if homeA else engA
 
-        # long in-flight stream on the home replica
-        resp = _post(raddr, {
-            "messages": _messages("tenant-d", "long answer please"),
-            "stream": True, "max_tokens": 24}, timeout=600)
-        # wait until it holds a slot
-        deadline = time.monotonic() + 60
-        while home_eng.active == 0 and time.monotonic() < deadline:
-            time.sleep(0.01)
+        # long in-flight stream on the home replica, PACED at a
+        # quarter second a token until the drain below has landed: the
+        # tiny model finishes 24 tokens before this thread can look,
+        # so polling for a held slot raced the stream's end and lost
+        # in every run. Paced, not frozen: the engine thread must keep
+        # running its commands (the refused submit below registers a
+        # system prompt on it)
+        emit = home_eng._emit
+
+        def emit_paced(req, *a, **kw):
+            emit(req, *a, **kw)
+            held.set()
+            gate.wait(0.25)
+
+        home_eng._emit = emit_paced
+        # posted from a thread: random weights decode to no text, so
+        # the router has no line to flush (and urlopen no status to
+        # return) before the held stream ends
+        box = {}
+        poster = threading.Thread(
+            target=lambda: box.update(resp=_post(raddr, {
+                "messages": _messages("tenant-d", "long answer please"),
+                "stream": True, "max_tokens": 24}, timeout=600)),
+            daemon=True)
+        poster.start()
+        assert held.wait(60), "the stream never started decoding"
         assert home_eng.active >= 1
 
         # drain the home replica directly (the operator's move)
@@ -269,10 +288,13 @@ def test_drained_replica_gets_zero_new_admissions(tiny_config, params):
         assert cold_eng.stats.requests_completed >= 3
         # the in-flight stream FINISHED on the draining home (drain
         # lets in-flight work complete; zero new admissions landed)
-        events = _read_sse(resp)
+        gate.set()
+        poster.join(60)
+        events = _read_sse(box["resp"])
         assert _text_of(events)
         assert home_eng.stats.requests_completed == base_home + 1
     finally:
+        gate.set()
         rhttpd.shutdown()
         router.close()
         for h in (httpdA, httpdB):

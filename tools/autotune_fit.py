@@ -19,16 +19,15 @@ Inputs:
 
   * ``--bench FILE [FILE ...]`` — JSON documents scanned recursively
     for observation records: any dict carrying ``config`` (EngineConfig
-    JSON) plus ``tok_s`` (and optionally ``offered_rps``). The
-    ``bench.py --autotune`` tier emits these under
-    ``autotune_observations``; hand-built sweep files work the same.
+    JSON) plus ``tok_s`` (and optionally ``offered_rps``), e.g.
+    hand-built sweep files.
   * ``--step-log PATH --step-config JSON`` — one flight-recorder JSONL
     per engine config (the recorder has no config column): the log is
     sliced into ``--window`` second windows, each contributing one
     observation under the named config. Repeat the pair per config.
 
 Usage:
-    python tools/autotune_fit.py --bench BENCH_r*.json \
+    python tools/autotune_fit.py --bench sweep*.json \
         --out policy.json
     python tools/autotune_fit.py \
         --step-log s16.jsonl --step-config '{"slots": 16}' \

@@ -29,8 +29,8 @@ import os
 import pathlib
 import sys
 
-# absolute repo root so the tool works from any cwd (the
-# engine_profile.py precedent — no sys.path.insert(0, ".") hack)
+# absolute repo root so the tool works from any cwd (no
+# sys.path.insert(0, ".") hack)
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(REPO_ROOT) not in sys.path:
     sys.path.insert(0, str(REPO_ROOT))
