@@ -568,13 +568,15 @@ def run_blocks_ragged_paged(blocks, x, cache: PagedKVCache, pos, active,
 
 def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
                          active, rope, config: LlamaConfig,
-                         attn: str = "fold"):
+                         attn: str = "fold", counters: bool = False):
     """model.forward_ragged's signature over a paged cache — un-jitted,
-    so serve.engine.make_decode_scan can build the K-step paged decode
-    scan from it (dispatch amortization works for paged serving exactly
-    like dense)."""
-    return _forward_ragged_paged(params, tokens, cache, pos, active,
-                                 rope, config, attn)[:2]
+    so serve.engine.make_decode_scan can build the sampled paged decode
+    programs from it (one step in flight, or a K-step scan, exactly
+    like dense). counters=True returns what a step program returns
+    (_step_result: a sparse model's expert counters third)."""
+    out = _step_result(*_forward_ragged_paged(
+        params, tokens, cache, pos, active, rope, config, attn))
+    return out if counters else out[:2]
 
 
 def _forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,

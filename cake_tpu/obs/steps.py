@@ -113,6 +113,11 @@ _STEPS_TOTAL = _m.counter(
     "cake_steps_total",
     "Engine steps recorded by the flight recorder, by step kind",
     labelnames=("kind",))
+_DECODE_CHAINED = _m.counter(
+    "cake_decode_steps_chained_total",
+    "Decode steps dispatched from the tokens still on the device while "
+    "the step before them was in flight (of cake_steps_total"
+    "{kind=\"decode\"})")
 _STEP_DISPATCH = _m.histogram(
     "cake_step_dispatch_seconds",
     "Per-step dispatch wall seconds, by step kind",
@@ -395,7 +400,10 @@ class StepRecord:
     """One engine step. dispatch_s is host wall to get the work onto
     the device (for double-buffered bursts, the dispatch half alone);
     device_s is the measured completion wall (the fetch half, a proxy
-    for device time on sync paths); wall_s the whole step."""
+    for device time on sync paths); wall_s the whole step. A step
+    dispatched while the one before it was in flight (`chained`) has
+    wall_s = its fetch's end - the previous step's fetch's end, the
+    time it added to the loop, and device_s the same."""
 
     step: int
     ts: float                      # wall-clock
@@ -431,7 +439,11 @@ class StepRecord:
     phases: Optional[Dict[str, float]] = None
     # end of the previous step's fetch -> start of this step's first
     # dispatch: how long the engine left the device with nothing queued
+    # (0.0 for a chained step: the device had the step before it)
     gap_s: Optional[float] = None
+    # a decode step: dispatched from the previous step's on-device
+    # carry while that step was still in flight
+    chained: Optional[bool] = None
     # sparse-expert counters since the previous record that carried
     # them, in the order of MOE_COUNTERS
     moe: Optional[Tuple[float, ...]] = None
@@ -473,6 +485,8 @@ class StepRecord:
                              for k, v in self.phases.items()}
         if self.gap_s is not None:
             out["gap_s"] = round(self.gap_s, 6)
+        if self.chained is not None:
+            out["chained"] = self.chained
         if self.moe is not None:
             for (key, _series), v in zip(MOE_COUNTERS, self.moe):
                 out[key] = round(v, 3)
@@ -650,7 +664,8 @@ class StepTelemetry:
                tokens_computed: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
-               moe: Optional[Sequence[float]] = None) -> StepRecord:
+               moe: Optional[Sequence[float]] = None,
+               chained: Optional[bool] = None) -> StepRecord:
         """Append one step record; derives MFU / HBM utilization from
         `cost` and the step's device seconds. Any subset of the three
         timings may be given; missing ones fall back to the others.
@@ -662,7 +677,11 @@ class StepTelemetry:
         rows rode this dispatch (the per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
-        step program's sparse-expert counters (StepRecord.moe)."""
+        step program's sparse-expert counters (StepRecord.moe).
+        chained: a decode step that was dispatched while the step
+        before it was in flight (cake_decode_steps_chained_total). Its
+        gap_s is 0.0, the device had work queued, whatever the spans
+        say: its dispatch span lies in the record before its own."""
         wall = wall_s if wall_s is not None else (
             (dispatch_s or 0.0) + (device_s or 0.0))
         disp = dispatch_s if dispatch_s is not None else wall
@@ -675,7 +694,7 @@ class StepTelemetry:
             if cost.bytes_accessed > 0 and bps:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
         phases, self._phases = self._phases, {}
-        gap, self._gap = self._gap, None
+        gap, self._gap = (0.0 if chained else self._gap), None
         if "fetch" not in phases:
             # the device was never drained: the next step has no gap
             self._fetch_t1 = None
@@ -693,12 +712,14 @@ class StepTelemetry:
                 tokens_real=tokens_real, tokens_computed=tokens_computed,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
-                phases=phases or None, gap_s=gap,
+                phases=phases or None, gap_s=gap, chained=chained,
                 moe=(tuple(float(v) for v in moe)
                      if moe is not None else None))
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
+        if chained:
+            _DECODE_CHAINED.inc()
         _STEP_DISPATCH.labels(kind=kind).observe(disp)
         for k, v in (("decode", rows_decode), ("prefill", rows_prefill),
                      ("idle", rows_idle)):

@@ -858,6 +858,9 @@ class InferenceEngine:
         # fetch, and then on the host for the next step record
         self._moe_pending: list = []
         self._moe_fetched: list = []
+        # small host arrays the chained decode dispatches last sent the
+        # device, by name (_held)
+        self._dev_held: dict = {}
         # distributed-trace annotation: events published with a rid
         # pick up the request's x-cake-trace id from the tracer, so
         # the front-door router's federated timeline can select this
@@ -1201,7 +1204,7 @@ class InferenceEngine:
                     budget = np.asarray(
                         op.get("budget", [op["n"]] * self.max_slots),
                         np.int32)
-                    toks, _lps, _ti, _tl = self._decode_scan_device(
+                    toks, *_ = self._decode_scan_device(
                         op["rows"], op["n"], op["n_top"], budget=budget)
                     self._finalize_scan_mirrors(op["rows"], op["n"], toks,
                                                 budget)
@@ -2152,13 +2155,8 @@ class InferenceEngine:
                     if decode_plan and self._spec:
                         self._do_decode_spec(decode_plan)
                     elif decode_plan:
-                        n = self._scan_steps_for(decode_plan)
-                        if n > 1 and not self._multihost:
-                            self._decode_burst(decode_plan, n)
-                        elif n > 1:
-                            self._do_decode_scan(decode_plan, n)
-                        else:
-                            self._do_decode(decode_plan)
+                        self._decode_rows(decode_plan,
+                                          chain=not prefill_plan)
                 if getattr(self, "_fail_recs", None) is not None:
                     # a successful iteration (real device work incl.
                     # collectives) proves the mesh recovered: the
@@ -3366,6 +3364,7 @@ class InferenceEngine:
         self._pos[:] = 0
         self._last_tok[:] = 0
         self._steps[:] = 0
+        self._dev_held.clear()
         B = self.max_slots
         self._ring = jnp.full((B, self.defaults.repeat_last_n), -1,
                               jnp.int32)
@@ -4400,10 +4399,12 @@ class InferenceEngine:
         well-occupied mixed launch instead of two under-occupied ones.
 
         decode_scan interaction (the K-step-burst admission-delay fix):
-        scan bursts only run while NO prompt is mid-prefill and nobody
-        waits in the queue (_scan_steps_for's queue gate); the moment a
-        request is admitted, the loop falls back to single mixed steps
-        so its chunks ride every iteration instead of stalling behind a
+        the decode programs run only while NO prompt is mid-prefill,
+        and chain (one step in flight, or K-step scan bursts) only
+        while nobody waits in the queue (_host_attention_pending,
+        _scan_steps_for's queue gate); the moment a request is
+        admitted, the loop falls back to single mixed steps so its
+        chunks ride every iteration instead of stalling behind a
         K-token scan burst."""
         if prefill_plan:
             with self.flight.span("schedule"):
@@ -4411,8 +4412,8 @@ class InferenceEngine:
                     self._mixed_admit(rid, slot)
         if not self._mixed_pending:
             # pure decode: the decode programs are strictly cheaper
-            # here (C=1 step, K-step scan bursts) and no admission is
-            # waiting on a step boundary
+            # here (C=1 step kept in flight, K-step scan bursts) and no
+            # admission is waiting on a step boundary
             if decode_plan and self._resident_parked:
                 # an admission above parked a decode-resident slot
                 # (_spill_resident_stream): drop its stale row before
@@ -4426,11 +4427,7 @@ class InferenceEngine:
                 # fall through to the plain decode paths below
                 decode_plan = self._do_spec_paged(decode_plan)
             if decode_plan:
-                n = self._scan_steps_for(decode_plan)
-                if n > 1:
-                    self._decode_burst(decode_plan, n)
-                else:
-                    self._do_decode(decode_plan)
+                self._decode_rows(decode_plan, chain=not prefill_plan)
             return
         self._mixed_dispatch(decode_plan)
 
@@ -5378,6 +5375,36 @@ class InferenceEngine:
                                 proposed=g * len(plan), accepted=0,
                                 tokens=0, gamma=g, fault=True)
 
+    def _decode_rows(self, decode_plan, chain: bool = True) -> None:
+        """One pure-decode iteration. A single-host engine keeps a step
+        in flight (_decode_burst: the sampled one-step program chained
+        from the tokens still on the device, or --decode-scan's K-step
+        scans). chain=False: this iteration admitted rows that the
+        plan does not hold yet (the scheduler plans a wave's admissions
+        and its decode rows apart), so one dispatch, then back to the
+        planner: a stretch would leave them idle for its whole length.
+        The synchronous step (_do_decode: dispatch, sample
+        eagerly, fetch, emit) stays where something the engine observes
+        says so: followers replay every step from host mirrors
+        (multi-host); the step fns bring no sampled program; a row sits
+        one token from max_seq_len (the device carry has no window
+        freeze); or the rows are what the paged speculative partition
+        left behind (one step, then back to the planner: a stretch
+        would starve the spec rows)."""
+        n = self._scan_steps_for(decode_plan)
+        if self._multihost:
+            if n > 1:
+                self._do_decode_scan(decode_plan, n)
+            else:
+                self._do_decode(decode_plan)
+        elif n > 1 or (self._decode_scan_impl is not None
+                       and self._specp is None
+                       and all(self._pos[slot] + 1 < self.max_seq_len
+                               for _, slot in decode_plan)):
+            self._decode_burst(decode_plan, n, chain)
+        else:
+            self._do_decode(decode_plan)
+
     @engine_thread_only
     def _do_decode(self, decode_plan) -> None:
         t0 = time.perf_counter()
@@ -5397,7 +5424,7 @@ class InferenceEngine:
                           dispatch_s=self.flight.open_phase("dispatch"),
                           device_s=self.flight.open_phase("fetch"),
                           rids=[r for r, _s in decode_plan],
-                          moe=self._take_moe())
+                          moe=self._take_moe(), chained=False)
         with self.flight.span("emit"):
             for rid, slot in decode_plan:
                 req = self._slot_req[slot]
@@ -5509,74 +5536,128 @@ class InferenceEngine:
                           rids=[r for r, _s in decode_plan])
         self._complete_scan(decode_plan, n, fetched, budget)
 
-    def _decode_burst(self, decode_plan, n: int) -> None:
-        """Double-buffered chained scans: dispatch scan k+1 (its inputs
-        chained on device from scan k's final carry — zero host
-        round-trips between scans) BEFORE fetching scan k's tokens, so
-        scan k's device-to-host fetch overlaps scan k+1's device
-        compute. Single-host only: a follower
-        rebuilds scan inputs from its mirrors, which match the chained
+    def _decode_burst(self, decode_plan, n: int,
+                      chain: bool = True) -> None:
+        """A stretch of pure decode with one dispatch in flight: dispatch
+        k+1 (its inputs chained on device from k's final carry — zero
+        host round-trips between them) BEFORE fetching k's tokens, so
+        the fetch and the emit of k run while the device computes k+1.
+        n = 1 is the sampled one-step program (records of kind
+        `decode`), n > 1 --decode-scan's K-step scans. The stretch ends
+        when the host needs the loop back (_host_attention_pending),
+        when a row of its plan finished (the slot is the planner's to
+        fill), when no row has budget or window left, after
+        STRETCH_STEPS dispatches, and after its first where the caller
+        says the plan is about to change (chain=False). Single-host
+        only: a follower
+        rebuilds its inputs from its mirrors, which match the chained
         carry for live rows but diverge for rows that froze (EOS) inside
-        an earlier not-yet-fetched scan — lockstep multi-host serving
-        keeps the synchronous _do_decode_scan path instead."""
+        an earlier not-yet-fetched dispatch — lockstep multi-host
+        serving keeps the synchronous paths instead."""
         t0 = time.perf_counter()
         self._implicated = decode_plan
-        if self._faults is not None:
-            self._faults.check("engine.decode", step=self.stats.steps)
+        span = self.flight.span
         rows = [s for _, s in decode_plan]
+        rids = [r for r, _s in decode_plan]
         n_top = self._n_top_for(rows)
-        # tokens dispatched in not-yet-fetched scans, per slot: added at
-        # dispatch, removed at fetch — budget math and the window guard
-        # both project the device state past the stale host mirrors by
-        # exactly this amount
+        kind = "decode" if n == 1 else "decode_scan"
+        # tokens dispatched in not-yet-fetched programs, per slot: added
+        # at dispatch, removed at fetch — budget math and the window
+        # guard both project the device state past the stale host
+        # mirrors by exactly this amount
         shipped: dict = {}
+        # dispatches so far, of them unfetched, and the end of the
+        # newest fetch
+        flying = {"sent": 0, "unfetched": 0, "fetch_t1": 0.0}
 
         def can_chain(_n_inflight) -> bool:
             # real work remains, and the PROJECTED device position
             # (host mirror + unfetched in-flight tokens) still fits the
             # window: the mirror lags the device by the in-flight
-            # scans, and the device program has no max_seq freeze.
+            # dispatches, and the device program has no max_seq freeze.
             # (The per-slot `shipped` dict is finer-grained than the
             # driver's in-flight count, so the latter goes unused.)
-            return (self._scan_budget(decode_plan, n, shipped).any()
+            # A row that finished in the emit just before this gate
+            # left its slot to the planner, and a caller that waits for
+            # each reply sends its next request a few ms from now, too
+            # late for this gate: chaining on made that arrival wait
+            # out two steps and meet the next one (twice as many double
+            # admissions behind a stretch, and itl_p95_ms moving with
+            # their count; my chip run, PR 29). The step in flight
+            # covers the time the arrival needs.
+            return (chain and flying["sent"] < STRETCH_STEPS
+                    and all(self._slot_req[s] is not None for s in rows)
+                    and self._scan_budget(decode_plan, n, shipped).any()
                     and all(self._pos[s] + shipped.get(s, 0) + n
                             < self.max_seq_len for s in rows))
 
         def dispatch(state):
-            # recomputed rather than smuggled out of can_chain: nothing
-            # host-side changes between the gate and the dispatch (same
-            # thread), and an explicit recompute keeps _drive_burst's
-            # can_chain a pure gate
-            budget = self._scan_budget(decode_plan, n, shipped)
+            if self._faults is not None:
+                # the chaos plane's sites fire once a step, as when
+                # every step was an iteration of the run loop (which
+                # checked engine.step before this stretch's first)
+                if flying["sent"]:
+                    self._faults.check("engine.step",
+                                       step=self.stats.steps)
+                self._faults.check("engine.decode", step=self.stats.steps)
+            t_start = time.perf_counter()
+            with span("build"):
+                # recomputed rather than smuggled out of can_chain:
+                # nothing host-side changes between the gate and the
+                # dispatch (same thread), and an explicit recompute
+                # keeps _drive_burst's can_chain a pure gate
+                budget = self._scan_budget(decode_plan, n, shipped)
             t0d = time.perf_counter()
             outs, state = self._dispatch_scan_device(
                 rows, n, n_top, budget, state=state)
             disp = time.perf_counter() - t0d
             js, self._last_jit = self._last_jit, None
-            for _, slot in decode_plan:
+            for slot in rows:
                 shipped[slot] = shipped.get(slot, 0) + int(budget[slot])
             self.stats.steps += n
-            return (outs, budget, disp, js), state
+            chained = flying["unfetched"] > 0
+            flying["sent"] += 1
+            flying["unfetched"] += 1
+            return (outs, budget, t_start, disp, js, chained), state
 
         def complete(devs):
-            outs_k, budget_k, disp_k, js_k = devs
-            t0f = time.perf_counter()
-            fetched = self._fetch_scan(outs_k)
-            fetch = time.perf_counter() - t0f
-            # one record per scan: its own dispatch wall (this scan's
-            # trace+enqueue) and the fetch wall as the device-side proxy
-            self._record_step("decode_scan", rows=len(rows),
-                              tokens=int(budget_k.sum()),
-                              dispatch_s=disp_k, device_s=fetch,
-                              wall_s=disp_k + fetch, js=js_k,
-                              rids=[r for r, _s in decode_plan])
-            self._complete_scan(decode_plan, n, fetched, budget_k)
-            for _, slot in decode_plan:
-                shipped[slot] = (shipped.get(slot, 0)
-                                 - int(budget_k[slot]))
+            outs_k, budget_k, t_start, disp_k, js_k, chained = devs
+            with span("fetch"):
+                fetched = self._fetch_scan(outs_k)
+            t1 = time.perf_counter()
+            flying["unfetched"] -= 1
+            # what this dispatch added to the loop: from its own start,
+            # or from the end of the fetch before it when it was queued
+            # behind that step — never less than the device needed.
+            # For a chained step that period is the best reading of the
+            # device's time too; its fetch alone waited for less.
+            wall = t1 - max(t_start, flying["fetch_t1"])
+            flying["fetch_t1"] = t1
+            moe = fetched[4]
+            self._record_step(
+                kind, rows=int(np.count_nonzero(budget_k)),
+                tokens=int(budget_k.sum()), wall_s=wall,
+                dispatch_s=disp_k,
+                device_s=(wall if chained
+                          else self.flight.open_phase("fetch")),
+                js=js_k, rids=rids,
+                moe=np.sum(moe, axis=0) if moe else None,
+                chained=chained)
+            with span("emit"):
+                self._complete_scan(decode_plan, n, fetched, budget_k)
+            for slot in rows:
+                shipped[slot] -= int(budget_k[slot])
+            if self._journal is not None:
+                # once per completed step, as the run loop flushes once
+                # per iteration: no later and no rarer than before
+                with span("admin"):
+                    self._journal.flush()
 
         steps0 = self.stats.steps
-        self._drive_burst(dispatch, complete, can_chain)
+        # n = 1: the first dispatch is the step the synchronous path
+        # would have run, whoever waits; only what follows is gated
+        self._drive_burst(dispatch, complete, can_chain,
+                          first_unconditional=(n == 1))
         dt = time.perf_counter() - t0
         self.stats.decode_time_s += dt
         self._obs_paged_step("decode",
@@ -5588,7 +5669,7 @@ class InferenceEngine:
         A row emits min(its budget, EOS cut) tokens; the device program
         froze it at exactly that point (budget freeze + EOS freeze in
         make_decode_scan), so mirrors advance by the emitted count."""
-        toks_host, lps_host, tops_i_host, tops_l_host = fetched
+        toks_host, lps_host, tops_i_host, tops_l_host = fetched[:4]
         for rid, slot in decode_plan:
             req = self._slot_req[slot]
             if req is None or req.rid != rid:
@@ -5621,41 +5702,67 @@ class InferenceEngine:
         replicated output localized), so the surrounding single-step ops
         keep their process-local sampling while the scan itself runs
         sampling inside the mesh program identically on every process.
-        state: a previous scan's final carry to chain from (single-host
-        bursts); None rebuilds the inputs from the host mirrors."""
-        B = self.max_slots
-        if state is None:
-            active = np.zeros(B, bool)
-            for slot in rows:
-                active[slot] = True
-            last_tok = jnp.asarray(self._last_tok, jnp.int32)
-            pos = jnp.asarray(np.minimum(self._pos, self.max_seq_len - 1),
-                              jnp.int32)
-            steps = jnp.asarray(self._steps, jnp.int32)
-            active = jnp.asarray(active)
-        else:
-            last_tok, pos, steps, active = state
-        keys, ring = self._keys, self._ring
-        if self._multihost:
-            keys, ring = np.asarray(keys), np.asarray(ring)
-        fargs = (self.params, last_tok, pos, active, self.cache,
-                 self.rope, self.config, keys, ring, steps,
-                 jnp.asarray(self._temp), jnp.asarray(self._top_p),
-                 jnp.asarray(self._penalty),
-                 jnp.asarray(budget, jnp.int32))
-        fkw = dict(num_steps=n, top_k=self.defaults.top_k, n_top=n_top)
-        js = self._obs_jit("decode_scan", (n, n_top),
-                           self._decode_scan_impl, fargs, fkw)
-        t0 = time.perf_counter()
-        (toks, lps, tops_i, tops_l, self.cache, keys_o, ring_o,
-         state_o) = self._decode_scan_impl(*fargs, **fkw)
-        js.finish(time.perf_counter() - t0)
-        self._last_jit = js
+        state: a previous dispatch's final carry to chain from
+        (single-host bursts); None rebuilds the inputs from the host
+        mirrors.
+        Returns ((tokens, logprobs, top ids, top logprobs, [a sparse
+        model's expert counters]) on the device, the final carry)."""
+        with self.flight.span("build"):
+            B = self.max_slots
+            if state is None:
+                active = np.zeros(B, bool)
+                for slot in rows:
+                    active[slot] = True
+                last_tok, pos, steps, active = (self._placed(a) for a in (
+                    self._last_tok.astype(np.int32),
+                    np.minimum(self._pos, self.max_seq_len - 1)
+                    .astype(np.int32),
+                    self._steps.astype(np.int32), active))
+            else:
+                last_tok, pos, steps, active = state
+            keys, ring = self._placed(self._keys), self._placed(self._ring)
+            if self._multihost:
+                keys, ring = np.asarray(keys), np.asarray(ring)
+            fargs = (self.params, last_tok, pos, active, self.cache,
+                     self.rope, self.config, keys, ring, steps,
+                     self._held("temp", self._temp),
+                     self._held("top_p", self._top_p),
+                     self._held("penalty", self._penalty),
+                     self._held("budget", np.asarray(budget, np.int32)))
+            fkw = dict(num_steps=n, top_k=self.defaults.top_k, n_top=n_top)
+        with self.flight.span("dispatch"):
+            js = self._obs_jit(
+                "decode_scan" if n > 1 else "decode_step_sampled",
+                (n, n_top), self._decode_scan_impl, fargs, fkw)
+            t0 = time.perf_counter()
+            (toks, lps, tops_i, tops_l, self.cache, keys_o, ring_o,
+             state_o, *moe) = self._decode_scan_impl(*fargs, **fkw)
+            js.finish(time.perf_counter() - t0)
+            self._last_jit = js
         if self._multihost:
             keys_h, ring_h = jax.device_get((keys_o, ring_o))
             keys_o, ring_o = jnp.asarray(keys_h), jnp.asarray(ring_h)
         self._keys, self._ring = keys_o, ring_o
-        return (toks, lps, tops_i, tops_l), state_o
+        return (toks, lps, tops_i, tops_l, moe), state_o
+
+    def _held(self, name: str, host: np.ndarray):
+        """`host` on the device, copied again only when it differs from
+        the last copy under `name`: a stretch of chained decode steps
+        changes neither its sampling options nor, until a row runs
+        out, its budget, so its dispatches send the device nothing."""
+        got = self._dev_held.get(name)
+        if got is None or not np.array_equal(got[0], host):
+            got = self._dev_held[name] = (host.copy(), self._placed(host))
+        return got[1]
+
+    def _placed(self, x):
+        """x on the device(s), where the sampled programs leave their
+        own small outputs (DecodePrograms.out_sharding); followers keep
+        process-local copies."""
+        sharding = getattr(self._decode_scan_impl, "out_sharding", None)
+        if sharding is None or self._multihost:
+            return jnp.asarray(x)
+        return jax.device_put(x, sharding)
 
     @staticmethod
     def _fetch_scan(outs) -> tuple:
@@ -5949,6 +6056,16 @@ class InferenceEngine:
             log.exception("pre-fail snapshot failed")
 
 
+# The most dispatches one stretch of in-flight decode makes before it
+# hands the engine thread back to _run_loop (_decode_burst): the
+# autotune tick, the queue gauges, preemption and the journal's
+# compaction check run between iterations only, and one long request
+# with nobody else arriving must not starve them. 32 steps are ~0.5 s at
+# a 16 ms step (1.8 s at the four-chip engine's 57 ms), and the one
+# unhidden host gap a stretch end costs (~8 ms) is under 2 % of it.
+STRETCH_STEPS = 32
+
+
 class QueueFullError(Exception):
     """Admission queue full. retry_after: computed seconds a client
     should wait before retrying — derived from the measured service
@@ -5987,80 +6104,134 @@ def _masked_sample(active_mask, keys, logits, ring, steps, temp, top_p,
     return nxt, keys, ring, lp, top_ids, top_lps
 
 
-def make_decode_scan(forward_fn, out_sharding=None):
-    """Build a jitted num_steps-ragged-decode+sample scan over any
-    ragged forward (single-device model.forward_ragged, or the
+class DecodePrograms:
+    """The sampled decode programs over one ragged forward, called as
+    the scan always was (`num_steps=` picks the program): `step`, one
+    decode step + sample with no lax.scan around it, the program the
+    engine keeps in flight; `scan`, num_steps of the same body in one
+    lax.scan (--decode-scan N>1). `lower` is obs/steps.lower_cost's
+    seam. `out_sharding` is where the programs leave their small
+    outputs (make_decode_scan); the engine puts the small inputs it
+    rebuilds from host mirrors there too, so that a stretch's first
+    dispatch and its chained ones are ONE executable: a mesh program
+    otherwise compiles, or loads from the cache, once per combination
+    of host-made and program-made arguments (five times a start-up on
+    the four-chip engine, 5 s of its warm-up; my chip run, PR 29)."""
+
+    def __init__(self, step, scan, out_sharding=None):
+        self.step, self.scan = step, scan
+        self.out_sharding = out_sharding
+
+    def __call__(self, *args, num_steps: int, **kw):
+        if num_steps == 1:
+            return self.step(*args, **kw)
+        return self.scan(*args, num_steps=num_steps, **kw)
+
+    def lower(self, *args, num_steps: int, **kw):
+        if num_steps == 1:
+            return self.step.lower(*args, **kw)
+        return self.scan.lower(*args, num_steps=num_steps, **kw)
+
+
+def make_decode_scan(forward_fn, out_sharding=None) -> DecodePrograms:
+    """Build the jitted sampled decode programs (DecodePrograms) over
+    any ragged forward (single-device model.forward_ragged, or the
     shard_mapped pipelined forward from parallel.pipeline
-    .make_engine_step_fns — the step_fns-forces-scan-1 limitation is
-    gone: a pipelined engine amortizes host dispatch across K tokens
-    per round trip exactly like the single-device engine).
+    .make_engine_step_fns): one decode step + sample, once
+    (`decode_step_sampled`, num_steps=1) or num_steps times in a
+    lax.scan (`decode_scan`), so a pipelined engine keeps a step in
+    flight, or amortizes host dispatch across K tokens per round trip,
+    exactly like the single-device engine.
 
     forward_fn(params, tokens, cache, pos, active, rope, config)
-    -> (logits, cache), with model.forward_ragged's signature.
+    -> (logits, cache), with model.forward_ragged's signature; a
+    sparse model's forward returns its expert counters third
+    (paged._step_result), which the one-step program returns last and
+    the scan drops.
     out_sharding: optional sharding constraint for the non-cache
     outputs (multi-host serving localizes them per process, so they
     must leave the program fully replicated).
 
-    Same per-row semantics as the single-step path (_do_decode +
+    Same per-row semantics as the synchronous step (_do_decode +
     _sample_rows — both go through _masked_sample): inactive rows touch
     neither their cache lines nor their PRNG/ring state, and a row that
-    emits EOS mid-scan freezes for the remaining steps — in single-step
-    mode the scheduler frees the slot immediately, so without freezing
-    the slot's PRNG/ring stream would diverge between the two modes.
+    emits EOS freezes from then on — in the synchronous step the
+    scheduler frees the slot immediately, so without freezing the
+    slot's PRNG/ring stream would diverge between the two modes.
     A row also freezes once it has emitted `budget[row]` tokens within
-    this call, so a scan may be dispatched past a request's
+    this call, so a program may be dispatched past a request's
     max_new_tokens (or chained speculatively, _decode_burst) without
     writing a single token beyond the budget.
     Returns ([B, num_steps] tokens, [B, num_steps] logprobs,
     [B, num_steps, n_top] x2, cache, keys, ring, state) where state =
     (tok, pos, steps, live) is the final carry — feeding it back as
-    (last_tok, pos, steps, active) chains a follow-up scan entirely on
-    device (no host round-trip between scans). The host mirrors
+    (last_tok, pos, steps, active) chains the next dispatch entirely on
+    device (no host round-trip between them). The host mirrors
     (_pos/_steps/_last_tok) are advanced by the caller.
     """
 
-    @partial(jax.jit, static_argnames=("config", "num_steps", "top_k",
-                                       "n_top"),
-             donate_argnames=("cache", "keys", "ring"))
-    def decode_scan(params, last_tok, pos, active, cache: KVCache, rope,
-                    config, keys, ring, steps, temp, top_p, penalty,
-                    budget, num_steps: int, top_k, n_top: int = 0):
-        eos_ids = jnp.asarray(config.eos_token_ids, jnp.int32)
-        steps_in = steps
+    def body(carry, params, rope, config, temp, top_p, penalty, steps_in,
+             budget, top_k, n_top):
+        tok, pos, cache, keys, ring, steps, live = carry
+        # per-row budget freeze: emitted-so-far = steps - steps_in
+        # (both advance only while live), so a row stops producing
+        # the moment its allowance for this call is used up
+        live = live & ((steps - steps_in) < budget)
+        logits, cache, *moe = forward_fn(params, tok[:, None], cache, pos,
+                                         live, rope, config)
+        nxt, keys, ring, lp, t_i, t_l = _masked_sample(
+            live, keys, logits, ring, steps, temp, top_p, penalty,
+            top_k=top_k, n_top=n_top)
+        tok = jnp.where(live, nxt, tok)
+        pos = pos + live
+        steps = steps + live
+        live = live & ~jnp.isin(
+            nxt, jnp.asarray(config.eos_token_ids, jnp.int32))
+        return ((tok, pos, cache, keys, ring, steps, live),
+                (nxt, lp, t_i, t_l), moe)
 
-        def body(carry, _):
-            tok, pos, cache, keys, ring, steps, live = carry
-            # per-row budget freeze: emitted-so-far = steps - steps_in
-            # (both advance only while live), so a row stops producing
-            # the moment its allowance for this call is used up
-            live = live & ((steps - steps_in) < budget)
-            logits, cache = forward_fn(params, tok[:, None], cache, pos,
-                                       live, rope, config)
-            nxt, keys, ring, lp, t_i, t_l = _masked_sample(
-                live, keys, logits, ring, steps, temp, top_p, penalty,
-                top_k=top_k, n_top=n_top)
-            tok = jnp.where(live, nxt, tok)
-            pos = pos + live
-            steps = steps + live
-            live = live & ~jnp.isin(nxt, eos_ids)
-            return ((tok, pos, cache, keys, ring, steps, live),
-                    (nxt, lp, t_i, t_l))
-
-        ((tok, pos, cache, keys, ring, steps, live),
-         (toks, lps, tops_i, tops_l)) = jax.lax.scan(
-            body, (last_tok, pos, cache, keys, ring, steps, active), None,
-            length=num_steps)
-        # [B, num_steps(, n_top)] each
-        outs = (toks.T, lps.T, jnp.swapaxes(tops_i, 0, 1),
-                jnp.swapaxes(tops_l, 0, 1), keys, ring)
+    def result(carry, outs, moe=()):
+        """outs: [B, num_steps(, n_top)] each."""
+        tok, pos, cache, keys, ring, steps, live = carry
+        outs = (*outs, keys, ring, tok, pos, steps, live, *moe)
         if out_sharding is not None:
             outs = tuple(jax.lax.with_sharding_constraint(o, out_sharding)
                          for o in outs)
-        toks_o, lps_o, ti_o, tl_o, keys_o, ring_o = outs
-        state = (tok, pos, steps, live)
-        return toks_o, lps_o, ti_o, tl_o, cache, keys_o, ring_o, state
+        (toks_o, lps_o, ti_o, tl_o, keys_o, ring_o, tok, pos, steps, live,
+         *moe) = outs
+        return (toks_o, lps_o, ti_o, tl_o, cache, keys_o, ring_o,
+                (tok, pos, steps, live), *moe)
 
-    return decode_scan
+    jit = partial(jax.jit, donate_argnames=("cache", "keys", "ring"))
+
+    # the name is the XLA module's (jit_decode_step_...): the benchmark
+    # finds a decode step's device time by that prefix
+    @partial(jit, static_argnames=("config", "top_k", "n_top"))
+    def decode_step_sampled(params, last_tok, pos, active, cache, rope,
+                            config, keys, ring, steps, temp, top_p,
+                            penalty, budget, top_k, n_top: int = 0):
+        carry, outs, moe = body(
+            (last_tok, pos, cache, keys, ring, steps, active), params,
+            rope, config, temp, top_p, penalty, steps, budget, top_k,
+            n_top)
+        return result(carry, tuple(o[:, None] for o in outs), moe)
+
+    @partial(jit, static_argnames=("config", "num_steps", "top_k",
+                                   "n_top"))
+    def decode_scan(params, last_tok, pos, active, cache: KVCache, rope,
+                    config, keys, ring, steps, temp, top_p, penalty,
+                    budget, num_steps: int, top_k, n_top: int = 0):
+        def scanned(carry, _):
+            return body(carry, params, rope, config, temp, top_p,
+                        penalty, steps, budget, top_k, n_top)[:2]
+
+        carry, (toks, lps, tops_i, tops_l) = jax.lax.scan(
+            scanned, (last_tok, pos, cache, keys, ring, steps, active),
+            None, length=num_steps)
+        return result(carry, (toks.T, lps.T, jnp.swapaxes(tops_i, 0, 1),
+                              jnp.swapaxes(tops_l, 0, 1)))
+
+    return DecodePrograms(decode_step_sampled, decode_scan, out_sharding)
 
 
 def _builtin_forward_ragged(params, tokens, cache, pos, active, rope,
@@ -6085,7 +6256,7 @@ def _paged_forward_ragged(params, tokens, cache, pos, active, rope,
                           config):
     from cake_tpu.models.llama.paged import forward_ragged_paged
     return forward_ragged_paged(params, tokens, cache, pos, active, rope,
-                                config)
+                                config, counters=True)
 
 
 # module-level like its dense/ring siblings so the jit cache is shared
@@ -6097,7 +6268,7 @@ def _paged_forward_ragged_pallas(params, tokens, cache, pos, active,
                                  rope, config):
     from cake_tpu.models.llama.paged import forward_ragged_paged
     return forward_ragged_paged(params, tokens, cache, pos, active,
-                                rope, config, attn="pallas")
+                                rope, config, attn="pallas", counters=True)
 
 
 _decode_scan_paged_pallas = make_decode_scan(_paged_forward_ragged_pallas)

@@ -38,6 +38,30 @@ PROMPT = [5, 6, 7, 8, 9]
 N_TOK = 12
 
 
+def _interrupt_at(eng, n):
+    """Arm `eng` to stop once a request holds n tokens; call before the
+    submit, then _stopped(eng). The flag is set on the engine thread
+    itself (a hook on _emit), so the interrupt lands mid-generation
+    however fast the loop decodes: a 10 ms poll from the test's thread
+    lost that race once a decode step was kept in flight (PR 29: twelve
+    tokens of the tiny model take a few milliseconds)."""
+    emit = eng._emit
+
+    def hooked(req, *a, **kw):
+        emit(req, *a, **kw)
+        if len(req.out_tokens) >= n:
+            eng._stop.set()
+
+    eng._emit = hooked
+
+
+def _stopped(eng):
+    deadline = time.time() + 60
+    while not eng._stop.is_set() and time.time() < deadline:
+        time.sleep(0.001)
+    eng.stop()
+
+
 def test_checkpoint_resume_matches_uninterrupted(params, tmp_path):
     from cake_tpu.serve import checkpoint
 
@@ -49,11 +73,9 @@ def test_checkpoint_resume_matches_uninterrupted(params, tmp_path):
 
     # interrupted run: stop mid-generation, snapshot, restore elsewhere
     eng1 = _engine(params).start()
+    _interrupt_at(eng1, 4)
     h1 = eng1.submit(PROMPT, max_new_tokens=N_TOK)
-    deadline = time.time() + 60
-    while len(h1.token_ids) < 4 and time.time() < deadline:
-        time.sleep(0.01)
-    eng1.stop()
+    _stopped(eng1)
     got_before = h1.token_ids
     assert 0 < len(got_before) < N_TOK, "expected a mid-flight interrupt"
     path = str(tmp_path / "engine.ckpt")
@@ -283,11 +305,9 @@ def test_resume_primes_repeat_penalty_ring(params, tmp_path):
         want = h.token_ids
 
     eng1 = mk().start()
+    _interrupt_at(eng1, 5)
     h1 = eng1.submit(PROMPT, max_new_tokens=N_TOK, repeat_penalty=1.3)
-    deadline = time.time() + 60
-    while len(h1.token_ids) < 5 and time.time() < deadline:
-        time.sleep(0.01)
-    eng1.stop()
+    _stopped(eng1)
     assert 0 < len(h1.token_ids) < N_TOK
     path = str(tmp_path / "ring.ckpt")
     checkpoint.save(eng1, path)
@@ -378,24 +398,21 @@ def test_double_interrupt_preserves_penalty_window(params, tmp_path):
         assert h.wait(60)
         want = h.token_ids
 
-    def interrupt_after(eng, handle, n):
-        deadline = time.time() + 60
-        while len(handle.token_ids) < n and time.time() < deadline:
-            time.sleep(0.01)
-        eng.stop()
-        assert len(handle.token_ids) >= n
-
     transcript = []
     eng1 = mk().start()
+    _interrupt_at(eng1, 4)
     h1 = eng1.submit(PROMPT, max_new_tokens=N_TOK, repeat_penalty=1.3)
-    interrupt_after(eng1, h1, 4)
+    _stopped(eng1)
+    assert 4 <= len(h1.token_ids) < N_TOK
     transcript += h1.token_ids
     p1 = str(tmp_path / "leg1.ckpt")
     checkpoint.save(eng1, p1)
 
     eng2 = mk().start()
+    _interrupt_at(eng2, 2)
     h2s, _ = checkpoint.restore(eng2, p1)
-    interrupt_after(eng2, h2s[0], 2)
+    _stopped(eng2)
+    assert len(h2s[0].token_ids) >= 2
     transcript += h2s[0].token_ids
     p2 = str(tmp_path / "leg2.ckpt")
     checkpoint.save(eng2, p2)

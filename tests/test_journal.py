@@ -64,6 +64,30 @@ def _engine(tiny_config, params, **kw):
         **kw)
 
 
+def _abandon_at(engine, hs, n):
+    """Start the engine on what is queued, and _abandon it once every
+    stream of `hs` holds n tokens. The stop is
+    set on the engine thread itself, from a hook on _emit, so it lands
+    mid-generation however fast the loop decodes: a poll from the
+    test's thread lost that race now and then once a decode step was
+    kept in flight (PR 29: twelve tokens take a few milliseconds)."""
+    assert engine._thread is None, "queue the requests, then abandon"
+    emit = engine._emit
+
+    def hooked(req, *a, **kw):
+        emit(req, *a, **kw)
+        if min(len(h._req.out_tokens) for h in hs) >= n:
+            engine._stop.set()
+
+    engine._emit = hooked
+    # started here, with everything queued: the streams are admitted
+    # in one iteration and advance together
+    engine.start()
+    engine._thread.join(120)
+    assert not engine._thread.is_alive()
+    _abandon(engine)
+
+
 def _abandon(engine):
     """Simulate a hard death for an in-process engine: stop the loop
     WITHOUT any retire/teardown path running (no tombstones, no
@@ -254,13 +278,10 @@ def test_dense_replay_token_identical_after_abandon(
         tiny_config, params, tmp_path, dense_clean):
     jpath = str(tmp_path / "dense.journal")
     engA = _engine(tiny_config, params, journal=jpath)
-    engA.start()
     hs = [engA.submit(list(P1), max_new_tokens=GEN,
                       idempotency_key="key-1"),
           engA.submit(list(P2), max_new_tokens=GEN)]
-    while min(len(h._req.out_tokens) for h in hs) < 4:
-        time.sleep(0.005)
-    _abandon(engA)
+    _abandon_at(engA, hs, 4)
 
     engB = _engine(tiny_config, params, journal=jpath)
     engB.start()
@@ -307,11 +328,8 @@ def test_paged_shared_prefix_replay_identical_and_pool_conserved(
 
     jpath = str(tmp_path / "paged.journal")
     engA = _engine(tiny_config, params, journal=jpath, **kw)
-    engA.start()
     _, hs = submit_wave(engA)
-    while min(len(h._req.out_tokens) for h in hs) < 3:
-        time.sleep(0.005)
-    _abandon(engA)
+    _abandon_at(engA, hs, 3)
 
     engB = _engine(tiny_config, params, journal=jpath, **kw)
     engB.start()
@@ -354,13 +372,10 @@ def test_size_triggered_compaction_preserves_replay(
     engA = _engine(tiny_config, params, journal=jpath)
     # force a compaction on nearly every iteration
     engA._journal.compact_bytes = 1
-    engA.start()
     hs = [engA.submit(list(P1), max_new_tokens=GEN),
           engA.submit(list(P2), max_new_tokens=GEN)]
-    while min(len(h._req.out_tokens) for h in hs) < 4:
-        time.sleep(0.005)
+    _abandon_at(engA, hs, 4)
     assert engA._journal.compactions > 0
-    _abandon(engA)
     records, bad, _torn = read_records(jpath)
     assert bad == 0
     # compacted: one admit (+ optional emit) per live request + header
@@ -402,12 +417,9 @@ def test_stale_consumed_sideline_does_not_truncate_live_journal(
     disambiguates it from a crashed-mid-recovery sideline."""
     jpath = str(tmp_path / "stale.journal")
     engA = _engine(tiny_config, params, journal=jpath)
-    engA.start()
     hs = [engA.submit(list(P1), max_new_tokens=GEN),
           engA.submit(list(P2), max_new_tokens=GEN)]
-    while min(len(h._req.out_tokens) for h in hs) < 4:
-        time.sleep(0.005)
-    _abandon(engA)
+    _abandon_at(engA, hs, 4)
     # simulate "removal failed": plant a STALE sideline (old state)
     # next to a live journal that carries the consumed marker
     stale = json.dumps(_admit(999, [1, 2, 3])) + "\n"
@@ -686,12 +698,9 @@ def test_sse_resume_across_restart_exact_suffix(
 
     client_stream.wants_count = True
     engA = _engine(tiny_config, params, journal=jpath)
-    engA.start()
-    engA.submit(list(P1), max_new_tokens=GEN, stream=client_stream,
-                idempotency_key="sse-key")
-    while len(seen) < 4:
-        time.sleep(0.005)
-    _abandon(engA)
+    h = engA.submit(list(P1), max_new_tokens=GEN, stream=client_stream,
+                    idempotency_key="sse-key")
+    _abandon_at(engA, [h], 4)
     last_seen = max(seen)    # the client's Last-Event-ID
     assert 0 < last_seen < GEN
 

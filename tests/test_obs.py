@@ -297,7 +297,9 @@ def test_sp_engine_lifecycle_traces(tiny_engine_setup):
     disp = m.REGISTRY.get("cake_sp_dispatch_total")
     assert disp is not None
     assert disp.labels(op="prefill", mode="sp").value >= 1
-    assert disp.labels(op="decode", mode="sp").value >= 1
+    # (a single-host engine's decode steps run the sampled one-step
+    # program of the third step fn)
+    assert disp.labels(op="decode_scan", mode="sp").value >= 1
 
 
 def test_engine_reset_failure_counter(tiny_engine_setup):

@@ -472,16 +472,29 @@ def paged_engine():
 @pytest.mark.parametrize("kind", ["mixed", "decode"])
 def test_paged_step_timings_are_the_spans(paged_engine, kind):
     """dispatch_s is the `dispatch` span and device_s the `fetch` span
-    of a mixed and of a decode step (both used to repeat wall_s);
-    wall_s stays the whole step."""
+    of a mixed step (both used to repeat wall_s); wall_s stays the whole
+    step. A decode step is kept in flight: its own dispatch's seconds,
+    and for a chained step wall_s = device_s = the period between two
+    fetches, with no `sample` span anywhere (the program samples)."""
     recs = [r for r in paged_engine.flight.dump() if r["kind"] == kind]
     assert recs, paged_engine.flight.summary()
     for r in recs:
         ph = r["phases"]
+        if kind == "decode":
+            assert "sample" not in ph and "fetch" in ph, r
+            if r["chained"]:
+                # (its dispatch ran inside the period before its own)
+                assert r["device_s"] == r["wall_s"] and r["gap_s"] == 0.0
+            else:
+                assert {"build", "dispatch"} <= set(ph), r
+                assert r["dispatch_s"] < r["wall_s"]
+                assert r["device_s"] < r["wall_s"]
+            continue
+        assert r["dispatch_s"] < r["wall_s"], r
         assert {"build", "dispatch", "sample", "fetch"} <= set(ph), r
         assert r["dispatch_s"] == pytest.approx(ph["dispatch"], abs=2e-6)
         assert r["device_s"] == pytest.approx(ph["fetch"], abs=2e-6)
-        assert r["dispatch_s"] < r["wall_s"] and r["device_s"] < r["wall_s"]
+        assert r["device_s"] < r["wall_s"]
         covered = sum(ph[k] for k in ("build", "dispatch", "sample",
                                       "fetch"))
         assert covered <= r["wall_s"] + 1e-4
@@ -490,21 +503,28 @@ def test_paged_step_timings_are_the_spans(paged_engine, kind):
     # 0.000224 failed the driver's run at PR 26): they are two
     # measurements if they differ anywhere
     assert any(r["dispatch_s"] != r["device_s"] for r in recs)
+    if kind == "decode":
+        # one request, nobody else arriving: one stretch, whose first
+        # step alone was dispatched with nothing in flight
+        assert [r["chained"] for r in reversed(recs)] == \
+            [False] + [True] * (len(recs) - 1)
 
 
 def test_paged_decode_steps_carry_gap_and_emit(paged_engine):
     recs = [r for r in reversed(paged_engine.flight.dump())
             if r["kind"] == "decode"]
-    later = recs[1:]          # the first follows a mixed step's fetch too
-    assert later and all("gap_s" in r for r in later)
-    for r in later:
-        # the gap is host work: the previous step's emit, the loop's
-        # admin and schedule, this step's build
-        ph = r["phases"]
-        host = sum(ph.get(k, 0.0) for k in ("emit", "admin", "schedule",
-                                            "build"))
-        assert 0 < host <= r["gap_s"] + 1e-4, r
-        assert ph["emit"] > 0
+    assert all("gap_s" in r for r in recs)
+    # the stretch's first step follows a mixed step's fetch: its gap is
+    # host work (that step's emit, the loop's admin and schedule, its
+    # own build); every later step was queued behind the one before it
+    first, later = recs[0], recs[1:]
+    host = sum(first["phases"].get(k, 0.0)
+               for k in ("emit", "admin", "schedule", "build"))
+    assert 0 < host <= first["gap_s"] + 1e-4, first
+    assert later and all(r["gap_s"] == 0.0 for r in later)
+    # a record holds the spans since the record before it: the emit of
+    # the step before, while this one ran
+    assert all(r["phases"]["emit"] > 0 for r in later)
 
 
 def test_dense_engine_steps_carry_phases(engine):
@@ -514,8 +534,15 @@ def test_dense_engine_steps_carry_phases(engine):
     assert prefill and decode
     assert {"schedule", "build", "dispatch", "sample", "fetch"} \
         <= set(prefill[0]["phases"])
-    assert all({"build", "dispatch", "sample", "fetch", "emit"}
-               <= set(r["phases"]) for r in decode)
+    # a decode step samples inside its program; a stretch's first
+    # record holds its own build and dispatch, a chained one the emit
+    # of the step before it
+    assert all("fetch" in r["phases"] and "sample" not in r["phases"]
+               for r in decode)
+    assert all({"build", "dispatch"} <= set(r["phases"])
+               for r in decode if not r["chained"])
+    assert all("emit" in r["phases"] for r in decode if r["chained"])
+    assert any(r["chained"] for r in decode)
     assert any("gap_s" in r for r in decode)
 
 

@@ -296,13 +296,15 @@ def test_engine_paged_page_accounting_invariant(tiny_config, params):
 
         # error path: the next decode step blows up; the engine fails
         # the request, releases its pages and resets
-        real_step = eng._decode_step
+        # (the sampled one-step program: what a pure-decode iteration
+        # of a single-host engine dispatches)
+        real_step = eng._decode_scan_impl
 
         def boom(*a, **kw):
-            eng._decode_step = real_step
+            eng._decode_scan_impl = real_step
             raise RuntimeError("injected device failure")
 
-        eng._decode_step = boom
+        eng._decode_scan_impl = boom
         errored = eng.submit([9] * 9, max_new_tokens=4, temperature=0.0,
                              repeat_penalty=1.0)
         assert errored.wait(timeout=300)

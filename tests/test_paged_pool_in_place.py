@@ -72,6 +72,26 @@ def _step_args(kind_of_step):
             jnp.asarray([1, C], jnp.int32), active)
 
 
+def _sampled(attn: str, lower: bool = False):
+    """The sampled one-step decode program (PR 29: the step the engine
+    keeps in flight) in decode_step_ragged_paged's call shape."""
+    from cake_tpu.serve import engine
+
+    progs = (engine._decode_scan_paged_pallas if attn == "pallas"
+             else engine._decode_scan_paged)
+    fn = progs.step.lower if lower else progs.step
+
+    def run(params, tokens, pos, active, cache, rope, config):
+        B = tokens.shape[0]
+        return fn(params, tokens[:, 0], pos, active, cache, rope, config,
+                  jax.random.split(jax.random.PRNGKey(0), B),
+                  jnp.full((B, 8), -1, jnp.int32), jnp.zeros(B, jnp.int32),
+                  jnp.zeros(B, jnp.float32), jnp.ones(B, jnp.float32),
+                  jnp.ones(B, jnp.float32), jnp.ones(B, jnp.int32),
+                  top_k=None, n_top=0)
+    return run
+
+
 # "mixed@T": the packed mixed step at n_tokens = T (PR 27). The step
 # arguments hold 1 + C = 5 tokens: 6 is a packed size just above them,
 # SLOTS * C = 8 the packed program at the windows' own size.
@@ -104,7 +124,8 @@ def _size(var):
 
 
 @pytest.mark.parametrize("kind", KINDS)
-@pytest.mark.parametrize("step", ["decode", "mixed", "mixed@6"])
+@pytest.mark.parametrize("step", ["decode", "mixed", "mixed@6",
+                                  "decode+sample"])
 def test_step_program_never_handles_a_pool(tiny_config, params, step, kind,
                                            monkeypatch):
     import cake_tpu.ops.ragged_paged_attention as rpa
@@ -123,9 +144,14 @@ def test_step_program_never_handles_a_pool(tiny_config, params, step, kind,
     layer_elems = int(np.prod(pool.shape[1:]))
     assert layer_elems > biggest
     rope = RopeTables.create(cfg, T)
-    jaxpr = jax.make_jaxpr(
-        lambda c: STEPS[step](params, *_step_args(step), c, rope,
-                              config=cfg, attn="pallas"))(cache).jaxpr
+    if step == "decode+sample":
+        jaxpr = jax.make_jaxpr(
+            lambda c: _sampled("pallas")(params, *_step_args("decode"), c,
+                                         rope, cfg))(cache).jaxpr
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda c: STEPS[step](params, *_step_args(step), c, rope,
+                                  config=cfg, attn="pallas"))(cache).jaxpr
 
     # the layer loop: the one scan over the blocks
     loops = [e for e in _walk(jaxpr) if e.primitive.name == "scan"
@@ -319,6 +345,32 @@ def test_step_donates_the_pool_and_copies_none(tiny_config, params, step):
     k_in, v_in = cache.k, cache.v
     _, out = STEPS[step](params, *args, cache, rope, config=cfg,
                          attn="fold")
+    assert k_in.is_deleted() and v_in.is_deleted()
+    assert out.k.shape == k_in.shape and out.k.nbytes == pool_bytes
+    assert out.v.shape == v_in.shape and out.v.nbytes == pool_bytes
+
+
+def test_sampled_decode_step_donates_the_pool_and_copies_none(
+        tiny_config, params):
+    """The same for the program that samples: the pool (and the keys
+    and the ring) donated in and aliased out, no temporary of a layer's
+    pool's size beside it (the fold, as above)."""
+    cfg = tiny_config
+    cache = PagedKVCache.create(cfg, SLOTS, 600, PAGE, T,
+                                dtype=jnp.float32)
+    cache = cache._replace(
+        table=cache.table.at[:, :2].set(jnp.asarray([[1, 2], [3, 4]])))
+    rope = RopeTables.create(cfg, T)
+    args = _step_args("decode")
+    pool_bytes = cache.k.nbytes
+    mem = _sampled("fold", lower=True)(
+        params, *args, cache, rope, cfg).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // cfg.num_hidden_layers
+    k_in, v_in = cache.k, cache.v
+    toks, _lp, _ti, _tl, out, *_ = _sampled("fold")(
+        params, *args, cache, rope, cfg)
+    assert toks.shape == (SLOTS, 1)
     assert k_in.is_deleted() and v_in.is_deleted()
     assert out.k.shape == k_in.shape and out.k.nbytes == pool_bytes
     assert out.v.shape == v_in.shape and out.v.nbytes == pool_bytes

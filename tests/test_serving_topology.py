@@ -339,6 +339,32 @@ def test_sp_honors_kv_dtype():
     assert cache.sp.tail_k.dtype == jnp.float8_e4m3fn
 
 
+def test_engine_over_topology_loads_one_sampled_program_per_n_top(
+        topo_path):
+    """The step a pipelined engine keeps in flight (PR 29) is ONE
+    executable per n_top: the inputs a stretch's first dispatch rebuilds
+    from host mirrors are put where the program leaves its own outputs
+    (DecodePrograms.out_sharding), so the first dispatch, the first
+    after fresh keys and the chained ones do not each compile (or load)
+    the mesh program again: five at a start-up before, 5 s of the
+    four-chip cell's warm-up."""
+    from cake_tpu.master import Master
+    args = _mk_args(topology=topo_path)
+    master = Master(args, text_generator=_ctx(args).load_text_model())
+    engine = master.make_engine(max_slots=4)
+    step = engine._decode_scan_impl.step
+    before = step._cache_size()
+    with engine:
+        for want_top in (True, True, False, False):
+            h = engine.submit([7, 11, 13], max_new_tokens=6,
+                              temperature=0.0, repeat_penalty=1.0,
+                              want_top_logprobs=want_top)
+            assert h.wait(timeout=180)
+    chained = [r for r in engine.flight.dump() if r.get("chained")]
+    assert chained, "the pipelined engine kept no step in flight"
+    assert step._cache_size() - before == 2
+
+
 def test_engine_over_topology_multistep_scan_matches_k1(topo_path):
     """Round-3 verdict #4: the pipelined engine decodes K tokens per
     dispatch (scan INSIDE the shard_mapped program) and its output is
