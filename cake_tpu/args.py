@@ -191,8 +191,9 @@ class Args:
     paged_attn: str = "auto"
     # --require-model-type NAME: refuse to start unless the model
     # directory's config.json resolves to this family (a config.json
-    # `model_type`: llama, mistral, qwen2, mixtral, olmoe). An assertion
-    # for scripted deployments, not a switch: nothing else reads it
+    # `model_type`: llama, mistral, qwen2, mixtral, olmoe, glm_moe_dsa).
+    # An assertion for scripted deployments, not a switch: nothing else
+    # reads it
     require_model_type: Optional[str] = None
     # --kv-host-pages N: host-RAM spill tier for the paged pool
     # (cake_tpu/kv/host_tier.py) — preemption victims' pages and cold

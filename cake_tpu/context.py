@@ -134,6 +134,14 @@ class Context:
         # host/device copy ever exists, which is what lets a 70B (or
         # Mixtral-8x22B) topology actually load instead of dying at the
         # eager full-tree load.
+        if getattr(cfg, "kv_lora_rank", None) and (
+                plan.stages > 1 or plan.tp > 1 or plan.dp > 1 or a.sp > 1
+                or a.draft_model is not None):
+            raise ValueError(
+                "model_type glm_moe_dsa (latent attention over the page "
+                "pool) does not serve yet over a topology, --tp, --dp, "
+                "--sp or with --draft-model: one chip's paged engine "
+                "only (ROADMAP.md lists each as left to do)")
         born_sharded = (
             (plan.stages > 1 or plan.tp > 1 or plan.dp > 1)
             and (a.sp <= 1 or plan.stages > 1)

@@ -30,6 +30,9 @@ MODEL_TYPES = {
     "mixtral": "sparse experts, top-k weights renormalised",
     "olmoe": "sparse experts with the raw top-k probabilities, and an "
              "RMSNorm over the query and key projections",
+    "glm_moe_dsa": "latent attention over one cache row a token, a learned "
+                   "sparse indexer whose key sets layers share, "
+                   "sigmoid-routed experts with a shared one (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -45,6 +48,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
         raise ValueError(
             f"unknown model_type {model_type!r} in config.json: this "
             "program serves " + ", ".join(MODEL_TYPES))
+    if model_type == "glm_moe_dsa":
+        from cake_tpu.models.moe.config import GlmMoeDsaConfig
+        return GlmMoeDsaConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)
@@ -91,6 +97,12 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of a query or key head (the RoPE
+        tables' width): the whole head but for latent attention."""
+        return self.head_dim
 
     @property
     def is_moe(self) -> bool:
@@ -140,7 +152,8 @@ class LlamaConfig:
             # SentencePiece vocab — Llama-3 header tokens don't exist
             # there; Qwen2 uses ChatML
             chat_template={"mistral": "mistral", "mixtral": "mistral",
-                           "qwen2": "chatml", "olmoe": "tulu"}.get(
+                           "qwen2": "chatml", "olmoe": "tulu",
+                           "glm_moe_dsa": "chatml"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",
                                    raw.get("model_type") == "qwen2"),

@@ -49,7 +49,7 @@ class RopeTables(NamedTuple):
     @classmethod
     def create(cls, config: LlamaConfig, max_seq_len: int) -> "RopeTables":
         cos, sin = precompute_rope(
-            config.head_dim, max_seq_len, config.rope_theta
+            config.rope_dim, max_seq_len, config.rope_theta
         )
         return cls(cos, sin)
 

@@ -13,6 +13,16 @@ over-allocating HBM.
 Layout (all static shapes — XLA-friendly):
   pool_k/pool_v: [L, N_pages, page, KV*hd]   (page = tokens per page)
   table:         [slots, max_pages] int32    (page ids; -1 = unmapped)
+What a pool row is depends on the attention kind. GQA (every family but
+one): `k` and `v` hold a token's keys and values, KV*hd wide, in every
+layer. Latent attention (glm_moe_dsa): `k` holds ONE latent row a token
+and layer, [L, N_pages, page, kv_lora_rank + qk_rope_head_dim, padded with
+zeros to whole 128-lane tiles: 576 -> 640] (the normed c_kv and the
+rotated key all heads share; no values: they are up-projected from
+it), and `v` the sparse indexer's key,
+[L_full, N_pages, page, index_head_dim], in the layers that compute an
+index only. Both pools go through the one page table and allocator: a
+page id names the same token range in each.
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -79,12 +89,19 @@ class PagedKVCache(NamedTuple):
                 f"page_size {page_size} must divide max_seq_len "
                 f"{max_seq_len}")
         L = config.num_hidden_layers
-        KV = config.num_key_value_heads
-        hd = config.head_dim
-        shape = (L, n_pages, page_size, KV * hd)
+        if getattr(config, "kv_lora_rank", None):
+            # latent attention: one latent row a token, and the
+            # indexer's key in the layers that compute an index
+            shape_k = (L, n_pages, page_size, config.latent_row)
+            shape_v = (len(config.full_layers), n_pages, page_size,
+                       config.index_head_dim)
+        else:
+            shape_k = shape_v = (L, n_pages, page_size,
+                                 config.num_key_value_heads
+                                 * config.head_dim)
         return cls(
-            k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            k=jnp.zeros(shape_k, dtype),
+            v=jnp.zeros(shape_v, dtype),
             table=jnp.full((slots, max_seq_len // page_size), -1,
                            jnp.int32),
         )
@@ -336,6 +353,24 @@ def update_pool_per_row(pool_k, pool_v, layer, k, v, pos, active, table):
     pv = pool_v.at[layer, idx, offs].set(
         _rows(v[:, 0]).astype(pool_v.dtype), mode="drop")
     return pk, pv
+
+
+@jax.named_scope("kv")
+def write_token_rows(pool, layer, rows, slot, position, valid, table):
+    """Scatter one cache row a token into layer `layer` of one pool:
+    rows [T, W] at (table[slot[t], position[t] // page], position[t] %
+    page). slot/position [T] int32; valid [T] bool. Tokens that are not
+    valid, and positions past the table or on unmapped pages, route to
+    the out-of-bounds index and drop. What the latent pools' writers
+    are: a decode step's tokens (slot = the row) and a mixed step's
+    packed axis alike."""
+    N, P = pool.shape[1], pool.shape[2]
+    max_pages = table.shape[1]
+    pidx = position // P
+    pages = table[slot, jnp.minimum(pidx, max_pages - 1)]
+    ok = valid & (pidx < max_pages) & (pages >= 0)
+    return pool.at[layer, jnp.where(ok, pages, N), position % P].set(
+        rows.astype(pool.dtype), mode="drop")
 
 
 def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
@@ -756,11 +791,14 @@ class PackPlan(NamedTuple):
     width: int
 
 
-def mixed_token_buckets(slots: int, width: int) -> tuple:
+def mixed_token_buckets(slots: int, width: int,
+                        prefill_rows: tuple = (1, 2)) -> tuple:
     """The static sizes T of the packed mixed step for an engine of
     `slots` rows and `width`-token windows, ascending: what one
     prefilling row and what two need beside decode rows in every other
-    slot (p*width + slots - p tokens, rounded up to 16 positions). The
+    slot (p*width + slots - p tokens for p in `prefill_rows`, rounded up
+    to 16 positions; latent attention takes one window a dispatch:
+    models/moe/glm_dsa.py). The
     last is the most one dispatch computes: a step that holds more is
     run in several (serve/engine._mixed_dispatch), two prefilling rows
     at a time.
@@ -775,7 +813,7 @@ def mixed_token_buckets(slots: int, width: int) -> tuple:
     size also costs the start-up a trace and a load."""
     full = slots * width
     return tuple(sorted({min(full, -(-(p * width + slots - p) // 16) * 16)
-                         for p in (1, 2)}))
+                         for p in prefill_rows}))
 
 
 def mixed_bucket_for(buckets: tuple, n_real: int) -> int:

@@ -15,6 +15,7 @@ them apart is data, not code:
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Tuple
 
 from cake_tpu.models.llama.config import LlamaConfig
 
@@ -102,3 +103,156 @@ class MoEConfig(LlamaConfig):
             num_experts_per_tok=8, norm_topk_prob=False, qk_norm=True,
             hf_layout="olmoe", chat_template="tulu",
         )
+
+
+@dataclass(frozen=True)
+class GlmMoeDsaConfig(MoEConfig):
+    """GLM-5.2 (`model_type: glm_moe_dsa`): latent attention (MLA), the
+    learned sparse indexer (DSA) whose key sets a "full" layer computes
+    and the "shared" layers after it reuse, leading dense layers, then
+    sigmoid-routed experts with a shared one. The equations are in
+    models/reference/glm_moe_dsa.py; the served path in
+    models/moe/glm_dsa.py.
+
+    `intermediate_size` is the dense layers' FFN width,
+    `moe_intermediate_size` an expert's. `num_local_experts` counts the
+    routed experts HELD here (config.json `n_routed_experts`);
+    `n_routed_experts_total` is the router's width (the published
+    count, config.json key of the same name, absent = all held) and
+    `first_routed_expert` the first held expert's index: one chip's
+    share of an expert-parallel deployment routes over all of them and
+    computes its own (ops/moe.moe_mlp)."""
+
+    q_lora_rank: int = 2048
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # per layer: "dense" | "sparse", and "full" | "shared"
+    mlp_layer_types: Tuple[str, ...] = ()
+    indexer_types: Tuple[str, ...] = ()
+    moe_intermediate_size: int = 2048
+    n_routed_experts_total: int = 256
+    first_routed_expert: int = 0
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+
+    @property
+    def rope_dim(self) -> int:
+        return self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """A latent pool row: the normed c_kv and the rotated shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The width a latent row is STORED at: the latent padded with
+        zeros to whole 128-lane tiles (576 -> 640). The TPU keeps an
+        array whose minor dimension is not a multiple of 128 in a
+        transposed tiled layout, and a step program converted the whole
+        pool on the way in and on the way out (two copies of 15 ms
+        each at 9 x 800 x 128 x 576; compiler, PR 30). A test-sized
+        latent (under one tile) is stored as it is."""
+        w = self.latent_width
+        return w if w <= 128 else -(-w // 128) * 128
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.indexer_types)
+                     if t == "full")
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.mlp_layer_types)
+                     if t == "sparse")
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "GlmMoeDsaConfig":
+        L = raw["num_hidden_layers"]
+        rope = raw.get("rope_parameters") or {}
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, rope_theta=rope.get("rope_theta",
+                                     raw.get("rope_theta", 10000.0))))
+        dense = raw.get("first_k_dense_replace", 0)
+        mlp = tuple(raw.get("mlp_layer_types")
+                    or ["dense"] * dense + ["sparse"] * (L - dense))
+        freq = raw.get("index_topk_freq", 1)
+        idx = tuple(raw.get("indexer_types")
+                    or ["full" if i < dense or (i - dense) % freq == freq - 1
+                        else "shared" for i in range(L)])
+        if len(mlp) != L or len(idx) != L:
+            raise ValueError(
+                f"mlp_layer_types ({len(mlp)}) and indexer_types "
+                f"({len(idx)}) must name num_hidden_layers = {L} layers")
+        if idx[0] != "full":
+            raise ValueError("indexer_types must start with a full layer: "
+                             "a shared layer reuses the set below it")
+        for name in ("n_group", "topk_group"):
+            if raw.get(name, 1) != 1:
+                raise ValueError(f"{name} = {raw[name]}: group-limited "
+                                 "routing is not implemented")
+        if raw.get("n_shared_experts", 1) != 1:
+            raise ValueError("n_shared_experts must be 1")
+        if raw.get("num_nextn_predict_layers", 0):
+            raise ValueError(
+                "num_nextn_predict_layers > 0: the multi-token-prediction "
+                "module is not served (it drafts for speculation and adds "
+                "nothing to the next-token logits); set it to 0")
+        held = raw["n_routed_experts"]
+        total = raw.get("n_routed_experts_total", held)
+        first = raw.get("first_routed_expert", 0)
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        return cls(
+            **{f: getattr(base, f) for f in base.__dataclass_fields__},
+            num_local_experts=held,
+            num_experts_per_tok=raw["num_experts_per_tok"],
+            norm_topk_prob=raw.get("norm_topk_prob", True),
+            hf_layout="glm_moe_dsa",
+            q_lora_rank=raw["q_lora_rank"],
+            kv_lora_rank=raw["kv_lora_rank"],
+            qk_nope_head_dim=raw["qk_nope_head_dim"],
+            qk_rope_head_dim=raw["qk_rope_head_dim"],
+            v_head_dim=raw["v_head_dim"],
+            index_n_heads=raw["index_n_heads"],
+            index_head_dim=raw["index_head_dim"],
+            index_topk=raw["index_topk"],
+            mlp_layer_types=mlp, indexer_types=idx,
+            moe_intermediate_size=raw["moe_intermediate_size"],
+            n_routed_experts_total=total, first_routed_expert=first,
+            routed_scaling_factor=raw.get("routed_scaling_factor", 1.0),
+            scoring_func=raw.get("scoring_func", "sigmoid"),
+        )
+
+    @classmethod
+    def tiny_glm(cls, **overrides) -> "GlmMoeDsaConfig":
+        """GLM-5.2's block at a test's size: one dense layer with a full
+        indexer, then sparse layers shared x2, full, shared; index_topk
+        8, so that a context of a few dozen tokens passes it several
+        times over; 8 routed experts, all held."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=5, num_attention_heads=4,
+            num_key_value_heads=4, rms_norm_eps=1e-5, rope_theta=10000.0,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=True, hf_layout="glm_moe_dsa",
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_n_heads=2,
+            index_head_dim=16, index_topk=8,
+            mlp_layer_types=("dense",) + ("sparse",) * 4,
+            indexer_types=("full", "shared", "shared", "full", "shared"),
+            moe_intermediate_size=32, n_routed_experts_total=8,
+        )
+        base.update(overrides)
+        return cls(**base)

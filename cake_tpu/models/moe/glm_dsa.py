@@ -1,0 +1,507 @@
+"""GLM-5.2 (`glm_moe_dsa`) on the paged engine: the step programs.
+
+The equations are models/reference/glm_moe_dsa.py's; this is how the
+served path computes them over the page pool (models/llama/paged.py says
+what a pool row is here: one latent row a token and layer, and the
+indexer's key in the layers that compute an index).
+
+Both step programs run ONE trunk over a flat list of tokens, each with
+its row (slot) and position: a decode step's B tokens, or a mixed step's
+packed axis (paged.pack_plan). A layer:
+
+  * `mla_q`: the low-rank query path with its norm, RoPE on the rope
+    part (interleaved pairs), and the key up-projection absorbed into
+    the query (q_lat = q_nope W_kvb^K): attention then runs over the
+    latent itself, all heads sharing a row;
+  * `mla_kv`: the token's latent row (normed c_kv | rotated k_pe),
+    written into the latent pool under `kv`;
+  * a FULL indexer layer, `indexer`: the index query from the query
+    latent, the key (LayerNorm, RoPE) written into the index pool, and
+    the scores of every visible key of the token's row, float32: one
+    query a row against its pages for the rows' single tokens, and the
+    window's queries against their row's pages in blocks of keys
+    (ops/mla_attention.py); `index_topk`: the exact top index_topk,
+    ties to the lower index (lax.top_k for a row's single token, the
+    exact mask of ops/mla_attention.select_mask for a window). A SHARED
+    layer reuses the Selection the nearest full layer below left: the
+    value that travels between layers, which is why the layer loop is a
+    Python loop over stacks per kind of layer and not one scan;
+  * `mla_attn`: a row's single token gathers its selected rows out of
+    the latent pool (`mla_gather`, one XLA gather) and attends them in
+    one pass (`cake_mla_attn`); a window's tokens attend their row's
+    pages where they lie, under the selection as a bias
+    (`cake_mla_window_attn`); each has its XLA fold; the value
+    up-projection is applied to the result;
+  * the FFN: dense SwiGLU, or ops/moe.moe_mlp with the sigmoid rule,
+    the selection bias, the held experts and the shared expert.
+
+ONE WINDOW A DISPATCH. The window's score pass takes the one row whose
+keys the queries share, so a mixed dispatch holds at most one row with
+more than one token (the engine groups its rows so:
+serve/engine._mixed_groups) and there is one packed size
+(paged.mixed_token_buckets(..., prefill_rows=(1,))): with one program a
+row's bits do not depend on what shares its step, and a layer here makes
+two discrete choices (keys, experts) that a rounding can flip. A row's
+single token takes the rows' path in the mixed program and in the decode
+program alike.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from cake_tpu.models.llama import paged
+from cake_tpu.models.llama.paged import PagedKVCache, write_token_rows
+from cake_tpu.models.moe.config import GlmMoeDsaConfig
+from cake_tpu.ops import mla_attention as mla
+from cake_tpu.ops.moe import LayerOf, moe_mlp
+from cake_tpu.ops.norms import rms_norm
+from cake_tpu.ops.quant import QTensor, qmatmul
+
+ATTN_LEAVES = ("attn_norm", "wq_a", "q_a_norm", "wq_b", "wkv_a",
+               "kv_a_norm", "wkv_b_k", "wkv_b_v", "wo", "mlp_norm")
+INDEX_LEAVES = ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w")
+DENSE_LEAVES = ("w_gate", "w_up", "w_down")
+SPARSE_LEAVES = ("router", "router_bias", "ws_gate", "ws_up", "ws_down")
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+# a step program returns obs/steps.STEP_COUNTERS, in that order: the
+# expert counters' five, then the routed rows and the indexer's
+N_COUNTERS = 11
+
+
+class Window(NamedTuple):
+    """The one row of a mixed dispatch that holds a window: row (its
+    slot), start (its first packed index), width (static C), member [T]
+    (packed positions that are its tokens, when it has more than one),
+    col [T] (a position's index in the window); and on the window's own
+    axis [C]: positions, real (which of the C are its tokens), last_pos
+    (its last token's position)."""
+
+    row: jnp.ndarray
+    start: jnp.ndarray
+    width: int
+    member: jnp.ndarray
+    col: jnp.ndarray
+    positions: jnp.ndarray
+    real: jnp.ndarray
+    last_pos: jnp.ndarray
+
+
+def layer_leaves(blocks, config: GlmMoeDsaConfig, i: int) -> dict:
+    """Layer i's leaves out of the stacks per kind (static indices); the
+    experts as (stack, index) for the grouped matmul."""
+    def at(names, j):
+        return {k: jax.tree.map(lambda a: a[j], blocks[k]) for k in names}
+
+    lp = at(ATTN_LEAVES, i)
+    if config.indexer_types[i] == "full":
+        lp.update(at(INDEX_LEAVES, config.full_layers.index(i)))
+    if config.mlp_layer_types[i] == "sparse":
+        j = config.sparse_layers.index(i)
+        lp.update(at(SPARSE_LEAVES, j))
+        lp.update({k: LayerOf(blocks[k], jnp.int32(j))
+                   for k in EXPERT_LEAVES})
+    else:
+        lp.update(at(DENSE_LEAVES, i - sum(s < i
+                                           for s in config.sparse_layers)))
+    return lp
+
+
+def rope_pairs(x, cos, sin):
+    """RoPE on interleaved pairs: x [T, ..., d], cos/sin [T, d/2]; the
+    pair (x[2i], x[2i+1]) turns by the i-th angle."""
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1] // 2,)
+    c = cos.astype(jnp.float32).reshape(shape)
+    s = sin.astype(jnp.float32).reshape(shape)
+    even = x[..., 0::2].astype(jnp.float32)
+    odd = x[..., 1::2].astype(jnp.float32)
+    out = jnp.stack([even * c - odd * s, odd * c + even * s], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rope_head(x, cos, sin, n_rope: int):
+    return jnp.concatenate(
+        [rope_pairs(x[..., :n_rope], cos, sin), x[..., n_rope:]], -1)
+
+
+def _layernorm(x, weight, bias, eps: float = 1e-6):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    y = (xf - mean) * lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _per_head(w, heads: int):
+    """A [R, H*d] up-projection as (values [R, H, d] in the compute
+    type's width, per-channel scale [H, d] or None)."""
+    if isinstance(w, QTensor):
+        return (w.q.reshape(w.q.shape[0], heads, -1),
+                w.scale.reshape(heads, -1))
+    return w.reshape(w.shape[0], heads, -1), None
+
+
+def absorb_query(q_nope, wkv_b_k):
+    """q_nope [T, H, dn] through the key up-projection [R, H*dn] ->
+    q_lat [T, H, R]: q_nope . k_nope[s] == q_lat . c_kv[s]. A
+    per-channel int8 weight's scale lies on the contracted axis, so it
+    multiplies the query first."""
+    w, scale = _per_head(wkv_b_k, q_nope.shape[1])
+    if scale is not None:
+        q_nope = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
+    return jnp.einsum("thd,rhd->thr", q_nope, w.astype(q_nope.dtype),
+                      preferred_element_type=jnp.float32
+                      ).astype(q_nope.dtype)
+
+
+def unabsorb_value(o_lat, wkv_b_v):
+    """The attended latent [T, H, R] through the value up-projection
+    [R, H*dv] -> [T, H, dv]."""
+    w, scale = _per_head(wkv_b_v, o_lat.shape[1])
+    out = jnp.einsum("thr,rhv->thv", o_lat, w.astype(o_lat.dtype),
+                     preferred_element_type=jnp.float32)
+    if scale is not None:
+        out = out * scale
+    return out.astype(o_lat.dtype)
+
+
+def _key_block(max_pages: int, page: int) -> int:
+    """Keys a block of the window's score pass holds: whole pages, a
+    divisor of the row's page count, about a thousand keys."""
+    per = max(d for d in range(1, max_pages + 1)
+              if max_pages % d == 0 and d * page <= max(1024, page))
+    return per * page
+
+
+class Selection(NamedTuple):
+    """The key sets a full indexer layer leaves for the shared layers
+    after it. idx [B, K] / n_valid [B]: each row's SINGLE token's
+    selected positions in the row, best first, and how many are real
+    (a decode step's tokens; in a mixed dispatch every row's first
+    packed token). bias [C, S] float32: the window's sets as the bias
+    ops/mla_attention.attend_window takes (0 on a query's selected
+    keys, -1e30 elsewhere), or None where there is no window."""
+
+    idx: jnp.ndarray
+    n_valid: jnp.ndarray
+    bias: Optional[jnp.ndarray]
+
+
+def _window_slice(x, window: Window):
+    """The window's C entries of a packed [T, ...] array (padded by a
+    window so that the slice never clamps)."""
+    x = jnp.pad(x, ((0, window.width),) + ((0, 0),) * (x.ndim - 1))
+    return lax.dynamic_slice_in_dim(x, window.start, window.width, axis=0)
+
+
+def select_keys(lp, h, c_q, cos, sin, slot, position, real, first,
+                pool_idx, layer_f: int, table, config: GlmMoeDsaConfig,
+                window: Optional[Window]):
+    """A full indexer layer's key sets: writes the tokens' index keys,
+    scores every visible key of each token's row, takes the top
+    index_topk. first [B]: each row's single token on the token axis.
+    Returns (pool_idx, Selection, distinct: the cache rows this dispatch
+    selected, counted once each)."""
+    c = config
+    T = h.shape[0]
+    nI, dI, dr = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    P, max_pages = pool_idx.shape[2], table.shape[1]
+    S = max_pages * P
+    K = min(c.index_topk, S)
+    span = jnp.arange(S)[None, :]
+    with jax.named_scope("indexer"):
+        qI = _rope_head(qmatmul(c_q, lp["wi_q"]).reshape(T, nI, dI),
+                        cos, sin, dr)
+        kI = _rope_head(_layernorm(qmatmul(h, lp["wi_k"]), lp["wi_k_norm"],
+                                   lp["wi_k_bias"]), cos, sin, dr)
+        w = (jnp.dot(h.astype(jnp.float32), lp["wi_w"].astype(jnp.float32))
+             * (nI ** -0.5) * (dI ** -0.5))
+        pool_idx = write_token_rows(pool_idx, layer_f, kI, slot, position,
+                                    real, table)
+        # every row's keys as one [S, dI] range (an unmapped page reads
+        # page 0: it lies beyond every visible position)
+        keys = pool_idx.at[layer_f, jnp.maximum(table, 0)].get(
+            mode="promise_in_bounds").reshape(table.shape[0], S, dI)
+        rows = mla.index_scores_rows(qI[first], keys, w[first])   # [B, S]
+        # (an idle row's first packed index is its successor's: not its)
+        rows_pos = position[first]
+        rows_real = real[first] & (slot[first] == jnp.arange(first.shape[0]))
+        rows = jnp.where(span <= rows_pos[:, None], rows, -jnp.inf)
+        if window is not None:
+            win = mla.index_scores_window(
+                _window_slice(qI, window), keys[window.row],
+                _window_slice(w, window), window.last_pos,
+                _key_block(max_pages, P))
+    with jax.named_scope("index_topk"):
+        _, idx = lax.top_k(rows, K)
+        n_valid = jnp.minimum(rows_pos + 1, K).astype(jnp.int32)
+        single = rows_real
+        bias = None
+        distinct = jnp.float32(0)
+        if window is not None:
+            picked = mla.select_mask(
+                win, span <= window.positions[:, None], K)
+            bias = jnp.where(picked, 0.0, mla.NEG_INF).astype(jnp.float32)
+            # distinct cache rows selected: a window's tokens share their
+            # row's keys, a single token's are its own
+            distinct = jnp.sum(
+                jnp.any(picked & window.real[:, None], axis=0),
+                dtype=jnp.float32)
+            single = single & ~((jnp.arange(table.shape[0]) == window.row)
+                                & jnp.any(window.real))
+        distinct = distinct + jnp.sum(jnp.where(single, n_valid, 0),
+                                      dtype=jnp.float32)
+    return pool_idx, Selection(idx.astype(jnp.int32), n_valid, bias), distinct
+
+
+def project_latent(lp, h, cos, sin, slot, position, real, pool_lat,
+                   layer: int, table, config):
+    """The query path and the token's latent row, written into the
+    pool. Returns (q_cat [T, H, row]: the absorbed query | the rotated
+    rope part | zeros over the stored row's padding, pool_lat, c_q: the
+    query latent the indexer reads)."""
+    c = config
+    T = h.shape[0]
+    H, R = c.num_attention_heads, c.kv_lora_rank
+    dn, dr = c.qk_nope_head_dim, c.qk_rope_head_dim
+    with jax.named_scope("mla_q"):
+        c_q = rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"],
+                       c.rms_norm_eps)
+        q = qmatmul(c_q, lp["wq_b"]).reshape(T, H, dn + dr)
+        # zeros where the stored row has its padding (config.latent_row)
+        pad = pool_lat.shape[-1] - R - dr
+        q_cat = jnp.concatenate(
+            [absorb_query(q[..., :dn], lp["wkv_b_k"]),
+             rope_pairs(q[..., dn:], cos, sin),
+             jnp.zeros((T, H, pad), q.dtype)], -1)          # [T, H, row]
+    with jax.named_scope("mla_kv"):
+        kva = qmatmul(h, lp["wkv_a"])
+        row = jnp.concatenate(
+            [rms_norm(kva[:, :R], lp["kv_a_norm"], c.rms_norm_eps),
+             rope_pairs(kva[:, R:], cos, sin),
+             jnp.zeros((T, pad), kva.dtype)], -1)           # [T, row]
+        pool_lat = write_token_rows(pool_lat, layer, row, slot, position,
+                                    real, table)
+    return q_cat, pool_lat, c_q
+
+
+def attend(q_cat, pool_lat, layer: int, table, slot, first,
+           selection: Selection, config, attn: str,
+           window: Optional[Window]):
+    """Every token over its selected rows -> the attended latent
+    [T, H, R]. A row's single token: its rows gathered (`mla_gather`)
+    and attended in one pass (`cake_mla_attn`). The window's tokens:
+    their row's pages where they lie, under the selection's bias
+    (`cake_mla_window_attn`)."""
+    c = config
+    P = pool_lat.shape[2]
+    scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+    with jax.named_scope("mla_gather"):
+        # a list's tail past n_valid may name an unmapped page: read
+        # page 0 there (finite, never attended) rather than pay a
+        # select over the gathered rows for a fill value
+        rows = jnp.arange(first.shape[0])[:, None]
+        pages = jnp.maximum(table[rows, selection.idx // P], 0)
+        kv = pool_lat.at[layer, pages, selection.idx % P].get(
+            mode="promise_in_bounds")
+    with jax.named_scope("mla_attn"):
+        out = mla.attend_selected(q_cat[first], kv.astype(q_cat.dtype),
+                                  selection.n_valid, c.kv_lora_rank, scale,
+                                  impl=attn)
+        if window is None:
+            return out
+        win = mla.attend_window(
+            _window_slice(q_cat, window), pool_lat, jnp.int32(layer),
+            table[window.row], selection.bias, window.last_pos,
+            c.kv_lora_rank, scale, impl=attn)
+        return jnp.where(window.member[:, None, None], win[window.col],
+                         out[slot])
+
+
+class TrunkOut(NamedTuple):
+    """x [T, D] after the final norm; cache; counters [N_COUNTERS];
+    and the two choices themselves, for a tool that compares them with
+    the reference's (chip_compare.py; a step program drops them):
+    experts [L_sparse, T, k]; selected [L_full, B, K] / n_selected [B],
+    each row's single token's keys; selected_window [L_full, C, S]
+    bool, the window's (empty where there is no window)."""
+
+    x: jnp.ndarray
+    cache: PagedKVCache
+    counters: jnp.ndarray
+    experts: jnp.ndarray
+    selected: jnp.ndarray
+    n_selected: jnp.ndarray
+    selected_window: jnp.ndarray
+
+
+def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
+          rope, config: GlmMoeDsaConfig, attn: str,
+          window: Optional[Window] = None, first=None) -> TrunkOut:
+    """Embed, every layer, final norm, over T tokens: token_ids, slot,
+    position [T] int32, real [T] bool (a token that is not real writes
+    nothing, is not routed, and its output is garbage nobody reads).
+    first [B]: each row's single token on the token axis (with a window:
+    its first packed token); without a window T == B and token t is
+    row t's."""
+    c = config
+    blocks = params["blocks"]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], token_ids, axis=0)
+    at = jnp.minimum(position, rope.cos.shape[0] - 1)
+    cos, sin = jnp.take(rope.cos, at, axis=0), jnp.take(rope.sin, at, axis=0)
+    first_expert = (c.first_routed_expert
+                    if c.num_local_experts < c.n_routed_experts_total
+                    else None)
+    pool_lat, pool_idx, table = cache.k, cache.v, cache.table
+    if first is None:
+        first = jnp.arange(x.shape[0])
+    selection = None
+    moe, experts, selected, windows = [], [], [], []
+    distinct = jnp.float32(0)
+    with jax.named_scope("layers"):
+        for i in range(c.num_hidden_layers):
+            lp = layer_leaves(blocks, c, i)
+            with jax.named_scope("attn_norm"):
+                h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+            with jax.named_scope("attn"):
+                q_cat, pool_lat, c_q = project_latent(
+                    lp, h, cos, sin, slot, position, real, pool_lat, i,
+                    table, c)
+                if "wi_q" in lp:
+                    pool_idx, selection, last_distinct = select_keys(
+                        lp, h, c_q, cos, sin, slot, position, real, first,
+                        pool_idx, c.full_layers.index(i), table, c, window)
+                    selected.append(selection.idx)
+                    if selection.bias is not None:
+                        windows.append(selection.bias == 0)
+                distinct = distinct + last_distinct
+                o_lat = attend(q_cat, pool_lat, i, table, slot, first,
+                               selection, c, attn, window)
+                o = unabsorb_value(o_lat, lp["wkv_b_v"])
+            with jax.named_scope("o_proj"):
+                x = x + qmatmul(o.reshape(o.shape[0], -1), lp["wo"])
+            with jax.named_scope("ffn"):
+                h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
+                if "router" in lp:
+                    out, stats = moe_mlp(
+                        lp, h[None], c.num_experts_per_tok,
+                        c.norm_topk_prob, token_mask=real[None],
+                        first_expert=first_expert, scoring=c.scoring_func,
+                        scale=c.routed_scaling_factor)
+                    moe.append(stats)
+                    experts.append(stats.experts)
+                    x = x + out[0]
+                else:
+                    gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
+                    x = x + qmatmul(gate * qmatmul(h, lp["w_up"]),
+                                    lp["w_down"])
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+    n_real = jnp.sum(real, dtype=jnp.float32)
+    L, Lf = c.num_hidden_layers, len(c.full_layers)
+    stepped = (n_real > 0).astype(jnp.float32)
+    visible = jnp.where(real, position + 1, 0).astype(jnp.float32)
+    f32 = jnp.float32
+
+    def over(field, reduce):
+        return (reduce(jnp.stack([getattr(s, field) for s in moe]))
+                if moe else f32(0))
+
+    counters = jnp.stack([
+        over("rows", jnp.sum), over("rows_padded", jnp.sum),
+        over("load_max", jnp.mean), over("load_mean", jnp.mean),
+        over("touched", jnp.sum), over("rows_routed", jnp.sum),
+        L * jnp.sum(visible),
+        L * jnp.sum(jnp.minimum(visible, min(c.index_topk,
+                                             table.shape[1]
+                                             * pool_lat.shape[2]))),
+        distinct, Lf * stepped, (L - Lf) * stepped]).astype(f32)
+    return TrunkOut(x, cache._replace(k=pool_lat, v=pool_idx), counters,
+                    jnp.stack(experts) if experts else jnp.zeros((0,)),
+                    jnp.stack(selected), selection.n_valid,
+                    jnp.stack(windows) if windows else jnp.zeros((0,), bool))
+
+
+def window_of(plan: paged.PackPlan, pos, q_len, active) -> Window:
+    """The dispatch's one window: the row with the most tokens."""
+    n = jnp.where(active, q_len, 0)
+    row = jnp.argmax(n).astype(jnp.int32)
+    cols = jnp.arange(plan.width)
+    real = (cols < n[row]) & (n[row] > 1)
+    return Window(row, plan.start[row], plan.width,
+                  (plan.row == row) & (n[row] > 1) & plan.real, plan.col,
+                  pos[row] + cols, real,
+                  pos[row] + jnp.maximum(n[row], 1) - 1)
+
+
+def mixed_trunk(params, tokens, pos, q_len, active, cache: PagedKVCache,
+                rope, config: GlmMoeDsaConfig, attn: str, n_tokens: int):
+    """The mixed step's trunk on the packed axis [n_tokens] ->
+    (TrunkOut, PackPlan)."""
+    plan = paged.pack_plan(q_len, active, n_tokens, tokens.shape[1])
+    out = trunk(params, tokens[plan.row, plan.col], plan.row,
+                pos[plan.row] + plan.col, plan.real, cache, rope, config,
+                attn, window_of(plan, pos, q_len, active),
+                jnp.minimum(plan.start, n_tokens - 1))
+    return out, plan
+
+
+@partial(jax.jit, static_argnames=("config", "attn", "n_tokens"),
+         donate_argnames=("cache",))
+def mixed_step_latent(params, tokens, pos, q_len, active,
+                      cache: PagedKVCache, rope, config: GlmMoeDsaConfig,
+                      attn: str = "fold", n_tokens: Optional[int] = None):
+    """paged.mixed_step_paged's contract for latent attention: tokens
+    [B, C] right-padded windows, pos/q_len [B], active [B] -> (logits
+    [B, V] of each row's last real token, cache, counters). At most ONE
+    active row may hold more than one token (module docstring), and
+    n_tokens, the packed size, is required."""
+    if n_tokens is None:
+        raise ValueError("the latent mixed step runs on the packed axis: "
+                         "pass n_tokens")
+    out, plan = mixed_trunk(params, tokens, pos, q_len, active, cache, rope,
+                            config, attn, n_tokens)
+    with jax.named_scope("head"):
+        last = (jnp.maximum(q_len, 1) - 1).astype(jnp.int32)
+        last = jnp.take(out.x, jnp.minimum(plan.start + last, n_tokens - 1),
+                        axis=0)
+        logits = qmatmul(last, params["lm_head"]).astype(jnp.float32)
+    return logits, out.cache, out.counters
+
+
+def decode_trunk(params, tokens, cache: PagedKVCache, pos, active, rope,
+                 config: GlmMoeDsaConfig, attn: str) -> TrunkOut:
+    """One token a row: tokens [B, 1], pos/active [B]."""
+    B = tokens.shape[0]
+    return trunk(params, tokens[:, 0], jnp.arange(B, dtype=jnp.int32),
+                 pos.astype(jnp.int32), active, cache, rope, config, attn)
+
+
+def forward_ragged_latent(params, tokens, cache: PagedKVCache, pos, active,
+                          rope, config: GlmMoeDsaConfig,
+                          attn: str = "fold"):
+    """paged.forward_ragged_paged(..., counters=True)'s contract: what
+    serve.engine.make_decode_scan builds the sampled decode programs
+    from -> (logits [B, V], cache, counters)."""
+    out = decode_trunk(params, tokens, cache, pos, active, rope, config,
+                       attn)
+    with jax.named_scope("head"):
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+    return logits, out.cache, out.counters
+
+
+@partial(jax.jit, static_argnames=("config", "attn"),
+         donate_argnames=("cache",))
+def decode_step_latent(params, tokens, pos, active, cache: PagedKVCache,
+                       rope, config: GlmMoeDsaConfig, attn: str = "fold"):
+    """paged.decode_step_ragged_paged's contract (the synchronous
+    decode step)."""
+    return forward_ragged_latent(params, tokens, cache, pos, active, rope,
+                                 config, attn)

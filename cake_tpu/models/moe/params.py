@@ -24,30 +24,17 @@ from cake_tpu.models.llama.params import _np_dtype
 from cake_tpu.models.moe.config import MoEConfig
 
 
-def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
-                bits: Optional[int] = None):
-    """Random-init MoE parameter pytree (tests, benchmarks, a model
-    directory with no weights).
-
-    bits=8 draws the matmul leaves (attention, experts, head) as int8
-    per-channel QTensors directly, as `quantize_params(bits=8)` would
-    leave them: a full-precision copy of a published-width model never
-    exists (OLMoE-1B-7B is 13.8 GB in bf16, 6.9 GB as drawn here). Run
-    under jit (`init_params_jit`) each leaf's draw-and-cast fuses, so
-    the program holds the tree and nothing beside it. Norm weights are
-    drawn around 1 where the family has query/key norm, so that a test
-    sees them."""
+def _draws(rng: jax.Array, dtype, bits: Optional[int], n_keys: int):
+    """(w, mat, keys): the float draw `w(shape, fan_in)`, the matmul
+    leaf's draw `mat(name, shape, fan_in)` (an int8 per-channel QTensor
+    when bits == 8) and the key iterator both take from."""
     from cake_tpu.ops.quant import _BLOCK_CONTRACT, QTensor
 
     if bits not in (None, 8):
         raise NotImplementedError(
             "MoE expert weights quantize per-channel only; use "
             "--quant int8 for MoE models")
-    c = config
-    L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
-    E = c.num_local_experts
-    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    keys = iter(jax.random.split(rng, 16))
+    keys = iter(jax.random.split(rng, n_keys))
 
     def w(shape, fan_in):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -64,6 +51,96 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
             tuple(n for i, n in enumerate(shape) if i not in contract),
             np.sqrt(3.0) / (127.0 * np.sqrt(fan_in)), jnp.float32)
         return QTensor(q=q, scale=scale)
+
+    return w, mat, keys
+
+
+def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of a GlmMoeDsaConfig. Leaves are stacked per
+    KIND of layer, so each has the leading axis of the layers that have
+    it: the attention leaves [L, ...], the indexer's [L_full, ...]
+    (layers whose indexer_types entry is "full"), the dense FFN's
+    [L_dense, ...], the router's, the experts' and the shared expert's
+    [L_sparse, ...]. Norm weights, the indexer key's LayerNorm and the
+    router's selection bias are drawn away from their neutral values,
+    so that a test sees them."""
+    c = config
+    L, D = c.num_hidden_layers, c.hidden_size
+    H, R, Rq = c.num_attention_heads, c.kv_lora_rank, c.q_lora_rank
+    dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+    nI, dI = c.index_n_heads, c.index_head_dim
+    Lf, Ls = len(c.full_layers), len(c.sparse_layers)
+    Ld = L - Ls
+    E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
+                     c.moe_intermediate_size, c.intermediate_size)
+    w, mat, keys = _draws(rng, dtype, bits, 40)
+
+    def near(shape, centre):
+        return (centre + 0.1 * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    blocks = {
+        "attn_norm": near((L, D), 1.0),
+        "wq_a": mat("wq_a", (L, D, Rq), D),
+        "q_a_norm": near((L, Rq), 1.0),
+        "wq_b": mat("wq_b", (L, Rq, H * (dn + dr)), Rq),
+        "wkv_a": mat("wkv_a", (L, D, R + dr), D),
+        "kv_a_norm": near((L, R), 1.0),
+        "wkv_b_k": mat("wkv_b_k", (L, R, H * dn), R),
+        "wkv_b_v": mat("wkv_b_v", (L, R, H * dv), R),
+        "wo": mat("wo", (L, H * dv, D), H * dv),
+        "mlp_norm": near((L, D), 1.0),
+        "wi_q": mat("wi_q", (Lf, Rq, nI * dI), Rq),
+        "wi_k": mat("wi_k", (Lf, D, dI), D),
+        "wi_k_norm": near((Lf, dI), 1.0),
+        "wi_k_bias": near((Lf, dI), 0.0),
+        "wi_w": w((Lf, D, nI), D),
+        "router": w((Ls, D, Et), D),
+        # the selection bias (e_score_correction_bias): float32 as the
+        # scores it is added to, of the size of their spread
+        "router_bias": 0.05 * jax.random.normal(
+            next(keys), (Ls, Et), jnp.float32),
+        "we_gate": mat("we_gate", (Ls, E, D, Fe), D),
+        "we_up": mat("we_up", (Ls, E, D, Fe), D),
+        "we_down": mat("we_down", (Ls, E, Fe, D), Fe),
+        "ws_gate": mat("ws_gate", (Ls, D, Fe), D),
+        "ws_up": mat("ws_up", (Ls, D, Fe), D),
+        "ws_down": mat("ws_down", (Ls, Fe, D), Fe),
+    }
+    if Ld:
+        blocks.update({
+            "w_gate": mat("w_gate", (Ld, D, Fd), D),
+            "w_up": mat("w_up", (Ld, D, Fd), D),
+            "w_down": mat("w_down", (Ld, Fd, D), Fd),
+        })
+    return {
+        "embed": w((c.vocab_size, D), D),
+        "blocks": blocks,
+        "final_norm": near((D,), 1.0),
+        "lm_head": mat("lm_head", (D, c.vocab_size), D),
+    }
+
+
+def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
+                bits: Optional[int] = None):
+    """Random-init MoE parameter pytree (tests, benchmarks, a model
+    directory with no weights).
+
+    bits=8 draws the matmul leaves (attention, experts, head) as int8
+    per-channel QTensors directly, as `quantize_params(bits=8)` would
+    leave them: a full-precision copy of a published-width model never
+    exists (OLMoE-1B-7B is 13.8 GB in bf16, 6.9 GB as drawn here). Run
+    under jit (`init_params_jit`) each leaf's draw-and-cast fuses, so
+    the program holds the tree and nothing beside it. Norm weights are
+    drawn around 1 where the family has query/key norm, so that a test
+    sees them."""
+    if getattr(config, "kv_lora_rank", None):
+        return _init_glm_params(config, rng, dtype, bits)
+    c = config
+    L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
+    E = c.num_local_experts
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    w, mat, keys = _draws(rng, dtype, bits, 16)
 
     blocks = {
         "attn_norm": jnp.ones((L, D), dtype),

@@ -192,6 +192,38 @@ MOE_COUNTERS = (
         "Experts with at least one token, all layers: the expert "
         "weights the steps had to read")),
 )
+# a step program whose layers hold a SHARE of their router's experts,
+# or select their keys (glm_moe_dsa: models/moe/glm_dsa.trunk), returns
+# these after the five above; the rows above then count the held
+# experts' rows alone
+DSA_COUNTERS = (
+    ("moe_rows_routed", _m.counter(
+        "cake_moe_rows_routed_total",
+        "(token, expert) pairs the routers chose over ALL their "
+        "experts, all layers (over cake_moe_rows_total: the share "
+        "computed here)")),
+    ("dsa_keys_visible", _m.counter(
+        "cake_dsa_keys_visible_total",
+        "Keys visible to the query tokens, summed over tokens and "
+        "attention layers")),
+    ("dsa_keys_selected", _m.counter(
+        "cake_dsa_keys_selected_total",
+        "Keys the sparse indexer selected (attended), summed over "
+        "tokens and attention layers")),
+    ("dsa_rows_distinct", _m.counter(
+        "cake_dsa_rows_distinct_total",
+        "Distinct cache rows a dispatch selected, summed over "
+        "attention layers: what no kernel can avoid reading")),
+    ("dsa_index_layers", _m.counter(
+        "cake_dsa_index_layers_total",
+        "Attention layers that computed their key sets, summed over "
+        "dispatches")),
+    ("dsa_index_reused", _m.counter(
+        "cake_dsa_index_reused_total",
+        "Attention layers that reused the set of the layer below, "
+        "summed over dispatches")),
+)
+STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS
 
 
 def refresh_page_gauges(engine) -> None:
@@ -444,8 +476,9 @@ class StepRecord:
     # a decode step: dispatched from the previous step's on-device
     # carry while that step was still in flight
     chained: Optional[bool] = None
-    # sparse-expert counters since the previous record that carried
-    # them, in the order of MOE_COUNTERS
+    # the step programs' counters since the previous record that
+    # carried them, in the order of STEP_COUNTERS (a sparse model's
+    # five, a glm_moe_dsa model's eleven)
     moe: Optional[Tuple[float, ...]] = None
 
     def to_dict(self) -> Dict:
@@ -488,7 +521,7 @@ class StepRecord:
         if self.chained is not None:
             out["chained"] = self.chained
         if self.moe is not None:
-            for (key, _series), v in zip(MOE_COUNTERS, self.moe):
+            for (key, _series), v in zip(STEP_COUNTERS, self.moe):
                 out[key] = round(v, 3)
         return out
 
@@ -729,7 +762,7 @@ class StepTelemetry:
             _MIXED_TOKENS.inc(tokens_real)
             _MIXED_TOKENS_COMPUTED.inc(tokens_computed)
         if moe is not None:
-            for (_key, series), v in zip(MOE_COUNTERS, rec.moe):
+            for (_key, series), v in zip(STEP_COUNTERS, rec.moe):
                 series.inc(v)
         if mfu is not None:
             _STEP_MFU.labels(kind=kind).set(_sig(mfu))

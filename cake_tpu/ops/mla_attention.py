@@ -1,0 +1,303 @@
+"""Latent attention (MLA) over SELECTED cache rows, and the sparse
+indexer's score pass: the two new pieces of attention a glm_moe_dsa layer
+runs (models/moe/glm_dsa.py; the equations are in
+models/reference/glm_moe_dsa.py).
+
+`attend_selected`. Every head of a token attends the same list of cache
+rows (the indexer chose it), and a cache row is one latent: the normed
+c_kv [R] and the rotated shared key [dr]. With the key up-projection
+absorbed into the query (q_lat = q_nope W_kvb^K, [H, R]) a head's score
+against a row is one dot over R + dr numbers, and its value is the
+row's first R numbers (up-projected after the weighted sum): multi-query
+attention over the latent, H heads sharing each row. The rows arrive
+GATHERED, [T, K, R + dr] (the caller's XLA gather out of the page pool:
+a per-row DMA from inside a kernel costs more than the row), sorted
+best first, so a token's valid rows are its first n_valid.
+
+  * impl "fold": the reference semantics in XLA, float32 softmax.
+  * impl "pallas": `cake_mla_attn`, grid (T,): one token's [H, R + dr]
+    queries against its [K, R + dr] rows in VMEM, scores, the masked
+    softmax and the weighted sum in one pass (K = index_topk rows fit:
+    2048 x 576 bf16 is 2.4 MB), so the gathered rows are read once.
+
+`attend_window`. A window's queries share their row's keys, and between
+them select most of what is visible, so the window attends its row's
+pages WHERE THEY LIE, densely, under a bias that is 0 on a query's
+selected keys and -1e30 elsewhere (`cake_mla_window_attn`): gathering
+528 x 2,048 rows cost 25 ms a layer, a dense pass over 12k keys 3 (my
+chip run, PR 30). `select_mask` is the selection as that mask.
+
+`index_scores_rows`. I[b, s] = sum_j w[b, j] relu(qI[b, j] . kI[b, s])
+for one query a row against that row's whole key range, float32: the
+decode rows' pass. `index_scores_window` is the same for a window of C
+queries against ONE row's keys, computed in blocks of keys so that the
+[C, heads, block] intermediate stays small; blocks past the window's
+last position are skipped.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from cake_tpu.ops import ragged_paged_attention as rpa
+
+NEG_INF = -1e30
+
+
+def _attend_fold(q, kv, n_valid, r: int, scale: float):
+    K = kv.shape[1]
+    s = jnp.einsum("thw,tkw->thk", q, kv,
+                   preferred_element_type=jnp.float32) * scale
+    ok = jnp.arange(K)[None, :] < n_valid[:, None]
+    s = jnp.where(ok[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("thk,tkr->thr", p.astype(kv.dtype), kv[..., :r],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _attend_kernel(n_ref, q_ref, kv_ref, o_ref, *, r: int, scale: float):
+    t = pl.program_id(0)
+    q = q_ref[...]
+    kv = kv_ref[...]
+    s = rpa._dot(q, kv, trans_b=True) * scale              # [H, K] f32
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(col < n_ref[t], s, NEG_INF)
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=1, keepdims=True)
+    o = rpa._dot(p.astype(kv.dtype), kv[:, :r], trans_b=False)
+    o_ref[...] = (o / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
+def _attend_pallas(q, kv, n_valid, *, r: int, scale: float,
+                   interpret: bool):
+    T, H, W = q.shape
+    K = kv.shape[1]
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, r=r, scale=scale),
+        name="cake_mla_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(T,),
+            in_specs=[pl.BlockSpec((None, H, W), lambda t, n: (t, 0, 0)),
+                      pl.BlockSpec((None, K, W), lambda t, n: (t, 0, 0))],
+            out_specs=pl.BlockSpec((None, H, r), lambda t, n: (t, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((T, H, r), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(n_valid.astype(jnp.int32), q, kv)
+
+
+def attend_selected(q, kv, n_valid, r: int, scale: float,
+                    impl: str = "fold", interpret: Optional[bool] = None):
+    """q [T, H, W] (the absorbed query: q_lat | q_pe), kv [T, K, W] the
+    token's selected cache rows (its first n_valid[t] are real), r the
+    value's width (the leading r of a row). Returns the weighted sum of
+    the rows' first r numbers, [T, H, r]; a token with no valid row
+    (padding) gets an unspecified finite result."""
+    if impl == "pallas":
+        if interpret is None:
+            interpret = not rpa._on_tpu()
+        return _attend_pallas(q, kv, n_valid, r=r, scale=scale,
+                              interpret=interpret)
+    if impl != "fold":
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    return _attend_fold(q, kv, n_valid, r, scale)
+
+
+# -- a window's queries over their row's pages ---------------------------------
+
+
+def _window_fold(q, pool, layer, table_row, bias, r: int, scale: float):
+    """The reference semantics in XLA: the row's pages gathered whole,
+    every (query, key) scored, the bias added."""
+    C, H, W = q.shape
+    keys = pool.at[layer, jnp.maximum(table_row, 0)].get(
+        mode="promise_in_bounds").reshape(-1, W)               # [S, W]
+    s = jnp.einsum("chw,sw->chs", q, keys.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    s = s + bias[:, None, :]
+    ok = s > NEG_INF / 2
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("chs,sr->chr", (p / l).astype(q.dtype),
+                     keys[:, :r].astype(q.dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _window_kernel(layer_ref, table_ref, last_ref, q_ref, bias_ref, kv_ref,
+                   o_ref, acc_ref, m_ref, l_ref, *, r: int, scale: float,
+                   heads: int, page: int):
+    del layer_ref, table_ref
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * page <= last_ref[0])
+    def _():
+        q = q_ref[...]                                  # [tq*H, W]
+        kv = kv_ref[...]                                # [page, W]
+        s = rpa._dot(q, kv, trans_b=True) * scale       # [tq*H, page]
+        # a token's bias row reaches its H head rows through a one-hot
+        # product: rows of q are (token, head), rows of the bias tokens
+        bias = bias_ref[...]                            # [tq, page] f32
+        rows = lax.broadcasted_iota(jnp.int32, (q.shape[0], bias.shape[0]),
+                                    0) // heads
+        cols = lax.broadcasted_iota(jnp.int32, (q.shape[0], bias.shape[0]),
+                                    1)
+        s = s + jnp.dot((rows == cols).astype(jnp.float32), bias,
+                        preferred_element_type=jnp.float32)
+        m_old = m_ref[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_old - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + rpa._dot(
+            p.astype(kv.dtype), kv[:, :r], trans_b=False)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
+def _window_pallas(q, pool, layer, table_row, bias, last_pos, *, r: int,
+                   scale: float, interpret: bool):
+    C, H, W = q.shape
+    page, max_pages = pool.shape[2], table_row.shape[0]
+    tq = next(t for t in (16, 8, 4, 2, 1) if C % t == 0)
+
+    def kv_map(i, j, layer, table, last):
+        # pages past the window's last position repeat the last one
+        # needed: the DMA is elided, the compute skipped
+        return layer[0], table[jnp.minimum(j, last[0] // page)], 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, r=r, scale=scale, heads=H,
+                          page=page),
+        name="cake_mla_window_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(C // tq, max_pages),
+            in_specs=[
+                pl.BlockSpec((tq * H, W), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((tq, page), lambda i, j, *_: (i, j)),
+                pl.BlockSpec((None, None, page, W), kv_map)],
+            out_specs=pl.BlockSpec((tq * H, r), lambda i, j, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tq * H, r), jnp.float32),
+                            pltpu.VMEM((tq * H, 1), jnp.float32),
+                            pltpu.VMEM((tq * H, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((C * H, r), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.maximum(table_row, 0).astype(jnp.int32),
+      jnp.reshape(last_pos, (1,)).astype(jnp.int32),
+      q.reshape(C * H, W), bias, pool)
+    return out.reshape(C, H, r)
+
+
+def attend_window(q, pool, layer, table_row, bias, last_pos, r: int,
+                  scale: float, impl: str = "fold",
+                  interpret: Optional[bool] = None):
+    """A window's C queries (q [C, H, W], the absorbed form) over ONE
+    row's pages of the latent pool [L, N, page, W], read where they lie
+    through table_row [max_pages]: no gather. bias [C, S] float32 says
+    which keys a query attends: 0 for a selected key, NEG_INF for every
+    other (unselected, invisible, or on an unmapped page); last_pos:
+    the window's last position (pages past it are not read). Returns
+    [C, H, r]; a query with no selected key gets zeros.
+
+    impl "pallas": `cake_mla_window_attn`, grid (query tiles, pages),
+    the flash recurrence over the page axis, 16 tokens x H heads a tile
+    so that the MXU sees a thousand rows a page."""
+    if impl == "pallas":
+        if interpret is None:
+            interpret = not rpa._on_tpu()
+        return _window_pallas(q, pool, layer, table_row, bias, last_pos,
+                              r=r, scale=scale, interpret=interpret)
+    if impl != "fold":
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    return _window_fold(q, pool, layer, table_row, bias, r, scale)
+
+
+# -- the indexer's scores and its selection ------------------------------------
+
+
+def _sortable(x):
+    """float32 -> uint32 codes in the floats' order."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def select_mask(scores, visible, k: int):
+    """The top-k of each row as a mask: scores [N, S] float32, visible
+    [N, S] bool (what a row may select from) -> [N, S] bool with the k
+    largest visible scores of a row (all of them where fewer are
+    visible), ties at the k-th value to the lower index. Exact, and no
+    sort: the k-th largest code is found bit by bit (32 counting
+    passes), the ties by a running count."""
+    u = jnp.where(visible, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, cand):
+        trial = cand | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(u >= trial[:, None], axis=1)
+        return jnp.where(n >= k, trial, cand)
+
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[0], jnp.uint32))
+    above = visible & (u > kth[:, None])
+    tied = visible & (u == kth[:, None])
+    room = k - jnp.sum(above, axis=1, keepdims=True)
+    return above | (tied & (jnp.cumsum(tied, axis=1) <= room))
+
+
+def _weighted_relu(q, k, w):
+    """q [.., Q, J, d], k [.., S, d], w [.., Q, J] -> [.., Q, S] f32."""
+    dots = jnp.einsum("...qjd,...sd->...qjs", q, k,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("...qjs,...qj->...qs", jax.nn.relu(dots),
+                      w.astype(jnp.float32))
+
+
+def index_scores_rows(qI, kI, w):
+    """One query a row: qI [B, J, d], kI [B, S, d], w [B, J] ->
+    I [B, S] float32."""
+    return _weighted_relu(qI[:, None], kI, w[:, None])[:, 0]
+
+
+def index_scores_window(qI, kI, w, last_pos, block: int):
+    """A window's queries against one row's keys: qI [C, J, d],
+    kI [S, d] (S a multiple of block), w [C, J] -> I [C, S] float32.
+    Key blocks that start past `last_pos` (the window's last position:
+    nothing there is visible to any query) are zeros."""
+    C = qI.shape[0]
+    S = kI.shape[0]
+    blocks = kI.reshape(S // block, block, kI.shape[-1])
+
+    def one(args):
+        i, kb = args
+        return lax.cond(i * block <= last_pos,
+                        lambda: _weighted_relu(qI, kb, w),
+                        lambda: jnp.zeros((C, block), jnp.float32))
+
+    out = lax.map(one, (jnp.arange(S // block), blocks))    # [n, C, block]
+    return jnp.transpose(out, (1, 0, 2)).reshape(C, S)

@@ -5,11 +5,15 @@ The reference is dense-only (`mlp.rs:7-11` — SURVEY.md §2.6 lists expert
 parallelism as absent); this is a capability extension, shared by the
 Mixtral and OLMoE families (models/moe). One layer, on N tokens:
 
-  * `route`: float32 router logits, softmax over ALL experts, the k
-    largest probabilities and their indices; the weights are divided by
-    their sum only if the family says so (`norm_topk_prob`: Mixtral's
-    softmax over the top-k logits is exactly that; OLMoE keeps the raw
-    probabilities). Returns `(weights [N,k], experts [N,k])`.
+  * `route`: float32 router logits and the family's rule, which is
+    data (`scoring`, `norm_topk_prob`, `scale`, a bias leaf): scores by
+    softmax over ALL experts (Mixtral,
+    OLMoE) or by sigmoid (GLM), the k largest scores and their indices
+    (GLM selects by score + a learned bias and weighs by the score
+    alone), the weights divided by their sum only if the family says so
+    (`norm_topk_prob`: Mixtral's softmax over the top-k logits is
+    exactly that; OLMoE keeps the raw probabilities), times a scale.
+    Returns `(weights [N,k], experts [N,k])`.
   * `dispatch_plan`: the N*k (token, expert) pairs sorted by expert.
     Tokens outside `token_mask` (a mixed step's padded positions, idle
     rows) and, under expert parallelism, pairs whose expert lives on
@@ -31,11 +35,16 @@ Mixtral and OLMoE families (models/moe). One layer, on N tokens:
   * the combine gathers each token's k rows back and sums them under
     the routing weights.
 
-Under `shard_map` pass `ep_axis`: each shard holds an `[E/ep, ...]` slice
-of the expert weights, computes the pairs routed to its experts and
-`psum`s the partial outputs over the axis (a shard_map with
-`check_vma=False`, as parallel/pipeline.py's are: the kernel's result
-carries no varying-axes annotation).
+A layer may hold fewer experts than its router names. Under `shard_map`
+pass `ep_axis`: each shard holds an `[E/ep, ...]` slice of the expert
+weights, computes the pairs routed to its experts and `psum`s the
+partial outputs over the axis (a shard_map with `check_vma=False`, as
+parallel/pipeline.py's are: the kernel's result carries no varying-axes
+annotation). On one chip pass `first_expert`: the layer is one share of
+an expert-parallel deployment, routes over all the router's experts and
+computes the pairs routed to `first_expert .. first_expert + E_local - 1`;
+what the absent experts would add is left out (there is no exchange to
+run). A shared expert (`ws_*` leaves) is added to every token.
 
 Every call also returns the layer's counters (`MoEStats`), computed on
 the device from the same group sizes the kernel walks.
@@ -75,7 +84,9 @@ class MoEStats(NamedTuple):
     experts with at least one token, whose weights the step reads.
     experts [N, k] int32 is the routing itself, for a tool that
     compares it with a reference's (chip_compare.py); a step program
-    returns the counters alone and the compiler drops it."""
+    returns the counters alone and the compiler drops it.
+    rows_routed: the real tokens' pairs over ALL the router's experts
+    (== rows unless the layer holds a share of them)."""
 
     rows: jnp.ndarray
     rows_padded: jnp.ndarray
@@ -83,19 +94,37 @@ class MoEStats(NamedTuple):
     load_mean: jnp.ndarray
     touched: jnp.ndarray
     experts: jnp.ndarray
+    rows_routed: jnp.ndarray
 
 
-def route(x, router_w, k: int, norm_topk_prob: bool):
+def route(x, router_w, k: int, norm_topk_prob: bool,
+          scoring: str = "softmax", scale: float = 1.0, bias=None):
     """x [N, D], router_w [D, E] -> (weights [N, k] f32, experts [N, k]
-    int32). float32 logits whatever the activations' type; softmax over
-    all E experts; top-k of the probabilities; renormalised over the k
-    only if `norm_topk_prob`."""
+    int32). float32 logits whatever the activations' type; scores over
+    all E experts, by `scoring` ("softmax" or "sigmoid"); the top k;
+    renormalised over the k only if `norm_topk_prob`; times `scale`.
+    bias [E] f32 (GLM's e_score_correction_bias): added to the scores
+    for the CHOICE only, the weights are the unbiased scores."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}")
+    if bias is None:
+        weights, experts = lax.top_k(probs, k)
+    else:
+        _, experts = lax.top_k(probs + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        norm = jnp.sum(weights, axis=-1, keepdims=True)
+        # GLM's guard against an all-zero sigmoid row; softmax's sum
+        # of k probabilities needs none and keeps its bits
+        weights = weights / (norm + 1e-20 if scoring == "sigmoid" else norm)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -181,14 +210,16 @@ def dispatch_plan(experts, n_experts: int, valid=None,
                         lo.astype(jnp.int32), hi.astype(jnp.int32), tm)
 
 
-def plan_stats(plan: DispatchPlan, experts) -> MoEStats:
+def plan_stats(plan: DispatchPlan, experts, rows_routed=None) -> MoEStats:
     counts = plan.counts.astype(jnp.float32)
     visits = jnp.sum(plan.visit_hi > plan.visit_lo)
-    return MoEStats(rows=jnp.sum(counts),
+    rows = jnp.sum(counts)
+    return MoEStats(rows=rows,
                     rows_padded=(visits * plan.tm).astype(jnp.float32),
                     load_max=jnp.max(counts), load_mean=jnp.mean(counts),
                     touched=jnp.sum(counts > 0).astype(jnp.float32),
-                    experts=experts)
+                    experts=experts,
+                    rows_routed=rows if rows_routed is None else rows_routed)
 
 
 # -- the grouped matmul --------------------------------------------------------
@@ -327,14 +358,20 @@ def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
 
 
 def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
-            ep_axis: Optional[str] = None, token_mask=None):
+            ep_axis: Optional[str] = None, token_mask=None,
+            first_expert: Optional[int] = None, scoring: str = "softmax",
+            scale: float = 1.0):
     """Sparse SwiGLU FFN over experts -> (out [B, S, D], MoEStats).
 
     lp leaves: router [D, E]; we_gate/we_up [E_local, D, F]; we_down
     [E_local, F, D], each an array, a per-channel QTensor, or a LayerOf
-    around the stacked leaf. E_local == E except under shard_map EP,
-    where each shard holds its contiguous slice and `ep_axis` names the
-    mesh axis. token_mask [B, S] bool: positions that are not real
+    around the stacked leaf; optionally router_bias [E] (the choice's
+    bias, `route`) and ws_gate/ws_up/ws_down, a shared expert every
+    token takes. norm_topk_prob, scoring, scale: the family's rule
+    (`route`). E_local == E except where the layer holds a share: under
+    shard_map EP each shard holds its contiguous slice and `ep_axis`
+    names the mesh axis; on one chip `first_expert` (static) is the
+    first of the E_local held. token_mask [B, S] bool: positions that are not real
     (padding of a mixed window, idle rows) are not routed and come back
     zero. Returns the *unreduced-over-tp* output: when F is additionally
     Megatron-sharded the caller (block_skeleton) psums over tp, exactly
@@ -344,7 +381,8 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     N, k = B * S, num_experts_per_tok
     x = h.reshape(N, D)
     with jax.named_scope("router"):
-        weights, experts = route(x, lp["router"], k, norm_topk_prob)
+        weights, experts = route(x, lp["router"], k, norm_topk_prob,
+                                 scoring, scale, lp.get("router_bias"))
         routed = experts
 
     w_gate, layer = _stacked(lp["we_gate"])
@@ -352,17 +390,29 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     e_local = w_gate.shape[1]
     mask = None if token_mask is None else token_mask.reshape(N)
     valid = None if mask is None else jnp.broadcast_to(mask[:, None], (N, k))
-    if ep_axis is not None:
-        experts = experts - lax.axis_index(ep_axis) * e_local
+    rows_routed = None
+    if ep_axis is not None or first_expert is not None:
+        rows_routed = (jnp.float32(N * k) if valid is None
+                       else jnp.sum(valid, dtype=jnp.float32))
+        first = (first_expert if ep_axis is None
+                 else lax.axis_index(ep_axis) * e_local)
+        experts = experts - first
         here = (experts >= 0) & (experts < e_local)
         valid = here if valid is None else valid & here
         experts = jnp.clip(experts, 0, e_local - 1)
 
     out, plan = _experts_ffn(x, weights, experts, valid, stacks, layer,
                              e_local)
-    stats = plan_stats(plan, routed)
+    stats = plan_stats(plan, routed, rows_routed)
     if mask is not None:
         out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:
         out = lax.psum(out, ep_axis)
+    if "ws_gate" in lp:
+        # every shard computes it alike: added once, after the sum
+        from cake_tpu.ops.quant import qmatmul
+        with jax.named_scope("shared_expert"):
+            gate = jax.nn.silu(qmatmul(x, lp["ws_gate"]))
+            out = out + qmatmul(gate * qmatmul(x, lp["ws_up"]),
+                                lp["ws_down"]).astype(jnp.float32)
     return out.reshape(B, S, D).astype(h.dtype), stats

@@ -172,6 +172,11 @@ _BLOCK_CONTRACT = {
     "wq": (1,), "wk": (1,), "wv": (1,), "wo": (1,),
     "w_gate": (1,), "w_up": (1,), "w_down": (1,),
     "we_gate": (2,), "we_up": (2,), "we_down": (2,),
+    # latent attention, the sparse indexer and the shared expert
+    # (models/moe: glm_moe_dsa), stacked [layers of the kind, in, out]
+    "wq_a": (1,), "wq_b": (1,), "wkv_a": (1,), "wkv_b_k": (1,),
+    "wkv_b_v": (1,), "wi_q": (1,), "wi_k": (1,),
+    "ws_gate": (1,), "ws_up": (1,), "ws_down": (1,),
 }
 
 

@@ -689,6 +689,29 @@ class InferenceEngine:
         # step kind -> the paged attention that actually runs for it
         # ({"decode", "mixed"[, "spec"]} -> fold|pallas); empty = dense
         self.attn_impl: dict = {}
+        # latent attention (glm_moe_dsa): one latent row a token in the
+        # page pool, the sparse indexer's keys beside it
+        # (models/moe/glm_dsa.py). What the latent pool does not serve
+        # yet is refused here, by the option's name, never ignored.
+        self._latent = bool(getattr(config, "kv_lora_rank", None))
+        if self._latent:
+            refused = [name for name, on in (
+                ("serving without --kv-pages (the dense-slot engine)",
+                 not self.paged),
+                ("a topology / --tp / --sp (pipeline and tensor "
+                 "parallelism)", step_fns is not None),
+                ("--draft-model", self._spec),
+                ("--spec-draft", self._spec_paged),
+                ("--kv-dtype int8/int4 (quantized pages)", self.kv_quant),
+                ("--kv-host-pages (host spill)", kv_host_pages is not None),
+                ("--disagg (the prefill shipment)", disagg is not None),
+                ("--auto-prefix (prefix pages)", auto_prefix_system),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "model_type glm_moe_dsa (latent attention over the "
+                    "page pool) does not serve yet: " + "; ".join(refused)
+                    + " (ROADMAP.md lists each as left to do)")
         if self.paged:
             if step_fns is not None or self.ring or self._spec:
                 raise ValueError(
@@ -1659,6 +1682,11 @@ class InferenceEngine:
                           "aligned with the target, and a prefix-cached "
                           "target prefill would leave the draft cold "
                           "(acceptance would silently collapse)")
+            elif self._latent:
+                reason = ("the latent page pool (glm_moe_dsa) has no "
+                          "prefix pages yet: a shared head would need "
+                          "its latent rows and its index keys mapped "
+                          "together (ROADMAP.md)")
             elif self.ring:
                 reason = ("ring sliding-window caches own their layout "
                           "(a prefix install writes dense positions the "
@@ -2625,6 +2653,21 @@ class InferenceEngine:
         # compiles later
         self._mixed_buckets = mixed_token_buckets(self.max_slots,
                                                   self._mixed_chunk)
+        if self._latent:
+            # the latent step programs, behind the same signatures; one
+            # window a dispatch, so one packed size (models/moe/glm_dsa)
+            from cake_tpu.models.moe.glm_dsa import (
+                decode_step_latent, mixed_step_latent,
+            )
+            self._decode_step = partial(decode_step_latent, attn=impl)
+            self._decode_scan_impl = (_decode_scan_latent if impl == "fold"
+                                      else _decode_scan_latent_pallas)
+            self._mixed_step_fn = partial(mixed_step_latent, attn=impl)
+            self._mixed_buckets = mixed_token_buckets(
+                self.max_slots, self._mixed_chunk, prefill_rows=(1,))
+            # no prefix pages, no whole-prompt prefill program
+            self._prefix_capable = False
+            self._prefill_slot = self._prefix_pages_step = None
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -2730,6 +2773,19 @@ class InferenceEngine:
         if impl not in ("fold", "pallas"):
             raise ValueError(
                 f"--paged-attn must be fold or pallas, got {impl!r}")
+        if self._latent:
+            # one impl for both step kinds: the selected rows are
+            # gathered in XLA and attended by cake_mla_attn (pallas) or
+            # the XLA fold; its VMEM does not depend on the mixed width.
+            # 512 is the widest window whose gathered rows (width x
+            # index_topk x latent row) stay near a gigabyte.
+            width = self.prefill_chunk or min(512, self.max_seq_len)
+            self.paged_attn = impl
+            self._mixed_chunk = width
+            self.attn_impl = {"decode": impl, "mixed": impl}
+            log.info("latent paged attention: requested %s -> dsa-%s "
+                     "(mixed width %d)", requested or "auto", impl, width)
+            return
         packed4 = self._kv_dtype_name == "int4"
         pool_dtype = self._pool_dtype
         kw = dict(quantized=self.kv_quant, n_pages=kv_pages,
@@ -2790,8 +2846,8 @@ class InferenceEngine:
         flight record (None = the recorder's engine-wide flavor)."""
         if not self.paged:
             return None
-        return "paged-" + self.attn_impl.get(kind,
-                                             self.attn_impl["decode"])
+        return ("paged-dsa-" if self._latent else "paged-") \
+            + self.attn_impl.get(kind, self.attn_impl["decode"])
 
     def _capture_cache_identity(self) -> None:
         """Record the cache's placement/dtype so post-error and
@@ -2816,9 +2872,13 @@ class InferenceEngine:
     def _reconfig_supported(self) -> bool:
         return (not self._custom_steps and not self.ring
                 and not self._spec and not self._spec_paged
-                and not self._multihost)
+                and not self._multihost and not self._latent)
 
     def _reconfig_refusal(self) -> str:
+        if self._latent:
+            return ("the latent page pool (glm_moe_dsa) serves on pages "
+                    "only: there is no dense or quantized pool to "
+                    "switch to")
         if self._spec:
             return ("speculative serving has no hot-switch fold (the "
                     "draft cache cannot be rebuilt mid-round)")
@@ -4517,15 +4577,21 @@ class InferenceEngine:
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
         order, as many as the largest packed size holds. One group
-        unless three rows or more prefill at once."""
+        unless three rows or more prefill at once (latent attention:
+        two or more)."""
         budget = self._mixed_buckets[-1]
-        groups, used = [np.zeros(len(qlen), bool)], 0
+        # latent attention: one window (a row of several tokens) a
+        # dispatch, whatever the budget holds
+        windows = 1 if self._latent else len(qlen)
+        groups, used, wide = [np.zeros(len(qlen), bool)], 0, 0
         for slot in np.flatnonzero(qlen):
-            if used + qlen[slot] > budget:
+            if (used + qlen[slot] > budget
+                    or wide + (qlen[slot] > 1) > windows):
                 groups.append(np.zeros(len(qlen), bool))
-                used = 0
+                used = wide = 0
             groups[-1][slot] = True
             used += int(qlen[slot])
+            wide += int(qlen[slot] > 1)
         return groups
 
     def _warm_mixed_buckets(self) -> None:
@@ -6272,3 +6338,23 @@ def _paged_forward_ragged_pallas(params, tokens, cache, pos, active,
 
 
 _decode_scan_paged_pallas = make_decode_scan(_paged_forward_ragged_pallas)
+
+
+def _latent_forward_ragged(params, tokens, cache, pos, active, rope,
+                           config):
+    from cake_tpu.models.moe.glm_dsa import forward_ragged_latent
+    return forward_ragged_latent(params, tokens, cache, pos, active, rope,
+                                 config)
+
+
+_decode_scan_latent = make_decode_scan(_latent_forward_ragged)
+
+
+def _latent_forward_ragged_pallas(params, tokens, cache, pos, active,
+                                  rope, config):
+    from cake_tpu.models.moe.glm_dsa import forward_ragged_latent
+    return forward_ragged_latent(params, tokens, cache, pos, active, rope,
+                                 config, attn="pallas")
+
+
+_decode_scan_latent_pallas = make_decode_scan(_latent_forward_ragged_pallas)

@@ -2,10 +2,12 @@
 """Logits of the served path against the plain float32 reference, on the
 chip, at published widths.
 
-    python chip_compare.py                       OLMoE-1B-7B, the cell's
-                                                 configuration and options
-    python chip_compare.py --rehearse            its toy configuration on
-                                                 the CPU: proves the
+    python chip_compare.py [CONFIG_DIR]          a cell's configuration and
+                                                 options (a directory of
+                                                 benchmarks/configs; default
+                                                 OLMoE-1B-7B's)
+    python chip_compare.py [CONFIG_DIR] --rehearse   its toy configuration
+                                                 on the CPU: proves the
                                                  script, never the chip
 
 chip_smoke.py proves that the server starts and answers; this proves
@@ -50,6 +52,24 @@ renormalised. A per-head QK norm changes every logit by its own size
 and fails both by two orders of magnitude (tests/test_olmoe_reference.py
 holds the float32 path to 2e-4).
 
+GLM-5.2 (`benchmarks/configs/glm-5.2-int8-share16`, model_type
+glm_moe_dsa) goes the same way through its own step programs
+(`models/moe/glm_dsa.mixed_trunk`, `decode_trunk`) and its own
+reference (`cake_tpu/models/reference/glm_moe_dsa.py`, given the same
+held experts): four sequences of 12,200, 6,100, 4,100 and 3,000 prompt
+tokens in four of the eight rows, one 512-token window a dispatch with
+the rows that already decode beside it, then the decode program;
+logits at the last 256 prompt positions and at 16 decode steps (all
+beyond 2,048: every query there dropped keys) and at the last 256
+positions under 2,048 (where precision is read), each sparse layer's
+expert sets and each attention layer's attended key sets (the share in
+common with the reference's). Its limits (GLM_TOL, read off the chip as PR 26 read
+OLMoE's: PERF.md section 6, PR 30) lie between the worst the served
+path reads over three seeds and what must fail: the reference with
+int8 activations, with dense attention above index_topk keys, with
+softmax routing, and with every shared layer running indexer weights
+of its own drawn afresh.
+
 The last line of stdout is one JSON object with `ok`.
 """
 
@@ -67,6 +87,24 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(ROOT, "chiprun_out", "chip_compare")
 CONFIG_DIR = os.path.join(ROOT, "benchmarks", "configs", "olmoe-1b-7b-int8")
+
+# glm_moe_dsa: 9 layers, two discrete choices a layer, and the choice of
+# keys is chaotic under seeded weights (attention over 2,048 keys that
+# all look alike: a rounding reorders the edge of the top 2,048, and
+# the next layer's rounding starts from there). So precision is read
+# where no key is dropped (`mean_dense`: positions under index_topk),
+# as OLMoE's is; beyond it the limits are the mean error (`mean`) and
+# the least share of attended keys in common with the reference over
+# the nine attention layers (`keys`: a layer that attends other sets
+# shares about 2048/context of them by chance). The worst entry is
+# reported, not limited: one flipped expert moves an entry by 0.2 on
+# either side. Readings (PERF.md section 6, PR 30; served path over
+# three seeds / what must fail): mean_dense 3.4e-3 / int8 activations
+# 7.5e-3; mean 1.4e-2 / dense attention 7.7e-2, softmax routing 3.8e-2;
+# keys 0.90 / fresh shared indexers 0.61, dense attention 0.61.
+GLM_TOL = {"mean_dense": 5e-3, "mean": 2.5e-2, "keys": 0.85}
+GLM_PROMPTS = (12200, 6100, 4100, 3000)
+GLM_DECODE = 16
 
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
@@ -92,10 +130,10 @@ def cli_argv(cell: dict, model_dir: str, rehearse: bool) -> list:
     return argv
 
 
-def build_engine(rehearse: bool):
-    with open(os.path.join(CONFIG_DIR, "config.json")) as f:
+def build_engine(rehearse: bool, config_dir: str = CONFIG_DIR):
+    with open(os.path.join(config_dir, "config.json")) as f:
         config = json.load(f)
-    with open(os.path.join(CONFIG_DIR, "cell.json")) as f:
+    with open(os.path.join(config_dir, "cell.json")) as f:
         cell = json.load(f)
     if rehearse:
         config.update(cell["rehearse"]["config"])
@@ -163,6 +201,8 @@ def reference_run(ref, params, sequences, ref_cfg):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("config_dir", nargs="?", default=CONFIG_DIR,
+                    help="the cell's directory under benchmarks/configs")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--negatives", type=int, default=2,
@@ -181,7 +221,9 @@ def main() -> int:
     from cake_tpu.models.reference import olmoe as ref
     from cake_tpu.ops.quant import qmatmul
 
-    engine, cell, raw_config = build_engine(args.rehearse)
+    engine, cell, raw_config = build_engine(args.rehearse, args.config_dir)
+    if raw_config.get("model_type") == "glm_moe_dsa":
+        return compare_glm(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -402,6 +444,348 @@ def main() -> int:
     result["ok"] = bool(ok)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"result_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if ok else 1
+
+
+# -- glm_moe_dsa ---------------------------------------------------------------
+
+
+def compare_glm(engine, cell, args, t_start) -> int:
+    """The comparison above for latent attention with the sparse
+    indexer: the engine's own mixed and decode trunks with the head at
+    every position, against models/reference/glm_moe_dsa.py."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import glm_dsa
+    from cake_tpu.models.reference import glm_moe_dsa as ref
+    from cake_tpu.ops.quant import qmatmul
+
+    cfg, params, rope = engine.config, engine.params, engine.rope
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    attn = engine.attn_impl["mixed"]
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = glm_dsa.mixed_trunk(params, tokens, pos, q_len, active,
+                                     cache, rope, cfg, attn, n_tokens)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return (logits, out.cache, out.experts, out.selected,
+                out.n_selected, out.selected_window)
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        out = glm_dsa.decode_trunk(params, tokens, cache, pos, active, rope,
+                                   cfg, attn)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return logits, out.cache, out.experts, out.selected, out.n_selected
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    prompts = GLM_PROMPTS if not args.rehearse else (700, 400, 330, 300)
+    n_decode = GLM_DECODE if not args.rehearse else 6
+    last = LAST if not args.rehearse else 24
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for p in prompts]
+    assert len(sequences) <= B and max(prompts) + n_decode <= per_row * page
+    table = np.full((B, per_row), -1, np.int32)
+    for b in range(len(sequences)):
+        table[b] = b * per_row + np.arange(per_row)
+    assert table.max() < engine.cache.n_pages
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    engine.cache = None
+
+    got = [dict() for _ in sequences]       # position -> logits [V]
+    routed = [dict() for _ in sequences]    # position -> experts [Ls, k]
+    picked = [dict() for _ in sequences]    # position -> [Lf] key sets
+    off = [0] * len(sequences)
+
+    def keep(b, position, logits, experts_t, selected, n_sel, window=None):
+        """window: (the dispatch's window sets [Lf, C, S], the token's
+        index in it) for a window's token; a row's single token reads
+        its row of `selected` [Lf, B, K]."""
+        got[b][position] = logits
+        routed[b][position] = experts_t
+        if window is None:
+            picked[b][position] = [set(selected[f, b, :n_sel[b]].tolist())
+                                   for f in range(selected.shape[0])]
+        else:
+            sets, col = window
+            picked[b][position] = [set(np.flatnonzero(sets[f, col]).tolist())
+                                   for f in range(sets.shape[0])]
+
+    def compared(b, position):
+        """The prompt's last positions (beyond index_topk: every query
+        there dropped keys) and the last under index_topk (the dense
+        regime, where precision is read)."""
+        return (position >= prompts[b] - last
+                or cfg.index_topk - last <= position < cfg.index_topk)
+
+    steps = {"mixed": 0, "decode": 0}
+    t0 = time.monotonic()
+    while any(off[b] < prompts[b] for b in range(len(sequences))):
+        toks = np.zeros((B, C), np.int32)
+        pos = np.zeros(B, np.int32)
+        qlen = np.zeros(B, np.int32)
+        for b, seq in enumerate(sequences):
+            if off[b] < prompts[b]:
+                n = min(C, prompts[b] - off[b])
+            elif off[b] < prompts[b] + n_decode // 2:
+                n = 1          # half the decode steps ride mixed steps
+            else:
+                continue
+            toks[b, :n], pos[b], qlen[b] = seq[off[b]:off[b] + n], off[b], n
+        active = qlen > 0
+        # the dispatches the engine would run this step in: one window
+        # (a row of several tokens) each, the one-token rows beside it
+        for group in engine._mixed_groups(qlen):
+            glen = np.where(group, qlen, 0)
+            n_tokens = paged.mixed_bucket_for(engine._mixed_buckets,
+                                              int(glen.sum()))
+            logits, cache, experts, selected, n_sel, sets = window_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(glen), jnp.asarray(active & group), cache,
+                n_tokens)
+            first = np.cumsum(glen) - glen
+            wanted = [(b, j) for b in np.flatnonzero(glen)
+                      for j in range(glen[b]) if compared(b, off[b] + j)]
+            if wanted:
+                experts, selected, n_sel, sets = (
+                    np.asarray(experts), np.asarray(selected),
+                    np.asarray(n_sel), np.asarray(sets))
+                rows = np.asarray([first[b] + j for b, j in wanted])
+                fetched = np.asarray(logits[rows])
+                for i, (b, j) in enumerate(wanted):
+                    keep(b, off[b] + j, fetched[i],
+                         experts[:, first[b] + j], selected, n_sel,
+                         (sets, j) if glen[b] > 1 else None)
+        for b in range(len(sequences)):
+            off[b] += int(qlen[b])
+        steps["mixed"] += 1
+    while any(off[b] < len(s) for b, s in enumerate(sequences)):
+        toks = np.zeros((B, 1), np.int32)
+        pos = np.zeros(B, np.int32)
+        active = np.zeros(B, bool)
+        for b, seq in enumerate(sequences):
+            if off[b] < len(seq):
+                toks[b, 0], pos[b], active[b] = seq[off[b]], off[b], True
+        logits, cache, experts, selected, n_sel = decode_step(
+            params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(active),
+            cache)
+        logits, experts, selected, n_sel = (
+            np.asarray(logits), np.asarray(experts), np.asarray(selected),
+            np.asarray(n_sel))
+        for b in np.flatnonzero(active):
+            keep(b, off[b], logits[b], experts[:, b], selected, n_sel)
+            off[b] += 1
+        steps["decode"] += 1
+    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+        f"steps in {time.monotonic() - t0:.1f} s")
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time --------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = {k: getattr(cfg, k) for k in (
+        "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim", "rms_norm_eps", "rope_theta", "index_n_heads",
+        "index_head_dim", "index_topk", "num_experts_per_tok",
+        "norm_topk_prob", "routed_scaling_factor", "scoring_func")}
+    held = (cfg.first_routed_expert, cfg.num_local_experts)
+    plain = {"attention": ref.attention, "swiglu": ref.swiglu,
+             "index_scores": ref.index_scores, "select": ref.select,
+             "mm": ref.mm}
+
+    def compiled(**replaced):
+        """The reference's heavy functions under jit, traced anew (so
+        that a replaced `mm` is what they run); one compilation per
+        sequence length, function and config, not one per operation."""
+        for name, fn in dict(plain, **replaced).items():
+            setattr(ref, name, fn)
+        attention, index_scores = ref.attention, ref.index_scores
+        jitted = {}
+
+        def key_of(name, config):
+            return name, tuple(sorted(config.items()))
+
+        def jit_attention(lp, h, config, selected):
+            key = key_of("attention", config)
+            if key not in jitted:
+                jitted[key] = jax.jit(
+                    lambda lp, h, selected: attention(lp, h, config,
+                                                      selected))
+            return jitted[key](lp, h, selected)
+
+        def jit_index_scores(lp, h, c_q, config):
+            key = key_of("index_scores", config)
+            if key not in jitted:
+                jitted[key] = jax.jit(
+                    lambda lp, h, c_q: index_scores(lp, h, c_q, config))
+            return jitted[key](lp, h, c_q)
+
+        ref.attention, ref.index_scores = jit_attention, jit_index_scores
+        ref.swiglu = jax.jit(ref.swiglu)
+        ref.select = jax.jit(ref.select, static_argnames=("topk",))
+
+    def layers(fresh_indexers: bool = False):
+        from cake_tpu.ops.moe import LayerOf
+        donor = None
+        for i in range(cfg.num_hidden_layers):
+            lp = glm_dsa.layer_leaves(host["blocks"], cfg, i)
+            lp = {k: dequantized(jax.tree.map(
+                      lambda a: jnp.asarray(a[int(v.layer)]), v.stacked)
+                      if isinstance(v, LayerOf)
+                      else jax.tree.map(jnp.asarray, v))
+                  for k, v in lp.items()}
+            if "wi_q" in lp:
+                donor = lp
+            elif fresh_indexers:
+                # the nearest full layer's indexer shapes, drawn afresh
+                keys = jax.random.split(jax.random.PRNGKey(1000 + i), 3)
+                for key, name in zip(keys, ("wi_q", "wi_k", "wi_w")):
+                    w = donor[name]
+                    lp[name] = (jax.random.normal(key, w.shape, jnp.float32)
+                                * jnp.std(w))
+                lp["wi_k_norm"] = donor["wi_k_norm"]
+                lp["wi_k_bias"] = donor["wi_k_bias"]
+            yield lp
+
+    top = {k: dequantized(jax.tree.map(jnp.asarray, host[k]))
+           for k in ("embed", "final_norm", "lm_head")}
+
+    def reference(seqs, config=ref_cfg, **kw):
+        t0 = time.monotonic()
+        routing = [[] for _ in seqs]
+        selections = [[] for _ in seqs]
+        logits = ref.forward(top, list(seqs), config, layers=layers(**kw),
+                             held=held, routing=routing,
+                             selections=selections)
+        say(f"  reference over {sum(len(s) for s in seqs)} tokens in "
+            f"{time.monotonic() - t0:.1f} s")
+        return [np.asarray(x) for x in logits], routing, selections
+
+    compiled()
+    want, want_routing, want_keys = reference(sequences)
+    full = {layer: f for f, layer in enumerate(cfg.full_layers)}
+
+    def readings(rows, logits_at, experts_at, keys_at):
+        """The limits' readings over compared positions. rows: (b,
+        position) pairs; logits_at / experts_at (b, position) ->
+        logits [V] / [Ls, k]; keys_at (b, position, layer) -> the set
+        that layer attended. Against the reference: mean |error| /
+        range in the dense regime (positions under index_topk: every
+        visible key is attended, so only the expert choice can amplify
+        a rounding) and beyond it, with their worst entries; the least,
+        over attention layers, mean share of attended keys in common;
+        each sparse layer's share of positions with the same experts."""
+        dense = {"sum": 0.0, "n": 0, "worst": 0.0}
+        sparse = {"sum": 0.0, "n": 0, "worst": 0.0}
+        shared = np.zeros(cfg.num_hidden_layers)
+        same = np.zeros(len(cfg.sparse_layers))
+        n_sparse = 0
+        for b, position in rows:
+            w = want[b][position]
+            err = np.abs(logits_at(b, position) - w) / float(w.max() - w.min())
+            acc = dense if position < cfg.index_topk else sparse
+            acc["sum"] += float(err.sum())
+            acc["n"] += err.size
+            acc["worst"] = max(acc["worst"], float(err.max()))
+            same += [set(experts_at(b, position)[j])
+                     == set(want_routing[b][j][position])
+                     for j in range(len(cfg.sparse_layers))]
+            if position >= cfg.index_topk:
+                n_sparse += 1
+                for layer in range(cfg.num_hidden_layers):
+                    theirs = set(np.flatnonzero(
+                        want_keys[b][layer][position]).tolist())
+                    ours = keys_at(b, position, layer)
+                    shared[layer] += (len(ours & theirs)
+                                      / max(len(ours), len(theirs)))
+        return {
+            "mean_dense": dense["sum"] / max(dense["n"], 1),
+            "max_dense": dense["worst"],
+            "mean": sparse["sum"] / max(sparse["n"], 1),
+            "max": sparse["worst"],
+            "keys": float(shared.min()) / max(n_sparse, 1),
+            "keys_by_layer": [round(float(x) / max(n_sparse, 1), 4)
+                              for x in shared],
+            "same_experts_by_layer": [round(float(x) / len(rows), 4)
+                                      for x in same],
+        }
+
+    def passes(r):
+        return (r["mean_dense"] < GLM_TOL["mean_dense"]
+                and r["mean"] < GLM_TOL["mean"]
+                and r["keys"] >= GLM_TOL["keys"])
+
+    def nearest_full(layer):
+        return max(f for f in cfg.full_layers if f <= layer)
+
+    rows = [(b, position) for b in range(len(sequences))
+            for position in sorted(got[b])]
+    served = readings(
+        rows, lambda b, p: got[b][p], lambda b, p: routed[b][p],
+        lambda b, p, layer: picked[b][p][full[nearest_full(layer)]])
+    expected = sum(min(last, p) + n_decode
+                   + sum(1 for q in range(max(0, cfg.index_topk - last),
+                                          min(cfg.index_topk, p - last)))
+                   for p in prompts)
+    result = {
+        "positions": len(rows), "expected_positions": expected,
+        "served": served, "tol": GLM_TOL, "seed": args.seed,
+        "prompts": list(prompts), "steps": steps, "attention": impl,
+        "device": jax.devices()[0].device_kind,
+    }
+    ok = len(rows) == expected and passes(served)
+
+    # -- what must NOT pass: the reference, altered, against itself ----
+    if args.negatives:
+        short = sorted(range(len(sequences)),
+                       key=lambda b: len(sequences[b]))[:args.negatives]
+        seqs = [sequences[b] for b in short]
+        neg_rows = [(b, position) for b, position in rows if b in short]
+
+        def against_reference(run):
+            logits, routing, keys = run
+            at = {b: i for i, b in enumerate(short)}
+            return readings(
+                neg_rows, lambda b, p: logits[at[b]][p],
+                lambda b, p: [r[p] for r in routing[at[b]]],
+                lambda b, p, layer: set(np.flatnonzero(
+                    keys[at[b]][layer][p]).tolist()))
+
+        altered = {
+            "dense_attention_reference": dict(
+                config=dict(ref_cfg, dense_attention=True)),
+            "softmax_routing_reference": dict(
+                config=dict(ref_cfg, scoring_func="softmax")),
+            "fresh_shared_indexers_reference": dict(fresh_indexers=True),
+        }
+        for name, kw in altered.items():
+            result[name] = against_reference(reference(seqs, **kw))
+        compiled(mm=lambda x, w: plain["mm"](fake_int8(x), w))
+        result["int8_activation_reference"] = against_reference(
+            reference(seqs))
+        compiled()
+        for name in (*altered, "int8_activation_reference"):
+            if passes(result[name]):
+                say(f"FAILED: the {name} passes the tolerance")
+                ok = False
+    result["ok"] = bool(ok)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"glm_result_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)
