@@ -43,6 +43,7 @@ from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from cake_tpu.kv.quantized_pool import (
@@ -208,10 +209,12 @@ class PageAllocator:
 
 def table_set_slot(table: jnp.ndarray, slot: int,
                    pages: List[int]) -> jnp.ndarray:
-    """Map `slot` to `pages` (host-computed row; one tiny transfer)."""
-    row = jnp.full((table.shape[1],), -1, jnp.int32)
-    row = row.at[: len(pages)].set(jnp.asarray(pages, jnp.int32))
-    return table.at[slot].set(row)
+    """Map `slot` to `pages`: the row is made on the host, so one tiny
+    transfer and one scatter (an admission runs with the device idle,
+    and every eager program is a launch of its own)."""
+    row = np.full(table.shape[1], -1, np.int32)
+    row[: len(pages)] = pages
+    return table.at[slot].set(jnp.asarray(row))
 
 
 # -- device ops ---------------------------------------------------------------
@@ -800,7 +803,7 @@ def mixed_token_buckets(slots: int, width: int,
     to 16 positions; latent attention takes one window a dispatch:
     models/moe/glm_dsa.py). The
     last is the most one dispatch computes: a step that holds more is
-    run in several (serve/engine._mixed_dispatch), two prefilling rows
+    run in several (serve/engine._mixed_burst), two prefilling rows
     at a time.
 
     Why two sizes and not a ladder up to slots*width. Under chat

@@ -118,6 +118,11 @@ _DECODE_CHAINED = _m.counter(
     "Decode steps dispatched from the tokens still on the device while "
     "the step before them was in flight (of cake_steps_total"
     "{kind=\"decode\"})")
+_MIXED_CHAINED = _m.counter(
+    "cake_mixed_steps_chained_total",
+    "Mixed steps dispatched while the step before them was in flight, "
+    "their decode rows fed from the tokens still on the device (of "
+    "cake_steps_total{kind=\"mixed\"})")
 _STEP_DISPATCH = _m.histogram(
     "cake_step_dispatch_seconds",
     "Per-step dispatch wall seconds, by step kind",
@@ -473,8 +478,8 @@ class StepRecord:
     # dispatch: how long the engine left the device with nothing queued
     # (0.0 for a chained step: the device had the step before it)
     gap_s: Optional[float] = None
-    # a decode step: dispatched from the previous step's on-device
-    # carry while that step was still in flight
+    # a decode or mixed step: dispatched from the previous step's
+    # on-device carry while that step was still in flight
     chained: Optional[bool] = None
     # the step programs' counters since the previous record that
     # carried them, in the order of STEP_COUNTERS (a sparse model's
@@ -711,8 +716,9 @@ class StepTelemetry:
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
         step program's sparse-expert counters (StepRecord.moe).
-        chained: a decode step that was dispatched while the step
-        before it was in flight (cake_decode_steps_chained_total). Its
+        chained: a decode or mixed step that was dispatched while the
+        step before it was in flight (cake_decode_steps_chained_total,
+        cake_mixed_steps_chained_total). Its
         gap_s is 0.0, the device had work queued, whatever the spans
         say: its dispatch span lies in the record before its own."""
         wall = wall_s if wall_s is not None else (
@@ -752,7 +758,7 @@ class StepTelemetry:
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
         if chained:
-            _DECODE_CHAINED.inc()
+            (_MIXED_CHAINED if kind == "mixed" else _DECODE_CHAINED).inc()
         _STEP_DISPATCH.labels(kind=kind).observe(disp)
         for k, v in (("decode", rows_decode), ("prefill", rows_prefill),
                      ("idle", rows_idle)):

@@ -53,7 +53,7 @@ from cake_tpu.models.llama.generator import (
 from cake_tpu.models.llama.model import (
     RopeTables, decode_step_ragged, prefill_slot, prefill_slot_prefixed,
 )
-from cake_tpu.models.llama.paged import mixed_bucket_for
+from cake_tpu.models.llama.paged import mixed_bucket_for, mixed_step_paged
 from cake_tpu.ops.sampling import (
     SamplingConfig, sample_tokens_ragged, update_ring_per_row,
 )
@@ -2607,8 +2607,7 @@ class InferenceEngine:
         self.prefill_chunk already set."""
         from cake_tpu.models.llama.paged import (
             PageAllocator, PagedKVCache, decode_step_ragged_paged,
-            mixed_step_paged, mixed_token_buckets, prefill_prefix_pages,
-            prefill_slot_paged,
+            mixed_token_buckets, prefill_prefix_pages, prefill_slot_paged,
         )
         if kv_pages < 1 or kv_page_size < 1:
             raise ValueError(
@@ -2644,11 +2643,12 @@ class InferenceEngine:
                                           attn=impl)
         # token-level continuous batching: ONE jitted step consumes a
         # batch of (row kind, pos, q_len) descriptors — decode rows
-        # and prefill-chunk rows in the same launch
-        self._mixed_step_fn = partial(mixed_step_paged,
+        # and prefill-chunk rows in the same launch — and samples
+        # (make_mixed_sampled), so that a step can be kept in flight
+        self._mixed_step_fn = partial(_mixed_sampled_paged,
                                       attn=self.attn_impl["mixed"])
         # the packed sizes a mixed step's dispatches run at (the
-        # program's static n_tokens): _mixed_dispatch takes the smallest
+        # program's static n_tokens): _mixed_burst takes the smallest
         # that holds the tokens, start() runs each once so that none
         # compiles later
         self._mixed_buckets = mixed_token_buckets(self.max_slots,
@@ -2656,13 +2656,11 @@ class InferenceEngine:
         if self._latent:
             # the latent step programs, behind the same signatures; one
             # window a dispatch, so one packed size (models/moe/glm_dsa)
-            from cake_tpu.models.moe.glm_dsa import (
-                decode_step_latent, mixed_step_latent,
-            )
+            from cake_tpu.models.moe.glm_dsa import decode_step_latent
             self._decode_step = partial(decode_step_latent, attn=impl)
             self._decode_scan_impl = (_decode_scan_latent if impl == "fold"
                                       else _decode_scan_latent_pallas)
-            self._mixed_step_fn = partial(mixed_step_latent, attn=impl)
+            self._mixed_step_fn = partial(_mixed_sampled_latent, attn=impl)
             self._mixed_buckets = mixed_token_buckets(
                 self.max_slots, self._mixed_chunk, prefill_rows=(1,))
             # no prefix pages, no whole-prompt prefill program
@@ -4464,8 +4462,8 @@ class InferenceEngine:
         while nobody waits in the queue (_host_attention_pending,
         _scan_steps_for's queue gate); the moment a request is
         admitted, the loop falls back to single mixed steps so its
-        chunks ride every iteration instead of stalling behind a
-        K-token scan burst."""
+        chunks ride every step instead of stalling behind a K-token
+        scan burst. Those too are kept one in flight (_mixed_burst)."""
         if prefill_plan:
             with self.flight.span("schedule"):
                 for rid, slot in prefill_plan:
@@ -4477,7 +4475,7 @@ class InferenceEngine:
             if decode_plan and self._resident_parked:
                 # an admission above parked a decode-resident slot
                 # (_spill_resident_stream): drop its stale row before
-                # the device step (_mixed_dispatch re-validates per
+                # the device step (_mixed_burst re-validates per
                 # row; the decode programs do not)
                 decode_plan = self._live_decode_rows(decode_plan)
             if decode_plan and self._specp is not None:
@@ -4489,7 +4487,7 @@ class InferenceEngine:
             if decode_plan:
                 self._decode_rows(decode_plan, chain=not prefill_plan)
             return
-        self._mixed_dispatch(decode_plan)
+        self._mixed_burst(decode_plan)
 
     def _mixed_admit(self, rid: int, slot: int) -> None:
         """The paged engine's one admission: after the shared head
@@ -4554,25 +4552,38 @@ class InferenceEngine:
         self._pos[slot] = off
         self._mixed_pending[slot] = {"req": req, "ids": ids, "off": off}
 
-    def _run_mixed_step(self, tokens, pos, qlen, active,
-                        n_tokens: int) -> list:
-        """Dispatch the mixed step program of size n_tokens
-        (paged.mixed_step_paged) on host arrays through the compile
-        accountant; the cache is donated and replaced. Returns [logits,
-        and a sparse model's expert counters]."""
-        fargs = (self.params, jnp.asarray(tokens, jnp.int32),
-                 jnp.asarray(pos, jnp.int32), jnp.asarray(qlen, jnp.int32),
-                 jnp.asarray(active), self.cache, self.rope, self.config)
-        kw = {"n_tokens": n_tokens}
-        js = self._obs_jit("mixed_step", (tokens.shape[1], n_tokens),
+    def _run_mixed_step(self, step, carry, n_tokens: int) -> tuple:
+        """Dispatch the sampled mixed step program of size n_tokens
+        (make_mixed_sampled) on the packed step the host built and a
+        carry, through the compile accountant; the cache, the keys and
+        the ring are donated and replaced. carry None: a stretch's
+        first step, which no row reads a carry in. Returns ((tokens,
+        logprobs, top ids, top logprobs, [a sparse model's counters])
+        on the device, the carry)."""
+        if carry is None:
+            zero = np.zeros(self.max_slots, np.int32)
+            carry = tuple(self._held("carry." + name, a) for name, a in (
+                ("tok", zero), ("pos", zero), ("steps", zero),
+                ("live", zero != 0)))
+        fargs = (self.params, jnp.asarray(step), self.cache, self.rope,
+                 self.config, self._keys, self._ring,
+                 self._held("temp", self._temp),
+                 self._held("top_p", self._top_p),
+                 self._held("penalty", self._penalty), carry)
+        # one n_top form: the cap's top ids always, dropped on the host
+        # for the rows that did not ask
+        kw = {"n_tokens": n_tokens, "top_k": self.defaults.top_k,
+              "n_top": self.n_top}
+        js = self._obs_jit("mixed_step", (step.shape[1] - 4, n_tokens),
                            self._mixed_step_fn, fargs, kw)
         t0 = time.perf_counter()
-        logits, self.cache, *moe = self._mixed_step_fn(*fargs, **kw)
+        (nxt, lp, tids, tlps, self.cache, self._keys, self._ring, carry,
+         *moe) = self._mixed_step_fn(*fargs, **kw)
         js.finish(time.perf_counter() - t0)
         # a step of several dispatches compiled if any of them did
         js.new |= self._last_jit is not None and self._last_jit.new
         self._last_jit = js
-        return [logits, *moe]
+        return (nxt, lp, tids, tlps, moe), carry
 
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
@@ -4596,16 +4607,17 @@ class InferenceEngine:
 
     def _warm_mixed_buckets(self) -> None:
         """Run the mixed step once at every packed size with all rows
-        idle (an idle row touches neither pages nor output), so that
-        whatever token counts arrive later, no step compiles or loads a
-        program: the traffic's own warm-up cannot be relied on to touch
-        every size."""
-        B, C = self.max_slots, self._mixed_chunk
-        idle = (np.zeros((B, C), np.int64), np.zeros(B, np.int64),
-                np.zeros(B, np.int64), np.zeros(B, bool))
+        idle (an idle row touches neither pages nor output nor its
+        key), so that whatever token counts arrive later, no step
+        compiles or loads a program: the traffic's own warm-up cannot
+        be relied on to touch every size. A chained step, on the carry
+        the program returned, runs the same executable (one device,
+        uncommitted arrays either way), and the program has one n_top
+        form, so these runs are all its variants."""
+        idle = np.zeros((self.max_slots, self._mixed_chunk + 4), np.int32)
         marks = [time.perf_counter()]
         for bucket in self._mixed_buckets:
-            out = self._run_mixed_step(*idle, bucket)
+            out, _carry = self._run_mixed_step(idle, None, bucket)
             marks.append(time.perf_counter())
         jax.block_until_ready(out)
         self._last_jit = None
@@ -4615,135 +4627,239 @@ class InferenceEngine:
                  " + ".join(f"{b - a:.2f}" for a, b in zip(marks, marks[1:])),
                  time.perf_counter() - marks[-1])
 
-    def _mixed_dispatch(self, decode_plan) -> None:
-        """Build and run ONE mixed step: every decode row contributes
-        its last token (q_len=1), every mid-prefill slot its next
-        window (q_len=n at its current offset); rows whose window ends
-        their prompt sample their first token from the same launch the
-        decode rows sample their next."""
-        t0 = time.perf_counter()
+    @engine_thread_only
+    def _mixed_burst(self, decode_plan) -> None:
+        """A stretch of mixed steps with one in flight. A step: every
+        decode row contributes its last token (q_len=1), every
+        mid-prefill slot its next window (q_len=n at its current
+        offset); rows whose window ends their prompt sample their first
+        token from the same launch the decode rows sample their next
+        (the program samples: make_mixed_sampled). Step k+1 is
+        dispatched BEFORE k is fetched: a decode row's token, position
+        and step count come from k's carry on the device, a prompt's
+        next window from the host, which knows it without k's result.
+        Then ONE fetch of k's tokens and counters, its record, and its
+        emit while k+1 runs. When the last prompt of the stretch has
+        ended, the carry goes on into the sampled decode program
+        (_decode_stretch), so the decode steps after it stay chained.
+
+        The stretch ends as a decode stretch does (_decode_stretch's
+        can_chain): when the host needs the loop back
+        (_host_attention_pending), after an emit in which a row
+        finished (its slot is the planner's), before a row would pass
+        max_seq_len, after STRETCH_STEPS dispatches. Rows join between
+        stretches only (_mixed_admit). An engine that may not chain
+        runs the same program and fetches it at once."""
+        span = self.flight.span
+        B, C = self.max_slots, self._mixed_chunk
+        pending = self._mixed_pending
+        # the stretch's rows, slot -> rid: the plan's decode rows (an
+        # admission may have parked one: _spill_resident_stream) and
+        # every mid-prefill slot (this iteration's admissions are in
+        # no plan yet)
+        rows_of = {slot: rid for rid, slot in decode_plan
+                   if self._slot_req[slot] is not None
+                   and self._slot_req[slot].rid == rid}
+        rows_of.update((slot, p["req"].rid) for slot, p in pending.items())
+        plan = [(rows_of[slot], slot) for slot in sorted(rows_of)]
+        # the rids a record carries are those of the iteration the run
+        # loop would have spent on the step: its plan, then every
+        # mid-prefill slot
+        planned = [rid for rid, _slot in decode_plan]
         # blast radius: every decode row AND every mid-prefill slot
-        # rides this one launch
-        self._implicated = tuple(
-            [(rid, slot) for rid, slot in decode_plan]
-            + [(p["req"].rid, slot)
-               for slot, p in self._mixed_pending.items()])
-        if self._faults is not None:
-            self._faults.check("engine.mixed", step=self.stats.steps)
-        with self.flight.span("build"):
-            B, C = self.max_slots, self._mixed_chunk
-            tokens = np.zeros((B, C), np.int64)
-            pos = np.zeros(B, np.int64)
-            qlen = np.zeros(B, np.int64)
-            active = np.zeros(B, bool)
-            decode_rows: List[int] = []
-            for rid, slot in decode_plan:
-                if slot in self._mixed_pending:
-                    continue    # still prefilling: rides as a chunk row
-                req = self._slot_req[slot]
-                if req is None or req.rid != rid:
-                    continue
-                tokens[slot, 0] = self._last_tok[slot]
-                pos[slot] = min(self._pos[slot], self.max_seq_len - 1)
-                qlen[slot] = 1
-                active[slot] = True
-                decode_rows.append(slot)
-            chunk_rows: List[int] = []
-            finished: List[int] = []
-            for slot in sorted(self._mixed_pending):
-                p = self._mixed_pending[slot]
-                ids, off = p["ids"], p["off"]
-                n = min(C, len(ids) - off)
-                tokens[slot, :n] = ids[off:off + n]
-                pos[slot] = off
-                qlen[slot] = n
-                active[slot] = True
-                chunk_rows.append(slot)
-                if off + n >= len(ids):
-                    finished.append(slot)
-            if not decode_rows and not chunk_rows:
-                return
-            n_real = int(qlen.sum())
-            groups = self._mixed_groups(qlen)
-        with self.flight.span("dispatch"):
-            # every layer runs over the step's tokens packed out of
-            # their windows (paged.mixed_step_paged), at the smallest
-            # size that holds them. A step over the largest size runs
-            # in several dispatches, each over some of its rows: a row
-            # reads and writes its own pages only, so the rows of one
-            # step do not care which of them share a program.
-            self._last_jit = None
-            logits, computed = None, 0
-            for rows in groups:
-                size = mixed_bucket_for(self._mixed_buckets,
-                                        int(qlen[rows].sum()))
-                out, *moe = self._run_mixed_step(
-                    tokens, pos, np.where(rows, qlen, 0), active & rows,
-                    size)
-                logits = out if logits is None else jnp.where(
-                    jnp.asarray(rows)[:, None], out, logits)
-                self._moe_pending += moe
-                computed += size
-        emit_rows = decode_rows + finished
-        # advance the prefill frontiers BEFORE sampling/emit: a
-        # finishing row's _pos must read prompt-end when _emit runs
-        # its window-cap check (the _finish_prefill ordering)
-        for slot in chunk_rows:
-            p = self._mixed_pending[slot]
-            p["off"] += int(qlen[slot])
-            self._pos[slot] = p["off"]
-        if emit_rows:
-            nxt, lp, tids, tlps = self._sample_rows(
-                logits, rows=emit_rows, n_top=self._n_top_for(emit_rows))
-        else:
-            # every row is mid-prompt: nothing samples this step — skip
-            # the masked-sampling program entirely (its outputs would
-            # all be discarded, and it sits on the TTFT path)
-            nxt = lp = tids = tlps = None
-        self.stats.steps += 1
-        dt = time.perf_counter() - t0
-        # split the step wall by TOKEN share so the prefill/decode
-        # accounting stays meaningful under the mixed default (a mixed
-        # step IS both phases in one launch; all-to-decode would report
-        # prefill_time_s == 0 forever, and a per-row split would
-        # undercount a C-token chunk against a 1-token decode row)
-        chunk_toks = int(sum(qlen[s] for s in chunk_rows))
-        total_toks = chunk_toks + len(decode_rows)
-        pf = dt * chunk_toks / total_toks
-        self.stats.prefill_time_s += pf
-        self.stats.decode_time_s += dt - pf
-        self._obs_paged_step("mixed", dt)
-        # dispatch_s / device_s: the step's own spans, as the burst and
-        # speculative paths record them (device_s = the fetch wait)
-        self._record_step(
-            "mixed", rows=len(decode_rows) + len(chunk_rows),
-            tokens=len(emit_rows), wall_s=dt,
-            dispatch_s=self.flight.open_phase("dispatch"),
-            device_s=self.flight.open_phase("fetch"),
-            rows_decode=len(decode_rows), rows_prefill=len(chunk_rows),
-            rows_idle=B - len(decode_rows) - len(chunk_rows),
-            rids=[r for r, _s in self._implicated],
-            tokens_real=n_real, tokens_computed=computed,
-            moe=self._take_moe())
+        # rides these launches
+        self._implicated = tuple(decode_plan) + tuple(
+            (p["req"].rid, slot) for slot, p in pending.items())
+        # tokens sampled by steps the host has not fetched, per slot
+        # (_decode_stretch), and the stretch's dispatches
+        shipped: dict = {}
+        flying = _Flying()
+        # a follower replays every step from its host mirrors; the
+        # paged speculative engine's rows go back to its partition
+        # after every step (_do_spec_paged)
+        may_chain = not self._multihost and self._specp is None
+        # what takes over when no prompt is left: the sampled decode
+        # program on the same rows, if this engine keeps one in flight
+        tail_dispatch, tail_complete, tail_can_chain = self._decode_stretch(
+            plan, 1,
+            (may_chain and self._decode_scan_impl is not None
+             and self._decode_scan <= 1),
+            shipped, flying)
 
-        def _top(slot):
-            return (list(zip(tids[slot].tolist(), tlps[slot].tolist()))
-                    if tids.size else [])
+        def can_chain(n_inflight) -> bool:
+            if not pending:
+                return tail_can_chain(n_inflight)
+            # as _decode_stretch's gate; a window's positions lie
+            # inside its prompt
+            return (may_chain and flying.sent < STRETCH_STEPS
+                    and all(self._slot_req[s] is not None for s in rows_of)
+                    and all(self._pos[s] + shipped.get(s, 0) + 1
+                            < self.max_seq_len
+                            for s in rows_of if s not in pending))
 
-        with self.flight.span("emit"):
-            for slot in decode_rows:
-                req = self._slot_req[slot]
-                if req is None:
-                    continue
-                self._pos[slot] += 1
+        def dispatch(state):
+            if not pending:
+                # the last window went with the step before: decode on
+                # from its carry
+                devs, state = tail_dispatch(state)
+                return partial(tail_complete, devs), state
+            if self._faults is not None:
+                # once a step, as _decode_stretch's dispatch
+                if flying.sent:
+                    self._faults.check("engine.step",
+                                       step=self.stats.steps)
+                self._faults.check("engine.mixed", step=self.stats.steps)
+            t_start = time.perf_counter()
+            with span("build"):
+                # the step, a row a slot: the window, then position,
+                # q_len, step count and flags (make_mixed_sampled)
+                step = np.zeros((B, C + 4), np.int32)
+                tokens, pos, qlen, flags = (step[:, :C], step[:, C],
+                                            step[:, C + 1], step[:, C + 3])
+                step[:, C + 2] = self._steps
+                decode_rows: List[int] = []
+                for rid, slot in plan:
+                    req = self._slot_req[slot]
+                    if slot in pending or req is None or req.rid != rid:
+                        continue
+                    ahead = shipped.get(slot, 0)
+                    if req.max_new_tokens - len(req.out_tokens) <= ahead:
+                        continue    # its last token is in the step in flight
+                    tokens[slot, 0] = self._last_tok[slot]
+                    pos[slot] = min(self._pos[slot], self.max_seq_len - 1)
+                    qlen[slot] = 1
+                    flags[slot] = ROW_ACTIVE | ROW_SAMPLE | (
+                        ROW_FROM_CARRY if ahead else 0)
+                    decode_rows.append(slot)
+                chunk_rows: List[int] = []
+                finished: List[tuple] = []
+                for slot in sorted(pending):
+                    p = pending[slot]
+                    ids, off = p["ids"], p["off"]
+                    n = min(C, len(ids) - off)
+                    tokens[slot, :n] = ids[off:off + n]
+                    pos[slot] = off
+                    qlen[slot] = n
+                    flags[slot] = ROW_ACTIVE
+                    chunk_rows.append(slot)
+                    if off + n >= len(ids):
+                        flags[slot] |= ROW_SAMPLE
+                        finished.append((slot, p["req"]))
+                sampled = decode_rows + [slot for slot, _req in finished]
+                groups = self._mixed_groups(qlen)
+                rids = planned + [pending[slot]["req"].rid
+                                  for slot in chunk_rows]
+            with span("dispatch"):
+                # every layer runs over the step's tokens packed out of
+                # their windows (paged.mixed_step_paged), at the smallest
+                # size that holds them. A step over the largest size runs
+                # in several dispatches, each over some of its rows and
+                # sampling those: a row reads and writes its own pages,
+                # key and ring only, so the rows of one step do not care
+                # which of them share a program.
+                t0d = time.perf_counter()
+                self._last_jit = None
+                outs, computed = [], 0
+                for rows in groups:
+                    size = mixed_bucket_for(self._mixed_buckets,
+                                            int(qlen[rows].sum()))
+                    out, state = self._run_mixed_step(
+                        step if len(groups) == 1
+                        else np.where(rows[:, None], step, 0), state, size)
+                    outs.append((rows, out))
+                    computed += size
+                disp = time.perf_counter() - t0d
+            js, self._last_jit = self._last_jit, None
+            # the prefill frontiers, known without the step's result: a
+            # row whose window ended its prompt is a decode row of the
+            # next step, fed from the carry
+            for slot in chunk_rows:
+                p = pending[slot]
+                p["off"] += int(qlen[slot])
+                self._pos[slot] = p["off"]
+            for slot, _req in finished:
+                del pending[slot]
+            for slot in sampled:
+                shipped[slot] = shipped.get(slot, 0) + 1
+            self.stats.steps += 1
+            chained = flying.dispatched()
+            # from the second step on the planner would list every row
+            planned[:] = [rid for rid, _slot in plan]
+            devs = (outs, decode_rows, chunk_rows, finished, sampled, rids,
+                    int(qlen.sum()), computed, t_start, disp, js, chained)
+            return partial(complete, devs), state
+
+        def complete(devs):
+            (outs, decode_rows, chunk_rows, finished, sampled, rids, n_real,
+             computed, t_start, disp, js, chained) = devs
+            with span("fetch"):
+                # ONE fetch: the sampled tuple and the counters of every
+                # dispatch of the step
+                got = jax.device_get([out for _rows, out in outs])
+            wall = flying.fetched(t_start)
+            nxt, lp, tids, tlps, _moe = got[0]
+            for (rows, _out), (nxt_g, lp_g, tids_g, tlps_g, _m) in zip(
+                    outs[1:], got[1:]):
+                nxt, lp = np.where(rows, nxt_g, nxt), np.where(rows, lp_g, lp)
+                tids = np.where(rows[:, None], tids_g, tids)
+                tlps = np.where(rows[:, None], tlps_g, tlps)
+            moe = [m for *_sampled, moe_g in got for m in moe_g]
+            # split the step wall by TOKEN share so the prefill/decode
+            # accounting stays meaningful under the mixed default (a mixed
+            # step IS both phases in one launch; all-to-decode would report
+            # prefill_time_s == 0 forever, and a per-row split would
+            # undercount a C-token chunk against a 1-token decode row)
+            pf = wall * (n_real - len(decode_rows)) / n_real
+            self.stats.prefill_time_s += pf
+            self.stats.decode_time_s += wall - pf
+            self._obs_paged_step("mixed", wall)
+            # written after the fetch and before the emit: a request
+            # whose first token this step sampled is still a prefill
+            # row at the record's ts
+            self._record_step(
+                "mixed", rows=len(decode_rows) + len(chunk_rows),
+                tokens=len(sampled), wall_s=wall,
+                dispatch_s=disp,
+                device_s=(wall if chained
+                          else self.flight.open_phase("fetch")),
+                js=js, rows_decode=len(decode_rows),
+                rows_prefill=len(chunk_rows),
+                rows_idle=B - len(decode_rows) - len(chunk_rows),
+                rids=rids, tokens_real=n_real, tokens_computed=computed,
+                moe=np.sum(moe, axis=0) if moe else None, chained=chained)
+
+            def emit(req, slot):
+                self._steps[slot] += 1
+                self._last_tok[slot] = nxt[slot]
                 self._emit(req, int(nxt[slot]), logprob=float(lp[slot]),
-                           top=_top(slot))
-            for slot in finished:
-                p = self._mixed_pending.pop(slot, None)
-                if p is None:
-                    continue
-                self._emit(p["req"], int(nxt[slot]),
-                           logprob=float(lp[slot]), top=_top(slot))
+                           top=(list(zip(tids[slot].tolist(),
+                                         tlps[slot].tolist()))
+                                if req.want_top else []))
+
+            with span("emit"):
+                for slot in decode_rows:
+                    req = self._slot_req[slot]
+                    if req is None or req.rid != rows_of[slot]:
+                        # it ended in the step before (EOS: the program
+                        # froze it there)
+                        continue
+                    self._pos[slot] += 1
+                    emit(req, slot)
+                for slot, req in finished:
+                    if self._slot_req[slot] is req:
+                        emit(req, slot)
+            for slot in sampled:
+                shipped[slot] -= 1
+            if self._journal is not None:
+                # once per completed step, as the run loop flushes once
+                # per iteration: no later and no rarer than before
+                with span("admin"):
+                    self._journal.flush()
+
+        # the first dispatch is the step the run loop planned, whoever
+        # waits; only what follows is gated
+        self._drive_burst(dispatch, lambda finish: finish(), can_chain,
+                          first_unconditional=True)
 
     def _match_and_validate_prefix(self, ids: List[int]):
         """(pid, (p_ids, k, v)) of the longest matching registered prefix
@@ -5604,37 +5720,47 @@ class InferenceEngine:
 
     def _decode_burst(self, decode_plan, n: int,
                       chain: bool = True) -> None:
-        """A stretch of pure decode with one dispatch in flight: dispatch
-        k+1 (its inputs chained on device from k's final carry — zero
-        host round-trips between them) BEFORE fetching k's tokens, so
-        the fetch and the emit of k run while the device computes k+1.
-        n = 1 is the sampled one-step program (records of kind
-        `decode`), n > 1 --decode-scan's K-step scans. The stretch ends
+        """A stretch of pure decode with one dispatch in flight
+        (_decode_stretch through _drive_burst). n = 1 is the sampled
+        one-step program (records of kind `decode`), n > 1
+        --decode-scan's K-step scans."""
+        self._implicated = decode_plan
+        # n = 1: the first dispatch is the step the synchronous path
+        # would have run, whoever waits; only what follows is gated
+        self._drive_burst(
+            *self._decode_stretch(decode_plan, n, chain, {}, _Flying()),
+            first_unconditional=(n == 1))
+
+    def _decode_stretch(self, decode_plan, n: int, chain: bool,
+                        shipped: dict, flying: "_Flying") -> tuple:
+        """_drive_burst's (dispatch, complete, can_chain) for decode
+        steps on the rows of decode_plan: dispatch k+1 (its inputs
+        chained on device from k's final carry — zero host round-trips
+        between them) BEFORE fetching k's tokens, so the fetch and the
+        emit of k run while the device computes k+1. The stretch ends
         when the host needs the loop back (_host_attention_pending),
         when a row of its plan finished (the slot is the planner's to
         fill), when no row has budget or window left, after
         STRETCH_STEPS dispatches, and after its first where the caller
         says the plan is about to change (chain=False). Single-host
-        only: a follower
-        rebuilds its inputs from its mirrors, which match the chained
-        carry for live rows but diverge for rows that froze (EOS) inside
-        an earlier not-yet-fetched dispatch — lockstep multi-host
-        serving keeps the synchronous paths instead."""
-        t0 = time.perf_counter()
-        self._implicated = decode_plan
+        only: a follower rebuilds its inputs from its mirrors, which
+        match the chained carry for live rows but diverge for rows that
+        froze (EOS) inside an earlier not-yet-fetched dispatch —
+        lockstep multi-host serving keeps the synchronous paths
+        instead.
+
+        shipped: tokens dispatched in not-yet-fetched programs, per
+        slot: added at dispatch, removed at fetch — budget math and the
+        window guard both project the device state past the stale host
+        mirrors by exactly this amount. flying: the stretch's
+        dispatches. Both are the caller's: a stretch of mixed steps
+        goes on into this one with a step of its own in flight
+        (_mixed_burst)."""
         span = self.flight.span
         rows = [s for _, s in decode_plan]
         rids = [r for r, _s in decode_plan]
         n_top = self._n_top_for(rows)
         kind = "decode" if n == 1 else "decode_scan"
-        # tokens dispatched in not-yet-fetched programs, per slot: added
-        # at dispatch, removed at fetch — budget math and the window
-        # guard both project the device state past the stale host
-        # mirrors by exactly this amount
-        shipped: dict = {}
-        # dispatches so far, of them unfetched, and the end of the
-        # newest fetch
-        flying = {"sent": 0, "unfetched": 0, "fetch_t1": 0.0}
 
         def can_chain(_n_inflight) -> bool:
             # real work remains, and the PROJECTED device position
@@ -5651,7 +5777,7 @@ class InferenceEngine:
             # admissions behind a stretch, and itl_p95_ms moving with
             # their count; my chip run, PR 29). The step in flight
             # covers the time the arrival needs.
-            return (chain and flying["sent"] < STRETCH_STEPS
+            return (chain and flying.sent < STRETCH_STEPS
                     and all(self._slot_req[s] is not None for s in rows)
                     and self._scan_budget(decode_plan, n, shipped).any()
                     and all(self._pos[s] + shipped.get(s, 0) + n
@@ -5662,7 +5788,7 @@ class InferenceEngine:
                 # the chaos plane's sites fire once a step, as when
                 # every step was an iteration of the run loop (which
                 # checked engine.step before this stretch's first)
-                if flying["sent"]:
+                if flying.sent:
                     self._faults.check("engine.step",
                                        step=self.stats.steps)
                 self._faults.check("engine.decode", step=self.stats.steps)
@@ -5681,24 +5807,16 @@ class InferenceEngine:
             for slot in rows:
                 shipped[slot] = shipped.get(slot, 0) + int(budget[slot])
             self.stats.steps += n
-            chained = flying["unfetched"] > 0
-            flying["sent"] += 1
-            flying["unfetched"] += 1
-            return (outs, budget, t_start, disp, js, chained), state
+            return (outs, budget, t_start, disp, js,
+                    flying.dispatched()), state
 
         def complete(devs):
             outs_k, budget_k, t_start, disp_k, js_k, chained = devs
             with span("fetch"):
                 fetched = self._fetch_scan(outs_k)
-            t1 = time.perf_counter()
-            flying["unfetched"] -= 1
-            # what this dispatch added to the loop: from its own start,
-            # or from the end of the fetch before it when it was queued
-            # behind that step — never less than the device needed.
-            # For a chained step that period is the best reading of the
-            # device's time too; its fetch alone waited for less.
-            wall = t1 - max(t_start, flying["fetch_t1"])
-            flying["fetch_t1"] = t1
+            wall = flying.fetched(t_start)
+            self.stats.decode_time_s += wall
+            self._obs_paged_step("decode", wall / n)
             moe = fetched[4]
             self._record_step(
                 kind, rows=int(np.count_nonzero(budget_k)),
@@ -5719,15 +5837,7 @@ class InferenceEngine:
                 with span("admin"):
                     self._journal.flush()
 
-        steps0 = self.stats.steps
-        # n = 1: the first dispatch is the step the synchronous path
-        # would have run, whoever waits; only what follows is gated
-        self._drive_burst(dispatch, complete, can_chain,
-                          first_unconditional=(n == 1))
-        dt = time.perf_counter() - t0
-        self.stats.decode_time_s += dt
-        self._obs_paged_step("decode",
-                             dt / max(1, self.stats.steps - steps0))
+        return dispatch, complete, can_chain
 
     def _complete_scan(self, decode_plan, n: int, fetched,
                        budget) -> None:
@@ -6298,6 +6408,116 @@ def make_decode_scan(forward_fn, out_sharding=None) -> DecodePrograms:
                               jnp.swapaxes(tops_l, 0, 1)))
 
     return DecodePrograms(decode_step_sampled, decode_scan, out_sharding)
+
+
+class _Flying:
+    """The dispatches of one stretch (_decode_stretch, _mixed_burst):
+    how many were sent, how many of them the host has not fetched, and
+    when the newest fetch ended."""
+
+    __slots__ = ("sent", "unfetched", "fetch_t1")
+
+    def __init__(self):
+        self.sent = self.unfetched = 0
+        self.fetch_t1 = 0.0
+
+    def dispatched(self) -> bool:
+        """Count one dispatch. True: it is chained, sent while the one
+        before it was unfetched."""
+        chained = self.unfetched > 0
+        self.sent += 1
+        self.unfetched += 1
+        return chained
+
+    def fetched(self, t_start: float) -> float:
+        """Count the fetch that just ended, of the dispatch begun at
+        t_start. Returns what that dispatch added to the loop: from its
+        own start, or from the end of the fetch before it when it was
+        queued behind that step, never less than the device needed.
+        For a chained step that period is the best reading of the
+        device's time too; its fetch alone waited for less."""
+        t1 = time.perf_counter()
+        self.unfetched -= 1
+        wall = t1 - max(t_start, self.fetch_t1)
+        self.fetch_t1 = t1
+        return wall
+
+
+# what a row does in a mixed step (the last column of the packed step)
+ROW_ACTIVE, ROW_SAMPLE, ROW_FROM_CARRY = 1, 2, 4
+
+
+def make_mixed_sampled(mixed_fn):
+    """Build the jitted sampled mixed step over a mixed step function
+    (paged.mixed_step_paged, glm_dsa.mixed_step_latent: one signature):
+    the forward on the packed axis, then _masked_sample over the rows
+    that sample this step, then the EOS freeze of make_decode_scan's
+    body, so that the next step can be dispatched from this one's
+    tokens while they are still on the device.
+
+    step [B, C + 4] int32, the step as the host knows it, a row a slot:
+    its window of C tokens, then its position, its q_len, its step
+    count and its flags. ROW_ACTIVE: the row is in this dispatch;
+    ROW_SAMPLE: it samples (a decode row, a row whose window ends its
+    prompt; every other row keeps its key and ring); ROW_FROM_CARRY: a
+    step the host has not fetched yet sampled it, so its input token,
+    its position, its step count and whether it still lives (no EOS
+    yet) come from that step's carry. ONE array because each host array
+    is a transfer of its own, which a stretch's first step pays with
+    the device idle. carry = (tok, pos, steps, live), each [B], in the
+    decode programs' form. A row that is not active passes its carry
+    through, so a step of several dispatches threads one carry through
+    them.
+    Returns (tokens [B], logprobs [B], top ids and top logprobs
+    [B, n_top], cache, keys, ring, carry, and a sparse model's
+    counters): the carry feeds the next mixed step, or the sampled
+    decode programs as (last_tok, pos, steps, active)."""
+
+    # the name is the XLA module's (jit_mixed_step_...): the benchmark
+    # finds a mixed step's device time by that prefix
+    @partial(jax.jit, static_argnames=("config", "attn", "n_tokens",
+                                       "top_k", "n_top"),
+             donate_argnames=("cache", "keys", "ring"))
+    def mixed_step_sampled(params, step, cache, rope, config, keys, ring,
+                           temp, top_p, penalty, carry, attn, n_tokens,
+                           top_k, n_top: int = 0):
+        tokens = step[:, :-4]
+        pos, q_len, steps, flags = (step[:, i] for i in range(-4, 0))
+        active, sample, from_carry = ((flags & bit) != 0 for bit in (
+            ROW_ACTIVE, ROW_SAMPLE, ROW_FROM_CARRY))
+        c_tok, c_pos, c_steps, c_live = carry
+        tok = jnp.where(from_carry, c_tok, tokens[:, 0])
+        pos = jnp.where(from_carry, c_pos, pos)
+        steps = jnp.where(from_carry, c_steps, steps)
+        live = active & jnp.where(from_carry, c_live, True)
+        logits, cache, *counters = mixed_fn(
+            params, tokens.at[:, 0].set(tok), pos, q_len, live, cache,
+            rope, config, attn=attn, n_tokens=n_tokens)
+        sampled = sample & live
+        nxt, keys, ring, lp, t_i, t_l = _masked_sample(
+            sampled, keys, logits, ring, steps, temp, top_p, penalty,
+            top_k=top_k, n_top=n_top)
+        eos = jnp.isin(nxt, jnp.asarray(config.eos_token_ids, jnp.int32))
+        carry = (jnp.where(sampled, nxt, jnp.where(active, tok, c_tok)),
+                 jnp.where(active, pos + jnp.where(live, q_len, 0), c_pos),
+                 jnp.where(active, steps + sampled, c_steps),
+                 jnp.where(active, sampled & ~eos, c_live))
+        return (nxt, lp, t_i, t_l, cache, keys, ring, carry, *counters)
+
+    return mixed_step_sampled
+
+
+# module-level like the decode programs, so the jit cache is shared
+# across engine instances
+_mixed_sampled_paged = make_mixed_sampled(mixed_step_paged)
+
+
+def _mixed_step_latent(*args, **kw):
+    from cake_tpu.models.moe.glm_dsa import mixed_step_latent
+    return mixed_step_latent(*args, **kw)
+
+
+_mixed_sampled_latent = make_mixed_sampled(_mixed_step_latent)
 
 
 def _builtin_forward_ragged(params, tokens, cache, pos, active, rope,

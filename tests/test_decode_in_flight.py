@@ -181,15 +181,19 @@ def test_window_end_matches_synchronous(tiny_config, params):
 
 def _spy_dispatches(eng):
     """[(perf_counter, chained from the device carry)] of every sampled
-    dispatch."""
+    dispatch, decode and (a paged engine's) mixed."""
     seen = []
-    orig = eng._dispatch_scan_device
+    orig, orig_mixed = eng._dispatch_scan_device, eng._run_mixed_step
 
     def spy(rows, n, n_top, budget, state=None):
         seen.append((time.perf_counter(), state is not None))
         return orig(rows, n, n_top, budget, state=state)
 
-    eng._dispatch_scan_device = spy
+    def spy_mixed(step, carry, size):
+        seen.append((time.perf_counter(), carry is not None))
+        return orig_mixed(step, carry, size)
+
+    eng._dispatch_scan_device, eng._run_mixed_step = spy, spy_mixed
     return seen
 
 
@@ -276,9 +280,11 @@ def test_stretch_length_returns_to_the_loop(tiny_config, params):
     eng = make_engine(tiny_config, params, max_seq_len=256,
                       kv_pages=40, kv_page_size=8)
     serve(eng, [([5] * 9, dict(GREEDY, max_new_tokens=n + 1))])
-    flags = [r["chained"] for r in decode_records(eng)]
-    assert len(flags) == n
-    starts = [i for i, c in enumerate(flags) if not c]
+    recs = list(reversed(eng.flight.dump()))
+    assert [r["kind"] for r in recs] == ["mixed"] + ["decode"] * n
+    # the first stretch is the prompt's one window and the decode steps
+    # chained onto it
+    starts = [i for i, r in enumerate(recs) if not r["chained"]]
     assert starts == [0, STRETCH_STEPS, 2 * STRETCH_STEPS]
 
 
@@ -315,15 +321,18 @@ def test_records_of_a_stretch(tiny_config, params, flavour):
     assert len(recs) == 19 and not any(
         r["kind"] == "decode_scan" for r in eng.flight.dump())
     assert all(r["impl"] == flavour for r in recs)
-    assert [r["chained"] for r in recs] == [False] + [True] * 18
-    assert chained_total.value - before == 18
+    # a paged engine's first decode step is chained onto the mixed step
+    # that ended the prompts
+    first = flavour != "dense"
+    assert [r["chained"] for r in recs] == [first] + [True] * 18
+    assert chained_total.value - before == 18 + first
     rids = sorted(h._req.rid for h in hs)
     for r in recs:
         assert r["rows"] == 2 and r["tokens"] == 2
         assert sorted(r["rids"]) == rids and r["ts"] > 0
         assert "sample" not in r["phases"]
     assert all(r["gap_s"] == 0.0 for r in recs[1:])
-    assert recs[0]["gap_s"] > 0.0
+    assert (recs[0]["gap_s"] > 0.0) != first
     # a chained step's wall_s is the time it added to the loop: from
     # the fetch before it to its own, so the stretch's wall_s add up to
     # the time between its first record and its last

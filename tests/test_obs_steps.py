@@ -471,60 +471,47 @@ def paged_engine():
 
 @pytest.mark.parametrize("kind", ["mixed", "decode"])
 def test_paged_step_timings_are_the_spans(paged_engine, kind):
-    """dispatch_s is the `dispatch` span and device_s the `fetch` span
-    of a mixed step (both used to repeat wall_s); wall_s stays the whole
-    step. A decode step is kept in flight: its own dispatch's seconds,
-    and for a chained step wall_s = device_s = the period between two
-    fetches, with no `sample` span anywhere (the program samples)."""
+    """Both kinds of step are kept in flight: dispatch_s is a step's
+    own dispatch's seconds; a step with nothing in flight before it has
+    device_s = its `fetch` span and wall_s the whole step; a chained
+    step wall_s = device_s = the period between two fetches. No
+    `sample` span anywhere (the programs sample)."""
     recs = [r for r in paged_engine.flight.dump() if r["kind"] == kind]
     assert recs, paged_engine.flight.summary()
     for r in recs:
         ph = r["phases"]
-        if kind == "decode":
-            assert "sample" not in ph and "fetch" in ph, r
-            if r["chained"]:
-                # (its dispatch ran inside the period before its own)
-                assert r["device_s"] == r["wall_s"] and r["gap_s"] == 0.0
-            else:
-                assert {"build", "dispatch"} <= set(ph), r
-                assert r["dispatch_s"] < r["wall_s"]
-                assert r["device_s"] < r["wall_s"]
-            continue
-        assert r["dispatch_s"] < r["wall_s"], r
-        assert {"build", "dispatch", "sample", "fetch"} <= set(ph), r
-        assert r["dispatch_s"] == pytest.approx(ph["dispatch"], abs=2e-6)
-        assert r["device_s"] == pytest.approx(ph["fetch"], abs=2e-6)
-        assert r["device_s"] < r["wall_s"]
-        covered = sum(ph[k] for k in ("build", "dispatch", "sample",
-                                      "fetch"))
-        assert covered <= r["wall_s"] + 1e-4
+        assert "sample" not in ph and "fetch" in ph, r
+        if r["chained"]:
+            # (its dispatch ran inside the period before its own)
+            assert r["device_s"] == r["wall_s"] and r["gap_s"] == 0.0
+        else:
+            assert {"build", "dispatch"} <= set(ph), r
+            assert r["dispatch_s"] < r["wall_s"]
+            assert r["device_s"] < r["wall_s"]
+            # (its `dispatch` span holds the next step's dispatch too)
+            assert r["dispatch_s"] <= ph["dispatch"] + 1e-6
+            assert r["device_s"] == pytest.approx(ph["fetch"], abs=2e-6)
     # two spans of a few hundred microseconds, recorded to the
     # microsecond, coincide in one record now and then (0.000224 ==
     # 0.000224 failed the driver's run at PR 26): they are two
     # measurements if they differ anywhere
     assert any(r["dispatch_s"] != r["device_s"] for r in recs)
-    if kind == "decode":
-        # one request, nobody else arriving: one stretch, whose first
-        # step alone was dispatched with nothing in flight
-        assert [r["chained"] for r in reversed(recs)] == \
-            [False] + [True] * (len(recs) - 1)
+    # one request, nobody else arriving: one stretch from the prompt's
+    # first window to the last token, whose first step alone was
+    # dispatched with nothing in flight
+    flags = [r["chained"] for r in reversed(paged_engine.flight.dump())]
+    assert flags == [False] + [True] * (len(flags) - 1)
 
 
 def test_paged_decode_steps_carry_gap_and_emit(paged_engine):
     recs = [r for r in reversed(paged_engine.flight.dump())
             if r["kind"] == "decode"]
-    assert all("gap_s" in r for r in recs)
-    # the stretch's first step follows a mixed step's fetch: its gap is
-    # host work (that step's emit, the loop's admin and schedule, its
-    # own build); every later step was queued behind the one before it
-    first, later = recs[0], recs[1:]
-    host = sum(first["phases"].get(k, 0.0)
-               for k in ("emit", "admin", "schedule", "build"))
-    assert 0 < host <= first["gap_s"] + 1e-4, first
-    assert later and all(r["gap_s"] == 0.0 for r in later)
+    # the stretch went on from the prompt's last window into the decode
+    # steps: every one of them was queued behind the step before it
+    assert recs and all(r["gap_s"] == 0.0 for r in recs)
     # a record holds the spans since the record before it: the emit of
     # the step before, while this one ran
-    assert all(r["phases"]["emit"] > 0 for r in later)
+    assert all(r["phases"]["emit"] > 0 for r in recs)
 
 
 def test_dense_engine_steps_carry_phases(engine):
