@@ -1026,6 +1026,13 @@ class QueueFull(Exception):
         self.draining = draining
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # the listen backlog: socketserver's 5 resets connections when a
+    # wave of clients (32 closed-loop callers starting at once) connects
+    # faster than the accept loop runs
+    request_queue_size = 128
+
+
 def make_handler(api: ApiServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
@@ -1035,6 +1042,9 @@ def make_handler(api: ApiServer):
 
         def _json(self, code: int, obj: dict):
             data = json.dumps(obj).encode()
+            # counted BEFORE the body goes out: a client that scrapes
+            # /metrics as soon as it has its answer must find it counted
+            api._count(self.path, code)
             self.send_response(code)
             if code >= 400 and getattr(self, "_trace", None):
                 # echo the request's trace id on error responses: the
@@ -1046,7 +1056,6 @@ def make_handler(api: ApiServer):
             self.send_header("Content-Length", str(len(data)))
             self.end_headers()
             self.wfile.write(data)
-            api._count(self.path, code)
 
         def _retry_json(self, code: int, retry_after_s: float,
                         obj: dict):
@@ -1056,6 +1065,7 @@ def make_handler(api: ApiServer):
             the body as retry_after_s."""
             retry = max(1, int(-(-retry_after_s // 1)))
             data = json.dumps({**obj, "retry_after_s": retry}).encode()
+            api._count(self.path, code)
             self.send_response(code)
             self.send_header("Retry-After", str(retry))
             # attribute the backpressure to THIS replica: the router
@@ -1068,7 +1078,6 @@ def make_handler(api: ApiServer):
             self.send_header("Content-Length", str(len(data)))
             self.end_headers()
             self.wfile.write(data)
-            api._count(self.path, code)
 
         def _query(self) -> dict:
             """First value of each query param (the filter endpoints'
@@ -1431,7 +1440,7 @@ def start(master, address: str = "127.0.0.1:10128",
             master.args, "stall_timeout", 600.0))
     api = ApiServer(master, model_name, engine=engine, health=health,
                     collector=collector, replica_id=address)
-    httpd = ThreadingHTTPServer((host, int(port)), make_handler(api))
+    httpd = _HTTPServer((host, int(port)), make_handler(api))
     log.info("REST API listening on %s", address)
 
     announcer = None

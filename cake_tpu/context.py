@@ -142,6 +142,14 @@ class Context:
                 "pool) does not serve yet over a topology, --tp, --dp, "
                 "--sp or with --draft-model: one chip's paged engine "
                 "only (ROADMAP.md lists each as left to do)")
+        if getattr(cfg, "mamba_layers", None) and (
+                plan.stages > 1 or plan.tp > 1 or plan.dp > 1 or a.sp > 1
+                or a.draft_model is not None):
+            raise ValueError(
+                "model_type nemotron_h (a recurrent state a row beside "
+                "the page pool) does not serve yet over a topology, "
+                "--tp, --dp, --sp or with --draft-model: one chip's "
+                "paged engine only (ROADMAP.md lists each as left to do)")
         born_sharded = (
             (plan.stages > 1 or plan.tp > 1 or plan.dp > 1)
             and (a.sp <= 1 or plan.stages > 1)

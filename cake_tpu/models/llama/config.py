@@ -33,6 +33,9 @@ MODEL_TYPES = {
     "glm_moe_dsa": "latent attention over one cache row a token, a learned "
                    "sparse indexer whose key sets layers share, "
                    "sigmoid-routed experts with a shared one (paged engine)",
+    "nemotron_h": "one mixer a block: Mamba-2 with a recurrent state a row "
+                  "beside the page pool, relu² experts in a latent with a "
+                  "shared one, GQA without positions (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -51,6 +54,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "glm_moe_dsa":
         from cake_tpu.models.moe.config import GlmMoeDsaConfig
         return GlmMoeDsaConfig.from_hf_dict(raw)
+    if model_type == "nemotron_h":
+        from cake_tpu.models.moe.config import NemotronHConfig
+        return NemotronHConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)
@@ -153,7 +159,8 @@ class LlamaConfig:
             # there; Qwen2 uses ChatML
             chat_template={"mistral": "mistral", "mixtral": "mistral",
                            "qwen2": "chatml", "olmoe": "tulu",
-                           "glm_moe_dsa": "chatml"}.get(
+                           "glm_moe_dsa": "chatml",
+                           "nemotron_h": "chatml"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",
                                    raw.get("model_type") == "qwen2"),

@@ -23,6 +23,16 @@ it), and `v` the sparse indexer's key,
 [L_full, N_pages, page, index_head_dim], in the layers that compute an
 index only. Both pools go through the one page table and allocator: a
 page id names the same token range in each.
+A model with recurrent blocks (nemotron_h) keeps TWO kinds of state in
+the one cache object (HybridPagedCache): the K/V pool above for its
+attention blocks alone ([L_attn, ...]), and per ROW (slot), not per
+token, each Mamba block's SSM state `ssm` [L_M, slots, H, P, N] float32
+and the last conv_kernel-1 inputs of its causal conv `conv`
+[L_M, slots, K-1, conv_dim]. A row's state has no pages: nothing is
+allocated or released for it; the step programs zero it where a row's
+first token sits at position 0 (a request that takes the slot), carry
+it from window to window and from step to step, and leave the rows that
+hold no token in a dispatch (idle, frozen, out of budget) as they are.
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -89,6 +99,9 @@ class PagedKVCache(NamedTuple):
             raise ValueError(
                 f"page_size {page_size} must divide max_seq_len "
                 f"{max_seq_len}")
+        if getattr(config, "mamba_layers", None):
+            return HybridPagedCache.create(config, slots, n_pages,
+                                           page_size, max_seq_len, dtype)
         L = config.num_hidden_layers
         if getattr(config, "kv_lora_rank", None):
             # latent attention: one latent row a token, and the
@@ -114,6 +127,50 @@ class PagedKVCache(NamedTuple):
         return sum(leaf.nbytes
                    for leaf in jax.tree_util.tree_leaves((self.k,
                                                           self.v)))
+
+
+class HybridPagedCache(NamedTuple):
+    """PagedKVCache plus the rows' recurrent state (module docstring):
+    what a model with Mamba blocks carries through its step programs,
+    donated in and aliased out like the pools."""
+    k: jnp.ndarray        # [L_attn, N_pages, page, KV*hd]
+    v: jnp.ndarray
+    table: jnp.ndarray    # [slots, max_pages] int32
+    ssm: jnp.ndarray      # [L_M, slots, H, P, N] float32
+    conv: jnp.ndarray     # [L_M, slots, K-1, conv_dim]
+
+    page_size = PagedKVCache.page_size
+    n_pages = PagedKVCache.n_pages
+    max_pages = PagedKVCache.max_pages
+    max_seq_len = PagedKVCache.max_seq_len
+
+    @classmethod
+    def create(cls, config, slots: int, n_pages: int, page_size: int,
+               max_seq_len: int, dtype=jnp.bfloat16) -> "HybridPagedCache":
+        if max_seq_len % page_size:
+            raise ValueError(
+                f"page_size {page_size} must divide max_seq_len "
+                f"{max_seq_len}")
+        c = config
+        L_M = len(c.mamba_layers)
+        pool = (len(c.attn_layers), n_pages, page_size,
+                c.num_key_value_heads * c.head_dim)
+        return cls(
+            k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
+            table=jnp.full((slots, max_seq_len // page_size), -1,
+                           jnp.int32),
+            ssm=jnp.zeros((L_M, slots, c.mamba_num_heads, c.mamba_head_dim,
+                           c.ssm_state_size), jnp.float32),
+            conv=jnp.zeros((L_M, slots, c.conv_kernel - 1, c.conv_dim),
+                           dtype))
+
+    def memory_bytes(self) -> int:
+        """Pool bytes (the pages an allocator hands out)."""
+        return self.k.nbytes + self.v.nbytes
+
+    def state_bytes(self) -> int:
+        """Bytes of the rows' recurrent state."""
+        return self.ssm.nbytes + self.conv.nbytes
 
 
 class PageAllocator:

@@ -256,3 +256,164 @@ class GlmMoeDsaConfig(MoEConfig):
         )
         base.update(overrides)
         return cls(**base)
+
+
+@dataclass(frozen=True)
+class NemotronHConfig(MoEConfig):
+    """Nemotron-3 (`model_type: nemotron_h`): every block is ONE mixer
+    behind one RMS norm and one residual, its kind read from
+    `hybrid_override_pattern`: `M` a Mamba-2 block (a per-ROW recurrent
+    state, not per-token rows: models/llama/paged.HybridPagedCache), `E`
+    a LatentMoE block (sigmoid-routed relu² experts inside a
+    `moe_latent_size`-wide latent, a shared expert on the full width),
+    `*` GQA attention without a positional embedding. The equations are
+    in models/reference/nemotron_h.py; the served path in
+    models/moe/nemotron_h.py.
+
+    `intermediate_size` is unused by the blocks (config.json carries the
+    expert width there too). `num_local_experts` counts the routed
+    experts HELD here (config.json `n_routed_experts`),
+    `n_routed_experts_total` the router's width, `first_routed_expert`
+    the first held expert's index, as GlmMoeDsaConfig has them."""
+
+    pattern: Tuple[str, ...] = ()
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    n_routed_experts_total: int = 512
+    first_routed_expert: int = 0
+    routed_scaling_factor: float = 5.0
+    scoring_func: str = "sigmoid"
+
+    @property
+    def mamba_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.pattern) if t == "M")
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.pattern) if t == "E")
+
+    @property
+    def attn_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.pattern) if t == "*")
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal conv runs over: xs | B | C."""
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def in_proj_dim(self) -> int:
+        """z | xBC | dt."""
+        return self.d_inner + self.conv_dim + self.mamba_num_heads
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "NemotronHConfig":
+        L = raw["num_hidden_layers"]
+        pattern = tuple(raw["hybrid_override_pattern"])
+        if len(pattern) != L or set(pattern) - set("ME*"):
+            raise ValueError(
+                f"hybrid_override_pattern {''.join(pattern)!r} must name "
+                f"num_hidden_layers = {L} blocks out of M, E and *")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, rms_norm_eps=raw.get("layer_norm_epsilon",
+                                      raw.get("norm_eps", 1e-5)),
+            intermediate_size=raw.get("intermediate_size",
+                                      raw["moe_intermediate_size"])))
+        if raw.get("head_dim", base.head_dim) != base.head_dim:
+            raise ValueError(
+                f"head_dim {raw['head_dim']} != hidden_size / "
+                f"num_attention_heads = {base.head_dim}")
+        for name in ("n_group", "topk_group"):
+            if raw.get(name, 1) != 1:
+                raise ValueError(f"{name} = {raw[name]}: group-limited "
+                                 "routing is not implemented")
+        if raw.get("n_shared_experts", 1) != 1:
+            raise ValueError("n_shared_experts must be 1")
+        if raw.get("num_nextn_predict_layers", 0):
+            raise ValueError(
+                "num_nextn_predict_layers > 0: the multi-token-prediction "
+                "module is not served (it drafts for speculation and adds "
+                "nothing to the next-token logits); set it to 0")
+        for name, want in (("mlp_hidden_act", "relu2"),
+                           ("mamba_hidden_act", "silu")):
+            if raw.get(name, want) != want:
+                raise ValueError(f"{name} = {raw[name]!r}: only {want!r} "
+                                 "is implemented")
+        for name in ("attention_bias", "mamba_proj_bias", "mlp_bias",
+                     "use_bias"):
+            if raw.get(name, False):
+                raise ValueError(f"{name} = true: projection biases are "
+                                 "not implemented")
+        if not raw.get("use_conv_bias", True):
+            raise ValueError("use_conv_bias = false is not implemented")
+        if raw["mamba_num_heads"] % raw["n_groups"]:
+            raise ValueError("n_groups must divide mamba_num_heads")
+        held = raw["n_routed_experts"]
+        total = raw.get("n_routed_experts_total", held)
+        first = raw.get("first_routed_expert", 0)
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields["chat_template"] = "chatml"
+        return cls(
+            **fields,
+            num_local_experts=held,
+            num_experts_per_tok=raw["num_experts_per_tok"],
+            norm_topk_prob=raw.get("norm_topk_prob", True),
+            hf_layout="nemotron_h", pattern=pattern,
+            mamba_num_heads=raw["mamba_num_heads"],
+            mamba_head_dim=raw["mamba_head_dim"],
+            n_groups=raw["n_groups"],
+            ssm_state_size=raw["ssm_state_size"],
+            conv_kernel=raw.get("conv_kernel", 4),
+            chunk_size=raw.get("chunk_size", 128),
+            time_step_min=raw.get("time_step_min", 0.001),
+            time_step_max=raw.get("time_step_max", 0.1),
+            time_step_floor=raw.get("time_step_floor", 1e-4),
+            moe_intermediate_size=raw["moe_intermediate_size"],
+            moe_latent_size=raw["moe_latent_size"],
+            moe_shared_expert_intermediate_size=raw[
+                "moe_shared_expert_intermediate_size"],
+            n_routed_experts_total=total, first_routed_expert=first,
+            routed_scaling_factor=raw.get("routed_scaling_factor", 1.0),
+        )
+
+    @classmethod
+    def tiny_nemotron(cls, **overrides) -> "NemotronHConfig":
+        """Nemotron-3's blocks at a test's size: every kind of block,
+        4 Mamba heads of 8 in 2 groups, state 16, chunk 8, 16 routed
+        experts of which the first 4 are held, 3 a token."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=24,
+            num_hidden_layers=5, num_attention_heads=4,
+            num_key_value_heads=2, rms_norm_eps=1e-5, rope_theta=10000.0,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=4, num_experts_per_tok=3,
+            norm_topk_prob=True, hf_layout="nemotron_h",
+            pattern=tuple("EM*EM"), mamba_num_heads=4, mamba_head_dim=8,
+            n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+            moe_intermediate_size=24, moe_latent_size=32,
+            moe_shared_expert_intermediate_size=48,
+            n_routed_experts_total=16, first_routed_expert=0,
+            routed_scaling_factor=5.0,
+        )
+        base.update(overrides)
+        return cls(**base)

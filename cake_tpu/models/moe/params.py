@@ -121,6 +121,71 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     }
 
 
+def _init_nemotron_params(config, rng: jax.Array, dtype,
+                          bits: Optional[int]):
+    """The seeded tree of a NemotronHConfig. Leaves are stacked per KIND
+    of block: `norm` [L, D] (every block has one), the Mamba leaves
+    [L_M, ...], the attention leaves [L_attn, ...], the router's, the
+    latent projections', the experts' and the shared expert's
+    [L_E, ...]. `A_log`, `dt_bias` and `D` take the published init
+    (A = U(1, 16); dt log-uniform in [time_step_min, time_step_max],
+    floored at time_step_floor, through softplus's inverse; D = 1), so
+    that seeded states decay over 1-1000 tokens and neither vanish nor
+    blow up; they, the conv, the norms and the router's bias stay float
+    under bits=8. The conv's weight is stored [K, channels] (the
+    published layout is [channels, 1, K])."""
+    c = config
+    L, D = c.num_hidden_layers, c.hidden_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    Lm, La, Le = (len(c.mamba_layers), len(c.attn_layers),
+                  len(c.sparse_layers))
+    Hm, di, K = c.mamba_num_heads, c.d_inner, c.conv_kernel
+    E, Et, R = c.num_local_experts, c.n_routed_experts_total, \
+        c.moe_latent_size
+    Fe, Fs = c.moe_intermediate_size, c.moe_shared_expert_intermediate_size
+    w, mat, keys = _draws(rng, dtype, bits, 40)
+
+    def near(shape, centre, spread=0.1):
+        return (centre + spread * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (Lm, Hm), jnp.float32, np.log(c.time_step_min),
+        np.log(c.time_step_max)))
+    dt = jnp.maximum(dt, c.time_step_floor)
+    blocks = {
+        "norm": near((L, D), 1.0),
+        "w_in": mat("w_in", (Lm, D, c.in_proj_dim), D),
+        "conv_w": w((Lm, K, c.conv_dim), K),
+        "conv_b": near((Lm, c.conv_dim), 0.0),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(
+            next(keys), (Lm, Hm), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((Lm, Hm), jnp.float32),
+        "ssm_norm": near((Lm, di), 1.0),
+        "w_out": mat("w_out", (Lm, di, D), di),
+        "wq": mat("wq", (La, D, H * hd), D),
+        "wk": mat("wk", (La, D, KV * hd), D),
+        "wv": mat("wv", (La, D, KV * hd), D),
+        "wo": mat("wo", (La, H * hd, D), H * hd),
+        "router": w((Le, D, Et), D),
+        "router_bias": 0.05 * jax.random.normal(
+            next(keys), (Le, Et), jnp.float32),
+        "w_fc1": mat("w_fc1", (Le, D, R), D),
+        "w_fc2": mat("w_fc2", (Le, R, D), R),
+        "we_up": mat("we_up", (Le, E, R, Fe), R),
+        "we_down": mat("we_down", (Le, E, Fe, R), Fe),
+        "ws_up": mat("ws_up", (Le, D, Fs), D),
+        "ws_down": mat("ws_down", (Le, Fs, D), Fs),
+    }
+    return {
+        "embed": w((c.vocab_size, D), D),
+        "blocks": blocks,
+        "final_norm": near((D,), 1.0),
+        "lm_head": mat("lm_head", (D, c.vocab_size), D),
+    }
+
+
 def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
                 bits: Optional[int] = None):
     """Random-init MoE parameter pytree (tests, benchmarks, a model
@@ -136,6 +201,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
     sees them."""
     if getattr(config, "kv_lora_rank", None):
         return _init_glm_params(config, rng, dtype, bits)
+    if getattr(config, "mamba_layers", None):
+        return _init_nemotron_params(config, rng, dtype, bits)
     c = config
     L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
     E = c.num_local_experts

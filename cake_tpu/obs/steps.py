@@ -229,6 +229,40 @@ DSA_COUNTERS = (
         "summed over dispatches")),
 )
 STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS
+# a step program of a model with recurrent blocks (nemotron_h:
+# models/moe/nemotron_h.trunk) returns the expert counters' five, the
+# routed rows, then these: the rows' recurrent state and the two forms
+# of the scan
+SSM_COUNTERS = (
+    ("ssm_state_rows", _m.counter(
+        "cake_ssm_state_rows_total",
+        "Rows whose recurrent state a step read and wrote, summed over "
+        "Mamba blocks (a row with no token in a dispatch costs none)")),
+    ("ssm_tokens_scanned", _m.counter(
+        "cake_ssm_tokens_scanned_total",
+        "Tokens through the chunked scan (a prompt's windows), summed "
+        "over Mamba blocks")),
+    ("ssm_tokens_stepped", _m.counter(
+        "cake_ssm_tokens_stepped_total",
+        "Tokens through the one-step recurrence (a row's single "
+        "token), summed over Mamba blocks")),
+    ("ssm_state_resets", _m.counter(
+        "cake_ssm_state_resets_total",
+        "Rows whose recurrent state a step zeroed: a request took the "
+        "slot")),
+)
+SSM_LAYOUT = MOE_COUNTERS + DSA_COUNTERS[:1] + SSM_COUNTERS
+SSM_STATE_BYTES = _m.gauge(
+    "cake_ssm_state_bytes",
+    "Bytes of the rows' recurrent state beside the page pool (SSM state "
+    "and conv tails, every Mamba block, every slot)")
+
+
+def counter_layout(n: int) -> tuple:
+    """The (record key, series) of a step program's counter vector, by
+    its length: a sparse model's five, a glm_moe_dsa model's eleven, a
+    nemotron_h model's ten."""
+    return SSM_LAYOUT if n == len(SSM_LAYOUT) else STEP_COUNTERS[:n]
 
 
 def refresh_page_gauges(engine) -> None:
@@ -482,8 +516,8 @@ class StepRecord:
     # on-device carry while that step was still in flight
     chained: Optional[bool] = None
     # the step programs' counters since the previous record that
-    # carried them, in the order of STEP_COUNTERS (a sparse model's
-    # five, a glm_moe_dsa model's eleven)
+    # carried them, in the order of counter_layout (a sparse model's
+    # five, a glm_moe_dsa model's eleven, a nemotron_h model's ten)
     moe: Optional[Tuple[float, ...]] = None
 
     def to_dict(self) -> Dict:
@@ -526,7 +560,8 @@ class StepRecord:
         if self.chained is not None:
             out["chained"] = self.chained
         if self.moe is not None:
-            for (key, _series), v in zip(STEP_COUNTERS, self.moe):
+            for (key, _series), v in zip(counter_layout(len(self.moe)),
+                                         self.moe):
                 out[key] = round(v, 3)
         return out
 
@@ -768,7 +803,8 @@ class StepTelemetry:
             _MIXED_TOKENS.inc(tokens_real)
             _MIXED_TOKENS_COMPUTED.inc(tokens_computed)
         if moe is not None:
-            for (_key, series), v in zip(STEP_COUNTERS, rec.moe):
+            for (_key, series), v in zip(counter_layout(len(rec.moe)),
+                                         rec.moe):
                 series.inc(v)
         if mfu is not None:
             _STEP_MFU.labels(kind=kind).set(_sig(mfu))

@@ -330,9 +330,19 @@ def _stacked(leaf):
     return jax.tree.map(lambda a: a[None], leaf), jnp.int32(0)
 
 
-def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
-    """The routed SwiGLU on tokens x [N, D] -> (out [N, D] f32, plan).
-    weights/experts/valid: [N, k]; stacks: the three stacked leaves."""
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+ACTIVATIONS = {"silu": jax.nn.silu, "relu2": relu2}
+
+
+def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int,
+                 act: str = "silu"):
+    """The routed experts on tokens x [N, D] -> (out [N, D] f32, plan).
+    weights/experts/valid: [N, k]; stacks: the stacked leaves (gate or
+    None, up, down): with a gate act(gate) * up (SwiGLU), without one
+    act(up)."""
     N, D = x.shape
     k = experts.shape[1]
     w_gate, w_up, w_down = stacks
@@ -342,10 +352,14 @@ def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
     with jax.named_scope("experts"):
         walk = (plan.visit_tile, plan.visit_expert, plan.visit_lo,
                 plan.visit_hi)
-        gate = grouped_matmul(xs, w_gate, layer, *walk, tm=plan.tm)
+        # (the gated form's calls in the order they always had: the
+        # compiler's schedule follows it)
+        if w_gate is not None:
+            gate = grouped_matmul(xs, w_gate, layer, *walk, tm=plan.tm)
         up = grouped_matmul(xs, w_up, layer, *walk, tm=plan.tm)
-        act = jax.nn.silu(gate) * up
-        ys = grouped_matmul(act, w_down, layer, *walk, tm=plan.tm)
+        hidden = (ACTIVATIONS[act](up) if w_gate is None
+                  else ACTIVATIONS[act](gate) * up)
+        ys = grouped_matmul(hidden, w_down, layer, *walk, tm=plan.tm)
     with jax.named_scope("moe_combine"):
         picked = jnp.take(ys, plan.slot_of.reshape(N * k), axis=0)
         wk = jnp.where(plan.valid, weights, 0.0)             # [N, k]
@@ -360,14 +374,24 @@ def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int):
 def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             ep_axis: Optional[str] = None, token_mask=None,
             first_expert: Optional[int] = None, scoring: str = "softmax",
-            scale: float = 1.0):
-    """Sparse SwiGLU FFN over experts -> (out [B, S, D], MoEStats).
+            scale: float = 1.0, act: str = "silu"):
+    """Sparse FFN over experts -> (out [B, S, D], MoEStats).
 
     lp leaves: router [D, E]; we_gate/we_up [E_local, D, F]; we_down
     [E_local, F, D], each an array, a per-channel QTensor, or a LayerOf
     around the stacked leaf; optionally router_bias [E] (the choice's
     bias, `route`) and ws_gate/ws_up/ws_down, a shared expert every
-    token takes. norm_topk_prob, scoring, scale: the family's rule
+    token takes. What an expert computes is data of the leaves and of
+    `act` ("silu" | "relu2"): with `we_gate` (and `ws_gate`) the gated
+    act(gate) * up (SwiGLU: Mixtral, OLMoE, GLM), without them
+    act(up) (nemotron_h: relu², no gate). With `w_fc1` [D, R] / `w_fc2`
+    [R, D] the routed experts live in an R-wide LATENT (we_up [E, R, F],
+    we_down [E, F, R]): every token is projected down once, the shares'
+    sums meet in the latent (the psum under `ep_axis`) and are projected
+    back once (without `w_fc2` the result stays in the latent: one
+    share's part of the sum); the router and the shared expert read h
+    itself.
+    norm_topk_prob, scoring, scale: the family's rule
     (`route`). E_local == E except where the layer holds a share: under
     shard_map EP each shard holds its contiguous slice and `ep_axis`
     names the mesh axis; on one chip `first_expert` (static) is the
@@ -385,9 +409,16 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
                                  scoring, scale, lp.get("router_bias"))
         routed = experts
 
-    w_gate, layer = _stacked(lp["we_gate"])
-    stacks = (w_gate, _stacked(lp["we_up"])[0], _stacked(lp["we_down"])[0])
-    e_local = w_gate.shape[1]
+    from cake_tpu.ops.quant import qmatmul
+
+    w_up, layer = _stacked(lp["we_up"])
+    stacks = (_stacked(lp["we_gate"])[0] if "we_gate" in lp else None,
+              w_up, _stacked(lp["we_down"])[0])
+    e_local = getattr(w_up, "q", w_up).shape[1]
+    x_in = x
+    if "w_fc1" in lp:
+        with jax.named_scope("moe_latent"):
+            x_in = qmatmul(x, lp["w_fc1"])
     mask = None if token_mask is None else token_mask.reshape(N)
     valid = None if mask is None else jnp.broadcast_to(mask[:, None], (N, k))
     rows_routed = None
@@ -401,18 +432,24 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
         valid = here if valid is None else valid & here
         experts = jnp.clip(experts, 0, e_local - 1)
 
-    out, plan = _experts_ffn(x, weights, experts, valid, stacks, layer,
-                             e_local)
+    out, plan = _experts_ffn(x_in, weights, experts, valid, stacks, layer,
+                             e_local, act)
     stats = plan_stats(plan, routed, rows_routed)
     if mask is not None:
         out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:
         out = lax.psum(out, ep_axis)
-    if "ws_gate" in lp:
+    if "w_fc2" in lp:
+        with jax.named_scope("moe_latent"):
+            out = qmatmul(out.astype(h.dtype),
+                          lp["w_fc2"]).astype(jnp.float32)
+    if "ws_up" in lp:
         # every shard computes it alike: added once, after the sum
-        from cake_tpu.ops.quant import qmatmul
         with jax.named_scope("shared_expert"):
-            gate = jax.nn.silu(qmatmul(x, lp["ws_gate"]))
-            out = out + qmatmul(gate * qmatmul(x, lp["ws_up"]),
-                                lp["ws_down"]).astype(jnp.float32)
-    return out.reshape(B, S, D).astype(h.dtype), stats
+            if "ws_gate" in lp:
+                gate = ACTIVATIONS[act](qmatmul(x, lp["ws_gate"]))
+                hidden = gate * qmatmul(x, lp["ws_up"])
+            else:
+                hidden = ACTIVATIONS[act](qmatmul(x, lp["ws_up"]))
+            out = out + qmatmul(hidden, lp["ws_down"]).astype(jnp.float32)
+    return out.reshape(B, S, -1).astype(h.dtype), stats

@@ -712,6 +712,48 @@ class InferenceEngine:
                     "model_type glm_moe_dsa (latent attention over the "
                     "page pool) does not serve yet: " + "; ".join(refused)
                     + " (ROADMAP.md lists each as left to do)")
+        # recurrent blocks (nemotron_h): a state a ROW beside the page
+        # pool (models/llama/paged.HybridPagedCache), carried by the
+        # step programs. What moves pages today and cannot move a state
+        # yet is refused here, by the option's name, never ignored.
+        self._recurrent = bool(getattr(config, "mamba_layers", None))
+        if self._recurrent:
+            refused = [name for name, on in (
+                ("serving without --kv-pages (the dense-slot engine)",
+                 not self.paged),
+                ("a topology / --tp / --sp (pipeline and tensor "
+                 "parallelism)", step_fns is not None),
+                ("--draft-model (a rejected draft would have to roll "
+                 "the state back)", self._spec),
+                ("--spec-draft (a rejected draft would have to roll "
+                 "the state back)", self._spec_paged),
+                ("--kv-dtype int8/int4 (quantized pages)", self.kv_quant),
+                ("--kv-host-pages (host spill and preempt-and-restore: "
+                 "a state has no pages to spill)",
+                 kv_host_pages is not None),
+                ("--disagg (the prefill shipment carries pages, not a "
+                 "state)", disagg is not None),
+                ("--auto-prefix (prefix pages: a shared head has no "
+                 "state to map)", auto_prefix_system),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    "model_type nemotron_h (a recurrent state a row "
+                    "beside the page pool) does not serve yet: "
+                    + "; ".join(refused)
+                    + " (ROADMAP.md lists each as left to do)")
+        # one window (a row of several tokens) a mixed dispatch
+        self._one_window = self._latent or self._recurrent
+        # ... and, beside a recurrent state, one window a STEP: the
+        # prompts mid-prefill take their windows in the order they were
+        # admitted, one a step, so every step is ONE dispatch that
+        # every decode row rides. With a window a dispatch and every
+        # prompt in every step, a step of k prompts is k dispatches of
+        # which a decode row rides one: rows admitted together stayed
+        # together, and the cell's 32 callers moved in convoys of
+        # seconds of prefill, then seconds of decode (PERF.md §6, PR
+        # 33). The latent pool keeps its steps as they were measured.
+        self._window_a_step = self._recurrent
         if self.paged:
             if step_fns is not None or self.ring or self._spec:
                 raise ValueError(
@@ -1687,6 +1729,11 @@ class InferenceEngine:
                           "prefix pages yet: a shared head would need "
                           "its latent rows and its index keys mapped "
                           "together (ROADMAP.md)")
+            elif self._recurrent:
+                reason = ("a recurrent state (nemotron_h) has no prefix "
+                          "reuse yet: a shared head would need the "
+                          "state snapshotted at its last page's edge "
+                          "(ROADMAP.md)")
             elif self.ring:
                 reason = ("ring sliding-window caches own their layout "
                           "(a prefix install writes dense positions the "
@@ -2666,6 +2713,18 @@ class InferenceEngine:
             # no prefix pages, no whole-prompt prefill program
             self._prefix_capable = False
             self._prefill_slot = self._prefix_pages_step = None
+        if self._recurrent:
+            # the hybrid step programs, behind the same signatures; one
+            # window a dispatch, so one packed size (models/moe/nemotron_h)
+            from cake_tpu.models.moe.nemotron_h import decode_step_hybrid
+            self._decode_step = partial(decode_step_hybrid, attn=impl)
+            self._decode_scan_impl = (_decode_scan_hybrid if impl == "fold"
+                                      else _decode_scan_hybrid_pallas)
+            self._mixed_step_fn = partial(_mixed_sampled_hybrid, attn=impl)
+            self._mixed_buckets = mixed_token_buckets(
+                self.max_slots, self._mixed_chunk, prefill_rows=(1,))
+            self._prefix_capable = False
+            self._prefill_slot = self._prefix_pages_step = None
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -2685,6 +2744,12 @@ class InferenceEngine:
             self.cache = PagedKVCache.create(
                 self.config, self.max_slots, kv_pages, kv_page_size,
                 self.max_seq_len, dtype=pool_dtype)
+        if self._recurrent:
+            from cake_tpu.obs.steps import SSM_STATE_BYTES
+            SSM_STATE_BYTES.set(self.cache.state_bytes())
+            log.info("recurrent state: %d Mamba blocks x %d rows, %.2f GiB "
+                     "beside the pool", len(self.config.mamba_layers),
+                     self.max_slots, self.cache.state_bytes() / 2**30)
         log.info("paged KV: %d pages x %d tokens, %s attention, "
                  "%s storage (%.2f GiB pool; dense %d-slot "
                  "equivalent would be %.2f GiB)",
@@ -2784,6 +2849,48 @@ class InferenceEngine:
             log.info("latent paged attention: requested %s -> dsa-%s "
                      "(mixed width %d)", requested or "auto", impl, width)
             return
+        if self._recurrent:
+            # one impl for both step programs: the mixed program runs
+            # the decode kernel over the rows' single tokens and the
+            # mixed kernel over the window in sub-windows (what its
+            # VMEM holds), so its gate is asked at the sub-window
+            from cake_tpu.models.moe.nemotron_h import ATTN_SUBWINDOW
+            c = self.config
+            width = self.prefill_chunk or min(512, self.max_seq_len)
+            if width > ATTN_SUBWINDOW and width % ATTN_SUBWINDOW:
+                raise ValueError(
+                    f"--prefill-chunk {width}: model_type nemotron_h "
+                    f"takes a window of at most {ATTN_SUBWINDOW} tokens "
+                    f"or a multiple of {ATTN_SUBWINDOW}")
+            heads = (c.num_attention_heads, c.num_key_value_heads,
+                     c.head_dim)
+            max_pages = -(-self.max_seq_len // kv_page_size)
+            ok = (rpa.ragged_paged_supported(
+                      kv_page_size, *heads, n_pages=kv_pages,
+                      slots=self.max_slots, max_pages=max_pages)
+                  and rpa.ragged_paged_mixed_supported(
+                      kv_page_size, *heads, min(width, ATTN_SUBWINDOW),
+                      n_pages=kv_pages,
+                      slots=-(-width // ATTN_SUBWINDOW),
+                      max_pages=max_pages,
+                      q_itemsize=jnp.dtype(
+                          self.params["embed"].dtype).itemsize,
+                      kv_itemsize=jnp.dtype(self._pool_dtype).itemsize))
+            if impl == "pallas" and not ok:
+                if requested == "pallas":
+                    raise ValueError(
+                        f"--paged-attn pallas cannot serve model_type "
+                        f"nemotron_h on this device at page="
+                        f"{kv_page_size} heads={heads} mixed width="
+                        f"{width} (ops/ragged_paged_attention gates); "
+                        "use --paged-attn auto or fold")
+                impl = "fold"
+            self.paged_attn = impl
+            self._mixed_chunk = width
+            self.attn_impl = {"decode": impl, "mixed": impl}
+            log.info("hybrid paged attention: requested %s -> ssm-%s "
+                     "(mixed width %d)", requested or "auto", impl, width)
+            return
         packed4 = self._kv_dtype_name == "int4"
         pool_dtype = self._pool_dtype
         kw = dict(quantized=self.kv_quant, n_pages=kv_pages,
@@ -2844,8 +2951,9 @@ class InferenceEngine:
         flight record (None = the recorder's engine-wide flavor)."""
         if not self.paged:
             return None
-        return ("paged-dsa-" if self._latent else "paged-") \
-            + self.attn_impl.get(kind, self.attn_impl["decode"])
+        flavor = ("paged-dsa-" if self._latent
+                  else "paged-ssm-" if self._recurrent else "paged-")
+        return flavor + self.attn_impl.get(kind, self.attn_impl["decode"])
 
     def _capture_cache_identity(self) -> None:
         """Record the cache's placement/dtype so post-error and
@@ -2870,13 +2978,16 @@ class InferenceEngine:
     def _reconfig_supported(self) -> bool:
         return (not self._custom_steps and not self.ring
                 and not self._spec and not self._spec_paged
-                and not self._multihost and not self._latent)
+                and not self._multihost and not self._one_window)
 
     def _reconfig_refusal(self) -> str:
         if self._latent:
             return ("the latent page pool (glm_moe_dsa) serves on pages "
                     "only: there is no dense or quantized pool to "
                     "switch to")
+        if self._recurrent:
+            return ("a recurrent state (nemotron_h) lives beside the "
+                    "page pool: a rebuilt pool cannot replay it")
         if self._spec:
             return ("speculative serving has no hot-switch fold (the "
                     "draft cache cannot be rebuilt mid-round)")
@@ -4588,12 +4699,12 @@ class InferenceEngine:
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
         order, as many as the largest packed size holds. One group
-        unless three rows or more prefill at once (latent attention:
-        two or more)."""
+        unless three rows or more prefill at once (latent attention
+        and recurrent blocks: two or more)."""
         budget = self._mixed_buckets[-1]
-        # latent attention: one window (a row of several tokens) a
-        # dispatch, whatever the budget holds
-        windows = 1 if self._latent else len(qlen)
+        # latent attention, recurrent blocks: one window (a row of
+        # several tokens) a dispatch, whatever the budget holds
+        windows = 1 if self._one_window else len(qlen)
         groups, used, wide = [np.zeros(len(qlen), bool)], 0, 0
         for slot in np.flatnonzero(qlen):
             if (used + qlen[slot] > budget
@@ -4733,7 +4844,10 @@ class InferenceEngine:
                     decode_rows.append(slot)
                 chunk_rows: List[int] = []
                 finished: List[tuple] = []
-                for slot in sorted(pending):
+                # (a dict keeps insertion order: the first key is the
+                # prompt admitted first)
+                for slot in ([next(iter(pending))] if self._window_a_step
+                             else sorted(pending)):
                     p = pending[slot]
                     ids, off = p["ids"], p["off"]
                     n = min(C, len(ids) - off)
@@ -6578,3 +6692,31 @@ def _latent_forward_ragged_pallas(params, tokens, cache, pos, active,
 
 
 _decode_scan_latent_pallas = make_decode_scan(_latent_forward_ragged_pallas)
+
+
+def _mixed_step_hybrid(*args, **kw):
+    from cake_tpu.models.moe.nemotron_h import mixed_step_hybrid
+    return mixed_step_hybrid(*args, **kw)
+
+
+_mixed_sampled_hybrid = make_mixed_sampled(_mixed_step_hybrid)
+
+
+def _hybrid_forward_ragged(params, tokens, cache, pos, active, rope,
+                           config):
+    from cake_tpu.models.moe.nemotron_h import forward_ragged_hybrid
+    return forward_ragged_hybrid(params, tokens, cache, pos, active, rope,
+                                 config)
+
+
+_decode_scan_hybrid = make_decode_scan(_hybrid_forward_ragged)
+
+
+def _hybrid_forward_ragged_pallas(params, tokens, cache, pos, active,
+                                  rope, config):
+    from cake_tpu.models.moe.nemotron_h import forward_ragged_hybrid
+    return forward_ragged_hybrid(params, tokens, cache, pos, active, rope,
+                                 config, attn="pallas")
+
+
+_decode_scan_hybrid_pallas = make_decode_scan(_hybrid_forward_ragged_pallas)
