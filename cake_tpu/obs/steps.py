@@ -172,6 +172,17 @@ _MIXED_TOKENS_COMPUTED = _m.counter(
     "Token positions the mixed steps' layers ran over: the packed sizes "
     "of each step's dispatches (over cake_mixed_tokens_total: how well "
     "the sizes fit the traffic)")
+_MIXED_ATTN_TILES = _m.counter(
+    "cake_mixed_attn_q_tiles_total",
+    "Query tiles the mixed attention kernel folded a live page into, "
+    "summed over the mixed steps' active rows: one for a row whose real "
+    "queries lie in its first tile (a decode row), the window's for any "
+    "other (ops/ragged_paged_attention.mixed_q_tiles)")
+_MIXED_ATTN_TILES_WINDOW = _m.counter(
+    "cake_mixed_attn_q_tiles_window_total",
+    "Query tiles in the windows of the mixed steps' active rows: what a "
+    "kernel that folded every row's whole window would fold (over it, "
+    "cake_mixed_attn_q_tiles_total is the share the kernel does)")
 # sparse-expert counters, computed in the step program from the group
 # sizes its grouped matmuls walk (ops/moe.MoEStats, summed or averaged
 # over the layers by paged.scan_layers_paged_stats) and fetched with the
@@ -500,6 +511,11 @@ class StepRecord:
     # packed sizes of its dispatches, summed)
     tokens_real: Optional[int] = None
     tokens_computed: Optional[int] = None
+    # a mixed step whose rows go through the mixed attention kernel:
+    # the query tiles it folds for the step's active rows, and the
+    # tiles of their whole windows
+    attn_q_tiles: Optional[int] = None
+    attn_q_tiles_window: Optional[int] = None
     # rids whose rows this step's dispatched batch contained (bounded
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
@@ -550,6 +566,9 @@ class StepRecord:
         if self.tokens_computed is not None:
             out["tokens_real"] = self.tokens_real
             out["tokens_computed"] = self.tokens_computed
+        if self.attn_q_tiles is not None:
+            out["attn_q_tiles"] = self.attn_q_tiles
+            out["attn_q_tiles_window"] = self.attn_q_tiles_window
         if self.rids is not None:
             out["rids"] = list(self.rids)
         if self.phases:
@@ -735,6 +754,8 @@ class StepTelemetry:
                rows_idle: Optional[int] = None,
                tokens_real: Optional[int] = None,
                tokens_computed: Optional[int] = None,
+               attn_q_tiles: Optional[int] = None,
+               attn_q_tiles_window: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None,
@@ -746,8 +767,11 @@ class StepTelemetry:
         occupancy split and feed the cake_mixed_step_rows_total
         counters; tokens_real/tokens_computed its tokens and the
         positions its layers ran over (cake_mixed_tokens_total,
-        cake_mixed_tokens_computed_total). rids: the requests whose
-        rows rode this dispatch (the per-request explain's step linkage). impl: the attention
+        cake_mixed_tokens_computed_total); attn_q_tiles /
+        attn_q_tiles_window the query tiles its attention kernel folds
+        and those of its rows' whole windows
+        (cake_mixed_attn_q_tiles_total, ..._window_total). rids: the
+        requests whose rows rode this dispatch (the per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
         step program's sparse-expert counters (StepRecord.moe).
@@ -784,6 +808,8 @@ class StepTelemetry:
                 rows_decode=rows_decode, rows_prefill=rows_prefill,
                 rows_idle=rows_idle,
                 tokens_real=tokens_real, tokens_computed=tokens_computed,
+                attn_q_tiles=attn_q_tiles,
+                attn_q_tiles_window=attn_q_tiles_window,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
@@ -802,6 +828,9 @@ class StepTelemetry:
         if tokens_computed is not None:
             _MIXED_TOKENS.inc(tokens_real)
             _MIXED_TOKENS_COMPUTED.inc(tokens_computed)
+        if attn_q_tiles is not None:
+            _MIXED_ATTN_TILES.inc(attn_q_tiles)
+            _MIXED_ATTN_TILES_WINDOW.inc(attn_q_tiles_window)
         if moe is not None:
             for (_key, series), v in zip(counter_layout(len(rec.moe)),
                                          rec.moe):

@@ -48,7 +48,9 @@ metadata with a per-row query length: one grid processes decode rows
 (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page offset)
 in the same launch — the token-level continuous-batching step the
 engine's `mixed_step_paged` path dispatches, with per-row causal
-masking and the same per-row early exit.
+masking and the same per-row early exit. Its work in a (row, page)
+cell follows q_len too: a decode row folds its one query, not its
+window (`_mixed_fold`, MIXED_Q_TILE).
 
 CPU tests run the same kernel with interpret=True
 (tests/test_ragged_paged_attn.py), mirroring flash_attention.py.
@@ -430,21 +432,54 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     )(*operands)
 
 
-def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_ref,
-                      o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                      page_size: int, kv_heads: int, group: int,
-                      head_dim: int, q_width: int):
+# Queries to a TILE of the mixed kernel's window. A (row, page) grid
+# cell folds the row's first tile when that holds its every real query
+# (a decode row, q_len 1) and its whole window otherwise, so a decode
+# row costs MIXED_Q_TILE*G rows of scores a page and kv head, not C*G.
+# Every slice is static, so the tile need not fill a sublane tile; on a
+# v5e a call of 14 decode rows and two windows took, at Mistral's /
+# OLMoE's shapes, 242 / 282 us at a tile of 1, 244 / 283 at 2, 260 / 283
+# at 4, 285 / 306 at 16 (698 / 410 before; PERF.md section 6, PR 34).
+MIXED_Q_TILE = 1
+
+
+def mixed_q_tiles(q_len: int, q_width: int) -> int:
+    """Query tiles the mixed kernel folds a live page into for a row of
+    q_len real queries in a window of q_width: one, or all of them.
+    The host's count of the kernel's work (obs/steps `attn_q_tiles`)
+    is this, summed over a step's active rows."""
+    return 1 if q_len <= MIXED_Q_TILE else -(-q_width // MIXED_Q_TILE)
+
+
+def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, page_kv, *, scale: float, page_size: int,
+                kv_heads: int, group: int, head_dim: int, q_width: int):
     """One (row, page) grid step of the MIXED ragged fold: each row
     carries q_width query slots of which q_len are real — a decode row
     (q_len=1) and a prefill-chunk row (q_len=C at arbitrary page
-    offset) fold through the same grid.
+    offset) fold through the same grid. The one body of the float,
+    int8 and int4 kernels: `page_kv(kv, pid)` hands it kv head `kv` of
+    the page as (kh, vh [P, hd], k_scale, v_scale), the scales None for
+    a float pool.
 
     q_ref:   [1, C, H, hd] — the row's query window, first token at
-             absolute position pos (decode rows use column 0 only)
-    k_ref/v_ref: [1, page, KV*hd] — one physical page (flattened minor)
+             absolute position pos
     scratch: acc [KV*C*G, hd] f32, m/l [KV*C*G, 128] f32, rows ordered
     (kv, query, group) so each kv head's fold is a contiguous slice;
     carried across the page axis exactly like the decode kernel.
+
+    The work follows q_len: a row whose real queries all lie in its
+    first tile (MIXED_Q_TILE queries: a decode row, an idle row) folds,
+    initialises and finishes that tile alone — scratch rows
+    [kv*C*G, +Tq*G) of each kv head — and any other row its whole
+    window, in one piece (folding a window tile by tile under a loop
+    cost a full window 1.8x the time on a v5e: each tile pays for the
+    page's K and V again). A query's online softmax runs page by page
+    in the same order either way, so its result does not depend on its
+    row's q_len, nor on the other rows'. Output columns: those of a
+    tile the row did not fold are ZERO (written at the row's first
+    page); padded columns of a folded span are what they always were,
+    the fold of a query that is not there — finite, never read.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -454,215 +489,142 @@ def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref, v_r
     G = group
     P = page_size
     hd = head_dim
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    Tq = min(MIXED_Q_TILE, C)
 
     pos = pos_ref[b]
     # last REAL query's absolute position bounds the live page count;
     # q_len=0 (idle row) clamps to pos so the row still costs one page
     # of masked compute, never a negative bound
-    last = pos + jnp.maximum(qlen_ref[b], 1) - 1
+    n_q = jnp.maximum(qlen_ref[b], 1)
+    last = pos + n_q - 1
     page = table_ref[b, j]
     live = jnp.logical_and(j * P <= last, page >= 0)
 
-    @pl.when(live)
-    def _fold():
-        q = q_ref[0]                           # [C, H, hd]
-        # per-(query, column) causal mask: query i sits at absolute
-        # position pos + i and attends page slots <= it (current token
-        # included — its KV is written before the kernel runs)
-        qidx = jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 0) // G
-        col = j * P + jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 1)
-        valid = col <= pos + qidx
-        for kv in range(kv_heads):
-            kh = k_ref[0, :, kv * hd:(kv + 1) * hd]          # [P, hd]
-            vh = v_ref[0, :, kv * hd:(kv + 1) * hd]          # [P, hd]
-            qh = q[:, kv * G:(kv + 1) * G, :].reshape(C * G, hd)
-            s = _dot(qh, kh, trans_b=True) * scale           # [C*G, P]
-            s = jnp.where(valid, s, NEG_INF)
-            r0 = kv * C * G
-            m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            # a query whose causal horizon precedes this page (or an
-            # all-hole row) has every column masked: m_new stays
-            # NEG_INF and exp(s - m_new) would be exp(0)=1 garbage —
-            # the explicit mask multiply keeps its l at 0 so _finish
-            # emits zeros, matching the fold reference's guard
-            p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-            l_new = (alpha * l_ref[r0:r0 + C * G, :1]
-                     + jnp.sum(p, axis=-1, keepdims=True))
-            out = _dot(p.astype(vh.dtype), vh,
-                       trans_b=False)                        # [C*G, hd]
-            acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
-            m_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                m_new, (C * G, m_ref.shape[1]))
-            l_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                l_new, (C * G, l_ref.shape[1]))
+    def span(body):
+        """body(nq) once, nq (static) the queries this row folds."""
+        if Tq == C:
+            body(C)
+        else:
+            pl.when(n_q <= Tq)(functools.partial(body, Tq))
+            pl.when(n_q > Tq)(functools.partial(body, C))
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        for kv in range(kv_heads):
-            r0 = kv * C * G
-            l = l_ref[r0:r0 + C * G, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o = (acc_ref[r0:r0 + C * G] / l).reshape(C, G, hd)
-            o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
+    def rows(kv, nq):
+        return slice(kv * C * G, kv * C * G + nq * G)
 
-
-def _rpa_mixed_kernel_q8(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
-                         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                         l_ref, *, scale: float, page_size: int,
-                         kv_heads: int, group: int, head_dim: int,
-                         q_width: int):
-    """int8 variant of _rpa_mixed_kernel: pages stream as int8 and the
-    per-(page, kv-head) scales prefetch into SMEM (the decode q8
-    kernel's scheme with the mixed kernel's per-row query width) —
-    dequantization folds into the score and value dot outputs, so the
-    mixed step reads a quarter of the f32 page bytes."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    C = q_width
-    G = group
-    P = page_size
-    hd = head_dim
+    def heads(kv):
+        return slice(kv * G, (kv + 1) * G)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    pos = pos_ref[b]
-    last = pos + jnp.maximum(qlen_ref[b], 1) - 1
-    page = table_ref[b, j]
-    live = jnp.logical_and(j * P <= last, page >= 0)
+        def init(nq):
+            if nq < C:
+                o_ref[0, nq:] = jnp.zeros((C - nq,) + o_ref.shape[2:],
+                                          o_ref.dtype)
+            for kv in range(kv_heads):
+                r = rows(kv, nq)
+                acc_ref[r] = jnp.zeros((nq * G, hd), jnp.float32)
+                m_ref[r] = jnp.full((nq * G, m_ref.shape[1]), NEG_INF,
+                                    jnp.float32)
+                l_ref[r] = jnp.zeros((nq * G, l_ref.shape[1]), jnp.float32)
+        span(init)
 
     @pl.when(live)
     def _fold():
-        q = q_ref[0]                           # [C, H, hd]
         pid = jnp.maximum(page, 0)
-        qidx = jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 0) // G
-        col = j * P + jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 1)
-        valid = col <= pos + qidx
-        for kv in range(kv_heads):
-            kh = k_ref[0, :, kv * hd:(kv + 1) * hd].astype(
-                jnp.float32)                                 # [P, hd]
-            vh = v_ref[0, :, kv * hd:(kv + 1) * hd].astype(
-                jnp.float32)                                 # [P, hd]
-            qh = q[:, kv * G:(kv + 1) * G, :].reshape(
-                C * G, hd).astype(jnp.float32)
-            s = _dot(qh, kh, trans_b=True) * (
-                scale * sk_ref[pid * kv_heads + kv])         # [C*G, P]
-            s = jnp.where(valid, s, NEG_INF)
-            r0 = kv * C * G
-            m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            # all-masked query rows keep l at 0 so _finish emits
-            # zeros — the mixed f32 kernel's guard, unchanged
-            p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-            l_new = (alpha * l_ref[r0:r0 + C * G, :1]
-                     + jnp.sum(p, axis=-1, keepdims=True))
-            out = (_dot(p, vh, trans_b=False)
-                   * sv_ref[pid * kv_heads + kv])
-            acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
-            m_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                m_new, (C * G, m_ref.shape[1]))
-            l_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                l_new, (C * G, l_ref.shape[1]))
+
+        def fold(nq):
+            R = nq * G
+            # per-(query, column) causal mask: query i sits at absolute
+            # position pos + i and attends page slots <= it (current
+            # token included — its KV is written before the kernel runs)
+            qidx = jax.lax.broadcasted_iota(jnp.int32, (R, P), 0) // G
+            col = j * P + jax.lax.broadcasted_iota(jnp.int32, (R, P), 1)
+            valid = col <= pos + qidx
+            for kv in range(kv_heads):
+                kh, vh, k_scale, v_scale = page_kv(kv, pid)  # [P, hd]
+                qh = q_ref[0, :nq, heads(kv), :].reshape(R, hd)
+                if k_scale is None:
+                    s = _dot(qh, kh, trans_b=True) * scale   # [R, P]
+                else:
+                    # dequantization folds into the dot outputs: one
+                    # scale covers a page's every column of a kv head
+                    s = _dot(qh.astype(jnp.float32), kh,
+                             trans_b=True) * (scale * k_scale)
+                s = jnp.where(valid, s, NEG_INF)
+                r = rows(kv, nq)
+                m_prev = m_ref[r, :1]                        # [R, 1]
+                m_cur = jnp.max(s, axis=-1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_cur)
+                alpha = jnp.exp(m_prev - m_new)
+                # a query whose causal horizon precedes this page (or an
+                # all-hole row) has every column masked: m_new stays
+                # NEG_INF and exp(s - m_new) would be exp(0)=1 garbage —
+                # the explicit mask multiply keeps its l at 0 so _finish
+                # emits zeros, matching the fold reference's guard
+                p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+                l_new = (alpha * l_ref[r, :1]
+                         + jnp.sum(p, axis=-1, keepdims=True))
+                out = _dot(p.astype(vh.dtype), vh,
+                           trans_b=False)                    # [R, hd]
+                if v_scale is not None:
+                    out = out * v_scale
+                acc_ref[r] = acc_ref[r] * alpha + out
+                m_ref[r] = jnp.broadcast_to(m_new, (R, m_ref.shape[1]))
+                l_ref[r] = jnp.broadcast_to(l_new, (R, l_ref.shape[1]))
+        span(fold)
 
     @pl.when(j == nj - 1)
     def _finish():
-        for kv in range(kv_heads):
-            r0 = kv * C * G
-            l = l_ref[r0:r0 + C * G, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o = (acc_ref[r0:r0 + C * G] / l).reshape(C, G, hd)
-            o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
+        def finish(nq):
+            for kv in range(kv_heads):
+                r = rows(kv, nq)
+                l = l_ref[r, :1]
+                l = jnp.where(l == 0.0, 1.0, l)
+                o = (acc_ref[r] / l).reshape(nq, G, hd)
+                o_ref[0, :nq, heads(kv), :] = o.astype(o_ref.dtype)
+        span(finish)
 
 
-def _rpa_mixed_kernel_q4(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref, sv_ref,
-                         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                         l_ref, *, scale: float, page_size: int,
-                         kv_heads: int, group: int, head_dim: int,
-                         q_width: int):
-    """int4 variant of _rpa_mixed_kernel_q8: pages stream nibble-PACKED
-    (an eighth of the f32 page bytes) and unpack in registers per kv
-    head; scales prefetch into SMEM and fold into the dot outputs.
-    page_size is REAL tokens — the packed block holds page_size//2
-    sublanes."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    C = q_width
-    G = group
-    P = page_size
+def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref,
+                      v_ref, o_ref, acc_ref, m_ref, l_ref, *, head_dim: int,
+                      **shape):
+    """The mixed kernel over a float pool: k_ref/v_ref [1, page, KV*hd],
+    one physical page, a kv head a lane slice."""
     hd = head_dim
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def page_kv(kv, pid):
+        lanes = slice(kv * hd, (kv + 1) * hd)
+        return k_ref[0, :, lanes], v_ref[0, :, lanes], None, None
 
-    pos = pos_ref[b]
-    last = pos + jnp.maximum(qlen_ref[b], 1) - 1
-    page = table_ref[b, j]
-    live = jnp.logical_and(j * P <= last, page >= 0)
+    _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, page_kv, head_dim=hd, **shape)
 
-    @pl.when(live)
-    def _fold():
-        q = q_ref[0]                           # [C, H, hd]
-        pid = jnp.maximum(page, 0)
-        qidx = jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 0) // G
-        col = j * P + jax.lax.broadcasted_iota(jnp.int32, (C * G, P), 1)
-        valid = col <= pos + qidx
-        for kv in range(kv_heads):
-            kh = _unpack_nibbles(k_ref[0],
-                                 slice(kv * hd, (kv + 1) * hd))  # [P, hd]
-            vh = _unpack_nibbles(v_ref[0],
-                                 slice(kv * hd, (kv + 1) * hd))  # [P, hd]
-            qh = q[:, kv * G:(kv + 1) * G, :].reshape(
-                C * G, hd).astype(jnp.float32)
-            s = _dot(qh, kh, trans_b=True) * (
-                scale * sk_ref[pid * kv_heads + kv])         # [C*G, P]
-            s = jnp.where(valid, s, NEG_INF)
-            r0 = kv * C * G
-            m_prev = m_ref[r0:r0 + C * G, :1]                # [C*G, 1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            # all-masked query rows keep l at 0 so _finish emits
-            # zeros — the mixed f32 kernel's guard, unchanged
-            p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-            l_new = (alpha * l_ref[r0:r0 + C * G, :1]
-                     + jnp.sum(p, axis=-1, keepdims=True))
-            out = (_dot(p, vh, trans_b=False)
-                   * sv_ref[pid * kv_heads + kv])
-            acc_ref[r0:r0 + C * G] = acc_ref[r0:r0 + C * G] * alpha + out
-            m_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                m_new, (C * G, m_ref.shape[1]))
-            l_ref[r0:r0 + C * G] = jnp.broadcast_to(
-                l_new, (C * G, l_ref.shape[1]))
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        for kv in range(kv_heads):
-            r0 = kv * C * G
-            l = l_ref[r0:r0 + C * G, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o = (acc_ref[r0:r0 + C * G] / l).reshape(C, G, hd)
-            o_ref[0, :, kv * G:(kv + 1) * G, :] = o.astype(o_ref.dtype)
+def _rpa_mixed_kernel_q(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref,
+                        sv_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                        l_ref, *, packed4: bool, kv_heads: int,
+                        head_dim: int, **shape):
+    """The mixed kernel over a quantized pool: pages stream as int8 (a
+    quarter of the f32 page bytes) or nibble-PACKED int4 (an eighth;
+    the block holds page_size//2 sublanes and unpacks in registers per
+    kv head), and the per-(page, kv-head) scales prefetch into SMEM —
+    the decode q8/q4 kernels' scheme with the mixed kernel's per-row
+    query width."""
+    hd = head_dim
+
+    def page_kv(kv, pid):
+        lanes = slice(kv * hd, (kv + 1) * hd)
+        if packed4:
+            kh = _unpack_nibbles(k_ref[0], lanes)
+            vh = _unpack_nibbles(v_ref[0], lanes)
+        else:
+            kh = k_ref[0, :, lanes].astype(jnp.float32)
+            vh = v_ref[0, :, lanes].astype(jnp.float32)
+        at = pid * kv_heads + kv
+        return kh, vh, sk_ref[at], sv_ref[at]
+
+    _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, page_kv, kv_heads=kv_heads, head_dim=hd, **shape)
 
 
 def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
@@ -682,8 +644,11 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
     q:            [B, C, H, hd] — rope applied; every real query
                   token's KV must already be written to its page (the
                   write_windows_pages contract). Columns past q_len are
-                  padding: their output is garbage the caller never
-                  reads (the step fn samples at column q_len - 1).
+                  padding the caller never reads (the step fn samples
+                  at column q_len - 1); their output is finite: zero
+                  past the first tile of a row that folds no more
+                  (_mixed_fold), else the fold of a query that is not
+                  there.
     pool_k/pool_v:[L, N_pages, page, KV*hd] — the stacked pool, as
                   stored; only pages of `layer` are read
     layer:        int32 scalar (traced: the layer loop's counter)
@@ -734,10 +699,9 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
         return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
 
     if quantized:
-        kern_fn = _rpa_mixed_kernel_q4 if packed4 else _rpa_mixed_kernel_q8
         kernel = functools.partial(
-            kern_fn, scale=scale, page_size=P, kv_heads=KV,
-            group=G, head_dim=hd, q_width=C)
+            _rpa_mixed_kernel_q, packed4=packed4, scale=scale, page_size=P,
+            kv_heads=KV, group=G, head_dim=hd, q_width=C)
         n_prefetch = 6
         operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),

@@ -4696,6 +4696,22 @@ class InferenceEngine:
         self._last_jit = js
         return (nxt, lp, tids, tlps, moe), carry
 
+    def _attn_q_tiles(self, qlen, rows: List[int]) -> dict:
+        """A mixed step's attn_q_tiles / attn_q_tiles_window (obs/steps):
+        the query tiles the mixed attention kernel folds for the
+        step's active rows, counted from their q_len as the kernel
+        does, and the tiles of their whole windows. Nothing where the
+        rows do not go through that kernel as they are (latent
+        attention; recurrent blocks, whose program hands it the
+        window alone, in sub-windows)."""
+        if self._latent or self._recurrent:
+            return {}
+        from cake_tpu.ops.ragged_paged_attention import mixed_q_tiles
+        C = self._mixed_chunk
+        return {"attn_q_tiles": sum(mixed_q_tiles(int(qlen[slot]), C)
+                                    for slot in rows),
+                "attn_q_tiles_window": len(rows) * mixed_q_tiles(C, C)}
+
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
         order, as many as the largest packed size holds. One group
@@ -4863,6 +4879,7 @@ class InferenceEngine:
                 groups = self._mixed_groups(qlen)
                 rids = planned + [pending[slot]["req"].rid
                                   for slot in chunk_rows]
+                tiles = self._attn_q_tiles(qlen, decode_rows + chunk_rows)
             with span("dispatch"):
                 # every layer runs over the step's tokens packed out of
                 # their windows (paged.mixed_step_paged), at the smallest
@@ -4900,12 +4917,13 @@ class InferenceEngine:
             # from the second step on the planner would list every row
             planned[:] = [rid for rid, _slot in plan]
             devs = (outs, decode_rows, chunk_rows, finished, sampled, rids,
-                    int(qlen.sum()), computed, t_start, disp, js, chained)
+                    int(qlen.sum()), computed, tiles, t_start, disp, js,
+                    chained)
             return partial(complete, devs), state
 
         def complete(devs):
             (outs, decode_rows, chunk_rows, finished, sampled, rids, n_real,
-             computed, t_start, disp, js, chained) = devs
+             computed, tiles, t_start, disp, js, chained) = devs
             with span("fetch"):
                 # ONE fetch: the sampled tuple and the counters of every
                 # dispatch of the step
@@ -4940,7 +4958,8 @@ class InferenceEngine:
                 rows_prefill=len(chunk_rows),
                 rows_idle=B - len(decode_rows) - len(chunk_rows),
                 rids=rids, tokens_real=n_real, tokens_computed=computed,
-                moe=np.sum(moe, axis=0) if moe else None, chained=chained)
+                moe=np.sum(moe, axis=0) if moe else None, chained=chained,
+                **tiles)
 
             def emit(req, slot):
                 self._steps[slot] += 1

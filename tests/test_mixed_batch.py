@@ -229,6 +229,57 @@ def test_no_mixed_step_compiles_after_start(sixteen_slots, arrivals):
         assert _metric(name) - before[name] == sum(r[key] for r in mixed)
 
 
+TILE_SERIES = ("cake_mixed_attn_q_tiles_total",
+               "cake_mixed_attn_q_tiles_window_total")
+
+
+def test_mixed_records_count_the_query_tiles_attention_folds(sixteen_slots):
+    """attn_q_tiles / attn_q_tiles_window: the host's count of what the
+    mixed attention kernel folds for a step's active rows (one tile for
+    a decode row, the window's tiles for a row with more real queries
+    than a tile holds) beside the tiles of their whole windows; on
+    every mixed record, on no decode record, and summed in two /metrics
+    series."""
+    import numpy as np
+    from cake_tpu.ops.ragged_paged_attention import (
+        MIXED_Q_TILE, mixed_q_tiles,
+    )
+
+    eng, _ = sixteen_slots
+    full = mixed_q_tiles(16, 16)
+    assert MIXED_Q_TILE < 8 and full == 16 // MIXED_Q_TILE
+    # a hand-built step: 14 decode rows, a full window and one of 5
+    qlen = np.asarray([1] * 7 + [16, 5] + [1] * 7)
+    assert eng._attn_q_tiles(qlen, list(range(16))) == {
+        "attn_q_tiles": 14 + 2 * full, "attn_q_tiles_window": 16 * full}
+    # rows that are not in the step are not counted
+    assert eng._attn_q_tiles(qlen, [0, 7]) == {
+        "attn_q_tiles": 1 + full, "attn_q_tiles_window": 2 * full}
+    seen = {r["step"] for r in eng.flight.dump()}
+    before = {name: _metric(name) for name in TILE_SERIES}
+    a = eng.submit([5] * 9, max_new_tokens=24, temperature=0.0,
+                   repeat_penalty=1.0)
+    _wait_tokens(a, 3)
+    # windows of 16, 16 and 8 tokens beside a's decode row
+    b = eng.submit([7] * 40, max_new_tokens=4, temperature=0.0,
+                   repeat_penalty=1.0)
+    assert b.wait(timeout=300) and a.wait(timeout=300)
+    new = [r for r in eng.flight.dump() if r["step"] not in seen]
+    mixed = [r for r in new if r["kind"] == "mixed"]
+    assert any(r["rows_decode"] and r["rows_prefill"] for r in mixed)
+    for r in mixed:
+        assert r["attn_q_tiles"] == (r["rows_decode"]
+                                     + full * r["rows_prefill"]), r
+        assert r["attn_q_tiles_window"] == full * (
+            r["rows_decode"] + r["rows_prefill"]), r
+    decode = [r for r in new if r["kind"] == "decode"]
+    assert decode and not [r for r in decode if "attn_q_tiles" in r
+                           or "attn_q_tiles_window" in r]
+    for name, key in zip(TILE_SERIES, ("attn_q_tiles",
+                                       "attn_q_tiles_window")):
+        assert _metric(name) - before[name] == sum(r[key] for r in mixed)
+
+
 @pytest.mark.slow  # two engines under staggered load -> slow lane
 def test_mixed_admission_with_preemption_interleaved(tiny_config,
                                                      params):

@@ -66,6 +66,32 @@ def test_flight_recorder_ring_bounds():
     assert len(st.dump(limit=3)) == 3
 
 
+@pytest.mark.parametrize("kind,tiles", [
+    ("mixed", {"attn_q_tiles": 30, "attn_q_tiles_window": 128}),
+    ("mixed", {}), ("decode", {})])
+def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
+                                                                tiles):
+    """A mixed step whose rows go through the mixed attention kernel
+    records the query tiles it folds and those of the rows' windows;
+    the record of any other step has neither key, and the two /metrics
+    series move with the records that have them."""
+    series = ("cake_mixed_attn_q_tiles_total",
+              "cake_mixed_attn_q_tiles_window_total")
+
+    def read():
+        return [sum(float(ln.split()[-1])
+                    for ln in m.REGISTRY.render().splitlines()
+                    if ln.startswith(name + " ")) for name in series]
+
+    st = obs_steps.StepTelemetry(impl="t", capacity=4)
+    before = read()
+    rec = st.record(kind, rows=16, tokens=16, wall_s=0.01, **tiles)
+    got = {k: v for k, v in rec.to_dict().items() if k.startswith("attn_")}
+    assert got == tiles
+    assert [b - a for a, b in zip(before, read())] == [
+        tiles.get("attn_q_tiles", 0), tiles.get("attn_q_tiles_window", 0)]
+
+
 def test_mfu_math_against_hand_computed_matmul():
     """MFU = cost_analysis FLOPs / (peak x step seconds), with the
     matmul's FLOPs hand-computable: 2*M*K*N."""

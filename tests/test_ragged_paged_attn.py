@@ -17,8 +17,9 @@ from cake_tpu.models.llama.paged import (
     paged_attention, paged_attention_mixed,
 )
 from cake_tpu.ops.ragged_paged_attention import (
-    ragged_paged_attention, ragged_paged_attention_mixed,
-    ragged_paged_mixed_supported, ragged_paged_supported,
+    MIXED_Q_TILE, mixed_q_tiles, ragged_paged_attention,
+    ragged_paged_attention_mixed, ragged_paged_mixed_supported,
+    ragged_paged_supported,
 )
 
 P = 8           # page size
@@ -168,9 +169,9 @@ def test_mixed_kernel_parity_page_boundary_offsets():
     _assert_mixed_parity(q, pk, pv, table, pos, qlen)
 
 
-@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4)])
+@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4), (16, 1)])
 def test_mixed_kernel_parity_gqa(H, KV):
-    """GQA group sizes 4, 2 and 1 on a mixed decode+chunk batch."""
+    """GQA group sizes 4, 2, 1 and 16 on a mixed decode+chunk batch."""
     rng = np.random.default_rng(12)
     pk, pv = _pool(rng, KV=KV, hd=16)
     C = 5
@@ -578,9 +579,9 @@ def test_mixed_kernel_parity_int8_offsets_and_holes():
     _assert_mixed_parity_q8(q, pk, pv, table, pos, qlen)
 
 
-@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4)])
+@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4), (16, 1)])
 def test_mixed_kernel_parity_int8_gqa(H, KV):
-    """int8 mixed kernel at GQA group sizes 4, 2 and 1."""
+    """int8 mixed kernel at GQA group sizes 4, 2, 1 and 16."""
     rng = np.random.default_rng(24)
     pk, pv = _qpools(rng, KV=KV, hd=16)
     C = 5
@@ -697,9 +698,9 @@ def test_mixed_kernel_parity_int4_offsets_and_holes():
     _assert_mixed_parity_q4(q, pk, pv, table, pos, qlen)
 
 
-@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4)])
+@pytest.mark.parametrize("H,KV", [(8, 2), (6, 3), (4, 4), (16, 1)])
 def test_mixed_kernel_parity_int4_gqa(H, KV):
-    """int4 mixed kernel at GQA group sizes 4, 2 and 1."""
+    """int4 mixed kernel at GQA group sizes 4, 2, 1 and 16."""
     rng = np.random.default_rng(34)
     pk, pv = _q4pools(rng, KV=KV, hd=16)
     C = 5
@@ -730,3 +731,111 @@ def test_supported_gate_int4_page_tiling(monkeypatch):
     assert not ragged_paged_mixed_supported(128, H=32, KV=8, hd=128,
                                             q_width=1, packed4=True,
                                             n_pages=100_000)
+
+
+# -- the mixed kernel's work follows q_len (PR 34) ---------------------------
+#
+# A row whose real queries lie in its first tile of MIXED_Q_TILE queries
+# folds, initialises and finishes that tile alone; any other its whole
+# window. The three bodies (float, int8, int4 pools) share one fold.
+
+TQ = MIXED_Q_TILE
+SWEEP_C = 2 * TQ + 4
+SWEEP_Q_LENS = sorted({0, 1, TQ - 1, TQ, TQ + 1, SWEEP_C - 1, SWEEP_C})
+# a window's first query before, on and after a page edge
+SWEEP_POS = (P - 1, P, P + 1)
+_SWEEP_POOLS = {"f32": lambda rng, KV, hd: _pool(rng, KV, hd),
+                "int8": lambda rng, KV, hd: _qpools(rng, KV, hd),
+                "int4": lambda rng, KV, hd: _q4pools(rng, KV, hd)}
+
+
+def _mixed(kind, q, pk, pv, table, pos, qlen, layer=LAYER):
+    if kind == "f32":
+        return np.asarray(ragged_paged_attention_mixed(
+            q, pk, pv, layer, table, pos, qlen, interpret=True))
+    return np.asarray(ragged_paged_attention_mixed(
+        q, pk.q, pv.q, layer, table, pos, qlen, scale_k=pk.scale,
+        scale_v=pv.scale, packed4=kind == "int4", interpret=True))
+
+
+def _own_pages(rows):
+    """[rows, MAX_PAGES]: three mapped pages a row (pages are only
+    read, so rows may share them), the rest unmapped."""
+    table = np.full((rows, MAX_PAGES), -1, np.int32)
+    for b in range(rows):
+        table[b, :3] = (3 * b + np.arange(3)) % N_PAGES
+    return jnp.asarray(table)
+
+
+@pytest.mark.parametrize("G", [1, 4, 16])
+@pytest.mark.parametrize("kind", ["f32", "int8", "int4"])
+def test_mixed_kernel_follows_q_len(kind, G):
+    """Every q_len around the tile and the window's edges (0, 1, Tq-1,
+    Tq, Tq+1, C-1, C) at a first position before, on and after a page
+    edge, one row each in one launch: the real columns match the fold,
+    EVERY column is finite, and the columns of a tile the row did not
+    fold are zero."""
+    rng = np.random.default_rng(40 + G)
+    KV = 1 if G == 16 else 2
+    pk, pv = _SWEEP_POOLS[kind](rng, KV, 16)
+    cases = [(p, n) for p in SWEEP_POS for n in SWEEP_Q_LENS]
+    pos = jnp.asarray([p for p, _n in cases], jnp.int32)
+    qlen = jnp.asarray([n for _p, n in cases], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(len(cases), SWEEP_C, KV * G, 16)),
+                    jnp.float32)
+    table = _own_pages(len(cases))
+    want = np.asarray(paged_attention_mixed(q, pk, pv, LAYER, table, pos,
+                                            qlen))
+    got = _mixed(kind, q, pk, pv, table, pos, qlen)
+    assert np.isfinite(got).all()
+    atol = 1e-5 if kind == "f32" else 2e-5
+    for b, (_p, n) in enumerate(cases):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=atol,
+                                   rtol=atol, err_msg=str(cases[b]))
+        if mixed_q_tiles(n, SWEEP_C) == 1:
+            assert not got[b, TQ:].any(), cases[b]
+
+
+@pytest.mark.parametrize("windows", [0, 1, 2])
+def test_mixed_row_is_bit_equal_whatever_rows_share_its_call(windows):
+    """A decode row beside 0, 1 and 2 window rows, and a window row
+    alone or in company: rows do not see each other, and a query's
+    recurrence does not depend on how much of its row's window is
+    folded. The decode row's column 0 is the decode kernel's answer at
+    the same position."""
+    rng = np.random.default_rng(50)
+    pk, pv = _pool(rng, KV=2, hd=16)
+    C = SWEEP_C
+    q = jnp.asarray(rng.normal(size=(3, C, 8, 16)), jnp.float32)
+    table = _own_pages(3)
+    pos = jnp.asarray([2 * P + 3, P - 2, 3], jnp.int32)
+
+    def run(qlen):
+        return _mixed("f32", q, pk, pv, table, pos,
+                      jnp.asarray(qlen, jnp.int32))
+
+    alone = run([1, 0, 0])
+    got = run([1] + [C, C - 1][:windows] + [1, 1][windows:])
+    np.testing.assert_array_equal(got[0], alone[0])
+    decode = np.asarray(ragged_paged_attention(
+        q[:, :1], pk, pv, LAYER, table, pos, interpret=True))
+    np.testing.assert_allclose(got[0, 0], decode[0, 0], atol=1e-5,
+                               rtol=1e-5)
+    if windows:
+        # the window row's first query, folded with its whole window
+        # here and alone in its tile there
+        np.testing.assert_array_equal(got[1, 0], run([0, 1, 0])[1, 0])
+        np.testing.assert_array_equal(got[1], run([0, C, 0])[1])
+
+
+def test_mixed_q_tiles_counts_what_the_kernel_folds():
+    """The host's count (obs/steps `attn_q_tiles`): one tile for a row
+    whose real queries lie in its first tile, the window's otherwise.
+    14 decode rows beside windows of 128 and 37 tokens, width 128."""
+    C = 128
+    full = mixed_q_tiles(C, C)
+    assert full == -(-C // TQ) and mixed_q_tiles(0, C) == 1
+    assert mixed_q_tiles(1, C) == mixed_q_tiles(TQ, C) == 1
+    assert mixed_q_tiles(TQ + 1, C) == mixed_q_tiles(C - 1, C) == full
+    step = [1] * 14 + [128, 37]
+    assert sum(mixed_q_tiles(n, C) for n in step) == 14 + 2 * full
