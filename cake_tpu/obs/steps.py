@@ -44,6 +44,15 @@ pieces, all dependency-free:
     them as `cake/<phase>` TraceAnnotations carrying the step number,
     so a capture's host plane joins `/api/v1/steps` by step.
 
+  * **Stretch boundaries**: why a chain of in-flight steps ended
+    (BREAKS: `chain_break` on the next record that is not chained,
+    `cake_chain_breaks_total{cause}`, a stat of that step's first
+    `cake/dispatch`), what the host did while the device drained
+    (PARTS: one level under the phases, `StepTelemetry.part`, a
+    record's `parts` and `rows_admitted`), and whether a chained step's
+    successor reached the device after it had finished (`late`, from
+    the step's own fetch against LATE_FETCH_S).
+
 MFU here is model-FLOPs utilization: (program FLOPs from
 cost_analysis) / (peak chip FLOP/s x measured step seconds), clamped to
 1.0. A device kind that is not in the peak table (a CPU) has no peak,
@@ -123,6 +132,18 @@ _MIXED_CHAINED = _m.counter(
     "Mixed steps dispatched while the step before them was in flight, "
     "their decode rows fed from the tokens still on the device (of "
     "cake_steps_total{kind=\"mixed\"})")
+_CHAINED_LATE = _m.counter(
+    "cake_chained_steps_late_total",
+    "Chained decode and mixed steps whose own fetch returned at once "
+    "(under obs/steps.LATE_FETCH_S): the device had finished them "
+    "before the host sent the step after them (of "
+    "cake_decode_steps_chained_total + cake_mixed_steps_chained_total)")
+_CHAIN_BREAKS = _m.counter(
+    "cake_chain_breaks_total",
+    "Steps dispatched with nothing in flight before them, by why the "
+    "chain before them ended (obs/steps.BREAKS; idle = the loop had "
+    "nothing to run)",
+    labelnames=("cause",))
 _STEP_DISPATCH = _m.histogram(
     "cake_step_dispatch_seconds",
     "Per-step dispatch wall seconds, by step kind",
@@ -535,6 +556,19 @@ class StepRecord:
     # carried them, in the order of counter_layout (a sparse model's
     # five, a glm_moe_dsa model's eleven, a nemotron_h model's ten)
     moe: Optional[Tuple[float, ...]] = None
+    # a step that is not chained: why the chain before it ended (one of
+    # BREAKS; absent where no chain ended or the loop never waited: the
+    # engine's first step), and the rows admitted since the record
+    # before it
+    chain_break: Optional[str] = None
+    rows_admitted: Optional[int] = None
+    # host seconds by part of a phase (PARTS) since the previous record
+    parts: Optional[Dict[str, float]] = None
+    # a chained step: the seconds its own fetch waited, and whether
+    # that was no wait at all (under LATE_FETCH_S): the device had
+    # finished the step before the host sent the one after it
+    fetch_wait_s: Optional[float] = None
+    late: Optional[bool] = None
 
     def to_dict(self) -> Dict:
         out = {
@@ -578,6 +612,15 @@ class StepRecord:
             out["gap_s"] = round(self.gap_s, 6)
         if self.chained is not None:
             out["chained"] = self.chained
+        if self.chain_break is not None:
+            out["chain_break"] = self.chain_break
+        if self.rows_admitted is not None:
+            out["rows_admitted"] = self.rows_admitted
+        if self.parts:
+            out["parts"] = {k: round(v, 6) for k, v in self.parts.items()}
+        if self.fetch_wait_s is not None:
+            out["fetch_wait_s"] = round(self.fetch_wait_s, 6)
+            out["late"] = self.late
         if self.moe is not None:
             for (key, _series), v in zip(counter_layout(len(self.moe)),
                                          self.moe):
@@ -590,6 +633,41 @@ class StepRecord:
 # it belongs to no step and closes the open phase table.
 PHASES = ("admin", "schedule", "build", "dispatch", "sample", "fetch",
           "emit", "wait")
+
+# Why a chain of in-flight steps ended (serve/engine._drive_burst's
+# gate, the first condition that held, in the order it tests them):
+# the host wanted the loop back (stop, a request in the queue, a
+# cancel, a command); this engine or this step may not chain (sync:
+# multi-host, the paged speculative engine, no sampled program, a
+# caller whose plan is about to change); STRETCH_STEPS dispatches
+# (stretch_cap); a row of the plan finished in the last emit; no row
+# has budget left; a row would pass max_seq_len (window_end). "idle" is
+# the `wait` span's: the loop had nothing to run.
+BREAKS = ("stop", "queue", "cancel", "command", "sync", "stretch_cap",
+          "row_finished", "budget", "window_end", "idle")
+
+# The parts of a phase, "<phase>.<part>" (PERF.md §3): one level under
+# PHASES, for what a stretch boundary is made of. schedule: the
+# scheduler's plan; an admission's head, prefix match, pages, restore
+# or adoption (admit_pages); its sampling state and ring, the eager
+# device launches (admit_ring). dispatch: the call of the step program
+# itself, apart from the staging of its arguments. emit: the
+# detokenisation, a row a token (StepTelemetry.add_part: no annotation).
+PARTS = ("schedule.plan", "schedule.admit_pages", "schedule.admit_ring",
+         "dispatch.launch", "emit.detok")
+_PART_SPAN = {key: key.split(".")[0] for key in PARTS}
+
+# A chained step whose own fetch waited less than this was `late`: the
+# device had finished it before the host dispatched the step after it,
+# so it sat idle under a chain (the fetch follows that dispatch at
+# once). Measured on a TPU v5 lite (PR 35, 300 fetches a case):
+# jax.device_get of a FINISHED step's sampled tuple (tokens, logprobs,
+# 20 top ids and logprobs, ten counters) takes 0.55 ms at 16 rows and
+# 0.59 ms at 32 with the device idle, 0.60 / 0.65 ms with the next
+# program already running (p90 0.69 / 0.79, max 1.16); a fetch that
+# does wait reads the rest of the step (17.5 ms of an 18.2 ms
+# program). Twice the median under the next program.
+LATE_FETCH_S = 0.00125
 
 
 class _Span:
@@ -608,9 +686,45 @@ class _Span:
 
     def __enter__(self):
         import jax
+        tel = self._tel
+        stats = {"step": tel._next}
+        if (self._name == "dispatch" and tel._break is not None
+                and "dispatch" not in tel._phases):
+            # a stretch's first dispatch: the capture shows why the
+            # chain before it ended where the idle gap ends
+            stats["chain_break"] = tel._break
         # outside a capture a TraceAnnotation is a flag test
         self._ann = jax.profiler.TraceAnnotation(
-            "cake/" + self._name, step=self._tel._next)
+            "cake/" + self._name, **stats)
+        self._ann.__enter__()
+        tel._open = self._name
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._tel._open = None
+        self._ann.__exit__(*exc)
+        self._tel._close_span(self._name, self._t0, t1)
+        return False
+
+
+class _Part:
+    """One timed part of the open span (StepTelemetry.part): its
+    seconds go to the open step's `parts` under "<span>.<part>", beside
+    the span's own in `phases`, and it shows as `cake/<span>.<part>`
+    with the step number."""
+
+    __slots__ = ("_tel", "_key", "_ann", "_t0")
+
+    def __init__(self, tel: "StepTelemetry", key: str):
+        self._tel = tel
+        self._key = key
+
+    def __enter__(self):
+        import jax
+        self._ann = jax.profiler.TraceAnnotation(
+            "cake/" + self._key, step=self._tel._next)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -618,7 +732,8 @@ class _Span:
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
-        self._tel._close_span(self._name, self._t0, t1)
+        parts = self._tel._parts
+        parts[self._key] = parts.get(self._key, 0.0) + (t1 - self._t0)
         return False
 
 
@@ -664,6 +779,18 @@ class StepTelemetry:
         self._phases: Dict[str, float] = {}
         self._gap: Optional[float] = None
         self._fetch_t1: Optional[float] = None
+        # the open span's name, the open step's parts, and what waits
+        # for the next record that is not chained: why the last chain
+        # ended, the rows admitted since
+        self._open: Optional[str] = None
+        self._parts: Dict[str, float] = {}
+        self._break: Optional[str] = None
+        self._admitted = 0
+
+    @property
+    def next_step(self) -> int:
+        """The number the record being put together will carry."""
+        return self._next
 
     # -- step phases ----------------------------------------------------------
 
@@ -677,11 +804,58 @@ class StepTelemetry:
                              f"vocabulary is {PHASES}")
         return _Span(self, name)
 
+    def part(self, name: str) -> _Part:
+        """Context manager around one part of the OPEN span (one of
+        PARTS, named without its span): `with span("schedule"): with
+        part("plan"): ...`. Its seconds land in the `parts` of the next
+        record under "schedule.plan" and stay in the span's `phases`
+        entry, where they always were."""
+        key = f"{self._open}.{name}"
+        if self._open is None or key not in PARTS:
+            raise ValueError(
+                f"no part {name!r} of the open span {self._open!r}: the "
+                f"vocabulary is {PARTS}")
+        return _Part(self, key)
+
+    def add_part(self, key: str, seconds: float) -> None:
+        """Seconds of a part (its full name) measured by the caller:
+        for one that runs a row a token, where an annotation each would
+        cost more than the part. Counted inside its span only, like the
+        span's own seconds."""
+        span = _PART_SPAN.get(key)
+        if span is None:
+            raise ValueError(f"unknown part {key!r}: the vocabulary is "
+                             f"{PARTS}")
+        if span == self._open:
+            self._parts[key] = self._parts.get(key, 0.0) + seconds
+
+    def chain_broke(self, cause: str) -> None:
+        """A stretch of in-flight steps stopped chaining and has been
+        fetched to its end (serve/engine._drive_burst): `cause` (one of
+        BREAKS) rides the next record that is not chained."""
+        if cause not in BREAKS:
+            raise ValueError(f"unknown chain break {cause!r}: the "
+                             f"vocabulary is {BREAKS}")
+        self._break = cause
+
+    def admitted(self, rows: int = 1) -> None:
+        """Count rows admitted at this boundary (`rows_admitted` of the
+        next record that is not chained)."""
+        self._admitted += rows
+
+    def discard_open(self) -> None:
+        """Drop the open step's phases, parts and gap: what ran belongs
+        to no step (the engine's warm-up; the idle loop)."""
+        self._phases, self._parts = {}, {}
+        self._gap = self._fetch_t1 = None
+
     def _close_span(self, name: str, t0: float, t1: float) -> None:
         if name == "wait":
-            # nothing to run: what led up to the wait belongs to no step
-            self._phases = {}
-            self._gap = self._fetch_t1 = None
+            # nothing to run: what led up to the wait belongs to no
+            # step, and the next step starts no chain's successor
+            self.discard_open()
+            if self._next > 1:
+                self._break = "idle"
             return
         if name == "fetch":
             self._fetch_t1 = t1
@@ -759,7 +933,8 @@ class StepTelemetry:
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None,
-               chained: Optional[bool] = None) -> StepRecord:
+               chained: Optional[bool] = None,
+               fetch_wait_s: Optional[float] = None) -> StepRecord:
         """Append one step record; derives MFU / HBM utilization from
         `cost` and the step's device seconds. Any subset of the three
         timings may be given; missing ones fall back to the others.
@@ -779,7 +954,11 @@ class StepTelemetry:
         step before it was in flight (cake_decode_steps_chained_total,
         cake_mixed_steps_chained_total). Its
         gap_s is 0.0, the device had work queued, whatever the spans
-        say: its dispatch span lies in the record before its own."""
+        say: its dispatch span lies in the record before its own.
+        fetch_wait_s: a chained step's own fetch; under LATE_FETCH_S
+        the step is `late` (cake_chained_steps_late_total). A step that
+        is not chained takes what waited for it: `chain_break`
+        (cake_chain_breaks_total{cause}) and `rows_admitted`."""
         wall = wall_s if wall_s is not None else (
             (dispatch_s or 0.0) + (device_s or 0.0))
         disp = dispatch_s if dispatch_s is not None else wall
@@ -792,7 +971,16 @@ class StepTelemetry:
             if cost.bytes_accessed > 0 and bps:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
         phases, self._phases = self._phases, {}
+        parts, self._parts = self._parts, {}
         gap, self._gap = (0.0 if chained else self._gap), None
+        cause = admitted = late = None
+        if chained:
+            if fetch_wait_s is not None:
+                late = fetch_wait_s < LATE_FETCH_S
+        else:
+            fetch_wait_s = None
+            cause, self._break = self._break, None
+            admitted, self._admitted = self._admitted, 0
         if "fetch" not in phases:
             # the device was never drained: the next step has no gap
             self._fetch_t1 = None
@@ -814,12 +1002,19 @@ class StepTelemetry:
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
                 moe=(tuple(float(v) for v in moe)
-                     if moe is not None else None))
+                     if moe is not None else None),
+                chain_break=cause, rows_admitted=admitted,
+                parts=parts or None, fetch_wait_s=fetch_wait_s,
+                late=late)
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
         if chained:
             (_MIXED_CHAINED if kind == "mixed" else _DECODE_CHAINED).inc()
+            if late:
+                _CHAINED_LATE.inc()
+        elif cause is not None:
+            _CHAIN_BREAKS.labels(cause=cause).inc()
         _STEP_DISPATCH.labels(kind=kind).observe(disp)
         for k, v in (("decode", rows_decode), ("prefill", rows_prefill),
                      ("idle", rows_idle)):

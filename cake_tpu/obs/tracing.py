@@ -68,8 +68,11 @@ REQUESTS_FINISHED = _m.counter(
 
 @dataclass
 class TraceRecord:
-    """One request's lifecycle. Spans are (name, perf_counter ts);
-    `wall_start` anchors them to wall-clock for export."""
+    """One request's lifecycle. Spans are (name, perf_counter ts), or
+    (name, ts, step) where a step record caused the span (`prefill`:
+    the record of /api/v1/steps that was being put together when the
+    request was admitted); `wall_start` anchors them to wall-clock for
+    export."""
 
     rid: int
     prompt_tokens: int = 0
@@ -103,14 +106,14 @@ class TraceRecord:
     _last_token_t: float = 0.0
 
     def _t(self, name: str) -> Optional[float]:
-        for n, t in self.spans:
+        for n, t, *_step in self.spans:
             if n == name:
                 return t
         return None
 
     def _t_last(self, name: str) -> Optional[float]:
         t = None
-        for n, ts in self.spans:
+        for n, ts, *_step in self.spans:
             if n == name:
                 t = ts
         return t
@@ -148,9 +151,10 @@ class TraceRecord:
             "output_tokens": self.output_tokens,
             "submitted_at": round(self.wall_start, 6),
             "spans": [
-                {"name": n, "t": round(self.wall_start + (ts - t0), 6),
-                 "offset_s": round(ts - t0, 6)}
-                for n, ts in self.spans
+                dict({"name": n, "t": round(self.wall_start + (ts - t0), 6),
+                      "offset_s": round(ts - t0, 6)},
+                     **({"step": step[0]} if step else {}))
+                for n, ts, *step in self.spans
             ],
             "queue_wait_s": _r(self.queue_wait_s),
             "prefill_s": _r(self.prefill_s),
@@ -241,17 +245,23 @@ class RequestTracer:
         if rec is not None:
             self._event(rec, "rejected")
 
-    def span(self, rid: int, name: str, **fields) -> None:
+    def span(self, rid: int, name: str, step: Optional[int] = None,
+             **fields) -> None:
+        """step: the number of the step record that caused the span
+        (kept on the span, so /api/v1/requests joins /api/v1/steps)."""
         now = time.perf_counter()
         with self._lock:
             rec = self._active.get(rid)
             if rec is None:
                 return
-            rec.spans.append((name, now))
+            rec.spans.append((name, now) if step is None
+                             else (name, now, step))
+        if step is not None:
+            fields["step"] = step
         self._event(rec, name, **fields)
 
-    def prefill_start(self, rid: int) -> None:
-        self.span(rid, "prefill")
+    def prefill_start(self, rid: int, step: Optional[int] = None) -> None:
+        self.span(rid, "prefill", step=step)
 
     def first_token(self, rid: int) -> None:
         now = time.perf_counter()

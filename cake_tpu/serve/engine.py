@@ -2069,15 +2069,25 @@ class InferenceEngine:
             # cakelint: skip[affinity] shutdown window: the engine thread has exited (checked above); runtime assert backstops
             self._drain_cancellations()
 
-    def _host_attention_pending(self) -> bool:
-        """Something on the host side needs the run loop back: stop,
-        admissions waiting, cancellations, or commands."""
-        return (self._stop.is_set()
-                or self.scheduler.queue_depth > 0
-                or self._cancel_pending()
-                or self._commands_pending())
+    def _host_attention(self) -> Optional[str]:
+        """What on the host side needs the run loop back, as a chain
+        break (obs/steps.BREAKS), the first that holds: stop, an
+        admission waiting, a cancellation, a command. None: nothing."""
+        if self._stop.is_set():
+            return "stop"
+        if self.scheduler.queue_depth > 0:
+            return "queue"
+        if self._cancel_pending():
+            return "cancel"
+        if self._commands_pending():
+            return "command"
+        return None
 
-    def _drive_burst(self, dispatch, complete, can_chain,
+    def _host_attention_pending(self) -> bool:
+        """Something on the host side needs the run loop back."""
+        return self._host_attention() is not None
+
+    def _drive_burst(self, dispatch, complete, chain_break,
                      first_unconditional: bool = False) -> None:
         """THE double-buffered dispatch/fetch driver, shared by the
         decode burst and the speculative burst: dispatch k+1 (chained
@@ -2087,10 +2097,14 @@ class InferenceEngine:
 
         dispatch(state) -> (devs, state'): device dispatch, no fetch.
         complete(devs): fetch + emit one dispatch's results.
-        can_chain(n_inflight) -> bool: burst-specific budget/window
-        gating (called after the shared host-attention gate);
-        n_inflight = dispatched-but-unfetched count, for projecting
-        the device frontier past the stale host mirrors.
+        chain_break(n_inflight) -> the cause (obs/steps.BREAKS) for
+        which the burst may not dispatch ahead, or None: its own
+        budget/window gating (asked after the shared host-attention
+        gate); n_inflight = dispatched-but-unfetched count, for
+        projecting the device frontier past the stale host mirrors.
+        The first cause that stops a chain goes to the flight recorder
+        once its last step has been fetched (StepTelemetry.chain_broke:
+        the next record that is not chained carries it).
         first_unconditional: guarantee one dispatch per call even when
         the gates say stop — a caller whose planning loop has no other
         progress path would otherwise spin forever (the spec burst with
@@ -2098,17 +2112,23 @@ class InferenceEngine:
         inflight: list = []
         state = None
         first = first_unconditional
+        broke = None
         while True:
-            chain = first or (not self._host_attention_pending()
-                              and can_chain(len(inflight)))
+            cause = None if first else (
+                self._host_attention() or chain_break(len(inflight)))
             first = False
-            if chain:
+            if cause is None:
                 devs, state = dispatch(state)
                 inflight.append(devs)
+            elif inflight and broke is None:
+                broke = cause
             if not inflight:
                 break
-            if not chain or len(inflight) >= 2:
+            if cause is not None or len(inflight) >= 2:
                 complete(inflight.pop(0))
+                if broke is not None and not inflight:
+                    self.flight.chain_broke(broke)
+                    broke = None
 
     def _cancel_pending(self) -> bool:
         with self._rid_lock:
@@ -2186,7 +2206,8 @@ class InferenceEngine:
                     # through a just-released page-table row
                     self._maybe_preempt()
             with span("schedule"):
-                prefill_plan, decode_plan = self.scheduler.plan()
+                with self.flight.part("plan"):
+                    prefill_plan, decode_plan = self.scheduler.plan()
                 # decode-resident slots THIS iteration: the candidate
                 # set for _spill_resident_stream — plan()'s decode rows
                 # only, never same-wave admissions (their prefill may
@@ -4381,7 +4402,9 @@ class InferenceEngine:
         if req is None:  # cancelled between plan and here
             self.scheduler.cancel(rid)
             return None
-        self.tracer.prefill_start(rid)
+        # the step record being put together is the boundary that
+        # admits this request (/api/v1/requests joins /api/v1/steps)
+        self.tracer.prefill_start(rid, step=self.flight.next_step)
         t0 = time.perf_counter()
         req.slot = slot
         self._slot_req[slot] = req
@@ -4418,10 +4441,11 @@ class InferenceEngine:
         admission's first token in ONE host round-trip (a per-admission
         fetch waits for the device once per request — it adds up in
         TTFT when a wave of requests arrives together)."""
-        with self.flight.span("schedule"):
+        with self.flight.span("schedule"), self.flight.part("admit_pages"):
             admitted = self._prefill_admit(rid, slot)
         if admitted is None:
             return None
+        self.flight.admitted()
         req, t0, ids, prime = admitted
         hit = (self._match_and_validate_prefix(ids)
                if self._prefix_capable else None)
@@ -4570,7 +4594,7 @@ class InferenceEngine:
         decode_scan interaction (the K-step-burst admission-delay fix):
         the decode programs run only while NO prompt is mid-prefill,
         and chain (one step in flight, or K-step scan bursts) only
-        while nobody waits in the queue (_host_attention_pending,
+        while nobody waits in the queue (_host_attention,
         _scan_steps_for's queue gate); the moment a request is
         admitted, the loop falls back to single mixed steps so its
         chunks ride every step instead of stalling behind a K-token
@@ -4605,10 +4629,31 @@ class InferenceEngine:
         (_prefill_admit) match a prefix, allocate pages (or restore
         them from the host tier, or adopt a shipped prefill), set up
         the sampling state — and NO device dispatch: the prompt's
-        windows ride the next mixed step(s) as chunk rows."""
+        windows ride the next mixed step(s) as chunk rows. Two parts of
+        the `schedule` span: admit_pages (everything up to the row's
+        pages), admit_ring (the sampling state and the ring's eager
+        device launches)."""
+        with self.flight.part("admit_pages"):
+            ready = self._mixed_admit_pages(rid, slot)
+        if ready is None:
+            return
+        req, ids, prime, off = ready
+        with self.flight.part("admit_ring"):
+            self._temp[slot] = req.temperature
+            self._top_p[slot] = req.top_p
+            self._penalty[slot] = req.repeat_penalty
+            self._prime_ring(slot, prime)
+        self._pos[slot] = off
+        self._mixed_pending[slot] = {"req": req, "ids": ids, "off": off}
+
+    def _mixed_admit_pages(self, rid: int, slot: int):
+        """_mixed_admit up to the row's pages. Returns (req, ids, prime,
+        the offset its windows start at), or None where no prompt is
+        left to prefill: cancelled, requeued for pages, restored from
+        the host tier or adopted at its decode frontier."""
         admitted = self._prefill_admit(rid, slot)
         if admitted is None:
-            return
+            return None
         req, _t0, ids, prime = admitted
         # shipped-prefill adoption (disaggregated decode host): a
         # staged shipment replaces BOTH the prefix match and the local
@@ -4623,7 +4668,8 @@ class InferenceEngine:
         hit = (self._match_and_validate_prefix(ids)
                if self._prefix_capable and adopt is None else None)
         if not self._alloc_slot_pages(req, slot, hit):
-            return   # pool exhausted: requeued (or failed) inside
+            return None   # pool exhausted: requeued (or failed) inside
+        self.flight.admitted()
         hit = req._effective_hit       # spilled-prefix restore failure
         if getattr(req, "_kv_restored", False):
             # spilled preemption victim restored from the host tier:
@@ -4633,7 +4679,7 @@ class InferenceEngine:
             # mid-decode and must NOT ride the next mixed step as a
             # chunk row
             req._kv_restored = False
-            return
+            return None
         if adopt is not None:
             with self._rid_lock:
                 self._adopt_store.pop(rid, None)
@@ -4641,7 +4687,7 @@ class InferenceEngine:
                     and self._adopt_install(req, slot, adopt):
                 # the slot resumes as a DECODE row from the shipped
                 # frontier — it must not also ride as a chunk row
-                return
+                return None
             # refused (stale epoch / geometry / injected fault): fall
             # through — local prefill rewrites the row's pages and
             # scales, the documented degradation
@@ -4656,12 +4702,7 @@ class InferenceEngine:
             if self.events is not None:
                 self.events.publish("prefix_hit", rid=req.rid,
                                     pid=hit[0], tokens_saved=off)
-        self._temp[slot] = req.temperature
-        self._top_p[slot] = req.top_p
-        self._penalty[slot] = req.repeat_penalty
-        self._prime_ring(slot, prime)
-        self._pos[slot] = off
-        self._mixed_pending[slot] = {"req": req, "ids": ids, "off": off}
+        return req, ids, prime, off
 
     def _run_mixed_step(self, step, carry, n_tokens: int) -> tuple:
         """Dispatch the sampled mixed step program of size n_tokens
@@ -4688,8 +4729,11 @@ class InferenceEngine:
         js = self._obs_jit("mixed_step", (step.shape[1] - 4, n_tokens),
                            self._mixed_step_fn, fargs, kw)
         t0 = time.perf_counter()
-        (nxt, lp, tids, tlps, self.cache, self._keys, self._ring, carry,
-         *moe) = self._mixed_step_fn(*fargs, **kw)
+        # the launch alone: the staging of the step, the options and a
+        # first carry lies above it in the `dispatch` span
+        with self.flight.part("launch"):
+            (nxt, lp, tids, tlps, self.cache, self._keys, self._ring,
+             carry, *moe) = self._mixed_step_fn(*fargs, **kw)
         js.finish(time.perf_counter() - t0)
         # a step of several dispatches compiled if any of them did
         js.new |= self._last_jit is not None and self._last_jit.new
@@ -4743,9 +4787,13 @@ class InferenceEngine:
         form, so these runs are all its variants."""
         idle = np.zeros((self.max_slots, self._mixed_chunk + 4), np.int32)
         marks = [time.perf_counter()]
-        for bucket in self._mixed_buckets:
-            out, _carry = self._run_mixed_step(idle, None, bucket)
-            marks.append(time.perf_counter())
+        # (the launch is a part of the `dispatch` span; these belong to
+        # no step)
+        with self.flight.span("dispatch"):
+            for bucket in self._mixed_buckets:
+                out, _carry = self._run_mixed_step(idle, None, bucket)
+                marks.append(time.perf_counter())
+        self.flight.discard_open()
         jax.block_until_ready(out)
         self._last_jit = None
         log.info("mixed step: sizes %s ready in %.2f s (traced and "
@@ -4771,8 +4819,8 @@ class InferenceEngine:
         (_decode_stretch), so the decode steps after it stay chained.
 
         The stretch ends as a decode stretch does (_decode_stretch's
-        can_chain): when the host needs the loop back
-        (_host_attention_pending), after an emit in which a row
+        chain_break): when the host needs the loop back
+        (_host_attention), after an emit in which a row
         finished (its slot is the planner's), before a row would pass
         max_seq_len, after STRETCH_STEPS dispatches. Rows join between
         stretches only (_mixed_admit). An engine that may not chain
@@ -4807,22 +4855,28 @@ class InferenceEngine:
         may_chain = not self._multihost and self._specp is None
         # what takes over when no prompt is left: the sampled decode
         # program on the same rows, if this engine keeps one in flight
-        tail_dispatch, tail_complete, tail_can_chain = self._decode_stretch(
+        tail_dispatch, tail_complete, tail_chain_break = self._decode_stretch(
             plan, 1,
             (may_chain and self._decode_scan_impl is not None
              and self._decode_scan <= 1),
             shipped, flying)
 
-        def can_chain(n_inflight) -> bool:
+        def chain_break(n_inflight) -> Optional[str]:
             if not pending:
-                return tail_can_chain(n_inflight)
-            # as _decode_stretch's gate; a window's positions lie
+                return tail_chain_break(n_inflight)
+            # as _decode_stretch's gate, without its budget term (a
+            # prompt's windows are work); a window's positions lie
             # inside its prompt
-            return (may_chain and flying.sent < STRETCH_STEPS
-                    and all(self._slot_req[s] is not None for s in rows_of)
-                    and all(self._pos[s] + shipped.get(s, 0) + 1
-                            < self.max_seq_len
-                            for s in rows_of if s not in pending))
+            if not may_chain:
+                return "sync"
+            if flying.sent >= STRETCH_STEPS:
+                return "stretch_cap"
+            if any(self._slot_req[s] is None for s in rows_of):
+                return "row_finished"
+            if any(self._pos[s] + shipped.get(s, 0) + 1 >= self.max_seq_len
+                   for s in rows_of if s not in pending):
+                return "window_end"
+            return None
 
         def dispatch(state):
             if not pending:
@@ -4945,6 +4999,7 @@ class InferenceEngine:
             self.stats.prefill_time_s += pf
             self.stats.decode_time_s += wall - pf
             self._obs_paged_step("mixed", wall)
+            waited = self.flight.open_phase("fetch")
             # written after the fetch and before the emit: a request
             # whose first token this step sampled is still a prefill
             # row at the record's ts
@@ -4952,8 +5007,7 @@ class InferenceEngine:
                 "mixed", rows=len(decode_rows) + len(chunk_rows),
                 tokens=len(sampled), wall_s=wall,
                 dispatch_s=disp,
-                device_s=(wall if chained
-                          else self.flight.open_phase("fetch")),
+                device_s=wall if chained else waited, fetch_wait_s=waited,
                 js=js, rows_decode=len(decode_rows),
                 rows_prefill=len(chunk_rows),
                 rows_idle=B - len(decode_rows) - len(chunk_rows),
@@ -4991,7 +5045,7 @@ class InferenceEngine:
 
         # the first dispatch is the step the run loop planned, whoever
         # waits; only what follows is gated
-        self._drive_burst(dispatch, lambda finish: finish(), can_chain,
+        self._drive_burst(dispatch, lambda finish: finish(), chain_break,
                           first_unconditional=True)
 
     def _match_and_validate_prefix(self, ids: List[int]):
@@ -5313,15 +5367,19 @@ class InferenceEngine:
         # was admitted with room for >= 1 round (the force-finish guard
         # above), and skipping it would leave the run loop spinning
         # with full slots and a waiting queue.
-        def can_chain(n_inflight: int) -> bool:
-            return (all(not req.done.is_set()
-                        and (req.max_new_tokens - len(req.out_tokens)
-                             - n_inflight * (g + 1)) > 0
-                        for req, _ in plan)
-                    and all(self._pos[s] + (n_inflight + 1) * (g + 1)
-                            < self.max_seq_len for _, s in plan))
+        def chain_break(n_inflight: int) -> Optional[str]:
+            for req, _ in plan:
+                if req.done.is_set():
+                    return "row_finished"
+                if (req.max_new_tokens - len(req.out_tokens)
+                        - n_inflight * (g + 1)) <= 0:
+                    return "budget"
+            if any(self._pos[s] + (n_inflight + 1) * (g + 1)
+                   >= self.max_seq_len for _, s in plan):
+                return "window_end"
+            return None
 
-        self._drive_burst(dispatch, complete, can_chain,
+        self._drive_burst(dispatch, complete, chain_break,
                           first_unconditional=True)
         self.stats.decode_time_s += time.perf_counter() - t0
 
@@ -5768,7 +5826,8 @@ class InferenceEngine:
             js = self._obs_jit("decode_step", (), self._decode_step,
                                fargs)
             t0 = time.perf_counter()
-            logits, self.cache, *moe = self._decode_step(*fargs)
+            with self.flight.part("launch"):
+                logits, self.cache, *moe = self._decode_step(*fargs)
             self._moe_pending += moe
             js.finish(time.perf_counter() - t0)
             self._last_jit = js
@@ -5866,12 +5925,12 @@ class InferenceEngine:
 
     def _decode_stretch(self, decode_plan, n: int, chain: bool,
                         shipped: dict, flying: "_Flying") -> tuple:
-        """_drive_burst's (dispatch, complete, can_chain) for decode
+        """_drive_burst's (dispatch, complete, chain_break) for decode
         steps on the rows of decode_plan: dispatch k+1 (its inputs
         chained on device from k's final carry — zero host round-trips
         between them) BEFORE fetching k's tokens, so the fetch and the
         emit of k run while the device computes k+1. The stretch ends
-        when the host needs the loop back (_host_attention_pending),
+        when the host needs the loop back (_host_attention),
         when a row of its plan finished (the slot is the planner's to
         fill), when no row has budget or window left, after
         STRETCH_STEPS dispatches, and after its first where the caller
@@ -5895,7 +5954,7 @@ class InferenceEngine:
         n_top = self._n_top_for(rows)
         kind = "decode" if n == 1 else "decode_scan"
 
-        def can_chain(_n_inflight) -> bool:
+        def chain_break(_n_inflight) -> Optional[str]:
             # real work remains, and the PROJECTED device position
             # (host mirror + unfetched in-flight tokens) still fits the
             # window: the mirror lags the device by the in-flight
@@ -5910,11 +5969,18 @@ class InferenceEngine:
             # admissions behind a stretch, and itl_p95_ms moving with
             # their count; my chip run, PR 29). The step in flight
             # covers the time the arrival needs.
-            return (chain and flying.sent < STRETCH_STEPS
-                    and all(self._slot_req[s] is not None for s in rows)
-                    and self._scan_budget(decode_plan, n, shipped).any()
-                    and all(self._pos[s] + shipped.get(s, 0) + n
-                            < self.max_seq_len for s in rows))
+            if not chain:
+                return "sync"
+            if flying.sent >= STRETCH_STEPS:
+                return "stretch_cap"
+            if any(self._slot_req[s] is None for s in rows):
+                return "row_finished"
+            if not self._scan_budget(decode_plan, n, shipped).any():
+                return "budget"
+            if any(self._pos[s] + shipped.get(s, 0) + n >= self.max_seq_len
+                   for s in rows):
+                return "window_end"
+            return None
 
         def dispatch(state):
             if self._faults is not None:
@@ -5927,10 +5993,10 @@ class InferenceEngine:
                 self._faults.check("engine.decode", step=self.stats.steps)
             t_start = time.perf_counter()
             with span("build"):
-                # recomputed rather than smuggled out of can_chain:
+                # recomputed rather than smuggled out of chain_break:
                 # nothing host-side changes between the gate and the
                 # dispatch (same thread), and an explicit recompute
-                # keeps _drive_burst's can_chain a pure gate
+                # keeps _drive_burst's chain_break a pure gate
                 budget = self._scan_budget(decode_plan, n, shipped)
             t0d = time.perf_counter()
             outs, state = self._dispatch_scan_device(
@@ -5951,12 +6017,12 @@ class InferenceEngine:
             self.stats.decode_time_s += wall
             self._obs_paged_step("decode", wall / n)
             moe = fetched[4]
+            waited = self.flight.open_phase("fetch")
             self._record_step(
                 kind, rows=int(np.count_nonzero(budget_k)),
                 tokens=int(budget_k.sum()), wall_s=wall,
                 dispatch_s=disp_k,
-                device_s=(wall if chained
-                          else self.flight.open_phase("fetch")),
+                device_s=wall if chained else waited, fetch_wait_s=waited,
                 js=js_k, rids=rids,
                 moe=np.sum(moe, axis=0) if moe else None,
                 chained=chained)
@@ -5970,7 +6036,7 @@ class InferenceEngine:
                 with span("admin"):
                     self._journal.flush()
 
-        return dispatch, complete, can_chain
+        return dispatch, complete, chain_break
 
     def _complete_scan(self, decode_plan, n: int, fetched,
                        budget) -> None:
@@ -6044,8 +6110,9 @@ class InferenceEngine:
                 "decode_scan" if n > 1 else "decode_step_sampled",
                 (n, n_top), self._decode_scan_impl, fargs, fkw)
             t0 = time.perf_counter()
-            (toks, lps, tops_i, tops_l, self.cache, keys_o, ring_o,
-             state_o, *moe) = self._decode_scan_impl(*fargs, **fkw)
+            with self.flight.part("launch"):
+                (toks, lps, tops_i, tops_l, self.cache, keys_o, ring_o,
+                 state_o, *moe) = self._decode_scan_impl(*fargs, **fkw)
             js.finish(time.perf_counter() - t0)
             self._last_jit = js
         if self._multihost:
@@ -6211,7 +6278,11 @@ class InferenceEngine:
             # final=finished: flush any held-back UTF-8 tail — a stream
             # ending on an incomplete sequence would otherwise deliver
             # less text than the buffered response for the same request
+            t_detok = time.perf_counter()
             delta = self._incremental_text(req, final=finished)
+            # a row a token: two clock reads, no annotation each
+            self.flight.add_part("emit.detok",
+                                 time.perf_counter() - t_detok)
             if delta or finished:
                 try:
                     if req.stream_wants_count:

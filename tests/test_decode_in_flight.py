@@ -16,7 +16,9 @@ pinned here:
     stretch, and STRETCH_STEPS ends it when nothing else does;
   * the records: kind `decode`, the engine's impl, `chained`, `gap_s`,
     `wall_s`, a sparse model's `moe_*`, and the chained counter;
-  * which rows keep the synchronous step.
+  * which rows keep the synchronous step;
+  * why each stretch ended (PR 35): `chain_break` on the next record
+    that is not chained, `cake_chain_breaks_total{cause}`.
 """
 
 import dataclasses
@@ -33,6 +35,7 @@ from cake_tpu.models.llama.params import init_params
 from cake_tpu.obs import metrics as obs_metrics
 from cake_tpu.ops.sampling import SamplingConfig
 from cake_tpu.serve import engine as engine_mod
+from cake_tpu.obs.steps import LATE_FETCH_S
 from cake_tpu.serve.engine import STRETCH_STEPS, InferenceEngine
 
 T = 96
@@ -84,6 +87,18 @@ def serve(eng, requests, wait=300):
             assert h.wait(wait)
         state = (np.asarray(eng._keys), np.asarray(eng._ring))
     return hs, state
+
+
+def breaks(cause):
+    return obs_metrics.REGISTRY.get("cake_chain_breaks_total").labels(
+        cause=cause).value
+
+
+def spy_breaks(eng):
+    """Every cause the driver hands the recorder, in order."""
+    told, chain_broke = [], eng.flight.chain_broke
+    eng.flight.chain_broke = lambda c: (told.append(c), chain_broke(c))[1]
+    return told
 
 
 def decode_records(eng):
@@ -207,9 +222,12 @@ def _wait_tokens(h, n):
 @pytest.mark.parametrize("event", ["submit", "cancel"])
 def test_host_event_ends_the_stretch_within_one_step(tiny_config, params,
                                                      event):
+    cause = {"submit": "queue", "cancel": "cancel"}[event]
+    counted = breaks(cause)
     eng = make_engine(tiny_config, params, max_seq_len=512,
                       kv_pages=80, kv_page_size=8)
     seen = _spy_dispatches(eng)
+    told = spy_breaks(eng)
     with eng:
         long = eng.submit([5] * 9, max_new_tokens=400, **GREEDY)
         _wait_tokens(long, 5)
@@ -240,6 +258,16 @@ def test_host_event_ends_the_stretch_within_one_step(tiny_config, params,
                               if not chained and t > t_event)
         assert sum(t < first_unchained for t in chained_after) <= 1
         assert after is not None and other.token_ids
+        # the newcomer's first step is the one that says `queue`, and
+        # admitted one row
+        joined = next(r for r in reversed(eng.flight.dump())
+                      if other._req.rid in r["rids"])
+        assert joined["chain_break"] == "queue"
+        assert joined["rows_admitted"] == 1 and not joined["chained"]
+        assert breaks("queue") - counted >= 1
+    # the event ended the stretch by its own name (after a cancel
+    # nothing runs: the cause waits, the counter counts what landed)
+    assert told[0] == cause
 
 
 @pytest.mark.parametrize("flavour", ["dense", "paged-fold"])
@@ -271,12 +299,18 @@ def test_admitted_row_joins_the_step_after_its_prefill(tiny_config, params,
     joined = [r for r in after if rid_b in r["rids"]]
     assert joined and joined[0]["rows"] == 2
     assert len(joined) >= 38 and any(r["chained"] for r in joined)
+    if flavour == "dense":
+        # the one dispatch on the stale plan is a chain of one by the
+        # caller's word (chain=False): the step after it says `sync`
+        # (a paged engine's decode steps ride the mixed stretch on)
+        assert "sync" in [r.get("chain_break") for r in after[:3]]
 
 
 def test_stretch_length_returns_to_the_loop(tiny_config, params):
     """Nobody arrives, nothing ends: the stretch still hands the thread
     back to _run_loop every STRETCH_STEPS dispatches."""
     n = 2 * STRETCH_STEPS + 9
+    counted = breaks("stretch_cap")
     eng = make_engine(tiny_config, params, max_seq_len=256,
                       kv_pages=40, kv_page_size=8)
     serve(eng, [([5] * 9, dict(GREEDY, max_new_tokens=n + 1))])
@@ -286,6 +320,16 @@ def test_stretch_length_returns_to_the_loop(tiny_config, params):
     # chained onto it
     starts = [i for i, r in enumerate(recs) if not r["chained"]]
     assert starts == [0, STRETCH_STEPS, 2 * STRETCH_STEPS]
+    assert [recs[i].get("chain_break") for i in starts] == [
+        None, "stretch_cap", "stretch_cap"]
+    assert breaks("stretch_cap") - counted == 2
+    # a step whose successor was sent in time waited for the device;
+    # `late` is the host clock's word on the rest
+    chained = [r for r in recs if r["chained"]]
+    assert all("fetch_wait_s" in r and "late" in r for r in chained)
+    assert all(r["late"] == (r["fetch_wait_s"] < LATE_FETCH_S)
+               for r in chained)
+    assert not any("late" in recs[i] for i in starts)
 
 
 def test_finished_row_ends_the_stretch(tiny_config, params):
@@ -304,6 +348,8 @@ def test_finished_row_ends_the_stretch(tiny_config, params):
     assert recs[k]["chained"] and not recs[k + 1]["chained"]
     assert all(r["chained"] for r in recs[k + 2:])
     assert len(recs) == 29
+    assert recs[k + 1]["chain_break"] == "row_finished"
+    assert recs[k + 1]["rows_admitted"] == 0
 
 
 # -- the records ---------------------------------------------------------------

@@ -25,11 +25,15 @@ What is pinned here:
   * a step of several dispatches, a sparse model's counters, the latent
     model's one-window dispatches;
   * the records, the counter, the program's name and its one `n_top`
-    form; the paged speculative engine's mixed steps are not chained.
+    form; the paged speculative engine's mixed steps are not chained;
+  * why each chain ended (PR 35): every event names ITS cause on the
+    next record that is not chained and in `cake_chain_breaks_total`,
+    and the gates decide as they did before they said why.
 """
 
 import dataclasses
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -74,7 +78,7 @@ def make_engine(cfg, params, *, held_off=False, **kw):
         # the gate every stretch asks before it dispatches ahead: with
         # the host always wanting the loop back, each step is the same
         # program dispatched, fetched and emitted before the next
-        eng._host_attention_pending = lambda: True
+        eng._host_attention = lambda: "queue"
     return eng
 
 
@@ -106,6 +110,11 @@ def serve(eng, requests, later=(), wait=300):
             assert h.wait(wait)
         state = (np.asarray(eng._keys), np.asarray(eng._ring))
     return hs, state
+
+
+def breaks(cause):
+    return obs_metrics.REGISTRY.get("cake_chain_breaks_total").labels(
+        cause=cause).value
 
 
 def records(eng, kind=None):
@@ -274,6 +283,9 @@ def test_nothing_is_dispatched_ahead_after(tiny_config, params, event):
     """The event lands inside the emit of step k, with k+1 in flight:
     k+1 is completed, nothing is chained onto it, and the run loop
     plans the step after it (a new stretch's first: not chained)."""
+    cause = {"finish": "row_finished", "submit": "queue",
+             "cancel": "cancel", "command": "command"}[event]
+    counted = breaks(cause)
     eng = make_engine(tiny_config, params)
     n = 6 if event == "finish" else 4
     requests = [([5] * 9, dict(GREEDY, max_new_tokens=(
@@ -303,6 +315,13 @@ def test_nothing_is_dispatched_ahead_after(tiny_config, params, event):
     assert by_step[k]["chained"] and by_step[k + 1]["chained"]
     assert not by_step[k + 2]["chained"]
     assert by_step[k + 3]["chained"]
+    # the event names itself on the step the run loop planned after it,
+    # and on no chained record
+    assert by_step[k + 2]["chain_break"] == cause
+    assert breaks(cause) - counted >= 1
+    assert not any("chain_break" in r for r in by_step.values()
+                   if r["chained"])
+    assert by_step[k + 2]["rows_admitted"] == (event == "submit")
     rid = first.rid
     if event == "submit":
         assert hs[2]._req.rid in by_step[k + 2]["rids"]
@@ -330,6 +349,136 @@ def test_stretch_length_returns_to_the_loop(tiny_config, params):
     flags = [r["chained"] for r in records(eng)]
     assert [r["kind"] for r in records(eng)] == ["mixed"] * 40 + ["decode"] * 2
     assert [i for i, c in enumerate(flags) if not c] == [0, STRETCH_STEPS]
+
+
+@pytest.mark.parametrize("cause", ["stop", "stretch_cap", "window_end",
+                                   "sync", "budget", "idle"])
+def test_a_chain_names_why_it_ended(tiny_config, params, cause):
+    """What no request does to the loop from outside: a stop, the
+    stretch's length, the window's end, an engine that may not chain,
+    a plan out of budget, an idle loop. Each reaches the recorder as
+    itself (chain_broke, once a break) and lands on the next record
+    that is not chained."""
+    counted = breaks(cause)
+    kw, later = {}, []
+    requests = [([5] * 9, dict(GREEDY, max_new_tokens=12))]
+    if cause == "stretch_cap":
+        kw = dict(prefill_chunk=2, kv_pages=30)
+        requests = [([5] * 80, dict(GREEDY, max_new_tokens=3))]
+    elif cause == "window_end":
+        # a row three tokens from max_seq_len beside one that decodes
+        # on; submit() clamps a budget to the window, so the budget
+        # gate would speak first: lift the clamp once the row runs
+        requests = [([4, 9] * ((T - 4) // 2), dict(GREEDY, max_new_tokens=9)),
+                    ([8] * 9, dict(GREEDY, max_new_tokens=40))]
+        later = [(1, lambda hs: setattr(hs[0]._req, "max_new_tokens", 1000))]
+    elif cause == "sync":
+        kw = dict(spec_draft_params=params, spec_draft_config=tiny_config,
+                  spec_gamma=3)
+        later = [(3, ([7] * 29, dict(GREEDY, max_new_tokens=6)))]
+    eng = make_engine(tiny_config, params, **kw)
+    told = []
+    chain_broke = eng.flight.chain_broke
+    eng.flight.chain_broke = lambda c: (told.append(c), chain_broke(c))[1]
+    if cause == "stop":
+        h = eng.submit([5] * 9, max_new_tokens=60, **GREEDY)
+        emit = eng._emit
+
+        def hooked(req, *a, **k):
+            emit(req, *a, **k)
+            if len(req.out_tokens) == 4:
+                eng._stop.set()     # as stop() does, seen by the gate
+
+        eng._emit = hooked
+        eng.start()
+        eng._thread.join(120)
+        assert not eng._thread.is_alive()
+        recs = records(eng)
+        # the step in flight at the stop was fetched and emitted
+        assert len(h._req.out_tokens) == 5 and recs[-1]["chained"]
+        assert told == ["stop"]
+        # nothing ran after it: the cause waits for whoever would
+        landed = eng.flight.record("decode", wall_s=0.0, chained=False)
+        assert landed.chain_break == "stop"
+        eng.stop()
+    elif cause == "idle":
+        with eng:
+            for _ in range(2):
+                h = eng.submit([5] * 9, max_new_tokens=4, **GREEDY)
+                assert h.wait(300)
+                time.sleep(0.12)    # the loop finds nothing to run
+        heads = [r for r in records(eng) if not r["chained"]]
+        assert [r.get("chain_break") for r in heads] == [None, "idle"]
+        assert "gap_s" not in heads[1]
+        # the stretch before the wait ended as it would have anyway
+        assert told == ["budget", "budget"]
+    else:
+        serve(eng, requests, later)
+        heads = [r for r in records(eng)[1:] if not r.get("chained")]
+        if cause == "budget":
+            # the last token of the only row is in the step in flight:
+            # nothing is chained onto it, and nothing follows it
+            assert told == ["budget"] and not heads
+            return
+        assert {r.get("chain_break") for r in heads} >= {cause}, heads
+        assert cause in told
+        if cause == "stretch_cap":
+            assert [r["chain_break"] for r in heads] == ["stretch_cap"]
+        elif cause == "sync":
+            # every mixed step of the paged speculative engine is a
+            # chain of one; its rounds (kind `spec`) are no chain at all
+            recs = records(eng)
+            for before, r in zip(recs, recs[1:]):
+                assert (r.get("chain_break") == "sync") \
+                    == (before["kind"] == "mixed"), (before, r)
+    if cause != "budget":
+        assert breaks(cause) - counted >= 1
+
+
+# the fixed run of test_the_gates_decide_as_before, as the parent
+# (bbc166c, before the gates said why) recorded it: (kind, chained) of
+# every step in order, and every request's tokens
+M, D = "mixed", "decode"
+PARENT_STEPS = (
+    [(M, False)] + [(M, True)] * 3 + [(M, False), (M, True)]
+    + [(D, True)] * 6 + [(M, False)] + [(M, True)] * 2 + [(D, True)] * 8
+    + [(D, False)] + [(D, True)] * 9 + [(M, False), (M, True)]
+    + [(D, True)] * 2 + [(D, False)] + [(D, True)] * 4)
+PARENT_TOKENS = [
+    [52, 30, 178, 30, 178, 30, 3, 30, 3, 30, 3, 30] + [3] * 18
+    + [77, 21, 30] * 3 + [3],
+    [82, 199, 199, 199, 199, 199, 186], [104, 21, 235],
+    [9, 155, 43, 43, 43, 43, 43, 30], [162, 141]]
+
+
+def test_the_gates_decide_as_before(tiny_config, params):
+    """Same order, same terms: a fixed run (everything queued before
+    the loop starts, events inside fixed emits) gives the sequence of
+    record kinds and chained flags and the token streams the parent
+    gave (greedy, float32; the same under one, three and all CPU
+    threads), which are the streams of the engine with chaining held
+    off."""
+    requests = [([5, 6] * 10, dict(GREEDY, max_new_tokens=40)),
+                ([4] * 45, dict(GREEDY, max_new_tokens=7)),
+                ([9, 2, 7], dict(GREEDY, max_new_tokens=3))]
+    later = [(9, ([7] * 21, dict(GREEDY, max_new_tokens=8))),
+             (20, lambda hs: None),
+             (30, ([3] * 11, dict(GREEDY, max_new_tokens=2)))]
+    eng = make_engine(tiny_config, params, max_seq_len=64)
+    got, _ = serve(eng, requests, later)
+    want, _ = serve(make_engine(tiny_config, params, max_seq_len=64,
+                                held_off=True), requests, later)
+    assert_same_streams(got, want)
+    assert [h.token_ids for h in got] == PARENT_TOKENS
+    steps = [(r["kind"], bool(r["chained"])) for r in records(eng)]
+    assert steps == PARENT_STEPS
+    # and what the gates now say of each boundary: the third request's
+    # last token, the submit at token 9, a row's end, the submit at
+    # token 30, a row's end
+    assert [r.get("chain_break") for r in records(eng)
+            if not r["chained"]] == [None, "row_finished", "queue",
+                                     "row_finished", "queue",
+                                     "row_finished"]
 
 
 def test_stretches_cross_kinds(tiny_config, params):

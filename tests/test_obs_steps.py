@@ -485,6 +485,134 @@ def test_span_annotation_carries_the_records_step_number(monkeypatch):
                                      "emit", "wait"}
 
 
+# -- stretch boundaries: chain_break, parts, late ----------------------------
+
+
+@pytest.mark.parametrize("what", ["break", "part", "add_part"])
+def test_boundary_vocabularies_refuse_unknown_names(what):
+    st = obs_steps.StepTelemetry(impl="t")
+    with pytest.raises(ValueError, match="vocabulary"):
+        if what == "break":
+            st.chain_broke("tired")
+        elif what == "part":
+            with st.span("schedule"):
+                st.part("launch")      # dispatch's part, not schedule's
+        else:
+            st.add_part("emit.think", 0.001)
+    assert len(set(obs_steps.BREAKS)) == len(obs_steps.BREAKS) == 10
+    # every part names its phase
+    assert {p.split(".")[0] for p in obs_steps.PARTS} <= set(obs_steps.PHASES)
+
+
+def test_part_outside_an_open_span_raises():
+    st = obs_steps.StepTelemetry(impl="t")
+    with pytest.raises(ValueError, match="open span"):
+        st.part("plan")
+    with st.span("schedule"):
+        with st.part("plan"):
+            pass
+    with pytest.raises(ValueError, match="open span"):
+        st.part("plan")                # the span closed behind it
+
+
+def test_part_seconds_land_in_parts_and_once_in_phases(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(obs_steps.time, "perf_counter", clock)
+    st = obs_steps.StepTelemetry(impl="t")
+    with st.span("schedule"):
+        clock.t += 0.001
+        with st.part("plan"):
+            clock.t += 0.002
+        with st.part("admit_pages"):
+            clock.t += 0.003
+        with st.part("admit_pages"):       # a second admission adds up
+            clock.t += 0.003
+    with st.span("emit"):
+        clock.t += 0.004
+        st.add_part("emit.detok", 0.0015)
+        st.add_part("emit.detok", 0.0015)
+    # outside its span a measured part counts no more than the span
+    st.add_part("emit.detok", 0.5)
+    with st.span("dispatch"):
+        with st.part("launch"):
+            clock.t += 0.005
+    rec = st.record("mixed", wall_s=0.02, chained=False)
+    assert rec.parts == pytest.approx(
+        {"schedule.plan": 0.002, "schedule.admit_pages": 0.006,
+         "emit.detok": 0.003, "dispatch.launch": 0.005})
+    # the span's seconds are its own, parts included ONCE
+    assert rec.phases == pytest.approx(
+        {"schedule": 0.009, "emit": 0.004, "dispatch": 0.005})
+    d = rec.to_dict()
+    assert set(d["parts"]) == set(rec.parts)
+    assert set(d["phases"]) <= set(obs_steps.PHASES)
+    # a record carries `parts` only when it has any
+    assert "parts" not in st.record("decode", wall_s=0.01).to_dict()
+
+
+def test_chain_break_rides_the_next_unchained_record_only():
+    breaks = m.REGISTRY.get("cake_chain_breaks_total")
+    before = breaks.labels(cause="queue").value
+    st = obs_steps.StepTelemetry(impl="t")
+    first = st.record("mixed", wall_s=0.01, chained=False)
+    assert first.chain_break is None and first.rows_admitted == 0
+    assert "chain_break" not in first.to_dict()
+    st.chain_broke("queue")
+    st.admitted()
+    st.admitted(2)
+    flown = st.record("mixed", wall_s=0.01, chained=True)
+    assert flown.chain_break is None and flown.rows_admitted is None
+    assert "rows_admitted" not in flown.to_dict()
+    nxt = st.record("mixed", wall_s=0.01, chained=False)
+    assert nxt.to_dict()["chain_break"] == "queue"
+    assert nxt.to_dict()["rows_admitted"] == 3
+    # cleared by the record that took it; a record with no `chained`
+    # at all (the dense engine's prefill) counts as not chained
+    st.chain_broke("stretch_cap")
+    plain = st.record("prefill", wall_s=0.01)
+    assert plain.chain_break == "stretch_cap"
+    after = st.record("decode", wall_s=0.01, chained=False)
+    assert after.chain_break is None and after.rows_admitted == 0
+    assert breaks.labels(cause="queue").value - before == 1
+
+
+def test_wait_span_yields_idle(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(obs_steps.time, "perf_counter", clock)
+    st = obs_steps.StepTelemetry(impl="t")
+    _span(st, clock, "wait", 0.05)        # before the engine's first step
+    assert st.record("mixed", wall_s=0.01, chained=False).chain_break is None
+    st.chain_broke("row_finished")        # the last row of the plan
+    with st.span("schedule"):
+        with st.part("plan"):
+            clock.t += 0.001
+    _span(st, clock, "wait", 0.05)        # then nothing to run
+    rec = st.record("mixed", wall_s=0.01, chained=False)
+    assert rec.chain_break == "idle" and rec.gap_s is None
+    assert rec.parts is None              # what led up to the wait is nobody's
+
+
+@pytest.mark.parametrize("wait_s,late", [
+    (0.0, True), (obs_steps.LATE_FETCH_S / 2, True),
+    (obs_steps.LATE_FETCH_S, False), (0.012, False)])
+def test_late_follows_the_steps_own_fetch(wait_s, late):
+    counter = m.REGISTRY.get("cake_chained_steps_late_total")
+    before = counter.value
+    st = obs_steps.StepTelemetry(impl="t")
+    rec = st.record("decode", wall_s=0.015, chained=True,
+                    fetch_wait_s=wait_s)
+    assert rec.late is late
+    d = rec.to_dict()
+    assert d["late"] is late
+    assert d["fetch_wait_s"] == pytest.approx(wait_s, abs=1e-6)
+    assert counter.value - before == int(late)
+    # a stretch's first step waits for the whole step: never `late`
+    head = st.record("decode", wall_s=0.015, chained=False,
+                     fetch_wait_s=wait_s).to_dict()
+    assert "late" not in head and "fetch_wait_s" not in head
+    assert 1e-4 <= obs_steps.LATE_FETCH_S <= 2e-3
+
+
 @pytest.fixture(scope="module")
 def paged_engine():
     eng = _make_engine(kv_pages=16, kv_page_size=16)
@@ -592,15 +720,19 @@ def test_capture_holds_cake_spans_joined_by_step_number(tmp_path):
         recs = {r["step"]: r for r in eng.flight.dump()}
     assert got["xplane"] and got["perfetto_trace"] is None
     from jax.profiler import ProfileData
-    seen = {}
+    seen, causes = {}, {}
     for plane in ProfileData.from_file(got["xplane"]).planes:
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith("cake/"):
-                    step = int(dict(ev.stats)["step"])
+                    stats = dict(ev.stats)
+                    step = int(stats["step"])
                     seen.setdefault(step, {}).setdefault(
                         ev.name[5:], 0.0)
                     seen[step][ev.name[5:]] += ev.duration_ns / 1e9
+                    if "chain_break" in stats:
+                        assert ev.name == "cake/dispatch"
+                        causes[step] = stats["chain_break"]
     joined = [n for n in seen if n in recs and "dispatch" in seen[n]]
     assert len(joined) >= 4, (sorted(seen), sorted(recs))
     for n in joined:
@@ -608,3 +740,15 @@ def test_capture_holds_cake_spans_joined_by_step_number(tmp_path):
             # the same span on two clocks
             assert seen[n][name] == pytest.approx(
                 recs[n]["phases"][name], abs=2e-3)
+    # the boundary: the request arrived at an idle engine, so its first
+    # step's first dispatch says `idle`, where the record says it; the
+    # admission's parts lie on the host plane under that step's number
+    (first,) = causes
+    assert causes[first] == recs[first]["chain_break"] == "idle"
+    for part in ("schedule.plan", "schedule.admit_pages",
+                 "schedule.admit_ring", "dispatch.launch"):
+        assert seen[first][part] == pytest.approx(
+            recs[first]["parts"][part], abs=2e-3), part
+    assert recs[first]["rows_admitted"] == 1
+    assert any("dispatch.launch" in seen[n] for n in joined if n != first)
+
