@@ -6,7 +6,9 @@ tells us not to replicate). Growing shapes force recompilation under XLA, so
 the TPU design preallocates `[num_layers, batch, max_seq, kv_heads, head_dim]`
 buffers and writes each step's k/v with `dynamic_update_slice`; the absolute
 write position is a traced scalar, so prefill and every decode step reuse one
-compiled program.
+compiled program. The writers below take that STACKED buffer and a layer index
+and write in place (the layout contract above them); the loops around them
+carry it (model.scan_layers, parallel/pipeline._gpipe_stage_loop).
 
 Per-session isolation (reference `Cache::as_new`, cache.rs:125-129) is
 `KVCache.fresh()` — a zeroed cache of the same spec; `clear()` semantics
@@ -53,104 +55,128 @@ class KVCache(NamedTuple):
         return self.k.shape[1]
 
 
+def layer_rows(cache, layer, row0, n):
+    """Layer `layer`'s lines of rows row0..row0+n of a stacked buffer
+    [L, B, T, KV, hd] -> [n, T, KV, hd]: what attention reads."""
+    return lax.dynamic_slice(
+        cache, (layer, row0, 0, 0, 0), (1, n) + cache.shape[2:])[0]
+
+
+# -- writers --------------------------------------------------------------------
+#
+# Layout contract (PERF.md section 3): the stacked cache [L, B, T, KV, hd] is
+# the CARRY of every loop around it (the layer loop of model.run_blocks /
+# run_blocks_ragged, the GPipe tick of parallel/pipeline.py), donated in and
+# aliased out. A writer takes the whole buffer plus the layer index and does
+# ONE scatter / dynamic_update_slice at [layer, row, pos]; it returns the same
+# buffer. What must not be written (an inactive row, a pipeline bubble's
+# microbatch, a padded ring entry) is gated at the ROWS written (a scatter
+# routes them out of bounds and drops them, a window write puts back the
+# window that was there): never by a select over the cache. row0 is the
+# first batch row of the rows at hand (a microbatch of the tick; 0 = all).
+
+
+def _scatter(k_cache, v_cache, at, new_k, new_v):
+    """One scatter each of new_k / new_v at index `at`; out-of-bounds
+    targets drop."""
+    return (k_cache.at[at].set(new_k.astype(k_cache.dtype), mode="drop"),
+            v_cache.at[at].set(new_v.astype(v_cache.dtype), mode="drop"))
+
+
 @jax.named_scope("kv")
-def update_layer_cache_per_row(k_cache, v_cache, new_k, new_v, pos, active):
+def update_layer_cache_per_row(k_cache, v_cache, layer, new_k, new_v, pos,
+                               active, row0=0):
     """Write one new k/v per row at that row's own position (ragged decode).
 
-    k_cache/v_cache: [B, S_max, KV, hd]
-    new_k/new_v:     [B, 1, KV, hd] (single decode token per row)
-    pos:             [B] absolute positions (one per row)
-    active:          [B] bool; inactive rows keep their existing cache line
-                     (their pos may be stale — a retired slot must not
-                     corrupt state a future prefill won't overwrite).
+    k_cache/v_cache: [L, B, S_max, KV, hd]
+    new_k/new_v:     [n, 1, KV, hd] (single decode token per row)
+    pos:             [n] absolute positions (one per row)
+    active:          [n] bool; inactive rows keep their existing cache line
+                     (their pos may be stale: a retired slot must not
+                     corrupt state a future prefill won't overwrite):
+                     they route to the out-of-bounds row and drop.
     """
-    b = jnp.arange(k_cache.shape[0])
-    sel = active[:, None, None]
-    old_k = k_cache[b, pos]
-    old_v = v_cache[b, pos]
-    k_cache = k_cache.at[b, pos].set(
-        jnp.where(sel, new_k[:, 0].astype(k_cache.dtype), old_k))
-    v_cache = v_cache.at[b, pos].set(
-        jnp.where(sel, new_v[:, 0].astype(v_cache.dtype), old_v))
-    return k_cache, v_cache
+    B = k_cache.shape[1]
+    b = jnp.where(active, row0 + jnp.arange(new_k.shape[0]), B)
+    return _scatter(k_cache, v_cache, (layer, b, pos), new_k[:, 0],
+                    new_v[:, 0])
 
 
 @jax.named_scope("kv")
-def update_layer_cache(k_cache, v_cache, new_k, new_v, pos):
-    """Write one layer's new k/v at absolute position `pos`.
+def update_layer_cache(k_cache, v_cache, layer, new_k, new_v, pos, row0=0,
+                       live=None):
+    """Write a layer's new k/v window at absolute position `pos`.
 
-    k_cache/v_cache: [B, S_max, KV, hd]
-    new_k/new_v:     [B, S, KV, hd]
+    k_cache/v_cache: [L, B, S_max, KV, hd]
+    new_k/new_v:     [n, S, KV, hd]
     pos:             traced scalar start index
-    Returns the updated buffers (same shapes — jit-donatable).
+    live:            traced bool or None; False (a pipeline bubble) puts
+                     back the [n, S, KV, hd] window that was there.
+    Returns the updated buffers (same shapes, jit-donatable).
     """
-    zeros = (0, pos, 0, 0)
-    k_cache = lax.dynamic_update_slice(k_cache, new_k.astype(k_cache.dtype), zeros)
-    v_cache = lax.dynamic_update_slice(v_cache, new_v.astype(v_cache.dtype), zeros)
-    return k_cache, v_cache
+    start = (layer, row0, pos, 0, 0)
+
+    def write(cache, new):
+        new = new.astype(cache.dtype)[None]
+        if live is not None:
+            new = jnp.where(live, new,
+                            lax.dynamic_slice(cache, start, new.shape))
+        return lax.dynamic_update_slice(cache, new, start)
+
+    return write(k_cache, new_k), write(v_cache, new_v)
 
 
 # -- ring-buffer (sliding-window) writes --------------------------------------
 
 @jax.named_scope("kv")
-def update_layer_cache_ring(k_cache, v_cache, new_k, new_v, pos, n_real=None):
+def update_layer_cache_ring(k_cache, v_cache, layer, new_k, new_v, pos,
+                            n_real=None, row0=0):
     """Write S <= W new k/v at ring slots (pos+i) % W.
 
-    k_cache/v_cache: [B, W, KV, hd] ring buffers (W = window capacity)
-    new_k/new_v:     [B, S, KV, hd]
+    k_cache/v_cache: [L, B, W, KV, hd] ring buffers (W = window capacity)
+    new_k/new_v:     [n, S, KV, hd]
     pos:             traced scalar absolute start position
     n_real:          traced count of REAL tokens in the window; entries
-                     i >= n_real keep the slot's previous content — a
+                     i >= n_real keep the slot's previous content: a
                      padded chunk's junk would otherwise alias ring slots
                      of positions still inside upcoming queries' windows
                      (the dense cache never had this hazard: junk landed
-                     at untouched higher positions).
+                     at untouched higher positions). 0 writes nothing
+                     (a pipeline bubble).
     """
-    B, W = k_cache.shape[0], k_cache.shape[1]
-    S = new_k.shape[1]
+    W = k_cache.shape[2]
+    n, S = new_k.shape[:2]
     assert S <= W, f"ring write of {S} tokens exceeds ring capacity {W}"
-    slots = jnp.mod(pos + jnp.arange(S), W)                  # [S] unique
-    keep = (jnp.arange(S) >= (S if n_real is None else n_real))
-    old_k = k_cache[:, slots]
-    old_v = v_cache[:, slots]
-    sel = keep[None, :, None, None]
-    k_cache = k_cache.at[:, slots].set(
-        jnp.where(sel, old_k, new_k.astype(k_cache.dtype)))
-    v_cache = v_cache.at[:, slots].set(
-        jnp.where(sel, old_v, new_v.astype(v_cache.dtype)))
-    return k_cache, v_cache
+    b = (row0 + jnp.arange(n))[:, None]
+    i = jnp.arange(S)
+    slots = jnp.mod(pos + i, W)                              # [S] unique
+    if n_real is not None:
+        slots = jnp.where(i < n_real, slots, W)              # dropped
+    return _scatter(k_cache, v_cache, (layer, b, slots[None]), new_k, new_v)
 
 
-def update_layer_cache_per_row_ring(k_cache, v_cache, new_k, new_v, pos,
-                                    active):
+def update_layer_cache_per_row_ring(k_cache, v_cache, layer, new_k, new_v,
+                                    pos, active, row0=0):
     """Ragged single-token ring write: row b writes at slot pos[b] % W."""
-    W = k_cache.shape[1]
-    return update_layer_cache_per_row(k_cache, v_cache, new_k, new_v,
-                                      jnp.mod(pos, W), active)
+    W = k_cache.shape[2]
+    return update_layer_cache_per_row(k_cache, v_cache, layer, new_k, new_v,
+                                      jnp.mod(pos, W), active, row0)
 
 
 @jax.named_scope("kv")
-def update_layer_cache_window_per_row(k_cache, v_cache, new_k, new_v,
-                                      pos0, active):
+def update_layer_cache_window_per_row(k_cache, v_cache, layer, new_k, new_v,
+                                      pos0, active, row0=0):
     """Write a W-token window per row at that row's own start position
     (the batched speculative verify: row b's tokens j land at absolute
     positions pos0[b]+j).
 
-    k_cache/v_cache: [B, S_max, KV, hd]
-    new_k/new_v:     [B, W, KV, hd]
-    pos0:            [B] absolute start positions
-    active:          [B] bool; inactive rows keep their cache lines.
-    Indices clamp at S_max-1 (callers bound pos0+W <= S_max; the clamp
-    only protects inactive rows' stale pos0)."""
-    B, W = new_k.shape[:2]
-    b = jnp.arange(B)[:, None]
-    idx = jnp.clip(pos0[:, None] + jnp.arange(W)[None],
-                   0, k_cache.shape[1] - 1)
-    sel = active[:, None, None, None]
-    old_k = k_cache[b, idx]
-    old_v = v_cache[b, idx]
-    k_cache = k_cache.at[b, idx].set(
-        jnp.where(sel, new_k.astype(k_cache.dtype), old_k))
-    v_cache = v_cache.at[b, idx].set(
-        jnp.where(sel, new_v.astype(v_cache.dtype), old_v))
-    return k_cache, v_cache
+    k_cache/v_cache: [L, B, S_max, KV, hd]
+    new_k/new_v:     [n, W, KV, hd]
+    pos0:            [n] absolute start positions
+    active:          [n] bool; inactive rows keep their cache lines.
+    Positions clamp at S_max-1 (callers bound pos0+W <= S_max)."""
+    n, W = new_k.shape[:2]
+    B, T = k_cache.shape[1:3]
+    b = jnp.where(active, row0 + jnp.arange(n), B)[:, None]
+    idx = jnp.clip(pos0[:, None] + jnp.arange(W)[None], 0, T - 1)
+    return _scatter(k_cache, v_cache, (layer, b, idx), new_k, new_v)

@@ -23,7 +23,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from cake_tpu.models.llama.cache import (
-    KVCache, update_layer_cache, update_layer_cache_per_row,
+    KVCache, layer_rows, update_layer_cache, update_layer_cache_per_row,
+    update_layer_cache_per_row_ring, update_layer_cache_ring,
+    update_layer_cache_window_per_row,
 )
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.ops.attention import (
@@ -156,27 +158,31 @@ def block_skeleton(lp, x, config: LlamaConfig, attn_fn,
     return x, extras
 
 
-def block_forward(lp, x, k_cache, v_cache, pos, rope_c, rope_s, mask,
+def block_forward(lp, x, k_cache, v_cache, layer, pos, rope_c, rope_s, mask,
                   config: LlamaConfig, tp_axis: Optional[str] = None,
                   ep_axis: Optional[str] = None,
                   is_prefill: bool = False, chunked: bool = False,
-                  ring: bool = False, write_len=None):
+                  ring: bool = False, write_len=None, row0=0, live=None):
     """One decoder block with KV-cache update.
 
     lp: single-layer param dict (leaves without the L axis)
-    x:  [B, S, D]; k_cache/v_cache: [B, T, KV, hd]; pos: traced scalar
+    x:  [n, S, D]; k_cache/v_cache: the STACKED cache [L, B, T, KV, hd],
+    of which this block writes and attends [layer, row0:row0+n] (cache.py's
+    layout contract); pos: traced scalar
     rope_c/rope_s: [S, hd/2] rows for positions pos..pos+S
     mask: [S, T] boolean
     chunked: static — this prefill window continues an existing cache
     (pos may be > 0), so flash must use the cache-aware kernel; fresh
     whole-prompt prefill (pos == 0 by contract) uses the cheaper
     S-window kernel that never touches the cache tail.
+    live: traced bool or None; False (a pipeline bubble) writes nothing.
+    Returns (x, k_cache, v_cache).
     """
-    S = x.shape[1]
+    n, S = x.shape[:2]
+    T = k_cache.shape[2]
 
     def attn_fn(q, k, v):
         H, KV = q.shape[2], k.shape[2]
-        T = k_cache.shape[1]
         q = apply_rope(q, rope_c, rope_s)
         k = apply_rope(k, rope_c, rope_s)
         if ring:
@@ -185,16 +191,21 @@ def block_forward(lp, x, k_cache, v_cache, pos, rope_c, rope_s, mask,
             # destroy in-window history its own early queries need), then
             # write. Ring slots permute key positions, which the flash
             # kernels' sequential-position masks cannot express -> einsum.
-            from cake_tpu.models.llama.cache import update_layer_cache_ring
             k_full = jnp.concatenate(
-                [k_cache, k.astype(k_cache.dtype)], axis=1)
+                [layer_rows(k_cache, layer, row0, n),
+                 k.astype(k_cache.dtype)], axis=1)
             v_full = jnp.concatenate(
-                [v_cache, v.astype(v_cache.dtype)], axis=1)
+                [layer_rows(v_cache, layer, row0, n),
+                 v.astype(v_cache.dtype)], axis=1)
             attn = gqa_attention(q, k_full, v_full, mask=mask)
-            kc, vc = update_layer_cache_ring(k_cache, v_cache, k, v, pos,
-                                             n_real=write_len)
-            return attn, (kc, vc)
-        kc, vc = update_layer_cache(k_cache, v_cache, k, v, pos)
+            n_real = write_len
+            if live is not None:
+                n_real = jnp.where(live, S if n_real is None else n_real, 0)
+            return attn, update_layer_cache_ring(
+                k_cache, v_cache, layer, k, v, pos, n_real=n_real,
+                row0=row0)
+        kc, vc = update_layer_cache(k_cache, v_cache, layer, k, v, pos,
+                                    row0=row0, live=live)
         use_flash = is_prefill and config.use_flash_attention
         if use_flash and not chunked and flash_supported(S, S, H, KV, hd=config.head_dim):
             # Fresh prompt at pos=0 with an empty cache: causal attention
@@ -211,8 +222,10 @@ def block_forward(lp, x, k_cache, v_cache, pos, rope_c, rope_s, mask,
             # Continued prefill at pos>0: the cache-aware kernel attends
             # the cache under kj <= pos+qi; key blocks past the frontier
             # neither compute nor DMA (index-map clamp).
-            attn = flash_attention_cached(q, kc, vc, pos,
-                                          window=config.sliding_window)
+            attn = flash_attention_cached(
+                q, layer_rows(kc, layer, row0, n),
+                layer_rows(vc, layer, row0, n), pos,
+                window=config.sliding_window)
         else:
             if use_flash:
                 if (chunked and flash_supported(S, T, H, KV, hd=config.head_dim)
@@ -226,12 +239,34 @@ def block_forward(lp, x, k_cache, v_cache, pos, rope_c, rope_s, mask,
                         "flash attention requested but unsupported for "
                         "S=%d T=%d H=%d KV=%d (non-tileable shapes) — "
                         "falling back to the einsum path", S, T, H, KV)
-            attn = gqa_attention(q, kc, vc, mask=mask)
+            attn = gqa_attention(q, layer_rows(kc, layer, row0, n),
+                                 layer_rows(vc, layer, row0, n), mask=mask)
         return attn, (kc, vc)
 
     x, (k_cache, v_cache) = block_skeleton(lp, x, config, attn_fn,
                                            tp_axis=tp_axis, ep_axis=ep_axis)
     return x, k_cache, v_cache
+
+
+def scan_layers(blocks, x, cache: KVCache, layer_fn):
+    """The layer loop of every dense step program: scan `blocks` alone,
+    with the stacked cache as CARRY beside the hidden state and the layer
+    index (as paged.scan_layers_paged carries the pool). A scan's stacked
+    outputs cannot alias its inputs, so a cache passed as xs/ys is sliced
+    per layer, stacked back and kept twice; a carried cache that each
+    layer writes its rows into is one buffer from the donated input to
+    the output.
+
+    layer_fn(lp, h, layer, k, v) -> (h, k, v)."""
+    def body(carry, lp):
+        h, layer, k, v = carry
+        h, k, v = layer_fn(lp, h, layer, k, v)
+        return (h, layer + 1, k, v), None
+
+    with jax.named_scope("layers"):
+        (x, _, k, v), _ = lax.scan(
+            body, (x, jnp.int32(0), cache.k, cache.v), blocks)
+    return x, KVCache(k=k, v=v)
 
 
 def run_blocks(blocks, x, cache: KVCache, pos, rope_c, rope_s, mask,
@@ -241,25 +276,24 @@ def run_blocks(blocks, x, cache: KVCache, pos, rope_c, rope_s, mask,
                is_prefill: bool = False,
                chunked: bool = False,
                ring: bool = False,
-               write_len=None) -> Tuple[jnp.ndarray, KVCache]:
+               write_len=None, row0=0, live=None
+               ) -> Tuple[jnp.ndarray, KVCache]:
     """Scan the stacked blocks [L, ...] over the hidden state.
 
     This is the TPU equivalent of the reference's sequential block walk with
     contiguous-run batching (llama.rs:81-117): the scan compiles the whole
     contiguous range into one XLA program, so "batch blocks per hop" holds
-    by construction.
+    by construction. x: [n, S, D] is rows row0..row0+n of the cache's
+    batch (a microbatch of the pipeline's tick; all of it by default).
     """
-    def body(h, xs):
-        lp, kc, vc = xs
-        h, kc, vc = block_forward(lp, h, kc, vc, pos, rope_c, rope_s, mask,
-                                  config, tp_axis=tp_axis, ep_axis=ep_axis,
-                                  is_prefill=is_prefill, chunked=chunked,
-                                  ring=ring, write_len=write_len)
-        return h, (kc, vc)
+    def layer_fn(lp, h, layer, k, v):
+        return block_forward(lp, h, k, v, layer, pos, rope_c, rope_s, mask,
+                             config, tp_axis=tp_axis, ep_axis=ep_axis,
+                             is_prefill=is_prefill, chunked=chunked,
+                             ring=ring, write_len=write_len, row0=row0,
+                             live=live)
 
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
-    return x, KVCache(k=k_new, v=v_new)
+    return scan_layers(blocks, x, cache, layer_fn)
 
 
 def forward(params, tokens, cache: KVCache, pos, rope: RopeTables,
@@ -349,52 +383,44 @@ def run_blocks_ragged(blocks, x, cache: KVCache, pos, active,
                       tp_axis: Optional[str] = None,
                       ep_axis: Optional[str] = None,
                       ring: bool = False,
-                      cache_update=None
+                      cache_update=None, row0=0
                       ) -> Tuple[jnp.ndarray, KVCache]:
     """Scan the stacked blocks for per-row-position ragged decode.
 
-    x: [B, S, D]; pos/active: [B]; rope_c/rope_s: [B, S, hd/2] per-row
-    rows; mask: [B, S, T]. S = 1 for single-token decode; the batched
-    speculative verify passes S = gamma+1 windows with its own
-    cache_update. Inactive rows compute garbage but leave their cache
-    lines untouched. Shared by the single-device ragged decode, the
-    pipelined engine step (parallel/pipeline.py — stage-local
-    blocks/cache views), and forward_window_ragged, so the block-scan
-    attention wiring exists exactly once.
+    x: [n, S, D], rows row0..row0+n of the cache's batch; pos/active: [n];
+    rope_c/rope_s: [n, S, hd/2] per-row rows; mask: [n, S, T]. S = 1 for
+    single-token decode; the batched speculative verify passes
+    S = gamma+1 windows with its own cache_update. Inactive rows compute
+    garbage but leave their cache lines untouched. Shared by the
+    single-device ragged decode, the pipelined engine step
+    (parallel/pipeline.py: stage-local blocks/cache views, a microbatch's
+    rows, `active` false all through a bubble tick), and
+    forward_window_ragged, so the block-scan attention wiring exists
+    exactly once.
 
-    cache_update(kc, vc, k, v) -> (kc', vc'): override the per-layer KV
-    write; default = single-token per-row write (ring-modular when
-    ring=True)."""
+    cache_update(k_cache, v_cache, layer, k, v, pos, active, row0)
+    -> (k_cache, v_cache): the writer of the step's KV into the stacked
+    cache (cache.py's layout contract); default = single-token per-row
+    write (ring-modular when ring=True)."""
     if cache_update is None:
-        if ring:
-            from cake_tpu.models.llama.cache import (
-                update_layer_cache_per_row_ring,
-            )
+        cache_update = (update_layer_cache_per_row_ring if ring
+                        else update_layer_cache_per_row)
+    n = x.shape[0]
 
-            def cache_update(kc, vc, k, v):
-                return update_layer_cache_per_row_ring(kc, vc, k, v,
-                                                       pos, active)
-        else:
-            def cache_update(kc, vc, k, v):
-                return update_layer_cache_per_row(kc, vc, k, v, pos,
-                                                  active)
-
-    def body(h, xs):
-        lp, kc, vc = xs
-
+    def layer_fn(lp, h, layer, kc, vc):
         def attn_fn(q, k, v):
             q = apply_rope(q, rope_c, rope_s)
             k = apply_rope(k, rope_c, rope_s)
-            kc2, vc2 = cache_update(kc, vc, k, v)
-            return gqa_attention(q, kc2, vc2, mask=mask), (kc2, vc2)
+            kc2, vc2 = cache_update(kc, vc, layer, k, v, pos, active, row0)
+            attn = gqa_attention(q, layer_rows(kc2, layer, row0, n),
+                                 layer_rows(vc2, layer, row0, n), mask=mask)
+            return attn, (kc2, vc2)
 
         h, (kc, vc) = block_skeleton(lp, h, config, attn_fn,
                                      tp_axis=tp_axis, ep_axis=ep_axis)
-        return h, (kc, vc)
+        return h, kc, vc
 
-    with jax.named_scope("layers"):
-        x, (k_new, v_new) = lax.scan(body, x, (blocks, cache.k, cache.v))
-    return x, KVCache(k=k_new, v=v_new)
+    return scan_layers(blocks, x, cache, layer_fn)
 
 
 def ragged_decode(params, tokens, pos, active, cache: KVCache,
@@ -474,17 +500,9 @@ def forward_window_ragged(params, tokens, cache: KVCache, pos0, active,
     kj = jax.lax.broadcasted_iota(jnp.int32, (B, W, T), 2)
     mask = kj <= p[:, :, None]
 
-    from cake_tpu.models.llama.cache import (
-        update_layer_cache_window_per_row,
-    )
-
-    def window_update(kc, vc, k, v):
-        return update_layer_cache_window_per_row(kc, vc, k, v, pos0,
-                                                 active)
-
-    x, cache = run_blocks_ragged(params["blocks"], x, cache, pos0,
-                                 active, rope_c, rope_s, mask, config,
-                                 cache_update=window_update)
+    x, cache = run_blocks_ragged(
+        params["blocks"], x, cache, pos0, active, rope_c, rope_s, mask,
+        config, cache_update=update_layer_cache_window_per_row)
     x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
     logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, cache

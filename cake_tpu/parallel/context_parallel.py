@@ -907,9 +907,11 @@ def make_sp_engine_decode_body(config: LlamaConfig, tp_axis, Sl: int,
 
         def tail_update(tk, tv, k, v):
             # per-row active-masked write (ragged slots), vs the
-            # lockstep scalar-slot default
-            return update_layer_cache_per_row(tk, tv, k, v, t_slot,
-                                              active)
+            # lockstep scalar-slot default; the tail is this chain's
+            # scanned layer, a stack of one to the dense writer
+            tk, tv = update_layer_cache_per_row(tk[None], tv[None], 0,
+                                                k, v, t_slot, active)
+            return tk[0], tv[0]
 
         layer = sp_decode_layer(config, rope_c, rope_s, None, ctx_valid,
                                 tail_valid, tp_axis,

@@ -16,6 +16,17 @@ Composability: the stage body optionally runs manually tensor-parallel
 `model.block_forward(tp_axis=...)`) and data-parallel (`dp` axis shards the
 batch; no collectives in the block math), so one shard_mapped program covers
 dp x pp x tp.
+
+The cache under the tick: a stage's dense cache [L_local, B, T, KV_local,
+hd] is the CARRY of the tick loop and of the layer loop inside it, donated
+in and aliased out. The tick hands `run_microbatch` the whole buffers, the
+microbatch's row offset and `live`; it never slices, selects or splices a
+cache. A bubble tick (a stage with no microbatch yet, or none left) still
+runs its blocks, which costs no wall time while another stage works, but
+writes no row: the ragged body folds `live` into `active`, the uniform
+body puts back the [mb, S] window it would have written
+(models/llama/cache.py's layout contract). A page pool under the tick
+needs the same form: a pool cannot be select-masked either.
 """
 
 from __future__ import annotations
@@ -43,12 +54,14 @@ from cake_tpu.ops.rope import rope_rows
 def _gpipe_stage_loop(k, v, x, run_microbatch, *, num_microbatches: int):
     """Shared GPipe tick schedule (runs under shard_map, per-device views).
 
-    k, v: [L_local, B, T, KV_local, hd]; x: [B, S, D] (replicated over
-    stage). `run_microbatch(inp, k_mb, v_mb, idx, mb)` runs this stage's
-    blocks on one microbatch and returns (y, k_mb_new, v_mb_new); callers
-    close over whatever per-row state they need and slice it with
-    (idx, mb). Returns (out, k, v) with out valid on every stage after the
-    final broadcast.
+    k, v: [L_local, B, T, KV_local, hd], carried whole (module docstring);
+    x: [B, S, D] (replicated over stage).
+    `run_microbatch(inp, k, v, idx, mb, live)` runs this stage's blocks on
+    the microbatch whose rows are idx..idx+mb of the carried buffers and
+    returns (y, k, v) written in place; with `live` False (a bubble) it
+    writes no row, and its y is discarded here. Callers close over
+    whatever per-row state they need and slice it with (idx, mb). Returns
+    (out, k, v) with out valid on every stage after the final broadcast.
     """
     nstages = lax.axis_size("stage")
     sid = lax.axis_index("stage")
@@ -69,16 +82,7 @@ def _gpipe_stage_loop(k, v, x, run_microbatch, *, num_microbatches: int):
         fresh = lax.dynamic_slice_in_dim(x, idx, mb, axis=0)
         inp = jnp.where(sid == 0, fresh, buf)
 
-        with jax.named_scope("kv"):
-            k_mb = lax.dynamic_slice_in_dim(k, idx, mb, axis=1)
-            v_mb = lax.dynamic_slice_in_dim(v, idx, mb, axis=1)
-        y, k_new, v_new = run_microbatch(inp, k_mb, v_mb, idx, mb)
-        with jax.named_scope("kv"):
-            # mask side effects when this stage has no live microbatch
-            k_wr = jnp.where(live, k_new, k_mb)
-            v_wr = jnp.where(live, v_new, v_mb)
-            k = lax.dynamic_update_slice_in_dim(k, k_wr, idx, axis=1)
-            v = lax.dynamic_update_slice_in_dim(v, v_wr, idx, axis=1)
+        y, k, v = run_microbatch(inp, k, v, idx, mb, live)
 
         is_last = sid == nstages - 1
         cur = lax.dynamic_slice_in_dim(out, idx, mb, axis=0)
@@ -108,17 +112,19 @@ def _stage_pipeline_body(blocks, k, v, x, pos, wlen, rope_c, rope_s,
                          chunked: bool = False, ring: bool = False):
     """Per-device body for uniform-position forward (prefill / batch
     decode): pos, rope rows and mask are shared across the batch.
-    ring/wlen: sliding-window ring cache (stage-local [L_local, B, W]
-    slices; writes wrap at W with wlen junk-masking — model.run_blocks
-    ring semantics, identical per stage).
+    A bubble tick puts back the [mb, S] window it would have written
+    (model.run_blocks live=). ring/wlen: sliding-window ring cache
+    (stage-local [L_local, B, W] slices; writes wrap at W with wlen
+    junk-masking: model.run_blocks ring semantics, identical per stage).
     """
-    def run_microbatch(inp, k_mb, v_mb, idx, mb):
-        y, cache_mb = run_blocks(
-            blocks, inp, KVCache(k_mb, v_mb), pos, rope_c, rope_s, mask,
+    def run_microbatch(inp, k, v, idx, mb, live):
+        y, cache = run_blocks(
+            blocks, inp, KVCache(k, v), pos, rope_c, rope_s, mask,
             config, tp_axis=tp_axis, is_prefill=is_prefill,
-            chunked=chunked, ring=ring, write_len=wlen,
+            chunked=chunked, ring=ring, write_len=wlen, row0=idx,
+            live=live,
         )
-        return y, cache_mb.k, cache_mb.v
+        return y, cache.k, cache.v
 
     return _gpipe_stage_loop(k, v, x, run_microbatch,
                              num_microbatches=num_microbatches)
@@ -223,17 +229,19 @@ def _stage_pipeline_body_ragged(blocks, k, v, x, pos, active,
                                 ring: bool = False):
     """Per-device GPipe body for per-row-position single-token decode:
     every per-row quantity (pos, active, rope rows, mask) is sliced per
-    microbatch and the stage runs `run_blocks_ragged`. x: [B, 1, D].
+    microbatch and the stage runs `run_blocks_ragged` on that
+    microbatch's rows of the carried cache; on a bubble tick no row is
+    active. x: [B, 1, D].
     """
-    def run_microbatch(inp, k_mb, v_mb, idx, mb):
+    def run_microbatch(inp, k, v, idx, mb, live):
         sl = partial(lax.dynamic_slice_in_dim, start_index=idx,
                      slice_size=mb, axis=0)
-        y, cache_mb = run_blocks_ragged(
-            blocks, inp, KVCache(k_mb, v_mb), sl(pos), sl(active),
+        y, cache = run_blocks_ragged(
+            blocks, inp, KVCache(k, v), sl(pos), sl(active) & live,
             sl(rope_c), sl(rope_s), sl(mask), config, tp_axis=tp_axis,
-            ring=ring,
+            ring=ring, row0=idx,
         )
-        return y, cache_mb.k, cache_mb.v
+        return y, cache.k, cache.v
 
     return _gpipe_stage_loop(k, v, x, run_microbatch,
                              num_microbatches=num_microbatches)
