@@ -134,22 +134,21 @@ class Context:
         # host/device copy ever exists, which is what lets a 70B (or
         # Mixtral-8x22B) topology actually load instead of dying at the
         # eager full-tree load.
-        if getattr(cfg, "kv_lora_rank", None) and (
+        one_chip_only = next((family for attr, family in (
+            ("kv_lora_rank", "glm_moe_dsa (latent attention over the "
+                             "page pool)"),
+            ("mamba_layers", "nemotron_h (a recurrent state a row "
+                             "beside the page pool)"),
+            ("cca_time0", "zaya (a conv tail a row beside the page "
+                          "pool)")) if getattr(cfg, attr, None)), None)
+        if one_chip_only and (
                 plan.stages > 1 or plan.tp > 1 or plan.dp > 1 or a.sp > 1
                 or a.draft_model is not None):
             raise ValueError(
-                "model_type glm_moe_dsa (latent attention over the page "
-                "pool) does not serve yet over a topology, --tp, --dp, "
-                "--sp or with --draft-model: one chip's paged engine "
-                "only (ROADMAP.md lists each as left to do)")
-        if getattr(cfg, "mamba_layers", None) and (
-                plan.stages > 1 or plan.tp > 1 or plan.dp > 1 or a.sp > 1
-                or a.draft_model is not None):
-            raise ValueError(
-                "model_type nemotron_h (a recurrent state a row beside "
-                "the page pool) does not serve yet over a topology, "
-                "--tp, --dp, --sp or with --draft-model: one chip's "
-                "paged engine only (ROADMAP.md lists each as left to do)")
+                f"model_type {one_chip_only} does not serve yet over a "
+                "topology, --tp, --dp, --sp or with --draft-model: one "
+                "chip's paged engine only (ROADMAP.md lists each as left "
+                "to do)")
         born_sharded = (
             (plan.stages > 1 or plan.tp > 1 or plan.dp > 1)
             and (a.sp <= 1 or plan.stages > 1)

@@ -36,6 +36,10 @@ MODEL_TYPES = {
     "nemotron_h": "one mixer a block: Mamba-2 with a recurrent state a row "
                   "beside the page pool, relu² experts in a latent with a "
                   "shared one, GQA without positions (paged engine)",
+    "zaya": "compressed convolutional attention with a conv tail a row "
+            "beside the page pool, half of each head rotated, an MLP "
+            "router whose state runs down the layers, one expert a token "
+            "(paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -57,6 +61,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "nemotron_h":
         from cake_tpu.models.moe.config import NemotronHConfig
         return NemotronHConfig.from_hf_dict(raw)
+    if model_type == "zaya":
+        from cake_tpu.models.moe.config import ZayaConfig
+        return ZayaConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)
@@ -160,7 +167,7 @@ class LlamaConfig:
             chat_template={"mistral": "mistral", "mixtral": "mistral",
                            "qwen2": "chatml", "olmoe": "tulu",
                            "glm_moe_dsa": "chatml",
-                           "nemotron_h": "chatml"}.get(
+                           "nemotron_h": "chatml", "zaya": "chatml"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",
                                    raw.get("model_type") == "qwen2"),

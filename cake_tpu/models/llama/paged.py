@@ -33,6 +33,11 @@ allocated or released for it; the step programs zero it where a row's
 first token sits at position 0 (a request that takes the slot), carry
 it from window to window and from step to step, and leave the rows that
 hold no token in a dispatch (idle, frozen, out of budget) as they are.
+The state's leaves are the family's: compressed convolutional attention
+(zaya) keeps K and V pages in EVERY layer, no `ssm` leaf (None), and in
+`conv` [L, slots, 1, cca_tail_width] what a row's next token needs of
+the one before it (its latents before and after the first convolution,
+and the half of its values that is shifted by a token).
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -99,7 +104,8 @@ class PagedKVCache(NamedTuple):
             raise ValueError(
                 f"page_size {page_size} must divide max_seq_len "
                 f"{max_seq_len}")
-        if getattr(config, "mamba_layers", None):
+        if (getattr(config, "mamba_layers", None)
+                or getattr(config, "cca_time0", None)):
             return HybridPagedCache.create(config, slots, n_pages,
                                            page_size, max_seq_len, dtype)
         L = config.num_hidden_layers
@@ -130,13 +136,14 @@ class PagedKVCache(NamedTuple):
 
 
 class HybridPagedCache(NamedTuple):
-    """PagedKVCache plus the rows' recurrent state (module docstring):
-    what a model with Mamba blocks carries through its step programs,
-    donated in and aliased out like the pools."""
+    """PagedKVCache plus the rows' state (module docstring): what a
+    model with Mamba blocks, or with convolutions inside its attention,
+    carries through its step programs, donated in and aliased out like
+    the pools."""
     k: jnp.ndarray        # [L_attn, N_pages, page, KV*hd]
     v: jnp.ndarray
     table: jnp.ndarray    # [slots, max_pages] int32
-    ssm: jnp.ndarray      # [L_M, slots, H, P, N] float32
+    ssm: Optional[jnp.ndarray]   # [L_M, slots, H, P, N] float32, or None
     conv: jnp.ndarray     # [L_M, slots, K-1, conv_dim]
 
     page_size = PagedKVCache.page_size
@@ -152,25 +159,33 @@ class HybridPagedCache(NamedTuple):
                 f"page_size {page_size} must divide max_seq_len "
                 f"{max_seq_len}")
         c = config
-        L_M = len(c.mamba_layers)
-        pool = (len(c.attn_layers), n_pages, page_size,
+        if getattr(c, "cca_time0", None):
+            # convolutions inside attention: pages and a tail in every
+            # layer, no recurrent state
+            L_attn = L_M = c.num_hidden_layers
+            ssm = None
+            tail = (max(c.cca_time0, c.cca_time1) - 1, c.cca_tail_width)
+        else:
+            L_attn, L_M = len(c.attn_layers), len(c.mamba_layers)
+            ssm = jnp.zeros((L_M, slots, c.mamba_num_heads,
+                             c.mamba_head_dim, c.ssm_state_size),
+                            jnp.float32)
+            tail = (c.conv_kernel - 1, c.conv_dim)
+        pool = (L_attn, n_pages, page_size,
                 c.num_key_value_heads * c.head_dim)
         return cls(
             k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
             table=jnp.full((slots, max_seq_len // page_size), -1,
                            jnp.int32),
-            ssm=jnp.zeros((L_M, slots, c.mamba_num_heads, c.mamba_head_dim,
-                           c.ssm_state_size), jnp.float32),
-            conv=jnp.zeros((L_M, slots, c.conv_kernel - 1, c.conv_dim),
-                           dtype))
+            ssm=ssm, conv=jnp.zeros((L_M, slots) + tail, dtype))
 
     def memory_bytes(self) -> int:
         """Pool bytes (the pages an allocator hands out)."""
         return self.k.nbytes + self.v.nbytes
 
     def state_bytes(self) -> int:
-        """Bytes of the rows' recurrent state."""
-        return self.ssm.nbytes + self.conv.nbytes
+        """Bytes of the rows' state."""
+        return (0 if self.ssm is None else self.ssm.nbytes) + self.conv.nbytes
 
 
 class PageAllocator:

@@ -417,3 +417,147 @@ class NemotronHConfig(MoEConfig):
         )
         base.update(overrides)
         return cls(**base)
+
+
+@dataclass(frozen=True)
+class ZayaConfig(MoEConfig):
+    """ZAYA1 (`model_type: zaya`, the published ZAYA1-8B layout): every
+    layer is one attention sublayer and one expert sublayer, each behind
+    a learned residual scaling. Attention is compressed convolutional
+    attention (CCA): queries and keys live in a latent of
+    num_attention_heads * head_dim (half the hidden size at the
+    published widths), mixed along the sequence by two short causal
+    convolutions (a per-ROW tail of the last token's latents beside the
+    page pool: models/llama/paged.HybridPagedCache), half of the values
+    shifted by a token, half of each head rotated. The router is an MLP
+    over a `router_hidden_size`-wide state that runs down the layers;
+    every token takes ONE expert, weighed by its unrenormalised
+    probability. The equations are in models/reference/zaya.py; the
+    served path in models/moe/zaya.py.
+
+    `intermediate_size` carries `moe_intermediate_size` (an expert's
+    width: what models/moe/params and ops/moe read)."""
+
+    attn_head_dim: int = 128
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 0.5
+    router_hidden_size: int = 256
+
+    @property
+    def head_dim(self) -> int:
+        return self.attn_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """The rotated part of a head: its first
+        partial_rotary_factor * head_dim dims."""
+        return int(self.attn_head_dim * self.partial_rotary_factor)
+
+    @property
+    def cca_channels(self) -> int:
+        """Channels the convolutions run over: the query latent and the
+        key latent, one group of head_dim a head."""
+        return ((self.num_attention_heads + self.num_key_value_heads)
+                * self.attn_head_dim)
+
+    @property
+    def cca_tail_width(self) -> int:
+        """A row's tail in one layer: the last token's latents before
+        the convolutions and after the first, and the half of its
+        values the next token takes (c | a | W_v2 u)."""
+        return (2 * self.cca_channels
+                + self.num_key_value_heads * self.attn_head_dim // 2)
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "ZayaConfig":
+        L = raw["num_hidden_layers"]
+        missing = [k for k in ("cca_time0", "cca_time1", "num_experts",
+                               "moe_intermediate_size", "router_hidden_size",
+                               "head_dim") if k not in raw]
+        if missing:
+            raise ValueError(
+                "model_type zaya: config.json lacks " + ", ".join(missing)
+                + " (the ZAYA1-8B layout is the one served; the "
+                "Megatron-style layout of ZAYA1-base, with zaya_layers and "
+                "ffn_hidden_size_list, is not)")
+        for name in ("cca_time0", "cca_time1"):
+            if raw[name] != 2:
+                raise ValueError(
+                    f"{name} = {raw[name]}: a row's tail holds one token "
+                    "(kernel 2); longer kernels are not implemented")
+        if raw.get("sliding_window") is not None:
+            raise ValueError(
+                f"sliding_window = {raw['sliding_window']}: windowed CCA "
+                "layers (hybrid_sliding) are not implemented")
+        types = raw.get("layer_types") or ["hybrid"] * L
+        if len(types) != L or set(types) != {"hybrid"}:
+            raise ValueError(
+                f"layer_types must name num_hidden_layers = {L} layers, "
+                "each `hybrid` (CCA over the whole context, then "
+                "experts); got " + ", ".join(sorted(set(types))))
+        for name in ("attention_bias", "lm_head_bias"):
+            if raw.get(name, False):
+                raise ValueError(f"{name} = true: projection biases are "
+                                 "not implemented")
+        if raw.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act = {raw['hidden_act']!r}: only "
+                             "'silu' is implemented")
+        if raw["num_attention_heads"] % raw["num_key_value_heads"]:
+            raise ValueError("num_key_value_heads must divide "
+                             "num_attention_heads")
+        if raw["num_key_value_heads"] * raw["head_dim"] % 2:
+            raise ValueError("the value shift halves num_key_value_heads "
+                             "* head_dim: it must be even")
+        rope = raw.get("rope_parameters") or {}
+        rope = rope.get("hybrid", rope)
+        factor = rope.get("partial_rotary_factor",
+                          raw.get("partial_rotary_factor", 1.0))
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_type = {rope['rope_type']!r}: only "
+                             "'default' is implemented")
+        if int(raw["head_dim"] * factor) % 2 or not 0 < factor <= 1:
+            raise ValueError(
+                f"partial_rotary_factor = {factor}: the rotated part of "
+                f"a head of {raw['head_dim']} must be an even number of "
+                "dims, at most the head")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, intermediate_size=raw["moe_intermediate_size"],
+            rope_theta=rope.get("rope_theta",
+                                raw.get("rope_theta", 10000.0)),
+            sliding_window=None,
+            eos_token_id=raw.get("eos_token_id", raw["vocab_size"])))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields["chat_template"] = "chatml"
+        return cls(
+            **fields,
+            num_local_experts=raw["num_experts"],
+            num_experts_per_tok=raw["num_experts_per_tok"],
+            norm_topk_prob=raw.get("norm_topk_prob", False),
+            hf_layout="zaya",
+            attn_head_dim=raw["head_dim"],
+            cca_time0=raw["cca_time0"], cca_time1=raw["cca_time1"],
+            partial_rotary_factor=factor,
+            router_hidden_size=raw["router_hidden_size"],
+        )
+
+    @classmethod
+    def tiny_zaya(cls, **overrides) -> "ZayaConfig":
+        """ZAYA1's layer at a test's size: 4 layers, 4 query heads over
+        2 key heads of 16 (group 2; a test of the q-k mean passes
+        num_attention_heads=8 for the published group of 4), 4 experts
+        of 32, one a token, a 16-wide router."""
+        base = dict(
+            vocab_size=512, hidden_size=64, intermediate_size=32,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=2, rms_norm_eps=1e-5, rope_theta=5e6,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(512,), tie_word_embeddings=True,
+            chat_template="chatml",
+            num_local_experts=4, num_experts_per_tok=1,
+            norm_topk_prob=False, hf_layout="zaya",
+            attn_head_dim=16, cca_time0=2, cca_time1=2,
+            partial_rotary_factor=0.5, router_hidden_size=16,
+        )
+        base.update(overrides)
+        return cls(**base)

@@ -186,6 +186,75 @@ def _init_nemotron_params(config, rng: jax.Array, dtype,
     }
 
 
+def _init_zaya_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of a ZayaConfig, every leaf stacked [L, ...]. The
+    matmul leaves are `w_cca` (the fused projection into the latent,
+    columns [q | k | v1 | v2]), `wo`, the experts and the head; the
+    convolutions, the router's MLP, the norms, the keys' temperatures
+    and the residual scaling stay float under bits=8. The float leaves
+    are drawn away from their neutral values (residual alpha = 1 + 0.1
+    N, betas 0.02 N, k_temp in U(0.5, 2), r_gamma in U(0, 1), conv
+    weights N(0, 1 / fan-in), the router's bias 0.02 N), so that a
+    program that drops the term fails a comparison. The head is TIED:
+    the embedding transposed, quantized where the matmul leaves are."""
+    from cake_tpu.ops.quant import quantize
+
+    c = config
+    L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
+    E, R = c.num_local_experts, c.router_hidden_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    Cc, Gc = c.cca_channels, H + KV
+    w, mat, keys = _draws(rng, dtype, bits, 40)
+
+    def near(shape, centre, spread=0.1):
+        return (centre + spread * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo,
+                                  hi).astype(dtype)
+
+    def scaling():
+        return jnp.stack([near((L, D), 1.0), near((L, D), 0.0, 0.02),
+                          near((L, D), 1.0), near((L, D), 0.0, 0.02)],
+                         axis=1)
+
+    blocks = {
+        "attn_norm": near((L, D), 1.0),
+        "w_cca": mat("w_cca", (L, D, Cc + KV * hd), D),
+        "conv0_w": w((L, 2, Cc), 2),
+        "conv0_b": near((L, Cc), 0.0, 0.02),
+        "conv1_w": w((L, Gc, 2, hd, hd), 2 * hd),
+        "conv1_b": near((L, Cc), 0.0, 0.02),
+        "k_temp": uniform((L, KV), 0.5, 2.0),
+        "wo": mat("wo", (L, H * hd, D), H * hd),
+        "res_attn": scaling(),
+        "mlp_norm": near((L, D), 1.0),
+        "r_dn": w((L, D, R), D),
+        "r_dn_b": near((L, R), 0.0, 0.02),
+        "r_gamma": uniform((L, R), 0.0, 1.0),
+        "r_norm": near((L, R), 1.0),
+        "r_w1": w((L, R, R), R),
+        "r_b1": near((L, R), 0.0, 0.02),
+        "r_w2": w((L, R, R), R),
+        "r_b2": near((L, R), 0.0, 0.02),
+        "r_w3": w((L, R, E), R),
+        "router_bias": 0.02 * jax.random.normal(
+            next(keys), (L, E), jnp.float32),
+        "we_gate": mat("we_gate", (L, E, D, F), D),
+        "we_up": mat("we_up", (L, E, D, F), D),
+        "we_down": mat("we_down", (L, E, F, D), F),
+        "res_moe": scaling(),
+    }
+    embed = w((c.vocab_size, D), D)
+    if c.tie_word_embeddings:
+        head = quantize(embed.T, (0,)) if bits else embed.T
+    else:
+        head = mat("lm_head", (D, c.vocab_size), D)
+    return {"embed": embed, "blocks": blocks,
+            "final_norm": near((D,), 1.0), "lm_head": head}
+
+
 def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
                 bits: Optional[int] = None):
     """Random-init MoE parameter pytree (tests, benchmarks, a model
@@ -203,6 +272,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
         return _init_glm_params(config, rng, dtype, bits)
     if getattr(config, "mamba_layers", None):
         return _init_nemotron_params(config, rng, dtype, bits)
+    if getattr(config, "cca_time0", None):
+        return _init_zaya_params(config, rng, dtype, bits)
     c = config
     L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
     E = c.num_local_experts
@@ -245,6 +316,13 @@ def hf_layout(config: MoEConfig):
     ((leaf, HF expert tensor) x 3), HF prefix of the experts) for the
     config's family; shared by the eager and streaming loaders so their
     trees cannot structurally diverge."""
+    if config.hf_layout == "zaya":
+        raise NotImplementedError(
+            "model_type zaya: this program does not know the published "
+            "checkpoint's tensor names (the fused CCA projection, the "
+            "convolutions, the router's MLP and the residual scaling "
+            "have no counterpart it can name); it serves the family "
+            "from seeded weights only")
     attn = {
         "attn_norm": ("input_layernorm.weight", False),
         "wq": ("self_attn.q_proj.weight", True),

@@ -290,11 +290,33 @@ SSM_STATE_BYTES = _m.gauge(
     "and conv tails, every Mamba block, every slot)")
 
 
+# a step program of a model with convolutions inside attention (zaya:
+# models/moe/zaya.trunk) returns the expert counters' five, then these
+CCA_COUNTERS = (
+    ("cca_tail_rows", _m.counter(
+        "cake_cca_tail_rows_total",
+        "Rows whose conv tail a step read and wrote, summed over layers "
+        "(a row with no token in a dispatch costs none)")),
+    ("router_choice_by_bias", _m.counter(
+        "cake_cca_router_choice_by_bias_total",
+        "(token, layer) choices of an expert that the router's "
+        "balancing bias changed (0 where nothing reads the bias)")),
+)
+CCA_LAYOUT = MOE_COUNTERS + CCA_COUNTERS
+CCA_TAIL_BYTES = _m.gauge(
+    "cake_cca_tail_bytes",
+    "Bytes of the rows' conv tails beside the page pool (every layer, "
+    "every slot)")
+
+
 def counter_layout(n: int) -> tuple:
     """The (record key, series) of a step program's counter vector, by
-    its length: a sparse model's five, a glm_moe_dsa model's eleven, a
-    nemotron_h model's ten."""
-    return SSM_LAYOUT if n == len(SSM_LAYOUT) else STEP_COUNTERS[:n]
+    its length: a sparse model's five, a zaya model's seven, a
+    glm_moe_dsa model's eleven, a nemotron_h model's ten."""
+    for layout in (SSM_LAYOUT, CCA_LAYOUT):
+        if n == len(layout):
+            return layout
+    return STEP_COUNTERS[:n]
 
 
 def refresh_page_gauges(engine) -> None:
@@ -554,7 +576,8 @@ class StepRecord:
     chained: Optional[bool] = None
     # the step programs' counters since the previous record that
     # carried them, in the order of counter_layout (a sparse model's
-    # five, a glm_moe_dsa model's eleven, a nemotron_h model's ten)
+    # five, a zaya model's seven, a glm_moe_dsa model's eleven, a
+    # nemotron_h model's ten)
     moe: Optional[Tuple[float, ...]] = None
     # a step that is not chained: why the chain before it ended (one of
     # BREAKS; absent where no chain ended or the loop never waited: the

@@ -5,7 +5,9 @@ The reference is dense-only (`mlp.rs:7-11` — SURVEY.md §2.6 lists expert
 parallelism as absent); this is a capability extension, shared by the
 Mixtral and OLMoE families (models/moe). One layer, on N tokens:
 
-  * `route`: float32 router logits and the family's rule, which is
+  * `route` = `choose` over `router_logits`: float32 router logits
+    (the linear router's, or what the family hands `moe_mlp` as
+    `logits`) and the family's rule, which is
     data (`scoring`, `norm_topk_prob`, `scale`, a bias leaf): scores by
     softmax over ALL experts (Mixtral,
     OLMoE) or by sigmoid (GLM), the k largest scores and their indices
@@ -97,16 +99,22 @@ class MoEStats(NamedTuple):
     rows_routed: jnp.ndarray
 
 
-def route(x, router_w, k: int, norm_topk_prob: bool,
-          scoring: str = "softmax", scale: float = 1.0, bias=None):
-    """x [N, D], router_w [D, E] -> (weights [N, k] f32, experts [N, k]
-    int32). float32 logits whatever the activations' type; scores over
+def router_logits(x, router_w):
+    """x [N, D], router_w [D, E] -> float32 logits [N, E] whatever the
+    activations' type: the linear router every family but one has."""
+    return jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def choose(logits, k: int, norm_topk_prob: bool,
+           scoring: str = "softmax", scale: float = 1.0, bias=None):
+    """The family's rule on float32 logits [N, E], however they were
+    made -> (weights [N, k] f32, experts [N, k] int32). Scores over
     all E experts, by `scoring` ("softmax" or "sigmoid"); the top k;
     renormalised over the k only if `norm_topk_prob`; times `scale`.
-    bias [E] f32 (GLM's e_score_correction_bias): added to the scores
-    for the CHOICE only, the weights are the unbiased scores."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
+    bias [E] f32 (GLM's e_score_correction_bias, ZAYA's balancing
+    bias): added to the scores for the CHOICE only, the weights are
+    the unbiased scores."""
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
@@ -126,6 +134,14 @@ def route(x, router_w, k: int, norm_topk_prob: bool,
     if scale != 1.0:
         weights = weights * scale
     return weights, experts.astype(jnp.int32)
+
+
+def route(x, router_w, k: int, norm_topk_prob: bool,
+          scoring: str = "softmax", scale: float = 1.0, bias=None):
+    """x [N, D], router_w [D, E] -> `choose` over the linear router's
+    logits."""
+    return choose(router_logits(x, router_w), k, norm_topk_prob, scoring,
+                  scale, bias)
 
 
 # -- the sorted dispatch -------------------------------------------------------
@@ -374,10 +390,12 @@ def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int,
 def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             ep_axis: Optional[str] = None, token_mask=None,
             first_expert: Optional[int] = None, scoring: str = "softmax",
-            scale: float = 1.0, act: str = "silu"):
+            scale: float = 1.0, act: str = "silu", logits=None):
     """Sparse FFN over experts -> (out [B, S, D], MoEStats).
 
-    lp leaves: router [D, E]; we_gate/we_up [E_local, D, F]; we_down
+    lp leaves: router [D, E], the linear router, unless the family made
+    `logits` [B, S, E] float32 itself (ZAYA's MLP over a state that
+    runs down the layers: models/moe/zaya.router_logits); we_gate/we_up [E_local, D, F]; we_down
     [E_local, F, D], each an array, a per-channel QTensor, or a LayerOf
     around the stacked leaf; optionally router_bias [E] (the choice's
     bias, `route`) and ws_gate/ws_up/ws_down, a shared expert every
@@ -405,8 +423,10 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     N, k = B * S, num_experts_per_tok
     x = h.reshape(N, D)
     with jax.named_scope("router"):
-        weights, experts = route(x, lp["router"], k, norm_topk_prob,
-                                 scoring, scale, lp.get("router_bias"))
+        if logits is None:
+            logits = router_logits(x, lp["router"])
+        weights, experts = choose(logits.reshape(N, -1), k, norm_topk_prob,
+                                  scoring, scale, lp.get("router_bias"))
         routed = experts
 
     from cake_tpu.ops.quant import qmatmul

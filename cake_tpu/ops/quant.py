@@ -179,6 +179,10 @@ _BLOCK_CONTRACT = {
     "ws_gate": (1,), "ws_up": (1,), "ws_down": (1,),
     # Mamba-2's projections and the latent experts' (nemotron_h)
     "w_in": (1,), "w_out": (1,), "w_fc1": (1,), "w_fc2": (1,),
+    # CCA's fused projection into the latent, [q | k | v1 | v2] (zaya);
+    # its convolutions, router, norms, temperatures and residual scaling
+    # are small leaves and stay float (absent here)
+    "w_cca": (1,),
 }
 
 

@@ -50,9 +50,16 @@ def apply_rope(x, cos, sin):
     """Rotate-half RoPE.
 
     x:        [batch, seq, heads, head_dim]
-    cos/sin:  [seq, head_dim//2] shared across the batch, or
-              [batch, seq, head_dim//2] per-row (ragged decode).
+    cos/sin:  [seq, R//2] shared across the batch, or
+              [batch, seq, R//2] per-row (ragged decode).
+    R is the rotated part of a head: the whole head (tables of
+    head_dim//2), or under a partial rotary factor its first R dims,
+    paired (i, i + R//2); dims [R, head_dim) pass through.
     """
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     half = x.shape[-1] // 2
     x1 = x[..., :half]
     x2 = x[..., half:]

@@ -716,30 +716,37 @@ class InferenceEngine:
         # pool (models/llama/paged.HybridPagedCache), carried by the
         # step programs. What moves pages today and cannot move a state
         # yet is refused here, by the option's name, never ignored.
+        # Convolutions inside attention (zaya) keep a conv tail a ROW in
+        # the same cache object, with the same lifecycle: the same
+        # options move pages and cannot move a tail.
         self._recurrent = bool(getattr(config, "mamba_layers", None))
-        if self._recurrent:
+        self._cca = bool(getattr(config, "cca_time0", None))
+        if self._recurrent or self._cca:
+            family, what = (
+                ("nemotron_h (a recurrent state a row beside the page "
+                 "pool)", "state") if self._recurrent else
+                ("zaya (a conv tail a row beside the page pool)", "tail"))
             refused = [name for name, on in (
                 ("serving without --kv-pages (the dense-slot engine)",
                  not self.paged),
                 ("a topology / --tp / --sp (pipeline and tensor "
                  "parallelism)", step_fns is not None),
-                ("--draft-model (a rejected draft would have to roll "
-                 "the state back)", self._spec),
-                ("--spec-draft (a rejected draft would have to roll "
-                 "the state back)", self._spec_paged),
+                ("--draft-model (a rejected draft has advanced the "
+                 f"{what})", self._spec),
+                ("--spec-draft (a rejected draft has advanced the "
+                 f"{what})", self._spec_paged),
                 ("--kv-dtype int8/int4 (quantized pages)", self.kv_quant),
                 ("--kv-host-pages (host spill and preempt-and-restore: "
-                 "a state has no pages to spill)",
+                 f"a {what} has no pages to spill)",
                  kv_host_pages is not None),
                 ("--disagg (the prefill shipment carries pages, not a "
-                 "state)", disagg is not None),
-                ("--auto-prefix (prefix pages: a shared head has no "
-                 "state to map)", auto_prefix_system),
+                 f"{what})", disagg is not None),
+                ("--auto-prefix (prefix pages: a shared head has pages "
+                 f"but no {what} at its edge)", auto_prefix_system),
             ) if on]
             if refused:
                 raise ValueError(
-                    "model_type nemotron_h (a recurrent state a row "
-                    "beside the page pool) does not serve yet: "
+                    f"model_type {family} does not serve yet: "
                     + "; ".join(refused)
                     + " (ROADMAP.md lists each as left to do)")
         # one window (a row of several tokens) a mixed dispatch
@@ -1734,6 +1741,10 @@ class InferenceEngine:
                           "reuse yet: a shared head would need the "
                           "state snapshotted at its last page's edge "
                           "(ROADMAP.md)")
+            elif self._cca:
+                reason = ("a conv tail (zaya) has no prefix reuse yet: "
+                          "a shared head has pages but no tail at its "
+                          "last page's edge (ROADMAP.md)")
             elif self.ring:
                 reason = ("ring sliding-window caches own their layout "
                           "(a prefix install writes dense positions the "
@@ -2746,6 +2757,24 @@ class InferenceEngine:
                 self.max_slots, self._mixed_chunk, prefill_rows=(1,))
             self._prefix_capable = False
             self._prefill_slot = self._prefix_pages_step = None
+        if self._cca:
+            # the CCA step programs, behind the same signatures; the
+            # taps are gathers along the packed axis, so a dispatch
+            # holds two prefilling rows as the dense path's does
+            # (models/moe/zaya). ONE packed size, the two-window one:
+            # two programs of different shape round differently, and
+            # the choice of one expert of 16 is discrete, so with one
+            # program a row's bits do not depend on its company
+            from cake_tpu.models.moe.zaya import decode_step_cca
+            self._mixed_buckets = mixed_token_buckets(
+                self.max_slots, self._mixed_chunk, prefill_rows=(2,))
+            self._decode_step = partial(decode_step_cca, attn=impl)
+            self._decode_scan_impl = (_decode_scan_cca if impl == "fold"
+                                      else _decode_scan_cca_pallas)
+            self._mixed_step_fn = partial(_mixed_sampled_cca,
+                                          attn=self.attn_impl["mixed"])
+            self._prefix_capable = False
+            self._prefill_slot = self._prefix_pages_step = None
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -2771,6 +2800,12 @@ class InferenceEngine:
             log.info("recurrent state: %d Mamba blocks x %d rows, %.2f GiB "
                      "beside the pool", len(self.config.mamba_layers),
                      self.max_slots, self.cache.state_bytes() / 2**30)
+        if self._cca:
+            from cake_tpu.obs.steps import CCA_TAIL_BYTES
+            CCA_TAIL_BYTES.set(self.cache.state_bytes())
+            log.info("conv tails: %d layers x %d rows, %.1f MiB beside "
+                     "the pool", self.config.num_hidden_layers,
+                     self.max_slots, self.cache.state_bytes() / 2**20)
         log.info("paged KV: %d pages x %d tokens, %s attention, "
                  "%s storage (%.2f GiB pool; dense %d-slot "
                  "equivalent would be %.2f GiB)",
@@ -2973,7 +3008,8 @@ class InferenceEngine:
         if not self.paged:
             return None
         flavor = ("paged-dsa-" if self._latent
-                  else "paged-ssm-" if self._recurrent else "paged-")
+                  else "paged-ssm-" if self._recurrent
+                  else "paged-cca-" if self._cca else "paged-")
         return flavor + self.attn_impl.get(kind, self.attn_impl["decode"])
 
     def _capture_cache_identity(self) -> None:
@@ -2999,7 +3035,8 @@ class InferenceEngine:
     def _reconfig_supported(self) -> bool:
         return (not self._custom_steps and not self.ring
                 and not self._spec and not self._spec_paged
-                and not self._multihost and not self._one_window)
+                and not self._multihost and not self._one_window
+                and not self._cca)
 
     def _reconfig_refusal(self) -> str:
         if self._latent:
@@ -3009,6 +3046,9 @@ class InferenceEngine:
         if self._recurrent:
             return ("a recurrent state (nemotron_h) lives beside the "
                     "page pool: a rebuilt pool cannot replay it")
+        if self._cca:
+            return ("a conv tail (zaya) lives beside the page pool: a "
+                    "rebuilt pool cannot replay it")
         if self._spec:
             return ("speculative serving has no hot-switch fold (the "
                     "draft cache cannot be rebuilt mid-round)")
@@ -6810,3 +6850,30 @@ def _hybrid_forward_ragged_pallas(params, tokens, cache, pos, active,
 
 
 _decode_scan_hybrid_pallas = make_decode_scan(_hybrid_forward_ragged_pallas)
+
+
+def _mixed_step_cca(*args, **kw):
+    from cake_tpu.models.moe.zaya import mixed_step_cca
+    return mixed_step_cca(*args, **kw)
+
+
+_mixed_sampled_cca = make_mixed_sampled(_mixed_step_cca)
+
+
+def _cca_forward_ragged(params, tokens, cache, pos, active, rope, config):
+    from cake_tpu.models.moe.zaya import forward_ragged_cca
+    return forward_ragged_cca(params, tokens, cache, pos, active, rope,
+                              config)
+
+
+_decode_scan_cca = make_decode_scan(_cca_forward_ragged)
+
+
+def _cca_forward_ragged_pallas(params, tokens, cache, pos, active, rope,
+                               config):
+    from cake_tpu.models.moe.zaya import forward_ragged_cca
+    return forward_ragged_cca(params, tokens, cache, pos, active, rope,
+                              config, attn="pallas")
+
+
+_decode_scan_cca_pallas = make_decode_scan(_cca_forward_ragged_pallas)

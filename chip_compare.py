@@ -141,6 +141,48 @@ NEMOTRON_START, NEMOTRON_EDGE, NEMOTRON_SLOW_HEADS = 8, 3, 16
 NEMOTRON_JOBS = ((0, 4000), (1, 1000), (2, 2100), (1, 900))
 NEMOTRON_DECODE = 16
 
+# zaya: 40 layers, each a discrete choice of ONE expert of 16. Where a
+# token's two best experts lie nearer than a bf16 rounding the served
+# path takes the other, the next layer starts from there, and a free-
+# running reference drifts away by whole experts: a floor that would
+# hide a precision error. So the reference is TEACHER-FORCED: it takes
+# the served path's choice in every (token, layer) and weighs it by its
+# OWN probability, and its own choice along that trajectory is compared
+# with the served one (`agree`, a share: no cascade). Limits, each read
+# where its fault shows: `mean` and `max`, |error| / range of the
+# logits over the compared positions (precision; the router's state and
+# the top-1 weight through p); `mean_edge`, the mean over the first two
+# positions behind each 128-token window edge of a prompt (their taps
+# read the stored tail); `keys` and `values`, layer 0's stored K and V
+# pages against the reference's, relative (the convolutions, the q-k
+# mean, the norm and above all the ROTATED SHARE show in the keys, the
+# SHIFT in the values: attention over seeded keys is near uniform, so
+# neither moves a logit by much); `reuse`, a slot's second request
+# against the same request in a slot nothing has used (the served path
+# against itself: a tail that is not zeroed shows here); and `agree`
+# from below. Each limit lies between the worst the served path read on
+# the chip over seeds 0 / 1 / 2 and the LEAST the int8-activation
+# reference read there (PERF.md section 6, PR 37), about the geometric
+# middle: mean 1.079e-3 | 2.249e-3, max 1.128e-2 | 2.315e-2, mean_edge
+# 1.058e-3 | 1.926e-3, keys 3.414e-3 | 8.729e-3, values 2.358e-3 |
+# 8.693e-3, agree 0.9913 | 0.9749 (from above). What HOLDS the lower
+# precision out is `values` (3.7 times between the readings) and
+# `agree`; `mean` (2.08 times) and `keys` (2.56) are secondary: limits
+# fitted to three seeds, a fresh seed read under them in PERF.md
+# section 6. The five other altered
+# references fail by tens of times (conv taps keys 0.80, shift values
+# 1.0, rotation keys 1.02, renormalised mean 0.09) or, the router's
+# state, by `agree` 0.50-0.54 and `mean` 2.3e-3.
+ZAYA_TOL = {"mean": 1.55e-3, "max": 1.6e-2, "mean_edge": 1.5e-3,
+            "keys": 5.5e-3, "values": 4.5e-3, "reuse": 1e-3}
+ZAYA_AGREE = 0.984
+ZAYA_EDGE = 2
+# (slot, prompt tokens): both prompt classes of reason-closed in 8 of
+# the 32 rows at once, then a second request in slot 0
+ZAYA_JOBS = ((0, 129), (1, 192), (2, 256), (3, 192), (4, 897), (5, 1024),
+             (6, 960), (7, 960), (0, 140))
+ZAYA_DECODE = 64
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -261,6 +303,8 @@ def main() -> int:
         return compare_glm(engine, cell, args, t_start)
     if raw_config.get("model_type") == "nemotron_h":
         return compare_nemotron(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "zaya":
+        return compare_zaya(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -1177,6 +1221,321 @@ def compare_nemotron(engine, cell, args, t_start) -> int:
     result["ok"] = bool(ok)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"nemotron_result_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if ok else 1
+
+
+def compare_zaya(engine, cell, args, t_start) -> int:
+    """The comparison above for compressed convolutional attention: the
+    engine's own mixed and decode trunks with the head at every
+    position, against models/reference/zaya.py on teacher-forced
+    routing (ZAYA_TOL says why). Jobs run a slot each, two prefilling
+    rows a dispatch with the rows that already decode beside them, the
+    decode program when no row prefills; slot 0 takes a second request
+    when its first has finished (the tail it left must not reach the
+    second), and that request runs again in a slot nothing has used."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import zaya
+    from cake_tpu.models.reference import zaya as ref
+    from cake_tpu.ops.quant import qmatmul
+
+    cfg, params, rope = engine.config, engine.params, engine.rope
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    attn = engine.attn_impl["mixed"]
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = zaya.mixed_trunk(params, tokens, pos, q_len, active, cache,
+                                  rope, cfg, attn, n_tokens)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return logits, out.cache, out.experts
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        out = zaya.decode_trunk(params, tokens, cache, pos, active, rope,
+                                cfg, attn)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return logits, out.cache, out.experts
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = ZAYA_JOBS if not args.rehearse else (
+        (0, 21), (1, 30), (2, 45), (3, 17), (0, 19))
+    second = len(jobs) - 1
+    opener = next(i for i, (slot, _) in enumerate(jobs)
+                  if slot == jobs[second][0])
+    twin = len(jobs)
+    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
+    n_decode = ZAYA_DECODE if not args.rehearse else 6
+    last = LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs[:twin]]
+    sequences.append(sequences[second])
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    table = np.full((B, per_row), -1, np.int32)
+    for slot in {slot for slot, _ in jobs}:
+        table[slot] = slot * per_row + np.arange(per_row)
+    assert table.max() < engine.cache.n_pages
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    engine.cache = None
+    L, k = cfg.num_hidden_layers, cfg.num_experts_per_tok
+
+    got = [dict() for _ in jobs]        # position -> logits [V]
+    chosen = [np.zeros((len(s), L, k), np.int32) for s in sequences]
+    stored = [None] * len(jobs)         # layer 0's (K, V) at a job's end
+    off = [0] * len(jobs)
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, and the
+        positions behind each window edge."""
+        return (position >= prompts[i] - last
+                or (position >= C and position % C < ZAYA_EDGE))
+
+    def current(slot):
+        """The slot's first unfinished job."""
+        return next((i for i, (s, _) in enumerate(jobs)
+                     if s == slot and off[i] < len(sequences[i])
+                     and (i != second
+                          or off[opener] == len(sequences[opener]))), None)
+
+    steps = {"mixed": 0, "decode": 0}
+    t0 = time.monotonic()
+    while any(off[i] < len(s) for i, s in enumerate(sequences)):
+        live = {slot: current(slot) for slot in {s for s, _ in jobs}}
+        live = {slot: i for slot, i in live.items() if i is not None
+                # the twin starts with the request it mirrors
+                and (i != twin or off[opener] == len(sequences[opener]))}
+        qlen = np.zeros(B, np.int32)
+        pos = np.zeros(B, np.int32)
+        for slot, i in live.items():
+            qlen[slot] = (min(C, prompts[i] - off[i])
+                          if off[i] < prompts[i] else 1)
+            pos[slot] = off[i]
+        if (qlen > 1).any():
+            toks = np.zeros((B, C), np.int32)
+            for slot, i in live.items():
+                toks[slot, :qlen[slot]] = \
+                    sequences[i][off[i]:off[i] + qlen[slot]]
+            for group in engine._mixed_groups(qlen):
+                glen = np.where(group, qlen, 0)
+                n_tokens = paged.mixed_bucket_for(engine._mixed_buckets,
+                                                  int(glen.sum()))
+                logits, cache, experts = window_step(
+                    params, jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(glen), jnp.asarray(glen > 0), cache,
+                    n_tokens)
+                first = np.cumsum(glen) - glen
+                experts = np.asarray(experts)
+                wanted = []
+                for slot in np.flatnonzero(glen):
+                    i = live[slot]
+                    chosen[i][off[i]:off[i] + glen[slot]] = experts[
+                        :, first[slot]:first[slot] + glen[slot]
+                    ].transpose(1, 0, 2)
+                    wanted += [(slot, j) for j in range(glen[slot])
+                               if compared(i, off[i] + j)]
+                if wanted:
+                    rows = np.asarray([first[s] + j for s, j in wanted])
+                    fetched = np.asarray(logits[rows])
+                    for n, (slot, j) in enumerate(wanted):
+                        got[live[slot]][off[live[slot]] + j] = fetched[n]
+            steps["mixed"] += 1
+        else:
+            toks = np.zeros((B, 1), np.int32)
+            for slot, i in live.items():
+                toks[slot, 0] = sequences[i][off[i]]
+            logits, cache, experts = decode_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(qlen > 0), cache)
+            logits, experts = np.asarray(logits), np.asarray(experts)
+            for slot, i in live.items():
+                got[i][off[i]] = logits[slot]
+                chosen[i][off[i]] = experts[:, slot]
+            steps["decode"] += 1
+        for slot, i in live.items():
+            off[i] += int(qlen[slot])
+            if off[i] == len(sequences[i]):
+                pages = jnp.asarray(table[slot])
+                stored[i] = tuple(
+                    np.asarray(pool[0, pages].reshape(
+                        -1, pool.shape[-1])[:off[i]].astype(jnp.float32))
+                    for pool in (cache.k, cache.v))
+    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+        f"steps in {time.monotonic() - t0:.1f} s")
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time ---------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = zaya.reference_config(cfg)
+    attention, experts_fn = ref.attention, ref.experts
+    jitted = {}
+
+    def under_jit(name, config, make):
+        """A heavy function of the reference under jit: one trace per
+        config (its switches are read while tracing) and shape."""
+        key = (name, tuple(sorted(config.items())))
+        if key not in jitted:
+            jitted[key] = jax.jit(make(config))
+        return jitted[key]
+
+    def jit_attention(lp, u, config, keys=None):
+        # a tap's list cannot cross a jit: the jitted function returns
+        # what it received beside the output
+        def make(c):
+            def run(lp, u):
+                tap = []
+                return attention(lp, u, c, tap), tap[0]
+            return run
+        out, kv = under_jit("attention", config, make)(lp, u)
+        if keys is not None:
+            keys.append(kv)
+        return out
+
+    def jit_experts(lp, m, p, config, routing=None, forced=None):
+        def make(c):
+            def run(lp, m, p, forced):
+                tap = []
+                return experts_fn(lp, m, p, c, tap, forced), tap[0]
+            return run
+        out, own = under_jit("experts", config, make)(
+            lp, m, p, jnp.asarray(forced))
+        if routing is not None:
+            routing.append(own)
+        return out
+
+    ref.attention, ref.experts = jit_attention, jit_experts
+    top = {key: dequantized(jax.tree.map(jnp.asarray, host[key]))
+           for key in ("embed", "final_norm", "lm_head")}
+
+    def reference(idx, config=ref_cfg):
+        """The jobs `idx` through the reference, routed as the served
+        path routed them -> per job (logits, its own choices [S, L, k],
+        layer 0's (k, v))."""
+        t0 = time.monotonic()
+        routing = [[] for _ in idx]
+        keys = [[] for _ in idx]
+        forced = [[chosen[i][:, n] for n in range(L)] for i in idx]
+        logits = ref.forward(
+            top, [sequences[i] for i in idx], config,
+            layers=zaya.reference_layers(host["blocks"], cfg),
+            routing=routing, forced=forced, keys=keys)
+        say(f"  reference over {sum(len(sequences[i]) for i in idx)} "
+            f"tokens in {time.monotonic() - t0:.1f} s")
+        return {i: (np.asarray(logits[n]),
+                    np.stack([np.asarray(r) for r in routing[n]], axis=1),
+                    tuple(np.asarray(x) for x in keys[n][0]))
+                for n, i in enumerate(idx)}
+
+    want = reference(list(range(twin)))
+
+    def rel(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    def readings(idx, run, logits_at, stored_of):
+        """Over the compared positions of the jobs `idx`, against the
+        plain reference `want`: mean and worst |error| / range of the
+        logits; the mean behind the window edges; the share of (token,
+        layer) choices of `run` (a reference's, along the served
+        trajectory) that are the served path's; layer 0's stored keys
+        and values, relative (worst job)."""
+        total, n, worst, edge = 0.0, 0, 0.0, []
+        for i in idx:
+            for position in sorted(got[i]):
+                w = want[i][0][position]
+                err = np.abs(logits_at(i, position) - w) / float(
+                    w.max() - w.min())
+                total += float(err.sum())
+                n += err.size
+                worst = max(worst, float(err.max()))
+                if position < prompts[i] - last:
+                    edge.append(float(err.mean()))
+        agree = np.concatenate([(run[i][1] == chosen[i]).ravel()
+                                for i in idx])
+        return {"mean": total / max(n, 1), "max": worst,
+                "mean_edge": float(np.mean(edge)) if edge else 0.0,
+                "agree": float(agree.mean()),
+                "agree_by_layer": [round(float(np.mean(np.concatenate(
+                    [(run[i][1][:, j] == chosen[i][:, j]).ravel()
+                     for i in idx]))), 4) for j in range(L)],
+                "keys": max(rel(stored_of(i)[0], want[i][2][0])
+                            for i in idx),
+                "values": max(rel(stored_of(i)[1], want[i][2][1])
+                              for i in idx)}
+
+    def passes(r):
+        return (all(r[key] < limit for key, limit in ZAYA_TOL.items())
+                and r["agree"] >= ZAYA_AGREE)
+
+    def apart(logits_at):
+        """The slot's second request, by `logits_at`, against the same
+        request in a fresh slot: mean |difference| / range."""
+        errs = [np.abs(logits_at(p) - got[twin][p]) / float(
+                    want[second][0][p].max() - want[second][0][p].min())
+                for p in sorted(got[twin])]
+        return float(np.mean(np.concatenate(errs)))
+
+    every = list(range(twin))
+    served = readings(every, want, lambda i, p: got[i][p],
+                      lambda i: stored[i])
+    assert sorted(got[twin]) == sorted(got[second])
+    served["reuse"] = apart(lambda p: got[second][p])
+    n_rows = sum(len(g) for g in got[:twin])
+    expected = sum(
+        min(last, p) + n_decode
+        + sum(1 for q in range(C, p - last) if q % C < ZAYA_EDGE)
+        for p in prompts[:twin])
+    result = {
+        "positions": n_rows, "expected_positions": expected,
+        "served": served, "tol": ZAYA_TOL, "agree_floor": ZAYA_AGREE,
+        "seed": args.seed, "jobs": [list(j) for j in jobs], "steps": steps,
+        "attention": impl, "device": jax.devices()[0].device_kind,
+        "packed_sizes": list(engine._mixed_buckets),
+    }
+    ok = n_rows == expected and passes(served)
+
+    # -- what must NOT pass: the reference, altered, against itself ----
+    if args.negatives:
+        # the slot that is used twice, and the shortest long prompt (a
+        # window edge among its compared positions)
+        short = [opener, second, min(
+            (i for i in every if prompts[i] > C + last),
+            key=lambda i: prompts[i], default=opener)]
+        short = sorted(set(short))
+        altered = {
+            "int8_activation_reference": {"int8_activations": True},
+            "conv_taps_dropped_reference": {"drop_conv_taps": True},
+            "no_value_shift_reference": {"no_value_shift": True},
+            "full_rotary_reference": {"full_rotary": True},
+            "renormalised_top1_reference": {"renormalise_top1": True},
+            "no_router_state_reference": {"no_router_state": True},
+        }
+        for name, switch in altered.items():
+            run = reference(short, dict(ref_cfg, **switch))
+            r = readings(short, run, lambda i, p: run[i][0][p],
+                         lambda i: run[i][2])
+            r["reuse"] = 0.0
+            result[name] = r
+            if passes(r):
+                say(f"FAILED: the {name} passes the tolerance")
+                ok = False
+    result["ok"] = bool(ok)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"zaya_result_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)
