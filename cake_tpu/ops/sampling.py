@@ -58,19 +58,58 @@ def _mask_top_k(logits, k: int):
     return jnp.where(logits < kth, -jnp.inf, logits)
 
 
+def _ordered_keys(x):
+    """int32 keys that order as the f32 values of x do: a float's bits,
+    the low 31 flipped where the sign is set. -0.0 is filed with +0.0 (as
+    `<` files it) and -inf is the smallest key a top-k mask can leave."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    bits = jnp.where(x == 0.0, 0, bits)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _signed(prefix):
+    """A uint32 search prefix as the int32 key of the same rank."""
+    return jax.lax.bitcast_convert_type(
+        prefix ^ jnp.uint32(0x80000000), jnp.int32)
+
+
+def nucleus_floor(scaled, top_p):
+    """Nucleus (top-p) filtering without ordering the row: keep token i iff
+    the probability mass of the tokens STRICTLY above it is < p, so ties of
+    the last kept value survive together; the rest become -inf.
+
+    scaled: [..., V] f32 logits (-inf entries, a top-k mask's, stay out)
+    top_p:  a float or [...] f32, one p a row; p <= 0 keeps the maximum and
+            its ties, p >= 1 keeps every token
+
+    With g(K) = sum(probs[key > K]), non-increasing in K, the kept set is
+    {key >= K*} for K* = min{K : g(K) < p}. K* has 32 bits, fixed from the
+    top, each by ONE masked sum over the row: the candidate is the prefix
+    with this bit 0 and the lower bits 1, the largest K the 0 allows.
+    """
+    p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32),
+                         scaled.shape[:-1])[..., None]
+    probs = jax.nn.softmax(scaled, axis=-1)
+    keys = _ordered_keys(scaled)
+
+    def fix_bit(i, prefix):
+        bit = jnp.uint32(1) << (31 - i).astype(jnp.uint32)
+        above = jnp.sum(
+            jnp.where(keys > _signed(prefix | (bit - 1)), probs, 0.0),
+            axis=-1, keepdims=True)
+        return jnp.where(above < p, prefix, prefix | bit)
+
+    floor = jax.lax.fori_loop(0, 32, fix_bit, jnp.zeros(p.shape, jnp.uint32))
+    # the top token always survives (p <= 0 finds no K: every bit is set)
+    top = _ordered_keys(jnp.max(scaled, axis=-1, keepdims=True))
+    floor = jnp.minimum(_signed(floor), top)
+    floor = jnp.where(p >= 1.0, jnp.iinfo(jnp.int32).min, floor)
+    return jnp.where(keys < floor, -jnp.inf, scaled)
+
+
 def _mask_top_p(logits, p: float):
-    """Nucleus filtering: keep the smallest set of tokens whose cumulative
-    probability exceeds p (the top token always survives)."""
-    sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # keep entries where the cumulative mass *before* them is < p
-    keep_sorted = (cum - probs) < p
-    # threshold logit = smallest kept logit
-    kth = jnp.min(
-        jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True
-    )
-    return jnp.where(logits < kth, -jnp.inf, logits)
+    """Nucleus filtering with one p for every row (`nucleus_floor`)."""
+    return nucleus_floor(logits, p)
 
 
 @partial(jax.jit, static_argnames=("config",))
@@ -128,7 +167,8 @@ def sample_tokens_ragged(keys, logits, recent_tokens, temperature, top_p,
     logits:          [B, V]
     recent_tokens:   [B, N] ring buffers (-1 = empty)
     temperature:     [B] f32; <= 0 means greedy for that row
-    top_p:           [B] f32; >= 1 disables nucleus filtering for that row
+    top_p:           [B] f32; >= 1 keeps every token of that row, <= 0 its
+                     maximum alone (`nucleus_floor`)
     repeat_penalty:  [B] f32; 1.0 disables
     top_k:           static engine-wide k (the REST API exposes only
                      temperature/top_p per request, matching the reference's
@@ -153,16 +193,7 @@ def sample_tokens_ragged(keys, logits, recent_tokens, temperature, top_p,
     scaled = logits / safe_t
     if top_k is not None:
         scaled = _mask_top_k(scaled, top_k)
-    # per-row nucleus filtering; p>=1 keeps everything
-    sorted_logits = jnp.sort(scaled, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = (cum - probs) < jnp.clip(top_p, 0.0, 1.0)[:, None]
-    keep_sorted = keep_sorted.at[..., 0].set(True)  # top token always survives
-    kth = jnp.min(
-        jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True
-    )
-    filtered = jnp.where(scaled < kth, -jnp.inf, scaled)
+    filtered = nucleus_floor(scaled, top_p)
     sampled = jax.vmap(
         lambda k, lg: jax.random.categorical(k, lg)
     )(keys, filtered).astype(jnp.int32)
