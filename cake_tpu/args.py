@@ -192,7 +192,7 @@ class Args:
     # --require-model-type NAME: refuse to start unless the model
     # directory's config.json resolves to this family (a config.json
     # `model_type`: llama, mistral, qwen2, mixtral, olmoe, glm_moe_dsa,
-    # nemotron_h, zaya).
+    # dots3_note, nemotron_h, zaya).
     # An assertion for scripted deployments, not a switch: nothing else
     # reads it
     require_model_type: Optional[str] = None

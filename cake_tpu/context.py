@@ -135,8 +135,8 @@ class Context:
         # Mixtral-8x22B) topology actually load instead of dying at the
         # eager full-tree load.
         one_chip_only = next((family for attr, family in (
-            ("kv_lora_rank", "glm_moe_dsa (latent attention over the "
-                             "page pool)"),
+            ("kv_lora_rank", f"{getattr(cfg, 'hf_layout', '')} (latent "
+                             "attention over the page pool)"),
             ("mamba_layers", "nemotron_h (a recurrent state a row "
                              "beside the page pool)"),
             ("cca_time0", "zaya (a conv tail a row beside the page "

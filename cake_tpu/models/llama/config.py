@@ -33,6 +33,10 @@ MODEL_TYPES = {
     "glm_moe_dsa": "latent attention over one cache row a token, a learned "
                    "sparse indexer whose key sets layers share, "
                    "sigmoid-routed experts with a shared one (paged engine)",
+    "dots3_note": "two kinds of latent attention layer: full layers with "
+                  "an indexer each, sliding-window layers with a latent "
+                  "geometry of their own in a ring of pages a row; a "
+                  "sigmoid gate a head, rescaled latents (paged engine)",
     "nemotron_h": "one mixer a block: Mamba-2 with a recurrent state a row "
                   "beside the page pool, relu² experts in a latent with a "
                   "shared one, GQA without positions (paged engine)",
@@ -58,6 +62,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "glm_moe_dsa":
         from cake_tpu.models.moe.config import GlmMoeDsaConfig
         return GlmMoeDsaConfig.from_hf_dict(raw)
+    if model_type == "dots3_note":
+        from cake_tpu.models.moe.config import Dots3NoteConfig
+        return Dots3NoteConfig.from_hf_dict(raw)
     if model_type == "nemotron_h":
         from cake_tpu.models.moe.config import NemotronHConfig
         return NemotronHConfig.from_hf_dict(raw)
@@ -167,6 +174,7 @@ class LlamaConfig:
             chat_template={"mistral": "mistral", "mixtral": "mistral",
                            "qwen2": "chatml", "olmoe": "tulu",
                            "glm_moe_dsa": "chatml",
+                           "dots3_note": "chatml",
                            "nemotron_h": "chatml", "zaya": "chatml"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",

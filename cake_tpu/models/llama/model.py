@@ -53,7 +53,21 @@ class RopeTables(NamedTuple):
         cos, sin = precompute_rope(
             config.rope_dim, max_seq_len, config.rope_theta
         )
+        if getattr(config, "swa_rope_theta", None):
+            # a second kind of attention layer with a rotation of its own
+            return WindowRopeTables(cos, sin, *precompute_rope(
+                config.swa_qk_rope_head_dim, max_seq_len,
+                config.swa_rope_theta))
         return cls(cos, sin)
+
+
+class WindowRopeTables(NamedTuple):
+    """RopeTables of a model whose sliding-window layers rotate by a
+    theta (and over a width) of their own (dots3_note)."""
+    cos: jnp.ndarray
+    sin: jnp.ndarray
+    swa_cos: jnp.ndarray
+    swa_sin: jnp.ndarray
 
 
 def _qk_norm(x, weight, eps: float, tp_axis: Optional[str]):

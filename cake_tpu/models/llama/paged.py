@@ -38,6 +38,16 @@ The state's leaves are the family's: compressed convolutional attention
 `conv` [L, slots, 1, cca_tail_width] what a row's next token needs of
 the one before it (its latents before and after the first convolution,
 and the half of its values that is shifted by a token).
+A model with SLIDING-WINDOW latent layers beside its full ones
+(dots3_note) keeps a THIRD pool and a second table (WindowedPagedCache):
+the full layers' latent rows in `k` ([L_full, ...]: the sliding layers
+have none there) and index keys in `v`, through `table` as above; the
+sliding layers' rows, of their own width, in `w` [L_sliding, N_w, page,
+row_w] through `wtable` [slots, R], a RING: logical page j of a row lies
+in wtable[row, j mod R], so a row holds R window pages whatever its
+context (R: config.window_ring_pages; ring_holds states why nothing a
+query needs is overwritten). `table` is mapped once, at admission;
+`wtable` never moves: slot i owns pages i*R .. (i+1)*R - 1 of `w`.
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -110,6 +120,11 @@ class PagedKVCache(NamedTuple):
                                            page_size, max_seq_len, dtype)
         L = config.num_hidden_layers
         if getattr(config, "kv_lora_rank", None):
+            if config.sliding_layers:
+                raise ValueError(
+                    "a model with sliding-window latent layers keeps a "
+                    "pool and a table by kind of layer: "
+                    "WindowedPagedCache.create")
             # latent attention: one latent row a token, and the
             # indexer's key in the layers that compute an index
             shape_k = (L, n_pages, page_size, config.latent_row)
@@ -186,6 +201,86 @@ class HybridPagedCache(NamedTuple):
     def state_bytes(self) -> int:
         """Bytes of the rows' state."""
         return (0 if self.ssm is None else self.ssm.nbytes) + self.conv.nbytes
+
+
+class WindowedPagedCache(NamedTuple):
+    """PagedKVCache for latent attention plus the sliding-window
+    layers' pool and its ring table (module docstring), donated in and
+    aliased out like the other pools."""
+    k: jnp.ndarray        # [L_full, N_pages, page, latent_row]
+    v: jnp.ndarray        # [L_full, N_pages, page, index_head_dim]
+    table: jnp.ndarray    # [slots, max_pages] int32, -1 = unmapped
+    w: jnp.ndarray        # [L_sliding, N_window_pages, page, swa_latent_row]
+    wtable: jnp.ndarray   # [slots, R] int32: slot i's ring, fixed
+
+    page_size = PagedKVCache.page_size
+    n_pages = PagedKVCache.n_pages
+    max_pages = PagedKVCache.max_pages
+    max_seq_len = PagedKVCache.max_seq_len
+
+    @property
+    def n_window_pages(self) -> int:
+        return self.w.shape[1]
+
+    @property
+    def ring_pages(self) -> int:
+        return self.wtable.shape[1]
+
+    @classmethod
+    def create(cls, config, slots: int, n_pages: int, page_size: int,
+               max_seq_len: int, ring_pages: int,
+               dtype=jnp.bfloat16) -> "WindowedPagedCache":
+        if max_seq_len % page_size:
+            raise ValueError(
+                f"page_size {page_size} must divide max_seq_len "
+                f"{max_seq_len}")
+        c = config
+        return cls(
+            k=jnp.zeros((len(c.latent_layers), n_pages, page_size,
+                         c.latent_row), dtype),
+            v=jnp.zeros((len(c.full_layers), n_pages, page_size,
+                         c.index_head_dim), dtype),
+            table=jnp.full((slots, max_seq_len // page_size), -1,
+                           jnp.int32),
+            w=jnp.zeros((len(c.sliding_layers), slots * ring_pages,
+                         page_size, c.swa_latent_row), dtype),
+            wtable=jnp.arange(slots * ring_pages, dtype=jnp.int32)
+            .reshape(slots, ring_pages))
+
+    def memory_bytes(self) -> int:
+        """Bytes of the pools the page table maps (the full layers')."""
+        return self.k.nbytes + self.v.nbytes
+
+    def window_bytes(self) -> int:
+        """Bytes of the sliding layers' pool: slots x R pages, whatever
+        max_seq_len is."""
+        return self.w.nbytes
+
+
+def ring_holds(page_size: int, ring_pages: int, window: int, start: int,
+               n_written: int) -> bool:
+    """THE RING'S INEQUALITY. A dispatch writes a row's positions
+    start .. start + n_written - 1 into the ring and only then attends;
+    the write of logical page p takes the place of page p - ring_pages.
+    Its first query reaches back to position start - (window - 1), so
+    every key any query of the dispatch needs lies in pages
+    (start - window + 1) // page .. (start + n_written - 1) // page, and
+    the ring holds them all iff the newest page written, less
+    ring_pages, lies below the oldest page needed:
+
+        (start + n_written - 1) // page - ring_pages
+            < (start - (window - 1)) // page
+
+    (floor division, so that positions before the row's start count as
+    pages below 0: nothing lies there). With ring_pages =
+    ceil((window - 1 + width) / page) + 1 it holds for every start and
+    every n_written <= width (tests/test_dots3.py walks the
+    alignments). A stale slot AHEAD of a query (a page of the ring that
+    the row has not reached again) is masked by causality, as an
+    unwritten one is."""
+    newest = (start + n_written - 1) // page_size
+    oldest = (start - (window - 1)) // page_size
+    return newest - ring_pages < oldest
 
 
 class PageAllocator:
@@ -431,17 +526,21 @@ def update_pool_per_row(pool_k, pool_v, layer, k, v, pos, active, table):
 
 
 @jax.named_scope("kv")
-def write_token_rows(pool, layer, rows, slot, position, valid, table):
+def write_token_rows(pool, layer, rows, slot, position, valid, table,
+                     ring: bool = False):
     """Scatter one cache row a token into layer `layer` of one pool:
     rows [T, W] at (table[slot[t], position[t] // page], position[t] %
     page). slot/position [T] int32; valid [T] bool. Tokens that are not
     valid, and positions past the table or on unmapped pages, route to
     the out-of-bounds index and drop. What the latent pools' writers
     are: a decode step's tokens (slot = the row) and a mixed step's
-    packed axis alike."""
+    packed axis alike. ring: the table is a row's RING of pages
+    (WindowedPagedCache.wtable): logical page j lies in entry j mod R."""
     N, P = pool.shape[1], pool.shape[2]
     max_pages = table.shape[1]
     pidx = position // P
+    if ring:
+        pidx = pidx % max_pages
     pages = table[slot, jnp.minimum(pidx, max_pages - 1)]
     ok = valid & (pidx < max_pages) & (pages >= 0)
     return pool.at[layer, jnp.where(ok, pages, N), position % P].set(

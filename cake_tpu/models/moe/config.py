@@ -15,7 +15,7 @@ them apart is data, not code:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 from cake_tpu.models.llama.config import LlamaConfig
 
@@ -105,6 +105,51 @@ class MoEConfig(LlamaConfig):
         )
 
 
+def _stored_row(width: int) -> int:
+    """The width a latent row of `width` numbers is STORED at: padded
+    with zeros to whole 128-lane tiles (576 -> 640, 1,088 -> 1,152).
+    The TPU keeps an array whose minor dimension is not a multiple of
+    128 in a transposed tiled layout, and a step program converted the
+    whole pool on the way in and on the way out (two copies of 15 ms
+    each at 9 x 800 x 128 x 576; compiler, PR 30). A test-sized latent
+    (under one tile) is stored as it is."""
+    return width if width <= 128 else -(-width // 128) * 128
+
+
+class LatentGeometry(NamedTuple):
+    """The sizes of ONE kind of latent attention layer: what
+    models/moe/glm_dsa's trunk reads in place of the config, so that a
+    model may have two (dots3_note: full layers and sliding-window
+    layers, each with its own heads, ranks, head dims and theta).
+
+    scope: the prefix of the layer's named scopes and kernel names
+    ("mla" | "swa"); rope: the RopeTables fields the layer rotates by;
+    q_scale / kv_scale: what the normed query and kv latents are
+    multiplied by (1.0 = not at all); gated: each head's output is
+    multiplied by a sigmoid of the layer's normed input (the leaf
+    `w_attn_gate` [D, heads]); window: keys a query attends, its own
+    included (None = a full layer: the indexer's selection over
+    everything visible)."""
+
+    scope: str
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    row: int
+    rope: Tuple[str, str] = ("cos", "sin")
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    gated: bool = False
+    window: Optional[int] = None
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+
 @dataclass(frozen=True)
 class GlmMoeDsaConfig(MoEConfig):
     """GLM-5.2 (`model_type: glm_moe_dsa`): latent attention (MLA), the
@@ -152,20 +197,34 @@ class GlmMoeDsaConfig(MoEConfig):
 
     @property
     def latent_row(self) -> int:
-        """The width a latent row is STORED at: the latent padded with
-        zeros to whole 128-lane tiles (576 -> 640). The TPU keeps an
-        array whose minor dimension is not a multiple of 128 in a
-        transposed tiled layout, and a step program converted the whole
-        pool on the way in and on the way out (two copies of 15 ms
-        each at 9 x 800 x 128 x 576; compiler, PR 30). A test-sized
-        latent (under one tile) is stored as it is."""
-        w = self.latent_width
-        return w if w <= 128 else -(-w // 128) * 128
+        """The width a latent row is STORED at (_stored_row)."""
+        return _stored_row(self.latent_width)
 
     @property
     def full_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.indexer_types)
                      if t == "full")
+
+    @property
+    def sliding_layers(self) -> Tuple[int, ...]:
+        """Layers that attend a window of their own latent pool (none
+        in this family: Dots3NoteConfig)."""
+        return tuple(i for i, t in enumerate(self.indexer_types)
+                     if t == "sliding")
+
+    @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """Layers whose rows lie in the latent pool the page table
+        maps: every layer that is not a sliding one."""
+        return tuple(i for i, t in enumerate(self.indexer_types)
+                     if t != "sliding")
+
+    def geometry(self, layer: int) -> LatentGeometry:
+        """The sizes of `layer`'s latent attention."""
+        return LatentGeometry(
+            "mla", self.num_attention_heads, self.q_lora_rank,
+            self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim, self.latent_row)
 
     @property
     def sparse_layers(self) -> Tuple[int, ...]:
@@ -253,6 +312,173 @@ class GlmMoeDsaConfig(MoEConfig):
             mlp_layer_types=("dense",) + ("sparse",) * 4,
             indexer_types=("full", "shared", "shared", "full", "shared"),
             moe_intermediate_size=32, n_routed_experts_total=8,
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
+class Dots3NoteConfig(GlmMoeDsaConfig):
+    """dots3-note (`model_type: dots3_note`): TWO kinds of latent
+    attention layer in one model, by `layer_types`. A `full_attention`
+    layer is GlmMoeDsaConfig's "full" layer (MLA over the keys its OWN
+    indexer selects: nothing is shared); a `sliding_attention` layer is
+    latent attention with its own geometry (the `swa_*` keys) over the
+    last `sliding_window_size` keys, the query's own included, and no
+    indexer. Both gate each head's output by a sigmoid of the layer's
+    normed input and multiply the two normed latents by
+    sqrt(hidden / rank): the published config says so
+    (`attention_gate_type`, `apply_mla_qkv_lora_rescale`) and a config
+    that says otherwise is refused. The FFNs are
+    GlmMoeDsaConfig's. The equations are in
+    models/reference/dots3_note.py; the served path is
+    models/moe/glm_dsa.py's trunk with a geometry per kind of layer,
+    and the sliding layers' rows lie in a pool of their own, a ring of
+    `window_ring_pages` pages a row (models/llama/paged.WindowedPagedCache).
+
+    `indexer_types` holds "full" | "sliding" here (from `layer_types`)."""
+
+    swa_num_attention_heads: int = 64
+    swa_q_lora_rank: int = 1024
+    swa_kv_lora_rank: int = 1024
+    swa_qk_nope_head_dim: int = 192
+    swa_qk_rope_head_dim: int = 64
+    swa_v_head_dim: int = 128
+    swa_rope_theta: float = 50000.0
+    sliding_window_size: int = 513
+
+    @property
+    def swa_latent_row(self) -> int:
+        """A sliding layer's stored row: its normed c_kv and rotated
+        shared key, padded as latent_row is (1,088 -> 1,152)."""
+        return _stored_row(self.swa_kv_lora_rank + self.swa_qk_rope_head_dim)
+
+    def geometry(self, layer: int) -> LatentGeometry:
+        def scale(rank: int) -> float:
+            return (self.hidden_size / rank) ** 0.5
+
+        if self.indexer_types[layer] == "sliding":
+            return LatentGeometry(
+                "swa", self.swa_num_attention_heads, self.swa_q_lora_rank,
+                self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_latent_row, ("swa_cos", "swa_sin"),
+                scale(self.swa_q_lora_rank), scale(self.swa_kv_lora_rank),
+                True, self.sliding_window_size)
+        return super().geometry(layer)._replace(
+            q_scale=scale(self.q_lora_rank),
+            kv_scale=scale(self.kv_lora_rank), gated=True)
+
+    def window_ring_pages(self, page_size: int, width: int) -> int:
+        """R: the pages of the sliding layers' pool a ROW holds, whatever
+        its context: logical page j of the row lies in its physical page
+        j mod R. A dispatch writes at most `width` tokens of a row (a
+        prompt's window) before its queries attend, and the first of
+        them reaches sliding_window_size - 1 keys back, so the keys a
+        dispatch needs span at most (sliding_window_size - 1) + width
+        positions: ceil of that over the page, plus one for a span that
+        starts inside a page. The write of logical page p lands on
+        page p - R's place, and p - R is below every page the span
+        touches (models/llama/paged.ring_holds states the inequality)."""
+        return -(-(self.sliding_window_size - 1 + width) // page_size) + 1
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "Dots3NoteConfig":
+        L = raw["num_hidden_layers"]
+        kinds = {"full_attention": "full", "sliding_attention": "sliding"}
+        types = raw.get("layer_types") or ["full_attention"] * L
+        if len(types) != L or set(types) - set(kinds):
+            raise ValueError(
+                f"layer_types must name num_hidden_layers = {L} layers, "
+                "each full_attention or sliding_attention; got "
+                + ", ".join(sorted(set(types))))
+        for name in ("index_topk_freq", "indexer_types"):
+            if raw.get(name) not in (None, 1):
+                raise ValueError(
+                    f"{name}: every full_attention layer of model_type "
+                    "dots3_note computes its own key sets (layer_types "
+                    "says which layers those are)")
+        for name in ("attention_gate_type", "swa_attention_gate_type"):
+            if raw.get(name) != "headwise":
+                raise ValueError(f"{name} = {raw.get(name)!r}: model_type "
+                                 "dots3_note is served with a 'headwise' "
+                                 "gate in both kinds of layer")
+        if raw.get("apply_mla_qkv_lora_rescale") is not True:
+            raise ValueError(
+                "apply_mla_qkv_lora_rescale = "
+                f"{raw.get('apply_mla_qkv_lora_rescale')!r}: model_type "
+                "dots3_note is served with the rescale of the two normed "
+                "latents (true)")
+        for name in ("vision_config", "audio_config"):
+            if raw.get(name):
+                raise ValueError(
+                    f"{name}: the vision and audio towers are not served "
+                    "(the language model alone); take it out")
+        if raw.get("rope_scaling"):
+            raise ValueError("rope_scaling is not implemented")
+        if raw.get("attention_bias", False):
+            raise ValueError("attention_bias = true: projection biases "
+                             "are not implemented")
+        if raw.get("moe_layer_freq", 1) != 1:
+            raise ValueError("moe_layer_freq must be 1")
+        if raw.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act = {raw['hidden_act']!r}: only "
+                             "'silu' is implemented")
+        for name, full in (("swa_num_key_value_heads",
+                            "swa_num_attention_heads"),
+                           ("num_key_value_heads", "num_attention_heads")):
+            if raw.get(name, raw[full]) != raw[full]:
+                raise ValueError(f"{name} must equal {full}: latent "
+                                 "attention has a key a head")
+        if raw["sliding_window_size"] < 1:
+            raise ValueError("sliding_window_size must be at least 1")
+        # the refusals GLM's parser makes (n_group, shared experts, MTP,
+        # the held experts' range) and its fields
+        base = GlmMoeDsaConfig.from_hf_dict(dict(
+            raw, indexer_types=["full"] * L, index_topk_freq=1))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields.update(
+            hf_layout="dots3_note", chat_template="chatml",
+            sliding_window=None,
+            indexer_types=tuple(kinds[t] for t in types),
+            swa_num_attention_heads=raw["swa_num_attention_heads"],
+            swa_q_lora_rank=raw["swa_q_lora_rank"],
+            swa_kv_lora_rank=raw["swa_kv_lora_rank"],
+            swa_qk_nope_head_dim=raw["swa_qk_nope_head_dim"],
+            swa_qk_rope_head_dim=raw["swa_qk_rope_head_dim"],
+            swa_v_head_dim=raw["swa_v_head_dim"],
+            swa_rope_theta=raw.get("swa_rope_theta", base.rope_theta),
+            sliding_window_size=raw["sliding_window_size"])
+        return cls(**fields)
+
+    @classmethod
+    def tiny_dots3(cls, **overrides) -> "Dots3NoteConfig":
+        """dots3-note's layers at a test's size: a dense full layer,
+        then the published period (sliding x3, full) and the start of
+        another; window 6, index_topk 8, so that a context of a few
+        dozen tokens passes both several times over; two geometries
+        that differ in every size; 8 routed experts, all held."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=6, num_attention_heads=4,
+            num_key_value_heads=4, rms_norm_eps=1e-5, rope_theta=8e7,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=True, hf_layout="dots3_note",
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_n_heads=2,
+            index_head_dim=16, index_topk=8,
+            mlp_layer_types=("dense",) + ("sparse",) * 5,
+            indexer_types=("full", "sliding", "sliding", "sliding", "full",
+                           "sliding"),
+            moe_intermediate_size=32, n_routed_experts_total=8,
+            routed_scaling_factor=1.0,
+            swa_num_attention_heads=2, swa_q_lora_rank=24,
+            swa_kv_lora_rank=24, swa_qk_nope_head_dim=24,
+            swa_qk_rope_head_dim=4, swa_v_head_dim=8,
+            swa_rope_theta=5e4, sliding_window_size=6,
         )
         base.update(overrides)
         return cls(**base)

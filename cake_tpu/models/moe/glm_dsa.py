@@ -1,9 +1,11 @@
-"""GLM-5.2 (`glm_moe_dsa`) on the paged engine: the step programs.
+"""Latent attention on the paged engine: the step programs of GLM-5.2
+(`glm_moe_dsa`) and of dots3-note (`dots3_note`).
 
-The equations are models/reference/glm_moe_dsa.py's; this is how the
-served path computes them over the page pool (models/llama/paged.py says
-what a pool row is here: one latent row a token and layer, and the
-indexer's key in the layers that compute an index).
+The equations are models/reference/glm_moe_dsa.py's and
+models/reference/dots3_note.py's; this is how the served path computes
+them over the page pool (models/llama/paged.py says what a pool row is
+here: one latent row a token and layer, and the indexer's key in the
+layers that compute an index).
 
 Both step programs run ONE trunk over a flat list of tokens, each with
 its row (slot) and position: a decode step's B tokens, or a mixed step's
@@ -35,6 +37,23 @@ packed axis (paged.pack_plan). A layer:
   * the FFN: dense SwiGLU, or ops/moe.moe_mlp with the sigmoid rule,
     the selection bias, the held experts and the shared expert.
 
+KINDS OF LAYER. A layer takes its sizes from its LatentGeometry
+(config.geometry(i): heads, ranks, head dims, the RoPE table, the stored
+row), so one trunk serves a model with one geometry (GLM) and a model
+with two (dots3_note). By config.indexer_types a layer is FULL (its own
+indexer), SHARED (GLM only), or SLIDING (dots3_note only): no indexer,
+its rows in the sliding layers' own pool behind the ring table
+(paged.WindowedPagedCache), its scopes `swa_q`, `swa_kv`, `swa_gather`,
+`swa_attn`. A sliding layer's single token gathers the rows at
+positions pos, pos - 1, .. pos - (window - 1) through the ring and
+attends the first min(pos + 1, window) in one pass (`cake_swa_attn`:
+cake_mla_attn's body at the sliding sizes); its window attends the
+ring's R pages where they lie under the band t - (window - 1) <= s <= t
+as the bias (`cake_swa_window_attn`). Where the layer has a gate leaf
+the un-absorbed heads are multiplied by a sigmoid a head of the layer's
+normed input (`attn_gate`); where the geometry has scales the two normed
+latents are multiplied by them, so the stored row is the scaled one.
+
 ONE WINDOW A DISPATCH. The window's score pass takes the one row whose
 keys the queries share, so a mixed dispatch holds at most one row with
 more than one token (the engine groups its rows so:
@@ -57,7 +76,7 @@ from jax import lax
 
 from cake_tpu.models.llama import paged
 from cake_tpu.models.llama.paged import PagedKVCache, write_token_rows
-from cake_tpu.models.moe.config import GlmMoeDsaConfig
+from cake_tpu.models.moe.config import GlmMoeDsaConfig, LatentGeometry
 from cake_tpu.ops import mla_attention as mla
 from cake_tpu.ops.moe import LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
@@ -69,8 +88,10 @@ INDEX_LEAVES = ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w")
 DENSE_LEAVES = ("w_gate", "w_up", "w_down")
 SPARSE_LEAVES = ("router", "router_bias", "ws_gate", "ws_up", "ws_down")
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+GATE_LEAF = "w_attn_gate"
 # a step program returns obs/steps.STEP_COUNTERS, in that order: the
-# expert counters' five, then the routed rows and the indexer's
+# expert counters' five, then the routed rows and the indexer's; a
+# model with sliding layers appends obs/steps.SWA_COUNTERS' three
 N_COUNTERS = 11
 
 
@@ -95,10 +116,17 @@ class Window(NamedTuple):
 def layer_leaves(blocks, config: GlmMoeDsaConfig, i: int) -> dict:
     """Layer i's leaves out of the stacks per kind (static indices); the
     experts as (stack, index) for the grouped matmul."""
-    def at(names, j):
-        return {k: jax.tree.map(lambda a: a[j], blocks[k]) for k in names}
+    def at(names, j, stack=blocks):
+        return {k: jax.tree.map(lambda a: a[j], stack[k]) for k in names
+                if k in stack}
 
-    lp = at(ATTN_LEAVES, i)
+    # the attention leaves are stacked per kind of layer: the sliding
+    # layers' (their own shapes) under "swa"
+    if config.indexer_types[i] == "sliding":
+        lp = at(ATTN_LEAVES + (GATE_LEAF,),
+                config.sliding_layers.index(i), blocks["swa"])
+    else:
+        lp = at(ATTN_LEAVES + (GATE_LEAF,), config.latent_layers.index(i))
     if config.indexer_types[i] == "full":
         lp.update(at(INDEX_LEAVES, config.full_layers.index(i)))
     if config.mlp_layer_types[i] == "sparse":
@@ -261,18 +289,23 @@ def select_keys(lp, h, c_q, cos, sin, slot, position, real, first,
 
 
 def project_latent(lp, h, cos, sin, slot, position, real, pool_lat,
-                   layer: int, table, config):
+                   layer: int, table, config,
+                   geo: Optional[LatentGeometry] = None,
+                   ring: bool = False):
     """The query path and the token's latent row, written into the
-    pool. Returns (q_cat [T, H, row]: the absorbed query | the rotated
-    rope part | zeros over the stored row's padding, pool_lat, c_q: the
-    query latent the indexer reads)."""
-    c = config
+    pool (layer `layer` of pool_lat, through table; ring: the table is
+    the row's ring of window pages). geo: the layer's sizes (None: the
+    config's one geometry). Returns (q_cat [T, H, row]: the
+    absorbed query | the rotated rope part | zeros over the stored
+    row's padding, pool_lat, c_q: the query latent the indexer reads)."""
     T = h.shape[0]
-    H, R = c.num_attention_heads, c.kv_lora_rank
-    dn, dr = c.qk_nope_head_dim, c.qk_rope_head_dim
-    with jax.named_scope("mla_q"):
-        c_q = rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"],
-                       c.rms_norm_eps)
+    geo, eps = geo or config.geometry(0), config.rms_norm_eps
+    H, R = geo.heads, geo.kv_lora_rank
+    dn, dr = geo.qk_nope_head_dim, geo.qk_rope_head_dim
+    with jax.named_scope(f"{geo.scope}_q"):
+        c_q = rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"], eps)
+        if geo.q_scale != 1.0:
+            c_q = c_q * geo.q_scale
         q = qmatmul(c_q, lp["wq_b"]).reshape(T, H, dn + dr)
         # zeros where the stored row has its padding (config.latent_row)
         pad = pool_lat.shape[-1] - R - dr
@@ -280,28 +313,31 @@ def project_latent(lp, h, cos, sin, slot, position, real, pool_lat,
             [absorb_query(q[..., :dn], lp["wkv_b_k"]),
              rope_pairs(q[..., dn:], cos, sin),
              jnp.zeros((T, H, pad), q.dtype)], -1)          # [T, H, row]
-    with jax.named_scope("mla_kv"):
+    with jax.named_scope(f"{geo.scope}_kv"):
         kva = qmatmul(h, lp["wkv_a"])
+        c_kv = rms_norm(kva[:, :R], lp["kv_a_norm"], eps)
+        if geo.kv_scale != 1.0:
+            c_kv = c_kv * geo.kv_scale
         row = jnp.concatenate(
-            [rms_norm(kva[:, :R], lp["kv_a_norm"], c.rms_norm_eps),
-             rope_pairs(kva[:, R:], cos, sin),
+            [c_kv, rope_pairs(kva[:, R:], cos, sin),
              jnp.zeros((T, pad), kva.dtype)], -1)           # [T, row]
         pool_lat = write_token_rows(pool_lat, layer, row, slot, position,
-                                    real, table)
+                                    real, table, ring)
     return q_cat, pool_lat, c_q
 
 
 def attend(q_cat, pool_lat, layer: int, table, slot, first,
            selection: Selection, config, attn: str,
-           window: Optional[Window]):
+           window: Optional[Window],
+           geo: Optional[LatentGeometry] = None):
     """Every token over its selected rows -> the attended latent
     [T, H, R]. A row's single token: its rows gathered (`mla_gather`)
     and attended in one pass (`cake_mla_attn`). The window's tokens:
     their row's pages where they lie, under the selection's bias
     (`cake_mla_window_attn`)."""
-    c = config
+    geo = geo or config.geometry(0)
     P = pool_lat.shape[2]
-    scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+    scale = geo.softmax_scale
     with jax.named_scope("mla_gather"):
         # a list's tail past n_valid may name an unmapped page: read
         # page 0 there (finite, never attended) rather than pay a
@@ -312,14 +348,73 @@ def attend(q_cat, pool_lat, layer: int, table, slot, first,
             mode="promise_in_bounds")
     with jax.named_scope("mla_attn"):
         out = mla.attend_selected(q_cat[first], kv.astype(q_cat.dtype),
-                                  selection.n_valid, c.kv_lora_rank, scale,
-                                  impl=attn)
+                                  selection.n_valid, geo.kv_lora_rank,
+                                  scale, impl=attn)
         if window is None:
             return out
         win = mla.attend_window(
             _window_slice(q_cat, window), pool_lat, jnp.int32(layer),
             table[window.row], selection.bias, window.last_pos,
-            c.kv_lora_rank, scale, impl=attn)
+            geo.kv_lora_rank, scale, impl=attn)
+        return jnp.where(window.member[:, None, None], win[window.col],
+                         out[slot])
+
+
+def gathered_keys(window: int) -> int:
+    """The gathered axis of a sliding layer's single token: its window
+    of keys padded to whole tiles (513 -> 640; a test's handful to 8s)."""
+    tile = 128 if window > 128 else 8
+    return -(-window // tile) * tile
+
+
+def ring_key_positions(last_pos, page: int, ring_pages: int):
+    """The position whose row each of a ring's R * page slots holds,
+    [R * page], for a row whose newest written position is last_pos:
+    ring entry j holds the newest logical page congruent to j that the
+    row has reached (negative where it has reached none: nothing lies
+    there). Slots of the newest page past last_pos still hold the page
+    R before it; their positions read > last_pos here, which causality
+    masks."""
+    last_page = last_pos // page
+    j = jnp.arange(ring_pages)
+    logical = last_page - (last_page - j) % ring_pages
+    return (logical[:, None] * page + jnp.arange(page)[None, :]).reshape(-1)
+
+
+def attend_sliding(q_cat, pool_w, layer: int, wtable, slot, position,
+                   first, geo: LatentGeometry, attn: str,
+                   window: Optional[Window]):
+    """Every token over the last geo.window keys of its row (its own
+    included) -> the attended latent [T, H, R]. A row's single token:
+    the rows at positions pos, pos - 1, .. gathered through the ring
+    (`swa_gather`), newest first, so that its valid rows are the first
+    min(pos + 1, window), attended in one pass (`cake_swa_attn`). The
+    window's tokens: the ring's pages where they lie, under the band as
+    the bias (`cake_swa_window_attn`)."""
+    P, R = pool_w.shape[2], wtable.shape[1]
+    W, scale = geo.window, geo.softmax_scale
+    with jax.named_scope("swa_gather"):
+        rows_pos = position[first]
+        idx = jnp.maximum(
+            rows_pos[:, None] - jnp.arange(gathered_keys(W))[None, :], 0)
+        rows = jnp.arange(first.shape[0])[:, None]
+        pages = wtable[rows, (idx // P) % R]
+        kv = pool_w.at[layer, pages, idx % P].get(mode="promise_in_bounds")
+        n_valid = jnp.minimum(rows_pos + 1, W).astype(jnp.int32)
+    with jax.named_scope("swa_attn"):
+        out = mla.attend_selected(q_cat[first], kv.astype(q_cat.dtype),
+                                  n_valid, geo.kv_lora_rank, scale,
+                                  impl=attn, scope=geo.scope)
+        if window is None:
+            return out
+        s = ring_key_positions(window.last_pos, P, R)[None, :]
+        t = window.positions[:, None]
+        bias = jnp.where((s >= 0) & (s <= t) & (s > t - W), 0.0,
+                         mla.NEG_INF).astype(jnp.float32)
+        win = mla.attend_window(
+            _window_slice(q_cat, window), pool_w, jnp.int32(layer),
+            wtable[window.row], bias, jnp.int32(R * P - 1),
+            geo.kv_lora_rank, scale, impl=attn, scope=geo.scope)
         return jnp.where(window.member[:, None, None], win[window.col],
                          out[slot])
 
@@ -330,7 +425,11 @@ class TrunkOut(NamedTuple):
     the reference's (chip_compare.py; a step program drops them):
     experts [L_sparse, T, k]; selected [L_full, B, K] / n_selected [B],
     each row's single token's keys; selected_window [L_full, C, S]
-    bool, the window's (empty where there is no window)."""
+    bool, the window's (empty where there is no window); probe: the
+    first sliding layer from the inside, each [T, D]: its normed
+    input, its attention's output before the residual, and its FFN's
+    normed input (() for a model with no sliding layer), so that the
+    tool can hand the reference's layer the served path's own input."""
 
     x: jnp.ndarray
     cache: PagedKVCache
@@ -339,6 +438,7 @@ class TrunkOut(NamedTuple):
     selected: jnp.ndarray
     n_selected: jnp.ndarray
     selected_window: jnp.ndarray
+    probe: tuple
 
 
 def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
@@ -356,6 +456,12 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         x = jnp.take(params["embed"], token_ids, axis=0)
     at = jnp.minimum(position, rope.cos.shape[0] - 1)
     cos, sin = jnp.take(rope.cos, at, axis=0), jnp.take(rope.sin, at, axis=0)
+    # the rows of each RoPE table a kind of layer rotates by
+    turned = {("cos", "sin"): (cos, sin)}
+    if c.sliding_layers:
+        turned["swa_cos", "swa_sin"] = (jnp.take(rope.swa_cos, at, axis=0),
+                                        jnp.take(rope.swa_sin, at, axis=0))
+        pool_w, wtable = cache.w, cache.wtable
     first_expert = (c.first_routed_expert
                     if c.num_local_experts < c.n_routed_experts_total
                     else None)
@@ -363,32 +469,52 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     if first is None:
         first = jnp.arange(x.shape[0])
     selection = None
-    moe, experts, selected, windows = [], [], [], []
+    moe, experts, selected, windows, probe = [], [], [], [], ()
     distinct = jnp.float32(0)
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
             lp = layer_leaves(blocks, c, i)
             with jax.named_scope("attn_norm"):
                 h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+            geo = c.geometry(i)
             with jax.named_scope("attn"):
-                q_cat, pool_lat, c_q = project_latent(
-                    lp, h, cos, sin, slot, position, real, pool_lat, i,
-                    table, c)
-                if "wi_q" in lp:
-                    pool_idx, selection, last_distinct = select_keys(
-                        lp, h, c_q, cos, sin, slot, position, real, first,
-                        pool_idx, c.full_layers.index(i), table, c, window)
-                    selected.append(selection.idx)
-                    if selection.bias is not None:
-                        windows.append(selection.bias == 0)
-                distinct = distinct + last_distinct
-                o_lat = attend(q_cat, pool_lat, i, table, slot, first,
-                               selection, c, attn, window)
+                if geo.window is not None:
+                    j = c.sliding_layers.index(i)
+                    q_cat, pool_w, _ = project_latent(
+                        lp, h, *turned[geo.rope], slot, position, real,
+                        pool_w, j, wtable, c, geo, ring=True)
+                    o_lat = attend_sliding(q_cat, pool_w, j, wtable, slot,
+                                           position, first, geo, attn,
+                                           window)
+                else:
+                    j = c.latent_layers.index(i)
+                    q_cat, pool_lat, c_q = project_latent(
+                        lp, h, cos, sin, slot, position, real, pool_lat, j,
+                        table, c, geo)
+                    if "wi_q" in lp:
+                        pool_idx, selection, last_distinct = select_keys(
+                            lp, h, c_q, cos, sin, slot, position, real,
+                            first, pool_idx, c.full_layers.index(i), table,
+                            c, window)
+                        selected.append(selection.idx)
+                        if selection.bias is not None:
+                            windows.append(selection.bias == 0)
+                    distinct = distinct + last_distinct
+                    o_lat = attend(q_cat, pool_lat, j, table, slot, first,
+                                   selection, c, attn, window, geo)
                 o = unabsorb_value(o_lat, lp["wkv_b_v"])
+                if geo.gated:
+                    with jax.named_scope("attn_gate"):
+                        gate = jax.nn.sigmoid(
+                            qmatmul(h, lp[GATE_LEAF]).astype(jnp.float32))
+                        o = (o * gate[..., None]).astype(o.dtype)
             with jax.named_scope("o_proj"):
-                x = x + qmatmul(o.reshape(o.shape[0], -1), lp["wo"])
+                attn_out = qmatmul(o.reshape(o.shape[0], -1), lp["wo"])
+                x = x + attn_out
             with jax.named_scope("ffn"):
-                h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
+                h_attn, h = h, rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
+                if geo.window is not None and not probe:
+                    probe = (h_attn, attn_out, h)
                 if "router" in lp:
                     out, stats = moe_mlp(
                         lp, h[None], c.num_experts_per_tok,
@@ -405,7 +531,9 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     n_real = jnp.sum(real, dtype=jnp.float32)
-    L, Lf = c.num_hidden_layers, len(c.full_layers)
+    # the indexer's counters count the layers of the latent pool
+    # (all of them but the sliding ones)
+    L, Lf = len(c.latent_layers), len(c.full_layers)
     stepped = (n_real > 0).astype(jnp.float32)
     visible = jnp.where(real, position + 1, 0).astype(jnp.float32)
     f32 = jnp.float32
@@ -423,10 +551,19 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                                              table.shape[1]
                                              * pool_lat.shape[2]))),
         distinct, Lf * stepped, (L - Lf) * stepped]).astype(f32)
-    return TrunkOut(x, cache._replace(k=pool_lat, v=pool_idx), counters,
+    cache = cache._replace(k=pool_lat, v=pool_idx)
+    if c.sliding_layers:
+        Lw = len(c.sliding_layers)
+        counters = jnp.concatenate([counters, jnp.stack([
+            Lw * jnp.sum(visible),
+            Lw * jnp.sum(jnp.minimum(visible, c.sliding_window_size)),
+            Lw * stepped]).astype(f32)])
+        cache = cache._replace(w=pool_w)
+    return TrunkOut(x, cache, counters,
                     jnp.stack(experts) if experts else jnp.zeros((0,)),
                     jnp.stack(selected), selection.n_valid,
-                    jnp.stack(windows) if windows else jnp.zeros((0,), bool))
+                    jnp.stack(windows) if windows else jnp.zeros((0,), bool),
+                    probe)
 
 
 def window_of(plan: paged.PackPlan, pos, q_len, active) -> Window:

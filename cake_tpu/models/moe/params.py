@@ -65,12 +65,15 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     router's selection bias are drawn away from their neutral values,
     so that a test sees them."""
     c = config
-    L, D = c.num_hidden_layers, c.hidden_size
+    D = c.hidden_size
+    # the attention leaves of the layers in the latent pool (all of
+    # them but a dots3_note model's sliding layers, which follow below)
+    L = len(c.latent_layers)
     H, R, Rq = c.num_attention_heads, c.kv_lora_rank, c.q_lora_rank
     dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
     nI, dI = c.index_n_heads, c.index_head_dim
     Lf, Ls = len(c.full_layers), len(c.sparse_layers)
-    Ld = L - Ls
+    Ld = c.num_hidden_layers - Ls
     E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
                      c.moe_intermediate_size, c.intermediate_size)
     w, mat, keys = _draws(rng, dtype, bits, 40)
@@ -79,18 +82,33 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
         return (centre + 0.1 * jax.random.normal(
             next(keys), shape, jnp.float32)).astype(dtype)
 
+    def read_at(rank: int, scale: float) -> float:
+        """The fan-in a reader of a normed latent is drawn at: the
+        rank, times the square of what the latent is multiplied by (a
+        rescale by sqrt(hidden / rank) says the latent is read at the
+        hidden size's variance: it corrects an init of one sigma for
+        every matrix, and under this fan-in draw it would make the
+        scores seven times as wide, a softmax that is all but one-hot
+        and a model in which one rounding moves every later layer: the
+        chip comparison read 4.7e-2 of the logits' range so; PERF.md
+        section 6, PR 41). q, k and v then have the variance they have
+        without a rescale."""
+        return rank * scale ** 2
+
+    g = c.geometry(c.latent_layers[0])
+    fq, fkv = read_at(Rq, g.q_scale), read_at(R, g.kv_scale)
     blocks = {
         "attn_norm": near((L, D), 1.0),
         "wq_a": mat("wq_a", (L, D, Rq), D),
         "q_a_norm": near((L, Rq), 1.0),
-        "wq_b": mat("wq_b", (L, Rq, H * (dn + dr)), Rq),
+        "wq_b": mat("wq_b", (L, Rq, H * (dn + dr)), fq),
         "wkv_a": mat("wkv_a", (L, D, R + dr), D),
         "kv_a_norm": near((L, R), 1.0),
-        "wkv_b_k": mat("wkv_b_k", (L, R, H * dn), R),
-        "wkv_b_v": mat("wkv_b_v", (L, R, H * dv), R),
+        "wkv_b_k": mat("wkv_b_k", (L, R, H * dn), fkv),
+        "wkv_b_v": mat("wkv_b_v", (L, R, H * dv), fkv),
         "wo": mat("wo", (L, H * dv, D), H * dv),
         "mlp_norm": near((L, D), 1.0),
-        "wi_q": mat("wi_q", (Lf, Rq, nI * dI), Rq),
+        "wi_q": mat("wi_q", (Lf, Rq, nI * dI), fq),
         "wi_k": mat("wi_k", (Lf, D, dI), D),
         "wi_k_norm": near((Lf, dI), 1.0),
         "wi_k_bias": near((Lf, dI), 0.0),
@@ -113,12 +131,41 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
             "w_up": mat("w_up", (Ld, D, Fd), D),
             "w_down": mat("w_down", (Ld, Fd, D), Fd),
         })
-    return {
+    top = {
         "embed": w((c.vocab_size, D), D),
         "blocks": blocks,
         "final_norm": near((D,), 1.0),
         "lm_head": mat("lm_head", (D, c.vocab_size), D),
     }
+    if c.sliding_layers or g.gated:
+        # dots3_note, drawn from keys of their own so that the leaves
+        # above are what a glm_moe_dsa tree of these sizes would hold:
+        # the sliding layers' stack under "swa", in ITS geometry, and a
+        # head-wise gate [D, H] in every layer
+        w, mat, keys = _draws(jax.random.fold_in(rng, 1), dtype, bits, 20)
+        if g.gated:
+            blocks["w_attn_gate"] = mat("w_attn_gate", (L, D, H), D)
+    if c.sliding_layers:
+        g = c.geometry(c.sliding_layers[0])
+        n, Hs, Rs, Rqs = (len(c.sliding_layers), g.heads, g.kv_lora_rank,
+                          g.q_lora_rank)
+        fq, fkv = read_at(Rqs, g.q_scale), read_at(Rs, g.kv_scale)
+        blocks["swa"] = {
+            "attn_norm": near((n, D), 1.0),
+            "wq_a": mat("wq_a", (n, D, Rqs), D),
+            "q_a_norm": near((n, Rqs), 1.0),
+            "wq_b": mat("wq_b", (n, Rqs, Hs * (g.qk_nope_head_dim
+                                               + g.qk_rope_head_dim)), fq),
+            "wkv_a": mat("wkv_a", (n, D, Rs + g.qk_rope_head_dim), D),
+            "kv_a_norm": near((n, Rs), 1.0),
+            "wkv_b_k": mat("wkv_b_k", (n, Rs, Hs * g.qk_nope_head_dim), fkv),
+            "wkv_b_v": mat("wkv_b_v", (n, Rs, Hs * g.v_head_dim), fkv),
+            "wo": mat("wo", (n, Hs * g.v_head_dim, D), Hs * g.v_head_dim),
+            "mlp_norm": near((n, D), 1.0),
+        }
+        if g.gated:
+            blocks["swa"]["w_attn_gate"] = mat("w_attn_gate", (n, D, Hs), D)
+    return top
 
 
 def _init_nemotron_params(config, rng: jax.Array, dtype,

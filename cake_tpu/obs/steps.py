@@ -309,11 +309,31 @@ CCA_TAIL_BYTES = _m.gauge(
     "every slot)")
 
 
+# a step program of a model with sliding-window latent layers beside
+# its full ones (dots3_note: models/moe/glm_dsa.trunk) returns
+# STEP_COUNTERS, whose dsa_* then count the FULL layers alone, then these
+SWA_COUNTERS = (
+    ("swa_keys_visible", _m.counter(
+        "cake_swa_keys_visible_total",
+        "Keys visible to the query tokens (position + 1), summed over "
+        "tokens and sliding-window layers")),
+    ("swa_keys_attended", _m.counter(
+        "cake_swa_keys_attended_total",
+        "Keys the sliding-window layers attended (the visible ones "
+        "inside the window), summed over tokens and sliding layers")),
+    ("swa_layers", _m.counter(
+        "cake_swa_layers_total",
+        "Sliding-window layers run, summed over dispatches")),
+)
+SWA_LAYOUT = STEP_COUNTERS + SWA_COUNTERS
+
+
 def counter_layout(n: int) -> tuple:
     """The (record key, series) of a step program's counter vector, by
     its length: a sparse model's five, a zaya model's seven, a
-    glm_moe_dsa model's eleven, a nemotron_h model's ten."""
-    for layout in (SSM_LAYOUT, CCA_LAYOUT):
+    glm_moe_dsa model's eleven, a nemotron_h model's ten, a dots3_note
+    model's fourteen."""
+    for layout in (SSM_LAYOUT, CCA_LAYOUT, SWA_LAYOUT):
         if n == len(layout):
             return layout
     return STEP_COUNTERS[:n]

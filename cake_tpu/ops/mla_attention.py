@@ -77,14 +77,15 @@ def _attend_kernel(n_ref, q_ref, kv_ref, o_ref, *, r: int, scale: float):
     o_ref[...] = (o / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("r", "scale", "interpret", "scope"))
 def _attend_pallas(q, kv, n_valid, *, r: int, scale: float,
-                   interpret: bool):
+                   interpret: bool, scope: str = "mla"):
     T, H, W = q.shape
     K = kv.shape[1]
     return pl.pallas_call(
         functools.partial(_attend_kernel, r=r, scale=scale),
-        name="cake_mla_attn",
+        name="cake_" + scope + "_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(T,),
@@ -99,17 +100,21 @@ def _attend_pallas(q, kv, n_valid, *, r: int, scale: float,
 
 
 def attend_selected(q, kv, n_valid, r: int, scale: float,
-                    impl: str = "fold", interpret: Optional[bool] = None):
+                    impl: str = "fold", interpret: Optional[bool] = None,
+                    scope: str = "mla"):
     """q [T, H, W] (the absorbed query: q_lat | q_pe), kv [T, K, W] the
     token's selected cache rows (its first n_valid[t] are real), r the
     value's width (the leading r of a row). Returns the weighted sum of
     the rows' first r numbers, [T, H, r]; a token with no valid row
-    (padding) gets an unspecified finite result."""
+    (padding) gets an unspecified finite result. scope: the kind of
+    layer in the kernel's name in a trace (LatentGeometry.scope: a
+    sliding-window layer runs the same body at its own sizes as
+    `cake_swa_attn`)."""
     if impl == "pallas":
         if interpret is None:
             interpret = not rpa._on_tpu()
         return _attend_pallas(q, kv, n_valid, r=r, scale=scale,
-                              interpret=interpret)
+                              interpret=interpret, scope=scope)
     if impl != "fold":
         raise ValueError(f"unknown latent attention impl {impl!r}")
     return _attend_fold(q, kv, n_valid, r, scale)
@@ -178,12 +183,19 @@ def _window_kernel(layer_ref, table_ref, last_ref, q_ref, bias_ref, kv_ref,
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("r", "scale", "interpret", "scope"))
 def _window_pallas(q, pool, layer, table_row, bias, last_pos, *, r: int,
-                   scale: float, interpret: bool):
+                   scale: float, interpret: bool, scope: str = "mla"):
     C, H, W = q.shape
     page, max_pages = pool.shape[2], table_row.shape[0]
-    tq = next(t for t in (16, 8, 4, 2, 1) if C % t == 0)
+    # the widest tile of (token, head) rows whose queries (two buffers),
+    # accumulator and result (two) stay under half of the kernel's 16 MiB
+    # of scoped VMEM: 16 tokens at 64 heads of a 640-wide row, 8 at 128
+    # heads, 8 at 64 heads of a 1,152-wide row with a 1,024-wide value
+    tq = next(t for t in (16, 8, 4, 2, 1)
+              if C % t == 0 and (t * H * (4 * W + 8 * r) <= 8 * 2**20
+                                 or t == 1))
 
     def kv_map(i, j, layer, table, last):
         # pages past the window's last position repeat the last one
@@ -193,7 +205,7 @@ def _window_pallas(q, pool, layer, table_row, bias, last_pos, *, r: int,
     out = pl.pallas_call(
         functools.partial(_window_kernel, r=r, scale=scale, heads=H,
                           page=page),
-        name="cake_mla_window_attn",
+        name="cake_" + scope + "_window_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(C // tq, max_pages),
@@ -218,23 +230,28 @@ def _window_pallas(q, pool, layer, table_row, bias, last_pos, *, r: int,
 
 def attend_window(q, pool, layer, table_row, bias, last_pos, r: int,
                   scale: float, impl: str = "fold",
-                  interpret: Optional[bool] = None):
+                  interpret: Optional[bool] = None, scope: str = "mla"):
     """A window's C queries (q [C, H, W], the absorbed form) over ONE
     row's pages of the latent pool [L, N, page, W], read where they lie
     through table_row [max_pages]: no gather. bias [C, S] float32 says
     which keys a query attends: 0 for a selected key, NEG_INF for every
     other (unselected, invisible, or on an unmapped page); last_pos:
     the window's last position (pages past it are not read). Returns
-    [C, H, r]; a query with no selected key gets zeros.
+    [C, H, r]; a query with no selected key gets zeros. A sliding-window
+    layer passes its row's RING of pages as table_row (key index j *
+    page + o is then the o-th token of the ring's j-th page, whatever
+    position lies there: the bias says), and its own `scope` (the
+    kernel is then named `cake_swa_window_attn`).
 
     impl "pallas": `cake_mla_window_attn`, grid (query tiles, pages),
-    the flash recurrence over the page axis, 16 tokens x H heads a tile
-    so that the MXU sees a thousand rows a page."""
+    the flash recurrence over the page axis, 16 tokens x 64 heads (8 x
+    128) a tile so that the MXU sees a thousand rows a page."""
     if impl == "pallas":
         if interpret is None:
             interpret = not rpa._on_tpu()
         return _window_pallas(q, pool, layer, table_row, bias, last_pos,
-                              r=r, scale=scale, interpret=interpret)
+                              r=r, scale=scale, interpret=interpret,
+                              scope=scope)
     if impl != "fold":
         raise ValueError(f"unknown latent attention impl {impl!r}")
     return _window_fold(q, pool, layer, table_row, bias, r, scale)

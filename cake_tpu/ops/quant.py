@@ -177,6 +177,8 @@ _BLOCK_CONTRACT = {
     "wq_a": (1,), "wq_b": (1,), "wkv_a": (1,), "wkv_b_k": (1,),
     "wkv_b_v": (1,), "wi_q": (1,), "wi_k": (1,),
     "ws_gate": (1,), "ws_up": (1,), "ws_down": (1,),
+    # the head-wise attention gate [layers of the kind, D, H] (dots3_note)
+    "w_attn_gate": (1,),
     # Mamba-2's projections and the latent experts' (nemotron_h)
     "w_in": (1,), "w_out": (1,), "w_fc1": (1,), "w_fc2": (1,),
     # CCA's fused projection into the latent, [q | k | v1 | v2] (zaya);

@@ -694,6 +694,10 @@ class InferenceEngine:
         # (models/moe/glm_dsa.py). What the latent pool does not serve
         # yet is refused here, by the option's name, never ignored.
         self._latent = bool(getattr(config, "kv_lora_rank", None))
+        # ... and, where some layers attend a sliding window, their rows
+        # in a pool of their own behind a ring table a row
+        # (models/llama/paged.WindowedPagedCache)
+        self._windowed = self._latent and bool(config.sliding_layers)
         if self._latent:
             refused = [name for name, on in (
                 ("serving without --kv-pages (the dense-slot engine)",
@@ -709,8 +713,9 @@ class InferenceEngine:
             ) if on]
             if refused:
                 raise ValueError(
-                    "model_type glm_moe_dsa (latent attention over the "
-                    "page pool) does not serve yet: " + "; ".join(refused)
+                    f"model_type {config.hf_layout} (latent attention "
+                    "over the page pool) does not serve yet: "
+                    + "; ".join(refused)
                     + " (ROADMAP.md lists each as left to do)")
         # recurrent blocks (nemotron_h): a state a ROW beside the page
         # pool (models/llama/paged.HybridPagedCache), carried by the
@@ -1732,10 +1737,11 @@ class InferenceEngine:
                           "target prefill would leave the draft cold "
                           "(acceptance would silently collapse)")
             elif self._latent:
-                reason = ("the latent page pool (glm_moe_dsa) has no "
-                          "prefix pages yet: a shared head would need "
-                          "its latent rows and its index keys mapped "
-                          "together (ROADMAP.md)")
+                reason = (f"the latent page pool ({self.config.hf_layout}) "
+                          "has no prefix pages yet: a shared head would "
+                          "need its latent rows and its index keys (and a "
+                          "windowed model's ring) mapped together "
+                          "(ROADMAP.md)")
             elif self._recurrent:
                 reason = ("a recurrent state (nemotron_h) has no prefix "
                           "reuse yet: a shared head would need the "
@@ -2790,6 +2796,13 @@ class InferenceEngine:
             self.cache = qcls.create(
                 self.config, self.max_slots, kv_pages, kv_page_size,
                 self.max_seq_len)
+        elif self._windowed:
+            self.cache = self._fresh_windowed_cache(kv_pages, kv_page_size)
+            log.info("window pool: %d sliding layers x %d pages (%d a "
+                     "row, %d rows), %.2f GiB beside the pool",
+                     len(self.config.sliding_layers),
+                     self.cache.n_window_pages, self.cache.ring_pages,
+                     self.max_slots, self.cache.window_bytes() / 2**30)
         else:
             self.cache = PagedKVCache.create(
                 self.config, self.max_slots, kv_pages, kv_page_size,
@@ -2869,6 +2882,18 @@ class InferenceEngine:
                      kv_pages, kv_page_size,
                      self.d_cache.memory_bytes() / 2**30,
                      self._specp.live_gamma)
+
+    def _fresh_windowed_cache(self, kv_pages: int, kv_page_size: int):
+        """The pools by kind of layer. Slot i owns a ring of the window
+        pool for good (WindowedPagedCache.create maps it), so that pool
+        is slots x ring whatever max_seq_len, and admission, release
+        and a rebuild never touch it."""
+        from cake_tpu.models.llama.paged import WindowedPagedCache
+        return WindowedPagedCache.create(
+            self.config, self.max_slots, kv_pages, kv_page_size,
+            self.max_seq_len,
+            self.config.window_ring_pages(kv_page_size, self._mixed_chunk),
+            dtype=self._pool_dtype)
 
     def _resolve_paged_attn(self, requested: Optional[str],
                             kv_pages: int, kv_page_size: int) -> None:
@@ -3040,9 +3065,9 @@ class InferenceEngine:
 
     def _reconfig_refusal(self) -> str:
         if self._latent:
-            return ("the latent page pool (glm_moe_dsa) serves on pages "
-                    "only: there is no dense or quantized pool to "
-                    "switch to")
+            return (f"the latent page pool ({self.config.hf_layout}) "
+                    "serves on pages only: there is no dense or "
+                    "quantized pool to switch to")
         if self._recurrent:
             return ("a recurrent state (nemotron_h) lives beside the "
                     "page pool: a rebuilt pool cannot replay it")
@@ -3675,6 +3700,9 @@ class InferenceEngine:
                 return qcls.create(
                     self.config, self.max_slots, self.cache.n_pages,
                     self.cache.page_size, self.max_seq_len)
+            if self._windowed:
+                return self._fresh_windowed_cache(self.cache.n_pages,
+                                                  self.cache.page_size)
             return PagedKVCache.create(
                 self.config, self.max_slots, self.cache.n_pages,
                 self.cache.page_size, self.max_seq_len,

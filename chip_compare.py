@@ -183,6 +183,70 @@ ZAYA_JOBS = ((0, 129), (1, 192), (2, 256), (3, 192), (4, 897), (5, 1024),
              (6, 960), (7, 960), (0, 140))
 ZAYA_DECODE = 64
 
+# dots3_note: 9 layers, 3 full (an indexer each) and 6 sliding (513 keys
+# of a 9-page ring), two latent geometries, a gate a head, 8 of 256
+# experts a token of which this chip holds 32. As for glm_moe_dsa the
+# choice of keys and of experts is chaotic under seeded weights, so the
+# reference is TEACHER-FORCED in its experts (it computes the experts
+# the served path chose, weighed by its own scores) and precision is
+# read where no key is dropped (`mean_dense`: positions under
+# index_topk; every sliding layer already cuts there, positions past
+# 513); beyond it the limits are the mean error (`mean`) and the least
+# share of attended keys in common with the reference over the three
+# full layers (`keys`). `agree` is the least, over the sparse layers,
+# share of positions where the reference's OWN choice of 8 experts is
+# the served path's. `mean_wrapped` is `mean_dense` over the positions
+# past 1,152 alone (the ring has wrapped: a prefilled row's and a
+# decoded one's), reported. A window off by one and bfloat16 scores
+# move the logits by LESS than the served path's own rounding (7e-4
+# and 8e-4 against 2.5e-3), so no limit on an error can hold them:
+# `nearer` does, the served path's distance from the altered reference
+# over its distance from the plain one (root of summed squares over the
+# compared rows): above 1 while the served path computes the plain
+# reference, and what `nearer_if_served` reads (the same ratio for a
+# served path that computed the altered one with the same rounding)
+# if it did not. Readings (my chip runs, PR 41, seeds 0 / 1; served
+# path / what must fail): mean_dense 2.49e-3 / no gate 0.110, no
+# rescale 0.135; mean 1.21e-2 / dense attention 6.9e-2; keys 0.899 /
+# dense attention 0.856; agree 0.653 / no gate, no rescale 0.0; nearer
+# at its least 1.0159 (window - 1), 1.0406 (bf16 scores), 7.9 (dense
+# attention) / nearer_if_served at its most 0.985. PERF.md section 6.
+# THE PROBE (after review: `nearer` has 1 % of room on either side).
+# The trunk hands out the first sliding layer's normed input, its
+# attention's output and its FFN's normed input (TrunkOut.probe), and
+# the reference's layer runs on THAT input, so that no other layer's
+# rounding is on either side. `layer_err`: the served output against
+# the reference's (root of summed squares over the reference's, compared
+# positions of the three shorter rows); `layer_nearer`: the `nearer`
+# ratio on that output; `agree_same_input`: the share of ALL their
+# positions where the reference's router, on the served FFN input,
+# chooses the served path's 8 experts (no teacher, no cascade: `agree`
+# above reads the reference's own trajectory, where a third of the
+# positions differ by chaos, so it only tells a router that is wrong
+# from one that is right); `router_logit_err`: ops/moe.router_logits
+# (what moe_mlp calls) on the chip against the reference's product on
+# the same input, worst entry. The limits were set on a first reading
+# (my chip run, PR 41, seed 5, rows of 2,500 / 1,120 / 700; served path
+# / what must fail): layer_err 3.40e-3 / a window of 514, 512: 1.25e-2,
+# 1.23e-2 (no gate 1.01, no rescale 0.59); layer_nearer 3.79, 3.76 for
+# the window (298, 172) / layer_nearer_if_served 0.263, 0.267;
+# agree_same_input 1.0 / a bf16 router 0.904; router_logit_err 0.0 /
+# 1.49e-2: each between its two readings, 1.9 times of room either side
+# of layer_err, 2.5 times above and 5.6 below layer_nearer, 0.05 either
+# side of agree_same_input. Then seeds 0 / 1 with the 16.3k row, from
+# the clean archive: layer_err 3.49e-3 / 3.51e-3 against 1.38e-2 at
+# the least for a window off by one; layer_nearer 4.09 at the least,
+# layer_nearer_if_served 0.245 at the most; agree_same_input 1.0 / 1.0
+# against 0.921 / 0.917; router_logit_err 0.0 / 0.0 against 1.55e-2.
+DOTS3_TOL = {"mean_dense": 5e-3, "mean": 2.5e-2, "keys": 0.875, "agree": 0.3,
+             "nearer": 1.005, "layer_err": 6.5e-3, "layer_nearer": 1.5,
+             "agree_same_input": 0.95, "router_logit_err": 1e-3}
+# (prompt tokens): the cell's long class, a row past index_topk, one of
+# the short class whose DECODE crosses 1,152 (the ring wraps under the
+# decode program and under mixed steps), one past the window alone
+DOTS3_PROMPTS = (16300, 2500, 1120, 700)
+DOTS3_DECODE = 48
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -301,6 +365,8 @@ def main() -> int:
     engine, cell, raw_config = build_engine(args.rehearse, args.config_dir)
     if raw_config.get("model_type") == "glm_moe_dsa":
         return compare_glm(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "dots3_note":
+        return compare_dots3(engine, cell, args, t_start)
     if raw_config.get("model_type") == "nemotron_h":
         return compare_nemotron(engine, cell, args, t_start)
     if raw_config.get("model_type") == "zaya":
@@ -867,6 +933,524 @@ def compare_glm(engine, cell, args, t_start) -> int:
     result["ok"] = bool(ok)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"glm_result_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if ok else 1
+
+
+# -- dots3_note ----------------------------------------------------------------
+
+
+def compare_dots3(engine, cell, args, t_start) -> int:
+    """The comparison above for two kinds of latent layer over three
+    pools: the engine's own mixed and decode trunks (the ring table as
+    the engine maps it: R pages a row) with the head at every position,
+    against models/reference/dots3_note.py."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import glm_dsa
+    from cake_tpu.models.reference import dots3_note as ref
+    from cake_tpu.ops.moe import LayerOf
+    from cake_tpu.ops.quant import qmatmul
+
+    cfg, params, rope = engine.config, engine.params, engine.rope
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    attn = engine.attn_impl["mixed"]
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = glm_dsa.mixed_trunk(params, tokens, pos, q_len, active,
+                                     cache, rope, cfg, attn, n_tokens)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return (logits, out.cache, out.experts, out.selected,
+                out.n_selected, out.selected_window, out.probe)
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        out = glm_dsa.decode_trunk(params, tokens, cache, pos, active, rope,
+                                   cfg, attn)
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        return (logits, out.cache, out.experts, out.selected,
+                out.n_selected, out.probe)
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    R = engine.cache.ring_pages
+    W, K = cfg.sliding_window_size, cfg.index_topk
+    wrapped = R * page          # positions past it lie in a reused page
+    prompts = DOTS3_PROMPTS if not args.rehearse else (620, 300, 140, 30)
+    n_decode = DOTS3_DECODE if not args.rehearse else 8
+    last = LAST if not args.rehearse else 24
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for p in prompts]
+    n_seq = len(sequences)
+    assert n_seq <= B and max(prompts) + n_decode <= per_row * page
+    assert R == cfg.window_ring_pages(page, C)
+    table = np.full((B, per_row), -1, np.int32)
+    at = 0
+    for b, seq in enumerate(sequences):
+        n = -(-len(seq) // page)
+        table[b, :n] = at + np.arange(n)
+        at += n
+    assert at <= engine.cache.n_pages
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    engine.cache = None
+
+    got = [dict() for _ in sequences]       # position -> logits [V]
+    routed = [dict() for _ in sequences]    # position -> experts [Ls, k]
+    picked = [dict() for _ in sequences]    # position -> [Lf] key sets
+    shared_step = set()                     # (b, position): a decode row
+    off = [0] * n_seq                       # beside another row's window
+    # every position's experts, for the teacher-forced reference
+    all_routed = [np.zeros((len(cfg.sparse_layers), len(seq),
+                            cfg.num_experts_per_tok), np.int32)
+                  for seq in sequences]
+    # the first sliding layer from the inside (glm_dsa.TrunkOut.probe),
+    # at EVERY position of every row but the longest: its normed input,
+    # its attention's output, its FFN's normed input
+    probe_rows = sorted(range(n_seq), key=lambda b: len(sequences[b]))[:-1]
+    probed = {b: np.zeros((3, len(sequences[b]), cfg.hidden_size),
+                          np.float32) for b in probe_rows}
+
+    def keep(b, position, logits, experts_t, selected, n_sel, window=None):
+        got[b][position] = logits
+        routed[b][position] = experts_t
+        if window is None:
+            picked[b][position] = [set(selected[f, b, :n_sel[b]].tolist())
+                                   for f in range(selected.shape[0])]
+        else:
+            sets, col = window
+            picked[b][position] = [set(np.flatnonzero(sets[f, col]).tolist())
+                                   for f in range(sets.shape[0])]
+
+    def compared(b, position):
+        """The prompt's last positions and every decode step; the last
+        positions under index_topk (where precision is read); the first
+        past the window (the band starts to cut) and the first past the
+        ring's turn, in the rows that reach them."""
+        return (position >= prompts[b] - last
+                or K - last <= position < K
+                or W - 8 <= position < W + 24
+                or wrapped - 8 <= position < wrapped + 24)
+
+    steps = {"mixed": 0, "decode": 0}
+    t0 = time.monotonic()
+    while any(off[b] < prompts[b] for b in range(n_seq)):
+        toks = np.zeros((B, C), np.int32)
+        pos = np.zeros(B, np.int32)
+        qlen = np.zeros(B, np.int32)
+        for b, seq in enumerate(sequences):
+            if off[b] < prompts[b]:
+                n = min(C, prompts[b] - off[b])
+            elif off[b] < prompts[b] + n_decode // 2:
+                n = 1          # half the decode steps ride mixed steps
+            else:
+                continue
+            toks[b, :n], pos[b], qlen[b] = seq[off[b]:off[b] + n], off[b], n
+        active = qlen > 0
+        for group in engine._mixed_groups(qlen):
+            glen = np.where(group, qlen, 0)
+            n_tokens = paged.mixed_bucket_for(engine._mixed_buckets,
+                                              int(glen.sum()))
+            (logits, cache, experts, selected, n_sel, sets,
+             probe) = window_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(glen), jnp.asarray(active & group), cache,
+                n_tokens)
+            first = np.cumsum(glen) - glen
+            experts = np.asarray(experts)
+            for b in np.flatnonzero(glen):
+                all_routed[b][:, off[b]:off[b] + glen[b]] = experts[
+                    :, first[b]:first[b] + glen[b]]
+                if b in probed:
+                    for i, tapped in enumerate(probe):
+                        probed[b][i, off[b]:off[b] + glen[b]] = np.asarray(
+                            tapped[first[b]:first[b] + glen[b]], np.float32)
+            served_dtype = probe[2].dtype
+            wanted = [(b, j) for b in np.flatnonzero(glen)
+                      for j in range(glen[b]) if compared(b, off[b] + j)]
+            if wanted:
+                selected, n_sel, sets = (
+                    np.asarray(selected), np.asarray(n_sel),
+                    np.asarray(sets))
+                rows = np.asarray([first[b] + j for b, j in wanted])
+                fetched = np.asarray(logits[rows])
+                for i, (b, j) in enumerate(wanted):
+                    keep(b, off[b] + j, fetched[i],
+                         experts[:, first[b] + j], selected, n_sel,
+                         (sets, j) if glen[b] > 1 else None)
+                    if glen[b] == 1 and glen.max() > 1:
+                        shared_step.add((b, off[b] + j))
+        for b in range(n_seq):
+            off[b] += int(qlen[b])
+        steps["mixed"] += 1
+    while any(off[b] < len(s) for b, s in enumerate(sequences)):
+        toks = np.zeros((B, 1), np.int32)
+        pos = np.zeros(B, np.int32)
+        active = np.zeros(B, bool)
+        for b, seq in enumerate(sequences):
+            if off[b] < len(seq):
+                toks[b, 0], pos[b], active[b] = seq[off[b]], off[b], True
+        logits, cache, experts, selected, n_sel, probe = decode_step(
+            params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(active),
+            cache)
+        probe = [np.asarray(tapped, np.float32) for tapped in probe]
+        logits, experts, selected, n_sel = (
+            np.asarray(logits), np.asarray(experts), np.asarray(selected),
+            np.asarray(n_sel))
+        for b in np.flatnonzero(active):
+            keep(b, off[b], logits[b], experts[:, b], selected, n_sel)
+            all_routed[b][:, off[b]] = experts[:, b]
+            if b in probed:
+                for i, tapped in enumerate(probe):
+                    probed[b][i, off[b]] = tapped[b]
+            off[b] += 1
+        steps["decode"] += 1
+    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+        f"steps in {time.monotonic() - t0:.1f} s")
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time --------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = {k: getattr(cfg, k) for k in (
+        "hidden_size", "rms_norm_eps", "sliding_window_size",
+        "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim", "rope_theta", "swa_num_attention_heads",
+        "swa_qk_nope_head_dim", "swa_qk_rope_head_dim", "swa_v_head_dim",
+        "swa_rope_theta", "index_n_heads", "index_head_dim", "index_topk",
+        "num_experts_per_tok", "norm_topk_prob", "routed_scaling_factor",
+        "scoring_func")}
+    ref_cfg["layer_types"] = cfg.indexer_types
+    held = (cfg.first_routed_expert, cfg.num_local_experts)
+    plain = {"attend_block": ref.attend_block, "index_block": ref.index_block,
+             "select_block": ref.select_block, "swiglu": ref.swiglu,
+             "router": ref.router}
+    bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+
+    def compiled(low_precision: bool = False):
+        """The reference's heavy functions under jit; low_precision:
+        bfloat16 where the configuration says float32 (the attention
+        scores before the softmax, the index scores, the router's
+        scores)."""
+        attend, index, route = (plain["attend_block"], plain["index_block"],
+                                plain["router"])
+        if low_precision:
+            def attend(q_nope, q_pe, k_nope, k_pe, v, mask, scale):
+                scores = (jnp.einsum("thd,shd->hts", q_nope, k_nope)
+                          + jnp.einsum("thd,sd->hts", q_pe, k_pe)) * scale
+                scores = jnp.where(mask[None], bf16(scores), ref.NEG)
+                probs = bf16(jax.nn.softmax(scores, axis=-1))
+                return jnp.einsum("hts,shd->thd", probs, v)
+
+            def index(qI, kI, w):
+                return bf16(plain["index_block"](qI, kI, w))
+
+            def route(lp, h, config, forced=None):
+                k = config["num_experts_per_tok"]
+                scores = bf16(jax.nn.sigmoid(bf16(ref.mm(h, lp["router"]))))
+                order = jnp.argsort(-(scores + lp["router_bias"]), axis=-1,
+                                    stable=True)[:, :k]
+                chosen = order if forced is None else jnp.asarray(forced)
+                weights = jnp.take_along_axis(scores, chosen, axis=-1)
+                weights = weights / (jnp.sum(weights, -1, keepdims=True)
+                                     + 1e-20)
+                return (weights * config["routed_scaling_factor"], chosen,
+                        order)
+        ref.attend_block = jax.jit(attend, static_argnames=("scale",))
+        ref.index_block = jax.jit(index)
+        ref.select_block = jax.jit(plain["select_block"],
+                                   static_argnames=("topk",))
+        ref.swiglu = jax.jit(plain["swiglu"])
+        ref.router = route
+
+    def layers():
+        for i in range(cfg.num_hidden_layers):
+            lp = glm_dsa.layer_leaves(host["blocks"], cfg, i)
+            yield {k: dequantized(jax.tree.map(
+                       lambda a: jnp.asarray(a[int(v.layer)]), v.stacked)
+                       if isinstance(v, LayerOf)
+                       else jax.tree.map(jnp.asarray, v))
+                   for k, v in lp.items()}
+
+    top = {k: dequantized(jax.tree.map(jnp.asarray, host[k]))
+           for k in ("embed", "final_norm", "lm_head")}
+    full = {layer: f for f, layer in enumerate(cfg.full_layers)}
+
+    def reference(which, config=ref_cfg):
+        """The reference over the sequences `which`, TEACHER-FORCED:
+        every sparse layer computes the experts the served path chose
+        for the token, weighed by its own scores; `routing` receives
+        its own choice along that trajectory."""
+        t0 = time.monotonic()
+        seqs = [sequences[b] for b in which]
+        routing = [[] for _ in seqs]
+        selections = [[] for _ in seqs]
+        logits = ref.forward(top, seqs, config, layers=layers(),
+                             held=held, routing=routing,
+                             selections=selections,
+                             forced=[list(all_routed[b]) for b in which])
+        # the full layers' sets alone are compared: drop the bands
+        keys = [[m if i in full else None for i, m in enumerate(sel)]
+                for sel in selections]
+        say(f"  reference over {sum(len(s) for s in seqs)} tokens in "
+            f"{time.monotonic() - t0:.1f} s")
+        return [np.asarray(x) for x in logits], routing, keys
+
+    # -- the probe: ONE sliding layer (the first) on the served path's
+    # own inputs, so that the rounding of the layers before it and after
+    # it is in neither side: its attention's output and its router
+    p_layer = cfg.sliding_layers[0]
+    p_sparse = cfg.sparse_layers.index(p_layer)
+    lp = glm_dsa.layer_leaves(host["blocks"], cfg, p_layer)
+    p_leaves = {k: dequantized(jax.tree.map(jnp.asarray, lp[k]))
+                for k in (*glm_dsa.ATTN_LEAVES, glm_dsa.GATE_LEAF, "router",
+                          "router_bias")}
+    del lp
+
+    def probe_run(config=ref_cfg, low_precision=False):
+        """{row: (the layer's attention output [S, D], its router's own
+        choice [S, k], its router's logits [S, E])} from the reference's
+        functions as they stand (compiled() may have lowered them), on
+        the inputs the served path had."""
+        out = {}
+        with jax.default_matmul_precision("highest"):
+            for b in probe_rows:
+                h_attn, h_mlp = (jnp.asarray(probed[b][i]) for i in (0, 2))
+                logits = ref.mm(h_mlp, p_leaves["router"])
+                out[b] = (
+                    np.asarray(ref.attention(p_leaves, h_attn, config,
+                                             "sliding")),
+                    np.asarray(ref.router(p_leaves, h_mlp, config)[2]),
+                    np.asarray(bf16(logits) if low_precision else logits))
+        return out
+
+    def probe_readings(rows, ours, theirs, against=None):
+        """ours / theirs: {row: (attention output, experts chosen,
+        router logits)} of the probed layer. `layer_err`: the root of
+        the summed squares of the attention outputs' difference over
+        that of theirs, at the compared positions; `agree_same_input`:
+        the share of ALL positions whose expert sets are the same;
+        `router_logit_err`: the worst difference of a router logit.
+        against: a third run; `layer_nearer` is then the distance of
+        `against` from ours over its distance from theirs, and
+        `layer_nearer_if_served` the same ratio for an `against` that
+        computed ours with the rounding it has."""
+        diff = norm = same = count = 0.0
+        worst = to_ours = to_theirs = if_served = 0.0
+        for b in probe_rows:
+            at = [position for r, position in rows if r == b]
+            a, t = ours[b][0][at].astype(np.float64), theirs[b][0][at]
+            diff += float(np.sum(np.square(a - t)))
+            norm += float(np.sum(np.square(t)))
+            same += sum(set(x) == set(y)
+                        for x, y in zip(ours[b][1].tolist(),
+                                        theirs[b][1].tolist()))
+            count += len(ours[b][1])
+            worst = max(worst, float(np.abs(ours[b][2] - theirs[b][2]).max()))
+            if against is not None:
+                d = against[b][0][at].astype(np.float64) - t
+                to_ours += float(np.sum(np.square(d - (a - t))))
+                to_theirs += float(np.sum(np.square(d)))
+                if_served += float(np.sum(np.square(d + (a - t))))
+        out = {"layer_err": (diff / max(norm, 1e-300)) ** 0.5,
+               "agree_same_input": same / max(count, 1),
+               "router_logit_err": worst}
+        if against is not None:
+            out["layer_nearer"] = (to_ours / max(to_theirs, 1e-300)) ** 0.5
+            out["layer_nearer_if_served"] = (
+                to_theirs / max(if_served, 1e-300)) ** 0.5
+        return out
+
+    compiled()
+    want, want_routing, want_keys = reference(range(n_seq))
+    want_probe = probe_run()
+    # the served path's side of it: what the trunk handed out, the
+    # experts it chose there, and the logits of ITS router function
+    # (ops/moe.router_logits, what moe_mlp calls) on the same input
+    from cake_tpu.ops.moe import router_logits
+    served_probe = {
+        b: (probed[b][1], all_routed[b][p_sparse],
+            np.asarray(jax.jit(router_logits)(
+                jnp.asarray(probed[b][2], served_dtype),
+                jnp.asarray(host["blocks"]["router"][p_sparse]))))
+        for b in probe_rows}
+
+    def readings(rows, of, logits_at, experts_at, keys_at):
+        """The limits' readings over compared positions. rows: (b,
+        position) pairs; of: the reference run (logits, routing, keys)
+        the rows are read against; logits_at / experts_at (b, position)
+        -> logits [V] / [Ls, k]; keys_at (b, position, full layer) ->
+        the set that layer attended. Mean |error| / range in the dense
+        regime (positions under index_topk) and beyond it, with their
+        worst entries; the dense regime past the ring's turn alone; the
+        least, over the full layers, mean share of attended keys in
+        common; `agree`, the least over the sparse layers of the share
+        of positions whose expert sets are the same (under teacher
+        forcing: no cascade)."""
+        w_logits, w_routing, w_keys = of
+        acc = {k: {"sum": 0.0, "n": 0, "worst": 0.0}
+               for k in ("dense", "sparse", "wrapped", "shared_step")}
+        in_common = np.zeros(len(cfg.full_layers))
+        same = np.zeros(len(cfg.sparse_layers))
+        n_sparse = 0
+        for b, position in rows:
+            w = w_logits[b][position]
+            err = np.abs(logits_at(b, position) - w) / float(w.max() - w.min())
+            kinds = ["dense" if position < K else "sparse"]
+            if wrapped <= position < K:
+                kinds.append("wrapped")
+            if (b, position) in shared_step:
+                kinds.append("shared_step")
+            for kind in kinds:
+                a = acc[kind]
+                a["sum"] += float(err.sum())
+                a["n"] += err.size
+                a["worst"] = max(a["worst"], float(err.max()))
+            same += [set(experts_at(b, position)[j])
+                     == set(w_routing[b][j][position])
+                     for j in range(len(cfg.sparse_layers))]
+            if position >= K:
+                n_sparse += 1
+                for layer, f in full.items():
+                    theirs = set(np.flatnonzero(
+                        w_keys[b][layer][position]).tolist())
+                    ours = keys_at(b, position, f)
+                    in_common[f] += (len(ours & theirs)
+                                     / max(len(ours), len(theirs)))
+        mean = lambda a: a["sum"] / max(a["n"], 1)  # noqa: E731
+        return {
+            "mean_dense": mean(acc["dense"]),
+            "max_dense": acc["dense"]["worst"],
+            "mean": mean(acc["sparse"]), "max": acc["sparse"]["worst"],
+            "mean_wrapped": mean(acc["wrapped"]),
+            "mean_shared_step": mean(acc["shared_step"]),
+            "keys": float(in_common.min()) / max(n_sparse, 1),
+            "keys_by_layer": [round(float(x) / max(n_sparse, 1), 4)
+                              for x in in_common],
+            "agree": float(same.min()) / max(len(rows), 1),
+            "same_experts_by_layer": [round(float(x) / max(len(rows), 1), 4)
+                                      for x in same],
+            "positions": {k: a["n"] // max(int(cfg.vocab_size), 1)
+                          for k, a in acc.items()},
+        }
+
+    def failing(r):
+        """The limits a reading fails, by name."""
+        out = [k for k in ("mean_dense", "mean", "layer_err",
+                           "router_logit_err") if r[k] >= DOTS3_TOL[k]]
+        out += [k for k in ("keys", "agree", "agree_same_input")
+                if r[k] < DOTS3_TOL[k]]
+        # an altered reference only: the served path tells it from the
+        # plain one
+        return out + [k for k in ("nearer", "layer_nearer")
+                      if r.get(k, 0.0) >= DOTS3_TOL[k]]
+
+    rows = [(b, position) for b in range(n_seq)
+            for position in sorted(got[b])]
+    run = (want, want_routing, want_keys)
+    served = readings(rows, run, lambda b, p: got[b][p],
+                      lambda b, p: routed[b][p],
+                      lambda b, p, f: picked[b][p][f])
+    served.update(probe_readings(rows, served_probe, want_probe))
+    expected = sum(sum(1 for q in range(p + n_decode) if compared(b, q))
+                   for b, p in enumerate(prompts))
+    must_hold = {
+        "past_window": any(p >= W for _, p in rows),
+        "ring_wrapped_prefilled": any(wrapped <= p < prompts[b]
+                                      for b, p in rows),
+        "ring_wrapped_decoded": any(p >= max(wrapped, prompts[b])
+                                    for b, p in rows),
+        "past_index_topk": any(p >= K for _, p in rows),
+        "decode_row_beside_a_window": bool(shared_step),
+    }
+    result = {
+        "positions": len(rows), "expected_positions": expected,
+        "must_hold": must_hold, "served": served, "tol": DOTS3_TOL,
+        "seed": args.seed, "prompts": list(prompts), "steps": steps,
+        "attention": impl, "ring_pages": int(R),
+        "probe": {"layer": int(p_layer), "rows": probe_rows},
+        "device": jax.devices()[0].device_kind,
+    }
+    ok = (len(rows) == expected and not failing(served)
+          and all(must_hold.values()))
+
+    # -- what must NOT pass: the reference, altered, against itself ----
+    if args.negatives:
+        short = sorted(range(n_seq),
+                       key=lambda b: len(sequences[b]))[:args.negatives]
+        at = {b: i for i, b in enumerate(short)}
+        neg_rows = [(at[b], position) for b, position in rows if b in short]
+        plain_run = ([want[b] for b in short],
+                     [want_routing[b] for b in short],
+                     [want_keys[b] for b in short])
+
+        def against_reference(altered_run, altered_probe):
+            logits, routing, keys = altered_run
+            r = readings(
+                neg_rows, plain_run, lambda i, p: logits[i][p],
+                lambda i, p: [r[p] for r in routing[i]],
+                lambda i, p, f: set(np.flatnonzero(
+                    keys[i][cfg.full_layers[f]][p]).tolist()))
+            # the served path's distance from this reference over its
+            # distance from the plain one (root of the summed squares)
+            # and the same ratio had the served path computed THIS
+            # reference with the rounding it has: altered + (served -
+            # plain) against the two
+            to_altered = to_plain = if_served = 0.0
+            for i, p in neg_rows:
+                plain_p = want[short[i]][p]
+                d = got[short[i]][p].astype(np.float64) - plain_p
+                e = logits[i][p].astype(np.float64) - plain_p
+                to_altered += float(np.sum(np.square(d - e)))
+                to_plain += float(np.sum(np.square(d)))
+                if_served += float(np.sum(np.square(d + e)))
+            r["nearer"] = (to_altered / max(to_plain, 1e-300)) ** 0.5
+            r["nearer_if_served"] = (to_plain / max(if_served, 1e-300)) ** 0.5
+            # the probed layer: the altered layer as if it were served,
+            # and how far the served layer lies from it
+            r.update(probe_readings(rows, altered_probe, want_probe,
+                                    against=served_probe))
+            return r
+
+        altered = {
+            "dense_attention_reference": dict(ref_cfg, dense_attention=True),
+            "window_plus_one_reference": dict(ref_cfg,
+                                              sliding_window_size=W + 1),
+            "window_minus_one_reference": dict(ref_cfg,
+                                               sliding_window_size=W - 1),
+            "no_gate_reference": dict(ref_cfg, gate=False),
+            "no_rescale_reference": dict(ref_cfg, rescale=False),
+        }
+        for name, config in altered.items():
+            result[name] = against_reference(reference(short, config),
+                                             probe_run(config))
+        compiled(low_precision=True)
+        result["bf16_scores_reference"] = against_reference(
+            reference(short), probe_run(low_precision=True))
+        compiled()
+        result["fails"] = {}
+        for name in (*altered, "bf16_scores_reference"):
+            result["fails"][name] = failing(result[name])
+            if not result["fails"][name]:
+                say(f"FAILED: the {name} passes the tolerance")
+                ok = False
+    for name, fn in plain.items():
+        setattr(ref, name, fn)
+    result["ok"] = bool(ok)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"dots3_result_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)
