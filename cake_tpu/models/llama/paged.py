@@ -571,7 +571,8 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
         # slot's live data into the masked lanes). Whether the OOB row
         # read is actually elided is up to the XLA gather lowering —
         # the guarantee that dead pages cost NO bandwidth lives in the
-        # pallas kernel's index-map clamp, not here; the fold's masking
+        # pallas kernels (the decode kernel starts no copy for them,
+        # the mixed kernel's index map clamps), not here; the fold's masking
         # (below) keeps the fill value out of the output either way.
         idx = jnp.where(pages >= 0, pages, N)
         # a quantized pool dequantizes in the loop: int page * its
@@ -616,9 +617,10 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     over all max_pages — online-softmax accumulation where every page is
     read once and folded into running (m, l, o) stats; no dense per-slot
     copy ever exists. impl="pallas": the TPU-native single kernel
-    (ops/ragged_paged_attention.py) — same math, but each row streams
-    only its LIVE pages through VMEM and exits at ceil((pos+1)/page)
-    instead of folding the whole pool. The caller picks the impl the
+    (ops/ragged_paged_attention.py) — same math, but one grid step a
+    row whose loop runs over the row's LIVE pages alone, pos // page + 1
+    of them fetched by the kernel's own copies (an idle row takes no
+    trip), instead of folding the whole table. The caller picks the impl the
     shapes allow (serve/engine._setup_paged_exec resolves it once); on
     a chip the kernel raises on a shape its gate refuses — nothing here
     falls back.

@@ -204,6 +204,17 @@ _MIXED_ATTN_TILES_WINDOW = _m.counter(
     "Query tiles in the windows of the mixed steps' active rows: what a "
     "kernel that folded every row's whole window would fold (over it, "
     "cake_mixed_attn_q_tiles_total is the share the kernel does)")
+_DECODE_ATTN_PAGES = _m.counter(
+    "cake_decode_attn_pages_total",
+    "KV pages the decode attention kernel streams a layer, summed over "
+    "the decode steps' active rows: position // page + 1 for each, "
+    "counted on the host from the positions it dispatches")
+_DECODE_ATTN_PAGES_TABLE = _m.counter(
+    "cake_decode_attn_pages_table_total",
+    "Entries of the decode steps' page tables, slots x pages a slot: "
+    "what a kernel that stepped through the whole table would visit "
+    "(over it, cake_decode_attn_pages_total is the share that holds "
+    "work)")
 # sparse-expert counters, computed in the step program from the group
 # sizes its grouped matmuls walk (ops/moe.MoEStats, summed or averaged
 # over the layers by paged.scan_layers_paged_stats) and fetched with the
@@ -579,6 +590,11 @@ class StepRecord:
     # tiles of their whole windows
     attn_q_tiles: Optional[int] = None
     attn_q_tiles_window: Optional[int] = None
+    # a decode step whose rows go through the decode attention kernel:
+    # the pages it streams a layer for the step's active rows, and the
+    # entries of the whole page table
+    attn_pages: Optional[int] = None
+    attn_pages_table: Optional[int] = None
     # rids whose rows this step's dispatched batch contained (bounded
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
@@ -646,6 +662,9 @@ class StepRecord:
         if self.attn_q_tiles is not None:
             out["attn_q_tiles"] = self.attn_q_tiles
             out["attn_q_tiles_window"] = self.attn_q_tiles_window
+        if self.attn_pages is not None:
+            out["attn_pages"] = self.attn_pages
+            out["attn_pages_table"] = self.attn_pages_table
         if self.rids is not None:
             out["rids"] = list(self.rids)
         if self.phases:
@@ -973,6 +992,8 @@ class StepTelemetry:
                tokens_computed: Optional[int] = None,
                attn_q_tiles: Optional[int] = None,
                attn_q_tiles_window: Optional[int] = None,
+               attn_pages: Optional[int] = None,
+               attn_pages_table: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None,
@@ -988,7 +1009,10 @@ class StepTelemetry:
         cake_mixed_tokens_computed_total); attn_q_tiles /
         attn_q_tiles_window the query tiles its attention kernel folds
         and those of its rows' whole windows
-        (cake_mixed_attn_q_tiles_total, ..._window_total). rids: the
+        (cake_mixed_attn_q_tiles_total, ..._window_total); attn_pages /
+        attn_pages_table the pages a decode step's attention kernel
+        streams and the entries of its page table
+        (cake_decode_attn_pages_total, ..._table_total). rids: the
         requests whose rows rode this dispatch (the per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
@@ -1041,6 +1065,7 @@ class StepTelemetry:
                 tokens_real=tokens_real, tokens_computed=tokens_computed,
                 attn_q_tiles=attn_q_tiles,
                 attn_q_tiles_window=attn_q_tiles_window,
+                attn_pages=attn_pages, attn_pages_table=attn_pages_table,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
@@ -1069,6 +1094,9 @@ class StepTelemetry:
         if attn_q_tiles is not None:
             _MIXED_ATTN_TILES.inc(attn_q_tiles)
             _MIXED_ATTN_TILES_WINDOW.inc(attn_q_tiles_window)
+        if attn_pages is not None:
+            _DECODE_ATTN_PAGES.inc(attn_pages)
+            _DECODE_ATTN_PAGES_TABLE.inc(attn_pages_table)
         if moe is not None:
             for (_key, series), v in zip(counter_layout(len(rec.moe)),
                                          rec.moe):

@@ -8,18 +8,30 @@ and page reads never stay resident in VMEM. This kernel is the
 TPU-native formulation of the same online-softmax fold (the "Ragged
 Paged Attention" shape, PAPERS.md arxiv 2604.15464):
 
-  * grid (rows, pages) with the page axis innermost and sequential —
-    each grid step streams ONE page of the pool through VMEM and folds
-    it into f32 (m, l, acc) scratch carried across the page axis, the
-    flash-attention recurrence of `ops/flash_attention.py`;
+  * the DECODE kernel (`ragged_paged_attention`) runs one grid step a
+    ROW and walks the row's live pages itself: a loop of
+    `pos // page + 1` trips (a dynamic count) fetches page
+    `table[row, j]` of the layer out of the pool, which stays whole in
+    HBM, with the kernel's own copies into a ring of VMEM slots, and
+    folds it into the row's f32 (m, l, acc), the flash-attention
+    recurrence of `ops/flash_attention.py`, carried in registers. A
+    call costs what its live pages cost: a row that holds 3 pages of
+    a 16-page table takes 3 trips, an idle row none (the (rows, pages)
+    grid this replaced paid ~0.3 us for each of its cells, live or not:
+    PERF.md section 6, PR 42);
   * the page table and per-row positions ride as scalar-prefetched SMEM
-    operands, so the k/v BlockSpec index maps resolve `table[row, j]`
-    BEFORE the DMA is issued — the pool is indexed directly by physical
-    page id, no host-side gather and no dense per-row copy;
-  * per-row early exit: pages past the row's live count
-    `ceil((pos+1)/page)` clamp their index map to the last live page, so
-    Pallas elides the repeated DMA, and `pl.when` skips the compute —
-    a short row costs its own pages, not `max_pages`;
+    operands: the pool is indexed directly by physical page id, no
+    host-side gather and no dense per-row copy;
+  * the ring: K and V of the pages AHEAD are in flight while page j
+    folds, `decode_ring_depth` slots from the page's bytes (a static
+    shape), and the pages ahead are the NEXT rows' when this row's run
+    out, so only a call's first row starts cold. An unmapped hole
+    inside the live range starts no copy and folds nothing;
+  * the MIXED kernel keeps the older form: grid (rows, pages), page
+    axis innermost and sequential, one page a grid step through a k/v
+    BlockSpec whose index map resolves `table[row, j]`; pages past the
+    row's live count clamp their index to the last live page, so
+    Pallas elides the repeated DMA, and `pl.when` skips the compute;
   * causal + unmapped-page masking inside a live page (absolute slot
     `j*page + t` attends iff `<= pos` and the page id is mapped);
   * GQA without repeat_kv: the KV-head axis is unrolled statically
@@ -36,9 +48,10 @@ Paged Attention" shape, PAPERS.md arxiv 2604.15464):
 Layout contract: the kernels take the STACKED pool as it is stored,
 [L, N_pages, page, KV*hd] (`models/llama/paged.py`; a packed int4 pool
 [L, N_pages, page//2, KV*hd]), and the layer as one more scalar-prefetch
-operand. The k/v block is (layer, page) -> one (page, KV*hd) tile,
-lane-aligned when hd is a multiple of 128, DMA'd straight out of the
-pool: the wrappers neither slice a layer out nor reshape anything. (A
+operand. What a copy (decode) or a k/v block (mixed) moves is (layer,
+page) -> one (page, KV*hd) tile, lane-aligned when hd is a multiple of
+128, DMA'd straight out of the pool: the wrappers neither slice a
+layer out nor reshape anything. (A
 reshape from a per-head [.., KV, hd] pool to this shape is a relayout
 of the whole pool on the chip — four times the kernel's own time,
 PERF.md PR 24 — which is why the pool is STORED this way.)
@@ -83,148 +96,6 @@ def _dot(a, b, *, trans_b: bool):
         preferred_element_type=jnp.float32)
 
 
-def _rpa_kernel(layer_ref, pos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                acc_ref, m_ref, l_ref, *, scale: float, page_size: int,
-                kv_heads: int, group: int, head_dim: int):
-    """One (row, page) grid step of the ragged fold.
-
-    layer_ref: [1] — read by the k/v index maps only (every kernel here
-             takes it first and its body never touches it)
-    q_ref:   [1, 1, H, hd] — the row's single decode query, all heads
-    k_ref/v_ref: [1, page, KV*hd] — one physical page of the layer (the
-             block's layer axis is squeezed)
-    scratch: acc [H, hd] f32, m/l [H, 128] f32, carried across the page
-    axis (innermost, sequential) exactly like flash_attention's k axis.
-    """
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    pos = pos_ref[b]
-    page = table_ref[b, j]
-    # page j is live iff it covers a position <= pos AND is mapped; dead
-    # pages cost neither compute (gated here) nor bandwidth (their index
-    # map repeats the last live page, so the DMA is elided)
-    live = jnp.logical_and(j * page_size <= pos, page >= 0)
-
-    @pl.when(live)
-    def _fold():
-        q = q_ref[0, 0]                        # [H, hd]
-        P = page_size
-        hd = head_dim
-        # causal mask over the page's absolute slots (current token
-        # included); every gated-in page has >= 1 valid column, so the
-        # online max below never sees a fully-masked row
-        col_valid = (j * P + jax.lax.broadcasted_iota(
-            jnp.int32, (1, P), 1)) <= pos      # [1, P]
-        # scores per kv head: query group g of kv head k against the
-        # page's k-lane slice (static unroll — KV is small)
-        parts = []
-        for kv in range(kv_heads):
-            kh = k_ref[0, :, kv * hd:(kv + 1) * hd]    # [P, hd]
-            qh = q[kv * group:(kv + 1) * group]        # [G, hd]
-            parts.append(_dot(qh, kh, trans_b=True))
-        s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
-        s = jnp.where(col_valid, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]                  # [H, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                 # [H, P]
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        outs = []
-        for kv in range(kv_heads):
-            vh = v_ref[0, :, kv * hd:(kv + 1) * hd]    # [P, hd]
-            ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            outs.append(_dot(ph.astype(vh.dtype), vh, trans_b=False))
-        acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        # a row whose every page was dead (inactive slot / all-unmapped
-        # table) has l == 0: emit zeros, matching the fold reference's
-        # merge_attention_stats guard
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _rpa_kernel_q8(layer_ref, pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
-                   v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                   page_size: int, kv_heads: int, group: int,
-                   head_dim: int):
-    """int8 variant of _rpa_kernel: the page blocks stream as int8 (a
-    quarter of the f32 DMA bytes — the whole point of KV tiering) and
-    the per-(page, kv-head) scales ride as scalar-prefetched SMEM
-    operands (1-D, index page*KV + kv — _layer_scales). Because one
-    scale covers a page's every column for a given kv head,
-    dequantization folds into the dot OUTPUTS: the score block scales
-    by scale_k[page, kv] and the value fold by scale_v[page, kv] — no
-    dequantized page copy ever exists."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    pos = pos_ref[b]
-    page = table_ref[b, j]
-    live = jnp.logical_and(j * page_size <= pos, page >= 0)
-
-    @pl.when(live)
-    def _fold():
-        q = q_ref[0, 0]                        # [H, hd]
-        P = page_size
-        hd = head_dim
-        pid = jnp.maximum(page, 0)
-        col_valid = (j * P + jax.lax.broadcasted_iota(
-            jnp.int32, (1, P), 1)) <= pos      # [1, P]
-        parts = []
-        for kv in range(kv_heads):
-            kh = k_ref[0, :, kv * hd:(kv + 1) * hd].astype(
-                jnp.float32)                           # [P, hd]
-            qh = q[kv * group:(kv + 1) * group].astype(jnp.float32)
-            s_kv = _dot(qh, kh, trans_b=True)
-            parts.append(s_kv * sk_ref[pid * kv_heads + kv])
-        s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
-        s = jnp.where(col_valid, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                 # [H, P]
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        outs = []
-        for kv in range(kv_heads):
-            vh = v_ref[0, :, kv * hd:(kv + 1) * hd].astype(jnp.float32)
-            ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            o_kv = _dot(ph, vh, trans_b=False)
-            outs.append(o_kv * sv_ref[pid * kv_heads + kv])
-        acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
 def _unpack_nibbles(block, hd_slice):
     """In-register nibble unpack of one packed int4 page block column
     slice: block [P//2, hd] uint8 -> [P, hd] f32 in [-8, 7]. The pool's
@@ -237,70 +108,183 @@ def _unpack_nibbles(block, hd_slice):
                            axis=0).astype(jnp.float32)
 
 
-def _rpa_kernel_q4(layer_ref, pos_ref, table_ref, sk_ref, sv_ref, q_ref, k_ref,
-                   v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                   page_size: int, kv_heads: int, group: int,
-                   head_dim: int):
-    """int4 variant of _rpa_kernel_q8: the page blocks stream as
-    nibble-PACKED uint8 — an EIGHTH of the f32 DMA bytes — and unpack
-    in registers per kv head before the dots. Scales prefetch into
-    SMEM and fold into the dot outputs exactly like the int8 kernel;
-    page_size here is REAL tokens (the packed block holds page_size//2
-    sublanes)."""
+def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
+                 page_size: int, kv_heads: int, group: int, head_dim: int,
+                 packed4: bool):
+    """Fold one live page of a decode row into its online-softmax
+    stats: the one body of the float, int8 and int4 pools.
+
+    q:       [H, hd], the row's single query, all heads (f32 over a
+             quantized pool)
+    k_page/v_page: [page, KV*hd] views of the ring slot that holds
+             logical page j of the row (nibble-PACKED int4:
+             [page//2, KV*hd], unpacked in registers a kv head)
+    scales:  None for a float pool, else `scales(kv) -> (k, v)`, the
+             page's per-kv-head dequantization scales out of SMEM. One
+             scale covers a page's every column of a kv head, so it
+             folds into the dot OUTPUTS: no dequantized page exists.
+    stats:   (m [H, 1], l [H, 1], acc [H, hd]) f32, the flash-attention
+             recurrence of `ops/flash_attention.py`; returned updated.
+    """
+    P = page_size
+    hd = head_dim
+    G = group
+    m_prev, l_prev, acc = stats
+
+    def head(page, kv):
+        lanes = slice(kv * hd, (kv + 1) * hd)
+        if packed4:
+            return _unpack_nibbles(page[...], lanes)       # [P, hd] f32
+        h = page[:, lanes]
+        return h if scales is None else h.astype(jnp.float32)
+
+    # causal mask over the page's absolute slots (current token
+    # included); every folded page has >= 1 valid column, so the online
+    # max below never sees a fully-masked row
+    col_valid = (j * P + jax.lax.broadcasted_iota(
+        jnp.int32, (1, P), 1)) <= pos              # [1, P]
+    # scores a kv head: query group g of kv head k against the page's
+    # k-lane slice (static unroll: KV is small)
+    parts = []
+    for kv in range(kv_heads):
+        s_kv = _dot(q[kv * G:(kv + 1) * G], head(k_page, kv), trans_b=True)
+        parts.append(s_kv if scales is None else s_kv * scales(kv)[0])
+    s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
+    s = jnp.where(col_valid, s, NEG_INF)
+
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                         # [H, P]
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    outs = []
+    for kv in range(kv_heads):
+        vh = head(v_page, kv)                      # [P, hd]
+        o_kv = _dot(p[kv * G:(kv + 1) * G].astype(vh.dtype), vh,
+                    trans_b=False)
+        outs.append(o_kv if scales is None else o_kv * scales(kv)[1])
+    return m_new, l_new, acc * alpha + jnp.concatenate(outs, axis=0)
+
+
+def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
+                   depth: int, page_size: int, kv_heads: int,
+                   **fold_shape):
+    """One grid step: one ROW of the ragged decode fold. The kernel
+    walks the row's live pages itself, 0 .. pos // page, and fetches
+    page table[row, j] of the layer out of the pool (whole, in HBM)
+    with its own copies into a ring of `depth` VMEM slots, K and V of
+    the pages ahead in flight while page j folds.
+
+    sk_ref/sv_ref (a quantized pool only): the layer's flat scales
+    q_ref/o_ref:   [1, 1, H, hd], the row's query and result
+    k_hbm/v_hbm:   [L, N_pages, page, KV*hd], never read but by a copy
+    kbuf/vbuf:     [depth, page, KV*hd] VMEM; sem: DMA [2, depth]
+    cur:           SMEM int32 [4]: the copies' cursor (row, page, count
+                   of pages started) and the count of pages folded,
+                   carried from row to row: the pages ahead are the
+                   NEXT rows' when this row's run out, so the ring is
+                   warm at every row but the call's first.
+    """
+    if quantized:
+        sk_ref, sv_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur = refs
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+    nb = pl.num_programs(0)
+    layer = layer_ref[0]
+    max_pages = table_ref.shape[1]
+    H, hd = q_ref.shape[2:]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def live_pages(row):
+        return jnp.clip(pos_ref[row] // page_size + 1, 0, max_pages)
 
+    def copies(pid, slot):
+        return [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot],
+                                      sem.at[i, slot])
+                for i, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf)))]
+
+    def next_live_row(row):
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < nb, live_pages(jnp.minimum(r, nb - 1)) == 0),
+            lambda r: r + 1, row)
+
+    def start_next():
+        row, j, count = cur[0], cur[1], cur[2]
+
+        @pl.when(row < nb)
+        def _():
+            # an unmapped hole inside the live range starts no copy
+            # (and folds nothing, below): its slot stays idle
+            pid = table_ref[row, j]
+
+            @pl.when(pid >= 0)
+            def _():
+                for c in copies(pid, count % depth):
+                    c.start()
+
+            cur[2] = count + 1
+            row_ends = j + 1 == live_pages(row)
+
+            @pl.when(row_ends)
+            def _():
+                cur[0] = next_live_row(row + 1)
+                cur[1] = 0
+
+            @pl.when(jnp.logical_not(row_ends))
+            def _():
+                cur[1] = j + 1
+
+    @pl.when(b == 0)
+    def _():
+        cur[0] = next_live_row(0)
+        cur[1] = 0
+        cur[2] = 0
+        cur[3] = 0
+
+        def prime(_, carry):
+            start_next()
+            return carry
+
+        jax.lax.fori_loop(0, depth - 1, prime, 0)
+
+    n = live_pages(b)
+    first = cur[3]
+    cur[3] = first + n
     pos = pos_ref[b]
-    page = table_ref[b, j]
-    live = jnp.logical_and(j * page_size <= pos, page >= 0)
+    q = q_ref[0, 0]                                # [H, hd]
+    if quantized:
+        q = q.astype(jnp.float32)
 
-    @pl.when(live)
-    def _fold():
-        q = q_ref[0, 0]                        # [H, hd]
-        P = page_size
-        hd = head_dim
-        pid = jnp.maximum(page, 0)
-        col_valid = (j * P + jax.lax.broadcasted_iota(
-            jnp.int32, (1, P), 1)) <= pos      # [1, P]
-        parts = []
-        for kv in range(kv_heads):
-            kh = _unpack_nibbles(k_ref[0],
-                                 slice(kv * hd, (kv + 1) * hd))  # [P, hd]
-            qh = q[kv * group:(kv + 1) * group].astype(jnp.float32)
-            s_kv = _dot(qh, kh, trans_b=True)
-            parts.append(s_kv * sk_ref[pid * kv_heads + kv])
-        s = jnp.concatenate(parts, axis=0) * scale     # [H, P]
-        s = jnp.where(col_valid, s, NEG_INF)
+    def fold(j, pid, stats):
+        slot = (first + j) % depth
+        for c in copies(pid, slot):
+            c.wait()
+        scales = None
+        if quantized:
+            def scales(kv):
+                at = pid * kv_heads + kv
+                return sk_ref[at], sv_ref[at]
+        return _decode_fold(q, kbuf.at[slot], vbuf.at[slot], scales, j, pos,
+                            stats, page_size=page_size, kv_heads=kv_heads,
+                            **fold_shape)
 
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                 # [H, P]
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        outs = []
-        for kv in range(kv_heads):
-            vh = _unpack_nibbles(v_ref[0],
-                                 slice(kv * hd, (kv + 1) * hd))  # [P, hd]
-            ph = p[kv * group:(kv + 1) * group]        # [G, P]
-            o_kv = _dot(ph, vh, trans_b=False)
-            outs.append(o_kv * sv_ref[pid * kv_heads + kv])
-        acc_ref[:] = acc_ref[:] * alpha + jnp.concatenate(outs, axis=0)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    def page(j, stats):
+        # the slot this frees held page j - 1, folded a step ago
+        start_next()
+        pid = table_ref[b, j]
+        return jax.lax.cond(pid >= 0, lambda s: fold(j, pid, s),
+                            lambda s: s, stats)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, n, page, (jnp.full((H, 1), NEG_INF, jnp.float32),
+                     jnp.zeros((H, 1), jnp.float32),
+                     jnp.zeros((H, hd), jnp.float32)))
+    # a row that folded no page (an idle slot, an all-unmapped table)
+    # has l == 0 and started no copy: emit zeros, matching the fold
+    # reference's merge_attention_stats guard
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
 
 
 def _layer_scales(scale, layer):
@@ -365,7 +349,8 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
         interpret = not _on_tpu()
     if not interpret and not ragged_paged_supported(
             P, H, KV, hd, quantized=quantized, n_pages=N,
-            packed4=packed4, slots=B, max_pages=max_pages):
+            packed4=packed4, slots=B, max_pages=max_pages,
+            kv_itemsize=pool_k.dtype.itemsize):
         raise ValueError(
             f"ragged paged attention cannot run on this chip at page="
             f"{P} H={H} KV={KV} hd={hd} pool={pool_k.dtype} pages={N} "
@@ -373,49 +358,27 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
             "fold")
 
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def kv_index(b, j, layer_ref, pos_ref, table_ref, *_scales):
-        # clamp dead pages (past the row's live count) to the LAST live
-        # page: the repeated block index elides the DMA, so a short row
-        # streams only its own pages. Unmapped holes inside the live
-        # range clamp to page 0 — one page of wasted bandwidth, masked
-        # out in compute.
-        jj = jnp.minimum(j, pos_ref[b] // P)
-        page = table_ref[b, jj]
-        return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
-
+    operands = [layer, jnp.asarray(pos, jnp.int32),
+                jnp.asarray(table, jnp.int32)]
     if quantized:
-        kern_fn = _rpa_kernel_q4 if packed4 else _rpa_kernel_q8
-        kernel = functools.partial(
-            kern_fn, scale=scale, page_size=P, kv_heads=KV,
-            group=G, head_dim=hd)
-        n_prefetch = 5
-        operands = (layer, jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(table, jnp.int32),
-                    _layer_scales(scale_k, layer[0]),
-                    _layer_scales(scale_v, layer[0]),
-                    q, pool_k, pool_v)
-    else:
-        kernel = functools.partial(
-            _rpa_kernel, scale=scale, page_size=P, kv_heads=KV, group=G,
-            head_dim=hd)
-        n_prefetch = 3
-        operands = (layer, jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(table, jnp.int32), q, pool_k, pool_v)
+        operands += [_layer_scales(scale_k, layer[0]),
+                     _layer_scales(scale_v, layer[0])]
+    depth = decode_ring_depth(Pb * width * pool_k.dtype.itemsize)
+    kernel = functools.partial(
+        _decode_kernel, quantized=quantized, depth=depth, scale=scale,
+        page_size=P, kv_heads=KV, group=G, head_dim=hd, packed4=packed4)
+    row = pl.BlockSpec((1, 1, H, hd), lambda b, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
-            _pool_block(Pb, width, kv_index),
-            _pool_block(Pb, width, kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, H, hd),
-                               lambda b, j, *_: (b, 0, 0, 0)),
+        num_scalar_prefetch=len(operands),
+        grid=(B,),
+        in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((depth, Pb, width), pool_k.dtype),
+            pltpu.VMEM((depth, Pb, width), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
     return pl.pallas_call(
@@ -423,13 +386,12 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
         name="cake_decode_attn",
-        # only the page axis carries scratch state; rows schedule freely
-        # across megacore
+        # the ring's copies run ahead into the next row
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(*operands)
+    )(*operands, q, pool_k, pool_v)
 
 
 # Queries to a TILE of the mixed kernel's window. A (row, page) grid
@@ -760,6 +722,36 @@ _VMEM_SCOPED_LIMIT = 16 * 2**20
 _SMEM_BYTES = 2**20
 
 
+# The decode kernel's ring: what it keeps in flight AHEAD of the page
+# it folds. A page copy lands in well under a microsecond on a v5e, so
+# a MiB ahead (K and V together) covers it at 819 GB/s: 8 pairs of
+# 64 KiB pages (2 KV heads), 2 of 256 KiB (8), 1 of 512 KiB (16). Read
+# on the chip at the cells' shapes, calls of 16 and 32 rows (PERF.md
+# section 6, PR 42): ONE pair ahead cost 125 us where two or more cost
+# 100 at 64 KiB pages (the fold bounds that row from there on: sixteen
+# ahead read the same), 107 against 98 at 256 KiB, and nothing at
+# 512 KiB (144 with one ahead, 145 with two to eight).
+_RING_BYTES_AHEAD = 2**20
+_RING_PAGES_AHEAD_MAX = 16
+
+
+def decode_ring_depth(page_bytes: int) -> int:
+    """Slots of the decode kernel's ring for K (or V) pages of
+    `page_bytes`: the one that folds, and _RING_BYTES_AHEAD of K and V
+    in flight behind it (a page at least, _RING_PAGES_AHEAD_MAX at
+    most: a page of a few KiB is a test's)."""
+    ahead = -(-_RING_BYTES_AHEAD // (2 * page_bytes))
+    return 1 + min(max(ahead, 1), _RING_PAGES_AHEAD_MAX)
+
+
+def decode_vmem_bytes(page_bytes: int, H: int, hd: int) -> int:
+    """Scoped VMEM the decode kernel asks for: the K and the V ring and
+    the double-buffered q and out blocks ([H, hd], counted at four
+    bytes). The row's f32 stats live in registers."""
+    return (2 * decode_ring_depth(page_bytes) * page_bytes
+            + 2 * 2 * H * hd * 4)
+
+
 def _smem_need(slots: int, max_pages: int, scale_words: int) -> int:
     """Bytes the scalar-prefetch operands take: the [slots, max_pages]
     int32 table pads its minor dim to 128 words; pos/q_len and the flat
@@ -768,15 +760,16 @@ def _smem_need(slots: int, max_pages: int, scale_words: int) -> int:
     return table + 2 * slots * 4 + 2 * scale_words * 4
 
 
-def ragged_paged_supported(page_size: int, H: int, KV: int,
-                           hd: int, quantized: bool = False,
-                           n_pages: Optional[int] = None,
-                           packed4: bool = False,
-                           slots: Optional[int] = None,
-                           max_pages: Optional[int] = None) -> bool:
-    """Static shape gate for the hardware path: the shape classes whose
+def _pool_supported(page_size: int, H: int, KV: int,
+                    hd: int, quantized: bool = False,
+                    n_pages: Optional[int] = None,
+                    packed4: bool = False,
+                    slots: Optional[int] = None,
+                    max_pages: Optional[int] = None,
+                    kv_itemsize: int = 2) -> bool:
+    """What both kernels' gates ask of a pool: the shape classes whose
     numbers were checked against the fold ON A CHIP (v5e, PR 21), plus
-    the SMEM bound.
+    the SMEM bound and the decode kernel's ring in VMEM.
 
     Float pools: hd a multiple of 16 and pages of 8 tokens or more —
     hd 16/64/128 with 8/16/64/128-token bf16 pages all compiled under
@@ -790,7 +783,10 @@ def ragged_paged_supported(page_size: int, H: int, KV: int,
 
     The SMEM rule bounds the scalar-prefetch operands (the page table,
     and a quantized pool's whole-pool scales) against the measured
-    1 MiB; pass n_pages / slots / max_pages to enforce it."""
+    1 MiB; pass n_pages / slots / max_pages to enforce it. The VMEM
+    rule holds the decode kernel's ring of pages (decode_ring_depth
+    slots of K and of V; kv_itemsize is a float pool's) under the
+    compiler's scoped limit."""
     if H % KV != 0:
         return False
     if packed4 and page_size % 2:
@@ -803,8 +799,30 @@ def ragged_paged_supported(page_size: int, H: int, KV: int,
     else:
         tiles = hd % 16 == 0 and page_size % 8 == 0
     scale_words = n_pages * KV if quantized and n_pages else 0
-    return tiles and _smem_need(slots or 0, max_pages or 0,
-                                scale_words) <= _SMEM_BYTES
+    page_bytes = ((page_size // 2 if packed4 else page_size) * KV * hd
+                  * (1 if quantized else kv_itemsize))
+    return (tiles
+            and _smem_need(slots or 0, max_pages or 0,
+                           scale_words) <= _SMEM_BYTES
+            and decode_vmem_bytes(page_bytes, H, hd) <= _VMEM_SCOPED_LIMIT)
+
+
+def ragged_paged_supported(page_size: int, H: int, KV: int, hd: int,
+                           **pool) -> bool:
+    """Static shape gate for the DECODE kernel on the hardware path:
+    the pool's rules (_pool_supported, same arguments) and, on a chip,
+    a page row that fills whole lane tiles. The kernel's own copies
+    slice a (page, KV*hd) tile out of the pool in HBM, and Mosaic
+    admits such a slice only where KV*hd is a multiple of 128
+    ("Slice shape along dimension 3 must be aligned to tiling (128)":
+    compiler, PR 42, at 32, 64 and 192 lanes, float32 and bfloat16,
+    pages of 8 to 128 tokens). Every served model's pool is (hd 128;
+    hd 64 from 2 KV heads up); a test's hd 16 x 2, MQA at hd 64 and
+    2 KV heads of 96 decode through the fold on a chip, where the
+    (rows, pages) BlockSpec pipeline this kernel replaced compiled
+    them. The mixed kernel still takes them."""
+    return (_pool_supported(page_size, H, KV, hd, **pool)
+            and (not _on_tpu() or (KV * hd) % 128 == 0))
 
 
 def mixed_scratch_bytes(H: int, hd: int, q_width: int) -> int:
@@ -838,8 +856,8 @@ def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
                                  max_pages: Optional[int] = None,
                                  q_itemsize: int = 2,
                                  kv_itemsize: int = 2) -> bool:
-    """Gate for the MIXED hardware kernel: the decode gate's rules PLUS
-    a power-of-two GQA group and the VMEM bound. The kernel folds each
+    """Gate for the MIXED hardware kernel: the pool's rules
+    (_pool_supported) PLUS a power-of-two GQA group and the VMEM bound. The kernel folds each
     kv head's [C, G, hd] queries to [C*G, hd]; Mosaic does that shape
     cast for G in 1, 2, 4, 8 and refuses it for G=7 ("unsupported shape
     cast", v5e, PR 21). And unlike the C=1 decode kernel, its scratch
@@ -847,10 +865,11 @@ def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
     refuses the kernel outright past its scoped limit (at H=32,
     hd=128: C=128 needs 11 MiB and compiles, C=256 needs 21 MiB and
     does not)."""
-    if not ragged_paged_supported(page_size, H, KV, hd,
-                                  quantized=quantized, n_pages=n_pages,
-                                  packed4=packed4, slots=slots,
-                                  max_pages=max_pages):
+    if not _pool_supported(page_size, H, KV, hd,
+                           quantized=quantized, n_pages=n_pages,
+                           packed4=packed4, slots=slots,
+                           max_pages=max_pages,
+                           kv_itemsize=kv_itemsize):
         return False
     if not _on_tpu():
         return True      # interpret mode allocates host memory

@@ -4824,6 +4824,26 @@ class InferenceEngine:
                                     for slot in rows),
                 "attn_q_tiles_window": len(rows) * mixed_q_tiles(C, C)}
 
+    def _attn_pages(self, steps: List[tuple]) -> dict:
+        """A decode record's attn_pages / attn_pages_table (obs/steps):
+        the KV pages the decode attention kernel streams a layer for
+        the active rows, counted from the positions dispatched as the
+        kernel counts its trips (position // page + 1), and the entries
+        of the page table, which is what a grid over (slot, page)
+        stepped through. steps: (position of its first token, tokens)
+        for each active row; a scan's record sums its steps. Nothing
+        where the decode rows do not go through that kernel (a dense
+        cache, latent attention)."""
+        if not self.paged or self._latent or not steps:
+            return {}
+        P = self.cache.page_size
+        last = self.max_seq_len - 1
+        return {"attn_pages": sum(min(pos + i, last) // P + 1
+                                  for pos, n in steps for i in range(n)),
+                "attn_pages_table": (max(n for _pos, n in steps)
+                                     * self.max_slots
+                                     * self.cache.max_pages)}
+
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
         order, as many as the largest packed size holds. One group
@@ -5855,6 +5875,7 @@ class InferenceEngine:
         rows = [s for _, s in decode_plan]
         n_top = self._n_top_for(rows)
         self._publish({"op": "decode", "rows": rows, "n_top": n_top})
+        pages = self._attn_pages([(int(self._pos[s]), 1) for s in rows])
         nxt, lp, tids, tlps = self._decode_device(rows, n_top=n_top)
         self.stats.steps += 1
         dt = time.perf_counter() - t0
@@ -5865,7 +5886,7 @@ class InferenceEngine:
                           dispatch_s=self.flight.open_phase("dispatch"),
                           device_s=self.flight.open_phase("fetch"),
                           rids=[r for r, _s in decode_plan],
-                          moe=self._take_moe(), chained=False)
+                          moe=self._take_moe(), chained=False, **pages)
         with self.flight.span("emit"):
             for rid, slot in decode_plan:
                 req = self._slot_req[slot]
@@ -6066,6 +6087,11 @@ class InferenceEngine:
                 # dispatch (same thread), and an explicit recompute
                 # keeps _drive_burst's chain_break a pure gate
                 budget = self._scan_budget(decode_plan, n, shipped)
+                # the device's positions: the mirrors lag them by what
+                # the dispatches in flight ship
+                pages = self._attn_pages(
+                    [(int(self._pos[s]) + shipped.get(s, 0),
+                      int(budget[s])) for s in rows if budget[s]])
             t0d = time.perf_counter()
             outs, state = self._dispatch_scan_device(
                 rows, n, n_top, budget, state=state)
@@ -6075,10 +6101,10 @@ class InferenceEngine:
                 shipped[slot] = shipped.get(slot, 0) + int(budget[slot])
             self.stats.steps += n
             return (outs, budget, t_start, disp, js,
-                    flying.dispatched()), state
+                    flying.dispatched(), pages), state
 
         def complete(devs):
-            outs_k, budget_k, t_start, disp_k, js_k, chained = devs
+            outs_k, budget_k, t_start, disp_k, js_k, chained, pages = devs
             with span("fetch"):
                 fetched = self._fetch_scan(outs_k)
             wall = flying.fetched(t_start)
@@ -6093,7 +6119,7 @@ class InferenceEngine:
                 device_s=wall if chained else waited, fetch_wait_s=waited,
                 js=js_k, rids=rids,
                 moe=np.sum(moe, axis=0) if moe else None,
-                chained=chained)
+                chained=chained, **pages)
             with span("emit"):
                 self._complete_scan(decode_plan, n, fetched, budget_k)
             for slot in rows:

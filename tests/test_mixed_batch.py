@@ -280,6 +280,48 @@ def test_mixed_records_count_the_query_tiles_attention_folds(sixteen_slots):
         assert _metric(name) - before[name] == sum(r[key] for r in mixed)
 
 
+PAGE_SERIES = ("cake_decode_attn_pages_total",
+               "cake_decode_attn_pages_table_total")
+
+
+def test_decode_records_count_the_pages_attention_streams(sixteen_slots):
+    """attn_pages / attn_pages_table: the host's count of the KV pages
+    the decode attention kernel streams a layer for a step's active
+    rows (position // page + 1, from the positions it dispatches: the
+    mirrors plus what the steps in flight ship) beside the entries of
+    the page table; on every decode record, on no mixed record, and
+    summed in two /metrics series."""
+    eng, _ = sixteen_slots
+    table = 16 * eng.cache.max_pages
+    assert eng._attn_pages([(0, 1), (PAGE - 1, 1), (PAGE, 1),
+                            (3 * PAGE + 2, 1)]) == {
+        "attn_pages": 1 + 1 + 2 + 4, "attn_pages_table": table}
+    # a scan's record sums its steps: positions PAGE - 2, PAGE - 1, PAGE
+    assert eng._attn_pages([(PAGE - 2, 3), (0, 1)]) == {
+        "attn_pages": 1 + 1 + 2 + 1, "attn_pages_table": 3 * table}
+    assert eng._attn_pages([]) == {}
+    seen = {r["step"] for r in eng.flight.dump()}
+    before = {name: _metric(name) for name in PAGE_SERIES}
+    # a prompt of 9 tokens: the decode steps' current tokens sit at
+    # positions 9, 10, ... and cross two page edges
+    h = eng.submit([5] * 9, max_new_tokens=2 * PAGE + 4, temperature=0.0,
+                   repeat_penalty=1.0)
+    assert h.wait(timeout=300)
+    new = [r for r in eng.flight.dump() if r["step"] not in seen]
+    decode = sorted((r for r in new if r["kind"] == "decode"),
+                    key=lambda r: r["step"])
+    assert len(decode) == 2 * PAGE + 3 and any(r["chained"] for r in decode)
+    for k, r in enumerate(decode):
+        assert r["rows"] == 1 and r["attn_pages"] == (9 + k) // PAGE + 1, r
+        assert r["attn_pages_table"] == table, r
+    assert {r["attn_pages"] for r in decode} == {1, 2, 3}
+    mixed = [r for r in new if r["kind"] == "mixed"]
+    assert mixed and not [r for r in mixed if "attn_pages" in r
+                          or "attn_pages_table" in r]
+    for name, key in zip(PAGE_SERIES, ("attn_pages", "attn_pages_table")):
+        assert _metric(name) - before[name] == sum(r[key] for r in decode)
+
+
 @pytest.mark.slow  # two engines under staggered load -> slow lane
 def test_mixed_admission_with_preemption_interleaved(tiny_config,
                                                      params):

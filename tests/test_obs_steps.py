@@ -68,15 +68,20 @@ def test_flight_recorder_ring_bounds():
 
 @pytest.mark.parametrize("kind,tiles", [
     ("mixed", {"attn_q_tiles": 30, "attn_q_tiles_window": 128}),
-    ("mixed", {}), ("decode", {})])
+    ("mixed", {}), ("decode", {}),
+    ("decode", {"attn_pages": 46, "attn_pages_table": 256})])
 def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
                                                                 tiles):
     """A mixed step whose rows go through the mixed attention kernel
-    records the query tiles it folds and those of the rows' windows;
-    the record of any other step has neither key, and the two /metrics
-    series move with the records that have them."""
-    series = ("cake_mixed_attn_q_tiles_total",
-              "cake_mixed_attn_q_tiles_window_total")
+    records the query tiles it folds and those of the rows' windows, a
+    decode step whose rows go through the decode kernel the pages it
+    streams and the entries of its page table; the record of any other
+    step has none of the keys, and the four /metrics series move with
+    the records that have them."""
+    series = {"cake_mixed_attn_q_tiles_total": "attn_q_tiles",
+              "cake_mixed_attn_q_tiles_window_total": "attn_q_tiles_window",
+              "cake_decode_attn_pages_total": "attn_pages",
+              "cake_decode_attn_pages_table_total": "attn_pages_table"}
 
     def read():
         return [sum(float(ln.split()[-1])
@@ -89,7 +94,7 @@ def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
     got = {k: v for k, v in rec.to_dict().items() if k.startswith("attn_")}
     assert got == tiles
     assert [b - a for a, b in zip(before, read())] == [
-        tiles.get("attn_q_tiles", 0), tiles.get("attn_q_tiles_window", 0)]
+        tiles.get(key, 0) for key in series.values()]
 
 
 def test_mfu_math_against_hand_computed_matmul():

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from cake_tpu.models.llama.paged import (
     paged_attention, paged_attention_mixed,
 )
+from cake_tpu.ops import ragged_paged_attention as rpa
 from cake_tpu.ops.ragged_paged_attention import (
     MIXED_Q_TILE, mixed_q_tiles, ragged_paged_attention,
     ragged_paged_attention_mixed, ragged_paged_mixed_supported,
@@ -220,6 +221,111 @@ def test_mixed_fold_decode_row_bitwise_matches_decode_fold():
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
+# -- the decode kernel walks a row's live pages itself ---------------------------
+#
+# One grid step a row; the row's pages come through a ring of
+# RING_DEPTH slots (decode_ring_depth gives a test's 1 KiB page the
+# longest ring there is, which a five-page table never wraps), the
+# copies ahead running on into the next rows.
+
+RING_DEPTH = 3
+FULL = MAX_PAGES * P - 1        # the last position a table holds
+_DEAD = [-1] * MAX_PAGES
+# name: (table rows, positions)
+WALK_CASES = {
+    # an idle slot between two live rows: the copies ahead of row 0
+    # are row 2's
+    "dead_row_between": ([[7, 2, 9, -1, -1], _DEAD, [4, 11, 3, 1, -1]],
+                         [2 * P + 5, 0, 3 * P + 2]),
+    # dead rows first and last: the call's first copies skip a row,
+    # its last row starts none
+    "dead_rows_at_the_ends": ([_DEAD, [5, 8, -1, -1, -1], _DEAD],
+                              [P + 4, P + 1, 0]),
+    "live_pages_fill_the_table": ([[3, 6, 1, 10, 5], [8, 2, 7, 4, 9]],
+                                  [FULL, FULL - P + 1]),
+    "one_live_page": ([[6, -1, -1, -1, -1], [9, -1, -1, -1, -1]],
+                      [0, P - 1]),
+    # more live pages than the ring has slots, then fewer
+    "ring_wraps_and_does_not": ([[3, 6, 1, 10, 5], [8, 2, -1, -1, -1],
+                                 [11, 4, 7, 9, -1]],
+                                [FULL, P + 3, 3 * P + 6]),
+    # holes inside the live range, page 0 of the pool poisoned: a hole
+    # that read page 0 (as a clamped block index did) would show
+    "hole_reads_no_page": ([[4, -1, 11, 3, -1], [-1, 2, -1, 7, -1],
+                            [-1, -1, 5, -1, -1]],
+                           [3 * P + 2, 3 * P, 2 * P + 1]),
+}
+
+
+def _poisoned(kind, pool):
+    """`pool` with NaN wherever a read of its page 0 would pick it up:
+    in the page of a float pool, in the page's scales otherwise."""
+    if kind == "f32":
+        return pool.at[:, 0].set(jnp.nan)
+    return pool._replace(scale=pool.scale.at[:, 0].set(jnp.nan))
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("kind", ["f32", "int8", "int4"])
+def test_decode_kernel_walks_live_pages(kind, case, monkeypatch):
+    monkeypatch.setattr(rpa, "decode_ring_depth", lambda page_bytes:
+                        RING_DEPTH)
+    rng = np.random.default_rng(40)
+    pk, pv = (_poisoned(kind, pool)
+              for pool in _SWEEP_POOLS[kind](rng, 2, 16))
+    rows, pos = WALK_CASES[case]
+    assert 0 not in {p for row in rows for p in row}
+    table = jnp.asarray(rows, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(len(rows), 1, 4, 16)), jnp.float32)
+    for layer in CHECK_LAYERS:
+        want = np.asarray(paged_attention(q, pk, pv, layer, table, pos))
+        if kind == "f32":
+            got = ragged_paged_attention(q, pk, pv, layer, table, pos,
+                                         interpret=True)
+        else:
+            got = ragged_paged_attention(
+                q, pk.q, pv.q, layer, table, pos, scale_k=pk.scale,
+                scale_v=pv.scale, packed4=kind == "int4", interpret=True)
+        got = np.asarray(got)
+        assert np.isfinite(want).all()
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        for b, row in enumerate(rows):
+            if all(p < 0 for p in row):
+                assert not got[b].any()
+
+
+def test_decode_kernel_grid_is_one_step_a_row():
+    """The traced call: a grid of (rows,), whatever the table's width,
+    and the pool handed over whole, outside VMEM."""
+    rng = np.random.default_rng(41)
+    pk, pv = _pool(rng, KV=2, hd=16)
+    q = jnp.zeros((3, 1, 4, 16), jnp.float32)
+    table = jnp.zeros((3, MAX_PAGES), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: ragged_paged_attention(
+        *a, interpret=True))(q, pk, pv, jnp.int32(LAYER), table,
+                             jnp.zeros(3, jnp.int32))
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (3,)
+    assert call.params["name"] == "cake_decode_attn"
+    blocks = [str(bm.block_aval) for bm in mapping.block_mappings]
+    assert [b.startswith("Ref<any>") for b in blocks] == [
+        False, True, True, False]                  # q, pool_k, pool_v, out
+
+
+def test_decode_ring_depth_follows_the_page_bytes():
+    """A page copy ahead for every _RING_BYTES_AHEAD / (K + V page)
+    bytes, one at least: the cells' shapes (128-token bf16 pages of 2,
+    8 and 16 KV heads) and the int8 tier's."""
+    KiB = 1024
+    assert [rpa.decode_ring_depth(b * KiB) for b in (64, 256, 512)] == [
+        9, 3, 2]
+    assert rpa.decode_ring_depth(128 * KiB) == 5       # int8, 8 KV heads
+    assert rpa.decode_ring_depth(4096 * KiB) == 2
+    assert rpa.decode_ring_depth(1 * KiB) == 1 + rpa._RING_PAGES_AHEAD_MAX
+
+
 # -- the shapes the 8B server dispatches, on whatever backend runs them --------
 #
 # H=32, KV=8, hd=128, 128-token pages, bf16 queries: Llama-3-8B's
@@ -322,8 +428,9 @@ def test_mixed_kernel_real_backend_production_shapes(kind):
 def test_supported_gate():
     assert not ragged_paged_supported(P, H=5, KV=2, hd=16)  # H % KV
     # interpret mode takes any shape; so does the chip for a float pool
-    # down to hd=16 and 8-token pages (checked on a v5e, PR 21)
-    assert ragged_paged_supported(P, H=4, KV=2, hd=16)
+    # down to hd=16 and 8-token pages (checked on a v5e, PR 21) whose
+    # page row fills whole lane tiles (KV*hd a multiple of 128, PR 42)
+    assert ragged_paged_supported(P, H=8, KV=8, hd=16)
     assert ragged_paged_supported(128, H=4, KV=2, hd=128)
 
 
@@ -333,7 +440,7 @@ def test_supported_gate_on_chip_shape_classes(monkeypatch):
     production class only, and the mixed kernel only for power-of-two
     GQA groups (Mosaic refuses the [C, 7, hd] -> [7C, hd] cast)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ragged_paged_supported(8, H=4, KV=2, hd=16)
+    assert ragged_paged_supported(8, H=8, KV=8, hd=16)
     assert ragged_paged_supported(128, H=16, KV=4, hd=64)
     assert not ragged_paged_supported(8, H=4, KV=2, hd=20)
     assert not ragged_paged_supported(8, H=4, KV=2, hd=16, quantized=True)
@@ -345,6 +452,26 @@ def test_supported_gate_on_chip_shape_classes(monkeypatch):
                                             q_width=16)          # G=7
     assert ragged_paged_mixed_supported(128, H=32, KV=4, hd=128,
                                         q_width=16)              # G=8
+
+
+# (KV, hd): what the v5e compiler said of the decode kernel's copies at
+# each page row (ahead of time, PR 42; float32 and bfloat16, pages of
+# 8, 16, 64 and 128 tokens alike)
+@pytest.mark.parametrize("KV,hd,compiles", [
+    (2, 16, False), (1, 64, False), (3, 64, False), (2, 96, False),
+    (8, 16, True), (2, 64, True), (1, 128, True), (4, 96, True)])
+def test_decode_gate_wants_whole_lane_tiles(monkeypatch, KV, hd, compiles):
+    """The decode kernel's own copies slice a (page, KV*hd) tile out of
+    the pool in HBM, which Mosaic admits at a multiple of 128 lanes
+    only; the mixed kernel's BlockSpec takes the narrow rows still, and
+    off the chip (interpret mode) every shape passes."""
+    H = 2 * KV
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ragged_paged_supported(16, H=H, KV=KV, hd=hd)
+    assert ragged_paged_mixed_supported(16, H=H, KV=KV, hd=hd, q_width=8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ragged_paged_supported(16, H=H, KV=KV, hd=hd) == compiles
+    assert ragged_paged_mixed_supported(16, H=H, KV=KV, hd=hd, q_width=8)
 
 
 def test_mixed_supported_gate_bounds_scratch_vmem(monkeypatch):
@@ -411,6 +538,9 @@ def test_engine_pallas_matches_fold(tiny_config):
     from cake_tpu.ops.sampling import SamplingConfig
     from cake_tpu.serve.engine import InferenceEngine
 
+    # 2 KV heads of 64: a page row of 128 lanes, the narrowest the
+    # decode kernel's copies take on a chip (ragged_paged_supported)
+    tiny_config = type(tiny_config).tiny(hidden_size=256)
     params = init_params(tiny_config, jax.random.PRNGKey(0),
                          dtype=jnp.float32)
     prompts = [[5] * 9, [3, 7, 9, 11, 2]]
