@@ -132,6 +132,11 @@ def block_skeleton_stats(lp, x, config: LlamaConfig, attn_fn,
         if "q_norm" in lp:  # OLMoE query/key norm (config.qk_norm)
             q = _qk_norm(q, lp["q_norm"], config.rms_norm_eps, tp_axis)
             k = _qk_norm(k, lp["k_norm"], config.rms_norm_eps, tp_axis)
+        # without the barrier XLA folds the head split below into wq and
+        # wk and, every layer, slices and transposes them for it (copy
+        # s8[1,4096,4096] / [1,4096,1024] and their constant_dynamic-slice_
+        # fusions: 2.7 ms of a 13.5 ms decode step, PERF.md §6 PR 43)
+        q, k, v = lax.optimization_barrier((q, k, v))
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, KV, hd)
         v = v.reshape(B, S, KV, hd)

@@ -1,0 +1,117 @@
+"""The paged step programs stream their int8 weights where they lie.
+
+Compiled ahead of time for a described v5e (no chip; tools/step_hlo.py),
+the decode and the mixed program of every block kind that runs
+`model.block_skeleton_stats` must hold no `copy` with an `s8[...]`
+result and no stand-alone slice of a layer's weight: each matmul slices
+its layer out of the stacked `[L, in, out]` leaf inside its own fusion.
+Before PR 43 XLA folded the head split that follows the q and k
+projections into `wq` and `wk` and paid for it with a slice and a
+transposing copy of both, every layer of every step (PERF.md §6).
+
+This holds the property, not the fix: any change that brings a
+materialised int8 weight back into these programs fails here, at no
+chip time. Every compile of this file runs in the test's own process
+(one process holds libtpu), and the topology is described in a fixture,
+never at import (the on-chip-measurement guide, section 2).
+"""
+
+import dataclasses
+import importlib.util
+import pathlib
+
+import pytest
+
+import jax
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+CONFIGS = ROOT / "benchmarks" / "configs"
+
+# block kind -> the cell whose widths it is compiled at
+BLOCKS = {
+    "dense": "mistral-7b-int8",            # Llama/Mistral: GQA 32/8
+    "qkv_bias": "qwen2.5-32b-int8-4chip",  # Qwen2: bq/bk/bv, GQA 40/8
+    "q_norm": "olmoe-1b-7b-int8",          # OLMoE: q_norm/k_norm, MHA 16
+}
+# the mixed kernel takes no group of 5 query heads a KV head
+# (rpa.ragged_paged_mixed_supported): such a model's mixed step folds
+FOLDS = {("qkv_bias", "mixed")}
+
+
+@pytest.fixture(scope="module")
+def tool():
+    spec = importlib.util.spec_from_file_location(
+        "step_hlo", ROOT / "tools" / "step_hlo.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def one_chip(tool):
+    try:
+        return tool.describe_v5e()
+    except Exception as e:  # no libtpu, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_step_program_materialises_no_int8_weight(tool, one_chip, block,
+                                                  program):
+    from cake_tpu.models.llama.config import load_config
+
+    config = dataclasses.replace(load_config(str(CONFIGS / BLOCKS[block])),
+                                 num_hidden_layers=2)
+    decode, mixed = tool.step_fns(config)
+    attn = "fold" if (block, program) in FOLDS else "pallas"
+    # the served program: conftest's `highest` is the CPU goldens' need
+    with jax.default_matmul_precision("default"):
+        if program == "decode":
+            compiled = tool.compile_step(decode, config, one_chip, attn=attn)
+        else:
+            compiled = tool.compile_step(mixed, config, one_chip, attn=attn,
+                                         width=128, n_tokens=144)
+    hlo = compiled.as_text()
+    assert attn == "fold" or "tpu_custom_call" in hlo, (
+        "the Pallas kernels were interpreted")
+    found = tool.materialised_int8(hlo)
+    assert not found, (
+        f"{block} {program} step writes int8 weights to memory again: "
+        + ", ".join(map(str, found)))
+
+
+def test_materialised_int8_reads_the_parent_program(tool):
+    """The reader on the lines PR 42's decode program held (and on the
+    forms it must pass over): a fusion's own slice, a prefetch into
+    faster memory, a buffer handed on."""
+    hlo = """\
+HloModule jit_decode_step_ragged_paged
+
+%fused_computation.18 (param_0.476: s8[32,4096,4096], param_1.525: s32[]) -> s8[4096,4096] {
+  %param_0.476 = s8[32,4096,4096]{2,1,0:T(8,128)(4,1)} parameter(0)
+  %dynamic_slice.247 = s8[1,4096,4096]{2,1,0:T(8,128)(4,1)} dynamic-slice(%param_0.476, %param_1.525), dynamic_slice_sizes={1,4096,4096}
+  ROOT %bitcast.180 = s8[4096,4096]{1,0:T(8,128)(4,1)} bitcast(%dynamic_slice.247)
+}
+
+%while_body (p: (s32[], s8[32,4096,4096])) -> (s32[], s8[32,4096,4096]) {
+  %get-tuple-element.7 = s8[32,4096,4096]{2,1,0:T(8,128)(4,1)} get-tuple-element(%p), index=1
+  %constant_dynamic-slice_fusion.4 = s8[1,4096,4096]{2,1,0:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.7, %i), kind=kLoop, calls=%fused_computation.18
+  %copy.41 = s8[1,4096,4096]{1,2,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.4)
+  %bitcast.208 = s8[32,128,4096]{2,1,0:T(8,128)(4,1)S(1)} bitcast(%copy.41)
+  %slice-done = s8[1,14336,4096]{2,1,0:T(8,128)(4,1)S(1)} slice-done(%slice-start)
+  %copy-done.3 = s8[2,4096,1024]{2,1,0:T(8,128)(4,1)S(1)} copy-done(%copy-start.3)
+  %custom-call.18 = s8[2,14336,4096]{2,1,0:T(8,128)(4,1)S(1)} custom-call(%slice-done, %slice-done.1), custom_call_target="ConcatBitcast"
+  ROOT %tuple.1 = (s32[], s8[32,4096,4096]) tuple(%i, %get-tuple-element.7)
+}
+
+ENTRY %main (w: s8[32,4096,4096]) -> s8[32,4096,4096] {
+  %w = s8[32,4096,4096]{2,1,0:T(8,128)(4,1)} parameter(0)
+  ROOT %while.1 = (s32[], s8[32,4096,4096]) while(%t), condition=%c, body=%while_body
+}
+"""
+    found = tool.materialised_int8(hlo)
+    assert [(m.name, m.shape, m.opcode) for m in found] == [
+        ("constant_dynamic-slice_fusion.4", "s8[1,4096,4096]", "fusion"),
+        ("copy.41", "s8[1,4096,4096]", "copy"),
+    ]
