@@ -191,8 +191,7 @@ class Args:
     paged_attn: str = "auto"
     # --require-model-type NAME: refuse to start unless the model
     # directory's config.json resolves to this family (a config.json
-    # `model_type`: llama, mistral, qwen2, mixtral, olmoe, glm_moe_dsa,
-    # dots3_note, nemotron_h, zaya).
+    # `model_type`: a key of models/llama/config.MODEL_TYPES).
     # An assertion for scripted deployments, not a switch: nothing else
     # reads it
     require_model_type: Optional[str] = None

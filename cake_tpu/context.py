@@ -126,6 +126,12 @@ class Context:
         from cake_tpu.models import load_text_params
         from cake_tpu.parallel.plan import ParallelPlan
         plan = ParallelPlan.from_topology(cfg, self.topology, args=a)
+        refusal = cfg.family.refusal({
+            "topology": (plan.stages > 1 or plan.tp > 1 or plan.dp > 1
+                         or a.sp > 1),
+            "--draft-model": a.draft_model is not None})
+        if refusal:
+            raise ValueError(refusal)
 
         # stage-local load (reference worker.rs:106-127 parity, per
         # shard): with a sharded placement the tree is BORN on its mesh
@@ -134,21 +140,6 @@ class Context:
         # host/device copy ever exists, which is what lets a 70B (or
         # Mixtral-8x22B) topology actually load instead of dying at the
         # eager full-tree load.
-        one_chip_only = next((family for attr, family in (
-            ("kv_lora_rank", f"{getattr(cfg, 'hf_layout', '')} (latent "
-                             "attention over the page pool)"),
-            ("mamba_layers", "nemotron_h (a recurrent state a row "
-                             "beside the page pool)"),
-            ("cca_time0", "zaya (a conv tail a row beside the page "
-                          "pool)")) if getattr(cfg, attr, None)), None)
-        if one_chip_only and (
-                plan.stages > 1 or plan.tp > 1 or plan.dp > 1 or a.sp > 1
-                or a.draft_model is not None):
-            raise ValueError(
-                f"model_type {one_chip_only} does not serve yet over a "
-                "topology, --tp, --dp, --sp or with --draft-model: one "
-                "chip's paged engine only (ROADMAP.md lists each as left "
-                "to do)")
         born_sharded = (
             (plan.stages > 1 or plan.tp > 1 or plan.dp > 1)
             and (a.sp <= 1 or plan.stages > 1)

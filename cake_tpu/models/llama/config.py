@@ -10,6 +10,7 @@ a constant.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 from dataclasses import dataclass, field
@@ -113,6 +114,16 @@ class LlamaConfig:
     # tile (ops/flash_attention.py). Off by default so CPU test runs don't
     # pay interpret-mode cost; the TPU Context enables it.
     use_flash_attention: bool = False
+
+    # what the paged engine reads of this family of models
+    # (models/family.Family), as "module:NAME"; a config class of a
+    # family with a trunk of its own names its own
+    _family = "cake_tpu.models.llama.paged:FAMILY"
+
+    @property
+    def family(self):
+        module, name = self._family.split(":")
+        return getattr(importlib.import_module(module), name)
 
     @property
     def head_dim(self) -> int:

@@ -63,6 +63,7 @@ machinery the TPU design adds.
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial as _partial
 from typing import List, NamedTuple, Optional
 
@@ -76,10 +77,21 @@ from cake_tpu.kv.quantized_pool import (
     dequantize_pages, qupdate_pool_per_row, qwrite_prompt_pages,
     qwrite_windows_pages,
 )
+from cake_tpu.models.family import Family
 from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.models.step_programs import (
+    make_decode_scan, make_mixed_sampled,
+)
 from cake_tpu.parallel.context_parallel import (
     merge_attention_stats, partial_attention_stats,
 )
+
+
+def _check_pages(page_size: int, max_seq_len: int) -> None:
+    if max_seq_len % page_size:
+        raise ValueError(
+            f"page_size {page_size} must divide max_seq_len "
+            f"{max_seq_len}")
 
 
 class PagedKVCache(NamedTuple):
@@ -108,38 +120,23 @@ class PagedKVCache(NamedTuple):
 
     @classmethod
     def create(cls, config: LlamaConfig, slots: int, n_pages: int,
-               page_size: int, max_seq_len: int,
-               dtype=jnp.bfloat16) -> "PagedKVCache":
-        if max_seq_len % page_size:
-            raise ValueError(
-                f"page_size {page_size} must divide max_seq_len "
-                f"{max_seq_len}")
-        if (getattr(config, "mamba_layers", None)
-                or getattr(config, "cca_time0", None)):
-            return HybridPagedCache.create(config, slots, n_pages,
-                                           page_size, max_seq_len, dtype)
-        L = config.num_hidden_layers
-        if getattr(config, "kv_lora_rank", None):
-            if config.sliding_layers:
-                raise ValueError(
-                    "a model with sliding-window latent layers keeps a "
-                    "pool and a table by kind of layer: "
-                    "WindowedPagedCache.create")
-            # latent attention: one latent row a token, and the
-            # indexer's key in the layers that compute an index
-            shape_k = (L, n_pages, page_size, config.latent_row)
-            shape_v = (len(config.full_layers), n_pages, page_size,
-                       config.index_head_dim)
-        else:
-            shape_k = shape_v = (L, n_pages, page_size,
-                                 config.num_key_value_heads
-                                 * config.head_dim)
-        return cls(
-            k=jnp.zeros(shape_k, dtype),
-            v=jnp.zeros(shape_v, dtype),
-            table=jnp.full((slots, max_seq_len // page_size), -1,
-                           jnp.int32),
-        )
+               page_size: int, max_seq_len: int, dtype=jnp.bfloat16,
+               width: Optional[int] = None):
+        """The cache of `config`'s family: the pytree its step programs
+        carry (config.family.create_cache; this class for GQA and for
+        one kind of latent layer, a class below for the others). width:
+        the mixed step's window, which a pool of ring pages is sized
+        by."""
+        _check_pages(page_size, max_seq_len)
+        return config.family.create_cache(config, slots, n_pages, page_size,
+                                          max_seq_len, width, dtype)
+
+    @classmethod
+    def zeros(cls, shape_k: tuple, shape_v: tuple, slots: int,
+              max_pages: int, dtype) -> "PagedKVCache":
+        """Empty pools [L, N_pages, page, row] and an unmapped table."""
+        return cls(k=jnp.zeros(shape_k, dtype), v=jnp.zeros(shape_v, dtype),
+                   table=jnp.full((slots, max_pages), -1, jnp.int32))
 
     def memory_bytes(self) -> int:
         """ACTUAL pool storage bytes, summed per leaf — matches the
@@ -167,32 +164,16 @@ class HybridPagedCache(NamedTuple):
     max_seq_len = PagedKVCache.max_seq_len
 
     @classmethod
-    def create(cls, config, slots: int, n_pages: int, page_size: int,
-               max_seq_len: int, dtype=jnp.bfloat16) -> "HybridPagedCache":
-        if max_seq_len % page_size:
-            raise ValueError(
-                f"page_size {page_size} must divide max_seq_len "
-                f"{max_seq_len}")
-        c = config
-        if getattr(c, "cca_time0", None):
-            # convolutions inside attention: pages and a tail in every
-            # layer, no recurrent state
-            L_attn = L_M = c.num_hidden_layers
-            ssm = None
-            tail = (max(c.cca_time0, c.cca_time1) - 1, c.cca_tail_width)
-        else:
-            L_attn, L_M = len(c.attn_layers), len(c.mamba_layers)
-            ssm = jnp.zeros((L_M, slots, c.mamba_num_heads,
-                             c.mamba_head_dim, c.ssm_state_size),
-                            jnp.float32)
-            tail = (c.conv_kernel - 1, c.conv_dim)
-        pool = (L_attn, n_pages, page_size,
-                c.num_key_value_heads * c.head_dim)
+    def zeros(cls, pool: tuple, slots: int, max_pages: int, dtype, *,
+              ssm: Optional[tuple], conv: tuple) -> "HybridPagedCache":
+        """Empty pools `pool` [L_attn, N_pages, page, KV*hd], an
+        unmapped table, and the rows' state at the family's shapes
+        (ssm: None where it keeps no recurrent state)."""
         return cls(
             k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
-            table=jnp.full((slots, max_seq_len // page_size), -1,
-                           jnp.int32),
-            ssm=ssm, conv=jnp.zeros((L_M, slots) + tail, dtype))
+            table=jnp.full((slots, max_pages), -1, jnp.int32),
+            ssm=None if ssm is None else jnp.zeros(ssm, jnp.float32),
+            conv=jnp.zeros(conv, dtype))
 
     def memory_bytes(self) -> int:
         """Pool bytes (the pages an allocator hands out)."""
@@ -201,6 +182,8 @@ class HybridPagedCache(NamedTuple):
     def state_bytes(self) -> int:
         """Bytes of the rows' state."""
         return (0 if self.ssm is None else self.ssm.nbytes) + self.conv.nbytes
+
+    beside_bytes = state_bytes
 
 
 class WindowedPagedCache(NamedTuple):
@@ -230,10 +213,7 @@ class WindowedPagedCache(NamedTuple):
     def create(cls, config, slots: int, n_pages: int, page_size: int,
                max_seq_len: int, ring_pages: int,
                dtype=jnp.bfloat16) -> "WindowedPagedCache":
-        if max_seq_len % page_size:
-            raise ValueError(
-                f"page_size {page_size} must divide max_seq_len "
-                f"{max_seq_len}")
+        _check_pages(page_size, max_seq_len)
         c = config
         return cls(
             k=jnp.zeros((len(c.latent_layers), n_pages, page_size,
@@ -255,6 +235,8 @@ class WindowedPagedCache(NamedTuple):
         """Bytes of the sliding layers' pool: slots x R pages, whatever
         max_seq_len is."""
         return self.w.nbytes
+
+    beside_bytes = window_bytes
 
 
 def ring_holds(page_size: int, ring_pages: int, window: int, start: int,
@@ -781,7 +763,7 @@ def forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
                          active, rope, config: LlamaConfig,
                          attn: str = "fold", counters: bool = False):
     """model.forward_ragged's signature over a paged cache — un-jitted,
-    so serve.engine.make_decode_scan can build the sampled paged decode
+    so step_programs.make_decode_scan can build the sampled paged decode
     programs from it (one step in flight, or a K-step scan, exactly
     like dense). counters=True returns what a step program returns
     (_step_result: a sparse model's expert counters third)."""
@@ -809,13 +791,17 @@ def _forward_ragged_paged(params, tokens, cache: PagedKVCache, pos,
     return logits, cache, stats
 
 
+# the record keys of a sparse model's counter vector, in its order
+MOE_COUNTERS = ("moe_rows", "moe_rows_padded", "moe_load_max",
+                "moe_load_mean", "moe_experts_touched")
+
+
 def _step_result(logits, cache, stats):
     """What a step program returns: (logits, cache), and for a sparse
-    model a third, its expert counters [5] in the order of
-    obs/steps.MOE_COUNTERS (the engine fetches them with the sampled
-    tokens): rows, padded rows and experts touched summed over the
-    layers, the busiest and the average expert's tokens as the mean
-    over the layers."""
+    model a third, its expert counters [5] in the order of MOE_COUNTERS
+    (the engine fetches them with the sampled tokens): rows, padded
+    rows and experts touched summed over the layers, the busiest and
+    the average expert's tokens as the mean over the layers."""
     if stats is None:
         return logits, cache
     return logits, cache, jnp.stack([
@@ -1206,3 +1192,29 @@ def verify_window_paged(params, tokens, pos, q_len, active,
     with jax.named_scope("head"):
         logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, cache
+
+
+# -- what the engine reads of this family (models/family.py) ----------------
+
+
+def _create_pool(config: LlamaConfig, slots: int, n_pages: int,
+                 page_size: int, max_seq_len: int, width, dtype):
+    """A token's keys and values, KV*hd wide, in every layer."""
+    shape = (config.num_hidden_layers, n_pages, page_size,
+             config.num_key_value_heads * config.head_dim)
+    return PagedKVCache.zeros(shape, shape, slots,
+                              max_seq_len // page_size, dtype)
+
+
+# GQA rows in the pool: every option the engine has moves them, so the
+# table of what they cannot move is empty
+FAMILY = Family(
+    name="llama", decode_step=decode_step_ragged_paged,
+    decode_programs=make_decode_scan(
+        _partial(forward_ragged_paged, counters=True)),
+    mixed_step=mixed_step_paged,
+    mixed_sampled=make_mixed_sampled(mixed_step_paged),
+    create_cache=_create_pool)
+# ... and with sparse experts where the FFN was: the same programs,
+# which then return the expert counters
+SPARSE = dataclasses.replace(FAMILY, name="mixtral", counters=MOE_COUNTERS)

@@ -34,6 +34,8 @@ class MoEConfig(LlamaConfig):
     # gate_proj/up_proj/down_proj)
     hf_layout: str = "mixtral"
 
+    _family = "cake_tpu.models.llama.paged:SPARSE"
+
     @classmethod
     def from_hf_dict(cls, raw: dict) -> "MoEConfig":
         base = LlamaConfig.from_hf_dict(raw)
@@ -167,6 +169,8 @@ class GlmMoeDsaConfig(MoEConfig):
     `first_routed_expert` the first held expert's index: one chip's
     share of an expert-parallel deployment routes over all of them and
     computes its own (ops/moe.moe_mlp)."""
+
+    _family = "cake_tpu.models.moe.glm_dsa:FAMILY"
 
     q_lora_rank: int = 2048
     kv_lora_rank: int = 512
@@ -338,6 +342,8 @@ class Dots3NoteConfig(GlmMoeDsaConfig):
 
     `indexer_types` holds "full" | "sliding" here (from `layer_types`)."""
 
+    _family = "cake_tpu.models.moe.glm_dsa:WINDOWED"
+
     swa_num_attention_heads: int = 64
     swa_q_lora_rank: int = 1024
     swa_kv_lora_rank: int = 1024
@@ -502,6 +508,8 @@ class NemotronHConfig(MoEConfig):
     `n_routed_experts_total` the router's width, `first_routed_expert`
     the first held expert's index, as GlmMoeDsaConfig has them."""
 
+    _family = "cake_tpu.models.moe.nemotron_h:FAMILY"
+
     pattern: Tuple[str, ...] = ()
     mamba_num_heads: int = 128
     mamba_head_dim: int = 64
@@ -663,6 +671,8 @@ class ZayaConfig(MoEConfig):
 
     `intermediate_size` carries `moe_intermediate_size` (an expert's
     width: what models/moe/params and ops/moe read)."""
+
+    _family = "cake_tpu.models.moe.zaya:FAMILY"
 
     attn_head_dim: int = 128
     cca_time0: int = 2

@@ -74,9 +74,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from cake_tpu.models.family import Family, Windows, cannot_move
 from cake_tpu.models.llama import paged
 from cake_tpu.models.llama.paged import PagedKVCache, write_token_rows
 from cake_tpu.models.moe.config import GlmMoeDsaConfig, LatentGeometry
+from cake_tpu.models.step_programs import (
+    make_decode_scan, make_mixed_sampled,
+)
 from cake_tpu.ops import mla_attention as mla
 from cake_tpu.ops.moe import LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
@@ -89,10 +93,15 @@ DENSE_LEAVES = ("w_gate", "w_up", "w_down")
 SPARSE_LEAVES = ("router", "router_bias", "ws_gate", "ws_up", "ws_down")
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 GATE_LEAF = "w_attn_gate"
-# a step program returns obs/steps.STEP_COUNTERS, in that order: the
-# expert counters' five, then the routed rows and the indexer's; a
-# model with sliding layers appends obs/steps.SWA_COUNTERS' three
-N_COUNTERS = 11
+# the record keys of the vector a step program returns, in trunk's
+# order: the expert counters' five (the held experts' rows alone), the
+# routed rows and the indexer's; a model with sliding layers appends
+# SWA_COUNTERS, and the dsa_* then count its full layers alone
+COUNTERS = paged.MOE_COUNTERS + (
+    "moe_rows_routed", "dsa_keys_visible", "dsa_keys_selected",
+    "dsa_rows_distinct", "dsa_index_layers", "dsa_index_reused")
+SWA_COUNTERS = ("swa_keys_visible", "swa_keys_attended", "swa_layers")
+N_COUNTERS = len(COUNTERS)
 
 
 class Window(NamedTuple):
@@ -625,7 +634,7 @@ def forward_ragged_latent(params, tokens, cache: PagedKVCache, pos, active,
                           rope, config: GlmMoeDsaConfig,
                           attn: str = "fold"):
     """paged.forward_ragged_paged(..., counters=True)'s contract: what
-    serve.engine.make_decode_scan builds the sampled decode programs
+    step_programs.make_decode_scan builds the sampled decode programs
     from -> (logits [B, V], cache, counters)."""
     out = decode_trunk(params, tokens, cache, pos, active, rope, config,
                        attn)
@@ -642,3 +651,72 @@ def decode_step_latent(params, tokens, pos, active, cache: PagedKVCache,
     decode step)."""
     return forward_ragged_latent(params, tokens, cache, pos, active, rope,
                                  config, attn)
+
+
+# -- what the engine reads of this family (models/family.py) ----------------
+
+
+def create_cache(config: GlmMoeDsaConfig, slots: int, n_pages: int,
+                 page_size: int, max_seq_len: int, width, dtype):
+    """One latent row a token in every latent layer, and the indexer's
+    key in the layers that compute an index; with sliding layers, the
+    pools by kind of layer. Slot i owns a ring of the window pool for
+    good (WindowedPagedCache.create maps it): that pool is slots x ring
+    whatever max_seq_len, and admission, release and a rebuild never
+    touch it."""
+    c = config
+    if c.sliding_layers:
+        if width is None:
+            raise ValueError(
+                "a model with sliding-window latent layers keeps a pool "
+                "and a table by kind of layer, its ring sized by the "
+                "mixed step's window: pass width, or call "
+                "WindowedPagedCache.create")
+        return paged.WindowedPagedCache.create(
+            c, slots, n_pages, page_size, max_seq_len,
+            c.window_ring_pages(page_size, width), dtype=dtype)
+    return PagedKVCache.zeros(
+        (c.num_hidden_layers, n_pages, page_size, c.latent_row),
+        (len(c.full_layers), n_pages, page_size, c.index_head_dim),
+        slots, max_seq_len // page_size, dtype)
+
+
+def _resolve_attn(config, impl: str, *, prefill_chunk, max_seq_len: int,
+                  **_shapes):
+    """One impl for both step kinds: the selected rows are gathered in
+    XLA and attended by cake_mla_attn (pallas) or the XLA fold; its VMEM
+    does not depend on the mixed width. 512 is the widest window whose
+    gathered rows (width x index_topk x latent row) stay near a
+    gigabyte."""
+    return impl, prefill_chunk or min(512, max_seq_len)
+
+
+_DECODE_PROGRAMS = make_decode_scan(forward_ragged_latent)
+_MIXED_SAMPLED = make_mixed_sampled(mixed_step_latent)
+
+
+def _family(name: str, counters: tuple, beside=None) -> Family:
+    pool = f"the latent page pool ({name})"
+    return Family(
+        name=name, decode_step=decode_step_latent,
+        decode_programs=_DECODE_PROGRAMS, mixed_step=mixed_step_latent,
+        mixed_sampled=_MIXED_SAMPLED, create_cache=create_cache,
+        counters=counters,
+        # one window a dispatch (module docstring), so one packed size
+        prefill_rows=(1,), windows=Windows.DISPATCH, beside=beside,
+        impl="paged-dsa-", resolve_attn=_resolve_attn, kernel_rows=(),
+        what="latent attention over the page pool",
+        refuses=cannot_move(
+            "latent row and index key",
+            register_prefix=(
+                f"{pool} has no prefix pages yet: a shared head would "
+                "need its latent rows and its index keys (and a windowed "
+                "model's ring) mapped together (ROADMAP.md)"),
+            reconfigure=(
+                f"{pool} serves on pages only: there is no dense or "
+                "quantized pool to switch to")))
+
+
+FAMILY = _family("glm_moe_dsa", COUNTERS)
+WINDOWED = _family("dots3_note", COUNTERS + SWA_COUNTERS,
+                   ("window pool (a ring a row)", None))

@@ -53,10 +53,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from cake_tpu.models.family import Family, Windows, cannot_move
 from cake_tpu.models.llama import paged
 from cake_tpu.models.llama.paged import HybridPagedCache, write_token_rows
 from cake_tpu.models.moe.config import NemotronHConfig
 from cake_tpu.models.moe.glm_dsa import _window_slice
+from cake_tpu.models.step_programs import (
+    make_decode_scan, make_mixed_sampled,
+)
+from cake_tpu.ops import ragged_paged_attention as rpa
 from cake_tpu.ops.moe import LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import QTensor, qmatmul
@@ -67,9 +72,13 @@ ATTN_LEAVES = ("wq", "wk", "wv", "wo")
 SPARSE_LEAVES = ("router", "router_bias", "w_fc1", "w_fc2", "ws_up",
                  "ws_down")
 EXPERT_LEAVES = ("we_up", "we_down")
-# a step program returns obs/steps.SSM_LAYOUT, in that order: the expert
-# counters' five, the routed rows, then the recurrent state's four
-N_COUNTERS = 10
+# the record keys of the vector a step program returns, in trunk's
+# order: the expert counters' five, the routed rows, then the rows'
+# recurrent state and the two forms of the scan
+COUNTERS = paged.MOE_COUNTERS + (
+    "moe_rows_routed", "ssm_state_rows", "ssm_tokens_scanned",
+    "ssm_tokens_stepped", "ssm_state_resets")
+N_COUNTERS = len(COUNTERS)
 # queries a sub-window of the mixed attention kernel holds
 ATTN_SUBWINDOW = 128
 F32 = jnp.float32
@@ -499,7 +508,7 @@ def forward_ragged_hybrid(params, tokens, cache: HybridPagedCache, pos,
                           active, rope, config: NemotronHConfig,
                           attn: str = "fold"):
     """paged.forward_ragged_paged(..., counters=True)'s contract: what
-    serve.engine.make_decode_scan builds the sampled decode programs
+    step_programs.make_decode_scan builds the sampled decode programs
     from -> (logits [B, V], cache, counters)."""
     del rope
     out = decode_trunk(params, tokens, cache, pos, active, config, attn)
@@ -516,3 +525,84 @@ def decode_step_hybrid(params, tokens, pos, active, cache: HybridPagedCache,
     decode step)."""
     return forward_ragged_hybrid(params, tokens, cache, pos, active, rope,
                                  config, attn)
+
+
+# -- what the engine reads of this family (models/family.py) ----------------
+
+
+def create_cache(config: NemotronHConfig, slots: int, n_pages: int,
+                 page_size: int, max_seq_len: int, width, dtype):
+    """K/V pages for the attention blocks alone, and a state a ROW for
+    each Mamba block: the SSM state and the last conv_kernel - 1 inputs
+    of its causal conv."""
+    c = config
+    L_M = len(c.mamba_layers)
+    return HybridPagedCache.zeros(
+        (len(c.attn_layers), n_pages, page_size,
+         c.num_key_value_heads * c.head_dim),
+        slots, max_seq_len // page_size, dtype,
+        ssm=(L_M, slots, c.mamba_num_heads, c.mamba_head_dim,
+             c.ssm_state_size),
+        conv=(L_M, slots, c.conv_kernel - 1, c.conv_dim))
+
+
+def _resolve_attn(config, impl: str, *, explicit: bool, prefill_chunk,
+                  slots: int, n_pages: int, page_size: int,
+                  max_seq_len: int, q_itemsize: int, kv_itemsize: int):
+    """One impl for both step programs: the mixed program runs the
+    decode kernel over the rows' single tokens and the mixed kernel
+    over the window in sub-windows (what its VMEM holds), so its gate
+    is asked at the sub-window."""
+    c = config
+    width = prefill_chunk or min(512, max_seq_len)
+    if width > ATTN_SUBWINDOW and width % ATTN_SUBWINDOW:
+        raise ValueError(
+            f"--prefill-chunk {width}: model_type nemotron_h takes a "
+            f"window of at most {ATTN_SUBWINDOW} tokens or a multiple of "
+            f"{ATTN_SUBWINDOW}")
+    heads = (c.num_attention_heads, c.num_key_value_heads, c.head_dim)
+    max_pages = -(-max_seq_len // page_size)
+    ok = (rpa.ragged_paged_supported(
+              page_size, *heads, n_pages=n_pages, slots=slots,
+              max_pages=max_pages)
+          and rpa.ragged_paged_mixed_supported(
+              page_size, *heads, min(width, ATTN_SUBWINDOW),
+              n_pages=n_pages, slots=-(-width // ATTN_SUBWINDOW),
+              max_pages=max_pages, q_itemsize=q_itemsize,
+              kv_itemsize=kv_itemsize))
+    if impl == "pallas" and not ok:
+        if explicit:
+            raise ValueError(
+                "--paged-attn pallas cannot serve model_type nemotron_h "
+                f"on this device at page={page_size} heads={heads} mixed "
+                f"width={width} (ops/ragged_paged_attention gates); use "
+                "--paged-attn auto or fold")
+        impl = "fold"
+    return impl, width
+
+
+FAMILY = Family(
+    name="nemotron_h", decode_step=decode_step_hybrid,
+    decode_programs=make_decode_scan(forward_ragged_hybrid),
+    mixed_step=mixed_step_hybrid,
+    mixed_sampled=make_mixed_sampled(mixed_step_hybrid),
+    create_cache=create_cache, counters=COUNTERS,
+    # one window a dispatch (module docstring), so one packed size; and
+    # one a step, which is what kept this family's callers out of
+    # convoys (family.Windows)
+    prefill_rows=(1,), windows=Windows.STEP,
+    beside=("recurrent state", "ssm_state_bytes"),
+    impl="paged-ssm-", resolve_attn=_resolve_attn,
+    # the mixed program hands cake_mixed_attn the window alone, in
+    # sub-windows
+    kernel_rows=("decode",),
+    what="a recurrent state a row beside the page pool",
+    refuses=cannot_move(
+        "state",
+        register_prefix=(
+            "a recurrent state (nemotron_h) has no prefix reuse yet: a "
+            "shared head would need the state snapshotted at its last "
+            "page's edge (ROADMAP.md)"),
+        reconfigure=(
+            "a recurrent state (nemotron_h) lives beside the page pool: "
+            "a rebuilt pool cannot replay it")))

@@ -51,10 +51,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from cake_tpu.models.family import Family, cannot_move
 from cake_tpu.models.llama import paged
 from cake_tpu.models.llama.paged import HybridPagedCache
 from cake_tpu.models.moe.config import ZayaConfig
 from cake_tpu.models.moe.nemotron_h import Rows, dequantized
+from cake_tpu.models.step_programs import (
+    make_decode_scan, make_mixed_sampled,
+)
 from cake_tpu.ops.moe import EXPERT_LEAVES, LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import qmatmul
@@ -62,6 +66,10 @@ from cake_tpu.ops.rope import apply_rope
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
+# the record keys of the vector a step program returns, in trunk's
+# order: the expert counters' five, then the tails read and the choices
+# the router's bias changed
+COUNTERS = paged.MOE_COUNTERS + ("cca_tail_rows", "router_choice_by_bias")
 
 
 def split_cca(w_cca, config: ZayaConfig):
@@ -175,9 +183,9 @@ def scaled_sum(res, x, y):
 
 class TrunkOut(NamedTuple):
     """x [T, D] after the final norm; cache; counters in the order of
-    obs/steps.CCA_LAYOUT (the expert counters' five, then the tails read
-    and the choices the bias changed); and each layer's routing [L, T, k], for a tool that compares it with
-    the reference's (chip_compare.py; a step program drops it)."""
+    COUNTERS; and each layer's routing [L, T, k], for a tool that
+    compares it with the reference's (chip_compare.py; a step program
+    drops it)."""
 
     x: jnp.ndarray
     cache: HybridPagedCache
@@ -331,7 +339,7 @@ def decode_trunk(params, tokens, cache: HybridPagedCache, pos, active, rope,
 def forward_ragged_cca(params, tokens, cache: HybridPagedCache, pos, active,
                        rope, config: ZayaConfig, attn: str = "fold"):
     """paged.forward_ragged_paged(..., counters=True)'s contract: what
-    serve.engine.make_decode_scan builds the sampled decode programs
+    step_programs.make_decode_scan builds the sampled decode programs
     from -> (logits [B, V], cache, counters)."""
     out = decode_trunk(params, tokens, cache, pos, active, rope, config,
                        attn)
@@ -348,3 +356,43 @@ def decode_step_cca(params, tokens, pos, active, cache: HybridPagedCache,
     decode step)."""
     return forward_ragged_cca(params, tokens, cache, pos, active, rope,
                               config, attn)
+
+
+# -- what the engine reads of this family (models/family.py) ----------------
+
+
+def create_cache(config: ZayaConfig, slots: int, n_pages: int,
+                 page_size: int, max_seq_len: int, width, dtype):
+    """Pages and a conv tail a row in EVERY layer, no recurrent
+    state."""
+    c = config
+    L = c.num_hidden_layers
+    return HybridPagedCache.zeros(
+        (L, n_pages, page_size, c.num_key_value_heads * c.head_dim),
+        slots, max_seq_len // page_size, dtype, ssm=None,
+        conv=(L, slots, max(c.cca_time0, c.cca_time1) - 1,
+              c.cca_tail_width))
+
+
+# The taps are gathers along the packed axis, so a dispatch holds two
+# prefilling rows as the dense path's does, and K and V go through the
+# GQA kernels as any GQA model's (the engine's own rule resolves them).
+# ONE packed size, the two-window one: two programs of different shape
+# round differently, and the choice of one expert of 16 is discrete, so
+# with one program a row's bits do not depend on its company.
+FAMILY = Family(
+    name="zaya", decode_step=decode_step_cca,
+    decode_programs=make_decode_scan(forward_ragged_cca),
+    mixed_step=mixed_step_cca,
+    mixed_sampled=make_mixed_sampled(mixed_step_cca),
+    create_cache=create_cache, counters=COUNTERS, prefill_rows=(2,),
+    beside=("conv tails", "cca_tail_bytes"), impl="paged-cca-",
+    what="a conv tail a row beside the page pool",
+    refuses=cannot_move(
+        "tail",
+        register_prefix=(
+            "a conv tail (zaya) has no prefix reuse yet: a shared head "
+            "has pages but no tail at its last page's edge (ROADMAP.md)"),
+        reconfigure=(
+            "a conv tail (zaya) lives beside the page pool: a rebuilt "
+            "pool cannot replay it")))

@@ -215,11 +215,16 @@ _DECODE_ATTN_PAGES_TABLE = _m.counter(
     "what a kernel that stepped through the whole table would visit "
     "(over it, cake_decode_attn_pages_total is the share that holds "
     "work)")
+# The step programs' counters: (record key, series) by the group a
+# family's trunk returns them in. The ORDER of a program's vector is
+# the trunk's and is stated beside it (a family's `counters`,
+# models/family.py); COUNTER_SERIES below maps key -> series once, and a
+# recorder is given its engine's keys (StepTelemetry(counters=)).
+#
 # sparse-expert counters, computed in the step program from the group
 # sizes its grouped matmuls walk (ops/moe.MoEStats, summed or averaged
 # over the layers by paged.scan_layers_paged_stats) and fetched with the
-# sampled tokens; absent for a dense model. (record key, series), in the
-# order of the step program's vector.
+# sampled tokens; absent for a dense model
 MOE_COUNTERS = (
     ("moe_rows", _m.counter(
         "cake_moe_rows_total",
@@ -241,9 +246,8 @@ MOE_COUNTERS = (
         "weights the steps had to read")),
 )
 # a step program whose layers hold a SHARE of their router's experts,
-# or select their keys (glm_moe_dsa: models/moe/glm_dsa.trunk), returns
-# these after the five above; the rows above then count the held
-# experts' rows alone
+# or select their keys (models/moe/glm_dsa.trunk); the rows above then
+# count the held experts' rows alone
 DSA_COUNTERS = (
     ("moe_rows_routed", _m.counter(
         "cake_moe_rows_routed_total",
@@ -271,11 +275,8 @@ DSA_COUNTERS = (
         "Attention layers that reused the set of the layer below, "
         "summed over dispatches")),
 )
-STEP_COUNTERS = MOE_COUNTERS + DSA_COUNTERS
-# a step program of a model with recurrent blocks (nemotron_h:
-# models/moe/nemotron_h.trunk) returns the expert counters' five, the
-# routed rows, then these: the rows' recurrent state and the two forms
-# of the scan
+# a model with recurrent blocks (models/moe/nemotron_h.trunk): the rows'
+# recurrent state and the two forms of the scan
 SSM_COUNTERS = (
     ("ssm_state_rows", _m.counter(
         "cake_ssm_state_rows_total",
@@ -294,15 +295,13 @@ SSM_COUNTERS = (
         "Rows whose recurrent state a step zeroed: a request took the "
         "slot")),
 )
-SSM_LAYOUT = MOE_COUNTERS + DSA_COUNTERS[:1] + SSM_COUNTERS
 SSM_STATE_BYTES = _m.gauge(
     "cake_ssm_state_bytes",
     "Bytes of the rows' recurrent state beside the page pool (SSM state "
     "and conv tails, every Mamba block, every slot)")
 
 
-# a step program of a model with convolutions inside attention (zaya:
-# models/moe/zaya.trunk) returns the expert counters' five, then these
+# a model with convolutions inside attention (models/moe/zaya.trunk)
 CCA_COUNTERS = (
     ("cca_tail_rows", _m.counter(
         "cake_cca_tail_rows_total",
@@ -313,16 +312,15 @@ CCA_COUNTERS = (
         "(token, layer) choices of an expert that the router's "
         "balancing bias changed (0 where nothing reads the bias)")),
 )
-CCA_LAYOUT = MOE_COUNTERS + CCA_COUNTERS
 CCA_TAIL_BYTES = _m.gauge(
     "cake_cca_tail_bytes",
     "Bytes of the rows' conv tails beside the page pool (every layer, "
     "every slot)")
 
 
-# a step program of a model with sliding-window latent layers beside
-# its full ones (dots3_note: models/moe/glm_dsa.trunk) returns
-# STEP_COUNTERS, whose dsa_* then count the FULL layers alone, then these
+# a model with sliding-window latent layers beside its full ones
+# (models/moe/glm_dsa.trunk), whose dsa_* then count the FULL layers
+# alone
 SWA_COUNTERS = (
     ("swa_keys_visible", _m.counter(
         "cake_swa_keys_visible_total",
@@ -336,18 +334,11 @@ SWA_COUNTERS = (
         "cake_swa_layers_total",
         "Sliding-window layers run, summed over dispatches")),
 )
-SWA_LAYOUT = STEP_COUNTERS + SWA_COUNTERS
-
-
-def counter_layout(n: int) -> tuple:
-    """The (record key, series) of a step program's counter vector, by
-    its length: a sparse model's five, a zaya model's seven, a
-    glm_moe_dsa model's eleven, a nemotron_h model's ten, a dots3_note
-    model's fourteen."""
-    for layout in (SSM_LAYOUT, CCA_LAYOUT, SWA_LAYOUT):
-        if n == len(layout):
-            return layout
-    return STEP_COUNTERS[:n]
+COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
+                      + CCA_COUNTERS + SWA_COUNTERS)
+# what a family's cache keeps beside the page pool (family.Beside.gauge)
+BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
+                     "cca_tail_bytes": CCA_TAIL_BYTES}
 
 
 def refresh_page_gauges(engine) -> None:
@@ -611,10 +602,8 @@ class StepRecord:
     # on-device carry while that step was still in flight
     chained: Optional[bool] = None
     # the step programs' counters since the previous record that
-    # carried them, in the order of counter_layout (a sparse model's
-    # five, a zaya model's seven, a glm_moe_dsa model's eleven, a
-    # nemotron_h model's ten)
-    moe: Optional[Tuple[float, ...]] = None
+    # carried them, by record key, in the order of the programs' vector
+    moe: Optional[Dict[str, float]] = None
     # a step that is not chained: why the chain before it ended (one of
     # BREAKS; absent where no chain ended or the loop never waited: the
     # engine's first step), and the rows admitted since the record
@@ -684,9 +673,7 @@ class StepRecord:
             out["fetch_wait_s"] = round(self.fetch_wait_s, 6)
             out["late"] = self.late
         if self.moe is not None:
-            for (key, _series), v in zip(counter_layout(len(self.moe)),
-                                         self.moe):
-                out[key] = round(v, 3)
+            out.update((key, round(v, 3)) for key, v in self.moe.items())
         return out
 
 
@@ -807,7 +794,9 @@ class StepTelemetry:
     shared obs/jsonl.py durability semantics). key_prefix namespaces
     the accountant keys so engines with different configs cannot alias
     each other's signatures. peak_flops/hbm_bps override the
-    device-kind tables (tests pin them for exact MFU math)."""
+    device-kind tables (tests pin them for exact MFU math). counters:
+    the record keys of the vector the engine's step programs return, in
+    its order (its family's `counters`; each has a COUNTER_SERIES)."""
 
     # cakelint guards discipline: the event bus is an optional plane
     OPTIONAL_PLANES = ("_events",)
@@ -818,8 +807,13 @@ class StepTelemetry:
                  peak_flops: Optional[float] = None,
                  hbm_bps: Optional[float] = None,
                  accountant: Optional[JitAccountant] = None,
-                 events=None):
+                 events=None, counters: Sequence[str] = ()):
         self.impl = impl
+        self._counters = tuple(counters)
+        unknown = [k for k in self._counters if k not in COUNTER_SERIES]
+        if unknown:
+            raise ValueError(f"no series for the counters {unknown} "
+                             "(obs/steps.COUNTER_SERIES)")
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=max(1, int(capacity)))
         self._next = 1
@@ -1069,7 +1063,8 @@ class StepTelemetry:
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
-                moe=(tuple(float(v) for v in moe)
+                moe=(dict(zip(self._counters, map(float, moe),
+                              strict=True))
                      if moe is not None else None),
                 chain_break=cause, rows_admitted=admitted,
                 parts=parts or None, fetch_wait_s=fetch_wait_s,
@@ -1098,9 +1093,8 @@ class StepTelemetry:
             _DECODE_ATTN_PAGES.inc(attn_pages)
             _DECODE_ATTN_PAGES_TABLE.inc(attn_pages_table)
         if moe is not None:
-            for (_key, series), v in zip(counter_layout(len(rec.moe)),
-                                         rec.moe):
-                series.inc(v)
+            for key, v in rec.moe.items():
+                COUNTER_SERIES[key].inc(v)
         if mfu is not None:
             _STEP_MFU.labels(kind=kind).set(_sig(mfu))
         if hbm is not None:

@@ -776,7 +776,7 @@ def make_sp_engine_step_fns(mesh: Mesh, config: LlamaConfig,
 
     Returns (prefill_slot_fn, decode_ragged_fn, decode_scan_fn): the
     same signatures as model.prefill_slot / decode_step_ragged /
-    engine.make_decode_scan's product, over an SPEngineCache.
+    step_programs.make_decode_scan's product, over an SPEngineCache.
 
     Unlike the batch-1 SPGeneratorForward (whose tail positions start at
     ctx_len, leaving a documented rope gap for short prompts), the
@@ -839,7 +839,7 @@ def make_sp_engine_step_fns(mesh: Mesh, config: LlamaConfig,
     prefill_slot_fn = make_slot_prefill_fn(prefill_sm, ctx_len,
                                            mode=mode)
 
-    from cake_tpu.serve.engine import make_decode_scan
+    from cake_tpu.models.step_programs import make_decode_scan
     return instrument_sp_engine(
         (prefill_slot_fn, decode_ragged_fn,
          make_decode_scan(decode_ragged_forward)),

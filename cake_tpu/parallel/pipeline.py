@@ -331,7 +331,7 @@ def make_engine_step_fns(mesh: Mesh, config: LlamaConfig,
                                        rope, config)
         return jax.lax.with_sharding_constraint(logits, logits_repl), cache
 
-    from cake_tpu.serve.engine import make_decode_scan
+    from cake_tpu.models.step_programs import make_decode_scan
     decode_scan_fn = make_decode_scan(ragged_forward,
                                       out_sharding=logits_repl)
 

@@ -318,7 +318,7 @@ def make_sp_stage_engine_step_fns(mesh: Mesh, config: LlamaConfig,
     prefill_slot_fn = make_slot_prefill_fn(prefill_sm, ctx_len,
                                            mode=mode)
 
-    from cake_tpu.serve.engine import make_decode_scan
+    from cake_tpu.models.step_programs import make_decode_scan
     # shared instrumentation tail: every step fn dispatch-counted and
     # wall-timed (cake_sp_dispatch_total/_seconds{op,mode}), identical
     # to the plain-sp factory so the two modes' metrics cannot drift

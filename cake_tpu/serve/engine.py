@@ -50,13 +50,15 @@ from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import (
     bucket_length, encode_text, incremental_decode,
 )
+from cake_tpu.models.family import Windows
 from cake_tpu.models.llama.model import (
     RopeTables, decode_step_ragged, prefill_slot, prefill_slot_prefixed,
 )
-from cake_tpu.models.llama.paged import mixed_bucket_for, mixed_step_paged
-from cake_tpu.ops.sampling import (
-    SamplingConfig, sample_tokens_ragged, update_ring_per_row,
+from cake_tpu.models.llama.paged import mixed_bucket_for
+from cake_tpu.models.step_programs import (
+    ROW_ACTIVE, ROW_FROM_CARRY, ROW_SAMPLE, _masked_sample, make_decode_scan,
 )
+from cake_tpu.ops.sampling import SamplingConfig
 from cake_tpu.sched import (
     SchedConfig, ShedController, ShedError, make_scheduler,
 )
@@ -689,83 +691,22 @@ class InferenceEngine:
         # step kind -> the paged attention that actually runs for it
         # ({"decode", "mixed"[, "spec"]} -> fold|pallas); empty = dense
         self.attn_impl: dict = {}
-        # latent attention (glm_moe_dsa): one latent row a token in the
-        # page pool, the sparse indexer's keys beside it
-        # (models/moe/glm_dsa.py). What the latent pool does not serve
-        # yet is refused here, by the option's name, never ignored.
-        self._latent = bool(getattr(config, "kv_lora_rank", None))
-        # ... and, where some layers attend a sliding window, their rows
-        # in a pool of their own behind a ring table a row
-        # (models/llama/paged.WindowedPagedCache)
-        self._windowed = self._latent and bool(config.sliding_layers)
-        if self._latent:
-            refused = [name for name, on in (
-                ("serving without --kv-pages (the dense-slot engine)",
-                 not self.paged),
-                ("a topology / --tp / --sp (pipeline and tensor "
-                 "parallelism)", step_fns is not None),
-                ("--draft-model", self._spec),
-                ("--spec-draft", self._spec_paged),
-                ("--kv-dtype int8/int4 (quantized pages)", self.kv_quant),
-                ("--kv-host-pages (host spill)", kv_host_pages is not None),
-                ("--disagg (the prefill shipment)", disagg is not None),
-                ("--auto-prefix (prefix pages)", auto_prefix_system),
-            ) if on]
-            if refused:
-                raise ValueError(
-                    f"model_type {config.hf_layout} (latent attention "
-                    "over the page pool) does not serve yet: "
-                    + "; ".join(refused)
-                    + " (ROADMAP.md lists each as left to do)")
-        # recurrent blocks (nemotron_h): a state a ROW beside the page
-        # pool (models/llama/paged.HybridPagedCache), carried by the
-        # step programs. What moves pages today and cannot move a state
-        # yet is refused here, by the option's name, never ignored.
-        # Convolutions inside attention (zaya) keep a conv tail a ROW in
-        # the same cache object, with the same lifecycle: the same
-        # options move pages and cannot move a tail.
-        self._recurrent = bool(getattr(config, "mamba_layers", None))
-        self._cca = bool(getattr(config, "cca_time0", None))
-        if self._recurrent or self._cca:
-            family, what = (
-                ("nemotron_h (a recurrent state a row beside the page "
-                 "pool)", "state") if self._recurrent else
-                ("zaya (a conv tail a row beside the page pool)", "tail"))
-            refused = [name for name, on in (
-                ("serving without --kv-pages (the dense-slot engine)",
-                 not self.paged),
-                ("a topology / --tp / --sp (pipeline and tensor "
-                 "parallelism)", step_fns is not None),
-                ("--draft-model (a rejected draft has advanced the "
-                 f"{what})", self._spec),
-                ("--spec-draft (a rejected draft has advanced the "
-                 f"{what})", self._spec_paged),
-                ("--kv-dtype int8/int4 (quantized pages)", self.kv_quant),
-                ("--kv-host-pages (host spill and preempt-and-restore: "
-                 f"a {what} has no pages to spill)",
-                 kv_host_pages is not None),
-                ("--disagg (the prefill shipment carries pages, not a "
-                 f"{what})", disagg is not None),
-                ("--auto-prefix (prefix pages: a shared head has pages "
-                 f"but no {what} at its edge)", auto_prefix_system),
-            ) if on]
-            if refused:
-                raise ValueError(
-                    f"model_type {family} does not serve yet: "
-                    + "; ".join(refused)
-                    + " (ROADMAP.md lists each as left to do)")
-        # one window (a row of several tokens) a mixed dispatch
-        self._one_window = self._latent or self._recurrent
-        # ... and, beside a recurrent state, one window a STEP: the
-        # prompts mid-prefill take their windows in the order they were
-        # admitted, one a step, so every step is ONE dispatch that
-        # every decode row rides. With a window a dispatch and every
-        # prompt in every step, a step of k prompts is k dispatches of
-        # which a decode row rides one: rows admitted together stayed
-        # together, and the cell's 32 callers moved in convoys of
-        # seconds of prefill, then seconds of decode (PERF.md §6, PR
-        # 33). The latent pool keeps its steps as they were measured.
-        self._window_a_step = self._recurrent
+        # what the engine reads of the model's family (models/family.py):
+        # its step programs, its cache, its counters, and what its rows
+        # cannot move yet, which is refused here by the option's name,
+        # never ignored
+        self._family = family = config.family
+        refusal = family.refusal({
+            "--kv-pages": not self.paged,
+            "topology": step_fns is not None,
+            "--draft-model": self._spec,
+            "--spec-draft": self._spec_paged,
+            "--kv-dtype": self.kv_quant,
+            "--kv-host-pages": kv_host_pages is not None,
+            "--disagg": disagg is not None,
+            "--auto-prefix": auto_prefix_system})
+        if refusal:
+            raise ValueError(refusal)
         if self.paged:
             if step_fns is not None or self.ring or self._spec:
                 raise ValueError(
@@ -926,7 +867,7 @@ class InferenceEngine:
             impl=flavor, capacity=step_ring, log_path=step_log,
             key_prefix=(config, max_slots, max_seq_len,
                         str(self._cache_dtype), flavor),
-            events=self.events)
+            events=self.events, counters=family.counters)
         # latest dispatch's _JitStep (engine-thread-only mailbox between
         # the device-call seam and the step record that follows it)
         self._last_jit = None
@@ -1736,21 +1677,8 @@ class InferenceEngine:
                           "aligned with the target, and a prefix-cached "
                           "target prefill would leave the draft cold "
                           "(acceptance would silently collapse)")
-            elif self._latent:
-                reason = (f"the latent page pool ({self.config.hf_layout}) "
-                          "has no prefix pages yet: a shared head would "
-                          "need its latent rows and its index keys (and a "
-                          "windowed model's ring) mapped together "
-                          "(ROADMAP.md)")
-            elif self._recurrent:
-                reason = ("a recurrent state (nemotron_h) has no prefix "
-                          "reuse yet: a shared head would need the "
-                          "state snapshotted at its last page's edge "
-                          "(ROADMAP.md)")
-            elif self._cca:
-                reason = ("a conv tail (zaya) has no prefix reuse yet: "
-                          "a shared head has pages but no tail at its "
-                          "last page's edge (ROADMAP.md)")
+            elif not self._family.moves("register_prefix"):
+                reason = self._family.refuses["register_prefix"]
             elif self.ring:
                 reason = ("ring sliding-window caches own their layout "
                           "(a prefix install writes dense positions the "
@@ -2691,8 +2619,8 @@ class InferenceEngine:
         self._kv_dtype_name/self._base_cache_dtype/
         self.prefill_chunk already set."""
         from cake_tpu.models.llama.paged import (
-            PageAllocator, PagedKVCache, decode_step_ragged_paged,
-            mixed_token_buckets, prefill_prefix_pages, prefill_slot_paged,
+            PageAllocator, PagedKVCache, mixed_token_buckets,
+            prefill_prefix_pages, prefill_slot_paged,
         )
         if kv_pages < 1 or kv_page_size < 1:
             raise ValueError(
@@ -2713,74 +2641,39 @@ class InferenceEngine:
         self._pool_dtype = pool_dtype
         self._resolve_paged_attn(paged_attn, kv_pages, kv_page_size)
         impl = self.attn_impl["decode"]
+        # the family's step programs, behind the signatures they share
+        # (models/family.py). Token-level continuous batching: ONE
+        # jitted step consumes a batch of (row kind, pos, q_len)
+        # descriptors — decode rows and prefill-chunk rows in the same
+        # launch — and samples (make_mixed_sampled), so that a step can
+        # be kept in flight
+        family = self._family
+        self._decode_step = partial(family.decode_step, attn=impl)
+        self._decode_scan_impl = partial(family.decode_programs, attn=impl)
+        self._mixed_step_fn = partial(family.mixed_sampled,
+                                      attn=self.attn_impl["mixed"])
         # whole-prompt prefill into one slot's pages: the paged spec's
         # draft prefill (_spec_activate); requests' prompts ride the
-        # mixed step
-        self._prefill_slot = partial(prefill_slot_paged, attn=impl)
-        self._decode_step = partial(decode_step_ragged_paged, attn=impl)
-        self._decode_scan_impl = (_decode_scan_paged if impl == "fold"
-                                  else _decode_scan_paged_pallas)
-        # page-granular prefix sharing: registered prefixes (and
-        # auto_prefix_system heads) prefill ONCE into pool pages and
-        # are mapped read-only into every matching slot's table row
-        # (_alloc_slot_pages). _prefix_capable stays True.
-        self._prefix_pages_step = partial(prefill_prefix_pages,
-                                          attn=impl)
-        # token-level continuous batching: ONE jitted step consumes a
-        # batch of (row kind, pos, q_len) descriptors — decode rows
-        # and prefill-chunk rows in the same launch — and samples
-        # (make_mixed_sampled), so that a step can be kept in flight
-        self._mixed_step_fn = partial(_mixed_sampled_paged,
-                                      attn=self.attn_impl["mixed"])
+        # mixed step. Page-granular prefix sharing: registered prefixes
+        # (and auto_prefix_system heads) prefill ONCE into pool pages
+        # and are mapped read-only into every matching slot's table row
+        # (_alloc_slot_pages). Both programs are the GQA pool's: rows
+        # that hold more than K/V pages have neither, and no prefix
+        # pages.
+        if family.moves("register_prefix"):
+            self._prefill_slot = partial(prefill_slot_paged, attn=impl)
+            self._prefix_pages_step = partial(prefill_prefix_pages,
+                                              attn=impl)
+        else:
+            self._prefix_capable = False
+            self._prefill_slot = self._prefix_pages_step = None
         # the packed sizes a mixed step's dispatches run at (the
         # program's static n_tokens): _mixed_burst takes the smallest
         # that holds the tokens, start() runs each once so that none
         # compiles later
-        self._mixed_buckets = mixed_token_buckets(self.max_slots,
-                                                  self._mixed_chunk)
-        if self._latent:
-            # the latent step programs, behind the same signatures; one
-            # window a dispatch, so one packed size (models/moe/glm_dsa)
-            from cake_tpu.models.moe.glm_dsa import decode_step_latent
-            self._decode_step = partial(decode_step_latent, attn=impl)
-            self._decode_scan_impl = (_decode_scan_latent if impl == "fold"
-                                      else _decode_scan_latent_pallas)
-            self._mixed_step_fn = partial(_mixed_sampled_latent, attn=impl)
-            self._mixed_buckets = mixed_token_buckets(
-                self.max_slots, self._mixed_chunk, prefill_rows=(1,))
-            # no prefix pages, no whole-prompt prefill program
-            self._prefix_capable = False
-            self._prefill_slot = self._prefix_pages_step = None
-        if self._recurrent:
-            # the hybrid step programs, behind the same signatures; one
-            # window a dispatch, so one packed size (models/moe/nemotron_h)
-            from cake_tpu.models.moe.nemotron_h import decode_step_hybrid
-            self._decode_step = partial(decode_step_hybrid, attn=impl)
-            self._decode_scan_impl = (_decode_scan_hybrid if impl == "fold"
-                                      else _decode_scan_hybrid_pallas)
-            self._mixed_step_fn = partial(_mixed_sampled_hybrid, attn=impl)
-            self._mixed_buckets = mixed_token_buckets(
-                self.max_slots, self._mixed_chunk, prefill_rows=(1,))
-            self._prefix_capable = False
-            self._prefill_slot = self._prefix_pages_step = None
-        if self._cca:
-            # the CCA step programs, behind the same signatures; the
-            # taps are gathers along the packed axis, so a dispatch
-            # holds two prefilling rows as the dense path's does
-            # (models/moe/zaya). ONE packed size, the two-window one:
-            # two programs of different shape round differently, and
-            # the choice of one expert of 16 is discrete, so with one
-            # program a row's bits do not depend on its company
-            from cake_tpu.models.moe.zaya import decode_step_cca
-            self._mixed_buckets = mixed_token_buckets(
-                self.max_slots, self._mixed_chunk, prefill_rows=(2,))
-            self._decode_step = partial(decode_step_cca, attn=impl)
-            self._decode_scan_impl = (_decode_scan_cca if impl == "fold"
-                                      else _decode_scan_cca_pallas)
-            self._mixed_step_fn = partial(_mixed_sampled_cca,
-                                          attn=self.attn_impl["mixed"])
-            self._prefix_capable = False
-            self._prefill_slot = self._prefix_pages_step = None
+        self._mixed_buckets = mixed_token_buckets(
+            self.max_slots, self._mixed_chunk,
+            prefill_rows=family.prefill_rows)
         self._pager = PageAllocator(kv_pages, kv_page_size)
         self._slot_pages = {}
         # slot -> count of SHARED prefix pages in its table row (gauge
@@ -2789,36 +2682,14 @@ class InferenceEngine:
         self._slot_prefix_pages = {}
         self._prefix_pages_shared = 0
         self._prefix_last_hit = {}
-        if self.kv_quant:
-            from cake_tpu.kv import Int4PagedKVCache, QuantizedPagedKVCache
-            qcls = (Int4PagedKVCache if self._kv_dtype_name == "int4"
-                    else QuantizedPagedKVCache)
-            self.cache = qcls.create(
-                self.config, self.max_slots, kv_pages, kv_page_size,
-                self.max_seq_len)
-        elif self._windowed:
-            self.cache = self._fresh_windowed_cache(kv_pages, kv_page_size)
-            log.info("window pool: %d sliding layers x %d pages (%d a "
-                     "row, %d rows), %.2f GiB beside the pool",
-                     len(self.config.sliding_layers),
-                     self.cache.n_window_pages, self.cache.ring_pages,
-                     self.max_slots, self.cache.window_bytes() / 2**30)
-        else:
-            self.cache = PagedKVCache.create(
-                self.config, self.max_slots, kv_pages, kv_page_size,
-                self.max_seq_len, dtype=pool_dtype)
-        if self._recurrent:
-            from cake_tpu.obs.steps import SSM_STATE_BYTES
-            SSM_STATE_BYTES.set(self.cache.state_bytes())
-            log.info("recurrent state: %d Mamba blocks x %d rows, %.2f GiB "
-                     "beside the pool", len(self.config.mamba_layers),
-                     self.max_slots, self.cache.state_bytes() / 2**30)
-        if self._cca:
-            from cake_tpu.obs.steps import CCA_TAIL_BYTES
-            CCA_TAIL_BYTES.set(self.cache.state_bytes())
-            log.info("conv tails: %d layers x %d rows, %.1f MiB beside "
-                     "the pool", self.config.num_hidden_layers,
-                     self.max_slots, self.cache.state_bytes() / 2**20)
+        self.cache = self._fresh_pool(kv_pages, kv_page_size)
+        if family.beside is not None:
+            what, gauge = family.beside
+            beside = self.cache.beside_bytes()
+            if gauge is not None:
+                obs_steps.BESIDE_POOL_BYTES[gauge].set(beside)
+            log.info("%s: %.2f GiB beside the pool, %d rows", what,
+                     beside / 2**30, self.max_slots)
         log.info("paged KV: %d pages x %d tokens, %s attention, "
                  "%s storage (%.2f GiB pool; dense %d-slot "
                  "equivalent would be %.2f GiB)",
@@ -2883,17 +2754,20 @@ class InferenceEngine:
                      self.d_cache.memory_bytes() / 2**30,
                      self._specp.live_gamma)
 
-    def _fresh_windowed_cache(self, kv_pages: int, kv_page_size: int):
-        """The pools by kind of layer. Slot i owns a ring of the window
-        pool for good (WindowedPagedCache.create maps it), so that pool
-        is slots x ring whatever max_seq_len, and admission, release
-        and a rebuild never touch it."""
-        from cake_tpu.models.llama.paged import WindowedPagedCache
-        return WindowedPagedCache.create(
+    def _fresh_pool(self, kv_pages: int, kv_page_size: int):
+        """An empty pool of this engine's storage: a quantized pool
+        (cake_tpu/kv), or the family's cache."""
+        if self.kv_quant:
+            from cake_tpu.kv import Int4PagedKVCache, QuantizedPagedKVCache
+            qcls = (Int4PagedKVCache if self._kv_dtype_name == "int4"
+                    else QuantizedPagedKVCache)
+            return qcls.create(self.config, self.max_slots, kv_pages,
+                               kv_page_size, self.max_seq_len)
+        from cake_tpu.models.llama.paged import PagedKVCache
+        return PagedKVCache.create(
             self.config, self.max_slots, kv_pages, kv_page_size,
-            self.max_seq_len,
-            self.config.window_ring_pages(kv_page_size, self._mixed_chunk),
-            dtype=self._pool_dtype)
+            self.max_seq_len, dtype=self._pool_dtype,
+            width=self._mixed_chunk)
 
     def _resolve_paged_attn(self, requested: Optional[str],
                             kv_pages: int, kv_page_size: int) -> None:
@@ -2917,60 +2791,22 @@ class InferenceEngine:
         if impl not in ("fold", "pallas"):
             raise ValueError(
                 f"--paged-attn must be fold or pallas, got {impl!r}")
-        if self._latent:
-            # one impl for both step kinds: the selected rows are
-            # gathered in XLA and attended by cake_mla_attn (pallas) or
-            # the XLA fold; its VMEM does not depend on the mixed width.
-            # 512 is the widest window whose gathered rows (width x
-            # index_topk x latent row) stay near a gigabyte.
-            width = self.prefill_chunk or min(512, self.max_seq_len)
+        family = self._family
+        if family.resolve_attn is not None:
+            # the family's own rule, beside the kernel calls it
+            # describes: one impl for both step kinds
+            impl, width = family.resolve_attn(
+                self.config, impl, explicit=requested == "pallas",
+                prefill_chunk=self.prefill_chunk, slots=self.max_slots,
+                n_pages=kv_pages, page_size=kv_page_size,
+                max_seq_len=self.max_seq_len,
+                q_itemsize=jnp.dtype(self.params["embed"].dtype).itemsize,
+                kv_itemsize=jnp.dtype(self._pool_dtype).itemsize)
             self.paged_attn = impl
             self._mixed_chunk = width
             self.attn_impl = {"decode": impl, "mixed": impl}
-            log.info("latent paged attention: requested %s -> dsa-%s "
-                     "(mixed width %d)", requested or "auto", impl, width)
-            return
-        if self._recurrent:
-            # one impl for both step programs: the mixed program runs
-            # the decode kernel over the rows' single tokens and the
-            # mixed kernel over the window in sub-windows (what its
-            # VMEM holds), so its gate is asked at the sub-window
-            from cake_tpu.models.moe.nemotron_h import ATTN_SUBWINDOW
-            c = self.config
-            width = self.prefill_chunk or min(512, self.max_seq_len)
-            if width > ATTN_SUBWINDOW and width % ATTN_SUBWINDOW:
-                raise ValueError(
-                    f"--prefill-chunk {width}: model_type nemotron_h "
-                    f"takes a window of at most {ATTN_SUBWINDOW} tokens "
-                    f"or a multiple of {ATTN_SUBWINDOW}")
-            heads = (c.num_attention_heads, c.num_key_value_heads,
-                     c.head_dim)
-            max_pages = -(-self.max_seq_len // kv_page_size)
-            ok = (rpa.ragged_paged_supported(
-                      kv_page_size, *heads, n_pages=kv_pages,
-                      slots=self.max_slots, max_pages=max_pages)
-                  and rpa.ragged_paged_mixed_supported(
-                      kv_page_size, *heads, min(width, ATTN_SUBWINDOW),
-                      n_pages=kv_pages,
-                      slots=-(-width // ATTN_SUBWINDOW),
-                      max_pages=max_pages,
-                      q_itemsize=jnp.dtype(
-                          self.params["embed"].dtype).itemsize,
-                      kv_itemsize=jnp.dtype(self._pool_dtype).itemsize))
-            if impl == "pallas" and not ok:
-                if requested == "pallas":
-                    raise ValueError(
-                        f"--paged-attn pallas cannot serve model_type "
-                        f"nemotron_h on this device at page="
-                        f"{kv_page_size} heads={heads} mixed width="
-                        f"{width} (ops/ragged_paged_attention gates); "
-                        "use --paged-attn auto or fold")
-                impl = "fold"
-            self.paged_attn = impl
-            self._mixed_chunk = width
-            self.attn_impl = {"decode": impl, "mixed": impl}
-            log.info("hybrid paged attention: requested %s -> ssm-%s "
-                     "(mixed width %d)", requested or "auto", impl, width)
+            log.info("paged attention: requested %s -> %s%s (mixed width "
+                     "%d)", requested or "auto", family.impl, impl, width)
             return
         packed4 = self._kv_dtype_name == "int4"
         pool_dtype = self._pool_dtype
@@ -3032,10 +2868,8 @@ class InferenceEngine:
         flight record (None = the recorder's engine-wide flavor)."""
         if not self.paged:
             return None
-        flavor = ("paged-dsa-" if self._latent
-                  else "paged-ssm-" if self._recurrent
-                  else "paged-cca-" if self._cca else "paged-")
-        return flavor + self.attn_impl.get(kind, self.attn_impl["decode"])
+        return self._family.impl + self.attn_impl.get(
+            kind, self.attn_impl["decode"])
 
     def _capture_cache_identity(self) -> None:
         """Record the cache's placement/dtype so post-error and
@@ -3060,20 +2894,12 @@ class InferenceEngine:
     def _reconfig_supported(self) -> bool:
         return (not self._custom_steps and not self.ring
                 and not self._spec and not self._spec_paged
-                and not self._multihost and not self._one_window
-                and not self._cca)
+                and not self._multihost
+                and self._family.moves("reconfigure"))
 
     def _reconfig_refusal(self) -> str:
-        if self._latent:
-            return (f"the latent page pool ({self.config.hf_layout}) "
-                    "serves on pages only: there is no dense or "
-                    "quantized pool to switch to")
-        if self._recurrent:
-            return ("a recurrent state (nemotron_h) lives beside the "
-                    "page pool: a rebuilt pool cannot replay it")
-        if self._cca:
-            return ("a conv tail (zaya) lives beside the page pool: a "
-                    "rebuilt pool cannot replay it")
+        if not self._family.moves("reconfigure"):
+            return self._family.refuses["reconfigure"]
         if self._spec:
             return ("speculative serving has no hot-switch fold (the "
                     "draft cache cannot be rebuilt mid-round)")
@@ -3691,22 +3517,8 @@ class InferenceEngine:
                     self._specp.draft_config, self.max_slots,
                     self.cache.n_pages, self.cache.page_size,
                     self.max_seq_len, dtype=self._pool_dtype)
-            if self.kv_quant:
-                from cake_tpu.kv import (Int4PagedKVCache,
-                                         QuantizedPagedKVCache)
-                qcls = (Int4PagedKVCache
-                        if self._kv_dtype_name == "int4"
-                        else QuantizedPagedKVCache)
-                return qcls.create(
-                    self.config, self.max_slots, self.cache.n_pages,
-                    self.cache.page_size, self.max_seq_len)
-            if self._windowed:
-                return self._fresh_windowed_cache(self.cache.n_pages,
-                                                  self.cache.page_size)
-            return PagedKVCache.create(
-                self.config, self.max_slots, self.cache.n_pages,
-                self.cache.page_size, self.max_seq_len,
-                dtype=self._pool_dtype)
+            return self._fresh_pool(self.cache.n_pages,
+                                    self.cache.page_size)
         fresh = KVCache.create(self.config, self.max_slots,
                                self.cache.max_seq_len
                                if self.ring else self.max_seq_len,
@@ -4813,10 +4625,9 @@ class InferenceEngine:
         the query tiles the mixed attention kernel folds for the
         step's active rows, counted from their q_len as the kernel
         does, and the tiles of their whole windows. Nothing where the
-        rows do not go through that kernel as they are (latent
-        attention; recurrent blocks, whose program hands it the
-        window alone, in sub-windows)."""
-        if self._latent or self._recurrent:
+        rows do not go through that kernel as they are (the family's
+        kernel_rows)."""
+        if "mixed" not in self._family.kernel_rows:
             return {}
         from cake_tpu.ops.ragged_paged_attention import mixed_q_tiles
         C = self._mixed_chunk
@@ -4833,8 +4644,9 @@ class InferenceEngine:
         stepped through. steps: (position of its first token, tokens)
         for each active row; a scan's record sums its steps. Nothing
         where the decode rows do not go through that kernel (a dense
-        cache, latent attention)."""
-        if not self.paged or self._latent or not steps:
+        cache; the family's kernel_rows)."""
+        if (not self.paged or not steps
+                or "decode" not in self._family.kernel_rows):
             return {}
         P = self.cache.page_size
         last = self.max_seq_len - 1
@@ -4847,12 +4659,11 @@ class InferenceEngine:
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
         order, as many as the largest packed size holds. One group
-        unless three rows or more prefill at once (latent attention
-        and recurrent blocks: two or more)."""
+        unless three rows or more prefill at once (a family of one
+        window a dispatch: two or more)."""
         budget = self._mixed_buckets[-1]
-        # latent attention, recurrent blocks: one window (a row of
-        # several tokens) a dispatch, whatever the budget holds
-        windows = 1 if self._one_window else len(qlen)
+        windows = (len(qlen) if self._family.windows is Windows.FIT
+                   else 1)
         groups, used, wide = [np.zeros(len(qlen), bool)], 0, 0
         for slot in np.flatnonzero(qlen):
             if (used + qlen[slot] > budget
@@ -5004,7 +4815,8 @@ class InferenceEngine:
                 finished: List[tuple] = []
                 # (a dict keeps insertion order: the first key is the
                 # prompt admitted first)
-                for slot in ([next(iter(pending))] if self._window_a_step
+                for slot in ([next(iter(pending))]
+                             if self._family.windows is Windows.STEP
                              else sorted(pending)):
                     p = pending[slot]
                     ids, off = p["ids"], p["off"]
@@ -6552,162 +6364,6 @@ class QueueFullError(Exception):
         self.retry_after = retry_after
 
 
-@jax.jit
-@jax.named_scope("sample")
-def _split_keys(keys):
-    """Split a [B]-vector of PRNG keys into (next_keys, subkeys)."""
-    split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-    return split[:, 0], split[:, 1]
-
-
-@jax.named_scope("sample")
-def _masked_sample(active_mask, keys, logits, ring, steps, temp, top_p,
-                   penalty, *, top_k, n_top=0):
-    """ONE per-row sample with masked state advance — the single source of
-    the engine's sampling semantics: rows outside active_mask keep their
-    PRNG key and ring untouched. Used eagerly by _sample_rows and traced
-    inside _decode_scan, so the two decode paths cannot drift.
-    Returns (next_tokens [B], keys, ring, logprobs [B],
-    top ids [B, n_top], top logprobs [B, n_top])."""
-    new_keys, sub = _split_keys(keys)
-    nxt, lp, top_ids, top_lps = sample_tokens_ragged(
-        sub, logits, ring, temp, top_p, penalty, top_k=top_k, n_top=n_top)
-    keys = jnp.where(active_mask[:, None], new_keys, keys)
-    ring = jnp.where(active_mask[:, None],
-                     update_ring_per_row(ring, nxt, steps), ring)
-    return nxt, keys, ring, lp, top_ids, top_lps
-
-
-class DecodePrograms:
-    """The sampled decode programs over one ragged forward, called as
-    the scan always was (`num_steps=` picks the program): `step`, one
-    decode step + sample with no lax.scan around it, the program the
-    engine keeps in flight; `scan`, num_steps of the same body in one
-    lax.scan (--decode-scan N>1). `lower` is obs/steps.lower_cost's
-    seam. `out_sharding` is where the programs leave their small
-    outputs (make_decode_scan); the engine puts the small inputs it
-    rebuilds from host mirrors there too, so that a stretch's first
-    dispatch and its chained ones are ONE executable: a mesh program
-    otherwise compiles, or loads from the cache, once per combination
-    of host-made and program-made arguments (five times a start-up on
-    the four-chip engine, 5 s of its warm-up; my chip run, PR 29)."""
-
-    def __init__(self, step, scan, out_sharding=None):
-        self.step, self.scan = step, scan
-        self.out_sharding = out_sharding
-
-    def __call__(self, *args, num_steps: int, **kw):
-        if num_steps == 1:
-            return self.step(*args, **kw)
-        return self.scan(*args, num_steps=num_steps, **kw)
-
-    def lower(self, *args, num_steps: int, **kw):
-        if num_steps == 1:
-            return self.step.lower(*args, **kw)
-        return self.scan.lower(*args, num_steps=num_steps, **kw)
-
-
-def make_decode_scan(forward_fn, out_sharding=None) -> DecodePrograms:
-    """Build the jitted sampled decode programs (DecodePrograms) over
-    any ragged forward (single-device model.forward_ragged, or the
-    shard_mapped pipelined forward from parallel.pipeline
-    .make_engine_step_fns): one decode step + sample, once
-    (`decode_step_sampled`, num_steps=1) or num_steps times in a
-    lax.scan (`decode_scan`), so a pipelined engine keeps a step in
-    flight, or amortizes host dispatch across K tokens per round trip,
-    exactly like the single-device engine.
-
-    forward_fn(params, tokens, cache, pos, active, rope, config)
-    -> (logits, cache), with model.forward_ragged's signature; a
-    sparse model's forward returns its expert counters third
-    (paged._step_result), which the one-step program returns last and
-    the scan drops.
-    out_sharding: optional sharding constraint for the non-cache
-    outputs (multi-host serving localizes them per process, so they
-    must leave the program fully replicated).
-
-    Same per-row semantics as the synchronous step (_do_decode +
-    _sample_rows — both go through _masked_sample): inactive rows touch
-    neither their cache lines nor their PRNG/ring state, and a row that
-    emits EOS freezes from then on — in the synchronous step the
-    scheduler frees the slot immediately, so without freezing the
-    slot's PRNG/ring stream would diverge between the two modes.
-    A row also freezes once it has emitted `budget[row]` tokens within
-    this call, so a program may be dispatched past a request's
-    max_new_tokens (or chained speculatively, _decode_burst) without
-    writing a single token beyond the budget.
-    Returns ([B, num_steps] tokens, [B, num_steps] logprobs,
-    [B, num_steps, n_top] x2, cache, keys, ring, state) where state =
-    (tok, pos, steps, live) is the final carry — feeding it back as
-    (last_tok, pos, steps, active) chains the next dispatch entirely on
-    device (no host round-trip between them). The host mirrors
-    (_pos/_steps/_last_tok) are advanced by the caller.
-    """
-
-    def body(carry, params, rope, config, temp, top_p, penalty, steps_in,
-             budget, top_k, n_top):
-        tok, pos, cache, keys, ring, steps, live = carry
-        # per-row budget freeze: emitted-so-far = steps - steps_in
-        # (both advance only while live), so a row stops producing
-        # the moment its allowance for this call is used up
-        live = live & ((steps - steps_in) < budget)
-        logits, cache, *moe = forward_fn(params, tok[:, None], cache, pos,
-                                         live, rope, config)
-        nxt, keys, ring, lp, t_i, t_l = _masked_sample(
-            live, keys, logits, ring, steps, temp, top_p, penalty,
-            top_k=top_k, n_top=n_top)
-        tok = jnp.where(live, nxt, tok)
-        pos = pos + live
-        steps = steps + live
-        live = live & ~jnp.isin(
-            nxt, jnp.asarray(config.eos_token_ids, jnp.int32))
-        return ((tok, pos, cache, keys, ring, steps, live),
-                (nxt, lp, t_i, t_l), moe)
-
-    def result(carry, outs, moe=()):
-        """outs: [B, num_steps(, n_top)] each."""
-        tok, pos, cache, keys, ring, steps, live = carry
-        outs = (*outs, keys, ring, tok, pos, steps, live, *moe)
-        if out_sharding is not None:
-            outs = tuple(jax.lax.with_sharding_constraint(o, out_sharding)
-                         for o in outs)
-        (toks_o, lps_o, ti_o, tl_o, keys_o, ring_o, tok, pos, steps, live,
-         *moe) = outs
-        return (toks_o, lps_o, ti_o, tl_o, cache, keys_o, ring_o,
-                (tok, pos, steps, live), *moe)
-
-    jit = partial(jax.jit, donate_argnames=("cache", "keys", "ring"))
-
-    # the name is the XLA module's (jit_decode_step_...): the benchmark
-    # finds a decode step's device time by that prefix
-    @partial(jit, static_argnames=("config", "top_k", "n_top"))
-    def decode_step_sampled(params, last_tok, pos, active, cache, rope,
-                            config, keys, ring, steps, temp, top_p,
-                            penalty, budget, top_k, n_top: int = 0):
-        carry, outs, moe = body(
-            (last_tok, pos, cache, keys, ring, steps, active), params,
-            rope, config, temp, top_p, penalty, steps, budget, top_k,
-            n_top)
-        return result(carry, tuple(o[:, None] for o in outs), moe)
-
-    @partial(jit, static_argnames=("config", "num_steps", "top_k",
-                                   "n_top"))
-    def decode_scan(params, last_tok, pos, active, cache: KVCache, rope,
-                    config, keys, ring, steps, temp, top_p, penalty,
-                    budget, num_steps: int, top_k, n_top: int = 0):
-        def scanned(carry, _):
-            return body(carry, params, rope, config, temp, top_p,
-                        penalty, steps, budget, top_k, n_top)[:2]
-
-        carry, (toks, lps, tops_i, tops_l) = jax.lax.scan(
-            scanned, (last_tok, pos, cache, keys, ring, steps, active),
-            None, length=num_steps)
-        return result(carry, (toks.T, lps.T, jnp.swapaxes(tops_i, 0, 1),
-                              jnp.swapaxes(tops_l, 0, 1)))
-
-    return DecodePrograms(decode_step_sampled, decode_scan, out_sharding)
-
-
 class _Flying:
     """The dispatches of one stretch (_decode_stretch, _mixed_burst):
     how many were sent, how many of them the host has not fetched, and
@@ -6741,89 +6397,15 @@ class _Flying:
         return wall
 
 
-# what a row does in a mixed step (the last column of the packed step)
-ROW_ACTIVE, ROW_SAMPLE, ROW_FROM_CARRY = 1, 2, 4
-
-
-def make_mixed_sampled(mixed_fn):
-    """Build the jitted sampled mixed step over a mixed step function
-    (paged.mixed_step_paged, glm_dsa.mixed_step_latent: one signature):
-    the forward on the packed axis, then _masked_sample over the rows
-    that sample this step, then the EOS freeze of make_decode_scan's
-    body, so that the next step can be dispatched from this one's
-    tokens while they are still on the device.
-
-    step [B, C + 4] int32, the step as the host knows it, a row a slot:
-    its window of C tokens, then its position, its q_len, its step
-    count and its flags. ROW_ACTIVE: the row is in this dispatch;
-    ROW_SAMPLE: it samples (a decode row, a row whose window ends its
-    prompt; every other row keeps its key and ring); ROW_FROM_CARRY: a
-    step the host has not fetched yet sampled it, so its input token,
-    its position, its step count and whether it still lives (no EOS
-    yet) come from that step's carry. ONE array because each host array
-    is a transfer of its own, which a stretch's first step pays with
-    the device idle. carry = (tok, pos, steps, live), each [B], in the
-    decode programs' form. A row that is not active passes its carry
-    through, so a step of several dispatches threads one carry through
-    them.
-    Returns (tokens [B], logprobs [B], top ids and top logprobs
-    [B, n_top], cache, keys, ring, carry, and a sparse model's
-    counters): the carry feeds the next mixed step, or the sampled
-    decode programs as (last_tok, pos, steps, active)."""
-
-    # the name is the XLA module's (jit_mixed_step_...): the benchmark
-    # finds a mixed step's device time by that prefix
-    @partial(jax.jit, static_argnames=("config", "attn", "n_tokens",
-                                       "top_k", "n_top"),
-             donate_argnames=("cache", "keys", "ring"))
-    def mixed_step_sampled(params, step, cache, rope, config, keys, ring,
-                           temp, top_p, penalty, carry, attn, n_tokens,
-                           top_k, n_top: int = 0):
-        tokens = step[:, :-4]
-        pos, q_len, steps, flags = (step[:, i] for i in range(-4, 0))
-        active, sample, from_carry = ((flags & bit) != 0 for bit in (
-            ROW_ACTIVE, ROW_SAMPLE, ROW_FROM_CARRY))
-        c_tok, c_pos, c_steps, c_live = carry
-        tok = jnp.where(from_carry, c_tok, tokens[:, 0])
-        pos = jnp.where(from_carry, c_pos, pos)
-        steps = jnp.where(from_carry, c_steps, steps)
-        live = active & jnp.where(from_carry, c_live, True)
-        logits, cache, *counters = mixed_fn(
-            params, tokens.at[:, 0].set(tok), pos, q_len, live, cache,
-            rope, config, attn=attn, n_tokens=n_tokens)
-        sampled = sample & live
-        nxt, keys, ring, lp, t_i, t_l = _masked_sample(
-            sampled, keys, logits, ring, steps, temp, top_p, penalty,
-            top_k=top_k, n_top=n_top)
-        eos = jnp.isin(nxt, jnp.asarray(config.eos_token_ids, jnp.int32))
-        carry = (jnp.where(sampled, nxt, jnp.where(active, tok, c_tok)),
-                 jnp.where(active, pos + jnp.where(live, q_len, 0), c_pos),
-                 jnp.where(active, steps + sampled, c_steps),
-                 jnp.where(active, sampled & ~eos, c_live))
-        return (nxt, lp, t_i, t_l, cache, keys, ring, carry, *counters)
-
-    return mixed_step_sampled
-
-
-# module-level like the decode programs, so the jit cache is shared
-# across engine instances
-_mixed_sampled_paged = make_mixed_sampled(mixed_step_paged)
-
-
-def _mixed_step_latent(*args, **kw):
-    from cake_tpu.models.moe.glm_dsa import mixed_step_latent
-    return mixed_step_latent(*args, **kw)
-
-
-_mixed_sampled_latent = make_mixed_sampled(_mixed_step_latent)
-
-
 def _builtin_forward_ragged(params, tokens, cache, pos, active, rope,
                             config):
     from cake_tpu.models.llama.model import forward_ragged
     return forward_ragged(params, tokens, cache, pos, active, rope, config)
 
 
+# module-level so the jit cache is shared across engine instances
+# (restart flows, test suites); a paged family's programs are its
+# module's (models/family.py)
 _decode_scan = make_decode_scan(_builtin_forward_ragged)
 
 
@@ -6834,100 +6416,3 @@ def _ring_forward_ragged(params, tokens, cache, pos, active, rope, config):
 
 
 _decode_scan_ring = make_decode_scan(_ring_forward_ragged)
-
-
-def _paged_forward_ragged(params, tokens, cache, pos, active, rope,
-                          config):
-    from cake_tpu.models.llama.paged import forward_ragged_paged
-    return forward_ragged_paged(params, tokens, cache, pos, active, rope,
-                                config, counters=True)
-
-
-# module-level like its dense/ring siblings so the jit cache is shared
-# across engine instances (restart flows, test suites)
-_decode_scan_paged = make_decode_scan(_paged_forward_ragged)
-
-
-def _paged_forward_ragged_pallas(params, tokens, cache, pos, active,
-                                 rope, config):
-    from cake_tpu.models.llama.paged import forward_ragged_paged
-    return forward_ragged_paged(params, tokens, cache, pos, active,
-                                rope, config, attn="pallas", counters=True)
-
-
-_decode_scan_paged_pallas = make_decode_scan(_paged_forward_ragged_pallas)
-
-
-def _latent_forward_ragged(params, tokens, cache, pos, active, rope,
-                           config):
-    from cake_tpu.models.moe.glm_dsa import forward_ragged_latent
-    return forward_ragged_latent(params, tokens, cache, pos, active, rope,
-                                 config)
-
-
-_decode_scan_latent = make_decode_scan(_latent_forward_ragged)
-
-
-def _latent_forward_ragged_pallas(params, tokens, cache, pos, active,
-                                  rope, config):
-    from cake_tpu.models.moe.glm_dsa import forward_ragged_latent
-    return forward_ragged_latent(params, tokens, cache, pos, active, rope,
-                                 config, attn="pallas")
-
-
-_decode_scan_latent_pallas = make_decode_scan(_latent_forward_ragged_pallas)
-
-
-def _mixed_step_hybrid(*args, **kw):
-    from cake_tpu.models.moe.nemotron_h import mixed_step_hybrid
-    return mixed_step_hybrid(*args, **kw)
-
-
-_mixed_sampled_hybrid = make_mixed_sampled(_mixed_step_hybrid)
-
-
-def _hybrid_forward_ragged(params, tokens, cache, pos, active, rope,
-                           config):
-    from cake_tpu.models.moe.nemotron_h import forward_ragged_hybrid
-    return forward_ragged_hybrid(params, tokens, cache, pos, active, rope,
-                                 config)
-
-
-_decode_scan_hybrid = make_decode_scan(_hybrid_forward_ragged)
-
-
-def _hybrid_forward_ragged_pallas(params, tokens, cache, pos, active,
-                                  rope, config):
-    from cake_tpu.models.moe.nemotron_h import forward_ragged_hybrid
-    return forward_ragged_hybrid(params, tokens, cache, pos, active, rope,
-                                 config, attn="pallas")
-
-
-_decode_scan_hybrid_pallas = make_decode_scan(_hybrid_forward_ragged_pallas)
-
-
-def _mixed_step_cca(*args, **kw):
-    from cake_tpu.models.moe.zaya import mixed_step_cca
-    return mixed_step_cca(*args, **kw)
-
-
-_mixed_sampled_cca = make_mixed_sampled(_mixed_step_cca)
-
-
-def _cca_forward_ragged(params, tokens, cache, pos, active, rope, config):
-    from cake_tpu.models.moe.zaya import forward_ragged_cca
-    return forward_ragged_cca(params, tokens, cache, pos, active, rope,
-                              config)
-
-
-_decode_scan_cca = make_decode_scan(_cca_forward_ragged)
-
-
-def _cca_forward_ragged_pallas(params, tokens, cache, pos, active, rope,
-                               config):
-    from cake_tpu.models.moe.zaya import forward_ragged_cca
-    return forward_ragged_cca(params, tokens, cache, pos, active, rope,
-                              config, attn="pallas")
-
-
-_decode_scan_cca_pallas = make_decode_scan(_cca_forward_ragged_pallas)

@@ -438,9 +438,11 @@ def test_rows_that_keep_the_synchronous_step(tiny_config, params, reason):
 def test_one_step_program_is_found_by_the_benchmarks_prefix():
     """benchmarks/harness/trace_spans.py finds a decode step's device
     time by its XLA module's name."""
+    from cake_tpu.models.llama.config import MODEL_TYPES
+    from test_family import tiny_config
     for progs in (engine_mod._decode_scan, engine_mod._decode_scan_ring,
-                  engine_mod._decode_scan_paged,
-                  engine_mod._decode_scan_paged_pallas):
+                  *(tiny_config(model_type).family.decode_programs
+                    for model_type in MODEL_TYPES)):
         assert ("jit_" + progs.step.__name__).startswith("jit_decode_step")
         assert not ("jit_" + progs.scan.__name__).startswith(
             "jit_decode_step")

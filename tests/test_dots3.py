@@ -520,8 +520,8 @@ def test_counters_count_what_the_reference_attends(model):
     assert got["dsa_keys_visible"] == 2 * 253
     assert got["dsa_keys_selected"] == 2 * (36 + 14 * 8)
     assert got["dsa_index_layers"] == 2 * 5 and got["dsa_index_reused"] == 0
-    layout = obs_steps.counter_layout(len(counters))
-    assert tuple(k for k, _ in layout) == names
+    assert c.family.counters == names
+    assert names[-3:] == tuple(k for k, _ in obs_steps.SWA_COUNTERS)
 
 
 # -- the config ----------------------------------------------------------------
@@ -735,8 +735,7 @@ def test_a_rebuilt_cache_has_the_same_rings(engine_run):
     """The post-error rebuild makes the pools anew: the rings with
     them, no allocator to reset."""
     *_, eng = engine_run
-    fresh = eng._fresh_windowed_cache(eng.cache.n_pages,
-                                      eng.cache.page_size)
+    fresh = eng._fresh_pool(eng.cache.n_pages, eng.cache.page_size)
     assert fresh.w.shape == eng.cache.w.shape
     assert np.array_equal(np.asarray(fresh.wtable),
                           np.asarray(eng.cache.wtable))

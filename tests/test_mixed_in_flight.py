@@ -790,6 +790,8 @@ def test_the_paged_speculative_engines_mixed_steps_are_not_chained(
 def test_the_program_is_found_by_the_benchmarks_prefix():
     """benchmarks/harness/trace_spans.py finds a mixed step's device
     time by its XLA module's name."""
-    for program in (engine_mod._mixed_sampled_paged,
-                    engine_mod._mixed_sampled_latent):
+    from cake_tpu.models.llama.config import MODEL_TYPES
+    from test_family import tiny_config
+    for model_type in MODEL_TYPES:
+        program = tiny_config(model_type).family.mixed_sampled
         assert ("jit_" + program.__name__).startswith("jit_mixed_step")

@@ -583,10 +583,11 @@ def test_metrics_carry_the_state(engine_run):
     from cake_tpu.obs import steps as obs_steps
     *_, eng = engine_run
     assert obs_steps.SSM_STATE_BYTES.value == eng.cache.state_bytes() > 0
-    assert [k for k, _ in obs_steps.SSM_LAYOUT][5:] == [
+    assert nh.COUNTERS[5:] == (
         "moe_rows_routed", "ssm_state_rows", "ssm_tokens_scanned",
-        "ssm_tokens_stepped", "ssm_state_resets"]
-    assert len(obs_steps.SSM_LAYOUT) == nh.N_COUNTERS
+        "ssm_tokens_stepped", "ssm_state_resets")
+    assert nh.COUNTERS[6:] == tuple(k for k, _ in obs_steps.SSM_COUNTERS)
+    assert eng.flight._counters == nh.COUNTERS
 
 
 @pytest.mark.parametrize("refused,named", [

@@ -75,11 +75,10 @@ def _step_args(kind_of_step):
 def _sampled(attn: str, lower: bool = False):
     """The sampled one-step decode program (PR 29: the step the engine
     keeps in flight) in decode_step_ragged_paged's call shape."""
-    from cake_tpu.serve import engine
+    from cake_tpu.models.llama.paged import FAMILY
 
-    progs = (engine._decode_scan_paged_pallas if attn == "pallas"
-             else engine._decode_scan_paged)
-    fn = progs.step.lower if lower else progs.step
+    progs = FAMILY.decode_programs
+    fn = partial(progs.step.lower if lower else progs.step, attn=attn)
 
     def run(params, tokens, pos, active, cache, rope, config):
         B = tokens.shape[0]

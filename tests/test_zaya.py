@@ -421,7 +421,7 @@ def test_the_counters_count(model, traffic):
     _, _, out = mixed(model, fresh_cache(c), toks, np.zeros(B, np.int32),
                       qlen)
     counters = np.asarray(out.counters)
-    assert len(counters) == len(obs_steps.CCA_LAYOUT)
+    assert len(counters) == len(zaya.COUNTERS)
     L = c.num_hidden_layers
     assert counters[0] == L * (C + 3)            # one row a token a layer
     assert counters[5] == L * 2
@@ -572,11 +572,10 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
 def test_metrics_carry_the_tails_bytes(engine_run):
     *_, eng = engine_run
     assert obs_steps.CCA_TAIL_BYTES.value == eng.cache.state_bytes() > 0
-    assert [k for k, _ in obs_steps.CCA_LAYOUT][5:] == [
-        "cca_tail_rows", "router_choice_by_bias"]
-    assert obs_steps.counter_layout(7) is obs_steps.CCA_LAYOUT
-    assert len(obs_steps.counter_layout(10)) == 10
-    assert len(obs_steps.counter_layout(5)) == 5
+    assert zaya.COUNTERS[5:] == tuple(k for k, _ in obs_steps.CCA_COUNTERS)
+    assert zaya.COUNTERS[5:] == ("cca_tail_rows", "router_choice_by_bias")
+    assert eng.flight._counters == eng.config.family.counters
+    assert eng.config.family.counters == zaya.COUNTERS
 
 
 def test_recompute_preemption_gives_the_same_tokens():

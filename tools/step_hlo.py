@@ -98,7 +98,7 @@ def abstract_step_args(config, sharding, *, bits: Optional[int] = 8,
 
     params = on_device(jax.eval_shape(init, jax.random.PRNGKey(0)))
     cache = on_device(jax.eval_shape(lambda: PagedKVCache.create(
-        config, slots, n_pages, page_size, max_seq_len)))
+        config, slots, n_pages, page_size, max_seq_len, width=width)))
     rope = on_device(jax.eval_shape(
         lambda: RopeTables.create(config, max_seq_len)))
     row = on_device(jax.ShapeDtypeStruct((slots,), jnp.int32))
@@ -151,17 +151,8 @@ def materialised_int8(hlo_text: str) -> List[Materialised]:
 
 def step_fns(config):
     """(decode step, mixed step) of the config's family."""
-    if getattr(config, "kv_lora_rank", None):
-        from cake_tpu.models.moe import glm_dsa
-        return glm_dsa.decode_step_latent, glm_dsa.mixed_step_latent
-    if getattr(config, "mamba_layers", None):
-        from cake_tpu.models.moe import nemotron_h
-        return nemotron_h.decode_step_hybrid, nemotron_h.mixed_step_hybrid
-    if getattr(config, "cca_time0", None):
-        from cake_tpu.models.moe import zaya
-        return zaya.decode_step_cca, zaya.mixed_step_cca
-    from cake_tpu.models.llama import paged
-    return paged.decode_step_ragged_paged, paged.mixed_step_paged
+    family = config.family
+    return family.decode_step, family.mixed_step
 
 
 def main(argv=None) -> int:
