@@ -27,7 +27,8 @@ class Windows(enum.Enum):
     step is ONE dispatch that every decode row rides: with every prompt
     in every step 32 callers moved in convoys of seconds of prefill,
     then seconds of decode (PERF.md §6, PR 33). Which of the last two a
-    one-window family should take is measured for one (ROADMAP D18)."""
+    one-window family should take is measured for two (nemotron_h,
+    PR 33; deepseek_v2, PR 45: both STEP; ROADMAP D18)."""
 
     FIT = "fit"
     DISPATCH = "dispatch"

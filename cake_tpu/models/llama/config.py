@@ -38,6 +38,10 @@ MODEL_TYPES = {
                   "an indexer each, sliding-window layers with a latent "
                   "geometry of their own in a ring of pages a row; a "
                   "sigmoid gate a head, rescaled latents (paged engine)",
+    "deepseek_v2": "latent attention over every visible key (no indexer), "
+                   "YaRN on its rope part, softmax routing limited to the "
+                   "best groups of experts, two shared experts (paged "
+                   "engine)",
     "nemotron_h": "one mixer a block: Mamba-2 with a recurrent state a row "
                   "beside the page pool, relu² experts in a latent with a "
                   "shared one, GQA without positions (paged engine)",
@@ -66,6 +70,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "dots3_note":
         from cake_tpu.models.moe.config import Dots3NoteConfig
         return Dots3NoteConfig.from_hf_dict(raw)
+    if model_type == "deepseek_v2":
+        from cake_tpu.models.moe.config import DeepseekV2Config
+        return DeepseekV2Config.from_hf_dict(raw)
     if model_type == "nemotron_h":
         from cake_tpu.models.moe.config import NemotronHConfig
         return NemotronHConfig.from_hf_dict(raw)
@@ -186,6 +193,7 @@ class LlamaConfig:
                            "qwen2": "chatml", "olmoe": "tulu",
                            "glm_moe_dsa": "chatml",
                            "dots3_note": "chatml",
+                           "deepseek_v2": "chatml",
                            "nemotron_h": "chatml", "zaya": "chatml"}.get(
                                raw.get("model_type", ""), "llama3"),
             attention_bias=raw.get("attention_bias",

@@ -51,7 +51,8 @@ class RopeTables(NamedTuple):
     @classmethod
     def create(cls, config: LlamaConfig, max_seq_len: int) -> "RopeTables":
         cos, sin = precompute_rope(
-            config.rope_dim, max_seq_len, config.rope_theta
+            config.rope_dim, max_seq_len, config.rope_theta,
+            yarn=getattr(config, "rope_scaling", None),
         )
         if getattr(config, "swa_rope_theta", None):
             # a second kind of attention layer with a rotation of its own

@@ -21,8 +21,10 @@ zeros to whole 128-lane tiles: 576 -> 640] (the normed c_kv and the
 rotated key all heads share; no values: they are up-projected from
 it), and `v` the sparse indexer's key,
 [L_full, N_pages, page, index_head_dim], in the layers that compute an
-index only. Both pools go through the one page table and allocator: a
-page id names the same token range in each.
+index only (none in deepseek_v2, which attends every visible key: its
+`v` is empty, a latent pool with no index-key pool). Both pools go
+through the one page table and allocator: a page id names the same
+token range in each.
 A model with recurrent blocks (nemotron_h) keeps TWO kinds of state in
 the one cache object (HybridPagedCache): the K/V pool above for its
 attention blocks alone ([L_attn, ...]), and per ROW (slot), not per

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 from typing import NamedTuple, Optional, Tuple
 
 from cake_tpu.models.llama.config import LlamaConfig
+from cake_tpu.ops.rope import Yarn
 
 
 @dataclass(frozen=True)
@@ -131,7 +132,8 @@ class LatentGeometry(NamedTuple):
     multiplied by a sigmoid of the layer's normed input (the leaf
     `w_attn_gate` [D, heads]); window: keys a query attends, its own
     included (None = a full layer: the indexer's selection over
-    everything visible)."""
+    everything visible, or every visible key where the layer has no
+    indexer)."""
 
     scope: str
     heads: int
@@ -146,10 +148,13 @@ class LatentGeometry(NamedTuple):
     kv_scale: float = 1.0
     gated: bool = False
     window: Optional[int] = None
+    # what head_dim^-0.5 is multiplied by (YaRN's mscale^2; 1.0 = plain)
+    scale_factor: float = 1.0
 
     @property
     def softmax_scale(self) -> float:
-        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        return ((self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+                * self.scale_factor)
 
 
 @dataclass(frozen=True)
@@ -180,7 +185,8 @@ class GlmMoeDsaConfig(MoEConfig):
     index_n_heads: int = 32
     index_head_dim: int = 128
     index_topk: int = 2048
-    # per layer: "dense" | "sparse", and "full" | "shared"
+    # per layer: "dense" | "sparse", and "full" | "shared" (Dots3Note-
+    # Config: "full" | "sliding"; DeepseekV2Config: "dense", no indexer)
     mlp_layer_types: Tuple[str, ...] = ()
     indexer_types: Tuple[str, ...] = ()
     moe_intermediate_size: int = 2048
@@ -189,6 +195,10 @@ class GlmMoeDsaConfig(MoEConfig):
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
     scoring_func: str = "sigmoid"
+    # group-limited routing (ops/moe.choose; 1 / 1 = not at all: the
+    # parser here refuses anything else, DeepseekV2Config's takes it)
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def rope_dim(self) -> int:
@@ -262,6 +272,10 @@ class GlmMoeDsaConfig(MoEConfig):
                                  "routing is not implemented")
         if raw.get("n_shared_experts", 1) != 1:
             raise ValueError("n_shared_experts must be 1")
+        if raw.get("rope_scaling"):
+            raise ValueError(
+                "rope_scaling is not implemented for this model_type "
+                "(deepseek_v2 takes type yarn)")
         if raw.get("num_nextn_predict_layers", 0):
             raise ValueError(
                 "num_nextn_predict_layers > 0: the multi-token-prediction "
@@ -491,6 +505,146 @@ class Dots3NoteConfig(GlmMoeDsaConfig):
 
 
 @dataclass(frozen=True)
+class DeepseekV2Config(GlmMoeDsaConfig):
+    """DeepSeek-V2 (`model_type: deepseek_v2`): latent attention over
+    EVERY visible key (no indexer: `indexer_types` holds "dense" in every
+    layer, the latent pool has no index-key pool beside it), YaRN on the
+    rope part (`rope_scaling`: the frequencies in the RoPE tables, the
+    softmax scale times mscale^2 in the layer's LatentGeometry), and
+    group-limited routing: softmax over all the router's experts, the
+    `topk_group` best of `n_group` groups by each group's best score,
+    the top k inside them, not renormalised, times
+    `routed_scaling_factor` (ops/moe.choose); the `n_shared_experts`
+    shared experts are one MLP of n_shared_experts *
+    moe_intermediate_size. With a layer shared by n_group chips one
+    group is one chip's experts (device-limited routing), so
+    `first_routed_expert .. + n_routed_experts - 1` is a group here. The
+    equations are in models/reference/deepseek_v2.py; the served path is
+    models/moe/glm_dsa.py's trunk, its dense kind of layer."""
+
+    _family = "cake_tpu.models.moe.glm_dsa:DENSE"
+
+    n_group: int = 8
+    topk_group: int = 3
+    # config.json rope_scaling of type yarn (ops/rope.Yarn), or None
+    rope_scaling: Optional[Yarn] = None
+
+    def geometry(self, layer: int) -> LatentGeometry:
+        factor = (self.rope_scaling.softmax_factor if self.rope_scaling
+                  else 1.0)
+        return super().geometry(layer)._replace(scale_factor=factor)
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "DeepseekV2Config":
+        L = raw["num_hidden_layers"]
+        for name in ("index_topk", "index_n_heads", "index_head_dim",
+                     "indexer_types", "index_topk_freq"):
+            if raw.get(name) is not None:
+                raise ValueError(
+                    f"{name}: model_type deepseek_v2 attends every visible "
+                    "key; a model with a sparse indexer is glm_moe_dsa")
+        method = raw.get("topk_method", "group_limited_greedy")
+        if method != "group_limited_greedy":
+            raise ValueError(f"topk_method = {method!r}: only "
+                             "'group_limited_greedy' is implemented")
+        if raw.get("scoring_func", "softmax") != "softmax":
+            raise ValueError(f"scoring_func = {raw['scoring_func']!r}: "
+                             "model_type deepseek_v2 scores by softmax")
+        n_group, topk_group = raw.get("n_group", 1), raw.get("topk_group", 1)
+        total = raw.get("n_routed_experts_total", raw["n_routed_experts"])
+        if total % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(
+                f"n_group = {n_group}, topk_group = {topk_group}: the "
+                f"router's {total} experts fall into n_group equal groups "
+                "of which 1..n_group are taken")
+        if (raw["num_experts_per_tok"]
+                > topk_group * (total // n_group)):
+            raise ValueError(
+                f"num_experts_per_tok = {raw['num_experts_per_tok']} "
+                f"experts do not fit in {topk_group} groups of "
+                f"{total // n_group}")
+        if raw.get("q_lora_rank") is None:
+            raise ValueError(
+                "q_lora_rank is null (the DeepSeek-V2-Lite layout, a full-"
+                "rank query projection): not implemented")
+        if raw.get("attention_bias", False):
+            raise ValueError("attention_bias = true: projection biases "
+                             "are not implemented")
+        if raw.get("moe_layer_freq", 1) != 1:
+            raise ValueError("moe_layer_freq must be 1")
+        if raw.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act = {raw['hidden_act']!r}: only "
+                             "'silu' is implemented")
+        if (raw.get("num_key_value_heads", raw["num_attention_heads"])
+                != raw["num_attention_heads"]):
+            raise ValueError("num_key_value_heads must equal "
+                             "num_attention_heads: latent attention has a "
+                             "key a head")
+        if raw.get("n_shared_experts", 0) < 1:
+            raise ValueError("n_shared_experts must be at least 1")
+        yarn = None
+        scaling = raw.get("rope_scaling")
+        if scaling:
+            kind = scaling.get("type", scaling.get("rope_type"))
+            if kind != "yarn":
+                raise ValueError(f"rope_scaling type {kind!r}: only "
+                                 "'yarn' is implemented")
+            yarn = Yarn(
+                float(scaling["factor"]),
+                int(scaling["original_max_position_embeddings"]),
+                float(scaling.get("beta_fast", 32)),
+                float(scaling.get("beta_slow", 1)),
+                float(scaling.get("mscale", 1)),
+                float(scaling.get("mscale_all_dim", 0)))
+        # GLM's parser for what the two share (the dense / sparse split,
+        # MTP's refusal, the held experts' range): the keys it would
+        # refuse or lacks are handed over in its own terms
+        base = GlmMoeDsaConfig.from_hf_dict(dict(
+            raw, n_group=1, topk_group=1, n_shared_experts=1,
+            rope_scaling=None, indexer_types=["full"] * L,
+            index_n_heads=0, index_head_dim=0, index_topk=0,
+            scoring_func="softmax",
+            norm_topk_prob=raw.get("norm_topk_prob", False)))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields.update(
+            hf_layout="deepseek_v2", chat_template="chatml",
+            indexer_types=("dense",) * L,
+            n_shared_experts=raw["n_shared_experts"],
+            n_group=n_group, topk_group=topk_group, rope_scaling=yarn)
+        return cls(**fields)
+
+    @classmethod
+    def tiny_dsv2(cls, **overrides) -> "DeepseekV2Config":
+        """DeepSeek-V2's layers at a test's size: one dense layer, then
+        three sparse ones; 16 routed experts in 4 groups of which 2 are
+        taken, 3 a token, ALL held (a test of the share holds one
+        group); two shared experts; YaRN over a trained length of 16,
+        so that a context of a few dozen tokens is several times past
+        it."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=4, rms_norm_eps=1e-6, rope_theta=10000.0,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=16, num_experts_per_tok=3,
+            norm_topk_prob=False, hf_layout="deepseek_v2",
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_n_heads=0,
+            index_head_dim=0, index_topk=0,
+            mlp_layer_types=("dense",) + ("sparse",) * 3,
+            indexer_types=("dense",) * 4,
+            moe_intermediate_size=32, n_routed_experts_total=16,
+            n_shared_experts=2, routed_scaling_factor=16.0,
+            scoring_func="softmax", n_group=4, topk_group=2,
+            rope_scaling=Yarn(4.0, 16, 8.0, 1.0, 0.707, 0.707),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class NemotronHConfig(MoEConfig):
     """Nemotron-3 (`model_type: nemotron_h`): every block is ONE mixer
     behind one RMS norm and one residual, its kind read from
@@ -577,6 +731,10 @@ class NemotronHConfig(MoEConfig):
                                  "routing is not implemented")
         if raw.get("n_shared_experts", 1) != 1:
             raise ValueError("n_shared_experts must be 1")
+        if raw.get("rope_scaling"):
+            raise ValueError(
+                "rope_scaling is not implemented for model_type nemotron_h "
+                "(its attention blocks have no positional embedding)")
         if raw.get("num_nextn_predict_layers", 0):
             raise ValueError(
                 "num_nextn_predict_layers > 0: the multi-token-prediction "

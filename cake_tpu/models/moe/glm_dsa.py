@@ -1,8 +1,10 @@
 """Latent attention on the paged engine: the step programs of GLM-5.2
-(`glm_moe_dsa`) and of dots3-note (`dots3_note`).
+(`glm_moe_dsa`), of dots3-note (`dots3_note`) and of DeepSeek-V2
+(`deepseek_v2`).
 
-The equations are models/reference/glm_moe_dsa.py's and
-models/reference/dots3_note.py's; this is how the served path computes
+The equations are models/reference/glm_moe_dsa.py's,
+models/reference/dots3_note.py's and models/reference/deepseek_v2.py's;
+this is how the served path computes
 them over the page pool (models/llama/paged.py says what a pool row is
 here: one latent row a token and layer, and the indexer's key in the
 layers that compute an index).
@@ -39,9 +41,14 @@ packed axis (paged.pack_plan). A layer:
 
 KINDS OF LAYER. A layer takes its sizes from its LatentGeometry
 (config.geometry(i): heads, ranks, head dims, the RoPE table, the stored
-row), so one trunk serves a model with one geometry (GLM) and a model
-with two (dots3_note). By config.indexer_types a layer is FULL (its own
-indexer), SHARED (GLM only), or SLIDING (dots3_note only): no indexer,
+row, the softmax scale's factor), so one trunk serves a model with one
+geometry (GLM, DeepSeek-V2) and a model with two (dots3_note). By
+config.indexer_types a layer is FULL (its own indexer), SHARED (GLM
+only), DENSE (deepseek_v2 only: no indexer and no Selection: every
+visible key is attended, `attend_dense`: a row's single token walks its
+row's live pages where they lie, `cake_mla_decode_attn`, nothing is
+gathered; a window attends under causality alone as the bias; the FFN's
+router is limited to groups of experts), or SLIDING (dots3_note only): no indexer,
 its rows in the sliding layers' own pool behind the ring table
 (paged.WindowedPagedCache), its scopes `swa_q`, `swa_kv`, `swa_gather`,
 `swa_attn`. A sliding layer's single token gathers the rows at
@@ -102,6 +109,11 @@ COUNTERS = paged.MOE_COUNTERS + (
     "dsa_rows_distinct", "dsa_index_layers", "dsa_index_reused")
 SWA_COUNTERS = ("swa_keys_visible", "swa_keys_attended", "swa_layers")
 N_COUNTERS = len(COUNTERS)
+# a model whose layers have no indexer (deepseek_v2): the expert
+# counters' five, the routed rows, the tokens whose groups include the
+# held one, and the keys its single-token rows attended
+DENSE_COUNTERS = paged.MOE_COUNTERS + (
+    "moe_rows_routed", "moe_tokens_group_held", "mla_keys_attended")
 
 
 class Window(NamedTuple):
@@ -369,6 +381,54 @@ def attend(q_cat, pool_lat, layer: int, table, slot, first,
                          out[slot])
 
 
+class Visible(NamedTuple):
+    """What a layer with no indexer attends, the same in every such
+    layer of a dispatch: rows_pos [B], each row's single token's
+    position (-1: the row has none here: idle, or the window's row),
+    and the window's causal bias [C, S] float32 (None: no window)."""
+
+    rows_pos: jnp.ndarray
+    bias: Optional[jnp.ndarray]
+
+
+def visible_keys(slot, position, real, first, table, page: int,
+                 window: Optional[Window]) -> Visible:
+    rows = jnp.arange(first.shape[0])
+    # (an idle row's first packed index is its successor's: not its)
+    single = real[first] & (slot[first] == rows)
+    bias = None
+    if window is not None:
+        single = single & ~((rows == window.row) & jnp.any(window.real))
+        span = jnp.arange(table.shape[1] * page)[None, :]
+        bias = jnp.where(span <= window.positions[:, None], 0.0,
+                         mla.NEG_INF).astype(jnp.float32)
+    return Visible(jnp.where(single, position[first], -1), bias)
+
+
+def attend_dense(q_cat, pool_lat, layer: int, table, slot, first,
+                 visible: Visible, geo: LatentGeometry, attn: str,
+                 window: Optional[Window]):
+    """Every token over EVERY visible key of its row -> the attended
+    latent [T, H, R]; nothing is gathered. A row's single token walks
+    the row's live pages where they lie (`cake_mla_decode_attn`), in
+    the decode program and in the mixed program alike. The window's
+    tokens: their row's pages under causality as the bias
+    (`cake_mla_window_attn`)."""
+    scale = geo.softmax_scale
+    with jax.named_scope("mla_attn"):
+        out = mla.attend_pages(q_cat[first], pool_lat, jnp.int32(layer),
+                               table, visible.rows_pos, geo.kv_lora_rank,
+                               scale, impl=attn)
+        if window is None:
+            return out
+        win = mla.attend_window(
+            _window_slice(q_cat, window), pool_lat, jnp.int32(layer),
+            table[window.row], visible.bias, window.last_pos,
+            geo.kv_lora_rank, scale, impl=attn)
+        return jnp.where(window.member[:, None, None], win[window.col],
+                         out[slot])
+
+
 def gathered_keys(window: int) -> int:
     """The gathered axis of a sliding layer's single token: its window
     of keys padded to whole tiles (513 -> 640; a test's handful to 8s)."""
@@ -478,6 +538,10 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     if first is None:
         first = jnp.arange(x.shape[0])
     selection = None
+    dense = "dense" in c.indexer_types
+    if dense:
+        seen = visible_keys(slot, position, real, first, table,
+                            pool_lat.shape[2], window)
     moe, experts, selected, windows, probe = [], [], [], [], ()
     distinct = jnp.float32(0)
     with jax.named_scope("layers"):
@@ -508,9 +572,15 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                         selected.append(selection.idx)
                         if selection.bias is not None:
                             windows.append(selection.bias == 0)
-                    distinct = distinct + last_distinct
-                    o_lat = attend(q_cat, pool_lat, j, table, slot, first,
-                                   selection, c, attn, window, geo)
+                    if dense:
+                        o_lat = attend_dense(q_cat, pool_lat, j, table,
+                                             slot, first, seen, geo, attn,
+                                             window)
+                    else:
+                        distinct = distinct + last_distinct
+                        o_lat = attend(q_cat, pool_lat, j, table, slot,
+                                       first, selection, c, attn, window,
+                                       geo)
                 o = unabsorb_value(o_lat, lp["wkv_b_v"])
                 if geo.gated:
                     with jax.named_scope("attn_gate"):
@@ -529,7 +599,8 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                         lp, h[None], c.num_experts_per_tok,
                         c.norm_topk_prob, token_mask=real[None],
                         first_expert=first_expert, scoring=c.scoring_func,
-                        scale=c.routed_scaling_factor)
+                        scale=c.routed_scaling_factor, n_group=c.n_group,
+                        topk_group=c.topk_group)
                     moe.append(stats)
                     experts.append(stats.experts)
                     x = x + out[0]
@@ -551,15 +622,24 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         return (reduce(jnp.stack([getattr(s, field) for s in moe]))
                 if moe else f32(0))
 
-    counters = jnp.stack([
+    counters = [
         over("rows", jnp.sum), over("rows_padded", jnp.sum),
         over("load_max", jnp.mean), over("load_mean", jnp.mean),
-        over("touched", jnp.sum), over("rows_routed", jnp.sum),
-        L * jnp.sum(visible),
-        L * jnp.sum(jnp.minimum(visible, min(c.index_topk,
-                                             table.shape[1]
-                                             * pool_lat.shape[2]))),
-        distinct, Lf * stepped, (L - Lf) * stepped]).astype(f32)
+        over("touched", jnp.sum), over("rows_routed", jnp.sum)]
+    if dense:
+        held = [s.group_held for s in moe if s.group_held is not None]
+        counters += [
+            jnp.sum(jnp.stack(held)) if held else f32(0),
+            L * jnp.sum(jnp.maximum(seen.rows_pos + 1, 0),
+                        dtype=jnp.float32)]
+    else:
+        counters += [
+            L * jnp.sum(visible),
+            L * jnp.sum(jnp.minimum(visible, min(c.index_topk,
+                                                 table.shape[1]
+                                                 * pool_lat.shape[2]))),
+            distinct, Lf * stepped, (L - Lf) * stepped]
+    counters = jnp.stack(counters).astype(f32)
     cache = cache._replace(k=pool_lat, v=pool_idx)
     if c.sliding_layers:
         Lw = len(c.sliding_layers)
@@ -570,7 +650,10 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         cache = cache._replace(w=pool_w)
     return TrunkOut(x, cache, counters,
                     jnp.stack(experts) if experts else jnp.zeros((0,)),
-                    jnp.stack(selected), selection.n_valid,
+                    (jnp.stack(selected) if selected
+                     else jnp.zeros((0,), jnp.int32)),
+                    (seen.rows_pos + 1 if selection is None
+                     else selection.n_valid),
                     jnp.stack(windows) if windows else jnp.zeros((0,), bool),
                     probe)
 
@@ -684,10 +767,12 @@ def create_cache(config: GlmMoeDsaConfig, slots: int, n_pages: int,
 def _resolve_attn(config, impl: str, *, prefill_chunk, max_seq_len: int,
                   **_shapes):
     """One impl for both step kinds: the selected rows are gathered in
-    XLA and attended by cake_mla_attn (pallas) or the XLA fold; its VMEM
-    does not depend on the mixed width. 512 is the widest window whose
+    XLA and attended by cake_mla_attn, or a row's live pages walked by
+    cake_mla_decode_attn (pallas), or the XLA folds; their VMEM does
+    not depend on the mixed width. 512 is the widest window whose
     gathered rows (width x index_topk x latent row) stay near a
-    gigabyte."""
+    gigabyte, and the one the window kernel's bias [width, keys] was
+    sized at."""
     return impl, prefill_chunk or min(512, max_seq_len)
 
 
@@ -695,7 +780,18 @@ _DECODE_PROGRAMS = make_decode_scan(forward_ragged_latent)
 _MIXED_SAMPLED = make_mixed_sampled(mixed_step_latent)
 
 
-def _family(name: str, counters: tuple, beside=None) -> Family:
+def _family(name: str, counters: tuple, beside=None, *,
+            impl: str = "paged-dsa-", kernel_rows: tuple = (),
+            stored: str = "latent row and index key",
+            prefix_needs: str = (
+                "a shared head would need its latent rows and its index "
+                "keys (and a windowed model's ring) mapped together"),
+            windows: Windows = Windows.DISPATCH) -> Family:
+    """impl, kernel_rows: what the step records call the attention, and
+    the step kinds whose rows a kernel walks page by page (the host
+    counts those pages as it does cake_decode_attn's: the same rule);
+    stored, prefix_needs: what a token leaves in the pool, and why a
+    prefix cannot be mapped onto it yet."""
     pool = f"the latent page pool ({name})"
     return Family(
         name=name, decode_step=decode_step_latent,
@@ -703,15 +799,14 @@ def _family(name: str, counters: tuple, beside=None) -> Family:
         mixed_sampled=_MIXED_SAMPLED, create_cache=create_cache,
         counters=counters,
         # one window a dispatch (module docstring), so one packed size
-        prefill_rows=(1,), windows=Windows.DISPATCH, beside=beside,
-        impl="paged-dsa-", resolve_attn=_resolve_attn, kernel_rows=(),
+        prefill_rows=(1,), windows=windows, beside=beside,
+        impl=impl, resolve_attn=_resolve_attn, kernel_rows=kernel_rows,
         what="latent attention over the page pool",
         refuses=cannot_move(
-            "latent row and index key",
+            stored,
             register_prefix=(
-                f"{pool} has no prefix pages yet: a shared head would "
-                "need its latent rows and its index keys (and a windowed "
-                "model's ring) mapped together (ROADMAP.md)"),
+                f"{pool} has no prefix pages yet: {prefix_needs} "
+                "(ROADMAP.md)"),
             reconfigure=(
                 f"{pool} serves on pages only: there is no dense or "
                 "quantized pool to switch to")))
@@ -720,3 +815,19 @@ def _family(name: str, counters: tuple, beside=None) -> Family:
 FAMILY = _family("glm_moe_dsa", COUNTERS)
 WINDOWED = _family("dots3_note", COUNTERS + SWA_COUNTERS,
                    ("window pool (a ring a row)", None))
+# one window a STEP: the prompts mid-prefill take turns, and every
+# decode row rides every dispatch. Read against DISPATCH on the chip
+# at a steady state of dsv2.code-closed's traffic (its ramp stretched
+# to 80 s, the same two seeds under each; PERF.md section 6, PR 45):
+# DISPATCH reads the lower tpot_p50_ms (68.5 / 71.9 ms against 94.6 /
+# 94.3) and 2.7 % more out_tok_s (336.3 / 337.0 against 327.6 / 327.7),
+# and holds a request's first token back 12.5 / 12.7 s (1.4 / 1.5 with
+# turns) and every decoding row 0.92 - 0.99 s at each mixed step of 6 -
+# 7 dispatches (with turns its longest gap is one dispatch, 156 ms).
+# Turns are taken for the first token and the longest gap, against
+# both of the cell's judged readings
+DENSE = _family("deepseek_v2", DENSE_COUNTERS, impl="paged-mla-",
+                kernel_rows=("decode",), stored="latent row",
+                prefix_needs=("the prefix path prefills and maps K/V "
+                              "pages, not latent rows"),
+                windows=Windows.STEP)

@@ -56,7 +56,8 @@ def _draws(rng: jax.Array, dtype, bits: Optional[int], n_keys: int):
 
 
 def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
-    """The seeded tree of a GlmMoeDsaConfig. Leaves are stacked per
+    """The seeded tree of a GlmMoeDsaConfig (and of the config classes
+    over it). Leaves are stacked per
     KIND of layer, so each has the leading axis of the layers that have
     it: the attention leaves [L, ...], the indexer's [L_full, ...]
     (layers whose indexer_types entry is "full"), the dense FFN's
@@ -76,6 +77,8 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     Ld = c.num_hidden_layers - Ls
     E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
                      c.moe_intermediate_size, c.intermediate_size)
+    # the shared experts are ONE MLP of their summed width
+    Fs = c.n_shared_experts * Fe
     w, mat, keys = _draws(rng, dtype, bits, 40)
 
     def near(shape, centre):
@@ -121,10 +124,16 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
         "we_gate": mat("we_gate", (Ls, E, D, Fe), D),
         "we_up": mat("we_up", (Ls, E, D, Fe), D),
         "we_down": mat("we_down", (Ls, E, Fe, D), Fe),
-        "ws_gate": mat("ws_gate", (Ls, D, Fe), D),
-        "ws_up": mat("ws_up", (Ls, D, Fe), D),
-        "ws_down": mat("ws_down", (Ls, Fe, D), Fe),
+        "ws_gate": mat("ws_gate", (Ls, D, Fs), D),
+        "ws_up": mat("ws_up", (Ls, D, Fs), D),
+        "ws_down": mat("ws_down", (Ls, Fs, D), Fs),
     }
+    if not Lf:
+        # no layer has an indexer (deepseek_v2), and its softmax rule
+        # has no selection bias
+        for name in ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w",
+                     "router_bias"):
+            del blocks[name]
     if Ld:
         blocks.update({
             "w_gate": mat("w_gate", (Ld, D, Fd), D),

@@ -334,8 +334,24 @@ SWA_COUNTERS = (
         "cake_swa_layers_total",
         "Sliding-window layers run, summed over dispatches")),
 )
+# a model whose latent layers attend every visible key and whose
+# router is limited to groups of experts (deepseek_v2:
+# models/moe/glm_dsa.trunk's dense kind of layer)
+MLA_DENSE_COUNTERS = (
+    ("moe_tokens_group_held", _m.counter(
+        "cake_moe_tokens_group_held_total",
+        "Tokens whose chosen expert groups include the group held "
+        "here, summed over expert layers (over cake_moe_rows_routed_"
+        "total / experts a token: the share of tokens this chip's "
+        "group serves)")),
+    ("mla_keys_attended", _m.counter(
+        "cake_mla_keys_attended_total",
+        "Keys the single-token rows attended (position + 1 each), "
+        "summed over rows and latent layers: what cake_mla_decode_attn "
+        "walked")),
+)
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
-                      + CCA_COUNTERS + SWA_COUNTERS)
+                      + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)
 BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
                      "cca_tail_bytes": CCA_TAIL_BYTES}

@@ -1,7 +1,7 @@
-"""Latent attention (MLA) over SELECTED cache rows, and the sparse
-indexer's score pass: the two new pieces of attention a glm_moe_dsa layer
-runs (models/moe/glm_dsa.py; the equations are in
-models/reference/glm_moe_dsa.py).
+"""Latent attention (MLA) over SELECTED cache rows or over every live
+page of a row, and the sparse indexer's score pass: the pieces of
+attention a latent layer runs (models/moe/glm_dsa.py; the equations are
+in models/reference/glm_moe_dsa.py and deepseek_v2.py).
 
 `attend_selected`. Every head of a token attends the same list of cache
 rows (the indexer chose it), and a cache row is one latent: the normed
@@ -26,6 +26,13 @@ pages WHERE THEY LIE, densely, under a bias that is 0 on a query's
 selected keys and -1e30 elsewhere (`cake_mla_window_attn`): gathering
 528 x 2,048 rows cost 25 ms a layer, a dense pass over 12k keys 3 (my
 chip run, PR 30). `select_mask` is the selection as that mask.
+
+`attend_pages`. A layer with NO indexer (deepseek_v2) attends every
+visible key, so a row's single token walks the row's live pages where
+they lie (`cake_mla_decode_attn`: ragged_paged_attention.walk_live_pages,
+the GQA decode kernel's walk, over the one latent pool, all heads sharing a
+page): nothing is
+gathered, and a call costs what its live pages cost.
 
 `index_scores_rows`. I[b, s] = sum_j w[b, j] relu(qI[b, j] . kI[b, s])
 for one query a row against that row's whole key range, float32: the
@@ -118,6 +125,148 @@ def attend_selected(q, kv, n_valid, r: int, scale: float,
     if impl != "fold":
         raise ValueError(f"unknown latent attention impl {impl!r}")
     return _attend_fold(q, kv, n_valid, r, scale)
+
+
+# -- one query a row over every live page of the row -------------------------
+
+
+def _pages_fold(q, pool, layer, table, pos, r: int, scale: float):
+    """The kernel's recurrence in XLA: page j of every row gathered and
+    folded into the rows' float32 (m, l, acc), for j up to the longest
+    row's live pages."""
+    B, H, W = q.shape
+    P, max_pages = pool.shape[2], table.shape[1]
+    n = jnp.clip(pos // P + 1, 0, max_pages)
+
+    def page(j, stats):
+        m, l, acc = stats
+        pid = table[:, j]
+        kv = pool.at[layer, jnp.maximum(pid, 0)].get(
+            mode="promise_in_bounds").astype(q.dtype)          # [B, P, W]
+        s = jnp.einsum("bhw,bpw->bhp", q, kv,
+                       preferred_element_type=jnp.float32) * scale
+        ok = ((j * P + jnp.arange(P))[None, :] <= pos[:, None]) & (
+            (pid >= 0) & (j < n))[:, None]
+        s = jnp.where(ok[:, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(ok[:, None, :], jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * acc + jnp.einsum(
+                    "bhp,bpr->bhr", p.astype(q.dtype), kv[..., :r],
+                    preferred_element_type=jnp.float32))
+
+    _, l, acc = lax.fori_loop(
+        0, jnp.max(n), page,
+        (jnp.full((B, H, 1), NEG_INF, jnp.float32),
+         jnp.zeros((B, H, 1), jnp.float32),
+         jnp.zeros((B, H, r), jnp.float32)))
+    return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+
+
+# The page kernel's ring: a MiB of pages in flight ahead of the one
+# that folds, as the GQA decode kernel's (rpa._RING_BYTES_AHEAD, read
+# on the chip there); a latent row has no V pool, so the ring is one.
+def pages_ring_depth(page_bytes: int) -> int:
+    ahead = -(-rpa._RING_BYTES_AHEAD // page_bytes)
+    return 1 + min(max(ahead, 1), rpa._RING_PAGES_AHEAD_MAX)
+
+
+def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
+                  buf, sem, cur, acc_ref, *, r: int, scale: float,
+                  depth: int, page: int):
+    """One grid step: one ROW, its live pages of the layer's latent
+    pool walked by `rpa.walk_live_pages` (the GQA decode kernel's walk;
+    one pool, so one copy a page).
+
+    q_ref [H, W], o_ref [H, r]; buf [depth, page, W]; sem DMA [depth];
+    cur SMEM int32 [4], the walk's; acc_ref [H, r] f32 (at 128 heads x
+    512 the accumulator is the whole register file)."""
+    H = q_ref.shape[0]
+
+    def copies(layer, pid, slot):
+        return [pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot],
+                                      sem.at[slot])]
+
+    pos, pages = rpa.walk_live_pages(layer_ref, pos_ref, table_ref, cur,
+                                     copies, depth=depth, page_size=page)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def fold(j, pid, slot, stats):
+        m_prev, l_prev = stats
+        kv = buf[slot]                                   # [page, W]
+        s = rpa._dot(q_ref[...], kv, trans_b=True) * scale   # [H, page]
+        # the causal cut: only the last live page has columns past pos
+        visible = (j * page + lax.broadcasted_iota(
+            jnp.int32, (1, page), 1)) <= pos
+        s = jnp.where(visible, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        acc_ref[...] = alpha * acc_ref[...] + rpa._dot(
+            p.astype(kv.dtype), kv[:, :r], trans_b=False)
+        return m_new, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+
+    _, l = pages(fold, (jnp.full((H, 1), NEG_INF, jnp.float32),
+                        jnp.zeros((H, 1), jnp.float32)))
+    # a row that folded nothing (idle, or an all-unmapped table) has
+    # l == 0 and a zero accumulator: zeros, as the fold gives
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                  ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("r", "scale", "interpret"))
+def _pages_pallas(q, pool, layer, table, pos, *, r: int, scale: float,
+                  interpret: bool):
+    B, H, W = q.shape
+    page = pool.shape[2]
+    depth = pages_ring_depth(page * W * pool.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_pages_kernel, r=r, scale=scale, depth=depth,
+                          page=page),
+        name="cake_mla_decode_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, r), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((depth, page, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((depth,)),
+                            pltpu.SMEM((4,), jnp.int32),
+                            pltpu.VMEM((H, r), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, r), q.dtype),
+        # the ring's copies run ahead into the next row
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos.astype(jnp.int32),
+      table.astype(jnp.int32), q, pool)
+
+
+def attend_pages(q, pool, layer, table, pos, r: int, scale: float,
+                 impl: str = "fold", interpret: Optional[bool] = None):
+    """One query a ROW (q [B, H, W], the absorbed form) over EVERY key
+    of the row up to its position: the row's live pages 0 .. pos[b] //
+    page of the latent pool [L, N, page, W], read where they lie
+    through table [B, max_pages] (-1: a hole, skipped); the query's own
+    row must already be written. pos [B] int32; a negative position is
+    a row with no query (idle, or served another way in this
+    dispatch): it takes no trip and gets zeros. Returns [B, H, r].
+
+    impl "pallas": `cake_mla_decode_attn`, grid (rows,), a dynamic
+    count of trips a row, the kernel's own ring of page copies; per
+    page [H, W] . [W, page] scores and [H, page] . [page, r] values, a
+    float32 online softmax. impl "fold": the same recurrence in XLA."""
+    if impl == "pallas":
+        if interpret is None:
+            interpret = not rpa._on_tpu()
+        return _pages_pallas(q, pool, layer, table, pos, r=r, scale=scale,
+                             interpret=interpret)
+    if impl != "fold":
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    return _pages_fold(q, pool, jnp.asarray(layer, jnp.int32), table,
+                       pos.astype(jnp.int32), r, scale)
 
 
 # -- a window's queries over their row's pages ---------------------------------

@@ -88,7 +88,9 @@ class MoEStats(NamedTuple):
     compares it with a reference's (chip_compare.py); a step program
     returns the counters alone and the compiler drops it.
     rows_routed: the real tokens' pairs over ALL the router's experts
-    (== rows unless the layer holds a share of them)."""
+    (== rows unless the layer holds a share of them). group_held: the
+    real tokens whose chosen groups include the held experts' (group-
+    limited routing on a share; None elsewhere)."""
 
     rows: jnp.ndarray
     rows_padded: jnp.ndarray
@@ -97,6 +99,8 @@ class MoEStats(NamedTuple):
     touched: jnp.ndarray
     experts: jnp.ndarray
     rows_routed: jnp.ndarray
+    # (None is no leaf: a layer scan's outputs are what they were)
+    group_held: Optional[jnp.ndarray] = None
 
 
 def router_logits(x, router_w):
@@ -106,21 +110,47 @@ def router_logits(x, router_w):
                    precision=lax.Precision.HIGHEST)
 
 
-def choose(logits, k: int, norm_topk_prob: bool,
-           scoring: str = "softmax", scale: float = 1.0, bias=None):
+def top_groups(probs, n_group: int, topk_group: int):
+    """The groups a token may choose its experts from: probs [N, E],
+    the E experts in n_group equal groups of neighbours -> [N,
+    topk_group] int32, the groups with the largest BEST score, best
+    first, ties to the lower index (DeepSeek-V2's
+    group_limited_greedy)."""
+    N, E = probs.shape
+    best = jnp.max(probs.reshape(N, n_group, E // n_group), axis=-1)
+    return lax.top_k(best, topk_group)[1].astype(jnp.int32)
+
+
+def choose_in_groups(logits, k: int, norm_topk_prob: bool,
+                     scoring: str = "softmax", scale: float = 1.0, bias=None,
+                     n_group: int = 1, topk_group: int = 1):
     """The family's rule on float32 logits [N, E], however they were
-    made -> (weights [N, k] f32, experts [N, k] int32). Scores over
-    all E experts, by `scoring` ("softmax" or "sigmoid"); the top k;
-    renormalised over the k only if `norm_topk_prob`; times `scale`.
+    made -> (weights [N, k] f32, experts [N, k] int32, groups). Scores
+    over all E experts, by `scoring` ("softmax" or "sigmoid"); the top
+    k; renormalised over the k only if `norm_topk_prob`; times `scale`.
     bias [E] f32 (GLM's e_score_correction_bias, ZAYA's balancing
     bias): added to the scores for the CHOICE only, the weights are
-    the unbiased scores."""
+    the unbiased scores. n_group > 1 (DeepSeek-V2): the choice is
+    limited to the `topk_group` groups of `top_groups`, which are
+    returned ([N, topk_group] int32; None where the rule has no
+    groups); every other group's scores are set to 0 before the top
+    k."""
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
         probs = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"unknown scoring {scoring!r}")
+    groups = None
+    if n_group > 1:
+        if bias is not None:
+            raise ValueError("group-limited routing takes no choice bias")
+        E = probs.shape[-1]
+        groups = top_groups(probs, n_group, topk_group)
+        taken = jnp.any(
+            (jnp.arange(E) // (E // n_group))[None, None, :]
+            == groups[:, :, None], axis=1)
+        probs = jnp.where(taken, probs, 0.0)
     if bias is None:
         weights, experts = lax.top_k(probs, k)
     else:
@@ -133,15 +163,24 @@ def choose(logits, k: int, norm_topk_prob: bool,
         weights = weights / (norm + 1e-20 if scoring == "sigmoid" else norm)
     if scale != 1.0:
         weights = weights * scale
-    return weights, experts.astype(jnp.int32)
+    return weights, experts.astype(jnp.int32), groups
+
+
+def choose(logits, k: int, norm_topk_prob: bool,
+           scoring: str = "softmax", scale: float = 1.0, bias=None,
+           n_group: int = 1, topk_group: int = 1):
+    """`choose_in_groups` -> (weights, experts) alone."""
+    return choose_in_groups(logits, k, norm_topk_prob, scoring, scale, bias,
+                            n_group, topk_group)[:2]
 
 
 def route(x, router_w, k: int, norm_topk_prob: bool,
-          scoring: str = "softmax", scale: float = 1.0, bias=None):
+          scoring: str = "softmax", scale: float = 1.0, bias=None,
+          n_group: int = 1, topk_group: int = 1):
     """x [N, D], router_w [D, E] -> `choose` over the linear router's
     logits."""
     return choose(router_logits(x, router_w), k, norm_topk_prob, scoring,
-                  scale, bias)
+                  scale, bias, n_group, topk_group)
 
 
 # -- the sorted dispatch -------------------------------------------------------
@@ -390,7 +429,8 @@ def _experts_ffn(x, weights, experts, valid, stacks, layer, e_local: int,
 def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             ep_axis: Optional[str] = None, token_mask=None,
             first_expert: Optional[int] = None, scoring: str = "softmax",
-            scale: float = 1.0, act: str = "silu", logits=None):
+            scale: float = 1.0, act: str = "silu", logits=None,
+            n_group: int = 1, topk_group: int = 1):
     """Sparse FFN over experts -> (out [B, S, D], MoEStats).
 
     lp leaves: router [D, E], the linear router, unless the family made
@@ -409,8 +449,8 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     back once (without `w_fc2` the result stays in the latent: one
     share's part of the sum); the router and the shared expert read h
     itself.
-    norm_topk_prob, scoring, scale: the family's rule
-    (`route`). E_local == E except where the layer holds a share: under
+    norm_topk_prob, scoring, scale, n_group, topk_group: the family's
+    rule (`choose`). E_local == E except where the layer holds a share: under
     shard_map EP each shard holds its contiguous slice and `ep_axis`
     names the mesh axis; on one chip `first_expert` (static) is the
     first of the E_local held. token_mask [B, S] bool: positions that are not real
@@ -425,9 +465,16 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     with jax.named_scope("router"):
         if logits is None:
             logits = router_logits(x, lp["router"])
-        weights, experts = choose(logits.reshape(N, -1), k, norm_topk_prob,
-                                  scoring, scale, lp.get("router_bias"))
+        logits = logits.reshape(N, -1)
+        weights, experts, groups = choose_in_groups(
+            logits, k, norm_topk_prob, scoring, scale,
+            lp.get("router_bias"), n_group, topk_group)
         routed = experts
+        took = None
+        if groups is not None and first_expert is not None:
+            # the tokens whose groups include the held experts'
+            held = first_expert // (logits.shape[-1] // n_group)
+            took = jnp.any(groups == held, axis=-1)
 
     from cake_tpu.ops.quant import qmatmul
 
@@ -455,6 +502,9 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     out, plan = _experts_ffn(x_in, weights, experts, valid, stacks, layer,
                              e_local, act)
     stats = plan_stats(plan, routed, rows_routed)
+    if took is not None:
+        stats = stats._replace(group_held=jnp.sum(
+            took if mask is None else took & mask, dtype=jnp.float32))
     if mask is not None:
         out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:

@@ -166,42 +166,35 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
     return m_new, l_new, acc * alpha + jnp.concatenate(outs, axis=0)
 
 
-def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
-                   depth: int, page_size: int, kv_heads: int,
-                   **fold_shape):
-    """One grid step: one ROW of the ragged decode fold. The kernel
-    walks the row's live pages itself, 0 .. pos // page, and fetches
-    page table[row, j] of the layer out of the pool (whole, in HBM)
-    with its own copies into a ring of `depth` VMEM slots, K and V of
+def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
+                    depth: int, page_size: int):
+    """The walk of a decode kernel whose grid step is one ROW: the row's
+    live pages 0 .. pos // page, fetched out of pools that lie whole in
+    HBM by the kernel's own copies into rings of `depth` VMEM slots,
     the pages ahead in flight while page j folds.
 
-    sk_ref/sv_ref (a quantized pool only): the layer's flat scales
-    q_ref/o_ref:   [1, 1, H, hd], the row's query and result
-    k_hbm/v_hbm:   [L, N_pages, page, KV*hd], never read but by a copy
-    kbuf/vbuf:     [depth, page, KV*hd] VMEM; sem: DMA [2, depth]
-    cur:           SMEM int32 [4]: the copies' cursor (row, page, count
-                   of pages started) and the count of pages folded,
-                   carried from row to row: the pages ahead are the
-                   NEXT rows' when this row's run out, so the ring is
-                   warm at every row but the call's first.
-    """
-    if quantized:
-        sk_ref, sv_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur = refs
+    copies(layer, pid, slot): the async copies of the layer's page
+             `pid` into ring slot `slot`, one a pool (K and V; a latent
+             pool is a list of one), the same to start and to wait on.
+    cur:     SMEM int32 [4]: the copies' cursor (row, page, count of
+             pages started) and the count of pages folded, carried from
+             row to row: the pages ahead are the NEXT rows' when this
+             row's run out, so the ring is warm at every row but the
+             call's first. An unmapped hole inside the live range
+             starts no copy and folds nothing; a row with no live page
+             (pos < 0) takes no trip.
+
+    Primes the ring at the call's first row, then returns (pos, pages):
+    the row's position, and pages(fold, stats) -> stats, the loop over
+    the row's live pages with fold(j, pid, slot, stats) called once the
+    copies of logical page j (page id pid) have landed in `slot`."""
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer = layer_ref[0]
     max_pages = table_ref.shape[1]
-    H, hd = q_ref.shape[2:]
 
     def live_pages(row):
         return jnp.clip(pos_ref[row] // page_size + 1, 0, max_pages)
-
-    def copies(pid, slot):
-        return [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot],
-                                      sem.at[i, slot])
-                for i, (pool, buf) in enumerate(((k_hbm, kbuf),
-                                                 (v_hbm, vbuf)))]
 
     def next_live_row(row):
         return jax.lax.while_loop(
@@ -220,7 +213,7 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
 
             @pl.when(pid >= 0)
             def _():
-                for c in copies(pid, count % depth):
+                for c in copies(layer, pid, count % depth):
                     c.start()
 
             cur[2] = count + 1
@@ -251,15 +244,56 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
     n = live_pages(b)
     first = cur[3]
     cur[3] = first + n
-    pos = pos_ref[b]
+
+    def pages(fold, stats):
+        def landed(j, pid, stats):
+            slot = (first + j) % depth
+            for c in copies(layer, pid, slot):
+                c.wait()
+            return fold(j, pid, slot, stats)
+
+        def page(j, stats):
+            # the slot this frees held page j - 1, folded a step ago
+            start_next()
+            pid = table_ref[b, j]
+            return jax.lax.cond(pid >= 0, lambda s: landed(j, pid, s),
+                                lambda s: s, stats)
+
+        return jax.lax.fori_loop(0, n, page, stats)
+
+    return pos_ref[b], pages
+
+
+def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
+                   depth: int, page_size: int, kv_heads: int,
+                   **fold_shape):
+    """One grid step: one ROW of the ragged decode fold, its live pages
+    of the layer walked by `walk_live_pages`, K and V together.
+
+    sk_ref/sv_ref (a quantized pool only): the layer's flat scales
+    q_ref/o_ref:   [1, 1, H, hd], the row's query and result
+    k_hbm/v_hbm:   [L, N_pages, page, KV*hd], never read but by a copy
+    kbuf/vbuf:     [depth, page, KV*hd] VMEM; sem: DMA [2, depth]
+    cur:           SMEM int32 [4], the walk's
+    """
+    if quantized:
+        sk_ref, sv_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur = refs
+    H, hd = q_ref.shape[2:]
+
+    def copies(layer, pid, slot):
+        return [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot],
+                                      sem.at[i, slot])
+                for i, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf)))]
+
+    pos, pages = walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies,
+                                 depth=depth, page_size=page_size)
     q = q_ref[0, 0]                                # [H, hd]
     if quantized:
         q = q.astype(jnp.float32)
 
-    def fold(j, pid, stats):
-        slot = (first + j) % depth
-        for c in copies(pid, slot):
-            c.wait()
+    def fold(j, pid, slot, stats):
         scales = None
         if quantized:
             def scales(kv):
@@ -269,17 +303,9 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
                             stats, page_size=page_size, kv_heads=kv_heads,
                             **fold_shape)
 
-    def page(j, stats):
-        # the slot this frees held page j - 1, folded a step ago
-        start_next()
-        pid = table_ref[b, j]
-        return jax.lax.cond(pid >= 0, lambda s: fold(j, pid, s),
-                            lambda s: s, stats)
-
-    _, l, acc = jax.lax.fori_loop(
-        0, n, page, (jnp.full((H, 1), NEG_INF, jnp.float32),
-                     jnp.zeros((H, 1), jnp.float32),
-                     jnp.zeros((H, hd), jnp.float32)))
+    _, l, acc = pages(fold, (jnp.full((H, 1), NEG_INF, jnp.float32),
+                             jnp.zeros((H, 1), jnp.float32),
+                             jnp.zeros((H, hd), jnp.float32)))
     # a row that folded no page (an idle slot, an all-unmapped table)
     # has l == 0 and started no copy: emit zeros, matching the fold
     # reference's merge_attention_stats guard

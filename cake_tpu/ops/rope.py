@@ -13,19 +13,90 @@ recompute per step).
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple
+
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
 def precompute_rope(head_dim: int, max_seq_len: int, theta: float = 10000.0,
-                    dtype=jnp.float32):
-    """(cos, sin) tables of shape [max_seq_len, head_dim//2]."""
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+                    dtype=jnp.float32, yarn: "Yarn | None" = None):
+    """(cos, sin) tables of shape [max_seq_len, head_dim//2]. yarn: the
+    config's `rope_scaling` of type yarn (the frequencies blended, the
+    tables times its attention factor); None: plain RoPE."""
+    if yarn is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                      / head_dim)
+        )
+        factor = None
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn),
+                               jnp.float32)
+        factor = yarn.table_factor
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)  # [S, hd/2]
-    return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    if factor is not None and factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos.astype(dtype), sin.astype(dtype)
+
+
+class Yarn(NamedTuple):
+    """config.json `rope_scaling` of type "yarn" (DeepSeek-V2's keys):
+    positions past `original` (the trained length) are reached by
+    dividing the SLOW frequencies by `factor` and keeping the fast ones,
+    with a linear ramp between the pairs that turn beta_fast and
+    beta_slow times over the trained length."""
+
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def magnitude(scale: float, a: float) -> float:
+        """m(s, a) = 0.1 a ln s + 1 (1 at or under the trained length)."""
+        return 1.0 if scale <= 1 else 0.1 * a * math.log(scale) + 1.0
+
+    @property
+    def table_factor(self) -> float:
+        """What cos and sin are multiplied by."""
+        return (self.magnitude(self.factor, self.mscale)
+                / self.magnitude(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        """What the softmax scale head_dim^-0.5 is multiplied by:
+        m(factor, mscale_all_dim)^2 (1 where the key is absent or 0)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.magnitude(self.factor, self.mscale_all_dim) ** 2
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: Yarn) -> np.ndarray:
+    """The dim/2 blended frequencies, float64: with f_i = theta^(-2i/dim)
+    and c(n) = dim ln(original / (2 pi n)) / (2 ln theta), the pair at
+    which n turns fit the trained length, low = max(floor(c(beta_fast)),
+    0), high = min(ceil(c(beta_slow)), dim - 1), ramp r_i = clip((i -
+    low) / (high - low), 0, 1): inv_freq_i = (f_i / factor) r_i + f_i
+    (1 - r_i)."""
+    f = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+
+    def c(n: float) -> float:
+        return (dim * math.log(yarn.original / (n * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(c(yarn.beta_fast)), 0)
+    high = min(math.ceil(c(yarn.beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / span,
+                   0.0, 1.0)
+    return (f / yarn.factor) * ramp + f * (1.0 - ramp)
 
 
 def rope_rows(cos, sin, pos, seq_len: int):

@@ -19,7 +19,8 @@ from cake_tpu.models.llama.config import MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
-    Dots3NoteConfig, GlmMoeDsaConfig, MoEConfig, NemotronHConfig, ZayaConfig,
+    DeepseekV2Config, Dots3NoteConfig, GlmMoeDsaConfig, MoEConfig,
+    NemotronHConfig, ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
 
@@ -32,12 +33,14 @@ TINY = {
     "olmoe": MoEConfig.tiny_olmoe,
     "glm_moe_dsa": GlmMoeDsaConfig.tiny_glm,
     "dots3_note": Dots3NoteConfig.tiny_dots3,
+    "deepseek_v2": DeepseekV2Config.tiny_dsv2,
     "nemotron_h": NemotronHConfig.tiny_nemotron,
     "zaya": ZayaConfig.tiny_zaya,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
          "dots3_note": "latent row and index key",
+         "deepseek_v2": "latent row",
          "nemotron_h": "state", "zaya": "tail"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
@@ -194,7 +197,8 @@ def test_the_readmes_table_is_the_families_tables():
 # -- the seam stays where it is ------------------------------------------------
 
 NAMES = ("kv_lora_rank", "mamba_layers", "cca_time0", "sliding_layers",
-         "nemotron", "zaya", "glm", "dots3")
+         "nemotron", "zaya", "glm", "dots3", "deepseek", "rope_scaling",
+         "n_group")
 
 
 @pytest.mark.parametrize("where", ["cake_tpu/serve/engine.py",

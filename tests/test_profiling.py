@@ -194,6 +194,7 @@ def _kernel_calls():
         "cake_moe_gmm": _moe_gmm_call,
         "cake_mla_attn": _mla_calls()[0],
         "cake_mla_window_attn": _mla_calls()[1],
+        "cake_mla_decode_attn": _mla_calls()[2],
     }
 
 
@@ -209,7 +210,11 @@ def _mla_calls():
         lambda: mla.attend_window(
             q, pool, jnp.int32(1), jnp.zeros(2, jnp.int32),
             jnp.zeros((8, 16), jnp.float32), jnp.int32(7), 16, 0.2,
-            impl="pallas", interpret=True))
+            impl="pallas", interpret=True),
+        lambda: mla.attend_pages(
+            q, pool, jnp.int32(1), jnp.zeros((8, 2), jnp.int32),
+            jnp.full(8, 9, jnp.int32), 16, 0.2, impl="pallas",
+            interpret=True))
 
 
 def _moe_gmm_call():
@@ -224,7 +229,7 @@ def _moe_gmm_call():
 @pytest.mark.parametrize("name", [
     "cake_decode_attn", "cake_mixed_attn", "cake_flash_prefill",
     "cake_flash_prefill_cached", "cake_int4_matmul", "cake_moe_gmm",
-    "cake_mla_attn", "cake_mla_window_attn"])
+    "cake_mla_attn", "cake_mla_window_attn", "cake_mla_decode_attn"])
 def test_pallas_calls_carry_their_names(name):
     """A kernel event is recognised by name, not by the rank of its
     result: every pl.pallas_call in ops/ passes name=."""
@@ -245,7 +250,7 @@ def test_every_pallas_call_site_is_named():
             # the call's own argument list, up to the operands' call
             named += 'name="cake_' in text[m.end():m.end() + 1200].split(
                 ")(")[0]
-    assert sites == named == 8
+    assert sites == named == 9
 
 
 def test_scopes_leave_the_compiled_program_unchanged(monkeypatch):

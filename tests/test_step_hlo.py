@@ -115,3 +115,36 @@ ENTRY %main (w: s8[32,4096,4096]) -> s8[32,4096,4096] {
         ("constant_dynamic-slice_fusion.4", "s8[1,4096,4096]", "fusion"),
         ("copy.41", "s8[1,4096,4096]", "copy"),
     ]
+
+
+def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
+                                                              one_chip):
+    """`cake_mla_decode_attn` at dsv2.code-closed's shapes (32 rows, 128
+    heads over a 640-wide stored row, 512-wide values, pages of 128,
+    40 pages a row) goes through Mosaic: its ring of eight 160 KiB
+    pages, the [128, 512] float32 accumulator and the dynamic trip
+    count fit a v5e core's scoped VMEM and SMEM."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import mla_attention as mla
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, pool, table, pos):
+        return mla.attend_pages(q, pool, jnp.int32(1), table, pos, 512,
+                                0.1147, "pallas")
+
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(call).lower(
+                sds((32, 128, 640), jnp.bfloat16),
+                sds((2, 64, 128, 640), jnp.bfloat16),
+                sds((32, 40), jnp.int32), sds((32,), jnp.int32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_mla_decode_attn" in hlo
+    assert mla.pages_ring_depth(128 * 640 * 2) * 128 * 640 * 2 < 2 * 2**20
