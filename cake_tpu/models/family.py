@@ -108,6 +108,12 @@ class Family:
     # the step kinds whose rows go through cake_decode_attn /
     # cake_mixed_attn as they are (the host counts their pages / tiles)
     kernel_rows: tuple = ("decode", "mixed")
+    # a family whose windows go through the latent window kernel:
+    # window_walk(config, cache, width) -> (a window's last position ->
+    # (pages a query tile walks, softmax updates they take), over the
+    # layers of a dispatch); the host counts them into the mixed
+    # records. None: no such kernel
+    window_walk: Optional[Callable] = None
     # what its rows hold (the message's head) and cannot move yet:
     # option -> reason (cannot_move)
     what: str = ""

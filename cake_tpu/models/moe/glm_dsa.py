@@ -47,8 +47,9 @@ config.indexer_types a layer is FULL (its own indexer), SHARED (GLM
 only), DENSE (deepseek_v2 only: no indexer and no Selection: every
 visible key is attended, `attend_dense`: a row's single token walks its
 row's live pages where they lie, `cake_mla_decode_attn`, nothing is
-gathered; a window attends under causality alone as the bias; the FFN's
-router is limited to groups of experts), or SLIDING (dots3_note only): no indexer,
+gathered; a window attends under causality alone, which the window
+kernel reads off its positions: no bias array; the FFN's router is
+limited to groups of experts), or SLIDING (dots3_note only): no indexer,
 its rows in the sliding layers' own pool behind the ring table
 (paged.WindowedPagedCache), its scopes `swa_q`, `swa_kv`, `swa_gather`,
 `swa_attn`. A sliding layer's single token gathers the rows at
@@ -381,50 +382,40 @@ def attend(q_cat, pool_lat, layer: int, table, slot, first,
                          out[slot])
 
 
-class Visible(NamedTuple):
+def visible_keys(slot, position, real, first, window: Optional[Window]):
     """What a layer with no indexer attends, the same in every such
-    layer of a dispatch: rows_pos [B], each row's single token's
-    position (-1: the row has none here: idle, or the window's row),
-    and the window's causal bias [C, S] float32 (None: no window)."""
-
-    rows_pos: jnp.ndarray
-    bias: Optional[jnp.ndarray]
-
-
-def visible_keys(slot, position, real, first, table, page: int,
-                 window: Optional[Window]) -> Visible:
+    layer of a dispatch: each row's single token's position [B] (-1:
+    the row has none here: idle, or the window's row). The window's
+    tokens attend under causality, which its positions say."""
     rows = jnp.arange(first.shape[0])
     # (an idle row's first packed index is its successor's: not its)
     single = real[first] & (slot[first] == rows)
-    bias = None
     if window is not None:
         single = single & ~((rows == window.row) & jnp.any(window.real))
-        span = jnp.arange(table.shape[1] * page)[None, :]
-        bias = jnp.where(span <= window.positions[:, None], 0.0,
-                         mla.NEG_INF).astype(jnp.float32)
-    return Visible(jnp.where(single, position[first], -1), bias)
+    return jnp.where(single, position[first], -1)
 
 
 def attend_dense(q_cat, pool_lat, layer: int, table, slot, first,
-                 visible: Visible, geo: LatentGeometry, attn: str,
+                 rows_pos, geo: LatentGeometry, attn: str,
                  window: Optional[Window]):
     """Every token over EVERY visible key of its row -> the attended
-    latent [T, H, R]; nothing is gathered. A row's single token walks
-    the row's live pages where they lie (`cake_mla_decode_attn`), in
-    the decode program and in the mixed program alike. The window's
-    tokens: their row's pages under causality as the bias
+    latent [T, H, R]; nothing is gathered. A row's single token
+    (rows_pos: visible_keys) walks the row's live pages where they lie
+    (`cake_mla_decode_attn`), in the decode program and in the mixed
+    program alike. The window's tokens: their row's pages under
+    causality, which the kernel reads off their positions
     (`cake_mla_window_attn`)."""
     scale = geo.softmax_scale
     with jax.named_scope("mla_attn"):
         out = mla.attend_pages(q_cat[first], pool_lat, jnp.int32(layer),
-                               table, visible.rows_pos, geo.kv_lora_rank,
-                               scale, impl=attn)
+                               table, rows_pos, geo.kv_lora_rank, scale,
+                               impl=attn)
         if window is None:
             return out
         win = mla.attend_window(
             _window_slice(q_cat, window), pool_lat, jnp.int32(layer),
-            table[window.row], visible.bias, window.last_pos,
-            geo.kv_lora_rank, scale, impl=attn)
+            table[window.row], None, window.last_pos, geo.kv_lora_rank,
+            scale, impl=attn, positions=window.positions)
         return jnp.where(window.member[:, None, None], win[window.col],
                          out[slot])
 
@@ -540,8 +531,7 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     selection = None
     dense = "dense" in c.indexer_types
     if dense:
-        seen = visible_keys(slot, position, real, first, table,
-                            pool_lat.shape[2], window)
+        seen = visible_keys(slot, position, real, first, window)
     moe, experts, selected, windows, probe = [], [], [], [], ()
     distinct = jnp.float32(0)
     with jax.named_scope("layers"):
@@ -630,7 +620,7 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         held = [s.group_held for s in moe if s.group_held is not None]
         counters += [
             jnp.sum(jnp.stack(held)) if held else f32(0),
-            L * jnp.sum(jnp.maximum(seen.rows_pos + 1, 0),
+            L * jnp.sum(jnp.maximum(seen + 1, 0),
                         dtype=jnp.float32)]
     else:
         counters += [
@@ -652,7 +642,7 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                     jnp.stack(experts) if experts else jnp.zeros((0,)),
                     (jnp.stack(selected) if selected
                      else jnp.zeros((0,), jnp.int32)),
-                    (seen.rows_pos + 1 if selection is None
+                    (seen + 1 if selection is None
                      else selection.n_valid),
                     jnp.stack(windows) if windows else jnp.zeros((0,), bool),
                     probe)
@@ -776,6 +766,43 @@ def _resolve_attn(config, impl: str, *, prefill_chunk, max_seq_len: int,
     return impl, prefill_chunk or min(512, max_seq_len)
 
 
+def window_walk(config, cache, width: int):
+    """What the engine counts into a mixed record (Family.window_walk):
+    a window's last position -> (pages, folds) a query tile of the
+    window kernel walks in one dispatch, over every layer: the row's
+    live pages in a layer of the latent pool, the whole ring in a
+    sliding layer, at the pages a fold that the kernel takes for that
+    kind of layer's shapes (ops/mla_attention.window_tiles, as
+    attend / attend_dense / attend_sliding call it)."""
+    P = cache.k.shape[2]
+    kinds = []      # (layers, the fixed last index of a ring, pages, block)
+
+    def kind(layers, pool, table, biased: bool, ring: bool):
+        geo, pages = config.geometry(layers[0]), table.shape[1]
+        _tq, block = mla.window_tiles(
+            width, geo.heads, pool.shape[-1], geo.kv_lora_rank, P, pages,
+            pool.dtype.itemsize, biased)
+        kinds.append((len(layers), pages * P - 1 if ring else None, pages,
+                      block))
+
+    if config.latent_layers:
+        kind(config.latent_layers, cache.k, cache.table,
+             "dense" not in config.indexer_types, False)
+    if config.sliding_layers:
+        kind(config.sliding_layers, cache.w, cache.wtable, True, True)
+
+    def walk(last_pos: int):
+        pages = folds = 0
+        for layers, ring_last, max_pages, block in kinds:
+            p, f = mla.window_walk(
+                last_pos if ring_last is None else ring_last, P, max_pages,
+                block)
+            pages, folds = pages + layers * p, folds + layers * f
+        return pages, folds
+
+    return walk
+
+
 _DECODE_PROGRAMS = make_decode_scan(forward_ragged_latent)
 _MIXED_SAMPLED = make_mixed_sampled(mixed_step_latent)
 
@@ -801,6 +828,7 @@ def _family(name: str, counters: tuple, beside=None, *,
         # one window a dispatch (module docstring), so one packed size
         prefill_rows=(1,), windows=windows, beside=beside,
         impl=impl, resolve_attn=_resolve_attn, kernel_rows=kernel_rows,
+        window_walk=window_walk,
         what="latent attention over the page pool",
         refuses=cannot_move(
             stored,

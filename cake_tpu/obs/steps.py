@@ -215,6 +215,18 @@ _DECODE_ATTN_PAGES_TABLE = _m.counter(
     "what a kernel that stepped through the whole table would visit "
     "(over it, cake_decode_attn_pages_total is the share that holds "
     "work)")
+_WINDOW_PAGES = _m.counter(
+    "cake_mla_window_pages_total",
+    "Pages a query tile of the latent window kernel walks, summed over "
+    "the layers that run it and the mixed steps' dispatches: the live "
+    "pages of the window's row (a sliding layer's ring), counted on the "
+    "host from the window's last position "
+    "(ops/mla_attention.window_walk)")
+_WINDOW_FOLDS = _m.counter(
+    "cake_mla_window_folds_total",
+    "Softmax updates those pages take a query tile, a block of pages "
+    "each (under it, cake_mla_window_pages_total is the pages a fold "
+    "shares one accumulator pass among)")
 # The step programs' counters: (record key, series) by the group a
 # family's trunk returns them in. The ORDER of a program's vector is
 # the trunk's and is stated beside it (a family's `counters`,
@@ -602,6 +614,11 @@ class StepRecord:
     # entries of the whole page table
     attn_pages: Optional[int] = None
     attn_pages_table: Optional[int] = None
+    # a mixed step of a latent family: the pages a query tile of the
+    # window kernel walks over the step's dispatches and layers, and
+    # the softmax updates they take
+    window_pages: Optional[int] = None
+    window_folds: Optional[int] = None
     # rids whose rows this step's dispatched batch contained (bounded
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
@@ -670,6 +687,9 @@ class StepRecord:
         if self.attn_pages is not None:
             out["attn_pages"] = self.attn_pages
             out["attn_pages_table"] = self.attn_pages_table
+        if self.window_pages is not None:
+            out["window_pages"] = self.window_pages
+            out["window_folds"] = self.window_folds
         if self.rids is not None:
             out["rids"] = list(self.rids)
         if self.phases:
@@ -1004,6 +1024,8 @@ class StepTelemetry:
                attn_q_tiles_window: Optional[int] = None,
                attn_pages: Optional[int] = None,
                attn_pages_table: Optional[int] = None,
+               window_pages: Optional[int] = None,
+               window_folds: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None,
@@ -1022,8 +1044,12 @@ class StepTelemetry:
         (cake_mixed_attn_q_tiles_total, ..._window_total); attn_pages /
         attn_pages_table the pages a decode step's attention kernel
         streams and the entries of its page table
-        (cake_decode_attn_pages_total, ..._table_total). rids: the
-        requests whose rows rode this dispatch (the per-request explain's step linkage). impl: the attention
+        (cake_decode_attn_pages_total, ..._table_total); window_pages /
+        window_folds the pages a tile of the latent window kernel walks
+        in a mixed step and the softmax updates they take
+        (cake_mla_window_pages_total, cake_mla_window_folds_total).
+        rids: the requests whose rows rode this dispatch (the
+        per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
         kind (default: the recorder's engine-wide flavor). moe: the
         step program's sparse-expert counters (StepRecord.moe).
@@ -1076,6 +1102,7 @@ class StepTelemetry:
                 attn_q_tiles=attn_q_tiles,
                 attn_q_tiles_window=attn_q_tiles_window,
                 attn_pages=attn_pages, attn_pages_table=attn_pages_table,
+                window_pages=window_pages, window_folds=window_folds,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
@@ -1108,6 +1135,9 @@ class StepTelemetry:
         if attn_pages is not None:
             _DECODE_ATTN_PAGES.inc(attn_pages)
             _DECODE_ATTN_PAGES_TABLE.inc(attn_pages_table)
+        if window_pages is not None:
+            _WINDOW_PAGES.inc(window_pages)
+            _WINDOW_FOLDS.inc(window_folds)
         if moe is not None:
             for key, v in rec.moe.items():
                 COUNTER_SERIES[key].inc(v)

@@ -22,10 +22,41 @@ best first, so a token's valid rows are its first n_valid.
 
 `attend_window`. A window's queries share their row's keys, and between
 them select most of what is visible, so the window attends its row's
-pages WHERE THEY LIE, densely, under a bias that is 0 on a query's
-selected keys and -1e30 elsewhere (`cake_mla_window_attn`): gathering
-528 x 2,048 rows cost 25 ms a layer, a dense pass over 12k keys 3 (my
-chip run, PR 30). `select_mask` is the selection as that mask.
+pages WHERE THEY LIE, densely, every key of whole pages up to the
+window's last position (`cake_mla_window_attn`): gathering 528 x 2,048
+rows cost 25 ms a layer, a dense pass over 12k keys 3 (my chip run,
+PR 30). `select_mask` is the selection as a mask.
+
+  * grid (C // tq,): one step a TILE of tq tokens x H heads of query
+    rows (`window_tiles`: 8 x 128 or 16 x 64 = 1,024 rows of a 640-wide
+    row), resident with its float32 accumulator while the kernel walks
+    the row's live pages 0 .. last_pos // page itself;
+  * the walk: the pool lies whole in HBM and the kernel's own copies
+    fetch a BLOCK of B pages (4; 2 where blocks of 4 would pad a short
+    table: a 9-page ring) into one of two VMEM slots, block k + 1 in
+    flight while block k folds, on into the next tile's first block.
+    Every tile walks the same pages, so the cursor is arithmetic on
+    (tile, block) and rpa.walk_live_pages does not serve here: its unit
+    is a page (a slot a page, `fold` called a page, a hole skipped a
+    page, a row-to-row cursor in SMEM over a [rows, pages] table),
+    where a fold over B pages needs them side by side in one slot;
+  * a fold: scores [rows, B * page] in one product, ONE max / exp / sum
+    and ONE alpha * acc + p . V (contracted over the B * page keys), so
+    the accumulator, four times as wide as a page's scores, is read and
+    written once a block; m and l are the loop's carries;
+  * the mask arrives one of two ways, told apart by what the caller
+    passes: a bias array [C, S] float32 (0 on a key the query attends,
+    -1e30 elsewhere: a selection, a band), of which a tile holds its tq
+    whole rows, or no array and the window's positions (causality
+    alone: a [1, keys] compare against iota a token). Either way a
+    token's row of the mask reaches its H rows of the scores by a
+    sublane broadcast (`_spread`), not through the MXU. A page past the
+    last live one or an unmapped entry is masked whatever the caller
+    says.
+
+Alone on the chip at the cells' shapes (my chip run, PR 46; PERF.md
+section 6): 8.86 -> 3.65 ms at 32 pages of 128 heads, 12.8 -> 5.4 at 96
+pages of 64 heads, the 9-page ring of 1,152-wide rows 1.36 -> 1.20.
 
 `attend_pages`. A layer with NO indexer (deepseek_v2) attends every
 visible key, so a row's single token walks the row's live pages where
@@ -272,10 +303,29 @@ def attend_pages(q, pool, layer, table, pos, r: int, scale: float,
 # -- a window's queries over their row's pages ---------------------------------
 
 
-def _window_fold(q, pool, layer, table_row, bias, r: int, scale: float):
+def _window_bias(bias, positions, table_row, last_pos, page: int):
+    """The [C, S] float32 bias both window implementations mean: the
+    caller's, or causality from the window's positions (a query past
+    last_pos, a padded one, sees what the last real one sees), and
+    NEG_INF on every key the walk does not visit: a page past
+    last_pos's, an unmapped entry."""
+    S = table_row.shape[0] * page
+    span = jnp.arange(S)
+    if bias is None:
+        at = jnp.minimum(positions, last_pos)
+        bias = jnp.where(span[None, :] <= at[:, None], 0.0, NEG_INF)
+    walked = (table_row >= 0) & (jnp.arange(table_row.shape[0])
+                                 <= last_pos // page)
+    return jnp.where(jnp.repeat(walked, page)[None, :], bias,
+                     NEG_INF).astype(jnp.float32)
+
+
+def _window_fold(q, pool, layer, table_row, bias, last_pos, positions,
+                 r: int, scale: float):
     """The reference semantics in XLA: the row's pages gathered whole,
     every (query, key) scored, the bias added."""
     C, H, W = q.shape
+    bias = _window_bias(bias, positions, table_row, last_pos, pool.shape[2])
     keys = pool.at[layer, jnp.maximum(table_row, 0)].get(
         mode="promise_in_bounds").reshape(-1, W)               # [S, W]
     s = jnp.einsum("chw,sw->chs", q, keys.astype(q.dtype),
@@ -291,119 +341,258 @@ def _window_fold(q, pool, layer, table_row, bias, r: int, scale: float):
     return out.astype(q.dtype)
 
 
-def _window_kernel(layer_ref, table_ref, last_ref, q_ref, bias_ref, kv_ref,
-                   o_ref, acc_ref, m_ref, l_ref, *, r: int, scale: float,
-                   heads: int, page: int):
-    del layer_ref, table_ref
-    j = pl.program_id(1)
+# What the window call asks of the core's 128 MiB of VMEM (the compiler
+# grants a kernel 16 unless told), and what of it window_tiles plans
+# with: the rest is the compiler's own temporaries.
+_WINDOW_VMEM_LIMIT = 48 * 2**20
+_WINDOW_VMEM_PLAN = 32 * 2**20
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j * page <= last_ref[0])
+def window_tiles(C: int, H: int, W: int, r: int, page: int, max_pages: int,
+                 itemsize: int, biased: bool):
+    """(tq, B) of a window call, from its shapes alone: tq tokens a
+    query tile, B pages a softmax update.
+
+    tq: the widest tile of (token, head) rows whose queries (two
+    buffers), accumulator and result (two) stay under 8 MiB: 16 tokens
+    at 64 heads of a 640-wide bfloat16 row, 8 at 128 heads, 8 at 64
+    heads of a 1,152-wide row with a 1,024-wide value.
+    B: the most pages of (4, 2, 1) such that the score and probability
+    tiles (float32 both, and the probabilities again in the pool's
+    type), the value product, two ring slots of B pages and, where a
+    bias array comes, its two buffers of tq whole rows fit
+    _WINDOW_VMEM_PLAN beside that tile, and such that whole blocks pad
+    the TABLE by an eighth at most: a walk's last block is computed
+    whole and masked, and a short table (a ring of 9 pages, walked
+    whole by every tile) would pay 12 pages at 4 a fold. On the chip
+    (PERF.md section 6, PR 46): 4 a fold reads 3-4 % under 2 at 16-96
+    pages, 8 what 4 does; the ring 1.20 ms at 2, 1.32 at 4."""
+    tile = lambda t: t * H * (2 * W * itemsize + (4 + 2 * itemsize) * r)
+    tq = next(t for t in (16, 8, 4, 2, 1)
+              if C % t == 0 and (tile(t) <= 8 * 2**20 or t == 1))
+    rows = tq * H
+
+    def fits(b):
+        need = (tile(tq) + rows * 4 * r + rows * b * page * (8 + itemsize)
+                + 2 * b * page * W * itemsize
+                + (2 * tq * max_pages * page * 4 if biased else 0))
+        return need <= _WINDOW_VMEM_PLAN and 8 * (-max_pages % b) <= max_pages
+
+    return tq, next(b for b in (4, 2, 1) if fits(b) or b == 1)
+
+
+def window_walk(last_pos: int, page: int, max_pages: int, block: int):
+    """(pages, folds) a query tile of the window kernel walks for a
+    window that ends at last_pos: the row's live pages, and the softmax
+    updates they take at `block` pages each. The host's count of what
+    `_window_kernel` does (the step records' window_pages /
+    window_folds)."""
+    pages = min(max(last_pos // page + 1, 0), max_pages)
+    return pages, -(-pages // block)
+
+
+def _spread(s, rows, heads: int):
+    """s [tq * heads, keys] + a token's [1, keys] row on each of its
+    `heads` rows of s (rows(t) -> token t's): sublane broadcasts."""
+    return jnp.concatenate(
+        [s[t * heads:(t + 1) * heads] + rows(t)
+         for t in range(s.shape[0] // heads)], axis=0)
+
+
+def _window_kernel(layer_ref, table_ref, last_ref, *refs, r: int,
+                   scale: float, heads: int, page: int, block: int,
+                   biased: bool):
+    """One grid step: one TILE of tq tokens x heads query rows over the
+    row's live pages 0 .. last // page, `block` of them a softmax
+    update.
+
+    The pages come by the kernel's own copies out of the pool in HBM
+    into a ring of two slots of `block` pages: block k + 1 (the next
+    tile's first, after this tile's last: every tile walks the same
+    pages) is in flight while block k folds. A page past the last live
+    one or an unmapped entry starts no copy; its columns take NEG_INF
+    and its slot keeps what lay there (the ring starts as zeros, so it
+    is finite, and a probability of exactly 0 meets it).
+
+    pos_ref (no bias array): SMEM [C], the tokens' positions: a key is
+    visible at or before its query's. bias_ref [tq, S] float32
+    otherwise: the tile's whole rows. q_ref [tq * heads, W]; o_ref,
+    acc_ref [tq * heads, r]; buf [2, block * page, W]; sem DMA
+    [2, block]."""
+    if biased:
+        q_ref, bias_ref, pool_hbm, o_ref, buf, sem, acc_ref = refs
+    else:
+        pos_ref, q_ref, pool_hbm, o_ref, buf, sem, acc_ref = refs
+    i, tiles = pl.program_id(0), pl.num_programs(0)
+    rows = q_ref.shape[0]
+    tq = rows // heads
+    max_pages = table_ref.shape[0]
+    layer, last = layer_ref[0], last_ref[0]
+    n = jnp.clip(last // page + 1, 0, max_pages)
+    nb = pl.cdiv(n, block)
+
+    def page_of(k, b):
+        """Logical page b of block k: (its id, whether it is walked)."""
+        j = k * block + b
+        pid = table_ref[jnp.minimum(j, max_pages - 1)]
+        return pid, jnp.logical_and(j < n, pid >= 0)
+
+    def copies(k, slot, act):
+        for b in range(block):
+            pid, walked = page_of(k, b)
+
+            @pl.when(walked)
+            def _():
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[layer, pid],
+                    buf.at[slot, pl.ds(b * page, page)], sem.at[slot, b]))
+
+    @pl.when(i == 0)
     def _():
-        q = q_ref[...]                                  # [tq*H, W]
-        kv = kv_ref[...]                                # [page, W]
-        s = rpa._dot(q, kv, trans_b=True) * scale       # [tq*H, page]
-        # a token's bias row reaches its H head rows through a one-hot
-        # product: rows of q are (token, head), rows of the bias tokens
-        bias = bias_ref[...]                            # [tq, page] f32
-        rows = lax.broadcasted_iota(jnp.int32, (q.shape[0], bias.shape[0]),
-                                    0) // heads
-        cols = lax.broadcasted_iota(jnp.int32, (q.shape[0], bias.shape[0]),
-                                    1)
-        s = s + jnp.dot((rows == cols).astype(jnp.float32), bias,
-                        preferred_element_type=jnp.float32)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_old - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        buf[...] = jnp.zeros_like(buf)
+
+        @pl.when(nb > 0)
+        def _():
+            copies(0, 0, lambda c: c.start())
+
+    if not biased:
+        at = [jnp.minimum(pos_ref[i * tq + t], last) for t in range(tq)]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def token(t, k, walked):
+        """Token t's [1, block * page] float32 of the mask of block k:
+        0 on a key it attends, NEG_INF elsewhere."""
+        col = lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        parts = []
+        for b in range(block):
+            j = k * block + b
+            if biased:
+                # (clamped: the last block may pass the table's end)
+                start = pl.multiple_of(
+                    jnp.minimum(j, max_pages - 1) * page, page)
+                parts.append(jnp.where(
+                    walked[b], bias_ref[pl.ds(t, 1), pl.ds(start, page)],
+                    NEG_INF))
+            else:
+                seen = jnp.logical_and(j * page + col <= at[t], walked[b])
+                parts.append(jnp.where(seen, 0.0, NEG_INF))
+        return jnp.concatenate(parts, axis=1)
+
+    def fold(k, stats):
+        m_prev, l_prev = stats
+        slot = (i * nb + k) % 2
+        # the slot this frees held the block folded a step ago
+        more = k + 1 < nb
+
+        @pl.when(jnp.logical_or(more, i + 1 < tiles))
+        def _():
+            copies(jnp.where(more, k + 1, 0), 1 - slot,
+                   lambda c: c.start())
+
+        copies(k, slot, lambda c: c.wait())
+        kv = buf[slot]                                  # [block * page, W]
+        walked = [page_of(k, b)[1] for b in range(block)]
+        # a token's mask reaches its heads' rows by sublane broadcasts
+        s = _spread(rpa._dot(q_ref[...], kv, trans_b=True) * scale,
+                    lambda t: token(t, k, walked), heads)
+        # m starts at NEG_INF / 2: a masked score (NEG_INF) lies 5e29
+        # below any m, so its exponential is exactly 0, and a row that
+        # has met no key yet keeps l == 0 and a zero accumulator
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
         acc_ref[...] = alpha * acc_ref[...] + rpa._dot(
             p.astype(kv.dtype), kv[:, :r], trans_b=False)
-        m_ref[...] = m_new
+        return m_new, alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                      ).astype(o_ref.dtype)
+    _, l = lax.fori_loop(
+        0, nb, fold, (jnp.full((rows, 1), NEG_INF / 2, jnp.float32),
+                      jnp.zeros((rows, 1), jnp.float32)))
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("r", "scale", "interpret", "scope"))
-def _window_pallas(q, pool, layer, table_row, bias, last_pos, *, r: int,
-                   scale: float, interpret: bool, scope: str = "mla"):
+def _window_pallas(q, pool, layer, table_row, bias, last_pos, positions, *,
+                   r: int, scale: float, interpret: bool,
+                   scope: str = "mla"):
     C, H, W = q.shape
     page, max_pages = pool.shape[2], table_row.shape[0]
-    # the widest tile of (token, head) rows whose queries (two buffers),
-    # accumulator and result (two) stay under half of the kernel's 16 MiB
-    # of scoped VMEM: 16 tokens at 64 heads of a 640-wide row, 8 at 128
-    # heads, 8 at 64 heads of a 1,152-wide row with a 1,024-wide value
-    tq = next(t for t in (16, 8, 4, 2, 1)
-              if C % t == 0 and (t * H * (4 * W + 8 * r) <= 8 * 2**20
-                                 or t == 1))
-
-    def kv_map(i, j, layer, table, last):
-        # pages past the window's last position repeat the last one
-        # needed: the DMA is elided, the compute skipped
-        return layer[0], table[jnp.minimum(j, last[0] // page)], 0, 0
-
+    biased = bias is not None
+    tq, block = window_tiles(C, H, W, r, page, max_pages,
+                             pool.dtype.itemsize, biased)
+    tile = lambda width: pl.BlockSpec((tq * H, width), lambda i, *_: (i, 0))
+    scalars = [jnp.reshape(layer, (1,)), table_row,
+               jnp.reshape(last_pos, (1,))]
+    if biased:
+        operands = [q.reshape(C * H, W), bias]
+        in_specs = [tile(W), pl.BlockSpec((tq, max_pages * page),
+                                          lambda i, *_: (i, 0))]
+    else:
+        scalars.append(positions)
+        operands, in_specs = [q.reshape(C * H, W)], [tile(W)]
     out = pl.pallas_call(
         functools.partial(_window_kernel, r=r, scale=scale, heads=H,
-                          page=page),
+                          page=page, block=block, biased=biased),
         name="cake_" + scope + "_window_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(C // tq, max_pages),
-            in_specs=[
-                pl.BlockSpec((tq * H, W), lambda i, j, *_: (i, 0)),
-                pl.BlockSpec((tq, page), lambda i, j, *_: (i, j)),
-                pl.BlockSpec((None, None, page, W), kv_map)],
-            out_specs=pl.BlockSpec((tq * H, r), lambda i, j, *_: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((tq * H, r), jnp.float32),
-                            pltpu.VMEM((tq * H, 1), jnp.float32),
-                            pltpu.VMEM((tq * H, 1), jnp.float32)]),
+            num_scalar_prefetch=len(scalars),
+            grid=(C // tq,),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(r),
+            scratch_shapes=[pltpu.VMEM((2, block * page, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, block)),
+                            pltpu.VMEM((tq * H, r), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((C * H, r), q.dtype),
+        # the ring's copies run ahead into the next tile
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_WINDOW_VMEM_LIMIT),
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      jnp.maximum(table_row, 0).astype(jnp.int32),
-      jnp.reshape(last_pos, (1,)).astype(jnp.int32),
-      q.reshape(C * H, W), bias, pool)
+    )(*(s.astype(jnp.int32) for s in scalars), *operands, pool)
     return out.reshape(C, H, r)
 
 
 def attend_window(q, pool, layer, table_row, bias, last_pos, r: int,
                   scale: float, impl: str = "fold",
-                  interpret: Optional[bool] = None, scope: str = "mla"):
-    """A window's C queries (q [C, H, W], the absorbed form) over ONE
-    row's pages of the latent pool [L, N, page, W], read where they lie
-    through table_row [max_pages]: no gather. bias [C, S] float32 says
-    which keys a query attends: 0 for a selected key, NEG_INF for every
-    other (unselected, invisible, or on an unmapped page); last_pos:
-    the window's last position (pages past it are not read). Returns
-    [C, H, r]; a query with no selected key gets zeros. A sliding-window
-    layer passes its row's RING of pages as table_row (key index j *
-    page + o is then the o-th token of the ring's j-th page, whatever
-    position lies there: the bias says), and its own `scope` (the
-    kernel is then named `cake_swa_window_attn`).
+                  interpret: Optional[bool] = None, scope: str = "mla",
+                  positions=None):
+    """A window's C queries (q [C, H, W], the absorbed form, in the
+    pool's type) over ONE row's pages of the latent pool [L, N, page,
+    W], read where they lie through table_row [max_pages]: no gather.
+    last_pos: the window's last position; the walk is the row's live
+    pages 0 .. last_pos // page, and an unmapped entry (-1) among them
+    is never attended. Which of the walked keys a query attends arrives
+    one of two ways:
 
-    impl "pallas": `cake_mla_window_attn`, grid (query tiles, pages),
-    the flash recurrence over the page axis, 16 tokens x 64 heads (8 x
-    128) a tile so that the MXU sees a thousand rows a page."""
+      * bias [C, S] float32: 0 for a key the query attends, NEG_INF for
+        every other (a selection, a band);
+      * bias None and positions [C] int32: causality alone, a key at or
+        before its query's position (a padded query past last_pos sees
+        what the last real one does).
+
+    Returns [C, H, r]; a query with no key to attend gets zeros. A
+    sliding-window layer passes its row's RING of pages as table_row
+    (key index j * page + o is then the o-th token of the ring's j-th
+    page, whatever position lies there: the bias says), last_pos = the
+    ring's last index, and its own `scope` (the kernel is then named
+    `cake_swa_window_attn`).
+
+    impl "pallas": `cake_mla_window_attn` (module docstring). impl
+    "fold": the same semantics in XLA, every key of the table scored."""
+    if (bias is None) == (positions is None):
+        raise ValueError("a window's mask is a bias array or its "
+                         "positions: pass one")
     if impl == "pallas":
         if interpret is None:
             interpret = not rpa._on_tpu()
         return _window_pallas(q, pool, layer, table_row, bias, last_pos,
-                              r=r, scale=scale, interpret=interpret,
-                              scope=scope)
+                              positions, r=r, scale=scale,
+                              interpret=interpret, scope=scope)
     if impl != "fold":
         raise ValueError(f"unknown latent attention impl {impl!r}")
-    return _window_fold(q, pool, layer, table_row, bias, r, scale)
+    return _window_fold(q, pool, layer, table_row, bias, last_pos, positions,
+                        r, scale)
 
 
 # -- the indexer's scores and its selection ------------------------------------

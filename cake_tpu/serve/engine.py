@@ -2683,6 +2683,11 @@ class InferenceEngine:
         self._prefix_pages_shared = 0
         self._prefix_last_hit = {}
         self.cache = self._fresh_pool(kv_pages, kv_page_size)
+        # a latent family's window kernel: its walk as the host counts
+        # it, last position -> (pages, folds) (a function of shapes)
+        self._window_walk = (
+            family.window_walk(self.config, self.cache, self._mixed_chunk)
+            if family.window_walk is not None else None)
         if family.beside is not None:
             what, gauge = family.beside
             beside = self.cache.beside_bytes()
@@ -4635,6 +4640,25 @@ class InferenceEngine:
                                     for slot in rows),
                 "attn_q_tiles_window": len(rows) * mixed_q_tiles(C, C)}
 
+    def _window_pages(self, pos, qlen, groups) -> dict:
+        """A mixed record's window_pages / window_folds (obs/steps):
+        what a query tile of the latent window kernel walks, over the
+        step's dispatches and the layers that run it. A dispatch's
+        window is the row with the most tokens, as its program picks
+        it (the first of them; a dispatch of single tokens runs the
+        kernel over that row all the same). Nothing where the family
+        has no such kernel."""
+        if self._window_walk is None:
+            return {}
+        pages = folds = 0
+        for rows in groups:
+            n = np.where(rows, qlen, 0)
+            row = int(np.argmax(n))
+            walked = self._window_walk(int(pos[row]) + max(int(n[row]), 1)
+                                       - 1)
+            pages, folds = pages + walked[0], folds + walked[1]
+        return {"window_pages": pages, "window_folds": folds}
+
     def _attn_pages(self, steps: List[tuple]) -> dict:
         """A decode record's attn_pages / attn_pages_table (obs/steps):
         the KV pages the decode attention kernel streams a layer for
@@ -4833,7 +4857,9 @@ class InferenceEngine:
                 groups = self._mixed_groups(qlen)
                 rids = planned + [pending[slot]["req"].rid
                                   for slot in chunk_rows]
-                tiles = self._attn_q_tiles(qlen, decode_rows + chunk_rows)
+                tiles = {**self._attn_q_tiles(qlen,
+                                              decode_rows + chunk_rows),
+                         **self._window_pages(pos, qlen, groups)}
             with span("dispatch"):
                 # every layer runs over the step's tokens packed out of
                 # their windows (paged.mixed_step_paged), at the smallest

@@ -290,11 +290,12 @@ DOTS3_DECODE = 48
 # seeded weights averages it away: `probe` is what holds the softmax's
 # precision, and the exact attention with bfloat16 scores and
 # probabilities must fail it. `probe_window` is the same reading of the
-# WINDOW pass (cake_mla_window_attn under the causal bias: every
-# prefill token, half of the cell's device time): the longest row's
-# last 512 positions as one window over the same pages, queries drawn
-# the same way, held to the same limit. Each limit lies between the worst the
-# served path read on the chip and the LEAST an altered reference that
+# WINDOW pass (cake_mla_window_attn under causality, as attend_dense
+# calls it: every prefill token, most of the cell's device time): the
+# longest row's last 512 positions as one window over the same pages,
+# queries drawn the same way, held to the same limit. Each limit lies
+# between the worst the served path read on the chip and the LEAST an
+# altered reference that
 # it has to hold out read there, about the geometric middle (my chip
 # runs, PR 45, seeds 0 / 1 / 2; PERF.md section 6; served | must
 # fail): mean 3.85e-3 / 3.84e-3 / 3.89e-3 | 6.84e-2 at the least (x 16
@@ -837,8 +838,8 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
     say(f"probe: kernel {probe['served']:.3e}, exact attention with a "
         f"bfloat16 softmax {probe['bf16_softmax']:.3e}")
 
-    # -- the same for the WINDOW pass (cake_mla_window_attn under the
-    # causal bias: all of prefill): the row's last C positions as
+    # -- the same for the WINDOW pass (cake_mla_window_attn under
+    # causality: all of prefill): the row's last C positions as
     # one window over the same pages, queries drawn the same way
     Cw = min(C, n_keys)
     win_pos = jnp.arange(n_keys - Cw, n_keys)
@@ -846,12 +847,10 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
                             (Cw, geo.heads, row_w), jnp.float32)
           * (DSV2_PROBE_SPREAD / (norm * geo.softmax_scale))
           ).astype(cache.k.dtype)
-    bias = jnp.where(jnp.arange(per_row * page)[None, :] <= win_pos[:, None],
-                     0.0, mla.NEG_INF).astype(jnp.float32)
     served_win = np.asarray(mla.attend_window(
-        qw, cache.k, 0, jnp.asarray(table[0]), bias, jnp.int32(n_keys - 1),
-        R, geo.softmax_scale, impl=attn), np.float64)
-    del bias
+        qw, cache.k, 0, jnp.asarray(table[0]), None, jnp.int32(n_keys - 1),
+        R, geo.softmax_scale, impl=attn,
+        positions=win_pos.astype(jnp.int32)), np.float64)
 
     @partial(jax.jit, static_argnames="scores_dtype")
     def exact_block(qb, pos_b, scores_dtype):

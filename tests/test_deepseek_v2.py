@@ -321,6 +321,159 @@ def test_ring_depth_keeps_a_mebibyte_ahead():
     assert mla.pages_ring_depth(8 * 24 * 4) == 17         # a test's
 
 
+# -- the window kernel -------------------------------------------------------
+
+WP, WPAGES = 8, 11           # a page, a table: at 4 pages a fold, 2 3/4 blocks
+
+
+def _window_case(C_, last, hole=None, dtype=jnp.float32, seed=5):
+    """A window of C_ queries that ends at `last` (the prompt's first
+    window where it is shorter than C_: the tail of the queries is
+    padding) over a table of WPAGES pages, `hole` unmapped."""
+    rng = np.random.default_rng(seed)
+    L, N, W, r, H = 2, 24, 24, 16, 4
+    pool = jnp.asarray(rng.standard_normal((L, N, WP, W)), dtype)
+    q = jnp.asarray(rng.standard_normal((C_, H, W)), dtype)
+    table = rng.permutation(N)[:WPAGES].astype(np.int32)
+    if hole is not None:
+        table[hole] = -1
+    positions = max(last - C_ + 1, 0) + np.arange(C_, dtype=np.int32)
+    return pool, q, jnp.asarray(table), jnp.asarray(positions), r
+
+
+def _window_pair(q, pool, table, bias, last, r, positions, scope="mla"):
+    return [np.asarray(mla.attend_window(
+        q, pool, 1, table, bias, jnp.int32(last), r, 0.3, impl=impl,
+        scope=scope, positions=positions), np.float32)
+        for impl in ("pallas", "fold")]
+
+
+@pytest.mark.parametrize("masked_by", ["positions", "bias"])
+@pytest.mark.parametrize("C_", [24, 32], ids=["tq8", "tq16"])
+@pytest.mark.parametrize("last", [5, 15, 31, 32, 87], ids=[
+    "inside_the_first_page", "a_pages_last_token", "a_blocks_last_token",
+    "one_past_a_block", "the_tables_end"])
+def test_window_kernel_is_its_fold(last, C_, masked_by):
+    """cake_mla_window_attn (interpreted) against the XLA fold: the
+    walk ends inside a page, on a page's end, on a block's end, one
+    page into the next block and at the table's end (its last block is
+    three pages of four), at both tile widths, causality read off the
+    positions or handed as a bias array."""
+    pool, q, table, positions, r = _window_case(C_, last)
+    assert mla.window_tiles(C_, 4, 24, r, WP, WPAGES, 4, False) == (
+        16 if C_ == 32 else 8, 4)
+    bias = None
+    if masked_by == "bias":
+        bias = jnp.where(jnp.arange(WPAGES * WP)[None, :]
+                         <= positions[:, None], 0.0, mla.NEG_INF)
+        positions = None
+    kernel, fold = _window_pair(q, pool, table, bias, last, r, positions)
+    np.testing.assert_allclose(kernel, fold, atol=2e-6)
+    assert np.abs(kernel).max() > 0.05
+
+
+@pytest.mark.parametrize("masked_by", ["positions", "bias"])
+def test_window_kernel_never_attends_an_unmapped_entry(masked_by):
+    """A hole inside the live range: the kernel starts no copy for it
+    and its columns are masked, whatever the caller's mask says of
+    them; the queries whose every visible key lies in the hole (the
+    hole is page 0 and they are the first page's) get zeros."""
+    pool, q, table, positions, r = _window_case(24, 23, hole=0)
+    bias = None
+    if masked_by == "bias":
+        bias, positions = jnp.zeros((24, WPAGES * WP), jnp.float32), None
+    kernel, fold = _window_pair(q, pool, table, bias, 23, r, positions)
+    np.testing.assert_allclose(kernel, fold, atol=2e-6)
+    if masked_by == "positions":
+        assert not kernel[:WP].any() and kernel[WP:].any(axis=(1, 2)).all()
+
+
+def test_window_kernel_is_its_fold_in_bfloat16():
+    pool, q, table, positions, r = _window_case(32, 70, dtype=jnp.bfloat16)
+    kernel, fold = _window_pair(q, pool, table, None, 70, r, positions)
+    np.testing.assert_allclose(kernel, fold, atol=2e-2)
+
+
+def test_a_window_takes_a_bias_or_its_positions():
+    pool, q, table, positions, r = _window_case(24, 40)
+    with pytest.raises(ValueError, match="bias array or its positions"):
+        mla.attend_window(q, pool, 1, table, None, jnp.int32(40), r, 0.3)
+
+
+@pytest.mark.parametrize("last,C_", [(5, 24), (31, 24), (32, 32), (87, 32)])
+def test_host_counts_what_the_window_kernel_walks(monkeypatch, last, C_):
+    """window_walk (what the engine writes into a mixed record) against
+    the interpreted kernel's own trips: every page copy it starts and
+    every score product it runs, counted by callbacks from inside the
+    kernel; and the pages it visits, read off the result (values that
+    name their page, under a query of zeros)."""
+    from jax.experimental.pallas import tpu as pltpu
+    seen = {"copies": 0, "folds": 0}
+
+    def tick(key):
+        jax.debug.callback(lambda: seen.__setitem__(key, seen[key] + 1))
+
+    class Copy:
+        def __init__(self, *args):
+            self.copy = make_copy(*args)
+
+        def start(self):
+            tick("copies")
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    def dot(a, b, *, trans_b):
+        if trans_b:
+            tick("folds")
+        return rpa_dot(a, b, trans_b=trans_b)
+
+    make_copy, rpa_dot = pltpu.make_async_copy, mla.rpa._dot
+    monkeypatch.setattr(pltpu, "make_async_copy", Copy)
+    monkeypatch.setattr(mla.rpa, "_dot", dot)
+    pool, q, table, positions, r = _window_case(C_, last, seed=last)
+    # page j of the row holds the value e_j
+    named = np.zeros(pool.shape, np.float32)
+    named[1, np.asarray(table), :, :r] = np.eye(r)[:WPAGES, None, :]
+    out = np.asarray(mla._window_pallas.__wrapped__(
+        jnp.zeros_like(q), jnp.asarray(named), jnp.int32(1), table, None,
+        jnp.int32(last), positions, r=r, scale=0.3, interpret=True))
+    jax.effects_barrier()
+    tq, block = mla.window_tiles(C_, 4, 24, r, WP, WPAGES, 4, False)
+    pages, folds = mla.window_walk(last, WP, WPAGES, block)
+    tiles = C_ // tq
+    assert seen == {"copies": tiles * pages, "folds": tiles * folds}
+    # the last real query attends every key up to `last`, evenly
+    at = int(np.flatnonzero(np.asarray(positions) == last)[0])
+    want = np.zeros(r)
+    want[:pages] = WP
+    want[pages - 1] = last % WP + 1
+    np.testing.assert_allclose(out[at, 0], want / (last + 1), atol=1e-6)
+
+
+def test_window_tiles_follow_bytes_not_names():
+    """tq and the pages a fold at the three cells' shapes (bfloat16,
+    128-token pages, a 512-token window): four pages a fold under the
+    plan the call hands the compiler, two where whole blocks of four
+    would pad the table by more than an eighth (the 9-page ring)."""
+    for H, W, r, pages, biased, tiles in (
+            (128, 640, 512, 40, False, (8, 4)),
+            (64, 640, 512, 100, True, (16, 4)),
+            (128, 640, 512, 132, True, (8, 4)),
+            (64, 1152, 1024, 9, True, (8, 2))):
+        assert mla.window_tiles(512, H, W, r, 128, pages, 2, biased) == tiles
+    assert mla.window_tiles(512, 128, 640, 512, 128, 2, 2, False) == (8, 2)
+    assert mla.window_tiles(512, 128, 640, 512, 128, 5, 2, False) == (8, 1)
+    # four 512-token pages of float32 scores and probabilities at
+    # 1,024 rows are 20 MiB: past the plan beside the tile, so two
+    assert mla.window_tiles(512, 128, 640, 512, 512, 40, 2, False) == (8, 2)
+    assert mla.window_walk(4095, 128, 40, 4) == (32, 8)
+    assert mla.window_walk(4096, 128, 40, 4) == (33, 9)
+    assert mla.window_walk(9000, 128, 40, 4) == (40, 10)
+    assert mla.window_walk(-1, 128, 40, 4) == (0, 0)
+
+
 # -- the routing rule -------------------------------------------------------
 
 
@@ -681,6 +834,29 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     assert all(0 < r["attn_pages"] <= r["attn_pages_table"] for r in decode)
     assert all(v > 0 for v in moved.values()), moved
     assert eng._mixed_buckets == (32,) and not eng._prefix_capable
+
+
+def test_mixed_records_count_the_window_kernels_walk(engine_run):
+    """window_pages / window_folds: one window a step, 4 layers, the
+    row's live pages up to the window's last position, 4 of them a
+    fold; a decode record has neither."""
+    *_, prompts, _tokens, records, _moved, eng = engine_run
+    mixed = [r for r in records if r["kind"] == "mixed"]
+    assert mixed and all("window_pages" not in r for r in records
+                         if r["kind"] != "mixed")
+    table = eng.cache.max_pages
+    for r in mixed:
+        pages = r["window_pages"] // 4
+        assert r["window_pages"] == 4 * pages and 1 <= pages <= table
+        assert r["window_folds"] == 4 * -(-pages // 4)
+    # a prompt of 70 tokens ends in a window whose last position is 69
+    assert max(r["window_pages"] for r in mixed) == 4 * (69 // PAGE + 1)
+    assert eng._window_pages(
+        np.asarray([0, 17, 0, 40]), np.asarray([1, 1, 0, 32]),
+        [np.asarray([True, True, False, False]),
+         np.asarray([False, False, False, True])]) == {
+             # (a dispatch of single tokens: its first row, as argmax)
+             "window_pages": 4 * (1 + 9), "window_folds": 4 * (1 + 3)}
 
 
 @pytest.mark.parametrize("refused,option", [

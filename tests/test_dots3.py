@@ -29,6 +29,7 @@ from cake_tpu.models.moe.config import Dots3NoteConfig, GlmMoeDsaConfig
 from cake_tpu.models.moe.params import init_params
 from cake_tpu.models.reference import dots3_note as ref
 from cake_tpu.obs import steps as obs_steps
+from cake_tpu.ops import mla_attention as mla
 from cake_tpu.ops import moe as moe_ops
 from cake_tpu.ops.quant import QTensor, qmatmul
 
@@ -400,6 +401,43 @@ def test_ring_positions_name_what_each_slot_holds():
             assert named[((p // page) % R) * page + p % page] == p
 
 
+@pytest.mark.parametrize("newest", [13, 36, 77])
+def test_window_kernel_walks_a_ring_under_the_band(newest):
+    """cake_swa_window_attn (interpreted) against the XLA fold the way
+    attend_sliding calls it: the row's ring of 9 pages as the table
+    (four blocks of two and one of one), the band of 6 keys as the bias
+    over ring_key_positions, the walk's end the ring's last index,
+    whatever the row has reached; and against the band by hand."""
+    rng = np.random.default_rng(newest)
+    L, N, P, W, R, H, ring, C_, band = 2, 12, 4, 24, 16, 4, 9, 8, 6
+    pool = jnp.asarray(rng.standard_normal((L, N, P, W)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((C_, H, W)), jnp.float32)
+    wtable = jnp.asarray(rng.permutation(N)[:ring], jnp.int32)
+    assert mla.window_tiles(C_, H, W, R, P, ring, 4, True) == (8, 2)
+    t = (newest - C_ + 1 + np.arange(C_))[:, None]
+    held = np.asarray(glm_dsa.ring_key_positions(jnp.int32(newest), P,
+                                                 ring))[None, :]
+    seen = (held >= 0) & (held <= t) & (held > t - band)
+    bias = jnp.where(seen, 0.0, mla.NEG_INF).astype(jnp.float32)
+    args = (q, pool, 1, wtable, bias, jnp.int32(ring * P - 1), R, 0.2)
+    want = np.asarray(mla.attend_window(*args, impl="fold", scope="swa"))
+    got = np.asarray(mla.attend_window(*args, impl="pallas", interpret=True,
+                                       scope="swa"))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    slots = np.asarray(pool[1, np.asarray(wtable)]).reshape(-1, W)
+    for c_ in (0, C_ - 1):
+        keys = slots[seen[c_]]
+        assert len(keys) == min(band, int(t[c_, 0]) + 1)
+        s = np.asarray(q[c_]) @ keys.T * 0.2
+        p = np.exp(s - s.max(-1, keepdims=True))
+        np.testing.assert_allclose(
+            got[c_], (p / p.sum(-1, keepdims=True)) @ keys[:, :R], atol=1e-5)
+    assert "cake_swa_window_attn" in str(jax.make_jaxpr(
+        lambda *a: mla.attend_window(*a, R, 0.2, impl="pallas",
+                                     interpret=True, scope="swa"))(
+        q, pool, 1, wtable, bias, jnp.int32(ring * P - 1)))
+
+
 def test_pools_by_kind_of_layer():
     c = model_config()
     R = ring_of(c)
@@ -760,6 +798,28 @@ def test_step_records_carry_the_window_counters(engine_run):
                if k != "dsa_index_reused"), moved
     assert moved["swa_keys_attended"] < 0.2 * moved["swa_keys_visible"]
     assert eng._mixed_buckets == (16,) and not eng._prefix_capable
+
+
+def test_mixed_records_count_both_kinds_of_walk(engine_run):
+    """window_pages / window_folds: a full layer walks the row's live
+    pages, a sliding layer its whole ring whatever the row holds, each
+    kind at its own pages a fold."""
+    c, *_, records, _moved, eng = engine_run
+    full, sliding = len(c.latent_layers), len(c.sliding_layers)
+    ring = eng.cache.ring_pages
+    mixed = [r for r in records if r["kind"] == "mixed"]
+    assert mixed and all("window_pages" not in r for r in records
+                         if r["kind"] != "mixed")
+    for r in mixed:
+        assert r["window_pages"] >= full + sliding * ring
+        assert (r["window_pages"] / 4 <= r["window_folds"]
+                <= r["window_pages"])
+    # (a ring of 5 pages folds a page at a time: blocks would pad it
+    # by more than an eighth; the table of 32, four)
+    assert ring == 5 and eng._window_walk(0) == (full + sliding * ring,
+                                                 full + sliding * ring)
+    assert eng._window_walk(89) == (full * 23 + sliding * ring,
+                                    full * 6 + sliding * ring)
 
 
 @pytest.mark.parametrize("kw,says", [

@@ -281,6 +281,46 @@ def test_attention_kernel_is_the_fold(n_valid):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+@pytest.mark.parametrize("C_,last", [(24, 23), (32, 60), (32, 95)],
+                         ids=["tq8_first_window", "tq16_mid_block",
+                              "tq16_table_end"])
+def test_window_kernel_is_the_fold_under_a_selection(C_, last):
+    """cake_mla_window_attn (interpreted) against the XLA fold under a
+    selection's bias: 0 on the keys a query selected among those it
+    sees, NEG_INF elsewhere, added on a token's every head. A query
+    that selected nothing gets zeros, whole blocks of a query's keys
+    are unselected, and what the bias allows past the walk (here every
+    key of the table, for the last query) is still not attended."""
+    rng = np.random.default_rng(C_ + last)
+    L, N, P, W, R, H, pages = 2, 16, 8, 24, 16, 4, 12
+    pool = jnp.asarray(rng.standard_normal((L, N, P, W)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((C_, H, W)), jnp.float32)
+    table = jnp.asarray(rng.permutation(N)[:pages], jnp.int32)
+    positions = max(last - C_ + 1, 0) + np.arange(C_)
+    picked = ((np.arange(pages * P)[None, :] <= positions[:, None])
+              & (rng.random((C_, pages * P)) < 0.2))
+    picked[3] = False                          # a query with no key
+    # one whose first block of four pages (first page, in the first
+    # window) is all masked, and who selected its own key
+    picked[C_ - 2, :4 * P if last >= 5 * P else P] = False
+    picked[C_ - 2, last - 1] = True
+    picked[C_ - 1] = True
+    bias = jnp.where(picked, 0.0, mla.NEG_INF).astype(jnp.float32)
+    args = (q, pool, 1, table, bias, jnp.int32(last), R, 0.2)
+    want = np.asarray(mla.attend_window(*args, impl="fold"))
+    got = np.asarray(mla.attend_window(*args, impl="pallas", interpret=True))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert not got[3].any() and np.abs(got[C_ - 2]).max() > 1e-3
+    # the last query over exactly the walked keys, by hand
+    keys = np.asarray(pool[1, np.asarray(table)]).reshape(-1, W)[
+        :(last // P + 1) * P]
+    s = np.asarray(q[C_ - 1]) @ keys.T * 0.2
+    p = np.exp(s - s.max(-1, keepdims=True))
+    np.testing.assert_allclose(got[C_ - 1],
+                               (p / p.sum(-1, keepdims=True)) @ keys[:, :R],
+                               atol=1e-5)
+
+
 def test_window_scores_are_the_rows_scores():
     """The window's blocked score pass and the one-query-a-row pass are
     one function of (query, key); blocks past the window's end are
@@ -508,6 +548,24 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     assert any(r.get("chained") for r in records if r["kind"] == "decode")
     assert all(v > 0 for v in moved.values()), moved
     assert eng._mixed_buckets == (32,) and not eng._prefix_capable
+
+
+def test_mixed_records_count_the_window_kernels_walk(engine_run):
+    """window_pages / window_folds of a mixed step: every dispatch's
+    window (one a prefilling row, so a step of k of them sums k), its
+    row's live pages in each of the model's layers, 4 a fold."""
+    c, *_, records, _moved, eng = engine_run
+    L = c.num_hidden_layers
+    mixed = [r for r in records if r["kind"] == "mixed"]
+    assert mixed and all("window_pages" not in r for r in records
+                         if r["kind"] != "mixed")
+    for r in mixed:
+        assert r["window_pages"] % L == 0 and r["window_folds"] % L == 0
+        assert (r["window_pages"] / 4 <= r["window_folds"]
+                <= r["window_pages"])
+    assert eng._window_walk(0) == (L, L)
+    assert eng._window_walk(69) == (L * 9, L * 3)
+    assert eng._window_walk(10**6) == (L * 16, L * 4)      # the table's end
 
 
 def test_counters_count_what_the_reference_attends():

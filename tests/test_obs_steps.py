@@ -69,19 +69,23 @@ def test_flight_recorder_ring_bounds():
 @pytest.mark.parametrize("kind,tiles", [
     ("mixed", {"attn_q_tiles": 30, "attn_q_tiles_window": 128}),
     ("mixed", {}), ("decode", {}),
-    ("decode", {"attn_pages": 46, "attn_pages_table": 256})])
+    ("decode", {"attn_pages": 46, "attn_pages_table": 256}),
+    ("mixed", {"window_pages": 270, "window_folds": 75})])
 def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
                                                                 tiles):
     """A mixed step whose rows go through the mixed attention kernel
     records the query tiles it folds and those of the rows' windows, a
     decode step whose rows go through the decode kernel the pages it
-    streams and the entries of its page table; the record of any other
-    step has none of the keys, and the four /metrics series move with
-    the records that have them."""
+    streams and the entries of its page table, a mixed step of a latent
+    family the pages its window kernel walks and the softmax updates
+    they take; the record of any other step has none of the keys, and
+    the six /metrics series move with the records that have them."""
     series = {"cake_mixed_attn_q_tiles_total": "attn_q_tiles",
               "cake_mixed_attn_q_tiles_window_total": "attn_q_tiles_window",
               "cake_decode_attn_pages_total": "attn_pages",
-              "cake_decode_attn_pages_table_total": "attn_pages_table"}
+              "cake_decode_attn_pages_table_total": "attn_pages_table",
+              "cake_mla_window_pages_total": "window_pages",
+              "cake_mla_window_folds_total": "window_folds"}
 
     def read():
         return [sum(float(ln.split()[-1])
@@ -91,7 +95,8 @@ def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
     st = obs_steps.StepTelemetry(impl="t", capacity=4)
     before = read()
     rec = st.record(kind, rows=16, tokens=16, wall_s=0.01, **tiles)
-    got = {k: v for k, v in rec.to_dict().items() if k.startswith("attn_")}
+    got = {k: v for k, v in rec.to_dict().items()
+           if k.startswith(("attn_", "window_"))}
     assert got == tiles
     assert [b - a for a, b in zip(before, read())] == [
         tiles.get(key, 0) for key in series.values()]

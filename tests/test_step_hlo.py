@@ -148,3 +148,47 @@ def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "cake_mla_decode_attn" in hlo
     assert mla.pages_ring_depth(128 * 640 * 2) * 128 * 640 * 2 < 2 * 2**20
+
+
+@pytest.mark.parametrize("heads,row,value,pages,masked_by,scope", [
+    (128, 640, 512, 40, "positions", "mla"),      # dsv2.code-closed
+    (64, 640, 512, 100, "bias", "mla"),           # glm52.longdoc-closed
+    (128, 640, 512, 132, "bias", "mla"),          # dots3's full layers
+    (64, 1152, 1024, 9, "bias", "swa")],          # dots3's 9-page ring
+    ids=["dsv2", "glm", "dots3_full", "dots3_ring"])
+def test_latent_window_kernel_compiles_at_the_cells_widths(
+        tool, one_chip, heads, row, value, pages, masked_by, scope):
+    """`cake_mla_window_attn` / `cake_swa_window_attn` at the three
+    latent cells' shapes (a 512-token window, pages of 128) goes through
+    Mosaic at the tiles `window_tiles` picks: the resident tile, its
+    float32 accumulator, the two ring slots of a block of pages, the
+    block's score and probability tiles and a bias array's rows fit the
+    VMEM the call asks for, above the default 16 MiB."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import mla_attention as mla
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    biased = masked_by == "bias"
+
+    def call(q, pool, table, mask, last):
+        return mla.attend_window(
+            q, pool, jnp.int32(1), table, mask if biased else None, last,
+            value, 0.1147, impl="pallas", interpret=False, scope=scope,
+            positions=None if biased else mask)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(call).lower(
+            sds((512, heads, row), jnp.bfloat16),
+            sds((2, 64, 128, row), jnp.bfloat16), sds((pages,), jnp.int32),
+            (sds((512, pages * 128), jnp.float32) if biased
+             else sds((512,), jnp.int32)), sds((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert f"cake_{scope}_window_attn" in hlo
+    tq, block = mla.window_tiles(512, heads, row, value, 128, pages, 2,
+                                 biased)
+    assert tq * heads == (1024 if row == 640 else 512)
+    assert block == (2 if pages == 9 else 4)
