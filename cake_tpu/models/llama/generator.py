@@ -24,7 +24,7 @@ from __future__ import annotations
 import logging
 import time
 from functools import partial
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -96,20 +96,56 @@ def encode_text(tokenizer, text: str) -> List[int]:
     return list(enc.ids if hasattr(enc, "ids") else enc)
 
 
-def incremental_decode(tokenizer, ids: List[int],
-                       pending: str, final: bool = False) -> Tuple[str, str]:
-    """Streaming detokenization step: (new_text, updated_pending).
+class StreamDetokenizer:
+    """Streaming detokenization of one output: `add` takes the NEW ids
+    and returns the text they finalize. It keeps its place instead of
+    decoding the output from token 0 (the offset form of
+    text-generation-inference and vLLM): of the ids so far it holds
+    only those from `prefix_offset`, the start of what the last delta
+    ended with, and decodes that window twice, up to `read_offset` and
+    whole. Both decodes start at the same id, so a decoder that treats
+    a sequence's first token specially (a stripped leading space)
+    treats both alike and the tail of the second past the first is what
+    the whole decode would have appended.
 
-    Text is held back (empty delta) while the tail decodes to an incomplete
-    UTF-8 sequence (the replacement char), so multi-token characters stream
-    whole. final=True flushes a permanently-incomplete tail at end of
-    stream — the streamed total must equal the buffered decode of the same
-    ids."""
-    full = tokenizer.decode(ids)
-    new = full[len(pending):]
-    if new.endswith("�") and not final:
-        return "", pending
-    return new, full
+    Text is held back (empty delta, the window grows) while the tail
+    decodes to an incomplete UTF-8 sequence (the replacement char) or
+    to nothing, so multi-token characters stream whole. final=True
+    flushes a permanently-incomplete tail at end of stream — the
+    streamed total must equal the buffered decode of the same ids."""
+
+    __slots__ = ("_tokenizer", "_ids", "_read", "_prefix_text",
+                 "decoded_ids")
+
+    def __init__(self, tokenizer):
+        self._tokenizer = tokenizer
+        self._ids: List[int] = []        # ids[prefix_offset:]
+        self._read = 0                   # read_offset - prefix_offset
+        # decode(ids[prefix_offset:read_offset]); None until needed
+        self._prefix_text: Optional[str] = ""
+        # ids handed to tokenizer.decode so far (obs: `detok_ids`)
+        self.decoded_ids = 0
+
+    def _decode(self, ids: List[int]) -> str:
+        self.decoded_ids += len(ids)
+        return self._tokenizer.decode(ids)
+
+    def add(self, new_ids: Sequence[int] = (), final: bool = False) -> str:
+        ids = self._ids
+        ids.extend(new_ids)
+        if len(ids) == self._read:
+            return ""   # nothing unread (a flush with no held tail)
+        if self._prefix_text is None:
+            self._prefix_text = self._decode(ids[:self._read])
+        text = self._decode(ids)
+        if len(text) <= len(self._prefix_text) or (
+                text.endswith("\ufffd") and not final):
+            return ""
+        new = text[len(self._prefix_text):]
+        self._ids = ids[self._read:]
+        self._read = len(self._ids)
+        self._prefix_text = None
+        return new
 
 
 class LlamaGenerator:
@@ -202,7 +238,7 @@ class LlamaGenerator:
         self.index_pos = 0               # absolute position in the cache
         self._ring = jnp.full((self.batch_size, self.sampling.repeat_last_n),
                               -1, dtype=jnp.int32)
-        self._pending_text = ""
+        self._detok = StreamDetokenizer(self.tokenizer)
         self._prompt_len = 0
 
     def generated_tokens(self) -> int:
@@ -253,11 +289,10 @@ class LlamaGenerator:
         if tid in self.config.eos_token_ids:
             # flush any held-back UTF-8 tail so the streamed total equals
             # the buffered decode of the same ids (engine parity)
-            tail, self._pending_text = incremental_decode(
-                self.tokenizer, self.tokens[:-1], self._pending_text,
-                final=True)
-            return Token(id=tid, text=tail, is_end_of_stream=True)
-        return Token(id=tid, text=self._decode_incremental(), is_end_of_stream=False)
+            return Token(id=tid, text=self._detok.add(final=True),
+                         is_end_of_stream=True)
+        return Token(id=tid, text=self._detok.add((tid,)),
+                     is_end_of_stream=False)
 
     # -- internals -----------------------------------------------------------
 
@@ -316,12 +351,6 @@ class LlamaGenerator:
                 self.rope, self.config,
             )
         return logits
-
-    def _decode_incremental(self) -> str:
-        """Return newly-finalized text for the freshly appended token."""
-        new, self._pending_text = incremental_decode(
-            self.tokenizer, self.tokens, self._pending_text)
-        return new
 
     # -- fully on-device generation (throughput path) ------------------------
 

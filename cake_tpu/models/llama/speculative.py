@@ -48,7 +48,7 @@ from cake_tpu.models.chat import History, Message
 from cake_tpu.models.llama.cache import KVCache
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import (
-    bucket_length, encode_text, incremental_decode,
+    bucket_length, encode_text, StreamDetokenizer,
 )
 from cake_tpu.models.llama.model import (
     RopeTables, forward, forward_logits_all, prefill,
@@ -342,7 +342,7 @@ class SpeculativeGenerator:
         self.tokens: List[int] = []
         self.index_pos = 0
         self._buffer: List[int] = []
-        self._pending_text = ""
+        self._detok = StreamDetokenizer(self.tokenizer)
 
     def generated_tokens(self) -> int:
         return len(self.tokens)
@@ -378,13 +378,10 @@ class SpeculativeGenerator:
         tid = self._buffer.pop(0)
         self.tokens.append(tid)
         if tid in self.config.eos_token_ids:
-            tail, self._pending_text = incremental_decode(
-                self.tokenizer, self.tokens[:-1], self._pending_text,
-                final=True)
-            return Token(id=tid, text=tail, is_end_of_stream=True)
-        new, self._pending_text = incremental_decode(
-            self.tokenizer, self.tokens, self._pending_text)
-        return Token(id=tid, text=new, is_end_of_stream=False)
+            return Token(id=tid, text=self._detok.add(final=True),
+                         is_end_of_stream=True)
+        return Token(id=tid, text=self._detok.add((tid,)),
+                     is_end_of_stream=False)
 
     # -- internals ------------------------------------------------------------
 

@@ -51,7 +51,9 @@ pieces, all dependency-free:
     (PARTS: one level under the phases, `StepTelemetry.part`, a
     record's `parts` and `rows_admitted`), and whether a chained step's
     successor reached the device after it had finished (`late`, from
-    the step's own fetch against LATE_FETCH_S).
+    the step's own fetch against LATE_FETCH_S). `detok_ids`: the ids
+    the streaming detokeniser handed to the tokenizer's decode inside
+    `emit` (a few a token, however long the output).
 
 MFU here is model-FLOPs utilization: (program FLOPs from
 cost_analysis) / (peak chip FLOP/s x measured step seconds), clamped to
@@ -645,6 +647,10 @@ class StepRecord:
     rows_admitted: Optional[int] = None
     # host seconds by part of a phase (PARTS) since the previous record
     parts: Optional[Dict[str, float]] = None
+    # the ids handed to the tokenizer's decode by the detokenisation
+    # inside the `emit` span since the previous record (both decodes of
+    # a token counted); absent where nothing was detokenised
+    detok_ids: Optional[int] = None
     # a chained step: the seconds its own fetch waited, and whether
     # that was no wait at all (under LATE_FETCH_S): the device had
     # finished the step before the host sent the one after it
@@ -705,6 +711,8 @@ class StepRecord:
             out["rows_admitted"] = self.rows_admitted
         if self.parts:
             out["parts"] = {k: round(v, 6) for k, v in self.parts.items()}
+        if self.detok_ids:
+            out["detok_ids"] = self.detok_ids
         if self.fetch_wait_s is not None:
             out["fetch_wait_s"] = round(self.fetch_wait_s, 6)
             out["late"] = self.late
@@ -737,7 +745,8 @@ BREAKS = ("stop", "queue", "cancel", "command", "sync", "stretch_cap",
 # or adoption (admit_pages); its sampling state and ring, the eager
 # device launches (admit_ring). dispatch: the call of the step program
 # itself, apart from the staging of its arguments. emit: the
-# detokenisation, a row a token (StepTelemetry.add_part: no annotation).
+# detokenisation, a row a token (StepTelemetry.add_part: no annotation;
+# add_detok_ids beside it counts the ids it decodes).
 PARTS = ("schedule.plan", "schedule.admit_pages", "schedule.admit_ring",
          "dispatch.launch", "emit.detok")
 _PART_SPAN = {key: key.split(".")[0] for key in PARTS}
@@ -876,6 +885,7 @@ class StepTelemetry:
         # ended, the rows admitted since
         self._open: Optional[str] = None
         self._parts: Dict[str, float] = {}
+        self._detok_ids = 0
         self._break: Optional[str] = None
         self._admitted = 0
 
@@ -921,6 +931,13 @@ class StepTelemetry:
         if span == self._open:
             self._parts[key] = self._parts.get(key, 0.0) + seconds
 
+    def add_detok_ids(self, ids: int) -> None:
+        """Ids the detokenisation of a row's token handed to the
+        tokenizer's decode (`detok_ids` of the next record). Counted
+        inside the `emit` span only, like the part's seconds."""
+        if self._open == "emit":
+            self._detok_ids += ids
+
     def chain_broke(self, cause: str) -> None:
         """A stretch of in-flight steps stopped chaining and has been
         fetched to its end (serve/engine._drive_burst): `cause` (one of
@@ -939,6 +956,7 @@ class StepTelemetry:
         """Drop the open step's phases, parts and gap: what ran belongs
         to no step (the engine's warm-up; the idle loop)."""
         self._phases, self._parts = {}, {}
+        self._detok_ids = 0
         self._gap = self._fetch_t1 = None
 
     def _close_span(self, name: str, t0: float, t1: float) -> None:
@@ -1075,6 +1093,7 @@ class StepTelemetry:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
         phases, self._phases = self._phases, {}
         parts, self._parts = self._parts, {}
+        detok_ids, self._detok_ids = self._detok_ids, 0
         gap, self._gap = (0.0 if chained else self._gap), None
         cause = admitted = late = None
         if chained:
@@ -1110,8 +1129,8 @@ class StepTelemetry:
                               strict=True))
                      if moe is not None else None),
                 chain_break=cause, rows_admitted=admitted,
-                parts=parts or None, fetch_wait_s=fetch_wait_s,
-                late=late)
+                parts=parts or None, detok_ids=detok_ids or None,
+                fetch_wait_s=fetch_wait_s, late=late)
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()

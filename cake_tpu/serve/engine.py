@@ -48,7 +48,7 @@ from cake_tpu.obs.tracing import RequestTracer
 from cake_tpu.models.llama.cache import KVCache
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import (
-    bucket_length, encode_text, incremental_decode,
+    StreamDetokenizer, bucket_length, encode_text,
 )
 from cake_tpu.models.family import Windows
 from cake_tpu.models.llama.model import (
@@ -209,7 +209,12 @@ class _Request:
     submit_t: float = 0.0
     first_token_t: float = 0.0
     finish_t: float = 0.0
-    _pending_text: str = ""
+    # the streamed text's place in out_tokens (_incremental_text): the
+    # detokeniser, made at the first delta, and how many of out_tokens
+    # it has been handed. On the request, so a preempted and requeued
+    # row goes on where its text stopped
+    _detok: Optional[StreamDetokenizer] = None
+    _detok_seen: int = 0
 
 
 class RequestHandle:
@@ -6242,11 +6247,20 @@ class InferenceEngine:
             req.done.set()
 
     def _incremental_text(self, req: _Request, final: bool = False) -> str:
-        ids = [t for t in req.out_tokens
-               if t not in self.config.eos_token_ids]
-        new, req._pending_text = incremental_decode(
-            self.tokenizer, ids, req._pending_text, final=final)
-        return new
+        """The text that the tokens emitted since the last call
+        finalize: the step's one (more only for a stream attached to a
+        request under way), EOS never among them."""
+        det = req._detok
+        if det is None:
+            det = req._detok = StreamDetokenizer(self.tokenizer)
+        eos = self.config.eos_token_ids
+        fresh = [t for t in req.out_tokens[req._detok_seen:]
+                 if t not in eos]
+        req._detok_seen = len(req.out_tokens)
+        before = det.decoded_ids
+        delta = det.add(fresh, final=final)
+        self.flight.add_detok_ids(det.decoded_ids - before)
+        return delta
 
     def _fail_all(self, err: Exception, snapshot: bool = False) -> None:
         # beat-the-reference failure handling (the reference is fail-stop

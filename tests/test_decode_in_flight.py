@@ -18,7 +18,12 @@ pinned here:
     `wall_s`, a sparse model's `moe_*`, and the chained counter;
   * which rows keep the synchronous step;
   * why each stretch ended (PR 35): `chain_break` on the next record
-    that is not chained, `cake_chain_breaks_total{cause}`.
+    that is not chained, `cake_chain_breaks_total{cause}`;
+  * the streamed text (PR 47): a token's text comes from a detokeniser
+    that keeps its place on the request; the chunks are the buffered
+    text, a held token's logprob entry rides the chunk with its text,
+    EOS never reaches the tokenizer, and the records count the ids
+    decoded (`detok_ids`).
 """
 
 import dataclasses
@@ -446,3 +451,145 @@ def test_one_step_program_is_found_by_the_benchmarks_prefix():
         assert ("jit_" + progs.step.__name__).startswith("jit_decode_step")
         assert not ("jit_" + progs.scan.__name__).startswith(
             "jit_decode_step")
+
+
+# -- the streamed text -----------------------------------------------------------
+
+
+def collect(got):
+    """A stream callback as api/server.py's: (delta, final, n_done)."""
+    def stream(delta, final, n_done=0):
+        got.append((delta, final, n_done))
+    stream.wants_count = True
+    return stream
+
+
+STREAMED = {"greedy": GREEDY,
+            "sampled": dict(temperature=0.8, top_p=0.9, repeat_penalty=1.2)}
+PROMPTS = [[5, 9, 3 + i] for i in range(3)]
+
+
+def serve_streams(eng, sampling, n=40, prompts=PROMPTS):
+    got = [[] for _ in prompts]
+    hs, _ = serve(eng, [(p, dict(max_new_tokens=n, stream=collect(g),
+                                 **STREAMED[sampling]))
+                        for p, g in zip(prompts, got)])
+    return hs, got
+
+
+def whole_output_chunks(tok, ids):
+    """The reference: `incremental_decode` as it was before PR 47, the
+    whole output decoded again at every token. [(delta, n_done)] of the
+    chunks that carry text, and the text left for the flush."""
+    sent, chunks = "", []
+    for n in range(1, len(ids) + 1):
+        full = tok.decode(ids[:n])
+        new = full[len(sent):]
+        if new and not new.endswith("\ufffd"):
+            chunks.append((new, n))
+            sent = full
+    return chunks, tok.decode(ids)[len(sent):]
+
+
+@pytest.mark.parametrize("sampling", list(STREAMED))
+@pytest.mark.parametrize("flavour", ["paged-fold", "dense", "ring"])
+def test_streamed_chunks_are_the_buffered_text(tiny_config, params, flavour,
+                                               sampling):
+    """The same prompts and seed, streamed and buffered: the chunks of a
+    stream concatenate to the text the buffered response returns."""
+    hs, got = serve_streams(
+        make_engine(tiny_config, params, **FLAVOURS[flavour]), sampling)
+    buffered, _ = serve(
+        make_engine(tiny_config, params, **FLAVOURS[flavour]),
+        [(p, dict(max_new_tokens=40, **STREAMED[sampling]))
+         for p in PROMPTS])
+    for h, chunks, b in zip(hs, got, buffered):
+        assert h.token_ids == b.token_ids
+        assert "".join(c[0] for c in chunks) == b.text() == h.text()
+        assert [c[1] for c in chunks] == [False] * (len(chunks) - 1) + [True]
+        assert b._req._detok is None          # nobody streams: no decode
+
+
+@pytest.mark.parametrize("sampling", list(STREAMED))
+def test_a_held_tokens_entry_ships_with_its_text(tiny_config, params,
+                                                 sampling):
+    """Random bytes: most tokens are half a character. A token whose
+    text is incomplete sends no chunk, and the chunk that carries its
+    text counts its logprob entry (`n_done`); a token that has text is a
+    chunk of its own in the step that produced it. Chunk for chunk what
+    the whole-output form gave."""
+    hs, got = serve_streams(
+        make_engine(tiny_config, params, **PAGED), sampling)
+    tok = ByteTokenizer(tiny_config.vocab_size)
+    held = 0
+    for h, chunks in zip(hs, got):
+        ids = h.token_ids
+        assert len(ids) == 40
+        want, tail = whole_output_chunks(tok, ids)
+        *body, last = chunks
+        assert [(d, n) for d, _, n in body] == [
+            c for c in want if c[1] < len(ids)]
+        # the last token's chunk is the final one, tail flushed
+        assert last == ("".join(d for d, n in want if n == len(ids)) + tail,
+                        True, len(ids))
+        seen = [n for _, _, n in chunks]
+        assert seen == sorted(set(seen))
+        held += sum(b - a > 1 for a, b in zip([0] + seen, seen))
+        # what a chunk's entries spell is what has been sent
+        sent = ""
+        for delta, _, n in body:
+            sent += delta
+            assert tok.decode(ids[:n]) == sent
+    assert held > 5
+
+
+def test_eos_never_reaches_the_tokenizer(tiny_config, params):
+    """The stream's end is decided on the id; the tokenizer sees the
+    ids before it only, by whatever way the text is asked for."""
+    first, _ = serve_streams(make_engine(tiny_config, params, **PAGED),
+                             "greedy", n=12, prompts=PROMPTS[:1])
+    ids = first[0].token_ids
+    eos = ids[6]
+    cut = ids[:ids.index(eos)]
+    assert cut, "the stream would end on its first token"
+
+    class Spy(ByteTokenizer):
+        seen = []
+
+        def decode(self, ids):
+            self.seen.append(list(ids))
+            return super().decode(ids)
+
+    cfg = dataclasses.replace(tiny_config, eos_token_ids=(eos,))
+    eng = make_engine(cfg, params, **PAGED)
+    eng.tokenizer = Spy(cfg.vocab_size)
+    hs, got = serve_streams(eng, "greedy", n=12, prompts=PROMPTS[:1])
+    assert hs[0]._req.out_tokens == cut + [eos]
+    assert Spy.seen and not any(eos in ids for ids in Spy.seen)
+    assert "".join(c[0] for c in got[0]) == eng.tokenizer.decode(cut)
+    # the final chunk counts the EOS entry, and carries no text of it
+    assert got[0][-1][1:] == (True, len(cut) + 1)
+    assert hs[0].text() == eng.tokenizer.decode(cut)
+    assert not any(eos in ids for ids in Spy.seen)
+
+
+def test_records_count_the_ids_the_detokeniser_decodes(tiny_config, params):
+    """`detok_ids`: the ids handed to `decode` inside the emit span
+    since the record before. A few a token however long the output; a
+    buffered request decodes nothing while it runs."""
+    eng = make_engine(tiny_config, params, **PAGED)
+    hs, _ = serve_streams(eng, "greedy", n=60)
+    recs = list(reversed(eng.flight.dump()))
+    counted = [r for r in recs if "detok_ids" in r]
+    assert counted and all(r["detok_ids"] > 0 for r in counted)
+    assert all("emit.detok" in r["parts"] for r in counted)
+    total = sum(h._req._detok.decoded_ids for h in hs)
+    # the last step's emit follows the last record
+    assert 0 < total - sum(r["detok_ids"] for r in counted) <= 3 * 40
+    # random bytes: a held token widens the window, and still a
+    # quarter of what the whole-output form decoded (1 + ... + 60 a row)
+    assert total < 8 * 3 * 60
+    assert 4 * total < 3 * sum(range(1, 61))
+    quiet = make_engine(tiny_config, params, **PAGED)
+    serve(quiet, [(p, dict(max_new_tokens=20, **GREEDY)) for p in PROMPTS])
+    assert not any("detok_ids" in r for r in quiet.flight.dump())

@@ -64,6 +64,49 @@ def test_eos_detection():
             break
 
 
+@pytest.mark.parametrize("end", ["budget", "eos"])
+def test_token_texts_are_the_decode_of_the_ids(gen, end):
+    """The texts of a session's tokens concatenate to the decode of its
+    ids (PR 47: `StreamDetokenizer`, fed the new id only): short of an
+    incomplete tail while the stream runs, whole once EOS flushed it,
+    and EOS itself never reaches the tokenizer."""
+    def session(g, n):
+        g.reset()
+        g.add_message(Message.user("hello"))
+        return [g.next_token(i) for i in range(n)]
+
+    toks = session(gen, 24)
+    ids = [t.id for t in toks]
+    whole = gen.tokenizer.decode(ids)
+    text = "".join(t.text for t in toks)
+    if end == "budget":
+        assert whole.startswith(text) and text
+        assert not any(t.text.endswith("\ufffd") for t in toks)
+        assert not whole[len(text):].strip("\ufffd")
+        # a reset starts the text again
+        assert "".join(t.text for t in session(gen, 24)) == text
+        return
+    # the same stream with its 9th distinct-so-far token as EOS
+    eos = next(t for i, t in enumerate(ids) if i >= 8 and t not in ids[:i])
+    cfg = LlamaConfig.tiny(num_hidden_layers=2, eos_token_ids=(eos,))
+    seen = []
+
+    class Spy(ByteTokenizer):
+        def decode(self, ids):
+            seen.append(list(ids))
+            return super().decode(ids)
+
+    g = LlamaGenerator(cfg, gen.params, Spy(cfg.vocab_size),
+                       max_seq_len=256, cache_dtype=jnp.float32,
+                       sampling=SamplingConfig(temperature=0.0))
+    toks = session(g, ids.index(eos) + 1)
+    assert [t.id for t in toks] == ids[:ids.index(eos) + 1]
+    assert toks[-1].is_end_of_stream
+    assert "".join(t.text for t in toks) == gen.tokenizer.decode(
+        ids[:ids.index(eos)])
+    assert seen and not any(eos in s for s in seen)
+
+
 def test_prompt_too_long_raises(gen):
     gen.reset()
     gen.add_message(Message.user("y" * 500))

@@ -560,6 +560,45 @@ def test_part_seconds_land_in_parts_and_once_in_phases(monkeypatch):
     assert "parts" not in st.record("decode", wall_s=0.01).to_dict()
 
 
+@pytest.mark.parametrize("case", ["summed", "absent", "emit_only",
+                                  "discarded"])
+def test_detok_ids_since_the_record_before(case):
+    """The ids the detokenisation handed to `decode` (PR 47): summed
+    over the emit span's rows since the record before, absent where
+    nothing was emitted, counted inside `emit` only, dropped with an
+    open step that belongs to no record."""
+    st = obs_steps.StepTelemetry(impl="t")
+    if case == "summed":
+        with st.span("emit"):
+            for ids in (3, 3, 5):          # three rows' tokens
+                st.add_detok_ids(ids)
+        with st.span("emit"):              # a second emit, same record
+            st.add_detok_ids(4)
+        rec = st.record("decode", rows=3, tokens=3, wall_s=0.01)
+        assert rec.detok_ids == 15 and rec.to_dict()["detok_ids"] == 15
+        # taken by the record: the next one starts from nothing
+        assert st.record("decode", wall_s=0.01).detok_ids is None
+    elif case == "absent":
+        with st.span("emit"):
+            st.add_detok_ids(0)            # a flush with nothing held
+        with st.span("dispatch"):
+            pass
+        rec = st.record("decode", wall_s=0.01)
+        assert rec.detok_ids is None and "detok_ids" not in rec.to_dict()
+    elif case == "emit_only":
+        st.add_detok_ids(7)                # no span open
+        with st.span("admin"):
+            st.add_detok_ids(7)            # a recovered row's flush
+        with st.span("emit"):
+            st.add_detok_ids(2)
+        assert st.record("decode", wall_s=0.01).detok_ids == 2
+    else:
+        with st.span("emit"):
+            st.add_detok_ids(9)
+        st.discard_open()                  # the warm-up, the idle loop
+        assert st.record("decode", wall_s=0.01).detok_ids is None
+
+
 def test_chain_break_rides_the_next_unchained_record_only():
     breaks = m.REGISTRY.get("cake_chain_breaks_total")
     before = breaks.labels(cause="queue").value

@@ -73,14 +73,14 @@ def _uninterrupted(tiny_config, params, **kw):
         return list(h._req.out_tokens)
 
 
-def _preempted(tiny_config, params, **kw):
+def _preempted(tiny_config, params, stream=None, **kw):
     """Batch request preempted mid-decode by an interactive arrival on
     a 1-slot engine, then resumed; returns its final token stream."""
     eng = _engine(tiny_config, params, preemption=True, **kw)
     with eng:
         hb = eng.submit(BATCH_PROMPT, max_new_tokens=GEN,
                         temperature=0.0, repeat_penalty=1.0,
-                        priority="batch")
+                        priority="batch", stream=stream)
         _wait_tokens(hb, 4)
         hi = eng.submit(INTER_PROMPT, max_new_tokens=4,
                         temperature=0.0, repeat_penalty=1.0,
@@ -107,6 +107,35 @@ def test_preemption_token_equality_paged(tiny_config, params):
     # every page released: retire AND the preemption release both
     # returned their references (free + live == n_pages, live == 0)
     assert eng._pager.free_pages == eng.cache.n_pages
+
+
+@pytest.mark.parametrize("engine", ["dense", "paged"])
+def test_preempted_stream_continues_its_text(tiny_config, params, engine):
+    """A streaming row that is preempted and requeued goes on where its
+    text stopped (PR 47: the detokeniser's place lives on the request):
+    no chunk twice, none missing, a character split across the
+    preemption whole."""
+    from cake_tpu.models.llama.generator import ByteTokenizer
+    kw = dict(kv_pages=8, kv_page_size=PAGE) if engine == "paged" else {}
+    chunks = []
+
+    def stream(delta, final, n_done=0):
+        chunks.append((delta, final, n_done))
+    stream.wants_count = True
+
+    got, _eng = _preempted(tiny_config, params, stream=stream, **kw)
+    assert got == _uninterrupted(tiny_config, params, **kw)
+    tok = ByteTokenizer(tiny_config.vocab_size)
+    assert "".join(c[0] for c in chunks) == tok.decode(got)
+    counts = [c[2] for c in chunks]
+    assert counts == sorted(set(counts)) and counts[-1] == GEN
+    # chunks from both sides of the preemption (it came after 4 tokens)
+    assert counts[0] <= 4 < counts[-2]
+    assert [c[1] for c in chunks] == [False] * (len(chunks) - 1) + [True]
+    sent = ""
+    for delta, final, n in chunks[:-1]:
+        sent += delta
+        assert sent == tok.decode(got[:n])
 
 
 def test_paged_page_starvation_preempts_lower_class(tiny_config, params):

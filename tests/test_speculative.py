@@ -123,6 +123,25 @@ def test_interactive_session_matches_oracle(tiny_config, target, draft):
     assert [spec.next_token(i).id for i in range(10)] == want
 
 
+def test_interactive_session_streams_the_decode_of_its_ids(
+        tiny_config, target, draft):
+    """The speculative generator's token texts come from the one
+    detokeniser (PR 47): they are the oracle's, token for token, and
+    concatenate to the decode of the ids short of an incomplete tail."""
+    oracle = _oracle(tiny_config, target)
+    spec = _spec(tiny_config, target, draft)
+    for g in (oracle, spec):
+        g.add_message(Message.user("hi"))
+    want = [oracle.next_token(i) for i in range(16)]
+    got = [spec.next_token(i) for i in range(16)]
+    assert [t.id for t in got] == [t.id for t in want]
+    assert [t.text for t in got] == [t.text for t in want]
+    whole = spec.tokenizer.decode([t.id for t in got])
+    text = "".join(t.text for t in got)
+    assert text and whole.startswith(text)
+    assert not whole[len(text):].strip("\ufffd")
+
+
 def test_acceptance_stats_track(tiny_config, target, draft):
     spec = _spec(tiny_config, target, draft)
     prompt = np.full((1, 5), 3, np.int32)
