@@ -49,6 +49,11 @@ MODEL_TYPES = {
             "beside the page pool, half of each head rotated, an MLP "
             "router whose state runs down the layers, one expert a token "
             "(paged engine)",
+    "bailing_hybrid": "Kimi Delta Attention (a gated delta rule over a "
+                      "matrix state a row and head beside the page pool) "
+                      "with gated latent attention every few layers, "
+                      "sigmoid routing with a choice bias limited to "
+                      "groups by their two best (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -79,6 +84,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "zaya":
         from cake_tpu.models.moe.config import ZayaConfig
         return ZayaConfig.from_hf_dict(raw)
+    if model_type == "bailing_hybrid":
+        from cake_tpu.models.moe.config import BailingHybridConfig
+        return BailingHybridConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

@@ -137,7 +137,9 @@ class LatentGeometry(NamedTuple):
 
     scope: str
     heads: int
-    q_lora_rank: int
+    # None: a full-rank query projection (leaf `wq`; no `wq_a`,
+    # `q_a_norm`, `wq_b`)
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -177,7 +179,8 @@ class GlmMoeDsaConfig(MoEConfig):
 
     _family = "cake_tpu.models.moe.glm_dsa:FAMILY"
 
-    q_lora_rank: int = 2048
+    # None: a full-rank query (bailing_hybrid)
+    q_lora_rank: Optional[int] = 2048
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
@@ -199,6 +202,9 @@ class GlmMoeDsaConfig(MoEConfig):
     # parser here refuses anything else, DeepseekV2Config's takes it)
     n_group: int = 1
     topk_group: int = 1
+    # a group's score: the sum of its `group_top` best scores (1: its
+    # best, DeepseekV2Config's; 2: BailingHybridConfig's)
+    group_top: int = 1
 
     @property
     def rope_dim(self) -> int:
@@ -564,9 +570,15 @@ class DeepseekV2Config(GlmMoeDsaConfig):
                 f"experts do not fit in {topk_group} groups of "
                 f"{total // n_group}")
         if raw.get("q_lora_rank") is None:
+            # the shared trunk takes a full-rank query (project_latent's
+            # `wq`: bailing_hybrid's MLA layers); what is missing is the
+            # reference this model_type is held to
             raise ValueError(
                 "q_lora_rank is null (the DeepSeek-V2-Lite layout, a full-"
-                "rank query projection): not implemented")
+                "rank query projection): not implemented for model_type "
+                "deepseek_v2 (models/reference/deepseek_v2.py, the copy "
+                "its benchmark cell holds, has no full-rank query to "
+                "compare with)")
         if raw.get("attention_bias", False):
             raise ValueError("attention_bias = true: projection biases "
                              "are not implemented")
@@ -639,6 +651,185 @@ class DeepseekV2Config(GlmMoeDsaConfig):
             n_shared_experts=2, routed_scaling_factor=16.0,
             scoring_func="softmax", n_group=4, topk_group=2,
             rope_scaling=Yarn(4.0, 16, 8.0, 1.0, 0.707, 0.707),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+# config.json keys of model_type bailing_hybrid whose one served value is
+# the published one: anything else is refused by the key's name
+_BAILING_ONLY = {
+    "hidden_act": "silu", "score_function": "sigmoid",
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+    "gated_attention_proj_granularity_type": "head_wise",
+    "group_norm_size": 1, "num_shared_experts": 1, "linear_silu": True,
+    "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+    "use_qk_norm": True, "rope_interleave": True, "rope_scaling": None,
+    "scale_router_input": False, "up_proj_norm": False, "use_bias": False,
+    "use_qkv_bias": False, "use_mla_nope": False, "use_nGPT": False,
+    "value_norm": False, "num_kv_heads_for_linear_attn": 0,
+    "moe_router_enable_expert_bias": True, "norm_topk_prob": True,
+}
+
+
+@dataclass(frozen=True)
+class BailingHybridConfig(GlmMoeDsaConfig):
+    """Ling-3.0 (`model_type: bailing_hybrid`): layer i is latent
+    attention (MLA over every visible key, a full-rank query where
+    `q_lora_rank` is null, a sigmoid gate a head) where (i + 1) mod
+    `layer_group_size` == 0, and Kimi Delta Attention elsewhere: a
+    float32 MATRIX state a row and head beside the page pool, updated by
+    a gated delta rule with a decay a key channel
+    (models/llama/paged.HybridPagedCache: `ssm` the state, `conv` the
+    q | k | v tails of the short causal conv, `k` the latent pool of the
+    MLA layers alone). `first_k_dense_replace` dense SwiGLU layers, then
+    sigmoid-routed experts with a choice bias, limited to the
+    `topk_group` groups of `n_group` whose TWO best biased scores sum
+    highest (ops/moe.choose: group_top 2), and a shared expert. The
+    equations are in models/reference/bailing_hybrid.py; the served path
+    in models/moe/bailing_hybrid.py.
+
+    `indexer_types` holds "kda" | "dense" (an MLA layer: glm_dsa's
+    dense kind). `num_local_experts` counts the routed experts HELD
+    here (config.json `num_experts`), `n_routed_experts_total` the
+    router's width (`num_experts_total`, absent = all held),
+    `first_routed_expert` the first held expert's index."""
+
+    _family = "cake_tpu.models.moe.bailing_hybrid:FAMILY"
+
+    # heads are num_attention_heads in both kinds of layer; a KDA
+    # head's key and value width (config.json `head_dim`)
+    kda_head_dim: int = 128
+    conv_kernel: int = 4
+    # the bounded gate: g = kda_lower_bound * sigmoid(exp(A_log) (a + dt_bias))
+    kda_lower_bound: float = -5.0
+    group_top: int = 2
+
+    @property
+    def kda_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.indexer_types)
+                     if t == "kda")
+
+    @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """The MLA layers: the page pool holds their rows alone."""
+        return tuple(i for i, t in enumerate(self.indexer_types)
+                     if t == "dense")
+
+    @property
+    def kda_width(self) -> int:
+        """Channels of each of q, k, v, the decay and the output gate."""
+        return self.num_attention_heads * self.kda_head_dim
+
+    def geometry(self, layer: int) -> LatentGeometry:
+        return super().geometry(layer)._replace(gated=True)
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "BailingHybridConfig":
+        L = raw["num_hidden_layers"]
+        for name, want in _BAILING_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type bailing_hybrid "
+                    f"serves {want!r} only (not implemented)")
+        for name in ("expert_swiglu_limit_list",
+                     "share_expert_swiglu_limit_list"):
+            limits = list(raw.get(name) or [0] * L)
+            if len(limits) < L:
+                raise ValueError(f"{name} names {len(limits)} layers of "
+                                 f"num_hidden_layers = {L}")
+            clamped = [i for i in range(L) if limits[i]]
+            if clamped:
+                raise ValueError(
+                    f"{name}: a non-zero limit in served layers {clamped} "
+                    "(the clamp on the SwiGLU is not implemented: the "
+                    "published config does not give its form)")
+        if raw.get("num_nextn_predict_layers", 0):
+            raise ValueError(
+                "num_nextn_predict_layers > 0: the multi-token-prediction "
+                "module is not served (it drafts for speculation and adds "
+                "nothing to the next-token logits); set it to 0")
+        dn, dr = raw["qk_nope_head_dim"], raw["qk_rope_head_dim"]
+        for name, want in (("qk_head_dim", dn + dr), ("rotary_dim", dr)):
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]}: qk_nope_head_dim + "
+                    f"qk_rope_head_dim = {dn} + {dr}, of which the rope "
+                    "part is rotated")
+        if (raw.get("num_key_value_heads", raw["num_attention_heads"])
+                != raw["num_attention_heads"]):
+            raise ValueError("num_key_value_heads must equal "
+                             "num_attention_heads: a key a head in both "
+                             "kinds of layer")
+        period = raw["layer_group_size"]
+        dense = raw.get("first_k_dense_replace", 0)
+        held = raw["num_experts"]
+        total = raw.get("num_experts_total", held)
+        first = raw.get("first_routed_expert", 0)
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        n_group, topk_group = raw.get("n_group", 1), raw.get("topk_group", 1)
+        k = raw["num_experts_per_tok"]
+        if (total % n_group or not 1 <= topk_group <= n_group
+                or k > topk_group * (total // n_group)):
+            raise ValueError(
+                f"n_group = {n_group}, topk_group = {topk_group}: the "
+                f"router's {total} experts fall into n_group equal groups "
+                f"of which 1..n_group are taken and hold the {k} a token")
+        base = LlamaConfig.from_hf_dict(raw)
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields["chat_template"] = "chatml"
+        return cls(
+            **fields,
+            num_local_experts=held, num_experts_per_tok=k,
+            norm_topk_prob=True, hf_layout="bailing_hybrid",
+            q_lora_rank=raw.get("q_lora_rank"),
+            kv_lora_rank=raw["kv_lora_rank"],
+            qk_nope_head_dim=dn, qk_rope_head_dim=dr,
+            v_head_dim=raw["v_head_dim"],
+            index_n_heads=0, index_head_dim=0, index_topk=0,
+            mlp_layer_types=("dense",) * dense + ("sparse",) * (L - dense),
+            indexer_types=tuple("dense" if (i + 1) % period == 0 else "kda"
+                                for i in range(L)),
+            moe_intermediate_size=raw["moe_intermediate_size"],
+            n_routed_experts_total=total, first_routed_expert=first,
+            n_shared_experts=(
+                raw.get("moe_shared_expert_intermediate_size",
+                        raw["moe_intermediate_size"])
+                // raw["moe_intermediate_size"]),
+            routed_scaling_factor=raw.get("routed_scaling_factor", 1.0),
+            scoring_func="sigmoid", n_group=n_group, topk_group=topk_group,
+            kda_head_dim=raw["head_dim"],
+            conv_kernel=raw.get("short_conv_kernel_size", 4),
+            kda_lower_bound=float(raw.get("kda_lower_bound", -5)),
+        )
+
+    @classmethod
+    def tiny_ling(cls, **overrides) -> "BailingHybridConfig":
+        """Ling-3.0's layers at a test's size: two periods of three
+        (`K K M K K M`), one dense layer, then sparse ones; 4 heads of
+        8; 16 routed experts in 4 groups of which 2 are taken, 3 a
+        token, ALL held (a test of the share holds a group)."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=6, num_attention_heads=4,
+            num_key_value_heads=4, rms_norm_eps=1e-6, rope_theta=10000.0,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=16, num_experts_per_tok=3,
+            norm_topk_prob=True, hf_layout="bailing_hybrid",
+            q_lora_rank=None, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_n_heads=0,
+            index_head_dim=0, index_topk=0,
+            mlp_layer_types=("dense",) + ("sparse",) * 5,
+            indexer_types=("kda", "kda", "dense") * 2,
+            moe_intermediate_size=32, n_routed_experts_total=16,
+            n_shared_experts=1, routed_scaling_factor=2.5,
+            scoring_func="sigmoid", n_group=4, topk_group=2,
+            kda_head_dim=8, conv_kernel=4,
         )
         base.update(overrides)
         return cls(**base)

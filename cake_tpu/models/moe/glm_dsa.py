@@ -13,7 +13,8 @@ Both step programs run ONE trunk over a flat list of tokens, each with
 its row (slot) and position: a decode step's B tokens, or a mixed step's
 packed axis (paged.pack_plan). A layer:
 
-  * `mla_q`: the low-rank query path with its norm, RoPE on the rope
+  * `mla_q`: the low-rank query path with its norm (or one full-rank
+    projection where the config's `q_lora_rank` is null), RoPE on the rope
     part (interleaved pairs), and the key up-projection absorbed into
     the query (q_lat = q_nope W_kvb^K): attention then runs over the
     latent itself, all heads sharing a row;
@@ -94,7 +95,9 @@ from cake_tpu.ops.moe import LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import QTensor, qmatmul
 
-ATTN_LEAVES = ("attn_norm", "wq_a", "q_a_norm", "wq_b", "wkv_a",
+# (a layer with a full-rank query has `wq` in place of the three leaves
+# of the low-rank path)
+ATTN_LEAVES = ("attn_norm", "wq_a", "q_a_norm", "wq_b", "wq", "wkv_a",
                "kv_a_norm", "wkv_b_k", "wkv_b_v", "wo", "mlp_norm")
 INDEX_LEAVES = ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w")
 DENSE_LEAVES = ("w_gate", "w_up", "w_down")
@@ -319,16 +322,20 @@ def project_latent(lp, h, cos, sin, slot, position, real, pool_lat,
     the row's ring of window pages). geo: the layer's sizes (None: the
     config's one geometry). Returns (q_cat [T, H, row]: the
     absorbed query | the rotated rope part | zeros over the stored
-    row's padding, pool_lat, c_q: the query latent the indexer reads)."""
+    row's padding, pool_lat, c_q: the query latent the indexer reads;
+    None where the query projection is full-rank, leaf `wq`)."""
     T = h.shape[0]
     geo, eps = geo or config.geometry(0), config.rms_norm_eps
     H, R = geo.heads, geo.kv_lora_rank
     dn, dr = geo.qk_nope_head_dim, geo.qk_rope_head_dim
     with jax.named_scope(f"{geo.scope}_q"):
-        c_q = rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"], eps)
-        if geo.q_scale != 1.0:
-            c_q = c_q * geo.q_scale
-        q = qmatmul(c_q, lp["wq_b"]).reshape(T, H, dn + dr)
+        if "wq" in lp:
+            c_q, q = None, qmatmul(h, lp["wq"]).reshape(T, H, dn + dr)
+        else:
+            c_q = rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"], eps)
+            if geo.q_scale != 1.0:
+                c_q = c_q * geo.q_scale
+            q = qmatmul(c_q, lp["wq_b"]).reshape(T, H, dn + dr)
         # zeros where the stored row has its padding (config.latent_row)
         pad = pool_lat.shape[-1] - R - dr
         q_cat = jnp.concatenate(
@@ -420,6 +427,14 @@ def attend_dense(q_cat, pool_lat, layer: int, table, slot, first,
                          out[slot])
 
 
+def gate_heads(lp, h, o):
+    """The un-absorbed heads o [T, H, dv] times a sigmoid a head of the
+    layer's normed input h."""
+    with jax.named_scope("attn_gate"):
+        gate = jax.nn.sigmoid(qmatmul(h, lp[GATE_LEAF]).astype(jnp.float32))
+        return (o * gate[..., None]).astype(o.dtype)
+
+
 def gathered_keys(window: int) -> int:
     """The gathered axis of a sliding layer's single token: its window
     of keys padded to whole tiles (513 -> 640; a test's handful to 8s)."""
@@ -479,6 +494,42 @@ def attend_sliding(q_cat, pool_w, layer: int, wtable, slot, position,
                          out[slot])
 
 
+def held_from(config) -> Optional[int]:
+    """The first held expert where the layers hold a SHARE of their
+    router's experts, None where they hold all."""
+    c = config
+    return (c.first_routed_expert
+            if c.num_local_experts < c.n_routed_experts_total else None)
+
+
+def ffn(lp, h, real, config):
+    """A layer's FFN on its normed input h [T, D] -> (out [T, D],
+    MoEStats or None): ops/moe.moe_mlp by the config's rule where the
+    layer has a router, else a dense SwiGLU."""
+    c = config
+    if "router" not in lp:
+        gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
+        return qmatmul(gate * qmatmul(h, lp["w_up"]), lp["w_down"]), None
+    out, stats = moe_mlp(
+        lp, h[None], c.num_experts_per_tok, c.norm_topk_prob,
+        token_mask=real[None], first_expert=held_from(c),
+        scoring=c.scoring_func, scale=c.routed_scaling_factor,
+        n_group=c.n_group, topk_group=c.topk_group, group_top=c.group_top)
+    return out[0], stats
+
+
+def moe_counters(moe: list) -> list:
+    """paged.MOE_COUNTERS' five and the routed rows, over the sparse
+    layers' MoEStats (sums, but the loads: means over the layers)."""
+    def over(field, reduce):
+        return (reduce(jnp.stack([getattr(s, field) for s in moe]))
+                if moe else jnp.float32(0))
+
+    return [over("rows", jnp.sum), over("rows_padded", jnp.sum),
+            over("load_max", jnp.mean), over("load_mean", jnp.mean),
+            over("touched", jnp.sum), over("rows_routed", jnp.sum)]
+
+
 class TrunkOut(NamedTuple):
     """x [T, D] after the final norm; cache; counters [N_COUNTERS];
     and the two choices themselves, for a tool that compares them with
@@ -522,9 +573,6 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         turned["swa_cos", "swa_sin"] = (jnp.take(rope.swa_cos, at, axis=0),
                                         jnp.take(rope.swa_sin, at, axis=0))
         pool_w, wtable = cache.w, cache.wtable
-    first_expert = (c.first_routed_expert
-                    if c.num_local_experts < c.n_routed_experts_total
-                    else None)
     pool_lat, pool_idx, table = cache.k, cache.v, cache.table
     if first is None:
         first = jnp.arange(x.shape[0])
@@ -573,10 +621,7 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                                        geo)
                 o = unabsorb_value(o_lat, lp["wkv_b_v"])
                 if geo.gated:
-                    with jax.named_scope("attn_gate"):
-                        gate = jax.nn.sigmoid(
-                            qmatmul(h, lp[GATE_LEAF]).astype(jnp.float32))
-                        o = (o * gate[..., None]).astype(o.dtype)
+                    o = gate_heads(lp, h, o)
             with jax.named_scope("o_proj"):
                 attn_out = qmatmul(o.reshape(o.shape[0], -1), lp["wo"])
                 x = x + attn_out
@@ -584,20 +629,11 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                 h_attn, h = h, rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
                 if geo.window is not None and not probe:
                     probe = (h_attn, attn_out, h)
-                if "router" in lp:
-                    out, stats = moe_mlp(
-                        lp, h[None], c.num_experts_per_tok,
-                        c.norm_topk_prob, token_mask=real[None],
-                        first_expert=first_expert, scoring=c.scoring_func,
-                        scale=c.routed_scaling_factor, n_group=c.n_group,
-                        topk_group=c.topk_group)
+                out, stats = ffn(lp, h, real, c)
+                if stats is not None:
                     moe.append(stats)
                     experts.append(stats.experts)
-                    x = x + out[0]
-                else:
-                    gate = jax.nn.silu(qmatmul(h, lp["w_gate"]))
-                    x = x + qmatmul(gate * qmatmul(h, lp["w_up"]),
-                                    lp["w_down"])
+                x = x + out
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     n_real = jnp.sum(real, dtype=jnp.float32)
@@ -607,15 +643,7 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     stepped = (n_real > 0).astype(jnp.float32)
     visible = jnp.where(real, position + 1, 0).astype(jnp.float32)
     f32 = jnp.float32
-
-    def over(field, reduce):
-        return (reduce(jnp.stack([getattr(s, field) for s in moe]))
-                if moe else f32(0))
-
-    counters = [
-        over("rows", jnp.sum), over("rows_padded", jnp.sum),
-        over("load_max", jnp.mean), over("load_mean", jnp.mean),
-        over("touched", jnp.sum), over("rows_routed", jnp.sum)]
+    counters = moe_counters(moe)
     if dense:
         held = [s.group_held for s in moe if s.group_held is not None]
         counters += [

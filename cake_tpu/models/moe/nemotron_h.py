@@ -12,7 +12,8 @@ and one residual (a Python loop over the stacks per kind of block, as
 models/moe/glm_dsa.py's):
 
   * `M`, Mamba-2. `ssm_in`: one projection of every packed token.
-    `ssm_conv`: the causal depthwise conv along each ROW's tokens; the
+    `ssm_conv`: the causal depthwise conv along each ROW's tokens
+    (`causal_conv_rows`, which models/moe/bailing_hybrid.py calls too); the
     K-1 inputs before a row's first token come from the row's stored
     tail, never from the packed neighbour. The recurrence runs in its
     two forms, the same mathematics: `ssm_step`, the one-step update of
@@ -230,6 +231,38 @@ def ssm_scan(S0, x, Bm, Cm, dt, a, D, chunk: int):
     return S.reshape(H, P, N), y.reshape(nc * Q, H, P)[:C]
 
 
+def causal_conv_rows(x, tail, taps, bias, slot, rows: Rows):
+    """The causal depthwise conv along each ROW's tokens, then SiLU:
+    x [T, ch] the packed tokens' inputs, tail [B, K-1, ch] each row's
+    last K-1 inputs before this dispatch, taps [K, ch], bias [ch] or
+    None -> (silu(conv) [T, ch] in x's type, the rows' last K-1 inputs
+    for the step after this one [B, K-1, ch]; an idle row's are what it
+    had)."""
+    T, B, K = x.shape[0], tail.shape[0], taps.shape[0]
+    # a token's input d places back in its ROW: the packed neighbour
+    # while that is the row's own, else the tail
+    col = jnp.arange(T, dtype=jnp.int32) - rows.first[slot]
+    ext = jnp.concatenate(
+        [tail.reshape(B * (K - 1), -1).astype(x.dtype), x], 0)
+    t = jnp.arange(T, dtype=jnp.int32)
+    acc = 0.0 if bias is None else bias.astype(F32)[None, :]
+    for d in range(K):
+        if d == 0:
+            x_d = x          # the token's own input
+        else:
+            src = jnp.where(col >= d, B * (K - 1) + t - d,
+                            slot * (K - 1) + col - d + K - 1)
+            x_d = jnp.take(ext, src, axis=0)
+        acc = acc + taps[K - 1 - d].astype(F32)[None, :] * x_d.astype(F32)
+    u = jax.nn.silu(acc).astype(x.dtype)
+    # the row's last K-1 inputs, for the step after this one
+    back = rows.n[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]
+    src = jnp.where(
+        back >= 0, B * (K - 1) + rows.first[:, None] + back,
+        jnp.arange(B)[:, None] * (K - 1) + back + K - 1)
+    return u, jnp.take(ext, src, axis=0)
+
+
 # -- the blocks ----------------------------------------------------------------
 
 
@@ -239,10 +272,9 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
     state, over the packed tokens."""
     c = config
     T = h.shape[0]
-    B = rows.n.shape[0]
     H, P, G, N = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
                   c.ssm_state_size)
-    di, K = c.d_inner, c.conv_kernel
+    di = c.d_inner
     with jax.named_scope("qkv"), jax.named_scope("ssm_in"):
         zxd = qmatmul(h, lp["w_in"])
         z, xBC, dt = (zxd[:, :di], zxd[:, di:di + c.conv_dim],
@@ -255,29 +287,9 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
             S_old = ssm[j]
             S_in = jnp.where(fresh[:, None, None, None], 0.0, S_old)
         with jax.named_scope("ssm_conv"):
-            # a token's input d places back in its ROW: the packed
-            # neighbour while that is the row's own, else the tail
-            col = jnp.arange(T, dtype=jnp.int32) - rows.first[slot]
-            ext = jnp.concatenate(
-                [tail.reshape(B * (K - 1), -1).astype(xBC.dtype), xBC], 0)
-            t = jnp.arange(T, dtype=jnp.int32)
-            acc = lp["conv_b"].astype(F32)[None, :]
-            for d in range(K):
-                if d == 0:
-                    x_d = xBC          # the token's own input
-                else:
-                    src = jnp.where(col >= d, B * (K - 1) + t - d,
-                                    slot * (K - 1) + col - d + K - 1)
-                    x_d = jnp.take(ext, src, axis=0)
-                acc = acc + (lp["conv_w"][K - 1 - d].astype(F32)[None, :]
-                             * x_d.astype(F32))
-            u = jax.nn.silu(acc).astype(h.dtype)
-            # the row's last K-1 inputs, for the step after this one
-            back = rows.n[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]
-            src = jnp.where(
-                back >= 0, B * (K - 1) + rows.first[:, None] + back,
-                jnp.arange(B)[:, None] * (K - 1) + back + K - 1)
-            new_tail = jnp.take(ext, src, axis=0).astype(conv.dtype)
+            u, new_tail = causal_conv_rows(xBC, tail, lp["conv_w"],
+                                           lp["conv_b"], slot, rows)
+            new_tail = new_tail.astype(conv.dtype)
         xs = u[:, :di].reshape(T, H, P)
         Bm = u[:, di:di + G * N].reshape(T, G, N)
         Cm = u[:, di + G * N:].reshape(T, G, N)

@@ -177,6 +177,101 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     return top
 
 
+def _init_bailing_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of a BailingHybridConfig. Leaves are stacked per
+    KIND of layer: the two norms [L, D]; the KDA leaves [L_kda, ...]
+    (`w_kda_in`, the fused projection with columns [q | k | v | decay |
+    output gate], `w_kda_beta`, `kda_conv_w` [K, 3 * width] over q | k |
+    v, `A_log` [H], `dt_bias` [width], `kda_norm` [head_dim],
+    `w_kda_out`); the MLA leaves [L_mla, ...] as `_init_glm_params`
+    names them, `w_attn_gate` among them; the dense FFN's, the router's,
+    the experts' and the shared expert's as there. The taps, `A_log`,
+    `dt_bias`, the norms, the router and its bias stay float under
+    bits=8.
+
+    `A_log` = log U(1, 16) as published for KDA. Under seeded weights
+    the decay is placed, not learned: `dt_bias` puts each channel's
+    half-life log-uniform in 1 .. 1,000 tokens at a zero input
+    (g = lower_bound * sigmoid(exp(A_log) * dt_bias) = -ln 2 /
+    half-life), and the decay's columns of `w_kda_in` are drawn 16 times
+    narrower than the rest, so that exp(A_log) * (h W_f) moves the
+    gate's argument by a sigma of at most one and the state neither dies
+    in a token nor stops forgetting. `router_bias` (the published
+    `expert_bias`) is 0, as an untrained balancer's."""
+    c = config
+    L, D = c.num_hidden_layers, c.hidden_size
+    H, dk, W, K = (c.num_attention_heads, c.kda_head_dim, c.kda_width,
+                   c.conv_kernel)
+    Lk, Lm, Ls = len(c.kda_layers), len(c.latent_layers), \
+        len(c.sparse_layers)
+    Ld = L - Ls
+    R, Rq = c.kv_lora_rank, c.q_lora_rank
+    dn, dr, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+    E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
+                     c.moe_intermediate_size, c.intermediate_size)
+    Fs = c.n_shared_experts * Fe
+    w, mat, keys = _draws(rng, dtype, bits, 48)
+
+    def near(shape, centre):
+        return (centre + 0.1 * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    from cake_tpu.ops.quant import QTensor
+
+    w_in = mat("w_kda_in", (Lk, D, 5 * W), D)
+    narrow = jnp.where((jnp.arange(5 * W) // W) == 3, 1.0 / 16.0, 1.0)
+    w_in = (QTensor(q=w_in.q, scale=w_in.scale * narrow)
+            if isinstance(w_in, QTensor)
+            else (w_in.astype(jnp.float32) * narrow).astype(dtype))
+    A_log = jnp.log(jax.random.uniform(next(keys), (Lk, H), jnp.float32,
+                                       1.0, 16.0))
+    half_life = jnp.exp(jax.random.uniform(
+        next(keys), (Lk, W), jnp.float32, 0.0, np.log(1000.0)))
+    p = (np.log(2.0) / half_life) / -c.kda_lower_bound
+    blocks = {
+        "attn_norm": near((L, D), 1.0),
+        "mlp_norm": near((L, D), 1.0),
+        "w_kda_in": w_in,
+        "w_kda_beta": mat("w_kda_beta", (Lk, D, H), D),
+        "kda_conv_w": w((Lk, K, 3 * W), K),
+        "A_log": A_log,
+        "dt_bias": (jnp.log(p) - jnp.log1p(-p)) / jnp.repeat(
+            jnp.exp(A_log), dk, axis=-1),
+        "kda_norm": near((Lk, dk), 1.0),
+        "w_kda_out": mat("w_kda_out", (Lk, W, D), W),
+        **({"wq": mat("wq", (Lm, D, H * (dn + dr)), D)} if Rq is None else {
+            "wq_a": mat("wq_a", (Lm, D, Rq), D),
+            "q_a_norm": near((Lm, Rq), 1.0),
+            "wq_b": mat("wq_b", (Lm, Rq, H * (dn + dr)), Rq)}),
+        "wkv_a": mat("wkv_a", (Lm, D, R + dr), D),
+        "kv_a_norm": near((Lm, R), 1.0),
+        "wkv_b_k": mat("wkv_b_k", (Lm, R, H * dn), R),
+        "wkv_b_v": mat("wkv_b_v", (Lm, R, H * dv), R),
+        "wo": mat("wo", (Lm, H * dv, D), H * dv),
+        "w_attn_gate": mat("w_attn_gate", (Lm, D, H), D),
+        "router": w((Ls, D, Et), D),
+        "router_bias": jnp.zeros((Ls, Et), jnp.float32),
+        "we_gate": mat("we_gate", (Ls, E, D, Fe), D),
+        "we_up": mat("we_up", (Ls, E, D, Fe), D),
+        "we_down": mat("we_down", (Ls, E, Fe, D), Fe),
+        "ws_gate": mat("ws_gate", (Ls, D, Fs), D),
+        "ws_up": mat("ws_up", (Ls, D, Fs), D),
+        "ws_down": mat("ws_down", (Ls, Fs, D), Fs),
+    }
+    if Ld:
+        blocks.update({
+            "w_gate": mat("w_gate", (Ld, D, Fd), D),
+            "w_up": mat("w_up", (Ld, D, Fd), D),
+            "w_down": mat("w_down", (Ld, Fd, D), Fd),
+        })
+    return {
+        "embed": w((c.vocab_size, D), D),
+        "blocks": blocks,
+        "final_norm": near((D,), 1.0),
+        "lm_head": mat("lm_head", (D, c.vocab_size), D),
+    }
+
+
 def _init_nemotron_params(config, rng: jax.Array, dtype,
                           bits: Optional[int]):
     """The seeded tree of a NemotronHConfig. Leaves are stacked per KIND
@@ -324,6 +419,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
     the program holds the tree and nothing beside it. Norm weights are
     drawn around 1 where the family has query/key norm, so that a test
     sees them."""
+    if getattr(config, "kda_layers", None):
+        return _init_bailing_params(config, rng, dtype, bits)
     if getattr(config, "kv_lora_rank", None):
         return _init_glm_params(config, rng, dtype, bits)
     if getattr(config, "mamba_layers", None):

@@ -364,11 +364,34 @@ MLA_DENSE_COUNTERS = (
         "summed over rows and latent layers: what cake_mla_decode_attn "
         "walked")),
 )
+# a model with Kimi Delta Attention layers
+# (models/moe/bailing_hybrid.trunk): the two forms of the delta rule and
+# the rows' matrix state
+KDA_COUNTERS = (
+    ("kda_tokens_chunked", _m.counter(
+        "cake_kda_tokens_chunked_total",
+        "Tokens through the chunked delta rule (a prompt's windows), "
+        "summed over KDA layers")),
+    ("kda_tokens_stepped", _m.counter(
+        "cake_kda_tokens_stepped_total",
+        "Tokens through the one-step delta rule (a row's single token), "
+        "summed over KDA layers")),
+    ("kda_state_rows", _m.counter(
+        "cake_kda_state_rows_total",
+        "Rows whose matrix state a step read and wrote, summed over KDA "
+        "layers (a row with no token in a dispatch costs none)")),
+)
+KDA_STATE_BYTES = _m.gauge(
+    "cake_kda_state_bytes",
+    "Bytes of the rows' KDA state beside the page pool (the float32 "
+    "matrix a head and the conv tails, every KDA layer, every slot)")
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
-                      + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS)
+                      + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS
+                      + KDA_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)
 BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
-                     "cca_tail_bytes": CCA_TAIL_BYTES}
+                     "cca_tail_bytes": CCA_TAIL_BYTES,
+                     "kda_state_bytes": KDA_STATE_BYTES}
 
 
 def refresh_page_gauges(engine) -> None:

@@ -110,51 +110,57 @@ def router_logits(x, router_w):
                    precision=lax.Precision.HIGHEST)
 
 
-def top_groups(probs, n_group: int, topk_group: int):
+def top_groups(probs, n_group: int, topk_group: int, group_top: int = 1):
     """The groups a token may choose its experts from: probs [N, E],
     the E experts in n_group equal groups of neighbours -> [N,
-    topk_group] int32, the groups with the largest BEST score, best
-    first, ties to the lower index (DeepSeek-V2's
-    group_limited_greedy)."""
+    topk_group] int32, the groups with the largest score, best first,
+    ties to the lower index. A group's score is the sum of its
+    `group_top` best: 1, its BEST (DeepSeek-V2's group_limited_greedy);
+    2, the sum of its two best (noaux_tc: DeepSeek-V3, Ling)."""
     N, E = probs.shape
-    best = jnp.max(probs.reshape(N, n_group, E // n_group), axis=-1)
-    return lax.top_k(best, topk_group)[1].astype(jnp.int32)
+    grouped = probs.reshape(N, n_group, E // n_group)
+    if group_top == 1:
+        score = jnp.max(grouped, axis=-1)
+    else:
+        score = jnp.sum(lax.top_k(grouped, group_top)[0], axis=-1)
+    return lax.top_k(score, topk_group)[1].astype(jnp.int32)
 
 
 def choose_in_groups(logits, k: int, norm_topk_prob: bool,
                      scoring: str = "softmax", scale: float = 1.0, bias=None,
-                     n_group: int = 1, topk_group: int = 1):
+                     n_group: int = 1, topk_group: int = 1,
+                     group_top: int = 1):
     """The family's rule on float32 logits [N, E], however they were
     made -> (weights [N, k] f32, experts [N, k] int32, groups). Scores
     over all E experts, by `scoring` ("softmax" or "sigmoid"); the top
     k; renormalised over the k only if `norm_topk_prob`; times `scale`.
     bias [E] f32 (GLM's e_score_correction_bias, ZAYA's balancing
-    bias): added to the scores for the CHOICE only, the weights are
-    the unbiased scores. n_group > 1 (DeepSeek-V2): the choice is
-    limited to the `topk_group` groups of `top_groups`, which are
-    returned ([N, topk_group] int32; None where the rule has no
-    groups); every other group's scores are set to 0 before the top
-    k."""
+    bias, Ling's expert_bias): added to the scores for the CHOICE only
+    (of groups and of experts), the weights are the unbiased scores.
+    n_group > 1 (DeepSeek-V2, Ling): the choice is limited to the
+    `topk_group` groups of `top_groups`, which are returned ([N,
+    topk_group] int32; None where the rule has no groups); a group not
+    taken is never chosen (its scores are set to 0 before the top k;
+    under a bias, which may leave a taken score below 0, to -inf)."""
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
         probs = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"unknown scoring {scoring!r}")
+    choice = probs if bias is None else probs + bias.astype(jnp.float32)
     groups = None
     if n_group > 1:
-        if bias is not None:
-            raise ValueError("group-limited routing takes no choice bias")
         E = probs.shape[-1]
-        groups = top_groups(probs, n_group, topk_group)
+        groups = top_groups(choice, n_group, topk_group, group_top)
         taken = jnp.any(
             (jnp.arange(E) // (E // n_group))[None, None, :]
             == groups[:, :, None], axis=1)
-        probs = jnp.where(taken, probs, 0.0)
+        choice = jnp.where(taken, choice, 0.0 if bias is None else -jnp.inf)
     if bias is None:
-        weights, experts = lax.top_k(probs, k)
+        weights, experts = lax.top_k(choice, k)
     else:
-        _, experts = lax.top_k(probs + bias.astype(jnp.float32), k)
+        _, experts = lax.top_k(choice, k)
         weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
         norm = jnp.sum(weights, axis=-1, keepdims=True)
@@ -168,19 +174,19 @@ def choose_in_groups(logits, k: int, norm_topk_prob: bool,
 
 def choose(logits, k: int, norm_topk_prob: bool,
            scoring: str = "softmax", scale: float = 1.0, bias=None,
-           n_group: int = 1, topk_group: int = 1):
+           n_group: int = 1, topk_group: int = 1, group_top: int = 1):
     """`choose_in_groups` -> (weights, experts) alone."""
     return choose_in_groups(logits, k, norm_topk_prob, scoring, scale, bias,
-                            n_group, topk_group)[:2]
+                            n_group, topk_group, group_top)[:2]
 
 
 def route(x, router_w, k: int, norm_topk_prob: bool,
           scoring: str = "softmax", scale: float = 1.0, bias=None,
-          n_group: int = 1, topk_group: int = 1):
+          n_group: int = 1, topk_group: int = 1, group_top: int = 1):
     """x [N, D], router_w [D, E] -> `choose` over the linear router's
     logits."""
     return choose(router_logits(x, router_w), k, norm_topk_prob, scoring,
-                  scale, bias, n_group, topk_group)
+                  scale, bias, n_group, topk_group, group_top)
 
 
 # -- the sorted dispatch -------------------------------------------------------
@@ -385,6 +391,12 @@ def _stacked(leaf):
     return jax.tree.map(lambda a: a[None], leaf), jnp.int32(0)
 
 
+def _held_experts(leaf) -> int:
+    """The experts an expert leaf holds (its E axis), LayerOf or slice."""
+    w = getattr(leaf, "stacked", leaf)
+    return getattr(w, "q", w).shape[-3]
+
+
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
@@ -430,7 +442,7 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             ep_axis: Optional[str] = None, token_mask=None,
             first_expert: Optional[int] = None, scoring: str = "softmax",
             scale: float = 1.0, act: str = "silu", logits=None,
-            n_group: int = 1, topk_group: int = 1):
+            n_group: int = 1, topk_group: int = 1, group_top: int = 1):
     """Sparse FFN over experts -> (out [B, S, D], MoEStats).
 
     lp leaves: router [D, E], the linear router, unless the family made
@@ -449,8 +461,8 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     back once (without `w_fc2` the result stays in the latent: one
     share's part of the sum); the router and the shared expert read h
     itself.
-    norm_topk_prob, scoring, scale, n_group, topk_group: the family's
-    rule (`choose`). E_local == E except where the layer holds a share: under
+    norm_topk_prob, scoring, scale, n_group, topk_group, group_top: the
+    family's rule (`choose`). E_local == E except where the layer holds a share: under
     shard_map EP each shard holds its contiguous slice and `ep_axis`
     names the mesh axis; on one chip `first_expert` (static) is the
     first of the E_local held. token_mask [B, S] bool: positions that are not real
@@ -468,20 +480,24 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
         logits = logits.reshape(N, -1)
         weights, experts, groups = choose_in_groups(
             logits, k, norm_topk_prob, scoring, scale,
-            lp.get("router_bias"), n_group, topk_group)
+            lp.get("router_bias"), n_group, topk_group, group_top)
         routed = experts
         took = None
         if groups is not None and first_expert is not None:
-            # the tokens whose groups include the held experts'
-            held = first_expert // (logits.shape[-1] // n_group)
-            took = jnp.any(groups == held, axis=-1)
+            # the tokens whose groups include one of the held experts'
+            # (whole groups are held: one, or a run of neighbours)
+            per = logits.shape[-1] // n_group
+            held = first_expert // per
+            last = (first_expert + _held_experts(lp["we_up"]) - 1) // per
+            took = jnp.any(groups == held if last == held
+                           else (groups >= held) & (groups <= last), axis=-1)
 
     from cake_tpu.ops.quant import qmatmul
 
     w_up, layer = _stacked(lp["we_up"])
     stacks = (_stacked(lp["we_gate"])[0] if "we_gate" in lp else None,
               w_up, _stacked(lp["we_down"])[0])
-    e_local = getattr(w_up, "q", w_up).shape[1]
+    e_local = _held_experts(lp["we_up"])
     x_in = x
     if "w_fc1" in lp:
         with jax.named_scope("moe_latent"):

@@ -185,6 +185,10 @@ _BLOCK_CONTRACT = {
     # its convolutions, router, norms, temperatures and residual scaling
     # are small leaves and stay float (absent here)
     "w_cca": (1,),
+    # Kimi Delta Attention's projections (bailing_hybrid): the fused
+    # [q | k | v | decay | output gate], the write strength a head, the
+    # output; the conv taps, A_log, dt_bias and the head norm stay float
+    "w_kda_in": (1,), "w_kda_beta": (1,), "w_kda_out": (1,),
 }
 
 

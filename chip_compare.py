@@ -87,6 +87,20 @@ and with the x 16 left out.
 
     python chip_compare.py benchmarks/configs/deepseek-v2-int8-share8 [--seed N] [--rehearse]
 
+Ling-3.0-flash (`benchmarks/configs/ling-3.0-flash-int8-share4`,
+model_type bailing_hybrid) goes through `models/moe/bailing_hybrid`'s
+trunks and `cake_tpu/models/reference/bailing_hybrid.py`, given the same
+two held groups: a `d8k` and a `t2k` prompt of the cell through
+512-token windows, then decode through the state and the pages, every
+other row of the 32 decoding beside them (fillers: the step is the timed
+one), and a second request in the `t2k` row's slot once it has finished,
+with its twin in a slot nothing has used. Experts teacher-forced (the
+reference's recurrence token by token against the served chunked and
+one-step forms). Its limits (LING_TOL) lie between what the served path
+reads and what must fail: a bfloat16 state, a bfloat16 decay, the
+unbounded gate, a state not zeroed at position 0, a group's score by its
+best alone, the gate a head left out.
+
 The last line of stdout is one JSON object with `ok`.
 """
 
@@ -314,6 +328,58 @@ DSV2_DECODE = 24
 DSV2_LAST = 128
 DSV2_PROBE_SPREAD = 8.0     # the standard deviation of the probe's scores
 
+# bailing_hybrid: 12 layers, 10 of them a float32 matrix state a row and
+# head under the gated delta rule, 2 latent attention over every key, 10
+# discrete choices of 8 of 512 experts inside 4 of 8 groups, chaotic
+# under seeded weights (sigmoid scores 1e-3 apart), so the reference is
+# TEACHER-FORCED in its experts as for deepseek_v2, and nothing else is
+# forced. Limits, each read where its fault shows: `mean` and `max`,
+# |error| / range of the logits over the compared positions (a prompt's
+# first 8, the 3 behind its first window edge, its last 128 and every
+# decode step: the window boundary, the passage from a row's last
+# window to its first one-step update, contexts to 8.2k): precision,
+# the gate's form, the head-wise gate; `state`, the FIRST KDA layer's
+# stored matrix state at a request's end against the reference's,
+# relative (root of summed squares; the worst request): what the state
+# and the decay are held in shows there before any other layer's
+# rounding (a bfloat16 alpha near 1 cannot say 0.9993: a channel with a
+# half-life of 1,000 tokens forgets in 180 or never); `reuse`, a slot's
+# second request against the same request in a slot nothing has used,
+# relative to the reference's range (the served path against itself: no
+# floor; over the request's PROMPT positions, which both rows take
+# through the mixed program: the reused row's first decode tokens ride
+# its twin's windows while the twin's go through the decode program, a
+# rounding apart, and one flipped expert is 0.2 of a logit's range:
+# `reuse_decode`, reported; for the altered reference that starts from
+# the state the slot's last request left, against the plain reference);
+# `agree_same_input`, the share of compared positions where the
+# reference's router, on the served path's OWN input to the first
+# sparse layer (TrunkOut.ffn_in), chooses the served path's 8 experts
+# (no teacher, no cascade: the group rule); `router_logit_err`:
+# ops/moe.router_logits on the chip against the reference's product on
+# that input, worst entry. `agree` (the least over the sparse layers of
+# the share of positions where the reference's own choice along the
+# forced trajectory is the served path's), every KDA layer's state and
+# `mean_edge` are reported, not limited. Each limit lies between the
+# worst the served path read on the chip over seeds 0 / 1 / 2 and the
+# LEAST an altered reference that it has to hold out read there (my
+# chip runs, PR 48; served | must fail): mean 3.52e-3 / 3.53e-3 /
+# 3.49e-3 | 7.33e-3 (a bfloat16 state: 1.42 times over, 1.47 under; the
+# gate a head left out 2.34e-2, a state not zeroed 9.57e-3, a bfloat16
+# decay 4.77e-2, the unbounded gate 0.123); max 3.0e-2 | 5.2e-2; state
+# 5.17e-3 / 5.16e-3 / 5.14e-3 | 1.77e-2 (a bfloat16 state: 1.9 times
+# either side; a bfloat16 decay 0.156, the unbounded gate 2.35); reuse
+# 0.0 exactly | 1.53e-2 (a state not zeroed); agree_same_input 1.0 |
+# 0.457-0.515 (a group by its best alone); router_logit_err 0.0.
+LING_TOL = {"mean": 5e-3, "max": 0.1, "state": 1e-2, "reuse": 1e-3,
+            "router_logit_err": 1e-3}
+LING_AGREE = 0.9
+LING_START, LING_EDGE, LING_LAST = 8, 3, 128
+# (slot, prompt tokens): the cell's two prompt classes, and a second
+# request in slot 1 once its first has finished
+LING_JOBS = ((0, 8100), (1, 2000), (1, 1950))
+LING_DECODE = 24
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -440,6 +506,8 @@ def main() -> int:
         return compare_nemotron(engine, cell, args, t_start)
     if raw_config.get("model_type") == "zaya":
         return compare_zaya(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "bailing_hybrid":
+        return compare_ling(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -2542,6 +2610,386 @@ def compare_zaya(engine, cell, args, t_start) -> int:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)
     return 0 if ok else 1
+
+
+def compare_ling(engine, cell, args, t_start) -> int:
+    """The comparison above for a matrix state a row and head beside the
+    latent page pool: the engine's own mixed and decode trunks with the
+    head at every position, against models/reference/bailing_hybrid.py
+    on teacher-forced experts. Jobs run a slot each, one window a step
+    with every other row decoding beside it (the jobs' rows that have
+    finished their prompts, and fillers in every slot no job uses, so
+    that the step is the timed one), the decode program when no row
+    prefills; slot 1 takes a second request when its first has finished
+    (the state it left behind must not reach the second), and its twin
+    runs beside it in a slot nothing has used."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import bailing_hybrid as bh
+    from cake_tpu.models.reference import bailing_hybrid as ref
+    from cake_tpu.ops import moe as moe_ops
+    from cake_tpu.ops.quant import qmatmul
+
+    cfg, params, rope = engine.config, engine.params, engine.rope
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    attn = engine.attn_impl["mixed"]
+
+    def outputs(out):
+        """What a step hands the host: logits at every position, the
+        cache, every sparse layer's choice, and the first sparse
+        layer's input with the router's logits on it as moe_mlp makes
+        them."""
+        logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
+        h = out.ffn_in[0]
+        router = params["blocks"]["router"][0]
+        return (logits, out.cache, out.experts, h,
+                moe_ops.router_logits(h, router))
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = bh.mixed_trunk(params, tokens, pos, q_len, active, cache,
+                                rope, cfg, attn, n_tokens)
+        return outputs(out)
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        return outputs(bh.decode_trunk(params, tokens, cache, pos, active,
+                                       rope, cfg, attn))
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = LING_JOBS if not args.rehearse else ((0, 70), (1, 30), (1, 25))
+    # the slot's second request again, beside it, in a slot nothing has
+    # used: the same tokens, the same steps
+    second = len(jobs) - 1
+    opener = next(i for i, (slot, _) in enumerate(jobs)
+                  if slot == jobs[second][0])
+    twin = len(jobs)
+    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
+    n_decode = LING_DECODE if not args.rehearse else 6
+    last = LING_LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs[:twin]]
+    sequences.append(sequences[second])
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    job_slots = sorted({slot for slot, _ in jobs})
+    fillers = [b for b in range(B) if b not in job_slots]
+    table = (np.arange(B)[:, None] * per_row
+             + np.arange(per_row)[None, :]).astype(np.int32)
+    assert table.max() < engine.cache.n_pages
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    state_dtype = str(cache.ssm.dtype)
+    engine.cache = None
+    filler_tokens = rng.integers(0, cfg.vocab_size, (B, per_row * page))
+
+    Ls, k = len(cfg.sparse_layers), cfg.num_experts_per_tok
+    got = [dict() for _ in jobs]        # position -> logits [V]
+    ffn_in = [dict() for _ in jobs]     # position -> (h [D], logits [E])
+    # every position's experts, for the teacher-forced reference
+    all_routed = [np.zeros((Ls, len(seq), k), np.int32)
+                  for seq in sequences]
+    states = [None] * len(jobs)         # the rows' state at a job's end
+    off = [0] * len(jobs)
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, the request's
+        first positions and those behind the first window edge."""
+        return (position >= prompts[i] - last or position < LING_START
+                or C <= position < C + LING_EDGE)
+
+    def current(slot):
+        """The slot's first unfinished job."""
+        return next((i for i, (s, _) in enumerate(jobs)
+                     if s == slot and off[i] < len(sequences[i])
+                     and (i != twin
+                          or off[opener] == len(sequences[opener]))), None)
+
+    steps = {"mixed": 0, "decode": 0}
+    n_steps = 0
+    t0 = time.monotonic()
+    while any(off[i] < len(s) for i, s in enumerate(sequences)):
+        live = {slot: current(slot) for slot in job_slots}
+        live = {slot: i for slot, i in live.items() if i is not None}
+        qlen = np.zeros(B, np.int32)
+        pos = np.zeros(B, np.int32)
+        # one window a step, the jobs mid-prefill taking turns in order
+        # (family.Windows.STEP); every other live row decodes or waits
+        prefilling = [slot for slot, i in sorted(live.items())
+                      if off[i] < prompts[i]]
+        for slot, i in live.items():
+            if off[i] >= prompts[i]:
+                qlen[slot] = 1
+            elif slot == prefilling[0]:
+                qlen[slot] = min(C, prompts[i] - off[i])
+            pos[slot] = off[i]
+        qlen[fillers], pos[fillers] = 1, n_steps
+        width = C if prefilling else 1
+        toks = np.zeros((B, width), np.int32)
+        for slot, i in live.items():
+            toks[slot, :qlen[slot]] = sequences[i][off[i]:off[i] + qlen[slot]]
+        toks[fillers, 0] = filler_tokens[fillers, n_steps]
+        if prefilling:
+            logits, cache, experts, h, r_logits = window_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(qlen), jnp.asarray(qlen > 0), cache,
+                paged.mixed_bucket_for(engine._mixed_buckets,
+                                       int(qlen.sum())))
+            first = np.cumsum(qlen) - qlen
+            steps["mixed"] += 1
+        else:
+            logits, cache, experts, h, r_logits = decode_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(qlen > 0), cache)
+            first = np.arange(B)
+            steps["decode"] += 1
+        experts = np.asarray(experts)
+        wanted = []
+        for slot, i in live.items():
+            n = int(qlen[slot])
+            all_routed[i][:, off[i]:off[i] + n] = experts[
+                :, first[slot]:first[slot] + n]
+            wanted += [(i, off[i] + j, first[slot] + j) for j in range(n)
+                       if compared(i, off[i] + j)]
+        if wanted:
+            rows = jnp.asarray([r for _, _, r in wanted])
+            fetched = [np.asarray(x[rows]) for x in (logits, h, r_logits)]
+            for n, (i, position, _) in enumerate(wanted):
+                got[i][position] = fetched[0][n]
+                ffn_in[i][position] = (fetched[1][n], fetched[2][n])
+        n_steps += 1
+        for slot, i in live.items():
+            off[i] += int(qlen[slot])
+            if qlen[slot] and off[i] == len(sequences[i]):
+                states[i] = np.asarray(cache.ssm[:, slot])
+    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+        f"steps of {B} rows in {time.monotonic() - t0:.1f} s")
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time -----------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = dict(
+        num_attention_heads=cfg.num_attention_heads,
+        head_dim=cfg.kda_head_dim, kda_lower_bound=cfg.kda_lower_bound,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
+        num_experts_per_tok=k,
+        routed_scaling_factor=cfg.routed_scaling_factor)
+    held = (cfg.first_routed_expert, cfg.num_local_experts)
+    kda_core = ref.kda_core
+    jitted = {}
+
+    def jit_kda(lp, h, config, state=None, tails=None):
+        """The recurrence under jit: one trace per config (its switches
+        are read while tracing), shape and kind of start."""
+        key = (tuple(sorted(config.items())), state is None)
+        if key not in jitted:
+            jitted[key] = jax.jit(
+                lambda lp, h, state, tails: kda_core(lp, h, config, state,
+                                                     tails))
+        return jitted[key]({k_: v for k_, v in lp.items() if k_ != "kind"},
+                           h, state, tails)
+
+    ref.kda_core = jit_kda
+    ref.attend_block = jax.jit(ref.attend_block, static_argnames=("scale",))
+    ref.swiglu = jax.jit(ref.swiglu)
+
+    def layers():
+        # from the host copy: one layer's leaves cross to the device at
+        # a time, as stored, and widen there
+        return bh.reference_layers(host["blocks"], cfg)
+
+    top = {k_: dequantized(jax.tree.map(jnp.asarray, host[k_]))
+           for k_ in ("embed", "final_norm", "lm_head")}
+
+    def reference(which, config=ref_cfg, starts=None):
+        """The reference over the jobs `which`, TEACHER-FORCED in its
+        experts -> ({job: logits}, {job: its own choice along that
+        trajectory}, {job: each KDA layer's (final state, tails)})."""
+        t0 = time.monotonic()
+        seqs = [sequences[i] for i in which]
+        routing = [[] for _ in seqs]
+        finals = [[] for _ in seqs]
+        logits = ref.forward(top, seqs, config, layers=layers(), held=held,
+                             routing=routing, states=finals, starts=starts,
+                             forced=[list(all_routed[i]) for i in which])
+        say(f"  reference over {sum(len(s_) for s_ in seqs)} tokens in "
+            f"{time.monotonic() - t0:.1f} s")
+        return (dict(zip(which, (np.asarray(x) for x in logits))),
+                dict(zip(which, routing)), dict(zip(which, finals)))
+
+    def rel(a, b):
+        return float(np.linalg.norm(np.asarray(a, np.float64)
+                                    - np.asarray(b, np.float64))
+                     / np.linalg.norm(np.asarray(b, np.float64)))
+
+    # the first sparse layer's router, as the reference multiplies it
+    first_sparse = next(lp for lp in layers() if "router" in lp)
+    router = {k_: first_sparse[k_] for k_ in ("router", "router_bias")}
+    del first_sparse
+
+    def same_input(which, config):
+        """(agree_same_input, router_logit_err) over the compared
+        positions of `which`: the reference's router on the served
+        path's own FFN input."""
+        hs, served_logits, chosen = [], [], []
+        for i in which:
+            for position in sorted(got[i]):
+                hs.append(ffn_in[i][position][0])
+                served_logits.append(ffn_in[i][position][1])
+                chosen.append(all_routed[i][0, position])
+        with jax.default_matmul_precision("highest"):
+            h = jnp.asarray(np.stack(hs), jnp.float32)
+            _, _, own, _ = ref.router(router, h, config)
+            logit_err = float(jnp.max(jnp.abs(
+                ref.mm(h, router["router"]) - np.stack(served_logits))))
+        same = [set(a.tolist()) == set(b.tolist())
+                for a, b in zip(np.asarray(own), chosen)]
+        return float(np.mean(same)), logit_err
+
+    def readings(which, logits_of, routing_of, states_of, config=ref_cfg,
+                 against=None):
+        """Over the compared positions of the jobs `which`, the served
+        logits against `logits_of`: mean and worst |error| / range;
+        `mean_edge` over the positions behind the first window edge;
+        `agree`; `state`, the first KDA layer's stored state at the
+        jobs' ends against `states_of`'s (the worst job), and every
+        layer's; against: the plain reference's logits (`nearer` is then
+        the served path's distance from `logits_of` over its distance
+        from the plain reference, reported)."""
+        err_sum = n = worst = 0.0
+        edge = []
+        same = np.zeros(Ls)
+        count = 0
+        to_this = to_plain = 0.0
+        for i in which:
+            for position, logits in sorted(got[i].items()):
+                w = logits_of[i][position]
+                err = np.abs(logits - w) / float(w.max() - w.min())
+                err_sum += float(err.sum())
+                n += err.size
+                worst = max(worst, float(err.max()))
+                if C <= position < C + LING_EDGE:
+                    edge.append(float(err.mean()))
+                same += [set(all_routed[i][layer, position].tolist())
+                         == set(routing_of[i][layer][position].tolist())
+                         for layer in range(Ls)]
+                count += 1
+                if against is not None:
+                    to_this += float(np.sum(np.square(logits - w)))
+                    to_plain += float(np.sum(np.square(
+                        logits - against[i][position])))
+        by_layer = [max(rel(states[i][m], states_of[i][m][0])
+                        for i in which)
+                    for m in range(len(cfg.kda_layers))]
+        agree_same, logit_err = same_input(which, config)
+        out = {"mean": err_sum / n, "max": worst,
+               "mean_edge": float(np.mean(edge)) if edge else 0.0,
+               "agree": float(same.min()) / count,
+               "agree_same_input": agree_same,
+               "router_logit_err": logit_err, "state": by_layer[0],
+               "state_by_layer": [round(x, 5) for x in by_layer],
+               "positions": count}
+        if against is not None:
+            out["nearer"] = (to_this / max(to_plain, 1e-300)) ** 0.5
+        return out
+
+    def apart(logits_at, yardstick, decode=False):
+        """The slot's second request, by `logits_at`, against
+        `yardstick`: mean |difference| / the reference's range, over
+        its compared prompt positions (decode: its decode steps)."""
+        errs = [np.abs(logits_at(p) - other) / float(
+                    want[second][p].max() - want[second][p].min())
+                for p, other in sorted(yardstick.items())
+                if (p >= prompts[second]) == decode]
+        return float(np.mean(np.concatenate(errs)))
+
+    def passes(r):
+        return (all(r[k_] < limit for k_, limit in LING_TOL.items())
+                and r["agree_same_input"] > LING_AGREE)
+
+    plain = list(range(twin))
+    want, want_routing, want_finals = reference(plain)
+    served = readings(plain, want, want_routing, want_finals)
+    # the served path against itself: the reused slot against the fresh
+    assert sorted(got[twin]) == sorted(got[second])
+    served["reuse"] = apart(lambda p: got[second][p], got[twin])
+    served["reuse_decode"] = apart(lambda p: got[second][p], got[twin],
+                                   decode=True)
+    expected = sum(
+        len({q for q in range(p + n_decode)
+             if q >= p - last or q < LING_START or C <= q < C + LING_EDGE})
+        for p in prompts[:twin])
+    result = {
+        "served": served, "expected_positions": expected, "tol": LING_TOL,
+        "agree_same_input_floor": LING_AGREE, "seed": args.seed,
+        "jobs": [list(j) for j in jobs], "rows_a_step": B, "steps": steps,
+        "attention": impl, "device": jax.devices()[0].device_kind,
+        "state_dtype": state_dtype,
+        # the seeded state neither dies nor grows: its root mean square
+        # an entry at each request's end, first KDA layer
+        "state_rms_layer0": [round(float(np.sqrt(np.mean(np.square(
+            states[i][0])))), 5) for i in plain],
+    }
+    ok = (served["positions"] == expected and passes(served)
+          and state_dtype == "float32")
+    if not ok:
+        say("FAILED: the served path is outside the tolerance")
+
+    # -- what must NOT pass: the reference, altered, read as the served
+    # path is (on the slot that is used twice: its two requests) --------
+    if args.negatives:
+        short = [opener, second]
+        negatives = {
+            "bf16_state": dict(config=dict(ref_cfg,
+                                           kda_state_dtype="bfloat16")),
+            "bf16_decay": dict(config=dict(ref_cfg,
+                                           kda_decay_dtype="bfloat16")),
+            "unbounded_gate": dict(config=dict(ref_cfg,
+                                               kda_gate="softplus")),
+            # the second request starts from what the first left
+            "state_not_zeroed": dict(
+                starts=[None, list(want_finals[opener])]),
+            "group_score_by_the_best": dict(config=dict(ref_cfg,
+                                                        group_top=1)),
+            "no_head_gate": dict(config=dict(ref_cfg, head_gate=False))}
+        result["must_fail"] = {}
+        for name, kw in negatives.items():
+            say(f"negative: {name}")
+            logits, routing, finals = reference(short, **kw)
+            r = readings(short, logits, routing, finals,
+                         config=kw.get("config", ref_cfg), against=want)
+            # what `reuse` reads on the served side: the second request
+            # as this reference gives it against the plain one's
+            r["reuse"] = (apart(lambda p: logits[second][p],
+                                {p: want[second][p] for p in got[second]})
+                          if "starts" in kw else 0.0)
+            result["must_fail"][name] = r
+            if passes(r):
+                say(f"FAILED: the reference with {name} passes the "
+                    "tolerance")
+                ok = False
+    result["ok"] = bool(ok) or bool(args.rehearse and served["positions"]
+                                    == expected)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"result_ling_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if result["ok"] else 1
 
 
 if __name__ == "__main__":

@@ -19,8 +19,8 @@ from cake_tpu.models.llama.config import MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
-    DeepseekV2Config, Dots3NoteConfig, GlmMoeDsaConfig, MoEConfig,
-    NemotronHConfig, ZayaConfig,
+    BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, GlmMoeDsaConfig,
+    MoEConfig, NemotronHConfig, ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
 
@@ -36,12 +36,14 @@ TINY = {
     "deepseek_v2": DeepseekV2Config.tiny_dsv2,
     "nemotron_h": NemotronHConfig.tiny_nemotron,
     "zaya": ZayaConfig.tiny_zaya,
+    "bailing_hybrid": BailingHybridConfig.tiny_ling,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
          "dots3_note": "latent row and index key",
          "deepseek_v2": "latent row",
-         "nemotron_h": "state", "zaya": "tail"}
+         "nemotron_h": "state", "zaya": "tail",
+         "bailing_hybrid": "KDA state"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 
@@ -198,7 +200,7 @@ def test_the_readmes_table_is_the_families_tables():
 
 NAMES = ("kv_lora_rank", "mamba_layers", "cca_time0", "sliding_layers",
          "nemotron", "zaya", "glm", "dots3", "deepseek", "rope_scaling",
-         "n_group")
+         "n_group", "bailing", "kda")
 
 
 @pytest.mark.parametrize("where", ["cake_tpu/serve/engine.py",
