@@ -28,7 +28,14 @@ Mixtral and OLMoE families (models/moe). One layer, on N tokens:
     over the sorted rows. The grid walks (row tile, expert) VISITS: a
     tile of `tm` sorted rows that straddles two experts is visited once
     for each and stores only that expert's rows, so work is proportional
-    to N*k plus at most one tile per expert, never to N*E. Weights are
+    to N*k plus at most one tile per expert, never to N*E. The grid is
+    (N_out / tn, V): V = M/tm + E - 1 visits for each block of `tn`
+    output columns, and a grid step costs its fixed part whatever it
+    holds, so `tn` is the widest multiple of 128 that divides the
+    output width and whose blocks fit `GMM_VMEM_BUDGET` by
+    `gmm_vmem_bytes`' count (`out_tile`: a function of K, the width,
+    `tm` and the two element widths; `gmm_grid` gives a call's tile,
+    column tiles, visits and grid steps from shapes alone). Weights are
     read as stored: the kernel takes the layer's STACKED leaf
     `[L, E, in, out]` and the layer index as a scalar-prefetch operand
     (a scan-sliced operand of a custom call would be copied every
@@ -286,11 +293,61 @@ def plan_stats(plan: DispatchPlan, experts, rows_routed=None) -> MoEStats:
 # -- the grouped matmul --------------------------------------------------------
 
 
-def _out_tile(n_out: int) -> int:
-    for t in (512, 256, 128):
-        if n_out % t == 0:
-            return t
-    return n_out
+# What one grid step may hold in VMEM by `gmm_vmem_bytes`' count: GLM's
+# [6144, 512] int8 block beside a [128, 6144] bf16 tile, the fullest
+# step a served model ran under the {512, 256, 128} rule, counts 15.53
+# MiB and compiles under the compiler's 16 MiB of scoped VMEM (Mosaic
+# widens the block a slice at a time: the count is an upper bound).
+GMM_VMEM_BUDGET = 15 * 1024 * 1024 + 768 * 1024
+
+
+def gmm_vmem_bytes(tm: int, K: int, tn: int, x_bytes: int, w_bytes: int,
+                   scaled: bool) -> int:
+    """Bytes a `cake_moe_gmm` grid step holds at once: the activation
+    tile [tm, K] and the weight block [K, tn] as stored (and its scales,
+    one row padded to eight) twice each, for the pipeline's two buffers,
+    the block widened to the activation type where it is stored
+    narrower, the float32 accumulator and the output block twice."""
+    held = 2 * tm * K * x_bytes + 2 * K * tn * w_bytes
+    if scaled:
+        held += 2 * 8 * tn * 4
+    if w_bytes != x_bytes:
+        held += K * tn * x_bytes
+    return held + tm * tn * 4 + 2 * tm * tn * x_bytes
+
+
+def out_tile(K: int, n_out: int, tm: int, x_bytes: int, w_bytes: int,
+             scaled: bool) -> int:
+    """Output columns a grid step computes: the widest multiple of 128
+    that divides `n_out` and fits `GMM_VMEM_BUDGET` (128 where none
+    does; an `n_out` that is no multiple of 128 is one block)."""
+    if n_out % 128:
+        return n_out
+    fits = [tn for tn in range(128, n_out + 1, 128) if n_out % tn == 0
+            and gmm_vmem_bytes(tm, K, tn, x_bytes, w_bytes, scaled)
+            <= GMM_VMEM_BUDGET]
+    return max(fits, default=128)
+
+
+class GmmGrid(NamedTuple):
+    """One `grouped_matmul` call's walk: `steps` = `column_tiles` x
+    `visits` grid steps over blocks of `tn` output columns."""
+
+    tn: int
+    column_tiles: int
+    visits: int
+    steps: int
+
+
+def gmm_grid(n_pairs: int, n_experts: int, K: int, n_out: int,
+             x_bytes: int, w_bytes: int, scaled: bool) -> GmmGrid:
+    """The grid of one projection K -> n_out over `n_pairs` (token,
+    expert) pairs and `n_experts` held experts, from shapes and element
+    widths alone: what `dispatch_plan` and `grouped_matmul` build."""
+    tm = row_tile(n_pairs)
+    visits = -(-n_pairs // tm) + n_experts - 1
+    tn = out_tile(K, n_out, tm, x_bytes, w_bytes, scaled)
+    return GmmGrid(tn, n_out // tn, visits, n_out // tn * visits)
 
 
 def _gmm_kernel(layer_ref, tile_ref, expert_ref, lo_ref, hi_ref,
@@ -346,7 +403,8 @@ def grouped_matmul(x, w, layer, visit_tile, visit_expert, visit_lo,
     M, K = x.shape
     _, _, Kw, N = q.shape
     assert K == Kw and M % tm == 0, (x.shape, q.shape, tm)
-    tn = _out_tile(N)
+    tn = out_tile(K, N, tm, x.dtype.itemsize, q.dtype.itemsize,
+                  scaled)
     V = visit_tile.shape[0]
 
     def x_map(j, v, layer, tile, expert, lo, hi):

@@ -315,3 +315,166 @@ def test_engine_serves_moe_over_topology(tmp_path):
                    if t in CFG.eos_token_ids), 4)
     assert got[:eos_at + 1] == want[:min(eos_at + 1, 4)][:len(got)]
     assert len(got) >= min(eos_at + 1, 4)
+
+
+# -- cake_moe_gmm's output tile (PERF.md §6, PR 49) ----------------------------
+
+# cell -> (held experts, experts a token, a mixed step's tokens, a decode
+# step's rows, the expert projections K -> N): what tools/moe_grid.py
+# reads from benchmarks/configs/*/ (test_moe_grid_tool_reads_the_cells)
+CELLS = {
+    "nemotron3-super-int8-share4": (128, 22, 544, 32,
+                                    ((1024, 2688), (2688, 1024))),
+    "ling-3.0-flash-int8-share4": (128, 8, 544, 32,
+                                   ((2560, 768), (768, 2560))),
+    "olmoe-1b-7b-int8": (64, 8, 144, 16, ((2048, 1024), (1024, 2048))),
+    "zaya1-8b-int8": (16, 1, 288, 32, ((2048, 2048),)),
+    "glm-5.2-int8-share16": (16, 8, 528, 8, ((6144, 2048), (2048, 6144))),
+    "dots3-note-int8-share8": (32, 8, 544, 32,
+                               ((5120, 1536), (1536, 5120))),
+    "deepseek-v2-int8-share8": (20, 6, 544, 32,
+                                ((5120, 1536), (1536, 5120))),
+}
+GMM_CALLS = [(cell, kind, K, N) for cell, shape in CELLS.items()
+             for kind in ("decode", "mixed") for K, N in shape[4]]
+
+
+@pytest.fixture(scope="module")
+def grid_tool():
+    import importlib.util
+    import pathlib
+    spec = importlib.util.spec_from_file_location(
+        "moe_grid", pathlib.Path(__file__).resolve().parents[1]
+        / "tools" / "moe_grid.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("cell,kind,K,N", GMM_CALLS, ids=[
+    f"{c.split('-')[0]}-{k}-{K}x{N}" for c, k, K, N in GMM_CALLS])
+def test_out_tile_is_the_widest_that_fits(grid_tool, cell, kind, K, N):
+    """Every cell's expert calls, bf16 rows on int8 per-channel weights:
+    the tile divides the width in multiples of 128, fits the budget by
+    the function's own count where the next wider candidate does not,
+    is never narrower than the {512, 256, 128} rule's, and `gmm_grid`
+    counts the grid `dispatch_plan` and `grouped_matmul` build."""
+    from cake_tpu.ops import moe
+
+    E, k, mixed_tokens, decode_rows, _ = CELLS[cell]
+    n_pairs = (decode_rows if kind == "decode" else mixed_tokens) * k
+    tm = moe.row_tile(n_pairs)
+    tn = moe.out_tile(K, N, tm, 2, 1, True)
+    assert N % tn == 0 and tn % 128 == 0
+    assert moe.gmm_vmem_bytes(tm, K, tn, 2, 1, True) <= moe.GMM_VMEM_BUDGET
+    wider = [t for t in range(tn + 128, N + 1, 128) if N % t == 0]
+    assert all(moe.gmm_vmem_bytes(tm, K, t, 2, 1, True)
+               > moe.GMM_VMEM_BUDGET for t in wider)
+    assert tn >= grid_tool.tile_before(N)
+    grid = moe.gmm_grid(n_pairs, E, K, N, 2, 1, True)
+    plan = jax.eval_shape(
+        lambda e: moe.dispatch_plan(e, E),
+        jax.ShapeDtypeStruct((n_pairs // k, k), jnp.int32))
+    assert grid == (tn, N // tn, plan.visit_tile.shape[0],
+                    N // tn * plan.visit_tile.shape[0])
+
+
+@pytest.mark.parametrize("K,N,tm", [(1024, 2688, 128), (1024, 2688, 16),
+                                    (2560, 768, 128), (2560, 768, 16)])
+def test_out_tile_of_a_width_512_and_256_do_not_divide(K, N, tm):
+    """Nemotron's 2,688 = 21 x 128 took 128 and Ling's 768 took 256
+    under the old rule: 21 and 3 column tiles where one block fits."""
+    from cake_tpu.ops import moe
+
+    assert moe.out_tile(K, N, tm, 2, 1, True) == N
+
+
+@pytest.mark.parametrize("K,N,tm,x_bytes,w_bytes,scaled,want", [
+    (64, 96, 16, 4, 4, False, 96),        # no multiple of 128: one block
+    (6144, 2048, 128, 2, 1, True, 512),   # the fullest block: as it was
+    (6144, 2048, 128, 2, 2, False, 512),  # bf16: stored as it is used
+    (1 << 20, 256, 128, 2, 1, True, 128),  # nothing fits: the least tile
+])
+def test_out_tile_at_the_edges(K, N, tm, x_bytes, w_bytes, scaled, want):
+    from cake_tpu.ops import moe
+
+    assert moe.out_tile(K, N, tm, x_bytes, w_bytes, scaled) == want
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_moe_grid_tool_reads_the_cells(grid_tool, cell):
+    """tools/moe_grid.py, which prints PERF.md's table, finds in a
+    cell's directory the shapes this file's cases are made of."""
+    import pathlib
+
+    E, k, mixed_tokens, decode_rows, projections = CELLS[cell]
+    rows = grid_tool.rows(str(pathlib.Path(__file__).resolve().parents[1]
+                              / "benchmarks" / "configs" / cell))
+    assert {(r["kind"], r["n_tokens"]) for r in rows} >= {
+        ("decode", decode_rows), ("mixed", mixed_tokens)}
+    assert {(r["K"], r["N"]) for r in rows} == set(projections)
+    for r in rows:
+        assert (r["n_experts"], r["n_pairs"]) == (E, r["n_tokens"] * k)
+        assert r["steps"] <= r["steps_before"]
+        assert r["steps"] == r["column_tiles"] * r["visits"]
+
+
+def _sorted_rows_case(n_out, kind):
+    """58 (token, expert) pairs over 4 experts, one of them empty, 9
+    pairs dropped, in tiles of 16 that straddle experts; weights [1, 4,
+    K, n_out] int8 per-channel or bf16."""
+    from cake_tpu.ops import moe
+    from cake_tpu.ops.quant import QTensor
+
+    rng = np.random.default_rng(0)
+    K, E = 256, 4
+    experts = rng.choice([0, 2, 3], size=(29, 2), p=[0.35, 0.15, 0.5])
+    valid = rng.random((29, 2)) > 0.15
+    plan = moe.dispatch_plan(jnp.asarray(experts, jnp.int32), E,
+                             jnp.asarray(valid))
+    assert plan.tm == 16 and int(plan.counts[1]) == 0
+    x = jnp.asarray(rng.normal(size=(plan.src_token.shape[0], K)),
+                    jnp.bfloat16)
+    if kind == "int8":
+        w = QTensor(jnp.asarray(rng.integers(-127, 128, (1, E, K, n_out)),
+                                jnp.int8),
+                    jnp.asarray(rng.uniform(0.5, 1.5, (1, E, n_out)) / 2048,
+                                jnp.float32))
+        dense = np.asarray(w.q, np.float32) * np.asarray(w.scale)[:, :, None]
+    else:
+        w = jnp.asarray(rng.normal(size=(1, E, K, n_out)) / 16, jnp.bfloat16)
+        dense = np.asarray(w.astype(jnp.float32))
+    return plan, x, w, dense[0]
+
+
+@pytest.mark.parametrize("kind", ["int8", "bf16"])
+@pytest.mark.parametrize("n_out", [384, 640, 896])
+def test_grouped_matmul_is_the_same_under_two_tiles(monkeypatch, n_out, kind):
+    """Widths that 256 does not divide, interpreted: every sorted row is
+    its own expert's product (a tile that straddles experts keeps each
+    visit's rows, the empty expert takes no visit's), and one block of
+    `n_out` columns gives the bits that blocks of 128 give."""
+    from cake_tpu.ops import moe
+
+    plan, x, w, dense = _sorted_rows_case(n_out, kind)
+    walk = (plan.visit_tile, plan.visit_expert, plan.visit_lo, plan.visit_hi)
+    got = {}
+    for tn in (moe.out_tile(256, n_out, 16, 2, 1 if kind == "int8" else 2,
+                            kind == "int8"), 128):
+        # (the jitted wrapper caches on its static arguments, not on the
+        # module's globals: call what it wraps)
+        monkeypatch.setattr(moe, "out_tile", lambda *a, _tn=tn: _tn)
+        got[tn] = np.asarray(jax.jit(
+            lambda x, w: moe.grouped_matmul.__wrapped__(
+                x, w, jnp.int32(0), *walk, tm=plan.tm, interpret=True)
+        )(x, w).astype(jnp.float32))
+    assert sorted(got) == [128, n_out]
+    counts = np.asarray(plan.counts)
+    n_rows = int(counts.sum())
+    assert n_rows > 32 and np.array_equal(got[n_out][:n_rows],
+                                          got[128][:n_rows])
+    expert_of = np.repeat(np.arange(len(counts)), counts)
+    xs = np.asarray(x.astype(jnp.float32))
+    for r in range(n_rows):
+        np.testing.assert_allclose(got[n_out][r], xs[r] @ dense[expert_of[r]],
+                                   rtol=2e-2, atol=2e-2)

@@ -192,3 +192,42 @@ def test_latent_window_kernel_compiles_at_the_cells_widths(
                                  biased)
     assert tq * heads == (1024 if row == 640 else 512)
     assert block == (2 if pages == 9 else 4)
+
+
+@pytest.mark.parametrize("n_pairs,E,K,N", [
+    (528 * 8, 16, 6144, 2048),    # GLM's gate and up, mixed: the budget's
+    (32 * 6, 20, 1536, 5120),     # DeepSeek-V2's down, decode: tn 2,560
+    (32 * 6, 20, 5120, 1536),     # DeepSeek-V2's gate and up, decode: 768
+    (544 * 22, 128, 1024, 2688),  # Nemotron's up, mixed: one block
+    (32 * 22, 128, 1024, 2688),   # ... and in a decode step
+    (544 * 22, 128, 2688, 1024)],  # Nemotron's down, mixed
+    ids=["glm_up", "dsv2_down_decode", "dsv2_up_decode", "nemotron_up",
+         "nemotron_up_decode", "nemotron_down"])
+def test_expert_matmul_compiles_at_the_tile_the_budget_admits(
+        tool, one_chip, n_pairs, E, K, N):
+    """`cake_moe_gmm` at the cells' fullest blocks by `gmm_vmem_bytes`'
+    count (bf16 rows, int8 per-channel weights) goes through Mosaic
+    under the compiler's own scoped VMEM: `GMM_VMEM_BUDGET` admits
+    nothing the chip's compiler refuses."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import moe
+    from cake_tpu.ops.quant import QTensor
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm = moe.row_tile(n_pairs)
+    M = -(-n_pairs // tm) * tm
+    grid = moe.gmm_grid(n_pairs, E, K, N, 2, 1, True)
+    walk = [sds((grid.visits,), jnp.int32)] * 4
+    compiled = jax.jit(
+        lambda x, w, layer, *walk: moe.grouped_matmul.__wrapped__(
+            x, w, layer, *walk, tm=tm, interpret=False)).lower(
+        sds((M, K), jnp.bfloat16),
+        QTensor(sds((2, E, K, N), jnp.int8), sds((2, E, N), jnp.float32)),
+        sds((), jnp.int32), *walk).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_moe_gmm" in hlo
+    assert grid.tn > 512 or (K, N) == (6144, 2048)
+    assert moe.gmm_vmem_bytes(tm, K, grid.tn, 2, 1, True) > 10 * 2**20
