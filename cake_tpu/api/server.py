@@ -752,6 +752,8 @@ class ApiServer:
             # calling both would pay Device.memory_stats() twice per
             # scrape on a multi-device host
             obs_steps.refresh_device_gauges()
+        # the collections hook takes no lock: its sums reach the series here
+        obs_steps.refresh_gc_series()
         m.gauge("cake_requests_waiting",
                 "Requests inside HTTP admission").set(self._waiting)
         m.gauge("cake_serving_healthy",

@@ -39,10 +39,24 @@ pieces, all dependency-free:
     `ProfileBusyError` (HTTP 409).
 
   * **Step phases** (`StepTelemetry.span`): the engine loop times its
-    own phases (PHASES: admin, schedule, build, dispatch, sample,
-    fetch, emit) into each record's `phases` and `gap_s`, and shows
-    them as `cake/<phase>` TraceAnnotations carrying the step number,
-    so a capture's host plane joins `/api/v1/steps` by step.
+    own phases (PHASES: admin, schedule, build, gate, dispatch,
+    sample, fetch, record, emit, release) into each record's `phases` and
+    `gap_s`, and shows them as `cake/<phase>` TraceAnnotations carrying
+    the step number, so a capture's host plane joins `/api/v1/steps` by
+    step.
+
+  * **The engine thread's clock** (PR 50): a record's `loop_s` is the
+    thread's seconds since the record before it (or since a `wait`
+    ended), so `loop_s` less the sum of its `phases` is what lay
+    outside every span; `offcpu` is, by phase, a span's wall seconds
+    less its thread's CPU seconds (`time.thread_time`): under `fetch`
+    and `dispatch` the device or the runtime, under any other phase
+    the thread WAITING while it had work (the interpreter lock, a
+    collection on another thread, the kernel's scheduler), under
+    `none` the same of the loop outside every span; `gc_s`,
+    `gc_n`, `gc_max_s` are the process's collections since the record
+    before (one `gc.callbacks` hook, `_GcWatch`; a generation-2
+    collection is a `cake/gc` annotation on the thread that ran it).
 
   * **Stretch boundaries**: why a chain of in-flight steps ended
     (BREAKS: `chain_break` on the next record that is not chained,
@@ -68,9 +82,11 @@ for.
 from __future__ import annotations
 
 import functools
+import gc
 import logging
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -146,6 +162,17 @@ _CHAIN_BREAKS = _m.counter(
     "chain before them ended (obs/steps.BREAKS; idle = the loop had "
     "nothing to run)",
     labelnames=("cause",))
+_GC_PAUSE = _m.counter(
+    "cake_gc_pause_seconds_total",
+    "Seconds the process spent in the interpreter's cyclic collections "
+    "while a step recorder was open, by generation (every thread waits "
+    "out a collection; gc_s on the step record)",
+    labelnames=("generation",))
+_GC_COLLECTIONS = _m.counter(
+    "cake_gc_collections_total",
+    "The interpreter's cyclic collections while a step recorder was "
+    "open, by generation (gc_n on the step record)",
+    labelnames=("generation",))
 _STEP_DISPATCH = _m.histogram(
     "cake_step_dispatch_seconds",
     "Per-step dispatch wall seconds, by step kind",
@@ -461,6 +488,97 @@ def refresh_device_gauges() -> None:
             _DEV_HBM_LIMIT.labels(device=dev).set(float(s["bytes_limit"]))
 
 
+# -- collections as events -----------------------------------------------------
+
+
+class _GcWatch:
+    """The interpreter's cyclic collections, process-wide: one
+    `gc.callbacks` hook while any step recorder is open (`open` by
+    StepTelemetry, `close` by its close()), two clock reads a
+    collection. A collection runs on whichever thread's allocation
+    crossed the threshold and holds the interpreter lock throughout,
+    so every thread waits it out: the sums are the process's, by
+    generation, and a recorder takes their delta a record (`mark`).
+    The hook takes no lock (a collection can start inside any lock's
+    critical section, a metric's included): the series on /metrics are
+    brought up to the sums by `refresh_gc_series`, at scrape time. A
+    generation-2 collection is also a `cake/gc` TraceAnnotation on its
+    thread, so a capture's host plane shows it."""
+
+    RECENT = 256      # pauses kept for a record's `gc_max_s`
+
+    def __init__(self):
+        # users alone, never the hook; re-entrant: a collection inside
+        # open() may finalize another recorder, whose release closes
+        self._lock = threading.RLock()
+        self._users = 0
+        self._annotation = None
+        self._t0: Optional[float] = None
+        self._open_ann = None
+        self.seconds = [0.0, 0.0, 0.0]
+        self.count = [0, 0, 0]
+        self._recent: deque = deque(maxlen=self.RECENT)
+
+    def open(self, annotation) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                self._annotation = annotation
+                gc.callbacks.append(self._hook)
+
+    def close(self) -> None:
+        with self._lock:
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self._hook)
+                # closed by a finalizer inside a collection: its second
+                # call will not come
+                ann, self._open_ann = self._open_ann, None
+                if ann is not None:
+                    ann.__exit__(None, None, None)
+
+    def _hook(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if info["generation"] == 2:
+                self._open_ann = self._annotation("cake/gc")
+                self._open_ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        t0, self._t0 = self._t0, None
+        ann, self._open_ann = self._open_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if t0 is None:      # hooked between a collection's two calls
+            return
+        g = info["generation"]
+        self.seconds[g] += t1 - t0
+        self.count[g] += 1
+        self._recent.append(t1 - t0)
+
+    def mark(self) -> Tuple[float, int]:
+        """(seconds, collections) so far, all generations."""
+        return sum(self.seconds), sum(self.count)
+
+    def longest(self, last: int) -> float:
+        """The longest of the last `last` collections (of the RECENT
+        kept); 0.0 for none."""
+        k = min(last, len(self._recent))
+        return max(list(self._recent)[-k:]) if k > 0 else 0.0
+
+
+GC_WATCH = _GcWatch()
+
+
+def refresh_gc_series() -> None:
+    """Bring cake_gc_pause_seconds_total / cake_gc_collections_total up
+    to the watch's sums (scrape time: api/server.py)."""
+    for g in range(3):
+        _GC_PAUSE.labels(generation=str(g)).set_total(GC_WATCH.seconds[g])
+        _GC_COLLECTIONS.labels(generation=str(g)).set_total(
+            GC_WATCH.count[g])
+
+
 # -- XLA cost accounting ------------------------------------------------------
 
 
@@ -679,6 +797,24 @@ class StepRecord:
     # finished the step before the host sent the one after it
     fetch_wait_s: Optional[float] = None
     late: Optional[bool] = None
+    # engine-thread seconds from the record before this one (or from
+    # the end of a `wait`) to this one, by perf_counter: less the sum
+    # of `phases`, what lay outside every span. Absent on a recorder's
+    # first record
+    loop_s: Optional[float] = None
+    # by phase, a span's wall seconds less its thread's CPU seconds:
+    # the thread was not running (under fetch and dispatch it waited
+    # for the device or the runtime; under any other phase it had work
+    # and could not do it); under "none", the same of loop_s outside
+    # every span. Signed differences of two clocks: a few microseconds
+    # under 0 where the thread ran throughout, and on a host whose CPU
+    # clock ticks coarsely right only in sums
+    offcpu: Optional[Dict[str, float]] = None
+    # the process's cyclic collections since the record before: their
+    # seconds, their number, the longest one (_GcWatch)
+    gc_s: Optional[float] = None
+    gc_n: Optional[int] = None
+    gc_max_s: Optional[float] = None
 
     def to_dict(self) -> Dict:
         out = {
@@ -739,16 +875,29 @@ class StepRecord:
         if self.fetch_wait_s is not None:
             out["fetch_wait_s"] = round(self.fetch_wait_s, 6)
             out["late"] = self.late
+        if self.loop_s is not None:
+            out["loop_s"] = round(self.loop_s, 6)
+        if self.offcpu:
+            out["offcpu"] = {k: round(v, 6) for k, v in self.offcpu.items()}
+        if self.gc_n is not None:
+            out["gc_s"] = round(self.gc_s, 6)
+            out["gc_n"] = self.gc_n
+            out["gc_max_s"] = round(self.gc_max_s, 6)
         if self.moe is not None:
             out.update((key, round(v, 3)) for key, v in self.moe.items())
         return out
 
 
 # The step-phase vocabulary (PERF.md §3 lists what each covers in
-# serve/engine.py). "wait" is the idle engine: a trace annotation only,
+# serve/engine.py). "gate": a stretch's question whether it may
+# dispatch ahead (_drive_burst). "record": the writing of a step's
+# record, whose seconds go to the record AFTER it (the loop's clock
+# turns over where this span starts). "release": a completed dispatch's
+# device outputs die (the runtime gives the interpreter lock away while
+# it frees them). "wait" is the idle engine: a trace annotation only,
 # it belongs to no step and closes the open phase table.
-PHASES = ("admin", "schedule", "build", "dispatch", "sample", "fetch",
-          "emit", "wait")
+PHASES = ("admin", "schedule", "build", "gate", "dispatch", "sample",
+          "fetch", "record", "emit", "release", "wait")
 
 # Why a chain of in-flight steps ended (serve/engine._drive_burst's
 # gate, the first condition that held, in the order it tests them):
@@ -767,11 +916,21 @@ BREAKS = ("stop", "queue", "cancel", "command", "sync", "stretch_cap",
 # scheduler's plan; an admission's head, prefix match, pages, restore
 # or adoption (admit_pages); its sampling state and ring, the eager
 # device launches (admit_ring). dispatch: the call of the step program
-# itself, apart from the staging of its arguments. emit: the
-# detokenisation, a row a token (StepTelemetry.add_part: no annotation;
-# add_detok_ids beside it counts the ids it decodes).
+# itself, apart from the staging of its arguments. emit: a row's token
+# through serve/engine._emit by the clock reads at its seams, no
+# annotation a row (StepTelemetry.add_emit; EMIT_SEAMS in the order
+# they run): `rows` what the span does for a row outside _emit (the
+# mirrors, the tolist() / zip of the alternatives; from the span's
+# start or the row before), `trace` the request tracer and the TTFT
+# series, `report` the journal's note, the stats and the scheduler's
+# report, `detok` the detokenisation (add_detok_ids beside it counts
+# the ids it decodes), `stream` the request's callback (a queue put,
+# and whatever the interpreter hands the woken thread before it
+# returns), `retire` a finished row's release.
+EMIT_SEAMS = ("emit.rows", "emit.trace", "emit.report", "emit.detok",
+              "emit.stream", "emit.retire")
 PARTS = ("schedule.plan", "schedule.admit_pages", "schedule.admit_ring",
-         "dispatch.launch", "emit.detok")
+         "dispatch.launch") + EMIT_SEAMS
 _PART_SPAN = {key: key.split(".")[0] for key in PARTS}
 
 # A chained step whose own fetch waited less than this was `late`: the
@@ -795,14 +954,13 @@ class _Span:
     clock the device planes use. Engine thread only; spans do not nest
     (a nested span's seconds would count in both)."""
 
-    __slots__ = ("_tel", "_name", "_ann", "_t0")
+    __slots__ = ("_tel", "_name", "_ann", "_t0", "_cpu0")
 
     def __init__(self, tel: "StepTelemetry", name: str):
         self._tel = tel
         self._name = name
 
     def __enter__(self):
-        import jax
         tel = self._tel
         stats = {"step": tel._next}
         if (self._name == "dispatch" and tel._break is not None
@@ -811,18 +969,22 @@ class _Span:
             # chain before it ended where the idle gap ends
             stats["chain_break"] = tel._break
         # outside a capture a TraceAnnotation is a flag test
-        self._ann = jax.profiler.TraceAnnotation(
-            "cake/" + self._name, **stats)
+        self._ann = tel._annotation("cake/" + self._name, **stats)
         self._ann.__enter__()
         tel._open = self._name
-        self._t0 = time.perf_counter()
+        # the CPU clock's two reads lie INSIDE the wall clock's: on the
+        # chip's host one costs 5.5 us (a system call; my chip run,
+        # PR 50), which then counts in the span and not between spans
+        self._t0 = tel._mark = time.perf_counter()
+        self._cpu0 = tel._mark_cpu = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        cpu = time.thread_time() - self._cpu0
         t1 = time.perf_counter()
         self._tel._open = None
         self._ann.__exit__(*exc)
-        self._tel._close_span(self._name, self._t0, t1)
+        self._tel._close_span(self._name, self._t0, t1, cpu)
         return False
 
 
@@ -839,8 +1001,7 @@ class _Part:
         self._key = key
 
     def __enter__(self):
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(
+        self._ann = self._tel._annotation(
             "cake/" + self._key, step=self._tel._next)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
@@ -911,6 +1072,22 @@ class StepTelemetry:
         self._detok_ids = 0
         self._break: Optional[str] = None
         self._admitted = 0
+        # the engine thread's clock: where the open span started (inside
+        # `emit`, where the last row's seams ended), where the open
+        # step's loop_s starts, its seconds off the CPU by phase, and
+        # the collections counted up to the record before
+        self._mark = self._mark_cpu = 0.0
+        self._emit_acc = [0.0] * len(EMIT_SEAMS)
+        self._loop_t0: Optional[float] = None
+        self._loop_cpu0 = 0.0
+        self._offcpu: Dict[str, float] = {}
+        import jax
+        self._annotation = jax.profiler.TraceAnnotation
+        GC_WATCH.open(self._annotation)
+        # close() or, for a recorder nobody closed, its collection
+        self._gc_release = weakref.finalize(self, GC_WATCH.close)
+        self._gc_release.atexit = False
+        self._gc_mark = GC_WATCH.mark()
 
     @property
     def next_step(self) -> int:
@@ -954,6 +1131,28 @@ class StepTelemetry:
         if span == self._open:
             self._parts[key] = self._parts.get(key, 0.0) + seconds
 
+    def add_emit(self, t_in: float, t_trace: float, t_report: float,
+                 t_detok: float, t_stream: float, t_end: float) -> None:
+        """A row's token through serve/engine._emit, as the clock reads
+        at its seams: its entry, then the end of `trace`, `report`,
+        `detok`, `stream` and `retire` (a part that did not run ends
+        where the one before it did). One read a seam: each part is
+        the difference of two neighbours, and `emit.rows` runs from
+        where the row before ended (or the span started) to the entry.
+        Counted inside the `emit` span only, like add_part; summed in
+        place (a row a token: no dict, no loop) and folded into the
+        parts where the span closes."""
+        if self._open != "emit":
+            return
+        acc = self._emit_acc
+        acc[0] += t_in - self._mark
+        acc[1] += t_trace - t_in
+        acc[2] += t_report - t_trace
+        acc[3] += t_detok - t_report
+        acc[4] += t_stream - t_detok
+        acc[5] += t_end - t_stream
+        self._mark = t_end
+
     def add_detok_ids(self, ids: int) -> None:
         """Ids the detokenisation of a row's token handed to the
         tokenizer's decode (`detok_ids` of the next record). Counted
@@ -975,28 +1174,45 @@ class StepTelemetry:
         next record that is not chained)."""
         self._admitted += rows
 
-    def discard_open(self) -> None:
+    def discard_open(self, now: Optional[float] = None) -> None:
         """Drop the open step's phases, parts and gap: what ran belongs
-        to no step (the engine's warm-up; the idle loop)."""
-        self._phases, self._parts = {}, {}
+        to no step (the engine's warm-up; the idle loop). The next
+        record's loop_s starts here (`now`: a clock read the caller
+        already has)."""
+        self._phases, self._parts, self._offcpu = {}, {}, {}
         self._detok_ids = 0
         self._gap = self._fetch_t1 = None
+        self._loop_t0 = time.perf_counter() if now is None else now
+        self._loop_cpu0 = time.thread_time()
 
-    def _close_span(self, name: str, t0: float, t1: float) -> None:
+    def _close_span(self, name: str, t0: float, t1: float,
+                    cpu: float) -> None:
         if name == "wait":
             # nothing to run: what led up to the wait belongs to no
             # step, and the next step starts no chain's successor
-            self.discard_open()
+            self.discard_open(t1)
             if self._next > 1:
                 self._break = "idle"
             return
         if name == "fetch":
             self._fetch_t1 = t1
+        elif name == "emit":
+            # what followed the last row, or a span that emitted none,
+            # is its rows' own work too; then the rows' seams
+            acc, parts = self._emit_acc, self._parts
+            acc[0] += max(0.0, t1 - self._mark)
+            for i, key in enumerate(EMIT_SEAMS):
+                if acc[i] > 0.0:
+                    parts[key] = parts.get(key, 0.0) + acc[i]
+                    acc[i] = 0.0
         elif (name == "dispatch" and name not in self._phases
                 and self._fetch_t1 is not None):
             # the open step's first dispatch, after the last step's fetch
             self._gap = max(0.0, t0 - self._fetch_t1)
         self._phases[name] = self._phases.get(name, 0.0) + (t1 - t0)
+        # signed: where the thread's CPU clock ticks coarsely a span
+        # reads its wall, or its wall less a tick, and only sums are right
+        self._offcpu[name] = self._offcpu.get(name, 0.0) + (t1 - t0 - cpu)
 
     def open_phase(self, name: str) -> Optional[float]:
         """Seconds the open step has spent in `name` so far (None if it
@@ -1114,6 +1330,25 @@ class StepTelemetry:
                 mfu = min(1.0, cost.flops / (peak * dev))
             if cost.bytes_accessed > 0 and bps:
                 hbm = min(1.0, cost.bytes_accessed / (bps * dev))
+        # the loop's clock turns over where the `record` span around
+        # this call started (its seconds go to the record after this
+        # one, with the rest of what follows), else here
+        if self._open == "record":
+            now, cpu = self._mark, self._mark_cpu
+        else:
+            now, cpu = time.perf_counter(), time.thread_time()
+        offcpu, self._offcpu = self._offcpu, {}
+        loop_s = None
+        if self._loop_t0 is not None:
+            loop_s = now - self._loop_t0
+            # off the CPU outside every span: the loop's wall less its
+            # thread's CPU seconds, less what the spans hold of that
+            offcpu["none"] = (loop_s - (cpu - self._loop_cpu0)
+                              - sum(offcpu.values()))
+        self._loop_t0, self._loop_cpu0 = now, cpu
+        (gc_s0, gc_n0), self._gc_mark = self._gc_mark, GC_WATCH.mark()
+        gc_s, gc_n = self._gc_mark[0] - gc_s0, self._gc_mark[1] - gc_n0
+        gc_max_s = GC_WATCH.longest(gc_n)
         phases, self._phases = self._phases, {}
         parts, self._parts = self._parts, {}
         detok_ids, self._detok_ids = self._detok_ids, 0
@@ -1153,7 +1388,9 @@ class StepTelemetry:
                      if moe is not None else None),
                 chain_break=cause, rows_admitted=admitted,
                 parts=parts or None, detok_ids=detok_ids or None,
-                fetch_wait_s=fetch_wait_s, late=late)
+                fetch_wait_s=fetch_wait_s, late=late,
+                loop_s=loop_s, offcpu=offcpu or None,
+                gc_s=gc_s, gc_n=gc_n, gc_max_s=gc_max_s)
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()
@@ -1268,6 +1505,7 @@ class StepTelemetry:
         }
 
     def close(self) -> None:
+        self._gc_release()      # once, however often close() is called
         if self._log is not None:
             self._log.close()
 

@@ -2055,6 +2055,12 @@ class InferenceEngine:
         The first cause that stops a chain goes to the flight recorder
         once its last step has been fetched (StepTelemetry.chain_broke:
         the next record that is not chained carries it).
+        A completed dispatch's device outputs, and at the end the
+        stretch's last carry, die under the `release` span: the runtime
+        gives the interpreter lock away while it frees them, and the
+        thread then waits for it behind every handler thread the emit
+        woke (1-3 ms a step at 32 streams: my chip run, PR 50; the
+        carry a dispatch consumed dies at once, 0.07 ms).
         first_unconditional: guarantee one dispatch per call even when
         the gates say stop — a caller whose planning loop has no other
         progress path would otherwise spin forever (the spec burst with
@@ -2064,18 +2070,29 @@ class InferenceEngine:
         first = first_unconditional
         broke = None
         while True:
-            cause = None if first else (
-                self._host_attention() or chain_break(len(inflight)))
+            cause = None
+            if not first:
+                with self.flight.span("gate"):
+                    cause = (self._host_attention()
+                             or chain_break(len(inflight)))
             first = False
             if cause is None:
                 devs, state = dispatch(state)
                 inflight.append(devs)
+                # (`inflight` holds them now: a name left here would keep
+                # the stretch's last outputs alive past their span)
+                del devs
             elif inflight and broke is None:
                 broke = cause
             if not inflight:
+                with self.flight.span("release"):
+                    state = None
                 break
             if cause is not None or len(inflight) >= 2:
-                complete(inflight.pop(0))
+                done = inflight.pop(0)
+                complete(done)
+                with self.flight.span("release"):
+                    del done
                 if broke is not None and not inflight:
                     self.flight.chain_broke(broke)
                     broke = None
@@ -3538,14 +3555,6 @@ class InferenceEngine:
             v=jax.device_put(fresh.v, self._cache_shardings.v),
         )
 
-    def _obs_paged_step(self, path: str, seconds: float) -> None:
-        """Observe one paged-engine step's wall latency (scan/burst
-        callers pass their per-step average). No-op for dense engines —
-        the histogram exists to compare the fold vs pallas paged
-        attention impls."""
-        if self.paged:
-            _PAGED_ATTN_STEP.labels(path=path).observe(seconds)
-
     # -- step telemetry seams (obs/steps.py) -----------------------------
 
     def _obs_jit(self, name: str, key: tuple, fn, args: tuple,
@@ -3567,22 +3576,32 @@ class InferenceEngine:
 
     def _record_step(self, kind: str, *, rows: int, tokens: int,
                      dispatch_s=None, device_s=None, wall_s=None,
-                     js=None, **split) -> None:
+                     js=None, paged_step_s: Optional[float] = None,
+                     **split) -> None:
         """Append one flight record for the step that just completed,
         attaching the pending dispatch's cost info (js, or the
         engine-thread mailbox _last_jit) and page-pool occupancy.
         `split` carries the mixed step's occupancy breakdown
         (rows_decode / rows_prefill / rows_idle) and the dispatched
-        rows' `rids` (the per-request explain's step linkage)."""
-        if js is None:
-            js, self._last_jit = self._last_jit, None
-        self.flight.record(
-            kind, rows=rows, tokens=tokens, dispatch_s=dispatch_s,
-            device_s=device_s, wall_s=wall_s,
-            cost=js.cost if js is not None else None,
-            compiled=bool(js is not None and js.new),
-            impl=self._step_impl(kind),
-            **split, **self._page_kw())
+        rows' `rids` (the per-request explain's step linkage).
+        paged_step_s: a mixed or decode step's wall seconds for the
+        histogram that compares the fold and pallas paged attention
+        impls (scan/burst callers pass their per-step average; a dense
+        engine observes nothing). All of it under the `record` span."""
+        with self.flight.span("record"):
+            if paged_step_s is not None and self.paged:
+                _PAGED_ATTN_STEP.labels(
+                    path="mixed" if kind == "mixed" else "decode"
+                ).observe(paged_step_s)
+            if js is None:
+                js, self._last_jit = self._last_jit, None
+            self.flight.record(
+                kind, rows=rows, tokens=tokens, dispatch_s=dispatch_s,
+                device_s=device_s, wall_s=wall_s,
+                cost=js.cost if js is not None else None,
+                compiled=bool(js is not None and js.new),
+                impl=self._step_impl(kind),
+                **split, **self._page_kw())
 
     # -- SLO scheduling: preemption + shed seams (cake_tpu/sched) --------
 
@@ -4431,11 +4450,13 @@ class InferenceEngine:
                          if js is not None and js.cost is not None)
             cost = (obs_steps.CostInfo(flops=flops, bytes_accessed=nbytes)
                     if flops or nbytes else None)
-            self.flight.record(
-                "prefill", rows=len(pend), tokens=len(pend), wall_s=dt,
-                cost=cost,
-                compiled=any(js is not None and js.new for js in pend_js),
-                rids=[req.rid for (req, _t0, _s, _d) in pend])
+            with self.flight.span("record"):
+                self.flight.record(
+                    "prefill", rows=len(pend), tokens=len(pend), wall_s=dt,
+                    cost=cost,
+                    compiled=any(js is not None and js.new
+                                 for js in pend_js),
+                    rids=[req.rid for (req, _t0, _s, _d) in pend])
             with self.flight.span("emit"):
                 for (req, t0, slot, _), host in zip(pend, hosts):
                     tok, lp, top = self._finish_prefill_complete(slot,
@@ -4929,14 +4950,13 @@ class InferenceEngine:
             pf = wall * (n_real - len(decode_rows)) / n_real
             self.stats.prefill_time_s += pf
             self.stats.decode_time_s += wall - pf
-            self._obs_paged_step("mixed", wall)
             waited = self.flight.open_phase("fetch")
             # written after the fetch and before the emit: a request
             # whose first token this step sampled is still a prefill
             # row at the record's ts
             self._record_step(
                 "mixed", rows=len(decode_rows) + len(chunk_rows),
-                tokens=len(sampled), wall_s=wall,
+                tokens=len(sampled), wall_s=wall, paged_step_s=wall,
                 dispatch_s=disp,
                 device_s=wall if chained else waited, fetch_wait_s=waited,
                 js=js, rows_decode=len(decode_rows),
@@ -5723,9 +5743,9 @@ class InferenceEngine:
         self.stats.steps += 1
         dt = time.perf_counter() - t0
         self.stats.decode_time_s += dt
-        self._obs_paged_step("decode", dt)
         self._record_step("decode", rows=len(decode_plan),
                           tokens=len(decode_plan), wall_s=dt,
+                          paged_step_s=dt,
                           dispatch_s=self.flight.open_phase("dispatch"),
                           device_s=self.flight.open_phase("fetch"),
                           rids=[r for r, _s in decode_plan],
@@ -5836,9 +5856,9 @@ class InferenceEngine:
         self.stats.steps += n
         dt = time.perf_counter() - t0
         self.stats.decode_time_s += dt
-        self._obs_paged_step("decode", dt / n)
         self._record_step("decode_scan", rows=len(decode_plan),
                           tokens=int(budget.sum()), wall_s=dt,
+                          paged_step_s=dt / n,
                           rids=[r for r, _s in decode_plan])
         self._complete_scan(decode_plan, n, fetched, budget)
 
@@ -5952,13 +5972,12 @@ class InferenceEngine:
                 fetched = self._fetch_scan(outs_k)
             wall = flying.fetched(t_start)
             self.stats.decode_time_s += wall
-            self._obs_paged_step("decode", wall / n)
             moe = fetched[4]
             waited = self.flight.open_phase("fetch")
             self._record_step(
                 kind, rows=int(np.count_nonzero(budget_k)),
                 tokens=int(budget_k.sum()), wall_s=wall,
-                dispatch_s=disp_k,
+                paged_step_s=wall / n, dispatch_s=disp_k,
                 device_s=wall if chained else waited, fetch_wait_s=waited,
                 js=js_k, rids=rids,
                 moe=np.sum(moe, axis=0) if moe else None,
@@ -6182,7 +6201,11 @@ class InferenceEngine:
 
     def _emit(self, req: _Request, token_id: int,
               logprob: float = 0.0, top=None) -> None:
-        now = time.perf_counter()
+        # one clock read at each seam of a row's token (obs/steps.
+        # EMIT_SEAMS; flight.add_emit at the end): trace, report,
+        # detok, stream, retire
+        clock = time.perf_counter
+        now = clock()
         req.out_logprobs.append(logprob)
         req.out_top.append(top or [])
         if not req.out_tokens:
@@ -6195,6 +6218,7 @@ class InferenceEngine:
             _SCHED_TTFT.labels(req.priority).observe(now - req.submit_t)
         else:
             self.tracer.token(req.rid)
+        t_trace = clock()
         req.out_tokens.append(token_id)
         if req.crash_count:
             # a step that emits for this request succeeded: the crash
@@ -6211,15 +6235,13 @@ class InferenceEngine:
         eos = token_id in self.config.eos_token_ids
         hit_cap = (self._pos[req.slot] + 1 >= self.max_seq_len)
         finished = self.scheduler.report(req.rid, 1, eos or hit_cap)
+        t_detok = t_stream = t_end = t_report = clock()
         if req.stream is not None:
             # final=finished: flush any held-back UTF-8 tail — a stream
             # ending on an incomplete sequence would otherwise deliver
             # less text than the buffered response for the same request
-            t_detok = time.perf_counter()
             delta = self._incremental_text(req, final=finished)
-            # a row a token: two clock reads, no annotation each
-            self.flight.add_part("emit.detok",
-                                 time.perf_counter() - t_detok)
+            t_detok = t_stream = t_end = clock()
             if delta or finished:
                 try:
                     if req.stream_wants_count:
@@ -6228,6 +6250,7 @@ class InferenceEngine:
                         req.stream(delta, finished)
                 except Exception:  # noqa: BLE001
                     log.exception("stream callback failed rid=%d", req.rid)
+                t_stream = t_end = clock()
         if finished:
             req.finish_t = now
             if req.ship_sink is not None:
@@ -6245,6 +6268,9 @@ class InferenceEngine:
             self.tracer.finish(req.rid, "retired",
                                output_tokens=len(req.out_tokens))
             req.done.set()
+            t_end = clock()
+        self.flight.add_emit(now, t_trace, t_report, t_detok, t_stream,
+                             t_end)
 
     def _incremental_text(self, req: _Request, final: bool = False) -> str:
         """The text that the tokens emitted since the last call

@@ -549,7 +549,10 @@ def test_part_seconds_land_in_parts_and_once_in_phases(monkeypatch):
     rec = st.record("mixed", wall_s=0.02, chained=False)
     assert rec.parts == pytest.approx(
         {"schedule.plan": 0.002, "schedule.admit_pages": 0.006,
-         "emit.detok": 0.003, "dispatch.launch": 0.005})
+         "emit.detok": 0.003, "dispatch.launch": 0.005,
+         # what an emit span's row seams (add_emit) do not claim is its
+         # rows' own work: here the whole span
+         "emit.rows": 0.004})
     # the span's seconds are its own, parts included ONCE
     assert rec.phases == pytest.approx(
         {"schedule": 0.009, "emit": 0.004, "dispatch": 0.005})
@@ -800,4 +803,371 @@ def test_capture_holds_cake_spans_joined_by_step_number(tmp_path):
             recs[first]["parts"][part], abs=2e-3), part
     assert recs[first]["rows_admitted"] == 1
     assert any("dispatch.launch" in seen[n] for n in joined if n != first)
+    # the loop's two other spans lie on the host plane too: the stretch's
+    # gate under the number of the record being put together, the
+    # writing of a record under that record's own
+    assert any("gate" in seen[n] for n in joined)
+    assert all("record" in seen[n] for n in joined)
 
+
+
+# -- the engine thread's clock: loop_s, offcpu, emit by part, collections ------
+
+NEW_PHASES = ("gate", "record", "release")
+NEW_PARTS = ("emit.rows", "emit.trace", "emit.report", "emit.stream",
+             "emit.retire")
+CLOCK_FIELDS = ("loop_s", "offcpu", "gc_s", "gc_n", "gc_max_s")
+
+
+def _clocks(monkeypatch):
+    """perf_counter and thread_time under the test's control: `wall`
+    moves alone while the thread is off the CPU."""
+    wall, cpu = _Clock(), _Clock()
+    cpu.t = 7.0
+    monkeypatch.setattr(obs_steps.time, "perf_counter", wall)
+    monkeypatch.setattr(obs_steps.time, "thread_time", cpu)
+    return wall, cpu
+
+
+@pytest.mark.parametrize("name", NEW_PHASES + NEW_PARTS
+                         + ("phase?", "part?"))
+def test_the_clock_vocabulary(name):
+    st = obs_steps.StepTelemetry(impl="t")
+    if name in NEW_PHASES:
+        assert name in obs_steps.PHASES
+        with st.span(name):
+            pass
+    elif name in NEW_PARTS:
+        assert name in obs_steps.PARTS and name in obs_steps.EMIT_SEAMS
+        with st.span("emit"):
+            st.add_part(name, 0.002)
+        # (and, for emit.rows, the microseconds of this span itself)
+        assert st.record("decode", wall_s=0.01).parts[name] == \
+            pytest.approx(0.002, abs=1e-4)
+    elif name == "phase?":
+        with pytest.raises(ValueError, match="vocabulary"):
+            st.span("think")
+    else:
+        with pytest.raises(ValueError, match="vocabulary"):
+            st.add_part("emit.think", 0.001)
+        with pytest.raises(TypeError):
+            with st.span("emit"):
+                st.add_emit(1.0, 2.0)          # six seams, no fewer
+    st.close()
+    # the seams are parts of emit, in the order they run
+    assert obs_steps.EMIT_SEAMS == ("emit.rows", "emit.trace",
+                                    "emit.report", "emit.detok",
+                                    "emit.stream", "emit.retire")
+    assert set(obs_steps.EMIT_SEAMS) <= set(obs_steps.PARTS)
+
+
+@pytest.mark.parametrize("case", ["loop", "record_span", "wait",
+                                  "discard", "first"])
+def test_loop_s_runs_from_record_to_record(monkeypatch, case):
+    wall, cpu = _clocks(monkeypatch)
+    st = obs_steps.StepTelemetry(impl="t")
+    if case == "first":
+        # nothing to count from: a recorder's first record has none
+        assert st.record("decode", wall_s=0.01).loop_s is None
+        assert "loop_s" not in st.dump()[0]
+        return
+    st.record("decode", wall_s=0.01)
+    if case == "loop":
+        _span(st, wall, "emit", 0.006)
+        wall.t += 0.0005                   # between two spans
+        _span(st, wall, "gate", 0.001)
+        _span(st, wall, "fetch", 0.010)
+        wall.t += 0.00025
+        rec = st.record("decode", wall_s=0.01)
+        assert rec.loop_s == pytest.approx(0.01775)
+        # of ONE record: what lay outside every span in it
+        assert rec.loop_s - sum(rec.phases.values()) == pytest.approx(
+            0.00075)
+        assert rec.to_dict()["loop_s"] == pytest.approx(0.01775, abs=1e-6)
+    elif case == "record_span":
+        # the clock turns over where the `record` span starts; its
+        # seconds, the record's own writing included, go to the NEXT one
+        _span(st, wall, "fetch", 0.010)
+        with st.span("record"):
+            wall.t += 0.0002
+            mid = st.record("decode", wall_s=0.01)
+            wall.t += 0.0001
+        assert mid.loop_s == pytest.approx(0.010)
+        assert "record" not in mid.phases
+        _span(st, wall, "emit", 0.004)
+        nxt = st.record("decode", wall_s=0.01)
+        assert nxt.phases == pytest.approx({"record": 0.0003,
+                                            "emit": 0.004})
+        assert nxt.loop_s == pytest.approx(0.0043)
+    elif case == "wait":
+        _span(st, wall, "emit", 0.006)
+        wall.t += 0.002
+        _span(st, wall, "wait", 0.050)     # the origin moves to its end
+        wall.t += 0.0004
+        _span(st, wall, "dispatch", 0.003)
+        rec = st.record("decode", wall_s=0.01)
+        assert rec.loop_s == pytest.approx(0.0034)
+        assert rec.phases == pytest.approx({"dispatch": 0.003})
+        # (the test's thread_time stands still: all of it off the CPU,
+        # the 0.4 ms outside every span under `none`)
+        assert rec.offcpu == pytest.approx({"dispatch": 0.003,
+                                            "none": 0.0004})
+    else:
+        _span(st, wall, "dispatch", 5.0)   # the warm-up
+        st.discard_open()
+        _span(st, wall, "dispatch", 0.003)
+        rec = st.record("decode", wall_s=0.01)
+        assert rec.loop_s == pytest.approx(0.003) and rec.offcpu == \
+            pytest.approx({"dispatch": 0.003, "none": 0.0})
+
+
+def test_offcpu_is_a_spans_wall_less_its_threads_cpu(monkeypatch):
+    wall, cpu = _clocks(monkeypatch)
+    st = obs_steps.StepTelemetry(impl="t")
+    with st.span("emit"):                  # 6 ms, of which 4 on the CPU
+        wall.t += 0.006
+        cpu.t += 0.004
+    with st.span("emit"):                  # a second one adds up
+        wall.t += 0.001
+        cpu.t += 0.001
+    with st.span("fetch"):                 # waiting for the device
+        wall.t += 0.012
+        cpu.t += 0.0005
+    with st.span("gate"):                  # the two clocks' own jitter
+        wall.t += 0.0001
+        cpu.t += 0.00011
+    first = st.record("decode", wall_s=0.02)
+    # (signed: a clamp a span would bias the sums on a coarse CPU clock)
+    assert first.offcpu == pytest.approx(
+        {"emit": 0.002, "fetch": 0.0115, "gate": -0.00001})
+    assert set(first.offcpu) == set(first.phases)
+    d = first.to_dict()
+    assert d["offcpu"] == pytest.approx(first.offcpu, abs=1e-6)
+    # from the second record on the loop has an origin, and `none`
+    # holds what of it was off the CPU outside every span
+    with st.span("emit"):
+        wall.t += 0.004
+        cpu.t += 0.003
+    wall.t += 0.0030                       # between two spans: 1 ms of
+    cpu.t += 0.0020                        # it waiting
+    with st.span("fetch"):
+        wall.t += 0.010
+    rec = st.record("decode", wall_s=0.02)
+    assert rec.loop_s == pytest.approx(0.017)
+    assert rec.offcpu == pytest.approx(
+        {"emit": 0.001, "fetch": 0.010, "none": 0.001})
+    assert set(rec.offcpu) == set(rec.phases) | {"none"}
+    quiet = st.record("decode", wall_s=0.01).to_dict()
+    assert quiet["offcpu"] == {"none": 0.0} and "phases" not in quiet
+
+
+@pytest.mark.parametrize("case", ["streamed", "silent", "finished",
+                                  "two_rows", "outside"])
+def test_a_rows_seams_are_the_parts_of_emit(monkeypatch, case):
+    """add_emit takes the clock reads at a row's seams: each part is the
+    difference of two neighbours, a part that did not run ends where
+    the one before it did, and `emit.rows` runs from the span's start
+    (or the row before) to the row's entry, then from the last row to
+    the span's end."""
+    wall, _cpu = _clocks(monkeypatch)
+    st = obs_steps.StepTelemetry(impl="t")
+    t = wall.t
+    if case == "outside":
+        st.add_emit(t, t + 1, t + 2, t + 3, t + 4, t + 5)
+        st.add_part("emit.stream", 0.5)    # no span open: nobody's
+        with st.span("admin"):
+            st.add_emit(t, t + 1, t + 2, t + 3, t + 4, t + 5)
+            st.add_part("emit.stream", 0.5)
+        assert st.record("decode", wall_s=0.01).parts is None
+        return
+    with st.span("emit"):
+        wall.t += 0.0010                   # the mirrors, the zip
+        t = wall.t
+        if case == "streamed":
+            st.add_emit(t, t + .0002, t + .0005, t + .0006, t + .0009,
+                        t + .0009)
+            wall.t += 0.0009
+            want = {"emit.rows": 0.0010, "emit.trace": 0.0002,
+                    "emit.report": 0.0003, "emit.detok": 0.0001,
+                    "emit.stream": 0.0003}
+        elif case == "silent":             # no stream: three reads
+            st.add_emit(t, t + .0002, t + .0005, t + .0005, t + .0005,
+                        t + .0005)
+            wall.t += 0.0005
+            want = {"emit.rows": 0.0010, "emit.trace": 0.0002,
+                    "emit.report": 0.0003}
+        elif case == "finished":
+            st.add_emit(t, t + .0002, t + .0005, t + .0006, t + .0009,
+                        t + .0019)
+            wall.t += 0.0019
+            want = {"emit.rows": 0.0010, "emit.trace": 0.0002,
+                    "emit.report": 0.0003, "emit.detok": 0.0001,
+                    "emit.stream": 0.0003, "emit.retire": 0.0010}
+        else:
+            st.add_emit(t, t + .0002, t + .0005, t + .0006, t + .0009,
+                        t + .0009)
+            wall.t += 0.0009 + 0.0004      # the second row's own work
+            t = wall.t
+            st.add_emit(t, t + .0001, t + .0002, t + .0003, t + .0004,
+                        t + .0004)
+            wall.t += 0.0004
+            want = {"emit.rows": 0.0014, "emit.trace": 0.0003,
+                    "emit.report": 0.0004, "emit.detok": 0.0002,
+                    "emit.stream": 0.0004}
+        wall.t += 0.0003                   # after the last row
+        want["emit.rows"] += 0.0003
+    rec = st.record("decode", wall_s=0.01)
+    assert rec.parts == pytest.approx(want)
+    # the seams leave nothing of the span unnamed
+    assert sum(rec.parts.values()) == pytest.approx(rec.phases["emit"])
+
+
+def _collect(generation: int) -> None:
+    import gc
+    gc.collect(generation)
+
+
+@pytest.mark.parametrize("case", ["delta", "by_generation", "close",
+                                  "engine", "annotation"])
+def test_collections_are_events(monkeypatch, case):
+    import gc
+    # only the collections this test asks for (the hook hears those too)
+    gc.disable()
+    try:
+        _collections_are_events(monkeypatch, case)
+    finally:
+        gc.enable()
+
+
+def _collections_are_events(monkeypatch, case):
+    import gc
+    watch = obs_steps.GC_WATCH
+    users = watch._users
+    if case == "annotation":
+        made = []
+
+        class FakeAnnotation:
+            def __init__(self, name, **kw):
+                made.append(name)
+
+            def __enter__(self):
+                made.append("enter")
+
+            def __exit__(self, *exc):
+                made.append("exit")
+
+        st = obs_steps.StepTelemetry(impl="t")
+        # (the hook's annotation is its first opener's)
+        monkeypatch.setattr(watch, "_annotation", FakeAnnotation)
+        _collect(0)
+        assert made == []                  # a young collection: sums only
+        _collect(2)
+        assert made == ["cake/gc", "enter", "exit"]
+        st.close()
+        return
+    if case == "engine":
+        eng = _make_engine()
+        with eng:
+            assert watch._users == users + 1
+            assert watch._hook in gc.callbacks
+        # no hook left behind by an engine that stopped
+        assert watch._users == users
+        assert (watch._hook in gc.callbacks) == bool(users)
+        return
+    st = obs_steps.StepTelemetry(impl="t")
+    assert watch._users == users + 1
+    assert gc.callbacks.count(watch._hook) == 1    # one, however many
+    if case == "close":
+        st.close()
+        assert watch._users == users
+        assert (watch._hook in gc.callbacks) == bool(users)
+        st.close()                          # once, however often
+        assert watch._users == users
+        # a recorder nobody closed lets go when it is collected
+        obs_steps.StepTelemetry(impl="t")
+        assert watch._users == users
+        return
+    st.record("decode", wall_s=0.01)
+    if case == "delta":
+        before = watch.mark()
+        _collect(0)
+        _collect(2)
+        rec = st.record("decode", wall_s=0.01)
+        after = watch.mark()
+        assert rec.gc_n == after[1] - before[1] >= 2
+        assert rec.gc_s == pytest.approx(after[0] - before[0])
+        assert rec.gc_s > 0 and rec.gc_max_s == watch.longest(rec.gc_n)
+        assert 0 < rec.gc_max_s <= rec.gc_s
+        d = rec.to_dict()
+        assert d["gc_n"] == rec.gc_n and d["gc_max_s"] > 0
+        # taken by the record: the next one starts from nothing...
+        quiet = st.record("decode", wall_s=0.01)
+        if quiet.gc_n == 0:                 # (...unless one just ran)
+            assert quiet.gc_s == 0.0 and quiet.gc_max_s == 0.0
+        assert "gc_s" in quiet.to_dict()    # 0.0, not absent
+    else:
+        count, seconds = list(watch.count), list(watch.seconds)
+        _collect(1)
+        _collect(2)
+        _collect(2)
+        assert watch.count[1] - count[1] >= 1
+        assert watch.count[2] - count[2] == 2
+        assert watch.seconds[2] > seconds[2]
+        obs_steps.refresh_gc_series()
+        for name, sums in (("cake_gc_collections_total", watch.count),
+                           ("cake_gc_pause_seconds_total", watch.seconds)):
+            fam = m.REGISTRY.get(name)
+            assert fam.labels(generation="2").value == pytest.approx(
+                sums[2])
+    st.close()
+
+
+@pytest.mark.parametrize("kind", ["mixed", "decode"])
+def test_engine_records_carry_the_clock(paged_engine, kind):
+    recs = [r for r in paged_engine.flight.dump() if r["kind"] == kind]
+    assert recs
+    later = [r for r in recs if r["step"] > 1]
+    for r in later:
+        for field in CLOCK_FIELDS:
+            assert field in r, (field, r)
+        ph = r["phases"]
+        assert set(r["offcpu"]) == set(ph) | {"none"}
+        assert "record" in ph and "fetch" in ph, r
+        emit = sum(v for k, v in r.get("parts", {}).items()
+                   if k.startswith("emit."))
+        # (each rounded to the microsecond)
+        assert emit <= ph.get("emit", 0.0) + 1e-5, r
+        assert sum(ph.values()) <= r["loop_s"] + 1e-5, r
+        # (two clocks: a span that ran throughout reads a hair under 0)
+        assert all(-1e-3 <= v <= ph.get(k, r["loop_s"]) + 1e-3
+                   for k, v in r["offcpu"].items()), r
+    # the stretch asks its gate before every dispatch but its first,
+    # and names what an emit span did for its row
+    if kind == "decode":
+        assert any("gate" in r["phases"] for r in later)
+        # a completed step's device outputs die under a span of their own
+        assert sum("release" in r["phases"] for r in later) >= len(later) - 1
+        assert all({"emit.rows", "emit.trace", "emit.report"}
+                   <= set(r["parts"]) for r in later)
+
+
+def test_step_log_holds_the_clock(tmp_path):
+    path = tmp_path / "steps.jsonl"
+    eng = _make_engine(step_log=str(path), step_ring=64)
+    seen = []
+    with eng:
+        h = eng.submit(list(range(3, 3 + 16)), max_new_tokens=6,
+                       stream=lambda *a: seen.append(a))
+        assert h.wait(120)
+        ring = {r["step"]: r for r in eng.flight.dump()}
+    recs = read_jsonl(str(path))
+    assert len(recs) >= 6
+    for r in recs[1:]:
+        assert set(CLOCK_FIELDS) <= set(r), r
+        assert r == ring[r["step"]]
+    last = recs[-1]["parts"]
+    # the request's last token: the row was retired in that emit... which
+    # follows the last record, so the retire shows in no record of this
+    # run; the rows before it show the seams a streamed row has
+    assert {"emit.rows", "emit.trace", "emit.report", "emit.detok"} \
+        <= set(last), last
