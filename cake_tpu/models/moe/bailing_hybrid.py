@@ -22,15 +22,24 @@ stacks per kind of layer, as models/moe/glm_dsa.py's):
     q and k, the bounded decay g = lower_bound * sigmoid(exp(A_log) *
     (a + dt_bias)) a key channel and beta = sigmoid(.) a head, float32.
     The delta rule S <- (I - beta k k^T) Diag(exp(g)) S + beta k v^T,
-    o = S^T q runs in its two forms, the same mathematics: `kda_step`,
+    o = S^T q runs in its two forms, the same mathematics. `kda_step`:
     the recurrence itself on every row that holds ONE token (a decode
-    step's rows; the decode rows of a mixed dispatch), and `kda_chunk`,
-    the chunked form (`kda_chunked`) over the dispatch's one window,
-    starting from the row's stored state. `kda_state` is every read and
-    write of the stored state and tails: read once a layer, zeroed on
-    the way in for a row whose first token sits at position 0 (a request
-    that takes the slot: no launch of its own), written once a layer; a
-    row with no token in the dispatch keeps its bits. `kda_out`: the RMS
+    step's rows; the decode rows of a mixed dispatch), served by
+    ops/kda.step (`cake_kda_step`): ONE kernel that fetches a stepping
+    row's stored state once, updates it and writes it once, in place
+    in the stack; a row that takes the slot (its token at position 0)
+    starts from zeros with no read, and a row with no single token is
+    neither read nor written. The function `kda_step` below is the same
+    operations in jax.numpy: the comparison's form, not the served one.
+    `kda_chunk`: the chunked form (`kda_chunked`) over the dispatch's
+    one window, starting from the row's stored state. `kda_state` is
+    what is left of the stored state's traffic outside the kernel: the
+    conv tails (read and written once a layer), and the window row's
+    own state, read before the step and written after the chunks (2 MiB
+    each way at the published widths), zeroed on the way in for a row
+    whose first token sits at position 0 (a request that takes the
+    slot: no launch of its own); a row with no token in the dispatch
+    keeps its bits. `kda_out`: the RMS
     norm a head, the sigmoid gate a channel, the output projection;
   * an MLA layer: models/moe/glm_dsa.py's dense kind, CALLED
     (`project_latent` with a full-rank query, `attend_dense`: a row's
@@ -66,6 +75,7 @@ from cake_tpu.models.moe.nemotron_h import Rows, causal_conv_rows, dequantized
 from cake_tpu.models.step_programs import (
     make_decode_scan, make_mixed_sampled,
 )
+from cake_tpu.ops import kda
 from cake_tpu.ops.moe import LayerOf
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import qmatmul
@@ -158,16 +168,45 @@ def l2_normed(x):
 
 
 def kda_step(S, q, k, v, g, beta):
-    """One token a row: the recurrence itself. S [B, H, dk, dv] f32; q,
-    k [B, H, dk] f32 (normed); v [B, H, dv]; g [B, H, dk] f32 (log-
-    decay, 0: none); beta [B, H] f32 (0: the state passes unchanged) ->
-    (S_new [B, H, dk, dv] f32, o [B, H, dv] f32). Products and sums on
-    the vector unit in float32: the state's bytes are what it costs."""
+    """One token a row: the recurrence itself, in jax.numpy. The form
+    every comparison holds the served path to (the tests, the float32
+    reference's, an interpreter run); the served path itself runs
+    ops/kda.step, these operations in this order inside one kernel
+    over the stored state. S [B, H, dk, dv] f32; q, k [B, H, dk] f32
+    (normed); v [B, H, dv]; g [B, H, dk] f32 (log-decay, 0: none); beta
+    [B, H] f32 (0: the state passes unchanged) -> (S_new [B, H, dk, dv]
+    f32, o [B, H, dv] f32). Products and sums on the vector unit in
+    float32: the state's bytes are what it costs."""
     S = jnp.exp(g)[..., None] * S
     u = beta[..., None] * (v.astype(F32)
                            - jnp.sum(k[..., None] * S, axis=-2))
     S = S + k[..., None] * u[..., None, :]
     return S, jnp.sum(q[..., None] * S, axis=-2)
+
+
+def step_codes(rows: Rows):
+    """What ops/kda.step does with each row [B] int32: a row that holds
+    one token steps, from zeros if that token sits at position 0; any
+    other row (idle, or the dispatch's window) stays."""
+    return jnp.where(rows.n == 1,
+                     jnp.where(rows.pos == 0, kda.FRESH, kda.STEP),
+                     kda.STAY).astype(jnp.int32)
+
+
+def kda_step_fold(state, j, code, q, k, v, g, beta):
+    """ops/kda.step's contract in XLA over `kda_step`: layer j of the
+    stack read whole, every row stepped, the stepping rows' results
+    kept. The kernel's comparison (tests/test_kda_kernel.py) and
+    tools/kda_step_bench.py's other side; no step program calls it."""
+    S_old = lax.dynamic_index_in_dim(state, j, 0, keepdims=False)
+    S_new, o = kda_step(
+        jnp.where((code == kda.FRESH)[:, None, None, None], 0.0, S_old),
+        q, k, v, g, beta)
+    steps = code != kda.STAY
+    return (lax.dynamic_update_index_in_dim(
+                state, jnp.where(steps[:, None, None, None], S_new, S_old),
+                j, 0),
+            jnp.where(steps[:, None, None], o, 0.0))
 
 
 def kda_chunked(S0, q, k, v, g, beta, chunk: int = CHUNK):
@@ -252,8 +291,10 @@ def kda_layer(lp, h, state, tails, j: int, slot, real, rows: Rows, first,
     with jax.named_scope("attn"):
         with jax.named_scope("kda_state"):
             tail = jnp.where(fresh[:, None, None], 0, tails[j])
-            S_old = state[j]
-            S_in = jnp.where(fresh[:, None, None, None], 0.0, S_old)
+            if window is not None:
+                # the window's row alone, as stored BEFORE this layer's
+                # step: 2 MiB at the published widths
+                S0 = jnp.where(fresh[window.row], 0.0, state[j, window.row])
         with jax.named_scope("kda_conv"):
             u, new_tail = causal_conv_rows(qkv, tail, lp["kda_conv_w"], None,
                                            slot, rows)
@@ -268,8 +309,8 @@ def kda_layer(lp, h, state, tails, j: int, slot, real, rows: Rows, first,
             beta = jnp.where(real[:, None],
                              jax.nn.sigmoid(b.astype(F32)), 0.0)
         with jax.named_scope("kda_step"):
-            S_new, o1 = kda_step(S_in, q[first], k[first], v[first],
-                                 g[first], beta[first])
+            state, o1 = kda.step(state, j, step_codes(rows), q[first],
+                                 k[first], v[first], g[first], beta[first])
         if window is None:
             o = o1[slot]
         else:
@@ -278,15 +319,13 @@ def kda_layer(lp, h, state, tails, j: int, slot, real, rows: Rows, first,
                 # window's own the state passes through unchanged
                 own = window.real[:, None]
                 S_win, ow = kda_chunked(
-                    S_in[window.row],
+                    S0,
                     *(_window_slice(x, window) for x in (q, k, v)),
                     jnp.where(own[..., None], _window_slice(g, window), 0.0),
                     jnp.where(own, _window_slice(beta, window), 0.0))
             o = jnp.where(window.member[:, None, None], ow[window.col],
                           o1[slot])
         with jax.named_scope("kda_state"):
-            state = state.at[j].set(
-                jnp.where((rows.n == 1)[:, None, None, None], S_new, S_old))
             tails = tails.at[j].set(new_tail.astype(tails.dtype))
             if window is not None:
                 state = state.at[j, window.row].set(

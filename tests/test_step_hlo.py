@@ -150,6 +150,39 @@ def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
     assert mla.pages_ring_depth(128 * 640 * 2) * 128 * 640 * 2 < 2 * 2**20
 
 
+def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
+    """`cake_kda_step` at ling3.longreply-closed's shapes (10 layers of
+    32 rows x 32 heads of 128 x 128 float32) goes through Mosaic, and
+    the program that donates the stack holds it ONCE: the 640 MiB are
+    aliased in and out, with no second copy among the temporaries."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import kda
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, B, H, dk, dv = 10, 32, 32, 128, 128
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        compiled = jax.jit(kda.step, donate_argnums=(0,)).lower(
+            sds((L, B, H, dk, dv), jnp.float32), sds((), jnp.int32),
+            sds((B,), jnp.int32), sds((B, H, dk), jnp.float32),
+            sds((B, H, dk), jnp.float32), sds((B, H, dv), jnp.bfloat16),
+            sds((B, H, dk), jnp.float32), sds((B, H), jnp.float32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_kda_step" in hlo
+    memory = compiled.memory_analysis()
+    stack = L * B * H * dk * dv * 4
+    assert memory.alias_size_in_bytes == stack
+    assert memory.temp_size_in_bytes < 16 * 2**20
+    assert (kda.RING_DEPTH * kda.block_heads(H, dk * dv * 4) * dk * dv * 4
+            == 2 * 2**20)
+
+
 @pytest.mark.parametrize("heads,row,value,pages,masked_by,scope", [
     (128, 640, 512, 40, "positions", "mla"),      # dsv2.code-closed
     (64, 640, 512, 100, "bias", "mla"),           # glm52.longdoc-closed
