@@ -54,6 +54,11 @@ MODEL_TYPES = {
                       "with gated latent attention every few layers, "
                       "sigmoid routing with a choice bias limited to "
                       "groups by their two best (paged engine)",
+    "exaone_moe": "GQA in two kinds of layer: sliding-window layers that "
+                  "rotate and keep their K/V in a ring of pages a row, "
+                  "full layers with no positions on the allocator's "
+                  "pages; q and k normed a head; sigmoid-routed experts "
+                  "with a shared one (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -87,6 +92,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "bailing_hybrid":
         from cake_tpu.models.moe.config import BailingHybridConfig
         return BailingHybridConfig.from_hf_dict(raw)
+    if model_type == "exaone_moe":
+        from cake_tpu.models.moe.config import ExaoneMoeConfig
+        return ExaoneMoeConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

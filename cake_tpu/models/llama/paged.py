@@ -50,6 +50,12 @@ in wtable[row, j mod R], so a row holds R window pages whatever its
 context (R: config.window_ring_pages; ring_holds states why nothing a
 query needs is overwritten). `table` is mapped once, at admission;
 `wtable` never moves: slot i owns pages i*R .. (i+1)*R - 1 of `w`.
+A model whose sliding-window layers are plain GQA beside full GQA layers
+(exaone_moe) keeps the same split over ORDINARY K/V pages
+(WindowedKVCache): `k` / `v` [L_full, ...] through `table`, and the
+sliding layers' keys and values in `wk` / `wv` [L_sliding, slots * R,
+page, KV*hd] through the ring `wtable`; both attention kernels take the
+band (`window=`) and read a ring through it.
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -237,6 +243,52 @@ class WindowedPagedCache(NamedTuple):
         """Bytes of the sliding layers' pool: slots x R pages, whatever
         max_seq_len is."""
         return self.w.nbytes
+
+    beside_bytes = window_bytes
+
+
+class WindowedKVCache(NamedTuple):
+    """K and V pools by kind of layer (module docstring): the full
+    layers' on the allocator's pages, the sliding-window layers' in a
+    ring a row that slot i owns for good, donated in and aliased out
+    like the other pools."""
+    k: jnp.ndarray        # [L_full, N_pages, page, KV*hd]
+    v: jnp.ndarray
+    table: jnp.ndarray    # [slots, max_pages] int32, -1 = unmapped
+    wk: jnp.ndarray       # [L_sliding, slots * R, page, KV*hd]
+    wv: jnp.ndarray
+    wtable: jnp.ndarray   # [slots, R] int32: slot i's ring, fixed
+
+    page_size = PagedKVCache.page_size
+    n_pages = PagedKVCache.n_pages
+    max_pages = PagedKVCache.max_pages
+    max_seq_len = PagedKVCache.max_seq_len
+    ring_pages = WindowedPagedCache.ring_pages
+
+    @classmethod
+    def create(cls, layers_full: int, layers_sliding: int, row: int,
+               slots: int, n_pages: int, page_size: int, max_seq_len: int,
+               ring_pages: int, dtype=jnp.bfloat16) -> "WindowedKVCache":
+        """row: KV*hd, a token's keys (or values) in one layer."""
+        _check_pages(page_size, max_seq_len)
+        full = (layers_full, n_pages, page_size, row)
+        ring = (layers_sliding, slots * ring_pages, page_size, row)
+        return cls(
+            k=jnp.zeros(full, dtype), v=jnp.zeros(full, dtype),
+            table=jnp.full((slots, max_seq_len // page_size), -1,
+                           jnp.int32),
+            wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype),
+            wtable=jnp.arange(slots * ring_pages, dtype=jnp.int32)
+            .reshape(slots, ring_pages))
+
+    def memory_bytes(self) -> int:
+        """Bytes of the pools the page table maps (the full layers')."""
+        return self.k.nbytes + self.v.nbytes
+
+    def window_bytes(self) -> int:
+        """Bytes of the sliding layers' pools: slots x R pages, whatever
+        max_seq_len is."""
+        return self.wk.nbytes + self.wv.nbytes
 
     beside_bytes = window_bytes
 
@@ -531,11 +583,17 @@ def write_token_rows(pool, layer, rows, slot, position, valid, table,
         rows.astype(pool.dtype), mode="drop")
 
 
-def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
+def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
+                window: Optional[int] = None):
     """The XLA reference both paged attentions share: a fori_loop over
     all max_pages, every page gathered once from `pool[layer]` and
     folded into running (m, l, o) stats. causal_bound: [B, C] — the
-    last absolute slot query (b, i) attends."""
+    last absolute slot query (b, i) attends. window: a band (query
+    (b, i) attends its last `window` keys, the bound included); trip j
+    of a row is then the logical page first + j, first the page of its
+    first query's first key, read through table entry (first + j) mod
+    max_pages, so that `table` may be a ring (the kernels' rule:
+    ops/ragged_paged_attention._mixed_fold)."""
     B, C, H, hd = q.shape
     _, N, P, width = getattr(pool_k, "q", pool_k).shape
     KV = width // hd
@@ -546,10 +604,17 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
     m0 = jnp.full((B, KV, G, C, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((B, KV, G, C, 1), jnp.float32)
     o0 = jnp.zeros((B, KV, G, C, hd), jnp.float32)
+    if window is not None:
+        first = jnp.maximum(causal_bound[:, 0] - (window - 1), 0) // P
 
     def fold(j, carry):
         m, l, o = carry
-        pages = table[:, j]                          # [B]
+        if window is None:
+            pages = table[:, j]                      # [B]
+        else:
+            j = first + j                            # [B] logical pages
+            pages = jnp.take_along_axis(
+                table, (j % max_pages)[:, None], axis=1)[:, 0]
         # unmapped slots route to the out-of-bounds index N with a zero
         # fill instead of gathering page 0 (which aliases another
         # slot's live data into the masked lanes). Whether the OOB row
@@ -567,8 +632,13 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound):
         # validity: absolute slot j*P + t attends for query i iff it is
         # <= the query's causal bound (current token included) AND the
         # page is mapped
-        slots_abs = j * P + jnp.arange(P)            # [P]
-        valid = slots_abs[None, None, :] <= causal_bound[:, :, None]
+        if window is None:
+            slots_abs = j * P + jnp.arange(P)        # [P]
+            valid = slots_abs[None, None, :] <= causal_bound[:, :, None]
+        else:
+            slots_abs = (j[:, None] * P + jnp.arange(P))[:, None, :]
+            valid = ((slots_abs <= causal_bound[:, :, None])
+                     & (slots_abs > causal_bound[:, :, None] - window))
         valid &= (pages >= 0)[:, None, None]
         valid = valid[:, None, None, :, :]           # [B,1,1,C,P]
         mj, lj, oj = partial_attention_stats(q, kj, vj, valid)
@@ -594,7 +664,7 @@ def _kernel_pools(pool_k, pool_v):
 
 
 def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
-                    impl: str = "fold"):
+                    impl: str = "fold", window: Optional[int] = None):
     """Ragged decode attention over layer `layer` of the paged KV.
 
     impl="fold" (the documented REFERENCE semantics): an XLA fori_loop
@@ -613,6 +683,9 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     already be written to its page); pool_k/v: the stacked pool
     [L, N_pages, page, KV*hd]; layer: traced int32 scalar; table:
     [B, max_pages]; pos: [B] (position of the CURRENT token).
+    window (static): a row attends its last `window` keys alone, its
+    own included, and `table` may be a ring [B, R] (the kernel walks
+    the pages of the band; None: every key).
     Returns [B, 1, H, hd].
     """
     if impl == "pallas":
@@ -620,16 +693,18 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
             ragged_paged_attention,
         )
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
-        return ragged_paged_attention(q, kq, vq, layer, table, pos, **kw)
+        return ragged_paged_attention(q, kq, vq, layer, table, pos,
+                                      window=window, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
-    return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None])
+    return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None],
+                       window)
 
 
-@_partial(jax.jit, static_argnames=("packed4", "interpret"))
+@_partial(jax.jit, static_argnames=("packed4", "interpret", "window"))
 def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
                   interpret: bool, scale_k=None, scale_v=None,
-                  packed4: bool = False):
+                  packed4: bool = False, window: Optional[int] = None):
     """The mixed kernel behind a jit of its own. The mixed step's
     programs of every packed size call it on the same window shapes,
     and a jitted callee is traced once for all of them: the kernel's
@@ -641,11 +716,13 @@ def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
     )
     return ragged_paged_attention_mixed(
         q, pool_k, pool_v, layer, table, pos, q_len, scale_k=scale_k,
-        scale_v=scale_v, packed4=packed4, interpret=interpret)
+        scale_v=scale_v, packed4=packed4, window=window,
+        interpret=interpret)
 
 
 def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
-                          impl: str = "fold"):
+                          impl: str = "fold",
+                          window: Optional[int] = None):
     """Mixed ragged attention over layer `layer` of the paged KV: decode
     rows (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page
     offset) in ONE batch.
@@ -663,19 +740,22 @@ def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
     q: [B, C, H, hd] (rope applied; every real query token's KV already
     written to its page); pos: [B] position of each row's FIRST query;
     q_len: [B] real query tokens (0 = idle row). Columns past q_len are
-    padding whose output the caller never reads. Returns [B, C, H, hd].
+    padding whose output the caller never reads. window (static):
+    query i attends its last `window` keys alone, and `table` may be a
+    ring (paged_attention). Returns [B, C, H, hd].
     """
     if impl == "pallas":
         from cake_tpu.ops import ragged_paged_attention as rpa
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
         return _mixed_kernel(q, kq, vq, layer, table, pos, q_len,
-                             interpret=not rpa._on_tpu(), **kw)
+                             interpret=not rpa._on_tpu(), window=window,
+                             **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
     # per-query causality: query i of row b sits at pos[b] + i
     C = q.shape[1]
     return _fold_pages(q, pool_k, pool_v, layer, table,
-                       pos[:, None] + jnp.arange(C)[None, :])
+                       pos[:, None] + jnp.arange(C)[None, :], window)
 
 
 # -- model-level steps (engine step-fn signatures) ----------------------------

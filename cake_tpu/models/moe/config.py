@@ -835,6 +835,169 @@ class BailingHybridConfig(GlmMoeDsaConfig):
         return cls(**base)
 
 
+# config.json keys of model_type exaone_moe whose one served value is
+# the published one: anything else is refused by the key's name
+_EXAONE_ONLY = {
+    "hidden_act": "silu", "scoring_func": "sigmoid", "n_group": 1,
+    "topk_group": 1, "attention_bias": False, "rope_scaling": None,
+}
+_EXAONE_KINDS = {"sliding_attention": "sliding", "full_attention": "full"}
+
+
+@dataclass(frozen=True)
+class ExaoneMoeConfig(MoEConfig):
+    """K-EXAONE (`model_type: exaone_moe`): plain GQA in every layer,
+    in TWO kinds by `layer_types`. A `sliding_attention` layer rotates q
+    and k and a query attends its last `sliding_window` keys, its own
+    included; a `full_attention` layer attends every key at or before
+    the query and has NO positional rotation. Both RMS-norm q and k a
+    head before that. `first_k_dense_replace` dense SwiGLU layers, then
+    sigmoid-routed experts (a choice bias, the k best renormalised,
+    times `routed_scaling_factor`) and a shared expert: GLM-5.2's FFN
+    (models/moe/glm_dsa.ffn). The equations are in
+    models/reference/exaone_moe.py; the served path in
+    models/moe/exaone_moe.py, and its cache is K and V pages by kind of
+    layer: the full layers' on the allocator's pages, the sliding
+    layers' in a ring of `window_ring_pages` pages a row
+    (models/llama/paged.WindowedKVCache).
+
+    `indexer_types` holds "sliding" | "full" (from `layer_types`), as
+    Dots3NoteConfig's. `num_local_experts` counts the routed experts
+    HELD here (config.json `num_experts`), `n_routed_experts_total` the
+    router's width (`num_experts_total`, absent = all held),
+    `first_routed_expert` the first held expert's index. The base
+    class's `sliding_window` stays None (it asks the DENSE engine for a
+    ring cache: serve/engine.py); the window is `sliding_window_size`."""
+
+    _family = "cake_tpu.models.moe.exaone_moe:FAMILY"
+
+    # config.json `head_dim`: not hidden_size / heads here (6,144 / 64)
+    attn_head_dim: int = 128
+    sliding_window_size: int = 128
+    mlp_layer_types: Tuple[str, ...] = ()
+    indexer_types: Tuple[str, ...] = ()
+    moe_intermediate_size: int = 2048
+    n_routed_experts_total: int = 128
+    first_routed_expert: int = 0
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+    n_group: int = 1
+    topk_group: int = 1
+    group_top: int = 1
+
+    @property
+    def head_dim(self) -> int:
+        return self.attn_head_dim
+
+    # the layers by kind, as the latent families read the same two
+    # tuples; R, and why a ring of R pages holds every key a dispatch
+    # needs: the same count as the latent ring's
+    # (models/llama/paged.ring_holds)
+    full_layers = GlmMoeDsaConfig.full_layers
+    sliding_layers = GlmMoeDsaConfig.sliding_layers
+    sparse_layers = GlmMoeDsaConfig.sparse_layers
+    window_ring_pages = Dots3NoteConfig.window_ring_pages
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "ExaoneMoeConfig":
+        L = raw["num_hidden_layers"]
+        for name, want in _EXAONE_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type exaone_moe "
+                    f"serves {want!r} only (not implemented)")
+        if raw.get("num_nextn_predict_layers", 0):
+            raise ValueError(
+                "num_nextn_predict_layers > 0: the multi-token-prediction "
+                "module is not served (it drafts for speculation and adds "
+                "nothing to the next-token logits); set it to 0 (and "
+                "drop mtp_layer_types / mtp_sliding_windows)")
+        rope = raw.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(
+                f"rope_parameters.rope_type = {rope['rope_type']!r}: only "
+                "'default' (plain RoPE, one theta) is implemented")
+        W = raw["sliding_window"]
+        if W < 1:
+            raise ValueError("sliding_window must be at least 1")
+        types = list(raw.get("layer_types") or [])
+        if len(types) != L or set(types) - set(_EXAONE_KINDS):
+            raise ValueError(
+                f"layer_types must name num_hidden_layers = {L} layers, "
+                "each sliding_attention or full_attention; got "
+                f"{len(types)}: " + ", ".join(sorted(set(types))))
+        windows = raw.get("sliding_windows")
+        if windows is not None and list(windows) != [
+                W if t == "sliding_attention" else 0 for t in types]:
+            raise ValueError(
+                "sliding_windows must give sliding_window for each "
+                "sliding_attention layer of layer_types and 0 for each "
+                "full_attention layer: one window is served")
+        dense = raw.get("first_k_dense_replace", 0)
+        mlp = list(raw.get("mlp_layer_types")
+                   or ["dense"] * dense + ["sparse"] * (L - dense))
+        if len(mlp) != L or set(mlp) - {"dense", "sparse"}:
+            raise ValueError(
+                f"mlp_layer_types must name num_hidden_layers = {L} "
+                "layers, each dense or sparse")
+        if raw.get("num_shared_experts", 1) < 1:
+            raise ValueError("num_shared_experts must be at least 1")
+        held = raw["num_experts"]
+        total = raw.get("num_experts_total", held)
+        first = raw.get("first_routed_expert", 0)
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, rope_theta=rope.get("rope_theta",
+                                     raw.get("rope_theta", 10000.0))))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields.update(chat_template="chatml", sliding_window=None)
+        return cls(
+            **fields,
+            num_local_experts=held,
+            num_experts_per_tok=raw["num_experts_per_tok"],
+            norm_topk_prob=raw.get("norm_topk_prob", True),
+            hf_layout="exaone_moe",
+            attn_head_dim=raw.get(
+                "head_dim",
+                raw["hidden_size"] // raw["num_attention_heads"]),
+            sliding_window_size=W,
+            mlp_layer_types=tuple(mlp),
+            indexer_types=tuple(_EXAONE_KINDS[t] for t in types),
+            moe_intermediate_size=raw["moe_intermediate_size"],
+            n_routed_experts_total=total, first_routed_expert=first,
+            n_shared_experts=raw.get("num_shared_experts", 1),
+            routed_scaling_factor=raw.get("routed_scaling_factor", 1.0),
+        )
+
+    @classmethod
+    def tiny_exaone(cls, **overrides) -> "ExaoneMoeConfig":
+        """K-EXAONE's layers at a test's size: two published periods
+        (`S S S F` twice), layer 0 dense and seven sparse; 8 query
+        heads of 16 over 2 K/V heads (head_dim is NOT hidden / heads);
+        a window of 6 keys; 8 routed experts, 2 a token, all held."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=8, num_attention_heads=8,
+            num_key_value_heads=2, rms_norm_eps=1e-5, rope_theta=1e6,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(256,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=True, hf_layout="exaone_moe",
+            attn_head_dim=16, sliding_window_size=6,
+            mlp_layer_types=("dense",) + ("sparse",) * 7,
+            indexer_types=("sliding", "sliding", "sliding", "full") * 2,
+            moe_intermediate_size=32, n_routed_experts_total=8,
+            n_shared_experts=1, routed_scaling_factor=2.5,
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
 @dataclass(frozen=True)
 class NemotronHConfig(MoEConfig):
     """Nemotron-3 (`model_type: nemotron_h`): every block is ONE mixer

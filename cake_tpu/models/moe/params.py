@@ -272,6 +272,57 @@ def _init_bailing_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     }
 
 
+def _init_exaone_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of an ExaoneMoeConfig. Both kinds of attention
+    layer have the same shapes, so the attention leaves are ONE stack
+    [L, ...] (q_norm / k_norm [L, head_dim]: an RMSNorm a head); the
+    dense FFN's [L_dense, ...], the router's, the experts' and the
+    shared expert's [L_sparse, ...] as GLM's. Norm weights are drawn
+    away from 1 so that a test sees them; the choice bias is 0 (what a
+    checkpoint that was never balanced holds: a test replaces it)."""
+    c = config
+    L, D = c.num_hidden_layers, c.hidden_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    Ls = len(c.sparse_layers)
+    Ld = L - Ls
+    E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
+                     c.moe_intermediate_size, c.intermediate_size)
+    Fs = c.n_shared_experts * Fe
+    w, mat, keys = _draws(rng, dtype, bits, 32)
+
+    def near(shape, centre):
+        return (centre + 0.1 * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    blocks = {
+        "attn_norm": near((L, D), 1.0),
+        "wq": mat("wq", (L, D, H * hd), D),
+        "wk": mat("wk", (L, D, KV * hd), D),
+        "wv": mat("wv", (L, D, KV * hd), D),
+        "q_norm": near((L, hd), 1.0),
+        "k_norm": near((L, hd), 1.0),
+        "wo": mat("wo", (L, H * hd, D), H * hd),
+        "mlp_norm": near((L, D), 1.0),
+        "router": w((Ls, D, Et), D),
+        "router_bias": jnp.zeros((Ls, Et), jnp.float32),
+        "we_gate": mat("we_gate", (Ls, E, D, Fe), D),
+        "we_up": mat("we_up", (Ls, E, D, Fe), D),
+        "we_down": mat("we_down", (Ls, E, Fe, D), Fe),
+        "ws_gate": mat("ws_gate", (Ls, D, Fs), D),
+        "ws_up": mat("ws_up", (Ls, D, Fs), D),
+        "ws_down": mat("ws_down", (Ls, Fs, D), Fs),
+    }
+    if Ld:
+        blocks.update({
+            "w_gate": mat("w_gate", (Ld, D, Fd), D),
+            "w_up": mat("w_up", (Ld, D, Fd), D),
+            "w_down": mat("w_down", (Ld, Fd, D), Fd),
+        })
+    return {"embed": w((c.vocab_size, D), D), "blocks": blocks,
+            "final_norm": near((D,), 1.0),
+            "lm_head": mat("lm_head", (D, c.vocab_size), D)}
+
+
 def _init_nemotron_params(config, rng: jax.Array, dtype,
                           bits: Optional[int]):
     """The seeded tree of a NemotronHConfig. Leaves are stacked per KIND
@@ -421,6 +472,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
     sees them."""
     if getattr(config, "kda_layers", None):
         return _init_bailing_params(config, rng, dtype, bits)
+    if config.hf_layout == "exaone_moe":
+        return _init_exaone_params(config, rng, dtype, bits)
     if getattr(config, "kv_lora_rank", None):
         return _init_glm_params(config, rng, dtype, bits)
     if getattr(config, "mamba_layers", None):
@@ -476,6 +529,12 @@ def hf_layout(config: MoEConfig):
             "convolutions, the router's MLP and the residual scaling "
             "have no counterpart it can name); it serves the family "
             "from seeded weights only")
+    if config.hf_layout == "exaone_moe":
+        raise NotImplementedError(
+            "model_type exaone_moe: the published checkpoint is not in "
+            "this repository and its tensor names are not guessed (the "
+            "choice bias and the per-head q / k norms among them); it "
+            "is served from seeded weights only")
     attn = {
         "attn_norm": ("input_layernorm.weight", False),
         "wq": ("self_attn.q_proj.weight", True),

@@ -412,13 +412,61 @@ KDA_STATE_BYTES = _m.gauge(
     "cake_kda_state_bytes",
     "Bytes of the rows' KDA state beside the page pool (the float32 "
     "matrix a head and the conv tails, every KDA layer, every slot)")
+# a model whose sliding-window layers are GQA over a K/V ring a row
+# beside full GQA layers on the allocator's pages
+# (models/moe/exaone_moe.trunk; its sliding layers count SWA_COUNTERS'
+# three as dots3_note's do)
+GQA_WINDOW_COUNTERS = (
+    ("gqa_full_keys_attended", _m.counter(
+        "cake_gqa_full_keys_attended_total",
+        "Keys the full layers attended (position + 1 a query: every "
+        "visible key), summed over tokens and full layers")),
+    ("gqa_window_keys_single", _m.counter(
+        "cake_gqa_window_keys_single_total",
+        "Keys the single-token rows attended in the sliding layers "
+        "(min(position + 1, window) each: what the banded "
+        "cake_decode_attn calls folded), summed over rows and sliding "
+        "layers")),
+    ("gqa_full_keys_single", _m.counter(
+        "cake_gqa_full_keys_single_total",
+        "Keys the single-token rows attended in the full layers "
+        "(position + 1 each: what the full cake_decode_attn calls "
+        "folded), summed over rows and full layers")),
+    ("gqa_window_pages_walked", _m.counter(
+        "cake_gqa_window_pages_walked_total",
+        "Ring pages the single-token rows walked in the sliding layers "
+        "(the pages that hold the band), summed over rows and sliding "
+        "layers")),
+    ("gqa_full_pages_walked", _m.counter(
+        "cake_gqa_full_pages_walked_total",
+        "Pool pages the single-token rows walked in the full layers "
+        "(position // page + 1), summed over rows and full layers")),
+    ("gqa_rows_single", _m.counter(
+        "cake_gqa_rows_single_total",
+        "Rows that held one token in a dispatch (what the decode "
+        "attention kernel served), summed over dispatches")),
+    ("gqa_ring_pages_live", _m.counter(
+        "cake_gqa_ring_pages_live_total",
+        "Ring pages that hold keys of the rows with tokens in a "
+        "dispatch (at most R a row), summed over dispatches")),
+    ("gqa_full_pages_live", _m.counter(
+        "cake_gqa_full_pages_live_total",
+        "Full-pool pages those rows' contexts fill, summed over "
+        "dispatches (over cake_gqa_ring_pages_live_total: what a layer "
+        "of the other kind would hold)")),
+)
+GQA_WINDOW_POOL_BYTES = _m.gauge(
+    "cake_gqa_window_pool_bytes",
+    "Bytes of the sliding-window layers' K and V pools beside the page "
+    "pool (slots x ring pages, every sliding layer)")
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
                       + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS
-                      + KDA_COUNTERS)
+                      + KDA_COUNTERS + GQA_WINDOW_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)
 BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
                      "cca_tail_bytes": CCA_TAIL_BYTES,
-                     "kda_state_bytes": KDA_STATE_BYTES}
+                     "kda_state_bytes": KDA_STATE_BYTES,
+                     "gqa_window_pool_bytes": GQA_WINDOW_POOL_BYTES}
 
 
 def refresh_page_gauges(engine) -> None:

@@ -110,7 +110,7 @@ def _unpack_nibbles(block, hd_slice):
 
 def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
                  page_size: int, kv_heads: int, group: int, head_dim: int,
-                 packed4: bool):
+                 packed4: bool, window: Optional[int] = None):
     """Fold one live page of a decode row into its online-softmax
     stats: the one body of the float, int8 and int4 pools.
 
@@ -125,6 +125,8 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
              folds into the dot OUTPUTS: no dequantized page exists.
     stats:   (m [H, 1], l [H, 1], acc [H, hd]) f32, the flash-attention
              recurrence of `ops/flash_attention.py`; returned updated.
+    window:  the band (walk_live_pages): slots at or below pos - window
+             are masked as the slots past pos are.
     """
     P = page_size
     hd = head_dim
@@ -141,8 +143,10 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
     # causal mask over the page's absolute slots (current token
     # included); every folded page has >= 1 valid column, so the online
     # max below never sees a fully-masked row
-    col_valid = (j * P + jax.lax.broadcasted_iota(
-        jnp.int32, (1, P), 1)) <= pos              # [1, P]
+    col = j * P + jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
+    col_valid = col <= pos                         # [1, P]
+    if window is not None:
+        col_valid = jnp.logical_and(col_valid, col > pos - window)
     # scores a kv head: query group g of kv head k against the page's
     # k-lane slice (static unroll: KV is small)
     parts = []
@@ -167,11 +171,22 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
 
 
 def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
-                    depth: int, page_size: int):
+                    depth: int, page_size: int,
+                    window: Optional[int] = None):
     """The walk of a decode kernel whose grid step is one ROW: the row's
     live pages 0 .. pos // page, fetched out of pools that lie whole in
     HBM by the kernel's own copies into rings of `depth` VMEM slots,
     the pages ahead in flight while page j folds.
+
+    window (static): a BAND. The row attends its last `window` keys,
+    its own included, so the walk starts at the page that holds
+    position pos - window + 1 and takes the trips from there to
+    pos // page (two at a window of one page, whatever the context),
+    and logical page p is read through table entry p mod max_pages: a
+    table of every page a row can hold reads as it always did, and a
+    RING of R entries (models/llama/paged.WindowedKVCache.wtable)
+    serves its logical page p from entry p mod R. None: no band, and
+    the program is the one it was.
 
     copies(layer, pid, slot): the async copies of the layer's page
              `pid` into ring slot `slot`, one a pool (K and V; a latent
@@ -193,8 +208,26 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
     layer = layer_ref[0]
     max_pages = table_ref.shape[1]
 
+    def first_page(row):
+        """The logical page a row's walk starts at."""
+        if window is None:
+            return 0
+        return jnp.maximum(pos_ref[row] - (window - 1), 0) // page_size
+
     def live_pages(row):
-        return jnp.clip(pos_ref[row] // page_size + 1, 0, max_pages)
+        if window is None:
+            return jnp.clip(pos_ref[row] // page_size + 1, 0, max_pages)
+        pos = pos_ref[row]
+        return jnp.where(
+            pos >= 0,
+            jnp.minimum(pos // page_size - first_page(row) + 1, max_pages),
+            0)
+
+    def page_id(row, j):
+        """The page of trip j of a row's walk."""
+        if window is None:
+            return table_ref[row, j]
+        return table_ref[row, (first_page(row) + j) % max_pages]
 
     def next_live_row(row):
         return jax.lax.while_loop(
@@ -209,7 +242,7 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
         def _():
             # an unmapped hole inside the live range starts no copy
             # (and folds nothing, below): its slot stays idle
-            pid = table_ref[row, j]
+            pid = page_id(row, j)
 
             @pl.when(pid >= 0)
             def _():
@@ -244,18 +277,19 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
     n = live_pages(b)
     first = cur[3]
     cur[3] = first + n
+    j0 = first_page(b)
 
     def pages(fold, stats):
         def landed(j, pid, stats):
             slot = (first + j) % depth
             for c in copies(layer, pid, slot):
                 c.wait()
-            return fold(j, pid, slot, stats)
+            return fold(j if window is None else j0 + j, pid, slot, stats)
 
         def page(j, stats):
             # the slot this frees held page j - 1, folded a step ago
             start_next()
-            pid = table_ref[b, j]
+            pid = page_id(b, j)
             return jax.lax.cond(pid >= 0, lambda s: landed(j, pid, s),
                                 lambda s: s, stats)
 
@@ -266,7 +300,7 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
 
 def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
                    depth: int, page_size: int, kv_heads: int,
-                   **fold_shape):
+                   window: Optional[int] = None, **fold_shape):
     """One grid step: one ROW of the ragged decode fold, its live pages
     of the layer walked by `walk_live_pages`, K and V together.
 
@@ -288,7 +322,8 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
                                                  (v_hbm, vbuf)))]
 
     pos, pages = walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies,
-                                 depth=depth, page_size=page_size)
+                                 depth=depth, page_size=page_size,
+                                 window=window)
     q = q_ref[0, 0]                                # [H, hd]
     if quantized:
         q = q.astype(jnp.float32)
@@ -301,7 +336,7 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
                 return sk_ref[at], sv_ref[at]
         return _decode_fold(q, kbuf.at[slot], vbuf.at[slot], scales, j, pos,
                             stats, page_size=page_size, kv_heads=kv_heads,
-                            **fold_shape)
+                            window=window, **fold_shape)
 
     _, l, acc = pages(fold, (jnp.full((H, 1), NEG_INF, jnp.float32),
                              jnp.zeros((H, 1), jnp.float32),
@@ -336,6 +371,7 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                            scale: float | None = None,
                            scale_k=None, scale_v=None,
                            packed4: bool = False,
+                           window: Optional[int] = None,
                            interpret: bool | None = None):
     """Ragged decode attention over a paged KV pool, one Pallas kernel.
 
@@ -354,6 +390,10 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     packed4:      the pool is nibble-PACKED int4
                   ([L, N_pages, page//2, KV*hd] uint8, kv/quantized_pool
                   pack_page_nibbles layout); requires scale_k/scale_v.
+    window:       static. A row attends its last `window` keys, its own
+                  included, and walks the pages that hold them alone;
+                  `table` may then be a ring (walk_live_pages). None:
+                  every key up to pos.
     Returns [B, 1, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention` (the fold reference) to f32
     tolerance — tests/test_ragged_paged_attn.py pins the parity.
@@ -392,7 +432,8 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     depth = decode_ring_depth(Pb * width * pool_k.dtype.itemsize)
     kernel = functools.partial(
         _decode_kernel, quantized=quantized, depth=depth, scale=scale,
-        page_size=P, kv_heads=KV, group=G, head_dim=hd, packed4=packed4)
+        page_size=P, kv_heads=KV, group=G, head_dim=hd, packed4=packed4,
+        window=window)
     row = pl.BlockSpec((1, 1, H, hd), lambda b, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands),
@@ -439,9 +480,20 @@ def mixed_q_tiles(q_len: int, q_width: int) -> int:
     return 1 if q_len <= MIXED_Q_TILE else -(-q_width // MIXED_Q_TILE)
 
 
+def _mixed_first_page(pos, page_size: int, window: Optional[int]):
+    """The logical page grid step 0 of a mixed row stands for: the page
+    that holds the first key its first query attends under a band of
+    `window` keys (0 with no band: python, so that the program without
+    one is the one it was)."""
+    if window is None:
+        return 0
+    return jnp.maximum(pos - (window - 1), 0) // page_size
+
+
 def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
                 l_ref, page_kv, *, scale: float, page_size: int,
-                kv_heads: int, group: int, head_dim: int, q_width: int):
+                kv_heads: int, group: int, head_dim: int, q_width: int,
+                window: Optional[int] = None):
     """One (row, page) grid step of the MIXED ragged fold: each row
     carries q_width query slots of which q_len are real — a decode row
     (q_len=1) and a prefill-chunk row (q_len=C at arbitrary page
@@ -468,6 +520,14 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
     tile the row did not fold are ZERO (written at the row's first
     page); padded columns of a folded span are what they always were,
     the fold of a query that is not there — finite, never read.
+
+    window (static): a BAND. Query i attends keys pos + i - window + 1
+    .. pos + i. Grid step j then stands for logical page first + j,
+    first the page of the first key the row's first query attends, so
+    that no page wholly before the band is fetched or folded, and the
+    mask cuts the rest; logical page p is read through table entry
+    p mod max_pages (a ring of R entries serves page p from entry
+    p mod R; a whole table reads as it did).
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -485,8 +545,14 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
     # of masked compute, never a negative bound
     n_q = jnp.maximum(qlen_ref[b], 1)
     last = pos + n_q - 1
-    page = table_ref[b, j]
-    live = jnp.logical_and(j * P <= last, page >= 0)
+    # lp: the logical page this grid step stands for
+    if window is None:
+        lp = j
+        page = table_ref[b, j]
+    else:
+        lp = _mixed_first_page(pos, P, window) + j
+        page = table_ref[b, lp % nj]
+    live = jnp.logical_and(lp * P <= last, page >= 0)
 
     def span(body):
         """body(nq) once, nq (static) the queries this row folds."""
@@ -526,8 +592,10 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
             # position pos + i and attends page slots <= it (current
             # token included — its KV is written before the kernel runs)
             qidx = jax.lax.broadcasted_iota(jnp.int32, (R, P), 0) // G
-            col = j * P + jax.lax.broadcasted_iota(jnp.int32, (R, P), 1)
+            col = lp * P + jax.lax.broadcasted_iota(jnp.int32, (R, P), 1)
             valid = col <= pos + qidx
+            if window is not None:
+                valid = jnp.logical_and(valid, col > pos + qidx - window)
             for kv in range(kv_heads):
                 kh, vh, k_scale, v_scale = page_kv(kv, pid)  # [P, hd]
                 qh = q_ref[0, :nq, heads(kv), :].reshape(R, hd)
@@ -620,6 +688,7 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
                                  scale: float | None = None,
                                  scale_k=None, scale_v=None,
                                  packed4: bool = False,
+                                 window: Optional[int] = None,
                                  interpret: bool | None = None):
     """MIXED ragged attention over a paged KV pool, one Pallas kernel.
 
@@ -646,6 +715,11 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
                   position, exactly the decode kernel's pos)
     q_len:        [B] int32 — real query tokens per row (0 = idle row,
                   output zeros)
+    window:       static. Query i of a row attends keys pos + i -
+                  window + 1 .. pos + i, and the row's grid steps are
+                  the pages from its first query's first key on;
+                  `table` may then be a ring (_mixed_fold). None: every
+                  key up to the query.
     Returns [B, C, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention_mixed` (the fold reference)
     to f32 tolerance — tests/test_ragged_paged_attn.py pins the parity.
@@ -682,14 +756,19 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
         # page — the repeated block index elides the DMA, so a row
         # streams only the pages its window actually covers
         last = pos_ref[b] + jnp.maximum(qlen_ref[b], 1) - 1
-        jj = jnp.minimum(j, last // P)
+        if window is None:
+            jj = jnp.minimum(j, last // P)
+        else:
+            jj = jnp.minimum(
+                _mixed_first_page(pos_ref[b], P, window) + j,
+                last // P) % max_pages
         page = table_ref[b, jj]
         return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
 
     if quantized:
         kernel = functools.partial(
             _rpa_mixed_kernel_q, packed4=packed4, scale=scale, page_size=P,
-            kv_heads=KV, group=G, head_dim=hd, q_width=C)
+            kv_heads=KV, group=G, head_dim=hd, q_width=C, window=window)
         n_prefetch = 6
         operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),
@@ -700,7 +779,7 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
     else:
         kernel = functools.partial(
             _rpa_mixed_kernel, scale=scale, page_size=P, kv_heads=KV,
-            group=G, head_dim=hd, q_width=C)
+            group=G, head_dim=hd, q_width=C, window=window)
         n_prefetch = 4
         operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),

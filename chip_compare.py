@@ -380,6 +380,51 @@ LING_START, LING_EDGE, LING_LAST = 8, 3, 128
 LING_JOBS = ((0, 8100), (1, 2000), (1, 1950))
 LING_DECODE = 24
 
+# K-EXAONE (benchmarks/configs/k-exaone-236b-int8-share8, model_type
+# exaone_moe) goes the way Ling does (trunk_steps / drive_jobs: 32 rows
+# in every step, one 512-token window a step, then 24 decode steps a
+# request) against cake_tpu/models/reference/exaone_moe.py on
+# teacher-forced experts. Limits: `mean` and `max` over the compared
+# positions (a request's first 8, the 3 behind its first window edge,
+# its last 128 prompt positions, every decode step); `reuse`, a slot's
+# second request against its twin in a fresh slot over their PROMPT
+# positions (the served path against itself: a ring and pages that
+# still hold another request's keys must change nothing);
+# `agree_same_input` and `router_logit_err` as Ling's; `probe`, BOTH
+# ragged paged attention kernels THEMSELVES on the chip, banded through
+# the ring and unbanded through the table, against exact float32
+# attention over the K and V the served path left in row 0's pages (the
+# d8k request's: first sliding layer, first full layer), for queries
+# drawn so that the scores spread over +-30 and a few keys carry a row
+# (exaone_probe; DeepSeek-V2's probe for these kernels): relative error,
+# root of summed squares, the worst of the four calls. There a bfloat16
+# score (2^-9 of 30 is 0.06: 6 % of a probability) shows far above the
+# kernels' own rounding, where at the logits the near-uniform attention
+# of seeded weights averages it away (`mean` 2.08e-3 against the served
+# path's 1.89e-3, `nearer` 1.10: my chip run, PR 53, seed 0): `probe` is
+# what holds the softmax's precision. `mean_decode`
+# (the decode kernel's band through a ring that has wrapped),
+# `mean_edge`, `agree` and `reuse_decode` are reported. Each limit lies
+# between the worst the served path read on the chip and the LEAST an
+# altered reference that it has to hold out read there (my chip runs,
+# PR 53, seeds 0 / 1 / 2; served | must fail; the cell's cell.json,
+# `chip_compare`, has every reading): mean 1.89e-3 / 1.94e-3 / 1.93e-3 |
+# 1.065e-2 at the least (rotation in the full layers too; a window of
+# 127 or 129 1.83e-2-1.95e-2, no q / k norm 3.96e-2, every layer under
+# the band 7.04e-2); max 1.34e-2 / 1.41e-2 / 1.45e-2 | 7.38e-2; probe
+# 1.70e-3 / 1.70e-3 / 1.74e-3 | 8.4e-3 at the least of its four calls
+# (exact attention with a bfloat16 softmax; 1.87e-2 at the most); reuse
+# 0.0 exactly; agree_same_input 1.0; router_logit_err 0.0.
+EXAONE_TOL = {"mean": 5e-3, "max": 4e-2, "probe": 5e-3, "reuse": 1e-3,
+              "router_logit_err": 1e-3}
+EXAONE_PROBE_SPREAD = 8.0   # the standard deviation of the probe's scores
+EXAONE_AGREE = 0.9
+EXAONE_START, EXAONE_EDGE, EXAONE_LAST = 8, 3, 128
+# (slot, prompt tokens): the cell's two prompt classes, and a second
+# request in slot 1 once its first has finished
+EXAONE_JOBS = ((0, 8100), (1, 2000), (1, 1950))
+EXAONE_DECODE = 24
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -508,6 +553,8 @@ def main() -> int:
         return compare_zaya(engine, cell, args, t_start)
     if raw_config.get("model_type") == "bailing_hybrid":
         return compare_ling(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "exaone_moe":
+        return compare_exaone_moe(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -2612,40 +2659,23 @@ def compare_zaya(engine, cell, args, t_start) -> int:
     return 0 if ok else 1
 
 
-def compare_ling(engine, cell, args, t_start) -> int:
-    """The comparison above for a matrix state a row and head beside the
-    latent page pool: the engine's own mixed and decode trunks with the
-    head at every position, against models/reference/bailing_hybrid.py
-    on teacher-forced experts. Jobs run a slot each, one window a step
-    with every other row decoding beside it (the jobs' rows that have
-    finished their prompts, and fillers in every slot no job uses, so
-    that the step is the timed one), the decode program when no row
-    prefills; slot 1 takes a second request when its first has finished
-    (the state it left behind must not reach the second), and its twin
-    runs beside it in a slot nothing has used."""
+def trunk_steps(module, engine):
+    """(window_step, decode_step): a family's own mixed and decode
+    trunks (`module.mixed_trunk` / `decode_trunk`, the signatures
+    bailing_hybrid's and exaone_moe's share) under jit with the head at
+    EVERY position. Each hands the host (logits [T, V], the cache,
+    every sparse layer's choice [L_sparse, T, k], the first sparse
+    layer's input [T, D], the router's logits on it as moe_mlp makes
+    them [T, E])."""
     import jax
     import jax.numpy as jnp
 
-    from cake_tpu.models.llama import paged
-    from cake_tpu.models.moe import bailing_hybrid as bh
-    from cake_tpu.models.reference import bailing_hybrid as ref
     from cake_tpu.ops import moe as moe_ops
     from cake_tpu.ops.quant import qmatmul
 
-    cfg, params, rope = engine.config, engine.params, engine.rope
-    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
-    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
-        f"engine built in {time.monotonic() - t_start:.1f} s")
-    if not args.rehearse and impl != cell["expect_impl"]:
-        say(f"FAILED: expected attention {cell['expect_impl']}")
-        return 1
-    attn = engine.attn_impl["mixed"]
+    cfg, rope, attn = engine.config, engine.rope, engine.attn_impl["mixed"]
 
-    def outputs(out):
-        """What a step hands the host: logits at every position, the
-        cache, every sparse layer's choice, and the first sparse
-        layer's input with the router's logits on it as moe_mlp makes
-        them."""
+    def outputs(params, out):
         logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
         h = out.ffn_in[0]
         router = params["blocks"]["router"][0]
@@ -2655,64 +2685,61 @@ def compare_ling(engine, cell, args, t_start) -> int:
     @partial(jax.jit, static_argnames=("n_tokens",),
              donate_argnames=("cache",))
     def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
-        out, _ = bh.mixed_trunk(params, tokens, pos, q_len, active, cache,
-                                rope, cfg, attn, n_tokens)
-        return outputs(out)
+        out, _ = module.mixed_trunk(params, tokens, pos, q_len, active,
+                                    cache, rope, cfg, attn, n_tokens)
+        return outputs(params, out)
 
     @partial(jax.jit, donate_argnames=("cache",))
     def decode_step(params, tokens, pos, active, cache):
-        return outputs(bh.decode_trunk(params, tokens, cache, pos, active,
-                                       rope, cfg, attn))
+        return outputs(params, module.decode_trunk(
+            params, tokens, cache, pos, active, rope, cfg, attn))
 
+    return window_step, decode_step
+
+
+def drive_jobs(engine, params, cache, steps_of, jobs, sequences, prompts,
+               compared, rng, waits_for=None, at_end=None):
+    """Jobs (slot, prompt) through `steps_of` = trunk_steps(...) as the
+    timed path runs them: a slot each, ONE window a step, the jobs
+    mid-prefill taking turns in slot order (family.Windows.STEP), every
+    other live row decoding beside it (the jobs' rows that have finished
+    their prompts, and fillers in every slot no job uses, so that the
+    step has every row), the decode program when no row prefills; a
+    slot's later job starts when its earlier one has finished.
+    waits_for {job: job}: a job that starts only when another has
+    finished (a twin that runs beside a slot's second request).
+    at_end(job, slot, cache): called when a job's last token is done.
+    compared(job, position) -> bool: the positions whose logits come
+    back. Returns (got [{position: logits [V]}], ffn_in [{position:
+    (h [D], router logits [E])}], all_routed [[L_sparse, S, k]], steps,
+    cache)."""
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+
+    window_step, decode_step = steps_of
+    cfg = engine.config
     B, C = engine.max_slots, engine._mixed_chunk
-    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
-    jobs = LING_JOBS if not args.rehearse else ((0, 70), (1, 30), (1, 25))
-    # the slot's second request again, beside it, in a slot nothing has
-    # used: the same tokens, the same steps
-    second = len(jobs) - 1
-    opener = next(i for i, (slot, _) in enumerate(jobs)
-                  if slot == jobs[second][0])
-    twin = len(jobs)
-    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
-    n_decode = LING_DECODE if not args.rehearse else 6
-    last = LING_LAST if not args.rehearse else 12
-    rng = np.random.default_rng(args.seed)
-    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
-                 for _, p in jobs[:twin]]
-    sequences.append(sequences[second])
-    prompts = [p for _, p in jobs]
-    assert max(prompts) + n_decode <= per_row * page
+    per_row = cache.table.shape[1]
+    waits_for = waits_for or {}
     job_slots = sorted({slot for slot, _ in jobs})
     fillers = [b for b in range(B) if b not in job_slots]
-    table = (np.arange(B)[:, None] * per_row
-             + np.arange(per_row)[None, :]).astype(np.int32)
-    assert table.max() < engine.cache.n_pages
-    cache = engine.cache._replace(table=jnp.asarray(table))
-    state_dtype = str(cache.ssm.dtype)
-    engine.cache = None
-    filler_tokens = rng.integers(0, cfg.vocab_size, (B, per_row * page))
-
+    filler_tokens = rng.integers(0, cfg.vocab_size,
+                                 (B, per_row * cache.page_size))
     Ls, k = len(cfg.sparse_layers), cfg.num_experts_per_tok
-    got = [dict() for _ in jobs]        # position -> logits [V]
-    ffn_in = [dict() for _ in jobs]     # position -> (h [D], logits [E])
+    got = [dict() for _ in jobs]
+    ffn_in = [dict() for _ in jobs]
     # every position's experts, for the teacher-forced reference
     all_routed = [np.zeros((Ls, len(seq), k), np.int32)
                   for seq in sequences]
-    states = [None] * len(jobs)         # the rows' state at a job's end
     off = [0] * len(jobs)
-
-    def compared(i, position):
-        """The prompt's last positions, every decode step, the request's
-        first positions and those behind the first window edge."""
-        return (position >= prompts[i] - last or position < LING_START
-                or C <= position < C + LING_EDGE)
 
     def current(slot):
         """The slot's first unfinished job."""
         return next((i for i, (s, _) in enumerate(jobs)
                      if s == slot and off[i] < len(sequences[i])
-                     and (i != twin
-                          or off[opener] == len(sequences[opener]))), None)
+                     and (i not in waits_for or off[waits_for[i]]
+                          == len(sequences[waits_for[i]]))), None)
 
     steps = {"mixed": 0, "decode": 0}
     n_steps = 0
@@ -2722,8 +2749,6 @@ def compare_ling(engine, cell, args, t_start) -> int:
         live = {slot: i for slot, i in live.items() if i is not None}
         qlen = np.zeros(B, np.int32)
         pos = np.zeros(B, np.int32)
-        # one window a step, the jobs mid-prefill taking turns in order
-        # (family.Windows.STEP); every other live row decodes or waits
         prefilling = [slot for slot, i in sorted(live.items())
                       if off[i] < prompts[i]]
         for slot, i in live.items():
@@ -2769,10 +2794,144 @@ def compare_ling(engine, cell, args, t_start) -> int:
         n_steps += 1
         for slot, i in live.items():
             off[i] += int(qlen[slot])
-            if qlen[slot] and off[i] == len(sequences[i]):
-                states[i] = np.asarray(cache.ssm[:, slot])
+            if (at_end is not None and qlen[slot]
+                    and off[i] == len(sequences[i])):
+                at_end(i, slot, cache)
     say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
         f"steps of {B} rows in {time.monotonic() - t0:.1f} s")
+    return got, ffn_in, all_routed, steps, cache
+
+
+def rows_table(engine, whole=None, other_pages: int = 0):
+    """The comparisons' allocator: a page table mapped for good. Every
+    slot a whole row of pages (slot b on pages b * per_row ..), or,
+    where the pool is not provisioned for every row at full length,
+    the slots `whole` a whole row each and every other slot its first
+    `other_pages` pages (a filler that decodes from position 0)."""
+    B, per_row = engine.max_slots, engine.cache.table.shape[1]
+    table = np.full((B, per_row), -1, np.int32)
+    at = 0
+    for b in range(B):
+        n = per_row if whole is None or b in whole else other_pages
+        table[b, :n] = at + np.arange(n)
+        at += n
+    assert at <= engine.cache.n_pages
+    return table
+
+
+def same_router_input(ref, router, got, ffn_in, all_routed, which, config):
+    """(agree_same_input, router_logit_err) over the compared positions
+    of the jobs `which`: the reference's router (`ref.router`, its
+    leaves `router`) on the served path's own input to the first sparse
+    layer: the share of positions where it chooses the served path's
+    experts, and ops/moe.router_logits on the chip against the
+    reference's product on that input, worst entry."""
+    import jax
+    import jax.numpy as jnp
+
+    hs, served_logits, chosen = [], [], []
+    for i in which:
+        for position in sorted(got[i]):
+            hs.append(ffn_in[i][position][0])
+            served_logits.append(ffn_in[i][position][1])
+            chosen.append(all_routed[i][0, position])
+    with jax.default_matmul_precision("highest"):
+        h = jnp.asarray(np.stack(hs), jnp.float32)
+        own = ref.router(router, h, config)[2]
+        logit_err = float(jnp.max(jnp.abs(
+            ref.mm(h, router["router"]) - np.stack(served_logits))))
+    same = [set(a.tolist()) == set(b.tolist())
+            for a, b in zip(np.asarray(own), chosen)]
+    return float(np.mean(same)), logit_err
+
+
+def second_request_apart(logits_at, yardstick, want, prompt: int,
+                         decode: bool = False) -> float:
+    """A slot's second request, by `logits_at(position)`, against
+    `yardstick` {position: logits}: mean |difference| / the range of the
+    reference's logits `want` at that position, over its compared prompt
+    positions (decode: its decode steps)."""
+    errs = [np.abs(logits_at(p) - other) / float(
+                want[p].max() - want[p].min())
+            for p, other in sorted(yardstick.items())
+            if (p >= prompt) == decode]
+    return float(np.mean(np.concatenate(errs)))
+
+
+def logit_errors(got, logits_of, which):
+    """[(job, position, |served - reference| / the reference's range at
+    that position [V])] over the compared positions of `which`."""
+    return [(i, position,
+             np.abs(logits - logits_of[i][position])
+             / float(logits_of[i][position].max()
+                     - logits_of[i][position].min()))
+            for i in which for position, logits in sorted(got[i].items())]
+
+
+def compare_ling(engine, cell, args, t_start) -> int:
+    """The comparison above for a matrix state a row and head beside the
+    latent page pool: the engine's own mixed and decode trunks with the
+    head at every position, against models/reference/bailing_hybrid.py
+    on teacher-forced experts. Jobs run a slot each, one window a step
+    with every other row decoding beside it (the jobs' rows that have
+    finished their prompts, and fillers in every slot no job uses, so
+    that the step is the timed one), the decode program when no row
+    prefills; slot 1 takes a second request when its first has finished
+    (the state it left behind must not reach the second), and its twin
+    runs beside it in a slot nothing has used."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe import bailing_hybrid as bh
+    from cake_tpu.models.reference import bailing_hybrid as ref
+
+    cfg, params = engine.config, engine.params
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = LING_JOBS if not args.rehearse else ((0, 70), (1, 30), (1, 25))
+    # the slot's second request again, beside it, in a slot nothing has
+    # used: the same tokens, the same steps
+    second = len(jobs) - 1
+    opener = next(i for i, (slot, _) in enumerate(jobs)
+                  if slot == jobs[second][0])
+    twin = len(jobs)
+    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
+    n_decode = LING_DECODE if not args.rehearse else 6
+    last = LING_LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs[:twin]]
+    sequences.append(sequences[second])
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    cache = engine.cache._replace(table=jnp.asarray(rows_table(engine)))
+    state_dtype = str(cache.ssm.dtype)
+    engine.cache = None
+
+    Ls, k = len(cfg.sparse_layers), cfg.num_experts_per_tok
+    states = [None] * len(jobs)         # the rows' state at a job's end
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, the request's
+        first positions and those behind the first window edge."""
+        return (position >= prompts[i] - last or position < LING_START
+                or C <= position < C + LING_EDGE)
+
+    def keep_state(i, slot, cache):
+        states[i] = np.asarray(cache.ssm[:, slot])
+
+    # the twin starts when the slot's first request has finished, so
+    # that it runs beside the second: the same tokens, the same steps
+    got, ffn_in, all_routed, steps, cache = drive_jobs(
+        engine, params, cache, trunk_steps(bh, engine), jobs, sequences,
+        prompts, compared, rng, waits_for={twin: opener}, at_end=keep_state)
 
     # -- the reference: the served weights leave the device, then come
     # back dequantized one layer at a time -----------------------------------
@@ -2842,23 +3001,8 @@ def compare_ling(engine, cell, args, t_start) -> int:
     del first_sparse
 
     def same_input(which, config):
-        """(agree_same_input, router_logit_err) over the compared
-        positions of `which`: the reference's router on the served
-        path's own FFN input."""
-        hs, served_logits, chosen = [], [], []
-        for i in which:
-            for position in sorted(got[i]):
-                hs.append(ffn_in[i][position][0])
-                served_logits.append(ffn_in[i][position][1])
-                chosen.append(all_routed[i][0, position])
-        with jax.default_matmul_precision("highest"):
-            h = jnp.asarray(np.stack(hs), jnp.float32)
-            _, _, own, _ = ref.router(router, h, config)
-            logit_err = float(jnp.max(jnp.abs(
-                ref.mm(h, router["router"]) - np.stack(served_logits))))
-        same = [set(a.tolist()) == set(b.tolist())
-                for a, b in zip(np.asarray(own), chosen)]
-        return float(np.mean(same)), logit_err
+        return same_router_input(ref, router, got, ffn_in, all_routed,
+                                 which, config)
 
     def readings(which, logits_of, routing_of, states_of, config=ref_cfg,
                  against=None):
@@ -2908,14 +3052,8 @@ def compare_ling(engine, cell, args, t_start) -> int:
         return out
 
     def apart(logits_at, yardstick, decode=False):
-        """The slot's second request, by `logits_at`, against
-        `yardstick`: mean |difference| / the reference's range, over
-        its compared prompt positions (decode: its decode steps)."""
-        errs = [np.abs(logits_at(p) - other) / float(
-                    want[second][p].max() - want[second][p].min())
-                for p, other in sorted(yardstick.items())
-                if (p >= prompts[second]) == decode]
-        return float(np.mean(np.concatenate(errs)))
+        return second_request_apart(logits_at, yardstick, want[second],
+                                    prompts[second], decode)
 
     def passes(r):
         return (all(r[k_] < limit for k_, limit in LING_TOL.items())
@@ -2986,6 +3124,323 @@ def compare_ling(engine, cell, args, t_start) -> int:
                                     == expected)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"result_ling_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if result["ok"] else 1
+
+
+def exaone_probe(cache, cfg, n_keys: int, width: int, attn: str,
+                 seed: int) -> dict:
+    """{"served": {call: error}, "bf16_softmax": {call: error}}: the
+    two ragged paged attention kernels as exaone_moe.attention calls
+    them (`paged.paged_attention` for a row's single token,
+    `paged.paged_attention_mixed` for a window in entries of
+    `query_tile`), each over the first full layer's pool through the
+    table and over the first sliding layer's through the ring under the
+    band, against exact float32 attention over the same stored K and V
+    of row 0 (n_keys positions written), and the exact attention with
+    bfloat16 scores and probabilities against the same. Queries are
+    drawn so that the scores' standard deviation is
+    EXAONE_PROBE_SPREAD."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import exaone_moe as ex
+
+    H, KV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    W, P, R = cfg.sliding_window_size, cache.page_size, cache.ring_pages
+    B = cache.table.shape[0]
+    C = min(width, n_keys)
+    tile = ex.query_tile(C, H, KV, hd, P, cache.k.dtype.itemsize,
+                         cache.k.dtype.itemsize)
+    at = jnp.arange(n_keys)
+
+    def stored(pool, table, ring):
+        """Row 0's rows of layer 0 by position [S, KV, hd] (a ring holds
+        the last R pages' alone: older positions read what overwrote
+        them, and no banded query reaches them)."""
+        entry = (at // P) % R if ring else at // P
+        return pool[0, table[0, entry], at % P].reshape(n_keys, KV, hd)
+
+    kinds = {"full": (cache.k, cache.v, cache.table, None),
+             "window": (cache.wk, cache.wv, cache.wtable, W)}
+    pos = np.full(B, -1, np.int32)
+    pos[0] = n_keys - 1
+    out = {"served": {}, "bf16_softmax": {}}
+    for kind, (pk, pv, table, band) in kinds.items():
+        keys, vals = (stored(pool, table, band is not None).astype(
+            jnp.float32) for pool in (pk, pv))
+        recent = keys[-W:] if band else keys
+        norm = float(jnp.sqrt(jnp.mean(jnp.sum(jnp.square(recent), -1))))
+        sigma = EXAONE_PROBE_SPREAD * hd ** 0.5 / norm
+        q1 = (jax.random.normal(jax.random.PRNGKey(seed + 1), (B, 1, H, hd))
+              * sigma).astype(pk.dtype)
+        qw = (jax.random.normal(jax.random.PRNGKey(seed + 2), (C, H, hd))
+              * sigma).astype(pk.dtype)
+
+        @partial(jax.jit, static_argnames="dtype")
+        def exact(q, q_pos, dtype, keys=keys, vals=vals, band=band):
+            """q [T, H, hd] at positions q_pos over the stored rows."""
+            with jax.default_matmul_precision("highest"):
+                qf = q.astype(jnp.float32).reshape(-1, KV, H // KV, hd)
+                sc = (jnp.einsum("tkgd,skd->tkgs", qf, keys)
+                      * hd ** -0.5).astype(dtype)
+                seen = at[None, :] <= q_pos[:, None]
+                if band:
+                    seen &= at[None, :] > q_pos[:, None] - band
+                sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
+                pr = jax.nn.softmax(sc, axis=-1).astype(jnp.float32)
+                return jnp.einsum("tkgs,skd->tkgd", pr, vals).reshape(
+                    -1, H, hd)
+
+        def exact_window(dtype):
+            # (in blocks of queries: [C, heads, keys] float32 is a GB)
+            return np.concatenate([np.asarray(exact(
+                qw[i:i + tile], n_keys - C + i + jnp.arange(tile), dtype),
+                np.float64) for i in range(0, C, tile)])
+
+        def rel(a, b):
+            return float(np.sqrt(np.sum(np.square(a - b))
+                                 / max(np.sum(np.square(b)), 1e-300)))
+
+        served1 = np.asarray(paged.paged_attention(
+            q1, pk, pv, 0, table, jnp.asarray(pos), impl=attn,
+            window=band)[0, 0], np.float64)
+        want1 = np.asarray(exact(q1[0, 0][None], jnp.asarray([n_keys - 1]),
+                                 jnp.float32)[0], np.float64)
+        servedw = np.asarray(ex.attend_window(
+            qw, pk, pv, 0, table[0], jnp.int32(n_keys - C), jnp.int32(C),
+            attn, band), np.float64)
+        wantw = exact_window(jnp.float32)
+        out["served"].update({f"decode_{kind}": rel(served1, want1),
+                              f"mixed_{kind}": rel(servedw, wantw)})
+        out["bf16_softmax"].update({
+            f"decode_{kind}": rel(np.asarray(exact(
+                q1[0, 0][None], jnp.asarray([n_keys - 1]), jnp.bfloat16)[0],
+                np.float64), want1),
+            f"mixed_{kind}": rel(exact_window(jnp.bfloat16), wantw)})
+    say(f"probe: kernels {out['served']}, exact attention with a bfloat16 "
+        f"softmax {out['bf16_softmax']}")
+    return out
+
+
+def compare_exaone_moe(engine, cell, args, t_start) -> int:
+    """The comparison above for GQA in two kinds of layer over K/V
+    pages by kind (a ring a row under the sliding layers): the engine's
+    own mixed and decode trunks with the head at every position, all 32
+    rows in every step (drive_jobs), against
+    models/reference/exaone_moe.py on teacher-forced experts. The jobs
+    are the cell's two prompt classes (a d8k prompt wraps its ring a
+    dozen times), and a second request in the t2k row's slot, whose
+    ring and pages still hold the first's keys, beside its twin in a
+    slot nothing has used."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe import exaone_moe as ex
+    from cake_tpu.models.reference import exaone_moe as ref
+
+    cfg, params = engine.config, engine.params
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    C = engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = EXAONE_JOBS if not args.rehearse else ((0, 70), (1, 30), (1, 25))
+    second = len(jobs) - 1
+    opener = next(i for i, (slot, _) in enumerate(jobs)
+                  if slot == jobs[second][0])
+    twin = len(jobs)
+    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
+    n_decode = EXAONE_DECODE if not args.rehearse else 6
+    last = EXAONE_LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs[:twin]]
+    sequences.append(sequences[second])
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    # the cell's pool holds its traffic, not 32 rows at full length:
+    # the jobs' slots a whole row, a filler the pages its decoding fills
+    # (one position a step; the steps are fewer than the positions of
+    # all the jobs' windows and decode steps, one a step)
+    most_steps = sum(-(-p // C) + n_decode for p in prompts)
+    cache = engine.cache._replace(table=jnp.asarray(rows_table(
+        engine, whole={slot for slot, _ in jobs},
+        other_pages=-(-most_steps // page))))
+    ring = (cache.ring_pages, str(cache.wk.dtype))
+    engine.cache = None
+    Ls = len(cfg.sparse_layers)
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, the request's
+        first positions and those behind the first window edge."""
+        return (position >= prompts[i] - last or position < EXAONE_START
+                or C <= position < C + EXAONE_EDGE)
+
+    got, ffn_in, all_routed, steps, cache = drive_jobs(
+        engine, params, cache, trunk_steps(ex, engine), jobs, sequences,
+        prompts, compared, rng, waits_for={twin: opener})
+    probe = exaone_probe(cache, cfg, len(sequences[0]), C,
+                         engine.attn_impl["mixed"], args.seed)
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time -----------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = ex.reference_config(cfg)
+    held = (cfg.first_routed_expert, cfg.num_local_experts)
+    ref.attend_block = jax.jit(ref.attend_block,
+                               static_argnames=("window", "dtype"))
+    ref.swiglu = jax.jit(ref.swiglu)
+
+    def layers(kinds=None):
+        # from the host copy: one layer's leaves cross to the device at
+        # a time, as stored, and widen there
+        for lp in ex.reference_layers(host["blocks"], cfg):
+            yield lp if kinds is None else dict(lp, kind=kinds)
+
+    top = {k_: dequantized(jax.tree.map(jnp.asarray, host[k_]))
+           for k_ in ("embed", "final_norm", "lm_head")}
+
+    def reference(which, config=ref_cfg, kinds=None):
+        """The reference over the jobs `which`, TEACHER-FORCED in its
+        experts -> ({job: logits}, {job: its own choice along that
+        trajectory})."""
+        t0 = time.monotonic()
+        seqs = [sequences[i] for i in which]
+        routing = [[] for _ in seqs]
+        logits = ref.forward(top, seqs, config, layers=layers(kinds),
+                             held=held, routing=routing,
+                             forced=[list(all_routed[i]) for i in which])
+        say(f"  reference over {sum(len(s_) for s_ in seqs)} tokens in "
+            f"{time.monotonic() - t0:.1f} s")
+        return (dict(zip(which, (np.asarray(x) for x in logits))),
+                dict(zip(which, routing)))
+
+    first_sparse = next(lp for lp in layers() if "router" in lp)
+    router = {k_: first_sparse[k_] for k_ in ("router", "router_bias")}
+    del first_sparse
+
+    def readings(which, logits_of, routing_of, config=ref_cfg,
+                 against=None):
+        """Over the compared positions of the jobs `which`, the served
+        logits against `logits_of`: mean and worst |error| / range;
+        `mean_edge` over the positions behind the first window edge;
+        `mean_decode` over the decode steps (what went through the
+        decode kernel's band and the ring after it wrapped); `agree`;
+        against: the plain reference's logits (`nearer`: the served
+        path's distance from `logits_of` over its distance from the
+        plain reference, reported)."""
+        errs = logit_errors(got, logits_of, which)
+        same = np.zeros(Ls)
+        to_this = to_plain = 0.0
+        for i, position, _ in errs:
+            same += [set(all_routed[i][layer, position].tolist())
+                     == set(routing_of[i][layer][position].tolist())
+                     for layer in range(Ls)]
+            if against is not None:
+                to_this += float(np.sum(np.square(
+                    got[i][position] - logits_of[i][position])))
+                to_plain += float(np.sum(np.square(
+                    got[i][position] - against[i][position])))
+        edge = [float(e.mean()) for _, p_, e in errs if C <= p_ < C + EXAONE_EDGE]
+        dec = [float(e.mean()) for i, p_, e in errs if p_ >= prompts[i]]
+        agree_same, logit_err = same_router_input(
+            ref, router, got, ffn_in, all_routed, which, config)
+        out = {"mean": float(np.mean([e.mean() for *_, e in errs])),
+               "max": max(float(e.max()) for *_, e in errs),
+               "mean_edge": float(np.mean(edge)) if edge else 0.0,
+               "mean_decode": float(np.mean(dec)) if dec else 0.0,
+               "agree": float(same.min()) / len(errs),
+               "agree_same_input": agree_same,
+               "router_logit_err": logit_err, "positions": len(errs)}
+        if against is not None:
+            out["nearer"] = (to_this / max(to_plain, 1e-300)) ** 0.5
+        return out
+
+    def apart(logits_at, yardstick, decode=False):
+        return second_request_apart(logits_at, yardstick, want[second],
+                                    prompts[second], decode)
+
+    def passes(r):
+        return (all(r[k_] < limit for k_, limit in EXAONE_TOL.items())
+                and r["agree_same_input"] > EXAONE_AGREE)
+
+    plain = list(range(twin))
+    want, want_routing = reference(plain)
+    served = readings(plain, want, want_routing)
+    served["probe"] = max(probe["served"].values())
+    served["probe_by_kernel"] = probe["served"]
+    # the served path against itself: the reused slot against the fresh
+    assert sorted(got[twin]) == sorted(got[second])
+    served["reuse"] = apart(lambda p: got[second][p], got[twin])
+    served["reuse_decode"] = apart(lambda p: got[second][p], got[twin],
+                                   decode=True)
+    expected = sum(
+        len({q for q in range(p + n_decode)
+             if q >= p - last or q < EXAONE_START
+             or C <= q < C + EXAONE_EDGE})
+        for p in prompts[:twin])
+    result = {
+        "served": served, "expected_positions": expected,
+        "tol": EXAONE_TOL, "agree_same_input_floor": EXAONE_AGREE,
+        "seed": args.seed, "jobs": [list(j) for j in jobs],
+        "rows_a_step": engine.max_slots, "steps": steps, "attention": impl,
+        "device": jax.devices()[0].device_kind, "ring_pages": ring[0],
+        "pool_dtype": ring[1],
+        "query_tile": ex.query_tile(
+            C, cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim, page, 2 if ring[1] == "bfloat16" else 4,
+            2 if ring[1] == "bfloat16" else 4)}
+    ok = served["positions"] == expected and passes(served)
+    if not ok:
+        say("FAILED: the served path is outside the tolerance")
+
+    # -- what must NOT pass: the reference, altered, read as the served
+    # path is (on the slot that is used twice: its two requests) --------
+    if args.negatives:
+        short = [opener, second]
+        W = ref_cfg["sliding_window"]
+        negatives = {
+            "window_less_one": dict(config=dict(ref_cfg,
+                                                sliding_window=W - 1)),
+            "window_plus_one": dict(config=dict(ref_cfg,
+                                                sliding_window=W + 1)),
+            "bf16_softmax": dict(config=dict(ref_cfg,
+                                             softmax_dtype="bfloat16")),
+            "rope_in_full_layers": dict(config=dict(ref_cfg,
+                                                    rope_in_full=True)),
+            "no_qk_norm": dict(config=dict(ref_cfg, qk_norm=False)),
+            # every layer under the band: what a full layer served
+            # through a ring would answer
+            "window_in_full_layers": dict(kinds="sliding")}
+        result["must_fail"] = {}
+        for name, kw in negatives.items():
+            say(f"negative: {name}")
+            logits, routing = reference(short, **kw)
+            r = readings(short, logits, routing,
+                         config=kw.get("config", ref_cfg), against=want)
+            r["reuse"] = 0.0
+            # the probe's reading of an altered reference is the served
+            # kernels' but where the probe itself computes the alteration
+            r["probe_by_kernel"] = probe.get(name, probe["served"])
+            r["probe"] = max(r["probe_by_kernel"].values())
+            result["must_fail"][name] = r
+            if passes(r):
+                say(f"FAILED: the reference with {name} passes the "
+                    "tolerance")
+                ok = False
+    result["ok"] = bool(ok) or bool(args.rehearse and served["positions"]
+                                    == expected)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"result_exaone_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)

@@ -19,8 +19,8 @@ from cake_tpu.models.llama.config import MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
-    BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, GlmMoeDsaConfig,
-    MoEConfig, NemotronHConfig, ZayaConfig,
+    BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
+    GlmMoeDsaConfig, MoEConfig, NemotronHConfig, ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
 
@@ -37,13 +37,14 @@ TINY = {
     "nemotron_h": NemotronHConfig.tiny_nemotron,
     "zaya": ZayaConfig.tiny_zaya,
     "bailing_hybrid": BailingHybridConfig.tiny_ling,
+    "exaone_moe": ExaoneMoeConfig.tiny_exaone,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
          "dots3_note": "latent row and index key",
          "deepseek_v2": "latent row",
          "nemotron_h": "state", "zaya": "tail",
-         "bailing_hybrid": "KDA state"}
+         "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 

@@ -334,6 +334,8 @@ CELLS = {
                                ((5120, 1536), (1536, 5120))),
     "deepseek-v2-int8-share8": (20, 6, 544, 32,
                                 ((5120, 1536), (1536, 5120))),
+    "k-exaone-236b-int8-share8": (16, 8, 544, 32,
+                                  ((6144, 2048), (2048, 6144))),
 }
 GMM_CALLS = [(cell, kind, K, N) for cell, shape in CELLS.items()
              for kind in ("decode", "mixed") for K, N in shape[4]]

@@ -150,6 +150,56 @@ def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
     assert mla.pages_ring_depth(128 * 640 * 2) * 128 * 640 * 2 < 2 * 2**20
 
 
+@pytest.mark.parametrize("kernel", ["decode", "mixed"])
+@pytest.mark.parametrize("window,table_pages", [(128, 6), (None, 76)])
+def test_banded_attention_kernels_compile_at_the_cells_widths(
+        one_chip, kernel, window, table_pages):
+    """`cake_decode_attn` and `cake_mixed_attn` at
+    kexaone.longreply-closed's shapes (64 query heads over 8 K/V heads
+    of 128, bf16 pages of 128) go through Mosaic banded over a ring of 6
+    entries a row and unbanded over the full layers' table of 76: the
+    decode kernel for 32 rows, the mixed kernel for a 512-token window
+    as 8 entries of 64 queries (what its scoped VMEM holds at 64 heads:
+    `exaone_moe.query_tile`; one entry of 128 is refused by the gate)."""
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe.exaone_moe import query_tile
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, KV, hd, P = 64, 8, 128, 128
+    tile = query_tile(512, H, KV, hd, P, 2, 2)
+    assert tile == 64
+    pool = sds((3, 192, P, KV * hd), jnp.bfloat16)
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        assert not rpa.ragged_paged_mixed_supported(P, H, KV, hd, 128)
+        with jax.default_matmul_precision("default"):
+            if kernel == "decode":
+                compiled = jax.jit(lambda q, k, v, t, p: (
+                    rpa.ragged_paged_attention(
+                        q, k, v, jnp.int32(1), t, p, window=window,
+                        interpret=False))).lower(
+                    sds((32, 1, H, hd), jnp.bfloat16), pool, pool,
+                    sds((32, table_pages), jnp.int32),
+                    sds((32,), jnp.int32)).compile()
+            else:
+                n = 512 // tile
+                compiled = jax.jit(lambda q, k, v, t, p, n_q: (
+                    rpa.ragged_paged_attention_mixed(
+                        q, k, v, jnp.int32(1), t, p, n_q, window=window,
+                        interpret=False))).lower(
+                    sds((n, tile, H, hd), jnp.bfloat16), pool, pool,
+                    sds((n, table_pages), jnp.int32), sds((n,), jnp.int32),
+                    sds((n,), jnp.int32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and f"cake_{kernel}_attn" in hlo
+
+
 def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
     """`cake_kda_step` at ling3.longreply-closed's shapes (10 layers of
     32 rows x 32 heads of 128 x 128 float32) goes through Mosaic, and
