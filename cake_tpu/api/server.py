@@ -26,6 +26,7 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from cake_tpu.api import stream_writer as sw
 from cake_tpu.api.openai import (
     chunk_response, completion_response, parse_chat_request,
 )
@@ -95,7 +96,11 @@ class ApiServer:
         self.collector = collector
         if collector is not None and engine is not None:
             engine.telemetry = collector
+        # the one thread that writes this server's streamed chunks
+        # (api/stream_writer.py); the engine signals it once a step
+        self.stream_writer = sw.StreamWriter()
         if engine is not None:
+            engine.flight.stream_wake = self.stream_writer.signal
             engine.start()
         self._gen_lock = threading.Lock()
         self._waiting = 0
@@ -131,6 +136,10 @@ class ApiServer:
              trace_id=None) -> Optional[dict]:
         """Run one chat completion. If send_chunk is set, stream deltas
         through it and return None; else return the full response dict.
+        A send_chunk that carries its connection as `socket` (the HTTP
+        handler's) has its live deltas written there by the server's
+        stream writer thread (api/stream_writer.py); any other is
+        called for each.
         `on_start` fires after admission and before any tokens — the
         streaming handler sends its response headers there, so queue
         rejections still surface as a clean 503; a callback accepting
@@ -220,18 +229,6 @@ class ApiServer:
             trace_id=trace_id,
         )
 
-        def lp_entry(t, lp, top):
-            text = self.engine.tokenizer.decode([t])
-            e = {"token": text, "logprob": round(lp, 6),
-                 "bytes": list(text.encode()), "top_logprobs": []}
-            if n_top:
-                def alt(at, al):
-                    atext = self.engine.tokenizer.decode([at])
-                    return {"token": atext, "logprob": round(al, 6),
-                            "bytes": list(atext.encode())}
-                e["top_logprobs"] = [alt(at, al) for at, al in top[:n_top]]
-            return e
-
         from cake_tpu.sched import ShedError
         from cake_tpu.serve.errors import DrainingError
 
@@ -246,7 +243,8 @@ class ApiServer:
             h.wait()
             lp = None
             if want_lp:
-                lp = [lp_entry(t, l, top) for (t, l), top
+                lp = [sw.lp_entry(self.engine.tokenizer, n_top, t, l, top)
+                      for (t, l), top
                       in zip(h.token_logprobs, h.token_top_logprobs)]
             text = h.text()   # raises the typed error if the engine failed it
             rep = list(getattr(h._req, "replayed_tokens", ()) or ())
@@ -262,22 +260,21 @@ class ApiServer:
             return completion_response(text, self.model_name,
                                        logprobs=lp)
 
-        rid = str(uuid.uuid4())
-        # Deltas are queued by the engine thread and written here on the
-        # handler thread: a slow client must never block the engine loop
-        # (that would stall every other in-flight request).
-        import queue as _queue
-        deltas: _queue.Queue = _queue.Queue()
-
-        def stream(delta: str, final: bool, n_done: int = 0):
-            deltas.put((delta, final, n_done))
-
-        # wants_count: the engine snapshots the finalized-entry count on
-        # the engine thread at emit time, so each chunk's logprob entries
-        # pair exactly with the delta carrying their text (a held-back
-        # UTF-8 tail token's entry ships with the later chunk that
-        # contains its text, never ahead of it)
-        stream.wants_count = True
+        # The engine thread only appends a delta to its stream; who
+        # writes it depends on what the caller passed. A send_chunk that
+        # carries its connection (`socket`: the HTTP handler's) hands the
+        # stream to the server's one writer thread, which the engine
+        # signals once a step and which sends without blocking; any other
+        # (embedders, tests) is called from this thread, a wake-up a
+        # delta. Either way a slow client never blocks the engine loop
+        # (that would stall every other in-flight request), nor the other
+        # streams: one whose socket would block comes back to this
+        # thread, which can wait.
+        sock = getattr(send_chunk, "socket", None)
+        cs = sw.ChatStream(
+            self.engine.tokenizer, self.engine.config.eos_token_ids,
+            self.model_name, want_lp, n_top,
+            writer=self.stream_writer if sock is not None else None)
         # back-compat with 1-arg send_chunk callables (embedders,
         # tests): only a callback that accepts event_id gets the SSE
         # resume ids; others receive plain chunks
@@ -291,12 +288,29 @@ class ApiServer:
                 raw_send(obj)
 
         try:
-            h = self.engine.chat(messages, stream=stream, **kw)
+            h = self.engine.chat(messages, stream=cs.feed, **kw)
         except (QueueFullError, ShedError) as e:
             raise QueueFull(getattr(e, "retry_after", 1.0),
                             shed=isinstance(e, ShedError))
         except DrainingError as e:
             raise QueueFull(e.retry_after, draining=True)
+        cs.bind(h._req)
+        try:
+            return self._serve_stream(cs, h, send_chunk, sock, on_start,
+                                      last_event_id)
+        finally:
+            # the request holds the stream (its callback) and the stream
+            # held the request: a finished request's token lists go when
+            # the last count does, not at the next full collection
+            cs.req = None
+
+    def _serve_stream(self, cs, h, send_chunk, sock, on_start,
+                      last_event_id):
+        """An admitted (or attached) streaming request to its end: the
+        headers, an attach's replay, the live deltas through the stream
+        writer or on this thread, then the terminal error event or the
+        finish chunk. None, or DISCONNECTED."""
+        r = h._req
         if on_start is not None:
             # a callback accepting rid= gets the engine rid (the
             # handler echoes it as x-cake-rid before any tokens, so a
@@ -304,115 +318,42 @@ class ApiServer:
             # admission); plain zero-arg callbacks (embedders, tests)
             # keep working
             if _accepts_kwarg(on_start, "rid"):
-                on_start(rid=h._req.rid)
+                on_start(rid=r.rid)
             else:
                 on_start()
-        lp_cursor = 0
-        eos_ids = self.engine.config.eos_token_ids
-        r = h._req
-        # SSE event ids are ABSOLUTE token positions: tokens replayed
-        # from previous process generations count, so a client's
-        # Last-Event-ID survives any number of restarts
-        id_base = len(getattr(r, "replayed_tokens", ()) or ())
-        sent_id = id_base   # high-water mark of delivered event ids
-
-        def chunk_lp(upto):
-            nonlocal lp_cursor
-            if not want_lp:
-                return None
-            entries = [
-                lp_entry(r.out_tokens[i], r.out_logprobs[i], r.out_top[i])
-                for i in range(lp_cursor, upto)
-                if r.out_tokens[i] not in eos_ids
-            ]
-            lp_cursor = upto
-            return entries
-
-        # trim_from: set when a FRESH admission arrives with a
-        # Last-Event-ID (the front-door router failing a keyed stream
-        # over to a different replica, which re-runs the whole prompt
-        # deterministically): events at or below the client's high-water
-        # mark are suppressed, and the first batch crossing it re-decodes
-        # only the unseen token suffix — the attach path's exact-suffix
-        # semantics, without a local attach to replay from. Same text
-        # re-decode boundary caveat as the attach replay.
-        trim_from = None
         if getattr(h, "attached", False):
-            # idempotent reconnect: replay the held/journaled suffix
-            # after the client's Last-Event-ID as ONE chunk (its id is
-            # the absolute position it covers up to), then fall into
-            # the live loop — queued deltas at or below the replayed
-            # high-water mark are dropped there, so the client sees
-            # exactly the missing tokens: no duplicates, no gaps.
-            history = (list(getattr(r, "replayed_tokens", ()) or ())
-                       + list(r.out_tokens))
-            start_at = max(0, int(last_event_id or 0))
-            suffix = [t for t in history[start_at:]
-                      if t not in eos_ids]
+            # idempotent reconnect: the missing suffix first, then live
+            first = cs.replay(last_event_id)
             try:
-                if suffix:
-                    send_chunk(chunk_response(
-                        self.engine.tokenizer.decode(suffix),
-                        self.model_name, rid=rid),
-                        event_id=len(history))
+                if first is not None:
+                    send_chunk(*first)
             except OSError:
                 return DISCONNECTED   # reconnect died mid-replay
-            sent_id = max(start_at, len(history))
-            lp_cursor = max(0, sent_id - id_base)
         elif last_event_id:
             # fresh admission, resuming client: suppress what it holds
-            sent_id = max(sent_id, int(last_event_id))
-            lp_cursor = max(0, sent_id - id_base)
-            trim_from = lp_cursor
+            cs.resume_after(last_event_id)
 
-        while True:
-            try:
-                delta, final, n_done = deltas.get(timeout=0.5)
-            except _queue.Empty:
-                if h._req.done.is_set() and deltas.empty():
-                    break  # request ended without a final delta (error path)
-                continue
-            ev_id = id_base + n_done
-            if delta and ev_id > sent_id:
-                if trim_from is not None:
-                    # the batch crossing the resumed client's
-                    # Last-Event-ID: ship only the unseen suffix
-                    toks = [t for t in r.out_tokens[trim_from:n_done]
-                            if t not in eos_ids]
-                    delta = (self.engine.tokenizer.decode(toks)
-                             if toks else "")
-                    trim_from = None
-                    if not delta:
-                        # the whole crossing batch was EOS/empty:
-                        # nothing to write, but the position advances
-                        sent_id = ev_id
-                        if final:
-                            break
-                        continue
-                try:
-                    send_chunk(chunk_response(delta, self.model_name,
-                                              rid=rid,
-                                              logprobs=chunk_lp(n_done)),
-                               event_id=ev_id)
-                    sent_id = ev_id
-                except OSError:
-                    # client disconnected mid-stream: free the slot now
-                    # instead of decoding to max_tokens for nobody —
-                    # UNLESS the request is idempotency-keyed: the
-                    # client told us it will reconnect and resume, so
-                    # the stream keeps decoding for its return
-                    if r.idempotency_key is None:
-                        log.info("client disconnected; cancelling "
-                                 "request")
-                        self.engine.cancel(h)
-                    else:
-                        log.info("client disconnected; rid=%d keeps "
-                                 "decoding for an idempotent reconnect",
-                                 r.rid)
-                    return DISCONNECTED
-            if final:
-                break
+        outcome = (self.stream_writer.write(cs, sock) if sock is not None
+                   else sw.BLOCKED)
+        if outcome == sw.BLOCKED:
+            outcome = cs.pump(send_chunk, sock)
+        if outcome in (sw.GONE, sw.FAILED):
+            # client disconnected mid-stream (or the writer failed and
+            # the stream ends below): free the slot now instead of
+            # decoding to max_tokens for nobody — UNLESS the request is
+            # idempotency-keyed: the client told us it will reconnect
+            # and resume, so the stream keeps decoding for its return
+            if r.idempotency_key is None:
+                log.info("client disconnected; cancelling request")
+                self.engine.cancel(h)
+            else:
+                log.info("client disconnected; rid=%d keeps decoding "
+                         "for an idempotent reconnect", r.rid)
+            if outcome == sw.GONE:
+                return DISCONNECTED
         try:
+            if outcome == sw.FAILED:
+                raise cs.error
             h.text()  # raises if the engine failed the request
         except Exception as e:  # noqa: BLE001
             # the headers are long gone: an open SSE stream gets a
@@ -427,15 +368,7 @@ class ApiServer:
                 return DISCONNECTED
             return None
         try:
-            # the finish chunk flushes entries finalized after the last
-            # text-bearing delta (e.g. an EOS-terminated request whose
-            # final delta was empty), keeping the one-entry-per-token
-            # contract; the request is done, so the full lists are stable
-            send_chunk(chunk_response("", self.model_name,
-                                      finish="stop", rid=rid,
-                                      logprobs=chunk_lp(
-                                          len(h._req.out_tokens))),
-                       event_id=id_base + len(h._req.out_tokens))
+            send_chunk(*cs.finish_chunk())
         except OSError:
             return DISCONNECTED  # request already complete; just stop
         return None
@@ -1370,15 +1303,12 @@ def make_handler(api: ApiServer):
                 self._stream_started = True
 
             def send_chunk(obj: dict, event_id=None):
-                # the `id:` field makes the stream resumable: it is the
-                # absolute token position this event covers up to, and
-                # a reconnect echoes it back as Last-Event-ID
-                head = (f"id: {int(event_id)}\n"
-                        if event_id is not None else "")
-                payload = f"{head}data: {json.dumps(obj)}\n\n".encode()
-                self.wfile.write(hex(len(payload))[2:].encode() + b"\r\n")
-                self.wfile.write(payload + b"\r\n")
+                self.wfile.write(sw.sse_chunk(obj, event_id))
                 self.wfile.flush()
+
+            # the stream writer sends on the connection itself, without
+            # blocking, until the stream ends or the socket would block
+            send_chunk.socket = self.connection
 
             outcome = api.chat(body, send_chunk=send_chunk,
                                on_start=on_start,
@@ -1390,10 +1320,7 @@ def make_handler(api: ApiServer):
                 # trailer would only manufacture an error traceback
                 api._count(self.path, 200)
                 return
-            done = b"data: [DONE]\n\n"
-            self.wfile.write(hex(len(done))[2:].encode() + b"\r\n")
-            self.wfile.write(done + b"\r\n")
-            self.wfile.write(b"0\r\n\r\n")
+            self.wfile.write(b"e\r\ndata: [DONE]\n\n\r\n0\r\n\r\n")
             api._count(self.path, 200)
 
     return Handler
@@ -1616,6 +1543,8 @@ def start(master, address: str = "127.0.0.1:10128",
                 announcer.close()
             if health is not None:
                 health.close()
+            # what it held goes back to the handler threads
+            api.stream_writer.close()
 
     if block:
         serve()

@@ -13,6 +13,11 @@ component, so the framework works even where no C++ toolchain does:
 
 The shared object is compiled once into _build/ (keyed on a source hash)
 and dlopened via ctypes; no pip, no pybind11, no build system beyond g++.
+It is opened twice: the safetensors calls (file mapping, prefetch) through
+a `CDLL`, which lets the interpreter lock go around each call, and the
+scheduler's through a `PyDLL`, which keeps it: they take microseconds under
+the library's own mutex, and the engine makes one a row a token, where a
+lock let go is a lock some other thread takes.
 """
 
 from __future__ import annotations
@@ -33,6 +38,7 @@ _SOURCES = ("safetensors.cpp", "scheduler.cpp")
 
 _lock = threading.Lock()
 _lib = None
+_sched_lib = None
 _lib_error: str | None = None
 
 
@@ -60,9 +66,8 @@ def _build_library() -> str:
     return so_path
 
 
-def _declare(lib) -> None:
+def _declare_safetensors(lib) -> None:
     c = ctypes
-    # safetensors
     lib.cake_st_open.restype = c.c_void_p
     lib.cake_st_open.argtypes = [c.c_char_p, c.c_char_p, c.c_int]
     lib.cake_st_num_tensors.restype = c.c_int64
@@ -83,7 +88,10 @@ def _declare(lib) -> None:
     lib.cake_st_prefetch.argtypes = [c.c_void_p, c.c_int64]
     lib.cake_st_close.restype = None
     lib.cake_st_close.argtypes = [c.c_void_p]
-    # scheduler
+
+
+def _declare_scheduler(lib) -> None:
+    c = ctypes
     lib.cake_sched_create.restype = c.c_void_p
     lib.cake_sched_create.argtypes = [c.c_int32, c.c_int32]
     lib.cake_sched_destroy.restype = None
@@ -110,22 +118,35 @@ def _declare(lib) -> None:
     lib.cake_sched_completed.argtypes = [c.c_void_p]
 
 
-def get_library():
-    """Build (if needed) and dlopen the native library; None on failure."""
-    global _lib, _lib_error
+def _load() -> None:
+    global _lib, _sched_lib, _lib_error
     with _lock:
         if _lib is not None or _lib_error is not None:
-            return _lib
+            return
         try:
             so_path = _build_library()
-            lib = ctypes.CDLL(so_path)
-            _declare(lib)
-            _lib = lib
+            lib, sched = ctypes.CDLL(so_path), ctypes.PyDLL(so_path)
+            _declare_safetensors(lib)
+            _declare_scheduler(sched)
+            _lib, _sched_lib = lib, sched
         except Exception as e:  # toolchain missing, build error, ...
             _lib_error = str(e)
             log.warning("native library unavailable (%s); "
                         "using Python fallbacks", e)
-        return _lib
+
+
+def get_library():
+    """Build (if needed) and dlopen the native library, for the
+    `cake_st_*` calls; None on failure."""
+    _load()
+    return _lib
+
+
+def get_scheduler_library():
+    """The same library for the `cake_sched_*` calls, which keep the
+    interpreter lock; None on failure."""
+    _load()
+    return _sched_lib
 
 
 def is_available() -> bool:

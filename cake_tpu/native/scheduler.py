@@ -24,7 +24,7 @@ import threading
 from collections import deque
 from typing import Dict, List, Tuple
 
-from cake_tpu.native import get_library
+from cake_tpu.native import get_scheduler_library
 
 
 class PyScheduler:
@@ -135,7 +135,7 @@ class NativeScheduler:
     """ctypes wrapper over csrc/scheduler.cpp."""
 
     def __init__(self, max_slots: int, max_queue: int = 1024):
-        lib = get_library()
+        lib = get_scheduler_library()
         if lib is None:
             raise RuntimeError("native library unavailable")
         self._lib = lib
@@ -195,6 +195,6 @@ class NativeScheduler:
 
 def make_scheduler(max_slots: int, max_queue: int = 1024):
     """Native scheduler when the toolchain allows, else the Python one."""
-    if get_library() is not None:
+    if get_scheduler_library() is not None:
         return NativeScheduler(max_slots, max_queue)
     return PyScheduler(max_slots, max_queue)

@@ -67,7 +67,12 @@ pieces, all dependency-free:
     successor reached the device after it had finished (`late`, from
     the step's own fetch against LATE_FETCH_S). `detok_ids`: the ids
     the streaming detokeniser handed to the tokenizer's decode inside
-    `emit` (a few a token, however long the output).
+    `emit` (a few a token, however long the output). `stream_chunks`,
+    `stream_direct`, `stream_wakes`: the deltas that `emit` left for
+    the API server's stream writer (api/stream_writer.py: an append
+    each), those it handed to a callback a token, and the times it
+    signalled the writer, once where the span closed
+    (`cake_stream_chunks_total{path}`, `cake_stream_writer_wakes_total`).
 
 MFU here is model-FLOPs utilization: (program FLOPs from
 cost_analysis) / (peak chip FLOP/s x measured step seconds), clamped to
@@ -89,7 +94,7 @@ import time
 import weakref
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from cake_tpu.obs import metrics as _m
 from cake_tpu.obs.jsonl import JsonlAppender
@@ -162,6 +167,17 @@ _CHAIN_BREAKS = _m.counter(
     "chain before them ended (obs/steps.BREAKS; idle = the loop had "
     "nothing to run)",
     labelnames=("cause",))
+_STREAM_CHUNKS = _m.counter(
+    "cake_stream_chunks_total",
+    "Streamed deltas by who writes them: the API server's stream "
+    "writer (an append on the engine thread, one wake-up a step) or a "
+    "handler (a callback a token: an embedder's, a test's, a stream "
+    "the writer gave back to its handler thread)",
+    labelnames=("path",))
+_STREAM_WAKES = _m.counter(
+    "cake_stream_writer_wakes_total",
+    "Times the engine thread signalled the stream writer: once where "
+    "an emit span that left it deltas closed")
 _GC_PAUSE = _m.counter(
     "cake_gc_pause_seconds_total",
     "Seconds the process spent in the interpreter's cyclic collections "
@@ -840,6 +856,12 @@ class StepRecord:
     # inside the `emit` span since the previous record (both decodes of
     # a token counted); absent where nothing was detokenised
     detok_ids: Optional[int] = None
+    # the deltas the `emit` span left for the stream writer since the
+    # previous record, those it handed to a per-token callback, and the
+    # signals to the writer (one a span that left it any); absent at 0
+    stream_chunks: Optional[int] = None
+    stream_direct: Optional[int] = None
+    stream_wakes: Optional[int] = None
     # a chained step: the seconds its own fetch waited, and whether
     # that was no wait at all (under LATE_FETCH_S): the device had
     # finished the step before the host sent the one after it
@@ -920,6 +942,9 @@ class StepRecord:
             out["parts"] = {k: round(v, 6) for k, v in self.parts.items()}
         if self.detok_ids:
             out["detok_ids"] = self.detok_ids
+        for key in ("stream_chunks", "stream_direct", "stream_wakes"):
+            if getattr(self, key):
+                out[key] = getattr(self, key)
         if self.fetch_wait_s is not None:
             out["fetch_wait_s"] = round(self.fetch_wait_s, 6)
             out["late"] = self.late
@@ -972,9 +997,9 @@ BREAKS = ("stop", "queue", "cancel", "command", "sync", "stretch_cap",
 # start or the row before), `trace` the request tracer and the TTFT
 # series, `report` the journal's note, the stats and the scheduler's
 # report, `detok` the detokenisation (add_detok_ids beside it counts
-# the ids it decodes), `stream` the request's callback (a queue put,
-# and whatever the interpreter hands the woken thread before it
-# returns), `retire` a finished row's release.
+# the ids it decodes), `stream` the request's callback (an append for
+# the API server's stream writer, add_stream beside it; whatever a
+# per-token callback does), `retire` a finished row's release.
 EMIT_SEAMS = ("emit.rows", "emit.trace", "emit.report", "emit.detok",
               "emit.stream", "emit.retire")
 PARTS = ("schedule.plan", "schedule.admit_pages", "schedule.admit_ring",
@@ -1118,6 +1143,13 @@ class StepTelemetry:
         self._open: Optional[str] = None
         self._parts: Dict[str, float] = {}
         self._detok_ids = 0
+        # deltas left for the stream writer, handed to a callback, and
+        # signals to the writer, for the next record (counts of what
+        # was sent: an idle loop's discard_open keeps them); whether
+        # deltas wait for a signal; the signal (the API server sets it)
+        self._stream = [0, 0, 0]
+        self._stream_waits = False
+        self.stream_wake: Optional[Callable[[], None]] = None
         self._break: Optional[str] = None
         self._admitted = 0
         # the engine thread's clock: where the open span started (inside
@@ -1208,6 +1240,19 @@ class StepTelemetry:
         if self._open == "emit":
             self._detok_ids += ids
 
+    def add_stream(self, handed: bool) -> None:
+        """A delta went to its request's stream callback: `handed` when
+        the callback only queued it for the stream writer, which then
+        needs a signal. Inside an `emit` span the signal waits for the
+        span's end, one for all its rows, and the delta counts in the
+        next record; outside one (a speculative round, an adopted first
+        token, a recovered row's flush) it goes at once."""
+        if self._open == "emit":
+            self._stream[0 if handed else 1] += 1
+            self._stream_waits |= handed
+        elif handed and self.stream_wake is not None:
+            self.stream_wake()
+
     def chain_broke(self, cause: str) -> None:
         """A stretch of in-flight steps stopped chaining and has been
         fetched to its end (serve/engine._drive_burst): `cause` (one of
@@ -1253,6 +1298,11 @@ class StepTelemetry:
                 if acc[i] > 0.0:
                     parts[key] = parts.get(key, 0.0) + acc[i]
                     acc[i] = 0.0
+            if self._stream_waits:
+                self._stream_waits = False
+                self._stream[2] += 1
+                if self.stream_wake is not None:
+                    self.stream_wake()
         elif (name == "dispatch" and name not in self._phases
                 and self._fetch_t1 is not None):
             # the open step's first dispatch, after the last step's fetch
@@ -1400,6 +1450,7 @@ class StepTelemetry:
         phases, self._phases = self._phases, {}
         parts, self._parts = self._parts, {}
         detok_ids, self._detok_ids = self._detok_ids, 0
+        (chunks, direct, wakes), self._stream = self._stream, [0, 0, 0]
         gap, self._gap = (0.0 if chained else self._gap), None
         cause = admitted = late = None
         if chained:
@@ -1436,6 +1487,8 @@ class StepTelemetry:
                      if moe is not None else None),
                 chain_break=cause, rows_admitted=admitted,
                 parts=parts or None, detok_ids=detok_ids or None,
+                stream_chunks=chunks or None, stream_direct=direct or None,
+                stream_wakes=wakes or None,
                 fetch_wait_s=fetch_wait_s, late=late,
                 loop_s=loop_s, offcpu=offcpu or None,
                 gc_s=gc_s, gc_n=gc_n, gc_max_s=gc_max_s)
@@ -1449,6 +1502,11 @@ class StepTelemetry:
         elif cause is not None:
             _CHAIN_BREAKS.labels(cause=cause).inc()
         _STEP_DISPATCH.labels(kind=kind).observe(disp)
+        if chunks:
+            _STREAM_CHUNKS.labels(path="writer").inc(chunks)
+            _STREAM_WAKES.inc(wakes)
+        if direct:
+            _STREAM_CHUNKS.labels(path="handler").inc(direct)
         for k, v in (("decode", rows_decode), ("prefill", rows_prefill),
                      ("idle", rows_idle)):
             if v:

@@ -2549,14 +2549,8 @@ class InferenceEngine:
         the step failed: it has every token it asked for — deliver the
         final delta instead of resubmitting a zero-budget prefill."""
         if req.stream is not None:
-            delta = self._incremental_text(req, final=True)
-            try:
-                if req.stream_wants_count:
-                    req.stream(delta, True, len(req.out_tokens))
-                else:
-                    req.stream(delta, True)
-            except Exception:  # noqa: BLE001
-                log.exception("stream callback failed rid=%d", req.rid)
+            self._stream_out(req, self._incremental_text(req, final=True),
+                             True)
         req.finish_t = time.perf_counter()
         self.scheduler.cancel(req.rid)
         self._requests.pop(req.rid, None)
@@ -5350,11 +5344,8 @@ class InferenceEngine:
                            output_tokens=len(req.out_tokens))
         if req.stream is not None:
             try:
-                delta = self._incremental_text(req, final=True)
-                if req.stream_wants_count:
-                    req.stream(delta, True, len(req.out_tokens))
-                else:
-                    req.stream(delta, True)
+                self._stream_out(
+                    req, self._incremental_text(req, final=True), True)
             except Exception:  # noqa: BLE001
                 log.exception("stream callback failed rid=%d", req.rid)
         req.done.set()
@@ -6243,13 +6234,7 @@ class InferenceEngine:
             delta = self._incremental_text(req, final=finished)
             t_detok = t_stream = t_end = clock()
             if delta or finished:
-                try:
-                    if req.stream_wants_count:
-                        req.stream(delta, finished, len(req.out_tokens))
-                    else:
-                        req.stream(delta, finished)
-                except Exception:  # noqa: BLE001
-                    log.exception("stream callback failed rid=%d", req.rid)
+                self._stream_out(req, delta, finished)
                 t_stream = t_end = clock()
         if finished:
             req.finish_t = now
@@ -6271,6 +6256,22 @@ class InferenceEngine:
             t_end = clock()
         self.flight.add_emit(now, t_trace, t_report, t_detok, t_stream,
                              t_end)
+
+    def _stream_out(self, req: _Request, delta: str,
+                    finished: bool) -> None:
+        """A delta to the request's stream callback. One that returns
+        True only queued it (the API server's stream writer:
+        api/stream_writer.ChatStream.feed), and the recorder signals the
+        writer for it; any other was called for the token itself."""
+        try:
+            if req.stream_wants_count:
+                handed = req.stream(delta, finished, len(req.out_tokens))
+            else:
+                handed = req.stream(delta, finished)
+        except Exception:  # noqa: BLE001
+            log.exception("stream callback failed rid=%d", req.rid)
+            return
+        self.flight.add_stream(handed is True)
 
     def _incremental_text(self, req: _Request, final: bool = False) -> str:
         """The text that the tokens emitted since the last call

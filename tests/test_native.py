@@ -16,6 +16,32 @@ def test_native_library_builds():
     assert is_available(), "native library failed to build"
 
 
+def test_the_schedulers_calls_keep_the_interpreter_lock(tmp_path):
+    """One library, two handles (PR 54): the scheduler's calls, a row a
+    token on the engine thread and microseconds under the library's own
+    mutex, go through a PyDLL; the file mapping and prefetch of the
+    safetensors reader let the lock go (a CDLL)."""
+    import ctypes
+
+    from cake_tpu import native
+    from cake_tpu.native.safetensors import StFile
+
+    sched = make_scheduler(max_slots=2)
+    assert type(sched._lib) is ctypes.PyDLL
+    assert sched._lib is native.get_scheduler_library()
+    path, _tensors = _write_fixture(tmp_path)
+    f = StFile(path)
+    f.close()
+    assert type(f._lib) is ctypes.CDLL
+    assert f._lib is native.get_library()
+    assert sched._lib._name == f._lib._name
+    for name in ("submit", "cancel", "plan", "report", "queue_depth",
+                 "active", "completed"):
+        # declared on the handle that calls them, and on no other
+        assert getattr(sched._lib, f"cake_sched_{name}").argtypes
+        assert getattr(f._lib, f"cake_sched_{name}").argtypes is None
+
+
 # -- safetensors reader ------------------------------------------------------
 
 def _write_fixture(tmp_path):

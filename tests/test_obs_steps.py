@@ -602,6 +602,84 @@ def test_detok_ids_since_the_record_before(case):
         assert st.record("decode", wall_s=0.01).detok_ids is None
 
 
+@pytest.mark.parametrize("case", ["one_wake_a_span", "direct", "absent",
+                                  "outside_a_span", "kept"])
+def test_stream_counts_since_the_record_before(case):
+    """What `emit` did with its deltas (PR 54): those left for the API
+    server's stream writer (`stream_chunks`), the one signal a span
+    that left any (`stream_wakes`, sent where the span closes), those
+    handed to a per-token callback (`stream_direct`); and the series
+    that count them."""
+    from cake_tpu.obs import metrics as obs_metrics
+
+    def series():
+        chunks = obs_metrics.REGISTRY.get("cake_stream_chunks_total")
+        return (chunks.labels(path="writer").value,
+                chunks.labels(path="handler").value,
+                obs_metrics.REGISTRY.get(
+                    "cake_stream_writer_wakes_total").value)
+
+    st = obs_steps.StepTelemetry(impl="t")
+    woken = []
+    st.stream_wake = lambda: woken.append(st._open)
+    before = series()
+    if case == "one_wake_a_span":
+        with st.span("emit"):
+            for _row in range(3):
+                st.add_stream(True)
+            assert not woken              # never inside the rows' loop
+        assert woken == [None]            # once, after the span's clock
+        with st.span("emit"):             # a second emit, same record
+            st.add_stream(True)
+            st.add_stream(False)          # a stream given back
+        rec = st.record("decode", rows=3, tokens=3, wall_s=0.01)
+        assert (rec.stream_chunks, rec.stream_wakes,
+                rec.stream_direct) == (4, 2, 1)
+        d = rec.to_dict()
+        assert (d["stream_chunks"], d["stream_wakes"],
+                d["stream_direct"]) == (4, 2, 1)
+        assert len(woken) == 2
+        assert series() == (before[0] + 4, before[1] + 1, before[2] + 2)
+        # taken by the record: the next one starts from nothing
+        assert st.record("decode", wall_s=0.01).stream_chunks is None
+    elif case == "direct":
+        with st.span("emit"):
+            st.add_stream(False)
+            st.add_stream(False)
+        rec = st.record("decode", wall_s=0.01)
+        assert (rec.stream_chunks, rec.stream_wakes,
+                rec.stream_direct) == (None, None, 2)
+        assert not woken
+        assert series() == (before[0], before[1] + 2, before[2])
+    elif case == "absent":
+        with st.span("emit"):
+            pass                           # rows that stream nothing
+        d = st.record("decode", wall_s=0.01).to_dict()
+        assert not {"stream_chunks", "stream_wakes",
+                    "stream_direct"} & set(d)
+        assert not woken and series() == before
+    elif case == "outside_a_span":
+        # a speculative round, an adopted first token, a recovered
+        # row's flush: the writer is signalled at once, nothing counted
+        st.add_stream(True)
+        with st.span("admin"):
+            st.add_stream(True)
+            st.add_stream(False)
+        assert woken == [None, "admin"]
+        assert st.record("decode", wall_s=0.01).stream_chunks is None
+        st.stream_wake = None              # no server: nothing to call
+        st.add_stream(True)
+        with st.span("emit"):
+            st.add_stream(True)
+        assert st.record("decode", wall_s=0.01).stream_wakes == 1
+    else:
+        with st.span("emit"):
+            st.add_stream(True)
+        st.discard_open()                  # the idle loop: still sent
+        rec = st.record("decode", wall_s=0.01)
+        assert (rec.stream_chunks, rec.stream_wakes) == (1, 1)
+
+
 def test_chain_break_rides_the_next_unchained_record_only():
     breaks = m.REGISTRY.get("cake_chain_breaks_total")
     before = breaks.labels(cause="queue").value
@@ -831,7 +909,11 @@ def _clocks(monkeypatch):
 
 @pytest.mark.parametrize("name", NEW_PHASES + NEW_PARTS
                          + ("phase?", "part?"))
-def test_the_clock_vocabulary(name):
+def test_the_clock_vocabulary(monkeypatch, name):
+    # clocks that stand still: a span's own microseconds are 0 whatever
+    # else the machine runs (on the wall clock a loaded host read 1 ms
+    # inside the `emit.rows` case's span and failed it: PR 54)
+    _clocks(monkeypatch)
     st = obs_steps.StepTelemetry(impl="t")
     if name in NEW_PHASES:
         assert name in obs_steps.PHASES
