@@ -13,13 +13,25 @@ stored padding (640 kept for 576), so the need is a floor and a share
 of it cannot pass 100 %. At those widths a decode row's key costs 1.41
 ns by the bf16 peak and 1.41 ns by HBM on a v5e: the kernel sits on the
 ridge, and the need is the greater of the two.
+
+`L` is the number of LATENT layers, which is what a step record's
+`mla_keys_attended` sums over: every layer of a DeepSeek-V2 config; in a
+config with `layer_group_size` (bailing_hybrid: Ling-3.0) layer i is
+latent where (i + 1) mod `layer_group_size` = 0 and the others are KDA,
+2 of 12 in the Ling cell. At Ling's 32 heads a key costs 0.35 ns by the
+bf16 peak and the same 1.41 ns by HBM: bytes bound.
 """
 
 from __future__ import annotations
 
 
+def latent_layers(model_config: dict) -> int:
+    layers = model_config["num_hidden_layers"]
+    return layers // model_config.get("layer_group_size", 1)
+
+
 def dims(model_config: dict) -> dict:
-    return {"L": model_config["num_hidden_layers"],
+    return {"L": latent_layers(model_config),
             "H": model_config["num_attention_heads"],
             "row": (model_config["kv_lora_rank"]
                     + model_config["qk_rope_head_dim"]),

@@ -9,11 +9,47 @@ loop stopped), `t0`/`t1` (monotonic) and `wall_0`/`wall_1`
 (time.time()), `healthy_s`, `warmup_s`, `setup_s`, `model_config`,
 `server_args`, `cell`, `device`, and `trace` (the reduced trace, or
 None in an untraced run).
+
+A traced run's capture is read ONCE here for all the readers
+(`planes`), and `trace_spans.reduce_spans` is made once over it
+(`span_reduction`); both are kept on `run` under keys of their own.
 """
 
 from __future__ import annotations
 
+import os
+
+from . import spec, trace_spans
 from .e2e import median
+
+PLANES_KEY, SPANS_KEY = "_planes", "_span_reduction"
+
+
+def planes(run: dict):
+    """The capture's planes as `trace_spans.read_xspace` gives them, or
+    None where the run has no capture. Read once a run: the readers
+    share the list and leave it as it is."""
+    if PLANES_KEY not in run:
+        xplane = (run.get("trace") or {}).get("xplane")
+        run[PLANES_KEY] = (trace_spans.read_xspace(xplane)
+                           if xplane and os.path.isfile(xplane) else None)
+    return run[PLANES_KEY]
+
+
+def span_reduction(run: dict):
+    """`trace_spans.reduce_spans` over the run's capture, made once a
+    run, or None where there is no capture. The full tables go to
+    `benchmarks/.run/<cell>/trace_spans.json` and one `spans: {...}`
+    line to stderr, as `trace_spans.py` run by hand writes them."""
+    if SPANS_KEY not in run:
+        found = planes(run)
+        result = trace_spans.reduce_spans(found) if found else None
+        if result is not None and run.get("cell") is not None:
+            trace_spans.write(result, os.path.join(
+                spec.BENCH_DIR, ".run", run["cell"].name,
+                "trace_spans.json"))
+        run[SPANS_KEY] = result
+    return run[SPANS_KEY]
 
 
 def steps_of(run: dict, kind: str) -> list:

@@ -411,13 +411,8 @@ def reduce_spans(planes: list) -> dict:
     return out
 
 
-def main(argv) -> int:
-    src, dst = argv[1], argv[2]
-    xplane = tr.find_xplane(src)
-    if xplane is None:
-        print(f"no .xplane.pb under {src}", file=sys.stderr)
-        return 1
-    result = reduce_spans(read_xspace(xplane))
+def write(result: dict, dst: str) -> None:
+    """The full tables to `dst`, one `spans: {...}` line to stderr."""
     with open(dst, "w") as f:
         json.dump(result, f, indent=1)
     brief = {"scopes_s": {k: round(v, 4)
@@ -426,7 +421,16 @@ def main(argv) -> int:
              "kernels_s": {k: round(v, 4)
                            for k, v in result["kernels_s"].items()},
              "unnamed_custom_calls": result["unnamed_custom_calls"]}
-    print("spans: " + json.dumps(brief), file=sys.stderr)
+    print("spans: " + json.dumps(brief), file=sys.stderr, flush=True)
+
+
+def main(argv) -> int:
+    src, dst = argv[1], argv[2]
+    xplane = tr.find_xplane(src)
+    if xplane is None:
+        print(f"no .xplane.pb under {src}", file=sys.stderr)
+        return 1
+    write(reduce_spans(read_xspace(xplane)), dst)
     return 0
 
 

@@ -1,5 +1,4 @@
-"""Latent attention with the learned sparse indexer (glm_moe_dsa), and
-the long-document cell's own readings.
+"""Latent attention with the learned sparse indexer (glm_moe_dsa).
 
 - `dsa_selected_share_pct`: keys the indexer selected over keys visible,
   summed over query tokens and attention layers
@@ -29,22 +28,17 @@ the long-document cell's own readings.
   `dsa_keys_selected` and `dsa_rows_distinct`. The window kernel
   computes every VISIBLE key under a bias; only the SELECTED ones are
   needed, so its share reads low by design of the count.
-- `ttft_p50_ms.longdoc` (client TTFT, plain median, NOT judged: a 48 s
-  window holds a few dozen first tokens), `mixed_step_ms.longdoc`,
-  `mixed_step_device_ms.longdoc`: the readings `mixed_step_ms` and
-  `mixed_step_device_ms` give, under names that move `out_tok_s` (this
-  cell does not report `ttft_mean_ms`).
+
+The cell's mixed step and client TTFT are `window_steps.py`'s
+(`mixed_step_ms.tok`, `mixed_step_device_ms.tok`, `ttft_p50_ms.tok`).
 
 A program without the counters, the scopes or the kernel yields nothing
 for the metric concerned.
 """
 
-import os
-
-from harness import mla_roofline, trace_reduce as tr, trace_spans as ts
-from harness.e2e import median, ttft_samples
+from harness import mla_roofline, readers, trace_reduce as tr
+from harness import trace_spans as ts
 from harness.peaks import peaks
-from harness.readers import median_wall_ms
 from harness.server import metric_sum
 
 KERNEL, WINDOW_KERNEL = "cake_mla_attn", "cake_mla_window_attn"
@@ -65,13 +59,6 @@ METRICS = [
     {"name": "dev_share_mla_proj_pct", "unit": "%", "layer": PROGRAMS,
      "moves": "out_tok_s", "source": "device_trace"},
     {"name": "mla_attn_roofline", "unit": "%", "layer": KERNELS,
-     "moves": "out_tok_s", "source": "device_trace"},
-    {"name": "ttft_p50_ms.longdoc", "unit": "ms",
-     "layer": "scheduler and page allocator", "moves": "out_tok_s",
-     "source": "host_clock"},
-    {"name": "mixed_step_ms.longdoc", "unit": "ms", "layer": "step dispatch",
-     "moves": "out_tok_s", "source": "program_span"},
-    {"name": "mixed_step_device_ms.longdoc", "unit": "ms", "layer": PROGRAMS,
      "moves": "out_tok_s", "source": "device_trace"},
 ]
 
@@ -147,14 +134,10 @@ def attn_roofline(run):
 
 
 def from_trace(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
+    planes = readers.planes(run)
+    if not planes:
         return {}
-    planes = ts.read_xspace(xplane)
     out = {}
-    device_ms = ts.reduce_spans(planes)["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.longdoc"] = device_ms
     devices = sorted((p for p in planes if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -181,9 +164,4 @@ def read(run):
     out = counters(run)
     out.update(from_trace(run))
     out["mla_attn_roofline"] = attn_roofline(run)
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.longdoc"] = 1000.0 * median(first)
-    out["mixed_step_ms.longdoc"] = median_wall_ms(run, "mixed")
     return out

@@ -16,7 +16,11 @@ microseconds a token: a median of records reads 0.0 wherever most
 records lack the part. The emit of a step follows its record, so the
 two sums are one step apart at each end of the window. A program whose
 records name no such part (the parent: `emit.detok` alone) reports
-nothing."""
+nothing.
+
+The six parts are read at the seams of one span, so they add up to it
+and no metric reads the difference: a test over recorded step records
+holds it (`tests/test_retired_metrics.py`, over this file's `NAMED`)."""
 
 DISPATCH = "step dispatch"
 BY_PART = {"emit_trace_us_per_token": "emit.trace",
@@ -29,7 +33,7 @@ NAMED = (*BY_PART.values(), "emit.detok")
 METRICS = [
     {"name": name, "unit": "us", "layer": DISPATCH, "moves": "out_tok_s",
      "source": "program_span"}
-    for name in ("emit_us_per_token", *BY_PART, "emit_unnamed_us_per_token")
+    for name in ("emit_us_per_token", *BY_PART)
 ]
 
 
@@ -48,5 +52,4 @@ def read(run):
             for key in NAMED}
     out = {name: us(part[key]) for name, key in BY_PART.items()}
     out["emit_us_per_token"] = us(span)
-    out["emit_unnamed_us_per_token"] = us(span - sum(part.values()))
     return out

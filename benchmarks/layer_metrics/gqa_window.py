@@ -1,5 +1,4 @@
-"""GQA in two kinds of layer over K/V pages by kind (exaone_moe), and
-the K-EXAONE cell's own readings.
+"""GQA in two kinds of layer over K/V pages by kind (exaone_moe).
 
 - `gqa_window_attn_roofline`, `gqa_full_attn_roofline`: the device time
   of the two ragged paged attention kernels' events (device 0:
@@ -27,22 +26,21 @@ the K-EXAONE cell's own readings.
   walked a sliding layer (`cake_gqa_window_pages_walked_total` over
   `cake_gqa_rows_single_total` and the sliding layers): 2 at a window
   of one page that starts inside a page, whatever the context.
-- `ttft_p50_ms.kexaone` (client TTFT, plain median over both prompt
-  classes, NOT judged), `mixed_step_ms.kexaone`,
-  `mixed_step_device_ms.kexaone`.
+
+The cell's mixed step and client TTFT are `window_steps.py`'s
+(`mixed_step_ms.tok`, `mixed_step_device_ms.tok`, `ttft_p50_ms.tok`);
+its share of visible keys the sliding layers attend is `swa.py`'s
+`swa_attended_share_pct`.
 
 A program without the counters, the scopes or the kernels (the parent,
 another family) yields nothing for the metric concerned.
 """
 
 import bisect
-import os
 
 from harness import gqa_window_roofline as gw
-from harness import trace_reduce as tr, trace_spans as ts
-from harness.e2e import median, ttft_samples
+from harness import readers, trace_reduce as tr, trace_spans as ts
 from harness.peaks import peaks
-from harness.readers import median_wall_ms
 from harness.server import metric_sum
 
 KERNELS_BY_NAME = ("cake_decode_attn", "cake_mixed_attn")
@@ -52,7 +50,6 @@ ROOFLINES = {"gqa_window": "gqa_window_attn_roofline",
              "gqa_full": "gqa_full_attn_roofline"}
 FETCH_SPAN = ts.SPAN_PREFIX + "fetch"
 PROGRAMS, KERNELS = "step programs", "kernels"
-ALLOCATOR = "scheduler and page allocator"
 
 METRICS = [
     {"name": "gqa_window_attn_roofline", "unit": "%", "layer": KERNELS,
@@ -65,13 +62,6 @@ METRICS = [
      "moves": "out_tok_s", "source": "device_trace"},
     {"name": "gqa_window_pages_per_decode_row", "unit": "pages",
      "layer": KERNELS, "moves": "out_tok_s", "source": "program_counter"},
-    {"name": "ttft_p50_ms.kexaone", "unit": "ms", "layer": ALLOCATOR,
-     "moves": "out_tok_s", "source": "host_clock"},
-    {"name": "mixed_step_ms.kexaone", "unit": "ms",
-     "layer": "step dispatch", "moves": "out_tok_s",
-     "source": "program_span"},
-    {"name": "mixed_step_device_ms.kexaone", "unit": "ms",
-     "layer": PROGRAMS, "moves": "out_tok_s", "source": "device_trace"},
 ]
 
 
@@ -159,15 +149,11 @@ def rooflines(run, planes, scoped: list) -> dict:
 
 
 def from_trace(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if (not xplane or not os.path.isfile(xplane)
-            or not windowed_gqa(run["model_config"])):
+    planes = (readers.planes(run)
+              if windowed_gqa(run["model_config"]) else None)
+    if not planes:
         return {}
-    planes = ts.read_xspace(xplane)
     out = {}
-    device_ms = ts.reduce_spans(planes)["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.kexaone"] = device_ms
     devices = sorted((p for p in planes if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -195,11 +181,4 @@ def from_trace(run) -> dict:
 def read(run):
     out = counters(run)
     out.update(from_trace(run))
-    if not windowed_gqa(run["model_config"]):
-        return out
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.kexaone"] = 1000.0 * median(first)
-    out["mixed_step_ms.kexaone"] = median_wall_ms(run, "mixed")
     return out

@@ -5,11 +5,12 @@ waited, and `late`: true when that was no wait at all (under
 `obs/steps.LATE_FETCH_S`), so the device had finished the step before
 the host sent the one after it and sat idle under the chain, where no
 boundary shows it. `parts["emit.detok"]` is the detokenisation inside
-the `emit` span since the record before: a row's whole output decoded
-again for every token (`serve/engine._incremental_text`), hidden
-behind the device while the step is longer than the host's work on the
-one before it. A program whose records lack the fields reports
-nothing."""
+the `emit` span since the record before: since PR 47 a few ids a token
+through the request's `StreamDetokenizer`, whatever the output's length
+(`detok_window.py` counts them; before, a row's whole output decoded
+again for every token), hidden behind the device while the step is
+longer than the host's work on the one before it. A program whose
+records lack the fields reports nothing."""
 
 from harness.e2e import median
 

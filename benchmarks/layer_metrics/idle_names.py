@@ -28,7 +28,7 @@ import json
 import os
 import sys
 
-from harness import spec, trace_reduce as tr, trace_spans as ts
+from harness import readers, spec, trace_reduce as tr, trace_spans as ts
 
 DEVICE = "device"
 LONG_MS = 30.0
@@ -105,10 +105,10 @@ def long_gaps(idle: list, spans: dict) -> list:
 
 
 def read(run):
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
+    planes = readers.planes(run)
+    if not planes:
         return {}
-    idle, spans = idle_and_spans(ts.read_xspace(xplane))
+    idle, spans = idle_and_spans(planes)
     gaps = long_gaps(idle, spans)
     if run.get("cell") is not None:
         path = os.path.join(spec.BENCH_DIR, ".run", run["cell"].name,

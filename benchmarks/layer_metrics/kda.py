@@ -1,5 +1,4 @@
-"""Kimi Delta Attention beside the page pool (bailing_hybrid), and the
-long-reply cell's own readings.
+"""Kimi Delta Attention beside the page pool (bailing_hybrid).
 
 - `dev_share_kda_pct`: device self time under the scopes `kda_conv`,
   `kda_gate`, `kda_chunk`, `kda_step` and `kda_state` over busy device
@@ -27,22 +26,19 @@ long-reply cell's own readings.
   `..._stepped_total`); `kda_state_rows_per_step`: rows whose state a
   step touched (`cake_kda_state_rows_total` / KDA layers / steps): at
   most the rows busy, a guard that a row with no token costs nothing.
-- `ttft_p50_ms.longreply` (client TTFT, plain median, NOT judged),
-  `mixed_step_ms.longreply`, `mixed_step_device_ms.longreply`: the
-  readings `mixed_step_ms` and `mixed_step_device_ms` give, under names
-  that move `out_tok_s` (this cell does not report `ttft_mean_ms`).
+
+The cell's mixed step and client TTFT are `window_steps.py`'s
+(`mixed_step_ms.tok`, `mixed_step_device_ms.tok`, `ttft_p50_ms.tok`).
 
 A program without the counters, the scopes or the fetch spans yields
 nothing for the metric concerned.
 """
 
 import bisect
-import os
 
-from harness import kda_roofline, trace_reduce as tr, trace_spans as ts
-from harness.e2e import median, ttft_samples
+from harness import kda_roofline, readers, trace_reduce as tr
+from harness import trace_spans as ts
 from harness.peaks import peaks
-from harness.readers import median_wall_ms
 from harness.server import metric_sum
 
 KDA_SCOPES = ("kda_conv", "kda_gate", "kda_chunk", "kda_step", "kda_state")
@@ -66,14 +62,6 @@ METRICS = [
     {"name": "kda_state_rows_per_step", "unit": "rows",
      "layer": "scheduler and page allocator", "moves": "out_tok_s",
      "source": "program_counter"},
-    {"name": "ttft_p50_ms.longreply", "unit": "ms",
-     "layer": "scheduler and page allocator", "moves": "out_tok_s",
-     "source": "host_clock"},
-    {"name": "mixed_step_ms.longreply", "unit": "ms",
-     "layer": "step dispatch", "moves": "out_tok_s",
-     "source": "program_span"},
-    {"name": "mixed_step_device_ms.longreply", "unit": "ms",
-     "layer": PROGRAMS, "moves": "out_tok_s", "source": "device_trace"},
 ]
 
 
@@ -149,14 +137,10 @@ def rooflines(run, planes, scoped: list) -> dict:
 
 
 def from_trace(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
+    planes = readers.planes(run)
+    if not planes:
         return {}
-    planes = ts.read_xspace(xplane)
     out = {}
-    device_ms = ts.reduce_spans(planes)["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.longreply"] = device_ms
     devices = sorted((p for p in planes if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -187,9 +171,4 @@ def from_trace(run) -> dict:
 def read(run):
     out = counters(run)
     out.update(from_trace(run))
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.longreply"] = 1000.0 * median(first)
-    out["mixed_step_ms.longreply"] = median_wall_ms(run, "mixed")
     return out

@@ -1,5 +1,5 @@
 """Latent attention over every live page of a row, group-limited
-routing on a share (deepseek_v2), and the code cell's own readings.
+routing on a share (deepseek_v2; Ling-3.0's two latent layers join).
 
 - `mla_decode_attn_roofline`: the summed device time of the
   `cake_mla_decode_attn` events of the capture (device 0; one event is
@@ -32,11 +32,12 @@ routing on a share (deepseek_v2), and the code cell's own readings.
   (`cake_moe_tokens_group_held_total` against
   `cake_moe_rows_routed_total` / experts a token): 100 * topk_group /
   n_group = 37.5 under even routing.
-- `mixed_step_ms.code`, `mixed_step_device_ms.code`, `ttft_p50_ms.code`
-  (client TTFT, plain median, NOT judged: a 48 s window holds a few
-  dozen first tokens): the readings `mixed_step_ms`,
-  `mixed_step_device_ms` and a TTFT give, under names that move
-  `out_tok_s` (this cell does not report `ttft_mean_ms`).
+
+A record's keys are divided by the LATENT layers of the model
+(`mla_dense_roofline.dims`: all of DeepSeek-V2's, one in
+`layer_group_size` of Ling's). The cell's mixed step and client TTFT
+are `window_steps.py`'s (`mixed_step_ms.tok`,
+`mixed_step_device_ms.tok`, `ttft_p50_ms.tok`).
 
 A program without the counters, the scope or the kernels yields nothing
 for the metric concerned. The reader runs where `BENCHMARK.json` lists
@@ -44,13 +45,11 @@ one of its metrics for the cell, and nowhere else (`spec.Cell.per_layer`).
 """
 
 import bisect
-import os
 
 from harness import mla_dense_roofline as roof
-from harness import trace_reduce as tr, trace_spans as ts
-from harness.e2e import median, ttft_samples
+from harness import readers, trace_reduce as tr, trace_spans as ts
 from harness.peaks import peaks
-from harness.readers import median_wall_ms, mixed_step_rows
+from harness.readers import mixed_step_rows
 from harness.server import metric_sum
 
 DECODE_KERNEL, WINDOW_KERNEL = "cake_mla_decode_attn", "cake_mla_window_attn"
@@ -70,13 +69,6 @@ METRICS = [
      "moves": "tpot_p50_ms", "source": "program_counter"},
     {"name": "moe_group_held_share_pct", "unit": "%", "layer": PROGRAMS,
      "moves": "out_tok_s", "source": "program_counter"},
-    {"name": "mixed_step_ms.code", "unit": "ms", "layer": "step dispatch",
-     "moves": "out_tok_s", "source": "program_span"},
-    {"name": "mixed_step_device_ms.code", "unit": "ms", "layer": PROGRAMS,
-     "moves": "out_tok_s", "source": "device_trace"},
-    {"name": "ttft_p50_ms.code", "unit": "ms",
-     "layer": "scheduler and page allocator", "moves": "out_tok_s",
-     "source": "host_clock"},
 ]
 
 
@@ -203,18 +195,8 @@ def counters(run) -> dict:
     return out
 
 
-def load_planes(run):
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
-        return None
-    return ts.read_xspace(xplane)
-
-
 def from_trace(planes) -> dict:
     out = {}
-    device_ms = ts.reduce_spans(planes)["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.code"] = device_ms
     ops = device_ops(planes)
     if not ops:
         return out
@@ -232,14 +214,9 @@ def from_trace(planes) -> dict:
 
 def read(run):
     out = counters(run)
-    planes = load_planes(run)
+    planes = readers.planes(run)
     if planes:
         out.update(from_trace(planes))
         out["mla_decode_attn_roofline"] = decode_roofline(run, planes)
         out["mla_dense_window_roofline"] = window_roofline(run, planes)
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.code"] = 1000.0 * median(first)
-    out["mixed_step_ms.code"] = median_wall_ms(run, "mixed")
     return out

@@ -24,10 +24,10 @@ A program without the counters, the scopes or the kernel (a dense model,
 an older program) yields nothing for the metric concerned.
 """
 
-import os
 import re
 
-from harness import moe_roofline, trace_reduce as tr, trace_spans as ts
+from harness import moe_roofline, readers, trace_reduce as tr
+from harness import trace_spans as ts
 from harness.peaks import peaks
 from harness.server import metric_sum
 
@@ -116,10 +116,7 @@ def experts_roofline(run):
 
 
 def route_share(run):
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
-        return None
-    devices = sorted((p for p in ts.read_xspace(xplane)
+    devices = sorted((p for p in readers.planes(run) or ()
                       if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None

@@ -13,9 +13,7 @@
 Nothing in an untraced run; a program without the scope yields nothing.
 """
 
-import os
-
-from harness import trace_reduce as tr, trace_spans as ts
+from harness import readers, trace_reduce as tr, trace_spans as ts
 
 SCOPES = {"dev_share_sample_pct": ("sample",)}
 
@@ -47,7 +45,5 @@ def shares(planes: list) -> dict:
 
 
 def read(run):
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
-        return {}
-    return shares(ts.read_xspace(xplane))
+    planes = readers.planes(run)
+    return shares(planes) if planes else {}

@@ -1,5 +1,4 @@
-"""Recurrent blocks beside the page pool (nemotron_h), LatentMoE, and the
-agent cell's own readings.
+"""Recurrent blocks beside the page pool (nemotron_h) and LatentMoE.
 
 - `dev_share_ssm_pct`: device self time under the scopes `ssm_conv`,
   `ssm_scan`, `ssm_step`, `ssm_gate` and `ssm_state` over busy device
@@ -30,23 +29,19 @@ agent cell's own readings.
   `..._stepped_total`); `ssm_state_rows_per_step`: rows whose state a
   step touched (`cake_ssm_state_rows_total` / Mamba blocks / steps): at
   most the rows busy, a guard that a row with no token costs nothing.
-- `ttft_p50_ms.agent` (client TTFT, plain median, NOT judged),
-  `mixed_step_ms.agent`, `mixed_step_device_ms.agent`: the readings
-  `mixed_step_ms` and `mixed_step_device_ms` give, under names that move
-  `out_tok_s` (this cell does not report `ttft_mean_ms`).
+
+The cell's mixed step and client TTFT are `window_steps.py`'s
+(`mixed_step_ms.tok`, `mixed_step_device_ms.tok`, `ttft_p50_ms.tok`).
 
 A program without the counters, the scopes or the kernel yields nothing
 for the metric concerned.
 """
 
-import os
 import re
 
-from harness import (latent_moe_roofline, ssm_roofline, trace_reduce as tr,
-                     trace_spans as ts)
-from harness.e2e import median, ttft_samples
+from harness import (latent_moe_roofline, readers, ssm_roofline,
+                     trace_reduce as tr, trace_spans as ts)
 from harness.peaks import peaks
-from harness.readers import median_wall_ms
 from harness.server import metric_sum
 
 KERNEL = "cake_moe_gmm"
@@ -75,13 +70,6 @@ METRICS = [
     {"name": "ssm_state_rows_per_step", "unit": "rows",
      "layer": "scheduler and page allocator", "moves": "out_tok_s",
      "source": "program_counter"},
-    {"name": "ttft_p50_ms.agent", "unit": "ms",
-     "layer": "scheduler and page allocator", "moves": "out_tok_s",
-     "source": "host_clock"},
-    {"name": "mixed_step_ms.agent", "unit": "ms", "layer": "step dispatch",
-     "moves": "out_tok_s", "source": "program_span"},
-    {"name": "mixed_step_device_ms.agent", "unit": "ms", "layer": PROGRAMS,
-     "moves": "out_tok_s", "source": "device_trace"},
 ]
 
 
@@ -144,15 +132,11 @@ def executions(spans: dict) -> dict:
 
 
 def from_trace(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
+    planes = readers.planes(run)
+    if not planes:
         return {}
-    planes = ts.read_xspace(xplane)
-    spans = ts.reduce_spans(planes)
+    spans = readers.span_reduction(run)
     out = {}
-    device_ms = spans["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.agent"] = device_ms
     devices = sorted((p for p in planes if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -239,9 +223,4 @@ def read(run):
     out = counters(run)
     out.update(from_trace(run))
     out["latent_moe_experts_roofline"] = experts_roofline(run)
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.agent"] = 1000.0 * median(first)
-    out["mixed_step_ms.agent"] = median_wall_ms(run, "mixed")
     return out

@@ -34,19 +34,23 @@ long-and-short cell's own readings.
   `tpot_p50_ms`'s arithmetic, NOT judged in this cell: the median of
   ~100 requests' means falls on one of two levels, 82.2 or 84.1 ms,
   and a set of six runs spread 2.27 % against half its bound, 1.5 %:
-  my chip runs, PR 41), `mixed_step_ms.longshort`,
-  `mixed_step_device_ms.longshort`.
+  my chip runs, PR 41). These three are readings BY CLASS or of this
+  cell's own two levels; the cell's mixed step is `window_steps.py`'s
+  (`mixed_step_ms.tok`, `mixed_step_device_ms.tok`).
+
+`swa_attended_share_pct` is a counter any model with sliding-window
+layers emits (K-EXAONE's step programs too: its cell lists it); the
+scopes, kernels and rooflines are dots3_note's latent geometry, and a
+config without it (`windowed`) yields nothing for them.
 
 A program without the counters, the scopes or the kernels yields
 nothing for the metric concerned.
 """
 
-import os
-
-from harness import swa_roofline, trace_reduce as tr, trace_spans as ts
+from harness import readers, swa_roofline, trace_reduce as tr
+from harness import trace_spans as ts
 from harness.e2e import median, tpot_samples, ttft_samples
 from harness.peaks import peaks
-from harness.readers import median_wall_ms
 from harness.server import metric_sum
 
 SWA_KERNELS = ("cake_swa_attn", "cake_swa_window_attn")
@@ -75,11 +79,6 @@ METRICS = [
      "moves": "out_tok_s", "source": "host_clock"},
     {"name": "tpot_p50_ms.longshort", "unit": "ms", "layer": ALLOCATOR,
      "moves": "out_tok_s", "source": "host_clock"},
-    {"name": "mixed_step_ms.longshort", "unit": "ms",
-     "layer": "step dispatch", "moves": "out_tok_s",
-     "source": "program_span"},
-    {"name": "mixed_step_device_ms.longshort", "unit": "ms",
-     "layer": PROGRAMS, "moves": "out_tok_s", "source": "device_trace"},
 ]
 
 
@@ -97,7 +96,11 @@ def counters(run) -> dict:
 
 
 def windowed(model_config: dict) -> bool:
-    return "sliding_attention" in (model_config.get("layer_types") or ())
+    """Sliding-window LATENT layers, with the keys `swa_roofline.swa_dims`
+    reads: K-EXAONE's `layer_types` name `sliding_attention` too, over
+    plain GQA pages (`gqa_window.py` reads those)."""
+    return ("sliding_attention" in (model_config.get("layer_types") or ())
+            and "swa_num_attention_heads" in model_config)
 
 
 def need_per_dispatch(run, kind: str, which: str):
@@ -162,14 +165,10 @@ def kernels_roofline(run, names: tuple, which: str):
 
 
 def from_trace(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
+    planes = readers.planes(run)
+    if not planes or not windowed(run["model_config"]):
         return {}
-    planes = ts.read_xspace(xplane)
     out = {}
-    device_ms = ts.reduce_spans(planes)["metrics"].get("mixed_step_device_ms")
-    if device_ms is not None:
-        out["mixed_step_device_ms.longshort"] = device_ms
     devices = sorted((p for p in planes if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -202,5 +201,4 @@ def read(run):
     per_token = tpot_samples(run["records"], run["t0"], run["t1"])
     if per_token:
         out["tpot_p50_ms.longshort"] = 1000.0 * median(per_token)
-    out["mixed_step_ms.longshort"] = median_wall_ms(run, "mixed")
     return out

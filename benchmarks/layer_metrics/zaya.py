@@ -1,5 +1,5 @@
 """Compressed convolutional attention beside the page pool, the MLP
-router and top-1 experts (zaya), and the reasoning cell's own readings.
+router and top-1 experts (zaya).
 
 - `dev_share_cca_mix_pct`: device self time under the scope `cca_mix`
   (the convolutions, the q-k mean, the value shift, the norm and the
@@ -18,26 +18,23 @@ router and top-1 experts (zaya), and the reasoning cell's own readings.
 - `moe_experts_touched_per_layer`: distinct experts a decode step
   touches a layer, of the config's `num_experts`
   (`moe_experts_touched` of the window's decode records over layers).
-- `ttft_p50_ms.reason`: client TTFT, plain median, NOT judged (the cell
-  does not report `ttft_mean_ms`).
-- `mixed_step_ms.reason`: the reading `mixed_step_ms` gives, under a
-  name that moves `out_tok_s` (this cell does not report
-  `ttft_mean_ms`): the one packed size makes a dispatch with one
-  prefilling row pay a second window's padding, and this is where that
-  cost reads.
+
+The cell's mixed step and client TTFT are `window_steps.py`'s
+(`mixed_step_ms.tok`, `ttft_p50_ms.tok`): the one packed size makes a
+dispatch with one prefilling row pay a second window's padding, and
+`mixed_step_ms.tok` is where that cost reads.
 
 A program without the counters, the scopes or the kernel, or a config
 without `moe_intermediate_size`, yields nothing for the metric
 concerned.
 """
 
-import os
 import re
 
-from harness import trace_reduce as tr, trace_spans as ts, zaya_roofline
-from harness.e2e import median, ttft_samples
+from harness import readers, trace_reduce as tr, trace_spans as ts
+from harness import zaya_roofline
 from harness.peaks import peaks
-from harness.readers import median_wall_ms, steps_of
+from harness.readers import steps_of
 
 KERNEL = "cake_moe_gmm"
 SCOPES = {"dev_share_cca_mix_pct": ("cca_mix",),
@@ -54,11 +51,6 @@ METRICS = [
      "moves": "out_tok_s", "source": "device_trace"},
     {"name": "moe_experts_touched_per_layer", "unit": "experts",
      "layer": PROGRAMS, "moves": "out_tok_s", "source": "program_counter"},
-    {"name": "ttft_p50_ms.reason", "unit": "ms",
-     "layer": "scheduler and page allocator", "moves": "out_tok_s",
-     "source": "host_clock"},
-    {"name": "mixed_step_ms.reason", "unit": "ms", "layer": "step dispatch",
-     "moves": "out_tok_s", "source": "program_span"},
 ]
 
 
@@ -81,10 +73,7 @@ def touched_per_layer(run):
 
 
 def scope_shares(run) -> dict:
-    xplane = (run.get("trace") or {}).get("xplane")
-    if not xplane or not os.path.isfile(xplane):
-        return {}
-    devices = sorted((p for p in ts.read_xspace(xplane)
+    devices = sorted((p for p in readers.planes(run) or ()
                       if tr.is_device_plane(p["name"])),
                      key=lambda p: p["name"])
     ops = tr._line(devices[0], (ts.OPS_LINE,)) if devices else None
@@ -151,9 +140,4 @@ def read(run):
     out = scope_shares(run)
     out["top1_moe_experts_roofline"] = experts_roofline(run)
     out["moe_experts_touched_per_layer"] = touched_per_layer(run)
-    first = [x for v in ttft_samples(run["records"], run["t0"],
-                                     run["t1"]).values() for x in v]
-    if first:
-        out["ttft_p50_ms.reason"] = 1000.0 * median(first)
-    out["mixed_step_ms.reason"] = median_wall_ms(run, "mixed")
     return out

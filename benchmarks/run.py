@@ -44,6 +44,7 @@ PROBE_TOLERANCE = 2e-2     # first-token top-5 logprobs, batched vs alone
 PROBE_ALONE_OUT = 16       # tokens of the probe when served alone
 TRACE_SECONDS = 3.0        # of the steady window, through /api/v1/profile
 TRACE_AT_S = 2.0           # after the window opens
+REDUCE_LIMIT_S = 900       # harness/trace_reduce.py over one capture
 
 
 class RunFailure(Exception):
@@ -172,11 +173,16 @@ def reduce_trace(profile_dir: str, run_dir: str) -> dict:
     chip; held to the CPU so it cannot take it either."""
     out_path = os.path.join(run_dir, "trace_reduced.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(spec.BENCH_DIR, "harness", "trace_reduce.py"),
-         profile_dir, out_path],
-        env=env, capture_output=True, text=True, timeout=900)
+    try:
+        proc = subprocess.run(
+            [sys.executable,
+             os.path.join(spec.BENCH_DIR, "harness", "trace_reduce.py"),
+             profile_dir, out_path],
+            env=env, capture_output=True, text=True, timeout=REDUCE_LIMIT_S)
+    except subprocess.TimeoutExpired:
+        raise RunFailure("trace reduction (harness/trace_reduce.py over "
+                         f"{profile_dir}) passed its limit of "
+                         f"{REDUCE_LIMIT_S} s") from None
     if proc.returncode != 0:
         raise RunFailure("trace reduction failed: " + proc.stderr[-2000:])
     return spec.load_json(out_path)
@@ -361,7 +367,9 @@ def main(argv=None) -> int:
         if args.trace:
             if prof.get("status") != 200:
                 raise RunFailure(f"POST /api/v1/profile failed: {prof}")
+            t_reduce = time.monotonic()
             run["trace"] = reduce_trace(profile_dir, run_dir)
+            t_read = time.monotonic()
             if not run["trace"]["busy_s"] > 0 and not args.rehearse:
                 raise RunFailure("the trace shows no operation on a device")
             device["busy_s"] = run["trace"]["busy_s"]
@@ -371,6 +379,11 @@ def main(argv=None) -> int:
                 "idle_gaps": run["trace"]["idle_gaps"]}
             line["metrics"] = spec.read_layer_metrics(cell, run, found, say)
             shutil.rmtree(profile_dir, ignore_errors=True)
+            # the capture's cost to the run: the reduction's process,
+            # then the readers (one more read of it, shared)
+            line["trace_reduce_s"] = time.monotonic() - t_reduce
+            say(f"trace: reduced in {t_read - t_reduce:.1f} s, layer "
+                f"metrics read in {time.monotonic() - t_read:.1f} s")
         else:
             values = e2e.end_to_end(cell.names("end_to_end"), records, t0,
                                     t1, cell.traffic, setup_s)

@@ -6,14 +6,27 @@ import json
 import os
 import re
 
+import pytest
+
 from harness import spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# what `reduced` may never name: a hidden, intermediate, latent, state
+# or projection size, a key that ends in `_dim` or `_rank`, a head size,
+# an expansion factor, the experts a token. A COUNT OF LAYERS is a
+# depth, which every cut configuration lists (`num_hidden_layers` has
+# `hidden` in it and is no width)
 WIDTHS = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|"
-                    r"head_dim|expansion|experts_per_tok)")
+                    r"head_dim|head_size|expand|expansion|experts_per_tok)")
+DEPTHS = re.compile(r"^(num|n)_[a-z0-9_]*layers?$")
+PER_LAYER_CAP, END_TO_END_CAP, CELLS_CAP = 128, 16, 24
+
+
+def is_width(key: str) -> bool:
+    return bool(WIDTHS.search(key)) and not DEPTHS.match(key)
 
 
 def doc():
@@ -53,7 +66,7 @@ def test_configs():
                           dict)
         assert len(c["reduced"]) <= 16
         for key in c["reduced"]:
-            assert NAME.match(key) and not WIDTHS.search(key), key
+            assert NAME.match(key) and not is_width(key), key
         cell = spec.load_json(os.path.join(
             os.path.dirname(os.path.join(spec.ROOT, c["file"])), "cell.json"))
         assert cell["source"] == c["source"]
@@ -61,9 +74,26 @@ def test_configs():
     assert len({c["name"] for c in d["configs"]}) == len(d["configs"])
 
 
+@pytest.mark.parametrize("key, width", [
+    ("num_hidden_layers", False), ("num_nextn_predict_layers", False),
+    ("n_layers", False), ("vocab_size", False), ("eos_token_id", False),
+    ("n_routed_experts", False), ("num_experts", False),
+    ("layer_types", False), ("hybrid_override_pattern", False),
+    ("first_k_dense_replace", False), ("sliding_windows", False),
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("moe_latent_size", True),
+    ("ssm_state_size", True), ("mamba_d_state", True),
+    ("kv_lora_rank", True), ("q_lora_rank", True), ("head_dim", True),
+    ("qk_rope_head_dim", True), ("index_head_dim", True),
+    ("mamba_proj_bias", True), ("expand", True), ("mamba_expand", True),
+    ("num_experts_per_tok", True), ("hidden_layers_size", True)])
+def test_a_depth_passes_and_a_width_is_refused(key, width):
+    assert is_width(key) is width
+
+
 def test_workloads():
     d = doc()
-    assert 1 <= len(d["workloads"]) <= 24
+    assert 1 <= len(d["workloads"]) <= CELLS_CAP
     pairs = set()
     for w in d["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -81,7 +111,8 @@ def test_metrics():
     cells = {w["name"] for w in d["workloads"]}
     names = [m["name"] for m in d["end_to_end"] + d["per_layer"]]
     assert len(names) == len(set(names))
-    assert 1 <= len(d["end_to_end"]) <= 16 and 1 <= len(d["per_layer"]) <= 128
+    assert 1 <= len(d["end_to_end"]) <= END_TO_END_CAP
+    assert 1 <= len(d["per_layer"]) <= PER_LAYER_CAP
     for m in d["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}

@@ -10,9 +10,12 @@ import pytest
 from harness import spec
 
 NAME = "decode_attn_pages_live_pct"
+# the cells whose decode rows run `cake_decode_attn`, as of PR 55 (a
+# later cell appends itself)
 CELLS = ["mistral7b.chat-closed", "mistral7b.decode-long",
          "olmoe7b.chat-closed", "nemotron3s.agent-closed",
-         "zaya1.reason-closed"]
+         "zaya1.reason-closed", "dsv2.code-closed",
+         "ling3.longreply-closed", "kexaone.longreply-closed"]
 
 
 def step(kind, pages=None, table=None):
@@ -47,7 +50,8 @@ def test_share_of_the_table_that_holds_work():
 def test_the_metric_is_found_by_name_in_its_cells():
     doc = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
     (entry,) = [m for m in doc["per_layer"] if m["name"] == NAME]
-    assert entry["better"] == "higher" and entry["workloads"] == CELLS
+    assert entry["better"] == "higher"
+    assert entry["workloads"][:len(CELLS)] == CELLS
     for name in CELLS:
         cell = spec.Cell(name)
         assert NAME in cell.names("per_layer")

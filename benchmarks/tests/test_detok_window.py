@@ -54,7 +54,6 @@ def test_the_metric_is_found_by_name_in_every_cell():
     entry = next(m for m in doc["per_layer"] if m["name"] == NAME)
     assert entry["better"] == "lower" and entry["unit"] == "ids"
     assert entry["workloads"] == [w["name"] for w in doc["workloads"]]
-    assert len(entry["workloads"]) == 9
     for name in entry["workloads"]:
         cell = spec.Cell(name)
         assert NAME in cell.names("per_layer")

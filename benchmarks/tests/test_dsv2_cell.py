@@ -41,8 +41,13 @@ REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size",
            "eos_token_id"]
 NEW = ["mla_decode_attn_roofline", "mla_dense_window_roofline",
        "dev_share_mla_attn_pct", "mla_keys_per_decode_row",
-       "moe_group_held_share_pct", "mixed_step_ms.code",
-       "mixed_step_device_ms.code", "ttft_p50_ms.code"]
+       "moe_group_held_share_pct"]
+# the plain readings of a cell judged by tokens, joined by list (until
+# PR 55 this reader's three `.code` names)
+JOINED = {"mixed_step_ms.tok", "mixed_step_device_ms.tok",
+          "ttft_p50_ms.tok"}
+# Ling-3.0's two latent layers run the same kernels and counters
+ALSO = "ling3.longreply-closed"
 
 
 def load_reader(bench_dir=spec.BENCH_DIR):
@@ -152,15 +157,15 @@ def test_cell_reports_what_the_issue_lists():
     assert set(cell.names("end_to_end")) == {"tpot_p50_ms", "out_tok_s",
                                              "setup_s"}
     layers = set(cell.names("per_layer"))
-    assert set(NEW) <= layers
+    assert set(NEW) | JOINED <= layers
     for name in ("decode_attn_pages_live_pct",
                  "moe_held_rows_share_pct", "dev_share_mla_proj_pct",
                  "dev_share_moe_route_pct", "moe_rows_padded_pct",
                  "moe_expert_load_max_over_mean", "mixed_steps_chained_pct",
                  "boundary_admit_p50_ms", "rows_busy_pct",
                  "pages_in_use_pct", "mixed_step_share_pct",
-                 "step_gap_p50_ms", "host_emit_p50_ms",
-                 "host_schedule_p50_ms", "loop_covered_pct",
+                 "host_build_p50_ms", "host_emit_p50_ms",
+                 "loop_uncovered_pct",
                  "dev_share_attn_pct", "dev_share_ffn_pct",
                  "idle_attributed_pct", "decode_steps_chained_pct",
                  "chain_breaks_per_s", "boundary_gap_p50_ms",
@@ -178,7 +183,7 @@ def test_cell_reports_what_the_issue_lists():
                  "mla_attn_roofline",
                  "decode_step_roofline", "dsa_selected_share_pct",
                  "dsa_index_reuse_pct", "dev_share_indexer_pct",
-                 "swa_attn_roofline", "ttft_p50_ms.longdoc",
+                 "swa_attn_roofline", "step_gap_p50_ms",
                  "mixed_step_ms", "mixed_step_device_ms",
                  "queue_wait_p50_ms", "prefill_rows_per_mixed_step"):
         assert name not in layers, name
@@ -195,7 +200,7 @@ def test_reader_agrees_with_benchmark_json():
     at = names.index(NEW[0])
     assert names[at:at + len(NEW)] == NEW
     for name, m in entries.items():
-        assert m["workloads"] == [CELL]
+        assert m["workloads"] == [CELL, ALSO]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         for key in ("unit", "layer", "moves", "source"):
@@ -255,7 +260,10 @@ def test_the_cell_is_found_by_name_in_a_copy(tmp_path):
     got = spec.read_layer_metrics(cell, fake_run(cell=cell), found)
     assert got["moe_group_held_share_pct"] == {"value": 37.5, "unit": "%"}
     assert got["mla_keys_per_decode_row"]["value"] == pytest.approx(4200.0)
+    assert got["ttft_p50_ms.tok"]["value"] == pytest.approx(2250.0)
+    assert got["mixed_step_ms.tok"]["value"] == pytest.approx(100.0)
     assert "mla_decode_attn_roofline" not in got          # no capture
+    assert "mixed_step_device_ms.tok" not in got
     # an old cell does not report the new metrics
     old = spec.Cell("glm52.longdoc-closed", str(bench),
                     str(tmp_path / "BENCHMARK.json"))
@@ -341,25 +349,24 @@ def test_counters_and_the_clients_clock():
     # 3,000 token-layers held of 8,000 routed: 3 of 8 groups a token
     assert got["moe_group_held_share_pct"] == pytest.approx(37.5)
     assert got["mla_keys_per_decode_row"] == pytest.approx(4200.0)
-    # TTFTs 1.0 .. 3.0 s and one of 3.0: the plain median
-    assert got["ttft_p50_ms.code"] == pytest.approx(2250.0)
-    assert got["mixed_step_ms.code"] == pytest.approx(100.0)
     # no capture: nothing of the device
     for name in ("mla_decode_attn_roofline", "mla_dense_window_roofline",
-                 "dev_share_mla_attn_pct", "mixed_step_device_ms.code"):
+                 "dev_share_mla_attn_pct"):
         assert name not in got
+    assert not [k for k in got if k.startswith(("mixed_step", "ttft_"))]
 
 
 def test_another_program_yields_nothing():
-    """The `workloads` lists are the gate: no other cell lists a metric
-    of this file, so its reader never runs there (GLM's and dots3's
+    """The `workloads` lists are the gate: no other cell but Ling's
+    (two latent layers of the same kind) lists a metric of this file,
+    so its reader never runs there (GLM's and dots3's
     cake_mla_window_attn events are not read as this model's windows);
     and this model's config on a program without the counters or the
     kernels grows nothing."""
     names = {m["name"] for m in load_reader().METRICS}
     doc = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
     for w in doc["workloads"]:
-        if w["name"] != CELL:
+        if w["name"] not in (CELL, ALSO):
             assert not names & {m["name"]
                                 for m in spec.Cell(w["name"]).per_layer}
     run = fake_run(steps=[{"kind": "decode", "compiled": False, "rows": 32,

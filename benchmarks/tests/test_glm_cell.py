@@ -77,7 +77,9 @@ def test_benchmark_json_entry_matches_the_cell_file():
     assert entry["reduced"] == cell.cell["reduced"]
     assert entry["source"] == cell.cell["source"]
     assert entry["file"] == f"benchmarks/configs/{CONFIG}/config.json"
-    assert doc["workloads"][-1]["name"] == CELL
+    (work,) = [w for w in doc["workloads"] if w["name"] == CELL]
+    assert (work["config"], work["traffic"], work["chips"]) == (
+        CONFIG, "longdoc-closed", 1)
 
 
 def test_traffic_is_the_stated_cycle():
@@ -110,11 +112,14 @@ def test_cell_reports_what_the_issue_lists():
                                              "setup_s"}
     layers = set(cell.names("per_layer"))
     new = {m["name"] for m in load_reader().METRICS}
-    assert new <= layers and len(new) == 9
+    assert new <= layers and len(new) == 6
+    # the plain readings of a cell judged by tokens, joined by list
+    assert {"mixed_step_ms.tok", "mixed_step_device_ms.tok",
+            "ttft_p50_ms.tok"} <= layers
     for name in ("rows_busy_pct", "pages_in_use_pct", "mixed_step_share_pct",
                  "decode_steps_chained_pct", "dev_share_moe_route_pct",
                  "moe_rows_padded_pct", "moe_expert_load_max_over_mean",
-                 "step_gap_p50_ms", "decode_step_device_ms", "peak_hbm_gib"):
+                 "host_emit_p50_ms", "decode_step_device_ms", "peak_hbm_gib"):
         assert name in layers, name
     for name in ("mixed_step_ms", "mixed_step_device_ms",
                  "mixed_attn_roofline", "moe_experts_roofline",
@@ -130,7 +135,8 @@ def test_reader_agrees_with_benchmark_json():
                if m["name"] in declared}
     assert set(entries) == set(declared)
     for name, m in entries.items():
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
+        # later cells join a metric by list; this one stays on it
+        assert CELL in m["workloads"] and m["moves"] == "out_tok_s"
         for key in ("unit", "layer", "moves", "source"):
             assert declared[name][key] == m[key], (name, key)
 
@@ -223,7 +229,7 @@ def test_counters_over_the_window():
     assert got["moe_held_rows_share_pct"] == pytest.approx(6.25)
     assert got["mla_attn_roofline"] is None
     assert "dev_share_indexer_pct" not in got
-    assert got["mixed_step_ms.longdoc"] == pytest.approx(180.0)
+    assert not [k for k in got if k.startswith(("mixed_step", "ttft_"))]
 
 
 def test_a_program_without_the_counters_yields_nothing():

@@ -39,10 +39,9 @@ def test_share_of_the_windows_decode_steps_that_were_chained():
 
 def test_the_metric_is_found_by_name_and_belongs_to_every_cell():
     doc = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
-    entry = doc["per_layer"][-1]
-    assert entry["name"] == NAME and entry["better"] == "higher"
+    (entry,) = [m for m in doc["per_layer"] if m["name"] == NAME]
+    assert entry["better"] == "higher"
     assert entry["workloads"] == [w["name"] for w in doc["workloads"]]
-    assert len(entry["workloads"]) == 4
     for w in doc["workloads"]:
         cell = spec.Cell(w["name"])
         assert NAME in cell.names("per_layer"), w["name"]

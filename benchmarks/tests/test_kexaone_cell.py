@@ -37,8 +37,12 @@ REDUCED = ["num_hidden_layers", "layer_types", "mlp_layer_types",
            "num_nextn_predict_layers", "eos_token_id"]
 NEW = ["gqa_window_attn_roofline", "gqa_full_attn_roofline",
        "dev_share_gqa_window_pct", "dev_share_gqa_full_pct",
-       "gqa_window_pages_per_decode_row", "ttft_p50_ms.kexaone",
-       "mixed_step_ms.kexaone", "mixed_step_device_ms.kexaone"]
+       "gqa_window_pages_per_decode_row"]
+# the plain readings of a cell judged by tokens, joined by list (until
+# PR 55 this reader's three `.kexaone` names), and dots3's counter of
+# the sliding layers' keys, which this model's step programs emit too
+JOINED = {"mixed_step_ms.tok", "mixed_step_device_ms.tok",
+          "ttft_p50_ms.tok", "swa_attended_share_pct"}
 
 
 def load_reader(bench_dir=spec.BENCH_DIR):
@@ -150,33 +154,30 @@ def test_cell_reports_what_the_issue_lists():
     assert set(cell.names("end_to_end")) == {"tpot_p50_ms", "out_tok_s",
                                              "setup_s"}
     layers = set(cell.names("per_layer"))
-    assert set(NEW) <= layers
+    assert set(NEW) | JOINED <= layers
     for name in ("decode_step_device_ms", "decode_attn_pages_live_pct",
                  "moe_held_rows_share_pct",
                  "dev_share_moe_route_pct", "moe_rows_padded_pct",
                  "moe_expert_load_max_over_mean", "mixed_steps_chained_pct",
                  "boundary_admit_p50_ms", "rows_busy_pct",
                  "pages_in_use_pct", "mixed_step_share_pct",
-                 "step_gap_p50_ms", "host_emit_p50_ms",
-                 "loop_covered_pct", "dev_share_attn_pct",
+                 "host_build_p50_ms", "host_emit_p50_ms",
+                 "loop_uncovered_pct", "dev_share_attn_pct",
                  "dev_share_ffn_pct", "idle_attributed_pct",
                  "decode_steps_chained_pct", "chain_breaks_per_s",
                  "dev_share_sample_pct", "detok_ids_per_token",
                  "peak_hbm_gib", "compiles_in_window", "decode_step_ms"):
         assert name in layers, name
     # none whose `moves` the cell does not report, none of another
-    # cell's own
-    # NOT swa_attended_share_pct, though the counters are dots3's: in a
-    # traced run its reader (layer_metrics/swa.py) takes any config with
-    # sliding_attention layers for dots3_note's and raises on the
-    # missing `swa_*` keys, so the line would lack it (PERF.md section 7)
-    for name in ("swa_attended_share_pct",
-                 "moe_experts_roofline", "decode_attn_roofline",
+    # cell's own (swa_attended_share_pct joined at PR 55: swa.py's
+    # `windowed()` asks for dots3_note's `swa_*` keys before it reads
+    # that geometry, so this config costs the reader nothing)
+    for name in ("moe_experts_roofline", "decode_attn_roofline",
                  "mixed_attn_roofline", "decode_step_roofline",
                  "swa_attn_roofline", "dsa_full_attn_roofline",
                  "dev_share_swa_attn_pct", "mla_window_pages_per_fold",
                  "dev_share_mla_proj_pct", "moe_group_held_share_pct",
-                 "kda_step_roofline", "ttft_p50_ms.longreply",
+                 "kda_step_roofline", "step_gap_p50_ms",
                  "mixed_step_ms", "mixed_step_device_ms"):
         assert name not in layers, name
 
@@ -293,9 +294,36 @@ def test_counters_and_the_clients_clock():
     got = load_reader().read(fake_run())
     # 18,000 pages over 1,000 rows and 9 sliding layers
     assert got["gqa_window_pages_per_decode_row"] == pytest.approx(2.0)
-    assert got["mixed_step_ms.kexaone"] == pytest.approx(60.0)
-    assert got["ttft_p50_ms.kexaone"] == pytest.approx(700.0)
     assert "gqa_window_attn_roofline" not in got          # no capture
+    assert not [k for k in got if k.startswith(("mixed_step", "ttft_"))]
+
+
+def test_the_sliding_layers_counter_reads_through_dots3s_reader():
+    """`swa_attended_share_pct` (joined at PR 55) is `swa.py`'s: this
+    config has `sliding_attention` layers and none of dots3_note's
+    `swa_*` keys, and a traced run's kernel events must not take the
+    reader's whole output with a KeyError."""
+    path = os.path.join(spec.BENCH_DIR, "layer_metrics", "swa.py")
+    s = importlib.util.spec_from_file_location("layer_metric_swa", path)
+    swa = importlib.util.module_from_spec(s)
+    s.loader.exec_module(swa)
+    assert "sliding_attention" in cfg()["layer_types"]
+    assert not swa.windowed(cfg())
+    assert swa.windowed(spec.Cell("dots3.longshort-closed").model_config)
+    run = fake_run()
+    run["metrics_0"] = {"cake_swa_keys_visible_total": 1000.0,
+                        "cake_swa_keys_attended_total": 500.0}
+    run["metrics_1"] = {"cake_swa_keys_visible_total": 101000.0,
+                        "cake_swa_keys_attended_total": 4340.0}
+    run["trace"] = {"xplane": "/nonexistent.xplane.pb", "kernels": [
+        {"device": 0, "dur_s": 1e-4,
+         "name": "%cake_decode_attn.1 = bf16[32,64,128]{2,1,0} "
+                 "custom-call(...)"}]}
+    got = swa.read(run)
+    assert got["swa_attended_share_pct"] == pytest.approx(3.84)
+    assert got["swa_attn_roofline"] is None
+    assert got["dsa_full_attn_roofline"] is None
+    assert not [k for k in got if k.startswith("dev_share_")]
 
 
 def test_another_program_yields_nothing():
@@ -358,6 +386,8 @@ def test_the_cell_is_found_by_name_in_a_copy(tmp_path):
     got = spec.read_layer_metrics(cell, fake_run(cell=cell), found)
     assert got["gqa_window_pages_per_decode_row"] == {"value": 2.0,
                                                       "unit": "pages"}
+    assert got["mixed_step_ms.tok"]["value"] == pytest.approx(60.0)
+    assert got["ttft_p50_ms.tok"]["value"] == pytest.approx(700.0)
     # an old cell does not report the new metrics
     old = spec.Cell("ling3.longreply-closed", str(bench),
                     str(tmp_path / "BENCHMARK.json"))

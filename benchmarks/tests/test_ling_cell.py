@@ -50,8 +50,11 @@ REDUCED = ["num_hidden_layers", "num_experts", "vocab_size",
            "expert_swiglu_limit_list", "share_expert_swiglu_limit_list"]
 NEW = ["dev_share_kda_pct", "dev_share_kda_proj_pct", "kda_step_roofline",
        "kda_chunk_roofline", "kda_chunked_share_pct",
-       "kda_state_rows_per_step", "ttft_p50_ms.longreply",
-       "mixed_step_ms.longreply", "mixed_step_device_ms.longreply"]
+       "kda_state_rows_per_step"]
+# the plain readings of a cell judged by tokens, joined by list (until
+# PR 55 this reader's three `.longreply` names)
+JOINED = {"mixed_step_ms.tok", "mixed_step_device_ms.tok",
+          "ttft_p50_ms.tok"}
 
 
 def load_reader(bench_dir=spec.BENCH_DIR):
@@ -144,7 +147,6 @@ def test_benchmark_json_entries_match_the_cells_files():
     entry = next(c for c in doc["configs"] if c["name"] == CONFIG)
     work = next(w for w in doc["workloads"] if w["name"] == CELL)
     cell = spec.Cell(CELL)
-    assert doc["configs"][-1] is entry and doc["workloads"][-1] is work
     assert entry["reduced"] == cell.cell["reduced"]
     assert entry["source"] == cell.cell["source"]
     assert entry["file"] == f"benchmarks/configs/{CONFIG}/config.json"
@@ -166,17 +168,18 @@ def test_cell_reports_what_the_issue_lists():
     assert set(cell.names("end_to_end")) == {"tpot_p50_ms", "out_tok_s",
                                              "setup_s"}
     layers = set(cell.names("per_layer"))
-    assert set(NEW) <= layers
+    assert set(NEW) | JOINED <= layers
     for name in ("decode_step_device_ms", "decode_attn_pages_live_pct",
                  "moe_held_rows_share_pct", "moe_group_held_share_pct",
                  "dev_share_mla_proj_pct", "dev_share_mla_attn_pct",
                  "mla_dense_window_roofline", "mla_window_pages_per_fold",
+                 "mla_decode_attn_roofline", "mla_keys_per_decode_row",
                  "dev_share_moe_route_pct", "moe_rows_padded_pct",
                  "moe_expert_load_max_over_mean", "mixed_steps_chained_pct",
                  "boundary_admit_p50_ms", "rows_busy_pct",
                  "pages_in_use_pct", "mixed_step_share_pct",
-                 "step_gap_p50_ms", "host_emit_p50_ms",
-                 "host_schedule_p50_ms", "loop_covered_pct",
+                 "host_build_p50_ms", "host_emit_p50_ms",
+                 "loop_uncovered_pct",
                  "dev_share_attn_pct", "dev_share_ffn_pct",
                  "idle_attributed_pct", "decode_steps_chained_pct",
                  "chain_breaks_per_s", "boundary_gap_p50_ms",
@@ -185,16 +188,14 @@ def test_cell_reports_what_the_issue_lists():
                  "peak_hbm_gib", "compiles_in_window", "decode_step_ms"):
         assert name in layers, name
     # none whose `moves` the cell does not report, none of another
-    # cell's own. NOT mla_decode_attn_roofline nor mla_keys_per_decode_
-    # row: mla_dense.py divides a record's keys by num_hidden_layers,
-    # and 2 of this model's 12 layers are latent (PERF.md section 7);
-    # not moe_experts_roofline (moe_dims would read the DENSE layers'
-    # 6,144 as an expert's width)
-    for name in ("mla_decode_attn_roofline", "mla_keys_per_decode_row",
-                 "moe_experts_roofline", "mla_attn_roofline",
+    # cell's own. mla_decode_attn_roofline and mla_keys_per_decode_row
+    # joined at PR 55: mla_dense.py divides a record's keys by the
+    # LATENT layers (2 of this model's 12); not moe_experts_roofline
+    # (moe_dims would read the DENSE layers' 6,144 as an expert's width)
+    for name in ("moe_experts_roofline", "mla_attn_roofline",
                  "decode_step_roofline", "ssm_step_roofline",
                  "dsa_selected_share_pct", "dev_share_indexer_pct",
-                 "mixed_step_ms.code", "ttft_p50_ms.agent", "mixed_step_ms",
+                 "step_gap_p50_ms", "loop_covered_pct", "mixed_step_ms",
                  "mixed_step_device_ms", "queue_wait_p50_ms",
                  "prefill_rows_per_mixed_step"):
         assert name not in layers, name
@@ -205,8 +206,9 @@ def test_reader_agrees_with_benchmark_json():
     declared = {m["name"]: m for m in load_reader().METRICS}
     assert list(declared) == NEW
     names = [m["name"] for m in doc["per_layer"]]
-    assert names[-len(NEW):] == NEW                   # appended, in order
-    for m in doc["per_layer"][-len(NEW):]:
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW                      # in order
+    for m in doc["per_layer"][at:at + len(NEW)]:
         assert m["workloads"] == [CELL]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
@@ -275,7 +277,10 @@ def test_the_cell_is_found_by_name_in_a_copy(tmp_path):
     got = spec.read_layer_metrics(cell, fake_run(cell=cell), found)
     assert got["kda_chunked_share_pct"] == {"value": 80.0, "unit": "%"}
     assert got["kda_state_rows_per_step"]["value"] == pytest.approx(31.0)
+    assert got["ttft_p50_ms.tok"]["value"] == pytest.approx(2000.0)
+    assert got["mixed_step_ms.tok"]["value"] == pytest.approx(50.0)
     assert "kda_step_roofline" not in got                 # no capture
+    assert "mixed_step_device_ms.tok" not in got
     # an old cell does not report the new metrics
     old = spec.Cell("nemotron3s.agent-closed", str(bench),
                     str(tmp_path / "BENCHMARK.json"))
@@ -360,12 +365,43 @@ def test_counters_and_the_clients_clock():
     assert got["kda_chunked_share_pct"] == pytest.approx(80.0)
     # 1,240 (row, layer) pairs over 10 layers and 4 steps
     assert got["kda_state_rows_per_step"] == pytest.approx(31.0)
-    # TTFTs 1.0 .. 3.0 s: the plain median
-    assert got["ttft_p50_ms.longreply"] == pytest.approx(2000.0)
-    assert got["mixed_step_ms.longreply"] == pytest.approx(50.0)
     for name in ("kda_step_roofline", "kda_chunk_roofline",
-                 "dev_share_kda_pct", "mixed_step_device_ms.longreply"):
+                 "dev_share_kda_pct"):
         assert name not in got
+    assert not [k for k in got if k.startswith(("mixed_step", "ttft_"))]
+
+
+def test_the_latent_layers_metrics_divide_by_the_latent_layers():
+    """`mla_decode_attn_roofline` and `mla_keys_per_decode_row` (joined
+    at PR 55) read a record's `mla_keys_attended`, which this family
+    sums over its LATENT layers: 2 of 12 ((i + 1) mod `layer_group_size`
+    = 0), not `num_hidden_layers`; a config without the key (DeepSeek-
+    V2) keeps every layer."""
+    from harness import mla_dense_roofline
+    c = cfg()
+    assert (c["num_hidden_layers"], c["layer_group_size"]) == (12, 6)
+    assert mla_dense_roofline.dims(c) == {"L": 2, "H": 32, "row": 576,
+                                          "value": 512}
+    dsv2 = spec.Cell("dsv2.code-closed").model_config
+    assert "layer_group_size" not in dsv2
+    assert mla_dense_roofline.dims(dsv2)["L"] == dsv2["num_hidden_layers"]
+    # 32 heads: a key's 69,632 operations take 0.35 ns at the bf16 peak
+    # and its 1,152 bytes 1.41 ns at the HBM rate: bound by bytes
+    assert mla_dense_roofline.ops_per_pair(c) == 32 * (576 + 512) * 2
+    assert mla_dense_roofline.least_s(c, 1e6, 1e6, PEAK) == pytest.approx(
+        1e6 * 1152 / 819e9)
+    # a decode record of 32 rows at 6,000 keys in each of the 2 layers
+    path = os.path.join(spec.BENCH_DIR, "layer_metrics", "mla_dense.py")
+    s = importlib.util.spec_from_file_location("layer_metric_mla_dense", path)
+    mla_dense = importlib.util.module_from_spec(s)
+    s.loader.exec_module(mla_dense)
+    decode = {"kind": "decode", "compiled": False, "wall_s": 0.02,
+              "rows": 32, "attn_pages_table": 32 * 76,
+              "mla_keys_attended": 2 * 32 * 6000.0}
+    run = fake_run(steps=[decode] * 3, server_args={
+        "max-slots": 32, "max-seq-len": 9728, "kv-page-size": 128})
+    assert mla_dense.counters(run)["mla_keys_per_decode_row"] == \
+        pytest.approx(6000.0)
 
 
 def test_another_program_yields_nothing():

@@ -13,8 +13,7 @@ from harness import spec
 
 EMIT = ["emit_us_per_token", "emit_trace_us_per_token",
         "emit_report_us_per_token", "emit_stream_us_per_token",
-        "emit_retire_us_per_token", "emit_rows_us_per_token",
-        "emit_unnamed_us_per_token"]
+        "emit_retire_us_per_token", "emit_rows_us_per_token"]
 CLOCK = ["loop_uncovered_pct", "host_offcpu_pct", "gc_pause_share_pct",
          "gc_pause_max_ms", "host_pause_max_ms", "host_room_p50_ms"]
 IDLE = ["idle_unnamed_pct", "idle_gc_pct"]
@@ -120,12 +119,9 @@ def test_emit_parts_are_window_sums_over_the_tokens(found):
         1e6 * 0.0012 / tokens)
     assert got["emit_rows_us_per_token"] == pytest.approx(
         1e6 * (10 * 0.0016 + 0.0001) / tokens)
-    # the named parts and what is left make the span
-    named = sum(got[n] for n in EMIT[1:6]) + 1e6 * 10 * 0.0004 / tokens
-    assert named + got["emit_unnamed_us_per_token"] == pytest.approx(
-        got["emit_us_per_token"])
-    assert got["emit_unnamed_us_per_token"] == pytest.approx(
-        1e6 * (9 * 0.0004 + 0.0007 + 0.0001) / tokens)
+    # no name for what the parts leave of the span (PR 55: in a real
+    # record they leave nothing, `test_retired_metrics.py`)
+    assert set(got) == set(EMIT)
     # where most records lack a part a median reads 0.0; the sum does not
     assert got["emit_retire_us_per_token"] > 0
 

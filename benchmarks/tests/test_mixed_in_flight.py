@@ -10,8 +10,13 @@ import pytest
 from harness import spec
 
 NAME = "mixed_steps_chained_pct"
+# every cell on a paged engine, as of PR 55: the four-chip cell's dense
+# engine has no mixed step (a later cell appends itself)
 CELLS = ["mistral7b.chat-closed", "mistral7b.decode-long",
-         "olmoe7b.chat-closed", "glm52.longdoc-closed"]
+         "olmoe7b.chat-closed", "glm52.longdoc-closed",
+         "nemotron3s.agent-closed", "zaya1.reason-closed",
+         "dots3.longshort-closed", "dsv2.code-closed",
+         "ling3.longreply-closed", "kexaone.longreply-closed"]
 
 
 def step(kind, chained=None):
@@ -44,7 +49,9 @@ def test_share_of_the_windows_mixed_steps_that_were_chained():
 def test_the_metric_is_found_by_name_in_its_cells():
     doc = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
     (entry,) = [m for m in doc["per_layer"] if m["name"] == NAME]
-    assert entry["better"] == "higher" and entry["workloads"] == CELLS
+    assert entry["better"] == "higher"
+    assert entry["workloads"][:len(CELLS)] == CELLS
+    assert "qwen32b.chat-closed-4chip" not in entry["workloads"]
     for name in CELLS:
         cell = spec.Cell(name)
         assert NAME in cell.names("per_layer")

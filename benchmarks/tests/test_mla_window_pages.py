@@ -10,8 +10,9 @@ import pytest
 from harness import spec
 
 NAME = "mla_window_pages_per_fold"
+# the latent families' cells, as of PR 55 (a later cell appends itself)
 CELLS = ["glm52.longdoc-closed", "dots3.longshort-closed",
-         "dsv2.code-closed"]
+         "dsv2.code-closed", "ling3.longreply-closed"]
 
 
 def step(kind, pages=None, folds=None):
@@ -46,9 +47,9 @@ def test_pages_a_softmax_update():
 
 def test_the_metric_is_found_by_name_in_its_cells():
     doc = spec.load_json(os.path.join(spec.ROOT, "BENCHMARK.json"))
-    assert doc["per_layer"][-1]["name"] == NAME
-    entry = doc["per_layer"][-1]
-    assert entry["better"] == "higher" and entry["workloads"] == CELLS
+    (entry,) = [m for m in doc["per_layer"] if m["name"] == NAME]
+    assert entry["better"] == "higher"
+    assert entry["workloads"][:len(CELLS)] == CELLS
     for name in CELLS:
         cell = spec.Cell(name)
         assert NAME in cell.names("per_layer")
