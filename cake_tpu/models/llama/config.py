@@ -59,6 +59,12 @@ MODEL_TYPES = {
                   "full layers with no positions on the allocator's "
                   "pages; q and k normed a head; sigmoid-routed experts "
                   "with a shared one (paged engine)",
+    "granitemoehybrid": "a mixer AND a dense SwiGLU a layer, each branch "
+                        "scaled into the stream: Mamba-2 with a recurrent "
+                        "state a row beside the page pool, or GQA without "
+                        "positions at a softmax scale of its own; embedding, "
+                        "residual and logit multipliers; a tied head (paged "
+                        "engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -95,6 +101,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "exaone_moe":
         from cake_tpu.models.moe.config import ExaoneMoeConfig
         return ExaoneMoeConfig.from_hf_dict(raw)
+    if model_type == "granitemoehybrid":
+        from cake_tpu.models.moe.config import GraniteHybridConfig
+        return GraniteHybridConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

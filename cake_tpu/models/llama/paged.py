@@ -584,7 +584,8 @@ def write_token_rows(pool, layer, rows, slot, position, valid, table,
 
 
 def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
-                window: Optional[int] = None):
+                window: Optional[int] = None,
+                scale: Optional[float] = None):
     """The XLA reference both paged attentions share: a fori_loop over
     all max_pages, every page gathered once from `pool[layer]` and
     folded into running (m, l, o) stats. causal_bound: [B, C] — the
@@ -593,7 +594,8 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
     of a row is then the logical page first + j, first the page of its
     first query's first key, read through table entry (first + j) mod
     max_pages, so that `table` may be a ring (the kernels' rule:
-    ops/ragged_paged_attention._mixed_fold)."""
+    ops/ragged_paged_attention._mixed_fold). scale: what the scores are
+    multiplied by (None: 1/sqrt(hd))."""
     B, C, H, hd = q.shape
     _, N, P, width = getattr(pool_k, "q", pool_k).shape
     KV = width // hd
@@ -641,7 +643,7 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
                      & (slots_abs > causal_bound[:, :, None] - window))
         valid &= (pages >= 0)[:, None, None]
         valid = valid[:, None, None, :, :]           # [B,1,1,C,P]
-        mj, lj, oj = partial_attention_stats(q, kj, vj, valid)
+        mj, lj, oj = partial_attention_stats(q, kj, vj, valid, scale=scale)
         m_new = jnp.maximum(m, mj)
         a_old = jnp.exp(m - m_new)
         a_new = jnp.exp(mj - m_new)
@@ -664,7 +666,8 @@ def _kernel_pools(pool_k, pool_v):
 
 
 def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
-                    impl: str = "fold", window: Optional[int] = None):
+                    impl: str = "fold", window: Optional[int] = None,
+                    scale: Optional[float] = None):
     """Ragged decode attention over layer `layer` of the paged KV.
 
     impl="fold" (the documented REFERENCE semantics): an XLA fori_loop
@@ -685,7 +688,9 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     [B, max_pages]; pos: [B] (position of the CURRENT token).
     window (static): a row attends its last `window` keys alone, its
     own included, and `table` may be a ring [B, R] (the kernel walks
-    the pages of the band; None: every key).
+    the pages of the band; None: every key). scale (static): what the
+    scores are multiplied by, for a model that states its own (None:
+    1/sqrt(hd)).
     Returns [B, 1, H, hd].
     """
     if impl == "pallas":
@@ -694,17 +699,19 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
         )
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
         return ragged_paged_attention(q, kq, vq, layer, table, pos,
-                                      window=window, **kw)
+                                      window=window, scale=scale, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
     return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None],
-                       window)
+                       window, scale)
 
 
-@_partial(jax.jit, static_argnames=("packed4", "interpret", "window"))
+@_partial(jax.jit,
+          static_argnames=("packed4", "interpret", "window", "scale"))
 def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
                   interpret: bool, scale_k=None, scale_v=None,
-                  packed4: bool = False, window: Optional[int] = None):
+                  packed4: bool = False, window: Optional[int] = None,
+                  scale: Optional[float] = None):
     """The mixed kernel behind a jit of its own. The mixed step's
     programs of every packed size call it on the same window shapes,
     and a jitted callee is traced once for all of them: the kernel's
@@ -716,13 +723,14 @@ def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
     )
     return ragged_paged_attention_mixed(
         q, pool_k, pool_v, layer, table, pos, q_len, scale_k=scale_k,
-        scale_v=scale_v, packed4=packed4, window=window,
+        scale_v=scale_v, packed4=packed4, window=window, scale=scale,
         interpret=interpret)
 
 
 def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
                           impl: str = "fold",
-                          window: Optional[int] = None):
+                          window: Optional[int] = None,
+                          scale: Optional[float] = None):
     """Mixed ragged attention over layer `layer` of the paged KV: decode
     rows (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page
     offset) in ONE batch.
@@ -742,20 +750,21 @@ def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
     q_len: [B] real query tokens (0 = idle row). Columns past q_len are
     padding whose output the caller never reads. window (static):
     query i attends its last `window` keys alone, and `table` may be a
-    ring (paged_attention). Returns [B, C, H, hd].
+    ring; scale (static): the scores' multiplier (both as
+    paged_attention's). Returns [B, C, H, hd].
     """
     if impl == "pallas":
         from cake_tpu.ops import ragged_paged_attention as rpa
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
         return _mixed_kernel(q, kq, vq, layer, table, pos, q_len,
                              interpret=not rpa._on_tpu(), window=window,
-                             **kw)
+                             scale=scale, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
     # per-query causality: query i of row b sits at pos[b] + i
     C = q.shape[1]
     return _fold_pages(q, pool_k, pool_v, layer, table,
-                       pos[:, None] + jnp.arange(C)[None, :], window)
+                       pos[:, None] + jnp.arange(C)[None, :], window, scale)
 
 
 # -- model-level steps (engine step-fn signatures) ----------------------------

@@ -1309,3 +1309,159 @@ class ZayaConfig(MoEConfig):
         )
         base.update(overrides)
         return cls(**base)
+
+
+# config.json keys of model_type granitemoehybrid whose one served value
+# is refused by the key's name otherwise
+_GRANITE_ONLY = {
+    "position_embedding_type": "nope", "hidden_act": "silu",
+    "normalization_function": "rmsnorm", "mamba_proj_bias": False,
+    "attention_bias": False, "mamba_conv_bias": True,
+    "tie_word_embeddings": True,
+}
+_GRANITE_KINDS = ("mamba", "attention")
+
+
+@dataclass(frozen=True)
+class GraniteHybridConfig(MoEConfig):
+    """Granite-4.0-H (`model_type: granitemoehybrid`), its dense
+    members: every layer is a mixer AND a dense SwiGLU of
+    `shared_intermediate_size`, each behind an RMS norm, each branch
+    scaled by `residual_multiplier` on its way into the stream. The
+    mixer is named by `layer_types`: `mamba`, a Mamba-2 mixer (a
+    per-ROW recurrent state beside the page pool:
+    models/llama/paged.HybridPagedCache), or `attention`, GQA without a
+    positional embedding whose softmax scale is `attention_multiplier`,
+    not 1/sqrt(head_dim). The embedding is multiplied by
+    `embedding_multiplier`, the logits divided by `logits_scaling`; the
+    head is the embedding transposed. The four scalars are static
+    floats of the config. The equations are in
+    models/reference/granite_hybrid.py; the served path in
+    models/moe/granite_hybrid.py.
+
+    The Mamba fields carry the published names; the properties under
+    them are the names models/moe/nemotron_h.mamba_block reads (the
+    mixer is that one, called as it is). `num_local_experts` is 0 (the
+    family's sparse siblings are refused), so `is_moe` says by itself
+    that the seeded draw and the trunk live under models/moe."""
+
+    _family = "cake_tpu.models.moe.granite_hybrid:FAMILY"
+
+    layer_types: Tuple[str, ...] = ()
+    shared_intermediate_size: int = 8192
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_n_groups: int = 1
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+
+    is_moe = True
+
+    @property
+    def mamba_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "mamba")
+
+    @property
+    def attn_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "attention")
+
+    mamba_num_heads = property(lambda self: self.mamba_n_heads)
+    mamba_head_dim = property(lambda self: self.mamba_d_head)
+    n_groups = property(lambda self: self.mamba_n_groups)
+    ssm_state_size = property(lambda self: self.mamba_d_state)
+    conv_kernel = property(lambda self: self.mamba_d_conv)
+    chunk_size = property(lambda self: self.mamba_chunk_size)
+    d_inner = NemotronHConfig.d_inner
+    conv_dim = NemotronHConfig.conv_dim
+    in_proj_dim = NemotronHConfig.in_proj_dim
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "GraniteHybridConfig":
+        L = raw["num_hidden_layers"]
+        if raw.get("num_local_experts", 0) or raw.get(
+                "num_experts_per_tok", 0):
+            raise ValueError(
+                f"num_local_experts = {raw.get('num_local_experts')!r}, "
+                f"num_experts_per_tok = {raw.get('num_experts_per_tok')!r}: "
+                "model_type granitemoehybrid is served with a dense SwiGLU "
+                "in every layer (num_local_experts 0); its sparse siblings "
+                "are not implemented")
+        for name, want in _GRANITE_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type granitemoehybrid "
+                    f"is served with {name} = {want!r} only")
+        types = tuple(raw.get("layer_types") or ())
+        unknown = sorted(set(types) - set(_GRANITE_KINDS))
+        if len(types) != L or unknown:
+            raise ValueError(
+                f"layer_types must name num_hidden_layers = {L} layers, "
+                "each `mamba` or `attention`; got " + (
+                    ", ".join(unknown) if unknown else f"{len(types)} "
+                    "entries"))
+        if raw.get("rope_scaling"):
+            raise ValueError(
+                "rope_scaling is not implemented for model_type "
+                "granitemoehybrid (position_embedding_type nope: its "
+                "attention layers have no positional embedding)")
+        heads, d_head = raw["mamba_n_heads"], raw["mamba_d_head"]
+        if raw.get("mamba_expand", 2) * raw["hidden_size"] != heads * d_head:
+            raise ValueError(
+                f"mamba_expand {raw.get('mamba_expand', 2)} x hidden_size "
+                f"{raw['hidden_size']} != mamba_n_heads {heads} x "
+                f"mamba_d_head {d_head}")
+        if heads % raw.get("mamba_n_groups", 1):
+            raise ValueError("mamba_n_groups must divide mamba_n_heads")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, eos_token_id=raw.get("eos_token_id", raw["vocab_size"])))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields["chat_template"] = "chatml"
+        return cls(
+            **fields, num_local_experts=0, num_experts_per_tok=0,
+            hf_layout="granitemoehybrid", layer_types=types,
+            shared_intermediate_size=raw["shared_intermediate_size"],
+            embedding_multiplier=float(raw.get("embedding_multiplier", 1.0)),
+            attention_multiplier=float(raw.get(
+                "attention_multiplier", base.head_dim ** -0.5)),
+            residual_multiplier=float(raw.get("residual_multiplier", 1.0)),
+            logits_scaling=float(raw.get("logits_scaling", 1.0)),
+            mamba_n_heads=heads, mamba_d_head=d_head,
+            mamba_n_groups=raw.get("mamba_n_groups", 1),
+            mamba_d_state=raw["mamba_d_state"],
+            mamba_d_conv=raw.get("mamba_d_conv", 4),
+            mamba_expand=raw.get("mamba_expand", 2),
+            mamba_chunk_size=raw.get("mamba_chunk_size", 256),
+        )
+
+    @classmethod
+    def tiny_granite(cls, **overrides) -> "GraniteHybridConfig":
+        """Granite-4.0-H's layer at a test's size: both kinds of mixer,
+        4 Mamba heads of 16 in ONE group, state 16, chunk 8, 4 query
+        heads over 2 of 16 with a softmax scale that is NOT
+        1/sqrt(head_dim), the four multipliers away from 1."""
+        base = dict(
+            vocab_size=512, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=5, num_attention_heads=4,
+            num_key_value_heads=2, rms_norm_eps=1e-5, rope_theta=10000.0,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(512,), tie_word_embeddings=True,
+            chat_template="chatml",
+            num_local_experts=0, num_experts_per_tok=0,
+            hf_layout="granitemoehybrid",
+            layer_types=("mamba", "mamba", "attention", "mamba", "mamba"),
+            shared_intermediate_size=96, embedding_multiplier=12.0,
+            attention_multiplier=1.0 / 16, residual_multiplier=0.22,
+            logits_scaling=8.0, mamba_n_heads=8, mamba_d_head=16,
+            mamba_n_groups=1, mamba_d_state=16, mamba_d_conv=4,
+            mamba_expand=2, mamba_chunk_size=8,
+        )
+        base.update(overrides)
+        return cls(**base)

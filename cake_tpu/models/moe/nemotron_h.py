@@ -338,9 +338,14 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
 
 def attention_block(lp, h, pool_k, pool_v, j: int, table, slot, position,
                     real, rows: Rows, config: NemotronHConfig, attn: str,
-                    window: Optional[Window]):
+                    window: Optional[Window],
+                    scale: Optional[float] = None,
+                    subwindow: int = ATTN_SUBWINDOW):
     """h [T, D] -> (out [T, D], pool_k, pool_v): attention block j of
-    the K/V pool. No positional embedding."""
+    the K/V pool. No positional embedding. scale: the scores'
+    multiplier where the model states its own (None: 1/sqrt(hd));
+    subwindow: queries a sub-window of the mixed kernel holds (what
+    models/moe/granite_hybrid.py passes for its heads of 64)."""
     c = config
     T = h.shape[0]
     H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -355,12 +360,13 @@ def attention_block(lp, h, pool_k, pool_v, j: int, table, slot, position,
         last_pos = table.shape[1] * pool_k.shape[2] - 1
         pos = jnp.clip(rows.pos, 0, last_pos)
         out = paged.paged_attention(q[at][:, None], pool_k, pool_v, layer,
-                                    table, pos, impl=attn)[:, 0]
+                                    table, pos, impl=attn,
+                                    scale=scale)[:, 0]
         if window is None:
             o = out[slot]
         else:
             C = window.width
-            sub = min(C, ATTN_SUBWINDOW)
+            sub = min(C, subwindow)
             n_sub = C // sub
             starts = jnp.arange(n_sub, dtype=jnp.int32) * sub
             win = paged.paged_attention_mixed(
@@ -369,7 +375,8 @@ def attention_block(lp, h, pool_k, pool_v, j: int, table, slot, position,
                 jnp.broadcast_to(table[window.row][None],
                                  (n_sub, table.shape[1])),
                 jnp.minimum(pos[window.row] + starts, last_pos),
-                jnp.clip(window.n - starts, 0, sub), impl=attn)
+                jnp.clip(window.n - starts, 0, sub), impl=attn,
+                scale=scale)
             o = jnp.where(window.member[:, None, None],
                           win.reshape(C, H, hd)[window.col], out[slot])
     with jax.named_scope("o_proj"):

@@ -457,6 +457,63 @@ def _init_zaya_params(config, rng: jax.Array, dtype, bits: Optional[int]):
             "final_norm": near((D,), 1.0), "lm_head": head}
 
 
+# Mamba-2's published init of dt, which model_type granitemoehybrid
+# inherits (its config has no time_step_* keys): log-uniform in
+# [min, max], floored
+_GRANITE_DT = (0.001, 0.1, 1e-4)
+
+
+def _init_granite_params(config, rng: jax.Array, dtype,
+                         bits: Optional[int]):
+    """The seeded tree of a GraniteHybridConfig. The norms and the dense
+    SwiGLU (models/llama's leaves) are stacked [L, ...], the Mamba
+    leaves [L_M, ...] and the attention leaves [L_attn, ...] as
+    _init_nemotron_params stacks them, with the same draws of `A_log`,
+    `dt_bias` (dt in _GRANITE_DT) and `D`; they, the conv and the norms
+    stay float under bits=8. The head is TIED: the embedding transposed,
+    quantized where the matmul leaves are (as ZAYA's)."""
+    from cake_tpu.ops.quant import quantize
+
+    c = config
+    L, D, F = c.num_hidden_layers, c.hidden_size, c.shared_intermediate_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    Lm, La = len(c.mamba_layers), len(c.attn_layers)
+    Hm, di, K = c.mamba_num_heads, c.d_inner, c.conv_kernel
+    w, mat, keys = _draws(rng, dtype, bits, 24)
+
+    def near(shape, centre, spread=0.1):
+        return (centre + spread * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    lo, hi, floor = _GRANITE_DT
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        next(keys), (Lm, Hm), jnp.float32, np.log(lo), np.log(hi))), floor)
+    blocks = {
+        "norm": near((L, D), 1.0),
+        "mlp_norm": near((L, D), 1.0),
+        "w_gate": mat("w_gate", (L, D, F), D),
+        "w_up": mat("w_up", (L, D, F), D),
+        "w_down": mat("w_down", (L, F, D), F),
+        "w_in": mat("w_in", (Lm, D, c.in_proj_dim), D),
+        "conv_w": w((Lm, K, c.conv_dim), K),
+        "conv_b": near((Lm, c.conv_dim), 0.0),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(
+            next(keys), (Lm, Hm), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((Lm, Hm), jnp.float32),
+        "ssm_norm": near((Lm, di), 1.0),
+        "w_out": mat("w_out", (Lm, di, D), di),
+        "wq": mat("wq", (La, D, H * hd), D),
+        "wk": mat("wk", (La, D, KV * hd), D),
+        "wv": mat("wv", (La, D, KV * hd), D),
+        "wo": mat("wo", (La, H * hd, D), H * hd),
+    }
+    embed = w((c.vocab_size, D), D)
+    return {"embed": embed, "blocks": blocks,
+            "final_norm": near((D,), 1.0),
+            "lm_head": quantize(embed.T, (0,)) if bits else embed.T}
+
+
 def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
                 bits: Optional[int] = None):
     """Random-init MoE parameter pytree (tests, benchmarks, a model
@@ -476,6 +533,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
         return _init_exaone_params(config, rng, dtype, bits)
     if getattr(config, "kv_lora_rank", None):
         return _init_glm_params(config, rng, dtype, bits)
+    if config.hf_layout == "granitemoehybrid":
+        return _init_granite_params(config, rng, dtype, bits)
     if getattr(config, "mamba_layers", None):
         return _init_nemotron_params(config, rng, dtype, bits)
     if getattr(config, "cca_time0", None):

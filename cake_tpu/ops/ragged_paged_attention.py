@@ -933,8 +933,11 @@ def ragged_paged_supported(page_size: int, H: int, KV: int, hd: int,
 def mixed_scratch_bytes(H: int, hd: int, q_width: int) -> int:
     """f32 VMEM scratch the mixed kernel allocates per grid cell: the
     [KV*C*G, hd] accumulator plus two [KV*C*G, 128] m/l buffers, and
-    KV*G == H."""
-    return 4 * q_width * H * (hd + 256)
+    KV*G == H. A head narrower than a lane tile pads the accumulator's
+    rows to 128 lanes (the compiler at H=32, hd=64, C=256, PR 56:
+    "Scoped allocation with size 16.88M", 2.4 MiB over the unpadded
+    count)."""
+    return 4 * q_width * H * (max(hd, 128) + 256)
 
 
 def mixed_vmem_bytes(page_size: int, H: int, KV: int, hd: int,

@@ -425,6 +425,54 @@ EXAONE_START, EXAONE_EDGE, EXAONE_LAST = 8, 3, 128
 EXAONE_JOBS = ((0, 8100), (1, 2000), (1, 1950))
 EXAONE_DECODE = 24
 
+# granitemoehybrid: 40 dense layers, no discrete choice anywhere, so no
+# floor and no teacher forcing: the reference runs free. Limits, each
+# read where its fault shows, each between the worst the served path
+# read on the chip over seeds 0 / 1 / 2 and the LEAST an altered
+# reference that it has to catch read there (PERF.md section 6, PR 56):
+# `mean` and `max`, |error| / range of the logits over the compared
+# positions (bf16 activations through 40 layers and 80 residual
+# branches; a dropped multiplier or the gate behind the norm move both
+# by twenty times and more, a softmax scale of 1/sqrt(hd) `max` by
+# eight); `mean_edge`, the mean over the 3 positions behind the first
+# window edge, whose conv reads the stored tail; `attn`, BOTH attention
+# kernels at the model's own scale on the chip against exact attention
+# over the K and V pages the served path wrote (granite_probe), relative:
+# the scale shows there at full size (0.50 against 1.7e-3) where
+# attention over seeded keys at 1/64 is near uniform and four layers of
+# forty barely move a logit; `reuse`, a slot's second request against
+# the same request in a slot nothing has used (the served path against
+# itself: no floor); `state_slow`, the FIRST Mamba layer's 16 slowest
+# heads' carried state at a request's end against the reference's S_t,
+# relative: a guard on the recurrence (a dropped multiplier reads
+# 3.8e-2) that CANNOT tell a bfloat16 state (7.2e-3) from the served
+# one (5.0e-3), because each token's contribution is rounded to
+# bfloat16 on its way in and a sum of them keeps that relative error;
+# so the state's precision is held where it shows without a floor:
+# `state_f32_share` FROM BELOW, the share of the carried state's
+# entries (first Mamba layer, every job) whose float32 pattern has a bit
+# set under bfloat16's mantissa: ~1 for a float32 state, 0 for one
+# rounded to bfloat16 every token (read on the altered reference's own
+# S_t), beside `state_dtype`. `state` (every head of every layer, the
+# worst layer: it grows with depth as the activations' error does) is
+# reported, not limited. Readings, worst served over the three seeds |
+# least altered (my chip runs, PR 56): mean 2.522e-3 | 5.98e-3 (scale;
+# 5.4e-2 and more for a multiplier or the gate); max 2.10e-2 | 0.137
+# (scale); mean_edge 2.52e-3 | 7.9e-2 (the conv's tail dropped at a
+# window's edge); attn 1.669e-3 | 0.496; reuse 0.0 | 1.41e-2 (a slot's
+# state inherited); state_slow 5.14e-3 | 3.58e-2 (no embedding
+# multiplier; 7.7e-2 the conv's tail); state_f32_share 1.0 | 0.0.
+GRANITE_TOL = {"mean": 4e-3, "max": 6e-2, "mean_edge": 4e-3, "attn": 2e-2,
+               "reuse": 1e-3, "state_slow": 1e-2}
+GRANITE_F32_SHARE = 0.9
+GRANITE_START, GRANITE_EDGE, GRANITE_SLOW_HEADS = 8, 3, 16
+GRANITE_LAST = 64
+# (slot, prompt tokens): both prompt classes of sessions-closed, one
+# that ends inside a window, and a second request in slot 1 once its
+# first has finished (its twin is added beside it)
+GRANITE_JOBS = ((0, 2048), (1, 512), (2, 1400), (1, 450))
+GRANITE_DECODE = 24
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -555,6 +603,8 @@ def main() -> int:
         return compare_ling(engine, cell, args, t_start)
     if raw_config.get("model_type") == "exaone_moe":
         return compare_exaone_moe(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "granitemoehybrid":
+        return compare_granite(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -2726,7 +2776,8 @@ def drive_jobs(engine, params, cache, steps_of, jobs, sequences, prompts,
     fillers = [b for b in range(B) if b not in job_slots]
     filler_tokens = rng.integers(0, cfg.vocab_size,
                                  (B, per_row * cache.page_size))
-    Ls, k = len(cfg.sparse_layers), cfg.num_experts_per_tok
+    Ls = len(getattr(cfg, "sparse_layers", ()))
+    k = cfg.num_experts_per_tok
     got = [dict() for _ in jobs]
     ffn_in = [dict() for _ in jobs]
     # every position's experts, for the teacher-forced reference
@@ -3441,6 +3492,361 @@ def compare_exaone_moe(engine, cell, args, t_start) -> int:
                                     == expected)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"result_exaone_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if result["ok"] else 1
+
+
+def granite_steps(engine):
+    """trunk_steps for a family with no sparse layer
+    (models/moe/granite_hybrid.py: no rope argument, logits through
+    `logits_of`): the three expert outputs drive_jobs carries are empty."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe import granite_hybrid as gh
+
+    cfg, attn = engine.config, engine.attn_impl["mixed"]
+
+    def outputs(params, out):
+        T = out.x.shape[0]
+        return (gh.logits_of(out.x, params, cfg), out.cache,
+                jnp.zeros((0, T, 0), jnp.int32), jnp.zeros((T, 1)),
+                jnp.zeros((T, 1)))
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = gh.mixed_trunk(params, tokens, pos, q_len, active, cache,
+                                cfg, attn, n_tokens)
+        return outputs(params, out)
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        return outputs(params, gh.decode_trunk(params, tokens, cache, pos,
+                                               active, cfg, attn))
+
+    return window_step, decode_step
+
+
+def granite_probe(cache, cfg, n_keys: int, width: int, attn: str,
+                  seed: int) -> dict:
+    """Both attention kernels at the model's own scale against exact
+    attention over the K and V pages the served path wrote (slot 0's
+    first n_keys positions, attention layer 0): the one-token row
+    through `paged.paged_attention`, a window of `width` queries that
+    ends at n_keys through `paged.paged_attention_mixed` in the
+    sub-windows the trunk hands it, each with `scale=
+    attention_multiplier`. The queries are drawn so that the scores at
+    that scale spread by EXAONE_PROBE_SPREAD (the trunk's, over seeded
+    keys, are near uniform and would tell no scale from another).
+    -> {"served": worst relative error of the two, "sqrt_hd": what exact
+    attention at 1/sqrt(head_dim) reads against the same}."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import granite_hybrid as gh
+
+    H, KV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    P, B = cache.page_size, cache.table.shape[0]
+    scale = cfg.attention_multiplier
+    at = jnp.arange(n_keys)
+
+    def stored(pool):
+        return pool[0, cache.table[0, at // P], at % P].reshape(
+            n_keys, KV, hd).astype(jnp.float32)
+
+    keys, vals = stored(cache.k), stored(cache.v)
+    norm = float(jnp.sqrt(jnp.mean(jnp.sum(jnp.square(keys), -1))))
+    sigma = EXAONE_PROBE_SPREAD / (scale * norm)
+    q1 = (jax.random.normal(jax.random.PRNGKey(seed + 1), (B, 1, H, hd))
+          * sigma).astype(cache.k.dtype)
+    qw = (jax.random.normal(jax.random.PRNGKey(seed + 2), (width, H, hd))
+          * sigma).astype(cache.k.dtype)
+    sub = gh.subwindow(cfg, width, P, qw.dtype.itemsize,
+                       cache.k.dtype.itemsize)
+
+    @partial(jax.jit, static_argnames="scale")
+    def exact(q, q_pos, scale):
+        with jax.default_matmul_precision("highest"):
+            qf = q.astype(jnp.float32).reshape(-1, KV, H // KV, hd)
+            sc = jnp.einsum("tkgd,skd->tkgs", qf, keys) * scale
+            seen = at[None, :] <= q_pos[:, None]
+            sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
+            return jnp.einsum("tkgs,skd->tkgd", jax.nn.softmax(sc, axis=-1),
+                              vals).reshape(-1, H, hd)
+
+    def exact_all(scale):
+        """(the one-token row's, the window's in blocks of queries)."""
+        one = exact(q1[0, 0][None], jnp.asarray([n_keys - 1]), scale)[0]
+        win = [exact(qw[i:i + sub], n_keys - width + i + jnp.arange(sub),
+                     scale) for i in range(0, width, sub)]
+        return np.concatenate([np.asarray(one, np.float64).reshape(1, H, hd),
+                               *(np.asarray(w, np.float64) for w in win)])
+
+    def rel(a, b):
+        return float(np.sqrt(np.sum(np.square(a - b))
+                             / max(np.sum(np.square(b)), 1e-300)))
+
+    pos = np.full(B, -1, np.int32)
+    pos[0] = n_keys - 1
+    n_sub = width // sub
+    starts = jnp.arange(n_sub, dtype=jnp.int32) * sub
+    served = np.concatenate([
+        np.asarray(paged.paged_attention(
+            q1, cache.k, cache.v, jnp.int32(0), cache.table,
+            jnp.asarray(pos), impl=attn, scale=scale)[0], np.float64),
+        np.asarray(paged.paged_attention_mixed(
+            qw.reshape(n_sub, sub, H, hd), cache.k, cache.v, jnp.int32(0),
+            jnp.broadcast_to(cache.table[0][None],
+                             (n_sub, cache.table.shape[1])),
+            n_keys - width + starts, jnp.full(n_sub, sub, jnp.int32),
+            impl=attn, scale=scale), np.float64).reshape(width, H, hd)])
+    want = exact_all(scale)
+    out = {"served": rel(served, want),
+           "sqrt_hd": rel(exact_all(hd ** -0.5), want)}
+    say(f"probe: both kernels at scale {scale} {out['served']:.3e}; exact "
+        f"attention at 1/sqrt(hd) {out['sqrt_hd']:.3e}")
+    return out
+
+
+def compare_granite(engine, cell, args, t_start) -> int:
+    """The comparison above for a mixer and a dense SwiGLU a layer, a
+    recurrent state a row beside K/V pages of narrow heads: the
+    engine's own mixed and decode trunks with the head at every
+    position, all 64 rows in every step (drive_jobs), against
+    models/reference/granite_hybrid.py's full forward (no discrete
+    choice: nothing is teacher-forced), logits and the carried state
+    S_t. The jobs are the cell's two prompt classes and one that ends
+    inside a window; slot 1 takes a second request when its first has
+    finished, and its twin runs beside it in a slot nothing has used."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe import granite_hybrid as gh
+    from cake_tpu.models.reference import granite_hybrid as ref
+
+    cfg, params = engine.config, engine.params
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = GRANITE_JOBS if not args.rehearse else (
+        (0, 50), (1, 24), (2, 31), (1, 20))
+    second = len(jobs) - 1
+    opener = next(i for i, (slot, _) in enumerate(jobs)
+                  if slot == jobs[second][0])
+    twin = len(jobs)
+    jobs = (*jobs, (max(slot for slot, _ in jobs) + 1, jobs[second][1]))
+    n_decode = GRANITE_DECODE if not args.rehearse else 6
+    last = GRANITE_LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs[:twin]]
+    sequences.append(sequences[second])
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    cache = engine.cache._replace(table=jnp.asarray(rows_table(engine)))
+    state_dtype = str(cache.ssm.dtype)
+    engine.cache = None
+    states = [None] * len(jobs)         # the rows' state at a job's end
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, the request's
+        first positions and those behind the first window edge."""
+        return (position >= prompts[i] - last or position < GRANITE_START
+                or C <= position < C + GRANITE_EDGE)
+
+    def keep_state(i, slot, cache):
+        states[i] = np.asarray(cache.ssm[:, slot])
+
+    got, _, _, steps, cache = drive_jobs(
+        engine, params, cache, granite_steps(engine), jobs, sequences,
+        prompts, compared, rng, waits_for={twin: opener}, at_end=keep_state)
+    # (a rehearsal's rows are shorter than its window)
+    n_keys = prompts[0] + n_decode
+    probe = granite_probe(cache, cfg, n_keys,
+                          min(C, 1 << (n_keys.bit_length() - 1)),
+                          engine.attn_impl["mixed"], args.seed)
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time -----------------------------------
+    del cache
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = {k: getattr(cfg, k) for k in (
+        "rms_norm_eps", "mamba_n_heads", "mamba_d_head", "mamba_n_groups",
+        "mamba_d_state", "num_attention_heads", "num_key_value_heads",
+        "embedding_multiplier", "attention_multiplier",
+        "residual_multiplier", "logits_scaling")}
+    mamba = ref.mamba
+    jitted = {}
+
+    def jit_mamba(lp, h, config, state=None, tail=None):
+        """The mixer under jit: one trace per config (its switches are
+        read while tracing), shape and kind of start."""
+        key = (tuple(sorted(config.items())), state is None)
+        if key not in jitted:
+            jitted[key] = jax.jit(lambda lp, h, state, tail: mamba(
+                lp, h, config, state, tail))
+        return jitted[key]({k: v for k, v in lp.items() if k != "kind"}, h,
+                           state, tail)
+
+    ref.mamba = jit_mamba
+    ref.mlp = jax.jit(ref.mlp)
+    top = {k: dequantized(jax.tree.map(jnp.asarray, host[k]))
+           for k in ("embed", "final_norm", "lm_head")}
+    slow = np.argsort(np.asarray(host["blocks"]["A_log"][0]))[
+        :GRANITE_SLOW_HEADS]
+
+    def reference(which, config=ref_cfg, starts=None):
+        """The reference over the jobs `which` -> ({job: logits at its
+        compared positions}, {job: each Mamba layer's (S_t, tail)})."""
+        t0 = time.monotonic()
+        finals = [[] for _ in which]
+        logits = ref.forward(
+            top, [sequences[i] for i in which], config,
+            layers=gh.reference_layers(host["blocks"], cfg), states=finals,
+            starts=starts)
+        # (the compared positions alone cross to the host)
+        logits_of = {i: dict(zip(sorted(got[i]), np.asarray(
+            x[jnp.asarray(sorted(got[i]))]))) for i, x in zip(which, logits)}
+        finals_of = {i: [(np.asarray(S), np.asarray(t)) for S, t in f]
+                     for i, f in zip(which, finals)}
+        del logits
+        say(f"  reference over {sum(len(sequences[i]) for i in which)} "
+            f"tokens in {time.monotonic() - t0:.1f} s")
+        return logits_of, finals_of
+
+    def rel(a, b):
+        return float(np.linalg.norm(np.asarray(a, np.float64)
+                                    - np.asarray(b, np.float64))
+                     / np.linalg.norm(np.asarray(b, np.float64)))
+
+    def f32_share(first_layer_states):
+        """The share of float32 entries with a bit set under bfloat16's
+        mantissa (the least over the states handed in)."""
+        return min(float(np.mean(
+            np.ascontiguousarray(S, np.float32).view(np.uint32) & 0xFFFF
+            != 0)) for S in first_layer_states)
+
+    def readings(which, logits_of, finals_of, against=None):
+        """Over the compared positions of the jobs `which`, the served
+        logits against `logits_of`: mean and worst |error| / range;
+        `mean_edge`; `state_slow`, the first Mamba layer's slowest
+        heads' carried state against `finals_of`'s (the worst job);
+        `state`, every head of every layer (the worst layer, reported)."""
+        errs = logit_errors(got, logits_of, which)
+        edge = [float(e.mean()) for _, p, e in errs
+                if C <= p < C + GRANITE_EDGE]
+        by_layer = [max(rel(states[i][m], finals_of[i][m][0])
+                        for i in which)
+                    for m in range(len(cfg.mamba_layers))]
+        out = {"mean": float(np.mean(np.concatenate(
+                   [e for _, _, e in errs]))),
+               "max": max(float(e.max()) for _, _, e in errs),
+               "mean_edge": float(np.mean(edge)) if edge else 0.0,
+               "state_slow": max(rel(states[i][0][slow],
+                                     finals_of[i][0][0][slow])
+                                 for i in which),
+               "state": max(by_layer),
+               "state_by_layer": [round(x, 5) for x in by_layer],
+               "positions": len(errs)}
+        if against is not None:
+            to_this = sum(float(np.sum(np.square(
+                got[i][p] - logits_of[i][p]))) for i, p, _ in errs)
+            to_plain = sum(float(np.sum(np.square(
+                got[i][p] - against[i][p]))) for i, p, _ in errs)
+            out["nearer"] = (to_this / max(to_plain, 1e-300)) ** 0.5
+        return out
+
+    def passes(r):
+        return (all(r[k] < limit for k, limit in GRANITE_TOL.items())
+                and r["state_f32_share"] > GRANITE_F32_SHARE)
+
+    plain = list(range(twin))
+    want, want_finals = reference(plain)
+    served = readings(plain, want, want_finals)
+    served["attn"] = probe["served"]
+    served["state_f32_share"] = f32_share(states[i][0] for i in plain)
+    # the served path against itself: the reused slot against the fresh
+    assert sorted(got[twin]) == sorted(got[second])
+
+    def apart(logits_at, yardstick):
+        return second_request_apart(
+            logits_at, yardstick,
+            {p: want[second][p] for p in got[second]}, prompts[second])
+
+    served["reuse"] = apart(lambda p: got[second][p], got[twin])
+    expected = sum(len({q for q in range(p + n_decode) if compared(i, q)})
+                   for i, p in enumerate(prompts[:twin]))
+    result = {
+        "served": served, "expected_positions": expected,
+        "tol": GRANITE_TOL, "state_f32_share_floor": GRANITE_F32_SHARE,
+        "seed": args.seed,
+        "jobs": [list(j) for j in jobs], "rows_a_step": B, "steps": steps,
+        "attention": impl, "device": jax.devices()[0].device_kind,
+        "state_dtype": state_dtype, "probe": probe,
+        "subwindow": gh.subwindow(cfg, C, page, 2, 2),
+        "state_rms_layer0": [round(float(np.sqrt(np.mean(np.square(
+            states[i][0])))), 5) for i in plain],
+    }
+    ok = (served["positions"] == expected and passes(served)
+          and state_dtype == "float32")
+    if not ok:
+        say("FAILED: the served path is outside the tolerance")
+
+    # -- what must NOT pass: the reference, altered, read as the served
+    # path is (on the slot that is used twice: its two requests) --------
+    if args.negatives:
+        short = [opener, second]
+        negatives = {
+            "bf16_state": dict(config=dict(ref_cfg,
+                                           ssm_state_dtype="bfloat16")),
+            "scale_sqrt_hd": dict(config=dict(
+                ref_cfg, attention_multiplier=cfg.head_dim ** -0.5)),
+            "no_embedding_multiplier": dict(config=dict(
+                ref_cfg, embedding_multiplier=1.0)),
+            "no_residual_multiplier": dict(config=dict(
+                ref_cfg, residual_multiplier=1.0)),
+            "no_logits_scaling": dict(config=dict(ref_cfg,
+                                                  logits_scaling=1.0)),
+            "gate_after_norm": dict(config=dict(ref_cfg,
+                                                gate_after_norm=True)),
+            "conv_tail_dropped": dict(config=dict(ref_cfg, conv_window=C)),
+            # the second request starts from what the first left
+            "state_not_zeroed": dict(
+                starts=[None, list(want_finals[opener])]),
+        }
+        result["must_fail"] = {}
+        for name, kw in negatives.items():
+            say(f"negative: {name}")
+            logits, finals = reference(short, **kw)
+            r = readings(short, logits, finals, against=want)
+            # the probe reads exact attention at the other scale
+            r["attn"] = (probe["sqrt_hd"] if name == "scale_sqrt_hd"
+                         else probe["served"])
+            # what a served path that did this would carry: its own S_t
+            r["state_f32_share"] = f32_share(
+                finals[i][0][0] for i in short)
+            r["reuse"] = (apart(lambda p: logits[second][p],
+                                {p: want[second][p] for p in got[second]})
+                          if "starts" in kw else 0.0)
+            result["must_fail"][name] = r
+            if passes(r):
+                say(f"FAILED: the reference with {name} passes the "
+                    "tolerance")
+                ok = False
+    result["ok"] = bool(ok) or bool(args.rehearse and served["positions"]
+                                    == expected)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"result_granite_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)

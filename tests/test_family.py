@@ -20,7 +20,8 @@ from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
     BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
-    GlmMoeDsaConfig, MoEConfig, NemotronHConfig, ZayaConfig,
+    GlmMoeDsaConfig, GraniteHybridConfig, MoEConfig, NemotronHConfig,
+    ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
 
@@ -38,13 +39,15 @@ TINY = {
     "zaya": ZayaConfig.tiny_zaya,
     "bailing_hybrid": BailingHybridConfig.tiny_ling,
     "exaone_moe": ExaoneMoeConfig.tiny_exaone,
+    "granitemoehybrid": GraniteHybridConfig.tiny_granite,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
          "dots3_note": "latent row and index key",
          "deepseek_v2": "latent row",
          "nemotron_h": "state", "zaya": "tail",
-         "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring"}
+         "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring",
+         "granitemoehybrid": "state"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 
@@ -201,7 +204,7 @@ def test_the_readmes_table_is_the_families_tables():
 
 NAMES = ("kv_lora_rank", "mamba_layers", "cca_time0", "sliding_layers",
          "nemotron", "zaya", "glm", "dots3", "deepseek", "rope_scaling",
-         "n_group", "bailing", "kda")
+         "n_group", "bailing", "kda", "granite")
 
 
 @pytest.mark.parametrize("where", ["cake_tpu/serve/engine.py",

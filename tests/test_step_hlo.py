@@ -200,6 +200,60 @@ def test_banded_attention_kernels_compile_at_the_cells_widths(
     assert "tpu_custom_call" in hlo and f"cake_{kernel}_attn" in hlo
 
 
+@pytest.mark.parametrize("kernel", ["decode", "mixed"])
+def test_attention_kernels_compile_at_heads_of_64_under_a_stated_scale(
+        one_chip, kernel):
+    """`cake_decode_attn` and `cake_mixed_attn` at
+    granite4h.sessions-closed's shapes (32 query heads over 8 K/V heads
+    of 64: a pool row of 512 lanes, bf16 pages of 128, a table of 20
+    pages a row, `scale=` 1/64) go through Mosaic: the decode kernel for
+    64 rows, the mixed kernel for a 512-token window as 4 sub-windows of
+    128 queries (`granite_hybrid.subwindow`: the kernel's own VMEM
+    count; 256 queries are refused by the compiler for 16.88 MiB of its
+    16, which is what the count's padding of a head to 128 lanes
+    states)."""
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe.exaone_moe import query_tile
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, KV, hd, P, scale = 32, 8, 64, 128, 1.0 / 64
+    sub = query_tile(512, H, KV, hd, P, 2, 2)
+    assert sub == 128
+    assert rpa.mixed_vmem_bytes(P, H, KV, hd, 256) > rpa._VMEM_SCOPED_LIMIT
+    pool = sds((4, 1280, P, KV * hd), jnp.bfloat16)
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        assert rpa.ragged_paged_supported(P, H, KV, hd, n_pages=1280,
+                                          slots=64, max_pages=20)
+        assert rpa.ragged_paged_mixed_supported(P, H, KV, hd, sub)
+        assert not rpa.ragged_paged_mixed_supported(P, H, KV, hd, 256)
+        with jax.default_matmul_precision("default"):
+            if kernel == "decode":
+                compiled = jax.jit(lambda q, k, v, t, p: (
+                    rpa.ragged_paged_attention(
+                        q, k, v, jnp.int32(1), t, p, scale=scale,
+                        interpret=False))).lower(
+                    sds((64, 1, H, hd), jnp.bfloat16), pool, pool,
+                    sds((64, 20), jnp.int32), sds((64,), jnp.int32)).compile()
+            else:
+                n = 512 // sub
+                compiled = jax.jit(lambda q, k, v, t, p, n_q: (
+                    rpa.ragged_paged_attention_mixed(
+                        q, k, v, jnp.int32(1), t, p, n_q, scale=scale,
+                        interpret=False))).lower(
+                    sds((n, sub, H, hd), jnp.bfloat16), pool, pool,
+                    sds((n, 20), jnp.int32), sds((n,), jnp.int32),
+                    sds((n,), jnp.int32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and f"cake_{kernel}_attn" in hlo
+
+
 def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
     """`cake_kda_step` at ling3.longreply-closed's shapes (10 layers of
     32 rows x 32 heads of 128 x 128 float32) goes through Mosaic, and
