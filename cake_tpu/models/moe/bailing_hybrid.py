@@ -71,7 +71,9 @@ from cake_tpu.models.llama.paged import HybridPagedCache
 from cake_tpu.models.moe import glm_dsa
 from cake_tpu.models.moe.config import BailingHybridConfig
 from cake_tpu.models.moe.glm_dsa import Window, _window_slice
-from cake_tpu.models.moe.nemotron_h import Rows, causal_conv_rows, dequantized
+from cake_tpu.models.moe.nemotron_h import (
+    Rows, causal_conv_rows, dequantized, step_codes,
+)
 from cake_tpu.models.step_programs import (
     make_decode_scan, make_mixed_sampled,
 )
@@ -182,15 +184,6 @@ def kda_step(S, q, k, v, g, beta):
                            - jnp.sum(k[..., None] * S, axis=-2))
     S = S + k[..., None] * u[..., None, :]
     return S, jnp.sum(q[..., None] * S, axis=-2)
-
-
-def step_codes(rows: Rows):
-    """What ops/kda.step does with each row [B] int32: a row that holds
-    one token steps, from zeros if that token sits at position 0; any
-    other row (idle, or the dispatch's window) stays."""
-    return jnp.where(rows.n == 1,
-                     jnp.where(rows.pos == 0, kda.FRESH, kda.STEP),
-                     kda.STAY).astype(jnp.int32)
 
 
 def kda_step_fold(state, j, code, q, k, v, g, beta):

@@ -26,7 +26,15 @@ that hold the attention layers alone, no positional embedding) and
     as their own `scale=`; q is never pre-scaled. The window goes
     through `cake_mixed_attn` in sub-windows of `exaone_moe.query_tile`
     queries: what the kernel's VMEM holds by its own count (128 at 32
-    heads of 64 over 128-token pages: 256 ask for 16.9 MiB of 16).
+    heads of 64 over 128-token pages: 256 ask for 16.9 MiB of 16);
+  * a row's single token takes `mamba_block`'s one-step form through
+    ops/ssm.step (`cake_ssm_step`: the stepping rows' state alone, once
+    each way, in place). At 64 rows of 64 heads in one group XLA made
+    two fusions over a layer's state in the decode program (it read
+    the state twice) and wrote a whole layer's copy beside the stack in
+    the mixed one, where at Nemotron's shape it makes one (PERF.md
+    section 6, PR 57): a choice that flips on the row count is nothing
+    a served path can stand on.
 
 ONE WINDOW A DISPATCH AND A STEP, as nemotron_h: the chunked scan takes
 the one row whose tokens are contiguous on the packed axis and whose
@@ -52,6 +60,7 @@ from cake_tpu.models.step_programs import (
     make_decode_scan, make_mixed_sampled,
 )
 from cake_tpu.ops import ragged_paged_attention as rpa
+from cake_tpu.ops import ssm as ssm_ops
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import qmatmul
 
@@ -142,7 +151,7 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
             if kind == "mamba":
                 out, ssm, conv = nh.mamba_block(
                     lp, h, ssm, conv, c.mamba_layers.index(i), slot, real,
-                    rows, c, window)
+                    rows, c, window, step=ssm_ops.step)
             else:
                 out, pool_k, pool_v = nh.attention_block(
                     lp, h, pool_k, pool_v, c.attn_layers.index(i), table,

@@ -20,7 +20,13 @@ models/moe/glm_dsa.py's):
     every row that holds ONE token (a decode step's rows; the decode
     rows of a mixed dispatch), and `ssm_scan`, the chunked form (SSD,
     chunks of `chunk_size`) over the dispatch's one window, starting
-    from the row's stored state. `ssm_state` is every read and write of
+    from the row's stored state. The one-step form is a trunk's to
+    choose (`mamba_block(step=)`): here it is XLA over the layer's
+    whole state, which at these shapes compiles to ONE fusion that
+    reads the state once and writes it once; models/moe/
+    granite_hybrid.py, whose shapes XLA gives two or three passes,
+    passes ops/ssm.step (`cake_ssm_step`: the stepping rows' state
+    alone, in place). `ssm_state` is every read and write of
     the stored state: read once a block, zeroed on the way in for a row
     whose first token sits at position 0 (a request that takes the
     slot: no launch of its own), written once a block; a row with no
@@ -63,6 +69,7 @@ from cake_tpu.models.step_programs import (
     make_decode_scan, make_mixed_sampled,
 )
 from cake_tpu.ops import ragged_paged_attention as rpa
+from cake_tpu.ops import ssm as ssm_ops
 from cake_tpu.ops.moe import LayerOf, moe_mlp
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import QTensor, qmatmul
@@ -182,6 +189,33 @@ def ssm_step(S, x, Bm, Cm, dt, a, D):
     return S_new.reshape(B, H, P, N), y
 
 
+def step_codes(rows: Rows):
+    """What ops/ssm.step (and ops/kda.step) does with each row [B]
+    int32: a row that holds one token steps, from zeros if that token
+    sits at position 0; any other row (idle, or the dispatch's window)
+    stays."""
+    return jnp.where(rows.n == 1,
+                     jnp.where(rows.pos == 0, ssm_ops.FRESH, ssm_ops.STEP),
+                     ssm_ops.STAY).astype(jnp.int32)
+
+
+def ssm_step_fold(state, j, code, x, Bm, Cm, dt, a, D):
+    """ops/ssm.step's contract in XLA over `ssm_step`: layer j of the
+    stack read whole, every row stepped, the stepping rows' results
+    kept (`mamba_block`'s own select and write-back). The kernel's
+    comparison (tests/test_ssm_kernel.py) and tools/ssm_step_bench.py's
+    other side; no step program calls it."""
+    S_old = lax.dynamic_index_in_dim(state, j, 0, keepdims=False)
+    S_new, y = ssm_step(
+        jnp.where((code == ssm_ops.FRESH)[:, None, None, None], 0.0, S_old),
+        x, Bm, Cm, dt, a, D)
+    steps = code != ssm_ops.STAY
+    return (lax.dynamic_update_index_in_dim(
+                state, jnp.where(steps[:, None, None, None], S_new, S_old),
+                j, 0),
+            jnp.where(steps[:, None, None], y, 0.0))
+
+
 def ssm_scan(S0, x, Bm, Cm, dt, a, D, chunk: int):
     """A window of C tokens of ONE row, chunked (SSD). S0 [H, P, N] f32,
     the state the window starts from; x [C, H, P]; Bm, Cm [C, G, N];
@@ -267,9 +301,14 @@ def causal_conv_rows(x, tail, taps, bias, slot, rows: Rows):
 
 
 def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
-                config: NemotronHConfig, window: Optional[Window]):
+                config: NemotronHConfig, window: Optional[Window],
+                step=None):
     """h [T, D] -> (out [T, D], ssm, conv): Mamba block j of the stacked
-    state, over the packed tokens."""
+    state, over the packed tokens. step: the one-step form over the
+    stored stack, ops/ssm.step's contract (a stepping row's state in
+    place; the window's row read before it and written after it, so no
+    layer's state is ever a value); None: `ssm_step` in XLA over the
+    layer's whole state."""
     c = config
     T = h.shape[0]
     H, P, G, N = (c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
@@ -284,8 +323,18 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
     with jax.named_scope("attn"):
         with jax.named_scope("ssm_state"):
             tail = jnp.where(fresh[:, None, None], 0, conv[j])   # [B, K-1, ch]
-            S_old = ssm[j]
-            S_in = jnp.where(fresh[:, None, None, None], 0.0, S_old)
+            if step is None:
+                S_old = ssm[j]
+                S_in = jnp.where(fresh[:, None, None, None], 0.0, S_old)
+            elif window is not None:
+                # the window's row alone, as stored BEFORE this layer's
+                # step (it stays there): 2 MiB at Granite's widths. Read
+                # HERE: fused into the scan's consumers it is a read of
+                # the old stack after the kernel's write in place, and
+                # XLA copies the stack whole to keep one
+                S0, ssm = lax.optimization_barrier(
+                    (jnp.where(fresh[window.row], 0.0, ssm[j, window.row]),
+                     ssm))
         with jax.named_scope("ssm_conv"):
             u, new_tail = causal_conv_rows(xBC, tail, lp["conv_w"],
                                            lp["conv_b"], slot, rows)
@@ -300,8 +349,12 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
         D = lp["D"].astype(F32)
         with jax.named_scope("ssm_step"):
             at = jnp.minimum(rows.first, T - 1)
-            S_new, y1 = ssm_step(S_in, xs[at], Bm[at], Cm[at], dt[at],
-                                 a[at], D)
+            if step is None:
+                S_new, y1 = ssm_step(S_in, xs[at], Bm[at], Cm[at], dt[at],
+                                     a[at], D)
+            else:
+                ssm, y1 = step(ssm, j, step_codes(rows), xs[at], Bm[at],
+                               Cm[at], dt[at], a[at], D)
         single = rows.n == 1
         if window is None:
             y = y1[slot]
@@ -311,7 +364,7 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
                 # window's own the state passes through unchanged
                 own = (jnp.arange(window.width) < window.n)[:, None]
                 S_win, yw = ssm_scan(
-                    S_in[window.row],
+                    S_in[window.row] if step is None else S0,
                     *(_window_slice(v, window) for v in (xs, Bm, Cm)),
                     jnp.where(own, _window_slice(dt, window), 0.0),
                     jnp.where(own, _window_slice(a, window), 0.0),
@@ -319,8 +372,9 @@ def mamba_block(lp, h, ssm, conv, j: int, slot, real, rows: Rows,
             y = jnp.where(window.member[:, None, None], yw[window.col],
                           y1[slot])
         with jax.named_scope("ssm_state"):
-            ssm = ssm.at[j].set(
-                jnp.where(single[:, None, None, None], S_new, S_old))
+            if step is None:
+                ssm = ssm.at[j].set(
+                    jnp.where(single[:, None, None, None], S_new, S_old))
             conv = conv.at[j].set(new_tail)
             if window is not None:
                 ssm = ssm.at[j, window.row].set(

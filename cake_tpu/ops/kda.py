@@ -98,19 +98,23 @@ def _update(cols_ref, rows_ref, o_ref, ring, slot, i: int, H: int, hb: int):
     o_ref[0, i * hb:(i + 1) * hb, :] = jnp.concatenate(outs, axis=0)
 
 
-def _step_kernel(j_ref, code_ref, cols_ref, rows_ref, state_in, state_ref,
-                 o_ref, ring, sem, cur, *, depth: int, hb: int):
+def _step_kernel(j_ref, code_ref, *refs, depth: int, hb: int, update=_update):
     """One grid step: one ROW.
 
     j_ref [1], code_ref [B]: the layer of the stack, each row's code
+    refs: the row's operands (here cols_ref, rows_ref), then
     state_in / state_ref: [L, B, H, dk, dv] in HBM, ONE buffer (aliased)
+    o_ref: the row's output block
     ring [depth, hb, dk, dv] VMEM; sem DMA [2, depth]: in, out
     cur SMEM int32 [4]: the copies' cursor (row, block, count of blocks
         started) and the count of blocks updated, carried row to row
+    update: a block's arithmetic (ops/ssm.py rides these copies with
+        its own operands and its own)
     """
+    *operands, state_in, state_ref, o_ref, ring, sem, cur = refs
     del state_in
     b, nb = pl.program_id(0), pl.num_programs(0)
-    H = o_ref.shape[1]
+    H = state_ref.shape[2]
     nblk = H // hb
     ahead = depth // 2
     j = j_ref[0]
@@ -194,7 +198,7 @@ def _step_kernel(j_ref, code_ref, cols_ref, rows_ref, state_in, state_ref,
             def _():
                 ring[slot] = jnp.zeros(ring.shape[1:], F32)
 
-            _update(cols_ref, rows_ref, o_ref, ring, slot, i, H, hb)
+            update(*operands, o_ref, ring, slot, i, H, hb)
             store(b, i, slot).start()
 
     # every slot that held a block still has its last store in flight

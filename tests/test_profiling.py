@@ -196,6 +196,7 @@ def _kernel_calls():
         "cake_mla_window_attn": _mla_calls()[1],
         "cake_mla_decode_attn": _mla_calls()[2],
         "cake_kda_step": _kda_step_call,
+        "cake_ssm_step": _ssm_step_call,
     }
 
 
@@ -205,6 +206,16 @@ def _kda_step_call():
     return kda.step(jnp.zeros((1, 2, 2, 8, 8), jnp.float32), 0,
                     jnp.ones(2, jnp.int32), row, row, row, row,
                     jnp.zeros((2, 2), jnp.float32), interpret=True)
+
+
+def _ssm_step_call():
+    from cake_tpu.ops import ssm
+    rows = jnp.zeros((2, 1, 8), jnp.float32)
+    heads = jnp.zeros((2, 2), jnp.float32)
+    return ssm.step(jnp.zeros((1, 2, 2, 8, 8), jnp.float32), 0,
+                    jnp.ones(2, jnp.int32), jnp.zeros((2, 2, 8), jnp.float32),
+                    rows, rows, heads, heads, jnp.zeros(2, jnp.float32),
+                    interpret=True)
 
 
 def _mla_calls():
@@ -239,7 +250,7 @@ def _moe_gmm_call():
     "cake_decode_attn", "cake_mixed_attn", "cake_flash_prefill",
     "cake_flash_prefill_cached", "cake_int4_matmul", "cake_moe_gmm",
     "cake_mla_attn", "cake_mla_window_attn", "cake_mla_decode_attn",
-    "cake_kda_step"])
+    "cake_kda_step", "cake_ssm_step"])
 def test_pallas_calls_carry_their_names(name):
     """A kernel event is recognised by name, not by the rank of its
     result: every pl.pallas_call in ops/ passes name=."""
@@ -260,7 +271,7 @@ def test_every_pallas_call_site_is_named():
             # the call's own argument list, up to the operands' call
             named += 'name="cake_' in text[m.end():m.end() + 1200].split(
                 ")(")[0]
-    assert sites == named == 10
+    assert sites == named == 11
 
 
 def test_scopes_leave_the_compiled_program_unchanged(monkeypatch):

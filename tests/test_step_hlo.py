@@ -287,6 +287,111 @@ def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
             == 2 * 2**20)
 
 
+def test_ssm_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
+    """`cake_ssm_step` at granite4h.sessions-closed's shapes (36 layers
+    of 64 rows x 64 heads of 64 x 128 float32, one group) goes through
+    Mosaic inside the default scoped VMEM (the kernel sets no limit),
+    and the program that donates the stack holds it ONCE: the 4.5 GiB
+    are aliased in and out, with no second copy among the temporaries."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import kda, ssm
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, B, H, P, N, G = 36, 64, 64, 64, 128, 1
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        compiled = jax.jit(ssm.step, donate_argnums=(0,)).lower(
+            sds((L, B, H, P, N), jnp.float32), sds((), jnp.int32),
+            sds((B,), jnp.int32), sds((B, H, P), jnp.bfloat16),
+            sds((B, G, N), jnp.bfloat16), sds((B, G, N), jnp.bfloat16),
+            sds((B, H), jnp.float32), sds((B, H), jnp.float32),
+            sds((H,), jnp.float32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_ssm_step" in hlo
+    assert "vmem_limit_bytes" not in hlo
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * B * H * P * N * 4
+    assert memory.temp_size_in_bytes < 16 * 2**20
+    assert (kda.RING_DEPTH * kda.block_heads(H, P * N * 4) * P * N * 4
+            == 2 * 2**20)
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_granites_step_programs_move_the_state_in_the_kernel_alone(
+        tool, one_chip, program):
+    """A cut of Granite's served programs (mamba, attention, mamba at
+    the cell's widths, slots and window) for the described v5e: ONE
+    `cake_ssm_step` call a Mamba layer; no fusion under `ssm_step` /
+    `ssm_state` reads the state to reduce `y` (XLA's second pass over
+    it before PR 57) and none gives a whole layer's state (the mixed
+    program's fourth); the only other writer of the stack is the window
+    row's scatter, one a Mamba layer of the mixed program. Neither the
+    third pass nor the fourth can come back unseen."""
+    import json
+    import re
+
+    from cake_tpu.models.llama.config import load_config
+
+    cell = CONFIGS / "granite-4.0-h-micro-int8"
+    config = dataclasses.replace(
+        load_config(str(cell)), num_hidden_layers=3,
+        layer_types=("mamba", "attention", "mamba"))
+    with open(cell / "cell.json") as f:
+        cell = json.load(f)
+    sa = cell["server_args"]
+    shape = dict(slots=sa["max-slots"], n_pages=sa["kv-pages"],
+                 page_size=sa["kv-page-size"], max_seq_len=sa["max-seq-len"])
+    decode, mixed = tool.step_fns(config)
+    with jax.default_matmul_precision("default"):
+        if program == "decode":
+            compiled = tool.compile_step(decode, config, one_chip, **shape)
+        else:
+            width = cell["shape"]["mixed_width"]
+            compiled = tool.compile_step(
+                mixed, config, one_chip, width=width,
+                n_tokens=width + sa["max-slots"], **shape)
+    hlo = compiled.as_text()
+    c = config
+    layer = (f"f32[{sa['max-slots']},{c.mamba_num_heads},"
+             f"{c.mamba_head_dim},{c.ssm_state_size}]")
+    stack = layer.replace("f32[", "f32[2,")
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cake_ssm_step" in line]
+    assert len(calls) == 2 and all(stack in line for line in calls)
+    # what an instruction outside the fusions' bodies GIVES: its result
+    # type(s), before the opcode
+    gives = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s"
+                       r"(?:fusion|custom-call|copy|dynamic-update-slice)\(")
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    whole_layers, stack_writers, inside = [], [], None
+    for line in hlo.splitlines():
+        head = tool._COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+        m = gives.match(line)
+        if not m or inside in fused or "cake_ssm_step" in line:
+            continue
+        if layer in m.group(1):
+            whole_layers.append(line.strip()[:200])
+        if stack in m.group(1):
+            stack_writers.append(line.strip())
+    assert not whole_layers, "a whole layer's state is a value again: " \
+        + "; ".join(whole_layers)
+    assert not re.search(r"ssm_step/reduce_sum", hlo), (
+        "XLA reduces y over the state again")
+    # (a `copy` of the stack lands here too: XLA keeping the old stack
+    # beside the kernel's in-place write, 4.5 GiB a layer)
+    assert len(stack_writers) == (2 if program == "mixed" else 0), \
+        [line[:300] for line in stack_writers]
+    assert all("ssm_state/scatter" in line for line in stack_writers)
+
+
 @pytest.mark.parametrize("heads,row,value,pages,masked_by,scope", [
     (128, 640, 512, 40, "positions", "mla"),      # dsv2.code-closed
     (64, 640, 512, 100, "bias", "mla"),           # glm52.longdoc-closed
