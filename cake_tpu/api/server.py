@@ -736,16 +736,6 @@ class ApiServer:
                  "Engine iterations that failed and reset", st.errors),
             ):
                 m.counter(name, help_).set_total(val)
-            if getattr(self.engine, "_spec", False):
-                m.counter("cake_engine_spec_proposed_total",
-                          "Draft tokens proposed").set_total(
-                    st.spec_proposed)
-                m.counter("cake_engine_spec_accepted_total",
-                          "Draft tokens accepted").set_total(
-                    st.spec_accepted)
-                m.gauge("cake_engine_spec_acceptance",
-                        "Lifetime draft acceptance ratio").set(
-                    round(st.spec_acceptance, 4))
             # scrape-fresh per-class queue depths through the engine's
             # one registration site (no-op without the SLO scheduler)
             self.engine._set_queue_gauges()

@@ -16,14 +16,6 @@ from dataclasses import dataclass, field, fields, asdict
 from enum import Enum
 from typing import Optional
 
-# one source of truth for the quantized-KV-with-speculation config
-# error: Args.validate raises it on the CLI path, master.make_engine
-# raises it for programmatically-built Args that skipped validate()
-INT8_KV_SPEC_ERROR = (
-    "--kv-dtype int8/int4 is unavailable with --draft-model:"
-    " the speculative engine is gated off the paged "
-    "pool, so there are no KV pages to quantize")
-
 # the quantized paged-pool storage names ("int8" = 1 byte/value,
 # "int4" = two nibble-packed values/byte; cake_tpu/kv/quantized_pool)
 QUANTIZED_KV_DTYPES = ("int8", "int4")
@@ -90,9 +82,7 @@ class Args:
     top_p: Optional[float] = None
     top_k: Optional[int] = None
     # None = "not set": resolves to the reference default 1.1
-    # (llama.rs:311-320) for normal serving, and to 1.0 for speculative
-    # serving (whose parallel verify cannot replay a penalty ring) — an
-    # EXPLICIT value is honored (or rejected) everywhere
+    # (llama.rs:311-320); an EXPLICIT value is honored everywhere
     repeat_penalty: Optional[float] = None
     repeat_last_n: int = 128
     dtype: str = "bf16"                 # f16 | bf16 | f32 (TPU default bf16)
@@ -102,9 +92,7 @@ class Args:
     # int4 KV pages + per-page per-kv-head f32 scales, ~4x / ~8x the
     # resident decode streams per pool byte vs f32 — both require
     # --kv-pages (the page is the quantization unit; int4 additionally
-    # needs an even --kv-page-size) and are a loud config error with
-    # --draft-model (the spec engine is gated off the paged pool).
-    # None = same as dtype.
+    # needs an even --kv-page-size). None = same as dtype.
     kv_dtype: Optional[str] = None      # + f8_e4m3 | f8_e5m2 | int8 | int4
     max_seq_len: int = 4096             # reference hard constant (config.rs:6); tunable here
     batch_size: int = 1
@@ -141,22 +129,15 @@ class Args:
     # (weight-only per-channel), "int4" quarters it (group-wise, dense
     # models only); "none" keeps args.dtype weights
     quant: str = "none"
-    # speculative decoding (models/llama/speculative.py): path to a small
-    # draft model sharing the target's tokenizer; each target pass then
-    # verifies spec_gamma drafted tokens at once. Batch-1, single-device.
-    draft_model: Optional[str] = None
-    spec_gamma: int = 4
-    # PAGED speculative decoding (cake_tpu/spec): path to a small draft
-    # model whose KV rides a second paged pool behind the engine's one
-    # page allocator — spec becomes a row KIND of the mixed ragged step
-    # (many streams speculate concurrently) instead of the dense
-    # batch-engine above. Requires --kv-pages + f32/bf16 KV; shares
-    # --spec-gamma. Mutually exclusive with --draft-model.
+    # speculative decoding (cake_tpu/spec): path to a small draft model
+    # sharing the target's tokenizer, whose KV rides a second paged
+    # pool behind the engine's one page allocator — speculation is a
+    # row KIND of the paged engine (many streams speculate at once, a
+    # row whose sampling has no accept/resample identity decodes
+    # plain), and each round's one target pass verifies spec_gamma
+    # drafted tokens a row. Requires --kv-pages + f32/bf16 KV.
     spec_draft: Optional[str] = None
-    # batch-1 CLI speculation: propose-verify rounds chained on device
-    # per host fetch (spec_scan); the engine path batches across slots
-    # instead and ignores this
-    spec_rounds: int = 4
+    spec_gamma: int = 4
     # serving watchdog: fail (recoverably) when the engine makes no
     # progress for this many seconds with active requests; must exceed
     # the worst-case first-request compile time (parallel/health.py)
@@ -275,7 +256,7 @@ class Args:
     # instead of failing them all; repeatedly-implicated requests are
     # quarantined as poison, and a reset storm trips a breaker
     # (snapshot + clean stop). None = auto: on wherever the fold works
-    # (off for speculative and windowed serving)
+    # (off for windowed serving)
     recovery: Optional[bool] = None
     # --autotune {off,manual,auto}: live engine-config hot-switching
     # (cake_tpu/autotune). "manual" arms POST /api/v1/autotune (an
@@ -439,25 +420,19 @@ class Args:
                     f"--kv-dtype int4 requires an even --kv-page-size "
                     f"(got {self.kv_page_size}): pages nibble-pack "
                     "token pairs (cake_tpu/kv/quantized_pool)")
-            if self.draft_model is not None:
-                raise ValueError(INT8_KV_SPEC_ERROR)
         elif self.kv_dtype is not None:
             # single source of truth for storage dtypes
             from cake_tpu.utils.devices import resolve_kv_dtype
             resolve_kv_dtype(self.kv_dtype)
         if self.spec_draft is not None:
-            # paged speculative decoding (cake_tpu/spec): loud startup
+            # speculative decoding (cake_tpu/spec): loud startup
             # errors mirroring the engine's constructor checks, so a
             # bad flag combination fails before the model loads
-            if self.draft_model is not None:
-                raise ValueError(
-                    "--spec-draft (paged spec rows) and --draft-model "
-                    "(the dense spec engine) are mutually exclusive")
             if not self.kv_pages:
                 raise ValueError(
-                    "--spec-draft requires --kv-pages: paged "
-                    "speculative decoding shares the page allocator "
-                    "(use --draft-model for the dense spec engine)")
+                    "--spec-draft requires --kv-pages: a speculating "
+                    "row's draft and target KV share the paged pool's "
+                    "page allocator")
             if self.kv_dtype in ("int8", "int4"):
                 raise ValueError(
                     f"--spec-draft requires f32/bf16 KV pages, got "
@@ -602,7 +577,7 @@ class Args:
             raise ValueError(f"unsupported mode '{self.mode}'")
         for knob in ("tp", "dp", "sp", "microbatches", "batch_size",
                      "max_slots", "decode_scan", "spec_gamma",
-                     "spec_rounds", "trace_ring", "step_ring"):
+                     "trace_ring", "step_ring"):
             if getattr(self, knob) < 1:
                 raise ValueError(f"--{knob.replace('_', '-')} must be >= 1")
         return self

@@ -58,9 +58,9 @@ def _serve_multihost(master, args) -> int:
         engine = master.make_engine()
         if engine is None:
             raise ValueError(
-                "this serving mode (--draft-model multi-host, or an "
-                "sp composition without an engine contract) has no "
-                "multi-host step replay; serve it on one host")
+                "this serving mode (an sp composition without an "
+                "engine contract) has no multi-host step replay; "
+                "serve it on one host")
         # the pre-fail capture must outlive the heartbeat stale window
         # (the monitor is exactly the late-arriving consumer)
         engine.fail_recs_ttl = args.heartbeat_timeout + 60.0

@@ -128,8 +128,7 @@ class Context:
         plan = ParallelPlan.from_topology(cfg, self.topology, args=a)
         refusal = cfg.family.refusal({
             "topology": (plan.stages > 1 or plan.tp > 1 or plan.dp > 1
-                         or a.sp > 1),
-            "--draft-model": a.draft_model is not None})
+                         or a.sp > 1)})
         if refusal:
             raise ValueError(refusal)
 
@@ -153,12 +152,10 @@ class Context:
                 log.info("weights quantized to %s as they loaded "
                          "(weight-only)", a.quant)
 
-        # --repeat-penalty unset -> reference default 1.1 (llama.rs:311);
-        # speculative mode resolves unset to 1.0 instead (parallel verify
-        # has no penalty-ring replay) while honoring explicit values
+        # --repeat-penalty unset -> reference default 1.1 (llama.rs:311)
         penalty = a.repeat_penalty
         if penalty is None:
-            penalty = 1.0 if a.draft_model is not None else 1.1
+            penalty = 1.1
         sampling = SamplingConfig(
             temperature=a.temperature, top_k=a.top_k, top_p=a.top_p,
             repeat_penalty=penalty, repeat_last_n=a.repeat_last_n,
@@ -339,26 +336,13 @@ class Context:
                           parallel=(plan, mesh))
             log.info("topology-sharded serving:\n%s", plan.describe())
 
-        if a.draft_model is not None:
-            if kwargs or a.batch_size != 1:
-                raise ValueError(
-                    "--draft-model (speculative decoding) is batch-1 "
-                    "single-device; it does not compose with "
-                    "--sp/--tp/--dp/topology stages")
-            if a.prefill_chunk is not None:
-                raise ValueError(
-                    "--prefill-chunk is not supported with --draft-model "
-                    "(speculative prefill is whole-prompt)")
-            gen = self._load_speculative(cfg, params, tokenizer, sampling,
-                                         max_seq, kv_dtype)
-        else:
-            gen = LlamaGenerator(
-                cfg, params, tokenizer,
-                max_seq_len=max_seq,
-                batch_size=a.batch_size, sampling=sampling, seed=a.seed,
-                cache_dtype=kv_dtype, prefill_chunk=a.prefill_chunk,
-                **kwargs,
-            )
+        gen = LlamaGenerator(
+            cfg, params, tokenizer,
+            max_seq_len=max_seq,
+            batch_size=a.batch_size, sampling=sampling, seed=a.seed,
+            cache_dtype=kv_dtype, prefill_chunk=a.prefill_chunk,
+            **kwargs,
+        )
         from cake_tpu.utils.profiling import log_memory
         log_memory("model loaded")  # reference llama.rs:233-236
         return gen
@@ -433,38 +417,6 @@ class Context:
         log.info("weights quantized to %s (weight-only, %s)", a.quant,
                  "per-channel" if a.quant == "int8" else "group-wise")
         return params
-
-    def _load_speculative(self, cfg, params, tokenizer, sampling, max_seq,
-                          kv_dtype):
-        import dataclasses
-
-        from cake_tpu.models import load_text_params
-        from cake_tpu.models.llama.config import LlamaConfig, load_config
-        from cake_tpu.models.llama.speculative import SpeculativeGenerator
-
-        a = self.args
-        d_dir = a.draft_model
-        if d_dir and os.path.exists(os.path.join(d_dir, "config.json")):
-            d_cfg = dataclasses.replace(
-                load_config(d_dir), use_flash_attention=_resolve_flash(a))
-        else:
-            d_cfg = dataclasses.replace(
-                LlamaConfig.tiny(), use_flash_attention=_resolve_flash(a))
-        if d_cfg.vocab_size != cfg.vocab_size:
-            raise ValueError(
-                f"draft vocab {d_cfg.vocab_size} != target vocab "
-                f"{cfg.vocab_size}: speculation verifies draft token ids "
-                "directly, so the models must share a tokenizer")
-        d_params = load_text_params(d_cfg, d_dir, self.dtype,
-                                    quant=a.quant)
-        log.info("speculative serving: gamma=%d draft=%s", a.spec_gamma,
-                 d_dir or "<random tiny>")
-        return SpeculativeGenerator(
-            cfg, params, d_cfg, d_params, tokenizer,
-            gamma=a.spec_gamma, max_seq_len=max_seq, sampling=sampling,
-            seed=a.seed, cache_dtype=kv_dtype,
-            spec_rounds=a.spec_rounds,
-        )
 
     def load_image_model(self):
         from cake_tpu.models.sd.sd import SDGenerator

@@ -46,7 +46,7 @@ from typing import List, Optional
 SITES = frozenset({
     "engine.step",        # top of every engine iteration
     "engine.prefill",     # one admission's prefill (ctx: n_tokens)
-    "engine.decode",      # a ragged decode / scan / spec dispatch
+    "engine.decode",      # a ragged decode / scan dispatch
     "engine.mixed",       # a mixed (decode+prefill-chunk) dispatch
     "control.publish",    # coordinator -> follower op publish
     "control.recv",       # follower op receive

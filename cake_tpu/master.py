@@ -59,63 +59,6 @@ class Master:
             raise RuntimeError("no text generator loaded")
         from cake_tpu.serve import InferenceEngine
         g = self.llm
-        from cake_tpu.models.llama.speculative import SpeculativeGenerator
-        if isinstance(g, SpeculativeGenerator):
-            import jax
-            if jax.process_count() > 1:
-                # the spec engine's batched rounds are single-device;
-                # no multi-host step replay exists for them
-                log.info("no multi-host engine for --draft-model")
-                return None
-            # round-5: speculation inside the batching engine — the
-            # draft/verify rounds run BATCHED across slots (spec_round_batched), so
-            # concurrent API requests all speculate, stream, and
-            # checkpoint like any other engine request
-            if getattr(self.args, "kv_dtype", None) in ("int8", "int4"):
-                # loud config error, not a warning: an operator asking
-                # for quantized KV expects the capacity win, and the
-                # spec engine (gated off the paged pool) cannot
-                # deliver it
-                from cake_tpu.args import INT8_KV_SPEC_ERROR
-                raise ValueError(INT8_KV_SPEC_ERROR)
-            if getattr(self.args, "kv_pages", None):
-                log.warning("--kv-pages ignored with --draft-model: the "
-                            "spec engine's target+draft caches are not "
-                            "paged")
-            if getattr(self.args, "kv_host_pages", None):
-                log.warning("--kv-host-pages ignored with --draft-model:"
-                            " the host KV tier spills paged pool pages")
-            if getattr(self.args, "auto_prefix", False):
-                log.warning("--auto-prefix ignored with --draft-model: "
-                            "prefix caching is not implemented for the "
-                            "spec engine (draft cache has no prefix "
-                            "install path)")
-            if getattr(self.args, "autotune", "off") != "off":
-                log.warning("--autotune ignored with --draft-model: "
-                            "speculative serving has no hot-switch "
-                            "fold (the draft cache cannot be rebuilt "
-                            "mid-round)")
-            slots = max_slots or getattr(self.args, "max_slots", 8)
-            return InferenceEngine(
-                g.config, g.params, g.tokenizer,
-                max_slots=slots,
-                max_seq_len=g.max_seq_len,
-                sampling=g.sampling,
-                seed=self.args.seed,
-                cache_dtype=g.cache.k.dtype,
-                draft_params=g.draft_params,
-                draft_config=g.draft_config,
-                spec_gamma=g.gamma,
-                **self._trace_kwargs(),
-                **self._sched_kwargs(),
-                **self._fault_kwargs(),
-                # passed through so the engine's own guard WARNS that
-                # multi-step scans don't apply in speculative mode
-                # (each round already advances up to gamma+1 tokens),
-                # instead of the flag silently vanishing
-                decode_scan_steps=self.args.decode_scan,
-                **engine_kwargs,
-            )
         fwd = getattr(g, "_forward_fn", None)
         if fwd is not None and g.parallel is None:
             # custom forward without a (plan, mesh): the --sp adapter.
@@ -255,12 +198,12 @@ class Master:
         )
 
     def _spec_kwargs(self) -> dict:
-        """Paged speculative decoding (cake_tpu/spec): load the draft
-        model behind --spec-draft and hand the engine its params +
-        config (the engine builds the paged draft pool itself, sized by
-        the target pool's page geometry). Config resolution mirrors
-        context._load_speculative; the draft stays unquantized
-        (--quant targets the big model — a paged draft is small by
+        """Speculative decoding (cake_tpu/spec): load the draft model
+        behind --spec-draft and hand the engine its params + config
+        (the engine builds the paged draft pool itself, sized by the
+        target pool's page geometry). A directory without a
+        config.json is the tiny config; the draft stays unquantized
+        (--quant targets the big model — a draft is small by
         construction)."""
         d_dir = getattr(self.args, "spec_draft", None)
         if not d_dir:
@@ -287,7 +230,7 @@ class Master:
                 "tokenizer")
         d_params = load_text_params(d_cfg, d_dir,
                                     resolve_dtype(self.args.dtype))
-        log.info("paged speculative serving: gamma=%d draft=%s",
+        log.info("speculative serving: gamma=%d draft=%s",
                  self.args.spec_gamma, d_dir)
         return dict(spec_draft_params=d_params,
                     spec_draft_config=d_cfg,
@@ -319,8 +262,8 @@ class Master:
     def _sched_kwargs(self) -> dict:
         """SLO scheduling knobs (--priority-classes / --preemption /
         --shed), plumbed to every engine flavor; the engine itself
-        warns and degrades when a flavor cannot preempt (speculative,
-        windowed ctx+tail layouts)."""
+        warns and degrades when a flavor cannot preempt (windowed
+        ctx+tail layouts)."""
         return dict(
             priority_classes=getattr(self.args, "priority_classes",
                                      False),
@@ -348,9 +291,9 @@ class Master:
         (--fault-plan / --recovery / --journal / --journal-fsync),
         plumbed to every engine flavor; the engine warns and keeps the
         legacy fail-all path where the resume fold does not exist
-        (speculative, windowed ctx+tail layouts — the journal still
-        records and replays there, through the same resume path
-        checkpoints use)."""
+        (windowed ctx+tail layouts — the journal still records and
+        replays there, through the same resume path checkpoints
+        use)."""
         return dict(
             fault_plan=getattr(self.args, "fault_plan", None),
             recovery=getattr(self.args, "recovery", None),

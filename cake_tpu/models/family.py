@@ -43,8 +43,6 @@ _CANNOT_MOVE = {
                   "has no {noun})",
     "topology": "a topology / --tp / --dp / --sp (no step program of a "
                 "mesh carries a {noun})",
-    "--draft-model": "--draft-model (nothing takes back the {noun} a "
-                     "rejected draft wrote)",
     "--spec-draft": "--spec-draft (nothing takes back the {noun} a "
                     "rejected draft wrote)",
     "--kv-dtype": "--kv-dtype int8/int4 (the quantized pools hold K/V "

@@ -1455,6 +1455,8 @@ class StepTelemetry:
         cause = admitted = late = None
         if chained:
             if fetch_wait_s is not None:
+                # decided from the wait as the record holds it
+                fetch_wait_s = round(fetch_wait_s, 6)
                 late = fetch_wait_s < LATE_FETCH_S
         else:
             fetch_wait_s = None

@@ -313,7 +313,8 @@ class EngineStats:
     # and guard-driven reverts (engine.reconfigure)
     config_switches: int = 0
     config_rollbacks: int = 0
-    # speculative engine mode: drafts offered / kept across all slots
+    # speculative rounds (cake_tpu/spec): drafts offered / kept across
+    # all rows
     spec_proposed: int = 0
     spec_accepted: int = 0
 
@@ -411,8 +412,6 @@ class InferenceEngine:
         prefill_chunk: Optional[int] = None,
         top_logprobs_cap: int = 20,
         ring: Optional[bool] = None,
-        draft_params=None,
-        draft_config=None,
         spec_gamma: int = 4,
         spec_draft_params=None,
         spec_draft_config=None,
@@ -558,58 +557,22 @@ class InferenceEngine:
                 + (" (ring/sliding-window serving requires a chunk that "
                    "divides max_seq_len; pass --prefill-chunk)"
                    if self.ring else ""))
-        # speculative decoding INSIDE the engine (round-5: the former
-        # single-request island now composes with API batching and
-        # checkpointing): a draft model proposes spec_gamma tokens per
-        # slot round, the target verifies them in one pass
-        # (speculative.spec_round_batched), and the engine batches
-        # rounds across slots — each round emits 1..gamma+1 tokens.
-        self._spec = draft_params is not None
-        self.draft_params = draft_params
-        self.draft_config = draft_config
+        # speculative decoding (cake_tpu/spec): a KIND OF ROW of the
+        # paged engine — a draft model's KV lives in a second paged
+        # pool addressed by the SAME page allocator, streams opt in
+        # lazily per-row (incompatible sampling simply decodes plain),
+        # and acceptance truncates the speculative suffix pages back
+        # to the pool every round.
         self.spec_gamma = spec_gamma
-        if self._spec:
-            if step_fns is not None or self.ring:
-                raise ValueError(
-                    "the speculative engine requires the built-in dense "
-                    "single-device path (no topology/ring step fns)")
-            if draft_config.vocab_size != config.vocab_size:
-                raise ValueError(
-                    "draft and target must share a vocabulary")
-            if prefill_chunk is not None:
-                log.warning("prefill_chunk ignored in speculative mode "
-                            "(whole-prompt prefill keeps the draft cache "
-                            "aligned)")
-                prefill_chunk = None
-            if self._decode_scan > 1:
-                log.warning("decode_scan ignored in speculative mode "
-                            "(each spec round already amortizes up to "
-                            "gamma+1 tokens per dispatch)")
-                self._decode_scan = 1
-            # a prefix-cached target prefill would leave the draft cache
-            # cold at those positions — acceptance would silently
-            # collapse; keep the caches aligned instead
-            self._prefix_capable = False
-            self.d_rope = RopeTables.create(draft_config, max_seq_len)
-        # PAGED speculative decoding (cake_tpu/spec): spec as a row
-        # KIND of the paged engine, not a separate engine — a draft
-        # model's KV lives in a second paged pool addressed by the SAME
-        # page allocator, streams opt in lazily per-row (incompatible
-        # sampling simply decodes plain), and acceptance truncates the
-        # speculative suffix pages back to the pool every round.
         self._spec_paged = spec_draft_params is not None
         self._specp = None
         if self._spec_paged:
             from cake_tpu.spec import SpecPlane
-            if self._spec:
-                raise ValueError(
-                    "--spec-draft (paged spec rows) and --draft-model "
-                    "(the dense spec engine) are mutually exclusive")
             if kv_pages is None:
                 raise ValueError(
-                    "--spec-draft requires --kv-pages: paged "
-                    "speculative decoding shares the page allocator "
-                    "(use --draft-model for the dense spec engine)")
+                    "--spec-draft requires --kv-pages: a speculating "
+                    "row's draft and target KV share the paged pool's "
+                    "page allocator")
             if kv_dtype in ("int8", "int4"):
                 raise ValueError(
                     f"--spec-draft requires f32/bf16 KV pages, got "
@@ -638,8 +601,7 @@ class InferenceEngine:
         # nibble-packed int4 pages + per-page per-kv-head f32 scales —
         # ~4x / ~8x the resident streams per pool byte vs f32); other
         # names resolve to a plain pool dtype. Quantized KV without
-        # --kv-pages (the spec engine included: spec is gated off
-        # paged) is a loud config error, not a silent no-op.
+        # --kv-pages is a loud config error, not a silent no-op.
         self.kv_quant = kv_dtype in ("int8", "int4")
         # config identity the live-reconfiguration seam (reconfigure /
         # cake_tpu/autotune) needs verbatim: the configured storage
@@ -670,10 +632,7 @@ class InferenceEngine:
         if self.kv_quant and not self.paged:
             raise ValueError(
                 f"--kv-dtype {kv_dtype} requires --kv-pages: quantized "
-                "KV pages live in the paged pool"
-                + (" (speculative serving is gated off the paged "
-                   "engine, so it cannot quantize KV)" if self._spec
-                   else ""))
+                "KV pages live in the paged pool")
         self._host_tier = None
         # pid -> monotonic last-hit time (the cold-prefix LRU order)
         self._prefix_last_hit: dict = {}
@@ -704,7 +663,6 @@ class InferenceEngine:
         refusal = family.refusal({
             "--kv-pages": not self.paged,
             "topology": step_fns is not None,
-            "--draft-model": self._spec,
             "--spec-draft": self._spec_paged,
             "--kv-dtype": self.kv_quant,
             "--kv-host-pages": kv_host_pages is not None,
@@ -713,10 +671,10 @@ class InferenceEngine:
         if refusal:
             raise ValueError(refusal)
         if self.paged:
-            if step_fns is not None or self.ring or self._spec:
+            if step_fns is not None or self.ring:
                 raise ValueError(
                     "--kv-pages requires the built-in dense single-"
-                    "device path (no topology/ring/speculative mode)")
+                    "device path (no topology/ring)")
             if cache is not None:
                 raise ValueError(
                     "--kv-pages builds its own page pool; a pre-placed "
@@ -730,9 +688,6 @@ class InferenceEngine:
         if not self.paged:
             self.cache = cache if cache is not None else KVCache.create(
                 config, max_slots, cache_len, dtype=cache_dtype)
-        if self._spec:
-            self.d_cache = KVCache.create(draft_config, max_slots,
-                                          cache_len, dtype=cache_dtype)
         # remember placement so the post-error rebuild (see _run) restores
         # an identically-sharded cache even after donation freed the buffers
         self._capture_cache_identity()
@@ -744,7 +699,7 @@ class InferenceEngine:
         # the priority-free fallback.
         self._sched_cfg = sched_config or SchedConfig()
         self._slo = bool(priority_classes)
-        can_preempt = not self._spec and self.decode_budget is None
+        can_preempt = self.decode_budget is None
         if preemption is None:
             self._preemption = self._slo and can_preempt
         else:
@@ -755,19 +710,16 @@ class InferenceEngine:
             self._preemption = False
         if self._preemption and not can_preempt:
             log.warning(
-                "preemption disabled: %s",
-                "speculative serving keeps the draft cache aligned "
-                "with the target per round (no recompute-resume path)"
-                if self._spec else
-                "windowed (ctx+tail) layouts cannot fold generated "
-                "tokens back into the prompt window")
+                "preemption disabled: windowed (ctx+tail) layouts "
+                "cannot fold generated tokens back into the prompt "
+                "window")
             self._preemption = False
         # crash recovery (the fail-everything replacement): on a step
         # failure, snapshot-classify-reset-RESUBMIT the in-flight
         # requests through the checkpoint fold-tokens-into-prompt path
         # instead of failing them all. Auto-on wherever the fold works
-        # (the same flavors preemption can resume); speculative and
-        # windowed (ctx+tail) engines keep the legacy fail-all path.
+        # (the same flavors preemption can resume); windowed
+        # (ctx+tail) engines keep the legacy fail-all path.
         from cake_tpu.serve.errors import RecoveryConfig
         self._recovery_cfg = recovery_config or RecoveryConfig()
         if recovery is None:
@@ -776,11 +728,9 @@ class InferenceEngine:
             self._recover = bool(recovery)
             if self._recover and not can_preempt:
                 log.warning(
-                    "crash recovery disabled: %s",
-                    "speculative serving has no recompute-resume fold"
-                    if self._spec else
-                    "windowed (ctx+tail) layouts cannot fold generated "
-                    "tokens back into the prompt window")
+                    "crash recovery disabled: windowed (ctx+tail) "
+                    "layouts cannot fold generated tokens back into "
+                    "the prompt window")
                 self._recover = False
         # reset-storm breaker state: monotonic times of recent resets
         # (recovered OR legacy), consecutive-reset counter for backoff,
@@ -864,8 +814,7 @@ class InferenceEngine:
         # this engine's config so two engines with different configs
         # (or cache dtypes) can never alias each other's compiled
         # signatures in the process-global seen-set.
-        flavor = ("spec" if self._spec else
-                  f"paged-{self.paged_attn}" if self.paged else
+        flavor = (f"paged-{self.paged_attn}" if self.paged else
                   "ring" if self.ring else
                   "custom" if step_fns is not None else "dense")
         self.flight = obs_steps.StepTelemetry(
@@ -1338,21 +1287,6 @@ class InferenceEngine:
         d = self.defaults
         eff_temp = temperature if temperature is not None else d.temperature
         eff_top_p = top_p if top_p is not None else d.top_p
-        if self._spec:
-            # the accept/resample identity assumes the unfiltered
-            # temperature softmax, and the verify pass scores the burst
-            # in parallel (no within-burst penalty ring) — reject
-            # incompatible sampling with a clean client error
-            eff_pen = (d.repeat_penalty if repeat_penalty is None
-                       else repeat_penalty)
-            if (eff_top_p or 1.0) < 1.0 or eff_pen != 1.0:
-                raise ValueError(
-                    "speculative serving supports temperature-only "
-                    "sampling (top_p=1, repeat_penalty=1)")
-            if want_top_logprobs:
-                raise ValueError(
-                    "logprobs are unavailable in speculative serving "
-                    "(accepted drafts are not sampled step-by-step)")
         replayed = list(replay_tokens or ())
         if replayed and ids[-len(replayed):] != replayed:
             # the replay coordinate must be a literal suffix of the
@@ -1677,12 +1611,7 @@ class InferenceEngine:
             # name the ACTUAL refusal per engine flavor — the paged
             # engine serves prefixes now (page-granular sharing), so a
             # one-size message would blame the wrong subsystem
-            if self._spec:
-                reason = ("speculative serving keeps the draft cache "
-                          "aligned with the target, and a prefix-cached "
-                          "target prefill would leave the draft cold "
-                          "(acceptance would silently collapse)")
-            elif not self._family.moves("register_prefix"):
+            if not self._family.moves("register_prefix"):
                 reason = self._family.refuses["register_prefix"]
             elif self.ring:
                 reason = ("ring sliding-window caches own their layout "
@@ -2040,7 +1969,7 @@ class InferenceEngine:
     def _drive_burst(self, dispatch, complete, chain_break,
                      first_unconditional: bool = False) -> None:
         """THE double-buffered dispatch/fetch driver, shared by the
-        decode burst and the speculative burst: dispatch k+1 (chained
+        decode burst and the mixed burst: dispatch k+1 (chained
         from k's on-device state, zero host round-trips between
         dispatches) BEFORE completing k, so k's device-to-host fetch
         overlaps k+1's device compute.
@@ -2215,9 +2144,7 @@ class InferenceEngine:
                     else:
                         for rid, slot in prefill_plan:
                             self._do_prefill(rid, slot)
-                    if decode_plan and self._spec:
-                        self._do_decode_spec(decode_plan)
-                    elif decode_plan:
+                    if decode_plan:
                         self._decode_rows(decode_plan,
                                           chain=not prefill_plan)
                 if getattr(self, "_fail_recs", None) is not None:
@@ -2914,18 +2841,15 @@ class InferenceEngine:
 
     def _reconfig_supported(self) -> bool:
         return (not self._custom_steps and not self.ring
-                and not self._spec and not self._spec_paged
+                and not self._spec_paged
                 and not self._multihost
                 and self._family.moves("reconfigure"))
 
     def _reconfig_refusal(self) -> str:
         if not self._family.moves("reconfigure"):
             return self._family.refuses["reconfigure"]
-        if self._spec:
-            return ("speculative serving has no hot-switch fold (the "
-                    "draft cache cannot be rebuilt mid-round)")
         if self._spec_paged:
-            return ("paged speculative serving has no hot-switch fold "
+            return ("speculative serving has no hot-switch fold "
                     "(the draft pool shares the page allocator a "
                     "switch would swap wholesale)")
         if self.ring:
@@ -3459,10 +3383,6 @@ class InferenceEngine:
         # failed call they may already be deleted — rebuild so the engine
         # survives (transient OOM/XLA error must not brick serving)
         self.cache = self._fresh_cache()
-        if self._spec:
-            self.d_cache = KVCache.create(
-                self.draft_config, self.max_slots,
-                self.cache.max_seq_len, dtype=self._cache_dtype)
         self._pos[:] = 0
         self._last_tok[:] = 0
         self._steps[:] = 0
@@ -5105,14 +5025,6 @@ class InferenceEngine:
             logits, self.cache = self._prefill_slot(*fargs)
             js.finish(time.perf_counter() - t0)
             self._last_jit = js
-            if self._spec:
-                # the draft's KV must cover the prompt too (its
-                # proposals attend the same positions the target
-                # verifies)
-                _, self.d_cache = self._prefill_slot(
-                    self.draft_params, toks, plen, jnp.int32(slot),
-                    self.d_cache, self.d_rope, self.draft_config,
-                )
         return logits
 
     def _prefill_device(self, ids, slot: int, temp: float, top_p: float,
@@ -5197,158 +5109,6 @@ class InferenceEngine:
                 js.finish(time.perf_counter() - t0)
                 self._last_jit = js
         return logits
-
-    @engine_thread_only
-    def _do_decode_spec(self, decode_plan) -> None:
-        """One propose-verify-accept round for ALL planned slots in ONE
-        compiled program (speculative.spec_round_batched): batched
-        ragged draft steps + one windowed verify pass, so the weights
-        stream once per round instead of once per slot (the old
-        per-slot spec_step_slot dispatches ran B batch-1 model passes —
-        measured 29 tok/s aggregate at 8 streams on a v5e; batched
-        rounds remove that B-times weight re-read). Speculation stays a
-        latency feature; the engine's win is CONCURRENCY — many clients
-        speculate together — plus API streaming and checkpoint/resume
-        composition."""
-        self._implicated = decode_plan
-        if self._faults is not None:
-            self._faults.check("engine.decode", step=self.stats.steps)
-        from cake_tpu.models.llama.speculative import spec_round_batched
-
-        t0 = time.perf_counter()
-        g = self.spec_gamma
-        B = self.max_slots
-        plan = []
-        for rid, slot in decode_plan:
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            if self._pos[slot] + g + 1 >= self.max_seq_len:
-                # the round writes g+1 cache positions; too close to the
-                # window end, finish at the cap (loses at most gamma
-                # tokens of an already maxed-out context)
-                self._force_finish(req)
-                continue
-            plan.append((req, slot))
-        if not plan:
-            self.stats.decode_time_s += time.perf_counter() - t0
-            return
-        active = np.zeros(B, bool)
-        for _, slot in plan:
-            active[slot] = True
-        active_dev = jnp.asarray(active)
-        temp_dev = jnp.asarray(self._temp)
-
-        def dispatch(state):
-            if state is None:
-                last = jnp.asarray(self._last_tok[:, None], jnp.int32)
-                pos = jnp.asarray(
-                    np.minimum(self._pos, self.max_seq_len - 1),
-                    jnp.int32)
-            else:
-                last, pos = state
-            fargs = (self.params, self.draft_params, self.cache,
-                     self.d_cache, last, pos, active_dev, self._keys,
-                     temp_dev, self.rope, self.d_rope, self.config,
-                     self.draft_config, g)
-            js = self._obs_jit("spec_round", (g,), spec_round_batched,
-                               fargs)
-            t0d = time.perf_counter()
-            (out, n_emit, self.cache, self.d_cache, self._keys,
-             state_o) = spec_round_batched(*fargs)
-            disp = time.perf_counter() - t0d
-            js.finish(disp)
-            return (out, n_emit, disp, js), state_o
-
-        def complete(devs):
-            out_d, n_emit_d, disp_k, js_k = devs
-            # ONE batched fetch for every slot's round
-            t0f = time.perf_counter()
-            out_h, n_emit_h = jax.device_get((out_d, n_emit_d))
-            fetch = time.perf_counter() - t0f
-            round_tokens = 0
-            for req, slot in plan:
-                if req.done.is_set():
-                    # chained round dispatched before this req's EOS /
-                    # budget end was known — discard its junk (stats
-                    # too: post-EOS rounds condition on garbage)
-                    continue
-                n = int(n_emit_h[slot])
-                round_tokens += n
-                toks = [int(t) for t in out_h[slot, :n]]
-                self.stats.spec_proposed += g
-                self.stats.spec_accepted += n - 1
-                pos0 = int(self._pos[slot])
-                self._last_tok[slot] = toks[-1]
-                self._steps[slot] += n
-                for j, tok in enumerate(toks):
-                    # per-token position so _emit's cap check sees the
-                    # value a single-step loop would have had
-                    # (_do_decode_scan precedent — the post-burst
-                    # frontier would cap-finish the FIRST token of a
-                    # window-filling burst)
-                    self._pos[slot] = pos0 + j + 1
-                    self._emit(req, tok)
-                    if req.done.is_set():
-                        break   # EOS / budget mid-burst: drop the tail
-                # cache frontier for the next round: the burst wrote n
-                # accepted positions regardless of the emission budget;
-                # stale positions past it are masked like padding
-                self._pos[slot] = pos0 + n
-            self.stats.steps += 1
-            self._record_step("spec", rows=len(plan),
-                              tokens=round_tokens, dispatch_s=disp_k,
-                              device_s=fetch, wall_s=disp_k + fetch,
-                              js=js_k,
-                              rids=[req.rid for req, _s in plan])
-
-        # double-buffered chained rounds (single-host; multi-host spec
-        # has no engine), via the shared _drive_burst driver: round k+1
-        # is dispatched from round k's on-device state before round k's
-        # tokens are fetched. The window guard projects the device
-        # frontier by the worst case (g+1 per unfetched round); a round
-        # chained past a row's EOS computes junk the emit loop
-        # discards. The first round is unconditional: every planned row
-        # was admitted with room for >= 1 round (the force-finish guard
-        # above), and skipping it would leave the run loop spinning
-        # with full slots and a waiting queue.
-        def chain_break(n_inflight: int) -> Optional[str]:
-            for req, _ in plan:
-                if req.done.is_set():
-                    return "row_finished"
-                if (req.max_new_tokens - len(req.out_tokens)
-                        - n_inflight * (g + 1)) <= 0:
-                    return "budget"
-            if any(self._pos[s] + (n_inflight + 1) * (g + 1)
-                   >= self.max_seq_len for _, s in plan):
-                return "window_end"
-            return None
-
-        self._drive_burst(dispatch, complete, chain_break,
-                          first_unconditional=True)
-        self.stats.decode_time_s += time.perf_counter() - t0
-
-    def _force_finish(self, req: _Request) -> None:
-        """Finish a request that cannot receive another token (spec
-        window cap): the _emit finish tail, minus the token."""
-        self.scheduler.report(req.rid, 0, True)
-        req.finish_t = time.perf_counter()
-        if req.slot >= 0 and self._slot_req[req.slot] is req:
-            self._slot_req[req.slot] = None
-        self._requests.pop(req.rid, None)
-        if self._shed is not None:
-            self._shed.observe_retire()
-        self.stats.requests_completed += 1
-        self._journal_retire(req, "retired")
-        self.tracer.finish(req.rid, "retired",
-                           output_tokens=len(req.out_tokens))
-        if req.stream is not None:
-            try:
-                self._stream_out(
-                    req, self._incremental_text(req, final=True), True)
-            except Exception:  # noqa: BLE001
-                log.exception("stream callback failed rid=%d", req.rid)
-        req.done.set()
 
     # -- paged speculative decoding (cake_tpu/spec) ---------------------------
 
@@ -5491,8 +5251,8 @@ class InferenceEngine:
     def _spec_row_ready(self, rid: int, slot: int, g: int) -> bool:
         """Is this decode row riding THIS iteration's speculative
         round? Temperature-only sampling (top-p / repetition-penalty /
-        top-logprobs rows replay exactly on the plain path — dense-spec
-        submit() rejects them, the paged engine just declines per row),
+        top-logprobs rows replay exactly on the plain path: the round
+        declines the row, the engine serves the request),
         window room for a whole round, >= 1 emitted token (the round
         contract wants last_tok's KV unwritten at the decode frontier),
         and an enabled SpecState — activated lazily here, whatever path
@@ -5509,8 +5269,8 @@ class InferenceEngine:
             return False
         if self._pos[slot] + g + 1 >= self.max_seq_len:
             # too close to the window: the plain path finishes the
-            # stream at the cap (no dense-style _force_finish — the
-            # row loses speculation, not its tail tokens)
+            # stream at the cap (the row loses speculation, not its
+            # tail tokens)
             return False
         st = self._specp.spec_streams.get(slot)
         if st is not None and st.rid != req.rid:

@@ -1,10 +1,9 @@
-"""Paged speculative decoding — spec rows behind the one front door.
+"""Speculative decoding — spec rows behind the one front door.
 
-ROADMAP item 3's spec-on-paged-KV step: speculative decoding as a
-first-class ROW KIND in the paged serving engine instead of the dense
-single-sequence island in models/llama/speculative.py. Draft and
-target KV both live in paged pools addressed by the engine's ONE page
-allocator (same id space, same budget the admission gate counts); a
+Speculation is a ROW KIND of the paged serving engine (--spec-draft).
+Draft and target KV both live in paged pools addressed by the engine's
+ONE page allocator (same id space, same budget the admission gate
+counts); a
 stream's gamma-token speculative suffix occupies dedicated suffix
 pages that acceptance truncates back to the allocator after every
 round; and the acceptance-rate EMA closes the loop through the gamma
@@ -13,8 +12,7 @@ decode — never wedging it — with typed spec_round/spec_degraded
 events and cake_spec_* metrics.
 
 Layout:
-  accept.py — the accept/resample arithmetic (shared verbatim with the
-              dense rounds, which re-import it);
+  accept.py — the accept/resample arithmetic;
   round.py  — spec_round_paged, the one-launch batched draft+verify
               round over paged KV;
   state.py  — SpecState (per-stream pages + acceptance EMA) and

@@ -1,12 +1,9 @@
-"""The speculative accept/resample arithmetic, shared by every round.
+"""The accept/resample arithmetic of a speculative round.
 
-Moved out of models/llama/speculative.py so the PAGED round
-(cake_tpu/spec/round.py) and the dense rounds (_spec_round /
-spec_round_batched) consume literally the same functions — the subtle
-acceptance math (Leviathan et al., 2023 rejection sampling with the
-leftover-residual correction) exists exactly once. speculative.py
-re-imports these under their historical names, so the dense path's
-imports and tests are untouched.
+The subtle part of speculation (Leviathan et al., 2023: rejection
+sampling with the leftover-residual correction) apart from the round
+that moves KV (cake_tpu/spec/round.py), so that it is tested on arrays
+built by hand (tests/test_spec_accept.py).
 
 Everything here is branch-free jnp arithmetic on stacked logits —
 trace-safe inside any caller's jit, cache-layout agnostic (nothing

@@ -1,22 +1,20 @@
 """One batched propose-verify-accept round over PAGED KV.
 
-The dense engine's spec_round_batched, re-seated on the paged pool:
-the draft loop is a lax.scan of gamma+1 ragged paged decode steps
+The draft loop is a lax.scan of gamma+1 ragged paged decode steps
 (models/llama/paged.forward_ragged_paged — each step writes the draft
 token's KV into the DRAFT pool through the draft table row and attends
 it), the verify is ONE mixed-window pass with logits at every position
 (paged.verify_window_paged — target KV for positions pos..pos+gamma
 scatters into the target row's pages, suffix-extension pages included),
-and acceptance is the shared arithmetic in cake_tpu/spec/accept.py.
+and acceptance is the arithmetic in cake_tpu/spec/accept.py.
 
-Cache contract (identical to the dense round): last_tok sits at
-absolute `pos` with its KV not yet written in EITHER pool; the round
-writes positions pos..pos+gamma in both; positions past the accepted
-frontier hold masked garbage that the next round overwrites before
-attending (nothing rolls back). The CALLER (serve/engine._do_spec_paged)
-must have extended both table rows to cover pos+gamma inclusive —
-writes past the mapped pages are silently dropped by the -1 guard,
-which would zero an accepted position's KV.
+Cache contract: last_tok sits at absolute `pos` with its KV not yet
+written in EITHER pool; the round writes positions pos..pos+gamma in
+both; positions past the accepted frontier hold masked garbage that the
+next round overwrites before attending (nothing rolls back). The CALLER
+(serve/engine._do_spec_paged) must have extended both table rows to
+cover pos+gamma inclusive — writes past the mapped pages are silently
+dropped by the -1 guard, which would zero an accepted position's KV.
 
 Both pools share one PageAllocator id space (the draft pool is created
 with the target pool's page geometry), so this round needs no allocator

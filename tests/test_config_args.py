@@ -13,8 +13,7 @@ def test_defaults_match_reference():
     assert a.seed == 299792458          # lib.rs default
     assert a.sample_len == 100
     # repeat_penalty is a None sentinel so explicit values are
-    # distinguishable (speculative mode resolves unset to 1.0); the
-    # EFFECTIVE default for normal serving is still the reference's 1.1
+    # distinguishable; the EFFECTIVE default is the reference's 1.1
     assert a.repeat_penalty is None
     assert a.repeat_last_n == 128
     assert a.address == "127.0.0.1:10128"
@@ -22,8 +21,8 @@ def test_defaults_match_reference():
 
 
 def test_repeat_penalty_effective_defaults(tiny_config):
-    """Unset --repeat-penalty resolves to 1.1 (reference) for normal
-    serving and 1.0 for speculative serving; explicit values flow as-is."""
+    """Unset --repeat-penalty resolves to 1.1 (the reference's), whatever
+    else is asked for; explicit values flow as-is."""
     from cake_tpu.context import Context
 
     def sampling_for(**kw):
@@ -32,8 +31,20 @@ def test_repeat_penalty_effective_defaults(tiny_config):
         return Context.from_args(args).load_text_model().sampling
 
     assert sampling_for().repeat_penalty == 1.1
-    assert sampling_for(draft_model="").repeat_penalty == 1.0
+    assert sampling_for(spec_draft="d", kv_pages=16).repeat_penalty == 1.1
     assert sampling_for(repeat_penalty=1.3).repeat_penalty == 1.3
+
+
+@pytest.mark.parametrize("gone", [["--draft-model", "x"],
+                                  ["--spec-rounds", "2"]],
+                         ids=lambda gone: gone[0])
+def test_the_dense_speculative_engines_options_are_gone(gone):
+    """Speculation is a kind of row of the paged engine (--spec-draft);
+    the dense draft-and-verify engine's two options are unknown to the
+    parser, like any other word it never heard of."""
+    with pytest.raises(SystemExit) as e:
+        parse_args(["--model", "/tmp/m", *gone])
+    assert e.value.code == 2
 
 
 def test_parse_args_roundtrip():

@@ -562,7 +562,7 @@ def test_the_checkpoint_loader_says_what_it_cannot_name():
 
 @pytest.mark.parametrize("asked,named", [
     ("--kv-pages", "--kv-pages"), ("topology", "topology"),
-    ("--draft-model", "--draft-model"), ("--spec-draft", "--spec-draft"),
+    ("--spec-draft", "--spec-draft"),
     ("--kv-dtype", "--kv-dtype"), ("--kv-host-pages", "--kv-host-pages"),
     ("--disagg", "--disagg"), ("--auto-prefix", "--auto-prefix")])
 def test_family_refuses_by_name_beside_a_ring(asked, named):

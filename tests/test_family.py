@@ -128,12 +128,11 @@ def test_a_recorder_refuses_a_key_without_a_series():
 
 
 def engine_options(c, params):
-    """The eight options of the refusal table, as the engine's
+    """The seven options of the refusal table, as the engine's
     constructor is handed each."""
     return {
         "--kv-pages": dict(kv_pages=None),
         "topology": dict(step_fns=(print, print)),
-        "--draft-model": dict(draft_params=params, draft_config=c),
         "--spec-draft": dict(spec_draft_params=params, spec_draft_config=c,
                              spec_gamma=2),
         "--kv-dtype": dict(kv_dtype="int8"),

@@ -423,9 +423,6 @@ def test_args_validate_int8_rules():
     from cake_tpu.args import Args
     with pytest.raises(ValueError, match="requires --kv-pages"):
         Args(kv_dtype="int8").validate()
-    with pytest.raises(ValueError, match="draft-model"):
-        Args(kv_dtype="int8", kv_pages=64,
-             draft_model="x").validate()
     with pytest.raises(ValueError, match="kv-host-pages"):
         Args(kv_host_pages=0).validate()
     Args(kv_dtype="int8", kv_pages=64, kv_host_pages=4).validate()
@@ -439,32 +436,7 @@ def test_args_validate_int4_rules():
         Args(kv_dtype="int4").validate()
     with pytest.raises(ValueError, match="even --kv-page-size"):
         Args(kv_dtype="int4", kv_pages=64, kv_page_size=31).validate()
-    with pytest.raises(ValueError, match="draft-model"):
-        Args(kv_dtype="int4", kv_pages=64,
-             draft_model="x").validate()
     Args(kv_dtype="int4", kv_pages=64, kv_host_pages=4).validate()
-
-
-def test_master_spec_engine_int8_is_loud(tiny_config):
-    """--kv-dtype int8 with the spec engine is a config ERROR (spec is
-    gated off paged), not a silently-ignored flag."""
-    from cake_tpu.args import Args
-    from cake_tpu.master import Master
-    from cake_tpu.models.llama.generator import ByteTokenizer
-    from cake_tpu.models.llama.params import init_params
-    from cake_tpu.models.llama.speculative import SpeculativeGenerator
-    from cake_tpu.ops.sampling import SamplingConfig
-
-    args = Args(max_slots=2)
-    args.kv_dtype = "int8"      # past validate(), straight to master
-    p = init_params(tiny_config, jax.random.PRNGKey(0))
-    gen = SpeculativeGenerator(
-        tiny_config, p, tiny_config, p,
-        ByteTokenizer(tiny_config.vocab_size), max_seq_len=T,
-        sampling=SamplingConfig(temperature=1.0, repeat_penalty=1.0))
-    master = Master(args, text_generator=gen)
-    with pytest.raises(ValueError, match="draft-model"):
-        master.make_engine()
 
 
 # -- engine: int8 serving -----------------------------------------------------

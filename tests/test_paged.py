@@ -208,12 +208,6 @@ def test_engine_paged_oversubscription(tiny_config, params):
     assert eng._slot_pages == {}
 
 
-def test_engine_paged_rejects_bad_compositions(tiny_config, params):
-    with pytest.raises(ValueError, match="kv-pages"):
-        _engine(tiny_config, params, kv_pages=8, kv_page_size=PAGE,
-                draft_params=params, draft_config=tiny_config)
-
-
 def test_engine_paged_large_pages_small_prompts(tiny_config, params):
     """Page size LARGER than the prefill bucket (the default-config
     shape: 128-token pages, short prompts bucket to 32): prompt KV must

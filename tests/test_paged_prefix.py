@@ -452,19 +452,6 @@ def test_auto_prefix_heals_stale_entry_after_reset(tiny_config, params):
 def test_register_refusals_name_their_reason(tiny_config, params):
     """Each remaining refusal names its ACTUAL cause (the old message
     blamed ring/custom step fns for every engine flavor)."""
-    from cake_tpu.models.llama.generator import ByteTokenizer
-    from cake_tpu.ops.sampling import SamplingConfig
-    from cake_tpu.serve.engine import InferenceEngine
-
-    # speculative: the draft cache has no prefix install path
-    spec = InferenceEngine(
-        tiny_config, params, ByteTokenizer(tiny_config.vocab_size),
-        max_slots=2, max_seq_len=T,
-        sampling=SamplingConfig(temperature=0.0, repeat_penalty=1.0),
-        draft_params=params, draft_config=tiny_config)
-    with pytest.raises(ValueError, match="draft"):
-        spec.register_prefix([5] * 20)
-
     # paged: shorter than one page -> nothing to share, says so
     eng = _engine(tiny_config, params)
     with pytest.raises(ValueError, match="page-granular"):

@@ -240,15 +240,3 @@ def test_unknown_priority_rejected(tiny_config, params):
     eng = _engine(tiny_config, params)
     with pytest.raises(ValueError, match="priority"):
         eng.submit([5] * 4, max_new_tokens=2, priority="vip")
-
-
-def test_preemption_gated_off_for_speculative(tiny_config, params):
-    """Spec engines take priority ordering but warn preemption off (no
-    recompute-resume path keeps the draft cache aligned)."""
-    import jax
-    from cake_tpu.models.llama.params import init_params
-    d_params = init_params(tiny_config, jax.random.PRNGKey(1),
-                           dtype=jnp.float32)
-    eng = _engine(tiny_config, params, preemption=True,
-                  draft_params=d_params, draft_config=tiny_config)
-    assert eng._slo and not eng._preemption

@@ -244,8 +244,8 @@ def test_gamma_tuner_narrows_with_hysteresis():
 
 
 def test_spec_paged_rejects_incompatible_flavors(tiny_config, params):
-    """Constructor refusals name their reason: quantized KV pools,
-    missing paging, and the dense spec engine are all incompatible."""
+    """Constructor refusals name their reason: quantized KV pools and
+    missing paging are incompatible."""
     from cake_tpu.models.llama.generator import ByteTokenizer
     from cake_tpu.ops.sampling import SamplingConfig
     from cake_tpu.serve.engine import InferenceEngine
@@ -267,6 +267,260 @@ def test_spec_paged_rejects_incompatible_flavors(tiny_config, params):
         build(kv_pages=PAGES, kv_page_size=PAGE, kv_dtype="int8")
     with pytest.raises(ValueError, match="gamma"):
         build(kv_pages=PAGES, kv_page_size=PAGE, spec_gamma=0)
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        build(kv_pages=PAGES, kv_page_size=PAGE,
-              draft_params=params, draft_config=tiny_config)
+
+
+# -- a row's sampling decides whether it speculates, never whether it is served
+
+
+def _tokens(eng, *subs, gen=GEN):
+    """Submit (prompt, sampling options) pairs to a started engine, in
+    order; every stream's tokens once all are done."""
+    hs = [eng.submit(list(p), max_new_tokens=gen, **kw) for p, kw in subs]
+    assert all(h.wait(timeout=600) for h in hs), "wave timed out"
+    assert all(h._req.error is None for h in hs)
+    return [list(h._req.out_tokens) for h in hs]
+
+
+GREEDY_ROW = dict(temperature=0.0, repeat_penalty=1.0)
+HOT_ROW = dict(temperature=0.9, repeat_penalty=1.0)
+
+
+def test_more_requests_than_slots_chain_and_the_queue_drains(
+        tiny_config, params):
+    """Three requests on two slots: the third waits behind full slots
+    that speculate round after round, is admitted when one retires,
+    and all three are the plain engine's streams to the token."""
+    subs = [([5] * 9, GREEDY_ROW)] * 3
+    with _engine(tiny_config, params) as plain:
+        want = _tokens(plain, *subs, gen=30)
+    eng = _engine(tiny_config, params, **_spec_kw(params, tiny_config))
+    with eng:
+        got = _tokens(eng, *subs, gen=30)
+    assert got == want and [len(t) for t in got] == [30] * 3
+    # fewer rounds than tokens: rows rode rounds of more than one token
+    rounds = eng.events.dump(type="spec_round")
+    assert 0 < sum(e["rows"] for e in rounds) < 90
+    _pool_conserved(eng)
+
+
+def test_greedy_row_beside_a_temperature_row_is_the_plain_stream(
+        tiny_config, params, mismatched_draft, plain_dense):
+    """One round holds a greedy and a sampled row: the greedy row's
+    stream is the one it has alone and the one a plain engine gives it
+    (its key never advances, its acceptance is exact-match), whatever
+    the hot row beside it draws."""
+    d_params, d_cfg = mismatched_draft
+
+    def cold(*beside):
+        eng = _engine(tiny_config, params, **_spec_kw(d_params, d_cfg))
+        with eng:
+            out = _tokens(eng, (P1, GREEDY_ROW), *beside)
+        _pool_conserved(eng)
+        return out[0]
+
+    assert cold() == cold((P2, HOT_ROW)) == plain_dense[0]
+
+
+def test_temperature_row_repeats_under_its_seed_and_differs_under_another(
+        tiny_config, params, mismatched_draft):
+    """A sampled row speculates through rejection sampling: the same
+    engine seed gives the same stream twice, another seed another."""
+    d_params, d_cfg = mismatched_draft
+
+    def hot(seed):
+        eng = _engine(tiny_config, params, seed=seed,
+                      **_spec_kw(d_params, d_cfg))
+        with eng:
+            out = _tokens(eng, (P1, HOT_ROW))
+        assert eng.stats.spec_proposed > 0
+        return out[0]
+
+    a = hot(7)
+    assert len(a) == GEN and all(0 <= t < tiny_config.vocab_size
+                                 for t in a)
+    assert hot(7) == a
+    assert hot(8) != a
+
+
+@pytest.mark.parametrize("option", [
+    dict(temperature=0.8, top_p=0.9, repeat_penalty=1.0),
+    dict(temperature=0.0, repeat_penalty=1.3),
+    dict(temperature=0.8, repeat_penalty=1.0, want_top_logprobs=True),
+], ids=["top_p", "repeat_penalty", "top_logprobs"])
+def test_row_that_cannot_speculate_is_served_plain(tiny_config, params,
+                                                  option):
+    """Nucleus sampling, a repeat penalty and top-logprobs have no
+    accept/resample identity, so such a row rides no round: it is
+    served on the plain path, token for token what a plain engine
+    samples for it, while the greedy row beside it speculates."""
+    subs = [(P1, GREEDY_ROW), (P2, option)]
+    with _engine(tiny_config, params) as plain:
+        want = _tokens(plain, *subs)
+    eng = _engine(tiny_config, params, **_spec_kw(params, tiny_config))
+    with eng:
+        got = _tokens(eng, *subs)
+    assert got == want
+    rounds = eng.events.dump(type="spec_round")
+    assert rounds and all(e["rows"] == 1 for e in rounds)
+    _pool_conserved(eng)
+
+
+def test_row_near_the_windows_end_finishes_plain_with_its_tail(
+        tiny_config, params):
+    """Within gamma + 1 positions of max_seq_len a round's writes no
+    longer fit: the row leaves speculation and the plain path carries
+    it to the cap, every tail token a plain engine emits included."""
+    prompt = [(3 * j) % 50 + 3 for j in range(40)]
+    with _engine(tiny_config, params) as plain:
+        want = _tokens(plain, (prompt, GREEDY_ROW), gen=T)
+    eng = _engine(tiny_config, params, **_spec_kw(params, tiny_config))
+    with eng:
+        got = _tokens(eng, (prompt, GREEDY_ROW), gen=T)
+    assert got == want and len(got[0]) == T - len(prompt)
+    # it did speculate on the way, and not over the last gamma + 1
+    by_rounds = sum(e["tokens"] for e in eng.events.dump(type="spec_round"))
+    assert 0 < by_rounds <= len(got[0]) - (GAMMA + 1)
+    _pool_conserved(eng)
+
+
+def test_perfect_draft_accepts_every_proposal_and_the_series_move(
+        tiny_config, params):
+    """draft == target at f32: every round keeps all gamma drafts (the
+    proof of the plumbing: a draft row one position out of step with
+    its target row would crater it), the engine's counters say so, and
+    the plane's four series are the ones that report it."""
+    from cake_tpu.spec.state import (
+        SPEC_ACCEPT_RATIO, SPEC_ROUNDS, SPEC_TOKENS_PER_ROUND,
+    )
+    rounds0 = SPEC_ROUNDS.value
+    eng = _engine(tiny_config, params, max_slots=1,
+                  **_spec_kw(params, tiny_config))
+    with eng:
+        _tokens(eng, (P1, GREEDY_ROW), gen=1 + 3 * (GAMMA + 1))
+    st = eng.stats
+    assert st.spec_proposed == 3 * GAMMA
+    assert st.spec_accepted == st.spec_proposed
+    assert st.spec_acceptance == 1.0
+    assert SPEC_ROUNDS.value - rounds0 == 3
+    assert SPEC_ACCEPT_RATIO.value == 1.0
+    assert SPEC_TOKENS_PER_ROUND.value == GAMMA + 1
+    _pool_conserved(eng)
+
+
+# -- from the command line to the engine ---------------------------------------
+
+
+def _master(tmp_path, draft_config=None, **kw):
+    """A Master for `--spec-draft <dir>` over the weightless tiny
+    target; the draft directory holds a config.json or nothing."""
+    import json
+
+    from cake_tpu.args import Args
+    from cake_tpu.context import Context
+    from cake_tpu.master import Master
+    d_dir = tmp_path / "draft"
+    d_dir.mkdir()
+    if draft_config is not None:
+        (d_dir / "config.json").write_text(json.dumps(draft_config))
+    args = Args(model="", spec_draft=str(d_dir), spec_gamma=2,
+                kv_pages=2 * PAGES, kv_page_size=PAGE, max_seq_len=4 * T,
+                dtype="f32", temperature=0.0, repeat_penalty=1.0,
+                flash_attention=False, max_slots=2, **kw).validate()
+    return Master(args,
+                  text_generator=Context.from_args(args).load_text_model())
+
+
+def test_master_wires_spec_draft_from_args(tmp_path, tiny_config):
+    """--spec-draft from Args: the draft loads (drawn where the
+    directory holds no weights), the engine gets its plane at
+    --spec-gamma, and a request speculates."""
+    master = _master(tmp_path)
+    kw = master._spec_kwargs()
+    assert set(kw) == {"spec_draft_params", "spec_draft_config",
+                       "spec_gamma"}
+    assert kw["spec_gamma"] == 2
+    assert kw["spec_draft_config"].vocab_size == tiny_config.vocab_size
+    eng = master.make_engine()
+    assert eng._specp is not None and eng._specp.live_gamma == 2
+    with eng:
+        out, = _tokens(eng, (P1, GREEDY_ROW), gen=8)
+    assert len(out) == 8 and eng.stats.spec_proposed > 0
+
+
+def test_master_refuses_a_draft_of_another_vocabulary(tmp_path,
+                                                      tiny_config):
+    import dataclasses
+    cfg = {k: v for k, v in dataclasses.asdict(tiny_config).items()
+           if isinstance(v, (int, float, str)) or v is None}
+    cfg.update(vocab_size=tiny_config.vocab_size + 64,
+               architectures=["LlamaForCausalLM"])
+    master = _master(tmp_path, draft_config=cfg)
+    with pytest.raises(ValueError, match="share a tokenizer"):
+        master._spec_kwargs()
+
+
+def test_spec_draft_beside_a_topology_is_refused_by_name(tiny_config,
+                                                         params):
+    """A topology's step programs run no paged pool, and speculation
+    lives in one: the constructor says which option cannot stay."""
+    with pytest.raises(ValueError, match="--kv-pages requires the "
+                                         "built-in dense single-device"):
+        _engine(tiny_config, params, step_fns=(print, print),
+                **_spec_kw(params, tiny_config))
+
+
+@pytest.fixture(scope="module")
+def spec_server(tmp_path_factory):
+    from cake_tpu.api.server import start
+    master = _master(tmp_path_factory.mktemp("spec_api"), sample_len=6)
+    engine = master.make_engine()
+    httpd = start(master, address="127.0.0.1:0", block=False,
+                  engine=engine.start())
+    yield "http://%s:%d" % httpd.server_address[:2], engine
+    httpd.shutdown()
+    engine.stop()
+
+
+@pytest.mark.parametrize("stream", [False, True],
+                         ids=["buffered", "streamed"])
+def test_http_api_serves_a_spec_draft_engine(spec_server, stream):
+    """Two callers at once through the HTTP API of a --spec-draft
+    server, buffered and streamed: both are answered and their rows
+    rode speculative rounds."""
+    import json
+    import threading
+    import urllib.request
+    base, engine = spec_server
+    before = engine.stats.spec_proposed
+    answers = []
+
+    def one(msg):
+        req = urllib.request.Request(
+            base + "/api/v1/chat/completions",
+            data=json.dumps({
+                "messages": [{"role": "user", "content": msg}],
+                "max_tokens": 6, "stream": stream}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=300) as r:
+            answers.append(r.read().decode())
+
+    ts = [threading.Thread(target=one, args=(m,)) for m in ("hi", "yo")]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=300)
+    assert len(answers) == 2
+    for body in answers:
+        if stream:
+            events = [ln[6:] for ln in body.splitlines()
+                      if ln.startswith("data: ")]
+            assert events[-1] == "[DONE]"
+            last = json.loads(events[-2])
+            assert last["object"] == "chat.completion.chunk"
+            assert last["choices"][0]["finish_reason"] in ("stop",
+                                                           "length")
+        else:
+            obj = json.loads(body)
+            assert obj["choices"][0]["message"]["role"] == "assistant"
+    assert engine.stats.spec_proposed > before
+    assert 0.0 <= engine.stats.spec_acceptance <= 1.0
