@@ -31,8 +31,13 @@ stacks per kind of layer, as models/moe/glm_dsa.py's):
     starts from zeros with no read, and a row with no single token is
     neither read nor written. The function `kda_step` below is the same
     operations in jax.numpy: the comparison's form, not the served one.
-    `kda_chunk`: the chunked form (`kda_chunked`) over the dispatch's
-    one window, starting from the row's stored state. `kda_state` is
+    `kda_chunk`: the chunked form over the dispatch's one window,
+    starting from the row's stored state, served by ops/kda.chunked
+    (`cake_kda_chunk`): ONE kernel that holds a head block's state on
+    the chip from the window's first chunk to its last. The function
+    `kda_chunked` below is the same mathematics in XLA (a batched part,
+    then a 32-step scan that carries the state through HBM): the
+    comparison's form, not the served one. `kda_state` is
     what is left of the stored state's traffic outside the kernel: the
     conv tails (read and written once a layer), and the window row's
     own state, read before the step and written after the chunks (2 MiB
@@ -96,8 +101,8 @@ COUNTERS = glm_dsa.DENSE_COUNTERS + (
     "kda_tokens_chunked", "kda_tokens_stepped", "kda_state_rows")
 # tokens a chunk of the chunked rule holds. exp(-G) of a chunk's summed
 # log-decays must stay finite in float32: at the published bound of -5 a
-# token that is 16 tokens (5 x 16 = 80 < 88)
-CHUNK = 16
+# token that is 16 tokens (5 x 16 = 80 < 88): the kernel's own constant
+CHUNK = kda.CHUNK
 L2_EPS = 1e-6
 F32 = jnp.float32
 _mm = partial(jnp.einsum, precision=lax.Precision.HIGHEST)
@@ -221,8 +226,11 @@ def kda_chunked(S0, q, k, v, g, beta, chunk: int = CHUNK):
         U = W_v - W_k S,   o = Q+ S + P U,
         S' = Diag(e^G_Q) S + (k e^(G_Q - G))^T U.
     All float32 at the highest matmul precision: 2 GFLOP a layer and
-    window at the published widths, which the matrix unit does not
-    notice."""
+    window at the published widths. The form every comparison holds the
+    served path to (the tests, an interpreter run,
+    tools/kda_chunk_bench.py's other side); the served path runs
+    ops/kda.chunked, this mathematics inside one kernel. No step
+    program calls it."""
     C, H, dk = k.shape
     Q = min(chunk, C)
     pad = -C % Q
@@ -311,7 +319,7 @@ def kda_layer(lp, h, state, tails, j: int, slot, real, rows: Rows, first,
                 # the slice runs on into the next rows' tokens: past the
                 # window's own the state passes through unchanged
                 own = window.real[:, None]
-                S_win, ow = kda_chunked(
+                S_win, ow = kda.chunked(
                     S0,
                     *(_window_slice(x, window) for x in (q, k, v)),
                     jnp.where(own[..., None], _window_slice(g, window), 0.0),

@@ -1,4 +1,6 @@
-"""The delta rule's one-token form over the stored state, in place.
+"""The delta rule over the stored state: a row's single token in place
+(`step`, `cake_kda_step`) and a row's window chunked, the state held on
+the chip (`chunked`, `cake_kda_chunk`, the second half of this file).
 
 A KDA layer (models/moe/bailing_hybrid.py; the equations are
 models/reference/bailing_hybrid.py's) keeps a matrix state a row and
@@ -44,6 +46,46 @@ default scoped limit of 16 MiB, so the kernel sets no limit of its own.
 On a chip dk must fill sublane tiles (a multiple of 8) and dv lane
 tiles (of 128); the interpreter (no chip in sight: the tests) takes any
 width.
+
+A row that holds a WINDOW of a prompt takes the chunked form of the same
+rule (bailing_hybrid.kda_chunked states it: chunks of 16 tokens, a unit-
+lower-triangular solve a chunk by forward substitution, float32 operands
+and state, every product at the highest precision). In XLA that is a
+batched part and then a 32-step scan that carries the 2 MiB state
+through HBM and launches three 16-row products a step (650 us a layer
+and window on the chip). `chunked` (`cake_kda_chunk`) is the same
+mathematics with the state on the chip:
+
+  * grid (H / 8 head blocks, C / 128 chunk blocks), the chunk axis
+    innermost and sequential. A head block's state lies TRANSPOSED in a
+    VMEM scratch [dv, 8 dk] from the window's first chunk to its last:
+    S0 is read once and S_end written once (their blocks' indices do
+    not move along the chunk axis), q, k, v, g stream in once as they
+    lie ([C, H, d] in blocks of [128, 8, d]: a head's rows are strided
+    sublane loads) and `o` streams out once;
+  * what does not depend on the state runs first, a head at a time over
+    the block's 8 chunks: G (the running sum of g: a product with a
+    block-diagonal triangle of ones), K+, K-, Q+, K_end, e^G_Q, the
+    decayed scores of the block against itself in ONE product (a
+    chunk's own are its diagonal blocks: A, strictly lower, and P),
+    and (I + A) [W_v | W_k] = beta [V | K+] solved by forward
+    substitution on the vector unit: A's column s broadcasts along the
+    lanes, row s along the sublanes, 15 steps a chunk;
+  * what does runs a chunk after the other: [W_k; Q+] S a head (ONE
+    product of 32 rows), U = W_v - W_k S, and then the rank-16 updates
+    K_end^T U of all 8 heads as ONE product of [8 x 16, dv]^T against
+    K_end laid out block-diagonally [8 x 16, 8 dk], eight lane tiles
+    wide: a product one tile wide keeps one of the four matrix units
+    busy (eight [dv, 16] x [16, dk] products read 365 us a window where
+    the wide one reads 112); S <- e^G_Q S + that; o = Q+ S + P U over
+    the block at the end.
+
+What bounds it on the chip (PERF.md section 6, PR 59): 372 us a window
+and layer on float32 operands (393 with XLA's cast of a bfloat16 v in
+front) against XLA's 651 and 98 for a body that only moves the bytes. The copies are hidden; the matrix unit's six bfloat16 passes of
+each float32 product are not: the state's update alone is 112 us (its
+contraction is 16 deep in a unit 128 deep, and the highest precision is
+the cell's stated arithmetic).
 """
 
 from __future__ import annotations
@@ -263,3 +305,207 @@ def step(state, j, code, q, k, v, g, beta, interpret: Optional[bool] = None):
             "a head: dk must be a multiple of 8 and dv of 128")
     return _step_pallas(state, jnp.asarray(j, jnp.int32), code, q, k, v, g,
                         beta, interpret=interpret)
+
+
+# -- the chunked form over a window, the state held on the chip ----------------
+
+# tokens a chunk (the cell's configuration states it: at the bound of -5
+# a token e^-G of 16 summed log-decays is finite in float32), tokens a
+# grid step (whole chunks: 256 read 447 us a window on the chip where
+# 128 reads 372 and 64 386) and heads a program (a whole sublane tile
+# of the operands as they lie, [C, H, d]; 16 read what 8 do)
+CHUNK = 16
+CHUNK_BLOCK = 128
+CHUNK_HEADS = 8
+# the kernel's scoped VMEM, past the compiler's default of 16 MiB: at 8
+# heads of 128 x 128 the double-buffered blocks of q, k, v, g, o, S0 and
+# S_end are 7 MiB, the scratch 7.6 MiB (4 MiB of it the chunks' K_end
+# laid out block-diagonally), and the compiler's temporaries
+CHUNK_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def chunk_heads(H: int) -> int:
+    """Heads a program of the chunked kernel: the largest divisor of H
+    within CHUNK_HEADS."""
+    return max(d for d in range(1, min(H, CHUNK_HEADS) + 1) if H % d == 0)
+
+
+def _mm(a, b, contract):
+    """a . b over `contract` (a's axes, b's axes), float32 operands at
+    the highest precision."""
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=F32)
+
+
+def _chunk_kernel(s0_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, send_ref,
+                  o_ref, st, xs, wv, kbd, pm, us, oq, fe, *, Q: int,
+                  arith: bool):
+    """One grid step: hb heads x one block of n chunks of Q tokens.
+
+    s0_ref, send_ref [hb, dk, dv]: the state the window starts from and
+        leaves (a head block's, fetched and stored once a window)
+    q_ref, k_ref, g_ref [Cb, hb, dk], v_ref, o_ref [Cb, hb, dv]: the
+        block's tokens as they lie; beta_ref [Cb, hb]
+    st [dv, hb dk] VMEM: the heads' states TRANSPOSED and side by side
+        (a head a lane tile, dk on the lanes: a chunk's decay e^G_Q a
+        channel is a row that broadcasts along the sublanes), carried
+        from block to block
+    xs [hb, n, 2 Q, dk]: a chunk's W_k over its Q+ (ONE product with S)
+    wv, us, oq [hb, Cb, dv]: W_v, U and Q+ S
+    kbd [n, hb Q, hb dk]: a chunk's K_end of every head, block-diagonal
+        (head h's Q rows in lane tile h, zeros beside them)
+    pm [hb, Cb, Cb]: P, block-diagonal; fe [n, hb dk]: e^G_Q
+    """
+    c, nc = pl.program_id(1), pl.num_programs(1)
+    hb, dk, dv = s0_ref.shape
+    Cb = q_ref.shape[0]
+    n = Cb // Q
+
+    def head(h):        # head h's lane tile of the state
+        return slice(h * dk, (h + 1) * dk)
+
+    @pl.when(c == 0)
+    def _():
+        for h in range(hb):
+            st[:, head(h)] = s0_ref[h].T
+
+    if not arith:       # the bytes alone (tools/kda_chunk_bench.py)
+        o_ref[...] = v_ref[...]
+    else:
+        row = lax.broadcasted_iota(jnp.int32, (Cb, Cb), 0)
+        col = lax.broadcasted_iota(jnp.int32, (Cb, Cb), 1)
+        same = (row // Q) == (col // Q)
+        lower, strict = same & (col <= row), same & (col < row)
+        ones = jnp.where(lower, 1.0, 0.0).astype(F32)
+        zero = jnp.zeros((Q, dk), F32)
+        # what does not depend on S, a head at a time over the block
+        for h in range(hb):
+            q, k, g = q_ref[:, h, :], k_ref[:, h, :], g_ref[:, h, :]
+            beta = beta_ref[:, h:h + 1]
+            # G: the running sum of g from each chunk's start
+            G = _mm(ones, g, ((1,), (0,)))
+            G3 = G.reshape(n, Q, dk)
+            G_end = G3[:, Q - 1:Q, :]
+            fade = jnp.exp(G)
+            k_fade, k_grow, q_fade = k * fade, k * jnp.exp(-G), q * fade
+            k_end = (k.reshape(n, Q, dk)
+                     * jnp.exp(G_end - G3)).reshape(Cb, dk)
+            fe[:, head(h)] = jnp.exp(G_end).reshape(n, dk)
+            # Q+ K-^T over K+ K-^T, the block's tokens against the
+            # block's: a chunk's own are the diagonal blocks
+            sc = _mm(jnp.concatenate([q_fade, k_fade], axis=0), k_grow,
+                     ((1,), (1,)))
+            pm[h] = jnp.where(lower, sc[:Cb], 0.0)
+            A = jnp.where(strict, sc[Cb:], 0.0) * beta
+            # (I + A) [W_v | W_k] = beta [V | K+] by forward
+            # substitution, a chunk at a time: row s is final when its
+            # turn comes, and A's column s is zero down to row s
+            W = jnp.concatenate([beta * v_ref[:, h, :], beta * k_fade],
+                                axis=1)
+            for i in range(n):
+                at = slice(i * Q, (i + 1) * Q)
+                Wc = W[at]
+                for s in range(Q - 1):
+                    Wc = Wc - A[at, i * Q + s:i * Q + s + 1] * Wc[s:s + 1]
+                wv[h, at] = Wc[:, :dv]
+                xs[h, i, :Q] = Wc[:, dv:]
+                xs[h, i, Q:] = q_fade[at]
+                kbd[i, h * Q:(h + 1) * Q, :] = jnp.concatenate(
+                    [zero] * h + [k_end[at]] + [zero] * (hb - h - 1), axis=1)
+        # what does: a chunk after the other. U = W_v - W_k S and Q+ S a
+        # head; then every head's K_end^T U as ONE product, [hb Q, dv]^T
+        # against the block-diagonal [hb Q, hb dk]: 8 lane tiles wide
+        # (eight products of [dv, Q] by [Q, dk] read 365 us a window on
+        # the chip where this one reads 112)
+        for i in range(n):
+            at = slice(i * Q, (i + 1) * Q)
+            U = []
+            for h in range(hb):
+                XS = _mm(xs[h, i], st[:, head(h)], ((1,), (1,)))
+                U.append(wv[h, at] - XS[:Q])
+                us[h, at] = U[h]
+                oq[h, at] = XS[Q:]
+            st[...] = st[...] * fe[i:i + 1, :] + _mm(
+                jnp.concatenate(U, axis=0), kbd[i], ((0,), (0,)))
+        # o = Q+ S + P U, the block's chunks at once
+        for h in range(hb):
+            o_ref[:, h, :] = oq[h] + _mm(pm[h], us[h], ((1,), (0,)))
+
+    @pl.when(c == nc - 1)
+    def _():
+        for h in range(hb):
+            send_ref[h] = st[:, head(h)].T
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret", "arith"))
+def _chunk_pallas(S0, q, k, v, g, beta, *, heads: int, interpret: bool,
+                  arith: bool = True):
+    C, H, dk = k.shape
+    dv = v.shape[-1]
+    Q, hb = CHUNK, heads
+    # whole chunks, and whole blocks past one: a padded token (g = 0,
+    # beta = 0) passes the state through
+    Cp = -(-C // Q) * Q
+    Cb = min(Cp, CHUNK_BLOCK)
+    Cp = -(-Cp // Cb) * Cb
+    if Cp != C:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, Cp - C),) + ((0, 0),) * (x.ndim - 1))
+            for x in (q, k, v, g, beta))
+    n = Cb // Q
+
+    def tokens(d):
+        return pl.BlockSpec((Cb, hb, d), lambda h, c: (c, h, 0))
+
+    def state():
+        return pl.BlockSpec((hb, dk, dv), lambda h, c: (h, 0, 0))
+
+    S_end, o = pl.pallas_call(
+        functools.partial(_chunk_kernel, Q=Q, arith=arith),
+        name="cake_kda_chunk",
+        grid=(H // hb, Cp // Cb),
+        in_specs=[state(), tokens(dk), tokens(dk), tokens(dv), tokens(dk),
+                  pl.BlockSpec((None, Cb, hb), lambda h, c: (h, c, 0))],
+        out_specs=[state(), tokens(dv)],
+        scratch_shapes=[pltpu.VMEM((dv, hb * dk), F32),
+                        pltpu.VMEM((hb, n, 2 * Q, dk), F32),
+                        pltpu.VMEM((hb, Cb, dv), F32),
+                        pltpu.VMEM((n, hb * Q, hb * dk), F32),
+                        pltpu.VMEM((hb, Cb, Cb), F32),
+                        pltpu.VMEM((hb, Cb, dv), F32),
+                        pltpu.VMEM((hb, Cb, dv), F32),
+                        pltpu.VMEM((n, hb * dk), F32)],
+        out_shape=[jax.ShapeDtypeStruct((H, dk, dv), F32),
+                   jax.ShapeDtypeStruct((Cp, H, dv), F32)],
+        # a head block's chain over the chunk blocks is sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=CHUNK_VMEM_BYTES),
+        interpret=interpret,
+    )(S0, q, k, v.astype(F32), g,
+      # a head block's write strengths side by side: [H / hb, Cp, hb]
+      beta.reshape(Cp, H // hb, hb).swapaxes(0, 1))
+    return S_end, o[:C]
+
+
+def chunked(S0, q, k, v, g, beta, interpret: Optional[bool] = None):
+    """A window of C tokens of ONE row, chunked: bailing_hybrid.
+    kda_chunked's contract and its mathematics (chunks of CHUNK tokens,
+    the unit-lower-triangular solve by forward substitution, float32
+    operands and state, every product at the highest precision), the
+    state on the chip from the window's first chunk to its last. S0 [H,
+    dk, dv] f32; q, k [C, H, dk] f32; v [C, H, dv]; g [C, H, dk] f32;
+    beta [C, H] f32 (g and beta 0 past the row's real tokens) -> (S_end
+    [H, dk, dv] f32, o [C, H, dv] f32)."""
+    if interpret is None:
+        interpret = not rpa._on_tpu()
+    H, dk, dv = S0.shape
+    hb = chunk_heads(H)
+    if not interpret and (dk % 128 or dv % 128 or (hb % 8 and hb != H)):
+        raise ValueError(
+            f"cake_kda_chunk cannot run on this chip at {H} heads of a "
+            f"{dk} x {dv} state: dk and dv must be multiples of 128 and "
+            f"the heads a program ({hb}) whole sublane tiles")
+    return _chunk_pallas(S0, q, k, v, g, beta, heads=hb,
+                         interpret=interpret)

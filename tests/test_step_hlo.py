@@ -287,6 +287,82 @@ def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
             == 2 * 2**20)
 
 
+def test_kda_chunk_kernel_compiles_at_the_cells_widths(one_chip):
+    """`cake_kda_chunk` at ling3.longreply-closed's shapes (a window of
+    512 tokens, 32 heads of 128 x 128, float32 operands and state) goes
+    through Mosaic inside the scoped VMEM it states (its double-buffered
+    blocks and its scratch pass the compiler's default of 16 MiB), and
+    the program holds nothing beside its operands and results."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import kda
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, H, dk, dv = 512, 32, 128, 128
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        compiled = jax.jit(kda.chunked).lower(
+            sds((H, dk, dv), jnp.float32), sds((C, H, dk), jnp.float32),
+            sds((C, H, dk), jnp.float32), sds((C, H, dv), jnp.float32),
+            sds((C, H, dk), jnp.float32), sds((C, H), jnp.float32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_kda_chunk" in hlo
+    # the call's scoped memory is the limit the kernel states
+    assert "scoped_memory_configs" in hlo
+    assert f'"size":"{kda.CHUNK_VMEM_BYTES}"' in hlo
+    assert kda.chunk_heads(H) * kda.CHUNK == 128    # ONE tile of rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_lings_mixed_program_holds_no_loop_under_kda_chunk(tool, one_chip):
+    """A cut of Ling's served mixed program (a KDA layer and a latent
+    one at the cell's widths, slots and window) for the described v5e:
+    ONE `cake_kda_chunk` call a KDA layer, and no `while` anywhere whose
+    body, condition or own name lies under the scope `kda_chunk`: XLA's
+    32-step scan over the state is gone, not moved."""
+    import json
+    import re
+
+    from cake_tpu.models.llama.config import load_config
+
+    cell = CONFIGS / "ling-3.0-flash-int8-share4"
+    config = load_config(str(cell))
+    config = dataclasses.replace(
+        config, num_hidden_layers=2, indexer_types=("kda", "dense"),
+        mlp_layer_types=config.mlp_layer_types[:1] + ("sparse",))
+    with open(cell / "cell.json") as f:
+        cell = json.load(f)
+    sa = cell["server_args"]
+    width = sa["prefill-chunk"]
+    _, mixed = tool.step_fns(config)
+    with jax.default_matmul_precision("default"):
+        compiled = tool.compile_step(
+            mixed, config, one_chip, width=width,
+            n_tokens=width + sa["max-slots"], slots=sa["max-slots"],
+            n_pages=sa["kv-pages"], page_size=sa["kv-page-size"],
+            max_seq_len=sa["max-seq-len"])
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cake_kda_chunk" in line]
+    assert len(calls) == 1
+    assert "kda_chunk" in calls[0]          # the call lies under the scope
+    # every computation a `while` names, and the lines under the scope
+    loops = set(re.findall(r"(?:body|condition)=%?([\w.\-]+)", hlo))
+    under, inside = [], None
+    for line in hlo.splitlines():
+        head = tool._COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+        if "kda_chunk" in line and (inside in loops or " while(" in line):
+            under.append(line.strip()[:200])
+    assert not under, "a loop under kda_chunk again: " + "; ".join(under)
+
+
 def test_ssm_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
     """`cake_ssm_step` at granite4h.sessions-closed's shapes (36 layers
     of 64 rows x 64 heads of 64 x 128 float32, one group) goes through
