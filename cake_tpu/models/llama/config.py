@@ -65,6 +65,11 @@ MODEL_TYPES = {
                         "positions at a softmax scale of its own; embedding, "
                         "residual and logit multipliers; a tied head (paged "
                         "engine)",
+    "KeyeVL2": "Keye-VL-2.0's language model alone: GQA with q and k "
+               "normed a head, a learned sparse indexer that selects the "
+               "keys a query attends over ordinary K/V pages (its keys in "
+               "a third pool over the same page table), softmax-routed "
+               "experts, none shared (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -104,6 +109,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "granitemoehybrid":
         from cake_tpu.models.moe.config import GraniteHybridConfig
         return GraniteHybridConfig.from_hf_dict(raw)
+    if model_type == "KeyeVL2":
+        from cake_tpu.models.moe.config import KeyeVL2Config
+        return KeyeVL2Config.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

@@ -59,6 +59,11 @@ class RopeTables(NamedTuple):
             return WindowRopeTables(cos, sin, *precompute_rope(
                 config.swa_qk_rope_head_dim, max_seq_len,
                 config.swa_rope_theta))
+        if getattr(config, "index_rope_dim", None):
+            # a sparse indexer whose heads are rotated whole, by the
+            # frequencies of their own width
+            return IndexRopeTables(cos, sin, *precompute_rope(
+                config.index_rope_dim, max_seq_len, config.rope_theta))
         return cls(cos, sin)
 
 
@@ -69,6 +74,15 @@ class WindowRopeTables(NamedTuple):
     sin: jnp.ndarray
     swa_cos: jnp.ndarray
     swa_sin: jnp.ndarray
+
+
+class IndexRopeTables(NamedTuple):
+    """RopeTables of a model whose sparse indexer rotates its heads
+    whole, over a width of their own at the model's theta (KeyeVL2)."""
+    cos: jnp.ndarray
+    sin: jnp.ndarray
+    index_cos: jnp.ndarray
+    index_sin: jnp.ndarray
 
 
 def _qk_norm(x, weight, eps: float, tp_axis: Optional[str]):

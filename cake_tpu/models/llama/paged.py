@@ -56,6 +56,11 @@ A model whose sliding-window layers are plain GQA beside full GQA layers
 sliding layers' keys and values in `wk` / `wv` [L_sliding, slots * R,
 page, KV*hd] through the ring `wtable`; both attention kernels take the
 band (`window=`) and read a ring through it.
+A model whose queries attend the keys a learned indexer SELECTS among
+ordinary K/V pages (KeyeVL2) keeps a third pool on the one page table
+and allocator: `idx` [L, N_pages, page, index_head_dim], the indexer's
+key a token and layer (PagedKVCache's optional leaf; None, no leaf of
+the pytree, for every other family).
 Page j of a slot covers absolute positions [j*page, (j+1)*page): pages
 are position-contiguous, so decode attention is an online-softmax
 accumulation over the slot's pages — each page is gathered once, folded
@@ -109,6 +114,9 @@ class PagedKVCache(NamedTuple):
     k: jnp.ndarray        # [L, N_pages, page, KV*hd]
     v: jnp.ndarray        # [L, N_pages, page, KV*hd]
     table: jnp.ndarray    # [slots, max_pages] int32, -1 = unmapped
+    # the sparse indexer's keys beside K and V, through the same table
+    # [L, N_pages, page, index_head_dim]; None (no leaf) elsewhere
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def page_size(self) -> int:
@@ -141,18 +149,21 @@ class PagedKVCache(NamedTuple):
 
     @classmethod
     def zeros(cls, shape_k: tuple, shape_v: tuple, slots: int,
-              max_pages: int, dtype) -> "PagedKVCache":
+              max_pages: int, dtype,
+              shape_idx: Optional[tuple] = None) -> "PagedKVCache":
         """Empty pools [L, N_pages, page, row] and an unmapped table."""
         return cls(k=jnp.zeros(shape_k, dtype), v=jnp.zeros(shape_v, dtype),
-                   table=jnp.full((slots, max_pages), -1, jnp.int32))
+                   table=jnp.full((slots, max_pages), -1, jnp.int32),
+                   idx=(None if shape_idx is None
+                        else jnp.zeros(shape_idx, dtype)))
 
     def memory_bytes(self) -> int:
         """ACTUAL pool storage bytes, summed per leaf — matches the
         quantized cache's accounting (which adds f32 scale sidecars to
         the int8 pools) instead of assuming one dtype for the pool."""
         return sum(leaf.nbytes
-                   for leaf in jax.tree_util.tree_leaves((self.k,
-                                                          self.v)))
+                   for leaf in jax.tree_util.tree_leaves((self.k, self.v,
+                                                          self.idx)))
 
 
 class HybridPagedCache(NamedTuple):
@@ -585,7 +596,7 @@ def write_token_rows(pool, layer, rows, slot, position, valid, table,
 
 def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
                 window: Optional[int] = None,
-                scale: Optional[float] = None):
+                scale: Optional[float] = None, selected=None):
     """The XLA reference both paged attentions share: a fori_loop over
     all max_pages, every page gathered once from `pool[layer]` and
     folded into running (m, l, o) stats. causal_bound: [B, C] — the
@@ -595,7 +606,9 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
     first query's first key, read through table entry (first + j) mod
     max_pages, so that `table` may be a ring (the kernels' rule:
     ops/ragged_paged_attention._mixed_fold). scale: what the scores are
-    multiplied by (None: 1/sqrt(hd))."""
+    multiplied by (None: 1/sqrt(hd)). selected [B, max_pages, C, page]
+    float32: a query attends a key of a page only where its entry is
+    above 0.5 (None: every key the other rules leave)."""
     B, C, H, hd = q.shape
     _, N, P, width = getattr(pool_k, "q", pool_k).shape
     KV = width // hd
@@ -642,6 +655,10 @@ def _fold_pages(q, pool_k, pool_v, layer, table, causal_bound,
             valid = ((slots_abs <= causal_bound[:, :, None])
                      & (slots_abs > causal_bound[:, :, None] - window))
         valid &= (pages >= 0)[:, None, None]
+        if selected is not None:
+            # (no band beside a selection: j is the loop's scalar)
+            valid &= lax.dynamic_index_in_dim(selected, j, axis=1,
+                                              keepdims=False) > 0.5
         valid = valid[:, None, None, :, :]           # [B,1,1,C,P]
         mj, lj, oj = partial_attention_stats(q, kj, vj, valid, scale=scale)
         m_new = jnp.maximum(m, mj)
@@ -711,7 +728,7 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
 def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
                   interpret: bool, scale_k=None, scale_v=None,
                   packed4: bool = False, window: Optional[int] = None,
-                  scale: Optional[float] = None):
+                  scale: Optional[float] = None, selected=None):
     """The mixed kernel behind a jit of its own. The mixed step's
     programs of every packed size call it on the same window shapes,
     and a jitted callee is traced once for all of them: the kernel's
@@ -724,13 +741,13 @@ def _mixed_kernel(q, pool_k, pool_v, layer, table, pos, q_len, *,
     return ragged_paged_attention_mixed(
         q, pool_k, pool_v, layer, table, pos, q_len, scale_k=scale_k,
         scale_v=scale_v, packed4=packed4, window=window, scale=scale,
-        interpret=interpret)
+        selected=selected, interpret=interpret)
 
 
 def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
                           impl: str = "fold",
                           window: Optional[int] = None,
-                          scale: Optional[float] = None):
+                          scale: Optional[float] = None, selected=None):
     """Mixed ragged attention over layer `layer` of the paged KV: decode
     rows (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page
     offset) in ONE batch.
@@ -751,20 +768,28 @@ def paged_attention_mixed(q, pool_k, pool_v, layer, table, pos, q_len, *,
     padding whose output the caller never reads. window (static):
     query i attends its last `window` keys alone, and `table` may be a
     ring; scale (static): the scores' multiplier (both as
-    paged_attention's). Returns [B, C, H, hd].
+    paged_attention's). selected [B, max_pages, C, page] float32: the
+    keys a query attends among those it may see, by page, above 0.5
+    where it does (a sparse indexer's sets; None, and no operand of the
+    kernel, for every model without one). Returns [B, C, H, hd].
     """
     if impl == "pallas":
         from cake_tpu.ops import ragged_paged_attention as rpa
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
+        if selected is not None:
+            kw["selected"] = selected
         return _mixed_kernel(q, kq, vq, layer, table, pos, q_len,
                              interpret=not rpa._on_tpu(), window=window,
                              scale=scale, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
+    if selected is not None and window is not None:
+        raise ValueError("a selection is served without a band only")
     # per-query causality: query i of row b sits at pos[b] + i
     C = q.shape[1]
     return _fold_pages(q, pool_k, pool_v, layer, table,
-                       pos[:, None] + jnp.arange(C)[None, :], window, scale)
+                       pos[:, None] + jnp.arange(C)[None, :], window, scale,
+                       selected)
 
 
 # -- model-level steps (engine step-fn signatures) ----------------------------

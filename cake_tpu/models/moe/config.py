@@ -998,6 +998,161 @@ class ExaoneMoeConfig(MoEConfig):
         return cls(**base)
 
 
+# config.json keys of model_type KeyeVL2 whose one served value is the
+# published one: anything else is refused by the key's name
+_KEYE_ONLY = {
+    "hidden_act": "silu", "use_sliding_window": False,
+    "sliding_window": None, "decoder_sparse_step": 1,
+    "attention_bias": False, "tie_word_embeddings": False,
+}
+
+
+@dataclass(frozen=True)
+class KeyeVL2Config(MoEConfig):
+    """Keye-VL-2.0's LANGUAGE MODEL (`model_type: KeyeVL2`): every layer
+    alike. GQA with an RMSNorm a head on q and k before the rotation, a
+    learned sparse indexer beside it (the nested `sa_config`:
+    `index_n_heads` heads of `index_head_dim` over ONE key head; a query
+    attends the `index_topk` keys it scores highest, every visible key
+    while there are no more than that), softmax-routed experts with the
+    k best renormalised and none shared. The equations are in
+    models/reference/keye_vl2.py; the served path in
+    models/moe/keye_vl2.py, and its cache is ordinary K and V pages plus
+    the indexer's keys in a third pool over the same page table
+    (models/llama/paged.PagedKVCache.idx).
+
+    `mrope_section` deals the rotation's head_dim / 2 frequencies to
+    three position streams (temporal, height, width). For text the three
+    are equal and the rotation is the ordinary one at every frequency,
+    which is what is served: the vision tower that would make them
+    differ is refused by its key (`vision_config`)."""
+
+    _family = "cake_tpu.models.moe.keye_vl2:FAMILY"
+
+    attn_head_dim: int = 128
+    moe_intermediate_size: int = 768
+    index_n_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
+    # (the published kernel's score-pass tiles: what is selected does
+    # not depend on them)
+    q_chunk_size: int = 512
+    kv_chunk_size: int = 512
+    mrope_section: Tuple[int, ...] = (16, 24, 24)
+    # what glm_dsa.ffn reads of a config: every expert is held, the
+    # rule is softmax with no groups and no scale
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    group_top: int = 1
+
+    @property
+    def head_dim(self) -> int:
+        return self.attn_head_dim
+
+    @property
+    def n_routed_experts_total(self) -> int:
+        return self.num_local_experts
+
+    @property
+    def index_rope_dim(self) -> int:
+        """The indexer's heads are rotated whole (RopeTables.create)."""
+        return self.index_head_dim
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "KeyeVL2Config":
+        for tower in ("vision_config", "audio_config"):
+            if raw.get(tower) is not None:
+                raise ValueError(
+                    f"{tower}: model_type KeyeVL2 is served as its "
+                    "language model alone (no tower's layers are "
+                    "implemented, and no request carries an image or a "
+                    f"sound); take {tower} out of config.json")
+        for name, want in _KEYE_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type KeyeVL2 serves "
+                    f"{want!r} only (not implemented)")
+        if raw.get("mlp_only_layers"):
+            raise ValueError(
+                f"mlp_only_layers = {raw['mlp_only_layers']!r}: every "
+                "layer of model_type KeyeVL2 is served with experts (a "
+                "dense layer is not implemented)")
+        sa = raw.get("sa_config")
+        if not isinstance(sa, dict):
+            raise ValueError(
+                "sa_config: model_type KeyeVL2 needs the sparse "
+                "indexer's settings (indexer_head_dim, indexer_num_heads, "
+                "indexer_num_kv_heads, topk)")
+        if sa.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError(
+                f"sa_config.indexer_num_kv_heads = "
+                f"{sa['indexer_num_kv_heads']!r}: one index key a token "
+                "is served (not implemented)")
+        hd = raw.get("head_dim",
+                     raw["hidden_size"] // raw["num_attention_heads"])
+        rope = raw.get("rope_scaling") or {}
+        kind = rope.get("rope_type", rope.get("type", "default"))
+        if kind != "default" or rope.get("type", kind) != kind:
+            raise ValueError(
+                f"rope_scaling.rope_type = {kind!r}: only 'default' "
+                "(plain frequencies, dealt to three position streams by "
+                "mrope_section) is implemented")
+        section = tuple(rope.get("mrope_section") or (hd // 2,))
+        if sum(section) != hd // 2:
+            raise ValueError(
+                f"rope_scaling.mrope_section = {list(section)}: must sum "
+                f"to head_dim / 2 = {hd // 2}")
+        held = raw["num_experts"]
+        if raw.get("num_local_experts", held) != held:
+            raise ValueError(
+                f"num_local_experts = {raw['num_local_experts']!r}: every "
+                f"one of num_experts = {held} is held (a share of a "
+                "layer's experts is not implemented for model_type "
+                "KeyeVL2)")
+        # (sliding_window is None by the table above)
+        base = LlamaConfig.from_hf_dict(raw)
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields.update(chat_template="chatml")
+        return cls(
+            **fields,
+            num_local_experts=held,
+            num_experts_per_tok=raw["num_experts_per_tok"],
+            norm_topk_prob=raw.get("norm_topk_prob", True),
+            hf_layout="KeyeVL2",
+            attn_head_dim=hd,
+            moe_intermediate_size=raw["moe_intermediate_size"],
+            index_n_heads=sa["indexer_num_heads"],
+            index_head_dim=sa["indexer_head_dim"],
+            index_topk=sa["topk"],
+            q_chunk_size=sa.get("q_chunk_size", 512),
+            kv_chunk_size=sa.get("kv_chunk_size", 512),
+            mrope_section=section,
+        )
+
+    @classmethod
+    def tiny_keye(cls, **overrides) -> "KeyeVL2Config":
+        """Keye's layer at a test's size: 3 layers, 4 query heads over 2
+        K/V heads of 16, an indexer of 2 heads of 8 that selects 48
+        keys, 8 experts of 32, 2 a token."""
+        base = dict(
+            vocab_size=512, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=2, rms_norm_eps=1e-6, rope_theta=1e7,
+            max_position_embeddings=512, bos_token_id=1,
+            eos_token_ids=(512,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=8, num_experts_per_tok=2,
+            norm_topk_prob=True, hf_layout="KeyeVL2",
+            attn_head_dim=16, moe_intermediate_size=32,
+            index_n_heads=2, index_head_dim=8, index_topk=48,
+            mrope_section=(2, 3, 3),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
 @dataclass(frozen=True)
 class NemotronHConfig(MoEConfig):
     """Nemotron-3 (`model_type: nemotron_h`): every block is ONE mixer

@@ -421,7 +421,7 @@ def _resolve_attn(config, impl: str, *, explicit: bool, prefill_chunk,
     tile = query_tile(width, *heads, page_size, q_itemsize, kv_itemsize)
     if width % tile:
         raise ValueError(
-            f"--prefill-chunk {width}: model_type exaone_moe hands the "
+            f"--prefill-chunk {width}: model_type {c.family.name} hands the "
             f"window to the attention kernel in entries of {tile} queries "
             "(what its VMEM holds at these heads), which must divide it")
     max_pages = -(-max_seq_len // page_size)
@@ -435,7 +435,7 @@ def _resolve_attn(config, impl: str, *, explicit: bool, prefill_chunk,
     if impl == "pallas" and not ok:
         if explicit:
             raise ValueError(
-                "--paged-attn pallas cannot serve model_type exaone_moe "
+                f"--paged-attn pallas cannot serve model_type {c.family.name} "
                 f"on this device at page={page_size} heads={heads} mixed "
                 f"width={width} in entries of {tile} "
                 "(ops/ragged_paged_attention gates); use --paged-attn "

@@ -323,6 +323,49 @@ def _init_exaone_params(config, rng: jax.Array, dtype, bits: Optional[int]):
             "lm_head": mat("lm_head", (D, c.vocab_size), D)}
 
 
+def _init_keye_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of a KeyeVL2Config: every layer alike, so every
+    leaf is ONE stack [L, ...]: K-EXAONE's attention leaves (q_norm /
+    k_norm [L, head_dim]: an RMSNorm a head), GLM's indexer leaves
+    (`wi_q` here reads the normed hidden state: the model has no query
+    latent), the router and the experts. Norm weights and the index
+    key's LayerNorm are drawn away from their neutral values so that a
+    test sees them."""
+    c = config
+    L, D = c.num_hidden_layers, c.hidden_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    nI, dI = c.index_n_heads, c.index_head_dim
+    E, Fe = c.num_local_experts, c.moe_intermediate_size
+    w, mat, keys = _draws(rng, dtype, bits, 32)
+
+    def near(shape, centre):
+        return (centre + 0.1 * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    blocks = {
+        "attn_norm": near((L, D), 1.0),
+        "wq": mat("wq", (L, D, H * hd), D),
+        "wk": mat("wk", (L, D, KV * hd), D),
+        "wv": mat("wv", (L, D, KV * hd), D),
+        "q_norm": near((L, hd), 1.0),
+        "k_norm": near((L, hd), 1.0),
+        "wo": mat("wo", (L, H * hd, D), H * hd),
+        "wi_q": mat("wi_q", (L, D, nI * dI), D),
+        "wi_k": mat("wi_k", (L, D, dI), D),
+        "wi_k_norm": near((L, dI), 1.0),
+        "wi_k_bias": near((L, dI), 0.0),
+        "wi_w": w((L, D, nI), D),
+        "mlp_norm": near((L, D), 1.0),
+        "router": w((L, D, E), D),
+        "we_gate": mat("we_gate", (L, E, D, Fe), D),
+        "we_up": mat("we_up", (L, E, D, Fe), D),
+        "we_down": mat("we_down", (L, E, Fe, D), Fe),
+    }
+    return {"embed": w((c.vocab_size, D), D), "blocks": blocks,
+            "final_norm": near((D,), 1.0),
+            "lm_head": mat("lm_head", (D, c.vocab_size), D)}
+
+
 def _init_nemotron_params(config, rng: jax.Array, dtype,
                           bits: Optional[int]):
     """The seeded tree of a NemotronHConfig. Leaves are stacked per KIND
@@ -531,6 +574,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
         return _init_bailing_params(config, rng, dtype, bits)
     if config.hf_layout == "exaone_moe":
         return _init_exaone_params(config, rng, dtype, bits)
+    if config.hf_layout == "KeyeVL2":
+        return _init_keye_params(config, rng, dtype, bits)
     if getattr(config, "kv_lora_rank", None):
         return _init_glm_params(config, rng, dtype, bits)
     if config.hf_layout == "granitemoehybrid":
@@ -588,6 +633,12 @@ def hf_layout(config: MoEConfig):
             "convolutions, the router's MLP and the residual scaling "
             "have no counterpart it can name); it serves the family "
             "from seeded weights only")
+    if config.hf_layout == "KeyeVL2":
+        raise NotImplementedError(
+            "model_type KeyeVL2: the published checkpoint is not in this "
+            "repository and its tensor names are not guessed (the "
+            "indexer's projections and the per-head q / k norms among "
+            "them); it is served from seeded weights only")
     if config.hf_layout == "exaone_moe":
         raise NotImplementedError(
             "model_type exaone_moe: the published checkpoint is not in "

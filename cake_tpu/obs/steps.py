@@ -471,13 +471,29 @@ GQA_WINDOW_COUNTERS = (
         "dispatches (over cake_gqa_ring_pages_live_total: what a layer "
         "of the other kind would hold)")),
 )
+# a model whose indexer selects keys over ORDINARY K/V pages
+# (models/moe/keye_vl2.trunk; the dsa_* above count it as they count the
+# latent families', gqa_rows_single and gqa_full_pages_live as
+# exaone_moe's)
+DSA_GQA_COUNTERS = (
+    ("dsa_keys_single", _m.counter(
+        "cake_dsa_keys_single_total",
+        "Keys the single-token rows attended (min(position + 1, topk) "
+        "each: the selected rows their cake_decode_attn calls folded), "
+        "summed over rows and layers")),
+    ("dsa_keys_scanned_single", _m.counter(
+        "cake_dsa_keys_scanned_single_total",
+        "Index keys the single-token rows' indexers scored (position + "
+        "1 each: every visible key), summed over rows and layers")),
+)
 GQA_WINDOW_POOL_BYTES = _m.gauge(
     "cake_gqa_window_pool_bytes",
     "Bytes of the sliding-window layers' K and V pools beside the page "
     "pool (slots x ring pages, every sliding layer)")
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
                       + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS
-                      + KDA_COUNTERS + GQA_WINDOW_COUNTERS)
+                      + KDA_COUNTERS + GQA_WINDOW_COUNTERS
+                      + DSA_GQA_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)
 BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
                      "cca_tail_bytes": CCA_TAIL_BYTES,

@@ -493,7 +493,7 @@ def _mixed_first_page(pos, page_size: int, window: Optional[int]):
 def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
                 l_ref, page_kv, *, scale: float, page_size: int,
                 kv_heads: int, group: int, head_dim: int, q_width: int,
-                window: Optional[int] = None):
+                window: Optional[int] = None, sel_ref=None):
     """One (row, page) grid step of the MIXED ragged fold: each row
     carries q_width query slots of which q_len are real — a decode row
     (q_len=1) and a prefill-chunk row (q_len=C at arbitrary page
@@ -528,6 +528,13 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
     mask cuts the rest; logical page p is read through table entry
     p mod max_pages (a ring of R entries serves page p from entry
     p mod R; a whole table reads as it did).
+
+    sel_ref (None: no such operand, and the program is the one it was):
+    [1, 1, C, P] float32, the grid step's page of a per-(query, key)
+    selection: query i attends a key of this page only where its entry
+    is above 0.5 (a sparse indexer's sets: models/moe/keye_vl2.py). A
+    query's row reaches its G rows of the scores by a sublane
+    broadcast, as ops/mla_attention._spread's.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -596,6 +603,11 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
             valid = col <= pos + qidx
             if window is not None:
                 valid = jnp.logical_and(valid, col > pos + qidx - window)
+            if sel_ref is not None:
+                picked = jnp.concatenate(
+                    [jnp.broadcast_to(sel_ref[0, 0, i:i + 1, :], (G, P))
+                     for i in range(nq)], axis=0)            # [R, P]
+                valid = jnp.logical_and(valid, picked > 0.5)
             for kv in range(kv_heads):
                 kh, vh, k_scale, v_scale = page_kv(kv, pid)  # [P, hd]
                 qh = q_ref[0, :nq, heads(kv), :].reshape(R, hd)
@@ -642,18 +654,23 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
 
 
 def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref,
-                      v_ref, o_ref, acc_ref, m_ref, l_ref, *, head_dim: int,
+                      v_ref, *refs, head_dim: int, selecting: bool = False,
                       **shape):
     """The mixed kernel over a float pool: k_ref/v_ref [1, page, KV*hd],
-    one physical page, a kv head a lane slice."""
+    one physical page, a kv head a lane slice. selecting: one more
+    input follows them, the selection's page (_mixed_fold's sel_ref)."""
     hd = head_dim
+    sel_ref = None
+    if selecting:
+        sel_ref, *refs = refs
+    o_ref, acc_ref, m_ref, l_ref = refs
 
     def page_kv(kv, pid):
         lanes = slice(kv * hd, (kv + 1) * hd)
         return k_ref[0, :, lanes], v_ref[0, :, lanes], None, None
 
     _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, page_kv, head_dim=hd, **shape)
+                l_ref, page_kv, head_dim=hd, sel_ref=sel_ref, **shape)
 
 
 def _rpa_mixed_kernel_q(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref,
@@ -689,6 +706,7 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
                                  scale_k=None, scale_v=None,
                                  packed4: bool = False,
                                  window: Optional[int] = None,
+                                 selected=None,
                                  interpret: bool | None = None):
     """MIXED ragged attention over a paged KV pool, one Pallas kernel.
 
@@ -720,6 +738,15 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
                   the pages from its first query's first key on;
                   `table` may then be a ring (_mixed_fold). None: every
                   key up to the query.
+    selected:     [B, max_pages, C, page] float32, or None. Query
+                  (b, i) attends key j * page + o only where
+                  selected[b, j, i, o] is above 0.5, among the keys
+                  causality leaves it (a sparse indexer's sets), BY
+                  PAGE: the [C, page] block a grid step needs is one
+                  contiguous copy beside its K and V pages (query-major,
+                  it was C rows of 512 B a step). A float pool without a
+                  band only. None: no such operand, and the program is
+                  the one it was.
     Returns [B, C, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention_mixed` (the fold reference)
     to f32 tolerance — tests/test_ragged_paged_attn.py pins the parity.
@@ -749,7 +776,16 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
             "(ragged_paged_mixed_supported); use the fold or a "
             "narrower window")
 
+    if selected is not None and (quantized or window is not None):
+        raise ValueError("a selection is served over a float pool "
+                         "without a band only")
+
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def sel_index(b, j, layer_ref, pos_ref, qlen_ref, table_ref):
+        # (dead pages clamp to the last live one, as kv_index's)
+        last = pos_ref[b] + jnp.maximum(qlen_ref[b], 1) - 1
+        return (b, jnp.minimum(j, last // P), 0, 0)
 
     def kv_index(b, j, layer_ref, pos_ref, qlen_ref, table_ref, *_scales):
         # clamp dead pages (past the row's live count) to the LAST live
@@ -784,6 +820,11 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
         operands = (layer, jnp.asarray(pos, jnp.int32),
                     jnp.asarray(q_len, jnp.int32),
                     jnp.asarray(table, jnp.int32), q, pool_k, pool_v)
+    selecting = []
+    if selected is not None:
+        kernel = functools.partial(kernel, selecting=True)
+        operands += (selected.astype(jnp.float32),)
+        selecting = [pl.BlockSpec((1, 1, C, P), sel_index)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(B, max_pages),
@@ -791,7 +832,7 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
             pl.BlockSpec((1, C, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
             _pool_block(Pb, width, kv_index),
             _pool_block(Pb, width, kv_index),
-        ],
+        ] + selecting,
         out_specs=pl.BlockSpec((1, C, H, hd),
                                lambda b, j, *_: (b, 0, 0, 0)),
         scratch_shapes=[

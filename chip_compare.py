@@ -101,6 +101,21 @@ reads and what must fail: a bfloat16 state, a bfloat16 decay, the
 unbounded gate, a state not zeroed at position 0, a group's score by its
 best alone, the gate a head left out.
 
+Keye-VL-2.0's language model
+(`benchmarks/configs/keye-vl-2.0-lm-int8-8of48`, model_type KeyeVL2)
+goes through `models/moe/keye_vl2.mixed_trunk` / `decode_trunk` and
+`cake_tpu/models/reference/keye_vl2.py` (compare_keye): sequences of
+32,700, 16,300, 8,200 and 1,900 prompt tokens (one UNDER `topk`) in four
+of the eight rows beside four fillers, one 512-token window a dispatch,
+then 32 decode steps a row. BOTH of a layer's choices are teacher-forced
+in the reference (the served path's experts and its selected key sets at
+every position), so the logits read the arithmetic; the selection is
+compared on its own on that trajectory (the share of a query's set both
+sides chose, and each disagreement's distance from the reference's
+2,048th score). Its limits (KEYE_TOL, KEYE_KEYS) lie between what the
+served path reads and what must fail: int8 activations, and the
+reference's own selection at half the published `topk`.
+
 The last line of stdout is one JSON object with `ok`.
 """
 
@@ -477,6 +492,25 @@ MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
 N_DECODE = 32
+# KeyeVL2: 8 layers, two discrete choices a layer (2,048 keys of up to
+# 33k, 8 experts of 128). Both are TEACHER-FORCED in the reference (the
+# served path's experts and key sets at every position), so that the
+# logits read the arithmetic and not a flipped choice, and each choice
+# is compared on its own on the forced trajectory: `keys`, the least
+# layer's mean share of a query's set that both sides chose (positions
+# past topk), and `margin`, the worst disagreement's distance from the
+# reference's own threshold (its 2,048th score) in units of the
+# visible scores' standard deviation: a key chosen on one side alone
+# must be a near-tie, a bfloat16 rounding of the index query and key
+# wide. Limits: KEYE_TOL, each between the served path's worst reading
+# over seeds and the least an altered reference read (cell.json
+# `chip_compare.limits_why` has the readings, PR 60).
+KEYE_TOL = {"mean_dense": 1.1e-3, "mean": 1.2e-3, "max": 1.0e-2,
+            "margin": 0.27}
+KEYE_KEYS = 0.972
+KEYE_PROMPTS = (32700, 16300, 8200, 1900)
+KEYE_DECODE = 32
+
 LAST = 256          # prompt positions compared, from the prompt's end
 DECODE_IN_MIXED = 16  # of the 32, at most this many as one-token rows of
                       # mixed steps; the rest through the decode program
@@ -605,6 +639,8 @@ def main() -> int:
         return compare_exaone_moe(engine, cell, args, t_start)
     if raw_config.get("model_type") == "granitemoehybrid":
         return compare_granite(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "KeyeVL2":
+        return compare_keye(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -3847,6 +3883,309 @@ def compare_granite(engine, cell, args, t_start) -> int:
                                     == expected)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"result_granite_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if result["ok"] else 1
+
+
+# -- KeyeVL2 -------------------------------------------------------------------
+
+
+def compare_keye(engine, cell, args, t_start) -> int:
+    """The comparison above for a learned sparse indexer over ordinary
+    K/V pages: the engine's own mixed and decode trunks with the head at
+    the compared positions, every row in every step (the four sequences
+    in four rows, fillers decoding in the other four), against
+    models/reference/keye_vl2.py TEACHER-FORCED in both of a layer's
+    choices: the served path's experts and key sets at every position.
+    The selection is compared on its own on that trajectory: the
+    reference's own scores and sets at the compared positions."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.llama import paged
+    from cake_tpu.models.moe import keye_vl2 as kv2
+    from cake_tpu.models.reference import keye_vl2 as ref
+    from cake_tpu.ops.quant import qmatmul
+
+    cfg, params, rope = engine.config, engine.params, engine.rope
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    # (the cell lists the mixed step alone: a benchmark window can hold
+    # no decode step; both kinds are held to it here)
+    if not args.rehearse and set(impl.values()) != set(
+            cell["expect_impl"].values()):
+        say(f"FAILED: expected attention {cell['expect_impl']} in both "
+            "step kinds")
+        return 1
+    attn = engine.attn_impl["mixed"]
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    S_row = per_row * page
+    L, K = cfg.num_hidden_layers, min(cfg.index_topk, S_row)
+    prompts = KEYE_PROMPTS if not args.rehearse else (110, 70, 60, 30)
+    n_decode = KEYE_DECODE if not args.rehearse else 6
+    last = LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for p in prompts]
+    assert len(sequences) <= B and max(prompts) + n_decode <= S_row
+    fillers = list(range(len(sequences), B))
+    n_steps_most = sum(-(-p // C) for p in prompts) + n_decode
+    table = rows_table(engine, whole=set(range(len(sequences))),
+                       other_pages=-(-n_steps_most // page))
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    engine.cache = None
+    filler_tokens = rng.integers(0, cfg.vocab_size, (B, n_steps_most))
+
+    def sets_of(picked):
+        """A window's sets [L, C, S] bool as ascending index lists
+        [L, C, K] uint16, S_row where a set has fewer than K."""
+        score = jnp.where(picked, S_row - jnp.arange(S_row), 0)
+        top, _ = jax.lax.top_k(score, K)
+        return jnp.where(top > 0, S_row - top, S_row).astype(jnp.uint16)
+
+    @partial(jax.jit, static_argnames=("n_tokens",),
+             donate_argnames=("cache",))
+    def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
+        out, _ = kv2.mixed_trunk(params, tokens, pos, q_len, active, cache,
+                                 rope, cfg, attn, n_tokens, probe=True)
+        return (out.x, out.cache, out.experts, out.selected,
+                out.n_selected, sets_of(out.selected_window))
+
+    @partial(jax.jit, donate_argnames=("cache",))
+    def decode_step(params, tokens, pos, active, cache):
+        out = kv2.decode_trunk(params, tokens, cache, pos, active, rope,
+                               cfg, attn)
+        return out.x, out.cache, out.experts, out.selected, out.n_selected
+
+    @jax.jit
+    def head(params, x):
+        return qmatmul(x, params["lm_head"]).astype(jnp.float32)
+
+    def compared(b, position):
+        """The prompt's last positions and every decode step."""
+        return position >= prompts[b] - last
+
+    got = [dict() for _ in sequences]       # position -> logits [V]
+    # every position's choices, for the teacher-forced reference
+    routed = [np.zeros((L, len(s), cfg.num_experts_per_tok), np.int32)
+              for s in sequences]
+    picked = [np.full((L, len(s), K), S_row, np.uint16) for s in sequences]
+    off = [0] * len(sequences)
+    steps = {"mixed": 0, "decode": 0}
+    n_steps = 0
+    t0 = time.monotonic()
+    while any(off[b] < len(s) for b, s in enumerate(sequences)):
+        prefilling = [b for b in range(len(sequences))
+                      if off[b] < prompts[b]]
+        qlen = np.zeros(B, np.int32)
+        pos = np.zeros(B, np.int32)
+        for b, seq in enumerate(sequences):
+            if prefilling and b == prefilling[0]:
+                qlen[b] = min(C, prompts[b] - off[b])
+            elif prompts[b] <= off[b] < len(seq) and (
+                    not prefilling
+                    or off[b] < prompts[b] + n_decode // 2):
+                qlen[b] = 1     # half the decode steps ride mixed steps
+            pos[b] = off[b]
+        qlen[fillers], pos[fillers] = 1, n_steps
+        toks = np.zeros((B, C if prefilling else 1), np.int32)
+        for b, seq in enumerate(sequences):
+            toks[b, :qlen[b]] = seq[off[b]:off[b] + qlen[b]]
+        toks[fillers, 0] = filler_tokens[fillers, n_steps]
+        if prefilling:
+            x, cache, experts, selected, n_sel, sets = window_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(qlen), jnp.asarray(qlen > 0), cache,
+                paged.mixed_bucket_for(engine._mixed_buckets,
+                                       int(qlen.sum())))
+            first = np.cumsum(qlen) - qlen
+            sets = np.asarray(sets)
+            steps["mixed"] += 1
+        else:
+            x, cache, experts, selected, n_sel = decode_step(
+                params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(qlen > 0), cache)
+            first = np.arange(B)
+            steps["decode"] += 1
+        experts, selected, n_sel = (np.asarray(experts),
+                                    np.asarray(selected), np.asarray(n_sel))
+        wanted = []
+        for b in range(len(sequences)):
+            n = int(qlen[b])
+            if not n:
+                continue
+            routed[b][:, off[b]:off[b] + n] = experts[
+                :, first[b]:first[b] + n]
+            if n > 1:
+                picked[b][:, off[b]:off[b] + n] = sets[:, :n]
+            else:
+                picked[b][:, off[b], :n_sel[b]] = selected[:, b, :n_sel[b]]
+            wanted += [(b, off[b] + j, first[b] + j) for j in range(n)
+                       if compared(b, off[b] + j)]
+            off[b] += n
+        if wanted:
+            fetched = np.asarray(head(
+                params, x[jnp.asarray([r for _, _, r in wanted])]))
+            for n, (b, position, _) in enumerate(wanted):
+                got[b][position] = fetched[n]
+        n_steps += 1
+    say(f"served path: {steps['mixed']} mixed and {steps['decode']} decode "
+        f"steps of {B} rows in {time.monotonic() - t0:.1f} s")
+
+    # -- the reference: the served weights leave the device, then come
+    # back dequantized one layer at a time --------------------------------
+    del cache, x
+    host = jax.device_get(params)
+    engine.params = params = None
+    ref_cfg = kv2.reference_config(cfg)
+    plain_mm = ref.mm
+
+    def compiled(mm=plain_mm):
+        """The reference's heavy functions under jit, traced anew (so
+        that a replaced `mm` is what they run)."""
+        ref.mm = mm
+        for name, static in (("attend_block", ()), ("score_block", ()),
+                             ("select_block", ("topk",)),
+                             ("sets_as_mask", ("S",)), ("swiglu", ())):
+            fn = getattr(ref, name)
+            fn = getattr(fn, "__wrapped__", fn)
+            setattr(ref, name, jax.jit(fn, static_argnames=static))
+
+    def layers():
+        # from the host copy: one layer's leaves cross to the device at
+        # a time, as stored, and widen there
+        yield from kv2.reference_layers(host["blocks"], cfg)
+
+    top = {k_: dequantized(jax.tree.map(jnp.asarray, host[k_]))
+           for k_ in ("embed", "final_norm", "lm_head")}
+    keep = [np.asarray(sorted(g)) for g in got]
+
+    def reference(which, config=ref_cfg, forced_sets=True):
+        """The reference over the sequences `which`, teacher-forced in
+        its experts and (forced_sets) its key sets -> ({b: logits at
+        keep[b]}, {b: its own experts}, {b: its own scores and sets at
+        keep[b], a layer})."""
+        t0 = time.monotonic()
+        routing = [[] for _ in which]
+        seen = [[] for _ in which]
+        logits = ref.forward(
+            top, [sequences[b] for b in which], config, layers=layers(),
+            routing=routing, selections=seen,
+            forced=[list(routed[b]) for b in which],
+            forced_sets=([list(picked[b]) for b in which]
+                         if forced_sets else None),
+            keep=[keep[b] for b in which])
+        say(f"  reference over {sum(len(sequences[b]) for b in which)} "
+            f"tokens in {time.monotonic() - t0:.1f} s")
+        return (dict(zip(which, (np.asarray(v) for v in logits))),
+                dict(zip(which, routing)), dict(zip(which, seen)))
+
+    def readings(which, logits_of, routing_of, seen_of, topk):
+        """Over the compared positions of `which`: mean and worst
+        |error| / range of the logits under topk (every visible key is
+        attended) and beyond it; `keys`, the least layer's mean share
+        of a query's set both sides chose; `margin`, the worst
+        disagreement's distance from the reference's threshold over
+        the visible scores' standard deviation; `agree`, the least
+        layer's share of positions with the reference's experts."""
+        dense = {"sum": 0.0, "n": 0, "worst": 0.0}
+        sparse = {"sum": 0.0, "n": 0, "worst": 0.0}
+        shared, same = np.zeros(L), np.zeros(L)
+        n_sparse = n_all = 0
+        margin, disagreed = 0.0, 0
+        for b in which:
+            for n, position in enumerate(keep[b]):
+                w = logits_of[b][n]
+                err = np.abs(got[b][position] - w) / float(w.max() - w.min())
+                acc = dense if position < topk else sparse
+                acc["sum"] += float(err.sum())
+                acc["n"] += err.size
+                acc["worst"] = max(acc["worst"], float(err.max()))
+                n_all += 1
+                same += [set(routed[b][j, position].tolist())
+                         == set(routing_of[b][j][position].tolist())
+                         for j in range(L)]
+                if position < topk:
+                    continue
+                n_sparse += 1
+                for j in range(L):
+                    theirs = seen_of[b][j]["sets"][n]
+                    ours = np.zeros_like(theirs)
+                    mine = picked[b][j, position]
+                    ours[mine[mine < theirs.shape[0]]] = True
+                    both = int(np.sum(ours & theirs))
+                    shared[j] += both / max(int(ours.sum()),
+                                            int(theirs.sum()))
+                    apart = ours ^ theirs
+                    if apart.any():
+                        scores = seen_of[b][j]["scores"][n]
+                        visible = scores[:position + 1]
+                        threshold = scores[theirs].min()
+                        margin = max(margin, float(
+                            np.abs(scores[apart] - threshold).max()
+                            / max(float(visible.std()), 1e-30)))
+                        disagreed += int(apart.sum())
+        return {
+            "mean_dense": dense["sum"] / max(dense["n"], 1),
+            "max_dense": dense["worst"],
+            "mean": sparse["sum"] / max(sparse["n"], 1),
+            "max": max(sparse["worst"], dense["worst"]),
+            "keys": float(shared.min()) / max(n_sparse, 1),
+            "keys_by_layer": [round(float(v) / max(n_sparse, 1), 5)
+                              for v in shared],
+            "margin": margin, "keys_apart": disagreed,
+            "agree": float(same.min()) / max(n_all, 1),
+            "positions": n_all, "positions_past_topk": n_sparse}
+
+    def passes(r):
+        return (all(r[k_] < limit for k_, limit in KEYE_TOL.items())
+                and r["keys"] >= KEYE_KEYS)
+
+    compiled()
+    everyone = list(range(len(sequences)))
+    served = readings(everyone, *reference(everyone), cfg.index_topk)
+    expected = sum(min(last, p) + n_decode for p in prompts)
+    result = {
+        "served": served, "expected_positions": expected, "tol": KEYE_TOL,
+        "keys_floor": KEYE_KEYS, "seed": args.seed,
+        "prompts": list(prompts), "rows_a_step": B, "steps": steps,
+        "attention": impl, "device": jax.devices()[0].device_kind,
+        "query_tile": kv2.query_tile(
+            C, cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim, page, *([2, 2] if not args.rehearse else [4, 4]))}
+    ok = served["positions"] == expected and passes(served)
+    if not ok:
+        say("FAILED: the served path is outside the tolerance")
+
+    # -- what must NOT pass: the reference, altered, read as the served
+    # path is (on the shortest sequences) ---------------------------------
+    if args.negatives:
+        short = sorted(everyone, key=lambda b: len(sequences[b]))[
+            :args.negatives]
+        result["must_fail"] = {}
+        # the reference's own selection at half the published topk:
+        # another model past 1,024 keys
+        half = dict(ref_cfg, topk=cfg.index_topk // 2)
+        result["must_fail"]["selection_of_half_topk"] = readings(
+            short, *reference(short, config=half, forced_sets=False),
+            cfg.index_topk // 2)
+        compiled(mm=lambda a, w: plain_mm(fake_int8(a), w))
+        result["must_fail"]["int8_activations"] = readings(
+            short, *reference(short), cfg.index_topk)
+        compiled()
+        for name, r in result["must_fail"].items():
+            if passes(r):
+                say(f"FAILED: the reference with {name} passes the "
+                    "tolerance")
+                ok = False
+    result["ok"] = bool(ok) or bool(args.rehearse and served["positions"]
+                                    == expected)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"result_keye_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)

@@ -20,7 +20,8 @@ from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
     BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
-    GlmMoeDsaConfig, GraniteHybridConfig, MoEConfig, NemotronHConfig,
+    GlmMoeDsaConfig, GraniteHybridConfig, KeyeVL2Config, MoEConfig,
+    NemotronHConfig,
     ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
@@ -40,6 +41,7 @@ TINY = {
     "bailing_hybrid": BailingHybridConfig.tiny_ling,
     "exaone_moe": ExaoneMoeConfig.tiny_exaone,
     "granitemoehybrid": GraniteHybridConfig.tiny_granite,
+    "KeyeVL2": KeyeVL2Config.tiny_keye,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
@@ -47,7 +49,7 @@ NOUNS = {"glm_moe_dsa": "latent row and index key",
          "deepseek_v2": "latent row",
          "nemotron_h": "state", "zaya": "tail",
          "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring",
-         "granitemoehybrid": "state"}
+         "granitemoehybrid": "state", "KeyeVL2": "index-key pool"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 
