@@ -27,7 +27,9 @@ packed axis (paged.pack_plan). A layer:
     window's queries against their row's pages in blocks of keys
     (ops/mla_attention.py); `index_topk`: the exact top index_topk,
     ties to the lower index (lax.top_k for a row's single token, the
-    exact mask of ops/mla_attention.select_mask for a window). A SHARED
+    exact mask of ops/mla_attention.select_window for a window: one
+    kernel, `cake_dsa_select`, bounded by the window's last position).
+    A SHARED
     layer reuses the Selection the nearest full layer below left: the
     value that travels between layers, which is why the layer loop is a
     Python loop over stacks per kind of layer and not one scan;
@@ -106,11 +108,14 @@ EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 GATE_LEAF = "w_attn_gate"
 # the record keys of the vector a step program returns, in trunk's
 # order: the expert counters' five (the held experts' rows alone), the
-# routed rows and the indexer's; a model with sliding layers appends
+# routed rows and the indexer's (the last two: the keys the window's
+# selections walked, ops/mla_attention.select_walked, and the table's
+# width beside them); a model with sliding layers appends
 # SWA_COUNTERS, and the dsa_* then count its full layers alone
 COUNTERS = paged.MOE_COUNTERS + (
     "moe_rows_routed", "dsa_keys_visible", "dsa_keys_selected",
-    "dsa_rows_distinct", "dsa_index_layers", "dsa_index_reused")
+    "dsa_rows_distinct", "dsa_index_layers", "dsa_index_reused",
+    "dsa_select_keys_walked", "dsa_select_keys_table")
 SWA_COUNTERS = ("swa_keys_visible", "swa_keys_attended", "swa_layers")
 N_COUNTERS = len(COUNTERS)
 # a model whose layers have no indexer (deepseek_v2): the expert
@@ -298,8 +303,8 @@ def select_keys(lp, h, c_q, cos, sin, slot, position, real, first,
         bias = None
         distinct = jnp.float32(0)
         if window is not None:
-            picked = mla.select_mask(
-                win, span <= window.positions[:, None], K)
+            picked = mla.select_window(win, window.positions,
+                                       window.last_pos, K)
             bias = jnp.where(picked, 0.0, mla.NEG_INF).astype(jnp.float32)
             # distinct cache rows selected: a window's tokens share their
             # row's keys, a single token's are its own
@@ -651,12 +656,14 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
             L * jnp.sum(jnp.maximum(seen + 1, 0),
                         dtype=jnp.float32)]
     else:
+        S = table.shape[1] * pool_lat.shape[2]
+        # what the window's selections walked, and the table's width
+        walked = [0, 0] if window is None else [
+            Lf * mla.select_walked(window.last_pos, window.width, S), Lf * S]
         counters += [
             L * jnp.sum(visible),
-            L * jnp.sum(jnp.minimum(visible, min(c.index_topk,
-                                                 table.shape[1]
-                                                 * pool_lat.shape[2]))),
-            distinct, Lf * stepped, (L - Lf) * stepped]
+            L * jnp.sum(jnp.minimum(visible, min(c.index_topk, S))),
+            distinct, Lf * stepped, (L - Lf) * stepped] + walked
     counters = jnp.stack(counters).astype(f32)
     cache = cache._replace(k=pool_lat, v=pool_idx)
     if c.sliding_layers:

@@ -26,8 +26,10 @@ with its residual:
     visible key of the token's row in float32
     (ops/mla_attention.index_scores_rows / index_scores_window: GLM's
     and dots3's); `index_topk`: the exact top `index_topk`, ties to the
-    lower index (lax.top_k for a row's single token,
-    ops/mla_attention.select_mask for a window);
+    lower index (lax.top_k for a row's single token; for a window
+    ops/mla_attention.select_window, ONE kernel a layer,
+    `cake_dsa_select`, whose work follows the window's last position
+    and not the table's width);
   * `gqa_full`: the write of every real token's k and v into its page,
     then attention over the SELECTED keys alone. A row's single token:
     its positions sorted ascending, its K and V rows gathered out of the
@@ -81,11 +83,13 @@ from cake_tpu.ops.rope import apply_rope
 # order: the experts' five and the routed rows; the indexer's (GLM's
 # keys: the same quantities); what the single-token rows attended and
 # what their indexers scored; those rows, and the pages the dispatch's
-# rows' contexts fill
+# rows' contexts fill; the keys the window's selection walked, and the
+# table's width beside them
 COUNTERS = paged.MOE_COUNTERS + (
     "moe_rows_routed", "dsa_keys_visible", "dsa_keys_selected",
     "dsa_rows_distinct", "dsa_index_layers", "dsa_keys_single",
-    "dsa_keys_scanned_single", "gqa_rows_single", "gqa_full_pages_live")
+    "dsa_keys_scanned_single", "gqa_rows_single", "gqa_full_pages_live",
+    "dsa_select_keys_walked", "dsa_select_keys_table")
 F32 = jnp.float32
 
 
@@ -128,11 +132,12 @@ class Selection(NamedTuple):
 
 def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
                 pool_idx, layer, table, config: KeyeVL2Config,
-                window: Optional[Window], win_pos):
+                window: Optional[Window], win_pos, win_last):
     """A layer's key sets: writes the tokens' index keys, scores every
     visible key of each token's row, takes the top index_topk. first
     [B]: each row's first packed token; single_pos [B]: its single
-    token's position (-1: it has none here). Returns (pool_idx,
+    token's position (-1: it has none here); win_pos / win_last: the
+    window's first and last positions. Returns (pool_idx,
     Selection, distinct: the cache rows this dispatch selected, counted
     once each)."""
     c = config
@@ -159,11 +164,9 @@ def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
         rows = mla.index_scores_rows(qI[first], keys, w[first])   # [B, S]
         rows = jnp.where(span <= single_pos[:, None], rows, -jnp.inf)
         if window is not None:
-            positions = win_pos + jnp.arange(window.width)
             win = mla.index_scores_window(
                 _window_slice(qI, window), keys[window.row],
-                _window_slice(w, window),
-                win_pos + jnp.maximum(window.n, 1) - 1,
+                _window_slice(w, window), win_last,
                 _key_block(max_pages, P))
     with jax.named_scope("index_topk"):
         _, idx = lax.top_k(rows, K)
@@ -175,7 +178,8 @@ def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
         picked = None
         distinct = jnp.sum(n_valid, dtype=F32)
         if window is not None:
-            picked = mla.select_mask(win, span <= positions[:, None], K)
+            picked = mla.select_window(
+                win, win_pos + jnp.arange(window.width), win_last, K)
             in_window = jnp.arange(window.width) < window.n
             distinct = distinct + jnp.sum(
                 jnp.any(picked & in_window[:, None], axis=0), dtype=F32)
@@ -280,7 +284,10 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
     first = jnp.minimum(rows.first, T - 1)
     # a row's single token; the window's row and an idle row have none
     single_pos = jnp.where(rows.n == 1, rows.pos, -1)
-    win_pos = None if window is None else rows.pos[window.row]
+    win_pos = win_last = None
+    if window is not None:
+        win_pos = rows.pos[window.row]
+        win_last = win_pos + jnp.maximum(window.n, 1) - 1
     stacked = {k: blocks[k] for k in glm_dsa.EXPERT_LEAVES}
     scanned = {k: v for k, v in blocks.items() if k not in stacked}
 
@@ -301,7 +308,7 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
         with jax.named_scope("attn"):
             pool_idx, selection, distinct = select_keys(
                 lp, h, icos, isin, slot, position, real, first, single_pos,
-                pool_idx, layer, table, c, window, win_pos)
+                pool_idx, layer, table, c, window, win_pos, win_last)
             with jax.named_scope("gqa_full"):
                 pool_k = write_token_rows(pool_k, layer, k.reshape(T, KV * hd),
                                           slot, position, real, table)
@@ -338,10 +345,14 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     L, P = c.num_hidden_layers, cache.page_size
-    K = min(c.index_topk, table.shape[1] * P)
+    S = table.shape[1] * P
+    K = min(c.index_topk, S)
     visible = jnp.where(real, position + 1, 0).astype(F32)
     single = rows.n == 1
     last = rows.pos + rows.n - 1
+    # what the window's selection walked, and the table's width
+    walked = [0, 0] if window is None else [
+        L * mla.select_walked(win_last, window.width, S), L * S]
     counters = jnp.stack([
         jnp.sum(moe.rows), jnp.sum(moe.rows_padded), jnp.mean(moe.load_max),
         jnp.mean(moe.load_mean), jnp.sum(moe.touched),
@@ -353,6 +364,7 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
         L * jnp.sum(jnp.where(single, last + 1, 0), dtype=F32),
         jnp.sum(single, dtype=F32),
         jnp.sum(jnp.where(rows.n > 0, last // P + 1, 0), dtype=F32),
+        *walked,
     ]).astype(F32)
     return TrunkOut(
         x, cache._replace(k=pool_k, v=pool_v, idx=pool_idx), counters,

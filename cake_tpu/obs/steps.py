@@ -331,6 +331,15 @@ DSA_COUNTERS = (
         "cake_dsa_index_reused_total",
         "Attention layers that reused the set of the layer below, "
         "summed over dispatches")),
+    ("dsa_select_keys_walked", _m.counter(
+        "cake_dsa_select_keys_walked_total",
+        "Keys in the blocks cake_dsa_select walked (up to the window's "
+        "last position), summed over windows and indexer layers")),
+    ("dsa_select_keys_table", _m.counter(
+        "cake_dsa_select_keys_table_total",
+        "Keys the table is wide for those windows and layers (over it, "
+        "cake_dsa_select_keys_walked_total: the share of the table a "
+        "selection costs)")),
 )
 # a model with recurrent blocks (models/moe/nemotron_h.trunk): the rows'
 # recurrent state and the two forms of the scan

@@ -25,7 +25,7 @@ them select most of what is visible, so the window attends its row's
 pages WHERE THEY LIE, densely, every key of whole pages up to the
 window's last position (`cake_mla_window_attn`): gathering 528 x 2,048
 rows cost 25 ms a layer, a dense pass over 12k keys 3 (my chip run,
-PR 30). `select_mask` is the selection as a mask.
+PR 30). The selection arrives as a mask (`select_window`, below).
 
   * grid (C // tq,): one step a TILE of tq tokens x H heads of query
     rows (`window_tiles`: 8 x 128 or 16 x 64 = 1,024 rows of a 640-wide
@@ -71,6 +71,31 @@ decode rows' pass. `index_scores_window` is the same for a window of C
 queries against ONE row's keys, computed in blocks of keys so that the
 [C, heads, block] intermediate stays small; blocks past the window's
 last position are skipped.
+
+`select_window`. The window's top-k as a [C, S] mask, exact, ties at
+the k-th value to the lower index, by ONE kernel (`cake_dsa_select`;
+what the step programs call, under the scope `index_topk`):
+
+  * grid (C // tq,): a tile of tq queries (`select_tiles`: 128 at the
+    cells' tables) whose scores, as `_sortable`'s codes, stay in VMEM
+    through the 32 counting passes that find the k-th largest code bit
+    by bit, one pass for the keys above it, and the tie pass;
+  * bounded by the window's last position: the kernel's own copies
+    bring the blocks of keys that start at or before it and no other;
+    the rest of the mask is written False. Visibility is a compare of
+    the key's index with the query's position: no [C, S] operand;
+  * the ties: a count carried over the blocks in index order and, inside
+    a chunk of 128 keys, the prefix as a product with a triangle of
+    ones on the matrix unit: no running count over the table's width.
+
+`select_mask` is the plain form in XLA (a `fori_loop` of 32 full-width
+counting passes and a `cumsum` over [C, S]): the tests' other side and
+the kernel's statement; no step program calls it. Alone on the chip at
+a window of 512 (my chip run, PR 61; tools/dsa_select_bench.py): Keye's
+table of 33,280 at a context of 8k 3.42 -> 0.22 ms, 32k 3.41 -> 0.62;
+GLM's 12,800 at 8k 0.57 -> 0.21; dots3's 16,896 at 16k 1.38 -> 0.34
+(~0.1 ms of each the full-width mask around the call; inside Keye's
+mixed program a call reads 0.14 ms).
 """
 
 from __future__ import annotations
@@ -623,6 +648,210 @@ def select_mask(scores, visible, k: int):
     tied = visible & (u == kth[:, None])
     room = k - jnp.sum(above, axis=1, keepdims=True)
     return above | (tied & (jnp.cumsum(tied, axis=1) <= room))
+
+
+# What the selection kernel asks of the core's VMEM, and what of it a
+# query tile's resident codes may take (the result's two buffers are a
+# quarter of that again; the rest is the compiler's temporaries).
+_SELECT_VMEM_LIMIT = 48 * 2**20
+_SELECT_CODES_BYTES = 18 * 2**20
+_INT_MIN = -2**31
+
+
+def select_tiles(C: int, S: int):
+    """(tq, chunk, block) of a selection call, from its shapes alone:
+    tq queries a tile, whose codes [tq, S] stay in VMEM (the widest of
+    128, 64, .. 8 that divides C under _SELECT_CODES_BYTES: 128 at the
+    cells' tables, 17 MB at 33,280; where none divides, the window is
+    one tile);
+    chunk: the keys one vector op spans (128 lanes; a table that is no
+    multiple of 128, a test's, is one chunk); block: the keys one copy
+    brings and one step of the bound skips, the most whole chunks up to
+    16 that divide the table (13 x 128 at 260 chunks, 12 at 132, 10 at
+    100)."""
+    chunk = 128 if S % 128 == 0 else S
+    per = max(d for d in range(1, 17) if (S // chunk) % d == 0)
+    tq = next((t for t in (128, 64, 32, 16, 8)
+               if C % t == 0 and t * S * 4 <= _SELECT_CODES_BYTES), C)
+    return tq, chunk, per * chunk
+
+
+def select_walked(last_pos, C: int, S: int):
+    """Keys in the blocks a selection call walks for a window that ends
+    at last_pos (of the table's S): the step programs' count of what
+    `_select_kernel` does (dsa_select_keys_walked)."""
+    block = select_tiles(C, S)[2]
+    return (jnp.clip(last_pos, 0, S - 1) // block + 1) * block
+
+
+def _select_kernel(last_ref, pos_ref, scores_hbm, o_ref, codes, sem, *,
+                   k: int, chunk: int, block: int):
+    """One grid step: one TILE of tq queries against the row's keys
+    0 .. last, in blocks of `block`.
+
+    scores_hbm [C, S] float32 in HBM. The tile's live blocks (those
+    that start at or before `last`) come by the kernel's own copies
+    into `codes` [tq, S] and are turned in place into keys in the
+    floats' order, SIGNED int32 (held behind a bitcast: the scratch is
+    the copies' float32): `_sortable`'s code with its top bit flipped,
+    so that the vector unit's signed compare orders them; a key the
+    query does not see (past min(its position, last)) takes the lowest,
+    `_sortable`'s 0. They stay there through 32 counting passes (the
+    k-th largest code, bit by bit: a compare, a select and an add a
+    vector, one lane reduction a pass), one more for the keys above it,
+    and the tie pass: in index order, a carried count a query and the
+    prefix inside a chunk as a product with a triangle of ones on the
+    matrix unit (0/1 in bfloat16, float32 sums: exact). o_ref [tq, S]
+    int8: 1 on a selected key; blocks past `last` are written 0 and
+    were never read. pos_ref [tq, 1] int32; sem DMA [S // block]."""
+    i = pl.program_id(0)
+    tq, S = codes.shape
+    per = block // chunk
+    last = jnp.clip(last_ref[0], 0, S - 1)
+    nb = last // block + 1
+    at = jnp.minimum(pos_ref[...], last)                      # [tq, 1]
+    col = lax.broadcasted_iota(jnp.int32, (tq, chunk), 1)
+
+    def cols(j, p=None):
+        if p is None:
+            return pl.ds(pl.multiple_of(j * block, block), block)
+        return pl.ds(pl.multiple_of(j * block + p * chunk, chunk), chunk)
+
+    def copy(j):
+        return pltpu.make_async_copy(
+            scores_hbm.at[pl.ds(i * tq, tq), cols(j)],
+            codes.at[:, cols(j)], sem.at[j])
+
+    def start(j, carry):
+        copy(j).start()
+        return carry
+
+    lax.fori_loop(0, nb, start, 0)
+
+    def seen_by(j, p):
+        return j * block + p * chunk + col <= at
+
+    def bits(j, p):
+        return lax.bitcast_convert_type(codes[:, cols(j, p)], jnp.int32)
+
+    def encode(j, carry):
+        copy(j).wait()
+        for p in range(per):
+            u = bits(j, p)
+            # negative floats: the low 31 bits flipped
+            key = u ^ ((u >> 31) & jnp.int32(0x7FFFFFFF))
+            codes[:, cols(j, p)] = lax.bitcast_convert_type(
+                jnp.where(seen_by(j, p), key, _INT_MIN), jnp.float32)
+        return carry
+
+    lax.fori_loop(0, nb, encode, 0)
+
+    def count(pred):
+        """Keys of each query's live blocks with pred(key): [tq, 1]."""
+        def fold(j, acc):
+            for p in range(per):
+                acc = acc + pred(bits(j, p)).astype(jnp.float32)
+            return acc
+
+        # (float32 counts: exact to 2**24 keys, and the lanes' sum is
+        # the reduction the chip has)
+        acc = lax.fori_loop(0, nb, fold, jnp.zeros((tq, chunk), jnp.float32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def bit(b, cand):
+        # cand: the code's bits found so far (`_sortable`'s, unsigned,
+        # held in an int32); its signed key flips the top bit
+        trial = cand | (jnp.int32(1) << (31 - b))
+        key = jnp.broadcast_to(trial ^ _INT_MIN, (tq, chunk))
+        return jnp.where(count(lambda x: x >= key) >= k, trial, cand)
+
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros((tq, 1), jnp.int32))
+    kth = jnp.broadcast_to(kth ^ _INT_MIN, (tq, chunk))
+    room = k - count(lambda x: x > kth)
+    triangle = (lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+                <= lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+                ).astype(jnp.float32).astype(jnp.bfloat16)
+
+    def emit(j, tied_before):
+        for p in range(per):
+            x = bits(j, p)
+            tied = (x == kth) & seen_by(j, p)
+            # tied keys of this chunk at or before each column
+            upto = rpa._dot(tied.astype(jnp.float32).astype(jnp.bfloat16),
+                            triangle, trans_b=False)
+            take = (x > kth) | (tied & (tied_before + upto <= room))
+            o_ref[:, cols(j, p)] = take.astype(jnp.int32).astype(o_ref.dtype)
+            tied_before = tied_before + upto[:, chunk - 1:]
+        return tied_before
+
+    lax.fori_loop(0, nb, emit, jnp.zeros((tq, 1), jnp.float32))
+
+    def blank(j, carry):
+        o_ref[:, cols(j)] = jnp.zeros((tq, block), o_ref.dtype)
+        return carry
+
+    lax.fori_loop(nb, S // block, blank, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _select_pallas(scores, positions, last_pos, *, k: int, interpret: bool):
+    C, S = scores.shape
+    tq, chunk, block = select_tiles(C, S)
+    picked = pl.pallas_call(
+        functools.partial(_select_kernel, k=k, chunk=chunk, block=block),
+        name="cake_dsa_select",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(C // tq,),
+            in_specs=[pl.BlockSpec((tq, 1), lambda i, *_: (i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tq, S), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tq, S), jnp.float32),
+                            pltpu.SemaphoreType.DMA((S // block,))]),
+        out_shape=jax.ShapeDtypeStruct((C, S), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_SELECT_VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.reshape(last_pos, (1,)).astype(jnp.int32),
+      positions.astype(jnp.int32).reshape(C, 1), scores)
+    return picked != 0
+
+
+def select_window(scores, positions, last_pos, k: int,
+                  interpret: Optional[bool] = None):
+    """A window's top-k as a mask, by one kernel (`cake_dsa_select`):
+    scores [C, S] float32 of the window's C queries against ONE row's
+    keys, positions [C] int32, last_pos the window's last position ->
+    [C, S] bool, bit for bit
+
+        select_mask(scores, span <= min(positions, last_pos)[:, None], k)
+
+    so a query at or before last_pos selects among the keys at or
+    before its own position, and a padded query past it what the last
+    real one may see, by its own scores (nobody reads those rows).
+    Visibility comes from the positions (no [C, S] operand), and the
+    keys past last_pos's block are neither read nor counted: a call
+    costs what the window's context costs (`select_walked`), not what
+    the table is wide. `_select_kernel` says how; `select_tiles` what
+    it holds."""
+    C, S = scores.shape
+    if scores.dtype != jnp.float32 or positions.shape != (C,) or k < 1:
+        raise ValueError(
+            f"cake_dsa_select takes float32 scores [C, S], positions [C] "
+            f"and k >= 1: got {scores.dtype}{list(scores.shape)}, "
+            f"positions {list(positions.shape)}, k={k}")
+    if interpret is None:
+        interpret = not rpa._on_tpu()
+    tq = select_tiles(C, S)[0]
+    if tq * S * 4 > _SELECT_CODES_BYTES or (not interpret and S % 128):
+        raise ValueError(
+            f"cake_dsa_select cannot run at a window of {C} queries over "
+            f"{S} keys: a tile of {tq} queries' codes is {tq * S * 4} bytes "
+            f"of {_SELECT_CODES_BYTES}, and on the chip the table must be "
+            f"a multiple of 128 keys (select_tiles)")
+    return _select_pallas(scores, positions, last_pos, k=k,
+                          interpret=interpret)
 
 
 def _weighted_relu(q, k, w):

@@ -1833,9 +1833,11 @@ def compare_dots3(engine, cell, args, t_start) -> int:
     p_layer = cfg.sliding_layers[0]
     p_sparse = cfg.sparse_layers.index(p_layer)
     lp = glm_dsa.layer_leaves(host["blocks"], cfg, p_layer)
+    # (a layer holds the leaves its query path has: no `wq` beside the
+    # low-rank three)
     p_leaves = {k: dequantized(jax.tree.map(jnp.asarray, lp[k]))
                 for k in (*glm_dsa.ATTN_LEAVES, glm_dsa.GATE_LEAF, "router",
-                          "router_bias")}
+                          "router_bias") if k in lp}
     del lp
 
     def probe_run(config=ref_cfg, low_precision=False):

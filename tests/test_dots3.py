@@ -548,8 +548,9 @@ def test_counters_count_what_the_reference_attends(model):
     names = ("moe_rows", "moe_rows_padded", "moe_load_max", "moe_load_mean",
              "moe_experts_touched", "moe_rows_routed", "dsa_keys_visible",
              "dsa_keys_selected", "dsa_rows_distinct", "dsa_index_layers",
-             "dsa_index_reused", "swa_keys_visible", "swa_keys_attended",
-             "swa_layers")
+             "dsa_index_reused", "dsa_select_keys_walked",
+             "dsa_select_keys_table", "swa_keys_visible",
+             "swa_keys_attended", "swa_layers")
     got = dict(zip(names, counters.tolist()))
     assert len(counters) == glm_dsa.N_COUNTERS + len(obs_steps.SWA_COUNTERS)
     assert got["swa_keys_visible"] == 4 * 253
@@ -558,6 +559,9 @@ def test_counters_count_what_the_reference_attends(model):
     assert got["dsa_keys_visible"] == 2 * 253
     assert got["dsa_keys_selected"] == 2 * (36 + 14 * 8)
     assert got["dsa_index_layers"] == 2 * 5 and got["dsa_index_reused"] == 0
+    # 3 windows x 2 full layers over a table of one block
+    assert (got["dsa_select_keys_walked"] == got["dsa_select_keys_table"]
+            == 3 * 2 * MAX_SEQ)
     assert c.family.counters == names
     assert names[-3:] == tuple(k for k, _ in obs_steps.SWA_COUNTERS)
 

@@ -545,6 +545,9 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
         assert r["dsa_rows_distinct"] <= r["dsa_keys_selected"]
         assert r["dsa_index_reused"] == 1.5 * r["dsa_index_layers"]
         assert r["moe_rows_routed"] >= r["moe_rows"]
+        # the windows' selections: the mixed dispatches' alone
+        assert (r["dsa_select_keys_walked"] > 0) == (r["kind"] == "mixed")
+        assert r["dsa_select_keys_walked"] <= r["dsa_select_keys_table"]
     assert any(r.get("chained") for r in records if r["kind"] == "decode")
     assert all(v > 0 for v in moved.values()), moved
     assert eng._mixed_buckets == (32,) and not eng._prefix_capable

@@ -513,6 +513,13 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
         assert r["dsa_keys_single"] <= r["dsa_keys_scanned_single"]
         # (a step of k prompts is k dispatches: Windows.DISPATCH)
         assert r["dsa_index_layers"] % c.num_hidden_layers == 0
+        # the window's selection: a mixed dispatch's alone, and at this
+        # table (one block) the whole width
+        walked, table = r["dsa_select_keys_walked"], r["dsa_select_keys_table"]
+        assert walked == table == (
+            r["dsa_index_layers"] * eng.cache.table.shape[1]
+            * eng.cache.page_size
+            if r["kind"] == "mixed" else 0)
     assert any(r["dsa_keys_selected"] < r["dsa_keys_visible"]
                for r in counted)
     assert eng.flight._counters == kv2.COUNTERS
@@ -550,24 +557,29 @@ def test_prefix_registration_is_refused_by_name():
 # (368216f): no selected-set operand, leaf or table reaches a program
 # of a family without an indexer over K/V pages. A change that means to
 # move these programs re-pins them (the same calls in the parent's
-# tree); one that does not, and fails here, has moved them.
+# tree); one that does not, and fails here, has moved them. PR 61
+# re-pinned glm_moe_dsa's and dots3_note's eight: their mixed programs
+# call `cake_dsa_select` where `select_mask` stood, and all eight return
+# a counter vector two keys longer (`dsa_select_keys_walked` /
+# `_table`: two constant zeros in a decode program, nothing else of it
+# moves); the other twelve are as they were.
 LOWERED_BEFORE = {
     ("glm_moe_dsa", "decode", "fold"):
-        "56e60f9d3d8c4387e6cb6c57614586b530a4c6379257b941985c904899870d20",
+        "dce0f7aaa8b8a49daf146fd844459fbdbea9f14bd69e345a6c42b76e9cffcd31",
     ("glm_moe_dsa", "decode", "pallas"):
-        "c0462d43bafe1db9f34239825d296cedda549e23a60ccadee3ae38e019380859",
+        "1d7b0cd38214140d9263f38b95eefe73ce52c6cd518b31796c97d5cc9113f461",
     ("glm_moe_dsa", "mixed", "fold"):
-        "7b42a6109066d6e98ebcecd61d8cb9b8c71e250672bde7946830d4d1ea6f320b",
+        "4af0f53df587d217ce2b9683ca2cfcc577ca287004cd4535be663ca271d405ac",
     ("glm_moe_dsa", "mixed", "pallas"):
-        "d7519b1def91f310cc8c2ad9253aa365447008b2380efda3f352092cdb5fe2c2",
+        "fd8ffbbacb43f1b8e72c99f64c28835b86a00b6b89a1a0109dab2fb5d7364412",
     ("dots3_note", "decode", "fold"):
-        "71c8621b347c946990c803d2c2c6ea625b1e70a12d29059e0e43c917f55ab91c",
+        "b65a7ec4863f3eb41ee9c86a171bb30e7a5c206952a64bcb12d7a8d81a8bdbd4",
     ("dots3_note", "decode", "pallas"):
-        "b4c5f8ec9c61cb8edf0cb18817fae7b8179df761a5c7e7d3b3eba2467dd70338",
+        "33cdecb695272c592341dca150e1c2ce7f79e3d108a115e0b1630754b5a72346",
     ("dots3_note", "mixed", "fold"):
-        "91cdeecc414336e7a15ba70924c665914eaa3487074dc9f5ebb3514788cc7af9",
+        "91fb4367db60d29cd10539de142fee7cdb08239cba23a4545d28ff0cced3b4cb",
     ("dots3_note", "mixed", "pallas"):
-        "3b969adec124e9877ea10aef57c280d5fe8cfde6edd6a5a86c1ef416b4fad19e",
+        "1d91ec53855fe5b44a3689aa120240161047d8736e2a0f45223657d9209b891a",
     ("exaone_moe", "decode", "fold"):
         "07419160964f7238f5651f50e8b883b0627df46bf064c797682ff624ffa9815c",
     ("exaone_moe", "decode", "pallas"):

@@ -19,6 +19,7 @@ never at import (the on-chip-measurement guide, section 2).
 import dataclasses
 import importlib.util
 import pathlib
+import re
 
 import pytest
 
@@ -510,6 +511,88 @@ def test_latent_window_kernel_compiles_at_the_cells_widths(
                                  biased)
     assert tq * heads == (1024 if row == 640 else 512)
     assert block == (2 if pages == 9 else 4)
+
+
+@pytest.mark.parametrize("S", [33280, 12800, 16896],
+                         ids=["keyevl2", "glm52", "dots3"])
+def test_dsa_select_kernel_compiles_at_the_cells_widths(one_chip, S):
+    """`cake_dsa_select` at the three selecting cells' shapes (a window
+    of 512 queries, top 2,048, the table 33,280 / 12,800 / 16,896 keys
+    wide) goes through Mosaic inside the scoped VMEM it states: a tile
+    of 128 queries' resident codes (17 MB at 33,280) and the result's
+    two buffers pass the compiler's default of 16 MiB. The float32
+    scores reach the kernel as they lie (no copy in front of it) and
+    the program holds nothing beside its operands and result."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import mla_attention as mla
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, K = 512, 2048
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        compiled = jax.jit(
+            lambda s, p, last: mla.select_window(s, p, last, K)).lower(
+            sds((C, S), jnp.float32), sds((C,), jnp.int32),
+            sds((), jnp.int32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cake_dsa_select" in line]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert f'"size":"{mla._SELECT_VMEM_LIMIT}"' in calls[0]
+    # the scores reach it as they lie: no [512, S] array is written
+    # in front of the call
+    assert not re.search(
+        r"= (?:f32|s32)\[512,%d\]\S* (?:copy|bitcast-convert|fusion)\(" % S,
+        hlo)
+    tq, chunk, block = mla.select_tiles(C, S)
+    assert (tq, chunk) == (128, 128) and S % block == 0
+    assert tq * S * 4 + 2 * tq * S < mla._SELECT_VMEM_LIMIT
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_keyes_mixed_program_holds_one_select_call_a_layer(tool, one_chip):
+    """Keye's served mixed program at the cell's widths (two of its
+    alike layers: the trunk is one scan) for the described v5e: ONE
+    `cake_dsa_select` call in the layer scan, under the scope
+    `index_topk`, and under that scope no running count over the
+    window's [512, 33,280] codes any more (XLA's `reduce-window`s over
+    s32[512,260,128], a `cumsum` by name) and no counting loop."""
+    import json
+
+    from cake_tpu.models.llama.config import load_config
+
+    cell = CONFIGS / "keye-vl-2.0-lm-int8-8of48"
+    config = dataclasses.replace(load_config(str(cell)), num_hidden_layers=2)
+    with open(cell / "cell.json") as f:
+        sa = json.load(f)["server_args"]
+    width = sa["prefill-chunk"]
+    _, mixed = tool.step_fns(config)
+    with jax.default_matmul_precision("default"):
+        compiled = tool.compile_step(
+            mixed, config, one_chip, width=width,
+            n_tokens=width + sa["max-slots"], slots=sa["max-slots"],
+            n_pages=sa["kv-pages"], page_size=sa["kv-page-size"],
+            max_seq_len=sa["max-seq-len"])
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cake_dsa_select" in line]
+    assert len(calls) == 1
+    assert "index_topk" in calls[0]         # the call lies under the scope
+    under = []
+    for line in hlo.splitlines():
+        if "index_topk" not in line:
+            continue
+        if ("reduce-window(" in line or "cumsum" in line
+                or "[512,260,128]" in line or " while(" in line):
+            under.append(line.strip()[:200])
+    assert not under, ("the selection's XLA form under index_topk again: "
+                       + "; ".join(under))
 
 
 @pytest.mark.parametrize("n_pairs,E,K,N", [
