@@ -112,6 +112,14 @@ class Family:
     # layers of a dispatch); the host counts them into the mixed
     # records. None: no such kernel
     window_walk: Optional[Callable] = None
+    # a family that hands a dispatch's window to cake_mixed_attn in a
+    # form of its own (entries of one row's window): mixed_attn_walk(
+    # config, cache, width) -> ((the window's first position, its
+    # tokens, 0 where the dispatch holds single tokens alone) -> (pages
+    # the call walks a layer, its table's entries, softmax updates));
+    # the host counts them into the mixed records. None: its rows go as
+    # they are (kernel_rows), or the host cannot know the call
+    mixed_attn_walk: Optional[Callable] = None
     # what its rows hold (the message's head) and cannot move yet:
     # option -> reason (cannot_move)
     what: str = ""

@@ -74,6 +74,7 @@ from cake_tpu.models.step_programs import (
     make_decode_scan, make_mixed_sampled,
 )
 from cake_tpu.ops import mla_attention as mla
+from cake_tpu.ops import ragged_paged_attention as rpa
 from cake_tpu.ops.moe import LayerOf
 from cake_tpu.ops.norms import rms_norm
 from cake_tpu.ops.quant import qmatmul
@@ -462,6 +463,28 @@ def create_cache(config: KeyeVL2Config, slots: int, n_pages: int,
         shape_idx=row[:3] + (c.index_head_dim,))
 
 
+def mixed_attn_walk(config, cache, width: int):
+    """What the engine counts into a mixed record
+    (Family.mixed_attn_walk): (the window's first position, its tokens)
+    -> (pages, table entries, folds) of a layer's `cake_mixed_attn`
+    call, as attend_window makes it: the window in entries of
+    `query_tile` queries over its row's table, at the pages a fold the
+    kernel takes for these shapes."""
+    c = config
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    P, max_pages = cache.k.shape[2], cache.table.shape[1]
+    q_size = kv_size = cache.k.dtype.itemsize
+    tile = query_tile(width, H, KV, hd, P, q_size, kv_size)
+    block = rpa.mixed_block(P, H, KV, hd, tile, max_pages, q_size, kv_size,
+                            selecting=True)
+
+    def walk(first_pos: int, n: int):
+        return rpa.mixed_entries_walk(first_pos, n, width, tile, P,
+                                      max_pages, block)
+
+    return walk
+
+
 FAMILY = Family(
     name="KeyeVL2", decode_step=decode_step_selected,
     decode_programs=make_decode_scan(forward_ragged_selected),
@@ -475,7 +498,7 @@ FAMILY = Family(
     impl="paged-dsa-gqa-", resolve_attn=_resolve_attn,
     # no step kind's rows go through the kernels AS THEY ARE: a single
     # token's call walks its gathered rows, the window's carries a mask
-    kernel_rows=(),
+    kernel_rows=(), mixed_attn_walk=mixed_attn_walk,
     what="selected keys over K/V pages and an index-key pool beside them",
     refuses=cannot_move(
         "index-key pool",

@@ -272,6 +272,23 @@ _WINDOW_FOLDS = _m.counter(
     "Softmax updates those pages take a query tile, a block of pages "
     "each (under it, cake_mla_window_pages_total is the pages a fold "
     "shares one accumulator pass among)")
+_MIXED_ATTN_PAGES = _m.counter(
+    "cake_mixed_attn_pages_total",
+    "KV pages the mixed attention kernel walks a layer, summed over the "
+    "rows (or a window's entries) of the mixed steps' calls: from the "
+    "page of a row's first query's first key to that of its last real "
+    "query, none for an idle row, counted on the host from the positions "
+    "it dispatches (ops/ragged_paged_attention.mixed_walk)")
+_MIXED_ATTN_PAGES_TABLE = _m.counter(
+    "cake_mixed_attn_pages_table_total",
+    "Entries of those calls' page tables, rows x pages a row: what a "
+    "grid over (row, page) stepped through (over it, "
+    "cake_mixed_attn_pages_total is the share that holds work)")
+_MIXED_ATTN_FOLDS = _m.counter(
+    "cake_mixed_attn_folds_total",
+    "Softmax updates those pages take, a block of pages each (under it, "
+    "cake_mixed_attn_pages_total is the pages a fold shares one "
+    "accumulator pass among: ops/ragged_paged_attention.mixed_block)")
 # The step programs' counters: (record key, series) by the group a
 # family's trunk returns them in. The ORDER of a program's vector is
 # the trunk's and is stated beside it (a family's `counters`,
@@ -851,6 +868,13 @@ class StepRecord:
     # the softmax updates they take
     window_pages: Optional[int] = None
     window_folds: Optional[int] = None
+    # a mixed step whose family tells the host how it calls the mixed
+    # attention kernel: the pages the kernel walks a layer over the
+    # step's calls, the entries of their page tables, and the softmax
+    # updates the pages take
+    mixed_attn_pages: Optional[int] = None
+    mixed_attn_pages_table: Optional[int] = None
+    mixed_attn_folds: Optional[int] = None
     # rids whose rows this step's dispatched batch contained (bounded
     # by the engine's slot count) — the per-request explain endpoint
     # (obs/timeline.py) selects a request's steps through this
@@ -950,6 +974,10 @@ class StepRecord:
         if self.window_pages is not None:
             out["window_pages"] = self.window_pages
             out["window_folds"] = self.window_folds
+        if self.mixed_attn_pages is not None:
+            out["mixed_attn_pages"] = self.mixed_attn_pages
+            out["mixed_attn_pages_table"] = self.mixed_attn_pages_table
+            out["mixed_attn_folds"] = self.mixed_attn_folds
         if self.rids is not None:
             out["rids"] = list(self.rids)
         if self.phases:
@@ -1406,6 +1434,9 @@ class StepTelemetry:
                attn_pages_table: Optional[int] = None,
                window_pages: Optional[int] = None,
                window_folds: Optional[int] = None,
+               mixed_attn_pages: Optional[int] = None,
+               mixed_attn_pages_table: Optional[int] = None,
+               mixed_attn_folds: Optional[int] = None,
                rids: Optional[Sequence[int]] = None,
                impl: Optional[str] = None,
                moe: Optional[Sequence[float]] = None,
@@ -1427,7 +1458,11 @@ class StepTelemetry:
         (cake_decode_attn_pages_total, ..._table_total); window_pages /
         window_folds the pages a tile of the latent window kernel walks
         in a mixed step and the softmax updates they take
-        (cake_mla_window_pages_total, cake_mla_window_folds_total).
+        (cake_mla_window_pages_total, cake_mla_window_folds_total);
+        mixed_attn_pages / mixed_attn_pages_table / mixed_attn_folds the
+        pages the mixed attention kernel walks a layer over a mixed
+        step's calls, their tables' entries and the softmax updates
+        (cake_mixed_attn_pages_total, ..._table_total, ..._folds_total).
         rids: the requests whose rows rode this dispatch (the
         per-request explain's step linkage). impl: the attention
         this step actually ran, where the engine resolved it per step
@@ -1506,6 +1541,9 @@ class StepTelemetry:
                 attn_q_tiles_window=attn_q_tiles_window,
                 attn_pages=attn_pages, attn_pages_table=attn_pages_table,
                 window_pages=window_pages, window_folds=window_folds,
+                mixed_attn_pages=mixed_attn_pages,
+                mixed_attn_pages_table=mixed_attn_pages_table,
+                mixed_attn_folds=mixed_attn_folds,
                 rids=(tuple(int(r) for r in rids)
                       if rids is not None else None),
                 phases=phases or None, gap_s=gap, chained=chained,
@@ -1550,6 +1588,10 @@ class StepTelemetry:
         if window_pages is not None:
             _WINDOW_PAGES.inc(window_pages)
             _WINDOW_FOLDS.inc(window_folds)
+        if mixed_attn_pages is not None:
+            _MIXED_ATTN_PAGES.inc(mixed_attn_pages)
+            _MIXED_ATTN_PAGES_TABLE.inc(mixed_attn_pages_table)
+            _MIXED_ATTN_FOLDS.inc(mixed_attn_folds)
         if moe is not None:
             for key, v in rec.moe.items():
                 COUNTER_SERIES[key].inc(v)

@@ -240,7 +240,7 @@ def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
     512 the accumulator is the whole register file)."""
     H = q_ref.shape[0]
 
-    def copies(layer, pid, slot):
+    def copies(layer, pid, slot, *_trip):
         return [pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot],
                                       sem.at[slot])]
 
@@ -248,7 +248,7 @@ def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
                                      copies, depth=depth, page_size=page)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def fold(j, pid, slot, stats):
+    def fold(j, _found, slot, stats):
         m_prev, l_prev = stats
         kv = buf[slot]                                   # [page, W]
         s = rpa._dot(q_ref[...], kv, trans_b=True) * scale   # [H, page]

@@ -27,11 +27,12 @@ Paged Attention" shape, PAPERS.md arxiv 2604.15464):
     shape), and the pages ahead are the NEXT rows' when this row's run
     out, so only a call's first row starts cold. An unmapped hole
     inside the live range starts no copy and folds nothing;
-  * the MIXED kernel keeps the older form: grid (rows, pages), page
-    axis innermost and sequential, one page a grid step through a k/v
-    BlockSpec whose index map resolves `table[row, j]`; pages past the
-    row's live count clamp their index to the last live page, so
-    Pallas elides the repeated DMA, and `pl.when` skips the compute;
+  * the MIXED kernel walks the same way since PR 62 (it was the last on
+    a (rows, pages) grid, one page a grid step through a k/v BlockSpec):
+    a grid step a row, the walk bounded by the row's LAST real query,
+    `mixed_block` pages side by side in a ring slot and ONE softmax
+    update a block, the statistics carried by the loop; an idle row
+    takes no trip;
   * causal + unmapped-page masking inside a live page (absolute slot
     `j*page + t` attends iff `<= pos` and the page id is mapped);
   * GQA without repeat_kv: the KV-head axis is unrolled statically
@@ -48,10 +49,9 @@ Paged Attention" shape, PAPERS.md arxiv 2604.15464):
 Layout contract: the kernels take the STACKED pool as it is stored,
 [L, N_pages, page, KV*hd] (`models/llama/paged.py`; a packed int4 pool
 [L, N_pages, page//2, KV*hd]), and the layer as one more scalar-prefetch
-operand. What a copy (decode) or a k/v block (mixed) moves is (layer,
-page) -> one (page, KV*hd) tile, lane-aligned when hd is a multiple of
-128, DMA'd straight out of the pool: the wrappers neither slice a
-layer out nor reshape anything. (A
+operand. What a copy moves is (layer, page) -> one (page, KV*hd) tile,
+lane-aligned when hd is a multiple of 128, DMA'd straight out of the
+pool: the wrappers neither slice a layer out nor reshape anything. (A
 reshape from a per-head [.., KV, hd] pool to this shape is a relayout
 of the whole pool on the chip — four times the kernel's own time,
 PERF.md PR 24 — which is why the pool is STORED this way.)
@@ -61,9 +61,9 @@ metadata with a per-row query length: one grid processes decode rows
 (q_len=1) and prefill-chunk rows (q_len=C at arbitrary page offset)
 in the same launch — the token-level continuous-batching step the
 engine's `mixed_step_paged` path dispatches, with per-row causal
-masking and the same per-row early exit. Its work in a (row, page)
-cell follows q_len too: a decode row folds its one query, not its
-window (`_mixed_fold`, MIXED_Q_TILE).
+masking and the same per-row early exit. Its work a page follows
+q_len too: a decode row folds its one query, not its window
+(`_mixed_fold`, MIXED_Q_TILE).
 
 CPU tests run the same kernel with interpret=True
 (tests/test_ragged_paged_attn.py), mirroring flash_attention.py.
@@ -80,6 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_NEVER = 2**30      # a position no query reaches
 
 
 def _dot(a, b, *, trans_b: bool):
@@ -172,11 +173,12 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
 
 def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
                     depth: int, page_size: int,
-                    window: Optional[int] = None):
-    """The walk of a decode kernel whose grid step is one ROW: the row's
-    live pages 0 .. pos // page, fetched out of pools that lie whole in
-    HBM by the kernel's own copies into rings of `depth` VMEM slots,
-    the pages ahead in flight while page j folds.
+                    window: Optional[int] = None, first_ref=None,
+                    block: int = 1):
+    """The walk of a kernel whose grid step is one ROW: the row's live
+    pages 0 .. pos // page, fetched out of pools that lie whole in HBM
+    by the kernel's own copies into rings of `depth` VMEM slots, the
+    pages ahead in flight while page j folds.
 
     window (static): a BAND. The row attends its last `window` keys,
     its own included, so the walk starts at the page that holds
@@ -188,11 +190,25 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
     serves its logical page p from entry p mod R. None: no band, and
     the program is the one it was.
 
-    copies(layer, pid, slot): the async copies of the layer's page
-             `pid` into ring slot `slot`, one a pool (K and V; a latent
-             pool is a list of one), the same to start and to wait on.
-    cur:     SMEM int32 [4]: the copies' cursor (row, page, count of
-             pages started) and the count of pages folded, carried from
+    first_ref (None: pos_ref): where a row holds SEVERAL queries (the
+    mixed kernel), pos_ref gives the position of its last one, which
+    bounds the walk, and first_ref that of its first, whose band the
+    walk starts at.
+
+    block (static): the pages of a TRIP, side by side in one ring slot,
+    so that one fold (one softmax update) takes them all. 1: a trip is
+    a page, and the program is the one it was. More: a trip's pages
+    past the row's last live one, and its unmapped ones, start no copy
+    and are handed to the fold as not fetched (their part of the slot
+    keeps what lay there).
+
+    copies(layer, pid, slot, row, p, f): the async copies of the
+             layer's page `pid` into place f of ring slot `slot`, one a
+             pool (K and V; a latent pool is a list of one), the same
+             to start and to wait on. (row, p): whose page it is and
+             which of its walk, for a copy that goes by them.
+    cur:     SMEM int32 [4]: the copies' cursor (row, trip, count of
+             trips started) and the count of trips folded, carried from
              row to row: the pages ahead are the NEXT rows' when this
              row's run out, so the ring is warm at every row but the
              call's first. An unmapped hole inside the live range
@@ -201,18 +217,21 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
 
     Primes the ring at the call's first row, then returns (pos, pages):
     the row's position, and pages(fold, stats) -> stats, the loop over
-    the row's live pages with fold(j, pid, slot, stats) called once the
-    copies of logical page j (page id pid) have landed in `slot`."""
+    the row's trips with fold(j, found, slot, stats) called once the
+    copies of the trip that starts at logical page j have landed in
+    `slot`; found: [(pid, fetched)], a pair a page of the trip."""
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     layer = layer_ref[0]
     max_pages = table_ref.shape[1]
+    if first_ref is None:
+        first_ref = pos_ref
 
     def first_page(row):
         """The logical page a row's walk starts at."""
         if window is None:
             return 0
-        return jnp.maximum(pos_ref[row] - (window - 1), 0) // page_size
+        return jnp.maximum(first_ref[row] - (window - 1), 0) // page_size
 
     def live_pages(row):
         if window is None:
@@ -223,11 +242,29 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
             jnp.minimum(pos // page_size - first_page(row) + 1, max_pages),
             0)
 
+    def trips(row):
+        n = live_pages(row)
+        return n if block == 1 else (n + (block - 1)) // block
+
     def page_id(row, j):
-        """The page of trip j of a row's walk."""
+        """The page of place j of a row's walk."""
         if window is None:
             return table_ref[row, j]
         return table_ref[row, (first_page(row) + j) % max_pages]
+
+    def trip_pages(row, j):
+        """Trip j of a row's walk: [(place in the walk, page id,
+        whether it is fetched)], a triple a page."""
+        if block == 1:
+            pid = page_id(row, j)
+            return [(j, pid, pid >= 0)]
+        n = live_pages(row)
+        found = []
+        for f in range(block):
+            p = j * block + f
+            pid = page_id(row, jnp.minimum(p, max_pages - 1))
+            found.append((p, pid, jnp.logical_and(p < n, pid >= 0)))
+        return found
 
     def next_live_row(row):
         return jax.lax.while_loop(
@@ -242,15 +279,14 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
         def _():
             # an unmapped hole inside the live range starts no copy
             # (and folds nothing, below): its slot stays idle
-            pid = page_id(row, j)
-
-            @pl.when(pid >= 0)
-            def _():
-                for c in copies(layer, pid, count % depth):
-                    c.start()
+            for f, (p, pid, fetched) in enumerate(trip_pages(row, j)):
+                @pl.when(fetched)
+                def _():
+                    for c in copies(layer, pid, count % depth, row, p, f):
+                        c.start()
 
             cur[2] = count + 1
-            row_ends = j + 1 == live_pages(row)
+            row_ends = j + 1 == trips(row)
 
             @pl.when(row_ends)
             def _():
@@ -274,26 +310,38 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
 
         jax.lax.fori_loop(0, depth - 1, prime, 0)
 
-    n = live_pages(b)
+    n = trips(b)
     first = cur[3]
     cur[3] = first + n
     j0 = first_page(b)
 
     def pages(fold, stats):
-        def landed(j, pid, stats):
+        def landed(j, found, stats):
             slot = (first + j) % depth
-            for c in copies(layer, pid, slot):
-                c.wait()
-            return fold(j if window is None else j0 + j, pid, slot, stats)
+            for f, (p, pid, fetched) in enumerate(found):
+                def wait():
+                    for c in copies(layer, pid, slot, b, p, f):
+                        c.wait()
+                if block == 1:
+                    wait()
+                else:
+                    pl.when(fetched)(wait)
+            at = j if block == 1 else j * block
+            return fold(at if window is None else j0 + at,
+                        [(pid, fetched) for _p, pid, fetched in found],
+                        slot, stats)
 
-        def page(j, stats):
-            # the slot this frees held page j - 1, folded a step ago
+        def trip(j, stats):
+            # the slot this frees held trip j - 1, folded a step ago
             start_next()
-            pid = page_id(b, j)
-            return jax.lax.cond(pid >= 0, lambda s: landed(j, pid, s),
+            found = trip_pages(b, j)
+            if block > 1:
+                return landed(j, found, stats)
+            return jax.lax.cond(found[0][2],
+                                lambda s: landed(j, found, s),
                                 lambda s: s, stats)
 
-        return jax.lax.fori_loop(0, n, page, stats)
+        return jax.lax.fori_loop(0, n, trip, stats)
 
     return pos_ref[b], pages
 
@@ -315,7 +363,7 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
     q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur = refs
     H, hd = q_ref.shape[2:]
 
-    def copies(layer, pid, slot):
+    def copies(layer, pid, slot, *_trip):
         return [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot],
                                       sem.at[i, slot])
                 for i, (pool, buf) in enumerate(((k_hbm, kbuf),
@@ -328,7 +376,8 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
     if quantized:
         q = q.astype(jnp.float32)
 
-    def fold(j, pid, slot, stats):
+    def fold(j, found, slot, stats):
+        (pid, _fetched), = found
         scales = None
         if quantized:
             def scales(kv):
@@ -358,13 +407,6 @@ def _layer_scales(scale, layer):
     scale = jnp.asarray(scale, jnp.float32)
     return jax.lax.dynamic_index_in_dim(
         scale, layer, axis=0, keepdims=False).reshape(-1)
-
-
-def _pool_block(Pb: int, width: int, index_map):
-    """One page of one layer: the stacked pool's (layer, page) tile,
-    with the layer axis squeezed so the kernel bodies see
-    [1, page, KV*hd]."""
-    return pl.BlockSpec((None, 1, Pb, width), index_map)
 
 
 def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
@@ -461,8 +503,8 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     )(*operands, q, pool_k, pool_v)
 
 
-# Queries to a TILE of the mixed kernel's window. A (row, page) grid
-# cell folds the row's first tile when that holds its every real query
+# Queries to a TILE of the mixed kernel's window. A row's walk folds
+# the row's first tile when that holds its every real query
 # (a decode row, q_len 1) and its whole window otherwise, so a decode
 # row costs MIXED_Q_TILE*G rows of scores a page and kv head, not C*G.
 # Every slice is static, so the tile need not fill a sublane tile; on a
@@ -480,94 +522,66 @@ def mixed_q_tiles(q_len: int, q_width: int) -> int:
     return 1 if q_len <= MIXED_Q_TILE else -(-q_width // MIXED_Q_TILE)
 
 
-def _mixed_first_page(pos, page_size: int, window: Optional[int]):
-    """The logical page grid step 0 of a mixed row stands for: the page
-    that holds the first key its first query attends under a band of
-    `window` keys (0 with no band: python, so that the program without
-    one is the one it was)."""
-    if window is None:
-        return 0
-    return jnp.maximum(pos - (window - 1), 0) // page_size
+def _mixed_fold(pos_ref, qlen_ref, q_ref, o_ref, acc_ref, pages, page_kv, *,
+                scale: float, page_size: int, block: int, kv_heads: int,
+                group: int, head_dim: int, q_width: int,
+                quantized: bool = False, window: Optional[int] = None,
+                sel_page=None):
+    """One ROW of the MIXED ragged fold: each row carries q_width query
+    slots of which q_len are real — a decode row (q_len=1) and a
+    prefill-chunk row (q_len=C at arbitrary page offset) fold through
+    the same call. The one body of the float, int8 and int4 pools:
+    `page_kv(kv, slot, found)` hands it kv head `kv` of the trip's
+    pages in ring slot `slot` as (kh, vh [block * P, hd], k_scales,
+    v_scales), the scales a list of a page's each, None for a float
+    pool.
 
-
-def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, page_kv, *, scale: float, page_size: int,
-                kv_heads: int, group: int, head_dim: int, q_width: int,
-                window: Optional[int] = None, sel_ref=None):
-    """One (row, page) grid step of the MIXED ragged fold: each row
-    carries q_width query slots of which q_len are real — a decode row
-    (q_len=1) and a prefill-chunk row (q_len=C at arbitrary page
-    offset) fold through the same grid. The one body of the float,
-    int8 and int4 kernels: `page_kv(kv, pid)` hands it kv head `kv` of
-    the page as (kh, vh [P, hd], k_scale, v_scale), the scales None for
-    a float pool.
-
+    pages:   the row's walk (walk_live_pages): its live pages from the
+             page of the first key its first query attends to that of
+             its LAST real query, `block` of them a trip and a softmax
+             update. An idle row (q_len 0) takes no trip.
     q_ref:   [1, C, H, hd] — the row's query window, first token at
              absolute position pos
-    scratch: acc [KV*C*G, hd] f32, m/l [KV*C*G, 128] f32, rows ordered
-    (kv, query, group) so each kv head's fold is a contiguous slice;
-    carried across the page axis exactly like the decode kernel.
+    acc_ref: [KV*C*G, hd] f32, rows ordered (kv, query, group) so each
+             kv head's fold is a contiguous slice; the softmax's m and
+             l ([R, 1] a kv head) are carries of the walk's loop.
 
     The work follows q_len: a row whose real queries all lie in its
     first tile (MIXED_Q_TILE queries: a decode row, an idle row) folds,
-    initialises and finishes that tile alone — scratch rows
+    initialises and finishes that tile alone — accumulator rows
     [kv*C*G, +Tq*G) of each kv head — and any other row its whole
     window, in one piece (folding a window tile by tile under a loop
     cost a full window 1.8x the time on a v5e: each tile pays for the
-    page's K and V again). A query's online softmax runs page by page
-    in the same order either way, so its result does not depend on its
-    row's q_len, nor on the other rows'. Output columns: those of a
-    tile the row did not fold are ZERO (written at the row's first
-    page); padded columns of a folded span are what they always were,
-    the fold of a query that is not there — finite, never read.
+    page's K and V again). What does not change from page to page is
+    made once a row: the queries' [nq*G, hd] form a kv head, and the
+    mask's [R, P] of (query's position - column). A query's online
+    softmax runs trip by trip in the same order either way (both spans
+    take the same `block`), so its result does not depend on its row's
+    q_len, nor on the other rows'. Output columns: those of a tile the
+    row did not fold are ZERO; padded columns of a folded span are what
+    they always were, the fold of a query that is not there — finite,
+    never read.
 
     window (static): a BAND. Query i attends keys pos + i - window + 1
-    .. pos + i. Grid step j then stands for logical page first + j,
-    first the page of the first key the row's first query attends, so
-    that no page wholly before the band is fetched or folded, and the
-    mask cuts the rest; logical page p is read through table entry
-    p mod max_pages (a ring of R entries serves page p from entry
-    p mod R; a whole table reads as it did).
+    .. pos + i; the walk starts at the page of the first key the row's
+    first query attends, so that no page wholly before the band is
+    fetched or folded, and the mask cuts the rest.
 
-    sel_ref (None: no such operand, and the program is the one it was):
-    [1, 1, C, P] float32, the grid step's page of a per-(query, key)
-    selection: query i attends a key of this page only where its entry
-    is above 0.5 (a sparse indexer's sets: models/moe/keye_vl2.py). A
-    query's row reaches its G rows of the scores by a sublane
-    broadcast, as ops/mla_attention._spread's.
+    sel_page (None: no selection, and the program is the one it was):
+    sel_page(slot, f) -> [C, P] float32, page f of the trip of a
+    per-(query, key) selection: query i attends a key of that page only
+    where its entry is above 0.5 (a sparse indexer's sets:
+    models/moe/keye_vl2.py). A query's row reaches its G rows of the
+    scores by a sublane broadcast, as ops/mla_attention._spread's.
     """
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-
     C = q_width
     G = group
     P = page_size
     hd = head_dim
     Tq = min(MIXED_Q_TILE, C)
-
     pos = pos_ref[b]
-    # last REAL query's absolute position bounds the live page count;
-    # q_len=0 (idle row) clamps to pos so the row still costs one page
-    # of masked compute, never a negative bound
-    n_q = jnp.maximum(qlen_ref[b], 1)
-    last = pos + n_q - 1
-    # lp: the logical page this grid step stands for
-    if window is None:
-        lp = j
-        page = table_ref[b, j]
-    else:
-        lp = _mixed_first_page(pos, P, window) + j
-        page = table_ref[b, lp % nj]
-    live = jnp.logical_and(lp * P <= last, page >= 0)
-
-    def span(body):
-        """body(nq) once, nq (static) the queries this row folds."""
-        if Tq == C:
-            body(C)
-        else:
-            pl.when(n_q <= Tq)(functools.partial(body, Tq))
-            pl.when(n_q > Tq)(functools.partial(body, C))
+    n_q = qlen_ref[b]
 
     def rows(kv, nq):
         return slice(kv * C * G, kv * C * G + nq * G)
@@ -575,129 +589,182 @@ def _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
     def heads(kv):
         return slice(kv * G, (kv + 1) * G)
 
-    @pl.when(j == 0)
-    def _init():
-        def init(nq):
-            if nq < C:
-                o_ref[0, nq:] = jnp.zeros((C - nq,) + o_ref.shape[2:],
-                                          o_ref.dtype)
-            for kv in range(kv_heads):
-                r = rows(kv, nq)
-                acc_ref[r] = jnp.zeros((nq * G, hd), jnp.float32)
-                m_ref[r] = jnp.full((nq * G, m_ref.shape[1]), NEG_INF,
-                                    jnp.float32)
-                l_ref[r] = jnp.zeros((nq * G, l_ref.shape[1]), jnp.float32)
-        span(init)
+    def row(nq):
+        """The row's walk over its first nq (static) queries."""
+        R = nq * G
+        if nq < C:
+            o_ref[0, nq:] = jnp.zeros((C - nq,) + o_ref.shape[2:],
+                                      o_ref.dtype)
+        qs = []
+        for kv in range(kv_heads):
+            acc_ref[rows(kv, nq)] = jnp.zeros((R, hd), jnp.float32)
+            qh = q_ref[0, :nq, heads(kv), :].reshape(R, hd)
+            qs.append(qh.astype(jnp.float32) if quantized else qh)
+        # per-(query, column) causal mask: query i sits at absolute
+        # position pos + i and attends page slots <= it (current token
+        # included — its KV is written before the kernel runs): column
+        # t of logical page p is seen where p * P <= pos + i - t
+        ahead = (pos + jax.lax.broadcasted_iota(jnp.int32, (R, P), 0) // G
+                 - jax.lax.broadcasted_iota(jnp.int32, (R, P), 1))
 
-    @pl.when(live)
-    def _fold():
-        pid = jnp.maximum(page, 0)
-
-        def fold(nq):
-            R = nq * G
-            # per-(query, column) causal mask: query i sits at absolute
-            # position pos + i and attends page slots <= it (current
-            # token included — its KV is written before the kernel runs)
-            qidx = jax.lax.broadcasted_iota(jnp.int32, (R, P), 0) // G
-            col = lp * P + jax.lax.broadcasted_iota(jnp.int32, (R, P), 1)
-            valid = col <= pos + qidx
-            if window is not None:
-                valid = jnp.logical_and(valid, col > pos + qidx - window)
-            if sel_ref is not None:
-                picked = jnp.concatenate(
-                    [jnp.broadcast_to(sel_ref[0, 0, i:i + 1, :], (G, P))
-                     for i in range(nq)], axis=0)            # [R, P]
-                valid = jnp.logical_and(valid, picked > 0.5)
+        def fold(lp, found, slot, stats):
+            parts = []
+            for f, (_pid, fetched) in enumerate(found):
+                start = (lp + f) * P
+                if block > 1:
+                    # a page that was not fetched starts past every query
+                    start = jnp.where(fetched, start, _NEVER)
+                seen = start <= ahead
+                if window is not None:
+                    seen = jnp.logical_and(seen, start + window > ahead)
+                if sel_page is not None:
+                    sel = sel_page(slot, f)
+                    picked = jnp.concatenate(
+                        [jnp.broadcast_to(sel[i:i + 1, :], (G, P))
+                         for i in range(nq)], axis=0)        # [R, P]
+                    seen = jnp.logical_and(seen, picked > 0.5)
+                parts.append(seen)
+            valid = parts[0] if block == 1 else jnp.concatenate(parts, axis=1)
+            out_stats = []
             for kv in range(kv_heads):
-                kh, vh, k_scale, v_scale = page_kv(kv, pid)  # [P, hd]
-                qh = q_ref[0, :nq, heads(kv), :].reshape(R, hd)
-                if k_scale is None:
-                    s = _dot(qh, kh, trans_b=True) * scale   # [R, P]
-                else:
+                kh, vh, k_scales, v_scales = page_kv(kv, slot, found)
+                if k_scales is None:
+                    s = _dot(qs[kv], kh, trans_b=True) * scale  # [R, F*P]
+                elif block == 1:
                     # dequantization folds into the dot outputs: one
                     # scale covers a page's every column of a kv head
-                    s = _dot(qh.astype(jnp.float32), kh,
-                             trans_b=True) * (scale * k_scale)
+                    s = _dot(qs[kv], kh, trans_b=True) * (scale * k_scales[0])
+                else:
+                    s = _dot(qs[kv], kh, trans_b=True) * (
+                        scale * jnp.concatenate(
+                            [jnp.full((1, P), k, jnp.float32)
+                             for k in k_scales], axis=1))
                 s = jnp.where(valid, s, NEG_INF)
-                r = rows(kv, nq)
-                m_prev = m_ref[r, :1]                        # [R, 1]
+                m_prev, l_prev = stats[kv]                   # [R, 1]
                 m_cur = jnp.max(s, axis=-1, keepdims=True)
                 m_new = jnp.maximum(m_prev, m_cur)
                 alpha = jnp.exp(m_prev - m_new)
-                # a query whose causal horizon precedes this page (or an
+                # a query whose causal horizon precedes this trip (or an
                 # all-hole row) has every column masked: m_new stays
                 # NEG_INF and exp(s - m_new) would be exp(0)=1 garbage —
-                # the explicit mask multiply keeps its l at 0 so _finish
-                # emits zeros, matching the fold reference's guard
+                # the explicit mask multiply keeps its l at 0 so the
+                # finish emits zeros, matching the fold reference's guard
                 p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-                l_new = (alpha * l_ref[r, :1]
-                         + jnp.sum(p, axis=-1, keepdims=True))
-                out = _dot(p.astype(vh.dtype), vh,
-                           trans_b=False)                    # [R, hd]
-                if v_scale is not None:
-                    out = out * v_scale
-                acc_ref[r] = acc_ref[r] * alpha + out
-                m_ref[r] = jnp.broadcast_to(m_new, (R, m_ref.shape[1]))
-                l_ref[r] = jnp.broadcast_to(l_new, (R, l_ref.shape[1]))
-        span(fold)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        def finish(nq):
-            for kv in range(kv_heads):
+                l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+                if v_scales is None:
+                    out = _dot(p.astype(vh.dtype), vh,
+                               trans_b=False)                # [R, hd]
+                else:
+                    # a scale a page: each page's product by its own
+                    out = sum(_dot(p[:, f * P:(f + 1) * P],
+                                   vh[f * P:(f + 1) * P],
+                                   trans_b=False) * v
+                              for f, v in enumerate(v_scales))
                 r = rows(kv, nq)
-                l = l_ref[r, :1]
-                l = jnp.where(l == 0.0, 1.0, l)
-                o = (acc_ref[r] / l).reshape(nq, G, hd)
-                o_ref[0, :nq, heads(kv), :] = o.astype(o_ref.dtype)
-        span(finish)
+                acc_ref[r] = acc_ref[r] * alpha + out
+                out_stats.append((m_new, l_new))
+            return tuple(out_stats)
+
+        stats = pages(fold, tuple(
+            (jnp.full((R, 1), NEG_INF, jnp.float32),
+             jnp.zeros((R, 1), jnp.float32)) for _ in range(kv_heads)))
+        for kv in range(kv_heads):
+            # a row that folded no page (idle, all-unmapped) has l == 0
+            # and a zero accumulator: zeros, as the fold reference gives
+            l = stats[kv][1]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o = (acc_ref[rows(kv, nq)] / l).reshape(nq, G, hd)
+            o_ref[0, :nq, heads(kv), :] = o.astype(o_ref.dtype)
+
+    if Tq == C:
+        row(C)
+    else:
+        pl.when(n_q <= Tq)(functools.partial(row, Tq))
+        pl.when(n_q > Tq)(functools.partial(row, C))
 
 
-def _rpa_mixed_kernel(layer_ref, pos_ref, qlen_ref, table_ref, q_ref, k_ref,
-                      v_ref, *refs, head_dim: int, selecting: bool = False,
-                      **shape):
-    """The mixed kernel over a float pool: k_ref/v_ref [1, page, KV*hd],
-    one physical page, a kv head a lane slice. selecting: one more
-    input follows them, the selection's page (_mixed_fold's sel_ref)."""
-    hd = head_dim
-    sel_ref = None
+def _mixed_kernel(layer_ref, last_ref, pos_ref, qlen_ref, table_ref, *refs,
+                  quantized: bool, selecting: bool, packed4: bool,
+                  depth: int, block: int, page_size: int, kv_heads: int,
+                  head_dim: int, window: Optional[int] = None, **fold_shape):
+    """One grid step: one ROW of the mixed fold, its live pages of the
+    layer walked by `walk_live_pages`, K and V (and, selecting, the
+    selection's pages: indexed by row and LOGICAL page) together.
+
+    last_ref:      [B] the position of each row's last real query, -1
+                   for an idle row: the walk's bound (pos_ref: its
+                   first query's, where a band starts)
+    sk_ref/sv_ref  (a quantized pool only): the layer's flat scales
+    q_ref/o_ref:   [1, C, H, hd], the row's window and result
+    k_hbm/v_hbm:   [L, N_pages, page, KV*hd], never read but by a copy
+                   (sel_hbm: [B, max_pages, C, page] float32)
+    kbuf/vbuf:     [depth, block * page, KV*hd] VMEM (selbuf: [depth,
+                   block, C, page]); sem: DMA [pools, depth, block]
+    cur:           SMEM int32 [4], the walk's
+    acc_ref:       [KV*C*G, hd] f32
+    """
+    if quantized:
+        sk_ref, sv_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, *refs = refs
+    sel_hbm = selbuf = None
     if selecting:
-        sel_ref, *refs = refs
-    o_ref, acc_ref, m_ref, l_ref = refs
-
-    def page_kv(kv, pid):
-        lanes = slice(kv * hd, (kv + 1) * hd)
-        return k_ref[0, :, lanes], v_ref[0, :, lanes], None, None
-
-    _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, page_kv, head_dim=hd, sel_ref=sel_ref, **shape)
-
-
-def _rpa_mixed_kernel_q(layer_ref, pos_ref, qlen_ref, table_ref, sk_ref,
-                        sv_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                        l_ref, *, packed4: bool, kv_heads: int,
-                        head_dim: int, **shape):
-    """The mixed kernel over a quantized pool: pages stream as int8 (a
-    quarter of the f32 page bytes) or nibble-PACKED int4 (an eighth;
-    the block holds page_size//2 sublanes and unpacks in registers per
-    kv head), and the per-(page, kv-head) scales prefetch into SMEM —
-    the decode q8/q4 kernels' scheme with the mixed kernel's per-row
-    query width."""
+        sel_hbm, o_ref, kbuf, vbuf, selbuf, sem, cur, acc_ref = refs
+    else:
+        o_ref, kbuf, vbuf, sem, cur, acc_ref = refs
     hd = head_dim
+    Pb = kbuf.shape[1] // block     # a page's rows as stored
 
-    def page_kv(kv, pid):
+    def copies(layer, pid, slot, row, p, f):
+        at = pl.ds(f * Pb, Pb)
+        cs = [pltpu.make_async_copy(pool.at[layer, pid], buf.at[slot, at],
+                                    sem.at[i, slot, f])
+              for i, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                               (v_hbm, vbuf)))]
+        if selecting:
+            cs.append(pltpu.make_async_copy(
+                sel_hbm.at[row, p], selbuf.at[slot, f], sem.at[2, slot, f]))
+        return cs
+
+    if block > 1:
+        # a page that is not fetched keeps what lay in its place, and a
+        # probability of exactly 0 must meet a finite value there
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            vbuf[...] = jnp.zeros_like(vbuf)
+
+    _, pages = walk_live_pages(layer_ref, last_ref, table_ref, cur, copies,
+                               depth=depth, page_size=page_size,
+                               window=window, first_ref=pos_ref, block=block)
+
+    def page_kv(kv, slot, found):
         lanes = slice(kv * hd, (kv + 1) * hd)
+        if not quantized:
+            return kbuf[slot, :, lanes], vbuf[slot, :, lanes], None, None
         if packed4:
-            kh = _unpack_nibbles(k_ref[0], lanes)
-            vh = _unpack_nibbles(v_ref[0], lanes)
+            def unpacked(buf):
+                return jnp.concatenate(
+                    [_unpack_nibbles(buf[slot, f * Pb:(f + 1) * Pb], lanes)
+                     for f in range(block)], axis=0)
+            kh, vh = unpacked(kbuf), unpacked(vbuf)
         else:
-            kh = k_ref[0, :, lanes].astype(jnp.float32)
-            vh = v_ref[0, :, lanes].astype(jnp.float32)
-        at = pid * kv_heads + kv
-        return kh, vh, sk_ref[at], sv_ref[at]
+            kh = kbuf[slot, :, lanes].astype(jnp.float32)
+            vh = vbuf[slot, :, lanes].astype(jnp.float32)
+        def scales(ref):
+            if block == 1:
+                return [ref[found[0][0] * kv_heads + kv]]
+            # (a page that is not fetched has no scale: its place in the
+            # slot is finite, and 0 keeps it so)
+            return [jnp.where(fetched,
+                              ref[jnp.maximum(pid, 0) * kv_heads + kv], 0.0)
+                    for pid, fetched in found]
+        return kh, vh, scales(sk_ref), scales(sv_ref)
 
-    _mixed_fold(pos_ref, qlen_ref, table_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, page_kv, kv_heads=kv_heads, head_dim=hd, **shape)
+    _mixed_fold(pos_ref, qlen_ref, q_ref, o_ref, acc_ref, pages, page_kv,
+                page_size=page_size, block=block, kv_heads=kv_heads,
+                head_dim=hd, quantized=quantized, window=window,
+                sel_page=((lambda slot, f: selbuf[slot, f]) if selecting
+                          else None),
+                **fold_shape)
 
 
 def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
@@ -711,10 +778,12 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
     """MIXED ragged attention over a paged KV pool, one Pallas kernel.
 
     The per-row query-length extension of `ragged_paged_attention`: one
-    grid handles decode rows (q_len=1) and prefill-chunk rows (q_len=C
-    at arbitrary page offset) in the same launch, with per-row causal
-    masking and the same per-row early exit (a row streams only the
-    pages up to ceil((pos + q_len) / page)).
+    call handles decode rows (q_len=1) and prefill-chunk rows (q_len=C
+    at arbitrary page offset), a grid step a ROW, which walks its live
+    pages itself (walk_live_pages: the pages up to
+    ceil((pos + q_len) / page), `mixed_block` of them a softmax update,
+    out of the pool in HBM into rings of VMEM slots; an idle row takes
+    no trip), with per-row causal masking.
 
     q:            [B, C, H, hd] — rope applied; every real query
                   token's KV must already be written to its page (the
@@ -734,19 +803,19 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
     q_len:        [B] int32 — real query tokens per row (0 = idle row,
                   output zeros)
     window:       static. Query i of a row attends keys pos + i -
-                  window + 1 .. pos + i, and the row's grid steps are
-                  the pages from its first query's first key on;
-                  `table` may then be a ring (_mixed_fold). None: every
-                  key up to the query.
+                  window + 1 .. pos + i, and the row's walk starts at
+                  the page of its first query's first key; `table` may
+                  then be a ring (walk_live_pages). None: every key up
+                  to the query.
     selected:     [B, max_pages, C, page] float32, or None. Query
                   (b, i) attends key j * page + o only where
                   selected[b, j, i, o] is above 0.5, among the keys
                   causality leaves it (a sparse indexer's sets), BY
-                  PAGE: the [C, page] block a grid step needs is one
-                  contiguous copy beside its K and V pages (query-major,
-                  it was C rows of 512 B a step). A float pool without a
-                  band only. None: no such operand, and the program is
-                  the one it was.
+                  PAGE: the [C, page] block of a page is one contiguous
+                  copy beside its K and V pages (query-major, it was C
+                  rows of 512 B a page). A float pool without a band
+                  only. None: no such operand, and the program is the
+                  one it was.
     Returns [B, C, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention_mixed` (the fold reference)
     to f32 tolerance — tests/test_ragged_paged_attn.py pins the parity.
@@ -776,81 +845,74 @@ def ragged_paged_attention_mixed(q, pool_k, pool_v, layer, table, pos,
             "(ragged_paged_mixed_supported); use the fold or a "
             "narrower window")
 
-    if selected is not None and (quantized or window is not None):
+    selecting = selected is not None
+    if selecting and (quantized or window is not None):
         raise ValueError("a selection is served over a float pool "
                          "without a band only")
 
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def sel_index(b, j, layer_ref, pos_ref, qlen_ref, table_ref):
-        # (dead pages clamp to the last live one, as kv_index's)
-        last = pos_ref[b] + jnp.maximum(qlen_ref[b], 1) - 1
-        return (b, jnp.minimum(j, last // P), 0, 0)
-
-    def kv_index(b, j, layer_ref, pos_ref, qlen_ref, table_ref, *_scales):
-        # clamp dead pages (past the row's live count) to the LAST live
-        # page — the repeated block index elides the DMA, so a row
-        # streams only the pages its window actually covers
-        last = pos_ref[b] + jnp.maximum(qlen_ref[b], 1) - 1
-        if window is None:
-            jj = jnp.minimum(j, last // P)
-        else:
-            jj = jnp.minimum(
-                _mixed_first_page(pos_ref[b], P, window) + j,
-                last // P) % max_pages
-        page = table_ref[b, jj]
-        return (layer_ref[0], jnp.maximum(page, 0), 0, 0)
-
+    pos = jnp.asarray(pos, jnp.int32)
+    q_len = jnp.asarray(q_len, jnp.int32)
+    # the walk's bound: the row's LAST real query; an idle row has none
+    last = jnp.where(q_len > 0, pos + q_len - 1, -1)
+    operands = [layer, last, pos, q_len, jnp.asarray(table, jnp.int32)]
     if quantized:
-        kernel = functools.partial(
-            _rpa_mixed_kernel_q, packed4=packed4, scale=scale, page_size=P,
-            kv_heads=KV, group=G, head_dim=hd, q_width=C, window=window)
-        n_prefetch = 6
-        operands = (layer, jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(q_len, jnp.int32),
-                    jnp.asarray(table, jnp.int32),
-                    _layer_scales(scale_k, layer[0]),
-                    _layer_scales(scale_v, layer[0]),
-                    q, pool_k, pool_v)
-    else:
-        kernel = functools.partial(
-            _rpa_mixed_kernel, scale=scale, page_size=P, kv_heads=KV,
-            group=G, head_dim=hd, q_width=C, window=window)
-        n_prefetch = 4
-        operands = (layer, jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(q_len, jnp.int32),
-                    jnp.asarray(table, jnp.int32), q, pool_k, pool_v)
-    selecting = []
-    if selected is not None:
-        kernel = functools.partial(kernel, selecting=True)
-        operands += (selected.astype(jnp.float32),)
-        selecting = [pl.BlockSpec((1, 1, C, P), sel_index)]
+        operands += [_layer_scales(scale_k, layer[0]),
+                     _layer_scales(scale_v, layer[0])]
+    block = mixed_block(P, H, KV, hd, C, max_pages, q.dtype.itemsize,
+                        pool_k.dtype.itemsize, selecting=selecting)
+    depth = decode_ring_depth(block * Pb * width * pool_k.dtype.itemsize)
+    kernel = functools.partial(
+        _mixed_kernel, quantized=quantized, selecting=selecting,
+        packed4=packed4, depth=depth, block=block, scale=scale, page_size=P,
+        kv_heads=KV, group=G, head_dim=hd, q_width=C, window=window)
+    row = pl.BlockSpec((1, C, H, hd), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    ring = pltpu.VMEM((depth, block * Pb, width), pool_k.dtype)
+    inputs, in_specs = [q, pool_k, pool_v], [row, hbm, hbm]
+    rings = [ring, ring]
+    if selecting:
+        inputs.append(selected.astype(jnp.float32))
+        in_specs.append(hbm)
+        rings.append(pltpu.VMEM((depth, block, C, P), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, C, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
-            _pool_block(Pb, width, kv_index),
-            _pool_block(Pb, width, kv_index),
-        ] + selecting,
-        out_specs=pl.BlockSpec((1, C, H, hd),
-                               lambda b, j, *_: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KV * C * G, hd), jnp.float32),
-            pltpu.VMEM((KV * C * G, 128), jnp.float32),
-            pltpu.VMEM((KV * C * G, 128), jnp.float32),
-        ],
+        num_scalar_prefetch=len(operands),
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=row,
+        scratch_shapes=rings + [
+            pltpu.SemaphoreType.DMA((len(rings), depth, block)),
+            pltpu.SMEM((4,), jnp.int32),
+            pltpu.VMEM((KV * C * G, hd), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
         name="cake_mixed_attn",
+        # the ring's copies run ahead into the next row
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_MIXED_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(*operands)
+    )(*operands, *inputs)
+
+
+def mixed_entries_walk(first_pos: int, n: int, width: int, tile: int,
+                       page_size: int, max_pages: int, block: int):
+    """(pages, table entries, folds) of ONE call that hands a window of
+    `width` slots, n of them real tokens from first_pos on, to the mixed
+    kernel as width / tile entries of `tile` queries over the one row's
+    table (exaone_moe.attend_window's form, keye_vl2's): mixed_walk
+    summed over the entries; an entry past the window's last token is
+    idle."""
+    pages = folds = 0
+    for start in range(0, width, tile):
+        p, f = mixed_walk(first_pos + start, min(max(n - start, 0), tile),
+                          page_size, max_pages, block)
+        pages, folds = pages + p, folds + f
+    return pages, (width // tile) * max_pages, folds
 
 
 def _on_tpu() -> bool:
@@ -862,8 +924,9 @@ def _on_tpu() -> bool:
 # 0.0.34, PR 21) said "Scoped allocation with size 16.04M and limit
 # 16.00M exceeded scoped vmem limit" for the mixed kernel at H=32,
 # hd=128, C=256, and "Used 2.01M of 1.00M smem" for two f32[2048, 8]
-# scale operands. The kernels set no vmem_limit_bytes, so the default
-# scoped limit is the ceiling.
+# scale operands. The decode kernel sets no vmem_limit_bytes, so the
+# default scoped limit is its ceiling; the mixed kernel states its own
+# (_MIXED_VMEM_LIMIT) and keeps this one for an entry's WIDTH.
 _VMEM_SCOPED_LIMIT = 16 * 2**20
 _SMEM_BYTES = 2**20
 
@@ -966,34 +1029,84 @@ def ragged_paged_supported(page_size: int, H: int, KV: int, hd: int,
     hd 64 from 2 KV heads up); a test's hd 16 x 2, MQA at hd 64 and
     2 KV heads of 96 decode through the fold on a chip, where the
     (rows, pages) BlockSpec pipeline this kernel replaced compiled
-    them. The mixed kernel still takes them."""
+    them. The mixed kernel's copies are the same since PR 62, and so
+    is its rule (ragged_paged_mixed_supported)."""
     return (_pool_supported(page_size, H, KV, hd, **pool)
             and (not _on_tpu() or (KV * hd) % 128 == 0))
 
 
 def mixed_scratch_bytes(H: int, hd: int, q_width: int) -> int:
-    """f32 VMEM scratch the mixed kernel allocates per grid cell: the
-    [KV*C*G, hd] accumulator plus two [KV*C*G, 128] m/l buffers, and
-    KV*G == H. A head narrower than a lane tile pads the accumulator's
-    rows to 128 lanes (the compiler at H=32, hd=64, C=256, PR 56:
-    "Scoped allocation with size 16.88M", 2.4 MiB over the unpadded
-    count)."""
+    """f32 VMEM the mixed kernel keeps a row: the [KV*C*G, hd]
+    accumulator plus the softmax's m and l, [KV*C*G, 1] each, which as
+    carries of the walk's loop take a lane tile a row like the
+    [KV*C*G, 128] scratch they were, and KV*G == H. A head narrower
+    than a lane tile pads the accumulator's rows to 128 lanes (the
+    compiler at H=32, hd=64, C=256, PR 56: "Scoped allocation with size
+    16.88M", 2.4 MiB over the unpadded count)."""
     return 4 * q_width * H * (max(hd, 128) + 256)
+
+
+# What the mixed call asks of the core's 128 MiB of VMEM (the compiler
+# grants a kernel 16 unless told), and what of it mixed_block plans
+# with: the rest is the compiler's own temporaries. An ENTRY's width is
+# still chosen against the 16 MiB count at one page a fold
+# (ragged_paged_mixed_supported, exaone_moe.query_tile): wider entries
+# are a change of their own (ROADMAP.md S5 (c)).
+_MIXED_VMEM_LIMIT = 48 * 2**20
+_MIXED_VMEM_PLAN = 32 * 2**20
 
 
 def mixed_vmem_bytes(page_size: int, H: int, KV: int, hd: int,
                      q_width: int, q_itemsize: int = 2,
-                     kv_itemsize: int = 2) -> int:
-    """Scoped VMEM one mixed grid cell needs: the f32 scratch plus the
-    double-buffered q and out blocks ([C, H, hd]) and k/v page blocks.
-    Checked against the compiler at 20 shapes (H 8-64, KV 1-32, C
-    5-512, pages 16-256): every shape it refused needs more than
-    _VMEM_SCOPED_LIMIT by this count, and none it accepted was more
-    than 0.7 MiB over."""
+                     kv_itemsize: int = 2, block: int = 1,
+                     selecting: bool = False) -> int:
+    """Scoped VMEM the mixed kernel needs at `block` pages a fold: the
+    f32 scratch, the double-buffered q and out blocks ([C, H, hd]), the
+    K and the V ring (decode_ring_depth slots of `block` pages; the
+    selection's ring of [C, page] float32 beside them), and a kv
+    head's scores and probabilities ([C*G, block * page] float32 both,
+    and the probabilities again in the pool's type)."""
     q_block = q_width * H * hd * q_itemsize
-    kv_block = page_size * KV * hd * kv_itemsize
-    return (mixed_scratch_bytes(H, hd, q_width)
-            + 2 * 2 * q_block + 2 * 2 * kv_block)
+    page_bytes = page_size * KV * hd * kv_itemsize
+    slots = decode_ring_depth(block * page_bytes) * block
+    ring = slots * (2 * page_bytes
+                    + (q_width * page_size * 4 if selecting else 0))
+    scores = q_width * (H // KV) * block * page_size * (8 + kv_itemsize)
+    return (mixed_scratch_bytes(H, hd, q_width) + 2 * 2 * q_block + ring
+            + scores)
+
+
+def mixed_block(page_size: int, H: int, KV: int, hd: int, q_width: int,
+                max_pages: int, q_itemsize: int = 2, kv_itemsize: int = 2,
+                selecting: bool = False) -> int:
+    """Pages a fold of the mixed kernel, from the call's shapes alone:
+    the most of (4, 2, 1) whose count (mixed_vmem_bytes) fits
+    _MIXED_VMEM_PLAN and such that whole blocks pad the TABLE by an
+    eighth at most (a walk's last block is computed whole and masked:
+    a ring of 3 entries would pay 4 pages a row at 4 a fold), as
+    ops/mla_attention.window_tiles' B."""
+    def fits(f):
+        return (8 * (-max_pages % f) <= max_pages
+                and mixed_vmem_bytes(page_size, H, KV, hd, q_width,
+                                     q_itemsize, kv_itemsize, f,
+                                     selecting) <= _MIXED_VMEM_PLAN)
+    return next(f for f in (4, 2, 1) if f == 1 or fits(f))
+
+
+def mixed_walk(pos: int, q_len: int, page_size: int, max_pages: int,
+               block: int, window: Optional[int] = None):
+    """(pages, folds) the mixed kernel walks for a row whose first
+    query sits at pos and that holds q_len real ones: its live pages,
+    from the band's first under a window, and the softmax updates they
+    take at `block` pages each; none for an idle row. The host's count
+    of what the kernel does (the step records' mixed_attn_pages /
+    mixed_attn_folds)."""
+    if q_len <= 0:
+        return 0, 0
+    first = 0 if window is None else max(pos - (window - 1), 0) // page_size
+    pages = min(max((pos + q_len - 1) // page_size - first + 1, 0),
+                max_pages)
+    return pages, -(-pages // block)
 
 
 def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
@@ -1006,14 +1119,17 @@ def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
                                  q_itemsize: int = 2,
                                  kv_itemsize: int = 2) -> bool:
     """Gate for the MIXED hardware kernel: the pool's rules
-    (_pool_supported) PLUS a power-of-two GQA group and the VMEM bound. The kernel folds each
-    kv head's [C, G, hd] queries to [C*G, hd]; Mosaic does that shape
-    cast for G in 1, 2, 4, 8 and refuses it for G=7 ("unsupported shape
-    cast", v5e, PR 21). And unlike the C=1 decode kernel, its scratch
-    and q/out blocks scale with the query width C: the compiler
-    refuses the kernel outright past its scoped limit (at H=32,
-    hd=128: C=128 needs 11 MiB and compiles, C=256 needs 21 MiB and
-    does not)."""
+    (_pool_supported) PLUS a page row of whole lane tiles (the kernel's
+    own copies, as ragged_paged_supported's), a power-of-two GQA group
+    and the VMEM bound. The kernel folds each kv head's [C, G, hd]
+    queries to [C*G, hd]; Mosaic does that shape cast for G in 1, 2, 4,
+    8 and refuses it for G=7 ("unsupported shape cast", v5e, PR 21).
+    And unlike the C=1 decode kernel, its scratch and q/out blocks
+    scale with the query width C, which is held to what the count at
+    one page a fold (mixed_vmem_bytes) puts under the 16 MiB a kernel
+    is granted unasked (at H=32, hd=128: C=128 needs 12 MiB, C=256
+    needs 22): the widths the cells were sized at, though the kernel
+    states a higher limit now (wider entries: ROADMAP.md S5 (c))."""
     if not _pool_supported(page_size, H, KV, hd,
                            quantized=quantized, n_pages=n_pages,
                            packed4=packed4, slots=slots,
@@ -1023,7 +1139,7 @@ def ragged_paged_mixed_supported(page_size: int, H: int, KV: int,
     if not _on_tpu():
         return True      # interpret mode allocates host memory
     G = H // KV
-    if G & (G - 1):
+    if G & (G - 1) or (KV * hd) % 128:
         return False
     return mixed_vmem_bytes(page_size, H, KV, hd, q_width, q_itemsize,
                             kv_itemsize) <= _VMEM_SCOPED_LIMIT

@@ -2631,6 +2631,7 @@ class InferenceEngine:
         self._window_walk = (
             family.window_walk(self.config, self.cache, self._mixed_chunk)
             if family.window_walk is not None else None)
+        self._mixed_attn_walk = self._mixed_walk_of(family)
         if family.beside is not None:
             what, gauge = family.beside
             beside = self.cache.beside_bytes()
@@ -4599,6 +4600,61 @@ class InferenceEngine:
             pages, folds = pages + walked[0], folds + walked[1]
         return {"window_pages": pages, "window_folds": folds}
 
+    def _mixed_walk_of(self, family):
+        """A dispatch's rows -> (pages, table entries, folds) of its
+        layer's cake_mixed_attn call, as the host can know them: the
+        family's own form of the call (Family.mixed_attn_walk: the
+        dispatch's one window in entries), or every slot as it is where
+        the rows go through the kernel so (kernel_rows), at the pages a
+        fold the kernel takes for these shapes. None where neither
+        holds."""
+        if family.mixed_attn_walk is not None:
+            window = family.mixed_attn_walk(self.config, self.cache,
+                                            self._mixed_chunk)
+
+            def walk(pos, n):
+                # the dispatch's one window: the row with the most
+                # tokens, if it holds more than one
+                row = int(np.argmax(n))
+                return window(int(pos[row]), int(n[row]) if n[row] > 1 else 0)
+
+            return walk
+        if "mixed" not in family.kernel_rows:
+            return None
+        from cake_tpu.ops import ragged_paged_attention as rpa
+        c, P = self.config, self.cache.page_size
+        max_pages = self.cache.max_pages
+        block = rpa.mixed_block(
+            P, c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+            self._mixed_chunk, max_pages,
+            jnp.dtype(self.params["embed"].dtype).itemsize,
+            1 if self.kv_quant else jnp.dtype(self._pool_dtype).itemsize)
+
+        def walk(pos, n):
+            walked = [rpa.mixed_walk(int(p), int(q), P, max_pages, block)
+                      for p, q in zip(pos, n)]
+            return (sum(w[0] for w in walked), len(n) * max_pages,
+                    sum(w[1] for w in walked))
+
+        return walk
+
+    def _mixed_attn_pages(self, pos, qlen, groups) -> dict:
+        """A mixed record's mixed_attn_pages / mixed_attn_pages_table /
+        mixed_attn_folds (obs/steps): what the mixed attention kernel
+        walks a layer over the step's dispatches, counted from the
+        positions dispatched as the kernel counts its trips
+        (rpa.mixed_walk). Nothing where the family gives the host no
+        way to know its call."""
+        if self._mixed_attn_walk is None:
+            return {}
+        pages = table = folds = 0
+        for rows in groups:
+            walked = self._mixed_attn_walk(pos, np.where(rows, qlen, 0))
+            pages, table, folds = (pages + walked[0], table + walked[1],
+                                   folds + walked[2])
+        return {"mixed_attn_pages": pages, "mixed_attn_pages_table": table,
+                "mixed_attn_folds": folds}
+
     def _attn_pages(self, steps: List[tuple]) -> dict:
         """A decode record's attn_pages / attn_pages_table (obs/steps):
         the KV pages the decode attention kernel streams a layer for
@@ -4761,6 +4817,9 @@ class InferenceEngine:
                 tokens, pos, qlen, flags = (step[:, :C], step[:, C],
                                             step[:, C + 1], step[:, C + 3])
                 step[:, C + 2] = self._steps
+                # where the kernels find each row: the host's count of
+                # their walk goes by it
+                at = np.zeros(B, np.int64)
                 decode_rows: List[int] = []
                 for rid, slot in plan:
                     req = self._slot_req[slot]
@@ -4774,6 +4833,10 @@ class InferenceEngine:
                     qlen[slot] = 1
                     flags[slot] = ROW_ACTIVE | ROW_SAMPLE | (
                         ROW_FROM_CARRY if ahead else 0)
+                    # (the program reads a row's position off the carry:
+                    # the mirror plus what the steps in flight ship)
+                    at[slot] = min(self._pos[slot] + ahead,
+                                   self.max_seq_len - 1)
                     decode_rows.append(slot)
                 chunk_rows: List[int] = []
                 finished: List[tuple] = []
@@ -4786,7 +4849,7 @@ class InferenceEngine:
                     ids, off = p["ids"], p["off"]
                     n = min(C, len(ids) - off)
                     tokens[slot, :n] = ids[off:off + n]
-                    pos[slot] = off
+                    pos[slot] = at[slot] = off
                     qlen[slot] = n
                     flags[slot] = ROW_ACTIVE
                     chunk_rows.append(slot)
@@ -4799,7 +4862,8 @@ class InferenceEngine:
                                   for slot in chunk_rows]
                 tiles = {**self._attn_q_tiles(qlen,
                                               decode_rows + chunk_rows),
-                         **self._window_pages(pos, qlen, groups)}
+                         **self._window_pages(pos, qlen, groups),
+                         **self._mixed_attn_pages(at, qlen, groups)}
             with span("dispatch"):
                 # every layer runs over the step's tokens packed out of
                 # their windows (paged.mixed_step_paged), at the smallest

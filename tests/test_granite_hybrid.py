@@ -507,7 +507,8 @@ def test_prefix_registration_is_refused_by_name():
 # taken on the commit BEFORE this family called nemotron_h's blocks and
 # threaded `scale=` through models/llama/paged.py (01e49cf). A change
 # that means to move Nemotron's programs re-pins them; one that does
-# not, and fails here, has moved them.
+# not, and fails here, has moved them. PR 62 re-pinned ("mixed",
+# "pallas"): `cake_mixed_attn` walks its rows' pages itself.
 NEMOTRON_LOWERED = {
     ("decode", "fold"):
         "7dd6972f8076d6c9d50f9f4b4829cecfcdee79f89e4fe46be582dc862f811a7b",
@@ -516,7 +517,7 @@ NEMOTRON_LOWERED = {
     ("decode", "pallas"):
         "bb880d48c71d26bba75e01d83f4e73d3476e74f3010325969e128054f6c14181",
     ("mixed", "pallas"):
-        "6b9d363208bb33ebc35da24dc720cb48ff293143d853afc824a80b55d1017250",
+        "063e8e1b8f69688b1b96afb835bc7e34d619349ae747eea80bc8515c79ed8588",
 }
 
 

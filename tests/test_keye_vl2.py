@@ -528,6 +528,41 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     assert eng.cache.idx.shape == (3, 64, 8, 8)
 
 
+def test_mixed_records_count_the_windows_walk_of_its_pages(engine_run):
+    """mixed_attn_pages / _table / _folds on Keye's mixed records: a
+    dispatch's one window as `attend_window` hands it over (entries of
+    `query_tile` queries over the row's table), each entry walking from
+    page 0 to its last real query's, an entry past the window's tokens
+    and a dispatch of single tokens none; no decode record has them."""
+    from cake_tpu.ops import ragged_paged_attention as rpa
+    c, _, _, _, records, eng = engine_run
+    pages = eng.cache.table.shape[1]
+    walk = kv2.mixed_attn_walk(c, eng.cache, 16)
+    tile = kv2.query_tile(16, c.num_attention_heads, c.num_key_value_heads,
+                          c.head_dim, 8, 4, 4)
+    block = rpa.mixed_block(8, c.num_attention_heads, c.num_key_value_heads,
+                            c.head_dim, tile, pages, 4, 4, selecting=True)
+    # a full window at position 32 of pages of 8: every entry to its end
+    ends = [(32 + start + tile - 1) // 8 + 1 for start in range(0, 16, tile)]
+    assert walk(32, 16) == (sum(ends), 16 // tile * pages,
+                            sum(-(-e // block) for e in ends))
+    # five tokens: the entries past them are idle; none: no walk at all
+    assert walk(32, 5)[0] == sum(
+        (32 + start + min(5 - start, tile) - 1) // 8 + 1
+        for start in range(0, 16, tile) if start < 5)
+    assert walk(32, 0) == (0, 16 // tile * pages, 0)
+    mixed = [r for r in records if r["kind"] == "mixed"]
+    assert mixed and all("mixed_attn_pages" in r for r in mixed)
+    for r in mixed:
+        assert 0 <= r["mixed_attn_pages"] < r["mixed_attn_pages_table"]
+        assert r["mixed_attn_pages_table"] % (16 // tile * pages) == 0
+        assert (r["mixed_attn_pages"] / block <= r["mixed_attn_folds"]
+                <= r["mixed_attn_pages"])
+    assert any(r["mixed_attn_pages"] for r in mixed)
+    assert not [r for r in records
+                if r["kind"] == "decode" and "mixed_attn_pages" in r]
+
+
 @pytest.mark.parametrize("refused,named", [
     (dict(kv_pages=None), "--kv-pages"),
     (dict(step_fns=(print, print)), "topology"),
@@ -562,7 +597,11 @@ def test_prefix_registration_is_refused_by_name():
 # call `cake_dsa_select` where `select_mask` stood, and all eight return
 # a counter vector two keys longer (`dsa_select_keys_walked` /
 # `_table`: two constant zeros in a decode program, nothing else of it
-# moves); the other twelve are as they were.
+# moves); the other twelve are as they were. PR 62 re-pinned the three
+# mixed programs that call `cake_mixed_attn` (olmoe, mistral, exaone_moe:
+# the kernel walks its rows' pages itself, grid (rows,)); every decode
+# program, `walk_live_pages`' two earlier callers among them, and the
+# latent families' mixed programs lower as they did.
 LOWERED_BEFORE = {
     ("glm_moe_dsa", "decode", "fold"):
         "dce0f7aaa8b8a49daf146fd844459fbdbea9f14bd69e345a6c42b76e9cffcd31",
@@ -587,7 +626,7 @@ LOWERED_BEFORE = {
     ("exaone_moe", "mixed", "fold"):
         "ebaf614c91933bff09b2d03d5fad275dfa5ba8d441d5acf3154dcb5089727240",
     ("exaone_moe", "mixed", "pallas"):
-        "d805b9072529397cea01cdd9a6432ef6065254f6c83d487ef7170b5dd5206a32",
+        "23445f0f9880a42aa1e1b6e23df37c9124411e939a39bb5c2fccde35c3207ee0",
     ("olmoe", "decode", "fold"):
         "d167d15f7f4435124d3835fcf63e6099a80260db1c4993b5da23a9d645121784",
     ("olmoe", "decode", "pallas"):
@@ -595,7 +634,7 @@ LOWERED_BEFORE = {
     ("olmoe", "mixed", "fold"):
         "2ad674eac7dfcea6d6a4b13806c22d5495e2cc8eab0a28bf80822afc048c3b6c",
     ("olmoe", "mixed", "pallas"):
-        "bbab21ca2e3b9d0ad9aaf464bbc25d0c2556d8274ef9eb8606f4401bec3854f1",
+        "850ebea96af2939cad887a87e2d9edfa20caa61e2dffcc27f041bfb7b8c278ef",
     ("mistral", "decode", "fold"):
         "fbcbef1dea0981cad5b372f15ecbbaf5ebf3f46995bbf4c42b1d2ec5038b1fae",
     ("mistral", "decode", "pallas"):
@@ -603,7 +642,7 @@ LOWERED_BEFORE = {
     ("mistral", "mixed", "fold"):
         "ac61136edb0263ecde66c5f05812c1b952e1363297605f4dd1c428df3908d6ee",
     ("mistral", "mixed", "pallas"):
-        "ed777ce272fb34407fc35046785e659096776e5a3ccc88f80ab3d5eb211ad4b7",
+        "6d3f047daa027259c89add8a3519624363f056cc94a2e7927815c237052c2638",
 }
 
 

@@ -280,6 +280,64 @@ def test_mixed_records_count_the_query_tiles_attention_folds(sixteen_slots):
         assert _metric(name) - before[name] == sum(r[key] for r in mixed)
 
 
+WALK_SERIES = ("cake_mixed_attn_pages_total",
+               "cake_mixed_attn_pages_table_total",
+               "cake_mixed_attn_folds_total")
+
+
+def test_mixed_records_count_the_pages_attention_walks(sixteen_slots):
+    """mixed_attn_pages / mixed_attn_pages_table / mixed_attn_folds: the
+    host's count of what the mixed attention kernel walks a layer for
+    a step's rows (from the page of a row's first key to that of its
+    last real query, none for a row that is not in the step), the
+    entries of the call's page table, and the softmax updates at
+    `mixed_block` pages each; on every mixed record, on no decode
+    record, and summed in three /metrics series."""
+    import numpy as np
+    from cake_tpu.ops.ragged_paged_attention import mixed_block, mixed_walk
+
+    eng, _ = sixteen_slots
+    c, pages = eng.config, eng.cache.max_pages
+    F = mixed_block(PAGE, c.num_attention_heads, c.num_key_value_heads,
+                    c.head_dim, 16, pages, 4, 4)
+    # a hand-built step of one dispatch: two decode rows and a window of
+    # 16 that ends on a page's last slot, thirteen rows not in the step
+    pos = np.zeros(16, np.int64)
+    qlen = np.zeros(16, np.int64)
+    pos[:3], qlen[:3] = [0, 2 * PAGE, PAGE], [1, 1, 16]
+    want = [mixed_walk(p, n, PAGE, pages, F) for p, n in zip(pos, qlen)]
+    assert [w[0] for w in want[:4]] == [1, 3, 2, 0]
+    assert eng._mixed_attn_pages(pos, qlen, [qlen > 0]) == {
+        "mixed_attn_pages": 6, "mixed_attn_pages_table": 16 * pages,
+        "mixed_attn_folds": sum(w[1] for w in want)}
+    # two dispatches: each steps through the whole table
+    assert eng._mixed_attn_pages(pos, qlen, [qlen == 1, qlen > 1])[
+        "mixed_attn_pages_table"] == 2 * 16 * pages
+    seen = {r["step"] for r in eng.flight.dump()}
+    before = {name: _metric(name) for name in WALK_SERIES}
+    a = eng.submit([5] * 9, max_new_tokens=24, temperature=0.0,
+                   repeat_penalty=1.0)
+    _wait_tokens(a, 3)
+    b = eng.submit([7] * 40, max_new_tokens=4, temperature=0.0,
+                   repeat_penalty=1.0)
+    assert b.wait(timeout=300) and a.wait(timeout=300)
+    new = [r for r in eng.flight.dump() if r["step"] not in seen]
+    mixed = [r for r in new if r["kind"] == "mixed"]
+    assert any(r["rows_decode"] and r["rows_prefill"] for r in mixed)
+    for r in mixed:
+        rows = r["rows_decode"] + r["rows_prefill"]
+        assert rows <= r["mixed_attn_pages"] <= r["mixed_attn_pages_table"]
+        assert r["mixed_attn_pages_table"] == 16 * pages, r
+        assert (r["mixed_attn_pages"] / F <= r["mixed_attn_folds"]
+                <= r["mixed_attn_pages"]), r
+    decode = [r for r in new if r["kind"] == "decode"]
+    assert decode and not [r for r in decode if "mixed_attn_pages" in r]
+    for name, key in zip(WALK_SERIES, ("mixed_attn_pages",
+                                       "mixed_attn_pages_table",
+                                       "mixed_attn_folds")):
+        assert _metric(name) - before[name] == sum(r[key] for r in mixed)
+
+
 PAGE_SERIES = ("cake_decode_attn_pages_total",
                "cake_decode_attn_pages_table_total")
 

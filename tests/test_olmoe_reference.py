@@ -540,8 +540,14 @@ def test_paged_engine_serves_olmoe_and_records_the_expert_counters(params):
                and r["moe_experts_touched"] >= L for r in with_counters)
 
 
-def test_cli_refuses_a_directory_of_another_family(tmp_path, capsys):
+def test_cli_refuses_a_directory_of_another_family(tmp_path, capsys,
+                                                   monkeypatch):
     from cake_tpu import cli
+
+    # (in this process cli.main must not turn JAX's persistent cache on
+    # for the tests that follow: tests/test_serving_topology.py)
+    monkeypatch.setattr("cake_tpu.utils.compile_cache.enable_compile_cache",
+                        lambda: "off")
 
     (tmp_path / "config.json").write_text(json.dumps(
         {"model_type": "llama", "vocab_size": 64, "hidden_size": 32,

@@ -7,6 +7,8 @@ engine running `paged_attn="pallas"` must emit token-identical streams
 to `"fold"`. Cases stay tiny — tier-1 runs near its wall budget.
 """
 
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -461,17 +463,19 @@ def test_supported_gate_on_chip_shape_classes(monkeypatch):
     (2, 16, False), (1, 64, False), (3, 64, False), (2, 96, False),
     (8, 16, True), (2, 64, True), (1, 128, True), (4, 96, True)])
 def test_decode_gate_wants_whole_lane_tiles(monkeypatch, KV, hd, compiles):
-    """The decode kernel's own copies slice a (page, KV*hd) tile out of
-    the pool in HBM, which Mosaic admits at a multiple of 128 lanes
-    only; the mixed kernel's BlockSpec takes the narrow rows still, and
-    off the chip (interpret mode) every shape passes."""
+    """The kernels' own copies slice a (page, KV*hd) tile out of the
+    pool in HBM, which Mosaic admits at a multiple of 128 lanes only:
+    the decode kernel's since PR 42, the mixed kernel's since it walks
+    its pages too (PR 62); off the chip (interpret mode) every shape
+    passes."""
     H = 2 * KV
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert ragged_paged_supported(16, H=H, KV=KV, hd=hd)
     assert ragged_paged_mixed_supported(16, H=H, KV=KV, hd=hd, q_width=8)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert ragged_paged_supported(16, H=H, KV=KV, hd=hd) == compiles
-    assert ragged_paged_mixed_supported(16, H=H, KV=KV, hd=hd, q_width=8)
+    assert ragged_paged_mixed_supported(16, H=H, KV=KV, hd=hd,
+                                        q_width=8) == compiles
 
 
 def test_mixed_supported_gate_bounds_scratch_vmem(monkeypatch):
@@ -927,12 +931,16 @@ def test_mixed_kernel_follows_q_len(kind, G):
 
 
 @pytest.mark.parametrize("windows", [0, 1, 2])
-def test_mixed_row_is_bit_equal_whatever_rows_share_its_call(windows):
+@pytest.mark.parametrize("block", [1, 2, 4])
+def test_mixed_row_is_bit_equal_whatever_rows_share_its_call(
+        block, windows, monkeypatch):
     """A decode row beside 0, 1 and 2 window rows, and a window row
-    alone or in company: rows do not see each other, and a query's
-    recurrence does not depend on how much of its row's window is
-    folded. The decode row's column 0 is the decode kernel's answer at
-    the same position."""
+    alone or in company, at 1, 2 and 4 pages a fold: rows do not see
+    each other, and a query's recurrence does not depend on how much of
+    its row's window is folded (both spans take the same block, and a
+    block's mask is the query's own). The decode row's column 0 is the
+    decode kernel's answer at the same position."""
+    monkeypatch.setattr(rpa, "mixed_block", lambda *a, **kw: block)
     rng = np.random.default_rng(50)
     pk, pv = _pool(rng, KV=2, hd=16)
     C = SWEEP_C
@@ -956,6 +964,204 @@ def test_mixed_row_is_bit_equal_whatever_rows_share_its_call(windows):
         # here and alone in its tile there
         np.testing.assert_array_equal(got[1, 0], run([0, 1, 0])[1, 0])
         np.testing.assert_array_equal(got[1], run([0, C, 0])[1])
+
+
+# -- the mixed kernel walks its rows' live pages --------------------------------
+#
+# One grid step a row; the row's pages from the page of its first
+# query's first key to that of its LAST real query come through the
+# decode kernel's walk (walk_live_pages), `mixed_block` of them side by
+# side in a ring slot and a softmax update. The table holds 5 pages, so
+# blocks of 2 and of 4 divide neither it nor most live counts.
+
+WALK_C = SWEEP_C
+_BAND = 2 * P - 3       # a band that is no multiple of the page
+# name: (table rows, first positions, q_len, window); (kinds it runs in)
+MIXED_WALK_CASES = {
+    # decode rows at ragged positions, a dead table between them
+    "decode_rows": ([[7, 2, 9, 5, -1], _DEAD, [4, 11, 3, 1, 8], [6, 10, -1, -1, -1]],
+                    [2 * P + 5, 0, FULL, P], [1, 1, 1, 1], None),
+    # idle rows first, between and last: they take no trip, and the
+    # copies ahead skip them
+    "idle_rows": ([[5, 8, 2, -1, -1], [7, 2, 9, -1, -1], [3, 6, -1, -1, -1],
+                   [4, 11, 1, -1, -1]],
+                  [P + 4, 2 * P + 1, 3, 2 * P], [0, WALK_C, 0, 0], None),
+    # windows that start before, on and after a page edge, whole and
+    # short, beside a decode row
+    "prefill_at_page_offsets": ([[3, 6, 1, 10, 5], [8, 2, 7, 4, 9],
+                                 [11, 4, 7, -1, -1], [1, 9, 3, 6, -1]],
+                                [P - 2, 4 * P, P + 1, 3 * P - WALK_C],
+                                [WALK_C, WALK_C, 3, WALK_C - 1], None),
+    # holes inside the live range, page 0 of the pool poisoned
+    "hole_in_the_live_range": ([[4, -1, 11, 3, -1], [-1, 2, -1, 7, 5],
+                                [-1, -1, 5, -1, -1]],
+                               [3 * P + 2, 4 * P - 3, 2 * P + 1],
+                               [1, WALK_C, WALK_C], None),
+    # a band over a ring: logical pages past the table's five entries,
+    # read through p mod 5
+    "band_over_a_ring": ([[3, 6, 1, 10, 5], [8, 2, 7, 4, 9], [11, 4, 7, 9, 2]],
+                         [7 * P + 3, 9 * P - 2, 1], [1, WALK_C, WALK_C],
+                         _BAND),
+    # more trips than the ring has slots, then fewer
+    "ring_wraps_and_does_not": ([[3, 6, 1, 10, 5], [8, 2, -1, -1, -1],
+                                 [11, 4, 7, 9, -1]],
+                                [FULL - WALK_C + 1, P + 3, 3 * P + 1],
+                                [WALK_C, 1, WALK_C], None),
+}
+
+
+def _walk_inputs(kind, case):
+    """(q, pk, pv, table, pos, q_len, window) of a case: seeded, so
+    that the file of the parent's results stays true to them."""
+    rows, pos, qlen, window = MIXED_WALK_CASES[case]
+    rng = np.random.default_rng(62)
+    pk, pv = (_poisoned(kind, pool)
+              for pool in _SWEEP_POOLS[kind](rng, 2, 16))
+    assert 0 not in {p for row in rows for p in row}
+    q = jnp.asarray(rng.normal(size=(len(rows), WALK_C, 8, 16)), jnp.float32)
+    return (q, pk, pv, jnp.asarray(rows, jnp.int32),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(qlen, jnp.int32),
+            window)
+
+
+def _walk_selection(case):
+    """A per-(query, key) selection [B, max_pages, C, P] for a case:
+    about half of the keys, every query's own among them."""
+    rows, pos, _qlen, _w = MIXED_WALK_CASES[case]
+    rng = np.random.default_rng(63)
+    sel = rng.random((len(rows), MAX_PAGES, WALK_C, P)) < 0.5
+    for b, first in enumerate(pos):
+        for i in range(WALK_C):
+            at = min(first + i, FULL)
+            sel[b, at // P, i, at % P] = True
+    return jnp.asarray(sel, jnp.float32)
+
+
+def _walked(kind, inputs, selected=None):
+    q, pk, pv, table, pos, qlen, window = inputs
+    kw = {} if selected is None else {"selected": selected}
+    if kind == "f32":
+        return np.asarray(ragged_paged_attention_mixed(
+            q, pk, pv, LAYER, table, pos, qlen, window=window,
+            interpret=True, **kw))
+    return np.asarray(ragged_paged_attention_mixed(
+        q, pk.q, pv.q, LAYER, table, pos, qlen, scale_k=pk.scale,
+        scale_v=pv.scale, packed4=kind == "int4", window=window,
+        interpret=True))
+
+
+_WALK_PARAMS = ([(case, kind, False) for case in sorted(MIXED_WALK_CASES)
+                 for kind in ("f32", "int8", "int4")]
+                + [(case, "f32", True) for case in sorted(MIXED_WALK_CASES)
+                   if MIXED_WALK_CASES[case][3] is None])
+
+
+@pytest.mark.parametrize("block", [1, 2, 4])
+@pytest.mark.parametrize("case,kind,selecting", _WALK_PARAMS)
+def test_mixed_kernel_walks_live_pages(case, kind, selecting, block,
+                                       monkeypatch):
+    """The walked kernel against the fold reference at 1, 2 and 4 pages
+    a fold, over a ring of three slots: the real columns match, every
+    column is finite, a row that folds its first tile alone (a decode
+    row, an idle row) holds zeros past it, an idle row zeros only."""
+    monkeypatch.setattr(rpa, "decode_ring_depth", lambda nbytes: RING_DEPTH)
+    monkeypatch.setattr(rpa, "mixed_block", lambda *a, **kw: block)
+    inputs = _walk_inputs(kind, case)
+    q, pk, pv, table, pos, qlen, window = inputs
+    selected = _walk_selection(case) if selecting else None
+    want = np.asarray(paged_attention_mixed(
+        q, pk, pv, LAYER, table, pos, qlen, window=window,
+        selected=selected))
+    got = _walked(kind, inputs, selected)
+    assert np.isfinite(got).all()
+    atol = 1e-5 if kind == "f32" else 2e-5
+    for b, n in enumerate(np.asarray(qlen)):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=atol,
+                                   rtol=atol, err_msg=f"row {b}")
+        if mixed_q_tiles(n, WALK_C) == 1:
+            assert not got[b, TQ:].any(), b
+        if n == 0:
+            assert not got[b].any(), b
+
+
+PARENT_RESULTS = (pathlib.Path(__file__).parent / "data"
+                  / "mixed_attn_grid_results.npz")
+
+
+@pytest.mark.parametrize("case,kind,selecting", _WALK_PARAMS)
+def test_one_page_a_fold_is_bit_equal_to_the_grid_it_replaced(
+        case, kind, selecting, monkeypatch):
+    """At one page a fold the walk changes no bit of a column a caller
+    reads (a real query's): tests/data/mixed_attn_grid_results.npz
+    holds what the (rows, pages) grid's kernel gave on these inputs at
+    commit bca0bd8 (PR 61), interpreted as here."""
+    monkeypatch.setattr(rpa, "mixed_block", lambda *a, **kw: 1)
+    inputs = _walk_inputs(kind, case)
+    got = _walked(kind, inputs,
+                  _walk_selection(case) if selecting else None)
+    with np.load(PARENT_RESULTS) as saved:
+        want = saved[f"{case}-{kind}-{int(selecting)}"]
+    for b, n in enumerate(np.asarray(inputs[5])):
+        np.testing.assert_array_equal(got[b, :n], want[b, :n],
+                                      err_msg=f"row {b}")
+
+
+# mixed_block at the cells' shapes (the rule's answers, stated)
+MIXED_BLOCKS = {"keye": 4, "mistral": 4, "olmoe": 4, "zaya": 4,
+                "nemotron": 4, "granite": 4, "kexaone": 4,
+                "kexaone_ring": 2}
+
+
+def test_mixed_block_and_the_vmem_count_follow_the_shapes():
+    """`mixed_block` from the shapes alone, at the seven cells' calls:
+    4 pages a fold where the table admits it and the count fits the
+    plan, 2 on K-EXAONE's ring of 6 entries, 1 on a ring of 3; the
+    count's arithmetic at one page a fold; an entry's width is still
+    what the 16 MiB count admits."""
+    from cake_tpu.models.moe.exaone_moe import query_tile
+    from cake_tpu.ops.ragged_paged_attention import (
+        _MIXED_VMEM_LIMIT, _MIXED_VMEM_PLAN, _VMEM_SCOPED_LIMIT,
+        decode_ring_depth, mixed_block, mixed_scratch_bytes,
+        mixed_vmem_bytes, mixed_walk,
+    )
+
+    # (page, H, KV, hd, C, table): Keye, Mistral, OLMoE, ZAYA,
+    # Nemotron, Granite, K-EXAONE full and banded
+    cells = {"keye": (128, 32, 4, 128, 128, 260),
+             "mistral": (128, 32, 8, 128, 128, 16),
+             "olmoe": (128, 16, 16, 128, 128, 16),
+             "zaya": (128, 8, 2, 128, 128, 40),
+             "nemotron": (128, 32, 2, 128, 128, 40),
+             "granite": (128, 32, 8, 64, 128, 20),
+             "kexaone": (128, 64, 8, 128, 64, 76),
+             "kexaone_ring": (128, 64, 8, 128, 64, 6)}
+    for name, (page, H, KV, hd, C, table) in cells.items():
+        f = mixed_block(page, H, KV, hd, C, table,
+                        selecting=name == "keye")
+        assert f == MIXED_BLOCKS[name], name
+        assert mixed_vmem_bytes(page, H, KV, hd, C, block=f,
+                                selecting=name == "keye") <= _MIXED_VMEM_PLAN
+        assert mixed_vmem_bytes(page, H, KV, hd, C) <= _VMEM_SCOPED_LIMIT
+    assert _MIXED_VMEM_PLAN < _MIXED_VMEM_LIMIT
+    # the count, term by term, at Mistral's call
+    page_bytes = 128 * 8 * 128 * 2
+    assert mixed_vmem_bytes(128, 32, 8, 128, 128) == (
+        mixed_scratch_bytes(32, 128, 128) + 4 * 128 * 32 * 128 * 2
+        + decode_ring_depth(page_bytes) * 2 * page_bytes
+        + 128 * 4 * 128 * (8 + 2))
+    # a ring of 3 entries would pay 4 pages a row at 4 a fold
+    assert mixed_block(128, 64, 8, 128, 64, 3) == 1
+    # entry widths: what they were
+    assert query_tile(512, 32, 4, 128, 128, 2, 2) == 128
+    assert query_tile(512, 64, 8, 128, 128, 2, 2) == 64
+    assert query_tile(512, 32, 8, 64, 128, 2, 2) == 128
+    # the host's count of the walk
+    assert mixed_walk(0, 0, 128, 16, 4) == (0, 0)
+    assert mixed_walk(300, 1, 128, 16, 4) == (3, 1)
+    assert mixed_walk(1000, 128, 128, 16, 4) == (9, 3)
+    assert mixed_walk(1000, 128, 128, 16, 1) == (9, 9)
+    assert mixed_walk(1000, 64, 128, 6, 2, window=128) == (3, 2)
+    assert mixed_walk(10**6, 1, 128, 16, 4) == (16, 4)
 
 
 def test_mixed_q_tiles_counts_what_the_kernel_folds():

@@ -170,10 +170,24 @@ def test_int8_place_for_pipeline_specs(topo_path):
     assert wq.scale.ndim == wq.q.ndim - 1
 
 
-def test_cli_one_shot_with_topology(topo_path, capsys):
+def test_cli_one_shot_with_topology(topo_path, capsys, monkeypatch):
     """BASELINE config #2 from the CLI entry point (reference
-    cake-cli/src/main.rs:28-54 master path)."""
+    cake-cli/src/main.rs:28-54 master path).
+
+    In THIS process main() must not turn JAX's persistent compilation
+    cache on: the setting outlives the test, and every later test of
+    the worker would then read and write `<checkout>/.jax_cache` beside
+    the other workers. JAX writes an entry in place, with no lock and
+    no rename (`LRUCache.put`: `cache_path.write_bytes`), so a worker
+    that compiles a program another is writing reads half an entry and
+    dies inside `get_executable_and_time` ("Fatal Python error:
+    Aborted": test_step_parity's packed mixed step under six workers,
+    PR 61's tier-1 run)."""
+    import jax
+
     from cake_tpu.cli import main
+    monkeypatch.setattr("cake_tpu.utils.compile_cache.enable_compile_cache",
+                        lambda: "off")
     rc = main([
         "--topology", topo_path, "--max-seq-len", "256",
         "--sample-len", "4", "--temperature", "0.0",
@@ -182,6 +196,7 @@ def test_cli_one_shot_with_topology(topo_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "hi" in out
+    assert jax.config.jax_compilation_cache_dir is None
 
 
 def test_sp_serving_matches_dense_full_window():

@@ -255,6 +255,62 @@ def test_attention_kernels_compile_at_heads_of_64_under_a_stated_scale(
     assert "tpu_custom_call" in hlo and f"cake_{kernel}_attn" in hlo
 
 
+# (H, KV, hd, entries, queries an entry, pages a table row, selecting):
+# each cell's `cake_mixed_attn` call (K-EXAONE's two and Granite's are
+# the two tests above)
+MIXED_CALLS = {
+    "keyevl2": (32, 4, 128, 4, 128, 260, True),
+    "mistral7b": (32, 8, 128, 16, 128, 16, False),
+    "olmoe7b": (16, 16, 128, 16, 128, 16, False),
+    "zaya1": (8, 2, 128, 32, 128, 40, False),
+    "nemotron3s": (32, 2, 128, 4, 128, 40, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(MIXED_CALLS))
+def test_walked_mixed_kernel_compiles_at_the_cells_calls(one_chip, cell):
+    """`cake_mixed_attn` as it walks its pages (grid (rows,), the K and
+    V rings of `mixed_block` pages a slot, Keye's selection beside
+    them, the softmax's m and l as the loop's carries) goes through
+    Mosaic at each cell's call under the limit it states, and
+    `mixed_vmem_bytes` at that block lies under what `mixed_block`
+    plans with. Nemotron's group of 16 alone was refused for 0.9 MiB
+    while the kernel stated no limit (PR 34)."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, KV, hd, B, C, pages, selecting = MIXED_CALLS[cell]
+    P = 128
+    block = rpa.mixed_block(P, H, KV, hd, C, pages, selecting=selecting)
+    assert rpa.mixed_vmem_bytes(P, H, KV, hd, C, block=block,
+                                selecting=selecting) <= rpa._MIXED_VMEM_PLAN
+    pool = sds((2, B * 8, P, KV * hd), jnp.bfloat16)
+
+    def call(q, k, v, t, p, n_q, *sel):
+        return rpa.ragged_paged_attention_mixed(
+            q, k, v, jnp.int32(1), t, p, n_q, interpret=False,
+            **({"selected": sel[0]} if sel else {}))
+
+    args = [sds((B, C, H, hd), jnp.bfloat16), pool, pool,
+            sds((B, pages), jnp.int32), sds((B,), jnp.int32),
+            sds((B,), jnp.int32)]
+    if selecting:
+        args.append(sds((B, pages, C, P), jnp.float32))
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        assert rpa.ragged_paged_mixed_supported(P, H, KV, hd, C)
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(call).lower(*args).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_mixed_attn" in hlo
+
+
 def test_kda_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
     """`cake_kda_step` at ling3.longreply-closed's shapes (10 layers of
     32 rows x 32 heads of 128 x 128 float32) goes through Mosaic, and
