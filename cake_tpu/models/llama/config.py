@@ -70,6 +70,10 @@ MODEL_TYPES = {
                "keys a query attends over ordinary K/V pages (its keys in "
                "a third pool over the same page table), softmax-routed "
                "experts, none shared (paged engine)",
+    "brumby": "power retention of degree 2 in every layer: q and k normed "
+              "a head and rotated, a gate a K/V head, a matrix state a row "
+              "and layer that a GQA group shares beside a page pool of NO "
+              "layers; a dense SwiGLU, an untied head (paged engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -112,6 +116,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "KeyeVL2":
         from cake_tpu.models.moe.config import KeyeVL2Config
         return KeyeVL2Config.from_hf_dict(raw)
+    if model_type == "brumby":
+        from cake_tpu.models.moe.config import BrumbyConfig
+        return BrumbyConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

@@ -168,14 +168,22 @@ class PagedKVCache(NamedTuple):
 
 class HybridPagedCache(NamedTuple):
     """PagedKVCache plus the rows' state (module docstring): what a
-    model with Mamba blocks, or with convolutions inside its attention,
-    carries through its step programs, donated in and aliased out like
-    the pools."""
-    k: jnp.ndarray        # [L_attn, N_pages, page, KV*hd]
+    model with Mamba blocks, with convolutions inside its attention, or
+    with a matrix state a layer carries through its step programs,
+    donated in and aliased out like the pools. A family NONE of whose
+    layers keeps K/V (models/moe/brumby.py) makes the pool with zero
+    layers: `k` and `v` hold no byte, `memory_bytes()` is 0, and
+    `n_pages`, `page_size` and the table stay what the allocator keeps
+    its books of positions in."""
+    k: jnp.ndarray        # [L_attn, N_pages, page, KV*hd]; L_attn may be 0
     v: jnp.ndarray
     table: jnp.ndarray    # [slots, max_pages] int32
-    ssm: Optional[jnp.ndarray]   # [L_M, slots, H, P, N] float32, or None
-    conv: jnp.ndarray     # [L_M, slots, K-1, conv_dim]
+    # the rows' state, a family's own shapes: [L_M, slots, H, P, N] float32
+    # (Mamba, KDA), [L, slots, KV, NB, hd, DB] float32 (retention), or None
+    ssm: Optional[jnp.ndarray]
+    # what else a row keeps: conv tails [L_M, slots, K-1, conv_dim] in the
+    # pool's type; retention's normaliser z [L, slots, KV, D] float32
+    conv: jnp.ndarray
 
     page_size = PagedKVCache.page_size
     n_pages = PagedKVCache.n_pages

@@ -1620,3 +1620,89 @@ class GraniteHybridConfig(MoEConfig):
         )
         base.update(overrides)
         return cls(**base)
+
+
+# config.json keys of model_type brumby whose one served value is the
+# published one: anything else is refused by the key's name
+_BRUMBY_ONLY = {
+    "use_sliding_window": False, "sliding_window": None,
+    "rope_scaling": None, "attention_bias": False,
+    "tie_word_embeddings": False, "hidden_act": "silu",
+}
+
+
+@dataclass(frozen=True)
+class BrumbyConfig(MoEConfig):
+    """Brumby (`model_type: brumby`): Qwen3's dense block with the
+    softmax replaced by power retention of degree 2 in EVERY layer: q
+    and k normed a head and rotated whole, a gate a K/V head and token,
+    a matrix state a row, layer and K/V head that the query heads of a
+    GQA group share, no K/V page anywhere (the rows' state beside a page
+    pool of zero layers: models/llama/paged.HybridPagedCache), a dense
+    SwiGLU, an untied head. The config is Qwen3's key for key but
+    `model_type`; it has NO key for the mixer, whose constants (degree
+    2, the gate's form, the normaliser) are models/moe/brumby.py's,
+    named in its docstring. The equations are in
+    models/reference/brumby.py; the served path in models/moe/brumby.py.
+
+    `max_window_layers` is not read at all (Qwen's sliding-window
+    switch, meaningless where `use_sliding_window` must be false).
+    `num_local_experts` is 0, so `is_moe` says by itself that the seeded
+    draw and the trunk live under models/moe."""
+
+    _family = "cake_tpu.models.moe.brumby:FAMILY"
+
+    attn_head_dim: int = 128
+
+    is_moe = True
+
+    @property
+    def head_dim(self) -> int:
+        return self.attn_head_dim
+
+    @property
+    def group_size(self) -> int:
+        """Query heads that share a K/V head's state."""
+        return self.num_attention_heads // self.num_key_value_heads
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "BrumbyConfig":
+        for name, want in _BRUMBY_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type brumby serves "
+                    f"{want!r} only (not implemented)")
+        hd = raw.get("head_dim",
+                     raw["hidden_size"] // raw["num_attention_heads"])
+        if hd % 16:
+            raise ValueError(
+                f"head_dim = {hd}: the retention state keeps a head's "
+                "symmetric square in tiles of 16 x 16 (ops/retention.py); "
+                "head_dim must be a multiple of 16")
+        if raw["num_attention_heads"] % raw.get(
+                "num_key_value_heads", raw["num_attention_heads"]):
+            raise ValueError(
+                "num_key_value_heads must divide num_attention_heads")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, eos_token_id=raw.get("eos_token_id", raw["vocab_size"])))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields["chat_template"] = "chatml"
+        return cls(**fields, num_local_experts=0, num_experts_per_tok=0,
+                   hf_layout="brumby", attn_head_dim=hd)
+
+    @classmethod
+    def tiny_brumby(cls, **overrides) -> "BrumbyConfig":
+        """Brumby's layer at a test's size: 3 layers, 4 query heads over
+        2 K/V heads of 16 (a group of 2 shares a state of 256 x 16)."""
+        base = dict(
+            vocab_size=512, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=2, rms_norm_eps=1e-6, rope_theta=1e6,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(512,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=0, num_experts_per_tok=0,
+            hf_layout="brumby", attn_head_dim=16,
+        )
+        base.update(overrides)
+        return cls(**base)

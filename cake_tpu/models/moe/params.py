@@ -557,6 +557,58 @@ def _init_granite_params(config, rng: jax.Array, dtype,
             "lm_head": quantize(embed.T, (0,)) if bits else embed.T}
 
 
+# a retention gate's half-life at a zero input, in tokens: log-uniform
+# over the contexts the cell serves (benchmarks/configs/brumby-14b-int8-
+# 10of40/cell.json, `assumed` (f))
+_BRUMBY_HALF_LIFE = (16.0, 4096.0)
+
+
+def _init_brumby_params(config, rng: jax.Array, dtype, bits: Optional[int]):
+    """The seeded tree of a BrumbyConfig: every layer alike, so every
+    leaf is stacked [L, ...]. The matmul leaves are the projections, the
+    SwiGLU and the untied head; the norms (1 + 0.1 N: the stream's, and
+    q's and k's over head_dim, one vector a layer) and the gate stay
+    float under bits=8. The gate `w_g` [L, D, KV] is float32 and drawn
+    16 times narrower than the matrices, its bias `b_g` so that a K/V
+    head's half-life at a zero input is log-uniform in
+    _BRUMBY_HALF_LIFE: gamma = 2^(-1 / half-life), b_g = logit(gamma).
+    Under weights of variance 1 / fan-in the gate's input term is then a
+    few hundredths of b_g's spread: a state neither dies inside a window
+    nor holds a whole context at full weight."""
+    c = config
+    L, D, F = c.num_hidden_layers, c.hidden_size, c.intermediate_size
+    H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    w, mat, keys = _draws(rng, dtype, bits, 24)
+
+    def near(shape, centre, spread=0.1):
+        return (centre + spread * jax.random.normal(
+            next(keys), shape, jnp.float32)).astype(dtype)
+
+    lo, hi = _BRUMBY_HALF_LIFE
+    half_life = jnp.exp(jax.random.uniform(
+        next(keys), (L, KV), jnp.float32, np.log(lo), np.log(hi)))
+    gamma = jnp.exp2(-1.0 / half_life)
+    blocks = {
+        "norm": near((L, D), 1.0),
+        "wq": mat("wq", (L, D, H * hd), D),
+        "wk": mat("wk", (L, D, KV * hd), D),
+        "wv": mat("wv", (L, D, KV * hd), D),
+        "q_norm": near((L, hd), 1.0),
+        "k_norm": near((L, hd), 1.0),
+        "w_g": jax.random.normal(next(keys), (L, D, KV), jnp.float32)
+        / (16.0 * np.sqrt(D)),
+        "b_g": jnp.log(gamma) - jnp.log1p(-gamma),
+        "wo": mat("wo", (L, H * hd, D), H * hd),
+        "mlp_norm": near((L, D), 1.0),
+        "w_gate": mat("w_gate", (L, D, F), D),
+        "w_up": mat("w_up", (L, D, F), D),
+        "w_down": mat("w_down", (L, F, D), F),
+    }
+    return {"embed": w((c.vocab_size, D), D), "blocks": blocks,
+            "final_norm": near((D,), 1.0),
+            "lm_head": mat("lm_head", (D, c.vocab_size), D)}
+
+
 def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
                 bits: Optional[int] = None):
     """Random-init MoE parameter pytree (tests, benchmarks, a model
@@ -580,6 +632,8 @@ def init_params(config: MoEConfig, rng: jax.Array, dtype=jnp.bfloat16,
         return _init_glm_params(config, rng, dtype, bits)
     if config.hf_layout == "granitemoehybrid":
         return _init_granite_params(config, rng, dtype, bits)
+    if config.hf_layout == "brumby":
+        return _init_brumby_params(config, rng, dtype, bits)
     if getattr(config, "mamba_layers", None):
         return _init_nemotron_params(config, rng, dtype, bits)
     if getattr(config, "cca_time0", None):
@@ -645,6 +699,12 @@ def hf_layout(config: MoEConfig):
             "this repository and its tensor names are not guessed (the "
             "choice bias and the per-head q / k norms among them); it "
             "is served from seeded weights only")
+    if config.hf_layout == "brumby":
+        raise NotImplementedError(
+            "model_type brumby: the published checkpoint is not in this "
+            "repository and its tensor names are not guessed (the "
+            "retention gate's projection and the per-head q / k norms "
+            "among them); it is served from seeded weights only")
     attn = {
         "attn_norm": ("input_layernorm.weight", False),
         "wq": ("self_attn.q_proj.weight", True),

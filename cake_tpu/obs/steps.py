@@ -512,6 +512,31 @@ DSA_GQA_COUNTERS = (
         "Index keys the single-token rows' indexers scored (position + "
         "1 each: every visible key), summed over rows and layers")),
 )
+# a model of power retention layers (models/moe/brumby.trunk): the rows'
+# matrix state, the page pool of no layers beside it, and the two forms
+RETENTION_COUNTERS = (
+    ("retention_state_rows", _m.counter(
+        "cake_retention_state_rows_total",
+        "Rows whose retention state a step read and wrote, summed over "
+        "layers (a row with no token in a dispatch costs none)")),
+    ("retention_tokens_windowed", _m.counter(
+        "cake_retention_tokens_windowed_total",
+        "Tokens through the window form (a prompt's windows), summed "
+        "over layers")),
+    ("retention_tokens_stepped", _m.counter(
+        "cake_retention_tokens_stepped_total",
+        "Tokens through the one-step update (a row's single token: what "
+        "cake_retention_step moved a state for), summed over layers")),
+    ("retention_state_resets", _m.counter(
+        "cake_retention_state_resets_total",
+        "Rows whose retention state a step zeroed: a request took the "
+        "slot")),
+)
+RETENTION_STATE_BYTES = _m.gauge(
+    "cake_retention_state_bytes",
+    "Bytes of the rows' retention state (the float32 matrix S and the "
+    "normaliser z a K/V head, every layer, every slot) beside a page "
+    "pool of no layers")
 GQA_WINDOW_POOL_BYTES = _m.gauge(
     "cake_gqa_window_pool_bytes",
     "Bytes of the sliding-window layers' K and V pools beside the page "
@@ -519,12 +544,13 @@ GQA_WINDOW_POOL_BYTES = _m.gauge(
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
                       + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS
                       + KDA_COUNTERS + GQA_WINDOW_COUNTERS
-                      + DSA_GQA_COUNTERS)
+                      + DSA_GQA_COUNTERS + RETENTION_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)
 BESIDE_POOL_BYTES = {"ssm_state_bytes": SSM_STATE_BYTES,
                      "cca_tail_bytes": CCA_TAIL_BYTES,
                      "kda_state_bytes": KDA_STATE_BYTES,
-                     "gqa_window_pool_bytes": GQA_WINDOW_POOL_BYTES}
+                     "gqa_window_pool_bytes": GQA_WINDOW_POOL_BYTES,
+                     "retention_state_bytes": RETENTION_STATE_BYTES}
 
 
 def refresh_page_gauges(engine) -> None:

@@ -140,21 +140,29 @@ def _update(cols_ref, rows_ref, o_ref, ring, slot, i: int, H: int, hb: int):
     o_ref[0, i * hb:(i + 1) * hb, :] = jnp.concatenate(outs, axis=0)
 
 
-def _step_kernel(j_ref, code_ref, *refs, depth: int, hb: int, update=_update):
+def _step_kernel(j_ref, code_ref, *refs, depth: int, hb: int, update=_update,
+                 outs: int = 1, scratch: int = 0, stay=None):
     """One grid step: one ROW.
 
     j_ref [1], code_ref [B]: the layer of the stack, each row's code
     refs: the row's operands (here cols_ref, rows_ref), then
     state_in / state_ref: [L, B, H, dk, dv] in HBM, ONE buffer (aliased)
-    o_ref: the row's output block
+    o_refs: the row's `outs` output blocks (here one, o_ref)
     ring [depth, hb, dk, dv] VMEM; sem DMA [2, depth]: in, out
     cur SMEM int32 [4]: the copies' cursor (row, block, count of blocks
         started) and the count of blocks updated, carried row to row
-    update: a block's arithmetic (ops/ssm.py rides these copies with
-        its own operands and its own)
+    then `scratch` refs of the caller's own, handed to `update` last
+    update: a block's arithmetic (ops/ssm.py and ops/retention.py ride
+        these copies with their own operands and their own)
+    stay: what a STAYING row leaves in its output blocks, stay(*operands,
+        *o_refs); None: zeros in the first
     """
-    *operands, state_in, state_ref, o_ref, ring, sem, cur = refs
-    del state_in
+    more = refs[len(refs) - scratch:]
+    refs = refs[:len(refs) - scratch]
+    ring, sem, cur = refs[-3:]
+    o_refs = refs[-3 - outs:-3]
+    state_ref = refs[-4 - outs]
+    operands = refs[:-5 - outs]
     b, nb = pl.program_id(0), pl.num_programs(0)
     H = state_ref.shape[2]
     nblk = H // hb
@@ -222,7 +230,10 @@ def _step_kernel(j_ref, code_ref, *refs, depth: int, hb: int, update=_update):
 
     @pl.when(code == STAY)
     def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        if stay is None:
+            o_refs[0][...] = jnp.zeros_like(o_refs[0])
+        else:
+            stay(*operands, *o_refs)
 
     @pl.when(code != STAY)
     def _():
@@ -240,7 +251,7 @@ def _step_kernel(j_ref, code_ref, *refs, depth: int, hb: int, update=_update):
             def _():
                 ring[slot] = jnp.zeros(ring.shape[1:], F32)
 
-            update(*operands, o_ref, ring, slot, i, H, hb)
+            update(*operands, *o_refs, ring, slot, i, H, hb, *more)
             store(b, i, slot).start()
 
     # every slot that held a block still has its last store in flight

@@ -2637,18 +2637,28 @@ class InferenceEngine:
             beside = self.cache.beside_bytes()
             if gauge is not None:
                 obs_steps.BESIDE_POOL_BYTES[gauge].set(beside)
-            log.info("%s: %.2f GiB beside the pool, %d rows", what,
-                     beside / 2**30, self.max_slots)
-        log.info("paged KV: %d pages x %d tokens, %s attention, "
-                 "%s storage (%.2f GiB pool; dense %d-slot "
-                 "equivalent would be %.2f GiB)",
-                 kv_pages, kv_page_size, self.attn_impl,
-                 (self._kv_dtype_name + "+scales") if self.kv_quant
-                 else str(pool_dtype),
-                 self.cache.memory_bytes() / 2**30, self.max_slots,
-                 self.cache.memory_bytes() / 2**30
-                 * self.max_slots * self.max_seq_len
-                 / (kv_pages * kv_page_size))
+            log.info("%s: %.2f GiB (%d bytes) beside the pool, %d rows",
+                     what, beside / 2**30, beside, self.max_slots)
+        if self.cache.memory_bytes() == 0:
+            # a family none of whose layers keeps K/V: the pool is a
+            # stated case, not a small one (nothing below sizes, spills
+            # or reports by a page's bytes)
+            log.info("paged KV: a pool of NO layers, 0 bytes: %d pages x "
+                     "%d tokens are the allocator's bookkeeping of "
+                     "positions alone (%s attention); what a row holds "
+                     "lies beside the pool and the slots bound admission",
+                     kv_pages, kv_page_size, self.attn_impl)
+        else:
+            log.info("paged KV: %d pages x %d tokens, %s attention, "
+                     "%s storage (%.2f GiB pool; dense %d-slot "
+                     "equivalent would be %.2f GiB)",
+                     kv_pages, kv_page_size, self.attn_impl,
+                     (self._kv_dtype_name + "+scales") if self.kv_quant
+                     else str(pool_dtype),
+                     self.cache.memory_bytes() / 2**30, self.max_slots,
+                     self.cache.memory_bytes() / 2**30
+                     * self.max_slots * self.max_seq_len
+                     / (kv_pages * kv_page_size))
         # --kv-host-pages: host-RAM spill tier behind the page
         # allocator (cake_tpu/kv/host_tier.py) — preemption victims'
         # suffix pages and cold shared-prefix pages spill to pinned

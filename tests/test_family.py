@@ -19,7 +19,7 @@ from cake_tpu.models.llama.config import MODEL_TYPES, LlamaConfig
 from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
-    BailingHybridConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
+    BailingHybridConfig, BrumbyConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
     GlmMoeDsaConfig, GraniteHybridConfig, KeyeVL2Config, MoEConfig,
     NemotronHConfig,
     ZayaConfig,
@@ -42,6 +42,7 @@ TINY = {
     "exaone_moe": ExaoneMoeConfig.tiny_exaone,
     "granitemoehybrid": GraniteHybridConfig.tiny_granite,
     "KeyeVL2": KeyeVL2Config.tiny_keye,
+    "brumby": BrumbyConfig.tiny_brumby,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
@@ -49,7 +50,8 @@ NOUNS = {"glm_moe_dsa": "latent row and index key",
          "deepseek_v2": "latent row",
          "nemotron_h": "state", "zaya": "tail",
          "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring",
-         "granitemoehybrid": "state", "KeyeVL2": "index-key pool"}
+         "granitemoehybrid": "state", "KeyeVL2": "index-key pool",
+         "brumby": "state"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 

@@ -643,6 +643,35 @@ LOWERED_BEFORE = {
         "ac61136edb0263ecde66c5f05812c1b952e1363297605f4dd1c428df3908d6ee",
     ("mistral", "mixed", "pallas"):
         "6d3f047daa027259c89add8a3519624363f056cc94a2e7927815c237052c2638",
+    # the families whose shared code PR 63 (model_type brumby) touched,
+    # taken on the commit before it (8e4a1c0): ops/kda._step_kernel grew
+    # `outs` / `scratch` / `stay` for ops/retention.py, and
+    # HybridPagedCache a user with a pool of no layers. (Nemotron's four
+    # are pinned in tests/test_granite_hybrid.py.)
+    ("granitemoehybrid", "decode", "fold"):
+        "4d0b30fcb798ca1e210728e4844de83f67a5ec5e288ae576a071d106f0d33bdd",
+    ("granitemoehybrid", "decode", "pallas"):
+        "e95cb5ae513b83527192ef73752070b72b85d78920c5aba2e1e73a73bbeb6c7b",
+    ("granitemoehybrid", "mixed", "fold"):
+        "2b2ba9d6af4cb01ff2bca65a98b72e4eedb1f65327c9aeb855d40581eb367b68",
+    ("granitemoehybrid", "mixed", "pallas"):
+        "301c236cb11c4b166de4a3d4f4874d1a1442de2714a7c0f51fce5bdae8791843",
+    ("bailing_hybrid", "decode", "fold"):
+        "41209b0bd73b7a679d94731b52f785a93ca9e7d37f7474f429629e69170c0511",
+    ("bailing_hybrid", "decode", "pallas"):
+        "0b341c19342291aea597f6556ae0bb7f569ea6cf053ea450ba5a01462eebb438",
+    ("bailing_hybrid", "mixed", "fold"):
+        "e0954fcb46f5d0fdf9c5010bb6a47f0033479df54127c6abd45f6dd72e8783e9",
+    ("bailing_hybrid", "mixed", "pallas"):
+        "6f75ac21e1ded3136e980159849a4a383765f4415892fdf986db0322d95a79da",
+    ("zaya", "decode", "fold"):
+        "3c80cd2e9ef459be219508b6b178759f26358fdd3dd7ff814f63caed009d53cb",
+    ("zaya", "decode", "pallas"):
+        "177a0ba8eb7bfad696cb80b3f91fccea48c329954c348a50da8da4ffa6910376",
+    ("zaya", "mixed", "fold"):
+        "9931026bca1fdd9a69dfdd61bbe26300f3e30e8e222dca8e40bb93de76d63049",
+    ("zaya", "mixed", "pallas"):
+        "4b3d216473b5d6e37c4a31345bbf5b46733e1bb67df34a7b992fd0bd4263a15d",
 }
 
 

@@ -455,6 +455,86 @@ def test_ssm_step_kernel_compiles_in_place_at_the_cells_widths(one_chip):
             == 2 * 2**20)
 
 
+def test_retention_step_kernel_compiles_in_place_at_the_cells_widths(
+        one_chip):
+    """`cake_retention_step` at brumby14b.longreply16-closed's shapes
+    (10 layers of 16 rows x 8 K/V heads of [9, 128, 1024] float32, 5
+    query heads a group) goes through Mosaic inside the default scoped
+    VMEM, and the program that donates the stacks holds them ONCE: the
+    6.09 GB of S and z are aliased in and out, nothing among the
+    temporaries."""
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import kda, retention
+    from cake_tpu.ops import ragged_paged_attention as rpa
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, B, G, R, hd = 10, 16, 8, 5, 128
+    D = retention.state_width(hd)
+    assert retention.state_shape(G, hd, hd) == (G, 9, 128, 1024)
+    on_tpu, rpa._on_tpu = rpa._on_tpu, lambda: True
+    try:
+        compiled = jax.jit(retention.step, donate_argnums=(0, 1)).lower(
+            sds((L, B, G, 9, 128, 1024), jnp.float32),
+            sds((L, B, G, D), jnp.float32), sds((), jnp.int32),
+            sds((B,), jnp.int32), sds((B, G, R, hd), jnp.float32),
+            sds((B, G, hd), jnp.float32), sds((B, G, hd), jnp.bfloat16),
+            sds((B, G), jnp.float32)).compile()
+    finally:
+        rpa._on_tpu = on_tpu
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "cake_retention_step" in hlo
+    assert "vmem_limit_bytes" not in hlo
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * B * G * D * (hd + 1) * 4
+    assert memory.temp_size_in_bytes < 16 * 2**20
+    assert kda.RING_DEPTH * hd * 1024 * 4 == 2 * 2**20
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_brumbys_step_programs_hold_the_state_once(tool, one_chip, program):
+    """A two-layer cut of Brumby's served programs at the cell's widths,
+    slots and window for the described v5e: ONE `cake_retention_step`
+    call a layer over the whole stacks, which are aliased in and out
+    (2 x 16 rows x 37.75 MB + z), and the temporaries (the window form's:
+    phi(Q) of a K/V group, 94 MB, and its products; 0.29 GB) stay under
+    ONE layer's state (609 MB): neither the kernel's write in place nor
+    the window row's read behind its barrier costs a copy of a layer,
+    let alone of the stack."""
+    import json
+
+    from cake_tpu.models.llama.config import load_config
+
+    cell = CONFIGS / "brumby-14b-int8-10of40"
+    config = dataclasses.replace(load_config(str(cell)), num_hidden_layers=2)
+    with open(cell / "cell.json") as f:
+        cell = json.load(f)
+    sa = cell["server_args"]
+    shape = dict(slots=sa["max-slots"], n_pages=sa["kv-pages"],
+                 page_size=sa["kv-page-size"], max_seq_len=sa["max-seq-len"])
+    decode, mixed = tool.step_fns(config)
+    with jax.default_matmul_precision("default"):
+        if program == "decode":
+            compiled = tool.compile_step(decode, config, one_chip, **shape)
+        else:
+            width = cell["shape"]["mixed_width"]
+            compiled = tool.compile_step(
+                mixed, config, one_chip, width=width,
+                n_tokens=width + sa["max-slots"], **shape)
+    hlo = compiled.as_text()
+    stack = f"f32[2,{sa['max-slots'] * 8},9,128,1024]"
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cake_retention_step" in line]
+    assert len(calls) == 2 and all(stack in line for line in calls)
+    assert not tool.materialised_int8(hlo)
+    memory = compiled.memory_analysis()
+    state = 2 * sa["max-slots"] * 8 * 9216 * 129 * 4
+    assert memory.alias_size_in_bytes >= state
+    assert memory.temp_size_in_bytes < state // 2
+
+
 @pytest.mark.parametrize("program", ["decode", "mixed"])
 def test_granites_step_programs_move_the_state_in_the_kernel_alone(
         tool, one_chip, program):
