@@ -112,6 +112,12 @@ class Family:
     # layers of a dispatch); the host counts them into the mixed
     # records. None: no such kernel
     window_walk: Optional[Callable] = None
+    # a family whose single-token rows walk their latent pages
+    # (cake_mla_decode_attn): decode_walk(config, cache) -> (a row's
+    # position -> (pages it walks, softmax updates they take), over the
+    # layers that run the kernel); the host counts them into the decode
+    # and the mixed records. None: no such kernel
+    decode_walk: Optional[Callable] = None
     # a family that hands a dispatch's window to cake_mixed_attn in a
     # form of its own (entries of one row's window): mixed_attn_walk(
     # config, cache, width) -> ((the window's first position, its

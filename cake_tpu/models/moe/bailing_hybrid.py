@@ -540,6 +540,7 @@ FAMILY = Family(
     # a row's single token walks its live latent pages
     # (cake_mla_decode_attn), counted as cake_decode_attn's are
     kernel_rows=("decode",), window_walk=glm_dsa.window_walk,
+    decode_walk=glm_dsa.decode_walk,
     what="a matrix state a row and head beside the latent page pool",
     refuses=cannot_move(
         "KDA state",

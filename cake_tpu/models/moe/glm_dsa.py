@@ -838,6 +838,26 @@ def window_walk(config, cache, width: int):
     return walk
 
 
+def decode_walk(config, cache):
+    """What the engine counts into a decode or mixed record
+    (Family.decode_walk): a single-token row's position -> (pages,
+    folds) it walks through the page kernel over the layers with no
+    indexer, at the pages a fold that the kernel takes for the call's
+    shapes (ops/mla_attention.decode_block, as attend_dense's
+    attend_pages calls it)."""
+    layers = config.indexer_types.count("dense")
+    geo = config.geometry(config.indexer_types.index("dense"))
+    P, max_pages = cache.k.shape[2], cache.table.shape[1]
+    block = mla.decode_block(geo.heads, cache.k.shape[-1], geo.kv_lora_rank,
+                             P, max_pages, cache.k.dtype.itemsize)
+
+    def walk(pos: int):
+        pages, folds = mla.pages_walk(pos, P, max_pages, block)
+        return layers * pages, layers * folds
+
+    return walk
+
+
 _DECODE_PROGRAMS = make_decode_scan(forward_ragged_latent)
 _MIXED_SAMPLED = make_mixed_sampled(mixed_step_latent)
 
@@ -848,7 +868,8 @@ def _family(name: str, counters: tuple, beside=None, *,
             prefix_needs: str = (
                 "a shared head would need its latent rows and its index "
                 "keys (and a windowed model's ring) mapped together"),
-            windows: Windows = Windows.DISPATCH) -> Family:
+            windows: Windows = Windows.DISPATCH,
+            decode_walk=None) -> Family:
     """impl, kernel_rows: what the step records call the attention, and
     the step kinds whose rows a kernel walks page by page (the host
     counts those pages as it does cake_decode_attn's: the same rule);
@@ -863,7 +884,7 @@ def _family(name: str, counters: tuple, beside=None, *,
         # one window a dispatch (module docstring), so one packed size
         prefill_rows=(1,), windows=windows, beside=beside,
         impl=impl, resolve_attn=_resolve_attn, kernel_rows=kernel_rows,
-        window_walk=window_walk,
+        window_walk=window_walk, decode_walk=decode_walk,
         what="latent attention over the page pool",
         refuses=cannot_move(
             stored,
@@ -893,4 +914,4 @@ DENSE = _family("deepseek_v2", DENSE_COUNTERS, impl="paged-mla-",
                 kernel_rows=("decode",), stored="latent row",
                 prefix_needs=("the prefix path prefills and maps K/V "
                               "pages, not latent rows"),
-                windows=Windows.STEP)
+                windows=Windows.STEP, decode_walk=decode_walk)

@@ -272,6 +272,18 @@ _WINDOW_FOLDS = _m.counter(
     "Softmax updates those pages take a query tile, a block of pages "
     "each (under it, cake_mla_window_pages_total is the pages a fold "
     "shares one accumulator pass among)")
+_MLA_DECODE_PAGES = _m.counter(
+    "cake_mla_decode_pages_total",
+    "Pages the latent page-walking kernel (cake_mla_decode_attn) walks "
+    "for the single-token rows of the decode steps and of the mixed "
+    "steps' dispatches, summed over the layers that run it: position "
+    "// page + 1 a row, counted on the host from the positions it "
+    "dispatches (ops/mla_attention.pages_walk)")
+_MLA_DECODE_FOLDS = _m.counter(
+    "cake_mla_decode_folds_total",
+    "Softmax updates those pages take, a block of pages each (under it, "
+    "cake_mla_decode_pages_total is the pages a fold shares one "
+    "accumulator pass among: ops/mla_attention.decode_block)")
 _MIXED_ATTN_PAGES = _m.counter(
     "cake_mixed_attn_pages_total",
     "KV pages the mixed attention kernel walks a layer, summed over the "
@@ -894,6 +906,12 @@ class StepRecord:
     # the softmax updates they take
     window_pages: Optional[int] = None
     window_folds: Optional[int] = None
+    # a decode or mixed step of a family whose single-token rows walk
+    # their latent pages (cake_mla_decode_attn): the pages those rows
+    # walk over the step's dispatches and layers, and the softmax
+    # updates they take
+    mla_decode_pages: Optional[int] = None
+    mla_decode_folds: Optional[int] = None
     # a mixed step whose family tells the host how it calls the mixed
     # attention kernel: the pages the kernel walks a layer over the
     # step's calls, the entries of their page tables, and the softmax
@@ -1000,6 +1018,9 @@ class StepRecord:
         if self.window_pages is not None:
             out["window_pages"] = self.window_pages
             out["window_folds"] = self.window_folds
+        if self.mla_decode_pages is not None:
+            out["mla_decode_pages"] = self.mla_decode_pages
+            out["mla_decode_folds"] = self.mla_decode_folds
         if self.mixed_attn_pages is not None:
             out["mixed_attn_pages"] = self.mixed_attn_pages
             out["mixed_attn_pages_table"] = self.mixed_attn_pages_table
@@ -1460,6 +1481,8 @@ class StepTelemetry:
                attn_pages_table: Optional[int] = None,
                window_pages: Optional[int] = None,
                window_folds: Optional[int] = None,
+               mla_decode_pages: Optional[int] = None,
+               mla_decode_folds: Optional[int] = None,
                mixed_attn_pages: Optional[int] = None,
                mixed_attn_pages_table: Optional[int] = None,
                mixed_attn_folds: Optional[int] = None,
@@ -1485,6 +1508,10 @@ class StepTelemetry:
         window_folds the pages a tile of the latent window kernel walks
         in a mixed step and the softmax updates they take
         (cake_mla_window_pages_total, cake_mla_window_folds_total);
+        mla_decode_pages / mla_decode_folds the pages the step's
+        single-token rows walk through the latent page-walking kernel
+        and the softmax updates they take (cake_mla_decode_pages_total,
+        cake_mla_decode_folds_total);
         mixed_attn_pages / mixed_attn_pages_table / mixed_attn_folds the
         pages the mixed attention kernel walks a layer over a mixed
         step's calls, their tables' entries and the softmax updates
@@ -1567,6 +1594,8 @@ class StepTelemetry:
                 attn_q_tiles_window=attn_q_tiles_window,
                 attn_pages=attn_pages, attn_pages_table=attn_pages_table,
                 window_pages=window_pages, window_folds=window_folds,
+                mla_decode_pages=mla_decode_pages,
+                mla_decode_folds=mla_decode_folds,
                 mixed_attn_pages=mixed_attn_pages,
                 mixed_attn_pages_table=mixed_attn_pages_table,
                 mixed_attn_folds=mixed_attn_folds,
@@ -1614,6 +1643,9 @@ class StepTelemetry:
         if window_pages is not None:
             _WINDOW_PAGES.inc(window_pages)
             _WINDOW_FOLDS.inc(window_folds)
+        if mla_decode_pages is not None:
+            _MLA_DECODE_PAGES.inc(mla_decode_pages)
+            _MLA_DECODE_FOLDS.inc(mla_decode_folds)
         if mixed_attn_pages is not None:
             _MIXED_ATTN_PAGES.inc(mixed_attn_pages)
             _MIXED_ATTN_PAGES_TABLE.inc(mixed_attn_pages_table)

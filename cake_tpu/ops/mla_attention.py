@@ -36,10 +36,11 @@ PR 30). The selection arrives as a mask (`select_window`, below).
     table: a 9-page ring) into one of two VMEM slots, block k + 1 in
     flight while block k folds, on into the next tile's first block.
     Every tile walks the same pages, so the cursor is arithmetic on
-    (tile, block) and rpa.walk_live_pages does not serve here: its unit
-    is a page (a slot a page, `fold` called a page, a hole skipped a
-    page, a row-to-row cursor in SMEM over a [rows, pages] table),
-    where a fold over B pages needs them side by side in one slot;
+    (tile, block) and the kernel keeps a walk of its own:
+    rpa.walk_live_pages (which has folded blocks of pages side by side
+    in one slot since PR 62, `block=`) carries a row-to-row cursor in
+    SMEM over a [rows, pages] table, where this grid's steps are tiles
+    of ONE row's table;
   * a fold: scores [rows, B * page] in one product, ONE max / exp / sum
     and ONE alpha * acc + p . V (contracted over the B * page keys), so
     the accumulator, four times as wide as a page's scores, is read and
@@ -58,12 +59,20 @@ Alone on the chip at the cells' shapes (my chip run, PR 46; PERF.md
 section 6): 8.86 -> 3.65 ms at 32 pages of 128 heads, 12.8 -> 5.4 at 96
 pages of 64 heads, the 9-page ring of 1,152-wide rows 1.36 -> 1.20.
 
-`attend_pages`. A layer with NO indexer (deepseek_v2) attends every
-visible key, so a row's single token walks the row's live pages where
-they lie (`cake_mla_decode_attn`: ragged_paged_attention.walk_live_pages,
-the GQA decode kernel's walk, over the one latent pool, all heads sharing a
-page): nothing is
-gathered, and a call costs what its live pages cost.
+`attend_pages`. A layer with NO indexer (deepseek_v2, Ling's two MLA
+layers) attends every visible key, so a row's single token walks the
+row's live pages where they lie (`cake_mla_decode_attn`:
+ragged_paged_attention.walk_live_pages, the GQA decode kernel's walk,
+over the one latent pool, all heads sharing a page): nothing is
+gathered, and a call costs what its live pages cost. A trip of the walk
+is a BLOCK of F pages side by side in one ring slot
+(`walk_live_pages(block=F)`, as cake_mixed_attn's; F = `decode_block`
+from the call's shapes, 4 at both cells') and ONE softmax update:
+scores [H, F * page] in one product, one max / exp / sum, one pass over
+the [H, r] float32 accumulator. Alone on the chip (my chip run, PR 64;
+tools/mla_decode_attn_bench.py, PERF.md section 6): 32 rows at ~4.1k
+keys 667 -> 358 us a call at 128 heads (0.64 -> 0.35 us a page), 499 ->
+254 at 32 heads.
 
 `index_scores_rows`. I[b, s] = sum_j w[b, j] relu(qI[b, j] . kI[b, s])
 for one query a row against that row's whole key range, float32: the
@@ -220,42 +229,106 @@ def _pages_fold(q, pool, layer, table, pos, r: int, scale: float):
     return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
 
 
-# The page kernel's ring: a MiB of pages in flight ahead of the one
+# The page kernel's ring: a MiB of pages in flight ahead of the slot
 # that folds, as the GQA decode kernel's (rpa._RING_BYTES_AHEAD, read
 # on the chip there); a latent row has no V pool, so the ring is one.
-def pages_ring_depth(page_bytes: int) -> int:
-    ahead = -(-rpa._RING_BYTES_AHEAD // page_bytes)
+# A slot holds a TRIP's pages (decode_block of them), side by side.
+def pages_ring_depth(slot_bytes: int) -> int:
+    ahead = -(-rpa._RING_BYTES_AHEAD // slot_bytes)
     return 1 + min(max(ahead, 1), rpa._RING_PAGES_AHEAD_MAX)
+
+
+# What decode_block plans with: half of what a kernel is granted
+# unasked (the page kernel states no vmem_limit_bytes); the rest is the
+# compiler's own temporaries.
+_PAGES_VMEM_PLAN = rpa._VMEM_SCOPED_LIMIT // 2
+
+
+def pages_vmem_bytes(H: int, W: int, r: int, page: int, itemsize: int,
+                     block: int) -> int:
+    """Scoped VMEM the page kernel needs at `block` pages a fold: the
+    ring (pages_ring_depth slots of `block` pages), the double-buffered
+    query and result blocks, the float32 accumulator and the value
+    product beside it, and a trip's scores and probabilities ([H,
+    block * page] float32 both, the probabilities again in the pool's
+    type)."""
+    slot = block * page * W * itemsize
+    return (pages_ring_depth(slot) * slot + 2 * H * (W + r) * itemsize
+            + 2 * H * r * 4 + H * block * page * (8 + itemsize))
+
+
+def decode_block(H: int, W: int, r: int, page: int, max_pages: int,
+                 itemsize: int) -> int:
+    """Pages a fold of the page kernel, from the call's shapes alone:
+    the most of (4, 2, 1) whose count (pages_vmem_bytes) fits
+    _PAGES_VMEM_PLAN and such that whole blocks pad the TABLE by an
+    eighth at most (a row's last block is computed whole and masked),
+    as window_tiles' B and rpa.mixed_block. 4 at both cells' shapes
+    (128 heads over a table of 40 pages of 128 x 640 bfloat16: 3.6 MiB;
+    32 heads over one of 76: 2.3). Alone on the chip (PERF.md section
+    6, PR 64; tools/mla_decode_attn_bench.py)."""
+    def fits(f):
+        return (8 * (-max_pages % f) <= max_pages
+                and pages_vmem_bytes(H, W, r, page, itemsize, f)
+                <= _PAGES_VMEM_PLAN)
+    return next(f for f in (4, 2, 1) if f == 1 or fits(f))
+
+
+def pages_walk(pos: int, page: int, max_pages: int, block: int):
+    """(pages, folds) the page kernel walks for a row whose single
+    token sits at pos: its live pages 0 .. pos // page, and the softmax
+    updates they take at `block` pages each; none for a row with no
+    query (pos < 0). The host's count of what `_pages_kernel` does (the
+    step records' mla_decode_pages / mla_decode_folds)."""
+    pages = min(max(pos // page + 1, 0), max_pages)
+    return pages, -(-pages // block)
 
 
 def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
                   buf, sem, cur, acc_ref, *, r: int, scale: float,
-                  depth: int, page: int):
+                  depth: int, page: int, block: int):
     """One grid step: one ROW, its live pages of the layer's latent
     pool walked by `rpa.walk_live_pages` (the GQA decode kernel's walk;
-    one pool, so one copy a page).
+    one pool, so one copy a page), `block` of them a trip and ONE
+    softmax update: scores [H, block * page] in one product, one
+    max / exp / sum, one alpha * acc + p . V contracted over the trip's
+    keys. A row's last trip is computed whole and masked; a place that
+    was not fetched (past the row's last live page, an unmapped hole)
+    keeps what lay there, finite (the ring starts as zeros: it is K and
+    V at once), under a probability of exactly 0.
 
-    q_ref [H, W], o_ref [H, r]; buf [depth, page, W]; sem DMA [depth];
-    cur SMEM int32 [4], the walk's; acc_ref [H, r] f32 (at 128 heads x
-    512 the accumulator is the whole register file)."""
+    q_ref [H, W], o_ref [H, r]; buf [depth, block * page, W]; sem DMA
+    [depth, block]; cur SMEM int32 [4], the walk's; acc_ref [H, r] f32
+    (at 128 heads x 512 the accumulator is the whole register file)."""
     H = q_ref.shape[0]
 
-    def copies(layer, pid, slot, *_trip):
-        return [pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot],
-                                      sem.at[slot])]
+    def copies(layer, pid, slot, _row, _p, f):
+        return [pltpu.make_async_copy(
+            pool_hbm.at[layer, pid], buf.at[slot, pl.ds(f * page, page)],
+            sem.at[slot, f])]
+
+    if block > 1:
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            buf[...] = jnp.zeros_like(buf)
 
     pos, pages = rpa.walk_live_pages(layer_ref, pos_ref, table_ref, cur,
-                                     copies, depth=depth, page_size=page)
+                                     copies, depth=depth, page_size=page,
+                                     block=block)
     acc_ref[...] = jnp.zeros_like(acc_ref)
+    col = lax.broadcasted_iota(jnp.int32, (1, page), 1)
 
-    def fold(j, _found, slot, stats):
+    def fold(j, found, slot, stats):
         m_prev, l_prev = stats
-        kv = buf[slot]                                   # [page, W]
-        s = rpa._dot(q_ref[...], kv, trans_b=True) * scale   # [H, page]
-        # the causal cut: only the last live page has columns past pos
-        visible = (j * page + lax.broadcasted_iota(
-            jnp.int32, (1, page), 1)) <= pos
-        s = jnp.where(visible, s, NEG_INF)
+        kv = buf[slot]                                   # [block * page, W]
+        s = rpa._dot(q_ref[...], kv, trans_b=True) * scale
+        # the causal cut: only the last live page has columns past pos;
+        # a place that was not fetched starts past every position
+        starts = [(j + f) * page if block == 1
+                  else jnp.where(fetched, (j + f) * page, rpa._NEVER)
+                  for f, (_pid, fetched) in enumerate(found)]
+        visible = jnp.concatenate([at + col <= pos for at in starts], axis=1)
+        s = jnp.where(visible, s, NEG_INF)               # [H, block * page]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -263,7 +336,10 @@ def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
             p.astype(kv.dtype), kv[:, :r], trans_b=False)
         return m_new, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
-    _, l = pages(fold, (jnp.full((H, 1), NEG_INF, jnp.float32),
+    # (the running maximum starts ABOVE a masked score: a trip of holes
+    # alone leaves it there, and exp(NEG_INF - NEG_INF / 2) is exactly
+    # 0 where exp(NEG_INF - NEG_INF) would be 1)
+    _, l = pages(fold, (jnp.full((H, 1), NEG_INF / 2, jnp.float32),
                         jnp.zeros((H, 1), jnp.float32)))
     # a row that folded nothing (idle, or an all-unmapped table) has
     # l == 0 and a zero accumulator: zeros, as the fold gives
@@ -275,11 +351,12 @@ def _pages_kernel(layer_ref, pos_ref, table_ref, q_ref, pool_hbm, o_ref,
 def _pages_pallas(q, pool, layer, table, pos, *, r: int, scale: float,
                   interpret: bool):
     B, H, W = q.shape
-    page = pool.shape[2]
-    depth = pages_ring_depth(page * W * pool.dtype.itemsize)
+    page, itemsize = pool.shape[2], pool.dtype.itemsize
+    block = decode_block(H, W, r, page, table.shape[1], itemsize)
+    depth = pages_ring_depth(block * page * W * itemsize)
     return pl.pallas_call(
         functools.partial(_pages_kernel, r=r, scale=scale, depth=depth,
-                          page=page),
+                          page=page, block=block),
         name="cake_mla_decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -287,8 +364,8 @@ def _pages_pallas(q, pool, layer, table, pos, *, r: int, scale: float,
             in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, H, r), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((depth, page, W), pool.dtype),
-                            pltpu.SemaphoreType.DMA((depth,)),
+            scratch_shapes=[pltpu.VMEM((depth, block * page, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((depth, block)),
                             pltpu.SMEM((4,), jnp.int32),
                             pltpu.VMEM((H, r), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, r), q.dtype),
@@ -311,9 +388,11 @@ def attend_pages(q, pool, layer, table, pos, r: int, scale: float,
     dispatch): it takes no trip and gets zeros. Returns [B, H, r].
 
     impl "pallas": `cake_mla_decode_attn`, grid (rows,), a dynamic
-    count of trips a row, the kernel's own ring of page copies; per
-    page [H, W] . [W, page] scores and [H, page] . [page, r] values, a
-    float32 online softmax. impl "fold": the same recurrence in XLA."""
+    count of trips a row, the kernel's own ring of page copies; a trip
+    is decode_block pages (F): [H, W] . [W, F * page] scores and [H,
+    F * page] . [F * page, r] values, a float32 online softmax, one
+    update a trip. impl "fold": the same recurrence in XLA, a page a
+    step."""
     if impl == "pallas":
         if interpret is None:
             interpret = not rpa._on_tpu()
@@ -411,9 +490,8 @@ def window_walk(last_pos: int, page: int, max_pages: int, block: int):
     window that ends at last_pos: the row's live pages, and the softmax
     updates they take at `block` pages each. The host's count of what
     `_window_kernel` does (the step records' window_pages /
-    window_folds)."""
-    pages = min(max(last_pos // page + 1, 0), max_pages)
-    return pages, -(-pages // block)
+    window_folds): what a single-token row at last_pos walks."""
+    return pages_walk(last_pos, page, max_pages, block)
 
 
 def _spread(s, rows, heads: int):

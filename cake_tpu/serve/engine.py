@@ -2631,6 +2631,11 @@ class InferenceEngine:
         self._window_walk = (
             family.window_walk(self.config, self.cache, self._mixed_chunk)
             if family.window_walk is not None else None)
+        # its page kernel's: a single-token row's position -> (pages,
+        # folds)
+        self._decode_walk = (
+            family.decode_walk(self.config, self.cache)
+            if family.decode_walk is not None else None)
         self._mixed_attn_walk = self._mixed_walk_of(family)
         if family.beside is not None:
             what, gauge = family.beside
@@ -4665,26 +4670,40 @@ class InferenceEngine:
         return {"mixed_attn_pages": pages, "mixed_attn_pages_table": table,
                 "mixed_attn_folds": folds}
 
+    def _mla_decode_pages(self, positions) -> dict:
+        """A record's mla_decode_pages / mla_decode_folds (obs/steps):
+        what its single-token rows, at `positions`, walk through the
+        latent page kernel over the layers that run it, and the softmax
+        updates those pages take (the family's decode_walk). Nothing
+        where the family has no such kernel."""
+        if self._decode_walk is None:
+            return {}
+        walked = [self._decode_walk(int(pos)) for pos in positions]
+        return {"mla_decode_pages": sum(w[0] for w in walked),
+                "mla_decode_folds": sum(w[1] for w in walked)}
+
     def _attn_pages(self, steps: List[tuple]) -> dict:
         """A decode record's attn_pages / attn_pages_table (obs/steps):
         the KV pages the decode attention kernel streams a layer for
         the active rows, counted from the positions dispatched as the
         kernel counts its trips (position // page + 1), and the entries
         of the page table, which is what a grid over (slot, page)
-        stepped through. steps: (position of its first token, tokens)
-        for each active row; a scan's record sums its steps. Nothing
-        where the decode rows do not go through that kernel (a dense
-        cache; the family's kernel_rows)."""
+        stepped through; beside them what a latent family's page kernel
+        walks (_mla_decode_pages). steps: (position of its first token,
+        tokens) for each active row; a scan's record sums its steps.
+        Nothing where the decode rows do not go through that kernel (a
+        dense cache; the family's kernel_rows)."""
         if (not self.paged or not steps
                 or "decode" not in self._family.kernel_rows):
             return {}
         P = self.cache.page_size
         last = self.max_seq_len - 1
-        return {"attn_pages": sum(min(pos + i, last) // P + 1
-                                  for pos, n in steps for i in range(n)),
+        at = [min(pos + i, last) for pos, n in steps for i in range(n)]
+        return {"attn_pages": sum(pos // P + 1 for pos in at),
                 "attn_pages_table": (max(n for _pos, n in steps)
                                      * self.max_slots
-                                     * self.cache.max_pages)}
+                                     * self.cache.max_pages),
+                **self._mla_decode_pages(at)}
 
     def _mixed_groups(self, qlen) -> List[np.ndarray]:
         """The rows of a mixed step ([B] bool masks) by dispatch: slot
@@ -4873,6 +4892,8 @@ class InferenceEngine:
                 tiles = {**self._attn_q_tiles(qlen,
                                               decode_rows + chunk_rows),
                          **self._window_pages(pos, qlen, groups),
+                         **self._mla_decode_pages(
+                             at[slot] for slot in np.flatnonzero(qlen == 1)),
                          **self._mixed_attn_pages(at, qlen, groups)}
             with span("dispatch"):
                 # every layer runs over the step's tokens packed out of

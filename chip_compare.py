@@ -917,6 +917,65 @@ def main() -> int:
 # -- deepseek_v2 ---------------------------------------------------------------
 
 
+def probe_rel(a, b) -> float:
+    """|a - b| / |b|, the probes' reading."""
+    return float(np.sqrt(np.sum(np.square(a - b))
+                         / max(np.sum(np.square(b)), 1e-300)))
+
+
+def latent_pages_probe(pool, table, row: int, n_keys: int, geo, attn: str,
+                       seed: int):
+    """The page-walking kernel ITSELF (`cake_mla_decode_attn` under
+    attn "pallas") against exact attention over the pages the served
+    path wrote: layer 0 of the latent pool, `row`'s first n_keys keys,
+    queries drawn so that the scores' standard deviation is
+    DSV2_PROBE_SPREAD, every other row idle. -> ({"served",
+    "bf16_softmax"}: relative errors against float32 softmax attention,
+    the second that of exact attention whose scores and probabilities
+    are rounded to bfloat16, which must read past the limit; the row's
+    keys [S, row width]; their root mean square norm)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import mla_attention as mla
+
+    table = np.asarray(table)
+    B, per_row = table.shape
+    page, row_w, R = pool.shape[2], pool.shape[-1], geo.kv_lora_rank
+    keys = pool[0, jnp.asarray(table[row, :-(-n_keys // page)])].reshape(
+        -1, row_w)[:n_keys]                                     # [S, row]
+    norm = float(jnp.sqrt(jnp.mean(jnp.sum(jnp.square(
+        keys.astype(jnp.float32)), axis=-1))))
+    q = (jax.random.normal(jax.random.PRNGKey(seed + 1),
+                           (B, geo.heads, row_w), jnp.float32)
+         * (DSV2_PROBE_SPREAD / (norm * geo.softmax_scale))
+         ).astype(pool.dtype)
+    probe_pos = np.full(B, -1, np.int32)
+    probe_pos[row] = n_keys - 1
+    probe_table = np.full((B, per_row), -1, np.int32)
+    probe_table[row] = table[row]
+
+    def exact(scores_dtype):
+        """Softmax attention of the row's queries over its keys, float32
+        at `highest` but for the scores and probabilities' type."""
+        with jax.default_matmul_precision("highest"):
+            kf, qf = keys.astype(jnp.float32), q[row].astype(jnp.float32)
+            s = ((qf @ kf.T) * geo.softmax_scale).astype(scores_dtype)
+            p = jax.nn.softmax(s, axis=-1).astype(jnp.float32)
+            return np.asarray(p @ kf[:, :R], np.float64)
+
+    served_attn = np.asarray(mla.attend_pages(
+        q, pool, 0, jnp.asarray(probe_table), jnp.asarray(probe_pos), R,
+        geo.softmax_scale, impl=attn)[row], np.float64)
+    want_attn = exact(jnp.float32)
+
+    probe = {"served": probe_rel(served_attn, want_attn),
+             "bf16_softmax": probe_rel(exact(jnp.bfloat16), want_attn)}
+    say(f"probe: kernel {probe['served']:.3e}, exact attention with a "
+        f"bfloat16 softmax {probe['bf16_softmax']:.3e}")
+    return probe, keys, norm
+
+
 def compare_deepseek_v2(engine, cell, args, t_start) -> int:
     """The comparison above for latent attention over every live page
     and group-limited routing: the engine's own mixed and decode trunks
@@ -1050,41 +1109,8 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
     geo = cfg.geometry(0)
     R, row_w = geo.kv_lora_rank, cache.k.shape[-1]
     n_keys = len(sequences[0])
-    keys = cache.k[0, jnp.asarray(table[0, :-(-n_keys // page)])].reshape(
-        -1, row_w)[:n_keys]                                     # [S, row]
-    norm = float(jnp.sqrt(jnp.mean(jnp.sum(jnp.square(
-        keys.astype(jnp.float32)), axis=-1))))
-    q = (jax.random.normal(jax.random.PRNGKey(args.seed + 1),
-                           (B, geo.heads, row_w), jnp.float32)
-         * (DSV2_PROBE_SPREAD / (norm * geo.softmax_scale))
-         ).astype(cache.k.dtype)
-    probe_pos = np.full(B, -1, np.int32)
-    probe_pos[0] = n_keys - 1
-    probe_table = np.full((B, per_row), -1, np.int32)
-    probe_table[0] = table[0]
-
-    def exact(scores_dtype):
-        """Softmax attention of row 0's queries over its keys, float32
-        at `highest` but for the scores and probabilities' type."""
-        with jax.default_matmul_precision("highest"):
-            kf, qf = keys.astype(jnp.float32), q[0].astype(jnp.float32)
-            s = ((qf @ kf.T) * geo.softmax_scale).astype(scores_dtype)
-            p = jax.nn.softmax(s, axis=-1).astype(jnp.float32)
-            return np.asarray(p @ kf[:, :R], np.float64)
-
-    served_attn = np.asarray(mla.attend_pages(
-        q, cache.k, 0, jnp.asarray(probe_table), jnp.asarray(probe_pos), R,
-        geo.softmax_scale, impl=attn)[0], np.float64)
-    want_attn = exact(jnp.float32)
-
-    def rel(a, b):
-        return float(np.sqrt(np.sum(np.square(a - b))
-                             / max(np.sum(np.square(b)), 1e-300)))
-
-    probe = {"served": rel(served_attn, want_attn),
-             "bf16_softmax": rel(exact(jnp.bfloat16), want_attn)}
-    say(f"probe: kernel {probe['served']:.3e}, exact attention with a "
-        f"bfloat16 softmax {probe['bf16_softmax']:.3e}")
+    probe, keys, norm = latent_pages_probe(cache.k, table, 0, n_keys, geo,
+                                           attn, args.seed)
 
     # -- the same for the WINDOW pass (cake_mla_window_attn under
     # causality: all of prefill): the row's last C positions as
@@ -1119,8 +1145,8 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
             for i in range(0, Cw, step)])
 
     want_win = exact_window(jnp.float32)
-    probe_window = {"served": rel(served_win, want_win),
-                    "bf16_softmax": rel(exact_window(jnp.bfloat16),
+    probe_window = {"served": probe_rel(served_win, want_win),
+                    "bf16_softmax": probe_rel(exact_window(jnp.bfloat16),
                                         want_win)}
     say(f"probe of the window pass: kernel {probe_window['served']:.3e}, "
         f"exact attention with a bfloat16 softmax "
@@ -3069,6 +3095,15 @@ def compare_ling(engine, cell, args, t_start) -> int:
         engine, params, cache, trunk_steps(bh, engine), jobs, sequences,
         prompts, compared, rng, waits_for={twin: opener}, at_end=keep_state)
 
+    # -- the probe: the page-walking kernel itself against exact
+    # attention over the pages the served path wrote (the first latent
+    # layer, the longest job's row), as DeepSeek-V2's and under its limit
+    probe, _keys, _norm = latent_pages_probe(
+        cache.k, cache.table, jobs[0][0], len(sequences[0]),
+        cfg.geometry(cfg.latent_layers[0]), engine.attn_impl["mixed"],
+        args.seed)
+    del _keys
+
     # -- the reference: the served weights leave the device, then come
     # back dequantized one layer at a time -----------------------------------
     del cache
@@ -3203,12 +3238,14 @@ def compare_ling(engine, cell, args, t_start) -> int:
     served["reuse"] = apart(lambda p: got[second][p], got[twin])
     served["reuse_decode"] = apart(lambda p: got[second][p], got[twin],
                                    decode=True)
+    served["probe"] = probe["served"]
     expected = sum(
         len({q for q in range(p + n_decode)
              if q >= p - last or q < LING_START or C <= q < C + LING_EDGE})
         for p in prompts[:twin])
     result = {
         "served": served, "expected_positions": expected, "tol": LING_TOL,
+        "probe": dict(probe, limit=DSV2_TOL["probe"]),
         "agree_same_input_floor": LING_AGREE, "seed": args.seed,
         "jobs": [list(j) for j in jobs], "rows_a_step": B, "steps": steps,
         "attention": impl, "device": jax.devices()[0].device_kind,
@@ -3219,7 +3256,8 @@ def compare_ling(engine, cell, args, t_start) -> int:
             states[i][0])))), 5) for i in plain],
     }
     ok = (served["positions"] == expected and passes(served)
-          and state_dtype == "float32")
+          and state_dtype == "float32"
+          and probe["served"] < DSV2_TOL["probe"] < probe["bf16_softmax"])
     if not ok:
         say("FAILED: the served path is outside the tolerance")
 

@@ -720,6 +720,25 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     assert eng._mixed_buckets == (16,) and not eng._prefix_capable
 
 
+def test_records_count_the_two_latent_layers_page_walk(engine_run):
+    """mla_decode_pages / mla_decode_folds (cake_mla_decode_attn): a
+    decode record's rows over the TWO latent layers of six, the GQA
+    decode kernel's count of a layer's pages twice; the mixed records'
+    single-token rows likewise."""
+    *_, records, _moved, eng = engine_run
+    decode = [r for r in records if r["kind"] == "decode"]
+    assert decode
+    for r in decode:
+        assert r["mla_decode_pages"] == 2 * r["attn_pages"]
+        assert 0 < r["mla_decode_folds"] <= r["mla_decode_pages"]
+    assert any(r.get("mla_decode_pages")
+               for r in records if r["kind"] == "mixed")
+    # a table of 15 pages: blocks of 4 would pad it by one page in 15
+    assert eng.cache.max_pages == 15
+    assert eng._mla_decode_pages([40, 119]) == {
+        "mla_decode_pages": 2 * (6 + 15), "mla_decode_folds": 2 * (2 + 4)}
+
+
 def test_metrics_carry_the_state(engine_run):
     from cake_tpu.obs import steps as obs_steps
     *_, eng = engine_run

@@ -317,8 +317,149 @@ def test_page_walk_starts_no_trip_for_an_idle_call():
 
 
 def test_ring_depth_keeps_a_mebibyte_ahead():
+    """Slots of F pages: the one that folds and a MiB in flight."""
     assert mla.pages_ring_depth(128 * 640 * 2) == 8       # the cell's page
+    assert mla.pages_ring_depth(4 * 128 * 640 * 2) == 3   # its trip of four
+    assert mla.pages_ring_depth(2 * 128 * 640 * 2) == 5
     assert mla.pages_ring_depth(8 * 24 * 4) == 17         # a test's
+
+
+def _walked_pages(block, q, pool, layer, table, pos, r, monkeypatch):
+    """The interpreted kernel at `block` pages a fold (the rule
+    replaced; the jitted wrapper caches on its static arguments, not on
+    the module's globals, so the call goes under it)."""
+    monkeypatch.setattr(mla, "decode_block", lambda *shapes: block)
+    return mla._pages_pallas.__wrapped__(
+        q, pool, jnp.int32(layer), jnp.asarray(table), jnp.asarray(pos),
+        r=r, scale=0.3, interpret=True)
+
+
+@pytest.mark.parametrize("block", [1, 2, 4])
+def test_page_walk_folds_a_block_of_pages_an_update(block, monkeypatch):
+    """_pool_case's rows at 1, 2 and 4 pages a fold, over a table of 6:
+    a hole inside a block, an idle row, a last page cut mid-page, live
+    counts no block divides (3, 5; at 4 a fold the full table's last
+    trip passes the table's end), and two rows whose FIRST trip is
+    holes alone (nothing visible yet: a probability of exactly 0, not
+    exp(0)), one of them with nothing but holes."""
+    pool, q, table, pos, r = _pool_case()
+    table[2, :5] = [-1, -1, -1, -1, 3]
+    table[3, :2] = -1
+    pos[2] = 37
+    got = _walked_pages(block, q, pool, 1, table, pos, r, monkeypatch)
+    want = _dense_attention(pool, q, table, pos, r, 1, 0.3)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert np.asarray(got[2]).any() and not np.asarray(got[3]).any()
+    idle = np.full(5, -1, np.int32)
+    assert not np.asarray(_walked_pages(block, q, pool, 1, table, idle, r,
+                                        monkeypatch)).any()
+
+
+@pytest.mark.parametrize("block", [1, 2, 4])
+def test_page_walk_kernel_is_its_fold_in_bfloat16_at_every_block(
+        block, monkeypatch):
+    pool, q, table, pos, r = _pool_case(jnp.bfloat16)
+    kernel = _walked_pages(block, q, pool, 0, table, pos, r, monkeypatch)
+    fold = mla.attend_pages(q, pool, 0, jnp.asarray(table), jnp.asarray(pos),
+                            r, 0.3, "fold")
+    np.testing.assert_allclose(kernel.astype(jnp.float32),
+                               fold.astype(jnp.float32), atol=2e-2)
+
+
+def test_decode_block_follows_bytes_not_names():
+    """Pages a fold at the two cells' shapes (bfloat16, 128-token pages
+    of a 640-wide row, 512-wide values): four, at a count well under
+    what a kernel is granted unasked; fewer where whole blocks would pad
+    a short table by more than an eighth, or where a trip's ring,
+    scores and accumulator pass the plan."""
+    from cake_tpu.ops import ragged_paged_attention as rpa
+    for H, pages in ((128, 40), (32, 76)):      # DeepSeek-V2, Ling-3.0
+        assert mla.decode_block(H, 640, 512, 128, pages, 2) == 4
+        assert (mla.pages_vmem_bytes(H, 640, 512, 128, 2, 4)
+                <= mla._PAGES_VMEM_PLAN == rpa._VMEM_SCOPED_LIMIT // 2)
+    assert mla.pages_vmem_bytes(128, 640, 512, 128, 2, 4) == 3_735_552
+    assert mla.decode_block(128, 640, 512, 128, 9, 2) == 2
+    assert mla.decode_block(128, 640, 512, 128, 5, 2) == 1
+    assert mla.decode_block(128, 640, 512, 128, 2, 2) == 2
+    # pages of 512 tokens: a ring of two trips of four is 5 MiB and
+    # their scores and probabilities 2.5, 8.6 MiB in all
+    assert mla.decode_block(128, 640, 512, 512, 40, 2) == 2
+    assert mla.decode_block(4, 24, 16, 8, 6, 4) == 2     # _pool_case's
+    assert mla.pages_walk(4095, 128, 40, 4) == (32, 8)
+    assert mla.pages_walk(4096, 128, 40, 4) == (33, 9)
+    assert mla.pages_walk(9000, 128, 40, 4) == (40, 10)
+    assert mla.pages_walk(-1, 128, 40, 4) == (0, 0)
+
+
+@pytest.mark.parametrize("block", [1, 2, 4])
+def test_host_counts_what_the_page_kernel_walks(monkeypatch, block):
+    """pages_walk (what the engine writes into a record) against the
+    interpreted kernel's own trips: every page copy it starts and every
+    score product it runs, counted by callbacks from inside the
+    kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+    seen = {"copies": 0, "folds": 0}
+
+    def tick(key):
+        jax.debug.callback(lambda: seen.__setitem__(key, seen[key] + 1))
+
+    class Copy:
+        def __init__(self, *args):
+            self.copy = make_copy(*args)
+
+        def start(self):
+            tick("copies")
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    def dot(a, b, *, trans_b):
+        if trans_b:
+            tick("folds")
+        return rpa_dot(a, b, trans_b=trans_b)
+
+    make_copy, rpa_dot = pltpu.make_async_copy, mla.rpa._dot
+    monkeypatch.setattr(pltpu, "make_async_copy", Copy)
+    monkeypatch.setattr(mla.rpa, "_dot", dot)
+    pool, q, table, pos, r = _pool_case()
+    table[1, 1] = 9              # the host knows no hole: none here
+    _walked_pages(block, q, pool, 1, table, pos, r, monkeypatch)
+    jax.effects_barrier()
+    walked = [mla.pages_walk(int(p), 8, 6, block) for p in pos]
+    assert [w[0] for w in walked] == [3, 5, 0, 2, 6]
+    assert seen == {"copies": sum(w[0] for w in walked),
+                    "folds": sum(w[1] for w in walked)}
+
+
+def test_the_bench_tool_rehearses_and_reads_the_cells_shapes(capsys):
+    """tools/mla_decode_attn_bench.py at tiny widths: one JSON line,
+    the kernel at 1, 2 and 4 pages a fold against F = 1's result (that
+    against the XLA fold) with what each walks, the rule left as it
+    was; and the two cells' call shapes as it reads them from their
+    files."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(ROOT).resolve() / "tools" / "mla_decode_attn_bench.py"
+    spec = importlib.util.spec_from_file_location("mla_decode_attn_bench",
+                                                  path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    rule = mla.decode_block
+    assert tool.main(["--rehearse", "--calls", "2"]) == 0
+    assert mla.decode_block is rule
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert [c["block"] for c in line["cases"]] == [1, 2, 4]
+    for case in line["cases"]:
+        assert case["finite"] and case["err"] <= 1e-4
+        assert case["pages"] == 19 and case["us"] > 0
+    assert [c["folds"] for c in line["cases"]] == [19, 11, 7]
+    shapes = {name: (c["H"], c["W"], c["r"], c["page"], c["table"],
+                     len(c["pos"]), int((c["pos"] >= 0).sum()))
+              for name, c in tool.cases(False).items()}
+    assert shapes == {"dsv2": (128, 640, 512, 128, 40, 32, 30),
+                      "ling3": (32, 640, 512, 128, 76, 32, 32)}
 
 
 # -- the window kernel -------------------------------------------------------
@@ -834,6 +975,40 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     assert all(0 < r["attn_pages"] <= r["attn_pages_table"] for r in decode)
     assert all(v > 0 for v in moved.values()), moved
     assert eng._mixed_buckets == (32,) and not eng._prefix_capable
+
+
+def test_records_count_the_page_kernels_walk(engine_run):
+    """mla_decode_pages / mla_decode_folds: a decode record's rows and
+    a mixed record's single-token rows, position // page + 1 pages each
+    over 4 layers, at the pages a fold the kernel takes for the
+    engine's shapes; on /metrics as cake_mla_decode_pages_total /
+    cake_mla_decode_folds_total."""
+    from cake_tpu.obs import steps as obs_steps
+    *_, records, _moved, eng = engine_run
+    geo, pool = eng.config.geometry(0), eng.cache.k
+    block = mla.decode_block(geo.heads, pool.shape[-1], geo.kv_lora_rank,
+                             PAGE, eng.cache.max_pages, pool.dtype.itemsize)
+    assert block == 4 and eng.cache.max_pages == 16
+    decode = [r for r in records if r["kind"] == "decode"]
+    for r in decode:
+        # one layer's pages are the GQA decode kernel's count
+        assert r["mla_decode_pages"] == 4 * r["attn_pages"]
+        assert (r["mla_decode_pages"] >= r["mla_decode_folds"]
+                >= r["mla_decode_pages"] / block)
+    mixed = [r for r in records if r["kind"] == "mixed"]
+    assert all("mla_decode_pages" in r for r in mixed)
+    assert any(r["mla_decode_pages"] for r in mixed)     # rows beside
+    assert eng._mla_decode_pages([40, 7, 127]) == {
+        "mla_decode_pages": 4 * (6 + 1 + 16),
+        "mla_decode_folds": 4 * (2 + 1 + 4)}
+    assert eng._mla_decode_pages([]) == {"mla_decode_pages": 0,
+                                         "mla_decode_folds": 0}
+    before = (obs_steps._MLA_DECODE_PAGES.value,
+              obs_steps._MLA_DECODE_FOLDS.value)
+    eng.flight.record("decode", rows=1, tokens=1, wall_s=0.01,
+                      **eng._mla_decode_pages([40]))
+    assert (obs_steps._MLA_DECODE_PAGES.value - before[0],
+            obs_steps._MLA_DECODE_FOLDS.value - before[1]) == (24, 8)
 
 
 def test_mixed_records_count_the_window_kernels_walk(engine_run):

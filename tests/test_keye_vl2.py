@@ -658,12 +658,15 @@ LOWERED_BEFORE = {
         "301c236cb11c4b166de4a3d4f4874d1a1442de2714a7c0f51fce5bdae8791843",
     ("bailing_hybrid", "decode", "fold"):
         "41209b0bd73b7a679d94731b52f785a93ca9e7d37f7474f429629e69170c0511",
+    # (Ling's two pallas programs hold cake_mla_decode_attn, which PR 64
+    # MEANT to move: a block of pages a softmax update. Taken on PR 64's
+    # tree; its fold programs, and the thirty others, are the parent's.)
     ("bailing_hybrid", "decode", "pallas"):
-        "0b341c19342291aea597f6556ae0bb7f569ea6cf053ea450ba5a01462eebb438",
+        "65040a8411aed0443cff0b9ddcfbbee523bf36d4bad3a22b19113a9bdb73716b",
     ("bailing_hybrid", "mixed", "fold"):
         "e0954fcb46f5d0fdf9c5010bb6a47f0033479df54127c6abd45f6dd72e8783e9",
     ("bailing_hybrid", "mixed", "pallas"):
-        "6f75ac21e1ded3136e980159849a4a383765f4415892fdf986db0322d95a79da",
+        "81164b167ddf422391557614f623b09b7a7e2a47e92e91711acbccea945b63ac",
     ("zaya", "decode", "fold"):
         "3c80cd2e9ef459be219508b6b178759f26358fdd3dd7ff814f63caed009d53cb",
     ("zaya", "decode", "pallas"):

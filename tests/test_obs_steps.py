@@ -71,6 +71,9 @@ def test_flight_recorder_ring_bounds():
     ("mixed", {}), ("decode", {}),
     ("decode", {"attn_pages": 46, "attn_pages_table": 256}),
     ("mixed", {"window_pages": 270, "window_folds": 75}),
+    ("decode", {"mla_decode_pages": 15840, "mla_decode_folds": 4320}),
+    ("mixed", {"window_pages": 270, "window_folds": 75,
+               "mla_decode_pages": 495, "mla_decode_folds": 135}),
     ("mixed", {"mixed_attn_pages": 149, "mixed_attn_pages_table": 256,
                "mixed_attn_folds": 45})])
 def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
@@ -80,10 +83,12 @@ def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
     decode step whose rows go through the decode kernel the pages it
     streams and the entries of its page table, a mixed step of a latent
     family the pages its window kernel walks and the softmax updates
-    they take, a mixed step whose family tells the host its call of the
+    they take, a decode or mixed step of a family whose single-token
+    rows walk their latent pages the pages those walk and their softmax
+    updates, a mixed step whose family tells the host its call of the
     mixed kernel the pages that walks, its tables' entries and the
     softmax updates; the record of any other step has none of the keys,
-    and the nine /metrics series move with the records that have
+    and the eleven /metrics series move with the records that have
     them."""
     series = {"cake_mixed_attn_q_tiles_total": "attn_q_tiles",
               "cake_mixed_attn_q_tiles_window_total": "attn_q_tiles_window",
@@ -91,6 +96,8 @@ def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
               "cake_decode_attn_pages_table_total": "attn_pages_table",
               "cake_mla_window_pages_total": "window_pages",
               "cake_mla_window_folds_total": "window_folds",
+              "cake_mla_decode_pages_total": "mla_decode_pages",
+              "cake_mla_decode_folds_total": "mla_decode_folds",
               "cake_mixed_attn_pages_total": "mixed_attn_pages",
               "cake_mixed_attn_pages_table_total": "mixed_attn_pages_table",
               "cake_mixed_attn_folds_total": "mixed_attn_folds"}
@@ -104,7 +111,8 @@ def test_attention_tile_counts_ride_the_records_that_carry_them(kind,
     before = read()
     rec = st.record(kind, rows=16, tokens=16, wall_s=0.01, **tiles)
     got = {k: v for k, v in rec.to_dict().items()
-           if k.startswith(("attn_", "window_", "mixed_attn_"))}
+           if k.startswith(("attn_", "window_", "mixed_attn_",
+                            "mla_decode_"))}
     assert got == tiles
     assert [b - a for a, b in zip(before, read())] == [
         tiles.get(key, 0) for key in series.values()]

@@ -118,13 +118,17 @@ ENTRY %main (w: s8[32,4096,4096]) -> s8[32,4096,4096] {
     ]
 
 
-def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
-                                                              one_chip):
+@pytest.mark.parametrize("heads,table_pages", [(128, 40), (32, 76)],
+                         ids=["dsv2", "ling3"])
+def test_latent_page_walk_kernel_compiles_at_the_cells_widths(
+        tool, one_chip, heads, table_pages):
     """`cake_mla_decode_attn` at dsv2.code-closed's shapes (32 rows, 128
     heads over a 640-wide stored row, 512-wide values, pages of 128,
-    40 pages a row) goes through Mosaic: its ring of eight 160 KiB
-    pages, the [128, 512] float32 accumulator and the dynamic trip
-    count fit a v5e core's scoped VMEM and SMEM."""
+    40 pages a row) and at ling3.longreply-closed's (32 heads, 76 pages
+    a row) goes through Mosaic at the four pages a fold its rule takes
+    there: its ring of three slots of four 160 KiB pages, the [128,
+    512] float32 scores and accumulator and the dynamic trip count fit
+    a v5e core's scoped VMEM and SMEM."""
     import jax.numpy as jnp
 
     from cake_tpu.ops import mla_attention as mla
@@ -141,14 +145,17 @@ def test_latent_page_walk_kernel_compiles_at_the_cells_widths(tool,
     try:
         with jax.default_matmul_precision("default"):
             compiled = jax.jit(call).lower(
-                sds((32, 128, 640), jnp.bfloat16),
+                sds((32, heads, 640), jnp.bfloat16),
                 sds((2, 64, 128, 640), jnp.bfloat16),
-                sds((32, 40), jnp.int32), sds((32,), jnp.int32)).compile()
+                sds((32, table_pages), jnp.int32),
+                sds((32,), jnp.int32)).compile()
     finally:
         rpa._on_tpu = on_tpu
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "cake_mla_decode_attn" in hlo
-    assert mla.pages_ring_depth(128 * 640 * 2) * 128 * 640 * 2 < 2 * 2**20
+    assert mla.decode_block(heads, 640, 512, 128, table_pages, 2) == 4
+    slot = 4 * 128 * 640 * 2
+    assert mla.pages_ring_depth(slot) * slot < 2 * 2**20
 
 
 @pytest.mark.parametrize("kernel", ["decode", "mixed"])
