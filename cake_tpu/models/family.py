@@ -126,6 +126,10 @@ class Family:
     # the host counts them into the mixed records. None: its rows go as
     # they are (kernel_rows), or the host cannot know the call
     mixed_attn_walk: Optional[Callable] = None
+    # says(config) -> one sentence for the start-up log on what of the
+    # published model this process holds (a share of its layers or
+    # experts); None: nothing to say
+    says: Optional[Callable] = None
     # what its rows hold (the message's head) and cannot move yet:
     # option -> reason (cannot_move)
     what: str = ""

@@ -74,6 +74,13 @@ MODEL_TYPES = {
               "a head and rotated, a gate a K/V head, a matrix state a row "
               "and layer that a GQA group shares beside a page pool of NO "
               "layers; a dense SwiGLU, an untied head (paged engine)",
+    "longcat_flash": "shortcut-connected layers: two latent attentions "
+                     "over every visible key and two dense SwiGLUs a "
+                     "layer, and one routed MoE that reads the first "
+                     "FFN's input and returns after the second; identity "
+                     "(zero-compute) experts in the router's width, a "
+                     "choice bias, weights not renormalised (paged "
+                     "engine)",
 }
 _MOE_TYPES = ("mixtral", "olmoe")
 
@@ -119,6 +126,9 @@ def load_config_dict(raw: dict) -> "LlamaConfig":
     if model_type == "brumby":
         from cake_tpu.models.moe.config import BrumbyConfig
         return BrumbyConfig.from_hf_dict(raw)
+    if model_type == "longcat_flash":
+        from cake_tpu.models.moe.config import LongcatFlashConfig
+        return LongcatFlashConfig.from_hf_dict(raw)
     if model_type in _MOE_TYPES:
         from cake_tpu.models.moe import MoEConfig
         return MoEConfig.from_hf_dict(raw)

@@ -656,6 +656,155 @@ class DeepseekV2Config(GlmMoeDsaConfig):
         return cls(**base)
 
 
+# config.json keys of model_type longcat_flash whose one served value is
+# the published one: anything else is refused by the key's name
+_LONGCAT_ONLY = {
+    "zero_expert_type": "identity", "attention_method": "MLA",
+    "attention_bias": False, "rope_scaling": None, "router_bias": False,
+    "norm_topk_prob": False, "hidden_act": "silu",
+}
+
+
+@dataclass(frozen=True)
+class LongcatFlashConfig(GlmMoeDsaConfig):
+    """LongCat-Flash (`model_type: longcat_flash`): a published layer is
+    TWO sublayers, each latent attention over every visible key (the two
+    normed latents rescaled where `mla_scale_q_lora` / `mla_scale_kv_lora`
+    say so) and a dense SwiGLU, and ONE routed MoE that reads the first
+    sublayer's FFN input and is added after the second sublayer's FFN
+    (the shortcut). The router is `n_routed_experts_total +
+    zero_expert_num` wide: an index past the routed experts is an
+    identity expert, which returns the MoE's input and holds no matrix.
+    Softmax over the router's whole width, a choice bias, `moe_topk` a
+    token, the weights not renormalised, times `routed_scaling_factor`.
+    The equations are in models/reference/longcat_flash.py; the served
+    path is models/moe/glm_dsa.py's trunk.
+
+    Here `num_hidden_layers` counts SUBLAYERS (2 x config.json
+    `num_layers`): each has its attention leaves, its latent pool layer
+    and its dense FFN, `indexer_types` holds "dense" in every one and
+    `mlp_layer_types` ("shortcut", "dense") in turn: a "shortcut"
+    sublayer also holds its layer's router and experts.
+    `intermediate_size` is `ffn_hidden_size`, `moe_intermediate_size`
+    `expert_ffn_hidden_size`, `num_experts_per_tok` `moe_topk`."""
+
+    _family = "cake_tpu.models.moe.glm_dsa:SHORTCUT"
+
+    n_shared_experts: int = 0
+    zero_expert_num: int = 256
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+
+    @property
+    def num_layers(self) -> int:
+        """Published layers: two sublayers each."""
+        return self.num_hidden_layers // 2
+
+    @property
+    def shortcut_layers(self) -> Tuple[int, ...]:
+        """Sublayers that hold their layer's router and experts."""
+        return tuple(i for i, t in enumerate(self.mlp_layer_types)
+                     if t == "shortcut")
+
+    def geometry(self, layer: int) -> LatentGeometry:
+        def scale(on: bool, rank: int) -> float:
+            return (self.hidden_size / rank) ** 0.5 if on else 1.0
+
+        return super().geometry(layer)._replace(
+            q_scale=scale(self.mla_scale_q_lora, self.q_lora_rank),
+            kv_scale=scale(self.mla_scale_kv_lora, self.kv_lora_rank))
+
+    @classmethod
+    def from_hf_dict(cls, raw: dict) -> "LongcatFlashConfig":
+        for name, want in _LONGCAT_ONLY.items():
+            if raw.get(name, want) != want:
+                raise ValueError(
+                    f"{name} = {raw[name]!r}: model_type longcat_flash "
+                    f"serves {want!r} only (not implemented)")
+        if raw.get("q_lora_rank") is None:
+            raise ValueError(
+                "q_lora_rank is null (a full-rank query projection): not "
+                "implemented for model_type longcat_flash "
+                "(models/reference/longcat_flash.py has no full-rank "
+                "query to compare with)")
+        for name in ("mtp_num_layers", "num_nextn_predict_layers"):
+            if raw.get(name, 0):
+                raise ValueError(
+                    f"{name} > 0: the multi-token-prediction module is not "
+                    "served (it drafts for speculation and adds nothing to "
+                    "the next-token logits); set it to 0")
+        if (raw.get("num_key_value_heads") or raw["num_attention_heads"]
+                ) != raw["num_attention_heads"]:
+            raise ValueError("num_key_value_heads must equal "
+                             "num_attention_heads: latent attention has a "
+                             "key a head")
+        L = raw["num_layers"]
+        held = raw["n_routed_experts"]
+        total = raw.get("n_routed_experts_total", held)
+        first = raw.get("first_routed_expert", 0)
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"first_routed_expert = {first}: experts {first}.."
+                f"{first + held - 1} are not among the router's {total} "
+                "routed experts")
+        zero, k = raw.get("zero_expert_num", 0), raw["moe_topk"]
+        if zero < 0 or k > total + zero:
+            raise ValueError(
+                f"moe_topk = {k} of a router {total} + zero_expert_num = "
+                f"{zero} wide")
+        base = LlamaConfig.from_hf_dict(dict(
+            raw, num_hidden_layers=2 * L,
+            intermediate_size=raw["ffn_hidden_size"],
+            num_key_value_heads=raw["num_attention_heads"]))
+        fields = {f: getattr(base, f) for f in base.__dataclass_fields__}
+        fields.update(chat_template="chatml", sliding_window=None)
+        return cls(
+            **fields,
+            num_local_experts=held, num_experts_per_tok=k,
+            norm_topk_prob=False, hf_layout="longcat_flash",
+            q_lora_rank=raw["q_lora_rank"],
+            kv_lora_rank=raw["kv_lora_rank"],
+            qk_nope_head_dim=raw["qk_nope_head_dim"],
+            qk_rope_head_dim=raw["qk_rope_head_dim"],
+            v_head_dim=raw["v_head_dim"],
+            index_n_heads=0, index_head_dim=0, index_topk=0,
+            mlp_layer_types=("shortcut", "dense") * L,
+            indexer_types=("dense",) * (2 * L),
+            moe_intermediate_size=raw["expert_ffn_hidden_size"],
+            n_routed_experts_total=total, first_routed_expert=first,
+            routed_scaling_factor=raw.get("routed_scaling_factor", 1.0),
+            scoring_func="softmax", zero_expert_num=zero,
+            mla_scale_q_lora=bool(raw.get("mla_scale_q_lora", False)),
+            mla_scale_kv_lora=bool(raw.get("mla_scale_kv_lora", False)),
+        )
+
+    @classmethod
+    def tiny_longcat(cls, **overrides) -> "LongcatFlashConfig":
+        """LongCat-Flash's layers at a test's size: 2 layers = 4
+        sublayers, 4 heads, 16 routed experts ALL held (a test of the
+        share holds four), 8 zero experts, 3 a token."""
+        base = dict(
+            vocab_size=512, hidden_size=64, intermediate_size=96,
+            num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=4, rms_norm_eps=1e-5, rope_theta=1e7,
+            max_position_embeddings=256, bos_token_id=1,
+            eos_token_ids=(512,), tie_word_embeddings=False,
+            chat_template="chatml",
+            num_local_experts=16, num_experts_per_tok=3,
+            norm_topk_prob=False, hf_layout="longcat_flash",
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_n_heads=0,
+            index_head_dim=0, index_topk=0,
+            mlp_layer_types=("shortcut", "dense") * 2,
+            indexer_types=("dense",) * 4,
+            moe_intermediate_size=32, n_routed_experts_total=16,
+            routed_scaling_factor=6.0, scoring_func="softmax",
+            zero_expert_num=8,
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
 # config.json keys of model_type bailing_hybrid whose one served value is
 # the published one: anything else is refused by the key's name
 _BAILING_ONLY = {

@@ -1,10 +1,10 @@
 """Latent attention on the paged engine: the step programs of GLM-5.2
-(`glm_moe_dsa`), of dots3-note (`dots3_note`) and of DeepSeek-V2
-(`deepseek_v2`).
+(`glm_moe_dsa`), of dots3-note (`dots3_note`), of DeepSeek-V2
+(`deepseek_v2`) and of LongCat-Flash (`longcat_flash`).
 
 The equations are models/reference/glm_moe_dsa.py's,
-models/reference/dots3_note.py's and models/reference/deepseek_v2.py's;
-this is how the served path computes
+models/reference/dots3_note.py's, models/reference/deepseek_v2.py's and
+models/reference/longcat_flash.py's; this is how the served path computes
 them over the page pool (models/llama/paged.py says what a pool row is
 here: one latent row a token and layer, and the indexer's key in the
 layers that compute an index).
@@ -65,6 +65,15 @@ the un-absorbed heads are multiplied by a sigmoid a head of the layer's
 normed input (`attn_gate`); where the geometry has scales the two normed
 latents are multiplied by them, so the stored row is the scaled one.
 
+By config.mlp_layer_types a layer's FFN is DENSE, SPARSE, or SHORTCUT
+(longcat_flash only, whose layers here are its SUBLAYERS: attention and
+a dense SwiGLU each): a shortcut sublayer's normed FFN input also goes
+through its layer's routed experts (`shortcut_moe`: ops/moe.moe_mlp
+with the zero experts past the router's routed width), and what they
+return is carried past the NEXT sublayer and added after its FFN.
+Nothing of that sublayer feeds the experts, so the compiler is free to
+place them beside it.
+
 ONE WINDOW A DISPATCH. The window's score pass takes the one row whose
 keys the queries share, so a mixed dispatch holds at most one row with
 more than one token (the engine groups its rows so:
@@ -123,6 +132,10 @@ N_COUNTERS = len(COUNTERS)
 # held one, and the keys its single-token rows attended
 DENSE_COUNTERS = paged.MOE_COUNTERS + (
     "moe_rows_routed", "moe_tokens_group_held", "mla_keys_attended")
+# a model of shortcut layers (longcat_flash): no groups; the routed
+# pairs that chose a zero expert in the group counter's place
+SHORTCUT_COUNTERS = paged.MOE_COUNTERS + (
+    "moe_rows_routed", "moe_pairs_zero", "mla_keys_attended")
 
 
 class Window(NamedTuple):
@@ -159,14 +172,19 @@ def layer_leaves(blocks, config: GlmMoeDsaConfig, i: int) -> dict:
         lp = at(ATTN_LEAVES + (GATE_LEAF,), config.latent_layers.index(i))
     if config.indexer_types[i] == "full":
         lp.update(at(INDEX_LEAVES, config.full_layers.index(i)))
+    def routed(j):
+        return {**at(SPARSE_LEAVES, j),
+                **{k: LayerOf(blocks[k], jnp.int32(j))
+                   for k in EXPERT_LEAVES}}
+
     if config.mlp_layer_types[i] == "sparse":
-        j = config.sparse_layers.index(i)
-        lp.update(at(SPARSE_LEAVES, j))
-        lp.update({k: LayerOf(blocks[k], jnp.int32(j))
-                   for k in EXPERT_LEAVES})
+        lp.update(routed(config.sparse_layers.index(i)))
     else:
         lp.update(at(DENSE_LEAVES, i - sum(s < i
                                            for s in config.sparse_layers)))
+    if config.mlp_layer_types[i] == "shortcut":
+        # the layer's routed experts beside this sublayer's dense FFN
+        lp["shortcut"] = routed(config.shortcut_layers.index(i))
     return lp
 
 
@@ -519,7 +537,10 @@ def ffn(lp, h, real, config):
         lp, h[None], c.num_experts_per_tok, c.norm_topk_prob,
         token_mask=real[None], first_expert=held_from(c),
         scoring=c.scoring_func, scale=c.routed_scaling_factor,
-        n_group=c.n_group, topk_group=c.topk_group, group_top=c.group_top)
+        n_group=c.n_group, topk_group=c.topk_group, group_top=c.group_top,
+        # a router wider than its routed experts: zero experts past them
+        zero_from=(c.n_routed_experts_total
+                   if getattr(c, "zero_expert_num", 0) else None))
     return out[0], stats
 
 
@@ -544,8 +565,9 @@ class TrunkOut(NamedTuple):
     bool, the window's (empty where there is no window); probe: the
     first sliding layer from the inside, each [T, D]: its normed
     input, its attention's output before the residual, and its FFN's
-    normed input (() for a model with no sliding layer), so that the
-    tool can hand the reference's layer the served path's own input."""
+    normed input (of the first shortcut sublayer where the model has
+    those; () for a model with neither), so that the tool can hand the
+    reference's layer, or its router, the served path's own input."""
 
     x: jnp.ndarray
     cache: PagedKVCache
@@ -587,6 +609,9 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
         seen = visible_keys(slot, position, real, first, window)
     moe, experts, selected, windows, probe = [], [], [], [], ()
     distinct = jnp.float32(0)
+    # what a shortcut sublayer's experts returned, until the sublayer
+    # after it has added its FFN
+    carried = None
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
             lp = layer_leaves(blocks, c, i)
@@ -632,13 +657,18 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                 x = x + attn_out
             with jax.named_scope("ffn"):
                 h_attn, h = h, rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
-                if geo.window is not None and not probe:
+                if (geo.window is not None or "shortcut" in lp) and not probe:
                     probe = (h_attn, attn_out, h)
                 out, stats = ffn(lp, h, real, c)
+                if "shortcut" in lp:
+                    with jax.named_scope("shortcut_moe"):
+                        carried, stats = ffn(lp["shortcut"], h, real, c)
                 if stats is not None:
                     moe.append(stats)
                     experts.append(stats.experts)
                 x = x + out
+                if carried is not None and "shortcut" not in lp:
+                    x, carried = x + carried, None
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
     n_real = jnp.sum(real, dtype=jnp.float32)
@@ -650,7 +680,11 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
     f32 = jnp.float32
     counters = moe_counters(moe)
     if dense:
-        held = [s.group_held for s in moe if s.group_held is not None]
+        # the tokens whose groups include the held one, or (a router
+        # with zero experts has no groups) the pairs that chose one
+        held = [s.group_held if s.pairs_zero is None else s.pairs_zero
+                for s in moe]
+        held = [n for n in held if n is not None]
         counters += [
             jnp.sum(jnp.stack(held)) if held else f32(0),
             L * jnp.sum(jnp.maximum(seen + 1, 0),
@@ -869,7 +903,7 @@ def _family(name: str, counters: tuple, beside=None, *,
                 "a shared head would need its latent rows and its index "
                 "keys (and a windowed model's ring) mapped together"),
             windows: Windows = Windows.DISPATCH,
-            decode_walk=None) -> Family:
+            decode_walk=None, says=None) -> Family:
     """impl, kernel_rows: what the step records call the attention, and
     the step kinds whose rows a kernel walks page by page (the host
     counts those pages as it does cake_decode_attn's: the same rule);
@@ -884,7 +918,7 @@ def _family(name: str, counters: tuple, beside=None, *,
         # one window a dispatch (module docstring), so one packed size
         prefill_rows=(1,), windows=windows, beside=beside,
         impl=impl, resolve_attn=_resolve_attn, kernel_rows=kernel_rows,
-        window_walk=window_walk, decode_walk=decode_walk,
+        window_walk=window_walk, decode_walk=decode_walk, says=says,
         what="latent attention over the page pool",
         refuses=cannot_move(
             stored,
@@ -915,3 +949,25 @@ DENSE = _family("deepseek_v2", DENSE_COUNTERS, impl="paged-mla-",
                 prefix_needs=("the prefix path prefills and maps K/V "
                               "pages, not latent rows"),
                 windows=Windows.STEP, decode_walk=decode_walk)
+def _shortcut_share(config) -> str:
+    """The start-up sentence of a model of shortcut layers: its latent
+    layers and the share of its router it holds."""
+    c = config
+    first, held, total = (c.first_routed_expert, c.num_local_experts,
+                          c.n_routed_experts_total)
+    return (f"{c.num_hidden_layers} latent layers of {c.num_layers} "
+            f"shortcut-connected layers; routed experts {first}.."
+            f"{first + held - 1} ({held} of {total}) + {c.zero_expert_num} "
+            f"zero experts in a router {total + c.zero_expert_num} wide, "
+            f"{c.num_experts_per_tok} a token")
+
+
+# one window a STEP as DENSE: the same trunk, kernels and traffic shape
+# (a window's dispatch carries every decode row); not read against
+# DISPATCH on the chip for this family (PERF.md section 7)
+SHORTCUT = _family("longcat_flash", SHORTCUT_COUNTERS, impl="paged-mla-",
+                   kernel_rows=("decode",), stored="latent row",
+                   prefix_needs=("the prefix path prefills and maps K/V "
+                                 "pages, not latent rows"),
+                   windows=Windows.STEP, decode_walk=decode_walk,
+                   says=_shortcut_share)

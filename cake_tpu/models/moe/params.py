@@ -75,8 +75,14 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
     nI, dI = c.index_n_heads, c.index_head_dim
     Lf, Ls = len(c.full_layers), len(c.sparse_layers)
     Ld = c.num_hidden_layers - Ls
+    # a model of shortcut layers (longcat_flash) keeps a dense FFN in
+    # every sublayer and its routers and experts in the "shortcut" ones;
+    # its router is wider than its experts by the zero experts
+    shortcut = len(getattr(c, "shortcut_layers", ()))
+    Ls = Ls or shortcut
     E, Et, Fe, Fd = (c.num_local_experts, c.n_routed_experts_total,
                      c.moe_intermediate_size, c.intermediate_size)
+    Et += getattr(c, "zero_expert_num", 0)
     # the shared experts are ONE MLP of their summed width
     Fs = c.n_shared_experts * Fe
     w, mat, keys = _draws(rng, dtype, bits, 40)
@@ -126,13 +132,29 @@ def _init_glm_params(config, rng: jax.Array, dtype, bits: Optional[int]):
         "we_down": mat("we_down", (Ls, E, Fe, D), Fe),
         "ws_gate": mat("ws_gate", (Ls, D, Fs), D),
         "ws_up": mat("ws_up", (Ls, D, Fs), D),
-        "ws_down": mat("ws_down", (Ls, Fs, D), Fs),
+        "ws_down": mat("ws_down", (Ls, Fs, D), Fs or 1),
     }
     if not Lf:
         # no layer has an indexer (deepseek_v2), and its softmax rule
-        # has no selection bias
+        # has no selection bias (longcat_flash's has one)
         for name in ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w",
                      "router_bias"):
+            if not (shortcut and name == "router_bias"):
+                del blocks[name]
+    if shortcut:
+        # the router is a float32 leaf whatever the activations' type
+        # (a key of its own: the leaves above keep their draws)
+        blocks["router"] = jax.random.normal(
+            jax.random.fold_in(rng, 2), (Ls, D, Et),
+            jnp.float32) / np.sqrt(D)
+        # the choice bias at the size of ITS scores' spread: a softmax
+        # over Et outputs of unit-variance logits spreads 1.3 / Et
+        # where the sigmoid the draw above was sized for spreads 0.21;
+        # left at 0.05 it would choose the same k experts for every
+        # token
+        blocks["router_bias"] = blocks["router_bias"] * (6.0 / Et)
+    if not Fs:
+        for name in ("ws_gate", "ws_up", "ws_down"):
             del blocks[name]
     if Ld:
         blocks.update({
@@ -699,6 +721,13 @@ def hf_layout(config: MoEConfig):
             "this repository and its tensor names are not guessed (the "
             "choice bias and the per-head q / k norms among them); it "
             "is served from seeded weights only")
+    if config.hf_layout == "longcat_flash":
+        raise NotImplementedError(
+            "model_type longcat_flash: the published checkpoint is not in "
+            "this repository and its tensor names are not guessed (the "
+            "two attentions and FFNs a layer, the router's classifier "
+            "and its bias among them); it is served from seeded weights "
+            "only")
     if config.hf_layout == "brumby":
         raise NotImplementedError(
             "model_type brumby: the published checkpoint is not in this "

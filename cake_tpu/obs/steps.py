@@ -445,6 +445,17 @@ MLA_DENSE_COUNTERS = (
         "summed over rows and latent layers: what cake_mla_decode_attn "
         "walked")),
 )
+# a router wider than the expert matrices it indexes (longcat_flash's
+# zero-compute experts: models/moe/glm_dsa.trunk's shortcut layers)
+ZERO_EXPERT_COUNTERS = (
+    ("moe_pairs_zero", _m.counter(
+        "cake_moe_pairs_zero_total",
+        "Routed (token, expert) pairs that chose a zero-compute expert "
+        "(an index past the router's routed experts: the pair adds its "
+        "weight times the layer's input and reaches no matrix), summed "
+        "over expert layers (over cake_moe_rows_routed_total: the share "
+        "of pairs that cost nothing)")),
+)
 # a model with Kimi Delta Attention layers
 # (models/moe/bailing_hybrid.trunk): the two forms of the delta rule and
 # the rows' matrix state
@@ -555,6 +566,7 @@ GQA_WINDOW_POOL_BYTES = _m.gauge(
     "pool (slots x ring pages, every sliding layer)")
 COUNTER_SERIES = dict(MOE_COUNTERS + DSA_COUNTERS + SSM_COUNTERS
                       + CCA_COUNTERS + SWA_COUNTERS + MLA_DENSE_COUNTERS
+                      + ZERO_EXPERT_COUNTERS
                       + KDA_COUNTERS + GQA_WINDOW_COUNTERS
                       + DSA_GQA_COUNTERS + RETENTION_COUNTERS)
 # what a family's cache keeps beside the page pool (family.Beside.gauge)

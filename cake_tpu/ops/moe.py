@@ -97,7 +97,9 @@ class MoEStats(NamedTuple):
     rows_routed: the real tokens' pairs over ALL the router's experts
     (== rows unless the layer holds a share of them). group_held: the
     real tokens whose chosen groups include the held experts' (group-
-    limited routing on a share; None elsewhere)."""
+    limited routing on a share; None elsewhere). pairs_zero: the real
+    tokens' pairs that chose a zero expert (a router wider than its
+    experts; None elsewhere): counted in rows_routed, never in rows."""
 
     rows: jnp.ndarray
     rows_padded: jnp.ndarray
@@ -108,6 +110,7 @@ class MoEStats(NamedTuple):
     rows_routed: jnp.ndarray
     # (None is no leaf: a layer scan's outputs are what they were)
     group_held: Optional[jnp.ndarray] = None
+    pairs_zero: Optional[jnp.ndarray] = None
 
 
 def router_logits(x, router_w):
@@ -500,7 +503,8 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             ep_axis: Optional[str] = None, token_mask=None,
             first_expert: Optional[int] = None, scoring: str = "softmax",
             scale: float = 1.0, act: str = "silu", logits=None,
-            n_group: int = 1, topk_group: int = 1, group_top: int = 1):
+            n_group: int = 1, topk_group: int = 1, group_top: int = 1,
+            zero_from: Optional[int] = None):
     """Sparse FFN over experts -> (out [B, S, D], MoEStats).
 
     lp leaves: router [D, E], the linear router, unless the family made
@@ -525,8 +529,13 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     names the mesh axis; on one chip `first_expert` (static) is the
     first of the E_local held. token_mask [B, S] bool: positions that are not real
     (padding of a mixed window, idle rows) are not routed and come back
-    zero. Returns the *unreduced-over-tp* output: when F is additionally
-    Megatron-sharded the caller (block_skeleton) psums over tp, exactly
+    zero. zero_from (static; None: the router has none): the router's
+    first ZERO expert: an index at or past it holds no matrix, its pair
+    is neither sorted nor multiplied and adds its weight times the
+    layer's own input (an identity expert). Every share of a layer
+    computes that part alike, as it does a shared expert: it is added
+    once, after the sum. Returns the *unreduced-over-tp* output: when F
+    is additionally Megatron-sharded the caller (block_skeleton) psums over tp, exactly
     as for the dense path — EP and TP reductions compose.
     """
     B, S, D = h.shape
@@ -562,10 +571,21 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
             x_in = qmatmul(x, lp["w_fc1"])
     mask = None if token_mask is None else token_mask.reshape(N)
     valid = None if mask is None else jnp.broadcast_to(mask[:, None], (N, k))
-    rows_routed = None
-    if ep_axis is not None or first_expert is not None:
+    rows_routed = zero_weight = pairs_zero = None
+    if zero_from is not None:
+        zero = experts >= zero_from
+        if valid is not None:
+            zero = zero & valid
+        zero_weight = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+        pairs_zero = jnp.sum(zero, dtype=jnp.float32)
         rows_routed = (jnp.float32(N * k) if valid is None
                        else jnp.sum(valid, dtype=jnp.float32))
+        valid = ~zero if valid is None else valid & ~zero
+        experts = jnp.minimum(experts, zero_from - 1)
+    if ep_axis is not None or first_expert is not None:
+        if rows_routed is None:
+            rows_routed = (jnp.float32(N * k) if valid is None
+                           else jnp.sum(valid, dtype=jnp.float32))
         first = (first_expert if ep_axis is None
                  else lax.axis_index(ep_axis) * e_local)
         experts = experts - first
@@ -579,10 +599,16 @@ def moe_mlp(lp, h, num_experts_per_tok: int, norm_topk_prob: bool = True,
     if took is not None:
         stats = stats._replace(group_held=jnp.sum(
             took if mask is None else took & mask, dtype=jnp.float32))
+    if pairs_zero is not None:
+        stats = stats._replace(pairs_zero=pairs_zero)
     if mask is not None:
         out = jnp.where(mask[:, None], out, 0.0)
     if ep_axis is not None:
         out = lax.psum(out, ep_axis)
+    if zero_weight is not None:
+        # (a token outside the mask has no zero pair: its weight is 0)
+        with jax.named_scope("zero_experts"):
+            out = out + zero_weight[:, None] * x.astype(jnp.float32)
     if "w_fc2" in lp:
         with jax.named_scope("moe_latent"):
             out = qmatmul(out.astype(h.dtype),

@@ -2644,6 +2644,9 @@ class InferenceEngine:
                 obs_steps.BESIDE_POOL_BYTES[gauge].set(beside)
             log.info("%s: %.2f GiB (%d bytes) beside the pool, %d rows",
                      what, beside / 2**30, beside, self.max_slots)
+        if family.says is not None:
+            log.info("model_type %s: %s", family.name,
+                     family.says(self.config))
         if self.cache.memory_bytes() == 0:
             # a family none of whose layers keeps K/V: the pool is a
             # stated case, not a small one (nothing below sizes, spills

@@ -135,6 +135,21 @@ is read twice: as the served path is (served against altered), and
 `from_plain` (altered against the plain reference: what the alteration
 alone moves).
 
+LongCat-Flash-Chat (`benchmarks/configs/longcat-flash-int8-share32`,
+model_type longcat_flash) goes through `models/moe/glm_dsa`'s trunks
+(their shortcut kind of layer) and
+`cake_tpu/models/reference/longcat_flash.py`, given the same held
+experts (compare_longcat): a `d8k` and a `t2k` prompt of the cell
+through 512-token windows, then decode through the pages, every other
+row of the 32 decoding beside them. Experts teacher-forced; the router's
+own choice compared on the served path's own input; both latent kernels
+probed at 64 heads against exact attention. Its limits (LONGCAT_TOL) lie
+between what the served path reads and what must fail: a bfloat16
+softmax, int8 activations, the zero experts dropped, the weights
+renormalised, either latent's scale left out, the shortcut tapped from
+the second sublayer or returned before it, the choice bias in the
+weight.
+
 The last line of stdout is one JSON object with `ok`.
 """
 
@@ -533,6 +548,60 @@ BRUMBY_START, BRUMBY_EDGE, BRUMBY_LAST = 8, 3, 128
 BRUMBY_JOBS = ((0, 8100), (1, 2000), (1, 1950))
 BRUMBY_DECODE = 24
 
+# LongCat-Flash-Chat (benchmarks/configs/longcat-flash-int8-share32,
+# model_type longcat_flash) goes the way Ling does (drive_jobs: 32 rows
+# in every step, one 512-token window a step, then 24 decode steps a
+# request) against cake_tpu/models/reference/longcat_flash.py on
+# teacher-forced experts. Limits: `mean` and `max`, |error| / the
+# reference's range over the compared positions (a request's first 8,
+# the 3 behind its first window edge, its last 128 prompt positions,
+# every decode step); `probe` and `probe_window`, the two latent kernels
+# THEMSELVES at 64 heads against exact float32 attention over the pages
+# the served path wrote, under DeepSeek-V2's limit (DSV2_TOL["probe"]:
+# not loosened), where exact attention with a bfloat16 softmax must read
+# past it; `agree_same_input`, the share of compared positions where the
+# reference's router on the served path's own input to the first
+# layer's MoE chooses the served path's 12, and `router_logit_err`.
+# `moe_err`: the first layer's MoE (ops/moe.moe_mlp as the trunk calls
+# it) over the compared positions' OWN inputs as one batch against the
+# reference's moe_ffn on the same inputs and experts, relative error,
+# root of summed squares: what holds a routing weight that is a few per
+# cent off (the choice bias added to it), which moves the logits by a
+# twentieth of the served path's own rounding.
+# `agree` (along the forced trajectory) and `nearer` are reported. Each
+# limit lies between the worst the served path read on the chip over
+# seeds 0 / 1 / 2 and the LEAST an altered reference that it has to hold
+# out read there (my chip runs, PR 65; served | must fail; the cell's
+# cell.json, `chip_compare`, has every reading): mean 1.98e-3 / 2.02e-3
+# / 1.99e-3 | 6.82e-3 (int8 activations; every other alteration of the
+# layer 4.4e-2 to 0.13); max 1.38e-2 / 1.44e-2 / 1.41e-2 | 4.76e-2;
+# moe_err 1.71e-3 / 1.67e-3 / 1.67e-3 | 1.94e-2 (the bias in the weight,
+# whose logits read mean 2.09e-3, `nearer` 1.06: the logits cannot hold
+# it; renormalised 0.88, the zero experts dropped 9.99); probe 1.70e-3 /
+# 1.67e-3 / 1.72e-3 and probe_window 1.69e-3 (all three) | 1.26e-2 and
+# 1.43e-2 (exact attention with a bfloat16 softmax, whose logits read
+# mean 2.05e-3: the probes alone hold it); agree_same_input 1.0;
+# router_logit_err 1.9e-6.
+LONGCAT_TOL = {"mean": 3.5e-3, "max": 2.6e-2, "moe_err": 6e-3,
+               "router_logit_err": 1e-3}
+LONGCAT_AGREE = 0.9
+LONGCAT_START, LONGCAT_EDGE, LONGCAT_LAST = 8, 3, 128
+# (slot, prompt tokens): the cell's two prompt classes
+LONGCAT_JOBS = ((0, 8100), (1, 2000))
+LONGCAT_DECODE = 24
+# the altered references: each must fail a limit
+LONGCAT_NEGATIVES = {
+    "bf16_softmax": dict(softmax_dtype="bfloat16"),
+    "int8_activations": dict(int8_activations=True),
+    "zero_experts_dropped": dict(zero_experts=False),
+    "renormalised": dict(norm_topk_prob=True),
+    "no_q_scale": dict(mla_scale_q_lora=False),
+    "no_kv_scale": dict(mla_scale_kv_lora=False),
+    "tapped_from_the_second_sublayer": dict(tap=1),
+    "returned_before_the_second_sublayer": dict(back=0),
+    "bias_in_the_weight": dict(bias_in_weight=True),
+}
+
 MEAN_TOL = 1.6e-3   # mean |error| / range, all compared entries
 MAX_TOL = 3e-2      # worst entry / range
 PROMPTS = (100, 352, 736, 1248, 1792, 65, 384, 1000)
@@ -688,6 +757,8 @@ def main() -> int:
         return compare_keye(engine, cell, args, t_start)
     if raw_config.get("model_type") == "brumby":
         return compare_brumby(engine, cell, args, t_start)
+    if raw_config.get("model_type") == "longcat_flash":
+        return compare_longcat(engine, cell, args, t_start)
     cfg, params, rope = engine.config, engine.params, engine.rope
     impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
     say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
@@ -987,7 +1058,6 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
     from cake_tpu.models.llama import paged
     from cake_tpu.models.moe import glm_dsa
     from cake_tpu.models.reference import deepseek_v2 as ref
-    from cake_tpu.ops import mla_attention as mla
     from cake_tpu.ops.moe import LayerOf
     from cake_tpu.ops.quant import qmatmul
 
@@ -1107,50 +1177,15 @@ def compare_deepseek_v2(engine, cell, args, t_start) -> int:
     # attention over the pages the served path wrote (layer 0, row 0:
     # the longest), for queries whose scores spread widely
     geo = cfg.geometry(0)
-    R, row_w = geo.kv_lora_rank, cache.k.shape[-1]
     n_keys = len(sequences[0])
     probe, keys, norm = latent_pages_probe(cache.k, table, 0, n_keys, geo,
                                            attn, args.seed)
 
     # -- the same for the WINDOW pass (cake_mla_window_attn under
-    # causality: all of prefill): the row's last C positions as
-    # one window over the same pages, queries drawn the same way
-    Cw = min(C, n_keys)
-    win_pos = jnp.arange(n_keys - Cw, n_keys)
-    qw = (jax.random.normal(jax.random.PRNGKey(args.seed + 2),
-                            (Cw, geo.heads, row_w), jnp.float32)
-          * (DSV2_PROBE_SPREAD / (norm * geo.softmax_scale))
-          ).astype(cache.k.dtype)
-    served_win = np.asarray(mla.attend_window(
-        qw, cache.k, 0, jnp.asarray(table[0]), None, jnp.int32(n_keys - 1),
-        R, geo.softmax_scale, impl=attn,
-        positions=win_pos.astype(jnp.int32)), np.float64)
-
-    @partial(jax.jit, static_argnames="scores_dtype")
-    def exact_block(qb, pos_b, scores_dtype):
-        with jax.default_matmul_precision("highest"):
-            kf, qf = keys.astype(jnp.float32), qb.astype(jnp.float32)
-            s = (jnp.einsum("chw,sw->chs", qf, kf)
-                 * geo.softmax_scale).astype(scores_dtype)
-            s = jnp.where((jnp.arange(n_keys)[None, :]
-                           <= pos_b[:, None])[:, None, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1).astype(jnp.float32)
-            return jnp.einsum("chs,sr->chr", p, kf[:, :R])
-
-    def exact_window(scores_dtype):
-        # (in blocks of queries: [C, heads, keys] float32 would be a GB)
-        step = max(1, Cw // 8)
-        return np.concatenate([np.asarray(exact_block(
-            qw[i:i + step], win_pos[i:i + step], scores_dtype), np.float64)
-            for i in range(0, Cw, step)])
-
-    want_win = exact_window(jnp.float32)
-    probe_window = {"served": probe_rel(served_win, want_win),
-                    "bf16_softmax": probe_rel(exact_window(jnp.bfloat16),
-                                        want_win)}
-    say(f"probe of the window pass: kernel {probe_window['served']:.3e}, "
-        f"exact attention with a bfloat16 softmax "
-        f"{probe_window['bf16_softmax']:.3e}")
+    # causality: all of prefill): the row's last C positions as one
+    # window over the same pages, queries drawn the same way
+    probe_window = latent_window_probe(cache.k, table, 0, n_keys, C, geo,
+                                       attn, args.seed, keys, norm)
 
     # -- the reference: the served weights leave the device, then come
     # back dequantized one layer at a time --------------------------------
@@ -2820,14 +2855,14 @@ def compare_zaya(engine, cell, args, t_start) -> int:
     return 0 if ok else 1
 
 
-def trunk_steps(module, engine):
+def trunk_steps(module, engine, ffn_input=lambda out: out.ffn_in[0]):
     """(window_step, decode_step): a family's own mixed and decode
     trunks (`module.mixed_trunk` / `decode_trunk`, the signatures
-    bailing_hybrid's and exaone_moe's share) under jit with the head at
-    EVERY position. Each hands the host (logits [T, V], the cache,
-    every sparse layer's choice [L_sparse, T, k], the first sparse
-    layer's input [T, D], the router's logits on it as moe_mlp makes
-    them [T, E])."""
+    bailing_hybrid's, exaone_moe's and glm_dsa's share) under jit with
+    the head at EVERY position. Each hands the host (logits [T, V], the
+    cache, every sparse layer's choice [L_sparse, T, k], the first
+    sparse layer's input [T, D] (`ffn_input` of the trunk's result), the
+    router's logits on it as moe_mlp makes them [T, E])."""
     import jax
     import jax.numpy as jnp
 
@@ -2838,7 +2873,7 @@ def trunk_steps(module, engine):
 
     def outputs(params, out):
         logits = qmatmul(out.x, params["lm_head"]).astype(jnp.float32)
-        h = out.ffn_in[0]
+        h = ffn_input(out)
         router = params["blocks"]["router"][0]
         return (logits, out.cache, out.experts, h,
                 moe_ops.router_logits(h, router))
@@ -2887,7 +2922,8 @@ def drive_jobs(engine, params, cache, steps_of, jobs, sequences, prompts,
     fillers = [b for b in range(B) if b not in job_slots]
     filler_tokens = rng.integers(0, cfg.vocab_size,
                                  (B, per_row * cache.page_size))
-    Ls = len(getattr(cfg, "sparse_layers", ()))
+    Ls = (len(getattr(cfg, "sparse_layers", ()))
+          or len(getattr(cfg, "shortcut_layers", ())))
     k = cfg.num_experts_per_tok
     got = [dict() for _ in jobs]
     ffn_in = [dict() for _ in jobs]
@@ -3503,9 +3539,10 @@ def compare_exaone_moe(engine, cell, args, t_start) -> int:
     del first_sparse
 
     def readings(which, logits_of, routing_of, config=ref_cfg,
-                 against=None):
+                 against=None, name="plain"):
         """Over the compared positions of the jobs `which`, the served
         logits against `logits_of`: mean and worst |error| / range;
+        `moe_err`, the first layer's MoE on its own input (above);
         `mean_edge` over the positions behind the first window edge;
         `mean_decode` over the decode steps (what went through the
         decode kernel's band and the ring after it wrapped); `agree`;
@@ -4563,6 +4600,294 @@ def compare_brumby(engine, cell, args, t_start) -> int:
                                     == expected)
     result["seconds"] = round(time.monotonic() - t_start, 1)
     with open(os.path.join(OUT_DIR, f"result_brumby_seed{args.seed}.json"),
+              "w") as f:
+        json.dump(result, f, indent=1)
+    print(json.dumps(result), flush=True)
+    return 0 if result["ok"] else 1
+
+
+# -- longcat_flash -------------------------------------------------------------
+
+
+def latent_window_probe(pool, table, row: int, n_keys: int, width: int,
+                        geo, attn: str, seed: int, keys, norm) -> dict:
+    """`cake_mla_window_attn` ITSELF under causality (all of prefill)
+    against exact attention: `row`'s last `width` positions as one
+    window over the pages the served path wrote, queries drawn as
+    latent_pages_probe draws them (`keys`, `norm`: its returns).
+    -> {"served", "bf16_softmax"}."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.ops import mla_attention as mla
+
+    R, row_w = geo.kv_lora_rank, pool.shape[-1]
+    C = min(width, n_keys)
+    win_pos = jnp.arange(n_keys - C, n_keys)
+    qw = (jax.random.normal(jax.random.PRNGKey(seed + 2),
+                            (C, geo.heads, row_w), jnp.float32)
+          * (DSV2_PROBE_SPREAD / (norm * geo.softmax_scale))
+          ).astype(pool.dtype)
+    served = np.asarray(mla.attend_window(
+        qw, pool, 0, jnp.asarray(np.asarray(table)[row]), None,
+        jnp.int32(n_keys - 1), R, geo.softmax_scale, impl=attn,
+        positions=win_pos.astype(jnp.int32)), np.float64)
+
+    @partial(jax.jit, static_argnames="scores_dtype")
+    def exact_block(qb, pos_b, scores_dtype):
+        with jax.default_matmul_precision("highest"):
+            kf, qf = keys.astype(jnp.float32), qb.astype(jnp.float32)
+            s = (jnp.einsum("chw,sw->chs", qf, kf)
+                 * geo.softmax_scale).astype(scores_dtype)
+            s = jnp.where((jnp.arange(n_keys)[None, :]
+                           <= pos_b[:, None])[:, None, :], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1).astype(jnp.float32)
+            return jnp.einsum("chs,sr->chr", p, kf[:, :R])
+
+    def exact(scores_dtype):
+        # (in blocks of queries: [C, heads, keys] float32 would be a GB)
+        step = max(1, C // 8)
+        return np.concatenate([np.asarray(exact_block(
+            qw[i:i + step], win_pos[i:i + step], scores_dtype), np.float64)
+            for i in range(0, C, step)])
+
+    want = exact(jnp.float32)
+    out = {"served": probe_rel(served, want),
+           "bf16_softmax": probe_rel(exact(jnp.bfloat16), want)}
+    say(f"probe of the window pass: kernel {out['served']:.3e}, exact "
+        f"attention with a bfloat16 softmax {out['bf16_softmax']:.3e}")
+    return out
+
+
+def compare_longcat(engine, cell, args, t_start) -> int:
+    """The comparison above for shortcut-connected layers: the engine's
+    own mixed and decode trunks with the head at every position, 32
+    rows in every step, against models/reference/longcat_flash.py on
+    teacher-forced experts."""
+    import jax
+    import jax.numpy as jnp
+
+    from cake_tpu.models.moe import glm_dsa
+    from cake_tpu.models.reference import longcat_flash as ref
+    from cake_tpu.ops.moe import LayerOf
+
+    cfg, params = engine.config, engine.params
+    impl = {k: engine._step_impl(k) for k in ("mixed", "decode")}
+    say(f"device {jax.devices()[0].device_kind}; attention {impl}; "
+        f"engine built in {time.monotonic() - t_start:.1f} s")
+    if not args.rehearse and impl != cell["expect_impl"]:
+        say(f"FAILED: expected attention {cell['expect_impl']}")
+        return 1
+    attn = engine.attn_impl["mixed"]
+
+    B, C = engine.max_slots, engine._mixed_chunk
+    page, per_row = engine.cache.page_size, engine.cache.table.shape[1]
+    jobs = LONGCAT_JOBS if not args.rehearse else ((0, 70), (1, 30))
+    n_decode = LONGCAT_DECODE if not args.rehearse else 6
+    last = LONGCAT_LAST if not args.rehearse else 12
+    rng = np.random.default_rng(args.seed)
+    sequences = [rng.integers(0, cfg.vocab_size, p + n_decode)
+                 for _, p in jobs]
+    prompts = [p for _, p in jobs]
+    assert max(prompts) + n_decode <= per_row * page
+    # the jobs' rows whole; a filler decodes from position 0 for as many
+    # steps as the jobs take
+    steps_bound = sum(-(-p // C) for p in prompts) + n_decode + 2
+    table = rows_table(engine, whole={slot for slot, _ in jobs},
+                       other_pages=-(-steps_bound // page))
+    cache = engine.cache._replace(table=jnp.asarray(table))
+    engine.cache = None
+    Ls, k = len(cfg.shortcut_layers), cfg.num_experts_per_tok
+
+    def compared(i, position):
+        """The prompt's last positions, every decode step, the request's
+        first positions and those behind the first window edge."""
+        return (position >= prompts[i] - last or position < LONGCAT_START
+                or C <= position < C + LONGCAT_EDGE)
+
+    got, ffn_in, all_routed, steps, cache = drive_jobs(
+        engine, params, cache,
+        # the first shortcut sublayer's FFN input: TrunkOut.probe's third
+        trunk_steps(glm_dsa, engine, ffn_input=lambda out: out.probe[2]),
+        jobs, sequences, prompts, compared, rng)
+    zero_share = float(np.mean(np.concatenate(
+        [r.reshape(-1) for r in all_routed]) >= cfg.n_routed_experts_total))
+
+    # -- the probes: both latent kernels at this model's head count
+    # against exact attention over the pages the served path wrote (the
+    # first sublayer, the longest job's row), under DeepSeek-V2's limit
+    geo = cfg.geometry(0)
+    n_keys = len(sequences[0])
+    probe, keys, norm = latent_pages_probe(cache.k, table, jobs[0][0],
+                                           n_keys, geo, attn, args.seed)
+    probe_window = latent_window_probe(cache.k, table, jobs[0][0], n_keys,
+                                       C, geo, attn, args.seed, keys, norm)
+
+    # -- the reference: the served weights leave the device (but for
+    # the first layer's MoE reading below), then come back dequantized
+    # one sublayer at a time --------------------------------------------
+    del cache, keys
+    host = jax.device_get(params)
+    engine_params, engine.params, params = params, None, None
+    ref_cfg = dict(
+        {k_: getattr(cfg, k_) for k_ in (
+            "num_attention_heads", "hidden_size", "q_lora_rank",
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+            "rms_norm_eps", "rope_theta", "mla_scale_q_lora",
+            "mla_scale_kv_lora", "routed_scaling_factor")},
+        n_routed_experts=cfg.n_routed_experts_total, moe_topk=k)
+    held = (cfg.first_routed_expert, cfg.num_local_experts)
+    ref.attend_block = jax.jit(ref.attend_block,
+                               static_argnames=("scale", "dtype"))
+
+    def widened(v):
+        if isinstance(v, dict):
+            return {k_: widened(x) for k_, x in v.items()}
+        if isinstance(v, LayerOf):
+            v = jax.tree.map(lambda a: a[int(v.layer)], v.stacked)
+        return dequantized(jax.tree.map(jnp.asarray, v))
+
+    def layers():
+        # from the host copy: one sublayer's leaves cross to the device
+        # at a time, as stored, and widen there
+        for i in range(cfg.num_hidden_layers):
+            yield widened(glm_dsa.layer_leaves(host["blocks"], cfg, i))
+
+    top = {k_: dequantized(jax.tree.map(jnp.asarray, host[k_]))
+           for k_ in ("embed", "final_norm", "lm_head")}
+
+    def reference(which, config=ref_cfg):
+        """The reference over the jobs `which`, TEACHER-FORCED in its
+        experts -> ({job: logits}, {job: its own choice along that
+        trajectory})."""
+        t0 = time.monotonic()
+        seqs = [sequences[i] for i in which]
+        routing = [[] for _ in seqs]
+        logits = ref.forward(top, seqs, config, layers=layers(), held=held,
+                             routing=routing,
+                             forced=[list(all_routed[i]) for i in which])
+        say(f"  reference over {sum(len(s_) for s_ in seqs)} tokens in "
+            f"{time.monotonic() - t0:.1f} s")
+        return (dict(zip(which, (np.asarray(x) for x in logits))),
+                dict(zip(which, routing)))
+
+    # -- the first layer's MoE on the served path's OWN input: the
+    # served ops/moe.moe_mlp over the compared positions' inputs as one
+    # batch against the reference's moe_ffn on the same inputs and the
+    # same experts, under the plain config and under every altered one
+    # (what the logits cannot tell: a weight that is a few per cent off)
+    first = next(layers())["shortcut"]
+    router = {k_: first[k_] for k_ in ("router", "router_bias")}
+    at = [(i, position) for i in range(len(jobs))
+          for position in sorted(got[i])]
+    h_in = jnp.asarray(np.stack([ffn_in[i][position][0]
+                                 for i, position in at]))
+    served_moe = np.asarray(jax.jit(
+        lambda lp, h: glm_dsa.ffn(lp, h, jnp.ones(h.shape[0], bool),
+                                  cfg)[0])(
+        glm_dsa.layer_leaves(engine_params["blocks"], cfg, 0)["shortcut"],
+        h_in), np.float64)
+    chosen = np.stack([all_routed[i][0, position] for i, position in at])
+    moe_err = {}
+    for name, switch in {"plain": {}, **LONGCAT_NEGATIVES}.items():
+        with jax.default_matmul_precision("highest"):
+            want_moe = ref.moe_ffn(first, h_in.astype(jnp.float32),
+                                   dict(ref_cfg, **switch), held,
+                                   forced=chosen)
+        moe_err[name] = probe_rel(served_moe, np.asarray(want_moe,
+                                                         np.float64))
+    say("the first layer's MoE on its own input: " + ", ".join(
+        f"{name} {err:.3e}" for name, err in moe_err.items()))
+    del first, engine_params, want_moe
+
+    def readings(which, logits_of, routing_of, config=ref_cfg,
+                 against=None, name="plain"):
+        """Over the compared positions of the jobs `which`, the served
+        logits against `logits_of`: mean and worst |error| / range;
+        `moe_err`, the first layer's MoE on its own input (above);
+        `agree` along the forced trajectory (the least over the layers);
+        the router on the served path's own input; against: the plain
+        reference's logits (`nearer`, reported)."""
+        errs = logit_errors(got, logits_of, which)
+        same = np.zeros(Ls)
+        to_this = to_plain = 0.0
+        for i, position, _ in errs:
+            same += [set(all_routed[i][layer, position].tolist())
+                     == set(routing_of[i][layer][position].tolist())
+                     for layer in range(Ls)]
+            if against is not None:
+                to_this += float(np.sum(np.square(
+                    got[i][position] - logits_of[i][position])))
+                to_plain += float(np.sum(np.square(
+                    got[i][position] - against[i][position])))
+        agree_same, logit_err = same_router_input(
+            ref, router, got, ffn_in, all_routed, which, config)
+        out = {"mean": float(np.mean(np.concatenate(
+                   [e for _, _, e in errs]))),
+               "max": max(float(e.max()) for _, _, e in errs),
+               "mean_decode": float(np.mean(np.concatenate(
+                   [e for i, p_, e in errs if p_ >= prompts[i]]))),
+               "agree": float(same.min()) / len(errs),
+               "agree_same_input": agree_same,
+               "router_logit_err": logit_err, "moe_err": moe_err[name],
+               "positions": len(errs)}
+        if against is not None:
+            out["nearer"] = (to_this / max(to_plain, 1e-300)) ** 0.5
+        return out
+
+    def passes(r):
+        return (all(r[k_] < limit for k_, limit in LONGCAT_TOL.items())
+                and r["agree_same_input"] > LONGCAT_AGREE
+                and r["probe"] < DSV2_TOL["probe"]
+                and r["probe_window"] < DSV2_TOL["probe"])
+
+    plain = list(range(len(jobs)))
+    want, want_routing = reference(plain)
+    served = readings(plain, want, want_routing)
+    served["probe"], served["probe_window"] = (probe["served"],
+                                               probe_window["served"])
+    expected = sum(
+        len({q for q in range(p + n_decode)
+             if q >= p - last or q < LONGCAT_START
+             or C <= q < C + LONGCAT_EDGE}) for p in prompts)
+    result = {
+        "served": served, "expected_positions": expected,
+        "tol": dict(LONGCAT_TOL, probe=DSV2_TOL["probe"],
+                    agree_same_input_floor=LONGCAT_AGREE),
+        "probe": probe, "probe_window": probe_window,
+        "zero_pairs_share": round(zero_share, 4), "seed": args.seed,
+        "jobs": [list(j) for j in jobs], "rows_a_step": B, "steps": steps,
+        "attention": impl, "device": jax.devices()[0].device_kind,
+        "heads": geo.heads,
+    }
+    ok = served["positions"] == expected and passes(served)
+    if not ok:
+        say("FAILED: the served path is outside the tolerance")
+
+    # -- what must NOT pass: the reference, altered, read as the served
+    # path is (on the shorter job; the probes' reading is the served
+    # kernels' but for the softmax's own negative) -----------------------
+    if args.negatives:
+        short = [min(plain, key=lambda i: prompts[i])]
+        result["must_fail"] = {}
+        for name, switch in LONGCAT_NEGATIVES.items():
+            say(f"negative: {name}")
+            config = dict(ref_cfg, **switch)
+            logits, routing = reference(short, config)
+            r = readings(short, logits, routing, config=config,
+                         against=want, name=name)
+            r["probe"] = probe.get(name, probe["served"])
+            r["probe_window"] = probe_window.get(name,
+                                                 probe_window["served"])
+            result["must_fail"][name] = r
+            if passes(r):
+                say(f"FAILED: the reference with {name} passes the "
+                    "tolerance")
+                ok = False
+    result["ok"] = bool(ok) or bool(args.rehearse and served["positions"]
+                                    == expected)
+    result["seconds"] = round(time.monotonic() - t_start, 1)
+    with open(os.path.join(OUT_DIR, f"result_longcat_seed{args.seed}.json"),
               "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result), flush=True)

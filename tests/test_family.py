@@ -20,8 +20,8 @@ from cake_tpu.models.llama.model import RopeTables
 from cake_tpu.models.llama.paged import PagedKVCache, mixed_token_buckets
 from cake_tpu.models.moe.config import (
     BailingHybridConfig, BrumbyConfig, DeepseekV2Config, Dots3NoteConfig, ExaoneMoeConfig,
-    GlmMoeDsaConfig, GraniteHybridConfig, KeyeVL2Config, MoEConfig,
-    NemotronHConfig,
+    GlmMoeDsaConfig, GraniteHybridConfig, KeyeVL2Config, LongcatFlashConfig,
+    MoEConfig, NemotronHConfig,
     ZayaConfig,
 )
 from cake_tpu.obs import steps as obs_steps
@@ -43,6 +43,7 @@ TINY = {
     "granitemoehybrid": GraniteHybridConfig.tiny_granite,
     "KeyeVL2": KeyeVL2Config.tiny_keye,
     "brumby": BrumbyConfig.tiny_brumby,
+    "longcat_flash": LongcatFlashConfig.tiny_longcat,
 }
 # the families whose rows hold more than K/V pages, and the noun of each
 NOUNS = {"glm_moe_dsa": "latent row and index key",
@@ -51,7 +52,7 @@ NOUNS = {"glm_moe_dsa": "latent row and index key",
          "nemotron_h": "state", "zaya": "tail",
          "bailing_hybrid": "KDA state", "exaone_moe": "K/V ring",
          "granitemoehybrid": "state", "KeyeVL2": "index-key pool",
-         "brumby": "state"}
+         "brumby": "state", "longcat_flash": "latent row"}
 SLOTS, PAGES, PAGE, WIDTH, SEQ = 4, 16, 4, 8, 64
 
 
@@ -148,8 +149,16 @@ def engine_options(c, params):
 
 @pytest.fixture(scope="module")
 def models():
-    return {t: (tiny_config(t), init_params(tiny_config(t)))
-            for t in NOUNS}
+    """model_type -> (its tiny config, its seeded parameters), a family
+    drawn when a case first asks for it and kept: an xdist worker that
+    meets one case draws one family, not all of them."""
+    class Drawn(dict):
+        def __missing__(self, model_type):
+            c = tiny_config(model_type)
+            self[model_type] = c, init_params(c)
+            return self[model_type]
+
+    return Drawn()
 
 
 @pytest.mark.parametrize("option", _CANNOT_MOVE)
@@ -207,7 +216,7 @@ def test_the_readmes_table_is_the_families_tables():
 
 NAMES = ("kv_lora_rank", "mamba_layers", "cca_time0", "sliding_layers",
          "nemotron", "zaya", "glm", "dots3", "deepseek", "rope_scaling",
-         "n_group", "bailing", "kda", "granite")
+         "n_group", "bailing", "kda", "granite", "longcat", "zero_expert")
 
 
 @pytest.mark.parametrize("where", ["cake_tpu/serve/engine.py",

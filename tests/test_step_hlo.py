@@ -775,3 +775,54 @@ def test_expert_matmul_compiles_at_the_tile_the_budget_admits(
     assert "tpu_custom_call" in hlo and "cake_moe_gmm" in hlo
     assert grid.tn > 512 or (K, N) == (6144, 2048)
     assert moe.gmm_vmem_bytes(tm, K, grid.tn, 2, 1, True) > 10 * 2**20
+
+
+def test_longcats_step_programs_run_both_latent_kernels_at_64_heads(
+        tool, one_chip):
+    """A one-layer cut (two sublayers) of LongCat-Flash's served programs
+    at the cell's widths, slots, pool and window for the described v5e:
+    `cake_mla_decode_attn` in every sublayer of both programs and
+    `cake_mla_window_attn` in every sublayer of the mixed one go through
+    Mosaic at 64 heads (four pages a fold over a table of 76), the
+    layer's routed experts are three `cake_moe_gmm` calls, and the only
+    int8 arrays written to memory are the per-head views of the two
+    latent up-projections (`s8[1,512,8192]`), which every model of this
+    trunk copies (PERF.md section 7, PR 43's question): no dense FFN,
+    projection, expert or head is materialised."""
+    import json
+
+    from cake_tpu.models.llama.config import load_config
+    from cake_tpu.ops import mla_attention as mla
+
+    cell = CONFIGS / "longcat-flash-int8-share32"
+    config = load_config(str(cell))
+    config = dataclasses.replace(
+        config, num_hidden_layers=2, mlp_layer_types=("shortcut", "dense"),
+        indexer_types=("dense",) * 2)
+    with open(cell / "cell.json") as f:
+        cell = json.load(f)
+    sa = cell["server_args"]
+    shape = dict(slots=sa["max-slots"], n_pages=sa["kv-pages"],
+                 page_size=sa["kv-page-size"], max_seq_len=sa["max-seq-len"])
+    width = cell["shape"]["mixed_width"]
+    decode, mixed = tool.step_fns(config)
+    with jax.default_matmul_precision("default"):
+        programs = {
+            "decode": tool.compile_step(decode, config, one_chip, **shape),
+            "mixed": tool.compile_step(
+                mixed, config, one_chip, width=width,
+                n_tokens=width + sa["max-slots"], **shape)}
+    assert mla.decode_block(64, 640, 512, 128, 76, 2) == 4
+    for name, compiled in programs.items():
+        hlo = compiled.as_text()
+        calls = [line for line in hlo.splitlines() if "custom-call(" in line]
+
+        def count(kernel):
+            return sum(kernel in line for line in calls)
+
+        assert count("cake_mla_decode_attn") == 2, name
+        assert count("cake_mla_window_attn") == (2 if name == "mixed"
+                                                 else 0), name
+        assert count("cake_moe_gmm") == 3, name
+        assert {m.shape for m in tool.materialised_int8(hlo)} <= {
+            "s8[1,512,8192]"}, name
