@@ -692,7 +692,7 @@ def _kernel_pools(pool_k, pool_v):
 
 def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                     impl: str = "fold", window: Optional[int] = None,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, selected=None):
     """Ragged decode attention over layer `layer` of the paged KV.
 
     impl="fold" (the documented REFERENCE semantics): an XLA fori_loop
@@ -715,7 +715,10 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
     own included, and `table` may be a ring [B, R] (the kernel walks
     the pages of the band; None: every key). scale (static): what the
     scores are multiplied by, for a model that states its own (None:
-    1/sqrt(hd)).
+    1/sqrt(hd)). selected [B, max_pages, page] float32: the keys a row
+    attends among those up to its pos, by page, above 0.5 where it does
+    (a sparse indexer's set; None, and no operand of the kernel, for
+    every model without one).
     Returns [B, 1, H, hd].
     """
     if impl == "pallas":
@@ -723,12 +726,18 @@ def paged_attention(q, pool_k, pool_v, layer, table, pos, *,
             ragged_paged_attention,
         )
         kq, vq, kw = _kernel_pools(pool_k, pool_v)
+        if selected is not None:
+            kw["selected"] = selected
         return ragged_paged_attention(q, kq, vq, layer, table, pos,
                                       window=window, scale=scale, **kw)
     if impl != "fold":
         raise ValueError(f"unknown paged_attn impl {impl!r}")
+    if selected is not None:
+        if window is not None:
+            raise ValueError("a selection is served without a band only")
+        selected = selected[:, :, None, :]
     return _fold_pages(q, pool_k, pool_v, layer, table, pos[:, None],
-                       window, scale)
+                       window, scale, selected)
 
 
 @_partial(jax.jit,

@@ -26,18 +26,29 @@ with its residual:
     visible key of the token's row in float32
     (ops/mla_attention.index_scores_rows / index_scores_window: GLM's
     and dots3's); `index_topk`: the exact top `index_topk`, ties to the
-    lower index (lax.top_k for a row's single token; for a window
-    ops/mla_attention.select_window, ONE kernel a layer,
-    `cake_dsa_select`, whose work follows the window's last position
-    and not the table's width);
+    lower index, as a MASK over the row's table
+    (ops/mla_attention.select_window, `cake_dsa_select`, whose work
+    follows the last position it is given and not the table's width):
+    one call a layer for the dispatch's window, and one for the rows'
+    single tokens, ONE tile of B queries that differ in row and
+    position;
   * `gqa_full`: the write of every real token's k and v into its page,
-    then attention over the SELECTED keys alone. A row's single token:
-    its positions sorted ascending, its K and V rows gathered out of the
-    pools (`dsa_gather`: two XLA gathers of [rows, index_topk, KV * hd])
-    and laid out as pages of a pool of their own, which
-    `cake_decode_attn` walks as it walks any row's pages. While a row
-    holds no more than index_topk keys the gathered pages ARE its pages
-    and the result is the unselected kernel's, bit for bit. The
+    then attention over the SELECTED keys alone, WHERE THEY LIE. A
+    row's single token: `cake_decode_attn` walks the row's own live
+    pages of the layer's own pools and attends, of each, the keys its
+    mask marks (`selected=`: the row's [max_pages, page] block beside
+    its query). While a row holds no more than index_topk keys its mask
+    marks all it sees and the result is the unselected kernel's, bit
+    for bit. WHY A WALK AND NOT A GATHER: moving the selected rows out
+    of the pools (a full sort for `lax.top_k`, a second for their order,
+    a page look-up and two XLA gathers of [rows, index_topk, KV * hd] a
+    layer) read 0.98 ms a call of 8 rows at every length on a v5e, the
+    selection and the walk 0.15 ms at 4k keys a row, 0.26 at 8k, 0.48
+    at 16k and 0.92 at a table's end of 33,280
+    (tools/decode_selected_bench.py holds both forms; PERF.md section
+    6, PR 66). The walk costs 0.42 us a page and row, so past ~36k keys
+    a row the gather would win again: a table wider than that is where
+    this choice is owed a second look. The
     dispatch's one window: `cake_mixed_attn` over the row's pages where
     they lie, in entries of `exaone_moe.query_tile` queries, with the
     selection streamed in beside the pages as a per-(query, key) mask
@@ -85,12 +96,14 @@ from cake_tpu.ops.rope import apply_rope
 # keys: the same quantities); what the single-token rows attended and
 # what their indexers scored; those rows, and the pages the dispatch's
 # rows' contexts fill; the keys the window's selection walked, and the
-# table's width beside them
+# table's width beside them; the pages the single-token rows walked
+# under their masks, and the rows that did
 COUNTERS = paged.MOE_COUNTERS + (
     "moe_rows_routed", "dsa_keys_visible", "dsa_keys_selected",
     "dsa_rows_distinct", "dsa_index_layers", "dsa_keys_single",
     "dsa_keys_scanned_single", "gqa_rows_single", "gqa_full_pages_live",
-    "dsa_select_keys_walked", "dsa_select_keys_table")
+    "dsa_select_keys_walked", "dsa_select_keys_table",
+    "dsa_walk_pages_single", "dsa_walk_rows_single")
 F32 = jnp.float32
 
 
@@ -120,14 +133,12 @@ def reference_config(config: KeyeVL2Config) -> dict:
 
 
 class Selection(NamedTuple):
-    """A layer's key sets. idx [B, K] / n_valid [B]: each row's SINGLE
-    token's selected positions in the row, ASCENDING, of which the first
-    n_valid are real (the tail past them holds the row's capacity, a
-    position nobody reads). picked [C, S] bool: the window's sets (None
-    where there is no window)."""
+    """A layer's key sets, as masks over a row's table. rows [B, S]
+    bool: each row's SINGLE token's set among its own keys (a row with
+    no single token selects nothing); picked [C, S] bool: the window's
+    sets (None where there is no window)."""
 
-    idx: jnp.ndarray
-    n_valid: jnp.ndarray
+    rows: jnp.ndarray
     picked: Optional[jnp.ndarray]
 
 
@@ -147,7 +158,6 @@ def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
     P, max_pages = pool_idx.shape[2], table.shape[1]
     S = max_pages * P
     K = min(c.index_topk, S)
-    span = jnp.arange(S)[None, :]
     with jax.named_scope("indexer"):
         qI = apply_rope(qmatmul(h, lp["wi_q"]).reshape(1, T, nI, dI),
                         cos, sin)[0]
@@ -163,59 +173,37 @@ def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
         keys = pool_idx.at[layer, jnp.maximum(table, 0)].get(
             mode="promise_in_bounds").reshape(table.shape[0], S, dI)
         rows = mla.index_scores_rows(qI[first], keys, w[first])   # [B, S]
-        rows = jnp.where(span <= single_pos[:, None], rows, -jnp.inf)
         if window is not None:
             win = mla.index_scores_window(
                 _window_slice(qI, window), keys[window.row],
                 _window_slice(w, window), win_last,
                 _key_block(max_pages, P))
     with jax.named_scope("index_topk"):
-        _, idx = lax.top_k(rows, K)
-        n_valid = jnp.minimum(single_pos + 1, K).astype(jnp.int32)
-        # ascending, so that a row of no more than K keys gathers its
-        # own pages in their own order; the tail past n_valid sorts last
-        idx = jnp.sort(jnp.where(jnp.arange(K)[None, :] < n_valid[:, None],
-                                 idx, S - 1), axis=1)
+        # the rows' sets by the window's kernel: ONE tile of B queries,
+        # each with its own scores and position (a row sees the keys at
+        # or before its single token, one without sees and selects
+        # nothing), the walk bounded by the longest of them
+        own = mla.select_window(rows, single_pos, jnp.max(single_pos), K)
         picked = None
-        distinct = jnp.sum(n_valid, dtype=F32)
+        distinct = jnp.sum(jnp.minimum(single_pos + 1, K), dtype=F32)
         if window is not None:
             picked = mla.select_window(
                 win, win_pos + jnp.arange(window.width), win_last, K)
             in_window = jnp.arange(window.width) < window.n
             distinct = distinct + jnp.sum(
                 jnp.any(picked & in_window[:, None], axis=0), dtype=F32)
-    return pool_idx, Selection(idx.astype(jnp.int32), n_valid, picked), distinct
+    return pool_idx, Selection(own, picked), distinct
 
 
-def attend_rows(q, pool_k, pool_v, layer, table, selection: Selection,
-                attn: str):
-    """Each row's single query q [B, H, hd] over ITS selected keys: the
-    K and V rows gathered out of the pools (`dsa_gather`) and laid out
-    as pages of a one-layer pool of their own, B * K / page of them,
-    which the decode kernel walks through a table of consecutive ids up
-    to position n_valid - 1 (a row with no single token: -1, no trip,
-    zeros) -> [B, H, hd]."""
+def attend_rows(q, pool_k, pool_v, layer, table, single_pos, own, attn: str):
+    """Each row's single query q [B, H, hd] over ITS selected keys
+    where they lie: `cake_decode_attn` walks the row's own live pages
+    up to single_pos and attends, of each, the keys `own` [B, S] marks
+    (a row with no single token: -1, no trip, zeros) -> [B, H, hd]."""
     B = q.shape[0]
-    P, width = pool_k.shape[2], pool_k.shape[3]
-    K = selection.idx.shape[1]
-    Kp = -(-K // P) * P
-    with jax.named_scope("dsa_gather"):
-        # the tail past n_valid may name an unmapped page: read page 0
-        # there (finite, never attended)
-        rows = jnp.arange(B)[:, None]
-        pages = jnp.maximum(table[rows, selection.idx // P], 0)
-        at = selection.idx % P
-
-        def gathered(pool):
-            g = pool.at[layer, pages, at].get(mode="promise_in_bounds")
-            g = jnp.pad(g, ((0, 0), (0, Kp - K), (0, 0)))
-            return g.reshape(1, B * Kp // P, P, width)
-
-        gk, gv = gathered(pool_k), gathered(pool_v)
-    own = jnp.arange(B * Kp // P, dtype=jnp.int32).reshape(B, Kp // P)
     return paged.paged_attention(
-        q[:, None], gk, gv, jnp.int32(0), own, selection.n_valid - 1,
-        impl=attn)[:, 0]
+        q[:, None], pool_k, pool_v, layer, table, single_pos, impl=attn,
+        selected=own.astype(F32).reshape(B, table.shape[1], -1))[:, 0]
 
 
 def attend_window(q, pool_k, pool_v, layer, table_row, first_pos, n, picked,
@@ -250,18 +238,19 @@ class TrunkOut(NamedTuple):
     and for a tool that compares them with the reference's
     (chip_compare.py; a step program drops them): experts [L, T, k],
     each layer's choice; ffn_in [L, T, D], each layer's normed input
-    (what its router read); selected [L, B, K] / n_selected [B], each
-    row's single token's keys, ascending; selected_window [L, C, S]
-    bool, the window's sets (empty unless the trunk was asked to
-    `probe`: 17 MB a layer at the cell's sizes)."""
+    (what its router read); n_selected [B], the keys each row's single
+    token selects; selected [L, B, S] bool, those rows' sets, and
+    selected_window [L, C, S] bool, the window's (both empty unless the
+    trunk was asked to `probe`: the window's are 17 MB a layer at the
+    cell's sizes)."""
 
     x: jnp.ndarray
     cache: PagedKVCache
     counters: jnp.ndarray
     experts: jnp.ndarray
     ffn_in: jnp.ndarray
-    selected: jnp.ndarray
     n_selected: jnp.ndarray
+    selected: jnp.ndarray
     selected_window: jnp.ndarray
 
 
@@ -316,7 +305,7 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
                 pool_v = write_token_rows(pool_v, layer, v, slot, position,
                                           real, table)
                 out = attend_rows(q[first], pool_k, pool_v, layer, table,
-                                  selection, attn)
+                                  single_pos, selection.rows, attn)
                 if window is None:
                     o = out[slot]
                 else:
@@ -335,13 +324,13 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
             out, stats = glm_dsa.ffn(lp, h, real, c)
             x = x + out
         # (None is no leaf: a step program's scan stacks no masks)
-        sets = selection.picked if probe else None
+        sets = (selection.rows, selection.picked) if probe else None
         return ((x, layer + 1, pool_k, pool_v, pool_idx),
-                (stats, h, distinct, selection.idx, sets))
+                (stats, h, distinct, sets))
 
     with jax.named_scope("layers"):
         (x, _, pool_k, pool_v, pool_idx), (
-            moe, ffn_in, distinct, selected, sets) = lax.scan(
+            moe, ffn_in, distinct, sets) = lax.scan(
                 body, (x, jnp.int32(0), cache.k, cache.v, cache.idx), scanned)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
@@ -350,6 +339,7 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
     K = min(c.index_topk, S)
     visible = jnp.where(real, position + 1, 0).astype(F32)
     single = rows.n == 1
+    n_single = jnp.sum(single, dtype=F32)
     last = rows.pos + rows.n - 1
     # what the window's selection walked, and the table's width
     walked = [0, 0] if window is None else [
@@ -363,15 +353,19 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
         L * jnp.sum(jnp.where(single, jnp.minimum(last + 1, K), 0),
                     dtype=F32),
         L * jnp.sum(jnp.where(single, last + 1, 0), dtype=F32),
-        jnp.sum(single, dtype=F32),
+        n_single,
         jnp.sum(jnp.where(rows.n > 0, last // P + 1, 0), dtype=F32),
         *walked,
+        # every single-token row attends by the walk under its mask
+        L * jnp.sum(jnp.where(single, last // P + 1, 0), dtype=F32),
+        n_single,
     ]).astype(F32)
     return TrunkOut(
         x, cache._replace(k=pool_k, v=pool_v, idx=pool_idx), counters,
-        moe.experts, ffn_in, selected,
+        moe.experts, ffn_in,
         jnp.minimum(single_pos + 1, K).astype(jnp.int32),
-        jnp.zeros((0,), bool) if sets is None else sets)
+        *(jnp.zeros((0,), bool) if s is None else s
+          for s in (sets or (None, None))))
 
 
 # -- the step programs ---------------------------------------------------------
@@ -415,14 +409,15 @@ def mixed_step_selected(params, tokens, pos, q_len, active,
 
 
 def decode_trunk(params, tokens, cache: PagedKVCache, pos, active, rope,
-                 config: KeyeVL2Config, attn: str) -> TrunkOut:
+                 config: KeyeVL2Config, attn: str,
+                 probe: bool = False) -> TrunkOut:
     """One token a row: tokens [B, 1], pos/active [B]."""
     B = tokens.shape[0]
     rows = jnp.arange(B, dtype=jnp.int32)
     pos = pos.astype(jnp.int32)
     return trunk(params, tokens[:, 0], rows, pos, active,
                  Rows(rows, active.astype(jnp.int32), pos), cache, rope,
-                 config, attn)
+                 config, attn, probe=probe)
 
 
 def forward_ragged_selected(params, tokens, cache: PagedKVCache, pos, active,
@@ -496,8 +491,10 @@ FAMILY = Family(
     # is k dispatches (GLM's and dots3's form)
     prefill_rows=(1,), windows=Windows.DISPATCH,
     impl="paged-dsa-gqa-", resolve_attn=_resolve_attn,
-    # no step kind's rows go through the kernels AS THEY ARE: a single
-    # token's call walks its gathered rows, the window's carries a mask
+    # no step kind's rows go through the kernels AS THEY ARE: both
+    # calls carry a mask (a single token's walks its row's own pages
+    # since PR 66, and the step program counts them itself:
+    # dsa_walk_pages_single)
     kernel_rows=(), mixed_attn_walk=mixed_attn_walk,
     what="selected keys over K/V pages and an index-key pool beside them",
     refuses=cannot_move(

@@ -534,6 +534,17 @@ DSA_GQA_COUNTERS = (
         "cake_dsa_keys_scanned_single_total",
         "Index keys the single-token rows' indexers scored (position + "
         "1 each: every visible key), summed over rows and layers")),
+    ("dsa_walk_pages_single", _m.counter(
+        "cake_dsa_walk_pages_single_total",
+        "Pool pages the single-token rows walked under their selection's "
+        "mask (position // page + 1 each: what cake_decode_attn(selected=) "
+        "read to attend the selected keys where they lie), summed over "
+        "rows and layers")),
+    ("dsa_walk_rows_single", _m.counter(
+        "cake_dsa_walk_rows_single_total",
+        "Single-token rows that attended by that walk, summed over "
+        "dispatches (over cake_gqa_rows_single_total: the share of them "
+        "it serves)")),
 )
 # a model of power retention layers (models/moe/brumby.trunk): the rows'
 # matrix state, the page pool of no layers beside it, and the two forms

@@ -111,7 +111,7 @@ def _unpack_nibbles(block, hd_slice):
 
 def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
                  page_size: int, kv_heads: int, group: int, head_dim: int,
-                 packed4: bool, window: Optional[int] = None):
+                 packed4: bool, window: Optional[int] = None, sel=None):
     """Fold one live page of a decode row into its online-softmax
     stats: the one body of the float, int8 and int4 pools.
 
@@ -128,6 +128,9 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
              recurrence of `ops/flash_attention.py`; returned updated.
     window:  the band (walk_live_pages): slots at or below pos - window
              are masked as the slots past pos are.
+    sel:     None, or [1, page] float32: the page's row of a selection
+             (a sparse indexer's set); a slot is attended only where its
+             entry is above 0.5, among those the other rules leave.
     """
     P = page_size
     hd = head_dim
@@ -142,12 +145,14 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
         return h if scales is None else h.astype(jnp.float32)
 
     # causal mask over the page's absolute slots (current token
-    # included); every folded page has >= 1 valid column, so the online
-    # max below never sees a fully-masked row
+    # included); without a selection every folded page has >= 1 valid
+    # column, so the online max below never sees a fully-masked row
     col = j * P + jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
     col_valid = col <= pos                         # [1, P]
     if window is not None:
         col_valid = jnp.logical_and(col_valid, col > pos - window)
+    if sel is not None:
+        col_valid = jnp.logical_and(col_valid, sel > 0.5)
     # scores a kv head: query group g of kv head k against the page's
     # k-lane slice (static unroll: KV is small)
     parts = []
@@ -161,6 +166,10 @@ def _decode_fold(q, k_page, v_page, scales, j, pos, stats, *, scale: float,
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)                         # [H, P]
+    if sel is not None:
+        # a page with nothing selected, before any that has: m_new stays
+        # NEG_INF and exp(s - m_new) is exp(0) = 1 (_mixed_fold's guard)
+        p = p * col_valid.astype(jnp.float32)
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
     outs = []
     for kv in range(kv_heads):
@@ -347,20 +356,27 @@ def walk_live_pages(layer_ref, pos_ref, table_ref, cur, copies, *,
 
 
 def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
-                   depth: int, page_size: int, kv_heads: int,
-                   window: Optional[int] = None, **fold_shape):
+                   selecting: bool, depth: int, page_size: int,
+                   kv_heads: int, window: Optional[int] = None,
+                   **fold_shape):
     """One grid step: one ROW of the ragged decode fold, its live pages
     of the layer walked by `walk_live_pages`, K and V together.
 
     sk_ref/sv_ref (a quantized pool only): the layer's flat scales
     q_ref/o_ref:   [1, 1, H, hd], the row's query and result
     k_hbm/v_hbm:   [L, N_pages, page, KV*hd], never read but by a copy
+    sel_ref        (selecting only): [1, max_pages, page] float32, the
+                   row's selection by LOGICAL page, whole in VMEM
     kbuf/vbuf:     [depth, page, KV*hd] VMEM; sem: DMA [2, depth]
     cur:           SMEM int32 [4], the walk's
     """
     if quantized:
         sk_ref, sv_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, cur = refs
+    q_ref, k_hbm, v_hbm, *refs = refs
+    sel_ref = None
+    if selecting:
+        sel_ref, *refs = refs
+    o_ref, kbuf, vbuf, sem, cur = refs
     H, hd = q_ref.shape[2:]
 
     def copies(layer, pid, slot, *_trip):
@@ -385,7 +401,9 @@ def _decode_kernel(layer_ref, pos_ref, table_ref, *refs, quantized: bool,
                 return sk_ref[at], sv_ref[at]
         return _decode_fold(q, kbuf.at[slot], vbuf.at[slot], scales, j, pos,
                             stats, page_size=page_size, kv_heads=kv_heads,
-                            window=window, **fold_shape)
+                            window=window,
+                            sel=sel_ref[0, pl.ds(j, 1)] if selecting else None,
+                            **fold_shape)
 
     _, l, acc = pages(fold, (jnp.full((H, 1), NEG_INF, jnp.float32),
                              jnp.zeros((H, 1), jnp.float32),
@@ -414,6 +432,7 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                            scale_k=None, scale_v=None,
                            packed4: bool = False,
                            window: Optional[int] = None,
+                           selected=None,
                            interpret: bool | None = None):
     """Ragged decode attention over a paged KV pool, one Pallas kernel.
 
@@ -436,6 +455,17 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                   included, and walks the pages that hold them alone;
                   `table` may then be a ring (walk_live_pages). None:
                   every key up to pos.
+    selected:     [B, max_pages, page] float32, or None. Row b attends
+                  key j * page + o only where selected[b, j, o] is above
+                  0.5, among the keys up to its pos (a sparse indexer's
+                  set): the row walks its own live pages under the mask,
+                  a row's [max_pages, page] block in VMEM a grid step
+                  (float32 as the mixed kernel's: a page's row of it
+                  loads at a dynamic sublane like any 32-bit block's,
+                  and 133 KB a row at a table of 260 pages is 0.2 us of
+                  copy; a packed int8 block was not tried). A float
+                  pool without a band only. None: no such operand, and
+                  the program is the one it was.
     Returns [B, 1, H, hd] in q.dtype. Numerically matches
     `models/llama/paged.py:paged_attention` (the fold reference) to f32
     tolerance — tests/test_ragged_paged_attn.py pins the parity.
@@ -464,6 +494,10 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
             f"{P} H={H} KV={KV} hd={hd} pool={pool_k.dtype} pages={N} "
             f"table={B}x{max_pages} (ragged_paged_supported); use the "
             "fold")
+    selecting = selected is not None
+    if selecting and (quantized or window is not None):
+        raise ValueError("a selection is served over a float pool "
+                         "without a band only")
 
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     operands = [layer, jnp.asarray(pos, jnp.int32),
@@ -473,15 +507,20 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
                      _layer_scales(scale_v, layer[0])]
     depth = decode_ring_depth(Pb * width * pool_k.dtype.itemsize)
     kernel = functools.partial(
-        _decode_kernel, quantized=quantized, depth=depth, scale=scale,
-        page_size=P, kv_heads=KV, group=G, head_dim=hd, packed4=packed4,
-        window=window)
+        _decode_kernel, quantized=quantized, selecting=selecting,
+        depth=depth, scale=scale, page_size=P, kv_heads=KV, group=G,
+        head_dim=hd, packed4=packed4, window=window)
     row = pl.BlockSpec((1, 1, H, hd), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    inputs, in_specs = [q, pool_k, pool_v], [row, hbm, hbm]
+    if selecting:
+        inputs.append(selected.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((1, max_pages, P),
+                                     lambda b, *_: (b, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(operands),
         grid=(B,),
-        in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs,
         out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((depth, Pb, width), pool_k.dtype),
@@ -500,7 +539,7 @@ def ragged_paged_attention(q, pool_k, pool_v, layer, table, pos, *,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(*operands, q, pool_k, pool_v)
+    )(*operands, *inputs)
 
 
 # Queries to a TILE of the mixed kernel's window. A row's walk folds

@@ -4065,8 +4065,9 @@ def compare_keye(engine, cell, args, t_start) -> int:
     filler_tokens = rng.integers(0, cfg.vocab_size, (B, n_steps_most))
 
     def sets_of(picked):
-        """A window's sets [L, C, S] bool as ascending index lists
-        [L, C, K] uint16, S_row where a set has fewer than K."""
+        """Sets [L, C, S] bool (a window's queries, or the rows' single
+        tokens) as ascending index lists [L, C, K] uint16, S_row where
+        a set has fewer than K."""
         score = jnp.where(picked, S_row - jnp.arange(S_row), 0)
         top, _ = jax.lax.top_k(score, K)
         return jnp.where(top > 0, S_row - top, S_row).astype(jnp.uint16)
@@ -4076,14 +4077,15 @@ def compare_keye(engine, cell, args, t_start) -> int:
     def window_step(params, tokens, pos, q_len, active, cache, n_tokens):
         out, _ = kv2.mixed_trunk(params, tokens, pos, q_len, active, cache,
                                  rope, cfg, attn, n_tokens, probe=True)
-        return (out.x, out.cache, out.experts, out.selected,
+        return (out.x, out.cache, out.experts, sets_of(out.selected),
                 out.n_selected, sets_of(out.selected_window))
 
     @partial(jax.jit, donate_argnames=("cache",))
     def decode_step(params, tokens, pos, active, cache):
         out = kv2.decode_trunk(params, tokens, cache, pos, active, rope,
-                               cfg, attn)
-        return out.x, out.cache, out.experts, out.selected, out.n_selected
+                               cfg, attn, probe=True)
+        return (out.x, out.cache, out.experts, sets_of(out.selected),
+                out.n_selected)
 
     @jax.jit
     def head(params, x):

@@ -160,6 +160,50 @@ def test_a_table_that_is_no_multiple_of_128_is_one_chunk():
                     S // 2 + C - 1, 9)
 
 
+def rows_top_k(scores, positions, k):
+    """What a selecting model's single-token rows took before they went
+    through the kernel: `lax.top_k` over the keys at or before each
+    row's position, the first min(position + 1, k) of it, as sets."""
+    S = scores.shape[1]
+    seen = jnp.arange(S)[None, :] <= jnp.asarray(positions)[:, None]
+    _, idx = jax.lax.top_k(jnp.where(seen, jnp.asarray(scores), -jnp.inf), k)
+    sets = np.zeros(scores.shape, bool)
+    for b, p in enumerate(positions):
+        sets[b, np.asarray(idx[b, :min(int(p) + 1, k)])] = True
+    return sets
+
+
+# 2,080 keys: a test's table, one chunk; 33,280: Keye's
+@pytest.mark.parametrize("S", [256, 2080, 33280])
+@pytest.mark.parametrize("scores_are", ["distinct", "relu_floor"])
+def test_one_tile_of_rows_is_each_rows_own_top_k(S, scores_are):
+    """ONE tile of 8 queries that are eight ROWS: each with its own
+    scores and its own position (one of them -1: a row with no single
+    token), `last_pos` the greatest. Each row's set is `lax.top_k`'s
+    over what it sees, ties at the k-th value (half the scores exactly
+    0.0, the relu's floor) to the lower index in both; a row shorter
+    than k selects all it sees, the -1 row nothing."""
+    C = 8
+    k = S // 8
+    assert mla.select_tiles(C, S)[0] == C
+    positions = np.asarray([S - 1, k - 1, k, -1, S // 2, 3, k // 2,
+                            (3 * S) // 4], np.int32)
+    scores = np.abs(scores_of(C, S, S)) + 0.5
+    if scores_are == "relu_floor":
+        scores[np.random.default_rng(1).random((C, S)) < 0.5] = 0.0
+    got = assert_same(scores, positions, int(positions.max()), k)
+    np.testing.assert_array_equal(got, rows_top_k(scores, positions, k))
+    np.testing.assert_array_equal(got.sum(axis=1),
+                                  np.minimum(positions + 1, k))
+
+
+def test_a_tile_of_rows_with_no_single_token_selects_nothing():
+    """Every position -1 (a dispatch that holds a window alone): the
+    bound clips to the first block and nothing is seen."""
+    got = kernel(scores_of(8, 256, 9), np.full(8, -1, np.int32), -1, 16)
+    assert not np.asarray(got).any()
+
+
 @pytest.mark.parametrize("C,S,tiles", [
     (512, 33280, (128, 128, 1664)),     # keyevl2.longctx-closed
     (512, 12800, (128, 128, 1280)),     # glm52.longdoc-closed

@@ -72,9 +72,10 @@ def mixed(model, cache, toks, pos, qlen, attn="fold"):
 
 def decode(model, cache, toks, pos, active, attn="fold"):
     c, params, rope = model
-    return jax.jit(kv2.decode_trunk, static_argnames=("config", "attn"))(
+    return jax.jit(kv2.decode_trunk, static_argnames=(
+        "config", "attn", "probe"))(
         params, jnp.asarray(toks), cache, jnp.asarray(pos),
-        jnp.asarray(active), rope, config=c, attn=attn)
+        jnp.asarray(active), rope, config=c, attn=attn, probe=True)
 
 
 def serve(model, sequences, prompts, attn="fold"):
@@ -95,9 +96,11 @@ def serve(model, sequences, prompts, attn="fold"):
     def keep(i, position, logits, out, window_col=None):
         got[i][position] = np.asarray(logits)
         if window_col is None:
-            n = int(out.n_selected[i])
-            sets[i][position] = [set(np.asarray(out.selected[j, i, :n])
-                                     .tolist()) for j in range(L)]
+            sets[i][position] = [
+                set(np.flatnonzero(np.asarray(out.selected[j, i])).tolist())
+                for j in range(L)]
+            assert all(len(s) == int(out.n_selected[i])
+                       for s in sets[i][position])
         else:
             sets[i][position] = [
                 set(np.flatnonzero(np.asarray(
@@ -216,6 +219,27 @@ def test_counters_count_what_was_selected(model, served_run, traffic):
         L * c.num_experts_per_tok * sum(lengths))
 
 
+def test_counters_count_the_single_token_rows_walk(model, served_run,
+                                                   traffic):
+    """Every single-token row attends by the walk under its mask: the
+    rows that took it are the rows that held one token, and its pages
+    each such token's live pages (position // page + 1) a layer. The
+    tokens that were single: the eight past a prompt, and a prompt's
+    last window where that holds one token (17 = 16 + 1)."""
+    c = model[0]
+    total = dict(zip(kv2.COUNTERS, served_run[3]))
+    seqs, prompts = traffic
+    single = [t for seq, p in zip(seqs, prompts)
+              for t in range(len(seq))
+              if t >= p or (t == p - 1 and p % C == 1)]
+    assert total["dsa_walk_rows_single"] == total["gqa_rows_single"] == len(
+        single)
+    assert total["dsa_walk_pages_single"] == c.num_hidden_layers * sum(
+        t // PAGE + 1 for t in single)
+    assert total["dsa_keys_single"] == c.num_hidden_layers * sum(
+        min(t + 1, c.index_topk) for t in single)
+
+
 def test_pallas_kernels_serve_the_same_logits(model, traffic):
     """Both kernels interpreted, the window's mask streamed beside the
     pages: the fold's logits to float32 rounding, and its sets."""
@@ -249,18 +273,47 @@ def seeded_pools(c, n_keys: int, seed: int = 3):
 @pytest.mark.parametrize("attn", ["fold", "pallas"])
 def test_below_topk_a_single_token_is_the_unselected_kernel_bit_for_bit(
         model, attn):
-    """A row of 40 keys, all of them selected (topk 48): the gathered
-    pages are the row's own in their own order."""
+    """A row of 40 keys, all of them selected (topk 48): the mask
+    changes no bit of cake_decode_attn's result."""
     c = model[0]
     k, v, table, q = seeded_pools(c, 40)
     pos = jnp.asarray([39], jnp.int32)
     plain = paged_attention(q[:1, None], k, v, jnp.int32(0), table, pos,
                             impl=attn)[:, 0]
-    idx = jnp.sort(jnp.where(jnp.arange(48) < 40, jnp.arange(48),
-                             MAX_SEQ - 1))[None].astype(jnp.int32)
-    picked = kv2.attend_rows(q[:1], k, v, jnp.int32(0), table,
-                             kv2.Selection(idx, pos + 1, None), attn)
+    picked = kv2.attend_rows(q[:1], k, v, jnp.int32(0), table, pos,
+                             jnp.arange(MAX_SEQ)[None, :] <= pos[:, None],
+                             attn)
     assert np.array_equal(np.asarray(plain), np.asarray(picked))
+
+
+@pytest.mark.parametrize("attn", ["fold", "pallas"])
+def test_a_rows_selection_is_exact_attention_over_the_chosen_keys(
+        model, attn):
+    """Above topk: three rows over one table at positions 39, 21 and
+    none (-1), each over ITS 12 keys where they lie in its own pages,
+    against exact softmax attention over those keys alone; the row
+    without a token gives zeros."""
+    c = model[0]
+    k, v, table, q = seeded_pools(c, 40)
+    KV, hd, H = c.num_key_value_heads, c.head_dim, c.num_attention_heads
+    rng = np.random.default_rng(6)
+    pos = np.asarray([39, 21, -1], np.int32)
+    own = np.zeros((3, MAX_SEQ), bool)
+    for b in range(2):
+        own[b, rng.choice(pos[b] + 1, 12, replace=False)] = True
+    own[2, :8] = True       # marked, and no position to see it from
+    got = np.asarray(kv2.attend_rows(
+        q[:3], k, v, jnp.int32(0), jnp.broadcast_to(table, (3, MAX_SEQ // PAGE)),
+        jnp.asarray(pos), jnp.asarray(own), attn))
+    keys = np.asarray(k[0, 1:]).reshape(MAX_SEQ, KV, hd)
+    vals = np.asarray(v[0, 1:]).reshape(MAX_SEQ, KV, hd)
+    qn = np.asarray(q[:2]).reshape(2, KV, H // KV, hd)
+    s = np.einsum("tkgd,skd->tkgs", qn, keys) / np.sqrt(hd)
+    s = np.where(own[:2, None, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("tkgs,skd->tkgd", p / p.sum(-1, keepdims=True), vals)
+    np.testing.assert_allclose(got[:2].reshape(want.shape), want, atol=2e-5)
+    assert not got[2].any()
 
 
 @pytest.mark.parametrize("attn", ["fold", "pallas"])
@@ -305,6 +358,29 @@ def test_a_windows_selection_is_exact_attention_over_the_chosen_keys(
     want = np.einsum("tkgs,skd->tkgd", p / p.sum(-1, keepdims=True), vals)
     np.testing.assert_allclose(np.asarray(got).reshape(want.shape), want,
                                atol=2e-5)
+
+
+def test_the_bench_tool_rehearses_and_checks_both_forms(capsys):
+    """tools/decode_selected_bench.py at tiny shapes: one JSON line, the
+    gather form it keeps (what this trunk served until PR 66) and the
+    mask form select the same sets and attend to the same output, every
+    form timed at every length."""
+    import importlib.util
+
+    path = os.path.join(ROOT, "tools", "decode_selected_bench.py")
+    spec = importlib.util.spec_from_file_location("decode_selected_bench",
+                                                  path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(["--rehearse", "--calls", "2", "--lengths",
+                      "20,100"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert [case["keys"] for case in line["cases"]] == [20, 100]
+    for case in line["cases"]:
+        assert case["same_sets"] and case["max_abs_diff"] < 1e-4
+        assert {"gather_us", "mask_us", "mask_select_us", "mask_attend_us",
+                "walk_us"} <= set(case)
+    assert "crossover_keys" in line
 
 
 # -- the rotation --------------------------------------------------------------
@@ -511,6 +587,10 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
     for r in counted:
         assert r["dsa_keys_selected"] <= r["dsa_keys_visible"]
         assert r["dsa_keys_single"] <= r["dsa_keys_scanned_single"]
+        # every single-token row walks its pages under its mask
+        assert r["dsa_walk_rows_single"] == r["gqa_rows_single"]
+        assert (r["dsa_walk_pages_single"] * eng.cache.page_size
+                >= r["dsa_keys_scanned_single"])
         # (a step of k prompts is k dispatches: Windows.DISPATCH)
         assert r["dsa_index_layers"] % c.num_hidden_layers == 0
         # the window's selection: a mixed dispatch's alone, and at this

@@ -297,6 +297,159 @@ def test_decode_kernel_walks_live_pages(kind, case, monkeypatch):
                 assert not got[b].any()
 
 
+# -- the decode kernel under a selection (a sparse indexer's set) ------------
+
+SEL_H, SEL_KV = 32, 4           # a group of 8, Keye's
+
+
+def _sel_all(pos):
+    return np.arange(MAX_PAGES * P) <= pos
+
+
+def _sel_pages_skipped(skipped):
+    """Every visible key but those of the logical pages `skipped`."""
+    def sel(pos):
+        m = _sel_all(pos)
+        for j in skipped:
+            m[j * P:(j + 1) * P] = False
+        return m
+    return sel
+
+
+def _sel_scattered(pos):
+    rng = np.random.default_rng(pos + 1)
+    return _sel_all(pos) & (rng.random(MAX_PAGES * P) < 0.3)
+
+
+# name: (table rows, positions, a row's position -> its selection
+# [MAX_PAGES * P] bool)
+SELECTED_CASES = {
+    # a row shorter than topk: every visible key selected, the
+    # unselected kernel's result bit for bit
+    "all_visible_selected": ([[7, 2, 9, -1, -1], [4, 11, -1, -1, -1]],
+                             [2 * P + 5, P + 3], _sel_all),
+    "scattered": ([[7, 2, 9, 5, -1], [4, 11, 3, 1, 8]],
+                  [3 * P + 2, FULL], _sel_scattered),
+    # a page with NO selected key: the row's first (nothing folded yet:
+    # the guard), one in mid-row, and the row's last
+    "first_page_unselected": ([[7, 2, 9, -1, -1], [4, 11, 3, -1, -1]],
+                              [2 * P + 5, 2 * P], _sel_pages_skipped([0])),
+    "mid_page_unselected": ([[7, 2, 9, 5, -1], [4, 11, 3, -1, -1]],
+                            [3 * P + 2, 2 * P + 7], _sel_pages_skipped([1])),
+    "last_page_unselected": ([[7, 2, 9, 5, -1], [4, 11, 3, -1, -1]],
+                             [3 * P + 2, 2 * P + 7],
+                             lambda pos: _sel_pages_skipped([pos // P])(pos)),
+    # an idle row (position -1) between two live ones: zeros, no trip
+    "idle_row_between": ([[7, 2, 9, -1, -1], _DEAD, [4, 11, 3, 1, -1]],
+                         [2 * P + 5, -1, 3 * P + 2], _sel_scattered),
+    # a mask that marks keys PAST the position: never attended
+    "selects_past_the_position": (
+        [[7, 2, 9, 5, 6], [4, 11, 3, 1, 8]], [P + 2, 2 * P + 1],
+        lambda pos: np.ones(MAX_PAGES * P, bool)),
+    # a row whose selection is empty: zeros
+    "nothing_selected": ([[7, 2, 9, -1, -1], [4, 11, -1, -1, -1]],
+                         [2 * P + 5, P + 3],
+                         lambda pos: np.zeros(MAX_PAGES * P, bool)),
+}
+
+
+def _selected_inputs(case):
+    rng = np.random.default_rng(43)
+    pk, pv = _pool(rng, KV=SEL_KV, hd=16)
+    rows, pos, sel = SELECTED_CASES[case]
+    q = jnp.asarray(rng.normal(size=(len(rows), 1, SEL_H, 16)), jnp.float32)
+    mask = np.stack([sel(p) for p in pos])
+    return (q, pk, pv, jnp.asarray(rows, jnp.int32),
+            jnp.asarray(pos, jnp.int32), mask)
+
+
+@pytest.mark.parametrize("case", sorted(SELECTED_CASES))
+def test_decode_kernel_attends_under_a_selection(case, monkeypatch):
+    """`selected=` [B, max_pages, page]: the kernel walks the row's own
+    live pages and attends the marked keys alone; against the fold
+    given the same mask, and against exact softmax attention over the
+    chosen keys."""
+    monkeypatch.setattr(rpa, "decode_ring_depth", lambda page_bytes:
+                        RING_DEPTH)
+    q, pk, pv, table, pos, mask = _selected_inputs(case)
+    B = q.shape[0]
+    selected = jnp.asarray(mask, jnp.float32).reshape(B, MAX_PAGES, P)
+    for layer in CHECK_LAYERS:
+        want = np.asarray(paged_attention(q, pk, pv, layer, table, pos,
+                                          selected=selected))
+        got = np.asarray(ragged_paged_attention(
+            q, pk, pv, layer, table, pos, selected=selected, interpret=True))
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        for b in range(B):
+            seen = mask[b] & (np.arange(MAX_PAGES * P) <= int(pos[b]))
+            if not seen.any():
+                assert not got[b].any()
+                continue
+            pages = np.asarray(table[b])
+            keys = np.asarray(pk[layer])[pages].reshape(-1, SEL_KV, 16)[seen]
+            vals = np.asarray(pv[layer])[pages].reshape(-1, SEL_KV, 16)[seen]
+            qb = np.asarray(q[b, 0]).reshape(SEL_KV, SEL_H // SEL_KV, 16)
+            s = np.einsum("kgd,skd->kgs", qb, keys) / 4.0
+            p = np.exp(s - s.max(-1, keepdims=True))
+            exact = np.einsum("kgs,skd->kgd", p / p.sum(-1, keepdims=True),
+                              vals)
+            np.testing.assert_allclose(got[b, 0].reshape(exact.shape), exact,
+                                       atol=2e-5)
+        if case == "all_visible_selected":
+            plain = ragged_paged_attention(q, pk, pv, layer, table, pos,
+                                           interpret=True)
+            assert np.array_equal(got, np.asarray(plain))
+
+
+@pytest.mark.parametrize("refused", ["quantized", "window"])
+def test_a_selection_is_refused_over_a_quantized_pool_or_a_band(refused):
+    q, pk, pv, table, pos, mask = _selected_inputs("scattered")
+    selected = jnp.asarray(mask, jnp.float32).reshape(-1, MAX_PAGES, P)
+    kw = {}
+    if refused == "quantized":
+        qk, qv = _qpools(np.random.default_rng(0), SEL_KV, 16)
+        pk, pv, kw = qk.q, qv.q, dict(scale_k=qk.scale, scale_v=qv.scale)
+    else:
+        kw = dict(window=P)
+    with pytest.raises(ValueError, match="float pool without a band"):
+        ragged_paged_attention(q, pk, pv, LAYER, table, pos,
+                               selected=selected, interpret=True, **kw)
+    if refused == "window":
+        with pytest.raises(ValueError, match="without a band"):
+            paged_attention(q, pk, pv, LAYER, table, pos, window=P,
+                            selected=selected)
+
+
+# sha256 of the kernel's lowered text with no selection, taken on the
+# commit before the kernel took `selected=` (c8c6a0b, PR 65's tree; this
+# file's call under conftest's settings): None adds no operand, no
+# branch and no instruction
+DECODE_LOWERED_BEFORE = (
+    "9d9f68f57bf15f6ad37c43bb3e2cde087c4d3118334f12c069bbda3f6af8904b")
+
+
+def test_no_selection_lowers_to_the_kernel_it_was():
+    import hashlib
+    rng = np.random.default_rng(41)
+    pk, pv = _pool(rng, KV=2, hd=16)
+    lowered = jax.jit(lambda *a: ragged_paged_attention(
+        *a, interpret=True)).lower(
+        jnp.zeros((3, 1, 4, 16), jnp.float32), pk, pv, jnp.int32(LAYER),
+        jnp.zeros((3, MAX_PAGES), jnp.int32), jnp.zeros(3, jnp.int32))
+    digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    assert digest == DECODE_LOWERED_BEFORE, digest
+    # and with one: a fourth block, the row's [max_pages, page] of it
+    jaxpr = jax.make_jaxpr(lambda *a: ragged_paged_attention(
+        *a[:-1], selected=a[-1], interpret=True))(
+        jnp.zeros((3, 1, 4, 16), jnp.float32), pk, pv, jnp.int32(LAYER),
+        jnp.zeros((3, MAX_PAGES), jnp.int32), jnp.zeros(3, jnp.int32),
+        jnp.ones((3, MAX_PAGES, P), jnp.float32))
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    blocks = [str(bm.block_aval)
+              for bm in call.params["grid_mapping"].block_mappings]
+    assert len(blocks) == 5 and f"[1,{MAX_PAGES},{P}]" in blocks[3]
+
+
 def test_decode_kernel_grid_is_one_step_a_row():
     """The traced call: a grid of (rows,), whatever the table's width,
     and the pool handed over whole, outside VMEM."""

@@ -699,13 +699,11 @@ def test_dsa_select_kernel_compiles_at_the_cells_widths(one_chip, S):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
-def test_keyes_mixed_program_holds_one_select_call_a_layer(tool, one_chip):
-    """Keye's served mixed program at the cell's widths (two of its
-    alike layers: the trunk is one scan) for the described v5e: ONE
-    `cake_dsa_select` call in the layer scan, under the scope
-    `index_topk`, and under that scope no running count over the
-    window's [512, 33,280] codes any more (XLA's `reduce-window`s over
-    s32[512,260,128], a `cumsum` by name) and no counting loop."""
+@pytest.fixture(scope="module")
+def keyes_programs(tool, one_chip):
+    """Keye's served step programs at the cell's widths (two of its
+    alike layers: the trunk is one scan) for the described v5e ->
+    {"decode" | "mixed": optimised HLO}."""
     import json
 
     from cake_tpu.models.llama.config import load_config
@@ -715,18 +713,32 @@ def test_keyes_mixed_program_holds_one_select_call_a_layer(tool, one_chip):
     with open(cell / "cell.json") as f:
         sa = json.load(f)["server_args"]
     width = sa["prefill-chunk"]
-    _, mixed = tool.step_fns(config)
+    shape = dict(slots=sa["max-slots"], n_pages=sa["kv-pages"],
+                 page_size=sa["kv-page-size"], max_seq_len=sa["max-seq-len"])
+    decode, mixed = tool.step_fns(config)
     with jax.default_matmul_precision("default"):
-        compiled = tool.compile_step(
-            mixed, config, one_chip, width=width,
-            n_tokens=width + sa["max-slots"], slots=sa["max-slots"],
-            n_pages=sa["kv-pages"], page_size=sa["kv-page-size"],
-            max_seq_len=sa["max-seq-len"])
-    hlo = compiled.as_text()
+        return {
+            "decode": tool.compile_step(decode, config, one_chip,
+                                        **shape).as_text(),
+            "mixed": tool.compile_step(
+                mixed, config, one_chip, width=width,
+                n_tokens=width + sa["max-slots"], **shape).as_text()}
+
+
+def test_keyes_mixed_program_holds_two_select_calls_a_layer(keyes_programs):
+    """TWO `cake_dsa_select` calls in the mixed program's layer scan,
+    both under the scope `index_topk`: the window's (a mask
+    s8[512,33280]) and the single-token rows' (ONE tile of 8 queries,
+    s8[8,33280]); under that scope no running count over the window's
+    [512, 33,280] codes (XLA's `reduce-window`s over s32[512,260,128],
+    a `cumsum` by name) and no counting loop."""
+    hlo = keyes_programs["mixed"]
     calls = [line for line in hlo.splitlines()
              if "custom-call(" in line and "cake_dsa_select" in line]
-    assert len(calls) == 1
-    assert "index_topk" in calls[0]         # the call lies under the scope
+    assert len(calls) == 2
+    assert all("index_topk" in call for call in calls)
+    assert sorted(re.search(r"= \(?(s8\[\d+,33280\])", call).group(1)
+                  for call in calls) == ["s8[512,33280]", "s8[8,33280]"]
     under = []
     for line in hlo.splitlines():
         if "index_topk" not in line:
@@ -736,6 +748,33 @@ def test_keyes_mixed_program_holds_one_select_call_a_layer(tool, one_chip):
             under.append(line.strip()[:200])
     assert not under, ("the selection's XLA form under index_topk again: "
                        + "; ".join(under))
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_keyes_single_token_rows_attend_where_their_keys_lie(
+        keyes_programs, program):
+    """A single-token row's selected keys stay in the pools: in both
+    programs the rows' selection is one `cake_dsa_select` call of 8
+    queries and their attention one `cake_decode_attn` call that takes
+    the mask by page (f32[8,260,128]) beside the layer's own pools; no
+    `sort` is left under the scope `attn` (the rows' `lax.top_k` was a
+    full sort of f32[8,33280], `jnp.sort` one of s32[8,2048]; the
+    router's and the dispatch's, under `ffn`, stay), nothing is
+    gathered out of the K and V pools (bf16[16384,512] each, 8 rows x
+    2,048 keys of 1 KB) or looked up for it (s32[16384]), and no scope
+    `dsa_gather` remains."""
+    hlo = keyes_programs[program]
+    calls = [line for line in hlo.splitlines() if "custom-call(" in line]
+    rows = [c for c in calls if "cake_dsa_select" in c and "s8[8,33280]" in c]
+    attend = [c for c in calls if "cake_decode_attn" in c]
+    assert len(rows) == 1 and len(attend) == 1
+    assert "f32[8,260,128]" in attend[0] and "bf16[8,1,32,128]" in attend[0]
+    sorts = [line.strip()[:200] for line in hlo.splitlines()
+             if re.search(r"\bsort\(", line)]
+    assert sorts and not [line for line in sorts if "/attn/" in line
+                          or "[8,33280]" in line or "[8,2048]" in line]
+    assert "[16384,512]" not in hlo and "s32[16384]" not in hlo
+    assert "dsa_gather" not in hlo
 
 
 @pytest.mark.parametrize("n_pairs,E,K,N", [
