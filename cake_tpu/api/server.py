@@ -32,9 +32,11 @@ from cake_tpu.api.openai import (
 )
 from cake_tpu.args import ImageGenerationArgs
 from cake_tpu.obs import metrics as obs_metrics
+from cake_tpu.obs import startup as obs_startup
 from cake_tpu.obs import steps as obs_steps
 from cake_tpu.obs import tracing as obs_tracing
 from cake_tpu.serve.errors import EngineRequestError
+from cake_tpu.startup import STARTUP
 
 log = logging.getLogger(__name__)
 
@@ -429,6 +431,11 @@ class ApiServer:
                "queue_depth": self._waiting}
         if not lite:
             out["model"] = self.model_name
+            started = obs_startup.report()
+            if started is not None:
+                # the start-up clock's phases, its mark and the
+                # programs made so far (obs/startup.py)
+                out["startup"] = started
         if failed:
             out["reason"] = self.health_state.reason
         if self.engine is None:
@@ -1047,7 +1054,11 @@ def make_handler(api: ApiServer):
                 # ?lite=1: the router's cheap poll variant (a subtree
                 # of the full document; any other value means full)
                 lite = self._query().get("lite") == "1"
-                return self._json(200, api.health(lite=lite))
+                doc = api.health(lite=lite)
+                if doc["status"] == "ok":
+                    # the first one is the start-up clock's mark
+                    obs_startup.healthy()
+                return self._json(200, doc)
             if self.path == "/api/v1/cluster":
                 return self._json(200, api.cluster())
             if route == "/api/v1/requests":
@@ -1338,7 +1349,8 @@ def start(master, address: str = "127.0.0.1:10128",
     drains-then-forgets instead of inferring death from silence."""
     host, port = address.rsplit(":", 1)
     if engine is None and master.llm is not None:
-        engine = master.make_engine()
+        with STARTUP.phase("engine"):
+            engine = master.make_engine()
     if engine is None and master.llm is not None:
         # engine-less locked-path serving: unreachable for the built-in
         # compositions as of round-5 (every sp mode has an engine
@@ -1357,9 +1369,12 @@ def start(master, address: str = "127.0.0.1:10128",
         from cake_tpu.parallel.health import ServingHealth
         health = ServingHealth(engine, stall_after_s=getattr(
             master.args, "stall_timeout", 600.0))
+    # (starts the engine: a paged one files `warm_steps` and
+    # `weights_ready` there, engine._warm_mixed_buckets)
     api = ApiServer(master, model_name, engine=engine, health=health,
                     collector=collector, replica_id=address)
-    httpd = _HTTPServer((host, int(port)), make_handler(api))
+    with STARTUP.phase("server"):
+        httpd = _HTTPServer((host, int(port)), make_handler(api))
     log.info("REST API listening on %s", address)
 
     announcer = None

@@ -13,6 +13,10 @@ import logging
 import os
 import sys
 
+# (nothing but the standard library: the process's first `import jax`
+# is a phase of main's)
+from cake_tpu.startup import STARTUP
+
 
 def _serve_multihost(master, args) -> int:
     """Serve the REST API over a mesh that spans processes.
@@ -55,7 +59,8 @@ def _serve_multihost(master, args) -> int:
         # every process builds the identical engine (the shared-cache
         # zeros allocation is a global computation, so construction
         # order matters and must match across hosts)
-        engine = master.make_engine()
+        with STARTUP.phase("engine"):
+            engine = master.make_engine()
         if engine is None:
             raise ValueError(
                 "this serving mode (an sp composition without an "
@@ -423,14 +428,18 @@ def router_main(argv=None) -> int:
 
 
 def main(argv=None) -> int:
-    from cake_tpu.args import parse_args
-    from cake_tpu.master import Master
+    STARTUP.start()     # `boot` ends here
+    with STARTUP.phase("args"):
+        from cake_tpu.args import parse_args
 
-    logging.basicConfig(
-        level=logging.INFO,
-        format="[%(asctime)s] %(levelname)s %(name)s: %(message)s",
-    )
-    args, sd_args, img_args = parse_args(argv)
+        logging.basicConfig(
+            level=logging.INFO,
+            format="[%(asctime)s] %(levelname)s %(name)s: %(message)s",
+        )
+        args, sd_args, img_args = parse_args(argv)
+    if args.router or not args.api:
+        # only a serving process has a first healthy answer to run to
+        STARTUP.stop()
 
     if args.router:
         # BEFORE Master.from_args/initialize: the router is a
@@ -480,41 +489,51 @@ def main(argv=None) -> int:
             "tunes the write-ahead request journal's durability "
             "barrier (serve/journal.py)")
 
-    if getattr(args, "require_model_type", None):
-        # before any device or weight is touched: a directory that
-        # resolves to another family must not be served under this name
-        from cake_tpu.models.llama.config import _read_config
-        found = (_read_config(args.model).get("model_type", "llama")
-                 if args.model else None)
-        if found != args.require_model_type:
-            print(f"--require-model-type {args.require_model_type}: "
-                  f"{args.model!r} resolves to model_type {found!r}",
-                  file=sys.stderr)
+    with STARTUP.phase("import_jax"):
+        # the process's first `import jax` (cake_tpu.models and
+        # cake_tpu.obs both reach it)
+        if getattr(args, "require_model_type", None):
+            # before any device or weight is touched: a directory that
+            # resolves to another family must not be served under this
+            # name
+            from cake_tpu.models.llama.config import _read_config
+            found = (_read_config(args.model).get("model_type", "llama")
+                     if args.model else None)
+            if found != args.require_model_type:
+                print(f"--require-model-type {args.require_model_type}: "
+                      f"{args.model!r} resolves to model_type {found!r}",
+                      file=sys.stderr)
+                return 2
+
+        if args.mode == "worker":
+            print(
+                "cake-tpu runs the whole topology as one SPMD program over "
+                "the device mesh; there is no separate worker process. Run "
+                "in master mode on the host attached to the TPU slice.",
+                file=sys.stderr,
+            )
             return 2
 
-    if args.mode == "worker":
-        print(
-            "cake-tpu runs the whole topology as one SPMD program over the "
-            "device mesh; there is no separate worker process. Run in "
-            "master mode on the host attached to the TPU slice.",
-            file=sys.stderr,
-        )
-        return 2
+        from cake_tpu.master import Master
+        from cake_tpu.obs import startup
+        from cake_tpu.utils.compile_cache import enable_compile_cache
+        cache_dir = enable_compile_cache()
+        startup.listen()
+    logging.getLogger(__name__).info("compile cache: %s", cache_dir)
 
-    from cake_tpu.utils.compile_cache import enable_compile_cache
-    logging.getLogger(__name__).info(
-        "compile cache: %s", enable_compile_cache())
+    with STARTUP.phase("backend"):
+        # multi-host: every host runs this same program (SPMD);
+        # coordinates auto-detected on TPU pods or taken from CAKE_*
+        # env vars. jax.devices() is where the TPU client starts
+        import jax
 
-    # multi-host: every host runs this same program (SPMD); coordinates
-    # auto-detected on TPU pods or taken from CAKE_* env vars
-    from cake_tpu.parallel.distributed import initialize
-    initialize()
+        from cake_tpu.parallel.distributed import initialize
+        initialize()
+        jax.devices()
 
     master = Master.from_args(args, sd_args)
 
     if args.api:
-        import jax
-
         from cake_tpu.api import start
         if jax.process_count() > 1:
             return _serve_multihost(master, args)

@@ -106,31 +106,36 @@ class Context:
         ParallelPlan and the generator drives the pipelined forward — the
         reference's topology-driven serving (topology.rs:43-91 feeding
         llama.rs:203-220), as one SPMD program instead of TCP hops."""
-        from cake_tpu.models.llama.config import LlamaConfig
-        from cake_tpu.models.llama.generator import (
-            ByteTokenizer, LlamaGenerator, load_tokenizer,
-        )
-        from cake_tpu.ops.sampling import SamplingConfig
-
         import dataclasses
 
-        a = self.args
-        cfg = self.llama_config or dataclasses.replace(
-            LlamaConfig.tiny(), use_flash_attention=_resolve_flash(a)
-        )
-        if a.model and os.path.exists(os.path.join(a.model, "tokenizer.json")):
-            tokenizer = load_tokenizer(a.model)
-        else:
-            tokenizer = ByteTokenizer(cfg.vocab_size)
+        from cake_tpu.startup import STARTUP
 
-        from cake_tpu.models import load_text_params
-        from cake_tpu.parallel.plan import ParallelPlan
-        plan = ParallelPlan.from_topology(cfg, self.topology, args=a)
-        refusal = cfg.family.refusal({
-            "topology": (plan.stages > 1 or plan.tp > 1 or plan.dp > 1
-                         or a.sp > 1)})
-        if refusal:
-            raise ValueError(refusal)
+        a = self.args
+        with STARTUP.phase("config"):
+            # the model's modules are imported here for the first time
+            from cake_tpu.models import load_text_params
+            from cake_tpu.models.llama.config import LlamaConfig
+            from cake_tpu.models.llama.generator import (
+                ByteTokenizer, LlamaGenerator, load_tokenizer,
+            )
+            from cake_tpu.ops.sampling import SamplingConfig
+            from cake_tpu.parallel.plan import ParallelPlan
+
+            cfg = self.llama_config or dataclasses.replace(
+                LlamaConfig.tiny(), use_flash_attention=_resolve_flash(a)
+            )
+            if a.model and os.path.exists(
+                    os.path.join(a.model, "tokenizer.json")):
+                tokenizer = load_tokenizer(a.model)
+            else:
+                tokenizer = ByteTokenizer(cfg.vocab_size)
+
+            plan = ParallelPlan.from_topology(cfg, self.topology, args=a)
+            refusal = cfg.family.refusal({
+                "topology": (plan.stages > 1 or plan.tp > 1 or plan.dp > 1
+                             or a.sp > 1)})
+            if refusal:
+                raise ValueError(refusal)
 
         # stage-local load (reference worker.rs:106-127 parity, per
         # shard): with a sharded placement the tree is BORN on its mesh
@@ -146,8 +151,9 @@ class Context:
         if born_sharded:
             params = None   # built inside the topology branch, post-mesh
         else:
-            params = load_text_params(cfg, a.model, self.dtype,
-                                      quant=a.quant)
+            with STARTUP.phase("weights"):
+                params = load_text_params(cfg, a.model, self.dtype,
+                                          quant=a.quant)
             if a.quant in ("int8", "int4"):
                 log.info("weights quantized to %s as they loaded "
                          "(weight-only)", a.quant)
@@ -314,37 +320,40 @@ class Context:
                     f"per-replica batch {a.batch_size // plan.dp} must be "
                     f"divisible by --microbatches {a.microbatches} "
                     "(GPipe slices the batch into microbatches)")
-            mesh = plan.build_mesh()
             tp, dp = plan.tp > 1, plan.dp > 1
-            from cake_tpu.parallel.sharding import create_sharded_cache
-            cache = create_sharded_cache(
-                cfg, a.batch_size, max_seq, mesh,
-                tp_axis="tp" if tp else None,
-                dp_axis="dp" if dp else None,
-                stage_axis="stage", dtype=kv_dtype,
-            )
+            with STARTUP.phase("mesh"):
+                mesh = plan.build_mesh()
+                from cake_tpu.parallel.sharding import create_sharded_cache
+                cache = create_sharded_cache(
+                    cfg, a.batch_size, max_seq, mesh,
+                    tp_axis="tp" if tp else None,
+                    dp_axis="dp" if dp else None,
+                    stage_axis="stage", dtype=kv_dtype,
+                )
             if params is None:
                 params = self._params_on_mesh(cfg, mesh, tp)
-            params, cache = place_for_pipeline(params, cache, mesh,
-                                               tp=tp, dp=dp)
-            fwd = make_pipeline_forward(
-                mesh, cfg,
-                num_microbatches=a.microbatches,
-                tp=tp, dp=dp, params=params,
-            )
+            with STARTUP.phase("place"):
+                params, cache = place_for_pipeline(params, cache, mesh,
+                                                   tp=tp, dp=dp)
+                fwd = make_pipeline_forward(
+                    mesh, cfg,
+                    num_microbatches=a.microbatches,
+                    tp=tp, dp=dp, params=params,
+                )
             kwargs = dict(forward_fn=fwd, cache=cache,
                           parallel=(plan, mesh))
             log.info("topology-sharded serving:\n%s", plan.describe())
 
-        gen = LlamaGenerator(
-            cfg, params, tokenizer,
-            max_seq_len=max_seq,
-            batch_size=a.batch_size, sampling=sampling, seed=a.seed,
-            cache_dtype=kv_dtype, prefill_chunk=a.prefill_chunk,
-            **kwargs,
-        )
-        from cake_tpu.utils.profiling import log_memory
-        log_memory("model loaded")  # reference llama.rs:233-236
+        with STARTUP.phase("generator"):
+            gen = LlamaGenerator(
+                cfg, params, tokenizer,
+                max_seq_len=max_seq,
+                batch_size=a.batch_size, sampling=sampling, seed=a.seed,
+                cache_dtype=kv_dtype, prefill_chunk=a.prefill_chunk,
+                **kwargs,
+            )
+            from cake_tpu.utils.profiling import log_memory
+            log_memory("model loaded")  # reference llama.rs:233-236
         return gen
 
     def _params_on_mesh(self, cfg, mesh, tp: bool):
@@ -353,15 +362,19 @@ class Context:
         under jit with the plan's shardings as out_shardings — each
         device generates its own shard, so a weightless 8B topology
         never builds the tree on device 0 first."""
+        from cake_tpu.startup import STARTUP
         from cake_tpu.utils.loading import has_weights
 
         if has_weights(self.args.model):
-            return self._maybe_quantize(
-                self._load_params_streamed(cfg, mesh, tp))
+            with STARTUP.phase("weights"):
+                params = self._load_params_streamed(cfg, mesh, tp)
+            with STARTUP.phase("quantize"):
+                return self._maybe_quantize(params)
         log.warning("no weights at %r; using random init",
                     self.args.model)
         bits = {"int8": 8, "int4": 4}.get(self.args.quant)
-        return _sharded_init(cfg, self.dtype, bits, mesh, tp)()
+        with STARTUP.phase("weights"):
+            return _sharded_init(cfg, self.dtype, bits, mesh, tp)()
 
     def _load_params_streamed(self, cfg, mesh, tp: bool):
         """Stream weights from disk directly onto their pipeline shards

@@ -40,8 +40,10 @@ class Master:
 
     @classmethod
     def from_args(cls, args: Args, sd_args=None) -> "Master":
-        from cake_tpu.context import Context
-        ctx = Context.from_args(args, sd_args)
+        from cake_tpu.startup import STARTUP
+        with STARTUP.phase("context"):
+            from cake_tpu.context import Context
+            ctx = Context.from_args(args, sd_args)
         if args.model_type.value == "image":
             return cls(args, image_generator=ctx.load_image_model())
         return cls(args, text_generator=ctx.load_text_model())

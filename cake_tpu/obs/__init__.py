@@ -25,6 +25,13 @@ real measurement substrate, dependency-free:
     dispatch start: the device with nothing queued); the same phases
     are `cake/<phase>` TraceAnnotations with the step number in a
     capture (`StepTelemetry.span`).
+  * `obs.startup` — what is read of start-up's own clock
+    (`cake_tpu/startup.py`: named phases from the process's start to
+    the first healthy answer): `cake_startup_phase_seconds{phase}`,
+    the `startup` block of `/api/v1/health`; and the seconds that go
+    into making programs, from `jax.monitoring` (`cake_jit_trace/
+    lower/backend/cost_analysis_seconds_total`, cache hits and
+    misses).
   * `obs.events` — the cross-subsystem event bus: typed,
     request-linked events (preempted, kv_spill/kv_restore, prefix_hit,
     recovered/poisoned, reconfigured, shed, fault_injected, recompile)

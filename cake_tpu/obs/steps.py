@@ -22,9 +22,12 @@ pieces, all dependency-free:
     ``Lowered.cost_analysis()`` FLOPs + bytes-accessed. Combined with
     the measured step time this yields `cake_step_mfu{kind}` and
     `cake_step_hbm_util{kind}`; every new signature also bumps
-    `cake_jit_compiles_total{fn}` and lands in the compile-seconds
-    histogram. A rising compile counter during steady-state decode is a
-    shape-leak recompilation storm — previously invisible.
+    `cake_jit_compiles_total{fn}`, and its step record carries `jit_s`:
+    what the trace, the lowering, the backend (compile or cache load)
+    and the cost analysis took across it (obs/startup.py's counters,
+    fed by jax.monitoring). A rising compile counter during
+    steady-state decode is a shape-leak recompilation storm —
+    previously invisible.
 
   * **Device gauges** (`refresh_device_gauges`): per-device HBM
     live/peak/limit bytes from `Device.memory_stats()` — a graceful
@@ -97,6 +100,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from cake_tpu.obs import metrics as _m
+from cake_tpu.obs import startup as _startup
 from cake_tpu.obs.jsonl import JsonlAppender
 
 log = logging.getLogger(__name__)
@@ -208,9 +212,6 @@ _JIT_COMPILES = _m.counter(
     "New jit signatures dispatched per step fn (a rise during "
     "steady-state decode is a shape-leak recompilation storm)",
     labelnames=("fn",))
-_JIT_COMPILE_SECONDS = _m.histogram(
-    "cake_jit_compile_seconds",
-    "Wall seconds of step-fn dispatches that compiled a new signature")
 _DEV_HBM_IN_USE = _m.gauge(
     "cake_device_hbm_bytes_in_use",
     "Live HBM bytes per device (Device.memory_stats; absent on CPU)",
@@ -813,29 +814,31 @@ class JitAccountant:
         self._lock = threading.Lock()
         self._seen: Dict[tuple, Optional[CostInfo]] = {}
 
-    def begin(self, name: str, key: tuple,
-              cost_cb) -> Tuple[bool, Optional[CostInfo]]:
-        """(is_new_signature, cost). On a new signature: increments the
-        per-fn compile counter and captures cost via cost_cb() (called
-        BEFORE the dispatch executes, while donated buffers are still
-        alive)."""
+    def begin(self, name: str, key: tuple, cost_cb
+              ) -> Tuple[bool, Optional[CostInfo], Optional[tuple]]:
+        """(is_new_signature, cost, before). On a new signature:
+        increments the per-fn compile counter and captures cost via
+        cost_cb() (called BEFORE the dispatch executes, while donated
+        buffers are still alive), timed into
+        cake_jit_cost_analysis_seconds_total; `before` is
+        obs/startup.seconds() read ahead of it, so that whoever records
+        the step can say what the signature's program cost to make."""
         with self._lock:
             if key in self._seen:
-                return False, self._seen[key]
+                return False, self._seen[key], None
+        before = _startup.seconds()
         cost = None
         try:
-            cost = cost_cb()
+            with _startup.PROGRAMS.costing():
+                cost = cost_cb()
         except Exception:  # noqa: BLE001
             log.debug("cost callback failed for %s", name, exc_info=True)
         with self._lock:
             if key in self._seen:   # racing thread won
-                return False, self._seen[key]
+                return False, self._seen[key], None
             self._seen[key] = cost
         _JIT_COMPILES.labels(fn=name).inc()
-        return True, cost
-
-    def compile_seconds(self, seconds: float) -> None:
-        _JIT_COMPILE_SECONDS.observe(seconds)
+        return True, cost, before
 
 
 ACCOUNTANT = JitAccountant()
@@ -848,20 +851,13 @@ def _no_cost() -> None:
 class _JitStep:
     """Handle returned by StepTelemetry.jit_step: `.new` says this
     dispatch compiles a fresh signature, `.cost` carries the program's
-    CostInfo; call `.finish(elapsed)` after the dispatch so compile
-    wall time lands in the histogram."""
+    CostInfo."""
 
-    __slots__ = ("new", "cost", "_acct")
+    __slots__ = ("new", "cost")
 
-    def __init__(self, new: bool, cost: Optional[CostInfo],
-                 acct: JitAccountant):
+    def __init__(self, new: bool, cost: Optional[CostInfo]):
         self.new = new
         self.cost = cost
-        self._acct = acct
-
-    def finish(self, seconds: float) -> None:
-        if self.new:
-            self._acct.compile_seconds(seconds)
 
 
 # -- flight recorder ----------------------------------------------------------
@@ -1001,6 +997,11 @@ class StepRecord:
     gc_s: Optional[float] = None
     gc_n: Optional[int] = None
     gc_max_s: Optional[float] = None
+    # a `compiled` step: what making its programs took from the first
+    # new signature's accounting to this record, by part
+    # (obs/startup.SECONDS: trace, lower, backend, the cache's load
+    # inside backend, cost_analysis)
+    jit_s: Optional[Dict[str, float]] = None
 
     def to_dict(self) -> Dict:
         out = {
@@ -1081,6 +1082,8 @@ class StepRecord:
             out["gc_max_s"] = round(self.gc_max_s, 6)
         if self.moe is not None:
             out.update((key, round(v, 3)) for key, v in self.moe.items())
+        if self.jit_s is not None:
+            out["jit_s"] = {k: round(v, 6) for k, v in self.jit_s.items()}
         return out
 
 
@@ -1244,6 +1247,9 @@ class StepTelemetry:
         self._next = 1
         self._log = JsonlAppender(log_path) if log_path else None
         self._acct = accountant or ACCOUNTANT
+        # obs/startup.seconds() as the first new signature since the
+        # last record found them (a `compiled` record's `jit_s`)
+        self._jit_before: Optional[tuple] = None
         self._prefix = tuple(key_prefix)
         self._peak = peak_flops
         self._bps = hbm_bps
@@ -1391,13 +1397,14 @@ class StepTelemetry:
         self._admitted += rows
 
     def discard_open(self, now: Optional[float] = None) -> None:
-        """Drop the open step's phases, parts and gap: what ran belongs
-        to no step (the engine's warm-up; the idle loop). The next
+        """Drop the open step's phases, parts, gap and the reading for
+        `jit_s`: what ran belongs to no step (the engine's warm-up; the
+        idle loop). The next
         record's loop_s starts here (`now`: a clock read the caller
         already has)."""
         self._phases, self._parts, self._offcpu = {}, {}, {}
         self._detok_ids = 0
-        self._gap = self._fetch_t1 = None
+        self._gap = self._fetch_t1 = self._jit_before = None
         self._loop_t0 = time.perf_counter() if now is None else now
         self._loop_cpu0 = time.thread_time()
 
@@ -1463,11 +1470,15 @@ class StepTelemetry:
         table entry (the CPU lane) the extra lowering buys nothing."""
         if not any(self._peaks()):
             cost_cb = _no_cost
-        new, cost = self._acct.begin(
+        new, cost, before = self._acct.begin(
             fn_name, self._prefix + (fn_name,) + tuple(key), cost_cb)
-        if new and self._events is not None:
-            self._events.publish("recompile", fn=fn_name, impl=self.impl)
-        return _JitStep(new, cost, self._acct)
+        if new:
+            if self._jit_before is None:
+                self._jit_before = before
+            if self._events is not None:
+                self._events.publish("recompile", fn=fn_name,
+                                     impl=self.impl)
+        return _JitStep(new, cost)
 
     def _peaks(self) -> Tuple[Optional[float], Optional[float]]:
         """(peak FLOP/s, HBM bytes/s) — the pinned overrides, else the
@@ -1601,6 +1612,14 @@ class StepTelemetry:
         if "fetch" not in phases:
             # the device was never drained: the next step has no gap
             self._fetch_t1 = None
+        jit_s = None
+        if compiled and self._jit_before is not None:
+            # read only here. A chained step's dispatch precedes the
+            # record of the step before it, so the reading waits for
+            # the record that says `compiled`
+            before, self._jit_before = self._jit_before, None
+            jit_s = {key: after - b for (key, _), after, b in zip(
+                _startup.SECONDS, _startup.seconds(), before)}
         with self._lock:
             rec = StepRecord(
                 step=self._next, ts=time.time(), kind=kind,
@@ -1634,7 +1653,7 @@ class StepTelemetry:
                 stream_wakes=wakes or None,
                 fetch_wait_s=fetch_wait_s, late=late,
                 loop_s=loop_s, offcpu=offcpu or None,
-                gc_s=gc_s, gc_n=gc_n, gc_max_s=gc_max_s)
+                gc_s=gc_s, gc_n=gc_n, gc_max_s=gc_max_s, jit_s=jit_s)
             self._next += 1
             self._ring.append(rec)
         _STEPS_TOTAL.labels(kind=kind).inc()

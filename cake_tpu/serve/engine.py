@@ -45,6 +45,7 @@ from cake_tpu.obs import steps as obs_steps
 from cake_tpu.obs.events import EventBus
 from cake_tpu.obs.slo import SLOAccountant, parse_slo_targets
 from cake_tpu.obs.tracing import RequestTracer
+from cake_tpu.startup import STARTUP
 from cake_tpu.models.llama.cache import KVCache
 from cake_tpu.models.llama.config import LlamaConfig
 from cake_tpu.models.llama.generator import (
@@ -1703,9 +1704,7 @@ class InferenceEngine:
                      self.config)
             js = self._obs_jit("prefill_prefix_pages", (aligned,),
                                self._prefix_pages_step, fargs)
-            t0 = time.perf_counter()
             self.cache = self._prefix_pages_step(*fargs)
-            js.finish(time.perf_counter() - t0)
         except Exception:
             self._pager.release(pages)
             raise
@@ -3496,8 +3495,7 @@ class InferenceEngine:
         a new (engine-config, name, key) signature bumps
         cake_jit_compiles_total{fn} and captures cost_analysis FLOPs /
         bytes from one extra lowering (trace only, no XLA compile) —
-        run NOW, before the dispatch consumes its donated buffers.
-        Callers time the dispatch and hand the wall to js.finish()."""
+        run NOW, before the dispatch consumes its donated buffers."""
         return self.flight.jit_step(
             name, key, lambda: obs_steps.lower_cost(fn, args, kwargs))
 
@@ -4572,13 +4570,11 @@ class InferenceEngine:
               "n_top": self.n_top}
         js = self._obs_jit("mixed_step", (step.shape[1] - 4, n_tokens),
                            self._mixed_step_fn, fargs, kw)
-        t0 = time.perf_counter()
         # the launch alone: the staging of the step, the options and a
         # first carry lies above it in the `dispatch` span
         with self.flight.part("launch"):
             (nxt, lp, tids, tlps, self.cache, self._keys, self._ring,
              carry, *moe) = self._mixed_step_fn(*fargs, **kw)
-        js.finish(time.perf_counter() - t0)
         # a step of several dispatches compiled if any of them did
         js.new |= self._last_jit is not None and self._last_jit.new
         self._last_jit = js
@@ -4740,12 +4736,15 @@ class InferenceEngine:
         marks = [time.perf_counter()]
         # (the launch is a part of the `dispatch` span; these belong to
         # no step)
-        with self.flight.span("dispatch"):
+        with STARTUP.phase("warm_steps"), self.flight.span("dispatch"):
             for bucket in self._mixed_buckets:
                 out, _carry = self._run_mixed_step(idle, None, bucket)
                 marks.append(time.perf_counter())
         self.flight.discard_open()
-        jax.block_until_ready(out)
+        # where start-up first waits for the device: for these runs and
+        # for all it launched before them, the weights' draw first
+        with STARTUP.phase("weights_ready"):
+            jax.block_until_ready(out)
         self._last_jit = None
         log.info("mixed step: sizes %s ready in %.2f s (traced and "
                  "loaded in %s s, then %.2f s for the device)",
@@ -5098,9 +5097,7 @@ class InferenceEngine:
             js = self._obs_jit("prefill_prefixed",
                                (width, int(pk.shape[2])),
                                prefill_slot_prefixed, fargs)
-            t0 = time.perf_counter()
             logits, self.cache = prefill_slot_prefixed(*fargs)
-            js.finish(time.perf_counter() - t0)
             self._last_jit = js
         return self._finish_prefill(logits, slot, len(ids), temp,
                                     top_p, penalty, prime, n_top=n_top,
@@ -5119,9 +5116,7 @@ class InferenceEngine:
         with self.flight.span("dispatch"):
             js = self._obs_jit("prefill_slot", (bucket,),
                                self._prefill_slot, fargs)
-            t0 = time.perf_counter()
             logits, self.cache = self._prefill_slot(*fargs)
-            js.finish(time.perf_counter() - t0)
             self._last_jit = js
         return logits
 
@@ -5202,9 +5197,7 @@ class InferenceEngine:
             with self.flight.span("dispatch"):
                 js = self._obs_jit("prefill_chunk", (C,),
                                    self._prefill_chunk_step, fargs)
-                t0 = time.perf_counter()
                 logits, self.cache = self._prefill_chunk_step(*fargs)
-                js.finish(time.perf_counter() - t0)
                 self._last_jit = js
         return logits
 
@@ -5276,7 +5269,6 @@ class InferenceEngine:
         (out, n_emit, self.cache, self.d_cache,
          self._keys) = self._spec_round_fn(*fargs)
         disp = time.perf_counter() - t0d
-        js.finish(disp)
         # ONE batched fetch for every row's round
         t0f = time.perf_counter()
         out_h, n_emit_h = jax.device_get((out, n_emit))
@@ -5411,9 +5403,7 @@ class InferenceEngine:
                  self.d_cache, sp.rope, sp.draft_config)
         js = self._obs_jit("spec_draft_prefill", (bucket,),
                            self._prefill_slot, fargs)
-        t0 = time.perf_counter()
         _logits, self.d_cache = self._prefill_slot(*fargs)
-        js.finish(time.perf_counter() - t0)
         self._last_jit = js
         return True
 
@@ -5626,11 +5616,9 @@ class InferenceEngine:
         with self.flight.span("dispatch"):
             js = self._obs_jit("decode_step", (), self._decode_step,
                                fargs)
-            t0 = time.perf_counter()
             with self.flight.part("launch"):
                 logits, self.cache, *moe = self._decode_step(*fargs)
             self._moe_pending += moe
-            js.finish(time.perf_counter() - t0)
             self._last_jit = js
         if self._multihost:
             logits = np.asarray(logits)  # see _finish_prefill
@@ -5914,11 +5902,9 @@ class InferenceEngine:
             js = self._obs_jit(
                 "decode_scan" if n > 1 else "decode_step_sampled",
                 (n, n_top), self._decode_scan_impl, fargs, fkw)
-            t0 = time.perf_counter()
             with self.flight.part("launch"):
                 (toks, lps, tops_i, tops_l, self.cache, keys_o, ring_o,
                  state_o, *moe) = self._decode_scan_impl(*fargs, **fkw)
-            js.finish(time.perf_counter() - t0)
             self._last_jit = js
         if self._multihost:
             keys_h, ring_h = jax.device_get((keys_o, ring_o))
