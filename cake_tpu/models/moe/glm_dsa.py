@@ -117,14 +117,16 @@ EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 GATE_LEAF = "w_attn_gate"
 # the record keys of the vector a step program returns, in trunk's
 # order: the expert counters' five (the held experts' rows alone), the
-# routed rows and the indexer's (the last two: the keys the window's
-# selections walked, ops/mla_attention.select_walked, and the table's
-# width beside them); a model with sliding layers appends
-# SWA_COUNTERS, and the dsa_* then count its full layers alone
+# routed rows and the indexer's (the last three: the keys the window's
+# selections walked, ops/mla_attention.select_walked, the table's
+# width beside them, and the keys its score pass visited,
+# index_scored); a model with sliding layers appends SWA_COUNTERS, and
+# the dsa_* then count its full layers alone
 COUNTERS = paged.MOE_COUNTERS + (
     "moe_rows_routed", "dsa_keys_visible", "dsa_keys_selected",
     "dsa_rows_distinct", "dsa_index_layers", "dsa_index_reused",
-    "dsa_select_keys_walked", "dsa_select_keys_table")
+    "dsa_select_keys_walked", "dsa_select_keys_table",
+    "dsa_index_keys_scored")
 SWA_COUNTERS = ("swa_keys_visible", "swa_keys_attended", "swa_layers")
 N_COUNTERS = len(COUNTERS)
 # a model whose layers have no indexer (deepseek_v2): the expert
@@ -247,14 +249,6 @@ def unabsorb_value(o_lat, wkv_b_v):
     return out.astype(o_lat.dtype)
 
 
-def _key_block(max_pages: int, page: int) -> int:
-    """Keys a block of the window's score pass holds: whole pages, a
-    divisor of the row's page count, about a thousand keys."""
-    per = max(d for d in range(1, max_pages + 1)
-              if max_pages % d == 0 and d * page <= max(1024, page))
-    return per * page
-
-
 class Selection(NamedTuple):
     """The key sets a full indexer layer leaves for the shared layers
     after it. idx [B, K] / n_valid [B]: each row's SINGLE token's
@@ -312,8 +306,7 @@ def select_keys(lp, h, c_q, cos, sin, slot, position, real, first,
         if window is not None:
             win = mla.index_scores_window(
                 _window_slice(qI, window), keys[window.row],
-                _window_slice(w, window), window.last_pos,
-                _key_block(max_pages, P))
+                _window_slice(w, window), window.last_pos)
     with jax.named_scope("index_topk"):
         _, idx = lax.top_k(rows, K)
         n_valid = jnp.minimum(rows_pos + 1, K).astype(jnp.int32)
@@ -691,9 +684,11 @@ def trunk(params, token_ids, slot, position, real, cache: PagedKVCache,
                         dtype=jnp.float32)]
     else:
         S = table.shape[1] * pool_lat.shape[2]
-        # what the window's selections walked, and the table's width
-        walked = [0, 0] if window is None else [
-            Lf * mla.select_walked(window.last_pos, window.width, S), Lf * S]
+        # what the window's selections walked, the table's width, and
+        # the keys their score passes visited
+        walked = [0, 0, 0] if window is None else [
+            Lf * mla.select_walked(window.last_pos, window.width, S), Lf * S,
+            Lf * mla.index_scored(window.last_pos, S)]
         counters += [
             L * jnp.sum(visible),
             L * jnp.sum(jnp.minimum(visible, min(c.index_topk, S))),

@@ -23,9 +23,13 @@ with its residual:
   * `indexer`: the index query from the normed hidden state (16 heads
     of 64, rotated whole by frequencies of their own width), the key
     (LayerNorm, rotated) written into `idx`, and the scores of every
-    visible key of the token's row in float32
-    (ops/mla_attention.index_scores_rows / index_scores_window: GLM's
-    and dots3's); `index_topk`: the exact top `index_topk`, ties to the
+    visible key of the token's row in float32: a row's single token
+    by ops/mla_attention.index_scores_rows, the dispatch's window by
+    index_scores_window (`cake_dsa_index`: one kernel that stores the
+    window's [queries, keys] scores where it computes them and visits
+    the key blocks up to the window's last position, the rest written
+    zeros; GLM's and dots3's too); `index_topk`: the exact top
+    `index_topk`, ties to the
     lower index, as a MASK over the row's table
     (ops/mla_attention.select_window, `cake_dsa_select`, whose work
     follows the last position it is given and not the table's width):
@@ -77,7 +81,7 @@ from cake_tpu.models.llama.paged import PagedKVCache, write_token_rows
 from cake_tpu.models.moe import glm_dsa
 from cake_tpu.models.moe.config import KeyeVL2Config
 from cake_tpu.models.moe.exaone_moe import _resolve_attn, query_tile
-from cake_tpu.models.moe.glm_dsa import _key_block, _layernorm, _window_slice
+from cake_tpu.models.moe.glm_dsa import _layernorm, _window_slice
 from cake_tpu.models.moe.nemotron_h import (
     Rows, Window, dequantized, window_of,
 )
@@ -103,7 +107,7 @@ COUNTERS = paged.MOE_COUNTERS + (
     "dsa_rows_distinct", "dsa_index_layers", "dsa_keys_single",
     "dsa_keys_scanned_single", "gqa_rows_single", "gqa_full_pages_live",
     "dsa_select_keys_walked", "dsa_select_keys_table",
-    "dsa_walk_pages_single", "dsa_walk_rows_single")
+    "dsa_index_keys_scored", "dsa_walk_pages_single", "dsa_walk_rows_single")
 F32 = jnp.float32
 
 
@@ -176,8 +180,7 @@ def select_keys(lp, h, cos, sin, slot, position, real, first, single_pos,
         if window is not None:
             win = mla.index_scores_window(
                 _window_slice(qI, window), keys[window.row],
-                _window_slice(w, window), win_last,
-                _key_block(max_pages, P))
+                _window_slice(w, window), win_last)
     with jax.named_scope("index_topk"):
         # the rows' sets by the window's kernel: ONE tile of B queries,
         # each with its own scores and position (a row sees the keys at
@@ -341,9 +344,11 @@ def trunk(params, token_ids, slot, position, real, rows: Rows,
     single = rows.n == 1
     n_single = jnp.sum(single, dtype=F32)
     last = rows.pos + rows.n - 1
-    # what the window's selection walked, and the table's width
-    walked = [0, 0] if window is None else [
-        L * mla.select_walked(win_last, window.width, S), L * S]
+    # what the window's selection walked, the table's width, and the
+    # keys its score pass visited
+    walked = [0, 0, 0] if window is None else [
+        L * mla.select_walked(win_last, window.width, S), L * S,
+        L * mla.index_scored(win_last, S)]
     counters = jnp.stack([
         jnp.sum(moe.rows), jnp.sum(moe.rows_padded), jnp.mean(moe.load_max),
         jnp.mean(moe.load_mean), jnp.sum(moe.touched),

@@ -370,6 +370,12 @@ DSA_COUNTERS = (
         "Keys the table is wide for those windows and layers (over it, "
         "cake_dsa_select_keys_walked_total: the share of the table a "
         "selection costs)")),
+    ("dsa_index_keys_scored", _m.counter(
+        "cake_dsa_index_keys_scored_total",
+        "Keys in the blocks cake_dsa_index visited (up to the window's "
+        "last position), summed over windows and indexer layers (over "
+        "cake_dsa_select_keys_table_total: the share of the table a "
+        "score pass costs)")),
 )
 # a model with recurrent blocks (models/moe/nemotron_h.trunk): the rows'
 # recurrent state and the two forms of the scan

@@ -76,10 +76,36 @@ keys 667 -> 358 us a call at 128 heads (0.64 -> 0.35 us a page), 499 ->
 
 `index_scores_rows`. I[b, s] = sum_j w[b, j] relu(qI[b, j] . kI[b, s])
 for one query a row against that row's whole key range, float32: the
-decode rows' pass. `index_scores_window` is the same for a window of C
-queries against ONE row's keys, computed in blocks of keys so that the
-[C, heads, block] intermediate stays small; blocks past the window's
-last position are skipped.
+decode rows' pass (XLA). `index_scores_window` is the same for a window
+of C queries against ONE row's keys, by ONE kernel (`cake_dsa_index`;
+what the step programs call, under the scope `indexer`):
+
+  * grid (C // tq, S // kb) (`index_tiles`: 512 x 512 at the cells'
+    shapes): a tile's queries, every head of them, and their weights
+    stay in VMEM while the key axis runs; a block of kb keys is
+    streamed; a head at a time, [tq, d] x [d, kb] on the matrix unit
+    into float32, then relu, the head's weight and the sum over heads
+    on the vector unit, and the tile is stored where it belongs in
+    [C, S]: no [heads, C, block] intermediate, no stack of blocks and
+    no transpose in HBM;
+  * bounded by the window's last position: a key block that starts
+    past it is not fetched (its index map names the last live block
+    again) and not computed, and its tile is written zeros, which
+    nobody may select (`index_scored` counts the keys of the blocks
+    visited).
+
+The blocked `lax.map` it replaced (a `lax.cond` a block, the blocks
+stacked [blocks, C, block] and transposed: four XLA ops, 0.44 s of a
+3 s capture in Keye's cell, ledger PR 67) lives in
+tests/test_dsa_index_kernel.py and tools/dsa_index_bench.py alone.
+Alone on the chip at a window of 512 (my chip run, PR 68; that tool):
+Keye's 16 heads of 64 over 33,280 keys at a context of 8k 519 -> 159
+us, 32k 920 -> 417; GLM's 32 of 128 over 12,800 at 8k 425 -> 234;
+dots3's 64 of 128 over 16,896 at 16k 1,052 -> 775. The matrix unit
+passes 8 rows of a 128-wide tile in 8 cycles whatever the contraction's
+depth, so a live step of 512 x 512 costs heads x 512 passes (Keye 5.5
+us by count, 6.5 read; its 64-deep heads fill half of each pass), and a
+step past the bound the 1 MB of zeros it writes, 1.0 us.
 
 `select_window`. The window's top-k as a [C, S] mask, exact, ties at
 the k-th value to the lower index, by ONE kernel (`cake_dsa_select`;
@@ -946,20 +972,150 @@ def index_scores_rows(qI, kI, w):
     return _weighted_relu(qI[:, None], kI, w[:, None])[:, 0]
 
 
-def index_scores_window(qI, kI, w, last_pos, block: int):
-    """A window's queries against one row's keys: qI [C, J, d],
-    kI [S, d] (S a multiple of block), w [C, J] -> I [C, S] float32.
-    Key blocks that start past `last_pos` (the window's last position:
-    nothing there is visible to any query) are zeros."""
-    C = qI.shape[0]
+# What the score kernel asks of the core's VMEM, what of it a call's
+# tiles may take by `index_vmem_bytes`' count, and the most 128-key
+# chunks a key block holds.
+_INDEX_VMEM_LIMIT = 48 * 2**20
+_INDEX_TILE_BYTES = 24 * 2**20
+_INDEX_BLOCK_CHUNKS = 4
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def index_vmem_bytes(tq: int, J: int, d: int, kb: int, itemsize: int) -> int:
+    """VMEM one step of `_index_kernel` holds: the query tile
+    [tq, J * d] and its weights [tq, J] float32, a key block [kb, d]
+    and the result tile [tq, kb] float32, two buffers each (the
+    pipeline's), and two more [tq, kb] float32 for a head's products
+    and the running sum. A minor axis takes whole vectors of 128
+    lanes."""
+    return (2 * tq * _lanes(J * d) * itemsize + 2 * tq * _lanes(J) * 4
+            + 2 * kb * _lanes(d) * itemsize + 4 * tq * _lanes(kb) * 4)
+
+
+def index_key_block(S: int) -> int:
+    """Keys one step of the score kernel streams and one step of its
+    bound skips: the most whole chunks up to _INDEX_BLOCK_CHUNKS that
+    divide the table (a chunk is 128 keys; 8 in a table that is no
+    multiple of 128, a test's): 512 at 260, 100 and 132 chunks."""
+    chunk = 128 if S % 128 == 0 else 8 if S % 8 == 0 else S
+    return chunk * max(n for n in range(1, _INDEX_BLOCK_CHUNKS + 1)
+                       if (S // chunk) % n == 0)
+
+
+def index_tiles(C: int, J: int, d: int, S: int, itemsize: int = 2):
+    """(tq, kb) of a score call, from its shapes alone. kb:
+    `index_key_block`. tq: the queries whose heads stay resident over
+    the key axis, the widest of 512, 256, .. 8 that divides C under
+    _INDEX_TILE_BYTES by `index_vmem_bytes` (where none divides, the
+    window is one tile): 512 at the three cells' shapes (16 heads of
+    64, 32 of 128, 64 of 128: 6.8, 12.8 and 20.8 MB)."""
+    kb = index_key_block(S)
+    tq = next((t for t in (512, 256, 128, 64, 32, 16, 8)
+               if C % t == 0 and index_vmem_bytes(t, J, d, kb, itemsize)
+               <= _INDEX_TILE_BYTES), C)
+    return tq, kb
+
+
+def index_scored(last_pos, S: int):
+    """Keys of the blocks a score call visits for a window that ends at
+    last_pos (of the table's S): the step programs' count of what
+    `_index_kernel` does (dsa_index_keys_scored)."""
+    kb = index_key_block(S)
+    return (jnp.clip(last_pos, 0, S - 1) // kb + 1) * kb
+
+
+def _index_kernel(last_ref, q_ref, w_ref, k_ref, o_ref, *, heads: int):
+    """One grid step: a TILE of tq queries against ONE block of kb
+    keys. q_ref [tq, heads * d] (a query's heads side by side) and
+    w_ref [tq, heads] float32 are the tile's and stay while the key
+    axis runs; k_ref [kb, d] is the block the index map brought: the
+    step's own where it starts at or before last_ref[0], else the last
+    live one again, which the pipeline does not fetch a second time.
+    A live block: a head at a time, [tq, d] x [d, kb] on the matrix
+    unit into float32, the relu, the head's weight and the sum over
+    heads in ascending order on the vector unit, all float32; the tile
+    is stored where it belongs in [C, S]. A block past the bound is
+    written zeros and nothing of it was read."""
+    kb = o_ref.shape[1]
+    d = q_ref.shape[1] // heads
+    live = pl.program_id(1) * kb <= last_ref[0]
+
+    @pl.when(live)
+    def _():
+        keys = k_ref[...]
+        acc = None
+        for j in range(heads):
+            dots = rpa._dot(q_ref[:, j * d:(j + 1) * d], keys, trans_b=True)
+            term = w_ref[:, j:j + 1] * jnp.maximum(dots, 0.0)
+            acc = term if acc is None else acc + term
+        o_ref[...] = acc
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _index_pallas(qI, kI, w, last_pos, *, interpret: bool):
+    C, J, d = qI.shape
     S = kI.shape[0]
-    blocks = kI.reshape(S // block, block, kI.shape[-1])
+    tq, kb = index_tiles(C, J, d, S, qI.dtype.itemsize)
+    last = jnp.clip(last_pos, 0, S - 1).astype(jnp.int32).reshape(1)
+    return pl.pallas_call(
+        functools.partial(_index_kernel, heads=J),
+        name="cake_dsa_index",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(C // tq, S // kb),
+            in_specs=[
+                pl.BlockSpec((tq, J * d), lambda i, j, last: (i, 0)),
+                pl.BlockSpec((tq, J), lambda i, j, last: (i, 0)),
+                pl.BlockSpec(
+                    (kb, d),
+                    lambda i, j, last: (jnp.minimum(j, last[0] // kb), 0))],
+            out_specs=pl.BlockSpec((tq, kb), lambda i, j, last: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((C, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_INDEX_VMEM_LIMIT),
+        interpret=interpret,
+    )(last, qI.reshape(C, J * d), w.astype(jnp.float32), kI)
 
-    def one(args):
-        i, kb = args
-        return lax.cond(i * block <= last_pos,
-                        lambda: _weighted_relu(qI, kb, w),
-                        lambda: jnp.zeros((C, block), jnp.float32))
 
-    out = lax.map(one, (jnp.arange(S // block), blocks))    # [n, C, block]
-    return jnp.transpose(out, (1, 0, 2)).reshape(C, S)
+def index_scores_window(qI, kI, w, last_pos,
+                        interpret: Optional[bool] = None):
+    """A window's queries against one row's keys, by one kernel
+    (`cake_dsa_index`): qI [C, J, d], kI [S, d] (the pool's dtype, as
+    qI's), w [C, J] -> I [C, S] float32,
+
+        I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])
+
+    every product, the relu, the weights and the sum in float32. Key
+    blocks that start past `last_pos` (the window's last position:
+    nothing there is visible to any query) are zeros, and are neither
+    read nor computed: a call costs what the window's context costs
+    (`index_scored`), not what the table is wide. `_index_kernel` says
+    how; `index_tiles` what it holds."""
+    C, J, d = qI.shape
+    S = kI.shape[0]
+    if kI.shape != (S, d) or w.shape != (C, J) or kI.dtype != qI.dtype:
+        raise ValueError(
+            f"cake_dsa_index takes queries [C, J, d], keys [S, d] of their "
+            f"dtype and weights [C, J]: got {qI.dtype}{list(qI.shape)}, "
+            f"{kI.dtype}{list(kI.shape)}, {list(w.shape)}")
+    if interpret is None:
+        interpret = not rpa._on_tpu()
+    tq, kb = index_tiles(C, J, d, S, qI.dtype.itemsize)
+    need = index_vmem_bytes(tq, J, d, kb, qI.dtype.itemsize)
+    if need > _INDEX_TILE_BYTES or (not interpret
+                                    and (S % 128 or (J * d) % 128)):
+        raise ValueError(
+            f"cake_dsa_index cannot run at a window of {C} queries of {J} "
+            f"heads of {d} over {S} keys: a tile of {tq} queries against "
+            f"{kb} keys is {need} bytes of {_INDEX_TILE_BYTES}, and on the "
+            f"chip the table and a query's {J * d} numbers must be "
+            f"multiples of 128 (index_tiles)")
+    return _index_pallas(qI, kI, w, last_pos, interpret=interpret)

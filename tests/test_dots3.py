@@ -549,7 +549,8 @@ def test_counters_count_what_the_reference_attends(model):
              "moe_experts_touched", "moe_rows_routed", "dsa_keys_visible",
              "dsa_keys_selected", "dsa_rows_distinct", "dsa_index_layers",
              "dsa_index_reused", "dsa_select_keys_walked",
-             "dsa_select_keys_table", "swa_keys_visible",
+             "dsa_select_keys_table", "dsa_index_keys_scored",
+             "swa_keys_visible",
              "swa_keys_attended", "swa_layers")
     got = dict(zip(names, counters.tolist()))
     assert len(counters) == glm_dsa.N_COUNTERS + len(obs_steps.SWA_COUNTERS)
@@ -562,6 +563,8 @@ def test_counters_count_what_the_reference_attends(model):
     # 3 windows x 2 full layers over a table of one block
     assert (got["dsa_select_keys_walked"] == got["dsa_select_keys_table"]
             == 3 * 2 * MAX_SEQ)
+    # ... and score passes of one block too (index_tiles: 4 chunks of 8)
+    assert got["dsa_index_keys_scored"] == 3 * 2 * min(MAX_SEQ, 32)
     assert c.family.counters == names
     assert names[-3:] == tuple(k for k, _ in obs_steps.SWA_COUNTERS)
 

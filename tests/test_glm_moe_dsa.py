@@ -322,14 +322,16 @@ def test_window_kernel_is_the_fold_under_a_selection(C_, last):
 
 
 def test_window_scores_are_the_rows_scores():
-    """The window's blocked score pass and the one-query-a-row pass are
-    one function of (query, key); blocks past the window's end are
-    skipped (zeros nobody may select)."""
-    C_, J, d, S = 5, 2, 16, 32
+    """The window's score kernel and the one-query-a-row pass are one
+    function of (query, key); blocks past the window's end are skipped
+    (zeros nobody may select)."""
+    C_, J, d, S = 5, 2, 16, 48
     qI = jax.random.normal(jax.random.PRNGKey(6), (C_, J, d))
     kI = jax.random.normal(jax.random.PRNGKey(7), (S, d))
     w = jax.random.normal(jax.random.PRNGKey(8), (C_, J))
-    win = mla.index_scores_window(qI, kI, w, jnp.int32(19), block=8)
+    # two blocks of 24 keys: the second starts past position 19
+    assert mla.index_tiles(C_, J, d, S) == (C_, 24)
+    win = mla.index_scores_window(qI, kI, w, jnp.int32(19))
     rows = mla.index_scores_rows(qI, jnp.broadcast_to(kI, (C_, S, d)), w)
     np.testing.assert_allclose(win[:, :24], rows[:, :24], rtol=1e-5,
                                atol=1e-5)
@@ -548,6 +550,9 @@ def test_step_records_name_the_attention_and_carry_the_counters(engine_run):
         # the windows' selections: the mixed dispatches' alone
         assert (r["dsa_select_keys_walked"] > 0) == (r["kind"] == "mixed")
         assert r["dsa_select_keys_walked"] <= r["dsa_select_keys_table"]
+        # ... and their score passes'
+        assert (r["dsa_index_keys_scored"] > 0) == (r["kind"] == "mixed")
+        assert r["dsa_index_keys_scored"] <= r["dsa_select_keys_table"]
     assert any(r.get("chained") for r in records if r["kind"] == "decode")
     assert all(v > 0 for v in moved.values()), moved
     assert eng._mixed_buckets == (32,) and not eng._prefix_capable

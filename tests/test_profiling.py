@@ -271,7 +271,7 @@ def test_every_pallas_call_site_is_named():
             # the call's own argument list, up to the operands' call
             named += 'name="cake_' in text[m.end():m.end() + 1200].split(
                 ")(")[0]
-    assert sites == named == 14
+    assert sites == named == 15
 
 
 def test_scopes_leave_the_compiled_program_unchanged(monkeypatch):

@@ -750,6 +750,87 @@ def test_keyes_mixed_program_holds_two_select_calls_a_layer(keyes_programs):
                        + "; ".join(under))
 
 
+def _indexers_window_pass(hlo, S):
+    """(the `cake_dsa_index` calls, what is left of the blocked map) of
+    a mixed program's optimised HLO over a table of S keys: the calls'
+    lines, and every instruction that gives a stack of score blocks
+    ([blocks, 512, block] float32, or one block of it), a copy or a
+    transpose of the window's [512, S] scores, or a loop or a branch
+    under the scope `indexer`."""
+    # (by the instruction's own name: `cake_dsa_select`'s line names
+    # the scores too, as its operand)
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*(?:ROOT )?%cake_dsa_index[\w.\-]* = ", line)]
+    left = [line.strip()[:200] for line in hlo.splitlines()
+            if re.search(r"= f32\[512,%d\]\S* (?:copy|transpose)\(" % S, line)
+            or ("/indexer/" in line and re.search(
+                r"= \(?f32\[\d+,512,\d+\]| (?:while|conditional)\(", line))]
+    return calls, left
+
+
+def test_keyes_mixed_program_scores_its_window_in_one_call(keyes_programs):
+    """ONE `cake_dsa_index` call in the mixed program's layer scan,
+    under the scope `indexer`, whose f32[512,33280] result is the
+    window's `cake_dsa_select`'s operand as it lies: no stack of score
+    blocks (f32[52,512,640] at the parent), no block of one
+    (f32[1,512,640]), no transposing copy of f32[512,33280], no loop
+    or branch under `indexer`. The decode program holds no such call:
+    its rows' pass is `index_scores_rows` as it was."""
+    hlo = keyes_programs["mixed"]
+    calls, left = _indexers_window_pass(hlo, 33280)
+    assert len(calls) == 1 and "/indexer/" in calls[0]
+    assert "tpu_custom_call" in calls[0]
+    scores = re.match(r"\s*(%[\w.\-]+) = f32\[512,33280\]", calls[0])
+    assert scores, calls[0][:200]
+    select = [line for line in hlo.splitlines()
+              if "cake_dsa_select" in line and "s8[512,33280]" in line
+              and "custom-call(" in line]
+    assert len(select) == 1 and scores.group(1) + ")" in select[0]
+    assert not left, "the blocked map under indexer again: " + "; ".join(left)
+    assert "cake_dsa_index" not in keyes_programs["decode"]
+
+
+@pytest.mark.parametrize("cell,cut,S", [
+    ("glm-5.2-int8-share16", dict(indexer_types=("full",)), 12800),
+    ("dots3-note-int8-share8", dict(indexer_types=("full",)), 16896)],
+    ids=["glm52", "dots3"])
+def test_latent_mixed_programs_score_their_window_in_one_call(
+        tool, one_chip, cell, cut, S):
+    """GLM's and dots3's mixed programs at the cells' widths (ONE full
+    indexer layer with a dense FFN: every full layer is alike) for the
+    described v5e: one `cake_dsa_index` call under `indexer`, at 32
+    heads of 128 over 12,800 keys and at 64 of 128 over 16,896, through
+    Mosaic at the tiles `index_tiles` picks; its result is
+    `cake_dsa_select`'s operand, and nothing of the blocked map is left
+    (`_indexers_window_pass`)."""
+    import json
+
+    from cake_tpu.models.llama.config import load_config
+
+    cell = CONFIGS / cell
+    config = dataclasses.replace(
+        load_config(str(cell)), num_hidden_layers=1,
+        mlp_layer_types=("dense",), **cut)
+    with open(cell / "cell.json") as f:
+        sa = json.load(f)["server_args"]
+    width = sa["prefill-chunk"]
+    mixed = tool.step_fns(config)[1]
+    with jax.default_matmul_precision("default"):
+        hlo = tool.compile_step(
+            mixed, config, one_chip, width=width,
+            n_tokens=width + sa["max-slots"], slots=sa["max-slots"],
+            n_pages=sa["kv-pages"], page_size=sa["kv-page-size"],
+            max_seq_len=sa["max-seq-len"]).as_text()
+    calls, left = _indexers_window_pass(hlo, S)
+    assert len(calls) == 1 and "/indexer/" in calls[0]
+    scores = re.match(r"\s*(%%[\w.\-]+) = f32\[512,%d\]" % S, calls[0])
+    assert scores, calls[0][:200]
+    select = [line for line in hlo.splitlines()
+              if "cake_dsa_select" in line and "custom-call(" in line]
+    assert len(select) == 1 and scores.group(1) + ")" in select[0]
+    assert not left, "the blocked map under indexer again: " + "; ".join(left)
+
+
 @pytest.mark.parametrize("program", ["decode", "mixed"])
 def test_keyes_single_token_rows_attend_where_their_keys_lie(
         keyes_programs, program):
